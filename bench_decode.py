@@ -38,6 +38,8 @@ def _decode_tps(engine, n_seqs, prompt_len, new_tokens, *, sequential):
 
 
 def main():
+    from ray_tpu._private import compile_cache
+    compile_cache.place()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--config", default="gpt2-small")
     ap.add_argument("--lanes", type=int, default=32)

@@ -32,6 +32,8 @@ from __future__ import annotations
 import argparse
 import json
 import os
+import subprocess
+import sys
 import threading
 import time
 
@@ -198,12 +200,29 @@ def main():
     ap.add_argument("--prefix-len", type=int, default=96)
     ap.add_argument("--budget", type=int, default=8)
     ap.add_argument("--concurrency", type=int, default=6)
+    ap.add_argument("--phase", choices=["engines"], default=None,
+                    help=argparse.SUPPRESS)
     args = ap.parse_args()
 
-    exact = check_token_exact()
+    if args.phase == "engines":
+        rate_cold, _ = run_hit_rate(with_tier=False)
+        rate_tier, st_tier = run_hit_rate(with_tier=True)
+        print(json.dumps({"exact": check_token_exact(),
+                          "rate_cold": rate_cold, "rate_tier": rate_tier,
+                          "st_tier": st_tier}))
+        return
 
-    rate_cold, _ = run_hit_rate(with_tier=False)
-    rate_tier, st_tier = run_hit_rate(with_tier=True)
+    # The in-process engine phases run in a fresh interpreter: this
+    # process goes on to start replica processes that need the device, so
+    # it must never touch jax itself.
+    p = subprocess.run([sys.executable, os.path.abspath(__file__),
+                        "--phase", "engines"], capture_output=True,
+                       text=True, timeout=600)
+    if p.returncode != 0:
+        raise SystemExit(f"engine phases failed:\n{p.stderr[-2000:]}")
+    eng = json.loads(p.stdout.strip().splitlines()[-1])
+    exact, st_tier = eng["exact"], eng["st_tier"]
+    rate_cold, rate_tier = eng["rate_cold"], eng["rate_tier"]
     assert rate_tier > rate_cold, (
         f"spill tier did not raise hit rate: {rate_tier} <= {rate_cold}")
 

@@ -44,6 +44,8 @@ def _measure(engine, prompt, new_tokens):
 
 
 def main():
+    from ray_tpu._private import compile_cache
+    compile_cache.place()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--config", default="gpt2-small")
     ap.add_argument("--prefix-len", type=int, default=512,

@@ -32,6 +32,8 @@ import argparse
 import gc
 import json
 import os
+import subprocess
+import sys
 import time
 
 
@@ -115,11 +117,18 @@ def bench_publish_vs_rollout(args):
     # the first drafted steps) outside the timed loop.
     ray_tpu.get([a.rollout.remote(prompts, args.new_tokens)
                  for a in actors])
-    # Publish real params: build one local engine for the payload tree.
-    from ray_tpu.rl.rollout import EngineRolloutActor as _Local
-    local = _Local("gpt", args.config, max_lanes=1, temperature=0.0,
-                   seed=0)
-    weights = local.engine.params
+    # Publish real params.  A worker builds the payload tree: this
+    # driver's children hold the devices, so it must not touch jax.
+    @ray_tpu.remote(num_cpus=1)
+    def payload(config):
+        import jax
+        import numpy as np
+
+        from ray_tpu.models import gpt
+        return jax.tree.map(np.asarray, gpt.init_params(
+            gpt.CONFIGS[config], jax.random.key(0)))
+
+    weights = ray_tpu.get(payload.remote(args.config))
     publisher = WeightPublisher()
     rollout_wall = publish_wall = 0.0
     tokens = 0
@@ -134,7 +143,8 @@ def bench_publish_vs_rollout(args):
         tokens += sum(m["tokens"] for _b, _v, m in out)
         for _b, v, _m in out:
             assert v == round_i + 1, "gang missed a version boundary"
-    local.engine.shutdown()
+    for a in actors:
+        ray_tpu.kill(a)
     return {
         "gang_size": args.gang_size,
         "rounds": args.rounds,
@@ -178,6 +188,8 @@ def bench_learner_vs_staleness(args):
 
 
 def main():
+    from ray_tpu._private import compile_cache
+    compile_cache.place()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--config", default="nano")
     ap.add_argument("--prompt-len", type=int, default=32)
@@ -188,18 +200,39 @@ def main():
     ap.add_argument("--gang-lanes", type=int, default=8)
     ap.add_argument("--rounds", type=int, default=5)
     ap.add_argument("--learner-window-s", type=float, default=6.0)
+    ap.add_argument("--phase", choices=["spec", "publish", "learner"],
+                    default=None, help=argparse.SUPPRESS)
     args = ap.parse_args()
 
-    import ray_tpu
+    if args.phase == "spec":
+        print(json.dumps(bench_rollout_spec(args)))
+        return
+    if args.phase is not None:
+        import ray_tpu
+        ray_tpu.init(num_cpus=max(4, args.gang_size + 2),
+                     object_store_memory=128 << 20)
+        try:
+            fn = (bench_publish_vs_rollout if args.phase == "publish"
+                  else bench_learner_vs_staleness)
+            print(json.dumps(fn(args)))
+        finally:
+            ray_tpu.shutdown()
+        return
 
-    spec_rows = bench_rollout_spec(args)
-    ray_tpu.init(num_cpus=max(4, args.gang_size + 2),
-                 object_store_memory=128 << 20)
-    try:
-        pub = bench_publish_vs_rollout(args)
-        learner_rows = bench_learner_vs_staleness(args)
-    finally:
-        ray_tpu.shutdown()
+    def run(phase):
+        """Each phase in a fresh interpreter: the in-process engines, the
+        engine gang and the driver-side learner each need the device, and
+        a process that has touched jax keeps it from the next."""
+        p = subprocess.run(
+            [sys.executable, os.path.abspath(__file__), "--phase", phase]
+            + sys.argv[1:], capture_output=True, text=True, timeout=1800)
+        if p.returncode != 0:
+            raise SystemExit(f"{phase} phase failed:\n{p.stderr[-2000:]}")
+        return json.loads(p.stdout.strip().splitlines()[-1])
+
+    spec_rows = run("spec")
+    pub = run("publish")
+    learner_rows = run("learner")
 
     top = next(r for r in spec_rows if r["lanes"] == 1)
     doc = {

@@ -88,6 +88,8 @@ def _run(engine, prompts, new_tokens):
 
 
 def main():
+    from ray_tpu._private import compile_cache
+    compile_cache.place()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--config", default="nano",
                     help="model config (nano keeps the number tracking "

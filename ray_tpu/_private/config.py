@@ -178,12 +178,14 @@ _D("kv_tier_store_blocks", 1024, int,
    "object-store/disk tier capacity in KV blocks (LRU beyond this "
    "is dropped for real); 0 disables the second tier")
 # -- train fault tolerance -------------------------------------------------
-_D("train_hang_timeout_s", 60.0, float,
+_D("train_hang_timeout_s", 600.0, float,
    "gang declared hung when NO worker makes observable progress (a "
    "consumed report or an advanced step beacon) for this long; the "
    "watchdog then collects per-rank stacks and fails the gang instead "
    "of waiting in a collective forever.  Must exceed the slowest "
-   "legitimate train step.")
+   "legitimate train step, and the first step includes its compile: "
+   "gpt2-small's takes 45 s cold on a v5e chip after ~40 s of start-up, "
+   "which the earlier 60 s default would have called a hang.")
 _D("train_beacon_poll_s", 5.0, float,
    "how often the driver-side watchdog polls worker step beacons while "
    "blocked waiting on gang reports")

@@ -3066,6 +3066,8 @@ class _KeyScheduler:
             except Exception as e:
                 raise _RetryableSubmitError(f"lease rpc failed: {e}",
                                             node.node_id)
+            if lease.get("permanent"):
+                raise ValueError(f"lease refused: {lease['reason']}")
             if not lease.get("granted"):
                 raise _RetryableSubmitError(
                     f"lease rejected: {lease.get('reason')}", node.node_id,

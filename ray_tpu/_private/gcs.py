@@ -723,6 +723,8 @@ class GcsServer:
         spec = info.creation_spec
         pg_id = spec.placement_group if spec is not None else None
         tried: set[NodeID] = set()
+        refused = None    # reason of a node's permanent lease refusal
+        refusers: set[NodeID] = set()
         attempt = 0
         # PG actors pend until the PG is removed (reference: PG-scheduled
         # work queues on the bundle indefinitely); non-PG actors give up
@@ -778,8 +780,11 @@ class GcsServer:
                 node = sched.pick_node(self._alive_nodes(), pick_demand,
                                        strategy="DEFAULT", exclude=tried)
             if node is None:
+                if refusers and tried <= refusers:
+                    break       # every feasible node refused for good
                 await self._wait_change(0.2)  # wait for capacity/new nodes
                 tried.clear()
+                tried.update(refusers)
                 continue
             job_int = int.from_bytes(
                 info.creation_spec.job_id.binary(), "little") \
@@ -802,6 +807,17 @@ class GcsServer:
                 await self._wait_change(0.2)
                 continue
             if not lease.get("granted"):
+                if lease.get("permanent"):
+                    # This node can never serve the demand (e.g. a chip
+                    # count it cannot bind).  Try the others (a bundle has
+                    # no others); when none is left, fail with the reason
+                    # instead of burning the attempt budget.
+                    refused = lease["reason"]
+                    refusers.add(node.node_id)
+                    tried.add(node.node_id)
+                    if pg_id is not None:
+                        break
+                    continue
                 if lease.get("reason") in ("busy", "resources"):
                     # Saturation is not a placement failure: the node
                     # queued us for its whole lease window and is still
@@ -844,6 +860,7 @@ class GcsServer:
                 self._bump("actors", info.actor_id)
                 return
             info.state = "ALIVE"
+            info.death_cause = ""
             info.address = worker_addr
             info.native_port = lease.get("native_port", 0)
             info.node_id = node.node_id
@@ -853,8 +870,15 @@ class GcsServer:
             logger.info("actor %s alive at %s", info.actor_id.hex()[:8],
                         worker_addr)
             return
+        else:
+            refused = None      # the attempts ran out; nothing broke out
         info.state = "DEAD"
-        info.death_cause = "scheduling failed after 100 attempts"
+        if refused is not None:
+            info.death_cause = f"lease refused: {refused}"
+        else:
+            info.death_cause = "scheduling failed after 100 attempts" + (
+                f"; last worker failure: {info.death_cause}"
+                if info.death_cause else "")
         info.version += 1
         self._bump("actors", info.actor_id)
 
@@ -923,6 +947,11 @@ class GcsServer:
         if (actor is not None and dead_addr and actor.address
                 and dead_addr != actor.address):
             return {"ok": True, "stale": True}
+        if actor is not None and actor.state == "RESTARTING":
+            # A worker died while (re)constructing the actor.  Scheduling
+            # is already retrying; keep what the worker said for the
+            # cause, should the retries run out.
+            actor.death_cause = req.get("reason", "")
         if actor is not None and actor.state in ("ALIVE", "PENDING"):
             if req.get("intentional"):
                 actor.state = "DEAD"

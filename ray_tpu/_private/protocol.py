@@ -233,6 +233,11 @@ def validate_options(opts: dict, for_actor: bool) -> dict:
             kind = "actor" if for_actor else "task"
             raise ValueError(f"invalid {kind} option {k!r}; allowed: {sorted(allowed)}")
         merged[k] = v
+    tpus = merged.get("num_tpus")
+    if tpus is not None and tpus != int(tpus):
+        raise ValueError(
+            f"num_tpus={tpus}: chips are leased whole — a chip belongs to "
+            f"one process at a time, so a fraction of one cannot be shared")
     return merged
 
 

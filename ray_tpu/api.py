@@ -15,6 +15,7 @@ import threading
 
 from ray_tpu.exceptions import ActorDiedError
 from ray_tpu.object_ref import ObjectRef
+from ray_tpu._private.accelerators import default_num_tpus
 from ray_tpu._private.ids import ActorID, JobID
 from ray_tpu._private.protocol import validate_options
 
@@ -432,7 +433,9 @@ class ActorClass:
 
     def remote(self, *args, **kwargs) -> ActorHandle:
         worker = _get_worker()
-        actor_id = worker.create_actor(self._cls, args, kwargs, self._options)
+        opts = dict(self._options, num_tpus=default_num_tpus(
+            self._cls, self._options.get("num_tpus")))
+        actor_id = worker.create_actor(self._cls, args, kwargs, opts)
         meta = {}
         for name, fn in inspect.getmembers(self._cls, inspect.isfunction):
             meta[name] = {"num_returns": 1}

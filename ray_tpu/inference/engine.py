@@ -45,6 +45,7 @@ from __future__ import annotations
 
 import collections
 import itertools
+import logging
 import queue
 import threading
 import time
@@ -55,9 +56,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private.accelerators import leased_chips, require_chip_lease
 from ray_tpu.inference.kv_cache import PagedKVCache
 from ray_tpu.util import events, spans
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
+
+logger = logging.getLogger(__name__)
 
 _DONE = object()
 
@@ -287,12 +291,20 @@ class InferenceEngine:
                  spec_adaptive: bool = True,
                  kv_tier: Optional[bool] = None,
                  capture_logp: bool = False):
+        require_chip_lease("InferenceEngine")
         self.model = _resolve_model(model)
         self.config = (self.model.CONFIGS[config] if isinstance(config, str)
                        else config)
+        device = jax.devices()[0]
+        self.backend = device.platform
+        self.device_kind = device.device_kind
+        logger.info("InferenceEngine on backend=%s device_kind=%s chips=%s",
+                    self.backend, self.device_kind, leased_chips() or "-")
         if params is None:
-            params = self.model.init_params(self.config,
-                                            jax.random.key(seed))
+            # One compiled program, not one dispatch per op: building a
+            # gpt2-small engine op by op took 55 s on a v5e chip.
+            params = jax.jit(self.model.init_params, static_argnums=0)(
+                self.config, jax.random.key(seed))
         self.params = params
         self.max_lanes = max_lanes
         self.prefill_chunk = prefill_chunk
@@ -332,6 +344,8 @@ class InferenceEngine:
         self._rid = itertools.count(1)
         self._step_fns: Dict = {}
         self._step_impls: Dict = {}   # un-jitted twins (shape introspection)
+        self._step_avals: Dict = {}   # argument shapes of each step's compile
+        self._step_compile_s: Dict = {}   # wall of each step's first call
         self._evictions_reported = 0
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
@@ -551,6 +565,9 @@ class InferenceEngine:
         cs = self.cache.stats
         st = self._spec_stats
         return {
+            "backend": self.backend,
+            "device_kind": self.device_kind,
+            "chips": list(leased_chips()),
             "active": self.num_active,
             "waiting": self.num_waiting,
             "max_lanes": self.max_lanes,
@@ -576,6 +593,29 @@ class InferenceEngine:
             "spec_accepted_per_step": (st["emitted"] / st["bursts"]
                                        if st["bursts"] else 0.0),
         }
+
+    def compiled_steps(self) -> dict:
+        """What XLA built for each step shape dispatched so far: seconds
+        its first call spent compiling, the number of Mosaic kernel calls
+        in the compiled program and the bytes of arguments updated in
+        place (the donated KV pools).
+        Recompiles each shape ahead of time (a persistent-cache hit where
+        the cache is on), so call it for a check, not per request."""
+        out = {}
+        # _step_compile_s is filled last, so its keys are complete steps
+        # even while the scheduler thread is adding a new shape.
+        for key, compile_s in list(self._step_compile_s.items()):
+            t, sample, spec = key
+            compiled = self._step_fns[key].lower(
+                *self._step_avals[key]).compile()
+            name = f"t{t}" + ("_sample" if sample else "") \
+                + ("_spec" if spec else "")
+            out[name] = {
+                "compile_s": round(compile_s, 2),
+                "custom_calls": compiled.as_text().count("tpu_custom_call"),
+                "donated_bytes":
+                    compiled.memory_analysis().alias_size_in_bytes}
+        return out
 
     # ---------------- scheduler ----------------
 
@@ -782,8 +822,13 @@ class InferenceEngine:
         t, sample, args = batch
         key = (t, sample, spec)
         fn = self._step_fns.get(key)
-        if fn is None:
+        first = fn is None
+        if first:
+            t0 = time.perf_counter()
             fn = self._step_fns[key] = self._make_step_fn(sample, spec)
+            self._step_avals[key] = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
+                (self.params, self.cache.k, self.cache.v, *args))
         if self._capture_logp:
             next_tok, logp, k, v = fn(self.params, self.cache.k,
                                       self.cache.v, *args)
@@ -791,6 +836,10 @@ class InferenceEngine:
             next_tok, k, v = fn(self.params, self.cache.k, self.cache.v,
                                 *args)
             logp = None
+        if first:
+            # The first call of a shape returns once it has compiled (the
+            # dispatch itself is asynchronous): its wall is compile time.
+            self._step_compile_s[key] = time.perf_counter() - t0
         self.cache.update_pools(k, v)
         return next_tok, logp
 
@@ -877,9 +926,9 @@ class InferenceEngine:
             return next_tok, k, v
 
         self._step_impls[(sample, "spec") if spec else sample] = step
-        # Donating the pools makes the cache update in-place on TPU; CPU
-        # ignores donation with a warning, so only ask for it on TPU.
-        donate = (1, 2) if jax.default_backend() == "tpu" else ()
+        # Donating the pools makes the cache update in place; the CPU
+        # backend ignores donation with a warning, so don't ask it.
+        donate = () if self.backend == "cpu" else (1, 2)
         return jax.jit(step, donate_argnums=donate)
 
     def _commit(self, live, chunks, toks, lps=None):

@@ -9,8 +9,9 @@ config):
     compiled block regardless of depth (fast compiles, small HLO);
   * every param leaf has a logical sharding spec (parallel.sharding rules
     decide DP/FSDP/TP placement);
-  * attention = flash (Pallas) on one chip, ring attention when the mesh has
-    a seq axis > 1;
+  * attention = flash (Pallas) on one chip and per shard (shard_map over
+    batch and heads) under a mesh, ring attention when the mesh has a seq
+    axis > 1;
   * optional Switch-style MoE MLP for expert parallelism;
   * `jax.checkpoint` (remat) on the block when configured — trades FLOPs for
     HBM, the standard TPU memory lever.
@@ -27,9 +28,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.ring_attention import ring_attention
-from ray_tpu.parallel.mesh import mesh_axis_size
+from ray_tpu.ops.attention import mesh_flash_attention
 from ray_tpu.parallel.sharding import (
     logical_to_spec, named_sharding, tree_shardings, with_logical_constraint)
 
@@ -215,10 +214,7 @@ def _block(x, p, config: GPTConfig, mesh):
     v = jnp.einsum("bld,dhk->blhk", h, p["wv"].astype(h.dtype))
     q = with_logical_constraint(q, ("batch", "length", "heads", "kv"),
                                 mesh=mesh)
-    if mesh is not None and mesh_axis_size(mesh, "seq") > 1:
-        attn = ring_attention(q, k, v, mesh=mesh, causal=True)
-    else:
-        attn = flash_attention(q, k, v, causal=True)
+    attn = mesh_flash_attention(q, k, v, mesh=mesh, causal=True)
     attn_out = jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
     x = x + attn_out
 

@@ -22,9 +22,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.ops.attention import flash_attention
-from ray_tpu.ops.ring_attention import ring_attention
-from ray_tpu.parallel.mesh import mesh_axis_size
+from ray_tpu.ops.attention import mesh_flash_attention
 from ray_tpu.parallel.sharding import (
     tree_shardings, with_logical_constraint)
 
@@ -172,10 +170,7 @@ def _block(x, p, config: LlamaConfig, mesh, position_offset=0):
         v = jnp.repeat(v, c.q_per_kv, axis=2)
     q = with_logical_constraint(q, ("batch", "length", "heads", "kv"),
                                 mesh=mesh)
-    if mesh is not None and mesh_axis_size(mesh, "seq") > 1:
-        attn = ring_attention(q, k, v, mesh=mesh, causal=True)
-    else:
-        attn = flash_attention(q, k, v, causal=True)
+    attn = mesh_flash_attention(q, k, v, mesh=mesh, causal=True)
     x = x + jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
 
     h = _rmsnorm(x, p["mlp_norm"], c.norm_eps)

@@ -12,13 +12,41 @@ Layouts: q/k/v are [batch, length, heads, head_dim] (BLHD) throughout.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.parallel.sharding import logical_to_spec
+
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
+
+
+def _interpret_kernels() -> bool:
+    """Pallas mode for the default backend: compiled Mosaic on TPU, the
+    interpreter on CPU (tests).  Anything else has no kernel path here,
+    and silently interpreting there would hide that."""
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise NotImplementedError(
+        f"attention kernels target TPU (Mosaic) and CPU (interpreter); "
+        f"backend is {backend!r}")
+
+
+@functools.lru_cache(maxsize=None)
+def _log_reference_path(op: str, shape: tuple) -> None:
+    """Once per (op, shape): a TPU run took the [L, L] XLA path."""
+    logger.warning("%s: shape %s does not fit the Pallas kernel; "
+                   "running the XLA reference path", op, shape)
 
 
 def reference_attention(q, k, v, *, causal: bool = True,
@@ -105,26 +133,38 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         lse_ref[...] = m_ref[...] + jnp.log(l_safe)
 
 
-try:  # Pallas import kept lazy-safe for platforms without it.
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
-
-
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None, block_q: int = 1024,
                     block_k: int = 1024, interpret: Optional[bool] = None):
     """Blockwise attention via Pallas.  Falls back to XLA attention when the
-    shape does not tile (length % block != 0) or Pallas is unavailable.
+    shape does not tile (length % block != 0; logged once per shape on TPU).
 
     Differentiable end-to-end in Pallas: the forward saves (O, logsumexp)
     and the backward runs flash-style dq and dk/dv kernels (causal block
     skipping, f32 VMEM accumulators) — never materializing [L, L]."""
     return _flash(q, k, v, causal, scale, block_q, block_k, interpret)
+
+
+def mesh_flash_attention(q, k, v, *, mesh=None, causal: bool = True):
+    """flash_attention for a model block that may run under a mesh.
+
+    GSPMD cannot partition a Mosaic custom call ("Mosaic kernels cannot be
+    automatically partitioned"), so on more than one device the kernel
+    runs per shard inside shard_map: batch split over the data axes and
+    heads over tensor, the two dims attention is independent across.  A
+    mesh with a seq axis rides ring attention instead."""
+    if mesh is None or mesh.size == 1:
+        return flash_attention(q, k, v, causal=causal)
+    if mesh.shape.get("seq", 1) > 1:
+        from ray_tpu.ops.ring_attention import ring_attention
+        return ring_attention(q, k, v, mesh=mesh, causal=causal)
+    spec = logical_to_spec(("batch", "length", "heads", "kv"), mesh=mesh)
+    return jax.shard_map(
+        functools.partial(flash_attention, causal=causal), mesh=mesh,
+        in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(q, k, v)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -155,7 +195,7 @@ _flash.defvjp(_flash_fwd, _flash_bwd)
 
 
 def _use_pallas(q_len, kv_len, d, block_q, block_k, causal):
-    return (_HAS_PALLAS and q_len % block_q == 0 and kv_len % block_k == 0
+    return (q_len % block_q == 0 and kv_len % block_k == 0
             and d in (64, 128, 256) and not (causal and q_len != kv_len))
 
 
@@ -181,10 +221,12 @@ def _flash_forward_impl(q, k, v, causal, scale, block_q, block_k, interpret):
     b, q_len, h, d = q.shape
     kv_len = k.shape[1]
     block_q, block_k = _fit_blocks(q_len, kv_len, block_q, block_k)
-    if not _use_pallas(q_len, kv_len, d, block_q, block_k, causal):
-        return reference_attention(q, k, v, causal=causal, scale=scale), None
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_kernels()
+    if not _use_pallas(q_len, kv_len, d, block_q, block_k, causal):
+        if not interpret:
+            _log_reference_path("flash_attention", (q.shape, k.shape))
+        return reference_attention(q, k, v, causal=causal, scale=scale), None
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     n_kv_blocks = kv_len // block_k
 
@@ -342,29 +384,30 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
-def _use_paged_kernel(d):
-    return _HAS_PALLAS and d in (64, 128, 256)
-
-
 def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens, *,
                            scale: Optional[float] = None,
                            use_kernel: Optional[bool] = None,
                            interpret: Optional[bool] = None):
     """Single-query paged attention: q [B, H, D] (one decode token per
-    lane) over each lane's block table.  Pallas kernel where the head dim
-    allows, masked-dense fallback elsewhere.  ctx_lens counts tokens
-    already written to the pool INCLUDING the current one."""
+    lane) over each lane's block table.  By default the Pallas kernel on
+    TPU where the head dim allows, the masked-dense path on CPU (the
+    interpreter is too slow for the engine tests) and for other head dims
+    (logged once per shape on TPU).  ctx_lens counts tokens already
+    written to the pool INCLUDING the current one."""
     b, h, d = q.shape
     if use_kernel is None:
-        use_kernel = (_use_paged_kernel(d)
-                      and jax.default_backend() == "tpu")
+        use_kernel = not _interpret_kernels()
+        if use_kernel and d not in (64, 128, 256):
+            _log_reference_path("paged_decode_attention",
+                                (q.shape, k_pool.shape))
+            use_kernel = False
     if not use_kernel:
         out = paged_attention_reference(
             q[:, None], k_pool, v_pool, block_tables, ctx_lens,
             (ctx_lens - 1)[:, None], scale=scale)
         return out[:, 0]
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_kernels()
     nb, bs, kh, _ = k_pool.shape
     mb = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
@@ -505,7 +548,7 @@ def _flash_backward_impl(q, k, v, out, lse, g, causal, scale, block_q,
     kv_len = k.shape[1]
     block_q, block_k = _fit_blocks(q_len, kv_len, block_q, block_k)
     if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+        interpret = _interpret_kernels()
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     n_q_blocks = q_len // block_q
     n_kv_blocks = kv_len // block_k

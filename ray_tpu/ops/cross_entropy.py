@@ -153,10 +153,6 @@ def _spmd_lse_tgt(logits, t_c, offset):
     return lse, tgt, iota
 
 
-
-from ray_tpu.parallel.mesh import shard_map_compat as _shard_map
-
-
 def _vshard(mesh, head):
     return head.shape[1] // max(mesh.shape.get(VOCAB_AXIS, 1), 1)
 
@@ -210,11 +206,11 @@ def _spmd_fwd_call(x, head, targets, valid, mesh, n_chunks):
             jax.lax.psum(jnp.sum(v2), ROW_AXES), 1.0)
         return total / denom, denom
 
-    return _shard_map(
-        fwd_impl, mesh,
-        (P(("data", "fsdp"), "seq", None), P(None, VOCAB_AXIS),
-         P(("data", "fsdp"), "seq"), P(("data", "fsdp"), "seq")),
-        (P(), P()),
+    return jax.shard_map(
+        fwd_impl, mesh=mesh,
+        in_specs=(P(("data", "fsdp"), "seq", None), P(None, VOCAB_AXIS),
+                  P(("data", "fsdp"), "seq"), P(("data", "fsdp"), "seq")),
+        out_specs=(P(), P()), check_vma=False,
     )(x, head, targets, valid)
 
 
@@ -260,11 +256,13 @@ def _ce_spmd_bwd(mesh, n_chunks, res, g):
         dhead_l = jax.lax.psum(dhead_l, ROW_AXES).astype(head_l.dtype)
         return dx_l, dhead_l
 
-    dx, dhead = _shard_map(
-        bwd_impl, mesh,
-        (P(("data", "fsdp"), "seq", None), P(None, VOCAB_AXIS),
-         P(("data", "fsdp"), "seq"), P(("data", "fsdp"), "seq"), P()),
-        (P(("data", "fsdp"), "seq", None), P(None, VOCAB_AXIS)),
+    dx, dhead = jax.shard_map(
+        bwd_impl, mesh=mesh,
+        in_specs=(P(("data", "fsdp"), "seq", None), P(None, VOCAB_AXIS),
+                  P(("data", "fsdp"), "seq"), P(("data", "fsdp"), "seq"),
+                  P()),
+        out_specs=(P(("data", "fsdp"), "seq", None), P(None, VOCAB_AXIS)),
+        check_vma=False,
     )(x, head, targets, valid, scale_g)
     return dx, dhead, None, None
 

@@ -26,7 +26,6 @@ import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
 from ray_tpu.ops.attention import NEG_INF
-from ray_tpu.parallel.mesh import shard_map_compat
 
 
 def _block_attend(q, k, v, scale, mask):
@@ -127,6 +126,6 @@ def ring_attention(q, k, v, *, mesh: Mesh, axis: str = "seq",
         denom = jnp.maximum(l, 1e-30).transpose(0, 2, 1)[..., None]
         return (acc.astype(jnp.float32) / denom).astype(qs.dtype)
 
-    fn = shard_map_compat(local, mesh, in_specs=(spec, spec, spec),
-                          out_specs=spec)
+    fn = jax.shard_map(local, mesh=mesh, in_specs=(spec, spec, spec),
+                       out_specs=spec, check_vma=False)
     return fn(q, k, v)

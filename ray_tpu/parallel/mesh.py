@@ -205,18 +205,3 @@ def single_device_mesh() -> Mesh:
 
 def mesh_axis_size(mesh: Mesh, axis: str) -> int:
     return mesh.shape.get(axis, 1)
-
-
-def shard_map_compat(f, mesh: Mesh, in_specs, out_specs):
-    """shard_map with replication checking off, across jax versions
-    (the flag was renamed check_rep -> check_vma around jax 0.8)."""
-    import inspect
-
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    kw = ("check_rep" if "check_rep"
-          in inspect.signature(shard_map).parameters else "check_vma")
-    return shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                     **{kw: False})

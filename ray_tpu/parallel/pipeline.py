@@ -21,7 +21,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ray_tpu.parallel.mesh import shard_map_compat
 
 
 def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
@@ -93,12 +92,12 @@ def pipeline_apply(stage_fn: Callable[[Any, jax.Array], jax.Array],
                             jnp.zeros_like(outputs))
         return jax.lax.psum(outputs, axis)
 
-    fn = shard_map_compat(
-        per_stage, mesh,
+    fn = jax.shard_map(
+        per_stage, mesh=mesh,
         in_specs=(jax.tree.map(lambda _: param_spec, stage_params,
                                is_leaf=lambda x: x is None),
                   io_spec),
-        out_specs=io_spec)
+        out_specs=io_spec, check_vma=False)
     return fn(stage_params, microbatches)
 
 

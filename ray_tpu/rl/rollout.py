@@ -25,8 +25,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ray_tpu.rllib.rollout_worker import (RolloutWorker,
-                                          _force_cpu_platform_if_worker)
+from ray_tpu.rllib.rollout_worker import RolloutWorker
 from ray_tpu.rllib.sample_batch import SampleBatch
 from ray_tpu.util import spans
 
@@ -34,10 +33,12 @@ from ray_tpu.util import spans
 class EngineRolloutActor:
     """Trajectory generation through the serving engine.
 
-    Usable in-process or as a `ray_tpu` actor (one per CPU slot — the
-    worker process pins jax to CPU so rollout gangs never fight the
-    learner for the chip).
+    Usable in-process or as a `ray_tpu` actor.  On a cluster that
+    advertises TPU each actor leases one chip (``leases_chip``) unless
+    its options say otherwise; elsewhere it runs on the worker's CPU.
     """
+
+    leases_chip = True
 
     def __init__(self, model="gpt", config="nano", *, params=None,
                  max_lanes: int = 4, spec_k: int = 0,
@@ -46,7 +47,6 @@ class EngineRolloutActor:
                  reward_fn: Optional[Callable[[List[int], List[int]],
                                               float]] = None,
                  **engine_kwargs):
-        _force_cpu_platform_if_worker()
         from ray_tpu.inference.engine import InferenceEngine
         self.engine = InferenceEngine(
             model, config, params, max_lanes=max_lanes, spec_k=spec_k,

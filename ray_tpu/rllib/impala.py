@@ -146,7 +146,6 @@ class _VTraceLearner:
             # (reference: LearnerGroup's DDP fleet, learner_group.py:51).
             from jax.sharding import PartitionSpec as P
 
-            from ray_tpu.parallel.mesh import shard_map_compat
             k = self.mesh.shape["data"]
 
             def shard_step(params, opt_state, batch):
@@ -162,8 +161,9 @@ class _VTraceLearner:
                          for key, v in batch.items()}
                 return step(params, opt_state, local)
 
-            step_fn = shard_map_compat(
-                shard_step, self.mesh, (P(), P(), P()), (P(), P(), P()))
+            step_fn = jax.shard_map(
+                shard_step, mesh=self.mesh, in_specs=(P(), P(), P()),
+                out_specs=(P(), P(), P()), check_vma=False)
         else:
             step_fn = step
         # No donation: the learner thread updates params while the driver

@@ -128,8 +128,6 @@ class JaxLearner:
         order — regression-gated in tests/test_rllib_dp.py)."""
         from jax.sharding import PartitionSpec as P
 
-        from ray_tpu.parallel.mesh import shard_map_compat
-
         mesh = self.mesh
         k = mesh.shape["data"]
         num_epochs = self.config.get("num_sgd_iter", 1)
@@ -185,8 +183,9 @@ class JaxLearner:
                 lambda m: jnp.mean(m), metrics)
             return params, opt_state, mean_metrics
 
-        return shard_map_compat(shard_update, mesh,
-                                (P(), P(), P(), P()), (P(), P(), P()))
+        return jax.shard_map(
+            shard_update, mesh=mesh, in_specs=(P(), P(), P(), P()),
+            out_specs=(P(), P(), P()), check_vma=False)
 
     def update(self, batch: SampleBatch) -> Dict[str, float]:
         jbatch = {k: jnp.asarray(v) for k, v in batch.items()}

@@ -198,8 +198,6 @@ class MultiAgentRolloutWorker:
                  policies: Optional[Dict[str, Any]] = None,
                  policy_mapping_fn: Optional[Callable[[str], str]] = None,
                  postprocess: bool = True):
-        from ray_tpu.rllib.rollout_worker import _force_cpu_platform_if_worker
-        _force_cpu_platform_if_worker()
         self.env = make_multi_agent_env(env, num_envs, seed=seed)
         self.num_envs = num_envs
         self.fragment_length = rollout_fragment_length

@@ -19,23 +19,6 @@ from ray_tpu.rllib.policy import JaxPolicy
 from ray_tpu.rllib.sample_batch import SampleBatch, compute_gae
 
 
-def _force_cpu_platform_if_worker() -> None:
-    """Pin jax to the CPU platform inside remote worker processes.
-
-    Must run before the process's first jax computation (config changes
-    after backend init are ignored).  JAX_PLATFORMS env alone is not
-    enough: the TPU bootstrap re-selects its platform at import time.
-    """
-    try:
-        from ray_tpu import api
-        if api._worker is None or api._worker.mode != "worker":
-            return
-        import jax
-        jax.config.update("jax_platforms", "cpu")
-    except Exception:
-        pass
-
-
 class RolloutWorker:
     """Steps a vectorized env with the current policy and emits SampleBatches.
 
@@ -56,12 +39,6 @@ class RolloutWorker:
                  exploration=None,
                  obs_connector=None,
                  action_connector=None):
-        # In a remote worker process, force the whole jax platform to CPU
-        # before the first jax use: rollout actors must not even initialize
-        # the TPU runtime (one chip, many actor processes).  In the driver
-        # the platform is left alone (the learner owns the chip) and the
-        # policy pins itself to the CPU backend instead.
-        _force_cpu_platform_if_worker()
         self.env = make_vector_env(env, num_envs, seed=seed)
         self.num_envs = num_envs
         self.fragment_length = rollout_fragment_length

@@ -40,10 +40,11 @@ from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu._private.accelerators import default_num_tpus
 from ray_tpu._private.config import GLOBAL_CONFIG
 from ray_tpu.exceptions import (
-    ActorDiedError, ActorUnavailableError, ReplicaStreamLostError,
-    ServeOverloadedError, TaskError)
+    ActorDiedError, ActorUnavailableError, GetTimeoutError,
+    ReplicaStreamLostError, ServeOverloadedError, TaskError)
 from ray_tpu.util import events, spans, tracing
 
 CONTROLLER_NAME = "SERVE_CONTROLLER"
@@ -54,6 +55,16 @@ REPLICA_STARTING = "STARTING"
 REPLICA_RUNNING = "RUNNING"
 REPLICA_DRAINING = "DRAINING"
 REPLICA_DEAD = "DEAD"
+
+
+def _kill_quietly(actors) -> None:
+    """Best-effort kill of replicas that never entered the routing table."""
+    for actor in actors:
+        try:
+            ray_tpu.kill(actor)
+        except Exception:
+            pass
+
 
 _SERVE_MET = None
 
@@ -683,7 +694,8 @@ class ServeController:
             while len(replicas) + len(started) < config.num_replicas:
                 actor = ReplicaActor.options(
                     num_cpus=opts.get("num_cpus", 0.1),
-                    num_tpus=opts.get("num_tpus"),
+                    num_tpus=default_num_tpus(cls_or_fn,
+                                              opts.get("num_tpus")),
                     resources=opts.get("resources"),
                     max_restarts=2,
                     # Replicas must execute up to max_concurrent_queries
@@ -710,8 +722,21 @@ class ServeController:
                                  timeout=120)
                 except Exception:
                     pass
-            for ref in verify:
-                ray_tpu.get(ref, timeout=120)
+            try:
+                for ref in verify:
+                    ray_tpu.get(ref, timeout=120)
+            except Exception as e:
+                # A replica that cannot start — its __init__ raised (no
+                # chip could be opened, say), its worker died, or it is
+                # still constructing — must not linger holding a chip
+                # lease, and serve.run's caller gets the reason.
+                _kill_quietly(started)
+                why = (f"no answer within 120 s of construction "
+                       f"({type(e).__name__}); its worker's log has the rest"
+                       if isinstance(e, GetTimeoutError) else str(e))
+                raise RuntimeError(
+                    f"deployment {name!r}: replica failed to start: "
+                    f"{why}") from e
             replicas.extend(started)
             with self._lock:
                 entry = self._deployments.get(name)
@@ -719,11 +744,7 @@ class ServeController:
                     # Deployment deleted concurrently: its old replicas
                     # are already draining via delete_deployment; the
                     # freshly-started ones never served and die now.
-                    for r in started:
-                        try:
-                            ray_tpu.kill(r)
-                        except Exception:
-                            pass
+                    _kill_quietly(started)
                     return
                 entry["replicas"][:] = replicas
                 entry["replica_vers"] = vers

@@ -72,7 +72,12 @@ class LLMDeployment:
                                                      max_new_tokens=16):
             ...                      # token ids, streamed as generated
         handle.remote([1, 2, 3]).result()   # non-streaming: full list
+
+    On a cluster that advertises TPU every replica leases one chip
+    (``leases_chip``); ``ray_actor_options={"num_tpus": n}`` overrides it.
     """
+
+    leases_chip = True
 
     def __init__(self, model="gpt", config="nano", params=None, *,
                  max_lanes: int = 8, block_size: int = 16,
@@ -143,8 +148,13 @@ class LLMDeployment:
         return self._engine.prefix_summary()
 
     def stats(self) -> dict:
-        """Engine occupancy + prefix-cache + speculative-acceptance
-        counters (the same numbers the engine exports through
-        util.metrics, so `cli metrics` scrapes them from the replica
-        process)."""
+        """The engine's backend and device, occupancy, prefix-cache and
+        speculative-acceptance counters (the same numbers the engine
+        exports through util.metrics, so `cli metrics` scrapes them from
+        the replica process)."""
         return self._engine.stats()
+
+    def compiled_steps(self) -> dict:
+        """Kernel calls and in-place bytes of each compiled step shape
+        (see InferenceEngine.compiled_steps)."""
+        return self._engine.compiled_steps()

@@ -73,16 +73,15 @@ def _coordinator_host() -> str:
     return socket.gethostbyname(socket.gethostname())
 
 
-def _init_jax_distributed(coordinator: str, num_processes: int,
+def _init_jax_distributed(coordinator: Optional[str], num_processes: int,
                           process_id: int, env: dict):
     os.environ.update({k: str(v) for k, v in env.items()})
     import jax
 
     if "JAX_PLATFORMS" in env:
-        try:
-            jax.config.update("jax_platforms", env["JAX_PLATFORMS"])
-        except Exception:
-            pass
+        # jax read the variable at import; a process that imported jax
+        # before this call needs the live config told too.
+        jax.config.update("jax_platforms", env["JAX_PLATFORMS"])
     if num_processes > 1:
         jax.distributed.initialize(
             coordinator_address=coordinator,
@@ -104,11 +103,13 @@ def _shutdown_jax_distributed():
 
 class TpuBackend(Backend):
     def on_start(self, worker_group: WorkerGroup, config: TpuConfig):
-        port = config.coordinator_port or worker_group.execute_single(
-            0, _find_free_port)
-        host = worker_group.execute_single(0, _coordinator_host)
-        coordinator = f"{host}:{port}"
         n = len(worker_group)
+        coordinator = None    # one worker is its own fabric: no rendezvous
+        if n > 1:
+            port = config.coordinator_port or worker_group.execute_single(
+                0, _find_free_port)
+            host = worker_group.execute_single(0, _coordinator_host)
+            coordinator = f"{host}:{port}"
         refs = []
         for rank, worker in enumerate(worker_group.workers):
             refs.append(worker.actor.run.remote(

@@ -13,11 +13,13 @@ fabric; the user loop sees the global mesh.
 
 from __future__ import annotations
 
+import dataclasses
 import os
 import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
+from ray_tpu._private.accelerators import chips_per_host
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.air.config import RunConfig, ScalingConfig
 from ray_tpu.train.backend import BackendConfig, TpuConfig
@@ -321,3 +323,15 @@ class JaxTrainer(DataParallelTrainer):
                  *, jax_config: Optional[TpuConfig] = None, **kwargs):
         super().__init__(train_loop_per_worker,
                          backend_config=jax_config or TpuConfig(), **kwargs)
+
+    def fit(self) -> Result:
+        # One worker is one jax host: on a cluster that advertises TPU a
+        # worker whose scaling config names no TPU leases a whole host's
+        # chips, rather than being pinned to the CPU beside idle chips.
+        if "TPU" not in self.scaling_config.worker_resources():
+            chips = chips_per_host()
+            if chips:
+                self.scaling_config = dataclasses.replace(
+                    self.scaling_config, use_tpu=True,
+                    tpus_per_worker=chips)
+        return super().fit()

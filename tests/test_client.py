@@ -38,6 +38,11 @@ def client_cluster():
             raise RuntimeError(f"client server died during startup: {buf}")
         time.sleep(0.2)
     assert port, "client server never reported its port"
+    # Keep draining: a server blocked on a full stdout pipe hangs every
+    # later test (grpc's poller can log tens of KB of BlockingIOError).
+    os.set_blocking(proc.stdout.fileno(), True)
+    import threading
+    threading.Thread(target=proc.stdout.read, daemon=True).start()
     ray_tpu.init(address=f"ray_tpu://127.0.0.1:{port}")
     yield cluster
     ray_tpu.shutdown()
