@@ -44,6 +44,7 @@ cache update).
 from __future__ import annotations
 
 import collections
+import contextlib
 import itertools
 import logging
 import queue
@@ -56,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu._private import compile_cache
 from ray_tpu._private.accelerators import leased_chips, require_chip_lease
 from ray_tpu.inference.kv_cache import PagedKVCache
 from ray_tpu.util import events, spans
@@ -64,6 +66,10 @@ from ray_tpu.util.metrics import Counter, Gauge, Histogram
 logger = logging.getLogger(__name__)
 
 _DONE = object()
+
+# The flat phases of one scheduler step (spans.phase: profiler annotations
+# `engine/<phase>`, seconds into the step's one ring record and stats()).
+_PHASES = ("admit", "build_batch", "dispatch", "fetch", "commit")
 
 _MET = None
 
@@ -141,13 +147,16 @@ class _Request:
     # runs outside the submitter's contextvars.
     trace: Optional[tuple] = None
     submitted: float = 0.0             # wall time of submit()
+    submitted_pc: float = 0.0          # perf_counter of submit(): queue wait
     last_emit: float = 0.0             # wall time of the previous token
     fed: int = 0            # prompt tokens in the cache (prefilled OR reused)
     produced: int = 0
-    # Open engine span for TRACED requests only: the prefill span
-    # (submit -> first token) until produced==1, then the current
-    # inter-token decode span.  Untraced requests never pay for these.
+    # Open engine spans for TRACED requests only: the prefill span
+    # (submit -> first token) until produced==1, then ONE decode span
+    # (first token -> finish); under the prefill span its child, the
+    # queue span (submit -> lane).  Untraced requests never pay for these.
     span_tok: object = None
+    queue_tok: object = None
     last_token: int = 0
     emitted: List[int] = field(default_factory=list)
     finish_reason: Optional[str] = None
@@ -250,6 +259,14 @@ class GenerationHandle:
         return list(self._req.logps)
 
 
+def _end_spans(req: _Request, **payload) -> None:
+    """Close whatever spans a traced request still has open: the queue
+    span inside its prefill span, or its decode span."""
+    spans.end(req.queue_tok, **payload)
+    spans.end(req.span_tok, **payload)
+    req.queue_tok = req.span_tok = None
+
+
 def _resolve_model(model):
     if isinstance(model, str):
         if model == "gpt":
@@ -292,6 +309,7 @@ class InferenceEngine:
                  kv_tier: Optional[bool] = None,
                  capture_logp: bool = False):
         require_chip_lease("InferenceEngine")
+        compile_cache.watch()      # before this engine's first program
         self.model = _resolve_model(model)
         self.config = (self.model.CONFIGS[config] if isinstance(config, str)
                        else config)
@@ -347,6 +365,13 @@ class InferenceEngine:
         self._step_avals: Dict = {}   # argument shapes of each step's compile
         self._step_compile_s: Dict = {}   # wall of each step's first call
         self._evictions_reported = 0
+        # Cumulative step accounting (stats()): what the engine thread did
+        # with its time, by phase, and how long admitted requests queued.
+        self._steps = 0
+        self._step_wall_s = 0.0
+        self._phase_s = dict.fromkeys(_PHASES, 0.0)
+        self._admitted = 0
+        self._queue_wait_s = 0.0
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
         self._thread: Optional[threading.Thread] = None
@@ -385,16 +410,21 @@ class InferenceEngine:
                                  else time.monotonic() + deadline_s),
                        trace=tracing.current_context(),
                        submitted=time.time(),
+                       submitted_pc=time.perf_counter(),
                        spec_k=self.spec_k,
                        prefill_only=prefill_only)
         events.record("engine", "submit", trace=req.trace, rid=rid,
                       prompt_len=len(prompt), max_new=max_new_tokens)
         if req.trace is not None:
             # Prefill span: submit -> first emitted token (TTFT, queue
-            # wait included).  _commit swaps it for per-token decode
-            # spans once tokens flow.
+            # wait included; its child `queue` ends at admission).
+            # _commit swaps it for the request's one decode span.
             req.span_tok = spans.begin("engine", "prefill", ctx=req.trace,
                                        rid=rid, prompt_len=len(prompt))
+            if req.span_tok is not None:
+                req.queue_tok = spans.begin(
+                    "engine", "queue", ctx=(req.trace[0], req.span_tok.sid),
+                    rid=rid)
         with self._work:
             if self._stopped:
                 raise RuntimeError("engine is shut down")
@@ -490,15 +520,13 @@ class InferenceEngine:
             else:
                 req.finish_reason = "cancelled"
                 req.out.put(_DONE)
-                spans.end(req.span_tok, ok=False)
-                req.span_tok = None
+                _end_spans(req, ok=False)
                 return True
             for lane, r in enumerate(self._lanes):
                 if r is req:
                     req.finish_reason = "cancelled"
                     req.out.put(_DONE)
-                    spans.end(req.span_tok, ok=False)
-                    req.span_tok = None
+                    _end_spans(req, ok=False)
                     self.cache.free_lane(lane)
                     self._lanes[lane] = None
                     events.record("engine", "lane_evict", trace=req.trace,
@@ -520,8 +548,7 @@ class InferenceEngine:
                 req.out.put(_DONE)
                 self.cache.free_lane(lane)
                 self._lanes[lane] = None
-                spans.end(req.span_tok, ok=False)
-                req.span_tok = None
+                _end_spans(req, ok=False)
                 events.record("engine", "deadline_kill", trace=req.trace,
                               rid=req.rid, lane=lane,
                               produced=req.produced)
@@ -531,8 +558,7 @@ class InferenceEngine:
             self._waiting.remove(req)
             req.finish_reason = "deadline"
             req.out.put(_DONE)
-            spans.end(req.span_tok, ok=False)
-            req.span_tok = None
+            _end_spans(req, ok=False)
             events.record("engine", "deadline_kill", trace=req.trace,
                           rid=req.rid, lane=None, produced=0)
 
@@ -592,6 +618,16 @@ class InferenceEngine:
             # anything above 1 is the speculative multiplier.
             "spec_accepted_per_step": (st["emitted"] / st["bursts"]
                                        if st["bursts"] else 0.0),
+            # Where the engine thread's time went, cumulatively: steps
+            # that advanced a lane, their wall, the same by phase
+            # (`_PHASES`), and submit -> lane summed over admissions.
+            "steps": self._steps,
+            "step_wall_s": self._step_wall_s,
+            "phase_s": dict(self._phase_s),
+            "admitted": self._admitted,
+            "queue_wait_s": self._queue_wait_s,
+            # This process's XLA compiles and persistent-cache loads.
+            "compile": compile_cache.counters(),
         }
 
     def compiled_steps(self) -> dict:
@@ -678,11 +714,17 @@ class InferenceEngine:
             met["hit_tokens"].inc(reused)
             met["miss_tokens"].inc(len(req.prompt) - reused)
             met["hits" if reused else "misses"].inc()
+            wait_s = time.perf_counter() - req.submitted_pc
+            self._admitted += 1
+            self._queue_wait_s += wait_s
+            spans.end(req.queue_tok, lane=lane)
+            req.queue_tok = None
             events.record("engine",
                           "prefix_hit" if reused else "prefix_miss",
                           trace=req.trace, rid=req.rid, lane=lane,
                           reused_tokens=reused,
-                          prompt_len=len(req.prompt))
+                          prompt_len=len(req.prompt),
+                          wait_ms=wait_s * 1e3)
         met["queue_depth"].set(len(self._waiting))
         evictions = self.cache.allocator.evictions
         if evictions > self._evictions_reported:
@@ -723,19 +765,27 @@ class InferenceEngine:
         ride along at chunk=1, so mixed speculative/plain lanes share
         the step, and adaptive-k backoff shrinks the verify FLOPs it
         pays for instead of padding to the configured maximum.
-        Returns False when fully idle."""
-        with self._lock:
-            self._expire_deadlines()
-            self._admit()
-            live = [(i, r) for i, r in enumerate(self._lanes)
-                    if r is not None]
-            if not live:
-                return False
-            plans = []
-            decode = [(i, r) for i, r in live if not r.prefilling]
-            if decode:
+        Returns False when fully idle.
+
+        The step is five flat phases (`_PHASES`), none inside another, so
+        that an idle gap of the device in a profiler trace carries the name
+        of what the host was doing; a population runs build_batch,
+        dispatch and fetch once each, so a mixed step has them twice.  One
+        `engine/step` ring record at the end holds the step's durations."""
+        took = dict.fromkeys(_PHASES, 0.0)         # seconds
+        with contextlib.ExitStack() as locked:
+            with spans.phase("engine", "admit") as ph:
+                locked.enter_context(self._lock)   # the wait is admission's
+                self._expire_deadlines()
+                self._admit()
+                live = [(i, r) for i, r in enumerate(self._lanes)
+                        if r is not None]
+                if not live:
+                    return False
+                decode = [(i, r) for i, r in live if not r.prefilling]
+                prefill = [(i, r) for i, r in live if r.prefilling]
                 spec = False
-                if self._proposer is not None:
+                if decode and self._proposer is not None:
                     dtok = spans.begin("engine", "spec_draft")
                     drafted = 0
                     for lane, req in decode:
@@ -743,35 +793,68 @@ class InferenceEngine:
                         drafted += len(req.draft)
                     spec = drafted > 0
                     spans.end(dtok, lanes=len(decode), drafted=drafted)
-                t = 1 + max(len(r.draft) for _, r in decode) if spec else 1
-                plans.append((spec, decode) + self._build_batch(decode, t))
-            prefill = [(i, r) for i, r in live if r.prefilling]
-            if prefill:
-                plans.append((False, prefill)
-                             + self._build_batch(prefill, self.prefill_chunk))
-            events.record("engine", "step", decode=len(decode),
-                          prefill=len(prefill),
-                          waiting=len(self._waiting))
+                waiting = len(self._waiting)
+            t_start = ph.t0
+            took["admit"] = ph.seconds
+            plans = []
+            with spans.phase("engine", "build_batch") as ph:
+                if decode:
+                    t = (1 + max(len(r.draft) for _, r in decode)
+                         if spec else 1)
+                    plans.append((spec, decode)
+                                 + self._build_batch(decode, t))
+                if prefill:
+                    plans.append((False, prefill) + self._build_batch(
+                        prefill, self.prefill_chunk))
+            took["build_batch"] = ph.seconds
         done = []
         for spec, lanes, batch, chunks in plans:
             vtok = spans.begin("engine", "spec_verify") if spec else None
-            next_tok, lps = self._run_step(batch, spec)
-            toks = np.asarray(next_tok)
+            with spans.phase("engine", "dispatch") as ph:
+                next_tok, lps = self._run_step(batch, spec)
+            took["dispatch"] += ph.seconds
+            with spans.phase("engine", "fetch") as ph:
+                # The host blocks here until the device has finished the
+                # step, then copies one int32 per lane back.
+                toks = np.asarray(next_tok)
+                if lps is not None:
+                    lps = np.asarray(lps)
+            took["fetch"] += ph.seconds
             if toks.ndim == 1:      # plain/prefill: one token per lane
                 toks = toks[:, None]
-            if lps is not None:
-                lps = np.asarray(lps)
-                if lps.ndim == 1:
-                    lps = lps[:, None]
+            if lps is not None and lps.ndim == 1:
+                lps = lps[:, None]
             spans.end(vtok, lanes=len(lanes))
             if spec:
                 self._spec_stats["steps"] += 1
                 _metrics()["spec_steps"].inc()
             done.append((lanes, chunks, toks, lps))
-        with self._work:
-            for lanes, chunks, toks, lps in done:
-                self._commit(lanes, chunks, toks, lps)
-            self._work.notify()
+        with contextlib.ExitStack() as locked:
+            with spans.phase("engine", "commit") as ph:
+                # Let go of the step's device arrays (nine uploads and the
+                # sampled tokens per population) here, inside a phase: left
+                # to the return, their release and what the runtime then
+                # does took 1.4 ms a step on a v5e, between two steps,
+                # under no phase's name (PERF.md 6, PR 23).
+                del plans, batch, next_tok
+                locked.enter_context(self._work)
+                for lanes, chunks, toks, lps in done:
+                    self._commit(lanes, chunks, toks, lps)
+                self._work.notify()
+            took["commit"] = ph.seconds
+            wall = ph.t0 + ph.seconds - t_start
+            self._steps += 1
+            self._step_wall_s += wall
+            for name in _PHASES:
+                self._phase_s[name] += took[name]
+            events.record(
+                "engine", "step", decode=len(decode), prefill=len(prefill),
+                waiting=waiting, wall_ms=wall * 1e3,
+                admit_ms=took["admit"] * 1e3,
+                build_ms=took["build_batch"] * 1e3,
+                dispatch_ms=took["dispatch"] * 1e3,
+                fetch_ms=took["fetch"] * 1e3,
+                commit_ms=took["commit"] * 1e3)
         return True
 
     def _build_batch(self, live, t):
@@ -964,8 +1047,7 @@ class InferenceEngine:
                     req.out.put(_DONE)
                     self.cache.free_lane(lane)
                     self._lanes[lane] = None
-                    spans.end(req.span_tok, tokens=0)
-                    req.span_tok = None
+                    _end_spans(req, tokens=0)
                     events.record("engine", "finish", trace=req.trace,
                                   rid=req.rid, reason="prefill",
                                   produced=0)
@@ -1036,9 +1118,6 @@ class InferenceEngine:
                 self._spec_stats["accepted"] += accepted
                 met["spec_drafted"].inc(len(draft))
                 met["spec_accepted"].inc(accepted)
-                events.record("engine", "spec_accept", trace=req.trace,
-                              rid=req.rid, lane=lane, drafted=len(draft),
-                              accepted=accepted, emitted=m)
                 if self._spec_adaptive:
                     # Per-lane draft length: grow on full acceptance,
                     # halve on total rejection, otherwise track what
@@ -1056,15 +1135,16 @@ class InferenceEngine:
             if req.finish_reason is None \
                     and int(self.cache.seq_lens[lane]) >= self.cache.max_seq_len:
                 req.finish_reason = "max_seq_len"
-            if req.trace is not None:
-                # Close the span ending at this emit (prefill for the
-                # first token, the previous decode gap otherwise) and
-                # open the next decode span unless the request is done.
+            if req.trace is not None and (first
+                                          or req.finish_reason is not None):
+                # The first emit closes the prefill span and opens the
+                # request's one decode span; the finish closes whichever
+                # is open.  Nothing per token in between.
                 spans.end(req.span_tok, tokens=req.produced)
                 req.span_tok = (
                     None if req.finish_reason is not None else
                     spans.begin("engine", "decode", ctx=req.trace,
-                                rid=req.rid, t=req.produced))
+                                rid=req.rid))
             if req.finish_reason is not None:
                 req.out.put(_DONE)
                 self.cache.free_lane(lane)

@@ -436,6 +436,9 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens, *,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
         interpret=interpret,
+        # The instruction's name in the HLO and so in a device trace: in
+        # the engine's layer scan it would be `closed_call.N` without.
+        name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       q[:, None].reshape(b, h, d), k_pool, v_pool)
     return out

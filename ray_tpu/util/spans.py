@@ -32,6 +32,19 @@ Usage:
 
     with spans.span("ingest", "h2d"):        # context form; nested spans
         device_put(batch)                    # become children via tracing
+
+A **phase** is the third form, for intervals too frequent for two ring
+slots (the engine's step has five, a dozen times a second):
+
+    with spans.phase("engine", "fetch") as ph:
+        toks = np.asarray(next_tok)
+    fetch_s += ph.seconds
+
+It writes nothing to the ring.  The caller gets the elapsed seconds and
+folds them into one record of its own; the interval itself goes, as
+``engine/fetch``, into the jax profiler's trace when a session is open
+(`jax.profiler.TraceAnnotation`: a flag test in C++ when none is), where
+it lies on the device's clock beside the device's own operations.
 """
 
 from __future__ import annotations
@@ -39,6 +52,7 @@ from __future__ import annotations
 import contextlib
 import itertools
 import secrets
+import sys
 import time
 from typing import Any, Optional, Tuple
 
@@ -104,7 +118,7 @@ def begin(plane: str, kind: str,
     if payload:
         p.update(payload)
     r.append(plane, kind, p, (trace_id, s))
-    return Span(plane, kind, trace_id, s, time.time())
+    return Span(plane, kind, trace_id, s, time.perf_counter())
 
 
 def end(tok: Optional[Span], **payload: Any) -> None:
@@ -115,7 +129,7 @@ def end(tok: Optional[Span], **payload: Any) -> None:
     r = events._recorder
     if r is None:
         return
-    p: dict = {"ph": "E", "dur": time.time() - tok.t0}
+    p: dict = {"ph": "E", "dur": time.perf_counter() - tok.t0}
     if payload:
         p.update(payload)
     r.append(tok.plane, tok.kind, p, (tok.trace_id, tok.sid))
@@ -138,3 +152,29 @@ def span(plane: str, kind: str,
         if cv is not None:
             tracing._ctx.reset(cv)
         end(tok)
+
+
+class phase:
+    """Context manager around one phase of a hot loop: ``seconds`` (and
+    ``t0``, both on ``time.perf_counter``) for the caller, a
+    ``<plane>/<kind>`` annotation for the jax profiler, nothing for the
+    ring.  Processes that never imported jax (the control-plane daemons)
+    get the clock alone and do not import it here."""
+
+    __slots__ = ("_ann", "t0", "seconds")
+
+    def __init__(self, plane: str, kind: str):
+        jax = sys.modules.get("jax")
+        self._ann = (jax.profiler.TraceAnnotation(f"{plane}/{kind}")
+                     if jax is not None else None)
+
+    def __enter__(self) -> "phase":
+        if self._ann is not None:
+            self._ann.__enter__()
+        self.t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.seconds = time.perf_counter() - self.t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)

@@ -1,6 +1,6 @@
 """Static plane hygiene (PR 11 satellite): every literal call site of
-``events.record(...)`` / ``spans.begin(...)`` / ``spans.span(...)`` in
-the package uses a plane string from ``events.PLANES`` and a sane kind,
+``events.record(...)`` / ``spans.begin(...)`` / ``spans.span(...)`` /
+``spans.phase(...)`` in the package uses a plane string from ``events.PLANES`` and a sane kind,
 and every file that opens spans imperatively also closes them.  Greps
 source so a typo'd plane ("sched " / "schedule") fails CI instead of
 silently fragmenting the `cli top` per-plane rates.
@@ -16,7 +16,7 @@ PKG = pathlib.Path(events.__file__).resolve().parents[1]
 # events.record("plane", "kind", ... / spans.begin("plane", "kind", ...
 # Payloads stay on later lines; plane+kind may wrap one line break.
 _CALL = re.compile(
-    r"(?:events\.record|spans\.begin|spans\.span)\(\s*\n?\s*"
+    r"(?:events\.record|spans\.begin|spans\.span|spans\.phase)\(\s*\n?\s*"
     r"(['\"])([^'\"]*)\1\s*,\s*\n?\s*(['\"])([^'\"]*)\3",
     re.MULTILINE)
 
@@ -231,3 +231,21 @@ def test_gcs_ft_event_kinds_present():
     }
     missing = required - sites
     assert not missing, f"gcs-ft event kinds vanished: {missing}"
+
+
+def test_engine_step_phases_present_and_only_in_the_profilers_trace():
+    """The decode cell's idle split (benchmark/idle_phases.py) and the
+    `engine/step` record's durations name these five phases; each is a
+    `spans.phase` (a profiler annotation and a number for the caller),
+    never a ring span: two ring slots a phase would flood the recorder at
+    a dozen steps a second."""
+    src = (PKG / "inference" / "engine.py").read_text()
+    phases = set(re.findall(r'spans\.phase\(\s*"engine",\s*"(\w+)"\)', src))
+    assert phases == {"admit", "build_batch", "dispatch", "fetch", "commit"}
+    ring = {k for _, _, pl, k in _call_sites() if pl == "engine"} - phases
+    assert {"step", "submit", "prefill", "queue", "decode"} <= ring
+    for path in sorted(PKG.rglob("*.py")):
+        for m in re.finditer(
+                r'(?:spans\.begin|spans\.span|events\.record)\(\s*\n?\s*'
+                r'"engine",\s*\n?\s*"(\w+)"', path.read_text()):
+            assert m.group(1) not in phases, (path.name, m.group(1))

@@ -89,6 +89,37 @@ def test_paged_decode_kernel_compiles_for_v5e_gqa(v5e, as_on_chip):
     assert "tpu_custom_call" in text
 
 
+def test_paged_decode_kernel_keeps_its_name_inside_a_layer_scan(v5e,
+                                                                 as_on_chip):
+    """In the engine's step the kernel sits in the layer scan, under no
+    `jit` of its own; without `pallas_call(name=...)` its instruction is
+    `closed_call.N`, and the device trace cannot tell it from any other
+    Mosaic kernel (`benchmark/trace_reduce.py` names kernels by that)."""
+    from ray_tpu.ops.attention import paged_attention
+    lanes, h, d, bs, nb, mb, layers = 8, 8, 64, 16, 64, 8, 2
+    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
+
+    def layers_of_attention(q, k_pools, v_pools, tables, ctx_lens):
+        def layer(x, pools):
+            out = paged_attention(x[:, None], *pools, tables, ctx_lens, None)
+            return x + out[:, 0], None
+        return jax.lax.scan(layer, q, (k_pools, v_pools))[0]
+
+    text = jax.jit(layers_of_attention).lower(
+        arg((lanes, h, d), jnp.bfloat16),
+        arg((layers, nb, bs, h, d), jnp.bfloat16),
+        arg((layers, nb, bs, h, d), jnp.bfloat16),
+        arg((lanes, mb), jnp.int32),
+        arg((lanes,), jnp.int32)).compile().as_text()
+    kernels = [line.split(" = ")[0].strip() for line in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in line]
+    assert kernels and all(
+        k.lstrip("%").startswith("paged_decode_attention") for k in kernels)
+
+
 def test_chip_binding_for_tpu_workers():
     chips = ChipAllocator(4)
     held = [chips.acquire(1) for _ in range(4)]
