@@ -131,23 +131,27 @@ def kernels_leg() -> dict:
                          .reshape(lanes, mb).astype(np.int32))
     ctx_lens = jnp.asarray(rng.integers(1, mb * bs + 1, lanes)
                            .astype(np.int32))          # ragged
+    # Pools as the engine stores them, [L, NB, BS, W], read at layer 1;
+    # gpt2-xl's 25 x 64 row is the one that is padded (1600 -> 1664).
+    layer = jnp.asarray(1, jnp.int32)
     for name, h, kh, d in (("gpt2-small", 12, 12, 64),
+                           ("gpt2-xl", 25, 25, 64),
                            ("llama-1b", 32, 4, 64)):
         kk = jax.random.fold_in(key, h)
         pq = jax.random.normal(kk, (lanes, h, d), jnp.bfloat16)
-        kp, vp = (jax.random.normal(jax.random.fold_in(kk, i),
-                                    (nb, bs, kh, d), jnp.bfloat16)
-                  for i in (1, 2))
+        kp, vp = (A.pack_kv_rows(jax.random.normal(
+            jax.random.fold_in(kk, i), (2, nb, bs, kh, d), jnp.bfloat16))
+            for i in (1, 2))
 
-        def paged(*a):
-            return A.paged_decode_attention(*a, use_kernel=True,
+        def paged(*a, kh=kh):
+            return A.paged_decode_attention(*a, kv_heads=kh, use_kernel=True,
                                             interpret=False)
 
-        got = compiled(paged, pq, kp, vp, tables, ctx_lens)(
-            pq, kp, vp, tables, ctx_lens)
+        got = compiled(paged, pq, kp, vp, tables, ctx_lens, layer)(
+            pq, kp, vp, tables, ctx_lens, layer)
         want = A.paged_attention_reference(
             pq[:, None], kp, vp, tables, ctx_lens,
-            (ctx_lens - 1)[:, None])[:, 0]
+            (ctx_lens - 1)[:, None], layer, kv_heads=kh)[:, 0]
         errs[f"paged_{name}"] = check(f"paged decode {name}", got, want)
 
     return {"platform": dev[0].platform, "device_kind": dev[0].device_kind,
@@ -361,7 +365,9 @@ def serve_leg(sz: Sizes, n: int) -> dict:
                          for r in replicas], timeout=120)
     steps = ray_tpu.get([r.handle_request.remote("compiled_steps", (), {})
                          for r in replicas], timeout=600)
-    # K and V pools, which the decode step must update in place.
+    # K and V pools, which the decode step must write and read where they
+    # are: donated, and no instruction that copies, slices out or stacks
+    # back the pool or a layer of it.
     c = gpt.CONFIGS[sz.model]
     pool_bytes = 2 * c.n_layers * (sz.lanes * c.max_seq_len) * c.d_model * 2
     for st, cs in zip(stats, steps):
@@ -375,6 +381,11 @@ def serve_leg(sz: Sizes, n: int) -> dict:
                 f"T=1 decode step has no tpu_custom_call: {cs}"))
             require(decode["donated_bytes"] >= pool_bytes, (
                 f"KV pools not donated: {decode} < {pool_bytes}"))
+            require(decode["pool_copies"] == 0, (
+                f"T=1 decode step moves the KV pool: {decode}"))
+            say(f"serve: T=1 step on chips {st['chips']}: temp_bytes "
+                f"{decode['temp_bytes']}, donated_bytes "
+                f"{decode['donated_bytes']}, pool_copies 0")
     chips = [tuple(st["chips"]) for st in stats]
     if sz.platform == "tpu":
         require(len(set(chips)) == n, f"replicas share chips: {chips}")

@@ -37,8 +37,11 @@ and seeded sampling alike.
 
 The engine is host-driven: block allocation, admission and stream
 fan-out are Python; the model math (sampling included) is one jax.jit'ed
-call per dispatched population with pools donated on TPU (in-place
-cache update).
+call per dispatched population.  The KV pools are donated on TPU and
+ride the step's layer loop whole: a step writes the blocks its new
+tokens fall in and reads the blocks it attends over in the engine's one
+buffer
+(`compiled_steps()` reports `pool_copies`, which must be 0).
 """
 
 from __future__ import annotations
@@ -59,7 +62,7 @@ import numpy as np
 
 from ray_tpu._private import compile_cache
 from ray_tpu._private.accelerators import leased_chips, require_chip_lease
-from ray_tpu.inference.kv_cache import PagedKVCache
+from ray_tpu.inference.kv_cache import PagedKVCache, count_pool_copies
 from ray_tpu.util import events, spans
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
 
@@ -633,8 +636,11 @@ class InferenceEngine:
     def compiled_steps(self) -> dict:
         """What XLA built for each step shape dispatched so far: seconds
         its first call spent compiling, the number of Mosaic kernel calls
-        in the compiled program and the bytes of arguments updated in
-        place (the donated KV pools).
+        in the compiled program, the bytes of arguments updated in place
+        (`donated_bytes`: the KV pools), the program's scratch
+        (`temp_bytes`) and the instructions that copy, slice out or stack
+        back the pool or whole layers of it (`pool_copies`, see
+        `kv_cache.count_pool_copies`: 0 when the pool stays where it is).
         Recompiles each shape ahead of time (a persistent-cache hit where
         the cache is on), so call it for a check, not per request."""
         out = {}
@@ -646,11 +652,13 @@ class InferenceEngine:
                 *self._step_avals[key]).compile()
             name = f"t{t}" + ("_sample" if sample else "") \
                 + ("_spec" if spec else "")
+            text, memory = compiled.as_text(), compiled.memory_analysis()
             out[name] = {
                 "compile_s": round(compile_s, 2),
-                "custom_calls": compiled.as_text().count("tpu_custom_call"),
-                "donated_bytes":
-                    compiled.memory_analysis().alias_size_in_bytes}
+                "custom_calls": text.count("tpu_custom_call"),
+                "donated_bytes": memory.alias_size_in_bytes,
+                "temp_bytes": memory.temp_size_in_bytes,
+                "pool_copies": count_pool_copies(text, self.cache.k.shape)}
         return out
 
     # ---------------- scheduler ----------------
@@ -1009,8 +1017,9 @@ class InferenceEngine:
             return next_tok, k, v
 
         self._step_impls[(sample, "spec") if spec else sample] = step
-        # Donating the pools makes the cache update in place; the CPU
-        # backend ignores donation with a warning, so don't ask it.
+        # Donated, the pools come back as the buffers they went in as
+        # (forward_cached writes and reads blocks of them in place);
+        # the CPU backend ignores donation with a warning, so don't ask.
         donate = () if self.backend == "cpu" else (1, 2)
         return jax.jit(step, donate_argnums=donate)
 

@@ -1,14 +1,27 @@
 """Paged KV cache: fixed-size blocks in a preallocated device pool.
 
-The pool is [n_layers, num_blocks, block_size, kv_heads, head_dim] per
-K and V (one allocation for the engine's lifetime — no per-request HBM
-churn).  Each live sequence owns an ordered list of block ids; the
-per-lane block tables map logical context positions onto pool blocks so
-sequences of wildly different lengths pack the same pool with at most
-block_size - 1 wasted slots each (the vLLM memory model).  Allocation
-and free are host-side refcount operations; the device arrays are
-functional — the jitted step returns updated pools and the cache rebinds
-them (donated on TPU, so the update is in place).
+The pool is one buffer per K and V for the engine's lifetime (no
+per-request HBM churn), stored as [n_layers, num_blocks, block_size, W]:
+a token's K (or V) of every kv head is one row of
+W = kv_heads * head_dim columns rounded up to a multiple of 128, which
+is the shape whose device layout is the row-major one the paged kernel's
+DMA reads (pad columns are zero and never read into a result;
+ops/attention.py).
+Each live sequence owns an ordered list of block ids; the per-lane block
+tables map logical context positions onto pool blocks so sequences of
+wildly different lengths pack the same pool with at most block_size - 1
+wasted slots each (the vLLM memory model).  Allocation and free are
+host-side refcount operations; the device arrays are functional — the
+jitted step returns updated pools and the cache rebinds them.  On TPU
+they are donated, and the step writes only the blocks its new tokens
+fall in and reads only the blocks it attends over, in that one buffer:
+`count_pool_copies` over the compiled step is the check
+(`InferenceEngine.compiled_steps()["pool_copies"]` must be 0).
+
+Outside the engine a block keeps the wire format
+[n_layers, n, block_size, kv_heads, head_dim] (export/install, the tier
+spill, serve/kv_tier/codec.py): `read_blocks` / `write_blocks` convert at
+the boundary.
 
 Prefix caching (content-addressed block sharing): a block that has been
 completely written ("sealed") is indexed by a hash chain over
@@ -28,11 +41,15 @@ from __future__ import annotations
 
 import collections
 import math
+import re
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ray_tpu.ops.attention import (kv_row_width, pack_kv_rows,
+                                   unpack_kv_rows)
 
 # Root of every hash chain (a block with no parent).
 _ROOT_HASH = 0
@@ -54,6 +71,70 @@ def chain_hashes(tokens: Sequence[int], block_size: int) -> List[int]:
                                             (i + 1) * block_size])))
         out.append(parent)
     return out
+
+
+# `  ROOT %name = bf16[48,256,16,1664]{3,2,1,0:T(8,128)(2,1)} opcode(%a, %b),
+#    attributes`
+_HLO_INSTR = re.compile(r"^\s*(ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                        r"([\w\-]+)\(([^)]*)\)(.*)$")
+_HLO_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+
+
+def count_pool_copies(hlo_text: str, pool_shape: Sequence[int]) -> int:
+    """Instructions of a compiled step that move the KV pool instead of
+    touching rows and blocks of it: the result is the whole stored pool
+    or whole layers of it (as a layer scan slices them out and stacks
+    them back), and the instruction, or the root of the fusion it calls,
+    is a `copy`, a `scatter`, a `dynamic-slice`, or a
+    `dynamic-update-slice` whose update is itself whole layers.  A row or
+    a block written into the pool is in place and not counted: where XLA
+    cannot update in place it inserts a `copy`, which is.  Zero means the
+    pool stays where it is."""
+    n_layers, *block = (int(d) for d in pool_shape)
+
+    def whole_layers(dims: str) -> bool:
+        shape = [int(d) for d in dims.split(",") if d]
+        return (shape[-len(block):] == block
+                and n_layers % math.prod(shape[:-len(block)]) == 0)
+
+    # computation -> {instruction: (dims, opcode, operand names, attributes)}
+    comps: Dict[str, Dict[str, tuple]] = {}
+    roots: Dict[str, str] = {}
+    current = None
+    for line in hlo_text.splitlines():
+        head = _HLO_COMPUTATION.match(line)
+        if head:
+            current = head.group(1)
+            comps[current] = {}
+            continue
+        m = _HLO_INSTR.match(line)
+        if m and current:
+            root, name, dims, opcode, operands, attrs = m.groups()
+            comps[current][name] = (
+                dims, opcode, re.findall(r"%([\w.\-]+)", operands), attrs)
+            if root:
+                roots[current] = name
+
+    def called(attrs: str) -> str:
+        return re.search(r"calls=%?([\w.\-]+)", attrs).group(1)
+
+    def moves(comp: str, name: str) -> bool:
+        dims, opcode, operands, attrs = comps[comp].get(
+            name, ("", "", [], ""))
+        if not whole_layers(dims):
+            return False
+        if opcode == "fusion":
+            return moves(called(attrs), roots[called(attrs)])
+        if opcode == "bitcast":         # a fusion's root behind a bitcast
+            return moves(comp, operands[0])
+        if opcode == "dynamic-update-slice":
+            return whole_layers(comps[comp].get(operands[1], ("",))[0])
+        return opcode in ("copy", "copy-start", "scatter", "dynamic-slice")
+
+    fused = {called(instr[3]) for body in comps.values()
+             for instr in body.values() if instr[1] == "fusion"}
+    return sum(moves(comp, name) for comp, body in comps.items()
+               if comp not in fused for name in body)
 
 
 class BlockAllocator:
@@ -172,7 +253,11 @@ class PagedKVCache:
         self.max_lanes = max_lanes
         self.max_seq_len = max_seq_len
         self.max_blocks_per_seq = math.ceil(max_seq_len / block_size)
-        shape = (n_layers, num_blocks, block_size, kv_heads, head_dim)
+        self.kv_heads = kv_heads
+        self.head_dim = head_dim
+        # The stored layout (module docstring): rows of W columns.
+        shape = (n_layers, num_blocks, block_size,
+                 kv_row_width(kv_heads, head_dim))
         self.k = jnp.zeros(shape, dtype)
         self.v = jnp.zeros(shape, dtype)
         self.allocator = BlockAllocator(num_blocks, on_evict=self._on_evict)
@@ -360,12 +445,12 @@ class PagedKVCache:
         if restored:
             idx = jnp.asarray(np.asarray([b for b, _p, _k in restored],
                                          np.int32))
-            kstack = np.stack([restores[i][2][0]
-                               for i in range(len(restores))], axis=1)
-            vstack = np.stack([restores[i][2][1]
-                               for i in range(len(restores))], axis=1)
-            self.k = self.k.at[:, idx].set(jnp.asarray(kstack))
-            self.v = self.v.at[:, idx].set(jnp.asarray(vstack))
+            self.write_blocks(
+                idx,
+                np.stack([payload[0] for _p, _k, payload in restores],
+                         axis=1),
+                np.stack([payload[1] for _p, _k, payload in restores],
+                         axis=1))
             for nb, _pos, key in restored:
                 # Restored blocks re-enter the device index (live now,
                 # evictable again once the lane lets go).
@@ -439,8 +524,9 @@ class PagedKVCache:
         if key is not None and self._index.get(key) == block:
             del self._index[key]
             if self.tier is not None:
-                self.tier.put(key, np.asarray(self.k[:, block]),
-                              np.asarray(self.v[:, block]))
+                k_np, v_np = self.read_blocks(
+                    jnp.asarray([block], jnp.int32))
+                self.tier.put(key, k_np[:, 0], v_np[:, 0])
 
     @property
     def num_indexed_blocks(self) -> int:
@@ -462,12 +548,13 @@ class PagedKVCache:
         if not entries:
             return None
         idx = jnp.asarray(np.asarray([b for _k, b in entries], np.int32))
+        k_np, v_np = self.read_blocks(idx)
         return {
             "v": 1,
             "block_size": self.block_size,
             "chain": [list(key[1]) for key, _b in entries],
-            "k": np.asarray(self.k[:, idx]),
-            "v_pool": np.asarray(self.v[:, idx]),
+            "k": k_np,
+            "v_pool": v_np,
         }
 
     def install_prefix(self, payload: dict) -> int:
@@ -485,7 +572,8 @@ class PagedKVCache:
                 self.block_size:
             return 0
         k_arr, v_arr = payload["k"], payload["v_pool"]
-        if tuple(k_arr.shape[2:]) != tuple(self.k.shape[2:]) or \
+        if tuple(k_arr.shape[2:]) != (self.block_size, self.kv_heads,
+                                      self.head_dim) or \
                 k_arr.shape[0] != self.k.shape[0]:
             return 0            # foreign model shape: refuse quietly
         parent = _ROOT_HASH
@@ -509,8 +597,7 @@ class PagedKVCache:
             return 0
         idx = jnp.asarray(np.asarray([b for _i, _k, b in new], np.int32))
         pos = np.asarray([i for i, _k, _b in new])
-        self.k = self.k.at[:, idx].set(jnp.asarray(k_arr[:, pos]))
-        self.v = self.v.at[:, idx].set(jnp.asarray(v_arr[:, pos]))
+        self.write_blocks(idx, k_arr[:, pos], v_arr[:, pos])
         # Index + park evictable only AFTER every alloc: the blocks stay
         # at refcount 1 through the loop above so a later alloc in the
         # same import can never reclaim an earlier install.
@@ -604,3 +691,19 @@ class PagedKVCache:
         """Rebind the functional pools returned by a jitted step."""
         self.k = k
         self.v = v
+
+    # ---------------- the wire format's boundary ----------------
+
+    def read_blocks(self, idx: jax.Array) -> Tuple[np.ndarray, np.ndarray]:
+        """Blocks `idx` of both pools in the wire format
+        [n_layers, n, block_size, kv_heads, head_dim], on the host."""
+        return tuple(np.asarray(unpack_kv_rows(pool[:, idx], self.kv_heads,
+                                               self.head_dim))
+                     for pool in (self.k, self.v))
+
+    def write_blocks(self, idx: jax.Array, k_blocks, v_blocks) -> None:
+        """Store wire-format blocks at `idx` (pad columns stay zero)."""
+        self.k = self.k.at[:, idx].set(
+            pack_kv_rows(jnp.asarray(k_blocks, self.k.dtype)))
+        self.v = self.v.at[:, idx].set(
+            pack_kv_rows(jnp.asarray(v_blocks, self.v.dtype)))

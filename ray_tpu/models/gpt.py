@@ -286,12 +286,13 @@ def forward_trunk(params: dict, tokens: jax.Array, config: GPTConfig,
     return x, jnp.sum(auxes)
 
 
-def _block_cached(x, p, k_pool, v_pool, config: GPTConfig, block_tables,
-                  positions, valid, ctx_lens):
-    """One transformer block over a paged KV cache: new K/V are scattered
-    into this layer's pool slice, then attention runs over the block
-    table (ops/attention.py paged path).  x [B, T, D]; positions [B, T]
-    absolute; ctx_lens [B] = context length including this slice."""
+def _block_cached(x, k_pool, v_pool, layer, p, config: GPTConfig,
+                  block_tables, positions, valid, ctx_lens):
+    """One transformer block over a paged KV cache: new K/V rows are
+    written into the whole pools at `layer`, then attention runs over the
+    block table in the same buffers (ops/attention.py paged path).
+    x [B, T, D]; positions [B, T] absolute; ctx_lens [B] = context length
+    including this slice."""
     from ray_tpu.ops.attention import paged_attention, paged_kv_update
 
     h = _layernorm(x, p["ln1_scale"], p["ln1_bias"])
@@ -299,9 +300,9 @@ def _block_cached(x, p, k_pool, v_pool, config: GPTConfig, block_tables,
     k = jnp.einsum("bld,dhk->blhk", h, p["wk"].astype(h.dtype))
     v = jnp.einsum("bld,dhk->blhk", h, p["wv"].astype(h.dtype))
     k_pool, v_pool = paged_kv_update(k_pool, v_pool, k, v, block_tables,
-                                     positions, valid)
+                                     positions, valid, layer)
     attn = paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
-                           positions)
+                           positions, layer)
     x = x + jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
 
     h = _layernorm(x, p["ln2_scale"], p["ln2_bias"])
@@ -320,11 +321,14 @@ def forward_cached(params: dict, tokens: jax.Array, positions: jax.Array,
     tokens [B, T] is a SLICE of each lane's sequence at absolute
     `positions` [B, T] (per-lane offsets — lanes decode at different
     depths); K/V for the slice are written into the paged pools
-    [n_layers, NB, BS, H, D] and attention covers each lane's whole
-    block table.  `valid` masks padding lanes/overhang (their cache
-    writes are dropped).  Returns (x [B, T, D], k_pool, v_pool) — the
-    lm head is applied by the caller on the positions it needs, so a
-    prefill chunk never materializes [B, T, V].
+    [n_layers, NB, BS, W] (inference/kv_cache.py's stored layout)
+    and attention covers each lane's whole block table.  The pools ride
+    the layer loop as its carry, whole: a layer writes its rows and reads
+    its blocks by index, nothing slices a layer out or stacks it back.
+    `valid` masks padding lanes/overhang (their cache writes are
+    dropped).  Returns (x [B, T, D], k_pool, v_pool) — the lm head is
+    applied by the caller on the positions it needs, so a prefill chunk
+    never materializes [B, T, V].
 
     Dense-MLP configs only (n_experts == 0): MoE decode would need
     per-token expert dispatch, which the serving engine doesn't support.
@@ -336,14 +340,14 @@ def forward_cached(params: dict, tokens: jax.Array, positions: jax.Array,
     x = params["tok_embed"][tokens].astype(c.dtype)
     x = x + params["pos_embed"][pos].astype(c.dtype)
 
-    def body(x, layer):
-        p, k_l, v_l = layer
-        x, k_l, v_l = _block_cached(x, p, k_l, v_l, c, block_tables,
-                                    positions, valid, ctx_lens)
-        return x, (k_l, v_l)
+    def body(carry, layer):
+        p, i = layer
+        return _block_cached(*carry, i, p, c, block_tables, positions,
+                             valid, ctx_lens), None
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, (params["blocks"], k_pool, v_pool),
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        body, (x, k_pool, v_pool),
+        (params["blocks"], jnp.arange(c.n_layers, dtype=jnp.int32)),
         unroll=min(c.scan_unroll, c.n_layers))
     x = _layernorm(x, params["final_ln_scale"], params["final_ln_bias"])
     return x, k_pool, v_pool

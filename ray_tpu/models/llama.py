@@ -224,11 +224,12 @@ def lm_head(params: dict, x: jax.Array, config: LlamaConfig) -> jax.Array:
     return x @ params["lm_head"].astype(config.dtype)
 
 
-def _block_cached(x, p, k_pool, v_pool, config: LlamaConfig, block_tables,
-                  positions, valid, ctx_lens):
-    """One Llama block over a paged KV cache.  K/V are cached with
-    kv_heads (GQA un-repeated — the whole point of the grouped cache);
-    the paged attention path expands groups itself."""
+def _block_cached(x, k_pool, v_pool, layer, p, config: LlamaConfig,
+                  block_tables, positions, valid, ctx_lens):
+    """One Llama block over a paged KV cache, written and read in the
+    whole pools at `layer`.  K/V are cached with kv_heads (GQA
+    un-repeated — the whole point of the grouped cache); the paged
+    attention path expands groups itself."""
     from ray_tpu.ops.attention import paged_attention, paged_kv_update
 
     c = config
@@ -242,9 +243,9 @@ def _block_cached(x, p, k_pool, v_pool, config: LlamaConfig, block_tables,
     q = _rope(q, c.rope_theta, positions[:, 0])
     k = _rope(k, c.rope_theta, positions[:, 0])
     k_pool, v_pool = paged_kv_update(k_pool, v_pool, k, v, block_tables,
-                                     positions, valid)
+                                     positions, valid, layer)
     attn = paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
-                           positions)
+                           positions, layer, kv_heads=c.n_kv_heads)
     x = x + jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
 
     h = _rmsnorm(x, p["mlp_norm"], c.norm_eps)
@@ -262,19 +263,19 @@ def forward_cached(params: dict, tokens: jax.Array, positions: jax.Array,
                    config: LlamaConfig):
     """Cached (incremental) trunk — same contract as gpt.forward_cached:
     tokens [B, T] at per-lane absolute `positions`, paged pools
-    [n_layers, NB, BS, KH, D] (KH = n_kv_heads), returns
-    (x [B, T, D], k_pool, v_pool)."""
+    [n_layers, NB, BS, W] (rows of n_kv_heads x head_dim) carried
+    whole through the layer loop, returns (x [B, T, D], k_pool, v_pool)."""
     c = config
     x = params["tok_embed"][tokens].astype(c.dtype)
 
-    def body(x, layer):
-        p, k_l, v_l = layer
-        x, k_l, v_l = _block_cached(x, p, k_l, v_l, c, block_tables,
-                                    positions, valid, ctx_lens)
-        return x, (k_l, v_l)
+    def body(carry, layer):
+        p, i = layer
+        return _block_cached(*carry, i, p, c, block_tables, positions,
+                             valid, ctx_lens), None
 
-    x, (k_pool, v_pool) = jax.lax.scan(
-        body, x, (params["blocks"], k_pool, v_pool),
+    (x, k_pool, v_pool), _ = jax.lax.scan(
+        body, (x, k_pool, v_pool),
+        (params["blocks"], jnp.arange(c.n_layers, dtype=jnp.int32)),
         unroll=min(c.scan_unroll, c.n_layers))
     x = _rmsnorm(x, params["final_norm"], c.norm_eps)
     return x, k_pool, v_pool

@@ -265,57 +265,134 @@ def _flash_forward_impl(q, k, v, causal, scale, block_q, block_k, interpret):
 # Paged-KV attention (decode path for the inference engine)
 # ---------------------------------------------------------------------------
 #
-# The KV cache lives in a preallocated block pool [num_blocks, block_size,
-# kv_heads, head_dim]; each sequence owns a row of a block table mapping its
-# logical context positions onto pool blocks (inference/kv_cache.py).  The
-# decode step asks: one query per lane attends over that lane's block table.
-# The Pallas kernel streams KV blocks from the pool via scalar-prefetched
-# block-table indices (positions past the context length are masked, so
-# unused table entries may point anywhere valid); the dense fallback gathers
-# the table into a contiguous context and masks — it covers CPU tests, odd
-# head dims, and the multi-token prefill path.
+# The KV cache is ONE buffer per K and V for the engine's lifetime,
+# [n_layers, num_blocks, block_size, W] (inference/kv_cache.py): a token's
+# K (or V) of every kv head is one row of W = kv_heads * head_dim columns,
+# rounded up to a multiple of 128 so that the array's device layout is the
+# row-major one a Mosaic DMA reads (a minor dim of 25 x 64 = 1600 makes the
+# runtime pick another, and XLA then converts the whole pool around every
+# kernel call).  Pad columns are zero and never reach a result.
+#
+# Each sequence owns a row of a block table mapping its logical context
+# positions onto pool blocks.  A step writes the blocks its new tokens fall
+# in at (layer, block) and attends over the lane's blocks of that layer;
+# both take the whole pool and a layer index, so the pool is never sliced,
+# stacked or copied (`kv_cache.count_pool_copies` checks the compiled
+# program).  The Pallas kernel streams KV blocks from the pool via
+# scalar-prefetched block-table indices (positions past the context length
+# are masked, so unused table entries may point anywhere valid); the dense
+# fallback gathers the table into a contiguous context and masks — it covers
+# CPU tests, odd head dims, and the multi-token prefill path.
+
+KV_ROW_ALIGN = 128
+# Blocks written per trip of the write loop: a T=1 step of 8 lanes is
+# straight-line code, a prefill chunk a short loop.
+_KV_WRITE_UNROLL = 8
+
+
+def kv_row_width(kv_heads: int, head_dim: int) -> int:
+    """Columns of one stored K/V row: kv_heads * head_dim rounded up to
+    the lane width."""
+    return -(-kv_heads * head_dim // KV_ROW_ALIGN) * KV_ROW_ALIGN
+
+
+def pack_kv_rows(x):
+    """[..., KH, D] -> [..., W] stored rows (heads side by side, zero
+    pad columns)."""
+    *lead, kh, d = x.shape
+    pad = kv_row_width(kh, d) - kh * d
+    rows = x.reshape(*lead, kh * d)
+    return jnp.pad(rows, [(0, 0)] * len(lead) + [(0, pad)]) if pad else rows
+
+
+def unpack_kv_rows(rows, kv_heads: int, head_dim: int):
+    """[..., W] stored rows -> [..., KH, D]."""
+    return rows[..., :kv_heads * head_dim].reshape(
+        *rows.shape[:-1], kv_heads, head_dim)
 
 
 def paged_kv_update(k_pool, v_pool, k_new, v_new, block_tables, positions,
-                    valid):
-    """Scatter new K/V for one layer into the paged pools.
+                    valid, layer=0):
+    """Write one layer's new K/V rows into the pools, in place.
 
-    k_pool/v_pool [NB, BS, KH, D]; k_new/v_new [B, T, KH, D];
-    block_tables [B, MB] int32; positions [B, T] absolute positions;
-    valid [B, T] bool — invalid slots (padding lanes, prompt overhang)
-    are dropped instead of written (out-of-range index + mode="drop").
+    k_pool/v_pool [L, NB, BS, W]; k_new/v_new [B, T, KH, D];
+    block_tables [B, MB] int32; positions [B, T] absolute and consecutive
+    per lane (positions[:, :1] + arange(T), as every prefill chunk,
+    decode token and draft run is); valid [B, T] bool — an invalid slot
+    (padding lane, prompt overhang) changes nothing.  `layer` may be
+    traced (the layer loop's index).
+
+    A lane's run touches at most (T + BS - 2) // BS + 1 blocks.  Each is
+    read, merged with the rows that fall in it and written back with one
+    `dynamic_update_slice` of a whole [BS, W] block: XLA updates the
+    loop-carried pool in place and keeps its layout (a scatter makes it
+    pick another layout for the whole pool), and a tile-aligned block
+    costs no more than one row (a row at a time, a 32-token chunk took
+    46 ms over 48 layers on the v5e, this 3.5; PERF.md section 6).
     """
-    nb, bs, kh, d = k_pool.shape
+    _, _, bs, w = k_pool.shape
     b, t = positions.shape
-    blk = positions // bs
-    blk = jnp.clip(blk, 0, block_tables.shape[1] - 1)
-    phys = jnp.take_along_axis(block_tables, blk, axis=1)        # [B, T]
-    flat = phys * bs + positions % bs                            # [B, T]
-    flat = jnp.where(valid, flat, nb * bs)                       # OOB => drop
-    flat = flat.reshape(-1)
-    k_pool = k_pool.reshape(nb * bs, kh, d).at[flat].set(
-        k_new.reshape(-1, kh, d), mode="drop").reshape(nb, bs, kh, d)
-    v_pool = v_pool.reshape(nb * bs, kh, d).at[flat].set(
-        v_new.reshape(-1, kh, d), mode="drop").reshape(nb, bs, kh, d)
-    return k_pool, v_pool
+    n_touch = (t + bs - 2) // bs + 1
+    first, lead = positions[:, 0] // bs, positions[:, 0] % bs
+    j = jnp.arange(n_touch)
+    # run[b, j, r]: which of the lane's T rows lands in row r of its j-th
+    # touched block.
+    run = j[None, :, None] * bs - lead[:, None, None] + jnp.arange(bs)
+    inside = (run >= 0) & (run < t)
+    run = jnp.clip(run, 0, t - 1).reshape(b, n_touch * bs)
+    write_row = (inside.reshape(b, -1)
+                 & jnp.take_along_axis(valid, run, axis=1)
+                 ).reshape(b * n_touch, bs, 1)
+    phys = jnp.take_along_axis(
+        block_tables,
+        jnp.clip(first[:, None] + j, 0, block_tables.shape[1] - 1),
+        axis=1).reshape(-1).astype(jnp.int32)                # [B * n_touch]
+
+    def blocks_of(new, pool):
+        rows = pack_kv_rows(new).astype(pool.dtype)              # [B, T, W]
+        return jnp.take_along_axis(rows, run[:, :, None], axis=1).reshape(
+            b * n_touch, bs, w)
+
+    new_blocks = (blocks_of(k_new, k_pool), blocks_of(v_new, v_pool))
+    layer = jnp.asarray(layer, jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+
+    def write(i, pools):
+        at = (layer, phys[i], zero, zero)
+
+        def merged(pool, blocks):
+            old = jax.lax.dynamic_slice(pool, at, (1, 1, bs, w))
+            block = jnp.where(write_row[i], blocks[i], old[0, 0])
+            return jax.lax.dynamic_update_slice(pool, block[None, None], at)
+
+        return tuple(merged(*pb) for pb in zip(pools, new_blocks))
+
+    return jax.lax.fori_loop(0, b * n_touch, write, (k_pool, v_pool),
+                             unroll=min(b * n_touch, _KV_WRITE_UNROLL))
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
-                              q_positions, *, scale=None):
+                              q_positions, layer=0, *,
+                              kv_heads: Optional[int] = None, scale=None):
     """Masked-dense paged attention (fallback + prefill path).
 
-    q [B, T, H, D] at absolute q_positions [B, T]; pools [NB, BS, KH, D]
-    (KH may divide H — GQA); ctx_lens [B] = tokens written per lane.
-    Each query attends to context positions <= its own (the query's K/V
-    must already be in the pool).  All-masked rows (inactive lanes) come
-    out as a uniform average, never NaN (finite NEG_INF).
+    q [B, T, H, D] at absolute q_positions [B, T]; pools
+    [L, NB, BS, W], read at `layer`; kv_heads (default H) may divide
+    H — GQA; ctx_lens [B] = tokens written per lane.  Each query attends
+    to context positions <= its own (the query's K/V must already be in
+    the pool).  All-masked rows (inactive lanes) come out as a uniform
+    average, never NaN (finite NEG_INF).
     """
     b, t, h, d = q.shape
-    nb, bs, kh, _ = k_pool.shape
+    bs = k_pool.shape[2]
+    kh = kv_heads or h
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     max_ctx = block_tables.shape[1] * bs
-    k_ctx = k_pool[block_tables].reshape(b, max_ctx, kh, d)
-    v_ctx = v_pool[block_tables].reshape(b, max_ctx, kh, d)
+    # One gather straight out of the engine's buffer: [B, MB, BS, W].
+    k_ctx = unpack_kv_rows(k_pool[layer, block_tables], kh, d).reshape(
+        b, max_ctx, kh, d)
+    v_ctx = unpack_kv_rows(v_pool[layer, block_tables], kh, d).reshape(
+        b, max_ctx, kh, d)
     if h != kh:
         k_ctx = jnp.repeat(k_ctx, h // kh, axis=2)
         v_ctx = jnp.repeat(v_ctx, h // kh, axis=2)
@@ -330,17 +407,22 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
     return out.astype(q.dtype)
 
 
-def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
-                         m_ref, l_ref, acc_ref, *, block_size: int,
-                         q_per_kv: int, scale: float, n_blocks: int):
+def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
+                         o_ref, m_ref, l_ref, acc_ref, *, block_size: int,
+                         scale: float, n_blocks: int):
     """One (lane, kv_block) grid step of single-query paged attention.
 
-    Scalar-prefetched block tables route each grid step's K/V DMA to the
-    lane's physical block (see the in_specs index maps); this kernel only
-    sees q [H, D], k/v [BS, KH, D] already in VMEM.  Online softmax
-    state persists in scratch across the lane's kv sweep, exactly like
-    the flash kernel above; blocks at/past the context length are
-    skipped entirely (their DMA still lands, but compute is gated)."""
+    Scalar-prefetched block tables and the layer index route each grid
+    step's K/V DMA from the engine's pool to VMEM (see the in_specs index
+    maps); this kernel only sees k/v [BS, W] rows as they are stored and
+    q [H, W] block-diagonal (head h's query in the columns of its kv
+    head, zeros elsewhere), so a row-by-row dot gives per-head scores with
+    no relayout of K.  The accumulator is [H, W]: head h's output is its
+    kv head's column slice, taken outside.  Online softmax state persists
+    in scratch across the lane's kv sweep, exactly like the flash kernel
+    above; blocks at/past the context length are skipped entirely (their
+    DMA still lands, but compute is gated)."""
+    del layer_ref                       # only the index maps read it
     lane = pl.program_id(0)
     blk = pl.program_id(1)
     base = blk * block_size
@@ -353,17 +435,12 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
 
     @pl.when(base < len_ref[lane])
     def _compute():
-        h, d = q_ref.shape
-        kh = h // q_per_kv
-        q = q_ref[...].astype(jnp.float32) * scale           # [H, D]
-        k_blk = k_ref[...].astype(jnp.float32)               # [BS, KH, D]
+        q = q_ref[...].astype(jnp.float32) * scale           # [H, W]
+        k_blk = k_ref[...].astype(jnp.float32)               # [BS, W]
         v_blk = v_ref[...].astype(jnp.float32)
-        q3 = q.reshape(kh, q_per_kv, d)
-        # Batched over kv heads: [KH, QPK, D] x [BS, KH, D] -> [KH, QPK, BS]
         s = jax.lax.dot_general(
-            q3, k_blk, (((2,), (2,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)
-        s = s.reshape(h, block_size)
+            q, k_blk, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [H, BS]
         pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(pos < len_ref[lane], s, NEG_INF)
         m = m_ref[...]
@@ -373,10 +450,9 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
         pv = jax.lax.dot_general(
-            p.reshape(kh, q_per_kv, block_size), v_blk,
-            (((2,), (0,)), ((0,), (1,))),
-            preferred_element_type=jnp.float32)              # [KH, QPK, D]
-        acc_ref[...] = acc_ref[...] * alpha + pv.reshape(h, d)
+            p, v_blk, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [H, W]
+        acc_ref[...] = acc_ref[...] * alpha + pv
 
     @pl.when(blk == n_blocks - 1)
     def _finalize():
@@ -384,17 +460,20 @@ def _paged_decode_kernel(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref,
         o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
-def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens, *,
+def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens,
+                           layer=0, *, kv_heads: Optional[int] = None,
                            scale: Optional[float] = None,
                            use_kernel: Optional[bool] = None,
                            interpret: Optional[bool] = None):
     """Single-query paged attention: q [B, H, D] (one decode token per
-    lane) over each lane's block table.  By default the Pallas kernel on
-    TPU where the head dim allows, the masked-dense path on CPU (the
+    lane) over each lane's block table, in the pools [L, NB, BS, W]
+    at `layer` (may be traced).  By default the Pallas kernel on TPU
+    where the head dim allows, the masked-dense path on CPU (the
     interpreter is too slow for the engine tests) and for other head dims
     (logged once per shape on TPU).  ctx_lens counts tokens already
     written to the pool INCLUDING the current one."""
     b, h, d = q.shape
+    kh = kv_heads or h
     if use_kernel is None:
         use_kernel = not _interpret_kernels()
         if use_kernel and d not in (64, 128, 256):
@@ -404,57 +483,66 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens, *,
     if not use_kernel:
         out = paged_attention_reference(
             q[:, None], k_pool, v_pool, block_tables, ctx_lens,
-            (ctx_lens - 1)[:, None], scale=scale)
+            (ctx_lens - 1)[:, None], layer, kv_heads=kh, scale=scale)
         return out[:, 0]
     if interpret is None:
         interpret = _interpret_kernels()
-    nb, bs, kh, _ = k_pool.shape
+    _, _, bs, w = k_pool.shape
     mb = block_tables.shape[1]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
+    # Head h's query sits in the columns of kv head h // (H / KH).
+    own = jnp.arange(h)[:, None] // (h // kh) == jnp.arange(kh)[None, :]
+    q_rows = pack_kv_rows(jnp.where(own[None, :, :, None], q[:, :, None, :],
+                                    jnp.zeros((), q.dtype)))    # [B, H, W]
     kernel = functools.partial(
-        _paged_decode_kernel, block_size=bs, q_per_kv=h // kh,
-        scale=scale, n_blocks=mb)
+        _paged_decode_kernel, block_size=bs, scale=scale, n_blocks=mb)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,      # block tables + context lengths
+        num_scalar_prefetch=3,      # block tables, context lengths, layer
         grid=(b, mb),
         in_specs=[
-            pl.BlockSpec((None, h, d), lambda i, j, bt, ln: (i, 0, 0)),
-            pl.BlockSpec((None, bs, kh, d),
-                         lambda i, j, bt, ln: (bt[i, j], 0, 0, 0)),
-            pl.BlockSpec((None, bs, kh, d),
-                         lambda i, j, bt, ln: (bt[i, j], 0, 0, 0)),
+            pl.BlockSpec((None, h, w), lambda i, j, bt, ln, ly: (i, 0, 0)),
+            pl.BlockSpec((None, None, bs, w),
+                         lambda i, j, bt, ln, ly: (ly[0], bt[i, j], 0, 0)),
+            pl.BlockSpec((None, None, bs, w),
+                         lambda i, j, bt, ln, ly: (ly[0], bt[i, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((None, h, d), lambda i, j, bt, ln: (i, 0, 0)),
+        out_specs=pl.BlockSpec((None, h, w),
+                               lambda i, j, bt, ln, ly: (i, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, d), jnp.float32),
+            pltpu.VMEM((h, w), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, d), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, h, w), q.dtype),
         interpret=interpret,
         # The instruction's name in the HLO and so in a device trace: in
         # the engine's layer scan it would be `closed_call.N` without.
         name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      q[:, None].reshape(b, h, d), k_pool, v_pool)
-    return out
+      jnp.asarray(layer, jnp.int32).reshape(1), q_rows, k_pool, v_pool)
+    # [B, H, W] -> head h's own D columns.
+    out = unpack_kv_rows(out, kh, d)                             # [B,H,KH,D]
+    return jnp.sum(jnp.where(own[None, :, :, None], out,
+                             jnp.zeros((), out.dtype)), axis=2)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens, q_positions,
-                    *, scale: Optional[float] = None):
+                    layer=0, *, kv_heads: Optional[int] = None,
+                    scale: Optional[float] = None):
     """Dispatch paged attention for a [B, T, H, D] query slice: the T=1
     decode step rides the single-query kernel path, multi-token prefill
     chunks ride the masked-dense path."""
     if q.shape[1] == 1:
         return paged_decode_attention(
-            q[:, 0], k_pool, v_pool, block_tables, ctx_lens,
-            scale=scale)[:, None]
+            q[:, 0], k_pool, v_pool, block_tables, ctx_lens, layer,
+            kv_heads=kv_heads, scale=scale)[:, None]
     return paged_attention_reference(q, k_pool, v_pool, block_tables,
-                                     ctx_lens, q_positions, scale=scale)
+                                     ctx_lens, q_positions, layer,
+                                     kv_heads=kv_heads, scale=scale)
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,

@@ -19,6 +19,7 @@ from ray_tpu.inference import BlockAllocator, InferenceEngine, PagedKVCache
 from ray_tpu.models import gpt, llama
 from ray_tpu.ops import paged_attention_reference, paged_decode_attention, \
     paged_kv_update
+from ray_tpu.ops.attention import pack_kv_rows, unpack_kv_rows
 
 
 # ---------------------------------------------------------------------------
@@ -157,52 +158,127 @@ def test_prefix_cache_lru_eviction_under_pressure():
 # Paged attention: kernel (interpret) vs dense reference
 # ---------------------------------------------------------------------------
 
-def test_paged_kv_update_masks_invalid_lanes():
-    nb, bs, kh, d = 4, 4, 2, 8
-    k_pool = jnp.zeros((nb, bs, kh, d))
-    v_pool = jnp.zeros((nb, bs, kh, d))
-    k_new = jnp.ones((2, 1, kh, d))
-    v_new = jnp.ones((2, 1, kh, d))
-    tables = jnp.array([[1, 2], [3, 0]], jnp.int32)
-    positions = jnp.array([[0], [5]], jnp.int32)
-    valid = jnp.array([[True], [False]])
-    k2, v2 = paged_kv_update(k_pool, v_pool, k_new, v_new, tables,
-                             positions, valid)
-    assert float(k2[1, 0].sum()) == kh * d      # lane 0 wrote block 1 slot 0
-    # The invalid lane wrote nowhere — pool otherwise untouched.
-    assert float(k2.sum()) == kh * d
-    assert float(v2.sum()) == kh * d
+def _stored_pool(blocks):
+    """Wire-format blocks [L, NB, BS, KH, D] -> the stored pool
+    [L, NB, BS, W] (zero pad columns)."""
+    return pack_kv_rows(jnp.asarray(blocks))
 
 
-@pytest.mark.parametrize("q_per_kv", [1, 4])
-def test_paged_decode_kernel_matches_reference(q_per_kv):
+def _dense_paged_attention(q, k_blocks, v_blocks, tables, ctx_lens,
+                           q_positions):
+    """Plain numpy attention over ONE layer's wire-format blocks
+    [NB, BS, KH, D]: the ground truth both paged paths answer to."""
+    q, k_blocks, v_blocks = (np.asarray(a, np.float64)
+                             for a in (q, k_blocks, v_blocks))
+    b, t, h, d = q.shape
+    kh = k_blocks.shape[2]
+    out = np.zeros_like(q)
+    for lane in range(b):
+        k_ctx = k_blocks[np.asarray(tables[lane])].reshape(-1, kh, d)
+        v_ctx = v_blocks[np.asarray(tables[lane])].reshape(-1, kh, d)
+        for i in range(t):
+            n = min(int(ctx_lens[lane]), int(q_positions[lane, i]) + 1)
+            for head in range(h):
+                g = head // (h // kh)
+                s = k_ctx[:n, g] @ q[lane, i, head] / np.sqrt(d)
+                p = np.exp(s - s.max())
+                out[lane, i, head] = (p / p.sum()) @ v_ctx[:n, g]
+    return out
+
+
+@pytest.mark.parametrize("case", [
+    # layers, layer, (kh, d), positions [B, T], valid [B, T]
+    pytest.param((1, 0, (2, 8), [[0], [5]], [[True], [False]]),
+                 id="t1_one_layer"),
+    pytest.param((3, 2, (2, 8), [[0], [5]], [[True], [False]]),
+                 id="t1_layer_2_of_3"),
+    pytest.param((2, 1, (25, 64), [[3], [6]], [[True], [True]]),
+                 id="t1_padded_row_25x64"),
+    # A chunk that starts mid-block, crosses into the lane's next block
+    # and has dead positions inside it and at its end (prompt overhang).
+    pytest.param((2, 1, (3, 8),
+                  [[2, 3, 4, 5, 6, 7], [0, 1, 2, 3, 4, 5]],
+                  [[True, True, False, True, True, False],
+                   [False, False, False, False, False, False]]),
+                 id="t6_crosses_block_boundary_with_invalid"),
+])
+def test_paged_kv_update_masks_invalid_lanes(case):
+    layers, layer, (kh, d), positions, valid = case
+    nb, bs = 4, 4
+    rng = np.random.default_rng(3)
+    positions, valid = np.asarray(positions, np.int32), np.asarray(valid)
+    b, t = positions.shape
+    before = rng.standard_normal((2, layers, nb, bs, kh, d)).astype(
+        np.float32)
+    k_new = rng.standard_normal((b, t, kh, d)).astype(np.float32)
+    v_new = rng.standard_normal((b, t, kh, d)).astype(np.float32)
+    tables = np.array([[1, 2], [3, 0]], np.int32)
+
+    k2, v2 = jax.jit(paged_kv_update)(
+        _stored_pool(before[0]), _stored_pool(before[1]), k_new, v_new,
+        tables, positions, valid, layer)
+
+    want = before.copy()
+    for lane, i in zip(*np.nonzero(valid)):
+        pos = positions[lane, i]
+        want[0, layer, tables[lane, pos // bs], pos % bs] = k_new[lane, i]
+        want[1, layer, tables[lane, pos // bs], pos % bs] = v_new[lane, i]
+    for got, expect in ((k2, want[0]), (v2, want[1])):
+        # Valid rows written, at the given layer only, and an invalid row
+        # changed nothing.
+        np.testing.assert_array_equal(
+            np.asarray(unpack_kv_rows(got, kh, d)), expect)
+        assert not np.asarray(got[..., kh * d:]).any()         # pad columns
+
+
+@pytest.mark.parametrize("kh,q_per_kv,layers,layer", [
+    pytest.param(2, 1, 1, 0, id="mha"),
+    pytest.param(2, 4, 1, 0, id="gqa4"),
+    pytest.param(2, 1, 3, 1, id="mha_layer_1_of_3"),
+    pytest.param(3, 2, 3, 2, id="gqa2_padded_row_layer_2_of_3"),
+])
+def test_paged_decode_kernel_matches_reference(kh, q_per_kv, layers, layer):
     rng = np.random.default_rng(0)
-    b, kh, d, bs, mb = 3, 2, 64, 8, 4
+    b, d, bs, mb = 3, 64, 8, 4
     h = kh * q_per_kv
     nb = 16
     q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    k_pool = jnp.asarray(rng.standard_normal((nb, bs, kh, d)), jnp.float32)
-    v_pool = jnp.asarray(rng.standard_normal((nb, bs, kh, d)), jnp.float32)
+    k_blocks = rng.standard_normal((layers, nb, bs, kh, d)).astype(np.float32)
+    v_blocks = rng.standard_normal((layers, nb, bs, kh, d)).astype(np.float32)
+    k_pool, v_pool = _stored_pool(k_blocks), _stored_pool(v_blocks)
     tables = jnp.asarray(rng.permutation(nb)[:b * mb].reshape(b, mb),
                          jnp.int32)
     ctx_lens = jnp.asarray([5, 17, 32], jnp.int32)   # partial/multi/full
     out_k = paged_decode_attention(q, k_pool, v_pool, tables, ctx_lens,
-                                   use_kernel=True, interpret=True)
+                                   layer, kv_heads=kh, use_kernel=True,
+                                   interpret=True)
     out_ref = paged_attention_reference(
         q[:, None], k_pool, v_pool, tables, ctx_lens,
-        (ctx_lens - 1)[:, None])[:, 0]
-    np.testing.assert_allclose(np.asarray(out_k), np.asarray(out_ref),
-                               atol=2e-5, rtol=2e-5)
+        (ctx_lens - 1)[:, None], layer, kv_heads=kh)[:, 0]
+    want = _dense_paged_attention(
+        q[:, None], k_blocks[layer], v_blocks[layer], tables, ctx_lens,
+        np.asarray(ctx_lens - 1)[:, None])[:, 0]
+    np.testing.assert_allclose(np.asarray(out_k), want, atol=2e-5, rtol=2e-5)
+    np.testing.assert_allclose(np.asarray(out_ref), want, atol=2e-5,
+                               rtol=2e-5)
 
 
 # ---------------------------------------------------------------------------
 # Cached decode == full forward (the correctness core of the engine)
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("family", ["gpt", "llama"])
+# 25 heads of 64 at nano depth: gpt2-xl's K/V row, 1600 columns stored in
+# rows of 1664.
+_GPT_25X64 = gpt.GPTConfig(vocab_size=256, n_layers=2, d_model=1600,
+                           n_heads=25, d_ff=128, max_seq_len=64,
+                           dtype=jnp.float32)
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama", "gpt_25x64"])
 def test_cached_logits_match_full_forward(family):
-    model = gpt if family == "gpt" else llama
-    config = model.CONFIGS["nano" if family == "gpt" else "llama-tiny"]
+    model = llama if family == "llama" else gpt
+    config = {"gpt": gpt.CONFIGS["nano"], "gpt_25x64": _GPT_25X64,
+              "llama": llama.CONFIGS["llama-tiny"]}[family]
     params = model.init_params(config, jax.random.key(1))
     rng = np.random.default_rng(1)
     tokens = rng.integers(0, config.vocab_size, size=21).tolist()
@@ -241,6 +317,38 @@ def test_cached_logits_match_full_forward(family):
     for pos, logits in got.items():
         np.testing.assert_allclose(logits, full[pos], atol=2e-4, rtol=2e-4,
                                    err_msg=f"{family} position {pos}")
+
+
+def test_export_import_round_trip_keeps_the_wire_format():
+    """Outside the engine a block is [L, n, BS, KH, D] whatever the pool's
+    stored layout: what one engine exports, a second imports and exports
+    again byte for byte, and the stored rows' pad columns stay zero."""
+    from ray_tpu.serve.kv_tier.codec import KVBlockCodec
+    prompt = list(range(1, 42))                 # (41-1)//8 = 5 sealed blocks
+    engines = [InferenceEngine("gpt", "nano", seed=0, max_lanes=2,
+                               block_size=8, max_seq_len=64, prefill_chunk=8,
+                               auto_start=False) for _ in range(2)]
+    first, second = engines
+    c = first.config
+    assert c.n_heads * c.head_dim % 128       # nano's row IS padded
+    first.prefill(prompt)
+    sent = first.export_prefix(prompt)
+    assert sent["k"].shape == sent["v_pool"].shape == (
+        c.n_layers, 5, 8, c.n_heads, c.head_dim)
+    assert sent["k"].dtype == first.cache.k.dtype and np.abs(sent["k"]).sum()
+
+    assert second.import_prefix(
+        KVBlockCodec.decode(KVBlockCodec.encode(sent))) == 5
+    back = second.export_prefix(prompt)
+    assert back["chain"] == sent["chain"]
+    for name in ("k", "v_pool"):
+        assert back[name].shape == sent[name].shape
+        assert back[name].tobytes() == sent[name].tobytes()
+    for eng in engines:
+        for pool in (eng.cache.k, eng.cache.v):
+            assert pool.shape == (c.n_layers, eng.cache.allocator.num_blocks,
+                                  8, 128)
+            assert not np.asarray(pool[..., c.n_heads * c.head_dim:]).any()
 
 
 # ---------------------------------------------------------------------------

@@ -7,6 +7,10 @@ says nothing about numerics, device ownership or the process model;
 `chip_smoke.py` covers those on the chip.
 """
 
+import functools
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import optax
@@ -15,8 +19,9 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu._private.accelerators import (
     ChipAllocator, chip_env, leasable)
+from ray_tpu.inference.kv_cache import count_pool_copies
 from ray_tpu.models import gpt
-from ray_tpu.ops.attention import paged_decode_attention
+from ray_tpu.ops.attention import kv_row_width, paged_decode_attention
 from ray_tpu.parallel import MeshConfig, create_mesh
 from ray_tpu.parallel.sharding import named_sharding, tree_shardings
 
@@ -73,20 +78,32 @@ def test_train_step_compiles_under_a_v5e_mesh(v5e, as_on_chip):
     assert text.count("tpu_custom_call") >= 3
 
 
+def _arg_on(device):
+    dev = jax.sharding.SingleDeviceSharding(device)
+    return lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                     sharding=dev)
+
+
+def _pool_shape(layers, nb, bs, kh, d):
+    return (layers, nb, bs, kv_row_width(kh, d))
+
+
 def test_paged_decode_kernel_compiles_for_v5e_gqa(v5e, as_on_chip):
     lanes, h, kh, d, bs, nb, mb = 8, 8, 2, 64, 16, 64, 8
-    dev = jax.sharding.SingleDeviceSharding(v5e[0])
-
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
-
-    text = jax.jit(paged_decode_attention).lower(
-        arg((lanes, h, d), jnp.bfloat16),
-        arg((nb, bs, kh, d), jnp.bfloat16),
-        arg((nb, bs, kh, d), jnp.bfloat16),
-        arg((lanes, mb), jnp.int32),
-        arg((lanes,), jnp.int32)).compile().as_text()
+    arg = _arg_on(v5e[0])
+    pool = arg(_pool_shape(2, nb, bs, kh, d), jnp.bfloat16)
+    text = jax.jit(functools.partial(paged_decode_attention, kv_heads=kh)
+                   ).lower(
+        arg((lanes, h, d), jnp.bfloat16), pool, pool,
+        arg((lanes, mb), jnp.int32), arg((lanes,), jnp.int32),
+        arg((), jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+def _kernel_names(text):
+    return [line.split(" = ")[0].strip().lstrip("%")
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
 
 
 def test_paged_decode_kernel_keeps_its_name_inside_a_layer_scan(v5e,
@@ -97,27 +114,115 @@ def test_paged_decode_kernel_keeps_its_name_inside_a_layer_scan(v5e,
     Mosaic kernel (`benchmark/trace_reduce.py` names kernels by that)."""
     from ray_tpu.ops.attention import paged_attention
     lanes, h, d, bs, nb, mb, layers = 8, 8, 64, 16, 64, 8, 2
-    dev = jax.sharding.SingleDeviceSharding(v5e[0])
+    arg = _arg_on(v5e[0])
 
-    def arg(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=dev)
-
-    def layers_of_attention(q, k_pools, v_pools, tables, ctx_lens):
-        def layer(x, pools):
-            out = paged_attention(x[:, None], *pools, tables, ctx_lens, None)
+    def layers_of_attention(q, k_pool, v_pool, tables, ctx_lens):
+        def layer(x, i):
+            out = paged_attention(x[:, None], k_pool, v_pool, tables,
+                                  ctx_lens, None, i)
             return x + out[:, 0], None
-        return jax.lax.scan(layer, q, (k_pools, v_pools))[0]
+        return jax.lax.scan(layer, q, jnp.arange(layers))[0]
 
+    pool = arg(_pool_shape(layers, nb, bs, h, d), jnp.bfloat16)
     text = jax.jit(layers_of_attention).lower(
-        arg((lanes, h, d), jnp.bfloat16),
-        arg((layers, nb, bs, h, d), jnp.bfloat16),
-        arg((layers, nb, bs, h, d), jnp.bfloat16),
+        arg((lanes, h, d), jnp.bfloat16), pool, pool,
         arg((lanes, mb), jnp.int32),
         arg((lanes,), jnp.int32)).compile().as_text()
-    kernels = [line.split(" = ")[0].strip() for line in text.splitlines()
-               if 'custom_call_target="tpu_custom_call"' in line]
+    kernels = _kernel_names(text)
     assert kernels and all(
-        k.lstrip("%").startswith("paged_decode_attention") for k in kernels)
+        k.startswith("paged_decode_attention") for k in kernels)
+
+
+def _compile_engine_step(device, cfg, t, lanes=8, num_blocks=64,
+                         block_size=16):
+    """The engine's greedy step for `t` tokens a lane, as
+    `benchmark/tools/aot_sizes.py::serve` builds it: no engine thread, no
+    weights, the pool in the shape `PagedKVCache` stores."""
+    from ray_tpu.inference.engine import InferenceEngine
+    arg = _arg_on(device)
+    eng = object.__new__(InferenceEngine)
+    eng.model, eng.config, eng._capture_logp = gpt, cfg, False
+    eng.backend, eng._step_impls = "tpu", {}
+    params = jax.tree.map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda k: gpt.init_params(cfg, k), jax.random.key(0)))
+    pool = arg(_pool_shape(cfg.n_layers, num_blocks, block_size,
+                           cfg.n_heads, cfg.head_dim), cfg.dtype)
+    mb = cfg.max_seq_len // block_size
+    compiled = eng._make_step_fn(False).lower(
+        params, pool, pool, arg((lanes, t), jnp.int32),
+        arg((lanes, t), jnp.int32), arg((lanes, t), jnp.bool_),
+        arg((lanes, mb), jnp.int32), arg((lanes,), jnp.int32),
+        arg((lanes,), jnp.int32), arg((lanes,), jnp.float32),
+        arg((lanes,), jnp.uint32), arg((lanes,), jnp.int32)).compile()
+    return compiled, pool, params
+
+
+@pytest.mark.parametrize("t", [1, 32], ids=["t1", "t_prefill_chunk"])
+@pytest.mark.parametrize("heads", [25, 12], ids=["25x64_padded_row",
+                                                 "12x64"])
+def test_engine_step_leaves_the_kv_pool_where_it_is(v5e, as_on_chip, heads,
+                                                    t):
+    """The mechanism of PERF.md section 6, PR 24, without a chip: the
+    compiled step writes rows and reads blocks of the donated pools and
+    never copies, slices out or stacks back the pool or a layer of it."""
+    cfg = gpt.GPTConfig(vocab_size=512, n_layers=4, d_model=heads * 64,
+                        n_heads=heads, d_ff=256, max_seq_len=256,
+                        scan_unroll=2)
+    compiled, pool, params = _compile_engine_step(v5e[0], cfg, t)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+
+    # By the program's own counter, and read off the text once more: no
+    # copy, scatter or slice whose result is the pool or whole layers of it.
+    assert count_pool_copies(text, pool.shape) == 0
+    block_dims = ",".join(str(d) for d in pool.shape[1:])
+    moved = re.compile(
+        r" = \w+\[(?:\d+,)*%s\]\S* (copy|copy-start|scatter|dynamic-slice)\("
+        % block_dims)
+    assert not [line for line in text.splitlines() if moved.search(line)]
+    # From parameter to result the pool has one layout: the row-major
+    # one the kernel's DMA reads.
+    layouts = set(re.findall(
+        r"\[%d,%s\]\{([\d,]*(?::T[()\d,]*)?)" % (pool.shape[0], block_dims),
+        text))
+    # (untiled: the kernel's operand constraint, where there is a kernel)
+    assert layouts - {"3,2,1,0"} == {"3,2,1,0:T(8,128)(2,1)"}
+
+    pool_bytes = 2 * math.prod(pool.shape)                      # bf16
+    assert memory.alias_size_in_bytes == 2 * pool_bytes         # K and V
+    # Scratch: the step's bf16 copies of the fp32 weights (PERF.md
+    # section 7) and less than one pool beside them.
+    weight_copies = sum(2 * math.prod(x.shape)
+                        for x in jax.tree.leaves(params))
+    assert memory.temp_size_in_bytes - weight_copies < pool_bytes
+
+    kernels = _kernel_names(text)
+    assert all(k.startswith("paged_decode_attention") for k in kernels)
+    assert len(kernels) == (cfg.scan_unroll if t == 1 else 0)
+
+
+def test_pool_copy_counter_sees_a_pool_scanned_over_layers(v5e, as_on_chip):
+    """What every tree before PR 24 compiled: the pools as the layer
+    scan's xs and stacked outputs.  The counter must not call that 0."""
+    from ray_tpu.ops.attention import paged_attention
+    lanes, h, d, bs, nb, mb, layers = 8, 12, 64, 16, 64, 8, 4
+    arg = _arg_on(v5e[0])
+
+    def scanned(q, k_pool, v_pool, tables, ctx_lens):
+        def layer(x, pools):
+            k_l, v_l = (p[None].at[0, tables[:, 0], 0, :64].set(
+                x[:, 0].astype(p.dtype)) for p in pools)
+            out = paged_attention(x[:, None], k_l, v_l, tables, ctx_lens,
+                                  None)
+            return x + out[:, 0], (k_l[0], v_l[0])
+        return jax.lax.scan(layer, q, (k_pool, v_pool))
+
+    pool = arg(_pool_shape(layers, nb, bs, h, d), jnp.bfloat16)
+    text = jax.jit(scanned, donate_argnums=(1, 2)).lower(
+        arg((lanes, h, d), jnp.bfloat16), pool, pool,
+        arg((lanes, mb), jnp.int32),
+        arg((lanes,), jnp.int32)).compile().as_text()
+    assert count_pool_copies(text, pool.shape) > 0
 
 
 def test_chip_binding_for_tpu_workers():
