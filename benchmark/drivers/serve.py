@@ -1,18 +1,20 @@
-"""Serve cells: `serve.run(<LLM deployment>)`, then an open-loop client on
-`handle.options("generate").stream(...)`.
+"""Serve cells: `serve.run(<LLM deployment>)`, then a client on
+`handle.options("generate").stream(...)`, open or closed loop.
 
 The parent deploys one replica, which leases the chip; the router, the
 replica actor, the stream tickets and the engine are all between the client
-below and the device.  Requests are sent when the schedule says, whether or
-not earlier ones have finished, each on a thread of its own (a thread waits
-on its stream and does nothing else), and timed from the instant they were
-due.
+below and the device.  Each request runs on a thread of its own (a thread
+waits on its stream and does nothing else) and is timed from the instant it
+was due.  Open loop (no `clients` in the traffic file): a request is sent
+when the schedule says, whether or not earlier ones have finished.  Closed
+loop (`clients`): that many are in flight, the list is taken in order, and
+a request is due the instant a client's last one ended.
 
-Traffic file keys: `generator`, `rate_rps`, `requests` (the generator's
-parameters), `engine` (lanes, pool, chunk), `max_concurrent_queries`,
-`warmup` (the request that compiles the cell's step shapes), `check`,
-`max_lateness_p99_ms`, `trace` (`at_s`, `slice_s`, `trace_every`),
-`rehearsal` (overrides for a CPU rehearsal).
+Traffic file keys: `generator`, `rate_rps` or `clients`, `requests` (the
+generator's parameters), `engine` (lanes, pool, chunk),
+`max_concurrent_queries`, `warmup` (the request that compiles the cell's
+step shapes), `check`, `max_lateness_p99_ms`, `trace` (`at_s`, `slice_s`,
+`trace_every`), `rehearsal` (overrides for a CPU rehearsal).
 """
 
 from __future__ import annotations
@@ -20,6 +22,7 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import queue
 import threading
 import time
 
@@ -28,8 +31,9 @@ import numpy as np
 from benchmark import manifest, metrics, trace_reduce
 
 
-def _one(handle, req, rec, stop, traced):
-    """One request on its own thread: pull the stream, stamp each token."""
+def _one(handle, req, rec, stop, traced, slots):
+    """One request on its own thread: pull the stream, stamp each token;
+    at its end, however it ends, its client is free from that instant."""
     from ray_tpu.util import tracing
     rec["sent"] = time.time()
     gen = None
@@ -62,14 +66,27 @@ def _one(handle, req, rec, stop, traced):
             except Exception as e:
                 rec.setdefault("close_error", repr(e)[:200])
         rec["done"] = time.time()
+        slots.put(rec["done"])
 
 
-def _drive(handle, schedule, base, stop, end_at, trace_every):
-    """Send every request at base + due; returns the records.  Stops early
-    once `stop` is set; sets it itself at `end_at`."""
-    records, threads = [], []
+def _drive(handle, schedule, base, stop, end_at, trace_every, clients=0):
+    """Send every request of the schedule, in its order, when it is due;
+    returns the records and the threads.  Stops once `stop` is set or
+    `end_at` has come.  A request is due at base + its `due`; with `clients`
+    (a closed loop) no earlier than the instant one of that many clients
+    came free: `slots` is their counting semaphore, and what it counts are
+    the instants at which each ended its last request."""
+    records, threads, slots = [], [], queue.Queue()
+    for _ in range(clients):
+        slots.put(float("-inf"))       # free since before the schedule began
     for req in schedule:
         due = base + req["due"]
+        while clients and not stop.is_set() and time.time() < end_at:
+            try:
+                due = max(due, slots.get(timeout=0.05))
+                break
+            except queue.Empty:
+                pass              # every client is waiting for its reply
         while True:
             wait = due - time.time()
             if wait <= 0 or stop.is_set():
@@ -81,10 +98,10 @@ def _drive(handle, schedule, base, stop, end_at, trace_every):
                "max_new_tokens": req["max_new_tokens"], "token_times": [],
                "tokens": [], "session": req["session"], "group": req["group"],
                "prompt": req["prompt"]}
-        traced = bool(trace_every) and req["due"] >= 0 \
+        traced = bool(trace_every) and due >= base \
             and req["id"] % trace_every == 0
         th = threading.Thread(target=_one, daemon=True,
-                              args=(handle, req, rec, stop, traced))
+                              args=(handle, req, rec, stop, traced, slots))
         th.start()
         records.append(rec)
         threads.append(th)
@@ -169,7 +186,12 @@ def run(ctx: dict, say) -> dict:
         watch.start()
         records, threads = _drive(gen_handle, schedule, base, stop, end_at,
                                   traffic["trace"]["trace_every"]
-                                  if ctx["trace"] else 0)
+                                  if ctx["trace"] else 0,
+                                  int(traffic.get("clients", 0)))
+        if "clients" in traffic and len(records) == len(schedule):
+            raise SystemExit(
+                f"the closed loop's list of {len(schedule)} requests ran out "
+                f"before the window closed: the lanes were not kept full")
         # Requests due inside the window run to their end (tail_s allows
         # for it); whatever is still open at end_at is cut.
         counted = metrics.in_window(records, base, seconds)
@@ -236,17 +258,25 @@ def run(ctx: dict, say) -> dict:
         f"argmax at {argmax_pct:.1f}% of positions: "
         f"{'ok' if ok_tokens else 'FAILED'}")
     s0, s1 = marks.get("stats0", {}), marks.get("stats1", {})
+    late_counted = metrics.lateness_ms(counted)
     say(f"generator lateness p50 "
         f"{metrics.percentile(lateness, 50) if lateness else 0:.2f} ms, p99 "
-        f"{late_p99:.2f} ms (limit {traffic['max_lateness_p99_ms']}); "
+        f"{late_p99:.2f} ms (limit {traffic['max_lateness_p99_ms']}), of "
+        f"those due in the window p99 "
+        f"{metrics.percentile(late_counted, 99) if late_counted else 0:.2f} "
+        f"ms; "
         f"{len(records)} sent, {len(counted)} due in the window, "
         f"{len(judged)} taken up or turned away, {len(failed)} of them "
         f"failed, {cut} cut in mid-stream when the window closed, {hung} "
         f"thread(s) hung")
     delta = {k: s1[k] - s0[k] for k in ("prefix_hit_tokens",
                                         "prefix_miss_tokens",
-                                        "blocks_evicted")
+                                        "blocks_evicted", "admitted", "steps")
              if k in s0 and k in s1}
+    if "compile" in s0 and "compile" in s1:     # nothing compiles in a window
+        delta["programs_compiled_or_loaded"] = sum(
+            s1["compile"][k] - s0["compile"][k]
+            for k in ("compiles", "cache_hits"))
     say(f"window: {tokens} output tokens in {seconds:g} s = "
         f"{tokens / seconds:.1f} tokens/s; lanes at open/close "
         f"{s0.get('active')}/{s1.get('active')} of {engine['max_lanes']}, "

@@ -1,22 +1,35 @@
-"""Open-loop request schedules for the serve cells, from parameters alone.
+"""Request lists for the serve cells, from parameters alone: an open loop's
+schedule of arrivals, or the list a closed loop of clients works through.
 
 One generator covers the mixes the benchmark has and the ones PERF.md keeps
 for later (independent requests, sessions with shared heads and growing
-history), so that a new mix is a new data file.  Every draw comes from
-`--seed`: arrival times, lengths, which session speaks, when one restarts,
-and every token.  The program's answers never feed back into a prompt (a
-session's history holds seeded stand-ins for the earlier answers), so the
-seed alone fixes the schedule and every prompt.  A mix is made steady from
-seed to seed by its parameters (a lead-in that fills the lanes, a rate well
-to one side of the knee), never by pinning the schedule.
+history), so that a new mix is a new data file.  Which loop a file is
+follows from one key: with `clients` it is closed (the driver sends a
+client's next request when its last one has ended, so the list has no
+arrival times and is the same for any window), without it open.  Every draw
+comes from `--seed`: arrival times, lengths, which session speaks, when one
+restarts, and every token.  The program's answers never feed back into a
+prompt (a session's history holds seeded stand-ins for the earlier answers),
+so the seed alone fixes the schedule and every prompt.  A mix is made steady from
+seed to seed by its parameters (a lead-in that fills the lanes; a rate well
+to one side of the knee, or more clients than lanes), never by pinning the
+schedule.
 
 Parameters (traffic file, under `requests` unless said otherwise):
-  rate_rps (top level)   mean arrivals per second, a Poisson process
+  rate_rps (top level)   open loop: mean arrivals per second, a Poisson
+                         process
+  clients (top level)    closed loop: how many requests are in flight; every
+                         request of the list is due at -lead_in_s and the
+                         driver sends it when a client is free
+  count                  closed loop: the length of the list (it has to
+                         outlast the window at any speed of the program)
   lead_in_s, tail_s      the schedule runs from -lead_in_s to seconds +
                          tail_s; only requests due in [0, seconds) count
-  fill_requests          that many extra requests due at -lead_in_s, their
-                         outputs scaled by a uniform draw: lanes filled with
-                         requests at every stage, as a long-running server's
+  fill_requests          that many requests due at -lead_in_s (open loop:
+                         besides the arrivals; closed loop: the first of the
+                         list), their outputs scaled by a uniform draw:
+                         lanes filled with requests at every stage, as a
+                         long-running server's
   prompt_len, output_len {"dist": "uniform" | "log_uniform", "lo", "hi"};
                          prompt_len is the new turn of each request
   sessions               null, or {"count", "groups", "grouped_share",
@@ -92,8 +105,12 @@ def make(traffic: dict, seed: int, seconds: float, vocab_size: int) -> list:
     rng_arrive, rng_fill, rng_pick, rng_len, rng_free = (
         np.random.default_rng([seed, i]) for i in range(5))
     start, end = -float(p["lead_in_s"]), float(seconds) + float(p["tail_s"])
-    times = ([start] * int(p.get("fill_requests", 0))
-             + _arrivals(rng_arrive, traffic["rate_rps"], start, end))
+    fill = int(p.get("fill_requests", 0))
+    if "clients" in traffic:
+        times = [start] * int(p["count"])
+    else:
+        times = [start] * fill + _arrivals(rng_arrive, traffic["rate_rps"],
+                                           start, end)
     spec = p.get("sessions")
     sessions = []
     if spec:
@@ -118,7 +135,7 @@ def make(traffic: dict, seed: int, seconds: float, vocab_size: int) -> list:
             prompt = rng_free.integers(
                 0, vocab_size, _length(rng_len, p["prompt_len"])).tolist()
             out_len = _length(rng_len, p["output_len"])
-        if i < int(p.get("fill_requests", 0)):
+        if i < fill:
             out_len = max(2, int(math.ceil(out_len * rng_fill.uniform())))
         requests.append({"id": i, "due": float(due), "prompt": prompt,
                          "max_new_tokens": out_len, "session": s,

@@ -1,5 +1,5 @@
-"""Metric arithmetic on what a run recorded: percentiles, open-loop request
-timings, tokens inside a window.  Plain Python, so the tests can check it on
+"""Metric arithmetic on what a run recorded: percentiles, request timings
+from the due instant, tokens inside a window.  Plain Python, so the tests can check it on
 hand-made records."""
 
 from __future__ import annotations
@@ -27,9 +27,10 @@ def in_window(records, t0: float, seconds: float) -> list:
 
 
 def ttft_ms(record) -> float:
-    """Due time (open loop: when the schedule said, not when the generator
-    got round to it) to the first token at the client.  A request that
-    failed, was refused or produced nothing counts as infinite."""
+    """Due time (open loop: when the schedule said; closed loop: the instant
+    its client came free; never when the generator got round to it) to the
+    first token at the client.  A request that failed, was refused or
+    produced nothing counts as infinite."""
     if record.get("error") or not record["token_times"]:
         return math.inf
     return (record["token_times"][0] - record["due"]) * 1e3

@@ -88,7 +88,7 @@ def test_a_fifth_cell_needs_only_new_files_and_entries(tmp_path):
         json.dumps(config))
     traffic = json.loads((root / "benchmark/traffic/decode_saturated.json")
                          .read_text())
-    traffic["rate_rps"] = 2.0
+    traffic["clients"] = 24
     traffic["requests"]["output_len"] = {"dist": "log_uniform", "lo": 8,
                                          "hi": 512}
     (root / "benchmark/traffic/decode_mixed.json").write_text(

@@ -43,6 +43,7 @@ def report(name, compiled, t0):
 def serve(config_name, lanes, num_blocks, block_size=16, chunk=32):
     from ray_tpu.inference.engine import InferenceEngine
     from ray_tpu.models import gpt
+    from ray_tpu.ops.attention import kv_row_width
     cfg = manifest.model_config(manifest.load().load_config(config_name))
     dev = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
@@ -57,8 +58,10 @@ def serve(config_name, lanes, num_blocks, block_size=16, chunk=32):
     params = jax.tree.map(
         lambda x: arg(x.shape, x.dtype),
         jax.eval_shape(lambda k: gpt.init_params(cfg, k), jax.random.key(0)))
-    pool = arg((cfg.n_layers, num_blocks, block_size, cfg.n_heads,
-                cfg.head_dim), cfg.dtype)
+    # as `PagedKVCache` stores it: rows of W columns, one layout for the
+    # write and the kernel's read
+    pool = arg((cfg.n_layers, num_blocks, block_size,
+                kv_row_width(cfg.n_heads, cfg.head_dim)), cfg.dtype)
     mb = cfg.max_seq_len // block_size
     for t in (1, chunk):
         t0 = time.perf_counter()

@@ -3,11 +3,11 @@
 knee or a size once, on the chip.  Never the measured command: `run.py` has
 no option for this.  Each point runs `run.py` in a process of its own with
 the cell's traffic file as it is on disk plus that point's overrides (one
-level deep: `{"rate_rps": 0.2, "requests": {"fill_requests": 8}}`), and its
+level deep: `{"clients": 24, "requests": {"fill_requests": 24}}`), and its
 `[bench]` lines are printed under the point.
 
   python3 benchmark/tools/sweep.py --workload serve_gpt2xl_decode --seed 3 \
-      --points '[{"rate_rps": 0.15}, {"rate_rps": 0.3}]' [--seconds 51]
+      --points '[{"clients": 16}, {"clients": 32}]' [--seconds 51]
 
 Arguments it does not know (`--seconds`, `--trace`, `--rehearse`) go on to
 `run.py`.
