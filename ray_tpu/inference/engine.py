@@ -327,6 +327,11 @@ class InferenceEngine:
             params = jax.jit(self.model.init_params, static_argnums=0)(
                 self.config, jax.random.key(seed))
         self.params = params
+        # An expert configuration's load counters live on the device and
+        # ride the step (forward_cached's `moe_load`); stats() fetches.
+        n_experts = getattr(self.config, "n_experts", 0)
+        self._moe_load = (jnp.zeros((n_experts + 2,), jnp.int32)
+                          if n_experts else None)
         self.max_lanes = max_lanes
         self.prefill_chunk = prefill_chunk
         self.seed = seed
@@ -631,7 +636,20 @@ class InferenceEngine:
             "queue_wait_s": self._queue_wait_s,
             # This process's XLA compiles and persistent-cache loads.
             "compile": compile_cache.counters(),
+            **self._moe_stats(),
         }
+
+    def _moe_stats(self) -> dict:
+        """An expert configuration's cumulative load, fetched from the
+        device here and nowhere else: assignments (token, expert) in all
+        and per expert, summed over layers; `experts_hit` summed over the
+        `layer_steps` (layer, step) pairs run so far."""
+        if self._moe_load is None:
+            return {}
+        load = np.asarray(self._moe_load).tolist()
+        return {"moe": {"assignments": sum(load[:-2]),
+                        "expert_load": load[:-2],
+                        "experts_hit": load[-2], "layer_steps": load[-1]}}
 
     def compiled_steps(self) -> dict:
         """What XLA built for each step shape dispatched so far: seconds
@@ -914,19 +932,20 @@ class InferenceEngine:
         key = (t, sample, spec)
         fn = self._step_fns.get(key)
         first = fn is None
+        # An expert configuration's step takes its load counters last and
+        # hands them back last (not donated: stats() may be reading them).
+        moe = () if self._moe_load is None else (self._moe_load,)
         if first:
             t0 = time.perf_counter()
             fn = self._step_fns[key] = self._make_step_fn(sample, spec)
             self._step_avals[key] = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                (self.params, self.cache.k, self.cache.v, *args))
-        if self._capture_logp:
-            next_tok, logp, k, v = fn(self.params, self.cache.k,
-                                      self.cache.v, *args)
-        else:
-            next_tok, k, v = fn(self.params, self.cache.k, self.cache.v,
-                                *args)
-            logp = None
+                (self.params, self.cache.k, self.cache.v, *args, *moe))
+        out = list(fn(self.params, self.cache.k, self.cache.v, *args, *moe))
+        if moe:
+            self._moe_load = out.pop()
+        next_tok, *logp, k, v = out
+        logp = logp[0] if logp else None
         if first:
             # The first call of a shape returns once it has compiled (the
             # dispatch itself is asynchronous): its wall is compile time.
@@ -950,10 +969,17 @@ class InferenceEngine:
             return jnp.take_along_axis(lp, out[..., None], axis=-1)[..., 0]
 
         def step(params, k, v, tokens, positions, valid, tables, ctx_lens,
-                 gather, temps, seeds, counters):
-            x, k, v = model.forward_cached(
+                 gather, temps, seeds, counters, *moe_load):
+            # An expert configuration's step takes its load counters last
+            # and returns them last, summed up on the device.
+            x, k, v, *moe_load = model.forward_cached(
                 params, tokens, positions, valid, k, v, tables, ctx_lens,
-                config)
+                config, *moe_load)
+            return (*sample_tokens(params, x, gather, temps, seeds,
+                                   counters), k, v, *moe_load)
+
+        def sample_tokens(params, x, gather, temps, seeds, counters):
+            """(next tokens,) or, capturing, (next tokens, their logps)."""
             if spec:
                 # Verify shape: EVERY position's next token is sampled
                 # in-graph — position j draws with the key the plain
@@ -984,9 +1010,8 @@ class InferenceEngine:
                                                   counters)
                     out = jnp.where(temps[:, None] > 0, sampled, greedy)
                 if capture:
-                    return out, _logp_at(logits, out,
-                                         temps[:, None, None]), k, v
-                return out, k, v
+                    return out, _logp_at(logits, out, temps[:, None, None])
+                return (out,)
             # Only each lane's last valid position reaches the lm head —
             # a prefill chunk never materializes [B, T, V], and the
             # logits never leave the device: sampling happens HERE and
@@ -997,9 +1022,8 @@ class InferenceEngine:
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             if not sample:
                 if capture:
-                    return greedy, _logp_at(logits, greedy,
-                                            temps[:, None]), k, v
-                return greedy, k, v
+                    return greedy, _logp_at(logits, greedy, temps[:, None])
+                return (greedy,)
 
             def draw(row, temp, seed, counter):
                 # Key = f(request seed, tokens produced): reproducible
@@ -1012,9 +1036,8 @@ class InferenceEngine:
             sampled = jax.vmap(draw)(logits, temps, seeds, counters)
             next_tok = jnp.where(temps > 0, sampled, greedy)
             if capture:
-                return next_tok, _logp_at(logits, next_tok,
-                                          temps[:, None]), k, v
-            return next_tok, k, v
+                return next_tok, _logp_at(logits, next_tok, temps[:, None])
+            return (next_tok,)
 
         self._step_impls[(sample, "spec") if spec else sample] = step
         # Donated, the pools come back as the buffers they went in as

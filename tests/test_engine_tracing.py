@@ -74,6 +74,36 @@ def test_step_records_hold_the_phases_and_stats_sum_them():
         _last_seq())
 
 
+def test_an_expert_engine_adds_counters_to_stats_and_nothing_to_a_step():
+    """OLMoE's block at nano size: the same one `engine/step` record a
+    step with the same fields, no other ring event per step or token, and
+    `stats()["moe"]` growing by tokens x top-k x layers."""
+    from ray_tpu.models import llama
+    cfg = llama.CONFIGS["olmoe-nano"]
+    engine = InferenceEngine("llama", cfg, auto_start=False, max_lanes=2,
+                             prefill_chunk=8)
+    engine.generate(list(range(1, 6)), 2)          # compile both shapes
+    s0, seq = engine.stats(), _last_seq()
+    out = engine.generate(list(range(1, 20)), 6)
+    s1 = engine.stats()
+    since = [e for e in events.snapshot(plane="engine") if e["seq"] > seq]
+    steps = [e for e in since if e["kind"] == "step"]
+    assert len(steps) == s1["steps"] - s0["steps"] > 0
+    assert {e["kind"] for e in since} <= {"step", "submit", "admit",
+                                          "finish", "prefix_miss"}
+    for e in steps:
+        assert set(e["payload"]) == {"decode", "prefill", "waiting",
+                                     "wall_ms", *PHASE_FIELDS}
+    moe0, moe1 = s0["moe"], s1["moe"]
+    assert moe1["assignments"] - moe0["assignments"] == (
+        (19 + len(out) - 1) * cfg.n_experts_per_tok * cfg.n_layers)
+    assert len(moe1["expert_load"]) == cfg.n_experts
+    assert moe1["layer_steps"] - moe0["layer_steps"] >= len(steps) * \
+        cfg.n_layers
+    assert 0 < moe1["experts_hit"] - moe0["experts_hit"] <= (
+        moe1["layer_steps"] - moe0["layer_steps"]) * cfg.n_experts
+
+
 def test_phases_are_flat_siblings_in_the_profilers_trace(tmp_path):
     from jax.profiler import ProfileData
     engine = InferenceEngine("gpt", "nano", max_lanes=2, prefill_chunk=8)

@@ -1,0 +1,244 @@
+"""The sparse-expert path (OLMoE's block in `models/llama.py`, `ops/moe.py`,
+the engine's load counters) against the plain float32 reference
+(`benchmark/reference/olmoe.py`), at nano size on the CPU with seeded
+weights.
+
+Tolerance: both sides compute in float32 from the same float32 weights, so
+they differ only by the order of additions (the reference sums every expert
+for every token, the program only the chosen ones; the kernel accumulates a
+tile at a time): logits of magnitude 5 agree to 1e-4 with a margin of thirty
+or more (2e-6 to 3e-6 observed).  Computing the router, an expert product or
+the weights in bf16 moves logits by 1e-2 and more and fails every case here.
+"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import olmoe as ref
+from ray_tpu.inference import InferenceEngine
+from ray_tpu.inference.kv_cache import PagedKVCache
+from ray_tpu.models import llama
+from ray_tpu.ops import moe
+
+CFG = llama.CONFIGS["olmoe-nano"]     # 2 layers, 64 wide, 16 experts, top-2
+TOL = 1e-4
+
+
+def _tokens(shape, seed=1):
+    return jax.random.randint(jax.random.key(seed), shape, 0, CFG.vocab_size)
+
+
+@pytest.mark.parametrize("qk_norm", [True, False],
+                         ids=["qk_norm", "no_qk_norm"])
+@pytest.mark.parametrize("norm_topk_prob", [False, True],
+                         ids=["probs_as_they_are", "norm_topk_prob"])
+def test_forward_matches_the_reference(qk_norm, norm_topk_prob):
+    cfg = dataclasses.replace(CFG, qk_norm=qk_norm,
+                              norm_topk_prob=norm_topk_prob)
+    params = llama.init_params(cfg, jax.random.key(0))
+    assert ("q_norm" in params["blocks"]) == qk_norm
+    # the norms' scales are ones at init: make them count
+    params["blocks"] = {
+        k: v * (1.0 + 0.1 * jax.random.normal(jax.random.key(7), v.shape))
+        if k.endswith("_norm") else v for k, v in params["blocks"].items()}
+    tokens = _tokens((3, 40))
+    got = llama.forward(params, tokens, cfg)
+    want = ref.logits(params, tokens, top_k=cfg.n_experts_per_tok,
+                      norm_topk_prob=norm_topk_prob)
+    assert float(jnp.max(jnp.abs(want))) > 1.0
+    np.testing.assert_allclose(got, want, atol=TOL)
+    if norm_topk_prob:      # and the option is not a no-op
+        other = ref.logits(params, tokens, top_k=cfg.n_experts_per_tok)
+        assert float(jnp.max(jnp.abs(other - want))) > 100 * TOL
+
+
+def test_chunked_prefill_then_decode_through_the_paged_cache_matches():
+    """Three lanes at different depths in one batch: a prefill chunk per
+    lane, then decode steps, against the reference's full forward."""
+    params = llama.init_params(CFG, jax.random.key(1))
+    rows = np.asarray(_tokens((3, 30), seed=2))
+    prefill = [5, 8, 3]                   # each lane's first chunk
+    want = np.asarray(ref.logits(params, rows))
+    bs, lanes = 8, 3
+    cache = PagedKVCache.for_model(llama, CFG, num_blocks=lanes * 4 + 1,
+                                   block_size=bs, max_lanes=lanes,
+                                   max_seq_len=32)
+    for lane in range(lanes):
+        cache.alloc_lane(lane, 30)
+    load = jnp.zeros((CFG.n_experts + 2,), jnp.int32)
+    depth = [0, 0, 0]
+
+    def run(chunks):
+        nonlocal load
+        t = max(len(c) for c in chunks)
+        tokens = np.zeros((lanes, t), np.int32)
+        valid = np.zeros((lanes, t), bool)
+        for lane, c in enumerate(chunks):
+            tokens[lane, :len(c)] = c
+            valid[lane, :len(c)] = True
+        positions = np.asarray(depth)[:, None] + np.arange(t)[None]
+        ctx = np.asarray([d + len(c) for d, c in zip(depth, chunks)])
+        x, k, v, load = llama.forward_cached(
+            params, jnp.asarray(tokens), jnp.asarray(positions, jnp.int32),
+            jnp.asarray(valid), cache.k, cache.v, cache.device_tables(),
+            jnp.asarray(ctx, jnp.int32), CFG, load)
+        cache.update_pools(k, v)
+        for lane, c in enumerate(chunks):
+            depth[lane] += len(c)
+            got = llama.lm_head(params, x[lane, len(c) - 1], CFG)
+            np.testing.assert_allclose(
+                got, want[lane, depth[lane] - 1], atol=TOL,
+                err_msg=f"lane {lane} position {depth[lane] - 1}")
+
+    run([rows[lane, :n].tolist() for lane, n in enumerate(prefill)])
+    for _ in range(6):                    # T=1, every lane at its own depth
+        run([[int(rows[lane, depth[lane]])] for lane in range(lanes)])
+    tokens_run = sum(depth)
+    load = np.asarray(load)
+    assert load[:-2].sum() == tokens_run * CFG.n_experts_per_tok \
+        * CFG.n_layers                    # padding positions reach no expert
+    assert load[-1] == 7 * CFG.n_layers   # (layer, step) pairs
+
+
+@pytest.mark.parametrize("t, k, e, d, f, block_m", [
+    (16, 2, 8, 64, 128, 8), (37, 2, 8, 64, 128, 8), (5, 3, 8, 64, 128, 16),
+    (64, 8, 16, 128, 256, 16)])
+@pytest.mark.parametrize("masked", [False, True], ids=["all_valid", "masked"])
+def test_grouped_path_matches_the_dense_per_expert_loop(t, k, e, d, f,
+                                                        block_m, masked):
+    rng = np.random.default_rng(t)
+    x = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    w_gate, w_up = (jnp.asarray(rng.normal(size=(2, e, d, f)), jnp.float32)
+                    / 8 for _ in range(2))
+    w_down = jnp.asarray(rng.normal(size=(2, e, f, d)), jnp.float32) / 8
+    ids = np.stack([rng.permutation(e)[:k] for _ in range(t)])
+    ids[:, 0] = 3                         # a skewed router: 3 takes everyone
+    ids[:, 1:][ids[:, 1:] == 3] = 4
+    ids = jnp.asarray(ids, jnp.int32)
+    weights = jnp.asarray(rng.random(size=(t, k)), jnp.float32)
+    valid = jnp.asarray(rng.random(t) < 0.7) if masked else None
+    got, load = moe.expert_ffn(x, ids, weights, w_gate, w_up, w_down, 1,
+                               valid)
+    want = jnp.zeros((t, d))
+    for ex in range(e):
+        hidden = jax.nn.silu(x @ w_gate[1, ex]) * (x @ w_up[1, ex])
+        share = jnp.sum(jnp.where(ids == ex, weights, 0.0), 1)
+        want += share[:, None] * (hidden @ w_down[1, ex])
+    keep = np.ones(t, bool) if valid is None else np.asarray(valid)
+    np.testing.assert_allclose(got, jnp.where(keep[:, None], want, 0.0),
+                               atol=1e-4)
+    assert np.asarray(load).tolist() == [
+        int(((np.asarray(ids) == ex) & keep[:, None]).sum())
+        for ex in range(e)]
+    if block_m:                # the same rows through one explicit tiling
+        order = jnp.argsort(ids.reshape(-1), stable=True)
+        rows = x[order // k]
+        counts = jnp.bincount(ids.reshape(-1), length=e)
+        tiled = moe.grouped_matmul(rows, w_gate, counts, 1, block_m=block_m,
+                                   block_n=f // 2)
+        np.testing.assert_allclose(
+            tiled, moe.grouped_matmul(rows, w_gate, counts, 1), atol=1e-5)
+
+
+def test_no_token_is_dropped_when_one_expert_takes_every_token():
+    """A router biased so that expert 3 is every token's first choice and
+    expert 5 nobody's (a feature every token's embedding shares, and two
+    router columns that read it): a capacity-bound dispatch would drop most
+    of 3's tokens; the dropless one agrees with the reference on all."""
+    params = llama.init_params(CFG, jax.random.key(3))
+    params["tok_embed"] = params["tok_embed"].at[:, 0].set(4.0)
+    params["blocks"]["router"] = params["blocks"]["router"].at[
+        :, 0, 3].set(8.0).at[:, 0, 5].set(-8.0)
+    tokens = _tokens((2, 24), seed=4)
+    np.testing.assert_allclose(
+        llama.forward(params, tokens, CFG),
+        ref.logits(params, tokens, top_k=CFG.n_experts_per_tok), atol=TOL)
+    eng = InferenceEngine("llama", CFG, params=params, max_lanes=2,
+                          block_size=8, prefill_chunk=8, auto_start=False)
+    prompt = np.asarray(tokens[0]).tolist()
+    out = eng.generate(prompt, 8)
+    load = eng.stats()["moe"]["expert_load"]
+    routed = (len(prompt) + len(out) - 1) * CFG.n_layers
+    assert load[3] == routed and load[5] == 0
+    assert sum(load) == routed * CFG.n_experts_per_tok
+    gaps, _ = ref.served_token_gaps(params, prompt, out)
+    assert max(gaps) < TOL
+    eng.shutdown()
+
+
+def test_engine_serves_the_configuration_and_counts_its_expert_load():
+    eng = InferenceEngine("llama", CFG, max_lanes=4, block_size=8,
+                          prefill_chunk=8, auto_start=False, seed=3)
+    assert eng.stats()["moe"] == {
+        "assignments": 0, "expert_load": [0] * CFG.n_experts,
+        "experts_hit": 0, "layer_steps": 0}
+    prompts = [list(range(5, 25)), list(range(40, 47)), list(range(90, 123))]
+    handles = [eng.submit(p, n) for p, n in zip(prompts, (12, 5, 9))]
+    while eng.step():
+        pass
+    outs = [h.tokens() for h in handles]
+    for prompt, out in zip(prompts, outs):
+        gaps, ranks = ref.served_token_gaps(eng.params, prompt, out)
+        assert len(gaps) == len(out) and max(gaps) < TOL and set(ranks) == {0}
+    stats = eng.stats()
+    # every prompt token and every generated token but a request's last
+    # went through every layer's router once, and chose top-k experts
+    tokens = sum(len(p) + len(o) - 1 for p, o in zip(prompts, outs))
+    moe_stats = stats["moe"]
+    assert moe_stats["assignments"] == sum(moe_stats["expert_load"]) \
+        == tokens * CFG.n_experts_per_tok * CFG.n_layers
+    assert moe_stats["layer_steps"] >= stats["steps"] * CFG.n_layers
+    assert 0 < moe_stats["experts_hit"] \
+        <= moe_stats["layer_steps"] * CFG.n_experts
+    # a dense configuration's stats carry no such key
+    dense = InferenceEngine("llama", "llama-tiny", max_lanes=2,
+                            auto_start=False)
+    assert "moe" not in dense.stats()
+    eng.shutdown(), dense.shutdown()
+
+
+def test_a_bf16_param_dtype_engine_holds_no_float32_leaf():
+    cfg = dataclasses.replace(CFG, dtype=jnp.bfloat16,
+                              param_dtype="bfloat16")
+    eng = InferenceEngine("llama", cfg, max_lanes=2, block_size=8,
+                          prefill_chunk=8, auto_start=False, seed=0)
+    dtypes = {x.dtype for x in jax.tree.leaves(eng.params)}
+    assert dtypes == {jnp.dtype(jnp.bfloat16)}
+    assert eng.cache.k.dtype == jnp.bfloat16
+    out = eng.generate(list(range(3, 20)), 6)
+    assert len(out) == 6
+    # bf16 weights against their own float32 upcast: activations round,
+    # and a near-tie of the router may flip; the served token stays within
+    # rounding of the reference's best
+    gaps, _ = ref.served_token_gaps(eng.params, list(range(3, 20)), out)
+    assert max(gaps) < 0.25
+    eng.shutdown()
+    # the presets and every configuration without the field stay float32
+    assert {x.dtype for x in jax.tree.leaves(jax.eval_shape(
+        lambda k: llama.init_params(llama.CONFIGS["llama-tiny"], k),
+        jax.random.key(0)))} == {jnp.dtype(jnp.float32)}
+
+
+def test_olmoe_sizes_specs_and_the_train_path():
+    cfg = llama.LlamaConfig(
+        vocab_size=50304, n_layers=16, d_model=2048, n_heads=16,
+        n_kv_heads=16, d_ff=1024, max_seq_len=4096, n_experts=64,
+        n_experts_per_tok=8, qk_norm=True, param_dtype="bfloat16")
+    shapes = jax.eval_shape(lambda k: llama.init_params(cfg, k),
+                            jax.random.key(0))
+    assert shapes["blocks"]["w_gate"].shape == (16, 64, 2048, 1024)
+    assert shapes["blocks"]["w_down"].shape == (16, 64, 1024, 2048)
+    assert shapes["blocks"]["router"].shape == (16, 2048, 64)
+    assert llama.num_params(cfg) == 6_919_161_856       # 13.84 GB in bf16
+    specs = llama.param_specs(cfg)
+    assert specs["blocks"]["w_up"] == ("layers", "experts", "embed",
+                                       "expert_mlp")
+    assert jax.tree.structure(
+        specs, is_leaf=lambda x: isinstance(x, tuple)) == jax.tree.structure(
+        shapes)
+    with pytest.raises(NotImplementedError, match="expert configuration"):
+        llama.loss_fn({}, {"tokens": jnp.zeros((1, 8), jnp.int32)}, CFG)

@@ -249,3 +249,27 @@ def test_engine_step_phases_present_and_only_in_the_profilers_trace():
                 r'(?:spans\.begin|spans\.span|events\.record)\(\s*\n?\s*'
                 r'"engine",\s*\n?\s*"(\w+)"', path.read_text()):
             assert m.group(1) not in phases, (path.name, m.group(1))
+
+
+def test_expert_load_is_fetched_by_stats_alone_and_kernels_keep_their_names():
+    """`stats()["moe"]` reads counters the step sums up on the device
+    (forward_cached's `moe_load`); the one host fetch of them sits in
+    `_moe_stats`, so an expert configuration adds no transfer and no ring
+    event to a step.  The benchmark's readers find the two kernels of its
+    step by the names their `pallas_call`s give."""
+    src = (PKG / "inference" / "engine.py").read_text()
+    fetches = re.findall(r"np\.asarray\(self\._moe_load\)", src)
+    assert len(fetches) == 1
+    body = src[src.index("def _moe_stats"):src.index("def compiled_steps")]
+    assert fetches[0] in body
+    # the step loop touches the buffer only to hand it on
+    step_body = src[src.index("    def step(self)"):
+                    src.index("    def _build_batch")]
+    assert "_moe_load" not in step_body and "moe" not in step_body
+    names = {
+        "moe.py": re.findall(r'name="(\w+)"',
+                             (PKG / "ops" / "moe.py").read_text()),
+        "attention.py": re.findall(
+            r'name="(\w+)"', (PKG / "ops" / "attention.py").read_text())}
+    assert names["moe.py"] == ["moe_grouped_matmul"]
+    assert "paged_decode_attention" in names["attention.py"]

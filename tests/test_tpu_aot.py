@@ -134,27 +134,33 @@ def test_paged_decode_kernel_keeps_its_name_inside_a_layer_scan(v5e,
 
 
 def _compile_engine_step(device, cfg, t, lanes=8, num_blocks=64,
-                         block_size=16):
+                         block_size=16, model=gpt, max_seq_len=None):
     """The engine's greedy step for `t` tokens a lane, as
     `benchmark/tools/aot_sizes.py::serve` builds it: no engine thread, no
-    weights, the pool in the shape `PagedKVCache` stores."""
+    weights, the pool in the shape `PagedKVCache` stores.  An expert
+    configuration's step takes its load counters last."""
     from ray_tpu.inference.engine import InferenceEngine
     arg = _arg_on(device)
     eng = object.__new__(InferenceEngine)
-    eng.model, eng.config, eng._capture_logp = gpt, cfg, False
+    eng.model, eng.config, eng._capture_logp = model, cfg, False
     eng.backend, eng._step_impls = "tpu", {}
     params = jax.tree.map(
         lambda x: arg(x.shape, x.dtype),
-        jax.eval_shape(lambda k: gpt.init_params(cfg, k), jax.random.key(0)))
+        jax.eval_shape(lambda k: model.init_params(cfg, k),
+                       jax.random.key(0)))
     pool = arg(_pool_shape(cfg.n_layers, num_blocks, block_size,
-                           cfg.n_heads, cfg.head_dim), cfg.dtype)
-    mb = cfg.max_seq_len // block_size
+                           getattr(cfg, "n_kv_heads", cfg.n_heads),
+                           cfg.head_dim), cfg.dtype)
+    mb = (max_seq_len or cfg.max_seq_len) // block_size
+    experts = getattr(cfg, "n_experts", 0)
+    moe_load = (arg((experts + 2,), jnp.int32),) if experts else ()
     compiled = eng._make_step_fn(False).lower(
         params, pool, pool, arg((lanes, t), jnp.int32),
         arg((lanes, t), jnp.int32), arg((lanes, t), jnp.bool_),
         arg((lanes, mb), jnp.int32), arg((lanes,), jnp.int32),
         arg((lanes,), jnp.int32), arg((lanes,), jnp.float32),
-        arg((lanes,), jnp.uint32), arg((lanes,), jnp.int32)).compile()
+        arg((lanes,), jnp.uint32), arg((lanes,), jnp.int32),
+        *moe_load).compile()
     return compiled, pool, params
 
 
@@ -199,6 +205,50 @@ def test_engine_step_leaves_the_kv_pool_where_it_is(v5e, as_on_chip, heads,
     kernels = _kernel_names(text)
     assert all(k.startswith("paged_decode_attention") for k in kernels)
     assert len(kernels) == (cfg.scan_unroll if t == 1 else 0)
+
+
+@pytest.mark.parametrize("t", [1, 32], ids=["t1", "t_prefill_chunk"])
+def test_olmoe_step_reads_its_experts_where_they_are(v5e, as_on_chip, t):
+    """OLMoE's widths (two of its sixteen layers; 16 lanes, 512 blocks,
+    requests of 1024 at most, as `serve_olmoe_decode` runs it): the compiled
+    step hands the stacked bf16 expert arrays to the grouped-matmul kernel
+    as they are.  A per-layer slice, a bf16 copy of a float32 leaf or a
+    transposed `w_down` would each be 0.27 GB moved per layer and step."""
+    from ray_tpu.models import llama
+    cfg = llama.LlamaConfig(
+        vocab_size=50304, n_layers=2, d_model=2048, n_heads=16,
+        n_kv_heads=16, d_ff=1024, max_seq_len=4096, n_experts=64,
+        n_experts_per_tok=8, qk_norm=True, param_dtype="bfloat16")
+    compiled, pool, params = _compile_engine_step(
+        v5e[0], cfg, t, lanes=16, num_blocks=512, model=llama,
+        max_seq_len=1024)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert {x.dtype for x in jax.tree.leaves(params)} == {
+        jnp.dtype(jnp.bfloat16)}
+
+    # Nothing but the parameters themselves (and the loop state that
+    # carries them) has the shape of an expert array, of a layer's experts
+    # or of one expert: no convert, copy, slice or transpose of them.
+    made = re.compile(
+        r" = \w+\[(?:\d+,)*(?:2048,1024|1024,2048)\]\S* ([\w\-]+)\(")
+    moved = [m.group(1) for line in text.splitlines()
+             if (m := made.search(line))]
+    assert set(moved) <= {"parameter", "get-tuple-element"}, set(moved)
+    assert count_pool_copies(text, pool.shape) == 0
+
+    kernels = _kernel_names(text)
+    grouped = [k for k in kernels if k.startswith("moe_grouped_matmul")]
+    paged = [k for k in kernels if k.startswith("paged_decode_attention")]
+    assert len(grouped) == 3                      # gate, up, down in the scan
+    assert len(paged) == (1 if t == 1 else 0)
+    assert len(kernels) == len(grouped) + len(paged)
+    # Scratch: a T=1 step needs next to none (2 MB at 16 layers); the T=32
+    # step gathers every lane's context of 1024 for the masked-dense
+    # attention (69 MB at 16 layers).  The parameters are the program's
+    # arguments: 2 layers are 1.89 GB of the model's 13.84.
+    assert memory.temp_size_in_bytes < (16 if t == 1 else 128) * 2 ** 20
+    assert memory.argument_size_in_bytes > sum(
+        2 * math.prod(x.shape) for x in jax.tree.leaves(params))
 
 
 def test_pool_copy_counter_sees_a_pool_scanned_over_layers(v5e, as_on_chip):
