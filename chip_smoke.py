@@ -383,9 +383,16 @@ def serve_leg(sz: Sizes, n: int) -> dict:
                 f"KV pools not donated: {decode} < {pool_bytes}"))
             require(decode["pool_copies"] == 0, (
                 f"T=1 decode step moves the KV pool: {decode}"))
+            # ... and multiply the weights the engine prepared as they
+            # are held: no cast, turn-round or re-made copy of a matrix.
+            copied = decode["weight_bytes_copied"]
+            require(not set(copied) & {"convert", "copy", "transpose",
+                                       "remat"}, (
+                f"T=1 decode step re-makes its weights: {copied}"))
             say(f"serve: T=1 step on chips {st['chips']}: temp_bytes "
                 f"{decode['temp_bytes']}, donated_bytes "
-                f"{decode['donated_bytes']}, pool_copies 0")
+                f"{decode['donated_bytes']}, pool_copies 0, "
+                f"weight_bytes_copied {copied}")
     chips = [tuple(st["chips"]) for st in stats]
     if sz.platform == "tpu":
         require(len(set(chips)) == n, f"replicas share chips: {chips}")

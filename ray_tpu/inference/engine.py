@@ -42,6 +42,14 @@ ride the step's layer loop whole: a step writes the blocks its new
 tokens fall in and reads the blocks it attends over in the engine's one
 buffer
 (`compiled_steps()` reports `pool_copies`, which must be 0).
+
+The weights the step multiplies are prepared once, not in every step:
+`model.serving_params` (one compiled program at construction and at
+every `update_params`) holds each leaf the cached forward would cast at
+its use in the activation dtype, in the form its matmul reads in place;
+`engine.params` stays what the caller gave.  A tree already held so is
+served as it is (`compiled_steps()` reports `weight_bytes_copied`: the
+bytes of weight-shaped converts, copies and slices a step still makes).
 """
 
 from __future__ import annotations
@@ -62,7 +70,8 @@ import numpy as np
 
 from ray_tpu._private import compile_cache
 from ray_tpu._private.accelerators import leased_chips, require_chip_lease
-from ray_tpu.inference.kv_cache import PagedKVCache, count_pool_copies
+from ray_tpu.inference.kv_cache import (PagedKVCache, count_pool_copies,
+                                        count_weight_bytes_copied)
 from ray_tpu.util import events, spans
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
 
@@ -279,7 +288,8 @@ def _resolve_model(model):
         else:
             raise ValueError(f"unknown model family {model!r}")
         return mod
-    return model  # a module implementing forward_cached/lm_head/CONFIGS
+    # a module implementing forward_cached/lm_head/serving_params/CONFIGS
+    return model
 
 
 class InferenceEngine:
@@ -326,7 +336,13 @@ class InferenceEngine:
             # gpt2-small engine op by op took 55 s on a v5e chip.
             params = jax.jit(self.model.init_params, static_argnums=0)(
                 self.config, jax.random.key(seed))
+        self._lock = threading.Lock()
+        self._work = threading.Condition(self._lock)
+        # What the caller gave, and what the step takes (_prepare).
         self.params = params
+        self._weights = {"prepared": 0, "prepare_s": 0.0,
+                         "served_bytes": 0, "given_bytes": 0}
+        self._served = self._prepare(params)
         # An expert configuration's load counters live on the device and
         # ride the step (forward_cached's `moe_load`); stats() fetches.
         n_experts = getattr(self.config, "n_experts", 0)
@@ -380,8 +396,6 @@ class InferenceEngine:
         self._phase_s = dict.fromkeys(_PHASES, 0.0)
         self._admitted = 0
         self._queue_wait_s = 0.0
-        self._lock = threading.Lock()
-        self._work = threading.Condition(self._lock)
         self._thread: Optional[threading.Thread] = None
         self._stopped = False
         self._auto = auto_start
@@ -453,18 +467,43 @@ class InferenceEngine:
                 pass
         return h.tokens()
 
+    def _prepare(self, params):
+        """The tree the step takes for `params`: `model.serving_params`,
+        one compiled program over the leaves that need re-making and none
+        where the caller already holds them so (then the tree itself).
+        Counted in `stats()["weights"]` and one ring event."""
+        t0 = time.perf_counter()
+        served = self.model.serving_params(params, self.config)
+        if served is not params:        # a program ran: its seconds
+            jax.block_until_ready(served)
+        took = time.perf_counter() - t0
+        given_bytes, served_bytes = (
+            sum(x.nbytes for x in jax.tree.leaves(tree))
+            for tree in (params, served))
+        with self._lock:
+            w = self._weights
+            w["prepared"] += 1
+            w["prepare_s"] += took
+            w["served_bytes"], w["given_bytes"] = served_bytes, given_bytes
+        events.record("engine", "weights_prepare", prepare_ms=took * 1e3,
+                      served_bytes=served_bytes, given_bytes=given_bytes)
+        return served
+
     def update_params(self, params, version: Optional[int] = None) -> int:
         """Swap the model weights IN PLACE between scheduler steps.
 
-        The jitted step reads ``self.params`` afresh at every dispatch,
+        The jitted step reads the served tree afresh at every dispatch,
         so the swap is a boundary between steps: in-flight lanes keep
         their KV state and continue generating under the NEW weights at
         the next dispatch — no lane is dropped, no request restarted.
+        The new weights are prepared (`_prepare`) before the lock is
+        taken, while the scheduler keeps stepping on the old ones.
         (The actor/learner RL path publishes learner weights through
         here at version boundaries.)  Returns the new policy version
         (``version`` when given, else the previous version + 1)."""
+        served = self._prepare(params)
         with self._work:
-            self.params = params
+            self.params, self._served = params, served
             self.policy_version = (int(version) if version is not None
                                    else self.policy_version + 1)
             events.record("engine", "weights_swap",
@@ -636,6 +675,10 @@ class InferenceEngine:
             "queue_wait_s": self._queue_wait_s,
             # This process's XLA compiles and persistent-cache loads.
             "compile": compile_cache.counters(),
+            # Preparations of the served weights (one at load, one per
+            # update_params), their seconds, and the bytes of the tree
+            # the step takes beside those of the tree given.
+            "weights": dict(self._weights),
             **self._moe_stats(),
         }
 
@@ -656,9 +699,13 @@ class InferenceEngine:
         its first call spent compiling, the number of Mosaic kernel calls
         in the compiled program, the bytes of arguments updated in place
         (`donated_bytes`: the KV pools), the program's scratch
-        (`temp_bytes`) and the instructions that copy, slice out or stack
+        (`temp_bytes`), the instructions that copy, slice out or stack
         back the pool or whole layers of it (`pool_copies`, see
-        `kv_cache.count_pool_copies`: 0 when the pool stays where it is).
+        `kv_cache.count_pool_copies`: 0 when the pool stays where it is)
+        and the bytes of weight-shaped results the step makes by opcode
+        (`weight_bytes_copied`, see `kv_cache.count_weight_bytes_copied`:
+        no `convert`, `copy` or `transpose` when the weights are read
+        where they are; a layer scan's slices of its groups are listed).
         Recompiles each shape ahead of time (a persistent-cache hit where
         the cache is on), so call it for a check, not per request."""
         out = {}
@@ -676,7 +723,9 @@ class InferenceEngine:
                 "custom_calls": text.count("tpu_custom_call"),
                 "donated_bytes": memory.alias_size_in_bytes,
                 "temp_bytes": memory.temp_size_in_bytes,
-                "pool_copies": count_pool_copies(text, self.cache.k.shape)}
+                "pool_copies": count_pool_copies(text, self.cache.k.shape),
+                "weight_bytes_copied": count_weight_bytes_copied(
+                    text, self._step_avals[key][0])}
         return out
 
     # ---------------- scheduler ----------------
@@ -940,8 +989,8 @@ class InferenceEngine:
             fn = self._step_fns[key] = self._make_step_fn(sample, spec)
             self._step_avals[key] = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                (self.params, self.cache.k, self.cache.v, *args, *moe))
-        out = list(fn(self.params, self.cache.k, self.cache.v, *args, *moe))
+                (self._served, self.cache.k, self.cache.v, *args, *moe))
+        out = list(fn(self._served, self.cache.k, self.cache.v, *args, *moe))
         if moe:
             self._moe_load = out.pop()
         next_tok, *logp, k, v = out

@@ -41,7 +41,9 @@ class GPTConfig:
     n_heads: int = 12
     d_ff: int = 3072
     max_seq_len: int = 1024
-    dtype: Any = jnp.bfloat16        # activation dtype (params kept fp32)
+    # Activation dtype.  Params are kept fp32 (training updates them);
+    # `serving_params` makes the form the engine's step multiplies.
+    dtype: Any = jnp.bfloat16
     n_experts: int = 0               # 0 = dense MLP; >0 = Switch MoE
     capacity_factor: float = 1.25
     remat: bool = False
@@ -286,6 +288,62 @@ def forward_trunk(params: dict, tokens: jax.Array, config: GPTConfig,
     return x, jnp.sum(auxes)
 
 
+# The leaves `forward_cached` and `lm_head` cast to the activation dtype
+# where they use them; the layer-norm leaves are used in float32.
+_SERVED_LEAVES = ("tok_embed", "pos_embed", "lm_head",
+                  "wq", "wk", "wv", "wo", "w_up", "w_down")
+
+
+def _w_down_served(w):
+    """[layers, d_ff, d_model] -> `w_down_t` [layers, d_model, d_ff] where
+    d_model is no multiple of 128: a bf16 [6400, 1600] array lies on a TPU
+    with d_ff minor (the layout that pads nothing), and the T=1 step
+    copied all 48 layers of it every time to multiply it the other way
+    round.  Rows of a multiple of 128 lie as they are written."""
+    if w.shape[-1] % 128 == 0:
+        return {"w_down": w}
+    return {"w_down_t": jnp.swapaxes(w, -1, -2)}
+
+
+def _rows_served(name, keep):
+    """A lookup table [n, d_model] -> `<name>_rows`, its rows padded to a
+    multiple of 128 columns: with rows of 1600 a table lies with n minor,
+    which the tied head reads as it is (so `keep` the table for it) and
+    a lookup cannot, so the step copied the whole table for 16 rows."""
+    def served(table):
+        pad = -table.shape[1] % 128
+        if not pad:
+            return {f"{name}_embed": table}
+        rows = {f"{name}_rows": jnp.pad(table, ((0, 0), (0, pad)))}
+        return {f"{name}_embed": table, **rows} if keep else rows
+    return served
+
+
+_SERVED_FORMS = {"w_down": _w_down_served,
+                 "tok_embed": _rows_served("tok", keep=True),
+                 "pos_embed": _rows_served("pos", keep=False)}
+
+
+def serving_params(params: dict, config: GPTConfig) -> dict:
+    """`params` as `forward_cached` and `lm_head` multiply them: the
+    leaves they cast at their use held in `config.dtype`, the others as
+    given (models/_functional.py::serving_params), and of those re-made
+    `w_down` and the two tables in the forms their uses read in place.
+    The engine makes this once per set of weights and its step takes it;
+    the raw tree gives the same tokens, paying casts and copies in every
+    call."""
+    from ray_tpu.models._functional import serving_params as _shared
+    return _shared(params, config.dtype, _SERVED_LEAVES, _SERVED_FORMS)
+
+
+def _embed(params, name, index, config: GPTConfig):
+    """Rows `index` of the `name` table: from the served rows where the
+    tree has them (serving_params), else cast as they are gathered."""
+    if f"{name}_rows" in params:
+        return params[f"{name}_rows"][index][..., :config.d_model]
+    return params[f"{name}_embed"][index].astype(config.dtype)
+
+
 def _block_cached(x, k_pool, v_pool, layer, p, config: GPTConfig,
                   block_tables, positions, valid, ctx_lens):
     """One transformer block over a paged KV cache: new K/V rows are
@@ -308,7 +366,11 @@ def _block_cached(x, k_pool, v_pool, layer, p, config: GPTConfig,
     h = _layernorm(x, p["ln2_scale"], p["ln2_bias"])
     hidden = jax.nn.gelu(
         jnp.einsum("bld,df->blf", h, p["w_up"].astype(h.dtype)))
-    x = x + jnp.einsum("blf,fd->bld", hidden, p["w_down"].astype(h.dtype))
+    if "w_down_t" in p:         # the served form, see serving_params
+        x = x + jnp.einsum("blf,df->bld", hidden, p["w_down_t"])
+    else:
+        x = x + jnp.einsum("blf,fd->bld", hidden,
+                           p["w_down"].astype(h.dtype))
     return x, k_pool, v_pool
 
 
@@ -337,8 +399,7 @@ def forward_cached(params: dict, tokens: jax.Array, positions: jax.Array,
     if c.n_experts:
         raise NotImplementedError("cached decode supports dense MLP only")
     pos = jnp.clip(positions, 0, c.max_seq_len - 1)
-    x = params["tok_embed"][tokens].astype(c.dtype)
-    x = x + params["pos_embed"][pos].astype(c.dtype)
+    x = _embed(params, "tok", tokens, c) + _embed(params, "pos", pos, c)
 
     def body(carry, layer):
         p, i = layer

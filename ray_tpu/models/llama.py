@@ -333,6 +333,24 @@ def lm_head(params: dict, x: jax.Array, config: LlamaConfig) -> jax.Array:
     return x @ params["lm_head"].astype(config.dtype)
 
 
+# The leaves `forward_cached` and `lm_head` cast to the activation dtype
+# where they use them; norm scales and the router are used in float32.
+_SERVED_LEAVES = ("tok_embed", "lm_head",
+                  "wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down")
+
+
+def serving_params(params: dict, config: LlamaConfig) -> dict:
+    """`params` as `forward_cached` and `lm_head` multiply them: the
+    leaves they cast at their use held in `config.dtype`, the others as
+    given (models/_functional.py::serving_params).  Parameters already
+    stored so (`param_dtype`) come back as the same arrays; experts are
+    multiplied as stored (`_moe_ffn` casts nothing), so they stay too."""
+    from ray_tpu.models._functional import serving_params as _shared
+    return _shared(params, config.dtype, tuple(
+        k for k in _SERVED_LEAVES
+        if not (config.n_experts and k in _EXPERT_LEAVES)))
+
+
 def _block_cached(x, k_pool, v_pool, p, config: LlamaConfig,
                   block_tables, positions, valid, ctx_lens):
     """One Llama block over a paged KV cache, written and read in the

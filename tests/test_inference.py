@@ -581,6 +581,163 @@ def test_sample_offset_resume_is_seed_consistent():
     assert h.tokens() == full[3:]
 
 
+# ---------------------------------------------------------------------------
+# The served weights: prepared once, multiplied as prepared
+# ---------------------------------------------------------------------------
+
+NANO_BF16 = gpt.GPTConfig(vocab_size=512, n_layers=2, d_model=64, n_heads=4,
+                          d_ff=128, max_seq_len=128, dtype=jnp.bfloat16)
+
+
+def test_serving_params_rounds_each_matrix_once_and_keeps_the_rest():
+    """float32 parameters under bf16 activations (gpt2-xl's case): every
+    leaf `forward_cached` casts at its use is held as that cast, `w_down`
+    the way round its matmul reads it and each table also as padded rows;
+    the layer norms, used in float32, are the very arrays given."""
+    params = gpt.init_params(NANO_BF16, jax.random.key(0))
+    served = gpt.serving_params(params, NANO_BF16)
+    blocks, given = served["blocks"], params["blocks"]
+    for name in ("wq", "wk", "wv", "wo", "w_up"):
+        assert blocks[name].dtype == jnp.bfloat16
+        np.testing.assert_array_equal(blocks[name],
+                                      given[name].astype(jnp.bfloat16))
+    assert "w_down" not in blocks
+    np.testing.assert_array_equal(
+        blocks["w_down_t"],
+        jnp.swapaxes(given["w_down"], 1, 2).astype(jnp.bfloat16))
+    np.testing.assert_array_equal(served["tok_embed"],
+                                  params["tok_embed"].astype(jnp.bfloat16))
+    for rows, table in (("tok_rows", "tok_embed"), ("pos_rows", "pos_embed")):
+        assert served[rows].shape == (params[table].shape[0], 128)
+        np.testing.assert_array_equal(
+            served[rows][:, :64], params[table].astype(jnp.bfloat16))
+        assert not np.asarray(served[rows][:, 64:], np.float32).any()
+    assert "pos_embed" not in served       # only ever looked up
+    for name in ("ln1_scale", "ln1_bias", "ln2_scale", "ln2_bias"):
+        assert blocks[name] is given[name]
+    assert served["final_ln_scale"] is params["final_ln_scale"]
+    assert served["final_ln_bias"] is params["final_ln_bias"]
+    # the leaves already rounded are not made again
+    again = gpt.serving_params(
+        {**params, "blocks": {**given, "wq": blocks["wq"]}}, NANO_BF16)
+    assert again["blocks"]["wq"] is blocks["wq"]
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama", "olmoe_bf16"])
+def test_serving_params_of_a_tree_held_in_the_activation_dtype_is_the_tree(
+        family):
+    """Nothing to round: the float32 CPU presets, and OLMoE's block with
+    `param_dtype="bfloat16"` (13.84 GB on the chip: no room for a second
+    copy).  The tree comes back leaf for leaf, and no program runs."""
+    import dataclasses
+    if family == "gpt":
+        mod, cfg = gpt, gpt.CONFIGS["nano"]
+    elif family == "llama":
+        mod, cfg = llama, llama.CONFIGS["llama-tiny"]
+    else:
+        mod, cfg = llama, dataclasses.replace(
+            llama.CONFIGS["olmoe-nano"], dtype=jnp.bfloat16,
+            param_dtype="bfloat16")
+    params = mod.init_params(cfg, jax.random.key(0))
+    served = mod.serving_params(params, cfg)
+    given, kept = jax.tree.leaves(params), jax.tree.leaves(served)
+    assert len(given) == len(kept)
+    assert all(a is b for a, b in zip(given, kept))
+    engine = InferenceEngine(mod, cfg, params, auto_start=False, max_lanes=2)
+    w = engine.stats()["weights"]
+    assert w["prepared"] == 1 and w["served_bytes"] == w["given_bytes"]
+    assert all(a is b for a, b in zip(given,
+                                      jax.tree.leaves(engine._served)))
+
+
+def test_a_float32_llama_under_bf16_activations_is_served_like_gpt2xl():
+    """The dense llama presets hold float32 parameters: the same treatment,
+    by the leaf's dtype alone; norms stay float32 and the same arrays."""
+    import dataclasses
+    cfg = dataclasses.replace(llama.CONFIGS["llama-tiny"],
+                              dtype=jnp.bfloat16)
+    params = llama.init_params(cfg, jax.random.key(0))
+    served = llama.serving_params(params, cfg)
+    for name in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"):
+        np.testing.assert_array_equal(
+            served["blocks"][name],
+            params["blocks"][name].astype(jnp.bfloat16))
+    for name in ("tok_embed", "lm_head"):
+        np.testing.assert_array_equal(served[name],
+                                      params[name].astype(jnp.bfloat16))
+    for name in ("attn_norm", "mlp_norm"):
+        assert served["blocks"][name] is params["blocks"][name]
+    assert served["final_norm"] is params["final_norm"]
+
+
+@pytest.mark.parametrize("family", ["gpt", "llama"])
+def test_engine_on_prepared_weights_emits_forward_cached_on_the_raw_tree(
+        family):
+    """The step takes the prepared tree; `forward_cached` on the raw
+    float32 parameters (an engine made to serve them as given, the way
+    every tree before PR 28 did) gives the same greedy tokens: the same
+    operand values enter the same matmuls."""
+    import dataclasses
+    if family == "gpt":
+        mod, cfg = gpt, NANO_BF16
+    else:
+        mod, cfg = llama, dataclasses.replace(llama.CONFIGS["llama-tiny"],
+                                              dtype=jnp.bfloat16)
+    params = mod.init_params(cfg, jax.random.key(3))
+    prompts = [list(range(1, 40)), [7, 9, 11], list(range(100, 150))]
+
+    def run(raw):
+        engine = InferenceEngine(family, cfg, params, auto_start=False,
+                                 max_lanes=2, prefill_chunk=16)
+        if raw:
+            engine._served = engine.params
+        else:
+            assert engine.params is params
+            assert engine._served is not params
+        handles = [engine.submit(p, 12) for p in prompts]
+        while engine.step():
+            pass
+        return [h.tokens() for h in handles]
+
+    assert run(raw=False) == run(raw=True)
+
+
+def test_update_params_prepares_once_and_the_next_step_serves_the_new_tree():
+    """One preparation at load and one per swap, each one
+    `engine/weights_prepare` ring event and none per step; after the swap
+    the lanes go on under the new weights: the tokens a fresh engine on
+    those weights emits from the same state."""
+    from ray_tpu.util import events
+    old = gpt.init_params(NANO_BF16, jax.random.key(0))
+    new = gpt.init_params(NANO_BF16, jax.random.key(1))
+    prompt = list(range(1, 30))
+
+    def prepares():
+        return [e for e in events.snapshot(plane="engine")
+                if e["kind"] == "weights_prepare"]
+
+    n0 = len(prepares())
+    engine = InferenceEngine("gpt", NANO_BF16, old, auto_start=False,
+                             max_lanes=2, prefill_chunk=32,
+                             prefix_cache=False)
+    assert engine.stats()["weights"]["prepared"] == 1
+    assert len(prepares()) == n0 + 1
+    head = engine.generate(prompt, 4)
+    assert engine.update_params(new) == 1
+    w = engine.stats()["weights"]
+    assert w["prepared"] == 2 and len(prepares()) == n0 + 2
+    assert w["given_bytes"] == sum(x.nbytes for x in jax.tree.leaves(new))
+    assert w["served_bytes"] < w["given_bytes"]        # bf16 for float32
+    assert engine.params is new
+    tail = engine.generate(prompt, 6)
+    assert len(prepares()) == n0 + 2                   # none per step
+    fresh = InferenceEngine("gpt", NANO_BF16, new, auto_start=False,
+                            max_lanes=2, prefill_chunk=32,
+                            prefix_cache=False)
+    assert tail == fresh.generate(prompt, 6)
+    assert head != tail
+
+
 def test_sampled_step_keeps_logits_on_device():
     eng = InferenceEngine("gpt", "nano", max_lanes=2, block_size=8,
                           max_seq_len=32, prefill_chunk=8,

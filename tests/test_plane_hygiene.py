@@ -273,3 +273,27 @@ def test_expert_load_is_fetched_by_stats_alone_and_kernels_keep_their_names():
             r'name="(\w+)"', (PKG / "ops" / "attention.py").read_text())}
     assert names["moe.py"] == ["moe_grouped_matmul"]
     assert "paged_decode_attention" in names["attention.py"]
+
+
+def test_weights_are_prepared_at_load_and_swap_and_never_in_a_step():
+    """`engine/weights_prepare` is one ring event per preparation of the
+    served weights: `_prepare` records it, and only the constructor and
+    `update_params` call `_prepare`; the step hands `_served` on as it is
+    (a cast or a copy there would be back in every token's path)."""
+    src = (PKG / "inference" / "engine.py").read_text()
+    sites = [(pl, k) for path, _, pl, k in _call_sites()
+             if k == "weights_prepare"]
+    assert sites == [("engine", "weights_prepare")]
+    body = src[src.index("    def _prepare(self"):
+               src.index("    def update_params(self")]
+    assert '"weights_prepare"' in body
+    assert len(re.findall(r"self\._prepare\(", src)) == 2
+    init = src[src.index("    def __init__(self, model="):
+               src.index("    # ---------------- public API")]
+    swap = src[src.index("    def update_params(self"):
+               src.index("    # -------- disaggregated prefill/decode")]
+    assert "self._prepare(params)" in init and "self._prepare(params)" in swap
+    step_path = src[src.index("    def step(self)"):
+                    src.index("    def _make_step_fn")]
+    assert "serving_params" not in step_path and "_prepare" not in step_path
+    assert step_path.count("self._served") == 2     # avals, and the call

@@ -98,6 +98,10 @@ def test_engine_weight_swap_mid_flight_keeps_lanes():
     assert all(np.isfinite(lp) and lp <= 0.0 for lp in h.logps)
     assert eng.policy_version == 7
     assert eng.stats()["policy_version"] == 7
+    # float32 learner weights under float32 activations: the swap prepared
+    # them (counted) and what the step takes is the tree it was handed.
+    assert eng.stats()["weights"]["prepared"] == 2
+    assert eng._served is eng.params
 
 
 def test_engine_rollout_actor_versioned_batch():

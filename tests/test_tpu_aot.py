@@ -19,7 +19,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu._private.accelerators import (
     ChipAllocator, chip_env, leasable)
-from ray_tpu.inference.kv_cache import count_pool_copies
+from ray_tpu.inference.kv_cache import (count_pool_copies,
+                                        count_weight_bytes_copied)
 from ray_tpu.models import gpt
 from ray_tpu.ops.attention import kv_row_width, paged_decode_attention
 from ray_tpu.parallel import MeshConfig, create_mesh
@@ -134,20 +135,25 @@ def test_paged_decode_kernel_keeps_its_name_inside_a_layer_scan(v5e,
 
 
 def _compile_engine_step(device, cfg, t, lanes=8, num_blocks=64,
-                         block_size=16, model=gpt, max_seq_len=None):
-    """The engine's greedy step for `t` tokens a lane, as
-    `benchmark/tools/aot_sizes.py::serve` builds it: no engine thread, no
-    weights, the pool in the shape `PagedKVCache` stores.  An expert
-    configuration's step takes its load counters last."""
+                         block_size=16, model=gpt, max_seq_len=None,
+                         prepared=True):
+    """The engine's greedy step for `t` tokens a lane: no engine thread,
+    no weights, the pool in the shape `PagedKVCache` stores, the
+    parameters in the shapes of the tree the engine prepares
+    (`model.serving_params`; with `prepared=False` those of `init_params`,
+    which every tree before PR 28 served).  An expert configuration's
+    step takes its load counters last."""
     from ray_tpu.inference.engine import InferenceEngine
     arg = _arg_on(device)
     eng = object.__new__(InferenceEngine)
     eng.model, eng.config, eng._capture_logp = model, cfg, False
     eng.backend, eng._step_impls = "tpu", {}
-    params = jax.tree.map(
-        lambda x: arg(x.shape, x.dtype),
-        jax.eval_shape(lambda k: model.init_params(cfg, k),
-                       jax.random.key(0)))
+    shapes = jax.eval_shape(lambda k: model.init_params(cfg, k),
+                            jax.random.key(0))
+    if prepared:
+        shapes = jax.eval_shape(lambda p: model.serving_params(p, cfg),
+                                shapes)
+    params = jax.tree.map(lambda x: arg(x.shape, x.dtype), shapes)
     pool = arg(_pool_shape(cfg.n_layers, num_blocks, block_size,
                            getattr(cfg, "n_kv_heads", cfg.n_heads),
                            cfg.head_dim), cfg.dtype)
@@ -196,11 +202,9 @@ def test_engine_step_leaves_the_kv_pool_where_it_is(v5e, as_on_chip, heads,
 
     pool_bytes = 2 * math.prod(pool.shape)                      # bf16
     assert memory.alias_size_in_bytes == 2 * pool_bytes         # K and V
-    # Scratch: the step's bf16 copies of the fp32 weights (PERF.md
-    # section 7) and less than one pool beside them.
-    weight_copies = sum(2 * math.prod(x.shape)
-                        for x in jax.tree.leaves(params))
-    assert memory.temp_size_in_bytes - weight_copies < pool_bytes
+    # Scratch: less than one pool; the weights are arguments as the step
+    # multiplies them, not bf16 copies made here (PERF.md section 6, PR 28).
+    assert memory.temp_size_in_bytes < pool_bytes
 
     kernels = _kernel_names(text)
     assert all(k.startswith("paged_decode_attention") for k in kernels)
@@ -249,6 +253,63 @@ def test_olmoe_step_reads_its_experts_where_they_are(v5e, as_on_chip, t):
     assert memory.temp_size_in_bytes < (16 if t == 1 else 128) * 2 ** 20
     assert memory.argument_size_in_bytes > sum(
         2 * math.prod(x.shape) for x in jax.tree.leaves(params))
+
+
+# gpt2-xl's widths, eight of its 48 layers (two turns of its scan, so the
+# loop over groups of four is there), the decode cell's 16 lanes and 512
+# blocks.
+XL8 = gpt.GPTConfig(n_layers=8, d_model=1600, n_heads=25, d_ff=6400,
+                    scan_unroll=4)
+_MATRIX = re.compile(
+    r" = \w+\[(?:\d+,)*(?:1600,25,64|25,64,1600|1600,6400|6400,1600|"
+    r"50304,1600|50304,1664|1024,1600|1024,1664)\]\S* ([\w\-]+)\(")
+
+
+@pytest.mark.parametrize("t", [1, 32], ids=["t1", "t_prefill_chunk"])
+def test_gpt2xl_step_multiplies_its_weights_as_they_are_held(v5e, as_on_chip,
+                                                             t):
+    """The mechanism of PERF.md section 6, PR 28, without a chip: on the
+    tree the engine prepares, the compiled step has no `convert`, `copy`
+    or `transpose` whose result has a matrix leaf's shape (61% of the
+    decode cell's busy time went there: fp32 -> bf16 of every matrix,
+    `w_down` and the table turned round, in every step), and next to no
+    scratch where the raw tree's step held a bf16 copy of every weight."""
+    compiled, pool, params = _compile_engine_step(
+        v5e[0], XL8, t, lanes=16, num_blocks=512)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert {x.dtype for x in jax.tree.leaves(params) if x.ndim > 2} == {
+        jnp.dtype(jnp.bfloat16)}
+    moved = {m.group(1) for line in text.splitlines()
+             if (m := _MATRIX.search(line))}
+    assert not moved & {"convert", "copy", "transpose"}, moved
+    copied = count_weight_bytes_copied(text, params)
+    assert not set(copied) & {"convert", "copy", "transpose", "remat"}, copied
+    assert count_pool_copies(text, pool.shape) == 0
+    # Scratch: a T=1 step's is activations; the T=32 step gathers every
+    # lane's context of 1024 for the masked-dense attention (two layers of
+    # K and V in flight).  The bf16 matrices of eight layers are 0.49 GB.
+    weights = sum(2 * math.prod(x.shape) for x in jax.tree.leaves(params)
+                  if x.ndim > 2)
+    assert memory.temp_size_in_bytes < (weights // 2 if t == 1
+                                        else weights)
+    kernels = _kernel_names(text)
+    assert all(k.startswith("paged_decode_attention") for k in kernels)
+    assert len(kernels) == (XL8.scan_unroll if t == 1 else 0)
+
+
+def test_weight_copy_counter_sees_a_raw_float32_tree(v5e, as_on_chip):
+    """What every tree before PR 28 compiled: `init_params`' float32
+    leaves handed to the step, which rounds and turns them in every call.
+    The counter must not call that nothing: every matrix is converted
+    (bf16 bytes of all of them, `w_down` in its `copy`), the scratch holds
+    the copies."""
+    compiled, _, params = _compile_engine_step(
+        v5e[0], XL8, 1, lanes=16, num_blocks=512, prepared=False)
+    copied = count_weight_bytes_copied(compiled.as_text(), params)
+    matrices = sum(2 * math.prod(x.shape) for x in jax.tree.leaves(params)
+                   if x.ndim > 2)
+    assert copied.get("convert", 0) + copied.get("copy", 0) >= matrices
+    assert compiled.memory_analysis().temp_size_in_bytes > matrices
 
 
 def test_pool_copy_counter_sees_a_pool_scanned_over_layers(v5e, as_on_chip):
