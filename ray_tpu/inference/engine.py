@@ -68,6 +68,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu import models
 from ray_tpu._private import compile_cache
 from ray_tpu._private.accelerators import leased_chips, require_chip_lease
 from ray_tpu.inference.kv_cache import (PagedKVCache, count_pool_copies,
@@ -279,19 +280,6 @@ def _end_spans(req: _Request, **payload) -> None:
     req.queue_tok = req.span_tok = None
 
 
-def _resolve_model(model):
-    if isinstance(model, str):
-        if model == "gpt":
-            from ray_tpu.models import gpt as mod
-        elif model == "llama":
-            from ray_tpu.models import llama as mod
-        else:
-            raise ValueError(f"unknown model family {model!r}")
-        return mod
-    # a module implementing forward_cached/lm_head/serving_params/CONFIGS
-    return model
-
-
 class InferenceEngine:
     """max_lanes concurrent sequences over one shared paged KV pool.
 
@@ -300,7 +288,7 @@ class InferenceEngine:
     auto_start=False the caller drives `step()` (deterministic tests,
     microbenchmarks).  `prefix_cache=False` disables content-addressed
     block reuse (every prompt prefills from token zero — the cold
-    baseline bench_prefix.py measures against).
+    baseline).
 
     `spec_k > 0` enables speculative decoding: `draft_proposer`
     (``"ngram"`` or a speculative.DraftProposer) suggests up to spec_k
@@ -323,7 +311,7 @@ class InferenceEngine:
                  capture_logp: bool = False):
         require_chip_lease("InferenceEngine")
         compile_cache.watch()      # before this engine's first program
-        self.model = _resolve_model(model)
+        self.model = models.family(model)
         self.config = (self.model.CONFIGS[config] if isinstance(config, str)
                        else config)
         device = jax.devices()[0]
@@ -345,7 +333,7 @@ class InferenceEngine:
         self._served = self._prepare(params)
         # An expert configuration's load counters live on the device and
         # ride the step (forward_cached's `moe_load`); stats() fetches.
-        n_experts = getattr(self.config, "n_experts", 0)
+        n_experts = self.config.n_experts
         self._moe_load = (jnp.zeros((n_experts + 2,), jnp.int32)
                           if n_experts else None)
         self.max_lanes = max_lanes

@@ -402,11 +402,10 @@ class PagedKVCache:
 
     @classmethod
     def for_model(cls, model, config, **kw) -> "PagedKVCache":
-        """Build a cache shaped for a models/ module (gpt or llama)."""
-        kv_heads = getattr(config, "n_kv_heads", config.n_heads)
+        """Build a cache shaped for an LM family's config (models/)."""
         kw.setdefault("max_seq_len", config.max_seq_len)
         kw.setdefault("dtype", config.dtype)
-        return cls(config.n_layers, kv_heads, config.head_dim, **kw)
+        return cls(config.n_layers, config.n_kv_heads, config.head_dim, **kw)
 
     # ---------------- host-side lane lifecycle ----------------
 

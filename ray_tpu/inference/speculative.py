@@ -113,15 +113,9 @@ class ModelDraftProposer(DraftProposer):
         import jax
         import jax.numpy as jnp
 
-        if isinstance(model, str):
-            if model == "gpt":
-                from ray_tpu.models import gpt as mod
-            elif model == "llama":
-                from ray_tpu.models import llama as mod
-            else:
-                raise ValueError(f"unknown draft model family {model!r}")
-            model = mod
-        self.model = model
+        from ray_tpu.models import decoder, family
+
+        self.model = model = family(model)
         self.config = (model.CONFIGS[config] if isinstance(config, str)
                        else config)
         if params is None:
@@ -130,8 +124,7 @@ class ModelDraftProposer(DraftProposer):
         self.window = int(window)
 
         def _next(params, toks):
-            out = model.forward(params, toks, self.config)
-            logits = out[0] if isinstance(out, tuple) else out
+            logits, _ = decoder.forward(model.spec, params, toks, self.config)
             return jnp.argmax(logits[0, -1]).astype(jnp.int32)
 
         self._next = jax.jit(_next)

@@ -1,0 +1,633 @@
+"""The decoder-only transformer, defined once for every LM family.
+
+A family (models/gpt.py, models/llama.py) is a config dataclass, its
+parameter format (`init_params`, `param_specs`) and `spec(config)`: a `Spec`
+naming the parts its block is made of and the leaves they read.  Everything
+that runs is here: the training block and the block over a paged KV cache,
+the two layer scans, the head, the loss, the form the weights are served in
+and the train step.  The public functions take the family's `spec` function
+first; a family module exports them bound to it (`bind`).
+
+Design (no reference counterpart: Ray hosts models, it doesn't ship them):
+  * pure functional: params are a pytree, forward is a jittable function
+    (plays directly with pjit/GSPMD and donation);
+  * layers are STACKED on a leading dim and applied with `lax.scan`: one
+    compiled block regardless of depth (fast compiles, small HLO);
+  * every param leaf has a logical sharding spec (parallel.sharding rules
+    decide DP/FSDP/TP placement; "kv_heads" shards GQA kv projections);
+  * attention = flash (Pallas) on one chip and per shard (shard_map over
+    batch and heads) under a mesh, ring attention when the mesh has a seq
+    axis > 1; over a paged KV cache, ops/attention.py's paged path;
+  * `jax.checkpoint` (remat) on the block when configured: trades FLOPs for
+    HBM, the standard TPU memory lever.
+
+A new architecture is a new `Spec` over these parts, or a new part; a
+choice is made from the spec, never from a family's name.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+import types
+from functools import partial
+from typing import Callable, Optional
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.ops.attention import mesh_flash_attention
+from ray_tpu.parallel.sharding import tree_shardings, with_logical_constraint
+
+
+# --------------------------------------------------------------------------
+# Parts: norms and positions
+# --------------------------------------------------------------------------
+
+def layernorm(x, scale, bias, eps=1e-5):
+    x32 = x.astype(jnp.float32)
+    mu = jnp.mean(x32, -1, keepdims=True)
+    var = jnp.var(x32, -1, keepdims=True)
+    y = (x32 - mu) * jax.lax.rsqrt(var + eps)
+    return (y * scale + bias).astype(x.dtype)
+
+
+def rmsnorm(x, scale, eps):
+    x32 = x.astype(jnp.float32)
+    y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
+    return (y * scale).astype(x.dtype)
+
+
+def rope(x, theta: float, offset=0):
+    """Rotary position embedding over [B, L, H, K] (rotate-half pairing:
+    the head dim splits into two halves treated as (real, imag)).
+
+    `offset` is the absolute position of x's first token: a scalar shared
+    by the batch, or a per-lane [B] array (cached decode: lanes sit at
+    different depths)."""
+    b, l, h, k = x.shape
+    half = k // 2
+    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    off = jnp.asarray(offset, jnp.float32)
+    pos = off[..., None] + jnp.arange(l, dtype=jnp.float32)  # [L] or [B, L]
+    ang = pos[..., None] * freqs                      # [L, half] / [B, L, half]
+    if ang.ndim == 2:
+        ang = ang[None]
+    cos = jnp.cos(ang)[:, :, None, :]
+    sin = jnp.sin(ang)[:, :, None, :]
+    x32 = x.astype(jnp.float32)
+    x1, x2 = x32[..., :half], x32[..., half:]
+    out = jnp.concatenate([x1 * cos - x2 * sin,
+                           x2 * cos + x1 * sin], axis=-1)
+    return out.astype(x.dtype)
+
+
+# --------------------------------------------------------------------------
+# Parts: feed-forwards.  `apply(h, p, config, mesh=None, valid=None)` on
+# normed h [B, L, D] with the layer's leaves `p` returns (y [B, L, D], the
+# layer's auxiliary loss or None, its expert load [E] or None).
+# --------------------------------------------------------------------------
+
+def _down(hidden, p):
+    """The down projection, from `w_down_t` where the tree holds that
+    form (serving_params)."""
+    if "w_down_t" in p:
+        return jnp.einsum("blf,df->bld", hidden, p["w_down_t"])
+    return jnp.einsum("blf,fd->bld", hidden, p["w_down"].astype(hidden.dtype))
+
+
+def gelu_mlp(h, p, config, mesh=None, valid=None):
+    hidden = jax.nn.gelu(
+        jnp.einsum("bld,df->blf", h, p["w_up"].astype(h.dtype)))
+    hidden = with_logical_constraint(hidden, ("batch", "length", "mlp"),
+                                     mesh=mesh)
+    return _down(hidden, p), None, None
+
+
+def swiglu_mlp(h, p, config, mesh=None, valid=None):
+    gate = jax.nn.silu(jnp.einsum("bld,df->blf", h,
+                                  p["w_gate"].astype(h.dtype)))
+    up = jnp.einsum("bld,df->blf", h, p["w_up"].astype(h.dtype))
+    hidden = with_logical_constraint(gate * up, ("batch", "length", "mlp"),
+                                     mesh=mesh)
+    return _down(hidden, p), None, None
+
+
+def moe_ffn(h, p, config, mesh=None, valid=None):
+    """Dropless top-k experts: softmax router, top-k, dropless dispatch
+    (ops/moe.py).  Router product, softmax and top-k run in float32 (the
+    eighth expert is often chosen by a fourth decimal); the chosen
+    probabilities weight the experts as they are unless
+    `config.norm_topk_prob`.  `p` holds the layer's router [D, E] and the
+    experts of ALL layers with the index `layer` (the kernel reads them in
+    place).  The load is the assignments each expert took."""
+    from ray_tpu.ops import moe
+
+    c = config
+    b, l, d = h.shape
+    x = h.reshape(b * l, d)
+    logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, experts = jax.lax.top_k(probs, c.n_experts_per_tok)
+    if c.norm_topk_prob:
+        weights = weights / jnp.sum(weights, -1, keepdims=True)
+    y, load = moe.expert_ffn(
+        x, experts, weights, p["w_gate"], p["w_up"], p["w_down"],
+        p["layer"], None if valid is None else valid.reshape(-1))
+    return y.reshape(b, l, d), None, load
+
+
+def switch_moe(h, p, config, mesh=None, valid=None):
+    """Switch-style top-1 experts with dense dispatch (einsum one-hot masks:
+    static shapes, XLA-friendly; no sort/scatter) and a capacity: the one
+    expert layer with a backward pass and an `expert` mesh axis."""
+    b, l, d = h.shape
+    e = config.n_experts
+    t = b * l
+    cap = int(math.ceil(t / e * config.capacity_factor))
+    xt = h.reshape(t, d)
+
+    logits = (xt.astype(jnp.float32)
+              @ p["router"].astype(jnp.float32))                # [T,E]
+    probs = jax.nn.softmax(logits, axis=-1)
+    gate = jnp.max(probs, -1)                      # [T]
+    expert = jnp.argmax(probs, -1)                 # [T]
+    onehot = jax.nn.one_hot(expert, e, dtype=jnp.float32)       # [T,E]
+    # Position of each token within its expert's queue.
+    pos = jnp.cumsum(onehot, axis=0) * onehot - 1.0             # [T,E]
+    keep = (pos < cap) & (onehot > 0)
+    dispatch = (jax.nn.one_hot(pos.astype(jnp.int32), cap)
+                * keep[..., None])                               # [T,E,C]
+
+    ex_in = jnp.einsum("tec,td->ecd", dispatch.astype(h.dtype), xt)
+    ex_in = with_logical_constraint(ex_in, ("experts", None, "embed"),
+                                    mesh=mesh)
+    hidden = jax.nn.gelu(jnp.einsum("ecd,edf->ecf", ex_in,
+                                    p["w_up"].astype(h.dtype)))
+    ex_out = jnp.einsum("ecf,efd->ecd", hidden, p["w_down"].astype(h.dtype))
+    combine = dispatch * gate[:, None, None]
+    out = jnp.einsum("tec,ecd->td", combine.astype(h.dtype), ex_out)
+
+    # Load-balancing aux loss (Switch eq. 4): mean prob * mean assignment.
+    density = jnp.mean(onehot, 0)
+    density_prob = jnp.mean(probs, 0)
+    aux = e * jnp.sum(density * density_prob)
+    return out.reshape(b, l, d), aux, None
+
+
+@dataclasses.dataclass(frozen=True)
+class FeedForward:
+    apply: Callable
+    # Leaves `apply` casts to the activation dtype at their use, which
+    # `serving_params` therefore holds in it (the rest are used as stored).
+    cast: tuple = ()
+    # Leaves kept whole outside the layer scan and read in place at
+    # `p["layer"]`: a scan would slice a layer's experts out in every step.
+    whole: tuple = ()
+    trains: bool = True     # has a backward pass and its auxiliary losses
+    serves: bool = True     # runs over a paged KV cache
+
+
+GELU = FeedForward(gelu_mlp, cast=("w_up", "w_down"))
+SWIGLU = FeedForward(swiglu_mlp, cast=("w_gate", "w_up", "w_down"))
+EXPERTS = FeedForward(moe_ffn, whole=("w_gate", "w_up", "w_down"),
+                      trains=False)
+SWITCH = FeedForward(switch_moe, serves=False)
+
+
+@dataclasses.dataclass(frozen=True)
+class Spec:
+    """The parts of one family's block at one config, and their leaves.
+    Shapes come from the config every family has: n_layers, d_model,
+    n_heads, n_kv_heads (< n_heads: grouped-query attention), head_dim,
+    vocab_size, max_seq_len, dtype, remat, scan_unroll."""
+    norm: Callable          # norm(x, *leaves): layernorm, or rmsnorm + eps
+    attn_norm: tuple        # the leaves of the block's first norm,
+    mlp_norm: tuple         # of its second,
+    final_norm: tuple       # and of the trunk's last
+    ffn: FeedForward
+    init_params: Callable   # (config, key) -> params
+    param_specs: Callable   # (config) -> the congruent logical-spec tree
+    # None: a learned table `pos_embed` added to the token embedding.
+    rope_theta: Optional[float] = None
+    # eps of an RMSNorm (`q_norm`, `k_norm`) on the projected q and k.
+    qk_norm: Optional[float] = None
+    tied_head: bool = False     # the head is `tok_embed.T`, not `lm_head`
+
+
+# --------------------------------------------------------------------------
+# The block, for training and over a paged KV cache
+# --------------------------------------------------------------------------
+
+def _norm(spec: Spec, x, p, leaves):
+    return spec.norm(x, *(p[name] for name in leaves))
+
+
+def _qkv(spec: Spec, h, p):
+    """Projected q, k, v [B, L, heads, head_dim] of normed h; with
+    `qk_norm`, q and k RMS-normalised over all their heads together
+    (OLMoE: the norm spans the whole projected vector, before RoPE)."""
+    q = jnp.einsum("bld,dhk->blhk", h, p["wq"].astype(h.dtype))
+    k = jnp.einsum("bld,dhk->blhk", h, p["wk"].astype(h.dtype))
+    v = jnp.einsum("bld,dhk->blhk", h, p["wv"].astype(h.dtype))
+    if spec.qk_norm is not None:
+        def norm(x, scale):
+            flat = rmsnorm(x.reshape(*x.shape[:2], -1), scale.reshape(-1),
+                           spec.qk_norm)
+            return flat.reshape(x.shape)
+        q, k = norm(q, p["q_norm"]), norm(k, p["k_norm"])
+    return q, k, v
+
+
+def _block(x, p, spec: Spec, config, mesh, position_offset=0):
+    c = config
+    h = _norm(spec, x, p, spec.attn_norm)
+    q, k, v = _qkv(spec, h, p)
+    if spec.rope_theta is not None:
+        q = rope(q, spec.rope_theta, position_offset)
+        k = rope(k, spec.rope_theta, position_offset)
+    if c.n_kv_heads < c.n_heads:
+        # GQA: each kv head serves n_heads / n_kv_heads query heads.
+        # Materializing the repeat keeps the attention kernels
+        # head-uniform; XLA fuses the broadcast into the kernel operand
+        # load.
+        k = jnp.repeat(k, c.n_heads // c.n_kv_heads, axis=2)
+        v = jnp.repeat(v, c.n_heads // c.n_kv_heads, axis=2)
+    q = with_logical_constraint(q, ("batch", "length", "heads", "kv"),
+                                mesh=mesh)
+    attn = mesh_flash_attention(q, k, v, mesh=mesh, causal=True)
+    x = x + jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
+
+    h = _norm(spec, x, p, spec.mlp_norm)
+    y, aux, _ = spec.ffn.apply(h, p, c, mesh)
+    if aux is None:
+        aux = jnp.zeros((), jnp.float32)
+    x = with_logical_constraint(x + y, ("batch", "length", "act_embed"),
+                                mesh=mesh)
+    return x, aux
+
+
+def _block_cached(x, k_pool, v_pool, p, spec: Spec, config, block_tables,
+                  positions, valid, ctx_lens):
+    """One block over a paged KV cache: new K/V rows are written into the
+    whole pools at `p["layer"]`, then attention runs over the block table
+    in the same buffers (ops/attention.py paged path).  K/V are cached
+    with kv_heads (GQA un-repeated: the whole point of the grouped cache);
+    the paged attention path expands groups itself.
+    x [B, T, D]; positions [B, T] absolute; ctx_lens [B] = context length
+    including this slice.  Returns (x, pools, the expert layer's load or
+    None)."""
+    from ray_tpu.ops.attention import paged_attention, paged_kv_update
+
+    c = config
+    layer = p["layer"]
+    h = _norm(spec, x, p, spec.attn_norm)
+    q, k, v = _qkv(spec, h, p)
+    if spec.rope_theta is not None:
+        # Per-token rotation at each token's own absolute position: offset
+        # = positions[:, 0] with L-consecutive slices means positions must
+        # be contiguous per lane, which prefill/decode slices always are.
+        q = rope(q, spec.rope_theta, positions[:, 0])
+        k = rope(k, spec.rope_theta, positions[:, 0])
+    k_pool, v_pool = paged_kv_update(k_pool, v_pool, k, v, block_tables,
+                                     positions, valid, layer)
+    attn = paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
+                           positions, layer, kv_heads=c.n_kv_heads)
+    x = x + jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
+
+    h = _norm(spec, x, p, spec.mlp_norm)
+    y, _, load = spec.ffn.apply(h, p, c, valid=valid)
+    return x + y, k_pool, v_pool, load
+
+
+def _layer_stack(blocks: dict, n_layers: int, whole: tuple):
+    """(what the layer loop scans over, what it closes over): the `whole`
+    leaves stay outside the scan, each layer takes its index."""
+    layers = jnp.arange(n_layers, dtype=jnp.int32)
+    scanned = {k: v for k, v in blocks.items() if k not in whole}
+    return (scanned, layers), {k: blocks[k] for k in whole}
+
+
+# --------------------------------------------------------------------------
+# Forward, head and loss
+# --------------------------------------------------------------------------
+
+def forward_trunk(family, params: dict, tokens: jax.Array, config,
+                  mesh=None, position_offset=0):
+    """Transformer stack up to (excluding) the lm head.
+    tokens [B, L] -> (x [B, L, D] normed, auxiliary loss summed over layers).
+
+    position_offset is the absolute position of the first token: a suffix
+    call at position p must read pos_embed[p:p+l], not pos_embed[:l], and
+    rotate RoPE from p (there a scalar or a per-lane [B] array)."""
+    c, spec = config, family(config)
+    x = params["tok_embed"][tokens].astype(c.dtype)
+    if spec.rope_theta is None:
+        pos = jax.lax.dynamic_slice_in_dim(params["pos_embed"],
+                                           position_offset, tokens.shape[1])
+        x = x + pos[None].astype(c.dtype)
+    x = with_logical_constraint(x, ("batch", "length", "act_embed"), mesh=mesh)
+
+    block = partial(_block, spec=spec, config=c, mesh=mesh,
+                    position_offset=position_offset)
+    if c.remat:
+        block = jax.checkpoint(
+            block, policy=jax.checkpoint_policies.nothing_saveable)
+
+    scanned, whole = _layer_stack(params["blocks"], c.n_layers,
+                                  spec.ffn.whole)
+
+    def body(x, layer):
+        p, i = layer
+        return block(x, {**p, **whole, "layer": i})
+
+    x, auxes = jax.lax.scan(body, x, scanned,
+                            unroll=min(c.scan_unroll, c.n_layers))
+    return _norm(spec, x, params, spec.final_norm), jnp.sum(auxes)
+
+
+def _head(spec: Spec, params: dict, config):
+    return (params["tok_embed"].T if spec.tied_head
+            else params["lm_head"]).astype(config.dtype)
+
+
+def lm_head(family, params: dict, x: jax.Array, config) -> jax.Array:
+    """Project hidden states [..., D] to vocab logits [..., V]."""
+    return x @ _head(family(config), params, config)
+
+
+def forward(family, params: dict, tokens: jax.Array, config, mesh=None,
+            position_offset=0):
+    """tokens [B, L] int32 -> (logits [B, L, V], auxiliary loss scalar)."""
+    x, aux = forward_trunk(family, params, tokens, config, mesh,
+                           position_offset)
+    logits = lm_head(family, params, x, config)
+    return with_logical_constraint(logits, ("batch", "length", "vocab"),
+                                   mesh=mesh), aux
+
+
+def loss_fn(family, params: dict, batch: dict, config, mesh=None):
+    """batch = {"tokens": [B, L]}: next-token cross-entropy, plus 0.01 of
+    the feed-forward's auxiliary loss.
+
+    Runs the model on the FULL length L and shifts targets instead of
+    slicing inputs to L-1: the sequence dim must stay divisible by the
+    mesh's seq axis for ring attention, and L-1 never is.
+
+    Single chip uses the fused chunked cross-entropy (never materializes
+    [B, L, V]: see ops/cross_entropy.py and PERF.md; the naive fp32
+    log_softmax was ~75% of the train step).  Under a mesh the shard_map
+    variant keeps the same property per-chip with vocab-sharded
+    logsumexp; the naive path remains only as the fallback for
+    non-divisible shapes.
+    """
+    from ray_tpu.ops.cross_entropy import (fused_cross_entropy,
+                                           fused_cross_entropy_spmd,
+                                           spmd_ce_applicable)
+
+    c, spec = config, family(config)
+    if not spec.ffn.trains:
+        raise NotImplementedError(
+            "training an expert configuration is not supported yet: the "
+            "grouped matmul (ops/moe.py) has no backward pass and the "
+            "router's auxiliary losses are not computed (ROADMAP.md R1)")
+    tokens = batch["tokens"]
+    targets = jnp.roll(tokens, -1, axis=1)
+    # Last position predicts the rolled-around token 0: always masked.
+    valid = jnp.ones_like(tokens, jnp.float32).at[:, -1].set(0.0)
+    mask = batch.get("loss_mask")
+    if mask is not None:
+        valid = valid * mask
+
+    multichip = mesh is not None and any(
+        s > 1 for s in mesh.shape.values())
+    if not multichip:
+        x, aux = forward_trunk(family, params, tokens, c, mesh)
+        b, l, d = x.shape
+        head = _head(spec, params, c)
+        loss = fused_cross_entropy(x.reshape(b * l, d), head,
+                                   targets.reshape(-1), valid.reshape(-1))
+    elif spmd_ce_applicable(mesh, c.vocab_size, *tokens.shape):
+        x, aux = forward_trunk(family, params, tokens, c, mesh)
+        loss = fused_cross_entropy_spmd(x, _head(spec, params, c), targets,
+                                        valid, mesh)
+    else:
+        logits, aux = forward(family, params, tokens, c, mesh)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        loss = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1)
+    return loss + 0.01 * aux
+
+
+# --------------------------------------------------------------------------
+# Serving: the weights as the cached forward multiplies them, and that
+# forward
+# --------------------------------------------------------------------------
+
+def _w_down_served(w):
+    """[layers, d_ff, d_model] -> `w_down_t` [layers, d_model, d_ff] where
+    d_model is no multiple of 128: a bf16 [6400, 1600] array lies on a TPU
+    with d_ff minor (the layout that pads nothing), and the T=1 step
+    copied all 48 layers of it every time to multiply it the other way
+    round.  Rows of a multiple of 128 lie as they are written."""
+    if w.shape[-1] % 128 == 0:
+        return {"w_down": w}
+    return {"w_down_t": jnp.swapaxes(w, -1, -2)}
+
+
+def _rows_served(name, keep):
+    """A lookup table [n, d_model] -> `<name>_rows`, its rows padded to a
+    multiple of 128 columns: with rows of 1600 a table lies with n minor,
+    which the tied head reads as it is (so `keep` the table for it) and
+    a lookup cannot, so the step copied the whole table for 16 rows."""
+    def served(table):
+        pad = -table.shape[1] % 128
+        if not pad:
+            return {f"{name}_embed": table}
+        rows = {f"{name}_rows": jnp.pad(table, ((0, 0), (0, pad)))}
+        return {f"{name}_embed": table, **rows} if keep else rows
+    return served
+
+
+# By whether the head is the token table (module level: `_remake` is
+# compiled once per set of forms).
+_SERVED_FORMS = {tied: (("w_down", _w_down_served),
+                        ("tok_embed", _rows_served("tok", keep=tied)),
+                        ("pos_embed", _rows_served("pos", keep=False)))
+                 for tied in (True, False)}
+
+
+@partial(jax.jit, static_argnums=(1, 2, 3))
+def _remake(leaves, names, dtype, forms):
+    forms = dict(forms)
+    return [forms[name](x.astype(dtype)) if name in forms
+            else {name: x.astype(dtype)} for x, name in zip(leaves, names)]
+
+
+def serving_params(family, params: dict, config) -> dict:
+    """`params` as `forward_cached` and `lm_head` multiply them.  Every
+    table, head, attention matrix and leaf the feed-forward casts
+    (`FeedForward.cast`; norm scales and routers are used in float32,
+    dropless experts as stored) is held in `config.dtype`: the rounding
+    the cached forward applies to that leaf at its use
+    (`p["wq"].astype(h.dtype)`), done once for all steps instead of once
+    per step.  Of those, `w_down` and the two tables are re-made in the
+    forms their uses read in place (`_w_down_served`, `_rows_served`).
+
+    It goes by the leaf's own dtype: one that is already in `config.dtype`
+    comes back as the same array, as does every leaf not named, so a tree
+    held in it (OLMoE's bf16 leaves, a float32 config) is returned as it
+    is, with no program run and no copy made.  The rest are made in one
+    compiled program.  The engine makes this once per set of weights and
+    its step takes it; the raw tree gives the same tokens, paying casts
+    and copies in every call."""
+    spec = family(config)
+    cast = ("tok_embed", "pos_embed", "lm_head",
+            "wq", "wk", "wv", "wo") + spec.ffn.cast
+    dtype = jnp.dtype(config.dtype)
+    flat = jax.tree_util.tree_flatten_with_path(params)[0]
+    names = [path[-1].key for path, _ in flat]
+    todo = [i for i, (_, x) in enumerate(flat)
+            if names[i] in cast and x.dtype != dtype]
+    if not todo:
+        return params
+    made = dict(zip(todo, _remake(
+        [flat[i][1] for i in todo], tuple(names[i] for i in todo), dtype,
+        _SERVED_FORMS[spec.tied_head])))
+    out = {}
+    for i, (path, x) in enumerate(flat):
+        node = out
+        for k in path[:-1]:
+            node = node.setdefault(k.key, {})
+        node.update(made.get(i, {names[i]: x}))
+    return out
+
+
+def _embed(params, name, index, config):
+    """Rows `index` of the `name` table: from the served rows where the
+    tree has them (serving_params), else cast as they are gathered."""
+    if f"{name}_rows" in params:
+        return params[f"{name}_rows"][index][..., :config.d_model]
+    return params[f"{name}_embed"][index].astype(config.dtype)
+
+
+def forward_cached(family, params: dict, tokens: jax.Array,
+                   positions: jax.Array, valid: jax.Array,
+                   k_pool: jax.Array, v_pool: jax.Array,
+                   block_tables: jax.Array, ctx_lens: jax.Array, config,
+                   moe_load=None):
+    """Cached (incremental) trunk for autoregressive decode/prefill.
+
+    tokens [B, T] is a SLICE of each lane's sequence at absolute
+    `positions` [B, T] (per-lane offsets: lanes decode at different
+    depths); K/V for the slice are written into the paged pools
+    [n_layers, NB, BS, W] (inference/kv_cache.py's stored layout, rows
+    of n_kv_heads x head_dim) and attention covers each lane's whole block
+    table.  The pools ride the layer loop as its carry, whole: a layer
+    writes its rows and reads its blocks by index, nothing slices a layer
+    out or stacks it back.  `valid` masks padding lanes/overhang (their
+    cache writes are dropped).  Returns (x [B, T, D], k_pool, v_pool): the
+    lm head is applied by the caller on the positions it needs, so a
+    prefill chunk never materializes [B, T, V].
+
+    With `moe_load` (int32 [n_experts + 2], an expert configuration's
+    running counters: assignments per expert, then experts hit summed
+    over (layer, step) pairs, then the count of those pairs) it is carried
+    through the layer loop too and returned fourth: the load stays on the
+    device until somebody asks."""
+    c, spec = config, family(config)
+    if not spec.ffn.serves:
+        raise NotImplementedError(
+            "this feed-forward has no path over a paged KV cache (the "
+            "Switch layer's capacity is a whole batch's)")
+    if spec.rope_theta is None:
+        pos = jnp.clip(positions, 0, c.max_seq_len - 1)
+        x = _embed(params, "tok", tokens, c) + _embed(params, "pos", pos, c)
+    else:
+        x = _embed(params, "tok", tokens, c)
+    scanned, whole = _layer_stack(params["blocks"], c.n_layers,
+                                  spec.ffn.whole)
+
+    def body(carry, layer):
+        x, k_pool, v_pool, seen = carry
+        p, i = layer
+        x, k_pool, v_pool, load = _block_cached(
+            x, k_pool, v_pool, {**p, **whole, "layer": i}, spec, c,
+            block_tables, positions, valid, ctx_lens)
+        if seen is not None:
+            seen = seen + jnp.concatenate([
+                load, jnp.sum(load > 0, dtype=jnp.int32)[None],
+                jnp.ones((1,), jnp.int32)])
+        return (x, k_pool, v_pool, seen), None
+
+    (x, k_pool, v_pool, seen), _ = jax.lax.scan(
+        body, (x, k_pool, v_pool, moe_load), scanned,
+        unroll=min(c.scan_unroll, c.n_layers))
+    x = _norm(spec, x, params, spec.final_norm)
+    return (x, k_pool, v_pool) if seen is None else (x, k_pool, v_pool, seen)
+
+
+# --------------------------------------------------------------------------
+# Parameters on a mesh, and the train step
+# --------------------------------------------------------------------------
+
+def shard_params(family, params: dict, mesh, config, rules=None) -> dict:
+    return jax.device_put(params, tree_shardings(
+        mesh, family(config).param_specs(config), rules))
+
+
+def num_params(family, config) -> int:
+    shapes = jax.eval_shape(partial(family(config).init_params, config),
+                            jax.random.key(0))
+    return sum(int(np.prod(x.shape)) for x in jax.tree.leaves(shapes))
+
+
+def make_train_step(family, config, optimizer, mesh=None):
+    """Returns (init_state, train_step): jittable train_step with
+    donation; under a mesh, params AND optimizer state are sharded
+    (ZeRO-3: Adam moments inherit each param's sharding via GSPMD
+    propagation through jit(optimizer.init)) and XLA inserts the
+    collectives."""
+    import optax
+
+    spec = family(config)
+
+    def init_state(key):
+        # One compiled program each, not one dispatch per op (building a
+        # gpt2-small engine op by op took 55 s on a v5e chip); under a
+        # mesh the params are born sharded, never whole on one device.
+        shardings = None
+        if mesh is not None:
+            from ray_tpu.parallel.sharding import shard_opt_state
+            shardings = tree_shardings(mesh, spec.param_specs(config))
+        params = jax.jit(spec.init_params, static_argnums=0,
+                         out_shardings=shardings)(config, key)
+        opt_state = jax.jit(optimizer.init)(params)
+        if mesh is not None:
+            opt_state = shard_opt_state(opt_state, params, shardings, mesh)
+        return {"params": params, "opt_state": opt_state,
+                "step": jnp.zeros((), jnp.int32)}
+
+    def train_step(state, batch):
+        loss, grads = jax.value_and_grad(partial(loss_fn, family))(
+            state["params"], batch, config, mesh)
+        updates, opt_state = optimizer.update(grads, state["opt_state"],
+                                              state["params"])
+        params = optax.apply_updates(state["params"], updates)
+        return ({"params": params, "opt_state": opt_state,
+                 "step": state["step"] + 1},
+                {"loss": loss})
+
+    return init_state, train_step
+
+
+def bind(family) -> types.SimpleNamespace:
+    """The decoder's public functions with `family` (config -> Spec)
+    bound: what a family module exports, and what the engine takes as its
+    `model` (with the family's own `init_params`, or given `params`)."""
+    return types.SimpleNamespace(spec=family, **{
+        f.__name__: partial(f, family) for f in (
+            forward_trunk, forward, lm_head, forward_cached, loss_fn,
+            serving_params, shard_params, num_params, make_train_step)})

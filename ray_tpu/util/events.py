@@ -118,7 +118,7 @@ def record(plane: str, kind: str,
            trace: Optional[Tuple[str, str]] = None, **payload) -> None:
     """Append one event.  The disabled fast path is a global read; the
     enabled fast path is a dict build + ring append (< 5 µs, see
-    `events_append` in MICROBENCH.json)."""
+    `events_append` of scripts/microbench.py)."""
     r = _recorder
     if r is None:
         if _initialized:

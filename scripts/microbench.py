@@ -558,7 +558,7 @@ def main():
     # them by reference; one chunk prefills) vs a never-seen prompt
     # (every chunk prefills).  The hit/miss ratio is the FLOP savings
     # prefix sharing buys on shared-system-prompt traffic — see
-    # bench_prefix.py for the TTFT view at serving scale.
+    # the serve cells of BENCHMARK.json for the TTFT view at serving scale.
     import itertools
     uid = itertools.count(1)
 

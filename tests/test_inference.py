@@ -6,6 +6,7 @@ continuous-batching lane admission and pool-exhaustion FIFO, in-step
 sampling determinism, and end-to-end streaming generation through
 serve."""
 
+import functools
 import threading
 import time
 
@@ -16,7 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.inference import BlockAllocator, InferenceEngine, PagedKVCache
-from ray_tpu.models import gpt, llama
+from ray_tpu.models import decoder, gpt, llama
 from ray_tpu.ops import paged_attention_reference, paged_decode_attention, \
     paged_kv_update
 from ray_tpu.ops.attention import pack_kv_rows, unpack_kv_rows
@@ -317,6 +318,106 @@ def test_cached_logits_match_full_forward(family):
     for pos, logits in got.items():
         np.testing.assert_allclose(logits, full[pos], atol=2e-4, rtol=2e-4,
                                    err_msg=f"{family} position {pos}")
+
+
+# ---------------------------------------------------------------------------
+# A family is a spec of the one decoder (models/decoder.py)
+# ---------------------------------------------------------------------------
+
+def _layernorm_rope_gelu():
+    """GPT's block (LayerNorm with bias, GELU, tied head) under RoPE: its
+    parameter format less the position table."""
+    def init_params(config, key):
+        params = gpt.init_params(config, key)
+        del params["pos_embed"]
+        return params
+
+    def param_specs(config):
+        specs = gpt.param_specs(config)
+        del specs["pos_embed"]
+        return specs
+
+    def spec(config):
+        return decoder.Spec(
+            norm=decoder.layernorm, attn_norm=("ln1_scale", "ln1_bias"),
+            mlp_norm=("ln2_scale", "ln2_bias"),
+            final_norm=("final_ln_scale", "final_ln_bias"),
+            ffn=decoder.GELU, rope_theta=10000.0, tied_head=True,
+            init_params=init_params, param_specs=param_specs)
+
+    return decoder.bind(spec), gpt.CONFIGS["nano"], init_params
+
+
+def _rmsnorm_table_swiglu_gqa():
+    """Llama's block (RMSNorm, SwiGLU, 4 heads over 2 kv heads, untied
+    head) under a learned position table: its parameter format plus one."""
+    def init_params(config, key):
+        key, table = jax.random.split(key)
+        return {**llama.init_params(config, key),
+                "pos_embed": jax.random.normal(
+                    table, (config.max_seq_len, config.d_model)) * 0.01}
+
+    def param_specs(config):
+        return {**llama.param_specs(config), "pos_embed": (None, None)}
+
+    def spec(config):
+        return decoder.Spec(
+            norm=functools.partial(decoder.rmsnorm, eps=config.norm_eps),
+            attn_norm=("attn_norm",), mlp_norm=("mlp_norm",),
+            final_norm=("final_norm",), ffn=decoder.SWIGLU,
+            init_params=init_params, param_specs=param_specs)
+
+    return decoder.bind(spec), llama.CONFIGS["llama-tiny"], init_params
+
+
+_FAMILIES = {
+    "layernorm_rope_gelu": _layernorm_rope_gelu,
+    "rmsnorm_table_swiglu_gqa": _rmsnorm_table_swiglu_gqa,
+    "gpt": lambda: (gpt, gpt.CONFIGS["nano"], gpt.init_params),
+    "llama": lambda: (llama, llama.CONFIGS["llama-tiny"], llama.init_params),
+}
+
+
+@pytest.mark.parametrize("family", list(_FAMILIES))
+def test_a_spec_of_the_decoder_is_a_family_the_engine_serves_and_a_step_trains(
+        family):
+    """The seam is one: a spec put together here from the decoder's parts,
+    in a combination no shipped family has, is served by the engine
+    (chunked prefill, then decode over the paged cache) token for token as
+    its own full forward continues the prompt, and one train step lowers
+    its loss.  The shipped families are two more such specs."""
+    import optax
+    model, config, init_params = _FAMILIES[family]()
+    params = init_params(config, jax.random.key(2))
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, config.vocab_size, size=n).tolist()
+               for n in (21, 5)]
+    new = 10
+
+    engine = InferenceEngine(model, config, params, auto_start=False,
+                             max_lanes=2, block_size=8, prefill_chunk=8,
+                             max_seq_len=64)
+    handles = [engine.submit(p, new) for p in prompts]
+    while engine.step():
+        pass
+
+    for prompt, handle in zip(prompts, handles):
+        # Causal, so one forward over prompt + continuation says what a
+        # greedy loop of full forwards would have emitted at each position.
+        emitted = handle.tokens()
+        logits, _ = decoder.forward(
+            model.spec, params, jnp.asarray([prompt + emitted], jnp.int32),
+            config)
+        want = jnp.argmax(logits[0, len(prompt) - 1:-1], axis=-1)
+        assert len(emitted) == new and emitted == want.tolist(), family
+
+    init_state, train_step = model.make_train_step(config, optax.adam(1e-2))
+    step = jax.jit(train_step)
+    batch = {"tokens": jnp.asarray(
+        rng.integers(0, config.vocab_size, size=(4, 32)), jnp.int32)}
+    state, first = step(init_state(jax.random.key(3)), batch)
+    _, second = step(state, batch)
+    assert float(second["loss"]) < float(first["loss"])
 
 
 def test_export_import_round_trip_keeps_the_wire_format():
@@ -651,20 +752,28 @@ def test_serving_params_of_a_tree_held_in_the_activation_dtype_is_the_tree(
 
 
 def test_a_float32_llama_under_bf16_activations_is_served_like_gpt2xl():
-    """The dense llama presets hold float32 parameters: the same treatment,
-    by the leaf's dtype alone; norms stay float32 and the same arrays."""
+    """The dense llama presets hold float32 parameters: the same treatment
+    (one `serving_params`, models/decoder.py), by the leaf's dtype and shape
+    alone; norms stay float32 and the same arrays.  Rows of 64 get the
+    stored forms gpt2-xl's rows of 1600 get; the untied head has no use for
+    the token table beside its rows."""
     import dataclasses
     cfg = dataclasses.replace(llama.CONFIGS["llama-tiny"],
                               dtype=jnp.bfloat16)
     params = llama.init_params(cfg, jax.random.key(0))
     served = llama.serving_params(params, cfg)
-    for name in ("wq", "wk", "wv", "wo", "w_gate", "w_up", "w_down"):
+    for name in ("wq", "wk", "wv", "wo", "w_gate", "w_up"):
         np.testing.assert_array_equal(
             served["blocks"][name],
             params["blocks"][name].astype(jnp.bfloat16))
-    for name in ("tok_embed", "lm_head"):
-        np.testing.assert_array_equal(served[name],
-                                      params[name].astype(jnp.bfloat16))
+    assert "w_down" not in served["blocks"] and "tok_embed" not in served
+    np.testing.assert_array_equal(
+        served["blocks"]["w_down_t"],
+        jnp.swapaxes(params["blocks"]["w_down"], 1, 2).astype(jnp.bfloat16))
+    np.testing.assert_array_equal(
+        served["tok_rows"][:, :64], params["tok_embed"].astype(jnp.bfloat16))
+    np.testing.assert_array_equal(served["lm_head"],
+                                  params["lm_head"].astype(jnp.bfloat16))
     for name in ("attn_norm", "mlp_norm"):
         assert served["blocks"][name] is params["blocks"][name]
     assert served["final_norm"] is params["final_norm"]
