@@ -37,7 +37,20 @@ and seeded sampling alike.
 
 The engine is host-driven: block allocation, admission and stream
 fan-out are Python; the model math (sampling included) is one jax.jit'ed
-call per dispatched population.  The KV pools are donated on TPU and
+call per dispatched population.  The scheduler runs one step AHEAD of its
+own results: an iteration admits, builds and dispatches step t+1 while
+the device still runs step t, and only then fetches and commits step t,
+so the host's work happens under a device program and not between two.
+Step t+1 is built from what step t will have left, which the host knows
+by counting (lengths, positions, sampling counters, a finish by length);
+the one thing it does not know, each lane's sampled token, stays on the
+device in an int32 array every step takes and hands back.  What only the
+fetch can tell (an `eos`) finds one token computed too many: its row is
+discarded at commit.  A draft proposer needs the token on the host, so a
+speculative engine runs the same loop at depth 0: it fetches each step
+in the iteration that dispatched it (`InferenceEngine.step`).
+
+The KV pools are donated on TPU and
 ride the step's layer loop whole: a step writes the blocks its new
 tokens fall in and reads the blocks it attends over in the engine's one
 buffer
@@ -164,6 +177,12 @@ class _Request:
     last_emit: float = 0.0             # wall time of the previous token
     fed: int = 0            # prompt tokens in the cache (prefilled OR reused)
     produced: int = 0
+    # Dispatched and not yet committed (the scheduler runs a step ahead of
+    # its results): positions the steps in flight write for this request,
+    # and tokens they sample.  `fed`, `produced`, `emitted`, `last_token`
+    # and the cache's `seq_lens` are the committed side.
+    ahead_len: int = 0
+    ahead_new: int = 0
     # Open engine spans for TRACED requests only: the prefill span
     # (submit -> first token) until produced==1, then ONE decode span
     # (first token -> finish); under the prefill span its child, the
@@ -189,6 +208,17 @@ class _Request:
     @property
     def prefilling(self) -> bool:
         return self.fed < len(self.prompt)
+
+    @property
+    def next_fed(self) -> int:
+        """`fed` as the step in flight will leave it."""
+        return self.fed + self.ahead_len if self.prefilling else self.fed
+
+    def samples(self, fed: int, chunk: int) -> bool:
+        """Whether the step that takes this request from `fed` prompt
+        tokens over `chunk` more positions samples a token for it: every
+        decode step does, and the prefill chunk that ends the prompt."""
+        return fed + chunk >= len(self.prompt) and not self.prefill_only
 
 
 class GenerationHandle:
@@ -371,6 +401,14 @@ class InferenceEngine:
         self.policy_version = 0
         self._lanes: List[Optional[_Request]] = [None] * max_lanes
         self._waiting: "collections.deque[_Request]" = collections.deque()
+        # The last sampled token of every lane, on the device: every step
+        # takes it and hands it back as the tokens it sampled, so the step
+        # after reads its decode lanes' input where the device left it.
+        self._last_tok = jnp.zeros((max_lanes,), jnp.int32)
+        # The step dispatched by the last iteration and not yet fetched
+        # (one entry per population), and how often the loop ran ahead.
+        self._flight: list = []
+        self._ahead = {"steps": 0, "sync_steps": 0, "overrun_tokens": 0}
         self._rid = itertools.count(1)
         self._step_fns: Dict = {}
         self._step_impls: Dict = {}   # un-jitted twins (shape introspection)
@@ -481,9 +519,12 @@ class InferenceEngine:
         """Swap the model weights IN PLACE between scheduler steps.
 
         The jitted step reads the served tree afresh at every dispatch,
-        so the swap is a boundary between steps: in-flight lanes keep
-        their KV state and continue generating under the NEW weights at
-        the next dispatch — no lane is dropped, no request restarted.
+        so the swap is a boundary between dispatches: the step in flight
+        finishes on the weights it was launched with (its tokens are
+        committed after the swap, and carry the log-probs of the weights
+        that sampled them); in-flight lanes keep their KV state and
+        continue generating under the NEW weights at the next dispatch
+        — no lane is dropped, no request restarted.
         The new weights are prepared (`_prepare`) before the lock is
         taken, while the scheduler keeps stepping on the old ones.
         (The actor/learner RL path publishes learner weights through
@@ -661,6 +702,12 @@ class InferenceEngine:
             "phase_s": dict(self._phase_s),
             "admitted": self._admitted,
             "queue_wait_s": self._queue_wait_s,
+            # Of those steps: `steps` dispatched while the step before was
+            # still unfetched, `sync_steps` could not (a proposer drafts
+            # from the fetched token; nothing was in flight; nothing was
+            # left to dispatch).  `overrun_tokens`: sampled for a request
+            # that had ended by the time they were fetched, and discarded.
+            "ahead": dict(self._ahead),
             # This process's XLA compiles and persistent-cache loads.
             "compile": compile_cache.counters(),
             # Preparations of the served weights (one at load, one per
@@ -730,6 +777,7 @@ class InferenceEngine:
         while True:
             with self._work:
                 while (not self._stopped and not self._waiting
+                       and not self._flight
                        and all(r is None for r in self._lanes)):
                     self._work.wait()
                 if self._stopped:
@@ -818,7 +866,20 @@ class InferenceEngine:
         return tuple(out)
 
     def step(self) -> bool:
-        """One scheduler iteration: admit, then advance every live lane.
+        """One scheduler iteration, one step ahead of its own results:
+        admit, build and dispatch the next step while the device still runs
+        the step the iteration before dispatched, and only then fetch and
+        commit that older step.  The device so has the next program queued
+        behind the one it runs, and the host's work happens under a device
+        program instead of between two.  What the next step needs of the one
+        in flight the host knows by counting (positions, context lengths,
+        sampling counters, a block boundary, the end of a prompt, a finish by
+        length: `_Request.ahead_len`, `ahead_new`), all but the sampled
+        token, and that stays on the device (`_last_tok`).  A draft proposer
+        does need the sampled token on the host, so an engine that has one
+        fetches a step in the iteration that dispatched it: the same loop
+        at depth 0, nothing in flight between iterations.
+
         Decode lanes and prefilling lanes dispatch as SEPARATE jitted
         steps (T=1 and T=prefill_chunk) so neither population pays the
         other's FLOP shape.  When speculation is on and any decode lane
@@ -828,25 +889,36 @@ class InferenceEngine:
         ride along at chunk=1, so mixed speculative/plain lanes share
         the step, and adaptive-k backoff shrinks the verify FLOPs it
         pays for instead of padding to the configured maximum.
-        Returns False when fully idle.
 
-        The step is five flat phases (`_PHASES`), none inside another, so
-        that an idle gap of the device in a profiler trace carries the name
-        of what the host was doing; a population runs build_batch,
-        dispatch and fetch once each, so a mixed step has them twice.  One
-        `engine/step` ring record at the end holds the step's durations."""
+        Returns False when fully idle: no lane live and nothing in flight,
+        so a caller that steps until then has seen every result.  What only
+        the fetch can tell (an `eos`; and a cancel, a deadline or a shutdown
+        may come at any time) finds the next step already dispatched with
+        the ended request in it: `_commit` discards that row.
+
+        An iteration is five flat phases (`_PHASES`), none inside another,
+        so that an idle gap of the device in a profiler trace carries the
+        name of what the host was doing; `dispatch` runs once per
+        population, so a mixed step has it twice.  One `engine/step` ring
+        record at the end holds the iteration's durations: `wall_ms` its
+        period, `fetch_ms` the time blocked on the older step, `ahead`
+        whether it dispatched with that step still unfetched."""
         took = dict.fromkeys(_PHASES, 0.0)         # seconds
+        older = self._flight
         with contextlib.ExitStack() as locked:
             with spans.phase("engine", "admit") as ph:
                 locked.enter_context(self._lock)   # the wait is admission's
                 self._expire_deadlines()
                 self._admit()
+                # A lane whose request the step in flight ends sits out.
                 live = [(i, r) for i, r in enumerate(self._lanes)
-                        if r is not None]
-                if not live:
+                        if r is not None and not self._ends_in_flight(i, r)]
+                if not live and not older:
                     return False
-                decode = [(i, r) for i, r in live if not r.prefilling]
-                prefill = [(i, r) for i, r in live if r.prefilling]
+                decode = [(i, r) for i, r in live
+                          if r.next_fed == len(r.prompt)]
+                prefill = [(i, r) for i, r in live
+                           if r.next_fed < len(r.prompt)]
                 spec = False
                 if decode and self._proposer is not None:
                     dtok = spans.begin("engine", "spec_draft")
@@ -864,50 +936,61 @@ class InferenceEngine:
                 if decode:
                     t = (1 + max(len(r.draft) for _, r in decode)
                          if spec else 1)
-                    plans.append((spec, decode)
-                                 + self._build_batch(decode, t))
+                    plans.append(self._plan(spec, decode, t))
                 if prefill:
-                    plans.append((False, prefill) + self._build_batch(
-                        prefill, self.prefill_chunk))
+                    plans.append(self._plan(False, prefill,
+                                            self.prefill_chunk))
             took["build_batch"] = ph.seconds
-        done = []
-        for spec, lanes, batch, chunks in plans:
+        newer = []
+        for spec, lanes, chunks, news, batch in plans:
             vtok = spans.begin("engine", "spec_verify") if spec else None
             with spans.phase("engine", "dispatch") as ph:
                 next_tok, lps = self._run_step(batch, spec)
             took["dispatch"] += ph.seconds
-            with spans.phase("engine", "fetch") as ph:
-                # The host blocks here until the device has finished the
-                # step, then copies one int32 per lane back.
+            newer.append((spec, vtok, lanes, chunks, news, next_tok, lps))
+        # The loop's depth.  A proposer drafts from the token this step
+        # samples: its engine fetches what it has just dispatched.  Any
+        # other leaves that in flight and fetches the step before it.
+        ahead = int(bool(newer and older))
+        keep = bool(newer) and self._proposer is None
+        retire = older if keep else older + newer
+        self._flight = newer if keep else []
+        done = []
+        with spans.phase("engine", "fetch") as ph:
+            # The host blocks here until the device has finished the older
+            # step; the copy back of one int32 per lane was started when
+            # that step was dispatched.
+            for spec, vtok, lanes, chunks, news, next_tok, lps in retire:
                 toks = np.asarray(next_tok)
                 if lps is not None:
                     lps = np.asarray(lps)
-            took["fetch"] += ph.seconds
-            if toks.ndim == 1:      # plain/prefill: one token per lane
-                toks = toks[:, None]
-            if lps is not None and lps.ndim == 1:
-                lps = lps[:, None]
-            spans.end(vtok, lanes=len(lanes))
-            if spec:
-                self._spec_stats["steps"] += 1
-                _metrics()["spec_steps"].inc()
-            done.append((lanes, chunks, toks, lps))
+                if toks.ndim == 1:  # plain/prefill: one token per lane
+                    toks = toks[:, None]
+                if lps is not None and lps.ndim == 1:
+                    lps = lps[:, None]
+                spans.end(vtok, lanes=len(lanes))
+                if spec:
+                    self._spec_stats["steps"] += 1
+                    _metrics()["spec_steps"].inc()
+                done.append((lanes, chunks, news, toks, lps))
+        took["fetch"] = ph.seconds
         with contextlib.ExitStack() as locked:
             with spans.phase("engine", "commit") as ph:
-                # Let go of the step's device arrays (nine uploads and the
+                # Let go of the steps' device arrays (nine uploads and the
                 # sampled tokens per population) here, inside a phase: left
                 # to the return, their release and what the runtime then
                 # does took 1.4 ms a step on a v5e, between two steps,
                 # under no phase's name (PERF.md 6, PR 23).
-                del plans, batch, next_tok
+                plans = batch = older = newer = retire = next_tok = lps = None
                 locked.enter_context(self._work)
-                for lanes, chunks, toks, lps in done:
-                    self._commit(lanes, chunks, toks, lps)
+                for lanes, chunks, news, toks, lps in done:
+                    self._commit(lanes, chunks, news, toks, lps)
                 self._work.notify()
             took["commit"] = ph.seconds
             wall = ph.t0 + ph.seconds - t_start
             self._steps += 1
             self._step_wall_s += wall
+            self._ahead["steps" if ahead else "sync_steps"] += 1
             for name in _PHASES:
                 self._phase_s[name] += took[name]
             events.record(
@@ -917,12 +1000,45 @@ class InferenceEngine:
                 build_ms=took["build_batch"] * 1e3,
                 dispatch_ms=took["dispatch"] * 1e3,
                 fetch_ms=took["fetch"] * 1e3,
-                commit_ms=took["commit"] * 1e3)
+                commit_ms=took["commit"] * 1e3, ahead=ahead)
         return True
+
+    def _plan(self, spec: bool, lanes, t: int) -> tuple:
+        """One population's step of `t` positions, built from what the step
+        in flight will have left, and from here on in flight itself: per
+        lane the positions it writes (`chunks`) and whether it samples a
+        token (`news`), which `_commit` takes off again."""
+        batch, chunks = self._build_batch(lanes, t)
+        news = {}
+        for lane, req in lanes:
+            news[lane] = int(req.samples(req.next_fed, chunks[lane]))
+            req.ahead_len += chunks[lane]
+            req.ahead_new += news[lane]
+        return spec, lanes, chunks, news, batch
+
+    def _ends_in_flight(self, lane: int, req: _Request) -> bool:
+        """Whether the step in flight ends `req` by a count the host has
+        without its result: a token budget, the cache's longest sequence, a
+        prefill-only prompt fed whole.  Its lane then sits the next step
+        out, and is free for another request when that result is
+        committed."""
+        if req.prefill_only:
+            return req.next_fed == len(req.prompt)
+        return req.ahead_new > 0 and (
+            req.produced + req.ahead_new >= req.max_new_tokens
+            or int(self.cache.seq_lens[lane]) + req.ahead_len
+            >= self.cache.max_seq_len)
 
     def _build_batch(self, live, t):
         """Host-side assembly of the fixed-shape lane arrays for one
-        population (lanes not in `live` ride along fully masked)."""
+        population (lanes not in `live` ride along fully masked), from the
+        lengths and counts the step in flight will have left: committed
+        plus `ahead_len` / `ahead_new`.  A decode lane whose last token
+        that step is still sampling is told to read it on the device
+        (`tokens` -1: the step takes it from `_last_tok`); `counters` is -1
+        where the lane samples nothing in this step (masked, or a prefill
+        chunk short of its prompt's end), and such a lane's entry of
+        `_last_tok` stays what it was."""
         n = self.max_lanes
         tokens = np.zeros((n, t), np.int32)
         positions = np.zeros((n, t), np.int32)
@@ -931,27 +1047,32 @@ class InferenceEngine:
         gather = np.zeros((n,), np.int32)
         temps = np.zeros((n,), np.float32)
         seeds = np.zeros((n,), np.uint32)
-        counters = np.zeros((n,), np.int32)
+        counters = np.full((n,), -1, np.int32)
         chunks = {}
         sample = False
         for lane, req in live:
-            start = int(self.cache.seq_lens[lane])
-            if req.prefilling:
-                chunk = min(t, len(req.prompt) - req.fed)
-                tokens[lane, :chunk] = req.prompt[req.fed:req.fed + chunk]
+            start = int(self.cache.seq_lens[lane]) + req.ahead_len
+            fed = req.next_fed
+            if fed < len(req.prompt):
+                chunk = min(t, len(req.prompt) - fed)
+                tokens[lane, :chunk] = req.prompt[fed:fed + chunk]
             else:
                 # Speculative lanes feed [last_token, d_1 .. d_k]; the
                 # verify step samples every position.  Draftless lanes
                 # are the plain chunk=1 decode, masked alongside.
                 chunk = 1 + len(req.draft)
-                tokens[lane, :chunk] = (req.last_token,) + tuple(req.draft)
+                tokens[lane, :chunk] = (
+                    -1 if req.ahead_new else req.last_token,) + tuple(
+                        req.draft)
             positions[lane] = start + np.arange(t)
             valid[lane, :chunk] = True
             ctx_lens[lane] = start + chunk
             gather[lane] = chunk - 1
             temps[lane] = req.temperature
             seeds[lane] = req.seed & 0xFFFFFFFF
-            counters[lane] = req.produced + req.sample_offset
+            if req.samples(fed, chunk):
+                counters[lane] = (req.produced + req.ahead_new
+                                  + req.sample_offset)
             sample = sample or req.temperature > 0
             chunks[lane] = chunk
             # Table entries must exist before the step writes K/V.
@@ -969,20 +1090,33 @@ class InferenceEngine:
         key = (t, sample, spec)
         fn = self._step_fns.get(key)
         first = fn is None
-        # An expert configuration's step takes its load counters last and
-        # hands them back last (not donated: stats() may be reading them).
+        # After its nine lane arrays a step takes the arrays that ride
+        # from step to step on the device: the lanes' last sampled tokens,
+        # and last an expert configuration's load counters (handed back
+        # last; not donated: stats() may be reading them).
         moe = () if self._moe_load is None else (self._moe_load,)
+        carried = (self._last_tok, *moe)
         if first:
             t0 = time.perf_counter()
             fn = self._step_fns[key] = self._make_step_fn(sample, spec)
             self._step_avals[key] = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                (self._served, self.cache.k, self.cache.v, *args, *moe))
-        out = list(fn(self._served, self.cache.k, self.cache.v, *args, *moe))
+                (self._served, self.cache.k, self.cache.v, *args, *carried))
+        out = list(fn(self._served, self.cache.k, self.cache.v, *args,
+                      *carried))
         if moe:
             self._moe_load = out.pop()
         next_tok, *logp, k, v = out
         logp = logp[0] if logp else None
+        if not spec:
+            # What the step sampled, over what it was given for the lanes
+            # that sampled nothing: the next step's `_last_tok`, and what
+            # the host fetches (the copy back starts now, ahead of the
+            # programs dispatched after this one).
+            self._last_tok = next_tok
+        next_tok.copy_to_host_async()
+        if logp is not None:
+            logp.copy_to_host_async()
         if first:
             # The first call of a shape returns once it has compiled (the
             # dispatch itself is asynchronous): its wall is compile time.
@@ -1005,15 +1139,29 @@ class InferenceEngine:
                 jnp.where(temps_b > 0, z / jnp.maximum(temps_b, 1e-6), z))
             return jnp.take_along_axis(lp, out[..., None], axis=-1)[..., 0]
 
+        n_moe = 1 if config.n_experts else 0
+
         def step(params, k, v, tokens, positions, valid, tables, ctx_lens,
-                 gather, temps, seeds, counters, *moe_load):
-            # An expert configuration's step takes its load counters last
-            # and returns them last, summed up on the device.
+                 gather, temps, seeds, counters, *carried):
+            # `carried`: the lanes' last sampled tokens, then (an expert
+            # configuration's step takes them last and returns them last,
+            # summed up on the device) the load counters.  A caller that
+            # lowers the step for its shapes alone may leave the tokens
+            # out: the same program less two selects.
+            moe_load = carried[len(carried) - n_moe:]
+            last_tok = carried[0] if len(carried) > n_moe else None
+            if last_tok is not None:
+                # A decode lane whose token the step before sampled reads
+                # it where that step left it (`_build_batch`'s -1).
+                tokens = jnp.where(tokens < 0, last_tok[:, None], tokens)
             x, k, v, *moe_load = model.forward_cached(
                 params, tokens, positions, valid, k, v, tables, ctx_lens,
                 config, *moe_load)
-            return (*sample_tokens(params, x, gather, temps, seeds,
-                                   counters), k, v, *moe_load)
+            next_tok, *logp = sample_tokens(params, x, gather, temps, seeds,
+                                            counters)
+            if last_tok is not None and not spec:
+                next_tok = jnp.where(counters >= 0, next_tok, last_tok)
+            return (next_tok, *logp, k, v, *moe_load)
 
         def sample_tokens(params, x, gather, temps, seeds, counters):
             """(next tokens,) or, capturing, (next tokens, their logps)."""
@@ -1083,18 +1231,30 @@ class InferenceEngine:
         donate = () if self.backend == "cpu" else (1, 2)
         return jax.jit(step, donate_argnums=donate)
 
-    def _commit(self, live, chunks, toks, lps=None):
+    def _commit(self, live, chunks, news, toks, lps=None):
         """Apply one dispatch's results: advance prefill cursors, seal
         newly-full blocks into the prefix index, stream sampled tokens
         (a multi-token speculative burst commits ATOMICALLY — one queue
         item), roll back rejected draft blocks, finish + free lanes.
 
         `toks` is [max_lanes, T]: T=1 rows for prefill/plain decode, the
-        per-position verify samples for a speculative dispatch."""
+        per-position verify samples for a speculative dispatch.  `chunks`
+        and `news` are what `_plan` put in flight for each lane.
+
+        The row of a request that ended while the step was in flight (an
+        `eos` in the step before it, cancel(), a deadline, shutdown()) is
+        dropped here: never streamed, never sealed, not counted in
+        `produced`.  Its blocks, one claimed for the overrun position
+        included, went back to the allocator with the lane, and what the
+        step wrote there is harmless as a rejected draft's is
+        (`PagedKVCache.truncate_lane`)."""
         met = _metrics()
         for lane, req in live:
+            req.ahead_len -= chunks[lane]
+            req.ahead_new -= news[lane]
             if self._lanes[lane] is not req:
-                continue  # shutdown()/cancel() cleared the lane mid-step
+                self._ahead["overrun_tokens"] += news[lane]
+                continue
             row = toks[lane]
             draft = req.draft
             req.draft = ()

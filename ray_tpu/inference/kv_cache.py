@@ -760,7 +760,16 @@ class PagedKVCache:
         they are always fresh, exclusively-owned allocations (shared
         prefix blocks live at the front of the table, and the sealed
         boundary never passes the committed length), so decref returns
-        them straight to the free list."""
+        them straight to the free list.
+
+        The same holds for the one position a step dispatched ahead of its
+        predecessor's result writes for a request that result then ended
+        (an `eos`, a cancel, a deadline): it lies at or past the committed
+        length in a block the lane owns alone, claimed by
+        `ensure_capacity` or already there, and `free_lane` returns that
+        block with the others.  The device runs programs in dispatch
+        order, so whoever gets the block next writes a position before it
+        reads it."""
         blocks = self._lane_blocks[lane]
         keep = max(self.blocks_needed(new_len), self._lane_sealed[lane])
         while len(blocks) > keep:

@@ -14,6 +14,7 @@ import jax
 
 from ray_tpu._private import compile_cache
 from ray_tpu.inference import InferenceEngine
+from ray_tpu.inference.speculative import DraftProposer
 from ray_tpu.util import events, tracing
 
 PHASE_FIELDS = ("admit_ms", "build_ms", "dispatch_ms", "fetch_ms",
@@ -93,19 +94,41 @@ def test_an_expert_engine_adds_counters_to_stats_and_nothing_to_a_step():
                                           "finish", "prefix_miss"}
     for e in steps:
         assert set(e["payload"]) == {"decode", "prefill", "waiting",
-                                     "wall_ms", *PHASE_FIELDS}
+                                     "wall_ms", *PHASE_FIELDS, "ahead"}
     moe0, moe1 = s0["moe"], s1["moe"]
     assert moe1["assignments"] - moe0["assignments"] == (
         (19 + len(out) - 1) * cfg.n_experts_per_tok * cfg.n_layers)
     assert len(moe1["expert_load"]) == cfg.n_experts
-    assert moe1["layer_steps"] - moe0["layer_steps"] >= len(steps) * \
+    # (the last iteration dispatches nothing: it fetches the last step)
+    dispatched = [e for e in steps
+                  if e["payload"]["decode"] or e["payload"]["prefill"]]
+    assert len(dispatched) == len(steps) - 1
+    assert moe1["layer_steps"] - moe0["layer_steps"] >= len(dispatched) * \
         cfg.n_layers
     assert 0 < moe1["experts_hit"] - moe0["experts_hit"] <= (
         moe1["layer_steps"] - moe0["layer_steps"]) * cfg.n_experts
 
 
-def test_phases_are_flat_siblings_in_the_profilers_trace(tmp_path):
+def _phases_in_trace(trace_dir):
+    """(start, end, name) of the `engine/` annotations in a profiler
+    trace, in time order, from the one thread that has any."""
     from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    host, = [p for p in ProfileData.from_file(path).planes
+             if p.name == "/host:CPU"]
+    lines = {}
+    for line in host.lines:
+        mine = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
+                for e in line.events if e.name.startswith("engine/")]
+        if mine:
+            lines[line.name] = sorted(mine)
+    assert len(lines) == 1, list(lines)        # the engine thread's line
+    spans, = lines.values()
+    return spans
+
+
+def test_phases_are_flat_siblings_in_the_profilers_trace(tmp_path):
     engine = InferenceEngine("gpt", "nano", max_lanes=2, prefill_chunk=8)
     try:
         engine.generate(list(range(1, 6)), 2)
@@ -119,24 +142,86 @@ def test_phases_are_flat_siblings_in_the_profilers_trace(tmp_path):
             jax.profiler.stop_trace()
     finally:
         engine.shutdown()
-    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile", "*",
-                                   "*.xplane.pb"))
-    host, = [p for p in ProfileData.from_file(path).planes
-             if p.name == "/host:CPU"]
-    lines = {}
-    for line in host.lines:
-        mine = [(e.start_ns, e.start_ns + e.duration_ns, e.name)
-                for e in line.events if e.name.startswith("engine/")]
-        if mine:
-            lines[line.name] = sorted(mine)
-    assert len(lines) == 1, list(lines)        # the engine thread's line
-    spans, = lines.values()
+    spans = _phases_in_trace(tmp_path)
     assert {name for _, _, name in spans} == {f"engine/{p}" for p in PHASES}
     # flat: each phase ends before the next begins, so none encloses another
     for (_, end, name), (start, _, after) in zip(spans, spans[1:]):
         assert end <= start, (name, after)
     # and in the order of a step
     assert [n for _, _, n in spans[:5]] == [f"engine/{p}" for p in PHASES]
+
+
+class _NeverDrafts(DraftProposer):
+    def propose(self, context, k):
+        return []
+
+
+@pytest.mark.parametrize("proposer", [False, True],
+                         ids=["a_step_ahead", "a_proposer_fetches_first"])
+def test_the_next_step_is_dispatched_before_the_last_one_is_fetched(
+        tmp_path, proposer):
+    """N steps of one request, by the phases' own clocks and the tokens
+    committed after each iteration: without a proposer the `dispatch` of
+    step i+1 has begun before the `fetch` that returns step i's tokens
+    ends, `stats()["ahead"]` counts those iterations, and every
+    `engine/step` record says which kind it was.  A proposer needs the
+    token on the host: each iteration fetches what it dispatched.  Either
+    way five flat phases and one ring record an iteration, none a token."""
+    n = 8
+    engine = _engine(**(dict(spec_k=1, draft_proposer=_NeverDrafts())
+                        if proposer else {}))
+    engine.generate(list(range(1, 6)), 2)          # compile both shapes
+    s0, seq = engine.stats(), _last_seq()
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        # a prompt of one chunk: step 1 prefills, steps 2..n decode
+        handle = engine.submit(list(range(1, 6)), n)
+        committed = []
+        while engine.step():
+            committed.append(len(handle._req.emitted))
+    finally:
+        jax.profiler.stop_trace()
+    assert len(handle.tokens()) == n
+    s1 = engine.stats()
+    since = [e for e in events.snapshot(plane="engine") if e["seq"] > seq]
+    assert {e["kind"] for e in since} - {"spec_draft"} <= {
+        "step", "submit", "finish", "prefix_miss", "prefix_hit"}
+    records = [e["payload"] for e in since if e["kind"] == "step"]
+    assert len(records) == len(committed) == s1["steps"] - s0["steps"]
+    assert s1["step_wall_s"] - s0["step_wall_s"] == pytest.approx(
+        sum(r["wall_ms"] for r in records) / 1e3)
+    ahead = {k: s1["ahead"][k] - s0["ahead"][k] for k in s1["ahead"]}
+
+    spans = _phases_in_trace(tmp_path)
+    # (the iteration that only fetches the last step dispatches nothing;
+    # the idle step() that ends the loop looks for admissions and returns)
+    assert [name for _, _, name in spans] == [
+        f"engine/{p}" for p in PHASES] * n + [
+        f"engine/{p}" for p in PHASES if p != "dispatch"] * (not proposer) + [
+        "engine/admit"]
+    for (_, end, name), (start, _, after) in zip(spans, spans[1:]):
+        assert end <= start, (name, after)
+    dispatches = [s for s in spans if s[2] == "engine/dispatch"]
+    fetches = [s for s in spans if s[2] == "engine/fetch"]
+    if proposer:
+        # iteration i dispatches step i and commits its token
+        assert committed == list(range(1, n + 1))
+        assert ahead == {"steps": 0, "sync_steps": n, "overrun_tokens": 0}
+        assert [r["ahead"] for r in records] == [0] * n
+    else:
+        # iteration i dispatches step i, then commits step i-1's token;
+        # one more iteration fetches step n's
+        assert committed == list(range(0, n + 1))
+        for i in range(1, n):           # step i's tokens come back in the
+            fetch_i = fetches[i]        # fetch of iteration i+1, after the
+            assert dispatches[i][0] < fetch_i[1]    # dispatch of step i+1
+            assert dispatches[i][1] <= fetch_i[0]
+        assert ahead == {"steps": n - 1, "sync_steps": 2,
+                         "overrun_tokens": 0}
+        assert [r["ahead"] for r in records] == [0] + [1] * (n - 1) + [0]
+        assert [r["decode"] + r["prefill"] for r in records] == [1] * n + [0]
 
 
 def test_compiles_are_counted_where_jax_reports_them():

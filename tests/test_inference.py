@@ -17,6 +17,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.inference import BlockAllocator, InferenceEngine, PagedKVCache
+from ray_tpu.inference.speculative import DraftProposer
 from ray_tpu.models import decoder, gpt, llama
 from ray_tpu.ops import paged_attention_reference, paged_decode_attention, \
     paged_kv_update
@@ -508,6 +509,103 @@ def test_engine_temperature_sampling_and_eos():
             pass
         assert h.tokens() == greedy[:1]
         assert h.finish_reason == "eos"
+
+
+# ---------------------------------------------------------------------------
+# One step ahead: the same tokens as the engine that fetches before it builds
+# ---------------------------------------------------------------------------
+
+class _NeverDrafts(DraftProposer):
+    """An engine with a proposer fetches a step before it builds the next
+    (the loop's depth 0).  This one proposes nothing, so every step is the
+    plain one: the order of old, through the public surface."""
+
+    def propose(self, context, k):
+        return []
+
+
+_AHEAD_MODELS = {"gpt": ("gpt", "nano"), "llama": ("llama", "llama-tiny"),
+                 "olmoe": ("llama", "olmoe-nano")}
+# name -> (prompt, max_new_tokens); six requests over two lanes
+_AHEAD_REQUESTS = {
+    "short": (list(range(3, 8)), 9),
+    "chunks": (list(range(40, 51)), 6),          # three prefill chunks
+    "eos": ([7, 1, 9], 12),
+    "max_seq_len": (list(range(100, 118)), 40),  # 18 + 40 > 24
+    "cancel": ([5, 9, 2, 6], 12),
+    "deadline": ([8, 8, 3], 12),
+}
+
+
+def _serve_ahead_requests(eng, temperature, eos_id):
+    handles = {
+        name: eng.submit(prompt, max_new_tokens=new, temperature=temperature,
+                         seed=11 + i, deadline_s=3600.0,
+                         eos_id=eos_id if name == "eos" else None)
+        for i, (name, (prompt, new)) in enumerate(_AHEAD_REQUESTS.items())}
+    cancel, deadline = handles["cancel"], handles["deadline"]
+    while eng.step():
+        # Both by what has been streamed, not by the clock or the step
+        # count: at depth 1 the next step is then already in flight.
+        if len(cancel._req.emitted) == 3 and cancel.finish_reason is None:
+            assert cancel.cancel()
+        if len(deadline._req.emitted) == 2:
+            deadline._req.deadline = time.monotonic() - 1.0
+    assert eng.num_active == 0 and eng.num_waiting == 0
+    assert eng.cache.allocator.num_free == eng.cache.allocator.num_blocks
+    return {name: (h.tokens(), h.logps, h.finish_reason)
+            for name, h in handles.items()}
+
+
+@pytest.mark.parametrize("family,mode", [
+    ("gpt", "greedy"), ("gpt", "sampled"), ("gpt", "capture_logp"),
+    ("llama", "greedy"), ("llama", "sampled"), ("llama", "capture_logp"),
+    ("olmoe", "greedy")])
+def test_a_step_ahead_serves_the_tokens_of_the_step_by_step_order(family,
+                                                                  mode):
+    """Requests of staggered prompt and output lengths on fewer lanes than
+    requests, one ending by `eos` mid-stream, one by `max_seq_len`, one
+    cancelled and one past its deadline while a step is in flight: tokens,
+    log-probs and finish reasons are those of an engine that fetches each
+    step before it builds the next.  The token computed past an end is
+    counted and goes nowhere, and every block comes back."""
+    model, config = _AHEAD_MODELS[family]
+    temperature = 0.0 if mode == "greedy" else 0.9
+    kw = dict(max_lanes=2, block_size=4, max_seq_len=24, prefill_chunk=4,
+              auto_start=False, seed=0, capture_logp=mode == "capture_logp")
+    ahead = InferenceEngine(model, config, **kw)
+    by_step = InferenceEngine(model, config, ahead.params, spec_k=1,
+                              draft_proposer=_NeverDrafts(), **kw)
+    # The eos id from a first run's own output: a token its stream had not
+    # shown before, so that it ends there and not earlier.
+    stream = _serve_ahead_requests(by_step, temperature, None)["eos"][0]
+    assert len(stream) == 12
+    cut = next(j for j in (*range(4, 11), 3, 2, 1, 0)
+               if stream[j] not in stream[:j])
+
+    want = _serve_ahead_requests(by_step, temperature, stream[cut])
+    got = _serve_ahead_requests(ahead, temperature, stream[cut])
+    for name in _AHEAD_REQUESTS:
+        assert got[name][0] == want[name][0], name
+        assert got[name][1] == pytest.approx(want[name][1], abs=1e-5), name
+        assert got[name][2] == want[name][2], name
+        assert len(got[name][1]) == (
+            len(got[name][0]) if mode == "capture_logp" else 0)
+    assert {name: reason for name, (_, _, reason) in got.items()} == {
+        "short": "length", "chunks": "length", "eos": "eos",
+        "max_seq_len": "max_seq_len", "cancel": "cancelled",
+        "deadline": "deadline"}
+    assert got["eos"][0] == stream[:cut + 1]          # eos streamed, last
+    assert len(got["max_seq_len"][0]) == 24 - 18 + 1
+    assert len(got["cancel"][0]) == 3 and len(got["deadline"][0]) == 2
+    # One token each was in flight past the eos, the cancel and the
+    # deadline; a finish by count was foreseen, and nothing ran past it.
+    s1, s0 = ahead.stats(), by_step.stats()
+    assert s1["ahead"]["overrun_tokens"] == 3
+    assert s1["ahead"]["steps"] > 0
+    assert s1["ahead"]["steps"] + s1["ahead"]["sync_steps"] == s1["steps"]
+    assert s0["ahead"] == {"steps": 0, "sync_steps": s0["steps"],
+                           "overrun_tokens": 0}
 
 
 # ---------------------------------------------------------------------------

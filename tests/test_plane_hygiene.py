@@ -297,3 +297,40 @@ def test_weights_are_prepared_at_load_and_swap_and_never_in_a_step():
                     src.index("    def _make_step_fn")]
     assert "serving_params" not in step_path and "_prepare" not in step_path
     assert step_path.count("self._served") == 2     # avals, and the call
+
+
+def test_the_engine_has_one_loop_and_no_option_sets_its_depth():
+    """The scheduler runs a step ahead of its results (PERF.md section 6,
+    PR 30) in the one `step()` there is: each phase opened once, `dispatch`
+    before `fetch` before `commit`; one `engine/step` record an iteration,
+    carrying `ahead`; what is in flight kept in one place, and how deep the
+    loop runs decided there from whether a proposer needs the fetched
+    token.  No keyword, flag or environment variable chooses it."""
+    import ast
+    src = (PKG / "inference" / "engine.py").read_text()
+    step_body = src[src.index("    def step(self)"):
+                    src.index("    def _plan(self")]
+    at = [step_body.index(f'spans.phase("engine", "{phase}")')
+          for phase in ("admit", "build_batch", "dispatch", "fetch",
+                        "commit")]
+    assert at == sorted(at)
+    assert all(step_body.count(f'spans.phase("engine", "{phase}")') == 1
+               for phase in ("admit", "build_batch", "dispatch", "fetch",
+                             "commit"))
+    assert src.count('spans.phase("engine"') == 5
+    records = [(pl, k) for _, _, pl, k in _call_sites()
+               if (pl, k) == ("engine", "step")]
+    assert len(records) == 1 and "ahead=ahead" in step_body
+    # the step in flight: set by the loop alone (None at construction)
+    assert src.count("self._flight = ") == 1 == step_body.count(
+        "self._flight = ")
+    assert step_body.count("self._proposer is None") == 1
+    assert "environ" not in src and "getenv" not in src
+    init, = [f for c in ast.parse(src).body
+             if isinstance(c, ast.ClassDef) and c.name == "InferenceEngine"
+             for f in c.body
+             if isinstance(f, ast.FunctionDef) and f.name == "__init__"]
+    assert [a.arg for a in init.args.kwonlyargs] == [
+        "max_lanes", "block_size", "num_blocks", "max_seq_len",
+        "prefill_chunk", "seed", "prefix_cache", "auto_start", "spec_k",
+        "draft_proposer", "spec_adaptive", "kv_tier", "capture_logp"]
