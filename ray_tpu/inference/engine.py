@@ -84,7 +84,8 @@ import numpy as np
 from ray_tpu import models
 from ray_tpu._private import compile_cache
 from ray_tpu._private.accelerators import leased_chips, require_chip_lease
-from ray_tpu.inference.kv_cache import (PagedKVCache, count_pool_copies,
+from ray_tpu.inference.kv_cache import (PagedKVCache, chain_hashes,
+                                        count_pool_copies,
                                         count_weight_bytes_copied)
 from ray_tpu.util import events, spans
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
@@ -204,6 +205,10 @@ class _Request:
     # blocks, then finish WITHOUT sampling — the sealed chain is the
     # product (export_prefix ships it to a decode engine).
     prefill_only: bool = False
+    # The prompt's block chain (`kv_cache.chain_hashes`), made by `submit`
+    # on the caller's thread where the prefix cache is on
+    # (`_head_is_being_sealed`).
+    chain: Optional[List[int]] = None
 
     @property
     def prefilling(self) -> bool:
@@ -302,6 +307,14 @@ class GenerationHandle:
         return list(self._req.logps)
 
 
+def _by_lane(per_row, rows, max_lanes: int):
+    """A compact program's per-row results [rows, T] as [max_lanes, T]."""
+    out = np.zeros((max_lanes,) + per_row.shape[1:], per_row.dtype)
+    held = rows < max_lanes
+    out[rows[held]] = per_row[held]
+    return out
+
+
 def _end_spans(req: _Request, **payload) -> None:
     """Close whatever spans a traced request still has open: the queue
     span inside its prefill span, or its decode span."""
@@ -327,13 +340,23 @@ class InferenceEngine:
     draft length off when its acceptance is low (and grows it back on
     full acceptance) so incompressible streams stop paying rejected
     verify FLOPs.
+
+    `prefill_lanes` < max_lanes makes the T=prefill_chunk program one
+    [prefill_lanes, T] batch of the lanes that prefill (the oldest
+    requests first; further prefilling lanes wait a step), gathered from
+    and scattered to their lanes by index, where by default it computes
+    [max_lanes, T] rows whether a lane prefills or not: with many lanes
+    and a long chunk those rows are most of the program.  Such an engine
+    also has the program at a quarter of T, for steps in which no lane
+    has more than that left to feed (`_prefill_len`).
     """
 
     def __init__(self, model="gpt", config="nano", params=None, *,
                  max_lanes: int = 8, block_size: int = 16,
                  num_blocks: Optional[int] = None,
                  max_seq_len: Optional[int] = None,
-                 prefill_chunk: int = 32, seed: int = 0,
+                 prefill_chunk: int = 32,
+                 prefill_lanes: Optional[int] = None, seed: int = 0,
                  prefix_cache: bool = True, auto_start: bool = True,
                  spec_k: int = 0, draft_proposer="ngram",
                  spec_adaptive: bool = True,
@@ -364,10 +387,12 @@ class InferenceEngine:
         # An expert configuration's load counters live on the device and
         # ride the step (forward_cached's `moe_load`); stats() fetches.
         n_experts = self.config.n_experts
-        self._moe_load = (jnp.zeros((n_experts + 2,), jnp.int32)
+        held = getattr(self.config, "n_experts_held", 0) or n_experts
+        self._moe_load = (jnp.zeros((held + 2,), jnp.int32)
                           if n_experts else None)
         self.max_lanes = max_lanes
         self.prefill_chunk = prefill_chunk
+        self.prefill_lanes = min(prefill_lanes or max_lanes, max_lanes)
         self.seed = seed
         max_seq_len = min(max_seq_len or self.config.max_seq_len,
                           self.config.max_seq_len)
@@ -422,6 +447,14 @@ class InferenceEngine:
         self._phase_s = dict.fromkeys(_PHASES, 0.0)
         self._admitted = 0
         self._queue_wait_s = 0.0
+        # Counted on the host as batches are built (`_build_batch`): the
+        # prefill programs and their rows, the valid tokens of all
+        # programs, and over a latent cache the T=1 steps and the context
+        # tokens they attended over.
+        self._prefill = {"steps": 0, "lanes": 0, "rows": 0, "rows_valid": 0}
+        self._tokens_run = 0
+        self._latent = ({"decode_steps": 0, "ctx_tokens": 0}
+                        if self.cache.kind == "latent" else None)
         self._thread: Optional[threading.Thread] = None
         self._stopped = False
         self._auto = auto_start
@@ -460,7 +493,9 @@ class InferenceEngine:
                        submitted=time.time(),
                        submitted_pc=time.perf_counter(),
                        spec_k=self.spec_k,
-                       prefill_only=prefill_only)
+                       prefill_only=prefill_only,
+                       chain=(chain_hashes(prompt, self.cache.block_size)
+                              if self.cache.prefix_cache_enabled else None))
         events.record("engine", "submit", trace=req.trace, rid=rid,
                       prompt_len=len(prompt), max_new=max_new_tokens)
         if req.trace is not None:
@@ -714,6 +749,12 @@ class InferenceEngine:
             # update_params), their seconds, and the bytes of the tree
             # the step takes beside those of the tree given.
             "weights": dict(self._weights),
+            # The T=prefill_chunk programs dispatched, the lanes that
+            # prefilled in them, the rows they computed and how many of
+            # those held a prompt token.
+            "prefill": dict(self._prefill),
+            **({} if self._latent is None else
+               {"latent": dict(self._latent)}),
             **self._moe_stats(),
         }
 
@@ -721,13 +762,23 @@ class InferenceEngine:
         """An expert configuration's cumulative load, fetched from the
         device here and nowhere else: assignments (token, expert) in all
         and per expert, summed over layers; `experts_hit` summed over the
-        `layer_steps` (layer, step) pairs run so far."""
+        `layer_steps` (layer, step) pairs run so far.  Where the experts
+        held are a share of the router's, those are the share's
+        (`assignments_held`), and `assignments` is what the router made in
+        all: top-k for every valid token and expert layer, counted on the
+        host."""
         if self._moe_load is None:
             return {}
+        c = self.config
         load = np.asarray(self._moe_load).tolist()
-        return {"moe": {"assignments": sum(load[:-2]),
-                        "expert_load": load[:-2],
-                        "experts_hit": load[-2], "layer_steps": load[-1]}}
+        out = {"assignments": sum(load[:-2]), "expert_load": load[:-2],
+               "experts_hit": load[-2], "layer_steps": load[-1]}
+        if len(load) - 2 < c.n_experts:
+            out["assignments_held"] = out["assignments"]
+            out["assignments"] = (
+                self._tokens_run * c.n_experts_per_tok
+                * (c.n_layers - getattr(c, "first_dense_layers", 0)))
+        return {"moe": out}
 
     def compiled_steps(self) -> dict:
         """What XLA built for each step shape dispatched so far: seconds
@@ -747,11 +798,12 @@ class InferenceEngine:
         # _step_compile_s is filled last, so its keys are complete steps
         # even while the scheduler thread is adding a new shape.
         for key, compile_s in list(self._step_compile_s.items()):
-            t, sample, spec = key
+            t, sample, spec, compact = key
             compiled = self._step_fns[key].lower(
                 *self._step_avals[key]).compile()
             name = f"t{t}" + ("_sample" if sample else "") \
-                + ("_spec" if spec else "")
+                + ("_spec" if spec else "") \
+                + (f"_lanes{self.prefill_lanes}" if compact else "")
             text, memory = compiled.as_text(), compiled.memory_analysis()
             out[name] = {
                 "compile_s": round(compile_s, 2),
@@ -812,6 +864,8 @@ class InferenceEngine:
             if self._lanes[lane] is not None or not self._waiting:
                 continue
             req = self._waiting[0]
+            if self._head_is_being_sealed(req):
+                break  # its shared head comes from the cache in a moment
             growth = (self.cache.blocks_needed(self._final_len(req))
                       - self.cache.blocks_needed(len(req.prompt)))
             if not self.cache.can_admit_prefix(
@@ -843,6 +897,25 @@ class InferenceEngine:
             events.record("engine", "blocks_evicted",
                           n=evictions - self._evictions_reported)
             self._evictions_reported = evictions
+
+    def _head_is_being_sealed(self, req: _Request) -> bool:
+        """Whether a lane that still prefills is writing blocks of `req`'s
+        own prompt head that the prefix index does not hold yet: `req`
+        then waits for them instead of prefilling a copy of its own beside
+        that lane (of N requests that arrive together with one 16k
+        document in front, one prefills it and N - 1 take it from the
+        cache).  The lane it waits for always advances, and once that has
+        sealed the shared blocks, or has gone, `req` is admitted.  Heads
+        are compared by the block chains `submit` made; the index is asked
+        only where some prefilling lane shares one."""
+        if req.chain is None:               # no prefix cache
+            return False
+        shared = max((sum(1 for _ in itertools.takewhile(
+            lambda ab: ab[0] == ab[1], zip(req.chain, other.chain)))
+            for other in self._lanes
+            if other is not None and other.prefilling), default=0)
+        return shared > 0 and shared > len(
+            self.cache.match_prefix(req.prompt))
 
     def _propose(self, lane: int, req: _Request) -> tuple:
         """Draft for one decode lane: ask the proposer for up to the
@@ -919,6 +992,10 @@ class InferenceEngine:
                           if r.next_fed == len(r.prompt)]
                 prefill = [(i, r) for i, r in live
                            if r.next_fed < len(r.prompt)]
+                if len(prefill) > self.prefill_lanes:
+                    # The oldest requests first; the others wait a step.
+                    prefill = sorted(prefill, key=lambda ir: ir[1].rid)[
+                        :self.prefill_lanes]
                 spec = False
                 if decode and self._proposer is not None:
                     dtok = spans.begin("engine", "spec_draft")
@@ -938,8 +1015,8 @@ class InferenceEngine:
                          if spec else 1)
                     plans.append(self._plan(spec, decode, t))
                 if prefill:
-                    plans.append(self._plan(False, prefill,
-                                            self.prefill_chunk))
+                    plans.append(self._plan(
+                        False, prefill, self._prefill_len(prefill), True))
             took["build_batch"] = ph.seconds
         newer = []
         for spec, lanes, chunks, news, batch in plans:
@@ -947,7 +1024,8 @@ class InferenceEngine:
             with spans.phase("engine", "dispatch") as ph:
                 next_tok, lps = self._run_step(batch, spec)
             took["dispatch"] += ph.seconds
-            newer.append((spec, vtok, lanes, chunks, news, next_tok, lps))
+            newer.append((spec, vtok, lanes, chunks, news, next_tok, lps,
+                          batch[3]))
         # The loop's depth.  A proposer drafts from the token this step
         # samples: its engine fetches what it has just dispatched.  Any
         # other leaves that in flight and fetches the step before it.
@@ -960,7 +1038,8 @@ class InferenceEngine:
             # The host blocks here until the device has finished the older
             # step; the copy back of one int32 per lane was started when
             # that step was dispatched.
-            for spec, vtok, lanes, chunks, news, next_tok, lps in retire:
+            for spec, vtok, lanes, chunks, news, next_tok, lps, rows \
+                    in retire:
                 toks = np.asarray(next_tok)
                 if lps is not None:
                     lps = np.asarray(lps)
@@ -968,6 +1047,10 @@ class InferenceEngine:
                     toks = toks[:, None]
                 if lps is not None and lps.ndim == 1:
                     lps = lps[:, None]
+                if rows is not None:
+                    # A [prefill_lanes, T] program's row -> its lane.
+                    toks, lps = (None if a is None else _by_lane(
+                        a, rows, self.max_lanes) for a in (toks, lps))
                 spans.end(vtok, lanes=len(lanes))
                 if spec:
                     self._spec_stats["steps"] += 1
@@ -1003,12 +1086,26 @@ class InferenceEngine:
                 commit_ms=took["commit"] * 1e3, ahead=ahead)
         return True
 
-    def _plan(self, spec: bool, lanes, t: int) -> tuple:
+    def _prefill_len(self, lanes) -> int:
+        """T of this step's prefill program: `prefill_chunk`; under
+        `prefill_lanes` < max_lanes a quarter of it where no prefilling
+        lane has more than that left to feed (a question behind a document
+        that came from the prefix cache: the long program would compute
+        four times the rows for it).  Two programs, both warmed by whoever
+        warms the engine's shapes; an engine without `prefill_lanes` keeps
+        its one."""
+        short = self.prefill_chunk // 4
+        if short and self.prefill_lanes < self.max_lanes and all(
+                len(r.prompt) - r.next_fed <= short for _, r in lanes):
+            return short
+        return self.prefill_chunk
+
+    def _plan(self, spec: bool, lanes, t: int, prefill=False) -> tuple:
         """One population's step of `t` positions, built from what the step
         in flight will have left, and from here on in flight itself: per
         lane the positions it writes (`chunks`) and whether it samples a
         token (`news`), which `_commit` takes off again."""
-        batch, chunks = self._build_batch(lanes, t)
+        batch, chunks = self._build_batch(lanes, t, prefill)
         news = {}
         for lane, req in lanes:
             news[lane] = int(req.samples(req.next_fed, chunks[lane]))
@@ -1029,7 +1126,7 @@ class InferenceEngine:
             or int(self.cache.seq_lens[lane]) + req.ahead_len
             >= self.cache.max_seq_len)
 
-    def _build_batch(self, live, t):
+    def _build_batch(self, live, t, prefill=False):
         """Host-side assembly of the fixed-shape lane arrays for one
         population (lanes not in `live` ride along fully masked), from the
         lengths and counts the step in flight will have left: committed
@@ -1038,8 +1135,15 @@ class InferenceEngine:
         (`tokens` -1: the step takes it from `_last_tok`); `counters` is -1
         where the lane samples nothing in this step (masked, or a prefill
         chunk short of its prompt's end), and such a lane's entry of
-        `_last_tok` stays what it was."""
-        n = self.max_lanes
+        `_last_tok` stays what it was.
+
+        A `prefill` population under `prefill_lanes` < max_lanes is built
+        compact: row i of its arrays is the i-th lane of `live`, `rows`
+        [prefill_lanes] names each row's lane (max_lanes for a row nobody
+        has: it reads lane max_lanes - 1's table fully masked and writes
+        nowhere), and the step gathers and scatters by it."""
+        compact = prefill and self.prefill_lanes < self.max_lanes
+        n = self.prefill_lanes if compact else self.max_lanes
         tokens = np.zeros((n, t), np.int32)
         positions = np.zeros((n, t), np.int32)
         valid = np.zeros((n, t), bool)
@@ -1048,67 +1152,92 @@ class InferenceEngine:
         temps = np.zeros((n,), np.float32)
         seeds = np.zeros((n,), np.uint32)
         counters = np.full((n,), -1, np.int32)
+        rows = np.full((n,), self.max_lanes, np.int32) if compact else None
         chunks = {}
         sample = False
-        for lane, req in live:
+        for i, (lane, req) in enumerate(live):
+            row = i if compact else lane
             start = int(self.cache.seq_lens[lane]) + req.ahead_len
             fed = req.next_fed
             if fed < len(req.prompt):
                 chunk = min(t, len(req.prompt) - fed)
-                tokens[lane, :chunk] = req.prompt[fed:fed + chunk]
+                tokens[row, :chunk] = req.prompt[fed:fed + chunk]
             else:
                 # Speculative lanes feed [last_token, d_1 .. d_k]; the
                 # verify step samples every position.  Draftless lanes
                 # are the plain chunk=1 decode, masked alongside.
                 chunk = 1 + len(req.draft)
-                tokens[lane, :chunk] = (
+                tokens[row, :chunk] = (
                     -1 if req.ahead_new else req.last_token,) + tuple(
                         req.draft)
-            positions[lane] = start + np.arange(t)
-            valid[lane, :chunk] = True
-            ctx_lens[lane] = start + chunk
-            gather[lane] = chunk - 1
-            temps[lane] = req.temperature
-            seeds[lane] = req.seed & 0xFFFFFFFF
+            positions[row] = start + np.arange(t)
+            valid[row, :chunk] = True
+            ctx_lens[row] = start + chunk
+            gather[row] = chunk - 1
+            temps[row] = req.temperature
+            seeds[row] = req.seed & 0xFFFFFFFF
             if req.samples(fed, chunk):
-                counters[lane] = (req.produced + req.ahead_new
-                                  + req.sample_offset)
+                counters[row] = (req.produced + req.ahead_new
+                                 + req.sample_offset)
             sample = sample or req.temperature > 0
             chunks[lane] = chunk
+            if compact:
+                rows[row] = lane
             # Table entries must exist before the step writes K/V.
             self.cache.ensure_capacity(lane, start + chunk)
+        fed_now = sum(chunks.values())
+        self._tokens_run += fed_now
+        if prefill:
+            pf = self._prefill
+            pf["steps"] += 1
+            pf["lanes"] += len(live)
+            pf["rows"] += n * t
+            pf["rows_valid"] += fed_now
+        elif t == 1 and self._latent is not None:
+            self._latent["decode_steps"] += 1
+            self._latent["ctx_tokens"] += int(
+                sum(ctx_lens[lane] for lane, _ in live))
         batch = (t, sample,
                  (jnp.asarray(tokens), jnp.asarray(positions),
                   jnp.asarray(valid), self.cache.device_tables(),
                   jnp.asarray(ctx_lens), jnp.asarray(gather),
                   jnp.asarray(temps), jnp.asarray(seeds),
-                  jnp.asarray(counters)))
+                  jnp.asarray(counters)), rows)
         return batch, chunks
 
     def _run_step(self, batch, spec: bool = False):
-        t, sample, args = batch
-        key = (t, sample, spec)
+        t, sample, args, rows = batch
+        compact = rows is not None
+        key = (t, sample, spec, compact)
         fn = self._step_fns.get(key)
         first = fn is None
-        # After its nine lane arrays a step takes the arrays that ride
-        # from step to step on the device: the lanes' last sampled tokens,
-        # and last an expert configuration's load counters (handed back
-        # last; not donated: stats() may be reading them).
+        # After its nine lane arrays (and a compact program's `rows`) a
+        # step takes the arrays that ride from step to step on the device:
+        # the lanes' last sampled tokens, and last an expert
+        # configuration's load counters (handed back last; not donated:
+        # stats() may be reading them).
         moe = () if self._moe_load is None else (self._moe_load,)
+        if compact:
+            args = (*args, jnp.asarray(rows))
         carried = (self._last_tok, *moe)
         if first:
             t0 = time.perf_counter()
-            fn = self._step_fns[key] = self._make_step_fn(sample, spec)
+            fn = self._step_fns[key] = self._make_step_fn(sample, spec,
+                                                          compact)
             self._step_avals[key] = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                 (self._served, self.cache.k, self.cache.v, *args, *carried))
         out = list(fn(self._served, self.cache.k, self.cache.v, *args,
                       *carried))
+        if compact:
+            # Every lane's last token, with those this program sampled
+            # scattered to their lanes.
+            self._last_tok = out.pop()
         if moe:
             self._moe_load = out.pop()
         next_tok, *logp, k, v = out
         logp = logp[0] if logp else None
-        if not spec:
+        if not spec and not compact:
             # What the step sampled, over what it was given for the lanes
             # that sampled nothing: the next step's `_last_tok`, and what
             # the host fetches (the copy back starts now, ahead of the
@@ -1124,7 +1253,8 @@ class InferenceEngine:
         self.cache.update_pools(k, v)
         return next_tok, logp
 
-    def _make_step_fn(self, sample: bool, spec: bool = False):
+    def _make_step_fn(self, sample: bool, spec: bool = False,
+                      compact: bool = False):
         model, config = self.model, self.config
         capture = self._capture_logp
 
@@ -1147,7 +1277,8 @@ class InferenceEngine:
             # configuration's step takes them last and returns them last,
             # summed up on the device) the load counters.  A caller that
             # lowers the step for its shapes alone may leave the tokens
-            # out: the same program less two selects.
+            # out: the same program less two selects.  (`v` is None over a
+            # latent cache.)
             moe_load = carried[len(carried) - n_moe:]
             last_tok = carried[0] if len(carried) > n_moe else None
             if last_tok is not None:
@@ -1162,6 +1293,21 @@ class InferenceEngine:
             if last_tok is not None and not spec:
                 next_tok = jnp.where(counters >= 0, next_tok, last_tok)
             return (next_tok, *logp, k, v, *moe_load)
+
+        def compact_step(params, k, v, tokens, positions, valid, tables,
+                         ctx_lens, gather, temps, seeds, counters, rows,
+                         last_tok, *moe_load):
+            # The same step over [prefill_lanes, T]: row i is lane rows[i]
+            # (max_lanes: nobody's).  Its table and its last token are
+            # gathered by that index, what it samples is scattered back to
+            # it, and every lane's last token is returned last.
+            mine = jnp.take(last_tok, rows, mode="clip")
+            next_tok, *rest = step(
+                params, k, v, tokens, positions, valid,
+                jnp.take(tables, rows, axis=0, mode="clip"), ctx_lens,
+                gather, temps, seeds, counters, mine, *moe_load)
+            return (next_tok, *rest,
+                    last_tok.at[rows].set(next_tok, mode="drop"))
 
         def sample_tokens(params, x, gather, temps, seeds, counters):
             """(next tokens,) or, capturing, (next tokens, their logps)."""
@@ -1224,12 +1370,13 @@ class InferenceEngine:
                 return next_tok, _logp_at(logits, next_tok, temps[:, None])
             return (next_tok,)
 
-        self._step_impls[(sample, "spec") if spec else sample] = step
+        impl = compact_step if compact else step
+        self._step_impls[(sample, "spec") if spec else sample] = impl
         # Donated, the pools come back as the buffers they went in as
         # (forward_cached writes and reads blocks of them in place);
         # the CPU backend ignores donation with a warning, so don't ask.
         donate = () if self.backend == "cpu" else (1, 2)
-        return jax.jit(step, donate_argnums=donate)
+        return jax.jit(impl, donate_argnums=donate)
 
     def _commit(self, live, chunks, news, toks, lps=None):
         """Apply one dispatch's results: advance prefill cursors, seal
@@ -1316,8 +1463,9 @@ class InferenceEngine:
                 if chunks[lane] > m:
                     self.cache.truncate_lane(
                         lane, int(self.cache.seq_lens[lane]))
-                self.cache.seal_full_blocks(
-                    lane, req.prompt + req.emitted + emit)
+                if self.cache.has_blocks_to_seal(lane):
+                    self.cache.seal_full_blocks(
+                        lane, req.prompt + req.emitted + emit)
             # SLO latency accounting: first emit is TTFT (queue wait +
             # prefill included); a later burst of m tokens closes m TBT
             # gaps of the mean inter-token latency this step achieved.

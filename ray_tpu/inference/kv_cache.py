@@ -23,6 +23,14 @@ Outside the engine a block keeps the wire format
 spill, serve/kv_tier/codec.py): `read_blocks` / `write_blocks` convert at
 the boundary.
 
+What a row holds comes from the model's attention (`for_model`): per-head K
+and V rows in a pool each (`kind` "kv"), or, for latent attention, ONE pool
+whose row is a token's latent and the one rotated key its heads share
+(`kind` "latent": kv_heads 1, `v` None; 576 numbers a token a layer where
+64 heads of K and V would be 16,384).  Allocator, block tables, prefix
+index, sealing and eviction never look inside a row; the wire format says
+which kind it carries and a cache installs only its own.
+
 Prefix caching (content-addressed block sharing): a block that has been
 completely written ("sealed") is indexed by a hash chain over
 (parent_hash, block_tokens) — the chain hash of a block is a function of
@@ -356,18 +364,22 @@ class PagedKVCache:
     def __init__(self, n_layers: int, kv_heads: int, head_dim: int, *,
                  num_blocks: int, block_size: int, max_lanes: int,
                  max_seq_len: int, dtype=jnp.float32,
-                 prefix_cache: bool = True):
+                 prefix_cache: bool = True, latent: bool = False):
         self.block_size = block_size
         self.max_lanes = max_lanes
         self.max_seq_len = max_seq_len
         self.max_blocks_per_seq = math.ceil(max_seq_len / block_size)
         self.kv_heads = kv_heads
         self.head_dim = head_dim
+        # What a row holds: K and V rows in a pool each, or (`latent`:
+        # kv_heads 1, head_dim the latent and its rotated key together) one
+        # latent row in the one pool.  `k` is that pool, `v` None.
+        self.kind = "latent" if latent else "kv"
         # The stored layout (module docstring): rows of W columns.
         shape = (n_layers, num_blocks, block_size,
                  kv_row_width(kv_heads, head_dim))
         self.k = jnp.zeros(shape, dtype)
-        self.v = jnp.zeros(shape, dtype)
+        self.v = None if latent else jnp.zeros(shape, dtype)
         self.allocator = BlockAllocator(num_blocks, on_evict=self._on_evict)
         # Unused table entries stay 0 — always a valid pool index; the
         # attention mask (positions >= ctx_len) hides whatever lives there.
@@ -402,10 +414,13 @@ class PagedKVCache:
 
     @classmethod
     def for_model(cls, model, config, **kw) -> "PagedKVCache":
-        """Build a cache shaped for an LM family's config (models/)."""
+        """Build a cache shaped for an LM family's config (models/): the
+        row its spec's attention leaves there (`decoder.Attention`)."""
         kw.setdefault("max_seq_len", config.max_seq_len)
         kw.setdefault("dtype", config.dtype)
-        return cls(config.n_layers, config.n_kv_heads, config.head_dim, **kw)
+        attn = model.spec(config).attn
+        return cls(config.n_layers, *attn.cache_row(config),
+                   latent=attn.latent, **kw)
 
     # ---------------- host-side lane lifecycle ----------------
 
@@ -552,12 +567,10 @@ class PagedKVCache:
         if restored:
             idx = jnp.asarray(np.asarray([b for b, _p, _k in restored],
                                          np.int32))
-            self.write_blocks(
-                idx,
-                np.stack([payload[0] for _p, _k, payload in restores],
-                         axis=1),
-                np.stack([payload[1] for _p, _k, payload in restores],
-                         axis=1))
+            self.write_blocks(idx, *(
+                None if restores[0][2][i] is None else
+                np.stack([payload[i] for _p, _k, payload in restores],
+                         axis=1) for i in (0, 1)))
             for nb, _pos, key in restored:
                 # Restored blocks re-enter the device index (live now,
                 # evictable again once the lane lets go).
@@ -585,6 +598,14 @@ class PagedKVCache:
             self.stats["misses"] += 1
         self.stats["miss_tokens"] += len(tokens) - cached_len
         return cached_len
+
+    def has_blocks_to_seal(self, lane: int) -> bool:
+        """Whether `seal_full_blocks` has anything to do for `lane`: a
+        caller that must build `tokens` first asks before it does (a
+        decoding lane fills a block once in block_size steps, and its
+        token list may be a 16k-token document long)."""
+        return (self.prefix_cache_enabled and self._lane_sealed[lane]
+                < int(self.seq_lens[lane]) // self.block_size)
 
     def seal_full_blocks(self, lane: int, tokens: Sequence[int]) -> None:
         """Index every newly-full block of this lane.  `tokens` is the
@@ -633,7 +654,8 @@ class PagedKVCache:
             if self.tier is not None:
                 k_np, v_np = self.read_blocks(
                     jnp.asarray([block], jnp.int32))
-                self.tier.put(key, k_np[:, 0], v_np[:, 0])
+                self.tier.put(key, k_np[:, 0],
+                              None if v_np is None else v_np[:, 0])
 
     @property
     def num_indexed_blocks(self) -> int:
@@ -658,6 +680,7 @@ class PagedKVCache:
         k_np, v_np = self.read_blocks(idx)
         return {
             "v": 1,
+            "kind": self.kind,
             "block_size": self.block_size,
             "chain": [list(key[1]) for key, _b in entries],
             "k": k_np,
@@ -676,7 +699,7 @@ class PagedKVCache:
         if not self.prefix_cache_enabled or not payload:
             return 0
         if payload.get("v") != 1 or payload.get("block_size") != \
-                self.block_size:
+                self.block_size or payload.get("kind", "kv") != self.kind:
             return 0
         k_arr, v_arr = payload["k"], payload["v_pool"]
         if tuple(k_arr.shape[2:]) != (self.block_size, self.kv_heads,
@@ -704,7 +727,8 @@ class PagedKVCache:
             return 0
         idx = jnp.asarray(np.asarray([b for _i, _k, b in new], np.int32))
         pos = np.asarray([i for i, _k, _b in new])
-        self.write_blocks(idx, k_arr[:, pos], v_arr[:, pos])
+        self.write_blocks(idx, k_arr[:, pos],
+                          None if v_arr is None else v_arr[:, pos])
         # Index + park evictable only AFTER every alloc: the blocks stay
         # at refcount 1 through the loop above so a later alloc in the
         # same import can never reclaim an earlier install.
@@ -803,23 +827,27 @@ class PagedKVCache:
             self._dev_tables = jnp.asarray(self.block_tables)
         return self._dev_tables
 
-    def update_pools(self, k: jax.Array, v: jax.Array) -> None:
-        """Rebind the functional pools returned by a jitted step."""
+    def update_pools(self, k: jax.Array, v: Optional[jax.Array]) -> None:
+        """Rebind the functional pools returned by a jitted step (`v` None
+        where the cache is latent)."""
         self.k = k
         self.v = v
 
     # ---------------- the wire format's boundary ----------------
 
-    def read_blocks(self, idx: jax.Array) -> Tuple[np.ndarray, np.ndarray]:
-        """Blocks `idx` of both pools in the wire format
-        [n_layers, n, block_size, kv_heads, head_dim], on the host."""
-        return tuple(np.asarray(unpack_kv_rows(pool[:, idx], self.kv_heads,
-                                               self.head_dim))
-                     for pool in (self.k, self.v))
+    def read_blocks(self, idx: jax.Array) -> Tuple[np.ndarray,
+                                                   Optional[np.ndarray]]:
+        """Blocks `idx` of the pools in the wire format
+        [n_layers, n, block_size, kv_heads, head_dim], on the host: (K, V),
+        or (latent rows, None) with `kind` "latent"."""
+        return tuple(None if pool is None else np.asarray(unpack_kv_rows(
+            pool[:, idx], self.kv_heads, self.head_dim))
+            for pool in (self.k, self.v))
 
     def write_blocks(self, idx: jax.Array, k_blocks, v_blocks) -> None:
         """Store wire-format blocks at `idx` (pad columns stay zero)."""
         self.k = self.k.at[:, idx].set(
             pack_kv_rows(jnp.asarray(k_blocks, self.k.dtype)))
-        self.v = self.v.at[:, idx].set(
-            pack_kv_rows(jnp.asarray(v_blocks, self.v.dtype)))
+        if self.v is not None:
+            self.v = self.v.at[:, idx].set(
+                pack_kv_rows(jnp.asarray(v_blocks, self.v.dtype)))

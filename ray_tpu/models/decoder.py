@@ -1,12 +1,14 @@
 """The decoder-only transformer, defined once for every LM family.
 
-A family (models/gpt.py, models/llama.py) is a config dataclass, its
-parameter format (`init_params`, `param_specs`) and `spec(config)`: a `Spec`
-naming the parts its block is made of and the leaves they read.  Everything
-that runs is here: the training block and the block over a paged KV cache,
-the two layer scans, the head, the loss, the form the weights are served in
-and the train step.  The public functions take the family's `spec` function
-first; a family module exports them bound to it (`bind`).
+A family (models/gpt.py, models/llama.py, models/axk1.py) is a config
+dataclass, its parameter format (`init_params`, `param_specs`) and
+`spec(config)`: a `Spec` naming the parts its block is made of (norms, an
+`Attention`, a `FeedForward`, a leading run of layers with another
+feed-forward) and the leaves they read.  Everything that runs is here: the
+training block and the block over a paged cache, the two layer scans, the
+head, the loss, the form the weights are served in and the train step.
+The public functions take the family's `spec` function first; a family
+module exports them bound to it (`bind`).
 
 Design (no reference counterpart: Ray hosts models, it doesn't ship them):
   * pure functional: params are a pytree, forward is a jittable function
@@ -15,9 +17,11 @@ Design (no reference counterpart: Ray hosts models, it doesn't ship them):
     compiled block regardless of depth (fast compiles, small HLO);
   * every param leaf has a logical sharding spec (parallel.sharding rules
     decide DP/FSDP/TP placement; "kv_heads" shards GQA kv projections);
-  * attention = flash (Pallas) on one chip and per shard (shard_map over
-    batch and heads) under a mesh, ring attention when the mesh has a seq
-    axis > 1; over a paged KV cache, ops/attention.py's paged path;
+  * attention is a part (`HEADS`: per-head K and V, flash (Pallas) on one
+    chip and per shard (shard_map over batch and heads) under a mesh, ring
+    attention when the mesh has a seq axis > 1; over a paged KV cache,
+    ops/attention.py's paged path.  `LATENT`: multi-head latent attention,
+    expanded for a whole sequence, absorbed over a latent paged cache);
   * `jax.checkpoint` (remat) on the block when configured: trades FLOPs for
     HBM, the standard TPU memory lever.
 
@@ -28,6 +32,7 @@ choice is made from the spec, never from a family's name.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 import types
 from functools import partial
@@ -59,16 +64,20 @@ def rmsnorm(x, scale, eps):
     return (y * scale).astype(x.dtype)
 
 
-def rope(x, theta: float, offset=0):
+def rope(x, theta: float, offset=0, freqs=None):
     """Rotary position embedding over [B, L, H, K] (rotate-half pairing:
     the head dim splits into two halves treated as (real, imag)).
 
     `offset` is the absolute position of x's first token: a scalar shared
     by the batch, or a per-lane [B] array (cached decode: lanes sit at
-    different depths)."""
+    different depths).  `freqs` [K / 2] replaces theta's own frequencies
+    (`yarn_freqs`)."""
     b, l, h, k = x.shape
     half = k // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if freqs is None:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = jnp.asarray(freqs, jnp.float32)
     off = jnp.asarray(offset, jnp.float32)
     pos = off[..., None] + jnp.arange(l, dtype=jnp.float32)  # [L] or [B, L]
     ang = pos[..., None] * freqs                      # [L, half] / [B, L, half]
@@ -81,6 +90,28 @@ def rope(x, theta: float, offset=0):
     out = jnp.concatenate([x1 * cos - x2 * sin,
                            x2 * cos + x1 * sin], axis=-1)
     return out.astype(x.dtype)
+
+
+def yarn_freqs(dim: int, theta: float, factor: float, original: int,
+               beta_fast: float, beta_slow: float) -> np.ndarray:
+    """YaRN's rotary frequencies [dim / 2]: theta's own where a dimension
+    turns more than `beta_fast` times over the `original` positions, those
+    divided by `factor` where it turns less than `beta_slow` times, and a
+    linear ramp between the two over the dimensions in between."""
+    own = theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+
+    def turns_dim(turns):
+        return dim * math.log(original / (turns * 2 * math.pi)) / (
+            2 * math.log(theta))
+
+    low = max(math.floor(turns_dim(beta_fast)), 0)
+    high = min(math.ceil(turns_dim(beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2) - low) / max(high - low, 1e-3), 0, 1)
+    return (own / factor * ramp + own * (1 - ramp)).astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
 # --------------------------------------------------------------------------
@@ -115,13 +146,22 @@ def swiglu_mlp(h, p, config, mesh=None, valid=None):
 
 
 def moe_ffn(h, p, config, mesh=None, valid=None):
-    """Dropless top-k experts: softmax router, top-k, dropless dispatch
-    (ops/moe.py).  Router product, softmax and top-k run in float32 (the
-    eighth expert is often chosen by a fourth decimal); the chosen
-    probabilities weight the experts as they are unless
-    `config.norm_topk_prob`.  `p` holds the layer's router [D, E] and the
+    """Dropless top-k experts: a router over all `config.n_experts`, top-k,
+    dropless dispatch (ops/moe.py).  Router product, scores and top-k run
+    in float32 (the eighth expert is often chosen by a fourth decimal).
+    `config.scoring_func` is "softmax" over the experts or "sigmoid" of
+    each; the chosen scores weight the experts as they are unless
+    `config.norm_topk_prob` (then they sum to one), times
+    `config.routed_scale`.  `p` holds the layer's router [D, E] and the
     experts of ALL layers with the index `layer` (the kernel reads them in
-    place).  The load is the assignments each expert took."""
+    place).
+
+    The experts may be a share of the router's: `p` then holds experts
+    `config.experts_offset` to `experts_offset + held` (an expert-parallel
+    deployment's chip).  The router still chooses among all E, this layer
+    computes the assignments that fall on its own, and the rest add
+    nothing here: they are another chip's part of the sum.  The load is
+    the assignments each held expert took."""
     from ray_tpu.ops import moe
 
     c = config
@@ -129,14 +169,32 @@ def moe_ffn(h, p, config, mesh=None, valid=None):
     x = h.reshape(b * l, d)
     logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    probs = jax.nn.softmax(logits, axis=-1)
-    weights, experts = jax.lax.top_k(probs, c.n_experts_per_tok)
+    scores = (jax.nn.sigmoid(logits) if c.scoring_func == "sigmoid"
+              else jax.nn.softmax(logits, axis=-1))
+    weights, experts = jax.lax.top_k(scores, c.n_experts_per_tok)
     if c.norm_topk_prob:
         weights = weights / jnp.sum(weights, -1, keepdims=True)
+    if c.routed_scale != 1.0:
+        weights = weights * c.routed_scale
+    share = p["w_gate"].shape[-3] < c.n_experts
     y, load = moe.expert_ffn(
         x, experts, weights, p["w_gate"], p["w_up"], p["w_down"],
-        p["layer"], None if valid is None else valid.reshape(-1))
+        p["layer"], None if valid is None else valid.reshape(-1),
+        first_held=c.experts_offset if share else None)
     return y.reshape(b, l, d), None, load
+
+
+def shared_moe_ffn(h, p, config, mesh=None, valid=None):
+    """`moe_ffn` beside a shared expert every token passes through: a dense
+    SwiGLU (`ws_gate`, `ws_up`, `ws_down`) whose result is added as it
+    is."""
+    y, _, load = moe_ffn(h, p, config, mesh, valid)
+    gate = jax.nn.silu(jnp.einsum("bld,df->blf", h,
+                                  p["ws_gate"].astype(h.dtype)))
+    up = jnp.einsum("bld,df->blf", h, p["ws_up"].astype(h.dtype))
+    shared = jnp.einsum("blf,fd->bld", gate * up,
+                        p["ws_down"].astype(h.dtype))
+    return shared + y, None, load
 
 
 def switch_moe(h, p, config, mesh=None, valid=None):
@@ -194,36 +252,21 @@ GELU = FeedForward(gelu_mlp, cast=("w_up", "w_down"))
 SWIGLU = FeedForward(swiglu_mlp, cast=("w_gate", "w_up", "w_down"))
 EXPERTS = FeedForward(moe_ffn, whole=("w_gate", "w_up", "w_down"),
                       trains=False)
+SHARED_EXPERTS = FeedForward(shared_moe_ffn,
+                             cast=("ws_gate", "ws_up", "ws_down"),
+                             whole=("w_gate", "w_up", "w_down"),
+                             trains=False)
 SWITCH = FeedForward(switch_moe, serves=False)
 
 
-@dataclasses.dataclass(frozen=True)
-class Spec:
-    """The parts of one family's block at one config, and their leaves.
-    Shapes come from the config every family has: n_layers, d_model,
-    n_heads, n_kv_heads (< n_heads: grouped-query attention), head_dim,
-    vocab_size, max_seq_len, dtype, remat, scan_unroll."""
-    norm: Callable          # norm(x, *leaves): layernorm, or rmsnorm + eps
-    attn_norm: tuple        # the leaves of the block's first norm,
-    mlp_norm: tuple         # of its second,
-    final_norm: tuple       # and of the trunk's last
-    ffn: FeedForward
-    init_params: Callable   # (config, key) -> params
-    param_specs: Callable   # (config) -> the congruent logical-spec tree
-    # None: a learned table `pos_embed` added to the token embedding.
-    rope_theta: Optional[float] = None
-    # eps of an RMSNorm (`q_norm`, `k_norm`) on the projected q and k.
-    qk_norm: Optional[float] = None
-    tied_head: bool = False     # the head is `tok_embed.T`, not `lm_head`
-
-
 # --------------------------------------------------------------------------
-# The block, for training and over a paged KV cache
+# Parts: attentions.  `apply(h, p, spec, config, mesh, position_offset)` on
+# normed h [B, L, D] is the causal attention of a whole sequence, projected
+# back to [B, L, D]; `cached(h, pools, p, spec, config, block_tables,
+# positions, valid, ctx_lens)` writes what the slice's tokens leave in the
+# paged pools at `p["cache_layer"]`, attends over each lane's block table
+# there and returns ([B, T, D], pools).
 # --------------------------------------------------------------------------
-
-def _norm(spec: Spec, x, p, leaves):
-    return spec.norm(x, *(p[name] for name in leaves))
-
 
 def _qkv(spec: Spec, h, p):
     """Projected q, k, v [B, L, heads, head_dim] of normed h; with
@@ -241,9 +284,9 @@ def _qkv(spec: Spec, h, p):
     return q, k, v
 
 
-def _block(x, p, spec: Spec, config, mesh, position_offset=0):
+def heads_attention(h, p, spec, config, mesh, position_offset=0):
+    """Multi-head or grouped-query attention with per-head K and V."""
     c = config
-    h = _norm(spec, x, p, spec.attn_norm)
     q, k, v = _qkv(spec, h, p)
     if spec.rope_theta is not None:
         q = rope(q, spec.rope_theta, position_offset)
@@ -258,32 +301,19 @@ def _block(x, p, spec: Spec, config, mesh, position_offset=0):
     q = with_logical_constraint(q, ("batch", "length", "heads", "kv"),
                                 mesh=mesh)
     attn = mesh_flash_attention(q, k, v, mesh=mesh, causal=True)
-    x = x + jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
-
-    h = _norm(spec, x, p, spec.mlp_norm)
-    y, aux, _ = spec.ffn.apply(h, p, c, mesh)
-    if aux is None:
-        aux = jnp.zeros((), jnp.float32)
-    x = with_logical_constraint(x + y, ("batch", "length", "act_embed"),
-                                mesh=mesh)
-    return x, aux
+    return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
 
 
-def _block_cached(x, k_pool, v_pool, p, spec: Spec, config, block_tables,
-                  positions, valid, ctx_lens):
-    """One block over a paged KV cache: new K/V rows are written into the
-    whole pools at `p["layer"]`, then attention runs over the block table
-    in the same buffers (ops/attention.py paged path).  K/V are cached
-    with kv_heads (GQA un-repeated: the whole point of the grouped cache);
-    the paged attention path expands groups itself.
-    x [B, T, D]; positions [B, T] absolute; ctx_lens [B] = context length
-    including this slice.  Returns (x, pools, the expert layer's load or
-    None)."""
+def heads_attention_cached(h, pools, p, spec, config, block_tables,
+                           positions, valid, ctx_lens):
+    """New K/V rows are written into the whole pools at the layer, then
+    attention runs over the block table in the same buffers
+    (ops/attention.py paged path).  K/V are cached with kv_heads (GQA
+    un-repeated: the whole point of the grouped cache); the paged
+    attention path expands groups itself."""
     from ray_tpu.ops.attention import paged_attention, paged_kv_update
 
-    c = config
-    layer = p["layer"]
-    h = _norm(spec, x, p, spec.attn_norm)
+    layer = p["cache_layer"]
     q, k, v = _qkv(spec, h, p)
     if spec.rope_theta is not None:
         # Per-token rotation at each token's own absolute position: offset
@@ -291,15 +321,195 @@ def _block_cached(x, k_pool, v_pool, p, spec: Spec, config, block_tables,
         # be contiguous per lane, which prefill/decode slices always are.
         q = rope(q, spec.rope_theta, positions[:, 0])
         k = rope(k, spec.rope_theta, positions[:, 0])
-    k_pool, v_pool = paged_kv_update(k_pool, v_pool, k, v, block_tables,
+    k_pool, v_pool = paged_kv_update(*pools, k, v, block_tables,
                                      positions, valid, layer)
     attn = paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
-                           positions, layer, kv_heads=c.n_kv_heads)
-    x = x + jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
+                           positions, layer, kv_heads=config.n_kv_heads)
+    return (jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype)),
+            (k_pool, v_pool))
+
+
+def _latent_qkv(h, p, spec, config, offset):
+    """MLA's projections of normed h [B, L, D]: per head q_nope
+    [B, L, H, qk_nope] and the rotated q_rope [B, L, H, qk_rope]; per token
+    the normed latent c_kv [B, L, kv_lora_rank] and the one rotated key
+    k_rope [B, L, qk_rope] all heads share."""
+    c = config
+    eps = c.norm_eps
+    c_q = rmsnorm(jnp.einsum("bld,dr->blr", h, p["w_qa"].astype(h.dtype)),
+                  p["q_norm"], eps)
+    q = jnp.einsum("blr,rhk->blhk", c_q, p["w_qb"].astype(h.dtype))
+    kv = jnp.einsum("bld,dr->blr", h, p["w_kva"].astype(h.dtype))
+    c_kv = rmsnorm(kv[..., :c.kv_lora_rank], p["kv_norm"], eps)
+    freqs = spec.rope_freqs
+    q_rope = rope(q[..., c.qk_nope_head_dim:], spec.rope_theta, offset,
+                  freqs)
+    k_rope = rope(kv[..., None, c.kv_lora_rank:], spec.rope_theta, offset,
+                  freqs)[:, :, 0]
+    return q[..., :c.qk_nope_head_dim], q_rope, c_kv, k_rope
+
+
+def _latent_up(p, config):
+    """(w_uk [H, qk_nope, C], w_uv [H, C, v]) of one layer: the two halves
+    of `w_kvb` [C, H, qk_nope + v], from the tree where it holds them
+    (serving_params), else split here."""
+    if "w_uk" in p:
+        return p["w_uk"], p["w_uv"]
+    return _kvb_served(p["w_kvb"], config.qk_nope_head_dim).values()
+
+
+def latent_attention(h, p, spec, config, mesh, position_offset=0):
+    """Multi-head latent attention (MLA), expanded: every token's key and
+    value of every head are made from its latent (`w_kvb`), the key gains
+    the shared rotated part, and plain causal attention runs over them.
+    score = (q_nope . k_nope + q_rope . k_rope) * attn_scale."""
+    from ray_tpu.ops.attention import reference_attention
+
+    c = config
+    q_nope, q_rope, c_kv, k_rope = _latent_qkv(h, p, spec, c,
+                                               position_offset)
+    kv = jnp.einsum("blc,chk->blhk", c_kv, p["w_kvb"].astype(h.dtype))
+    k = jnp.concatenate([
+        kv[..., :c.qk_nope_head_dim],
+        jnp.broadcast_to(k_rope[:, :, None], q_rope.shape)], -1)
+    q = jnp.concatenate([q_nope, q_rope], -1)
+    attn = reference_attention(q, k, kv[..., c.qk_nope_head_dim:],
+                               causal=True, scale=spec.attn_scale)
+    return jnp.einsum("blhk,hkd->bld", attn.astype(h.dtype),
+                      p["wo"].astype(h.dtype))
+
+
+def latent_attention_cached(h, pools, p, spec, config, block_tables,
+                            positions, valid, ctx_lens):
+    """MLA over a latent paged cache, absorbed: a token leaves one row
+    [c_kv | k_rope] in the one pool; the query's no-position part is
+    carried into the latent space (q_nope W_uk^T), scores and the weighted
+    sum run against the cached rows as they are, and the result comes back
+    through W_uv.  The same mathematics as `latent_attention`,
+    reassociated."""
+    from ray_tpu.ops import attention as ops
+
+    c = config
+    layer = p["cache_layer"]
+    q_nope, q_rope, c_kv, k_rope = _latent_qkv(h, p, spec, c,
+                                               positions[:, 0])
+    (pool,) = ops.paged_rows_update(
+        pools, (ops.pack_latent_rows(c_kv, k_rope),), block_tables,
+        positions, valid, layer)
+    w_uk, w_uv = _latent_up(p, c)
+    q_lat = jnp.einsum("blhk,hkc->blhc", q_nope, w_uk.astype(h.dtype))
+    out = ops.latent_attention(
+        ops.pack_latent_rows(q_lat, q_rope), pool, block_tables, ctx_lens,
+        positions, valid, layer, v_width=c.kv_lora_rank,
+        scale=spec.attn_scale)
+    attn = jnp.einsum("blhc,hcv->blhv", out, w_uv.astype(h.dtype))
+    return (jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype)),
+            (pool,))
+
+
+def _kvb_served(w, qk_nope: int):
+    """`w_kvb` [..., C, H, qk_nope + v] -> its two halves as the absorbed
+    form multiplies them: `w_uk` [..., H, qk_nope, C] and `w_uv`
+    [..., H, C, v]."""
+    return {"w_uk": jnp.moveaxis(w[..., :qk_nope], -3, -1),
+            "w_uv": jnp.moveaxis(w[..., qk_nope:], -3, -2)}
+
+
+@dataclasses.dataclass(frozen=True)
+class Attention:
+    apply: Callable
+    cached: Callable
+    # (kv_heads, head_dim) of a token's stored row, from the config.
+    cache_row: Callable
+    # One latent pool, not a K and a V pool (inference/kv_cache.py).
+    latent: bool = False
+    # Leaves `serving_params` holds in the activation dtype.
+    cast: tuple = ()
+    # The leaf `serving_params` re-makes into its served halves, once.
+    absorbed: Optional[str] = None
+
+
+HEADS = Attention(heads_attention, heads_attention_cached,
+                  cache_row=lambda c: (c.n_kv_heads, c.head_dim),
+                  cast=("wq", "wk", "wv", "wo"))
+LATENT = Attention(latent_attention, latent_attention_cached,
+                   cache_row=lambda c: (1, c.kv_lora_rank
+                                        + c.qk_rope_head_dim),
+                   latent=True,
+                   cast=("w_qa", "w_qb", "w_kva", "w_kvb", "wo"),
+                   absorbed="w_kvb")
+
+
+@dataclasses.dataclass(frozen=True)
+class Spec:
+    """The parts of one family's block at one config, and their leaves.
+    Shapes come from the config every family has: n_layers, d_model,
+    n_heads, vocab_size, max_seq_len, dtype, remat, scan_unroll, and what
+    its attention reads (`HEADS`: n_kv_heads (< n_heads: grouped-query
+    attention) and head_dim)."""
+    norm: Callable          # norm(x, *leaves): layernorm, or rmsnorm + eps
+    attn_norm: tuple        # the leaves of the block's first norm,
+    mlp_norm: tuple         # of its second,
+    final_norm: tuple       # and of the trunk's last
+    ffn: FeedForward
+    init_params: Callable   # (config, key) -> params
+    param_specs: Callable   # (config) -> the congruent logical-spec tree
+    attn: Attention = HEADS
+    # None: a learned table `pos_embed` added to the token embedding.
+    rope_theta: Optional[float] = None
+    # Rotary frequencies other than theta's own (`yarn_freqs`), and the
+    # factor on the scores where it is not head_dim ** -0.5 (`LATENT`).
+    rope_freqs: Optional[tuple] = None
+    attn_scale: Optional[float] = None
+    # eps of an RMSNorm (`q_norm`, `k_norm`) on the projected q and k.
+    qk_norm: Optional[float] = None
+    tied_head: bool = False     # the head is `tok_embed.T`, not `lm_head`
+    # A stack that is not uniform: its first `first_dense_layers` layers
+    # (`params["lead_blocks"]`, scanned apart) have `lead_ffn` as their
+    # feed-forward; the other n_layers - first_dense_layers have `ffn`.
+    first_dense_layers: int = 0
+    lead_ffn: Optional[FeedForward] = None
+
+
+# --------------------------------------------------------------------------
+# The block, for training and over a paged KV cache
+# --------------------------------------------------------------------------
+
+def _norm(spec: Spec, x, p, leaves):
+    return spec.norm(x, *(p[name] for name in leaves))
+
+
+def _block(x, p, spec: Spec, ffn: FeedForward, config, mesh,
+           position_offset=0):
+    c = config
+    h = _norm(spec, x, p, spec.attn_norm)
+    x = x + spec.attn.apply(h, p, spec, c, mesh, position_offset)
 
     h = _norm(spec, x, p, spec.mlp_norm)
-    y, _, load = spec.ffn.apply(h, p, c, valid=valid)
-    return x + y, k_pool, v_pool, load
+    y, aux, _ = ffn.apply(h, p, c, mesh)
+    if aux is None:
+        aux = jnp.zeros((), jnp.float32)
+    x = with_logical_constraint(x + y, ("batch", "length", "act_embed"),
+                                mesh=mesh)
+    return x, aux
+
+
+def _block_cached(x, pools, p, spec: Spec, ffn: FeedForward, config,
+                  block_tables, positions, valid, ctx_lens):
+    """One block over a paged cache: what the slice's tokens leave there
+    is written into the whole pools at `p["cache_layer"]`, then attention
+    runs over the block table in the same buffers (`Attention.cached`).
+    x [B, T, D]; positions [B, T] absolute; ctx_lens [B] = context length
+    including this slice.  Returns (x, pools, the expert layer's load or
+    None)."""
+    h = _norm(spec, x, p, spec.attn_norm)
+    attn, pools = spec.attn.cached(h, pools, p, spec, config, block_tables,
+                                   positions, valid, ctx_lens)
+    x = x + attn
+
+    h = _norm(spec, x, p, spec.mlp_norm)
+    y, _, load = ffn.apply(h, p, config, valid=valid)
+    return x + y, pools, load
 
 
 def _layer_stack(blocks: dict, n_layers: int, whole: tuple):
@@ -308,6 +518,16 @@ def _layer_stack(blocks: dict, n_layers: int, whole: tuple):
     layers = jnp.arange(n_layers, dtype=jnp.int32)
     scanned = {k: v for k, v in blocks.items() if k not in whole}
     return (scanned, layers), {k: blocks[k] for k in whole}
+
+
+def _stacks(spec: Spec, params: dict, config) -> list:
+    """The runs of like layers, in order: (blocks, layers, feed-forward,
+    index of the run's first layer in the whole stack)."""
+    lead = spec.first_dense_layers
+    main = (params["blocks"], config.n_layers - lead, spec.ffn, lead)
+    if not lead:
+        return [main]
+    return [(params["lead_blocks"], lead, spec.lead_ffn, 0), main]
 
 
 # --------------------------------------------------------------------------
@@ -330,22 +550,24 @@ def forward_trunk(family, params: dict, tokens: jax.Array, config,
         x = x + pos[None].astype(c.dtype)
     x = with_logical_constraint(x, ("batch", "length", "act_embed"), mesh=mesh)
 
-    block = partial(_block, spec=spec, config=c, mesh=mesh,
-                    position_offset=position_offset)
-    if c.remat:
-        block = jax.checkpoint(
-            block, policy=jax.checkpoint_policies.nothing_saveable)
+    aux = None
+    for blocks, n_layers, ffn, _ in _stacks(spec, params, c):
+        block = partial(_block, spec=spec, ffn=ffn, config=c, mesh=mesh,
+                        position_offset=position_offset)
+        if c.remat:
+            block = jax.checkpoint(
+                block, policy=jax.checkpoint_policies.nothing_saveable)
 
-    scanned, whole = _layer_stack(params["blocks"], c.n_layers,
-                                  spec.ffn.whole)
+        scanned, whole = _layer_stack(blocks, n_layers, ffn.whole)
 
-    def body(x, layer):
-        p, i = layer
-        return block(x, {**p, **whole, "layer": i})
+        def body(x, layer, block=block, whole=whole):
+            p, i = layer
+            return block(x, {**p, **whole, "layer": i})
 
-    x, auxes = jax.lax.scan(body, x, scanned,
-                            unroll=min(c.scan_unroll, c.n_layers))
-    return _norm(spec, x, params, spec.final_norm), jnp.sum(auxes)
+        x, auxes = jax.lax.scan(body, x, scanned,
+                                unroll=min(c.scan_unroll, n_layers))
+        aux = jnp.sum(auxes) if aux is None else aux + jnp.sum(auxes)
+    return _norm(spec, x, params, spec.final_norm), aux
 
 
 def _head(spec: Spec, params: dict, config):
@@ -388,7 +610,7 @@ def loss_fn(family, params: dict, batch: dict, config, mesh=None):
                                            spmd_ce_applicable)
 
     c, spec = config, family(config)
-    if not spec.ffn.trains:
+    if not spec.ffn.trains or spec.attn.latent:
         raise NotImplementedError(
             "training an expert configuration is not supported yet: the "
             "grouped matmul (ops/moe.py) has no backward pass and the "
@@ -451,12 +673,17 @@ def _rows_served(name, keep):
     return served
 
 
-# By whether the head is the token table (module level: `_remake` is
-# compiled once per set of forms).
-_SERVED_FORMS = {tied: (("w_down", _w_down_served),
-                        ("tok_embed", _rows_served("tok", keep=tied)),
-                        ("pos_embed", _rows_served("pos", keep=False)))
-                 for tied in (True, False)}
+# By whether the head is the token table, and the width at which an
+# attention's `absorbed` leaf splits (module level: `_remake` is compiled
+# once per set of forms).
+@functools.lru_cache(maxsize=None)
+def _served_forms(tied: bool, absorbed: Optional[str], split: int):
+    forms = (("w_down", _w_down_served),
+             ("tok_embed", _rows_served("tok", keep=tied)),
+             ("pos_embed", _rows_served("pos", keep=False)))
+    if absorbed:
+        forms += ((absorbed, partial(_kvb_served, qk_nope=split)),)
+    return forms
 
 
 @partial(jax.jit, static_argnums=(1, 2, 3))
@@ -469,12 +696,14 @@ def _remake(leaves, names, dtype, forms):
 def serving_params(family, params: dict, config) -> dict:
     """`params` as `forward_cached` and `lm_head` multiply them.  Every
     table, head, attention matrix and leaf the feed-forward casts
-    (`FeedForward.cast`; norm scales and routers are used in float32,
-    dropless experts as stored) is held in `config.dtype`: the rounding
-    the cached forward applies to that leaf at its use
-    (`p["wq"].astype(h.dtype)`), done once for all steps instead of once
-    per step.  Of those, `w_down` and the two tables are re-made in the
-    forms their uses read in place (`_w_down_served`, `_rows_served`).
+    (`Attention.cast`, `FeedForward.cast`; norm scales and routers are
+    used in float32, dropless experts as stored) is held in
+    `config.dtype`: the rounding the cached forward applies to that leaf
+    at its use (`p["wq"].astype(h.dtype)`), done once for all steps instead
+    of once per step.  Of those, `w_down` and the two tables are re-made in
+    the forms their uses read in place (`_w_down_served`, `_rows_served`),
+    and a latent attention's up-projection is split into the two halves
+    its absorbed form multiplies (`_kvb_served`), whatever its dtype.
 
     It goes by the leaf's own dtype: one that is already in `config.dtype`
     comes back as the same array, as does every leaf not named, so a tree
@@ -484,18 +713,20 @@ def serving_params(family, params: dict, config) -> dict:
     its step takes it; the raw tree gives the same tokens, paying casts
     and copies in every call."""
     spec = family(config)
-    cast = ("tok_embed", "pos_embed", "lm_head",
-            "wq", "wk", "wv", "wo") + spec.ffn.cast
+    ffn_cast = spec.ffn.cast + (spec.lead_ffn.cast if spec.lead_ffn else ())
+    cast = ("tok_embed", "pos_embed", "lm_head") + spec.attn.cast + ffn_cast
     dtype = jnp.dtype(config.dtype)
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
     names = [path[-1].key for path, _ in flat]
     todo = [i for i, (_, x) in enumerate(flat)
-            if names[i] in cast and x.dtype != dtype]
+            if names[i] == spec.attn.absorbed
+            or (names[i] in cast and x.dtype != dtype)]
     if not todo:
         return params
     made = dict(zip(todo, _remake(
         [flat[i][1] for i in todo], tuple(names[i] for i in todo), dtype,
-        _SERVED_FORMS[spec.tied_head])))
+        _served_forms(spec.tied_head, spec.attn.absorbed,
+                      getattr(config, "qk_nope_head_dim", 0)))))
     out = {}
     for i, (path, x) in enumerate(flat):
         node = out
@@ -515,16 +746,18 @@ def _embed(params, name, index, config):
 
 def forward_cached(family, params: dict, tokens: jax.Array,
                    positions: jax.Array, valid: jax.Array,
-                   k_pool: jax.Array, v_pool: jax.Array,
+                   k_pool: jax.Array, v_pool: Optional[jax.Array],
                    block_tables: jax.Array, ctx_lens: jax.Array, config,
                    moe_load=None):
     """Cached (incremental) trunk for autoregressive decode/prefill.
 
     tokens [B, T] is a SLICE of each lane's sequence at absolute
     `positions` [B, T] (per-lane offsets: lanes decode at different
-    depths); K/V for the slice are written into the paged pools
-    [n_layers, NB, BS, W] (inference/kv_cache.py's stored layout, rows
-    of n_kv_heads x head_dim) and attention covers each lane's whole block
+    depths); what the slice leaves in the cache is written into the paged
+    pools [n_layers, NB, BS, W] (inference/kv_cache.py's stored layout:
+    rows of n_kv_heads x head_dim in a K and a V pool, or, where the
+    spec's attention is latent, one latent row in the one pool `k_pool`,
+    `v_pool` None) and attention covers each lane's whole block
     table.  The pools ride the layer loop as its carry, whole: a layer
     writes its rows and reads its blocks by index, nothing slices a layer
     out or stacks it back.  `valid` masks padding lanes/overhang (their
@@ -532,7 +765,7 @@ def forward_cached(family, params: dict, tokens: jax.Array,
     lm head is applied by the caller on the positions it needs, so a
     prefill chunk never materializes [B, T, V].
 
-    With `moe_load` (int32 [n_experts + 2], an expert configuration's
+    With `moe_load` (int32 [experts held + 2], an expert configuration's
     running counters: assignments per expert, then experts hit summed
     over (layer, step) pairs, then the count of those pairs) it is carried
     through the layer loop too and returned fourth: the load stays on the
@@ -547,25 +780,29 @@ def forward_cached(family, params: dict, tokens: jax.Array,
         x = _embed(params, "tok", tokens, c) + _embed(params, "pos", pos, c)
     else:
         x = _embed(params, "tok", tokens, c)
-    scanned, whole = _layer_stack(params["blocks"], c.n_layers,
-                                  spec.ffn.whole)
+    pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
+    seen = moe_load
+    for blocks, n_layers, ffn, first in _stacks(spec, params, c):
+        scanned, whole = _layer_stack(blocks, n_layers, ffn.whole)
 
-    def body(carry, layer):
-        x, k_pool, v_pool, seen = carry
-        p, i = layer
-        x, k_pool, v_pool, load = _block_cached(
-            x, k_pool, v_pool, {**p, **whole, "layer": i}, spec, c,
-            block_tables, positions, valid, ctx_lens)
-        if seen is not None:
-            seen = seen + jnp.concatenate([
-                load, jnp.sum(load > 0, dtype=jnp.int32)[None],
-                jnp.ones((1,), jnp.int32)])
-        return (x, k_pool, v_pool, seen), None
+        def body(carry, layer, ffn=ffn, whole=whole, first=first):
+            x, pools, seen = carry
+            p, i = layer
+            x, pools, load = _block_cached(
+                x, pools, {**p, **whole, "layer": i,
+                           "cache_layer": i + first if first else i},
+                spec, ffn, c, block_tables, positions, valid, ctx_lens)
+            if seen is not None and load is not None:
+                seen = seen + jnp.concatenate([
+                    load, jnp.sum(load > 0, dtype=jnp.int32)[None],
+                    jnp.ones((1,), jnp.int32)])
+            return (x, pools, seen), None
 
-    (x, k_pool, v_pool, seen), _ = jax.lax.scan(
-        body, (x, k_pool, v_pool, moe_load), scanned,
-        unroll=min(c.scan_unroll, c.n_layers))
+        (x, pools, seen), _ = jax.lax.scan(
+            body, (x, pools, seen), scanned,
+            unroll=min(c.scan_unroll, n_layers))
     x = _norm(spec, x, params, spec.final_norm)
+    k_pool, v_pool = pools if len(pools) == 2 else (pools[0], None)
     return (x, k_pool, v_pool) if seen is None else (x, k_pool, v_pool, seen)
 
 

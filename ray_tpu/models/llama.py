@@ -42,6 +42,10 @@ class LlamaConfig:
     n_experts: int = 0            # 0 = dense SwiGLU; > 0: d_ff is one expert's
     n_experts_per_tok: int = 0
     norm_topk_prob: bool = False  # renormalise the chosen experts' weights
+    scoring_func: str = "softmax"     # the router's scores (decoder.moe_ffn)
+    routed_scale: float = 1.0         # on the chosen experts' weights
+    experts_offset: int = 0       # a share's first expert (its leaves hold
+                                  # fewer than n_experts: decoder.moe_ffn)
     qk_norm: bool = False         # RMSNorm on the projected q and k
     param_dtype: Any = jnp.float32   # a dtype or its name ("bfloat16")
 

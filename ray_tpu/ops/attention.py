@@ -311,16 +311,15 @@ def unpack_kv_rows(rows, kv_heads: int, head_dim: int):
         *rows.shape[:-1], kv_heads, head_dim)
 
 
-def paged_kv_update(k_pool, v_pool, k_new, v_new, block_tables, positions,
-                    valid, layer=0):
-    """Write one layer's new K/V rows into the pools, in place.
+def paged_rows_update(pools, rows, block_tables, positions, valid, layer=0):
+    """Write one layer's new stored rows into their pools, in place.
 
-    k_pool/v_pool [L, NB, BS, W]; k_new/v_new [B, T, KH, D];
-    block_tables [B, MB] int32; positions [B, T] absolute and consecutive
-    per lane (positions[:, :1] + arange(T), as every prefill chunk,
-    decode token and draft run is); valid [B, T] bool — an invalid slot
-    (padding lane, prompt overhang) changes nothing.  `layer` may be
-    traced (the layer loop's index).
+    pools: arrays [L, NB, BS, W_i]; rows: as many [B, T, W_i] (a token's
+    row as it is stored); block_tables [B, MB] int32; positions [B, T]
+    absolute and consecutive per lane (positions[:, :1] + arange(T), as
+    every prefill chunk, decode token and draft run is); valid [B, T] bool
+    (an invalid slot (padding lane, prompt overhang) changes nothing).
+    `layer` may be traced (the layer loop's index).
 
     A lane's run touches at most (T + BS - 2) // BS + 1 blocks.  Each is
     read, merged with the rows that fall in it and written back with one
@@ -330,7 +329,7 @@ def paged_kv_update(k_pool, v_pool, k_new, v_new, block_tables, positions,
     costs no more than one row (a row at a time, a 32-token chunk took
     46 ms over 48 layers on the v5e, this 3.5; PERF.md section 6).
     """
-    _, _, bs, w = k_pool.shape
+    bs = pools[0].shape[2]
     b, t = positions.shape
     n_touch = (t + bs - 2) // bs + 1
     first, lead = positions[:, 0] // bs, positions[:, 0] % bs
@@ -349,11 +348,11 @@ def paged_kv_update(k_pool, v_pool, k_new, v_new, block_tables, positions,
         axis=1).reshape(-1).astype(jnp.int32)                # [B * n_touch]
 
     def blocks_of(new, pool):
-        rows = pack_kv_rows(new).astype(pool.dtype)              # [B, T, W]
-        return jnp.take_along_axis(rows, run[:, :, None], axis=1).reshape(
-            b * n_touch, bs, w)
+        return jnp.take_along_axis(
+            new.astype(pool.dtype), run[:, :, None], axis=1).reshape(
+                b * n_touch, bs, pool.shape[3])
 
-    new_blocks = (blocks_of(k_new, k_pool), blocks_of(v_new, v_pool))
+    new_blocks = tuple(blocks_of(*np_) for np_ in zip(rows, pools))
     layer = jnp.asarray(layer, jnp.int32)
     zero = jnp.zeros((), jnp.int32)
 
@@ -361,14 +360,23 @@ def paged_kv_update(k_pool, v_pool, k_new, v_new, block_tables, positions,
         at = (layer, phys[i], zero, zero)
 
         def merged(pool, blocks):
-            old = jax.lax.dynamic_slice(pool, at, (1, 1, bs, w))
+            old = jax.lax.dynamic_slice(pool, at, (1, 1) + pool.shape[2:])
             block = jnp.where(write_row[i], blocks[i], old[0, 0])
             return jax.lax.dynamic_update_slice(pool, block[None, None], at)
 
         return tuple(merged(*pb) for pb in zip(pools, new_blocks))
 
-    return jax.lax.fori_loop(0, b * n_touch, write, (k_pool, v_pool),
+    return jax.lax.fori_loop(0, b * n_touch, write, tuple(pools),
                              unroll=min(b * n_touch, _KV_WRITE_UNROLL))
+
+
+def paged_kv_update(k_pool, v_pool, k_new, v_new, block_tables, positions,
+                    valid, layer=0):
+    """`paged_rows_update` for K and V: k_pool/v_pool [L, NB, BS, W];
+    k_new/v_new [B, T, KH, D], packed into stored rows here."""
+    return paged_rows_update(
+        (k_pool, v_pool), (pack_kv_rows(k_new), pack_kv_rows(v_new)),
+        block_tables, positions, valid, layer)
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
@@ -543,6 +551,284 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens, q_positions,
     return paged_attention_reference(q, k_pool, v_pool, block_tables,
                                      ctx_lens, q_positions, layer,
                                      kv_heads=kv_heads, scale=scale)
+
+
+# ---------------------------------------------------------------------------
+# Latent attention over a latent paged cache (MLA, absorbed form)
+# ---------------------------------------------------------------------------
+#
+# A latent pool [n_layers, num_blocks, block_size, W] holds ONE row a token
+# a layer: the normed latent c_kv (`v_width` columns, which are the key's
+# no-position part and the value at once) and behind it the one rotated key
+# k_rope all heads share, rounded up to the lane width with zero columns.
+# A query row is laid out the same way: q_nope carried into the latent
+# space (q_nope W_uk^T) and behind it the head's rotated q_rope.  Then
+# score = q_row . row, and the output in latent space is p @ row[:v_width];
+# the caller takes it through W_uv.  Every head reads the same row, so a
+# tile of the cache is read once for all heads, scores and values.
+
+def latent_row_width(v_width: int, rope_dim: int) -> int:
+    """Columns of one stored latent row, rounded up to the lane width."""
+    return -(-(v_width + rope_dim) // KV_ROW_ALIGN) * KV_ROW_ALIGN
+
+
+def pack_latent_rows(latent, rope_part):
+    """[..., C] and [..., R] -> [..., W] rows (zero pad columns)."""
+    pad = latent_row_width(latent.shape[-1], rope_part.shape[-1]) \
+        - latent.shape[-1] - rope_part.shape[-1]
+    rows = jnp.concatenate([latent, rope_part.astype(latent.dtype)], -1)
+    return jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)]) if pad \
+        else rows
+
+
+def latent_attention_reference(q, pool, block_tables, ctx_lens, q_positions,
+                               layer=0, *, v_width: int, scale: float):
+    """Masked-dense latent attention (ground truth, and the CPU's T=1
+    path): q [B, T, H, W] rows at absolute q_positions [B, T]; pool
+    [L, NB, BS, W] read at `layer`.  Gathers every lane's whole table.
+    Returns the output in latent space [B, T, H, v_width]."""
+    b = q.shape[0]
+    bs, w = pool.shape[2:]
+    max_ctx = block_tables.shape[1] * bs
+    ctx = pool[layer, block_tables].reshape(b, max_ctx, w).astype(
+        jnp.float32)
+    logits = jnp.einsum("bthw,bkw->bhtk", q.astype(jnp.float32), ctx) * scale
+    kpos = jnp.arange(max_ctx)
+    mask = ((kpos[None, None, None, :] <= q_positions[:, None, :, None])
+            & (kpos[None, None, None, :] < ctx_lens[:, None, None, None]))
+    probs = jax.nn.softmax(jnp.where(mask, logits, NEG_INF), axis=-1)
+    out = jnp.einsum("bhtk,bkc->bthc", probs, ctx[..., :v_width])
+    return out.astype(q.dtype)
+
+
+def _latent_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
+                          block_size: int, blocks_per_step: int,
+                          n_steps: int, v_width: int, scale: float):
+    """One (lane, run of `blocks_per_step` cache blocks) grid step.  q
+    [H, W]; each block [BS, W] arrives by its own DMA (the pool is handed
+    in once per block of the run, each with its own index map) and is read
+    once: scores for all H heads against its rows, then its first v_width
+    columns as the values.  Products run on the MXU in the pool's dtype
+    with float32 accumulation; the softmax state is float32 in scratch
+    across the lane's sweep, as in the kernels above, and is updated once
+    for the whole run where the blocks are as wide as the lanes (one
+    update a block was 31% of the kernel's roofline at blocks of 128 and
+    4.5% at blocks of 16: PERF.md section 6, PR 31)."""
+    del bt_ref, layer_ref               # only the index maps read them
+    blocks = refs[:blocks_per_step]
+    o_ref, m_ref, l_ref, acc_ref = refs[blocks_per_step:]
+    lane = pl.program_id(0)
+    step = pl.program_id(1)
+    n_ctx = len_ref[lane]
+
+    @pl.when(step == 0)
+    def _init():
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def update(s, base, values):
+        """One online-softmax update with scores s [H, N] of the tokens
+        from `base` on; values(p) is p @ their latents."""
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(pos < n_ctx, s * scale, NEG_INF)
+        m = m_ref[...]
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        alpha = jnp.exp(m - m_new)
+        m_ref[...] = m_new
+        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + values(p)
+
+    def scores(c_ref):
+        return jax.lax.dot_general(
+            q_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                  # [H, BS]
+
+    def weighted(p, c_ref):
+        return jnp.dot(p.astype(c_ref.dtype), c_ref[:, :v_width],
+                       preferred_element_type=jnp.float32)       # [H, C]
+
+    run = step * blocks_per_step * block_size
+    if block_size % 128 == 0:
+        # The run's blocks side by side: one update of the softmax state
+        # (a max, an exp, a rescale of the accumulator) for the whole run.
+        @pl.when(run < n_ctx)
+        def _compute():
+            update(jnp.concatenate([scores(c) for c in blocks], axis=1), run,
+                   lambda p: sum(weighted(
+                       p[:, r * block_size:(r + 1) * block_size], c)
+                       for r, c in enumerate(blocks)))
+    else:
+        # Blocks narrower than the lane width do not sit side by side
+        # without a relayout: one update a block.
+        for r, c_ref in enumerate(blocks):
+            base = run + r * block_size
+
+            @pl.when(base < n_ctx)
+            def _compute(c_ref=c_ref, base=base):
+                update(scores(c_ref), base, lambda p: weighted(p, c_ref))
+
+    @pl.when(step == n_steps - 1)
+    def _finalize():
+        l_safe = jnp.maximum(l_ref[...], 1e-30)
+        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+
+
+def latent_decode_attention(q, pool, block_tables, ctx_lens, layer=0, *,
+                            v_width: int, scale: float,
+                            blocks_per_step: Optional[int] = None,
+                            use_kernel: Optional[bool] = None,
+                            interpret: Optional[bool] = None):
+    """Single-query latent attention: q [B, H, W] rows (one decode token a
+    lane) over each lane's block table in the latent pool [L, NB, BS, W]
+    at `layer` (may be traced); ctx_lens counts the tokens written,
+    the current one included.  Returns [B, H, v_width], the output in
+    latent space.  The Pallas kernel on TPU, the masked-dense path on the
+    CPU (the interpreter is too slow for the engine tests).
+
+    A grid step takes `blocks_per_step` blocks (by default as many as
+    make 512 tokens): a 16k context is some 30 steps a lane, not a
+    thousand.  A step past the lane's last block names that block again,
+    so nothing is fetched for it."""
+    b, h, w = q.shape
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+    if not use_kernel:
+        return latent_attention_reference(
+            q[:, None], pool, block_tables, ctx_lens,
+            (ctx_lens - 1)[:, None], layer, v_width=v_width,
+            scale=scale)[:, 0]
+    if interpret is None:
+        interpret = _interpret_kernels()
+    bs = pool.shape[2]
+    mb = block_tables.shape[1]
+    kb = blocks_per_step or max(1, 512 // bs)
+    kb = min(kb, mb)
+    n_steps = -(-mb // kb)
+
+    def block_map(r):
+        def index(i, j, bt, ln, ly):
+            last = jnp.maximum(ln[i] - 1, 0) // bs
+            return (ly[0], bt[i, jnp.minimum(j * kb + r,
+                                             jnp.minimum(last, mb - 1))],
+                    0, 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,      # block tables, context lengths, layer
+        grid=(b, n_steps),
+        in_specs=[pl.BlockSpec((None, h, w),
+                               lambda i, j, bt, ln, ly: (i, 0, 0))]
+        + [pl.BlockSpec((None, None, bs, w), block_map(r))
+           for r in range(kb)],
+        out_specs=pl.BlockSpec((None, h, v_width),
+                               lambda i, j, bt, ln, ly: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, v_width), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, block_size=bs,
+                          blocks_per_step=kb, n_steps=n_steps,
+                          v_width=v_width, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        # The instruction's name in the HLO and so in a device trace.
+        name="latent_decode_attention",
+    )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), q.astype(pool.dtype),
+      *([pool] * kb))
+
+
+def latent_chunk_attention(q, pool, block_tables, ctx_lens, q_positions,
+                           valid, layer=0, *, v_width: int, scale: float,
+                           q_tile: int = 128, ctx_tile: int = 512):
+    """Latent attention for a [B, T, H, W] slice of T > 1 query rows (a
+    prefill chunk, a draft run): plain XLA, in tiles.  Only the lanes that
+    have valid rows do work, each over its OWN blocks: per lane a loop
+    over the tiles of `q_tile` query rows that hold a valid row, and under
+    it one over the tiles of `ctx_tile` context tokens at or before the
+    tile's last row (gathered through the lane's block table), with the
+    online softmax of the kernels above.  A padding lane, the rows of a
+    chunk behind the prompt's end and the context behind the causal
+    boundary cost nothing; no lane's whole context is ever gathered.
+    Rows without work come out zero.  Returns [B, T, H, v_width]."""
+    b, t, h, w = q.shape
+    bs = pool.shape[2]
+    mb = block_tables.shape[1]
+    qt = min(q_tile, t)
+    while t % qt:
+        qt -= 1
+    per = max(1, ctx_tile // bs)            # blocks a context tile
+    ct = per * bs
+    layer = jnp.asarray(layer, jnp.int32)
+    n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)           # [B]
+    kcol = jnp.arange(ct, dtype=jnp.int32)
+
+    def lane_body(lane, out):
+        table = block_tables[lane]
+        pos0 = q_positions[lane, 0]
+        n_ctx = ctx_lens[lane]
+
+        def q_body(qi, out):
+            rows = jax.lax.dynamic_slice_in_dim(q[lane], qi * qt, qt, 0)
+            rows = rows.reshape(qt * h, w)
+            qpos = jnp.repeat(pos0 + qi * qt
+                              + jnp.arange(qt, dtype=jnp.int32), h)
+            reach = jnp.minimum(pos0 + (qi + 1) * qt, n_ctx)
+
+            def ctx_body(kj, carry):
+                m, l, acc = carry
+                ids = jnp.clip(kj * per + jnp.arange(per), 0, mb - 1)
+                c = pool[layer, table[ids]].reshape(ct, w)
+                s = jax.lax.dot_general(
+                    rows, c, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32) * scale
+                kpos = kj * ct + kcol
+                keep = ((kpos[None, :] <= qpos[:, None])
+                        & (kpos[None, :] < n_ctx))
+                s = jnp.where(keep, s, NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m - m_new)
+                l = l * alpha + jnp.sum(p, -1, keepdims=True)
+                acc = acc * alpha + jnp.dot(
+                    p.astype(c.dtype), c[:, :v_width],
+                    preferred_element_type=jnp.float32)
+                return m_new, l, acc
+
+            init = (jnp.full((qt * h, 1), NEG_INF, jnp.float32),
+                    jnp.zeros((qt * h, 1), jnp.float32),
+                    jnp.zeros((qt * h, v_width), jnp.float32))
+            _, l, acc = jax.lax.fori_loop(0, -(-reach // ct), ctx_body, init)
+            o = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
+            return jax.lax.dynamic_update_slice(
+                out, o.reshape(1, qt, h, v_width), (lane, qi * qt, 0, 0))
+
+        return jax.lax.fori_loop(0, -(-n_valid[lane] // qt), q_body, out)
+
+    return jax.lax.fori_loop(0, b, lane_body,
+                             jnp.zeros((b, t, h, v_width), q.dtype))
+
+
+def latent_attention(q, pool, block_tables, ctx_lens, q_positions, valid,
+                     layer=0, *, v_width: int, scale: float):
+    """Dispatch latent attention for a [B, T, H, W] query slice: the T=1
+    decode step rides the single-query kernel, longer slices the tiled
+    path."""
+    if q.shape[1] == 1:
+        return latent_decode_attention(
+            q[:, 0], pool, block_tables, ctx_lens, layer, v_width=v_width,
+            scale=scale)[:, None]
+    return latent_chunk_attention(q, pool, block_tables, ctx_lens,
+                                  q_positions, valid, layer,
+                                  v_width=v_width, scale=scale)
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,

@@ -12,7 +12,10 @@ is a scalar the kernel's index maps take: a per-layer slice handed to a
 custom call would be a copy of the layer's experts every step).
 
 No token is dropped whatever the routing; padding tokens (`valid` false) go
-behind the last expert's rows and cost nothing.
+behind the last expert's rows and cost nothing, and so do assignments to
+experts that live elsewhere, where the experts held are a share of those the
+router chooses among (`expert_ffn(first_held=...)`: one chip of an
+expert-parallel deployment, without its exchange).
 """
 
 from __future__ import annotations
@@ -94,7 +97,11 @@ def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
     # the tile is the whole matrix where it fits (one 4 MB DMA for OLMoE's
     # experts) and the row tile is tall: on a v5e 128 x 2048 read 86% of
     # the chip's bandwidth and 16 x 512 80% (PERF.md 6, PR 27).
-    block_n = min(block_n, n)
+    # Wider experts (K 7168) take the widest tile under 8 MB that divides
+    # N, so that two buffers of it fit the kernel's fast memory.
+    block_n = min(block_n, n, max(128, (4 * 2 ** 20 // k) // 128 * 128))
+    while block_n > 128 and n % block_n:
+        block_n -= 128
     if n % block_n:
         raise ValueError(f"N={n} is not a multiple of block_n={block_n}")
     pad = -m % block_m
@@ -131,17 +138,25 @@ def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
 
 
 def expert_ffn(x, expert_ids, expert_weights, w_gate, w_up, w_down,
-               layer=0, valid=None):
+               layer=0, valid=None, first_held=None):
     """Dropless SwiGLU experts.  x [T, D]; expert_ids / expert_weights
     [T, k] (each token's chosen experts and what each counts for); weights
     [L, E, D, F] / [L, E, F, D] (or without L), multiplied as stored;
     `valid` [T] masks padding tokens, which reach no expert.
+
+    With `first_held` the E experts held here are a share of those the
+    ids run over: first_held to first_held + E.  An assignment to any
+    other goes behind every group, as a padding token's do, so the grouped
+    multiply sees only held experts and that assignment adds nothing.
 
     Returns (y [T, D], load [E] int32: the assignments each expert took)."""
     t, d = x.shape
     k = expert_ids.shape[1]
     e = w_gate.shape[-3]
     flat = expert_ids.reshape(-1).astype(jnp.int32)            # [T * k]
+    if first_held is not None:
+        flat = flat - first_held
+        flat = jnp.where((flat >= 0) & (flat < e), flat, e)
     if valid is not None:
         flat = jnp.where(jnp.repeat(valid, k), flat, e)   # behind every group
     order = jnp.argsort(flat, stable=True)     # sorted row -> assignment
@@ -154,7 +169,9 @@ def expert_ffn(x, expert_ids, expert_weights, w_gate, w_up, w_down,
     rank = jnp.zeros_like(order).at[order].set(
         jnp.arange(t * k, dtype=order.dtype))  # assignment -> sorted row
     y = ys[rank].reshape(t, k, d).astype(jnp.float32)
-    if valid is not None:
+    if first_held is not None:
+        y = jnp.where((flat < e).reshape(t, k, 1), y, 0.0)
+    elif valid is not None:
         y = jnp.where(valid[:, None, None], y, 0.0)   # rows no expert wrote
     out = jnp.einsum("tk,tkd->td", expert_weights.astype(jnp.float32), y)
     return out.astype(x.dtype), load

@@ -47,10 +47,14 @@ class KVBlockCodec:
         pickle.dump(
             {
                 "v": 1,
+                # "kv": K and V rows; "latent": one latent row in `k`,
+                # `v_pool` None (inference/kv_cache.py).
+                "kind": payload.get("kind", "kv"),
                 "block_size": int(payload["block_size"]),
                 "chain": [list(map(int, blk)) for blk in payload["chain"]],
                 "k": np.ascontiguousarray(payload["k"]),
-                "v_pool": np.ascontiguousarray(payload["v_pool"]),
+                "v_pool": (None if payload["v_pool"] is None else
+                           np.ascontiguousarray(payload["v_pool"])),
             },
             buf, protocol=pickle.HIGHEST_PROTOCOL)
         return buf.getvalue()
@@ -71,10 +75,13 @@ class KVBlockCodec:
         k, v = payload["k"], payload["v_pool"]
         n = len(payload["chain"])
         bs = payload["block_size"]
-        if k.shape != v.shape or k.shape[1] != n or k.shape[2] != bs:
+        latent = payload.setdefault("kind", "kv") == "latent"
+        if (v is None) != latent or (v is not None and k.shape != v.shape) \
+                or k.shape[1] != n or k.shape[2] != bs:
             raise KVCodecError(
-                f"frame shape mismatch: k{k.shape} v{v.shape} vs "
-                f"{n} chain blocks of size {bs}")
+                f"frame shape mismatch: k{k.shape} "
+                f"v{None if v is None else v.shape} vs {n} chain blocks "
+                f"of size {bs} of kind {payload['kind']}")
         return payload
 
     @staticmethod

@@ -97,7 +97,9 @@ class KVTierCache:
         if self.contains(key):
             self._touch(key)
             return
-        self._host[key] = (np.asarray(k_np), np.asarray(v_np))
+        # `v_np` None: a latent cache's block is one pool's rows.
+        self._host[key] = (np.asarray(k_np),
+                           None if v_np is None else np.asarray(v_np))
         self.counters["kv_tier_spilled_blocks"] += 1
         _metrics()["spilled"].inc()
         events.record("kv", "spilled", host=len(self._host),

@@ -83,7 +83,8 @@ class LLMDeployment:
                  max_lanes: int = 8, block_size: int = 16,
                  num_blocks: Optional[int] = None,
                  max_seq_len: Optional[int] = None,
-                 prefill_chunk: int = 32, seed: int = 0,
+                 prefill_chunk: int = 32,
+                 prefill_lanes: Optional[int] = None, seed: int = 0,
                  prefix_cache: bool = True, speculative: bool = False,
                  spec_k: Optional[int] = None, draft_proposer="ngram",
                  kv_tier: Optional[bool] = None):
@@ -98,7 +99,8 @@ class LLMDeployment:
             model, config, params, max_lanes=max_lanes,
             block_size=block_size, num_blocks=num_blocks,
             max_seq_len=max_seq_len, prefill_chunk=prefill_chunk,
-            seed=seed, prefix_cache=prefix_cache,
+            prefill_lanes=prefill_lanes, seed=seed,
+            prefix_cache=prefix_cache,
             spec_k=int(spec_k), draft_proposer=draft_proposer,
             spec_adaptive=GLOBAL_CONFIG.spec_adaptive,
             kv_tier=kv_tier)

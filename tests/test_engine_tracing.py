@@ -274,3 +274,60 @@ def test_a_traced_request_leaves_one_queue_prefill_and_decode_span():
                                                   "prefix_miss")]
     assert admitted["payload"]["wait_ms"] == pytest.approx(
         durs["queue"] * 1e3, abs=5.0)
+
+
+def test_a_latent_share_engine_counts_on_the_host_and_adds_nothing_to_a_step():
+    """A.X-K1's block at nano size, one share of four, with a compact
+    prefill program: the same one `engine/step` record a step, no other
+    ring event per step or token, and `stats()` growing `latent` (the T=1
+    steps and the context they attended over), `prefill` (programs, lanes,
+    rows, rows that held a prompt token) and a share's `moe` (the router's
+    assignments in all, counted on the host, beside those that fell on
+    held experts, counted on the device)."""
+    from ray_tpu.models import axk1
+    cfg = axk1.CONFIGS["axk1-nano-share"]
+    engine = InferenceEngine("axk1", cfg, auto_start=False, max_lanes=2,
+                             prefill_chunk=8, prefill_lanes=1, block_size=8)
+    engine.generate(list(range(1, 6)), 2)          # compile both shapes
+    s0, seq = engine.stats(), _last_seq()
+    prompt = list(range(1, 20))
+    out = engine.generate(prompt, 6)
+    s1 = engine.stats()
+    since = [e for e in events.snapshot(plane="engine") if e["seq"] > seq]
+    steps = [e for e in since if e["kind"] == "step"]
+    assert len(steps) == s1["steps"] - s0["steps"] > 0
+    assert {e["kind"] for e in since} <= {"step", "submit", "admit",
+                                          "finish", "prefix_miss"}
+    for e in steps:
+        assert set(e["payload"]) == {"decode", "prefill", "waiting",
+                                     "wall_ms", *PHASE_FIELDS, "ahead"}
+
+    def grew(key):
+        return {k: s1[key][k] - s0[key][k] for k in s1[key]
+                if not isinstance(s1[key][k], list)}
+
+    # 19 prompt tokens in three chunks of a [1, 8] program
+    assert grew("prefill") == {"steps": 3, "lanes": 3, "rows": 24,
+                               "rows_valid": 19}
+    # five T=1 steps, over contexts of 20, 21, ... tokens (the first
+    # output token comes from the prefill)
+    assert grew("latent") == {"decode_steps": len(out) - 1,
+                              "ctx_tokens": sum(range(20, 19 + len(out)))}
+    moe = grew("moe")
+    expert_layers = cfg.n_layers - cfg.first_dense_layers
+    tokens = 19 + len(out) - 1
+    assert moe["assignments"] == tokens * cfg.n_experts_per_tok * expert_layers
+    assert 0 < moe["assignments_held"] < moe["assignments"]
+    assert len(s1["moe"]["expert_load"]) == cfg.n_experts_held
+    assert sum(s1["moe"]["expert_load"]) == s1["moe"]["assignments_held"]
+    assert moe["layer_steps"] >= (3 + len(out) - 1) * expert_layers
+    assert 0 < moe["experts_hit"] <= moe["layer_steps"] * cfg.n_experts_held
+
+
+def test_engines_of_k_and_v_rows_have_no_latent_counters():
+    engine = _engine()
+    engine.generate(list(range(1, 12)), 3)
+    stats = engine.stats()
+    assert "latent" not in stats and "moe" not in stats
+    assert stats["prefill"] == {"steps": 2, "lanes": 2, "rows": 32,
+                                "rows_valid": 11}

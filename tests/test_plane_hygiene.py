@@ -332,5 +332,33 @@ def test_the_engine_has_one_loop_and_no_option_sets_its_depth():
              if isinstance(f, ast.FunctionDef) and f.name == "__init__"]
     assert [a.arg for a in init.args.kwonlyargs] == [
         "max_lanes", "block_size", "num_blocks", "max_seq_len",
-        "prefill_chunk", "seed", "prefix_cache", "auto_start", "spec_k",
-        "draft_proposer", "spec_adaptive", "kv_tier", "capture_logp"]
+        "prefill_chunk", "prefill_lanes", "seed", "prefix_cache",
+        "auto_start", "spec_k", "draft_proposer", "spec_adaptive", "kv_tier",
+        "capture_logp"]
+
+
+def test_latent_prefill_and_share_counters_are_host_sums_in_build_batch():
+    """`stats()["latent"]`, `stats()["prefill"]` and a share's total
+    `assignments` are sums the host makes while it builds a batch: no
+    transfer, no device array and no ring event of their own.  The latent
+    kernel keeps the name the benchmark's readers find it by."""
+    src = (PKG / "inference" / "engine.py").read_text()
+    build = src[src.index("    def _build_batch("):
+                src.index("    def _run_step(")]
+    for counter in ("self._prefill", "self._latent", "self._tokens_run"):
+        sites = [m.start() for m in re.finditer(re.escape(counter) + r"\b",
+                                                src)]
+        writes = [m.start() for m in re.finditer(
+            re.escape(counter) + r'(?:\["\w+"\])? \+?= ', src)]
+        assert sites and writes, counter
+        # written where they are made (the constructor) and in
+        # `_build_batch`, read by `stats()` and `_moe_stats` alone
+        inside = src.index("    def _build_batch(")
+        assert all(w < src.index("    def submit(") or inside <= w
+                   < inside + len(build) for w in writes), counter
+    body = build[build.index('"""', build.index('"""') + 3):]
+    assert "events.record" not in body and "spans." not in body
+    assert "np.asarray(self." not in body          # no fetch from the device
+    names = re.findall(r'name="(\w+)"',
+                       (PKG / "ops" / "attention.py").read_text())
+    assert "latent_decode_attention" in names

@@ -207,8 +207,8 @@ def test_mixed_spec_and_plain_lanes_share_a_step():
     dispatches = []
     orig = spec._build_batch
 
-    def snoop(live, t):
-        batch, chunks = orig(live, t)
+    def snoop(live, t, *prefill):
+        batch, chunks = orig(live, t, *prefill)
         dispatches.append((t, dict(chunks)))
         return batch, chunks
 

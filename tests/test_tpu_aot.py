@@ -361,3 +361,100 @@ def test_chip_binding_for_tpu_workers():
     with pytest.raises(ValueError):
         chip_env((0, 1), 4)
     assert chip_env((0,), 1) == {}
+
+
+# A.X-K1 at its published widths as `serve_axk1_docs_decode` serves it: one
+# chip's share (12 of 192 routed experts a layer, an eighth of the
+# vocabulary), one dense + six expert layers, 32 lanes over a latent pool of
+# 196,608 tokens in blocks of 128, requests of 16,896 tokens at most, the
+# prefill program over 4 lanes x 512.
+def _axk1_cell():
+    from ray_tpu.models import axk1
+    cfg = axk1.Axk1Config(vocab_size=20480, n_layers=7, n_experts_held=12,
+                          max_seq_len=131072)
+    return axk1, cfg, dict(lanes=32, block_size=128, num_blocks=1536,
+                           max_seq_len=16896)
+
+
+def _compile_axk1_step(device, t, rows):
+    """The engine's greedy step over a latent pool: `rows` lanes of `t`
+    tokens; compact (gathered by lane index) where rows < lanes."""
+    from ray_tpu.inference.engine import InferenceEngine
+    from ray_tpu.ops.attention import latent_row_width
+    model, cfg, cell = _axk1_cell()
+    arg = _arg_on(device)
+    eng = object.__new__(InferenceEngine)
+    eng.model, eng.config, eng._capture_logp = model, cfg, False
+    eng.backend, eng._step_impls = "tpu", {}
+    shapes = jax.eval_shape(lambda k: model.init_params(cfg, k),
+                            jax.random.key(0))
+    given = sum(math.prod(x.shape) * x.dtype.itemsize
+                for x in jax.tree.leaves(shapes))
+    shapes = jax.eval_shape(lambda p: model.serving_params(p, cfg), shapes)
+    served = sum(math.prod(x.shape) * x.dtype.itemsize
+                 for x in jax.tree.leaves(shapes))
+    params = jax.tree.map(lambda x: arg(x.shape, x.dtype), shapes)
+    pool = arg((cfg.n_layers, cell["num_blocks"], cell["block_size"],
+                latent_row_width(cfg.kv_lora_rank, cfg.qk_rope_head_dim)),
+               cfg.dtype)
+    lanes = cell["lanes"]
+    compact = rows < lanes
+    mb = cell["max_seq_len"] // cell["block_size"]
+    compiled = eng._make_step_fn(False, False, compact).lower(
+        params, pool, None, arg((rows, t), jnp.int32),
+        arg((rows, t), jnp.int32), arg((rows, t), jnp.bool_),
+        arg((lanes, mb), jnp.int32), arg((rows,), jnp.int32),
+        arg((rows,), jnp.int32), arg((rows,), jnp.float32),
+        arg((rows,), jnp.uint32), arg((rows,), jnp.int32),
+        *((arg((rows,), jnp.int32),) if compact else ()),
+        arg((lanes,), jnp.int32),
+        arg((cfg.n_experts_held + 2,), jnp.int32)).compile()
+    return compiled, pool, params, served - given
+
+
+@pytest.mark.parametrize("t,rows", [(1, 32), (512, 4), (128, 4)],
+                         ids=["t1_32_lanes", "t512_4_prefill_lanes",
+                              "t128_4_prefill_lanes"])
+def test_axk1_steps_fit_a_v5e_and_read_weights_and_pool_in_place(
+        v5e, as_on_chip, t, rows):
+    """Arguments and temporaries under the compiler's 15.75 GB, the latent
+    pool donated and left where it is, no matrix converted, copied or
+    transposed in a step (the absorbed halves of the up-projection are
+    arguments, made once), the T=1 step on the latent kernel and the
+    grouped multiply, the chunk on the grouped multiply alone."""
+    compiled, pool, params, extra = _compile_axk1_step(v5e[0], t, rows)
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert {x.dtype for x in jax.tree.leaves(params)} == {
+        jnp.dtype(jnp.bfloat16)}
+    assert extra == 0                     # served_bytes - given_bytes
+    pool_bytes = 2 * math.prod(pool.shape)
+    assert pool.shape == (7, 1536, 128, 640) and pool_bytes < 1.8e9
+    assert memory.alias_size_in_bytes == pool_bytes           # one pool
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    assert 11.0e9 < memory.argument_size_in_bytes < 11.6e9
+    assert memory.temp_size_in_bytes < (64 if t == 1 else 1024) * 2 ** 20
+    assert count_pool_copies(text, pool.shape) == 0
+    copied = count_weight_bytes_copied(text, params)
+    # (`copy-done`: XLA's own prefetch of a layer's slice; `convert`: the
+    # norm scales, kilobytes)
+    assert not set(copied) & {"copy", "transpose", "remat"}, copied
+    assert copied.get("convert", 0) < 2 ** 20, copied
+    kernels = {k.split(".")[0] for k in _kernel_names(text)}
+    assert kernels == ({"latent_decode_attention", "moe_grouped_matmul"}
+                       if t == 1 else {"moe_grouped_matmul"})
+
+
+def test_latent_decode_kernel_compiles_for_v5e_at_every_block_size(
+        v5e, as_on_chip):
+    from ray_tpu.ops.attention import latent_decode_attention
+    arg = _arg_on(v5e[0])
+    for bs in (16, 32, 64, 128):
+        text = jax.jit(functools.partial(
+            latent_decode_attention, v_width=512, scale=0.13)).lower(
+            arg((8, 64, 640), jnp.bfloat16),
+            arg((2, 4096 // bs, bs, 640), jnp.bfloat16),
+            arg((8, 2048 // bs), jnp.int32), arg((8,), jnp.int32),
+            arg((), jnp.int32)).compile().as_text()
+        (kernel,) = _kernel_names(text)
+        assert kernel.split("%")[-1].startswith("latent_decode_attention")
