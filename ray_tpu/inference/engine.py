@@ -69,6 +69,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import gc
 import itertools
 import logging
 import queue
@@ -84,9 +85,10 @@ import numpy as np
 from ray_tpu import models
 from ray_tpu._private import compile_cache
 from ray_tpu._private.accelerators import leased_chips, require_chip_lease
-from ray_tpu.inference.kv_cache import (PagedKVCache, chain_hashes,
+from ray_tpu.inference.kv_cache import (PagedKVCache, chain_keys,
                                         count_pool_copies,
                                         count_weight_bytes_copied)
+from ray_tpu.ops.attention import paged_blocks_per_step
 from ray_tpu.util import events, spans
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
 
@@ -205,10 +207,11 @@ class _Request:
     # blocks, then finish WITHOUT sampling — the sealed chain is the
     # product (export_prefix ships it to a decode engine).
     prefill_only: bool = False
-    # The prompt's block chain (`kv_cache.chain_hashes`), made by `submit`
-    # on the caller's thread where the prefix cache is on
-    # (`_head_is_being_sealed`).
-    chain: Optional[List[int]] = None
+    # The prompt's block chain (`kv_cache.chain_keys`), made by `submit`
+    # on the caller's thread where the prefix cache is on: admission looks
+    # the prompt's blocks up by it and walks no prompt (`_admit`,
+    # `_head_is_being_sealed`).
+    chain: Optional[List[tuple]] = None
 
     @property
     def prefilling(self) -> bool:
@@ -449,12 +452,19 @@ class InferenceEngine:
         self._queue_wait_s = 0.0
         # Counted on the host as batches are built (`_build_batch`): the
         # prefill programs and their rows, the valid tokens of all
-        # programs, and over a latent cache the T=1 steps and the context
-        # tokens they attended over.
+        # programs, and the T=1 steps with the context tokens they
+        # attended over: `_latent` over a latent cache, `_paged` over a
+        # K/V one, which also counts the runs of `_paged_run` tokens the
+        # decode kernel found context in (it visits no others).
         self._prefill = {"steps": 0, "lanes": 0, "rows": 0, "rows_valid": 0}
         self._tokens_run = 0
-        self._latent = ({"decode_steps": 0, "ctx_tokens": 0}
-                        if self.cache.kind == "latent" else None)
+        latent = self.cache.kind == "latent"
+        self._latent = {"decode_steps": 0, "ctx_tokens": 0} if latent else None
+        self._paged = None if latent else {
+            "decode_steps": 0, "ctx_tokens": 0, "runs_live": 0}
+        self._paged_run = block_size * paged_blocks_per_step(
+            block_size, self.cache.k.shape[3], self.cache.k.dtype.itemsize,
+            self.cache.max_blocks_per_seq)
         self._thread: Optional[threading.Thread] = None
         self._stopped = False
         self._auto = auto_start
@@ -494,7 +504,7 @@ class InferenceEngine:
                        submitted_pc=time.perf_counter(),
                        spec_k=self.spec_k,
                        prefill_only=prefill_only,
-                       chain=(chain_hashes(prompt, self.cache.block_size)
+                       chain=(chain_keys(prompt, self.cache.block_size)
                               if self.cache.prefix_cache_enabled else None))
         events.record("engine", "submit", trace=req.trace, rid=rid,
                       prompt_len=len(prompt), max_new=max_new_tokens)
@@ -753,7 +763,10 @@ class InferenceEngine:
             # prefilled in them, the rows they computed and how many of
             # those held a prompt token.
             "prefill": dict(self._prefill),
-            **({} if self._latent is None else
+            # T=1 steps and the context tokens their lanes attended over;
+            # over a K/V cache also the runs of the decode kernel that
+            # held context, of `decode_steps` x lanes x runs a lane.
+            **({"paged": dict(self._paged)} if self._latent is None else
                {"latent": dict(self._latent)}),
             **self._moe_stats(),
         }
@@ -869,10 +882,10 @@ class InferenceEngine:
             growth = (self.cache.blocks_needed(self._final_len(req))
                       - self.cache.blocks_needed(len(req.prompt)))
             if not self.cache.can_admit_prefix(
-                    req.prompt,
+                    req.prompt, keys=req.chain,
                     headroom_blocks=self._growth_reserve() + growth):
                 break  # FIFO: don't starve the head with later requests
-            reused = self.cache.adopt_prefix(lane, req.prompt)
+            reused = self.cache.adopt_prefix(lane, req.prompt, req.chain)
             self._waiting.popleft()
             req.fed = reused
             self._lanes[lane] = req
@@ -915,7 +928,7 @@ class InferenceEngine:
             for other in self._lanes
             if other is not None and other.prefilling), default=0)
         return shared > 0 and shared > len(
-            self.cache.match_prefix(req.prompt))
+            self.cache.match_prefix(req.prompt, req.chain))
 
     def _propose(self, lane: int, req: _Request) -> tuple:
         """Draft for one decode lane: ask the proposer for up to the
@@ -1193,10 +1206,14 @@ class InferenceEngine:
             pf["lanes"] += len(live)
             pf["rows"] += n * t
             pf["rows_valid"] += fed_now
-        elif t == 1 and self._latent is not None:
-            self._latent["decode_steps"] += 1
-            self._latent["ctx_tokens"] += int(
-                sum(ctx_lens[lane] for lane, _ in live))
+        elif t == 1:
+            ctx = [int(ctx_lens[lane]) for lane, _ in live]
+            seen = self._paged if self._latent is None else self._latent
+            seen["decode_steps"] += 1
+            seen["ctx_tokens"] += sum(ctx)
+            if self._latent is None:
+                self._paged["runs_live"] += sum(
+                    -(-c // self._paged_run) for c in ctx)
         batch = (t, sample,
                  (jnp.asarray(tokens), jnp.asarray(positions),
                   jnp.asarray(valid), self.cache.device_tables(),
@@ -1250,6 +1267,17 @@ class InferenceEngine:
             # The first call of a shape returns once it has compiled (the
             # dispatch itself is asynchronous): its wall is compile time.
             self._step_compile_s[key] = time.perf_counter() - t0
+            # What tracing and compiling (or loading) a program leaves on
+            # the heap lives as long as the process: out of the collector's
+            # way with it, and with everything else this old.  A full
+            # collection walks every tracked object with the interpreter
+            # stopped: 90-125 ms every 3 s in a replica that has served a
+            # while (215k objects, nearly all jax's), more in one that
+            # compiled its programs than in one that loaded them, and the
+            # device, one step ahead, idles through most of each; 12-14 ms
+            # over what is left (PERF.md section 6, PR 32).
+            gc.collect()
+            gc.freeze()
         self.cache.update_pools(k, v)
         return next_tok, logp
 

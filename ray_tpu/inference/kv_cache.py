@@ -63,6 +63,27 @@ from ray_tpu.ops.attention import (kv_row_width, pack_kv_rows,
 _ROOT_HASH = 0
 
 
+def _iter_chain_keys(tokens: Sequence[int], block_size: int):
+    parent = _ROOT_HASH
+    for i in range((len(tokens) - 1) // block_size):
+        key = (parent, tuple(map(int, tokens[i * block_size:
+                                             (i + 1) * block_size])))
+        yield key
+        parent = hash(key)
+
+
+def chain_keys(tokens: Sequence[int], block_size: int) -> List[Tuple]:
+    """The prefix index's key of every block-aligned prefix of `tokens`:
+    `(parent, block_tokens)` with `parent` the hash of the key before
+    (root 0), and the same one-token-left cap as match_prefix.  Whoever
+    has a prompt's keys (the engine makes them in `submit`, on the
+    caller's thread) hands them to `can_admit_prefix`, `adopt_prefix` and
+    `match_prefix`, which then look blocks up without walking the prompt
+    again: three walks of a 16k-token document were 7.7 ms of an admitting
+    iteration on the engine's thread (PERF.md section 6, PR 32)."""
+    return list(_iter_chain_keys(tokens, block_size))
+
+
 def chain_hashes(tokens: Sequence[int], block_size: int) -> List[int]:
     """Cumulative chain hash of every block-aligned prefix of `tokens`,
     in the exact convention the prefix index uses (`hash((parent,
@@ -71,14 +92,7 @@ def chain_hashes(tokens: Sequence[int], block_size: int) -> List[int]:
     processes (PYTHONHASHSEED randomizes str/bytes only), so a router
     can score replica summaries against a request without shipping
     tokens."""
-    out: List[int] = []
-    parent = _ROOT_HASH
-    for i in range((len(tokens) - 1) // block_size):
-        parent = hash((parent, tuple(int(t) for t in
-                                     tokens[i * block_size:
-                                            (i + 1) * block_size])))
-        out.append(parent)
-    return out
+    return [hash(key) for key in chain_keys(tokens, block_size)]
 
 
 # `  ROOT %name = bf16[48,256,16,1664]{3,2,1,0:T(8,128)(2,1)} opcode(%a, %b),
@@ -452,7 +466,8 @@ class PagedKVCache:
 
     # ---------------- prefix cache ----------------
 
-    def match_prefix(self, tokens: Sequence[int]) -> List[int]:
+    def match_prefix(self, tokens: Sequence[int],
+                     keys: Optional[List[Tuple]] = None) -> List[int]:
         """Longest chain of cached sealed blocks covering a block-aligned
         prefix of `tokens`, capped so at least one prompt token is always
         left to prefill (its logits seed the first sampled token).  Pure
@@ -461,15 +476,17 @@ class PagedKVCache:
         if not self.prefix_cache_enabled:
             return []
         out: List[int] = []
-        for kind, _key, block in self._match_chain(tokens):
+        for kind, _key, block in self._match_chain(tokens, keys):
             if kind != "dev":
                 break
             out.append(block)
         return out
 
-    def _match_chain(self, tokens: Sequence[int]) -> List[Tuple]:
+    def _match_chain(self, tokens: Sequence[int],
+                     keys: Optional[List[Tuple]] = None) -> List[Tuple]:
         """Longest cached chain covering a block-aligned prefix of
-        `tokens`, walking THROUGH the spill tier: each entry is
+        `tokens` (`keys`: their `chain_keys`, where the caller has them),
+        walking THROUGH the spill tier: each entry is
         ("dev", key, block) for a device-resident sealed block or
         ("tier", key, None) for a spilled one (restorable on adopt).  A
         device child behind a spilled parent is reachable again — the
@@ -477,11 +494,9 @@ class PagedKVCache:
         it by construction."""
         if not self.prefix_cache_enabled:
             return []
-        bs = self.block_size
         out: List[Tuple] = []
-        parent = _ROOT_HASH
-        for i in range((len(tokens) - 1) // bs):
-            key = (parent, tuple(int(t) for t in tokens[i * bs:(i + 1) * bs]))
+        for key in (_iter_chain_keys(tokens, self.block_size)
+                    if keys is None else keys):
             block = self._index.get(key)
             if block is not None:
                 out.append(("dev", key, block))
@@ -489,17 +504,17 @@ class PagedKVCache:
                 out.append(("tier", key, None))
             else:
                 break
-            parent = hash(key)
         return out
 
     def can_admit_prefix(self, tokens: Sequence[int],
-                         headroom_blocks: int = 0) -> bool:
+                         headroom_blocks: int = 0,
+                         keys: Optional[List[Tuple]] = None) -> bool:
         """Admission check that accounts for reuse: device-matched blocks
         are referenced (not allocated), but matched blocks currently
         parked evictable stop counting as free capacity once taken.
         Spilled matches still cost an allocation (they restore into
         fresh blocks), so they stay inside `need`."""
-        dev = [b for kind, _k, b in self._match_chain(tokens)
+        dev = [b for kind, _k, b in self._match_chain(tokens, keys)
                if kind == "dev"]
         need = (self.blocks_needed(len(tokens)) - len(dev)
                 + headroom_blocks)
@@ -507,18 +522,20 @@ class PagedKVCache:
                       - sum(self.allocator.is_evictable(b) for b in dev))
         return need <= free_after
 
-    def adopt_prefix(self, lane: int, tokens: Sequence[int]) -> int:
+    def adopt_prefix(self, lane: int, tokens: Sequence[int],
+                     keys: Optional[List[Tuple]] = None) -> int:
         """Sequence start with prefix reuse: take shares of the longest
         cached prefix chain (restoring any spilled links from the tier),
         allocate fresh blocks for the rest of the prompt, and report how
         many context tokens came from the cache (the engine skips
-        prefilling them)."""
+        prefilling them).  `keys`: the prompt's `chain_keys`, where the
+        caller has them."""
         if self._lane_blocks[lane]:
             raise ValueError(f"lane {lane} already allocated")
         if len(tokens) > self.max_seq_len:
             raise ValueError(f"prompt of {len(tokens)} exceeds max_seq_len "
                              f"{self.max_seq_len}")
-        entries = self._match_chain(tokens)
+        entries = self._match_chain(tokens, keys)
         # Pop spilled payloads out of the tier FIRST: once held here,
         # the allocations below can spill other blocks into the tier
         # without LRU pressure dropping the very chain being restored.
@@ -583,15 +600,9 @@ class PagedKVCache:
         self._install_lane(lane, cached + tail, cached_len)
         self._lane_parent[lane] = _ROOT_HASH
         if cached:
-            # Rebuild the chain cursor at the sealed boundary so blocks
-            # sealed later extend the same chain.
-            parent = _ROOT_HASH
-            bs = self.block_size
-            for i in range(len(cached)):
-                parent = hash((parent,
-                               tuple(int(t) for t in
-                                     tokens[i * bs:(i + 1) * bs])))
-            self._lane_parent[lane] = parent
+            # The chain cursor at the sealed boundary, so blocks sealed
+            # later extend the same chain: the hash of the last key.
+            self._lane_parent[lane] = hash(entries[len(cached) - 1][1])
             self.stats["hits"] += 1
             self.stats["hit_tokens"] += cached_len
         else:

@@ -278,11 +278,13 @@ def _flash_forward_impl(q, k, v, causal, scale, block_q, block_k, interpret):
 # in at (layer, block) and attends over the lane's blocks of that layer;
 # both take the whole pool and a layer index, so the pool is never sliced,
 # stacked or copied (`kv_cache.count_pool_copies` checks the compiled
-# program).  The Pallas kernel streams KV blocks from the pool via
-# scalar-prefetched block-table indices (positions past the context length
-# are masked, so unused table entries may point anywhere valid); the dense
-# fallback gathers the table into a contiguous context and masks — it covers
-# CPU tests, odd head dims, and the multi-token prefill path.
+# program).  The Pallas kernel copies the blocks that hold a lane's context
+# out of the pool by scalar-prefetched block-table indices, a run of blocks
+# at a time, and no others (unused table entries are never read; rows past
+# the context length inside the last block are masked); the dense fallback
+# gathers the table into a contiguous context and masks (there unused
+# entries may point anywhere valid) — it covers CPU tests, odd head dims,
+# and the multi-token prefill path.
 
 KV_ROW_ALIGN = 128
 # Blocks written per trip of the write loop: a T=1 step of 8 lanes is
@@ -415,62 +417,177 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
     return out.astype(q.dtype)
 
 
-def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_ref, l_ref, acc_ref, *, block_size: int,
-                         scale: float, n_blocks: int):
-    """One (lane, kv_block) grid step of single-query paged attention.
+# VMEM the decode kernel holds in cache rows: a run of blocks of each pool,
+# twice (one run being multiplied, the next in flight).
+_PAGED_RUN_VMEM = 4 << 20
+# A run's blocks are fetched by one DMA each, started and awaited in
+# straight-line code: past this many a run is mostly that code.
+_PAGED_RUN_BLOCKS = 16
 
-    Scalar-prefetched block tables and the layer index route each grid
-    step's K/V DMA from the engine's pool to VMEM (see the in_specs index
-    maps); this kernel only sees k/v [BS, W] rows as they are stored and
-    q [H, W] block-diagonal (head h's query in the columns of its kv
-    head, zeros elsewhere), so a row-by-row dot gives per-head scores with
-    no relayout of K.  The accumulator is [H, W]: head h's output is its
-    kv head's column slice, taken outside.  Online softmax state persists
-    in scratch across the lane's kv sweep, exactly like the flash kernel
-    above; blocks at/past the context length are skipped entirely (their
-    DMA still lands, but compute is gated)."""
-    del layer_ref                       # only the index maps read it
+
+def paged_blocks_per_step(block_size: int, width: int, itemsize: int,
+                          max_blocks: int) -> int:
+    """Blocks in a run of the decode kernel: the power of two that fits
+    `_PAGED_RUN_VMEM` (2 pools x 2 buffers x rows x width), at most
+    `_PAGED_RUN_BLOCKS` and the table's length.  256 tokens at the rows
+    the serve cells store (bf16, 1664 and 2048 columns, blocks of 16):
+    in the sweep of PERF.md section 6, PR 32, runs of 8 and of 16 blocks
+    read within 4% of each other and 16 was ahead on long contexts."""
+    fit = _PAGED_RUN_VMEM // (4 * block_size * width * itemsize)
+    kb = 1
+    while kb * 2 <= min(fit, _PAGED_RUN_BLOCKS, max_blocks):
+        kb *= 2
+    return kb
+
+
+def _head_columns(h: int, kh: int, d: int, w: int, dtype):
+    """Where head h's query sits in a [H, W] block-diagonal query: `own`
+    [H, W], the columns of its kv head h // (H / KH), and `spread` [D, W],
+    0/1 with column c holding dim c % D.  `where(own, q @ spread, 0)` lays
+    q [.., H, D] out there and `where(own, o, 0) @ spread.T` takes an
+    output's own columns back, each one exact fusion (a broadcast, a
+    reshape and a pad of [B, H, KH, D] are a relayout each for heads
+    narrower than the lane width)."""
+    col = np.arange(w)
+    own = col[None, :] // d == np.arange(h)[:, None] // (h // kh)
+    spread = col[None, :] % d == np.arange(d)[:, None]
+    return own, spread.astype(dtype)
+
+
+def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, sems, state, m_ref, l_ref,
+                         acc_ref, *, scale: float):
+    """One lane of single-query paged attention: a grid step sweeps the
+    lane's context run by run, R cache blocks a run, and only the runs
+    that hold context.
+
+    The pools stay where they are (HBM); the scalar-prefetched block
+    table, context lengths and layer index say which [BS, W] blocks of
+    stored rows to copy into k_buf / v_buf [2, R, BS, W], one DMA a live
+    block, the next run (or the next lane's first) in flight while this
+    one is multiplied.  q [H, W] is block-diagonal (head h's query in the
+    columns of its kv head, zeros elsewhere), so a row-by-row dot gives
+    per-head scores with no relayout of K.  The accumulator is [H, W]:
+    head h's output is its kv head's column slice, taken outside.  The
+    scores' product runs in the wider of the query's and the pool's dtype
+    (bf16 x bf16 is exact in the float32 it accumulates in); the softmax
+    state, the probabilities and their product with the values are
+    float32, as in the flash kernel above (the copies bound the kernel:
+    probabilities rounded to the pool's dtype read no faster).  The state
+    is updated once a run where a block is whole tile rows of its dtype,
+    so that a run's blocks are one [R * BS, W] operand without a relayout
+    (one update a block of 16 and a grid step a block held the kernel at
+    10-17% of its roofline: PERF.md section 6, PR 32)."""
+    _, kb, bs, _ = k_buf.shape
+    lanes, mb = bt_ref.shape
     lane = pl.program_id(0)
-    blk = pl.program_id(1)
-    base = blk * block_size
+    layer = layer_ref[0]
+    run_tokens = kb * bs
+    nxt = jnp.minimum(lane + 1, lanes - 1)
+    n_ctx = len_ref[lane]
+    n_runs = (n_ctx + run_tokens - 1) // run_tokens
+    dtype = jnp.promote_types(q_ref.dtype, k_buf.dtype)
 
-    @pl.when(blk == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+    def each_copy(i, run, slot, do):
+        """`do` every DMA of lane i's `run` into buffer `slot`: the
+        blocks that hold context, no others."""
+        n_blocks = (len_ref[i] + bs - 1) // bs
+        for r in range(kb):
+            blk = run * kb + r
 
-    @pl.when(base < len_ref[lane])
-    def _compute():
-        q = q_ref[...].astype(jnp.float32) * scale           # [H, W]
-        k_blk = k_ref[...].astype(jnp.float32)               # [BS, W]
-        v_blk = v_ref[...].astype(jnp.float32)
+            @pl.when(blk < n_blocks)
+            def _(r=r, blk=blk):
+                phys = bt_ref[i, jnp.minimum(blk, mb - 1)]
+                for p, (hbm, buf) in enumerate(((k_hbm, k_buf),
+                                                (v_hbm, v_buf))):
+                    do(pltpu.make_async_copy(
+                        hbm.at[layer, phys], buf.at[slot, r],
+                        sems.at[p, slot]))
+
+    def start(i, run, slot):
+        each_copy(i, run, slot, lambda dma: dma.start())
+
+    @pl.when(lane == 0)
+    def _first():
+        state[0] = 0                # the buffer of this lane's first run
+        state[1] = 0                # 1: that run is already in flight
+        # Rows behind a lane's last block are never fetched: their scores
+        # are masked, and their values must be finite.
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    slot0 = state[0]
+    next_live = (n_runs > 0) & (lane + 1 < lanes) & (len_ref[nxt] > 0)
+
+    @pl.when((n_runs > 0) & (state[1] == 0))
+    def _cold():                    # lane 0, or the lane before was empty
+        start(lane, 0, slot0)
+
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def update(k, v, base):
+        """One online-softmax update with the rows k, v [N, W] of the
+        tokens from `base` on."""
         s = jax.lax.dot_general(
-            q, k_blk, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [H, BS]
+            q_ref[...].astype(dtype), k.astype(dtype),
+            (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * scale      # [H, N]
         pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < len_ref[lane], s, NEG_INF)
+        s = jnp.where(pos < n_ctx, s, NEG_INF)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p, v_blk, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)              # [H, W]
+        pv = jnp.dot(p, v.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)     # [H, W]
         acc_ref[...] = acc_ref[...] * alpha + pv
 
-    @pl.when(blk == n_blocks - 1)
-    def _finalize():
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+    def sweep(run, carry):
+        slot = (slot0 + run) & 1
+
+        @pl.when(run + 1 < n_runs)
+        def _ahead():
+            start(lane, run + 1, 1 - slot)
+
+        @pl.when((run + 1 == n_runs) & next_live)
+        def _next_lane():
+            start(nxt, 0, 1 - slot)
+
+        each_copy(lane, run, slot, lambda dma: dma.wait())
+        base = run * run_tokens
+        if bs % (32 // k_buf.dtype.itemsize) == 0:
+            # Whole tile rows: the run's blocks are one operand, and the
+            # softmax state (a max, an exp, a rescale of the accumulator)
+            # is updated once for the run.
+            update(k_buf[slot].reshape(run_tokens, -1),
+                   v_buf[slot].reshape(run_tokens, -1), base)
+        else:
+            # A block that is part of a tile row does not stack without a
+            # relayout: one update a block.
+            for r in range(kb):
+                @pl.when(base + r * bs < n_ctx)
+                def _(r=r):
+                    update(k_buf[slot, r], v_buf[slot, r], base + r * bs)
+        return carry
+
+    jax.lax.fori_loop(0, n_runs, sweep, 0)
+
+    @pl.when(n_runs > 0)
+    def _advance():
+        state[0] = (slot0 + n_runs) & 1
+
+    state[1] = next_live.astype(jnp.int32)
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+        o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                            layer=0, *, kv_heads: Optional[int] = None,
                            scale: Optional[float] = None,
+                           blocks_per_step: Optional[int] = None,
                            use_kernel: Optional[bool] = None,
                            interpret: Optional[bool] = None):
     """Single-query paged attention: q [B, H, D] (one decode token per
@@ -479,7 +596,13 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     where the head dim allows, the masked-dense path on CPU (the
     interpreter is too slow for the engine tests) and for other head dims
     (logged once per shape on TPU).  ctx_lens counts tokens already
-    written to the pool INCLUDING the current one."""
+    written to the pool INCLUDING the current one.
+
+    The kernel takes a lane a grid step and a run of `blocks_per_step`
+    blocks at a time (by default what `paged_blocks_per_step` reads from
+    the pool's block size, row width and dtype; the argument is the
+    sweep's and the tests'): a lane costs the runs that hold its context,
+    an inactive lane (`ctx_lens` 0) nothing, and comes out zero."""
     b, h, d = q.shape
     kh = kv_heads or h
     if use_kernel is None:
@@ -497,45 +620,47 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens,
         interpret = _interpret_kernels()
     _, _, bs, w = k_pool.shape
     mb = block_tables.shape[1]
+    kb = min(blocks_per_step or paged_blocks_per_step(
+        bs, w, k_pool.dtype.itemsize, mb), mb)
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
-    # Head h's query sits in the columns of kv head h // (H / KH).
-    own = jnp.arange(h)[:, None] // (h // kh) == jnp.arange(kh)[None, :]
-    q_rows = pack_kv_rows(jnp.where(own[None, :, :, None], q[:, :, None, :],
-                                    jnp.zeros((), q.dtype)))    # [B, H, W]
-    kernel = functools.partial(
-        _paged_decode_kernel, block_size=bs, scale=scale, n_blocks=mb)
+    own, spread = _head_columns(h, kh, d, w, q.dtype)
+    q_rows = jnp.where(own, jnp.einsum(
+        "bhd,dw->bhw", q, spread, precision=jax.lax.Precision.HIGHEST),
+        jnp.zeros((), q.dtype))                                 # [B, H, W]
+    lane_spec = pl.BlockSpec((None, h, w), lambda i, bt, ln, ly: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,      # block tables, context lengths, layer
-        grid=(b, mb),
-        in_specs=[
-            pl.BlockSpec((None, h, w), lambda i, j, bt, ln, ly: (i, 0, 0)),
-            pl.BlockSpec((None, None, bs, w),
-                         lambda i, j, bt, ln, ly: (ly[0], bt[i, j], 0, 0)),
-            pl.BlockSpec((None, None, bs, w),
-                         lambda i, j, bt, ln, ly: (ly[0], bt[i, j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((None, h, w),
-                               lambda i, j, bt, ln, ly: (i, 0, 0)),
+        grid=(b,),
+        in_specs=[lane_spec, pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=lane_spec,
         scratch_shapes=[
+            pltpu.VMEM((2, kb, bs, w), k_pool.dtype),
+            pltpu.VMEM((2, kb, bs, w), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),        # [pool, buffer]
+            pltpu.SMEM((2,), jnp.int32),
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, 1), jnp.float32),
             pltpu.VMEM((h, w), jnp.float32),
         ],
     )
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_paged_decode_kernel, scale=scale),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, w), q.dtype),
         interpret=interpret,
+        # Lane by lane in order: a lane starts the next one's first fetch.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         # The instruction's name in the HLO and so in a device trace: in
         # the engine's layer scan it would be `closed_call.N` without.
         name="paged_decode_attention",
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), q_rows, k_pool, v_pool)
-    # [B, H, W] -> head h's own D columns.
-    out = unpack_kv_rows(out, kh, d)                             # [B,H,KH,D]
-    return jnp.sum(jnp.where(own[None, :, :, None], out,
-                             jnp.zeros((), out.dtype)), axis=2)
+    # [B, H, W] -> head h's own D columns, the same way back.
+    return jnp.einsum("bhw,dw->bhd",
+                      jnp.where(own, out, jnp.zeros((), out.dtype)), spread,
+                      precision=jax.lax.Precision.HIGHEST)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens, q_positions,

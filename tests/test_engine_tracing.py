@@ -331,3 +331,48 @@ def test_engines_of_k_and_v_rows_have_no_latent_counters():
     assert "latent" not in stats and "moe" not in stats
     assert stats["prefill"] == {"steps": 2, "lanes": 2, "rows": 32,
                                 "rows_valid": 11}
+
+
+def test_an_engine_of_k_and_v_rows_counts_the_decode_kernels_live_runs():
+    """`stats()["paged"]` (PR 32): the T=1 steps, the context tokens their
+    lanes attended over and the runs of the decode kernel that held
+    context, of `decode_steps` x lanes x runs a lane: host integers summed
+    while a batch is built, in a step's one `engine/step` record's shadow
+    (no ring event of their own)."""
+    engine = _engine()
+    engine.generate(list(range(1, 6)), 2)          # compile both shapes
+    s0, seq = engine.stats(), _last_seq()
+    out = engine.generate(list(range(1, 12)), 8)
+    s1 = engine.stats()
+    grew = {k: s1["paged"][k] - s0["paged"][k] for k in s1["paged"]}
+    assert all(type(v) is int for v in s1["paged"].values())
+    # seven T=1 steps, over contexts of 12, 13, ... tokens (the first
+    # output token comes from the prefill)
+    run = engine._paged_run
+    assert run % engine.cache.block_size == 0 and run > 0
+    ctx = range(12, 11 + len(out))
+    assert grew == {"decode_steps": len(out) - 1, "ctx_tokens": sum(ctx),
+                    "runs_live": sum(-(-c // run) for c in ctx)}
+    since = [e for e in events.snapshot(plane="engine") if e["seq"] > seq]
+    assert {e["kind"] for e in since} <= {"step", "submit", "admit",
+                                          "finish", "prefix_miss",
+                                          "prefix_hit"}
+
+
+def test_a_programs_first_call_freezes_what_it_left_on_the_heap():
+    """The engine collects and freezes the heap behind the first call of
+    each step program (PR 32): what tracing and compiling leave there is
+    out of the collector's way before the engine serves, and a later full
+    collection walks the young objects alone.  A shape that has run does
+    not freeze again: nothing a request allocates is frozen with it."""
+    import gc
+
+    gc.unfreeze()
+    engine = _engine()
+    assert gc.get_freeze_count() == 0
+    engine.generate(list(range(1, 6)), 2)          # compile both shapes
+    frozen = gc.get_freeze_count()
+    assert frozen > 1000
+    engine.generate(list(range(1, 12)), 8)         # both shapes have run
+    assert gc.get_freeze_count() <= frozen         # some have died since
+    gc.unfreeze()

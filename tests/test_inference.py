@@ -138,6 +138,53 @@ def test_prefix_cache_seal_match_adopt():
     assert len(cache.match_prefix(toks + [99])) == 3
 
 
+@pytest.mark.parametrize("prompt", [
+    list(range(1, 13)) + [99, 98],               # three cached blocks, a tail
+    list(range(1, 13)),                          # ends on a block's edge
+    list(range(1, 5)) + [77] + list(range(6, 14)),   # parts after a block
+    [50, 51, 52],                                # shorter than a block
+])
+def test_prefix_lookups_by_chain_keys_match_the_walk(prompt):
+    """A caller that has a prompt's `chain_keys` (the engine: `submit`
+    makes them) gets from `match_prefix`, `can_admit_prefix` and
+    `adopt_prefix` what the walk over the tokens gives, and the adopted
+    lane's chain cursor is where the walk left it: blocks sealed behind
+    it extend the same chain."""
+    from ray_tpu.inference.kv_cache import chain_hashes, chain_keys
+
+    def cache_with_three_sealed():
+        cache = PagedKVCache(n_layers=1, kv_heads=1, head_dim=4,
+                             num_blocks=12, block_size=4, max_lanes=2,
+                             max_seq_len=32)
+        cache.alloc_lane(0, 12)
+        cache.seq_lens[0] = 12
+        cache.seal_full_blocks(0, list(range(1, 13)))
+        return cache
+
+    keys = chain_keys(prompt, 4)
+    assert [hash(k) for k in keys] == chain_hashes(prompt, 4)
+    assert len(keys) == (len(prompt) - 1) // 4
+    walked, keyed = cache_with_three_sealed(), cache_with_three_sealed()
+    assert keyed.match_prefix(prompt, keys) == walked.match_prefix(prompt)
+    for headroom in (0, 6, 9):
+        assert keyed.can_admit_prefix(prompt, headroom, keys=keys) == \
+            walked.can_admit_prefix(prompt, headroom)
+    assert keyed.adopt_prefix(1, prompt, keys) == walked.adopt_prefix(
+        1, prompt)
+    assert keyed.lane_blocks(1) == walked.lane_blocks(1)
+    assert keyed._lane_parent[1] == walked._lane_parent[1] == (
+        hash(keys[len(walked.match_prefix(prompt)) - 1])
+        if walked.match_prefix(prompt) else 0)
+    # What the lane seals next hangs on the same chain in both.
+    grown = prompt + list(range(200, 200 + 8))
+    for cache in (walked, keyed):
+        cache.ensure_capacity(1, len(grown))
+        cache.seq_lens[1] = len(grown)
+        cache.seal_full_blocks(1, grown)
+    assert keyed.match_prefix(grown + [1]) == walked.match_prefix(grown + [1])
+    assert len(keyed.match_prefix(grown + [1])) == len(grown) // 4
+
+
 def test_prefix_cache_lru_eviction_under_pressure():
     cache = PagedKVCache(n_layers=1, kv_heads=1, head_dim=4, num_blocks=4,
                          block_size=4, max_lanes=2, max_seq_len=16)
@@ -233,36 +280,84 @@ def test_paged_kv_update_masks_invalid_lanes(case):
         assert not np.asarray(got[..., kh * d:]).any()         # pad columns
 
 
-@pytest.mark.parametrize("kh,q_per_kv,layers,layer", [
-    pytest.param(2, 1, 1, 0, id="mha"),
-    pytest.param(2, 4, 1, 0, id="gqa4"),
-    pytest.param(2, 1, 3, 1, id="mha_layer_1_of_3"),
-    pytest.param(3, 2, 3, 2, id="gqa2_padded_row_layer_2_of_3"),
+def _kernel_case(id, kh=2, q_per_kv=1, layers=1, layer=0, d=64, bs=8, mb=4,
+                 dtype=jnp.float32, ctx=(5, 17, 32), kb=(None,)):
+    """A case of the decode kernel's test: `ctx` tokens a lane (0: an
+    inactive lane, its table all zero), `kb` the `blocks_per_step` values
+    that must all give the reference's output (None: the kernel's own)."""
+    return pytest.param(kh, q_per_kv, layers, layer, d, bs, mb, dtype, ctx,
+                        kb, id=id)
+
+
+# Contexts over runs of 2 blocks of 8 (16 tokens): one token, a run's edge,
+# the middle of the second run, an inactive lane, every block of the table.
+_RUN_EDGES = (1, 16, 21, 0, 64)
+
+
+@pytest.mark.parametrize("kh,q_per_kv,layers,layer,d,bs,mb,dtype,ctx,kb", [
+    _kernel_case("mha"),                    # partial / several / all blocks
+    _kernel_case("gqa4", q_per_kv=4),
+    _kernel_case("mha_layer_1_of_3", layers=3, layer=1),
+    _kernel_case("gqa2_padded_row_layer_2_of_3", kh=3, q_per_kv=2, layers=3,
+                 layer=2),
+    _kernel_case("runs_of_2_blocks_edges_and_an_inactive_lane", mb=8,
+                 ctx=_RUN_EDGES, kb=(2,)),
+    _kernel_case("blocks_per_step_1_2_and_default_agree", mb=8,
+                 ctx=_RUN_EDGES, kb=(1, 2, None)),
+    _kernel_case("gqa4_layer_1_of_2_runs_of_4_blocks", q_per_kv=4, layers=2,
+                 layer=1, mb=8, ctx=(33, 1, 64, 32), kb=(4,)),
+    _kernel_case("gpt2xl_row_25x64_f32", kh=25, mb=8, ctx=(40, 0, 64),
+                 kb=(2, None)),
+    _kernel_case("bf16_blocks_of_16_stacked", bs=16, mb=8, dtype=jnp.bfloat16,
+                 ctx=(1, 32, 45, 0, 128), kb=(1, 2, None)),
+    _kernel_case("bf16_blocks_of_8_one_update_a_block", mb=8,
+                 dtype=jnp.bfloat16, ctx=_RUN_EDGES, kb=(1, 2, None)),
+    _kernel_case("bf16_gqa4_blocks_of_16", q_per_kv=4, bs=16,
+                 dtype=jnp.bfloat16, ctx=(17, 64, 3), kb=(2,)),
+    _kernel_case("gpt2xl_row_25x64_bf16_blocks_of_16", kh=25, bs=16,
+                 dtype=jnp.bfloat16, ctx=(50, 16, 64), kb=(2, None)),
+    _kernel_case("heads_of_128_bf16_blocks_of_16", kh=4, d=128, bs=16,
+                 dtype=jnp.bfloat16, ctx=(64, 0, 31), kb=(None,)),
 ])
-def test_paged_decode_kernel_matches_reference(kh, q_per_kv, layers, layer):
+def test_paged_decode_kernel_matches_reference(kh, q_per_kv, layers, layer,
+                                               d, bs, mb, dtype, ctx, kb):
     rng = np.random.default_rng(0)
-    b, d, bs, mb = 3, 64, 8, 4
+    b = len(ctx)
     h = kh * q_per_kv
-    nb = 16
-    q = jnp.asarray(rng.standard_normal((b, h, d)), jnp.float32)
-    k_blocks = rng.standard_normal((layers, nb, bs, kh, d)).astype(np.float32)
-    v_blocks = rng.standard_normal((layers, nb, bs, kh, d)).astype(np.float32)
-    k_pool, v_pool = _stored_pool(k_blocks), _stored_pool(v_blocks)
-    tables = jnp.asarray(rng.permutation(nb)[:b * mb].reshape(b, mb),
-                         jnp.int32)
-    ctx_lens = jnp.asarray([5, 17, 32], jnp.int32)   # partial/multi/full
-    out_k = paged_decode_attention(q, k_pool, v_pool, tables, ctx_lens,
-                                   layer, kv_heads=kh, use_kernel=True,
-                                   interpret=True)
-    out_ref = paged_attention_reference(
-        q[:, None], k_pool, v_pool, tables, ctx_lens,
-        (ctx_lens - 1)[:, None], layer, kv_heads=kh)[:, 0]
+    nb = b * mb + 1
+    # Inputs as the dtype holds them, so that the float64 ground truth
+    # differs from the kernel by arithmetic alone.
+    as_stored = lambda a: np.asarray(jnp.asarray(a, dtype), np.float32)
+    q = as_stored(rng.standard_normal((b, h, d)))
+    k_blocks = as_stored(rng.standard_normal((layers, nb, bs, kh, d)))
+    v_blocks = as_stored(rng.standard_normal((layers, nb, bs, kh, d)))
+    k_pool = _stored_pool(k_blocks).astype(dtype)
+    v_pool = _stored_pool(v_blocks).astype(dtype)
+    tables = 1 + rng.permutation(nb - 1)[:b * mb].reshape(b, mb)
+    ctx_lens = np.asarray(ctx, np.int32)
+    live = ctx_lens > 0
+    tables[~live] = 0                               # as the cache leaves it
+    tables = jnp.asarray(tables, jnp.int32)
     want = _dense_paged_attention(
-        q[:, None], k_blocks[layer], v_blocks[layer], tables, ctx_lens,
-        np.asarray(ctx_lens - 1)[:, None])[:, 0]
-    np.testing.assert_allclose(np.asarray(out_k), want, atol=2e-5, rtol=2e-5)
-    np.testing.assert_allclose(np.asarray(out_ref), want, atol=2e-5,
-                               rtol=2e-5)
+        q[live, None], k_blocks[layer], v_blocks[layer],
+        np.asarray(tables)[live], ctx_lens[live],
+        (ctx_lens[live] - 1)[:, None])[:, 0]
+    # float32 as before this kernel took runs; bf16: the output's rounding.
+    tol = 2e-5 if dtype == jnp.float32 else 1.6e-2
+    for blocks_per_step in kb:
+        out_k = np.asarray(paged_decode_attention(
+            jnp.asarray(q, dtype), k_pool, v_pool, tables,
+            jnp.asarray(ctx_lens), layer, kv_heads=kh,
+            blocks_per_step=blocks_per_step, use_kernel=True,
+            interpret=True), np.float32)
+        assert np.isfinite(out_k).all()             # the inactive lane too
+        np.testing.assert_allclose(out_k[live], want, atol=tol, rtol=tol)
+    out_ref = paged_attention_reference(
+        jnp.asarray(q, dtype)[:, None], k_pool, v_pool, tables,
+        jnp.asarray(ctx_lens), jnp.asarray(ctx_lens - 1)[:, None], layer,
+        kv_heads=kh)[:, 0]
+    np.testing.assert_allclose(np.asarray(out_ref, np.float32)[live], want,
+                               atol=tol, rtol=tol)
 
 
 # ---------------------------------------------------------------------------

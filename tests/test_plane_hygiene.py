@@ -338,14 +338,17 @@ def test_the_engine_has_one_loop_and_no_option_sets_its_depth():
 
 
 def test_latent_prefill_and_share_counters_are_host_sums_in_build_batch():
-    """`stats()["latent"]`, `stats()["prefill"]` and a share's total
+    """`stats()["latent"]`, `stats()["paged"]` (PR 32: the T=1 steps over a
+    K/V cache, their context tokens and the runs of the decode kernel that
+    held context), `stats()["prefill"]` and a share's total
     `assignments` are sums the host makes while it builds a batch: no
     transfer, no device array and no ring event of their own.  The latent
     kernel keeps the name the benchmark's readers find it by."""
     src = (PKG / "inference" / "engine.py").read_text()
     build = src[src.index("    def _build_batch("):
                 src.index("    def _run_step(")]
-    for counter in ("self._prefill", "self._latent", "self._tokens_run"):
+    for counter in ("self._prefill", "self._latent", "self._paged",
+                    "self._tokens_run"):
         sites = [m.start() for m in re.finditer(re.escape(counter) + r"\b",
                                                 src)]
         writes = [m.start() for m in re.finditer(
@@ -362,3 +365,6 @@ def test_latent_prefill_and_share_counters_are_host_sums_in_build_batch():
     names = re.findall(r'name="(\w+)"',
                        (PKG / "ops" / "attention.py").read_text())
     assert "latent_decode_attention" in names
+    # one Pallas call of that name: `paged_decode_roofline` divides by
+    # every kernel's calls, and two readers find this one by its name
+    assert names.count("paged_decode_attention") == 1

@@ -22,7 +22,8 @@ from ray_tpu._private.accelerators import (
 from ray_tpu.inference.kv_cache import (count_pool_copies,
                                         count_weight_bytes_copied)
 from ray_tpu.models import gpt
-from ray_tpu.ops.attention import kv_row_width, paged_decode_attention
+from ray_tpu.ops.attention import (kv_row_width, paged_blocks_per_step,
+                                   paged_decode_attention)
 from ray_tpu.parallel import MeshConfig, create_mesh
 from ray_tpu.parallel.sharding import named_sharding, tree_shardings
 
@@ -99,6 +100,35 @@ def test_paged_decode_kernel_compiles_for_v5e_gqa(v5e, as_on_chip):
         arg((lanes, mb), jnp.int32), arg((lanes,), jnp.int32),
         arg((), jnp.int32)).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("h,d", [(25, 64), (16, 128)],
+                         ids=["gpt2xl_25x64_in_1664", "olmoe_16x128_in_2048"])
+def test_paged_decode_kernel_compiles_for_v5e_at_the_serve_cells_shapes(
+        v5e, as_on_chip, h, d):
+    """`serve_gpt2xl_decode`'s and `serve_olmoe_decode`'s call: 16 lanes,
+    64 blocks of 16 a lane, bf16.  One kernel, a lane a grid step: the
+    sweep over the lane's context is inside it, run by run of R > 1 blocks
+    and only over the runs that hold context, where until PR 32 every one
+    of the lane's 64 blocks was a grid step (PERF.md section 6)."""
+    lanes, bs, nb, mb = 16, 16, 512, 64
+    arg = _arg_on(v5e[0])
+    pool = arg(_pool_shape(2, nb, bs, h, d), jnp.bfloat16)
+    args = (arg((lanes, h, d), jnp.bfloat16), pool, pool,
+            arg((lanes, mb), jnp.int32), arg((lanes,), jnp.int32),
+            arg((), jnp.int32))
+    text = jax.jit(paged_decode_attention).lower(*args).compile().as_text()
+    assert [k.split(".")[0] for k in _kernel_names(text)] == [
+        "paged_decode_attention"]
+    run = paged_blocks_per_step(bs, pool.shape[3], 2, mb)
+    assert 1 < run <= mb and mb % run == 0
+    # 2 pools x 2 buffers of a run's rows: a few MB of the 16 a core has
+    assert 4 * run * bs * pool.shape[3] * 2 <= 4 * 2 ** 20
+    calls = [e for e in jax.make_jaxpr(paged_decode_attention)(*args).eqns
+             if e.primitive.name == "pallas_call"]
+    assert len(calls) == 1
+    steps_a_lane = math.prod(calls[0].params["grid_mapping"].grid) // lanes
+    assert steps_a_lane <= mb // run
 
 
 def _kernel_names(text):
