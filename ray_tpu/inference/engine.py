@@ -56,6 +56,17 @@ tokens fall in and reads the blocks it attends over in the engine's one
 buffer
 (`compiled_steps()` reports `pool_copies`, which must be 0).
 
+A cache whose lanes hold windows (kv_cache.py: EVA's exact window behind
+the closed windows' summary rows) adds a third program, the compaction: when
+a lane's next position opens a window, the host closes the one before
+(`PagedKVCache.close_window` rewrites the lane's table and hands the
+window's blocks back) and dispatches `model.compact_cached` for it, a
+[prefill_lanes] batch by block index, in order between the step in flight,
+which wrote the window's last row, and the step being built, which reads the
+summaries.  Positions are the host's by counting, so the loop stays a step
+ahead; a prefill chunk is cut at a window's edge, so a slice's rows are
+consecutive in its lane's table.
+
 The weights the step multiplies are prepared once, not in every step:
 `model.serving_params` (one compiled program at construction and at
 every `update_params`) holds each leaf the cached forward would cast at
@@ -76,6 +87,7 @@ import queue
 import threading
 import time
 from dataclasses import dataclass, field
+from functools import partial
 from typing import Dict, List, Optional
 
 import jax
@@ -351,7 +363,11 @@ class InferenceEngine:
     [max_lanes, T] rows whether a lane prefills or not: with many lanes
     and a long chunk those rows are most of the program.  Such an engine
     also has the program at a quarter of T, for steps in which no lane
-    has more than that left to feed (`_prefill_len`).
+    has more than that left to feed (`_prefill_len`), and each of the two
+    at ONE row, for steps in which one lane prefills (an admission behind
+    a cached head is alone in its program more often than not, and three
+    rows of four were padding): four programs, all compiled when the
+    first of a T is (`_warm_widths`).
     """
 
     def __init__(self, model="gpt", config="nano", params=None, *,
@@ -465,6 +481,17 @@ class InferenceEngine:
         self._paged_run = block_size * paged_blocks_per_step(
             block_size, self.cache.k.shape[3], self.cache.k.dtype.itemsize,
             self.cache.max_blocks_per_seq)
+        # Over a windowed cache `_paged` counts the rows attended (what the
+        # kernel reads), `_eva` the same T=1 steps with their true context
+        # beside those rows.  The compaction program is made at its first
+        # use (`_compact`: fn, argument shapes, seconds of the first call).
+        self._eva = ({"decode_steps": 0, "ctx_tokens": 0, "rows_attended": 0}
+                     if self.cache.window else None)
+        self._compact: dict = {}
+        if self.cache.window and self.spec_k > 0:
+            raise NotImplementedError(
+                "speculative decoding over a windowed cache: a verify chunk "
+                "may cross a window's edge (ROADMAP.md)")
         self._thread: Optional[threading.Thread] = None
         self._stopped = False
         self._auto = auto_start
@@ -768,8 +795,21 @@ class InferenceEngine:
             # held context, of `decode_steps` x lanes x runs a lane.
             **({"paged": dict(self._paged)} if self._latent is None else
                {"latent": dict(self._latent)}),
+            **self._eva_stats(),
             **self._moe_stats(),
         }
+
+    def _eva_stats(self) -> dict:
+        """Over a windowed cache: the T=1 steps, the context tokens of
+        their lanes and the rows those lanes attended over instead (host
+        sums of `_build_batch`), the windows closed so far, and the pool's
+        blocks by kind now."""
+        if self._eva is None:
+            return {}
+        summary, exact = self.cache.blocks_by_kind()
+        return {"eva": {**self._eva,
+                        "compactions": self.cache.stats["windows_closed"],
+                        "summary_blocks": summary, "window_blocks": exact}}
 
     def _moe_stats(self) -> dict:
         """An expert configuration's cumulative load, fetched from the
@@ -816,7 +856,7 @@ class InferenceEngine:
                 *self._step_avals[key]).compile()
             name = f"t{t}" + ("_sample" if sample else "") \
                 + ("_spec" if spec else "") \
-                + (f"_lanes{self.prefill_lanes}" if compact else "")
+                + (f"_lanes{compact}" if compact else "")
             text, memory = compiled.as_text(), compiled.memory_analysis()
             out[name] = {
                 "compile_s": round(compile_s, 2),
@@ -826,6 +866,18 @@ class InferenceEngine:
                 "pool_copies": count_pool_copies(text, self.cache.k.shape),
                 "weight_bytes_copied": count_weight_bytes_copied(
                     text, self._step_avals[key][0])}
+        if "compile_s" in self._compact:
+            compiled = self._compact["fn"].lower(
+                *self._compact["avals"]).compile()
+            text, memory = compiled.as_text(), compiled.memory_analysis()
+            out[f"compact_lanes{self.prefill_lanes}"] = {
+                "compile_s": round(self._compact["compile_s"], 2),
+                "custom_calls": text.count("tpu_custom_call"),
+                "donated_bytes": memory.alias_size_in_bytes,
+                "temp_bytes": memory.temp_size_in_bytes,
+                "pool_copies": count_pool_copies(text, self.cache.k.shape),
+                "weight_bytes_copied": count_weight_bytes_copied(
+                    text, self._compact["avals"][0])}
         return out
 
     # ---------------- scheduler ----------------
@@ -854,8 +906,10 @@ class InferenceEngine:
                    self.cache.max_seq_len)
 
     def _growth_reserve(self) -> int:
-        """Blocks every LIVE lane may still claim before finishing (its
-        worst-case final length minus what it already owns).  Admission
+        """Blocks every LIVE lane may still claim before finishing (the most
+        it owns on the way to its worst-case final length, which over a
+        windowed cache is the close of its last window and not its end,
+        minus what it already owns).  Admission
         must leave this much unclaimed or a decode step's block-boundary
         growth can exhaust the pool mid-flight — with no preemption, the
         only safe policy is never to admit past the worst case."""
@@ -863,8 +917,8 @@ class InferenceEngine:
         for lane, req in enumerate(self._lanes):
             if req is None:
                 continue
-            reserve += (self.cache.blocks_needed(self._final_len(req))
-                        - len(self.cache.lane_blocks(lane)))
+            reserve += max(0, self.cache.lane_peak(lane, self._final_len(req))
+                           - len(self.cache.lane_blocks(lane)))
         return reserve
 
     def _admit(self):
@@ -879,11 +933,10 @@ class InferenceEngine:
             req = self._waiting[0]
             if self._head_is_being_sealed(req):
                 break  # its shared head comes from the cache in a moment
-            growth = (self.cache.blocks_needed(self._final_len(req))
-                      - self.cache.blocks_needed(len(req.prompt)))
             if not self.cache.can_admit_prefix(
                     req.prompt, keys=req.chain,
-                    headroom_blocks=self._growth_reserve() + growth):
+                    headroom_blocks=self._growth_reserve(),
+                    final_len=self._final_len(req)):
                 break  # FIFO: don't starve the head with later requests
             reused = self.cache.adopt_prefix(lane, req.prompt, req.chain)
             self._waiting.popleft()
@@ -927,8 +980,8 @@ class InferenceEngine:
             lambda ab: ab[0] == ab[1], zip(req.chain, other.chain)))
             for other in self._lanes
             if other is not None and other.prefilling), default=0)
-        return shared > 0 and shared > len(
-            self.cache.match_prefix(req.prompt, req.chain))
+        return shared > 0 and shared * self.cache.block_size \
+            > self.cache.match_len(req.prompt, req.chain)
 
     def _propose(self, lane: int, req: _Request) -> tuple:
         """Draft for one decode lane: ask the proposer for up to the
@@ -1117,7 +1170,10 @@ class InferenceEngine:
         """One population's step of `t` positions, built from what the step
         in flight will have left, and from here on in flight itself: per
         lane the positions it writes (`chunks`) and whether it samples a
-        token (`news`), which `_commit` takes off again."""
+        token (`news`), which `_commit` takes off again.  Lanes whose next
+        position opens a window have the one before closed first."""
+        if self.cache.window:
+            self._close_windows(lanes)
         batch, chunks = self._build_batch(lanes, t, prefill)
         news = {}
         for lane, req in lanes:
@@ -1152,11 +1208,14 @@ class InferenceEngine:
 
         A `prefill` population under `prefill_lanes` < max_lanes is built
         compact: row i of its arrays is the i-th lane of `live`, `rows`
-        [prefill_lanes] names each row's lane (max_lanes for a row nobody
-        has: it reads lane max_lanes - 1's table fully masked and writes
-        nowhere), and the step gathers and scatters by it."""
+        ([prefill_lanes], or [1] where one lane prefills) names each row's
+        lane (max_lanes for a row nobody has: it reads lane max_lanes - 1's
+        table fully masked and writes nowhere), and the step gathers and
+        scatters by it."""
         compact = prefill and self.prefill_lanes < self.max_lanes
-        n = self.prefill_lanes if compact else self.max_lanes
+        n = self.max_lanes
+        if compact:
+            n = 1 if len(live) == 1 else self.prefill_lanes
         tokens = np.zeros((n, t), np.int32)
         positions = np.zeros((n, t), np.int32)
         valid = np.zeros((n, t), bool)
@@ -1173,7 +1232,9 @@ class InferenceEngine:
             start = int(self.cache.seq_lens[lane]) + req.ahead_len
             fed = req.next_fed
             if fed < len(req.prompt):
-                chunk = min(t, len(req.prompt) - fed)
+                # (cut at a window's edge: a slice lies inside one window)
+                chunk = min(t, len(req.prompt) - fed,
+                            self.cache.window_room(start))
                 tokens[row, :chunk] = req.prompt[fed:fed + chunk]
             else:
                 # Speculative lanes feed [last_token, d_1 .. d_k]; the
@@ -1208,6 +1269,11 @@ class InferenceEngine:
             pf["rows_valid"] += fed_now
         elif t == 1:
             ctx = [int(ctx_lens[lane]) for lane, _ in live]
+            if self._eva is not None:
+                self._eva["decode_steps"] += 1
+                self._eva["ctx_tokens"] += sum(ctx)
+                ctx = [self.cache.rows_held(c) for c in ctx]
+                self._eva["rows_attended"] += sum(ctx)
             seen = self._paged if self._latent is None else self._latent
             seen["decode_steps"] += 1
             seen["ctx_tokens"] += sum(ctx)
@@ -1224,7 +1290,7 @@ class InferenceEngine:
 
     def _run_step(self, batch, spec: bool = False):
         t, sample, args, rows = batch
-        compact = rows is not None
+        compact = 0 if rows is None else len(rows)      # the program's rows
         key = (t, sample, spec, compact)
         fn = self._step_fns.get(key)
         first = fn is None
@@ -1240,7 +1306,7 @@ class InferenceEngine:
         if first:
             t0 = time.perf_counter()
             fn = self._step_fns[key] = self._make_step_fn(sample, spec,
-                                                          compact)
+                                                          bool(compact))
             self._step_avals[key] = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                 (self._served, self.cache.k, self.cache.v, *args, *carried))
@@ -1279,7 +1345,27 @@ class InferenceEngine:
             gc.collect()
             gc.freeze()
         self.cache.update_pools(k, v)
+        if first and compact and not spec:
+            self._warm_widths(batch)
         return next_tok, logp
+
+    def _warm_widths(self, batch) -> None:
+        """A compact program has been made at one of its two widths (one
+        row, `prefill_lanes` rows): make the other now, by a step nobody is
+        in (every row masked and nobody's: it writes nowhere and samples
+        nothing), so that whichever warms one shape of an engine has warmed
+        both and neither compiles when a second lane first prefills beside
+        another, minutes into serving."""
+        t, sample, args, rows = batch
+        for n in {1, self.prefill_lanes} - {len(rows)}:
+            if (t, sample, False, n) in self._step_fns:
+                continue
+            self._run_step((t, sample, (
+                jnp.zeros((n, t), jnp.int32), jnp.zeros((n, t), jnp.int32),
+                jnp.zeros((n, t), bool), args[3], jnp.ones((n,), jnp.int32),
+                jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32),
+                jnp.zeros((n,), jnp.uint32), jnp.full((n,), -1, jnp.int32)),
+                np.full((n,), self.max_lanes, np.int32)))
 
     def _make_step_fn(self, sample: bool, spec: bool = False,
                       compact: bool = False):
@@ -1337,6 +1423,12 @@ class InferenceEngine:
             return (next_tok, *rest,
                     last_tok.at[rows].set(next_tok, mode="drop"))
 
+        def next_logits(params, x):
+            # The next token's logits: the head's first `vocab_size`
+            # columns (all of them, but for a model whose head also
+            # predicts the tokens after the next).
+            return model.lm_head(params, x, config)[..., :config.vocab_size]
+
         def sample_tokens(params, x, gather, temps, seeds, counters):
             """(next tokens,) or, capturing, (next tokens, their logps)."""
             if spec:
@@ -1347,7 +1439,7 @@ class InferenceEngine:
                 # with non-speculative decode.  T = spec_k+1 is small;
                 # the [B, T, V] logits stay on device and the step's
                 # only non-pool output is [B, T] int32.
-                logits = model.lm_head(params, x, config)    # [B, T, V]
+                logits = next_logits(params, x)              # [B, T, V]
                 greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
                 if not sample:
                     out = greedy
@@ -1377,7 +1469,7 @@ class InferenceEngine:
             # the step's only non-pool output is one token id per lane.
             xg = jnp.take_along_axis(
                 x, gather[:, None, None].astype(jnp.int32), axis=1)[:, 0]
-            logits = model.lm_head(params, xg, config)       # [B, V]
+            logits = next_logits(params, xg)                 # [B, V]
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             if not sample:
                 if capture:
@@ -1405,6 +1497,51 @@ class InferenceEngine:
         # the CPU backend ignores donation with a warning, so don't ask.
         donate = () if self.backend == "cpu" else (1, 2)
         return jax.jit(impl, donate_argnums=donate)
+
+    def _close_windows(self, lanes) -> None:
+        """Close the full window of every lane of `lanes` whose next
+        position opens one (`PagedKVCache.close_window`: the table is
+        rewritten now, on the host, which knows positions by counting and
+        every token of the window: its last was sampled by a step already
+        committed) and dispatch the compaction program for them,
+        `prefill_lanes` rows a call: behind the step in flight, which wrote
+        the window's last row, ahead of the step being built."""
+        due = []
+        for lane, req in lanes:
+            start = int(self.cache.seq_lens[lane]) + req.ahead_len
+            if self.cache.window_due(lane, start):
+                due.append(self.cache.close_window(
+                    lane, req.prompt + req.emitted))
+        n = self.prefill_lanes
+        for i in range(0, len(due), n):
+            group = due[i:i + n]
+            tok = spans.begin("engine", "eva_compact")
+            src, dst = (np.zeros((n, len(group[0][j])), np.int32)
+                        for j in (0, 1))
+            for row, (s, d) in enumerate(group):
+                src[row], dst[row] = s, d
+            args = (self._served, self.cache.k, self.cache.v,
+                    jnp.asarray(src), jnp.asarray(dst),
+                    jnp.asarray(np.arange(n) < len(group)))
+            first = not self._compact
+            if first:
+                t0 = time.perf_counter()
+                self._compact = {
+                    "fn": self._make_compact_fn(),
+                    "avals": jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+                        x.shape, x.dtype), args)}
+            self.cache.update_pools(*self._compact["fn"](*args))
+            if first:
+                self._compact["compile_s"] = time.perf_counter() - t0
+            spans.end(tok, lanes=len(group))
+
+    def _make_compact_fn(self):
+        """The compaction program: `model.compact_cached` over (served
+        weights, K pool, V pool, src, dst, live), the pools donated like a
+        step's."""
+        donate = () if self.backend == "cpu" else (1, 2)
+        return jax.jit(partial(self.model.compact_cached,
+                               config=self.config), donate_argnums=donate)
 
     def _commit(self, live, chunks, news, toks, lps=None):
         """Apply one dispatch's results: advance prefill cursors, seal

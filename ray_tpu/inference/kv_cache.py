@@ -31,6 +31,26 @@ whose row is a token's latent and the one rotated key its heads share
 index, sealing and eviction never look inside a row; the wire format says
 which kind it carries and a cache installs only its own.
 
+How MANY rows a lane holds also comes from the attention.  One a token, or
+(`window`, `chunk`: EVA) the exact rows of the lane's open window of
+`window` tokens behind one summary row for every `chunk` tokens of each
+closed one (`kind` "windowed").  Both kinds of row have the same shape and
+live in the same pools under the same allocator; what differs is the
+book-keeping.  A lane's table is laid out [summary blocks of windows
+0..w-1 | the open window's blocks], so a lane of n tokens needs a sawtooth
+of blocks (`blocks_needed`), not n / block_size.  When the open window is
+full its lane `close_window`s: fresh blocks for the summaries take the
+window's place in the table (the device program that fills them is the
+engine's to dispatch) and the window's exact blocks go back to the
+allocator in mid-sequence, staying in the prefix index as evictable if they
+were sealed, like any sealed block.  The prefix index then holds two kinds
+of entry: a token block's exact rows under its chain key, as ever, and a
+closed window's summary blocks under the chain hash of the window's last
+token block.  A prefix of m tokens is the summary blocks of the windows it
+completes plus the exact blocks of the window it ends in; a closed
+window's exact blocks serve only a match that ends inside it, and may be
+evicted without breaking a longer one.
+
 Prefix caching (content-addressed block sharing): a block that has been
 completely written ("sealed") is indexed by a hash chain over
 (parent_hash, block_tokens) — the chain hash of a block is a function of
@@ -48,6 +68,7 @@ block-aligned cached prefix instead of re-prefilling it.
 from __future__ import annotations
 
 import collections
+import itertools
 import math
 import re
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
@@ -82,6 +103,22 @@ def chain_keys(tokens: Sequence[int], block_size: int) -> List[Tuple]:
     again: three walks of a 16k-token document were 7.7 ms of an admitting
     iteration on the engine's thread (PERF.md section 6, PR 32)."""
     return list(_iter_chain_keys(tokens, block_size))
+
+
+def _summary_key(last_key: Tuple, part: int) -> Tuple:
+    """The index key of block `part` of a closed window's summary rows:
+    under the chain hash of the window's last token block, so a function of
+    every token up to the window's end, like the rows."""
+    return (hash(last_key), ("summary", part))
+
+
+def _is_summary(key: Tuple) -> bool:
+    return key[1][:1] == ("summary",)
+
+
+def _chain_cursor(key: Tuple) -> int:
+    """The parent hash of whatever follows the block indexed under `key`."""
+    return key[0] if _is_summary(key) else hash(key)
 
 
 def chain_hashes(tokens: Sequence[int], block_size: int) -> List[int]:
@@ -299,6 +336,11 @@ class BlockAllocator:
     def num_free(self) -> int:
         return len(self._free) + len(self._evictable)
 
+    @property
+    def num_unused(self) -> int:
+        """Blocks that hold nothing (free, not merely evictable)."""
+        return len(self._free)
+
     def can_alloc(self, n: int) -> bool:
         return n <= self.num_free
 
@@ -378,17 +420,39 @@ class PagedKVCache:
     def __init__(self, n_layers: int, kv_heads: int, head_dim: int, *,
                  num_blocks: int, block_size: int, max_lanes: int,
                  max_seq_len: int, dtype=jnp.float32,
-                 prefix_cache: bool = True, latent: bool = False):
+                 prefix_cache: bool = True, latent: bool = False,
+                 window: int = 0, chunk: int = 0):
         self.block_size = block_size
         self.max_lanes = max_lanes
         self.max_seq_len = max_seq_len
-        self.max_blocks_per_seq = math.ceil(max_seq_len / block_size)
         self.kv_heads = kv_heads
         self.head_dim = head_dim
+        # Rows a lane holds (module docstring): one a token (`window` 0),
+        # or a window's exact rows behind the closed windows' summaries.
+        # `_win_blocks` exact and `_sum_blocks` summary blocks a window;
+        # a closed window shortens its lane's table by their difference.
+        self.window, self.chunk = window, chunk
+        self._win_blocks = self._sum_blocks = self._shrink = 0
+        if window:
+            rows = window // max(chunk, 1)
+            if latent or chunk < 1 or window % chunk or rows % block_size:
+                raise ValueError(
+                    f"a windowed cache needs K and V pools and a window "
+                    f"({window}) whose summary rows (one per {chunk}) fill "
+                    f"whole blocks of {block_size}")
+            self._win_blocks = window // block_size
+            self._sum_blocks = rows // block_size
+            self._shrink = self._win_blocks - self._sum_blocks
+        # The most table slots a lane fills: at its end or, with windows,
+        # at the close of its last whole one.
+        self.max_blocks_per_seq = max(
+            self.blocks_needed(max_seq_len),
+            self.blocks_needed((max_seq_len - 1) // window * window)
+            if window else 0)
         # What a row holds: K and V rows in a pool each, or (`latent`:
         # kv_heads 1, head_dim the latent and its rotated key together) one
         # latent row in the one pool.  `k` is that pool, `v` None.
-        self.kind = "latent" if latent else "kv"
+        self.kind = ("latent" if latent else "windowed" if window else "kv")
         # The stored layout (module docstring): rows of W columns.
         shape = (n_layers, num_blocks, block_size,
                  kv_row_width(kv_heads, head_dim))
@@ -410,11 +474,16 @@ class PagedKVCache:
         # 64-bit-hash-chain gamble (vLLM makes the same one).
         self._index: Dict[Tuple[int, Tuple[int, ...]], int] = {}
         self._block_key: Dict[int, Tuple[int, Tuple[int, ...]]] = {}
-        self._lane_sealed = [0] * max_lanes     # sealed block count per lane
+        # Token blocks sealed (hashed into the chain) per lane, and windows
+        # closed: the first `_sum_blocks` x closed entries of a lane's
+        # blocks are summaries, and token block i sits at entry
+        # i - closed x `_shrink`.
+        self._lane_sealed = [0] * max_lanes
+        self._lane_closed = [0] * max_lanes
         self._lane_parent = [_ROOT_HASH] * max_lanes   # chain hash cursor
         self.stats = {"hit_tokens": 0, "miss_tokens": 0, "hits": 0,
                       "misses": 0, "sealed_blocks": 0, "imported_blocks": 0,
-                      "restored_blocks": 0}
+                      "restored_blocks": 0, "windows_closed": 0}
         # Optional tiered spill cache (serve/kv_tier): evicted sealed
         # blocks move here instead of being destroyed, and the match /
         # adopt path restores them on hit (the SPILLED index state).
@@ -433,16 +502,66 @@ class PagedKVCache:
         kw.setdefault("max_seq_len", config.max_seq_len)
         kw.setdefault("dtype", config.dtype)
         attn = model.spec(config).attn
-        return cls(config.n_layers, *attn.cache_row(config),
-                   latent=attn.latent, **kw)
+        rows = attn.rows(config)
+        return cls(config.n_layers, rows.kv_heads, rows.head_dim,
+                   latent=attn.pools == 1, window=rows.window,
+                   chunk=rows.chunk, **kw)
 
     # ---------------- host-side lane lifecycle ----------------
 
     def blocks_needed(self, seq_len: int) -> int:
-        return math.ceil(max(seq_len, 1) / self.block_size)
+        """Table slots of a lane that holds `seq_len` tokens.  With windows
+        a sawtooth: the summary blocks of the windows before the last
+        token's, and the blocks its own window has filled so far."""
+        n = max(seq_len, 1)
+        if not self.window:
+            return math.ceil(n / self.block_size)
+        closed, last = divmod(n - 1, self.window)
+        return closed * self._sum_blocks + last // self.block_size + 1
+
+    def rows_held(self, seq_len: int) -> int:
+        """Rows a lane of `seq_len` tokens attends over: `seq_len`, or with
+        windows the summaries of the closed ones and the open one's rows."""
+        if not self.window or seq_len < 1:
+            return seq_len
+        closed, last = divmod(seq_len - 1, self.window)
+        return closed * (self.window // self.chunk) + last + 1
+
+    def peak_blocks(self, final_len: int, closed: int = 0) -> int:
+        """The most blocks a lane owns on its way to `final_len` tokens
+        with `closed` windows already closed: what admission reserves.
+        Without windows that is its end.  With them it is the close of the
+        last window it completes, where the window's blocks and the fresh
+        summary blocks are held together for a moment, if that is still to
+        come and is more than the end needs."""
+        at_end = self.blocks_needed(final_len)
+        last = (max(final_len, 1) - 1) // self.window if self.window else 0
+        if last <= closed:
+            return at_end
+        return max(at_end, (last - 1) * self._sum_blocks + self._win_blocks
+                   + self._sum_blocks)
+
+    def lane_peak(self, lane: int, final_len: int) -> int:
+        return self.peak_blocks(final_len, self._lane_closed[lane])
+
+    def window_room(self, start: int) -> int:
+        """Positions from `start` to the end of its window: a slice never
+        crosses one (no limit without windows)."""
+        return (self.window - start % self.window if self.window
+                else self.max_seq_len)
+
+    def _blocks_to_start(self, prompt_len: int, closed: int = 0) -> int:
+        """Blocks a lane starts with: what holds its prompt, or with
+        windows the summaries of the `closed` windows it adopts and the
+        prompt's part of the next one (the rest come as windows close)."""
+        if not self.window:
+            return self.blocks_needed(prompt_len)
+        return closed * self._sum_blocks + math.ceil(
+            min(prompt_len - closed * self.window, self.window)
+            / self.block_size)
 
     def can_admit(self, prompt_len: int) -> bool:
-        return self.allocator.can_alloc(self.blocks_needed(prompt_len))
+        return self.allocator.can_alloc(self.peak_blocks(prompt_len))
 
     def alloc_lane(self, lane: int, prompt_len: int) -> None:
         """Sequence start without prefix reuse: claim fresh blocks
@@ -452,15 +571,16 @@ class PagedKVCache:
         if prompt_len > self.max_seq_len:
             raise ValueError(f"prompt of {prompt_len} exceeds max_seq_len "
                              f"{self.max_seq_len}")
-        blocks = self.allocator.alloc(self.blocks_needed(prompt_len))
+        blocks = self.allocator.alloc(self._blocks_to_start(prompt_len))
         self._install_lane(lane, blocks, cached_len=0)
 
     def _install_lane(self, lane: int, blocks: List[int],
-                      cached_len: int) -> None:
+                      cached_len: int, closed: int = 0) -> None:
         self._lane_blocks[lane] = blocks
         self.block_tables[lane, :len(blocks)] = blocks
         self.seq_lens[lane] = cached_len
         self._lane_sealed[lane] = cached_len // self.block_size
+        self._lane_closed[lane] = closed
         self._lane_parent[lane] = _ROOT_HASH
         self._dev_tables = None
 
@@ -494,30 +614,68 @@ class PagedKVCache:
         it by construction."""
         if not self.prefix_cache_enabled:
             return []
-        out: List[Tuple] = []
-        for key in (_iter_chain_keys(tokens, self.block_size)
-                    if keys is None else keys):
+
+        def held(key):
             block = self._index.get(key)
             if block is not None:
-                out.append(("dev", key, block))
-            elif self.tier is not None and self.tier.contains(key):
-                out.append(("tier", key, None))
-            else:
+                return ("dev", key, block)
+            if self.tier is not None and self.tier.contains(key):
+                return ("tier", key, None)
+            return None
+
+        out: List[Tuple] = []
+        if self.window:
+            # Whole windows by their summary blocks, then the exact blocks
+            # of the window the match ends in.
+            if keys is None:
+                keys = chain_keys(tokens, self.block_size)
+            per, done = self._win_blocks, 0
+            while (done + 1) * per <= len(keys):
+                parts = [held(_summary_key(keys[(done + 1) * per - 1], s))
+                         for s in range(self._sum_blocks)]
+                if None in parts:
+                    break
+                out += parts
+                done += 1
+            keys = keys[done * per:(done + 1) * per]
+        for key in (_iter_chain_keys(tokens, self.block_size)
+                    if keys is None else keys):
+            entry = held(key)
+            if entry is None:
                 break
+            out.append(entry)
         return out
+
+    def _matched(self, entries: List[Tuple]) -> Tuple[int, int]:
+        """(windows closed, tokens covered) of a matched chain."""
+        summaries = sum(_is_summary(key) for _kind, key, _b in entries)
+        closed = summaries // self._sum_blocks if summaries else 0
+        return closed, (closed * self.window
+                        + (len(entries) - summaries) * self.block_size)
+
+    def match_len(self, tokens: Sequence[int],
+                  keys: Optional[List[Tuple]] = None) -> int:
+        """Tokens of `tokens` that `match_prefix`'s blocks cover."""
+        entries = list(itertools.takewhile(
+            lambda e: e[0] == "dev", self._match_chain(tokens, keys)))
+        return self._matched(entries)[1]
 
     def can_admit_prefix(self, tokens: Sequence[int],
                          headroom_blocks: int = 0,
-                         keys: Optional[List[Tuple]] = None) -> bool:
+                         keys: Optional[List[Tuple]] = None,
+                         final_len: Optional[int] = None) -> bool:
         """Admission check that accounts for reuse: device-matched blocks
         are referenced (not allocated), but matched blocks currently
         parked evictable stop counting as free capacity once taken.
         Spilled matches still cost an allocation (they restore into
-        fresh blocks), so they stay inside `need`."""
-        dev = [b for kind, _k, b in self._match_chain(tokens, keys)
-               if kind == "dev"]
-        need = (self.blocks_needed(len(tokens)) - len(dev)
-                + headroom_blocks)
+        fresh blocks), so they stay inside `need`.  With `final_len` the
+        request is counted at the most blocks it owns on its way there
+        (`peak_blocks`), not at its prompt."""
+        entries = self._match_chain(tokens, keys)
+        dev = [b for kind, _k, b in entries if kind == "dev"]
+        need = (self.peak_blocks(max(final_len or 0, len(tokens)),
+                                 self._matched(entries)[0])
+                - len(dev) + headroom_blocks)
         free_after = (self.allocator.num_free
                       - sum(self.allocator.is_evictable(b) for b in dev))
         return need <= free_after
@@ -551,6 +709,9 @@ class PagedKVCache:
                 usable = pos
                 break
             restores.append((pos, key, payload))
+        if self._sum_blocks > 1 and usable < len(entries) \
+                and _is_summary(entries[usable][1]):
+            usable -= usable % self._sum_blocks   # whole windows only
         entries = entries[:usable]
         restores = [r for r in restores if r[0] < usable]
         dev_blocks = [b for kind, _k, b in entries if kind == "dev"]
@@ -558,9 +719,11 @@ class PagedKVCache:
         # never evict a block this very request is about to reuse.
         for b in dev_blocks:
             self.allocator.incref(b)
+        closed, cached_len = self._matched(entries)
         try:
             fresh = self.allocator.alloc(
-                self.blocks_needed(len(tokens)) - len(dev_blocks))
+                self._blocks_to_start(len(tokens), closed)
+                - len(dev_blocks))
         except RuntimeError:
             for b in dev_blocks:
                 self.allocator.decref(b)
@@ -596,13 +759,11 @@ class PagedKVCache:
                 self.allocator.mark_cached(nb)
                 self.stats["restored_blocks"] += 1
         cached = chain_blocks
-        cached_len = len(cached) * self.block_size
-        self._install_lane(lane, cached + tail, cached_len)
-        self._lane_parent[lane] = _ROOT_HASH
+        self._install_lane(lane, cached + tail, cached_len, closed)
         if cached:
             # The chain cursor at the sealed boundary, so blocks sealed
             # later extend the same chain: the hash of the last key.
-            self._lane_parent[lane] = hash(entries[len(cached) - 1][1])
+            self._lane_parent[lane] = _chain_cursor(entries[-1][1])
             self.stats["hits"] += 1
             self.stats["hit_tokens"] += cached_len
         else:
@@ -618,37 +779,45 @@ class PagedKVCache:
         return (self.prefix_cache_enabled and self._lane_sealed[lane]
                 < int(self.seq_lens[lane]) // self.block_size)
 
-    def seal_full_blocks(self, lane: int, tokens: Sequence[int]) -> None:
+    def seal_full_blocks(self, lane: int, tokens: Sequence[int],
+                         upto: Optional[int] = None) -> None:
         """Index every newly-full block of this lane.  `tokens` is the
         lane's full token sequence (prompt + generated); only the first
         seq_lens[lane] of them have K/V in the pool, and a block seals
         the moment the write cursor crosses its end — mid-prefill too,
         so a concurrent identical prompt can start reusing the prefix
-        before the first request even finishes."""
+        before the first request even finishes.  `upto`: the tokens to take
+        as written where that is more than the committed `seq_lens`
+        (`close_window`)."""
         if not self.prefix_cache_enabled:
             return
         bs = self.block_size
-        full = int(self.seq_lens[lane]) // bs
+        full = (int(self.seq_lens[lane]) if upto is None else upto) // bs
         blocks = self._lane_blocks[lane]
+        behind = self._lane_closed[lane] * self._shrink
         while self._lane_sealed[lane] < full:
             i = self._lane_sealed[lane]
             key = (self._lane_parent[lane],
                    tuple(int(t) for t in tokens[i * bs:(i + 1) * bs]))
-            block = blocks[i]
+            block = blocks[i - behind]
             # First writer wins: if an identical block is already indexed
             # this one stays un-indexed freight (freed normally later);
             # an adopted shared block re-seals as itself (no-op).
             if key not in self._index and block not in self._block_key:
-                self._index[key] = block
-                self._block_key[block] = key
-                self.allocator.mark_cached(block)
-                self.stats["sealed_blocks"] += 1
-                if self.tier is not None:
-                    # Re-sealed on device: the spilled copy is stale
-                    # freight now (content-addressed, so identical).
-                    self.tier.discard(key)
+                self._seal(key, block)
             self._lane_parent[lane] = hash(key)
             self._lane_sealed[lane] += 1
+
+    def _seal(self, key: Tuple, block: int) -> None:
+        """Index `block`'s content under `key`."""
+        self._index[key] = block
+        self._block_key[block] = key
+        self.allocator.mark_cached(block)
+        self.stats["sealed_blocks"] += 1
+        if self.tier is not None:
+            # Re-sealed on device: the spilled copy is stale freight now
+            # (content-addressed, so identical).
+            self.tier.discard(key)
 
     def _on_evict(self, block: int) -> None:
         """Allocator reclaimed a cached block: drop its index entry —
@@ -689,11 +858,21 @@ class PagedKVCache:
             return None
         idx = jnp.asarray(np.asarray([b for _k, b in entries], np.int32))
         k_np, v_np = self.read_blocks(idx)
+        chain = [list(key[1]) for key, _b in entries]
+        if self.window:
+            # The kind of each block by the length of its chain entry: a
+            # summary block carries its whole window's tokens (every block
+            # of one window the same), an exact block its own.
+            per = self._sum_blocks
+            for i in range(0, sum(_is_summary(k) for k, _b in entries), per):
+                at = i // per * self.window
+                chain[i:i + per] = [list(map(int, tokens[
+                    at:at + self.window]))] * per
         return {
             "v": 1,
             "kind": self.kind,
             "block_size": self.block_size,
-            "chain": [list(key[1]) for key, _b in entries],
+            "chain": chain,
             "k": k_np,
             "v_pool": v_np,
         }
@@ -719,8 +898,19 @@ class PagedKVCache:
             return 0            # foreign model shape: refuse quietly
         parent = _ROOT_HASH
         new = []                # (chain_pos, key, block)
+        bs, part = self.block_size, 0
         for i, blk_tokens in enumerate(payload["chain"]):
-            key = (parent, tuple(int(t) for t in blk_tokens))
+            if self.window and len(blk_tokens) == self.window:
+                # A summary block: the chain runs through its window's
+                # token blocks once, at the window's first part.
+                if part == 0:
+                    for j in range(0, self.window, bs):
+                        parent = hash((parent, tuple(
+                            int(t) for t in blk_tokens[j:j + bs])))
+                key = (parent, ("summary", part))
+                part = (part + 1) % self._sum_blocks
+            else:
+                key = (parent, tuple(int(t) for t in blk_tokens))
             present = (key in self._index
                        or (self.tier is not None
                            and self.tier.contains(key)))
@@ -733,7 +923,7 @@ class PagedKVCache:
                 except RuntimeError:
                     break
                 new.append((i, key, b))
-            parent = hash(key)
+            parent = _chain_cursor(key)
         if not new:
             return 0
         idx = jnp.asarray(np.asarray([b for _i, _k, b in new], np.int32))
@@ -770,12 +960,75 @@ class PagedKVCache:
             "tier_blocks": 0 if self.tier is None else len(self.tier),
         }
 
+    # ---------------- windows ----------------
+
+    def window_due(self, lane: int, start: int) -> bool:
+        """Whether the lane must `close_window` before position `start` is
+        written: `start` opens a window and the one before is still open."""
+        return bool(self.window) and start > 0 \
+            and start % self.window == 0 \
+            and self._lane_closed[lane] < start // self.window
+
+    def close_window(self, lane: int, tokens: Sequence[int]) -> Tuple[
+            List[int], List[int]]:
+        """The lane's open window is full (`tokens`: the lane's sequence up
+        to the window's end at least): its exact blocks `src` leave the
+        table and go back to the allocator, fresh blocks `dst` for its
+        summary rows take their place, and (src, dst) are returned for the
+        device program that makes the one from the other.  The caller
+        dispatches that program before any that reads the new table or
+        writes a block handed out after this call; the device runs
+        programs in dispatch order, so whoever is given a `src` block next
+        writes it after the program has read it, and whoever adopts a `dst`
+        block from the index reads it after the program has written it.
+
+        The window's still unsealed exact blocks are sealed first (a match
+        that ends inside this window may use them, and the chain cursor
+        must stand at the window's end), then the summary blocks are
+        indexed under that cursor: first writer wins, as for any block."""
+        done = self._lane_closed[lane]
+        end = (done + 1) * self.window
+        self.seal_full_blocks(lane, tokens, upto=end)
+        blocks = self._lane_blocks[lane]
+        at = done * self._sum_blocks
+        src = blocks[at:]
+        if len(src) != self._win_blocks:
+            raise RuntimeError(f"lane {lane}: window {done} is not full")
+        dst = self.allocator.alloc(self._sum_blocks)
+        if self.prefix_cache_enabled:
+            for part, block in enumerate(dst):
+                key = (self._lane_parent[lane], ("summary", part))
+                if key not in self._index:
+                    self._seal(key, block)
+        blocks[at:] = dst
+        self.block_tables[lane, at:] = 0
+        self.block_tables[lane, at:at + len(dst)] = dst
+        self.allocator.free(src)
+        self._lane_closed[lane] = done + 1
+        self.stats["windows_closed"] += 1
+        self._dev_tables = None
+        return src, dst
+
+    def blocks_by_kind(self) -> Tuple[int, int]:
+        """(summary blocks, exact blocks) the pool holds now, live or
+        cached: the lanes' own and what the prefix index keeps."""
+        summary = {b for b, key in self._block_key.items()
+                   if _is_summary(key)}
+        for blocks, closed in zip(self._lane_blocks, self._lane_closed):
+            summary.update(blocks[:closed * self._sum_blocks])
+        held = self.allocator.num_blocks - self.allocator.num_unused
+        return len(summary), held - len(summary)
+
     # ---------------- lane growth / teardown ----------------
 
     def ensure_capacity(self, lane: int, new_len: int) -> None:
-        """Grow the lane's table as decode crosses block boundaries."""
+        """Grow the lane's table as decode crosses block boundaries (with
+        windows, inside the open one: `close_window` comes first)."""
         if new_len > self.max_seq_len:
             raise RuntimeError(f"lane {lane} exceeded max_seq_len")
+        if self.window and (new_len - 1) // self.window \
+                > self._lane_closed[lane]:
+            raise RuntimeError(f"lane {lane}: window not closed")
         need = self.blocks_needed(new_len)
         blocks = self._lane_blocks[lane]
         while len(blocks) < need:
@@ -806,7 +1059,8 @@ class PagedKVCache:
         order, so whoever gets the block next writes a position before it
         reads it."""
         blocks = self._lane_blocks[lane]
-        keep = max(self.blocks_needed(new_len), self._lane_sealed[lane])
+        keep = max(self.blocks_needed(new_len), self._lane_sealed[lane]
+                   - self._lane_closed[lane] * self._shrink)
         while len(blocks) > keep:
             b = blocks.pop()
             self.allocator.decref(b)
@@ -825,6 +1079,7 @@ class PagedKVCache:
         self.block_tables[lane, :] = 0
         self.seq_lens[lane] = 0
         self._lane_sealed[lane] = 0
+        self._lane_closed[lane] = 0
         self._lane_parent[lane] = _ROOT_HASH
         self._dev_tables = None
 
@@ -834,8 +1089,12 @@ class PagedKVCache:
     # ---------------- device mirrors ----------------
 
     def device_tables(self) -> jax.Array:
+        """The tables as a step takes them: a copy made now.  (`jnp.asarray`
+        of a numpy array is the same memory on the CPU backend, and a
+        step dispatched ahead runs after the host has gone on: a lane's
+        row is rewritten when its window closes and zeroed when it ends.)"""
         if self._dev_tables is None:
-            self._dev_tables = jnp.asarray(self.block_tables)
+            self._dev_tables = jnp.array(self.block_tables)
         return self._dev_tables
 
     def update_pools(self, k: jax.Array, v: Optional[jax.Array]) -> None:

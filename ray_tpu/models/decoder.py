@@ -1,6 +1,7 @@
 """The decoder-only transformer, defined once for every LM family.
 
-A family (models/gpt.py, models/llama.py, models/axk1.py) is a config
+A family (models/gpt.py, models/llama.py, models/axk1.py,
+models/evabyte.py) is a config
 dataclass, its parameter format (`init_params`, `param_specs`) and
 `spec(config)`: a `Spec` naming the parts its block is made of (norms, an
 `Attention`, a `FeedForward`, a leading run of layers with another
@@ -21,7 +22,9 @@ Design (no reference counterpart: Ray hosts models, it doesn't ship them):
     chip and per shard (shard_map over batch and heads) under a mesh, ring
     attention when the mesh has a seq axis > 1; over a paged KV cache,
     ops/attention.py's paged path.  `LATENT`: multi-head latent attention,
-    expanded for a whole sequence, absorbed over a latent paged cache);
+    expanded for a whole sequence, absorbed over a latent paged cache.
+    `EVA`: an exact window beside one summary row for every chunk behind
+    it, `HEADS`'s cached form over a table whose rows are not one a token);
   * `jax.checkpoint` (remat) on the block when configured: trades FLOPs for
     HBM, the standard TPU memory lever.
 
@@ -36,7 +39,7 @@ import functools
 import math
 import types
 from functools import partial
-from typing import Callable, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -58,10 +61,15 @@ def layernorm(x, scale, bias, eps=1e-5):
     return (y * scale + bias).astype(x.dtype)
 
 
-def rmsnorm(x, scale, eps):
+def rmsnorm(x, scale, eps, unit_offset: bool = False, dtype=None):
+    """x / rms(x) * scale in float32, back in x's dtype (or `dtype`: a
+    float32 residual stream normed into the matrices' dtype); with
+    `unit_offset` the learned scale is stored less one."""
     x32 = x.astype(jnp.float32)
     y = x32 * jax.lax.rsqrt(jnp.mean(x32 * x32, -1, keepdims=True) + eps)
-    return (y * scale).astype(x.dtype)
+    if unit_offset:
+        scale = 1.0 + scale.astype(jnp.float32)
+    return (y * scale).astype(dtype or x.dtype)
 
 
 def rope(x, theta: float, offset=0, freqs=None):
@@ -304,29 +312,86 @@ def heads_attention(h, p, spec, config, mesh, position_offset=0):
     return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
 
 
-def heads_attention_cached(h, pools, p, spec, config, block_tables,
-                           positions, valid, ctx_lens):
-    """New K/V rows are written into the whole pools at the layer, then
-    attention runs over the block table in the same buffers
-    (ops/attention.py paged path).  K/V are cached with kv_heads (GQA
-    un-repeated: the whole point of the grouped cache); the paged
-    attention path expands groups itself."""
+def _attend_rows(q, k, v, pools, p, config, block_tables, rows, valid,
+                 n_rows):
+    """The slice's K and V written into the whole pools at the layer, at
+    `rows` [B, T] of each lane's table, then attention over the table's
+    first `n_rows` [B] rows in the same buffers (ops/attention.py paged
+    path), projected back.  K/V are cached with kv_heads (GQA un-repeated:
+    the whole point of the grouped cache); the paged attention path expands
+    groups itself."""
     from ray_tpu.ops.attention import paged_attention, paged_kv_update
 
     layer = p["cache_layer"]
+    k_pool, v_pool = paged_kv_update(*pools, k, v, block_tables, rows, valid,
+                                     layer)
+    attn = paged_attention(q, k_pool, v_pool, block_tables, n_rows, rows,
+                           layer, kv_heads=config.n_kv_heads)
+    return (jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(q.dtype)),
+            (k_pool, v_pool))
+
+
+def heads_attention_cached(h, pools, p, spec, config, block_tables,
+                           positions, valid, ctx_lens):
+    """A token's K and V are one row of its lane's table, at its position.
+    (Per-token rotation at each token's own absolute position: offset =
+    positions[:, 0] with L-consecutive slices means positions must be
+    contiguous per lane, which prefill/decode slices always are.)"""
     q, k, v = _qkv(spec, h, p)
     if spec.rope_theta is not None:
-        # Per-token rotation at each token's own absolute position: offset
-        # = positions[:, 0] with L-consecutive slices means positions must
-        # be contiguous per lane, which prefill/decode slices always are.
         q = rope(q, spec.rope_theta, positions[:, 0])
         k = rope(k, spec.rope_theta, positions[:, 0])
-    k_pool, v_pool = paged_kv_update(*pools, k, v, block_tables,
-                                     positions, valid, layer)
-    attn = paged_attention(q, k_pool, v_pool, block_tables, ctx_lens,
-                           positions, layer, kv_heads=config.n_kv_heads)
-    return (jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype)),
-            (k_pool, v_pool))
+    return _attend_rows(q, k, v, pools, p, config, block_tables, positions,
+                        valid, ctx_lens)
+
+
+def eva_attention(h, p, spec, config, mesh, position_offset=0):
+    """EVA over a whole sequence that starts at position 0 (windows are
+    counted from there): ops/attention.py's `eva_attention`."""
+    from ray_tpu.ops import attention as ops
+
+    q, k, v = _qkv(spec, h, p)
+    q = rope(q, spec.rope_theta, position_offset)
+    k = rope(k, spec.rope_theta, position_offset)
+    attn = ops.eva_attention(q, k, v, p["eva_mu"], p["eva_phi"],
+                             window=config.window_size,
+                             chunk=config.chunk_size, mesh=mesh)
+    return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
+
+
+def eva_attention_cached(h, pools, p, spec, config, block_tables, positions,
+                         valid, ctx_lens):
+    """`heads_attention_cached` over a table whose rows are not one a token:
+    the summary rows of a lane's closed windows, then the exact rows of its
+    open one (`ops.eva_row`).  Rotation goes by the true position, the write
+    and the mask by the row, computed from it here; a slice lies inside one
+    window (the engine cuts chunks at window edges), so its rows are
+    consecutive like its positions.  Single-query attention over the first
+    `eva_row(ctx - 1) + 1` rows IS EVA's one softmax over exact keys and
+    summaries."""
+    from ray_tpu.ops.attention import eva_row
+
+    c = config
+    q, k, v = _qkv(spec, h, p)
+    q = rope(q, spec.rope_theta, positions[:, 0])
+    k = rope(k, spec.rope_theta, positions[:, 0])
+    rows = (eva_row(positions[:, :1], c.window_size, c.chunk_size)
+            + jnp.arange(positions.shape[1], dtype=positions.dtype))
+    n_rows = eva_row(jnp.maximum(ctx_lens - 1, 0), c.window_size,
+                     c.chunk_size) + 1
+    return _attend_rows(q, k, v, pools, p, c, block_tables, rows, valid,
+                        n_rows)
+
+
+def eva_compact(pools, p, config, src, dst, live):
+    """Close a window at one layer: `ops.eva_summarise` with the layer's
+    `eva_mu`, `eva_phi`."""
+    from ray_tpu.ops.attention import eva_summarise
+
+    return eva_summarise(*pools, p["eva_mu"], p["eva_phi"], src, dst, live,
+                         p["cache_layer"], chunk=config.chunk_size,
+                         kv_heads=config.n_kv_heads,
+                         head_dim=config.head_dim)
 
 
 def _latent_qkv(h, p, spec, config, offset):
@@ -416,28 +481,61 @@ def _kvb_served(w, qk_nope: int):
 
 
 @dataclasses.dataclass(frozen=True)
+class CacheRows:
+    """What an attention leaves in a paged cache, as the cache manager
+    needs to know it (`PagedKVCache.for_model`): a stored row's shape, and
+    how many rows a lane holds: one a token (`window` 0), or the exact rows
+    of the open window of `window` tokens behind one summary row for every
+    `chunk` tokens of each closed one."""
+    kv_heads: int
+    head_dim: int
+    window: int = 0
+    chunk: int = 0
+
+
+@dataclasses.dataclass(frozen=True)
 class Attention:
     apply: Callable
     cached: Callable
-    # (kv_heads, head_dim) of a token's stored row, from the config.
-    cache_row: Callable
-    # One latent pool, not a K and a V pool (inference/kv_cache.py).
-    latent: bool = False
+    rows: Callable          # config -> CacheRows
+    # A K and a V pool, or one pool (a latent row: inference/kv_cache.py).
+    pools: int = 2
     # Leaves `serving_params` holds in the activation dtype.
     cast: tuple = ()
     # The leaf `serving_params` re-makes into its served halves, once.
     absorbed: Optional[str] = None
+    # Where a lane's closed window becomes its summary rows:
+    # `compact(pools, p, config, src, dst, live)` at one layer, reading the
+    # leaves `compact_leaves` (`compact_cached`).
+    compact: Optional[Callable] = None
+    compact_leaves: tuple = ()
+    trains: bool = True     # `apply` is fit for the train path
+
+    # The older names, as benchmark/tools/aot_axk1_sizes.py reads them.
+    def cache_row(self, config) -> tuple:
+        rows = self.rows(config)
+        return rows.kv_heads, rows.head_dim
+
+    @property
+    def latent(self) -> bool:
+        return self.pools == 1
 
 
 HEADS = Attention(heads_attention, heads_attention_cached,
-                  cache_row=lambda c: (c.n_kv_heads, c.head_dim),
+                  rows=lambda c: CacheRows(c.n_kv_heads, c.head_dim),
                   cast=("wq", "wk", "wv", "wo"))
 LATENT = Attention(latent_attention, latent_attention_cached,
-                   cache_row=lambda c: (1, c.kv_lora_rank
-                                        + c.qk_rope_head_dim),
-                   latent=True,
+                   rows=lambda c: CacheRows(1, c.kv_lora_rank
+                                            + c.qk_rope_head_dim),
+                   pools=1, trains=False,
                    cast=("w_qa", "w_qb", "w_kva", "w_kvb", "wo"),
                    absorbed="w_kvb")
+EVA = Attention(eva_attention, eva_attention_cached,
+                rows=lambda c: CacheRows(c.n_kv_heads, c.head_dim,
+                                         c.window_size, c.chunk_size),
+                cast=("wq", "wk", "wv", "wo"),
+                compact=eva_compact, compact_leaves=("eva_mu", "eva_phi"),
+                trains=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -469,6 +567,11 @@ class Spec:
     # feed-forward; the other n_layers - first_dense_layers have `ffn`.
     first_dense_layers: int = 0
     lead_ffn: Optional[FeedForward] = None
+    # The dtype the residual stream is added in where it is not the
+    # activations' (float32 under bf16 matrices; `norm` then casts back),
+    # and that of the logits where the head's product is kept wider.
+    residual_dtype: Optional[Any] = None
+    logits_dtype: Optional[Any] = None
 
 
 # --------------------------------------------------------------------------
@@ -548,6 +651,8 @@ def forward_trunk(family, params: dict, tokens: jax.Array, config,
         pos = jax.lax.dynamic_slice_in_dim(params["pos_embed"],
                                            position_offset, tokens.shape[1])
         x = x + pos[None].astype(c.dtype)
+    if spec.residual_dtype is not None:
+        x = x.astype(spec.residual_dtype)
     x = with_logical_constraint(x, ("batch", "length", "act_embed"), mesh=mesh)
 
     aux = None
@@ -576,8 +681,14 @@ def _head(spec: Spec, params: dict, config):
 
 
 def lm_head(family, params: dict, x: jax.Array, config) -> jax.Array:
-    """Project hidden states [..., D] to vocab logits [..., V]."""
-    return x @ _head(family(config), params, config)
+    """Project hidden states [..., D] to vocab logits [..., V] (the head's
+    columns: `vocab_size` of them, or that many for each of a model's
+    prediction heads, the next token's first)."""
+    spec = family(config)
+    head = _head(spec, params, config)
+    if spec.logits_dtype is not None:
+        return jnp.dot(x, head, preferred_element_type=spec.logits_dtype)
+    return x @ head
 
 
 def forward(family, params: dict, tokens: jax.Array, config, mesh=None,
@@ -610,11 +721,16 @@ def loss_fn(family, params: dict, batch: dict, config, mesh=None):
                                            spmd_ce_applicable)
 
     c, spec = config, family(config)
-    if not spec.ffn.trains or spec.attn.latent:
+    if not spec.ffn.trains:
         raise NotImplementedError(
             "training an expert configuration is not supported yet: the "
             "grouped matmul (ops/moe.py) has no backward pass and the "
             "router's auxiliary losses are not computed (ROADMAP.md R1)")
+    if not spec.attn.trains:
+        raise NotImplementedError(
+            "this attention has no train path yet: latent attention is "
+            "served absorbed, EVA's whole-sequence form is plain XLA "
+            "without a backward pass of its own (ROADMAP.md)")
     tokens = batch["tokens"]
     targets = jnp.roll(tokens, -1, axis=1)
     # Last position predicts the rolled-around token 0: always masked.
@@ -780,6 +896,8 @@ def forward_cached(family, params: dict, tokens: jax.Array,
         x = _embed(params, "tok", tokens, c) + _embed(params, "pos", pos, c)
     else:
         x = _embed(params, "tok", tokens, c)
+    if spec.residual_dtype is not None:
+        x = x.astype(spec.residual_dtype)
     pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
     seen = moe_load
     for blocks, n_layers, ffn, first in _stacks(spec, params, c):
@@ -804,6 +922,30 @@ def forward_cached(family, params: dict, tokens: jax.Array,
     x = _norm(spec, x, params, spec.final_norm)
     k_pool, v_pool = pools if len(pools) == 2 else (pools[0], None)
     return (x, k_pool, v_pool) if seen is None else (x, k_pool, v_pool, seen)
+
+
+def compact_cached(family, params: dict, k_pool: jax.Array,
+                   v_pool: jax.Array, src: jax.Array, dst: jax.Array,
+                   live: jax.Array, config):
+    """Close a window of some lanes in every layer of the paged pools, in
+    place (`Attention.compact`): row i's exact rows in pool blocks `src[i]`
+    become the summary rows in blocks `dst[i]`; `live` [N] masks the rows
+    nobody has.  The pools ride the layer loop whole, as in
+    `forward_cached`; of the weights only the attention's
+    `compact_leaves` are read.  Returns (k_pool, v_pool)."""
+    c, spec = config, family(config)
+    pools = (k_pool, v_pool)
+    for blocks, n_layers, _, first in _stacks(spec, params, c):
+
+        def body(pools, layer, first=first):
+            p, i = layer
+            return spec.attn.compact(pools, {**p, "cache_layer": i + first},
+                                     c, src, dst, live), None
+
+        pools, _ = jax.lax.scan(
+            body, pools, ({k: blocks[k] for k in spec.attn.compact_leaves},
+                          jnp.arange(n_layers, dtype=jnp.int32)))
+    return pools
 
 
 # --------------------------------------------------------------------------
@@ -866,5 +1008,6 @@ def bind(family) -> types.SimpleNamespace:
     `model` (with the family's own `init_params`, or given `params`)."""
     return types.SimpleNamespace(spec=family, **{
         f.__name__: partial(f, family) for f in (
-            forward_trunk, forward, lm_head, forward_cached, loss_fn,
-            serving_params, shard_params, num_params, make_train_step)})
+            forward_trunk, forward, lm_head, forward_cached, compact_cached,
+            loss_fn, serving_params, shard_params, num_params,
+            make_train_step)})

@@ -381,6 +381,36 @@ def paged_kv_update(k_pool, v_pool, k_new, v_new, block_tables, positions,
         block_tables, positions, valid, layer)
 
 
+# Rows up to this many bytes are gathered by XLA's own gather.
+_GATHER_ROW_BYTES = 4096
+
+
+def _table_blocks(pool, layer, block_tables):
+    """pool[layer, block_tables]: the blocks of every lane's table,
+    [B, MB, BS, W].  One gather where a row is at most `_GATHER_ROW_BYTES`.
+    A wider row (EvaByte's 4,096 bf16 columns) XLA gathers in halves, each
+    from a slice of the WHOLE pool that it first copies out (2.4 GB four
+    times a layer at that cell's sizes, compiled for a v5e): there the
+    blocks are sliced out one by one into the result, a megabyte each."""
+    _, _, bs, w = pool.shape
+    if w * pool.dtype.itemsize <= _GATHER_ROW_BYTES:
+        return pool[layer, block_tables]
+    b, mb = block_tables.shape
+    flat = block_tables.reshape(-1).astype(jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+
+    def copy(i, out):
+        block = jax.lax.dynamic_slice(pool, (layer, flat[i], zero, zero),
+                                      (1, 1, bs, w))
+        return jax.lax.dynamic_update_slice(out, block[0], (i, zero, zero))
+
+    out = jax.lax.fori_loop(0, b * mb, copy,
+                            jnp.zeros((b * mb, bs, w), pool.dtype),
+                            unroll=_KV_WRITE_UNROLL)
+    return out.reshape(b, mb, bs, w)
+
+
 def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
                               q_positions, layer=0, *,
                               kv_heads: Optional[int] = None, scale=None):
@@ -398,11 +428,11 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
     kh = kv_heads or h
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     max_ctx = block_tables.shape[1] * bs
-    # One gather straight out of the engine's buffer: [B, MB, BS, W].
-    k_ctx = unpack_kv_rows(k_pool[layer, block_tables], kh, d).reshape(
-        b, max_ctx, kh, d)
-    v_ctx = unpack_kv_rows(v_pool[layer, block_tables], kh, d).reshape(
-        b, max_ctx, kh, d)
+    # Straight out of the engine's buffer: [B, MB, BS, W].
+    k_ctx = unpack_kv_rows(_table_blocks(k_pool, layer, block_tables), kh,
+                           d).reshape(b, max_ctx, kh, d)
+    v_ctx = unpack_kv_rows(_table_blocks(v_pool, layer, block_tables), kh,
+                           d).reshape(b, max_ctx, kh, d)
     if h != kh:
         k_ctx = jnp.repeat(k_ctx, h // kh, axis=2)
         v_ctx = jnp.repeat(v_ctx, h // kh, axis=2)
@@ -676,6 +706,121 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens, q_positions,
     return paged_attention_reference(q, k_pool, v_pool, block_tables,
                                      ctx_lens, q_positions, layer,
                                      kv_heads=kv_heads, scale=scale)
+
+
+# ---------------------------------------------------------------------------
+# EVA: an exact window beside one summary row for every chunk behind it
+# ---------------------------------------------------------------------------
+#
+# Position i attends exactly to the keys of its own window of `window`
+# positions and, for every EARLIER window, to one summary row (k-bar, v-bar)
+# for each `chunk` positions of it; one softmax over both.  A summary is a
+# chunk's keys (values) averaged under softmax(mu . k) (softmax(phi . k)),
+# mu and phi learned vectors per head.  In a lane's paged table the summary
+# rows of the closed windows come first, `window // chunk` a window, then the
+# exact rows of the open one: a row is a row, so the paged kernels above read
+# the table as it is.
+
+
+def eva_row(pos, window: int, chunk: int):
+    """The row of token position `pos` in its lane's table (ints or arrays):
+    behind the summary rows of the windows before its own."""
+    return pos // window * (window // chunk) + pos % window
+
+
+def eva_summaries(k, v, mu, phi):
+    """Chunks of rotated keys and values [..., C, H, D] -> their summary rows
+    (k-bar, v-bar) [..., H, D], in float32: weights softmax over the chunk's C
+    positions of mu . k for the keys and of phi . k for the values, mu, phi
+    [H, D].  Products and sums elementwise, so that float32 stays float32 on
+    a TPU (a chunk is 16 rows: nothing here is a matrix worth the MXU)."""
+    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+
+    def pooled(w, x):
+        logits = jnp.sum(k32 * w.astype(jnp.float32), -1)       # [..., C, H]
+        p = jax.nn.softmax(logits, axis=-2)
+        return jnp.sum(p[..., None] * x, axis=-3)
+
+    return pooled(mu, k32), pooled(phi, v32)
+
+
+def eva_summarise(k_pool, v_pool, mu, phi, src, dst, live, layer=0, *,
+                  chunk: int, kv_heads: int, head_dim: int):
+    """Close a window of `src.shape[0]` lanes at one layer, in place: the
+    exact rows in pool blocks `src` [N, window / BS] become summary rows
+    written into blocks `dst` [N, window / chunk / BS] of the same pools;
+    `live` [N] bool (a row nobody has changes nothing).  `layer` may be
+    traced.  The blocks are gathered by index and written back one whole
+    [BS, W] block at a time, as `paged_rows_update` writes: the pools stay
+    where they are."""
+    bs = k_pool.shape[2]
+    n, n_dst = dst.shape
+    rows = src.shape[1] * bs
+    layer = jnp.asarray(layer, jnp.int32)
+    zero = jnp.zeros((), jnp.int32)
+
+    def one_lane(blocks):
+        # A lane at a time: the float32 chunks of one window are 34 MB a
+        # pool at EvaByte's widths (`_table_blocks` says why its blocks are
+        # not gathered at those widths).
+        k, v = (unpack_kv_rows(_table_blocks(pool, layer, blocks[None]),
+                               kv_heads, head_dim).reshape(
+            rows // chunk, chunk, kv_heads, head_dim)
+            for pool in (k_pool, v_pool))
+        return tuple(pack_kv_rows(x.astype(pool.dtype)).reshape(
+            n_dst, bs, pool.shape[3])
+            for x, pool in zip(eva_summaries(k, v, mu, phi),
+                               (k_pool, v_pool)))
+
+    new = tuple(x.reshape(n * n_dst, bs, x.shape[-1])
+                for x in jax.lax.map(one_lane, src))
+    phys = dst.reshape(-1).astype(jnp.int32)
+    keep = jnp.repeat(live, n_dst)
+
+    def write(i, pools):
+        at = (layer, phys[i], zero, zero)
+
+        def merged(pool, blocks):
+            old = jax.lax.dynamic_slice(pool, at, (1, 1) + pool.shape[2:])
+            block = jnp.where(keep[i], blocks[i], old[0, 0])
+            return jax.lax.dynamic_update_slice(pool, block[None, None], at)
+
+        return tuple(merged(*pb) for pb in zip(pools, new))
+
+    return jax.lax.fori_loop(0, n * n_dst, write, (k_pool, v_pool),
+                             unroll=min(n * n_dst, _KV_WRITE_UNROLL))
+
+
+def eva_attention(q, k, v, mu, phi, *, window: int, chunk: int, mesh=None):
+    """EVA over a whole sequence from position 0: q, k, v [B, L, H, D]
+    (rotated), mu, phi [H, D] -> [B, L, H, D].  Up to one window it is
+    causal attention as it stands (`mesh_flash_attention`); past it, plain
+    XLA over windows: each window's causal scores beside its scores against
+    the summaries of every chunk of the windows before it, one softmax in
+    float32 (the serve path never runs this: it attends through the paged
+    cache, `eva_row`; a kernel and a backward pass are owed, ROADMAP.md)."""
+    b, l, h, d = q.shape
+    if l <= window:
+        return mesh_flash_attention(q, k, v, mesh=mesh, causal=True)
+    n_win, per = -(-l // window), window // chunk
+    pad = [(0, 0), (0, n_win * window - l), (0, 0), (0, 0)]
+    qw, kw, vw = (jnp.pad(x, pad).astype(jnp.float32).reshape(
+        b, n_win, window, h, d) for x in (q, k, v))
+    kbar, vbar = (x.reshape(b, n_win * per, h, d) for x in eva_summaries(
+        kw.reshape(b, n_win, per, chunk, h, d),
+        vw.reshape(b, n_win, per, chunk, h, d), mu, phi))
+    scale = 1.0 / np.sqrt(d)
+    exact = jnp.einsum("bwqhd,bwkhd->bwhqk", qw, kw) * scale
+    causal = jnp.tril(jnp.ones((window, window), bool))
+    exact = jnp.where(causal, exact, NEG_INF)
+    summ = jnp.einsum("bwqhd,bshd->bwhqs", qw, kbar) * scale
+    behind = (jnp.arange(n_win * per)[None, :] // per
+              < jnp.arange(n_win)[:, None])                    # [n_win, S]
+    summ = jnp.where(behind[None, :, None, None, :], summ, NEG_INF)
+    probs = jax.nn.softmax(jnp.concatenate([summ, exact], -1), axis=-1)
+    out = (jnp.einsum("bwhqs,bshd->bwqhd", probs[..., :n_win * per], vbar)
+           + jnp.einsum("bwhqk,bwkhd->bwqhd", probs[..., n_win * per:], vw))
+    return out.reshape(b, n_win * window, h, d)[:, :l].astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------

@@ -376,3 +376,55 @@ def test_a_programs_first_call_freezes_what_it_left_on_the_heap():
     engine.generate(list(range(1, 12)), 8)         # both shapes have run
     assert gc.get_freeze_count() <= frozen         # some have died since
     gc.unfreeze()
+
+
+def test_a_windowed_engine_counts_rows_and_compactions_and_adds_a_span():
+    """EvaByte's block at nano size (windows of 32, chunks of 4): `stats()`
+    grows `eva` (the T=1 steps, the context tokens of their lanes and the
+    rows they attended over instead, host sums; the windows closed; the
+    pool's blocks by kind), `paged` counts the rows the kernel reads, a
+    step still leaves its one `engine/step` record, and a compaction is one
+    span of its own name, `eva_compact`, on the ring (spans are recorded
+    there) and nothing else."""
+    from ray_tpu.models import evabyte
+    cfg = evabyte.CONFIGS["evabyte-nano"]
+    engine = InferenceEngine("evabyte", cfg, auto_start=False, max_lanes=2,
+                             prefill_chunk=16, prefill_lanes=1, block_size=8,
+                             num_blocks=32)
+    engine.generate(list(range(1, 40)), 2)         # compile all four shapes
+    s0, seq = engine.stats(), _last_seq()
+    assert s0["eva"]["compactions"] == 1
+    out = engine.generate(list(range(2, 30)), 40)  # 28 + 40: edges 32, 64
+    s1 = engine.stats()
+    since = [e for e in events.snapshot(plane="engine") if e["seq"] > seq]
+    steps = [e for e in since if e["kind"] == "step"]
+    assert len(steps) == s1["steps"] - s0["steps"] > 0
+    for e in steps:
+        assert set(e["payload"]) == {"decode", "prefill", "waiting",
+                                     "wall_ms", *PHASE_FIELDS, "ahead"}
+    assert {e["kind"] for e in since} <= {"step", "submit", "admit",
+                                          "finish", "prefix_miss",
+                                          "eva_compact"}
+    compacts = [e for e in since if e["kind"] == "eva_compact"
+                and e["payload"].get("ph") == "E"]
+    assert len(compacts) == 2 and all(
+        e["payload"]["lanes"] == 1 for e in compacts)
+    grew = {k: s1["eva"][k] - s0["eva"][k] for k in s1["eva"]}
+    ctx = list(range(29, 28 + len(out)))
+    rows = [engine.cache.rows_held(c) for c in ctx]
+    assert rows[3:5] == [32, 8 + 1] and rows[-1] == 16 + 3
+    assert {k: grew[k] for k in ("decode_steps", "ctx_tokens",
+                                 "rows_attended", "compactions")} == {
+        "decode_steps": len(out) - 1, "ctx_tokens": sum(ctx),
+        "rows_attended": sum(rows), "compactions": 2}
+    assert all(type(v) is int for v in s1["eva"].values())
+    # the request ended: what the pool still holds is what the index keeps
+    assert s1["eva"]["summary_blocks"] + s1["eva"]["window_blocks"] \
+        == s1["cached_blocks"]
+    assert s1["paged"]["ctx_tokens"] - s0["paged"]["ctx_tokens"] == sum(rows)
+    assert "eva" not in _engine().stats()
+    programs = engine.compiled_steps()
+    assert {"t1", "t16_lanes1", "compact_lanes1"} <= set(programs)
+    # (its `pool_copies` are 0 compiled for the chip: tests/test_tpu_aot.py;
+    # the CPU backend donates nothing)
+    assert programs["compact_lanes1"]["custom_calls"] == 0

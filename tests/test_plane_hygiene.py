@@ -348,7 +348,7 @@ def test_latent_prefill_and_share_counters_are_host_sums_in_build_batch():
     build = src[src.index("    def _build_batch("):
                 src.index("    def _run_step(")]
     for counter in ("self._prefill", "self._latent", "self._paged",
-                    "self._tokens_run"):
+                    "self._eva", "self._tokens_run"):
         sites = [m.start() for m in re.finditer(re.escape(counter) + r"\b",
                                                 src)]
         writes = [m.start() for m in re.finditer(
@@ -365,6 +365,14 @@ def test_latent_prefill_and_share_counters_are_host_sums_in_build_batch():
     names = re.findall(r'name="(\w+)"',
                        (PKG / "ops" / "attention.py").read_text())
     assert "latent_decode_attention" in names
+    # A windowed cache's counters are read where the others are, its
+    # compaction is dispatched outside `_build_batch` (under a span of its
+    # own) and closes windows on the host: no fetch from the device.
+    assert 'self._eva["compactions"]' not in src
+    close = src[src.index("    def _close_windows("):
+                src.index("    def _make_compact_fn(")]
+    assert close.count('spans.begin("engine", "eva_compact")') == 1
+    assert "events.record" not in close and "np.asarray(self." not in close
     # one Pallas call of that name: `paged_decode_roofline` divides by
     # every kernel's calls, and two readers find this one by its name
     assert names.count("paged_decode_attention") == 1

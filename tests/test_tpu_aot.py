@@ -488,3 +488,70 @@ def test_latent_decode_kernel_compiles_for_v5e_at_every_block_size(
             arg((), jnp.int32)).compile().as_text()
         (kernel,) = _kernel_names(text)
         assert kernel.split("%")[-1].startswith("latent_decode_attention")
+
+
+# EvaByte at its published widths as `serve_evabyte_sessions_decode` serves
+# it: one stage of a four-stage pipeline (8 of 32 layers), 24 lanes over a
+# windowed pool of 576 blocks of 128 rows of 4,096 columns, requests of
+# 15,104 bytes at most (a table of 22 blocks, the sawtooth's peak), the
+# prefill programs over 4 lanes x 512 and x 128, the compaction over 4.
+@pytest.fixture(scope="module")
+def evabyte_programs(v5e):
+    from benchmark.tools import aot_evabyte_sizes
+    default_backend = jax.default_backend
+    jax.default_backend = lambda: "tpu"     # kernel paths as on the chip
+    try:
+        return {name.split(" of 24")[0]: (compiled, pool, params)
+                for name, compiled, pool, params
+                in aot_evabyte_sizes.programs(v5e[0])}
+    finally:
+        jax.default_backend = default_backend
+
+
+@pytest.mark.parametrize("program", [
+    "engine step T=1 rows=24", "engine step T=512 rows=4",
+    "engine step T=128 rows=4", "engine step T=512 rows=1",
+    "engine step T=128 rows=1", "compaction rows=4 16 -> 1 blocks layers=8"])
+def test_evabyte_programs_fit_a_v5e_and_leave_pool_and_weights_in_place(
+        evabyte_programs, program):
+    """Arguments and temporaries under the compiler's 15.75 GB; both pools
+    donated and left where they are (the compaction too: it slices a
+    window's blocks out and writes a summary block back); no matrix
+    converted or transposed in any program, none copied in the T=1 step
+    and the compaction; the T=1 step on PR 32's paged kernel as it stands,
+    over a table of 22 blocks."""
+    compiled, pool, params = evabyte_programs[program]
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    assert pool == (8, 576, 128, 4096)
+    pool_bytes = 2 * math.prod(pool)
+    assert memory.alias_size_in_bytes == 2 * pool_bytes == 9_663_676_416
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    served = sum(math.prod(x.shape) * x.dtype.itemsize
+                 for x in jax.tree.leaves(params))
+    assert 3.2e9 < served < 3.3e9
+    assert {x.dtype for x in jax.tree.leaves(params)} == {
+        jnp.dtype(jnp.bfloat16)}
+    t1 = program.startswith("engine step T=1 ")
+    if program.startswith("compaction"):    # reads eva_mu and eva_phi alone
+        assert memory.argument_size_in_bytes < 2 * pool_bytes + 2 ** 20
+        assert memory.temp_size_in_bytes < 256 * 2 ** 20
+    else:
+        assert 12.9e9 < memory.argument_size_in_bytes < 13.0e9
+        assert memory.temp_size_in_bytes < (64 if t1 else 1100) * 2 ** 20
+    assert count_pool_copies(text, pool) == 0
+    # no half of a pool copied out to be gathered from (`_table_blocks`)
+    assert "mini-gather" not in text
+    copied = count_weight_bytes_copied(text, params)
+    assert not set(copied) & {"transpose", "remat"}, copied
+    assert copied.get("convert", 0) <= 2 ** 20, copied      # mu, phi, norms
+    if t1 or program.startswith("compaction"):
+        assert "copy" not in copied, copied
+    else:
+        # wq, wk and wv of each layer re-laid for a [2048, 4096] x
+        # [4096, 32, 128] product: PERF.md section 7
+        assert copied.get("copy", 0) <= 8 * 3 * 4096 * 4096 * 2, copied
+    kernels = {k.split(".")[0] for k in _kernel_names(text)}
+    assert kernels == ({"paged_decode_attention"} if t1 else set())
+    if t1:
+        assert "s32[24,22]" in text         # the table: lanes x peak blocks
