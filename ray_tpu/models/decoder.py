@@ -15,7 +15,10 @@ Design (no reference counterpart: Ray hosts models, it doesn't ship them):
   * pure functional: params are a pytree, forward is a jittable function
     (plays directly with pjit/GSPMD and donation);
   * layers are STACKED on a leading dim and applied with `lax.scan`: one
-    compiled block regardless of depth (fast compiles, small HLO);
+    compiled block regardless of depth (fast compiles, small HLO).  The
+    train path scans over the stacks; a served layer (the loops over a
+    paged cache) takes its matrices out of the stacks by its own index,
+    so each is read once, where it is held (`_layer_of`);
   * every param leaf has a logical sharding spec (parallel.sharding rules
     decide DP/FSDP/TP placement; "kv_heads" shards GQA kv projections);
   * attention is a part (`HEADS`: per-head K and V, flash (Pallas) on one
@@ -623,6 +626,19 @@ def _layer_stack(blocks: dict, n_layers: int, whole: tuple):
     return (scanned, layers), {k: blocks[k] for k in whole}
 
 
+def _layer_of(blocks: dict, i, whole: tuple = ()) -> dict:
+    """Layer `i`'s leaves over a paged cache, each read where its stack is
+    held: a leaf is the stack indexed at `i` (a one-layer slice, which XLA
+    fuses into the product that reads it; a GROUP of layers sliced out, as
+    `lax.scan` slices its `xs` when unrolled, is copied through HBM), a
+    `whole` leaf the stack itself, for a kernel that takes it with
+    `"layer"`."""
+    p = {k: v if k in whole
+         else jax.lax.dynamic_index_in_dim(v, i, keepdims=False)
+         for k, v in blocks.items()}
+    return {**p, "layer": i}
+
+
 def _stacks(spec: Spec, params: dict, config) -> list:
     """The runs of like layers, in order: (blocks, layers, feed-forward,
     index of the run's first layer in the whole stack)."""
@@ -876,10 +892,18 @@ def forward_cached(family, params: dict, tokens: jax.Array,
     `v_pool` None) and attention covers each lane's whole block
     table.  The pools ride the layer loop as its carry, whole: a layer
     writes its rows and reads its blocks by index, nothing slices a layer
-    out or stacks it back.  `valid` masks padding lanes/overhang (their
-    cache writes are dropped).  Returns (x [B, T, D], k_pool, v_pool): the
-    lm head is applied by the caller on the positions it needs, so a
-    prefill chunk never materializes [B, T, V].
+    out or stacks it back.  The weights are read the same way: the loop
+    scans over the layer indices alone and closes over the stacks, layer i
+    indexes its own leaves (`_layer_of`) and the `whole` ones go to their
+    kernel with `"layer"`; `scan_unroll` says how many layer bodies a
+    trip holds, for the scheduler to overlap, and nothing about what is
+    copied.  (The stacks as the scan's `xs` at `unroll=k` are sliced out k
+    layers at a time, and that group is materialised: every matrix written
+    and read back once a step, 38% of gpt2-xl's decode step until PR 34.)
+    `valid` masks padding lanes/overhang (their cache writes are dropped).
+    Returns (x [B, T, D], k_pool, v_pool): the lm head is applied by the
+    caller on the positions it needs, so a prefill chunk never materializes
+    [B, T, V].
 
     With `moe_load` (int32 [experts held + 2], an expert configuration's
     running counters: assignments per expert, then experts hit summed
@@ -901,13 +925,11 @@ def forward_cached(family, params: dict, tokens: jax.Array,
     pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
     seen = moe_load
     for blocks, n_layers, ffn, first in _stacks(spec, params, c):
-        scanned, whole = _layer_stack(blocks, n_layers, ffn.whole)
 
-        def body(carry, layer, ffn=ffn, whole=whole, first=first):
+        def body(carry, i, blocks=blocks, ffn=ffn, first=first):
             x, pools, seen = carry
-            p, i = layer
             x, pools, load = _block_cached(
-                x, pools, {**p, **whole, "layer": i,
+                x, pools, {**_layer_of(blocks, i, ffn.whole),
                            "cache_layer": i + first if first else i},
                 spec, ffn, c, block_tables, positions, valid, ctx_lens)
             if seen is not None and load is not None:
@@ -917,7 +939,7 @@ def forward_cached(family, params: dict, tokens: jax.Array,
             return (x, pools, seen), None
 
         (x, pools, seen), _ = jax.lax.scan(
-            body, (x, pools, seen), scanned,
+            body, (x, pools, seen), jnp.arange(n_layers, dtype=jnp.int32),
             unroll=min(c.scan_unroll, n_layers))
     x = _norm(spec, x, params, spec.final_norm)
     k_pool, v_pool = pools if len(pools) == 2 else (pools[0], None)
@@ -936,15 +958,15 @@ def compact_cached(family, params: dict, k_pool: jax.Array,
     c, spec = config, family(config)
     pools = (k_pool, v_pool)
     for blocks, n_layers, _, first in _stacks(spec, params, c):
+        read = {k: blocks[k] for k in spec.attn.compact_leaves}
 
-        def body(pools, layer, first=first):
-            p, i = layer
-            return spec.attn.compact(pools, {**p, "cache_layer": i + first},
-                                     c, src, dst, live), None
+        def body(pools, i, read=read, first=first):
+            return spec.attn.compact(
+                pools, {**_layer_of(read, i), "cache_layer": i + first},
+                c, src, dst, live), None
 
-        pools, _ = jax.lax.scan(
-            body, pools, ({k: blocks[k] for k in spec.attn.compact_leaves},
-                          jnp.arange(n_layers, dtype=jnp.int32)))
+        pools, _ = jax.lax.scan(body, pools,
+                                jnp.arange(n_layers, dtype=jnp.int32))
     return pools
 
 

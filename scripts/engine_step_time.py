@@ -1,0 +1,99 @@
+#!/usr/bin/env python3
+"""Time the engine's compiled step alone at `serve_gpt2xl_decode`'s sizes
+(gpt2-xl whole on the tree `serving_params` prepares, 16 lanes of 100-350
+tokens, 512 blocks of 16): the T=1 program and the T=32 program with two
+lanes prefilling a chunk, each called back to back with the pools fed
+round, so the figure is the device program's length and holds no host
+work.  Since PR 34 the host is the longer side of that cell's traced
+slice, and `decode_step_ms_p50` there reads the host (PERF.md sections 5
+and 7); this is where the program's own length comes from.  Not a tool the
+benchmark runs.  On the chip, from the root of a checkout (the parent's,
+to compare):
+
+  python3 scripts/engine_step_time.py [scan_unroll ...]
+
+With no argument the configuration's own `scan_unroll`; several give the
+layer loop at each (PERF.md section 6, PR 34: 9.45 ms at 4, 9.53 at 1,
+14.00 before the loop indexed its stacks).  The last line is one JSON
+object, ms a call over three sets of calls.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import json
+import os
+import sys
+import time
+
+sys.path.insert(0, os.getcwd())
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from benchmark import manifest
+from ray_tpu.inference.engine import InferenceEngine
+from ray_tpu.models import gpt
+from ray_tpu.ops.attention import kv_row_width
+
+
+def main(unrolls):
+    m = manifest.load()
+    cell = m.cells["serve_gpt2xl_decode"]
+    base = manifest.model_config(m.load_config(cell["config"]), None)
+    engine = m.load_traffic(cell["traffic"])["engine"]
+    lanes, bs, nb = (engine[k] for k in ("max_lanes", "block_size",
+                                         "num_blocks"))
+    mb = base.max_seq_len // bs
+    params = jax.block_until_ready(jax.jit(
+        lambda k: gpt.serving_params(gpt.init_params(base, k), base))(
+            jax.random.key(0)))
+    rng = np.random.default_rng(0)
+    dev = jax.devices()[0]
+    out = {"device": [dev.platform, dev.device_kind], "tree": os.getcwd()}
+    for unroll in unrolls or [base.scan_unroll]:
+        cfg = dataclasses.replace(base, scan_unroll=unroll)
+        eng = object.__new__(InferenceEngine)     # the step, no thread
+        eng.model, eng.config, eng._capture_logp = gpt, cfg, False
+        eng.backend, eng._step_impls = jax.default_backend(), {}
+        step = eng._make_step_fn(False)
+        for t in (1, engine["prefill_chunk"]):
+            shape = (cfg.n_layers, nb, bs,
+                     kv_row_width(cfg.n_heads, cfg.head_dim))
+            k, v = jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
+            ctx = rng.integers(100, 350, lanes)
+            tables = (np.arange(lanes)[:, None] * (nb // lanes)
+                      + np.arange(mb)[None]) % nb
+            valid = np.zeros((lanes, t), bool)
+            valid[:, 0] = True
+            positions = np.repeat((ctx - 1)[:, None], t, 1)
+            gather = np.zeros(lanes, np.int32)
+            for lane in (0, 1) if t > 1 else ():  # two prefill, 14 decode
+                valid[lane], ctx[lane], gather[lane] = True, t, t - 1
+                positions[lane] = np.arange(t)
+            args = [jnp.asarray(a) for a in (
+                rng.integers(0, cfg.vocab_size, (lanes, t)).astype(np.int32),
+                positions.astype(np.int32), valid, tables.astype(np.int32),
+                ctx.astype(np.int32), gather, np.zeros(lanes, np.float32),
+                np.zeros(lanes, np.uint32), np.zeros(lanes, np.int32))]
+
+            def run(n, k, v):
+                t0 = time.perf_counter()
+                for _ in range(n):
+                    tok, k, v = step(params, k, v, *args)
+                jax.block_until_ready((tok, k, v))
+                return 1000 * (time.perf_counter() - t0) / n, k, v
+
+            _, k, v = run(3, k, v)                # compile and warm
+            ms = []
+            for _ in range(3):
+                each, k, v = run(200 if t == 1 else 40, k, v)
+                ms.append(round(each, 4))
+            out[f"unroll{unroll}_t{t}_ms"] = ms
+            del k, v
+    print(json.dumps(out))
+
+
+if __name__ == "__main__":
+    main([int(a) for a in sys.argv[1:]])

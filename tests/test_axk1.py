@@ -120,10 +120,20 @@ def _cached_logits(cfg, params, tokens, chunk, block_size=8, served=False):
     return jnp.concatenate(out)
 
 
+# Two dense layers lead three expert layers, two layer bodies a trip: the
+# lead run is one trip, the main run a trip of two and a remainder of one,
+# and a layer of the main run writes the cache at its index + 2.
+LEAD_2_OF_5 = dataclasses.replace(NANO, first_dense_layers=2, n_layers=5,
+                                  scan_unroll=2)
+
+
 @pytest.mark.parametrize("cfg,served", [(NANO, False), (NANO, True),
-                                        (SHARE, True)],
+                                        (SHARE, True), (LEAD_2_OF_5, True),
+                                        (LEAD_2_OF_5, False)],
                          ids=["whole_raw_tree", "whole_served_tree",
-                              "share_served_tree"])
+                              "share_served_tree",
+                              "lead_2_of_5_unroll_2_served_tree",
+                              "lead_2_of_5_unroll_2_raw_tree"])
 def test_prefill_in_chunks_then_decode_matches_the_reference(cfg, served):
     """Absorbed over the latent paged cache = the reference's expanded full
     forward, from the raw tree (w_kvb split in every call) and from the

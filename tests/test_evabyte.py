@@ -106,11 +106,16 @@ def test_prefill_in_chunks_then_decode_across_two_window_edges(params, chunk):
     assert len(gaps) == 60 and max(gaps) < 1e-4, max(gaps)
 
 
-def test_cached_logits_equal_the_reference_at_every_position(params):
+@pytest.mark.parametrize("scan_unroll", [1, 2],
+                         ids=["a_layer_a_trip", "both_layers_a_trip"])
+def test_cached_logits_equal_the_reference_at_every_position(params,
+                                                             scan_unroll):
     """Logits, not tokens: the cached forward a position at a time (the T=1
     program's mathematics) against the reference's full forward, over two
-    window edges, with the windows closed by `compact_cached`."""
-    cache = PagedKVCache.for_model(evabyte, CFG, num_blocks=32,
+    window edges, with the windows closed by `compact_cached`; both loops
+    read a layer's leaves out of their stacks by the layer's index."""
+    cfg = dataclasses.replace(CFG, scan_unroll=scan_unroll)
+    cache = PagedKVCache.for_model(evabyte, cfg, num_blocks=32,
                                    block_size=BS, max_lanes=1,
                                    max_seq_len=128)
     tokens = _tokens(2 * W + 9, seed=2)
@@ -121,15 +126,15 @@ def test_cached_logits_equal_the_reference_at_every_position(params):
             src, dst = cache.close_window(0, tokens[:pos].tolist())
             cache.update_pools(*evabyte.compact_cached(
                 params, cache.k, cache.v, jnp.asarray([src]),
-                jnp.asarray([dst]), jnp.asarray([True]), CFG))
+                jnp.asarray([dst]), jnp.asarray([True]), cfg))
         cache.ensure_capacity(0, pos + 1)
         x, k, v = evabyte.forward_cached(
             params, jnp.asarray([[tok]]), jnp.asarray([[pos]]),
             jnp.asarray([[True]]), cache.k, cache.v, cache.device_tables(),
-            jnp.asarray([pos + 1]), CFG)
+            jnp.asarray([pos + 1]), cfg)
         cache.update_pools(k, v)
         cache.seq_lens[0] = pos + 1
-        rows.append(evabyte.lm_head(params, x[0, 0], CFG))
+        rows.append(evabyte.lm_head(params, x[0, 0], cfg))
     want = reference.row_logits(params, tokens, **SHAPE)
     np.testing.assert_allclose(np.asarray(jnp.stack(rows)),
                                np.asarray(want), **TOL)

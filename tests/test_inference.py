@@ -6,6 +6,7 @@ continuous-batching lane admission and pool-exhaustion FIFO, in-step
 sampling determinism, and end-to-end streaming generation through
 serve."""
 
+import dataclasses
 import functools
 import threading
 import time
@@ -371,11 +372,21 @@ _GPT_25X64 = gpt.GPTConfig(vocab_size=256, n_layers=2, d_model=1600,
                            dtype=jnp.float32)
 
 
-@pytest.mark.parametrize("family", ["gpt", "llama", "gpt_25x64"])
+# gpt's cases: the cached layer loop at depths its unroll fills (4 of 4)
+# and leaves a remainder of (6 layers: trips of 4 and 2): every layer
+# reads its own matrices out of the stacks by its index (decoder._layer_of).
+_nano = functools.partial(dataclasses.replace, gpt.CONFIGS["nano"])
+_CACHED = {"gpt": gpt.CONFIGS["nano"], "llama": llama.CONFIGS["llama-tiny"],
+           "gpt_25x64": _GPT_25X64,
+           "gpt_4_layers_unroll_4": _nano(n_layers=4, scan_unroll=4),
+           "gpt_6_layers_unroll_4": _nano(n_layers=6, scan_unroll=4),
+           "gpt_6_layers_unroll_1": _nano(n_layers=6)}
+
+
+@pytest.mark.parametrize("family", list(_CACHED))
 def test_cached_logits_match_full_forward(family):
     model = llama if family == "llama" else gpt
-    config = {"gpt": gpt.CONFIGS["nano"], "gpt_25x64": _GPT_25X64,
-              "llama": llama.CONFIGS["llama-tiny"]}[family]
+    config = _CACHED[family]
     params = model.init_params(config, jax.random.key(1))
     rng = np.random.default_rng(1)
     tokens = rng.integers(0, config.vocab_size, size=21).tolist()
@@ -414,6 +425,9 @@ def test_cached_logits_match_full_forward(family):
     for pos, logits in got.items():
         np.testing.assert_allclose(logits, full[pos], atol=2e-4, rtol=2e-4,
                                    err_msg=f"{family} position {pos}")
+    # and the greedy token of every position is the full forward's
+    assert [int(np.argmax(got[pos])) for pos in sorted(got)] == [
+        int(np.argmax(full[pos])) for pos in sorted(got)]
 
 
 # ---------------------------------------------------------------------------

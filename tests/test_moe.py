@@ -26,6 +26,7 @@ from ray_tpu.ops import moe
 
 CFG = llama.CONFIGS["olmoe-nano"]     # 2 layers, 64 wide, 16 experts, top-2
 TOL = 1e-4
+_LOADS = {}                           # n_layers -> the cached run's counters
 
 
 def _tokens(shape, seed=1):
@@ -56,20 +57,29 @@ def test_forward_matches_the_reference(qk_norm, norm_topk_prob):
         assert float(jnp.max(jnp.abs(other - want))) > 100 * TOL
 
 
-def test_chunked_prefill_then_decode_through_the_paged_cache_matches():
+@pytest.mark.parametrize("n_layers,scan_unroll", [(2, 1), (2, 2), (3, 2)],
+                         ids=["2_layers", "2_layers_unroll_2",
+                              "3_layers_unroll_2"])
+def test_chunked_prefill_then_decode_through_the_paged_cache_matches(
+        n_layers, scan_unroll):
     """Three lanes at different depths in one batch: a prefill chunk per
-    lane, then decode steps, against the reference's full forward."""
-    params = llama.init_params(CFG, jax.random.key(1))
+    lane, then decode steps, against the reference's full forward; the
+    expert arrays go to their kernel whole, every other leaf is its layer's
+    slice of its stack, and the load counters ride the loop whatever a
+    trip holds (one layer, all, two and a remainder of one)."""
+    cfg = dataclasses.replace(CFG, n_layers=n_layers,
+                              scan_unroll=scan_unroll)
+    params = llama.init_params(cfg, jax.random.key(1))
     rows = np.asarray(_tokens((3, 30), seed=2))
     prefill = [5, 8, 3]                   # each lane's first chunk
     want = np.asarray(ref.logits(params, rows))
     bs, lanes = 8, 3
-    cache = PagedKVCache.for_model(llama, CFG, num_blocks=lanes * 4 + 1,
+    cache = PagedKVCache.for_model(llama, cfg, num_blocks=lanes * 4 + 1,
                                    block_size=bs, max_lanes=lanes,
                                    max_seq_len=32)
     for lane in range(lanes):
         cache.alloc_lane(lane, 30)
-    load = jnp.zeros((CFG.n_experts + 2,), jnp.int32)
+    load = jnp.zeros((cfg.n_experts + 2,), jnp.int32)
     depth = [0, 0, 0]
 
     def run(chunks):
@@ -85,11 +95,11 @@ def test_chunked_prefill_then_decode_through_the_paged_cache_matches():
         x, k, v, load = llama.forward_cached(
             params, jnp.asarray(tokens), jnp.asarray(positions, jnp.int32),
             jnp.asarray(valid), cache.k, cache.v, cache.device_tables(),
-            jnp.asarray(ctx, jnp.int32), CFG, load)
+            jnp.asarray(ctx, jnp.int32), cfg, load)
         cache.update_pools(k, v)
         for lane, c in enumerate(chunks):
             depth[lane] += len(c)
-            got = llama.lm_head(params, x[lane, len(c) - 1], CFG)
+            got = llama.lm_head(params, x[lane, len(c) - 1], cfg)
             np.testing.assert_allclose(
                 got, want[lane, depth[lane] - 1], atol=TOL,
                 err_msg=f"lane {lane} position {depth[lane] - 1}")
@@ -99,9 +109,11 @@ def test_chunked_prefill_then_decode_through_the_paged_cache_matches():
         run([[int(rows[lane, depth[lane]])] for lane in range(lanes)])
     tokens_run = sum(depth)
     load = np.asarray(load)
-    assert load[:-2].sum() == tokens_run * CFG.n_experts_per_tok \
-        * CFG.n_layers                    # padding positions reach no expert
-    assert load[-1] == 7 * CFG.n_layers   # (layer, step) pairs
+    assert load[:-2].sum() == tokens_run * cfg.n_experts_per_tok \
+        * cfg.n_layers                    # padding positions reach no expert
+    assert load[-1] == 7 * cfg.n_layers   # (layer, step) pairs
+    # the same layers count the same experts however many a trip holds
+    np.testing.assert_array_equal(_LOADS.setdefault(n_layers, load), load)
 
 
 @pytest.mark.parametrize("t, k, e, d, f, block_m", [

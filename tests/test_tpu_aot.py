@@ -7,6 +7,7 @@ says nothing about numerics, device ownership or the process model;
 `chip_smoke.py` covers those on the chip.
 """
 
+import dataclasses
 import functools
 import math
 import re
@@ -21,7 +22,7 @@ from ray_tpu._private.accelerators import (
     ChipAllocator, chip_env, leasable)
 from ray_tpu.inference.kv_cache import (count_pool_copies,
                                         count_weight_bytes_copied)
-from ray_tpu.models import gpt
+from ray_tpu.models import decoder, gpt
 from ray_tpu.ops.attention import (kv_row_width, paged_blocks_per_step,
                                    paged_decode_attention)
 from ray_tpu.parallel import MeshConfig, create_mesh
@@ -285,27 +286,58 @@ def test_olmoe_step_reads_its_experts_where_they_are(v5e, as_on_chip, t):
         2 * math.prod(x.shape) for x in jax.tree.leaves(params))
 
 
-# gpt2-xl's widths, eight of its 48 layers (two turns of its scan, so the
-# loop over groups of four is there), the decode cell's 16 lanes and 512
-# blocks.
+# gpt2-xl's widths, eight of its 48 layers (two trips of its layer loop at
+# the preset's four layer bodies a trip), the decode cell's 16 lanes and
+# 512 blocks.
 XL8 = gpt.GPTConfig(n_layers=8, d_model=1600, n_heads=25, d_ff=6400,
                     scan_unroll=4)
 _MATRIX = re.compile(
     r" = \w+\[(?:\d+,)*(?:1600,25,64|25,64,1600|1600,6400|6400,1600|"
     r"50304,1600|50304,1664|1024,1600|1024,1664)\]\S* ([\w\-]+)\(")
+_RESULT = re.compile(r" = (.*?) [\w\-]+\(")     # an instruction's result type
+_LAYER_MATRIX = re.compile(
+    r"\w+\[((?:\d+,)?)(1600,25,64|25,64,1600|1600,6400|6400,1600)\]"
+    r"\{([^}]*)\}")
+
+
+def _layers_sliced_out(text, n_layers):
+    """(dims, layout) of every array outside a fusion's body that has the
+    shape of one layer, or of a group of fewer than `n_layers`, of a
+    stacked matrix: what a layer loop slices out of its stacks and holds.
+    (Inside a fusion's body such a shape is an operand being read.)"""
+    found, fused = [], False
+    for line in text.splitlines():
+        if line.endswith("{") and " = " not in line:      # a computation
+            fused = "fused_computation" in line.split("(")[0]
+        elif not fused and (made := _RESULT.search(line)):
+            found += [(group + dims, layout) for group, dims, layout
+                      in _LAYER_MATRIX.findall(made.group(1))
+                      if int(group.rstrip(",") or 1) < n_layers]
+    return found
 
 
 @pytest.mark.parametrize("t", [1, 32], ids=["t1", "t_prefill_chunk"])
+@pytest.mark.parametrize("unroll", [4, 1], ids=["unroll_4", "unroll_1"])
 def test_gpt2xl_step_multiplies_its_weights_as_they_are_held(v5e, as_on_chip,
-                                                             t):
-    """The mechanism of PERF.md section 6, PR 28, without a chip: on the
-    tree the engine prepares, the compiled step has no `convert`, `copy`
-    or `transpose` whose result has a matrix leaf's shape (61% of the
-    decode cell's busy time went there: fp32 -> bf16 of every matrix,
+                                                             unroll, t):
+    """The mechanisms of PERF.md section 6, PRs 28 and 34, without a chip.
+    On the tree the engine prepares, the compiled step has no `convert`,
+    `copy` or `transpose` whose result has a matrix leaf's shape (61% of
+    the decode cell's busy time went there: fp32 -> bf16 of every matrix,
     `w_down` and the table turned round, in every step), and next to no
-    scratch where the raw tree's step held a bf16 copy of every weight."""
+    scratch where the raw tree's step held a bf16 copy of every weight.
+    And the layer loop copies no group of layers out of the stacks: every
+    layer indexes its own matrices (`decoder._layer_of`), the slice fuses
+    into the product that reads it, and what XLA still slices out (two
+    per-head projections a layer) it prefetches into fast memory, `S(1)`:
+    their one read.  Until PR 34 the loop handed the stacks to `lax.scan`
+    as `xs`, which at `unroll=4` slices groups of four layers out: every
+    matrix written to HBM and read back, 38% of the cell's busy time.
+    How many layer bodies a trip holds (`scan_unroll`) decides what the
+    scheduler may overlap, not what is copied."""
+    cfg = dataclasses.replace(XL8, scan_unroll=unroll)
     compiled, pool, params = _compile_engine_step(
-        v5e[0], XL8, t, lanes=16, num_blocks=512)
+        v5e[0], cfg, t, lanes=16, num_blocks=512)
     text, memory = compiled.as_text(), compiled.memory_analysis()
     assert {x.dtype for x in jax.tree.leaves(params) if x.ndim > 2} == {
         jnp.dtype(jnp.bfloat16)}
@@ -315,16 +347,29 @@ def test_gpt2xl_step_multiplies_its_weights_as_they_are_held(v5e, as_on_chip,
     copied = count_weight_bytes_copied(text, params)
     assert not set(copied) & {"convert", "copy", "transpose", "remat"}, copied
     assert count_pool_copies(text, pool.shape) == 0
-    # Scratch: a T=1 step's is activations; the T=32 step gathers every
-    # lane's context of 1024 for the masked-dense attention (two layers of
-    # K and V in flight).  The bf16 matrices of eight layers are 0.49 GB.
+    # The bf16 matrices of eight layers are 0.49 GB.
     weights = sum(2 * math.prod(x.shape) for x in jax.tree.leaves(params)
                   if x.ndim > 2)
-    assert memory.temp_size_in_bytes < (weights // 2 if t == 1
+    sliced = sum(copied.get(op, 0)
+                 for op in ("dynamic-slice", "slice", "copy-done"))
+    # The T=32 step at four bodies a trip also prefetches pairs of layers
+    # of those two leaves (`slice-done [2,1600,25,64] S(1)`: 31% of the
+    # matrices' bytes in all) and keeps six of a trip's eight slices in
+    # HBM: a twelfth of the bytes, where the group copies were all.
+    prefill_of_4 = (t, unroll) == (32, 4)
+    assert sliced < weights // (3 if prefill_of_4 else 4), copied
+    held = _layers_sliced_out(text, cfg.n_layers)
+    assert held
+    in_hbm = {dims for dims, layout in held if "S(1)" not in layout}
+    assert in_hbm <= ({"1,1600,25,64"} if prefill_of_4 else set()), in_hbm
+    # Scratch: a T=1 step's is activations; the T=32 step gathers every
+    # lane's context of 1024 for the masked-dense attention (two layers of
+    # K and V in flight).
+    assert memory.temp_size_in_bytes < (16 * 2 ** 20 if t == 1
                                         else weights)
     kernels = _kernel_names(text)
     assert all(k.startswith("paged_decode_attention") for k in kernels)
-    assert len(kernels) == (XL8.scan_unroll if t == 1 else 0)
+    assert len(kernels) == (unroll if t == 1 else 0)
 
 
 def test_weight_copy_counter_sees_a_raw_float32_tree(v5e, as_on_chip):
@@ -339,7 +384,45 @@ def test_weight_copy_counter_sees_a_raw_float32_tree(v5e, as_on_chip):
     matrices = sum(2 * math.prod(x.shape) for x in jax.tree.leaves(params)
                    if x.ndim > 2)
     assert copied.get("convert", 0) + copied.get("copy", 0) >= matrices
-    assert compiled.memory_analysis().temp_size_in_bytes > matrices
+    assert compiled.memory_analysis().temp_size_in_bytes > matrices // 2
+
+
+def test_weight_copy_counter_sees_stacks_scanned_in_groups(v5e, as_on_chip):
+    """What every tree before PR 34 compiled: the stacked matrices as the
+    layer scan's `xs` at `unroll=4`, here gpt2-xl's feed-forward alone over
+    eight layers.  `lax.scan` slices a group of four layers out of each
+    stack a trip and XLA materialises it: the counter must read every
+    matrix, and the scratch hold a group.  The form the served loops have
+    (the stacks closed over, a layer indexed by the loop) copies nothing."""
+    arg = _arg_on(v5e[0])
+    stacks = {k: arg((8, 1600, 6400), jnp.bfloat16)
+              for k in ("w_up", "w_down_t")}
+    matrices = sum(2 * math.prod(x.shape) for x in stacks.values())
+
+    def mlp(x, p):
+        hidden = jax.nn.gelu(jnp.einsum("bd,df->bf", x, p["w_up"]))
+        return x + jnp.einsum("bf,df->bd", hidden, p["w_down_t"])
+
+    def stacks_as_xs(x, stacks):
+        return jax.lax.scan(lambda x, p: (mlp(x, p), None), x, stacks,
+                            unroll=4)[0]
+
+    def stacks_indexed(x, stacks):
+        return jax.lax.scan(
+            lambda x, i: (mlp(x, decoder._layer_of(stacks, i)), None), x,
+            jnp.arange(8), unroll=4)[0]
+
+    def compiled(loop):
+        return jax.jit(loop).lower(arg((16, 1600), jnp.bfloat16),
+                                   stacks).compile()
+
+    old, new = compiled(stacks_as_xs), compiled(stacks_indexed)
+    copied = count_weight_bytes_copied(old.as_text(), stacks)
+    assert copied.get("dynamic-slice", 0) + copied.get("slice", 0) \
+        >= matrices, copied
+    assert old.memory_analysis().temp_size_in_bytes > matrices // 8
+    assert not count_weight_bytes_copied(new.as_text(), stacks)
+    assert new.memory_analysis().temp_size_in_bytes < 2 ** 20
 
 
 def test_pool_copy_counter_sees_a_pool_scanned_over_layers(v5e, as_on_chip):
