@@ -57,9 +57,9 @@ def _warm(actor, prompts, spec_k):
     actor.rollout(prompts[:1], max_new_tokens=4)
     eng = actor.engine
     if spec_k:
-        eng._run_step(eng._build_batch([], 1)[0])
+        eng._run_step(eng._upload(eng._build_batch([], 1)[0]))
         for t in range(2, spec_k + 2):
-            eng._run_step(eng._build_batch([], t)[0], True)
+            eng._run_step(eng._upload(eng._build_batch([], t)[0]), True)
 
 
 def _timed_rollout(actor, prompts, new_tokens):

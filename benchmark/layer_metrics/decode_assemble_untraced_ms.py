@@ -1,0 +1,11 @@
+"""Mean `assemble` milliseconds an iteration (a population's numpy arrays, the
+per-lane loop, `ensure_capacity`, the host-side counters) over the seconds of
+the window's timeline that the profiler's session did not touch: the part
+as the scored run has it.  `decode_assemble_ms_p50` is the median of the ring's
+records, most of which a traced run writes inside the profiler's stop."""
+
+from benchmark import step_parts
+
+
+def read(run: dict):
+    return step_parts.part_untraced_ms(run, "assemble")

@@ -111,6 +111,43 @@ _DONE = object()
 # The flat phases of one scheduler step (spans.phase: profiler annotations
 # `engine/<phase>`, seconds into the step's one ring record and stats()).
 _PHASES = ("admit", "build_batch", "dispatch", "fetch", "commit")
+# What `build_batch` and `commit` are made of: each also a spans.phase, nested
+# in its phase as `engine.build_batch/<part>` or `engine.commit/<part>` (the
+# `engine.` prefix keeps them out of every reader of the flat `engine/`
+# names), milliseconds into the step's record, seconds into stats()["part_s"].
+_PARTS = ("windows", "assemble", "upload", "release", "lock", "deliver")
+# One row of stats()["timeline"]: the loop's sums over one wall-clock second
+# (`phase_s` in the order of _PHASES, `part_s` of _PARTS).
+_TIMELINE = ("t", "steps", "prefill_steps", "wall_s", "phase_s", "part_s",
+             "cpu_s", "cpu_wall_s", "cpu_steps", "gc_s", "longest_ms",
+             "longest_phase")
+_TIMELINE_ROWS = 128
+# The thread's CPU clock is read in one iteration of this many on average,
+# drawn and not counted off, so that no rhythm of the traffic falls in step
+# with it: the clock is a system call (5.5 us alone and 16 us in a serving
+# replica on a v5e's host, four reads an iteration; PERF.md section 6,
+# PR 35), `perf_counter` is not.
+_CPU_EVERY = 4
+
+# The collector's pauses, counted where they happen (`gc.callbacks`; hooked
+# by the first engine of a process): collections by generation, their
+# seconds, and those of the full ones.  Whichever thread allocates runs a
+# collection and every other stands still meanwhile, so the seconds that
+# fall inside an iteration of the engine's loop are that iteration's.
+_GC = {"collections": [0, 0, 0], "seconds": 0.0, "full_seconds": 0.0,
+       "t0": 0.0}
+
+
+def _gc_hook(phase: str, info: dict) -> None:
+    if phase == "start":
+        _GC["t0"] = time.perf_counter()
+        return
+    took = time.perf_counter() - _GC["t0"]
+    gen = info["generation"]
+    _GC["collections"][gen] += 1
+    _GC["seconds"] += took
+    if gen == 2:
+        _GC["full_seconds"] += took
 
 _MET = None
 
@@ -464,6 +501,30 @@ class InferenceEngine:
         self._steps = 0
         self._step_wall_s = 0.0
         self._phase_s = dict.fromkeys(_PHASES, 0.0)
+        # The same by part of `build_batch` and `commit` (`_PARTS`); the
+        # engine thread's CPU seconds over the four host phases beside
+        # their wall seconds, in the `_cpu_steps` iterations that read the
+        # clock (one in `_CPU_EVERY`, by `_dice`: a linear congruential
+        # draw; wall less CPU is the time the thread held no core);
+        # collector seconds inside iterations; and all of the loop's sums
+        # by the wall-clock second for the last `_TIMELINE_ROWS` seconds in
+        # which it ran (rows as `_TIMELINE` names them): differences of the
+        # sums between the seconds' first iterations, so that an iteration
+        # pays one clock read and one comparison for it.  `_second` is the
+        # open row: its second, the sums at its start, and the longest
+        # iteration in it with the phase that took most of that.
+        self._part_s = dict.fromkeys(_PARTS, 0.0)
+        self._prefill_steps = 0
+        self._cpu_s = 0.0
+        self._cpu_wall_s = 0.0
+        self._cpu_steps = 0
+        self._dice = 0
+        self._gc_s = 0.0
+        self._timeline: "collections.deque[list]" = collections.deque(
+            maxlen=_TIMELINE_ROWS)
+        self._second = [0, self._sums(), 0.0, ""]
+        if _gc_hook not in gc.callbacks:
+            gc.callbacks.append(_gc_hook)
         self._admitted = 0
         self._queue_wait_s = 0.0
         # Counted on the host as batches are built (`_build_batch`): the
@@ -772,6 +833,19 @@ class InferenceEngine:
             "steps": self._steps,
             "step_wall_s": self._step_wall_s,
             "phase_s": dict(self._phase_s),
+            # `build_batch` and `commit` by part, the thread's CPU seconds
+            # over admit, build_batch, dispatch and commit, the process's
+            # collector pauses, and the sums above by the second.
+            "part_s": dict(self._part_s),
+            "cpu_s": self._cpu_s,
+            "cpu_wall_s": self._cpu_wall_s,
+            "cpu_steps": self._cpu_steps,
+            "gc": {"collections": list(_GC["collections"]),
+                   "seconds": _GC["seconds"],
+                   "full_seconds": _GC["full_seconds"]},
+            "timeline": {
+                "columns": list(_TIMELINE), "phases": list(_PHASES),
+                "parts": list(_PARTS), "rows": self._timeline_rows()},
             "admitted": self._admitted,
             "queue_wait_s": self._queue_wait_s,
             # Of those steps: `steps` dispatched while the step before was
@@ -1041,8 +1115,22 @@ class InferenceEngine:
         population, so a mixed step has it twice.  One `engine/step` ring
         record at the end holds the iteration's durations: `wall_ms` its
         period, `fetch_ms` the time blocked on the older step, `ahead`
-        whether it dispatched with that step still unfetched."""
+        whether it dispatched with that step still unfetched; the parts of
+        `build_batch` and `commit` (`_PARTS`: nested annotations under
+        `engine.` names, which no reader of the flat ones sees), `gc_ms`
+        (collector pauses that fell inside it) and, in one iteration of
+        `_CPU_EVERY`, `cpu_ms` beside `cpu_wall_ms` (the thread's CPU time
+        and the wall time from `admit`'s start to `dispatch`'s end and
+        over `commit`: the blocked `fetch` left out).  The same sums go
+        into `stats()` and, by the wall-clock second, into its
+        `timeline`."""
         took = dict.fromkeys(_PHASES, 0.0)         # seconds
+        parts = dict.fromkeys(_PARTS, 0.0)
+        gc0 = _GC["seconds"]
+        self._dice = (self._dice * 1103515245 + 12345) & 0x7FFFFFFF
+        clocked = self._dice < 0x80000000 // _CPU_EVERY
+        if clocked:
+            cpu0, on0 = time.thread_time(), time.perf_counter()
         older = self._flight
         with contextlib.ExitStack() as locked:
             with spans.phase("engine", "admit") as ph:
@@ -1079,10 +1167,11 @@ class InferenceEngine:
                 if decode:
                     t = (1 + max(len(r.draft) for _, r in decode)
                          if spec else 1)
-                    plans.append(self._plan(spec, decode, t))
+                    plans.append(self._plan(parts, spec, decode, t))
                 if prefill:
                     plans.append(self._plan(
-                        False, prefill, self._prefill_len(prefill), True))
+                        parts, False, prefill, self._prefill_len(prefill),
+                        True))
             took["build_batch"] = ph.seconds
         newer = []
         for spec, lanes, chunks, news, batch in plans:
@@ -1100,6 +1189,9 @@ class InferenceEngine:
         retire = older if keep else older + newer
         self._flight = newer if keep else []
         done = []
+        if clocked:
+            cpu = time.thread_time() - cpu0
+            on = time.perf_counter() - on0
         with spans.phase("engine", "fetch") as ph:
             # The host blocks here until the device has finished the older
             # step; the copy back of one int32 per lane was started when
@@ -1123,25 +1215,52 @@ class InferenceEngine:
                     _metrics()["spec_steps"].inc()
                 done.append((lanes, chunks, news, toks, lps))
         took["fetch"] = ph.seconds
+        if clocked:
+            cpu0, on0 = time.thread_time(), time.perf_counter()
         with contextlib.ExitStack() as locked:
             with spans.phase("engine", "commit") as ph:
-                # Let go of the steps' device arrays (nine uploads and the
-                # sampled tokens per population) here, inside a phase: left
-                # to the return, their release and what the runtime then
-                # does took 1.4 ms a step on a v5e, between two steps,
-                # under no phase's name (PERF.md 6, PR 23).
-                plans = batch = older = newer = retire = next_tok = lps = None
-                locked.enter_context(self._work)
-                for lanes, chunks, news, toks, lps in done:
-                    self._commit(lanes, chunks, news, toks, lps)
-                self._work.notify()
+                with spans.phase("engine.commit", "release") as part:
+                    # Let go of the steps' device arrays (nine uploads and
+                    # the sampled tokens per population) here, inside a
+                    # phase: left to the return, their release and what the
+                    # runtime then does took 1.4 ms a step on a v5e, between
+                    # two steps, under no phase's name (PERF.md 6, PR 23).
+                    plans = batch = older = newer = retire = None
+                    next_tok = lps = None
+                parts["release"] = part.seconds
+                with spans.phase("engine.commit", "lock") as part:
+                    locked.enter_context(self._work)
+                parts["lock"] = part.seconds
+                with spans.phase("engine.commit", "deliver") as part:
+                    for lanes, chunks, news, toks, lps in done:
+                        self._commit(lanes, chunks, news, toks, lps)
+                    self._work.notify()
+                parts["deliver"] = part.seconds
             took["commit"] = ph.seconds
             wall = ph.t0 + ph.seconds - t_start
+            paused = _GC["seconds"] - gc0
+            now = int(time.time())
+            if now != self._second[0]:
+                self._close_second(now)
+            if wall * 1e3 > self._second[2]:
+                self._second[2:] = wall * 1e3, max(took, key=took.get)
             self._steps += 1
+            self._prefill_steps += bool(prefill)
             self._step_wall_s += wall
+            self._gc_s += paused
             self._ahead["steps" if ahead else "sync_steps"] += 1
             for name in _PHASES:
                 self._phase_s[name] += took[name]
+            for name in _PARTS:
+                self._part_s[name] += parts[name]
+            clock = {}
+            if clocked:
+                cpu += time.thread_time() - cpu0
+                on += time.perf_counter() - on0
+                self._cpu_s += cpu
+                self._cpu_wall_s += on
+                self._cpu_steps += 1
+                clock = {"cpu_ms": cpu * 1e3, "cpu_wall_ms": on * 1e3}
             events.record(
                 "engine", "step", decode=len(decode), prefill=len(prefill),
                 waiting=waiting, wall_ms=wall * 1e3,
@@ -1149,8 +1268,52 @@ class InferenceEngine:
                 build_ms=took["build_batch"] * 1e3,
                 dispatch_ms=took["dispatch"] * 1e3,
                 fetch_ms=took["fetch"] * 1e3,
-                commit_ms=took["commit"] * 1e3, ahead=ahead)
+                commit_ms=took["commit"] * 1e3, ahead=ahead,
+                windows_ms=parts["windows"] * 1e3,
+                assemble_ms=parts["assemble"] * 1e3,
+                upload_ms=parts["upload"] * 1e3,
+                release_ms=parts["release"] * 1e3,
+                lock_ms=parts["lock"] * 1e3,
+                deliver_ms=parts["deliver"] * 1e3,
+                gc_ms=paused * 1e3, **clock)
         return True
+
+    def _sums(self) -> list:
+        """The loop's cumulative sums, in the order of a timeline's row
+        from its second column to `gc_s`."""
+        return [self._steps, self._prefill_steps, self._step_wall_s,
+                [self._phase_s[p] for p in _PHASES],
+                [self._part_s[p] for p in _PARTS],
+                self._cpu_s, self._cpu_wall_s, self._cpu_steps, self._gc_s]
+
+    def _row_since(self, second: list, sums: list) -> list:
+        """The timeline's row of the open second `second`, as the
+        difference of `sums` and the sums at its start."""
+        t, base, longest_ms, longest_phase = second
+        return [t, *([x - y for x, y in zip(a, b)] if isinstance(a, list)
+                     else a - b for a, b in zip(sums, base)),
+                longest_ms, longest_phase]
+
+    def _close_second(self, now: int) -> None:
+        """The first iteration to end in another wall-clock second
+        (`time.time()`, the clock a caller's own marks are on) closes the
+        row of the second before: host numbers alone, nothing on the ring,
+        nothing per token."""
+        sums = self._sums()
+        if sums[0] > self._second[1][0]:
+            self._timeline.append(self._row_since(self._second, sums))
+        self._second = [now, sums, 0.0, ""]
+
+    def _timeline_rows(self) -> list:
+        """The closed rows and the open one (for `stats()`, from any
+        thread: the loop may close a second meanwhile, so the open row is
+        given only if it is a later second than the last closed one)."""
+        rows = [[list(x) if isinstance(x, list) else x for x in row]
+                for row in list(self._timeline)]
+        second, sums = self._second, self._sums()
+        if sums[0] > second[1][0] and (not rows or second[0] > rows[-1][0]):
+            rows.append(self._row_since(second, sums))
+        return rows[-_TIMELINE_ROWS:]
 
     def _prefill_len(self, lanes) -> int:
         """T of this step's prefill program: `prefill_chunk`; under
@@ -1166,21 +1329,44 @@ class InferenceEngine:
             return short
         return self.prefill_chunk
 
-    def _plan(self, spec: bool, lanes, t: int, prefill=False) -> tuple:
+    def _plan(self, parts: dict, spec: bool, lanes, t: int,
+              prefill=False) -> tuple:
         """One population's step of `t` positions, built from what the step
         in flight will have left, and from here on in flight itself: per
         lane the positions it writes (`chunks`) and whether it samples a
         token (`news`), which `_commit` takes off again.  Lanes whose next
-        position opens a window have the one before closed first."""
+        position opens a window have the one before closed first.  The
+        seconds of its three parts are added to `parts`: `windows` (the
+        compaction's dispatch included), `assemble` (host arrays, tables'
+        entries, counters), `upload` (the host arrays and the block tables
+        handed to the device)."""
         if self.cache.window:
-            self._close_windows(lanes)
-        batch, chunks = self._build_batch(lanes, t, prefill)
-        news = {}
-        for lane, req in lanes:
-            news[lane] = int(req.samples(req.next_fed, chunks[lane]))
-            req.ahead_len += chunks[lane]
-            req.ahead_new += news[lane]
+            with spans.phase("engine.build_batch", "windows") as ph:
+                self._close_windows(lanes)
+            parts["windows"] += ph.seconds
+        with spans.phase("engine.build_batch", "assemble") as ph:
+            arrays, chunks = self._build_batch(lanes, t, prefill)
+            news = {}
+            for lane, req in lanes:
+                news[lane] = int(req.samples(req.next_fed, chunks[lane]))
+                req.ahead_len += chunks[lane]
+                req.ahead_new += news[lane]
+        parts["assemble"] += ph.seconds
+        with spans.phase("engine.build_batch", "upload") as ph:
+            batch = self._upload(arrays)
+        parts["upload"] += ph.seconds
         return spec, lanes, chunks, news, batch
+
+    def _upload(self, arrays) -> tuple:
+        """A population's host arrays (`_build_batch`) as `_run_step` takes
+        them: eight small device arrays and the block tables' copy, fourth
+        among them.  A compact program's `rows` stay on the host (`step`
+        gives each fetched row back to its lane by them); `_run_step`
+        uploads them, and `dispatch` has that time."""
+        t, sample, host, rows = arrays
+        args = [jnp.asarray(a) for a in host]
+        args.insert(3, self.cache.device_tables())
+        return t, sample, tuple(args), rows
 
     def _ends_in_flight(self, lane: int, req: _Request) -> bool:
         """Whether the step in flight ends `req` by a count the host has
@@ -1280,13 +1466,9 @@ class InferenceEngine:
             if self._latent is None:
                 self._paged["runs_live"] += sum(
                     -(-c // self._paged_run) for c in ctx)
-        batch = (t, sample,
-                 (jnp.asarray(tokens), jnp.asarray(positions),
-                  jnp.asarray(valid), self.cache.device_tables(),
-                  jnp.asarray(ctx_lens), jnp.asarray(gather),
-                  jnp.asarray(temps), jnp.asarray(seeds),
-                  jnp.asarray(counters)), rows)
-        return batch, chunks
+        arrays = (t, sample, (tokens, positions, valid, ctx_lens, gather,
+                              temps, seeds, counters), rows)
+        return arrays, chunks
 
     def _run_step(self, batch, spec: bool = False):
         t, sample, args, rows = batch

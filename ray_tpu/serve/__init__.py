@@ -26,7 +26,11 @@ from ray_tpu.serve.api import (  # noqa: F401
 )
 from ray_tpu.serve.asgi import ingress  # noqa: F401
 from ray_tpu.serve.batching import batch  # noqa: F401
-from ray_tpu.serve.llm import LLMDeployment, llm_stream_resume  # noqa: F401
+from ray_tpu.serve.llm import (  # noqa: F401
+    LLMDeployment,
+    LLMReplica,
+    llm_stream_resume,
+)
 from ray_tpu.serve.kv_tier import (  # noqa: F401
     DecodeLLMDeployment,
     DisaggLLMHandle,

@@ -18,9 +18,11 @@ from (seed, produced+sample_offset), so the resumed request draws the
 same keys the dead replica would have drawn).
 """
 
+import time
 from typing import List, Optional
 
 from ray_tpu.serve.api import deployment
+from ray_tpu.util import spans
 
 
 def llm_stream_resume(args, kwargs, received):
@@ -59,9 +61,10 @@ def llm_stream_resume(args, kwargs, received):
     return (new_prompt,), kwargs
 
 
-@deployment(name="llm", max_concurrent_queries=64)
-class LLMDeployment:
-    """Replica callable wrapping an InferenceEngine.
+class LLMReplica:
+    """Replica callable wrapping an InferenceEngine: the class that
+    `serve.LLMDeployment` deploys, under a name of its own for whoever
+    subclasses it or calls it without a cluster.
 
     Usage::
 
@@ -104,6 +107,7 @@ class LLMDeployment:
             spec_k=int(spec_k), draft_proposer=draft_proposer,
             spec_adaptive=GLOBAL_CONFIG.spec_adaptive,
             kv_tier=kv_tier)
+        self._profile = None        # the open profiler session's span
 
     def generate(self, prompt, max_new_tokens: int = 16,
                  temperature: float = 0.0, eos_id: Optional[int] = None,
@@ -160,3 +164,32 @@ class LLMDeployment:
         """Kernel calls and in-place bytes of each compiled step shape
         (see InferenceEngine.compiled_steps)."""
         return self._engine.compiled_steps()
+
+    def start_trace(self, trace_dir: str) -> bool:
+        """Open a jax profiler session in this replica, the only process
+        that can trace its chip (`handle.options("start_trace")`): device
+        operations and the engine's `engine/<phase>` annotations on one
+        clock, the Python tracer off (it would slow the loop it measures).
+        One `engine/profile` span a session says when the profiler was
+        open, and how long its start and stop held the caller."""
+        import jax
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        t0 = time.perf_counter()
+        self._profile = spans.begin("engine", "profile", trace_dir=trace_dir)
+        jax.profiler.start_trace(trace_dir, profiler_options=options)
+        self._profile_start_s = time.perf_counter() - t0
+        return True
+
+    def stop_trace(self) -> bool:
+        """Close the session `start_trace` opened and write its file."""
+        import jax
+        t0 = time.perf_counter()
+        jax.profiler.stop_trace()
+        spans.end(self._profile, start_s=self._profile_start_s,
+                  stop_s=time.perf_counter() - t0)
+        self._profile = None
+        return True
+
+
+LLMDeployment = deployment(name="llm", max_concurrent_queries=64)(LLMReplica)

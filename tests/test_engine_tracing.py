@@ -20,6 +20,15 @@ from ray_tpu.util import events, tracing
 PHASE_FIELDS = ("admit_ms", "build_ms", "dispatch_ms", "fetch_ms",
                 "commit_ms")
 PHASES = ("admit", "build_batch", "dispatch", "fetch", "commit")
+# what `build_batch` and `commit` are made of, and the two clocks beside them
+BUILD_PARTS = ("windows", "assemble", "upload")
+COMMIT_PARTS = ("release", "lock", "deliver")
+PARTS = BUILD_PARTS + COMMIT_PARTS
+PART_FIELDS = tuple(f"{p}_ms" for p in PARTS)
+STEP_FIELDS = {"decode", "prefill", "waiting", "wall_ms", *PHASE_FIELDS,
+               "ahead", *PART_FIELDS, "gc_ms"}
+# one iteration in `engine._CPU_EVERY`, drawn, reads the thread's CPU clock too
+CLOCK_FIELDS = {"cpu_ms", "cpu_wall_ms"}
 
 
 def _engine(**kw):
@@ -93,8 +102,7 @@ def test_an_expert_engine_adds_counters_to_stats_and_nothing_to_a_step():
     assert {e["kind"] for e in since} <= {"step", "submit", "admit",
                                           "finish", "prefix_miss"}
     for e in steps:
-        assert set(e["payload"]) == {"decode", "prefill", "waiting",
-                                     "wall_ms", *PHASE_FIELDS, "ahead"}
+        assert set(e["payload"]) - CLOCK_FIELDS == STEP_FIELDS
     moe0, moe1 = s0["moe"], s1["moe"]
     assert moe1["assignments"] - moe0["assignments"] == (
         (19 + len(out) - 1) * cfg.n_experts_per_tok * cfg.n_layers)
@@ -299,8 +307,7 @@ def test_a_latent_share_engine_counts_on_the_host_and_adds_nothing_to_a_step():
     assert {e["kind"] for e in since} <= {"step", "submit", "admit",
                                           "finish", "prefix_miss"}
     for e in steps:
-        assert set(e["payload"]) == {"decode", "prefill", "waiting",
-                                     "wall_ms", *PHASE_FIELDS, "ahead"}
+        assert set(e["payload"]) - CLOCK_FIELDS == STEP_FIELDS
 
     def grew(key):
         return {k: s1[key][k] - s0[key][k] for k in s1[key]
@@ -400,8 +407,7 @@ def test_a_windowed_engine_counts_rows_and_compactions_and_adds_a_span():
     steps = [e for e in since if e["kind"] == "step"]
     assert len(steps) == s1["steps"] - s0["steps"] > 0
     for e in steps:
-        assert set(e["payload"]) == {"decode", "prefill", "waiting",
-                                     "wall_ms", *PHASE_FIELDS, "ahead"}
+        assert set(e["payload"]) - CLOCK_FIELDS == STEP_FIELDS
     assert {e["kind"] for e in since} <= {"step", "submit", "admit",
                                           "finish", "prefix_miss",
                                           "eva_compact"}
@@ -428,3 +434,290 @@ def test_a_windowed_engine_counts_rows_and_compactions_and_adds_a_span():
     # (its `pool_copies` are 0 compiled for the chip: tests/test_tpu_aot.py;
     # the CPU backend donates nothing)
     assert programs["compact_lanes1"]["custom_calls"] == 0
+
+
+def _median(values):
+    values = sorted(values)
+    return values[len(values) // 2]
+
+
+def test_step_records_name_the_parts_of_build_batch_and_commit():
+    """The six parts and the two clocks in the step's one record: the
+    parts of a phase add up to it (what is left over is loop glue, a few
+    microseconds); one iteration in `_CPU_EVERY` on average, drawn and not
+    counted off, reads the thread's CPU clock around the four host phases
+    (`cpu_ms`, no more than their wall, `cpu_wall_ms`); and `stats()` sums
+    all of it and counts those iterations (`cpu_steps`)."""
+    engine = _engine()
+    engine.generate(list(range(1, 6)), 2)          # compile both shapes
+    s0, seq = engine.stats(), _last_seq()
+    handles = [engine.submit(list(range(1, n)), 12) for n in (4, 12, 20)]
+    ran = _drain(engine)
+    assert all(len(h.tokens()) == 12 for h in handles)
+    s1 = engine.stats()
+    since = [e for e in events.snapshot(plane="engine") if e["seq"] > seq]
+    records = [e["payload"] for e in since if e["kind"] == "step"]
+    # one ring event an iteration, none a token (36 were delivered)
+    assert len(records) == ran == s1["steps"] - s0["steps"]
+    assert {e["kind"] for e in since} <= {"step", "submit", "admit", "finish",
+                                          "prefix_miss", "prefix_hit"}
+    clocked = [r for r in records if "cpu_ms" in r]
+    assert 0 < len(clocked) < ran
+    assert len(clocked) == s1["cpu_steps"] - s0["cpu_steps"]
+    for r in records:
+        assert set(r) == STEP_FIELDS | (CLOCK_FIELDS if r in clocked
+                                        else set())
+        assert all(r[f] >= 0.0 for f in PART_FIELDS + ("gc_ms",))
+        assert r["windows_ms"] == 0.0              # no windowed cache here
+        assert sum(r[f"{p}_ms"] for p in BUILD_PARTS) <= r["build_ms"]
+        assert sum(r[f"{p}_ms"] for p in COMMIT_PARTS) <= r["commit_ms"]
+    for r in clocked:
+        # from admit's start to dispatch's end and over commit: the four
+        # host phases and the glue between them, fetch left out
+        host = r["wall_ms"] - r["fetch_ms"]
+        assert host * 0.9 - 0.05 <= r["cpu_wall_ms"] <= host + 0.1
+        assert 0.0 <= r["cpu_ms"] <= r["cpu_wall_ms"] * 1.05 + 0.05
+    # an iteration that built something: its parts are nearly all of it
+    # (the median: one preempted iteration of a loaded machine is allowed)
+    built = [r for r in records if r["decode"] or r["prefill"]]
+    assert _median([sum(r[f"{p}_ms"] for p in BUILD_PARTS) / r["build_ms"]
+                    for r in built]) > 0.9
+    assert _median([sum(r[f"{p}_ms"] for p in COMMIT_PARTS) / r["commit_ms"]
+                    for r in records]) > 0.9
+    assert all(r["assemble_ms"] > 0.0 and r["upload_ms"] > 0.0 for r in built)
+    assert set(s1["part_s"]) == set(PARTS)
+    for part in PARTS:
+        assert s1["part_s"][part] - s0["part_s"][part] == pytest.approx(
+            sum(r[f"{part}_ms"] for r in records) / 1e3)
+    for key in ("cpu", "cpu_wall"):
+        assert s1[f"{key}_s"] - s0[f"{key}_s"] == pytest.approx(
+            sum(r[f"{key}_ms"] for r in clocked) / 1e3)
+    assert 0.0 < s1["cpu_s"] - s0["cpu_s"] <= (
+        s1["cpu_wall_s"] - s0["cpu_wall_s"]) * 1.05
+    assert set(s1["phase_s"]) == set(PHASES)       # five keys, as before
+
+
+def test_bench_rl_warms_every_verify_width_through_build_upload_run():
+    """`bench_rl.py` compiles its engine's programs outside the timed
+    rollout by the chain the loop itself runs, `_build_batch` -> `_upload`
+    -> `_run_step`, over no lane at all: the script's own `_warm` on a
+    nano actor leaves a program for T=1 and every verify width, and a
+    rollout behind it compiles nothing."""
+    import argparse
+    import sys
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    sys.path.insert(0, root)
+    try:
+        import bench_rl
+    finally:
+        sys.path.remove(root)
+    args = argparse.Namespace(config="nano", prompt_len=8, new_tokens=6,
+                              spec_k=2)
+    actor = bench_rl._make_actor(args.spec_k, 2, args, None)
+    prompts = bench_rl._prompts(2, args.prompt_len, 4, 64)
+    bench_rl._warm(actor, prompts, args.spec_k)
+    engine = actor.engine
+    made = set(engine._step_fns)
+    assert {(1, False, False, 0), (2, False, True, 0), (3, False, True, 0)} \
+        <= made
+    batch, _, metrics = actor.rollout(prompts, args.new_tokens)
+    assert metrics["tokens"] == 2 * args.new_tokens
+    assert set(engine._step_fns) == made
+
+
+def test_collector_pauses_are_counted_where_they_happen():
+    """One `gc.callbacks` hook a process, whatever the number of engines:
+    `stats()["gc"]` counts collections by generation with their seconds,
+    and a collection that falls inside an iteration is in that iteration's
+    `gc_ms`."""
+    import gc
+
+    from ray_tpu.inference import engine as engine_mod
+
+    engine, other = _engine(), _engine()
+    assert gc.callbacks.count(engine_mod._gc_hook) == 1
+    engine.generate(list(range(1, 6)), 2)          # compile both shapes
+    g0 = engine.stats()["gc"]
+    gc.collect()
+    gc.collect(0)
+    g1 = engine.stats()["gc"]
+    assert g1 == other.stats()["gc"]               # the process's, not an engine's
+    assert g1["collections"][2] == g0["collections"][2] + 1
+    assert g1["collections"][0] == g0["collections"][0] + 1
+    assert g1["seconds"] > g0["seconds"]
+    assert g0["full_seconds"] < g1["full_seconds"] <= g1["seconds"]
+    # a full collection inside every iteration's `admit`
+    admit = engine._admit
+    engine._admit = lambda: (gc.collect(), admit())[1]
+    seq = _last_seq()
+    engine.submit(list(range(1, 6)), 4)
+    ran = _drain(engine)
+    records = [e["payload"] for e in _steps_since(seq)]
+    g2 = engine.stats()["gc"]
+    assert len(records) == ran and all(r["gc_ms"] > 0.0 for r in records)
+    assert g2["collections"][2] >= g1["collections"][2] + ran
+    assert sum(r["gc_ms"] for r in records) / 1e3 <= (
+        g2["seconds"] - g1["seconds"]) * (1 + 1e-9)
+    assert all(r["gc_ms"] <= r["admit_ms"] for r in records)
+
+
+def test_the_timeline_keeps_the_loops_sums_by_the_second(monkeypatch):
+    """`stats()["timeline"]`: rows of the wall-clock seconds in which the
+    loop ran, which add up to `steps`, `step_wall_s`, `phase_s`, `part_s`
+    and `cpu_s` over the same span; plain lists of host numbers under one
+    header (JSON as it stands); never more than 128 rows."""
+    import json
+    import time
+
+    engine = _engine()
+    engine.generate(list(range(1, 6)), 2)          # compile both shapes
+    first = engine.stats()
+    assert first["timeline"]["columns"] == [
+        "t", "steps", "prefill_steps", "wall_s", "phase_s", "part_s",
+        "cpu_s", "cpu_wall_s", "cpu_steps", "gc_s", "longest_ms",
+        "longest_phase"]
+    assert first["timeline"]["phases"] == list(PHASES)
+    assert first["timeline"]["parts"] == list(PARTS)
+
+    def totals(stats):
+        rows = stats["timeline"]["rows"]
+        return {"steps": sum(r[1] for r in rows),
+                "prefill_steps": sum(r[2] for r in rows),
+                "wall_s": sum(r[3] for r in rows),
+                "phase_s": [sum(r[4][i] for r in rows) for i in range(5)],
+                "part_s": [sum(r[5][i] for r in rows) for i in range(6)],
+                "cpu_s": sum(r[6] for r in rows),
+                "cpu_wall_s": sum(r[7] for r in rows),
+                "cpu_steps": sum(r[8] for r in rows)}
+
+    # everything this engine ever ran is still in the timeline
+    t = totals(first)
+    assert t["steps"] == first["steps"]
+    assert t["wall_s"] == pytest.approx(first["step_wall_s"])
+    assert t["phase_s"] == pytest.approx(
+        [first["phase_s"][p] for p in PHASES])
+    assert t["part_s"] == pytest.approx([first["part_s"][p] for p in PARTS])
+    assert t["cpu_s"] == pytest.approx(first["cpu_s"])
+    assert t["cpu_wall_s"] == pytest.approx(first["cpu_wall_s"]) and \
+        t["cpu_wall_s"] > 0.0
+    assert t["cpu_steps"] == first["cpu_steps"] > 0
+    assert t["prefill_steps"] == first["prefill"]["steps"] == 1
+    handles = [engine.submit(list(range(1, n)), 6) for n in (4, 20)]
+    _drain(engine)
+    assert all(len(h.tokens()) == 6 for h in handles)
+    later = engine.stats()
+    t = totals(later)
+    assert t["steps"] == later["steps"] > first["steps"]
+    assert t["wall_s"] == pytest.approx(later["step_wall_s"])
+    assert t["phase_s"] == pytest.approx(
+        [later["phase_s"][p] for p in PHASES])
+    rows = later["timeline"]["rows"]
+    now = int(time.time())
+    assert [r[0] for r in rows] == sorted({r[0] for r in rows})
+    assert all(now - 120 < r[0] <= now for r in rows)
+    for r in rows:
+        assert r[1] >= 1 and r[11] in PHASES       # ran, so something was longest
+        assert r[10] / 1e3 <= r[3] * (1 + 1e-9)    # the longest is one of them
+    # what a copy of stats() holds is the caller's: host numbers, JSON
+    text = json.dumps(later["timeline"])
+    assert json.loads(text) == later["timeline"]
+    rows[0][4][0] += 1.0
+    assert engine.stats()["timeline"]["rows"][0][4][0] != rows[0][4][0]
+
+    # 200 seconds of iterations leave the newest 128
+    clock = iter(range(10_000, 10_200))
+    monkeypatch.setattr(time, "time", lambda: float(next(clock)))
+    for _ in range(200):
+        engine._close_second(int(time.time()))
+        engine._steps += 1
+        engine._step_wall_s += 0.005
+        engine._second[2:] = 5.0, "fetch"
+    monkeypatch.undo()
+    rows = engine.stats()["timeline"]["rows"]
+    # (the open second is given too: 127 closed rows behind it)
+    assert len(rows) == 128 and [r[0] for r in rows] == list(
+        range(10_072, 10_200))
+    assert rows[-1][1:3] == [1, 0] and rows[-1][3] == pytest.approx(0.005)
+    assert rows[-1][10:] == [5.0, "fetch"]
+    assert len(engine._timeline) == 128
+    assert len(json.dumps(engine.stats()["timeline"])) < 64 * 1024
+
+
+def _annotations(trace_dir, prefix):
+    """(start, end, name) of the annotations whose name starts with
+    `prefix`, in time order, over every line of the host plane."""
+    from jax.profiler import ProfileData
+    path, = glob.glob(os.path.join(str(trace_dir), "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    host, = [p for p in ProfileData.from_file(path).planes
+             if p.name == "/host:CPU"]
+    return sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
+                  for line in host.lines for e in line.events
+                  if e.name.startswith(prefix))
+
+
+def test_every_part_lies_inside_its_phase_in_the_profilers_trace(tmp_path):
+    """`engine.build_batch/<part>` and `engine.commit/<part>` are nested in
+    `engine/build_batch` and `engine/commit`; the five `engine/` phases
+    stay flat siblings, and no part's name starts with the `engine/` that
+    the idle split and the flatness test read."""
+    from ray_tpu.models import evabyte
+    engine = InferenceEngine(
+        "evabyte", evabyte.CONFIGS["evabyte-nano"], auto_start=False,
+        max_lanes=2, prefill_chunk=16, prefill_lanes=1, block_size=8,
+        num_blocks=32)
+    engine.generate(list(range(1, 40)), 2)         # compile all four shapes
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        seq = _last_seq()
+        assert len(engine.generate(list(range(2, 30)), 10)) == 10
+    finally:
+        jax.profiler.stop_trace()
+    records = [e["payload"] for e in _steps_since(seq)]
+    assert any(r["windows_ms"] > 0.0 for r in records)      # the edge at 32
+    phases = _phases_in_trace(tmp_path)
+    for (_, end, name), (start, _, after) in zip(phases, phases[1:]):
+        assert end <= start, (name, after)
+    parts = _annotations(tmp_path, "engine.")
+    assert {name for _, _, name in parts} == {
+        *(f"engine.build_batch/{p}" for p in BUILD_PARTS),
+        *(f"engine.commit/{p}" for p in COMMIT_PARTS)}
+    for start, end, name in parts:
+        phase = name.split("/")[0].replace(".", "/")
+        assert any(p0 <= start and end <= p1 and pname == phase
+                   for p0, p1, pname in phases), name
+    # and flat among themselves, in the order of an iteration
+    for (_, end, name), (start, _, after) in zip(parts, parts[1:]):
+        assert end <= start, (name, after)
+    closes = [p for p in parts if p[2] == "engine.build_batch/windows"]
+    assert len(closes) == sum(r["decode"] + r["prefill"] > 0 for r in records)
+
+
+def test_a_replica_traces_itself_and_says_when(tmp_path):
+    """`serve.LLMReplica` (the class `serve.LLMDeployment` deploys) opens
+    and closes a profiler session on its own chip; a session leaves one
+    `engine/profile` span with the seconds its start and stop took, and a
+    trace that holds the engine's phases."""
+    from ray_tpu import serve
+    assert serve.LLMDeployment._cls_or_fn is serve.LLMReplica
+    replica = serve.LLMReplica(model="gpt", config="nano", max_lanes=2,
+                               prefill_chunk=8)
+    try:
+        list(replica.generate(list(range(1, 6)), 2))
+        seq = _last_seq()
+        assert replica.start_trace(str(tmp_path)) is True
+        assert len(list(replica.generate(list(range(1, 9)), 4))) == 4
+        assert replica.stop_trace() is True
+    finally:
+        replica._engine.shutdown()
+    edges = [e for e in events.snapshot(plane="engine", kind="profile")
+             if e["seq"] > seq]
+    assert [e["payload"]["ph"] for e in edges] == ["B", "E"]
+    assert edges[0]["payload"]["trace_dir"] == str(tmp_path)
+    end = edges[1]["payload"]
+    assert end["start_s"] > 0.0 and end["stop_s"] > 0.0
+    assert end["dur"] >= end["start_s"] + end["stop_s"]
+    assert {n for _, _, n in _phases_in_trace(tmp_path)} >= {
+        "engine/build_batch", "engine/fetch", "engine/commit"}

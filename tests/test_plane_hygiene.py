@@ -40,8 +40,16 @@ def test_call_sites_exist():
 
 
 def test_planes_are_registered():
+    """A plane is one of `events.PLANES`, or `<plane>.<phase>` for a part
+    nested in the `spans.phase` of that plane and kind (the engine's
+    `engine.build_batch/upload`: a profiler annotation under a name no
+    reader of the flat `engine/` phases sees; it writes nothing to the
+    ring, so it is no plane of the recorder's)."""
+    sites = {(pl, k) for _, _, pl, k in _call_sites()}
     bad = [(str(f), ln, pl, k) for f, ln, pl, k in _call_sites()
-           if pl not in events.PLANES]
+           if pl not in events.PLANES
+           and not (pl.count(".") == 1 and tuple(pl.split(".")) in sites
+                    and pl.split(".")[0] in events.PLANES)]
     assert not bad, f"unregistered plane strings: {bad}"
 
 
@@ -376,3 +384,49 @@ def test_latent_prefill_and_share_counters_are_host_sums_in_build_batch():
     # one Pallas call of that name: `paged_decode_roofline` divides by
     # every kernel's calls, and two readers find this one by its name
     assert names.count("paged_decode_attention") == 1
+
+
+def test_the_parts_the_clocks_and_the_timeline_add_no_transfer_and_no_event():
+    """PR 35's additions to an iteration: six nested `spans.phase` parts
+    under `engine.<phase>` names (never `engine/`, which the idle split and
+    the flatness test read), two reads of the thread's CPU clock on either
+    side of `fetch` in one iteration of four, drawn (a system call each), a
+    difference of the collector's seconds and a row of host numbers a
+    second.  None of it touches the device, the ring beyond
+    the one `engine/step` record, or the environment."""
+    src = (PKG / "inference" / "engine.py").read_text()
+    parts = re.findall(r'spans\.phase\(\s*"(engine\.\w+)",\s*"(\w+)"\)', src)
+    assert sorted(parts) == sorted([
+        ("engine.build_batch", "windows"), ("engine.build_batch", "assemble"),
+        ("engine.build_batch", "upload"), ("engine.commit", "release"),
+        ("engine.commit", "lock"), ("engine.commit", "deliver")])
+    assert 'spans.phase("engine/' not in src
+    step_body = src[src.index("    def step(self)"):
+                    src.index("    def _sums(self)")]
+    assert step_body.count("time.thread_time()") == 4 == src.count(
+        "time.thread_time()")
+    assert step_body.count("if clocked:") == 4     # each read is a sampled one
+    assert step_body.count("events.record(") == 1
+    # the commit's parts sit inside the commit phase, in this order
+    commit = step_body[step_body.index('spans.phase("engine", "commit")'):]
+    at = [commit.index(f'spans.phase("engine.commit", "{p}")')
+          for p in ("release", "lock", "deliver")]
+    assert at == sorted(at) and at[-1] < commit.index('took["commit"]')
+    keep = src[src.index("    def _sums(self)"):
+               src.index("    def _prefill_len(")]
+    hook = src[src.index("def _gc_hook("):src.index("def _metrics(")]
+    for body in (keep, hook):
+        for banned in ("jnp.", "jax.", "np.", "events.", "spans.", "self.cache",
+                       "_last_tok", "_moe_load"):
+            assert banned not in body, banned
+    # the uploads are where `upload` times them, and nowhere else in a step
+    plan = src[src.index("    def _plan(self"):
+               src.index("    def _ends_in_flight(")]
+    assert plan.count("self.cache.device_tables()") == 1 == src.count(
+        "self.cache.device_tables()")
+    build = src[src.index("    def _build_batch("):
+                src.index("    def _run_step(")]
+    run = src[src.index("    def _run_step("):src.index("    def _warm_widths(")]
+    # (but for a compact program's `rows`, which stay `dispatch`'s)
+    assert "jnp.asarray" not in build
+    assert run.count("jnp.asarray") == 1 == run.count("jnp.asarray(rows)")
