@@ -248,8 +248,10 @@ def run(ctx: dict, say) -> dict:
     ranks = [k for v in verdicts for k in v[1]]
     argmax_pct = 100.0 * float(np.mean([k == 0 for k in ranks])) \
         if ranks else 0.0
-    ok_tokens = bool(gaps) and max(gaps) <= check["max_gap"] \
-        and float(np.mean(gaps)) <= check["mean_gap"]
+    seen = {"mean_gap": float(np.mean(gaps)) if gaps else None,
+            "max_gap": max(gaps) if gaps else None, "argmax_pct": argmax_pct}
+    ok_tokens = bool(gaps) and seen["max_gap"] <= check["max_gap"] \
+        and seen["mean_gap"] <= check["mean_gap"]
     say(f"reference check on {len(samples)} request(s), {len(gaps)} served "
         f"tokens, {check_s:.1f} s: reference logit of the served token under "
         f"the reference maximum by mean {np.mean(gaps) if gaps else -1:.4f} "
@@ -315,6 +317,8 @@ def run(ctx: dict, say) -> dict:
         f"{manifest.memory_line(after)}")
     run = {
         "correct": ok_tokens and not hung,
+        "compared": {k: {"value": seen[k], "limit": check[k]}
+                     for k in ("mean_gap", "max_gap")},
         "attempted": len(judged), "failed": len(failed),
         "end_to_end": end_to_end, "device": device, "memory": after,
         "fields": ctx["fields"], "traffic": traffic, "cell": cell,
@@ -324,9 +328,9 @@ def run(ctx: dict, say) -> dict:
         "engine_events": engine_events, "marks": marks,
         "notes": {"lateness_p99_ms": late_p99, "cut": cut,
                   "tokens": tokens, "stats0": s0, "stats1": s1,
-                  "check": {"mean_gap": float(np.mean(gaps)) if gaps else None,
-                            "max_gap": max(gaps) if gaps else None,
-                            "argmax_pct": argmax_pct}},
+                  "check": seen, "base": base,
+                  "marks": {k: marks[k] for k in ("trace_on", "trace_off")
+                            if k in marks}},
     }
     if ctx["trace"] and "trace_off" in marks:
         trace_reduce.attach(run, os.path.join(ctx["out_dir"], "trace"), ctx,

@@ -226,6 +226,10 @@ def run(ctx: dict, say) -> dict:
                  setup["warmup_losses"] + [window["final_loss"]])
     run = {
         "correct": finite and all(c["ok"] for c in final["checks"]),
+        "compared": {
+            f"loss_diff_step{c['step']}": {
+                "value": abs(c["system"] - c["reference"]),
+                "limit": c["tolerance"]} for c in final["checks"]},
         "attempted": window["steps"], "failed": 0 if finite else 1,
         "end_to_end": {"train_tokens_per_s": tokens_per_s,
                        "setup_s": setup_s},

@@ -15,7 +15,7 @@ kernel's block-diagonal query multiplies besides.
 
 from __future__ import annotations
 
-from benchmark import flops
+from benchmark import flops, metrics
 from benchmark.latent_flops import window
 
 
@@ -44,20 +44,12 @@ def live_rows(records, t: float, f: dict) -> int:
 
 
 def slice_rows(run: dict, instants: int = 16):
-    """`live_rows` over the traced slice, or None: the mean at `instants`
-    evenly spaced moments of [trace_on, trace_on + slice_s].  (Not up to the
-    `trace_off` mark: that is set when the profiler has written its file,
-    seconds after the slice's end; and not at one moment: a lane that ends
-    or starts moves the total by 4%.)"""
-    marks = run.get("marks", {})
-    if "trace_on" not in marks or "trace_off" not in marks:
-        return None
-    on = marks["trace_on"]
-    span = min(float(run["traffic"]["trace"]["slice_s"]),
-               marks["trace_off"] - on)
-    return sum(live_rows(run["records"], on + span * (i + 0.5) / instants,
-                         run["fields"])
-               for i in range(instants)) / instants
+    """`live_rows` over the traced slice, or None without one: the mean at
+    `instants` evenly spaced moments of it (`metrics.slice_mean`, the one
+    rule of the paged kernels' readers)."""
+    return metrics.slice_mean(
+        run, lambda records, t: live_rows(records, t, run["fields"]),
+        instants)
 
 
 def decode_attention(total_rows: float, lanes: int, f: dict):

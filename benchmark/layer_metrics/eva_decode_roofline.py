@@ -9,18 +9,23 @@ a byte it is bound by bytes)."""
 
 from __future__ import annotations
 
-from benchmark import eva_flops, flops, manifest
+from benchmark import eva_flops, flops, manifest, readers
 
 
 def read(run: dict):
     t = run.get("trace") or {}
     kernel = (t.get("kernels") or {}).get("paged_decode_attention")
-    if not kernel or not kernel["seconds"] \
-            or "window_size" not in run["fields"]:
+    if "window_size" not in run["fields"]:
         return None
+    if not kernel or not kernel["seconds"]:
+        return readers.not_measured(run, "no trace, or no kernel time: no "
+                                         "paged_decode_attention call in it")
     total = eva_flops.slice_rows(run)
+    if total is None:
+        return readers.not_measured(run, readers.NO_SESSION)
     if not total:
-        return None
+        return readers.not_measured(run, "no rows in the slice: no request "
+                                         "held a token during it")
     least, _ = flops.roofline_s(*eva_flops.decode_attention(
         total, run["traffic"]["engine"]["max_lanes"], run["fields"]),
         manifest.peaks(run["device"]["kind"]))

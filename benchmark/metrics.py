@@ -94,6 +94,30 @@ def live_context_tokens(records, t: float) -> int:
     return total
 
 
+def slice_mean(run: dict, fn, instants: int = 16):
+    """The mean of `fn(records, t)` at `instants` evenly spaced moments of
+    the traced slice [trace_on, trace_on + slice_s], or None where no
+    profiler session was opened and closed.  The `trace_off` mark is set
+    when the profiler has written its file, seconds to minutes after the
+    slice's end, so it only says that a session was closed and caps a slice
+    cut short; nothing read here moves with when it was set.  Not one
+    moment either: a lane that ends or starts moves the total by 4%."""
+    marks = run.get("marks", {})
+    if "trace_on" not in marks or "trace_off" not in marks:
+        return None
+    on = marks["trace_on"]
+    span = min(float(run["traffic"]["trace"]["slice_s"]),
+               marks["trace_off"] - on)
+    return sum(fn(run["records"], on + span * (i + 0.5) / instants)
+               for i in range(instants)) / instants
+
+
+def slice_context_tokens(run: dict, instants: int = 16):
+    """`live_context_tokens` over the traced slice (`slice_mean`): what a
+    paged kernel's time in the slice is divided by."""
+    return slice_mean(run, live_context_tokens, instants)
+
+
 def spread(values) -> float:
     """Distance between the quartiles over the median: the driver's measure
     of run-to-run spread."""

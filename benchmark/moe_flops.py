@@ -10,7 +10,7 @@ number of its rows, and activations are read and written once.
 
 from __future__ import annotations
 
-from benchmark import flops, metrics
+from benchmark import flops
 
 
 def window_load(run: dict):
@@ -27,16 +27,6 @@ def window_load(run: dict):
     return (m1["assignments"] - m0["assignments"],
             [b - a for a, b in zip(m0["expert_load"], m1["expert_load"])],
             m1["experts_hit"] - m0["experts_hit"], steps)
-
-
-def slice_context(run: dict):
-    """Tokens of context the live requests held in the middle of the traced
-    slice (the client's own records), or None without a slice."""
-    marks = run.get("marks", {})
-    if "trace_off" not in marks:
-        return None
-    return metrics.live_context_tokens(
-        run["records"], (marks["trace_on"] + marks["trace_off"]) / 2)
 
 
 def grouped_matmul(rows: float, experts_hit: float, k: int, n: int,

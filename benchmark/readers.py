@@ -50,21 +50,42 @@ def flash_roofline(run: dict):
     return 100.0 * needed / kernel["seconds"]
 
 
-def paged_roofline(run: dict):
+NO_SESSION = ("no closed profiler session: the trace_on or trace_off mark "
+              "is missing")
+
+
+def not_measured(run: dict, why: str) -> None:
+    """A reader's `None`, with the input it lacked kept for `run.py`'s "not
+    measured" line: a metric left out of a traced run's last line refuses
+    the run, and the refusal does not say which input was missing."""
+    run["not_measured"] = why
+    return None
+
+
+def paged_roofline(run: dict, kv_heads: int):
+    """Device time of the kernel named `paged_decode_attention` in the traced
+    slice against the least the chip could take for its calls: each call is
+    one layer's single-query attention, `kv_heads` cached heads wide, over
+    the context the lanes held DURING the slice
+    (`metrics.slice_context_tokens`, from the client's own records)."""
     t = run.get("trace")
-    if not t or not t["kernel_s"] or "trace_off" not in run.get("marks", {}):
-        return None
-    f = run["fields"]
-    calls = sum(k["calls"] for k in t["kernels"].values())
-    mid = (run["marks"]["trace_on"] + run["marks"]["trace_off"]) / 2
-    context = metrics.live_context_tokens(run["records"], mid)
+    if not t:
+        return not_measured(run, "no trace")
+    kernel = t["kernels"].get("paged_decode_attention")
+    if not kernel or not kernel["seconds"]:
+        return not_measured(run, "no kernel time: no paged_decode_attention "
+                                 "call in the trace")
+    context = metrics.slice_context_tokens(run)
+    if context is None:
+        return not_measured(run, NO_SESSION)
     if not context:
-        return None
-    peaks = manifest.peaks(run["device"]["kind"])
+        return not_measured(run, "no context in the slice: no request held "
+                                 "a token during it")
+    f = run["fields"]
     least, _ = flops.roofline_s(*flops.paged_decode(
-        context, run["traffic"]["engine"]["max_lanes"], f["n_heads"],
-        f["d_model"] // f["n_heads"]), peaks)
-    return 100.0 * least * calls / t["kernel_s"]
+        context, run["traffic"]["engine"]["max_lanes"], kv_heads,
+        f["d_model"] // f["n_heads"]), manifest.peaks(run["device"]["kind"]))
+    return 100.0 * least * kernel["calls"] / kernel["seconds"]
 
 
 def span_seconds(events: list, kind: str) -> dict:

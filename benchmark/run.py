@@ -156,6 +156,7 @@ def main(argv=None) -> int:
         values = {}
         for name in names:
             reader = manifest_mod.module("layer_metrics", name)
+            run.pop("not_measured", None)
             try:
                 value = reader.read(run)
             except KeyError as e:
@@ -164,7 +165,9 @@ def main(argv=None) -> int:
                 say(f"rehearsal: {name}: {e}")  # the CPU has no peaks row
                 value = None
             if value is None:
-                say(f"per-layer metric {name}: not measured")
+                why = run.get("not_measured")   # `readers.not_measured`
+                say(f"per-layer metric {name}: not measured"
+                    + (f" ({why})" if why else ""))
             else:
                 values[name] = value
         units = manifest.per_layer
@@ -184,11 +187,18 @@ def main(argv=None) -> int:
         line["breakdown"] = run["breakdown"]
     if args.rehearse:
         line["rehearsal"] = True
+    # What `correct` compared, each number beside its limit: the line's last
+    # key and standard error's last lines, which is all the driver's record
+    # keeps of a run that was not correct.
+    line["compared"] = run["compared"]
     with open(os.path.join(out_dir, "result.json"), "w") as f:
         json.dump({"line": line, "notes": run.get("notes", {})}, f, indent=1)
     say(f"entries in the compile cache now: "
         f"{compile_cache.entry_count(cache_dir)}; wall "
         f"{time.time() - T_PROCESS_START:.1f} s")
+    for name, c in line["compared"].items():
+        print(f"[bench] compared {name}: {c['value']} (limit {c['limit']})",
+              file=sys.stderr, flush=True)
     print(json.dumps(line), flush=True)
     return 0
 
