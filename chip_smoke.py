@@ -26,6 +26,7 @@ Usage: python3 chip_smoke.py          (needs a TPU; about 3 minutes cold)
 from __future__ import annotations
 
 import dataclasses
+import functools
 import json
 import os
 import signal
@@ -153,6 +154,25 @@ def kernels_leg() -> dict:
             pq[:, None], kp, vp, tables, ctx_lens,
             (ctx_lens - 1)[:, None], layer, kv_heads=kh)[:, 0]
         errs[f"paged_{name}"] = check(f"paged decode {name}", got, want)
+        # The T = 32 step's tiles (plain XLA, no Mosaic call) on the same
+        # pools: every lane's last rows as one chunk, short where the
+        # context is, one lane in four with no valid row.
+        t = 32
+        cq = jax.random.normal(jax.random.fold_in(kk, 3), (lanes, t, h, d),
+                               jnp.bfloat16)
+        start = jnp.maximum(ctx_lens - t, 0)
+        pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None]
+        valid = (pos < ctx_lens[:, None]) & (jnp.arange(lanes) % 4 < 3)[:, None]
+        got = jax.jit(functools.partial(A.paged_chunk_attention, kv_heads=kh))(
+            cq, kp, vp, tables, ctx_lens, pos, valid, layer)
+        want = A.paged_attention_reference(cq, kp, vp, tables, ctx_lens, pos,
+                                           layer, kv_heads=kh)
+        keep = valid[..., None, None]
+        errs[f"paged_t32_{name}"] = check(
+            f"paged chunk T=32 {name}", jnp.where(keep, got, 0),
+            jnp.where(keep, want, 0))
+        require(not np.asarray(got[3::4], np.float32).any(),
+                f"paged chunk T=32 {name}: a lane without work is not zero")
 
     return {"platform": dev[0].platform, "device_kind": dev[0].device_kind,
             "n_devices": len(dev), "max_abs_err": errs,

@@ -533,7 +533,8 @@ class InferenceEngine:
         # attended over: `_latent` over a latent cache, `_paged` over a
         # K/V one, which also counts the runs of `_paged_run` tokens the
         # decode kernel found context in (it visits no others).
-        self._prefill = {"steps": 0, "lanes": 0, "rows": 0, "rows_valid": 0}
+        self._prefill = {"steps": 0, "lanes": 0, "rows": 0, "rows_valid": 0,
+                         "ctx_rows": 0}
         self._tokens_run = 0
         latent = self.cache.kind == "latent"
         self._latent = {"decode_steps": 0, "ctx_tokens": 0} if latent else None
@@ -1453,6 +1454,11 @@ class InferenceEngine:
             pf["lanes"] += len(live)
             pf["rows"] += n * t
             pf["rows_valid"] += fed_now
+            # The table rows each prefilling lane's last valid row attends
+            # over: what the tiled T > 1 attention reads, where the dense
+            # path read every lane's whole table.
+            pf["ctx_rows"] += sum(self.cache.rows_held(int(c))
+                                  for c in ctx_lens[valid[:, 0]])
         elif t == 1:
             ctx = [int(ctx_lens[lane]) for lane, _ in live]
             if self._eva is not None:

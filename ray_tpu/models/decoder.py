@@ -318,18 +318,18 @@ def heads_attention(h, p, spec, config, mesh, position_offset=0):
 def _attend_rows(q, k, v, pools, p, config, block_tables, rows, valid,
                  n_rows):
     """The slice's K and V written into the whole pools at the layer, at
-    `rows` [B, T] of each lane's table, then attention over the table's
-    first `n_rows` [B] rows in the same buffers (ops/attention.py paged
-    path), projected back.  K/V are cached with kv_heads (GQA un-repeated:
-    the whole point of the grouped cache); the paged attention path expands
-    groups itself."""
+    `rows` [B, T] of each lane's table, then attention of the `valid` rows
+    over the table's first `n_rows` [B] rows in the same buffers
+    (ops/attention.py paged path), projected back.  K/V are cached with
+    kv_heads (GQA un-repeated: the whole point of the grouped cache); the
+    paged attention path groups the query heads itself."""
     from ray_tpu.ops.attention import paged_attention, paged_kv_update
 
     layer = p["cache_layer"]
     k_pool, v_pool = paged_kv_update(*pools, k, v, block_tables, rows, valid,
                                      layer)
     attn = paged_attention(q, k_pool, v_pool, block_tables, n_rows, rows,
-                           layer, kv_heads=config.n_kv_heads)
+                           layer, valid=valid, kv_heads=config.n_kv_heads)
     return (jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(q.dtype)),
             (k_pool, v_pool))
 

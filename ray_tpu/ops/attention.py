@@ -283,8 +283,10 @@ def _flash_forward_impl(q, k, v, causal, scale, block_q, block_k, interpret):
 # at a time, and no others (unused table entries are never read; rows past
 # the context length inside the last block are masked); the dense fallback
 # gathers the table into a contiguous context and masks (there unused
-# entries may point anywhere valid) — it covers CPU tests, odd head dims,
-# and the multi-token prefill path.
+# entries may point anywhere valid) — it covers the T=1 step on the CPU
+# and at odd head dims, and is the tests' oracle.  A T > 1 slice (prefill
+# chunk, draft run) attends in tiles of plain XLA over the blocks of the
+# lanes that have valid rows (`paged_chunk_attention`), on every backend.
 
 KV_ROW_ALIGN = 128
 # Blocks written per trip of the write loop: a T=1 step of 8 lanes is
@@ -386,16 +388,16 @@ _GATHER_ROW_BYTES = 4096
 
 
 def _table_blocks(pool, layer, block_tables):
-    """pool[layer, block_tables]: the blocks of every lane's table,
-    [B, MB, BS, W].  One gather where a row is at most `_GATHER_ROW_BYTES`.
-    A wider row (EvaByte's 4,096 bf16 columns) XLA gathers in halves, each
-    from a slice of the WHOLE pool that it first copies out (2.4 GB four
-    times a layer at that cell's sizes, compiled for a v5e): there the
-    blocks are sliced out one by one into the result, a megabyte each."""
+    """pool[layer, block_tables]: the blocks a table (of any rank) names,
+    [*block_tables.shape, BS, W].  One gather where a row is at most
+    `_GATHER_ROW_BYTES`.  A wider row (EvaByte's 4,096 bf16 columns) XLA
+    gathers in halves, each from a slice of the WHOLE pool that it first
+    copies out (2.4 GB four times a layer at that cell's sizes, compiled
+    for a v5e): there the blocks are sliced out one by one into the result,
+    a megabyte each."""
     _, _, bs, w = pool.shape
     if w * pool.dtype.itemsize <= _GATHER_ROW_BYTES:
         return pool[layer, block_tables]
-    b, mb = block_tables.shape
     flat = block_tables.reshape(-1).astype(jnp.int32)
     layer = jnp.asarray(layer, jnp.int32)
     zero = jnp.zeros((), jnp.int32)
@@ -405,16 +407,16 @@ def _table_blocks(pool, layer, block_tables):
                                       (1, 1, bs, w))
         return jax.lax.dynamic_update_slice(out, block[0], (i, zero, zero))
 
-    out = jax.lax.fori_loop(0, b * mb, copy,
-                            jnp.zeros((b * mb, bs, w), pool.dtype),
-                            unroll=_KV_WRITE_UNROLL)
-    return out.reshape(b, mb, bs, w)
+    out = jax.lax.fori_loop(0, flat.shape[0], copy,
+                            jnp.zeros((flat.shape[0], bs, w), pool.dtype),
+                            unroll=min(flat.shape[0], _KV_WRITE_UNROLL))
+    return out.reshape(*block_tables.shape, bs, w)
 
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
                               q_positions, layer=0, *,
                               kv_heads: Optional[int] = None, scale=None):
-    """Masked-dense paged attention (fallback + prefill path).
+    """Masked-dense paged attention (the T=1 fallback and the oracle).
 
     q [B, T, H, D] at absolute q_positions [B, T]; pools
     [L, NB, BS, W], read at `layer`; kv_heads (default H) may divide
@@ -693,19 +695,174 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                       precision=jax.lax.Precision.HIGHEST)
 
 
+def _chunk_attention(q, out, block_tables, ctx_lens, q_positions, valid, *,
+                     fold, read, score, weigh, unfold, heads: int,
+                     block_size: int, v_width: int, scale: float,
+                     q_tile: int, ctx_tile: int, skip_idle: bool = False):
+    """The loop of the tiled T > 1 paths (`paged_chunk_attention`,
+    `latent_chunk_attention`), plain XLA.  Only the lanes that have valid
+    rows do work, each over its OWN blocks: per lane a loop over the tiles
+    of `q_tile` query rows of q [B, T, ...] that hold a valid row (`valid`
+    [B, T] marks a prefix of each lane's rows, `q_positions` [B, T] are
+    consecutive), and under it one over the tiles of `ctx_tile` context
+    rows at or before the tile's last row, with the online softmax of the
+    kernels above in float32.  A padding lane, the rows of a chunk behind
+    the prompt's end and the context behind the causal boundary cost
+    nothing; no lane's whole context is ever gathered.  Rows without work
+    stay as `out` [B, T, ...] has them (zero).
+
+    What a row is, is the caller's: `fold(tile)` makes a tile's queries
+    [QT, ...] the rows [..., QT * heads, K] the products take, `heads` to a
+    query, one after the other; `read(table, ids)` reads the context tile
+    at blocks `ids` of the lane's `table`; `score(rows, c)` [..., R, CT]
+    and `weigh(p, c)` [..., R, v_width] are the two products, in float32;
+    `unfold(o)` lays the rows' results out as [1, QT, ...] of `out`.
+    With `skip_idle` the lane loop makes a trip for each lane that has a
+    valid row and none for the others (a trip that finds no work is 2.5 us
+    on a v5e: 1.7 ms of a step of 48 layers in which 14 of 16 lanes ride
+    along; PERF.md section 6, PR 38)."""
+    t = q.shape[1]
+    mb = block_tables.shape[1]
+    qt = min(q_tile, t)
+    while t % qt:
+        qt -= 1
+    # blocks a context tile: no more than a table has
+    per = max(1, min(ctx_tile // block_size, mb))
+    ct = per * block_size
+    n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)           # [B]
+    kcol = jnp.arange(ct, dtype=jnp.int32)
+
+    def lane_body(lane, out):
+        table = block_tables[lane]
+        pos0 = q_positions[lane, 0]
+        n_ctx = ctx_lens[lane]
+
+        def q_body(qi, out):
+            rows = fold(jax.lax.dynamic_slice_in_dim(q[lane], qi * qt, qt, 0))
+            qpos = jnp.repeat(pos0 + qi * qt
+                              + jnp.arange(qt, dtype=jnp.int32), heads)
+            reach = jnp.minimum(pos0 + (qi + 1) * qt, n_ctx)
+
+            def ctx_body(kj, carry):
+                m, l, acc = carry
+                ids = jnp.clip(kj * per + jnp.arange(per), 0, mb - 1)
+                c = read(table, ids)
+                s = score(rows, c) * scale
+                kpos = kj * ct + kcol
+                keep = ((kpos[None, :] <= qpos[:, None])
+                        & (kpos[None, :] < n_ctx))
+                s = jnp.where(keep, s, NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m - m_new)
+                l = l * alpha + jnp.sum(p, -1, keepdims=True)
+                acc = acc * alpha + weigh(p, c)
+                return m_new, l, acc
+
+            lead = rows.shape[:-1]
+            init = (jnp.full(lead + (1,), NEG_INF, jnp.float32),
+                    jnp.zeros(lead + (1,), jnp.float32),
+                    jnp.zeros(lead + (v_width,), jnp.float32))
+            _, l, acc = jax.lax.fori_loop(0, -(-reach // ct), ctx_body, init)
+            o = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
+            return jax.lax.dynamic_update_slice(
+                out, unfold(o), (lane, qi * qt) + (0,) * (out.ndim - 2))
+
+        return jax.lax.fori_loop(0, -(-n_valid[lane] // qt), q_body, out)
+
+    if not skip_idle:
+        return jax.lax.fori_loop(0, q.shape[0], lane_body, out)
+    busy = n_valid > 0
+    order = jnp.argsort(~busy, stable=True)         # the busy lanes first
+    return jax.lax.fori_loop(0, jnp.sum(busy, dtype=jnp.int32),
+                             lambda i, out: lane_body(order[i], out), out)
+
+
+def paged_chunk_attention(q, k_pool, v_pool, block_tables, ctx_lens,
+                          q_positions, valid, layer=0, *,
+                          kv_heads: Optional[int] = None,
+                          scale: Optional[float] = None,
+                          q_tile: int = 512,
+                          ctx_tile: Optional[int] = None):
+    """Paged attention for a [B, T, H, D] slice of T > 1 query rows (a
+    prefill chunk, a draft run) over K/V pools [L, NB, BS, W] at `layer`:
+    `_chunk_attention`'s tiles, so a step of 16 lanes of which two prefill
+    64 tokens reads and scores those two lanes' 64 rows, not 1,024 rows of
+    all 16.  A tile's rows are read through the lane's table
+    (`_table_blocks`: block by block where a row is too wide for XLA's
+    gather) and unpacked to `kv_heads` heads; the query heads of a group
+    multiply their one K/V head as they are (GQA without a repeat of the
+    context).  Products in the pools' dtype accumulated in float32, as the
+    T=1 kernel's.  Each valid query attends to the rows at or before its
+    own `q_positions`; rows without work come out zero.
+
+    Tiles by the shapes (the arguments are the sweep's and the tests'): a
+    chunk of up to 512 rows is one query tile, so a context tile is read
+    once a chunk (EvaByte's [4, 512] over 2.8k rows: 9.7 ms for eight
+    layers at 512, 21.0 at 128, 33.7 dense); a context tile is four times
+    the chunk, between 128 and 512 rows: a short chunk's products are
+    small and it pays by the rows it reads (gpt2-xl's [16, 32] over 100-350
+    tokens: 19.2 ms for 48 layers at 128, 27.6 at 512), a long chunk's by
+    the trips it makes (v5e, PERF.md section 6, PR 38)."""
+    b, t, h, d = q.shape
+    kh = kv_heads or h
+    g = h // kh
+    bs, w = k_pool.shape[2:]
+    layer = jnp.asarray(layer, jnp.int32)
+    if ctx_tile is None:
+        ctx_tile = min(512, max(128, 4 * t))
+    if w * k_pool.dtype.itemsize > _GATHER_ROW_BYTES:
+        # Never one block a tile where `_table_blocks` slices blocks out:
+        # a lone `dynamic_slice` fuses into the product that reads it, and
+        # XLA re-lays the WHOLE pool for that product (both pools copied,
+        # 9 GB at EvaByte's sizes: the program does not fit a v5e).
+        ctx_tile = max(ctx_tile, 2 * bs)
+
+    def fold(tile):                     # [QT, H, D] -> [KH, QT * G, D]
+        return jnp.moveaxis(tile.reshape(-1, kh, g, d), 1, 0).reshape(
+            kh, -1, d)
+
+    def read(table, ids):               # K and V, each [CT, KH, D]
+        return tuple(unpack_kv_rows(
+            _table_blocks(pool, layer, table[ids]), kh, d).reshape(-1, kh, d)
+            for pool in (k_pool, v_pool))
+
+    def score(rows, kv):
+        return jnp.einsum("nrd,cnd->nrc", rows, kv[0],
+                          preferred_element_type=jnp.float32)
+
+    def weigh(p, kv):
+        return jnp.einsum("nrc,cnd->nrd", p.astype(kv[1].dtype), kv[1],
+                          preferred_element_type=jnp.float32)
+
+    def unfold(o):                      # [KH, QT * G, D] -> [1, QT, H, D]
+        return jnp.moveaxis(o.reshape(kh, -1, g, d), 0, 1).reshape(
+            1, -1, h, d)
+
+    out = _chunk_attention(
+        q.astype(k_pool.dtype), jnp.zeros(q.shape, k_pool.dtype),
+        block_tables, ctx_lens, q_positions, valid, fold=fold, read=read,
+        score=score, weigh=weigh, unfold=unfold, heads=g, block_size=bs,
+        v_width=d, scale=scale if scale is not None else 1.0 / np.sqrt(d),
+        q_tile=q_tile, ctx_tile=ctx_tile, skip_idle=True)
+    return out.astype(q.dtype)
+
+
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens, q_positions,
-                    layer=0, *, kv_heads: Optional[int] = None,
+                    layer=0, *, valid=None, kv_heads: Optional[int] = None,
                     scale: Optional[float] = None):
     """Dispatch paged attention for a [B, T, H, D] query slice: the T=1
-    decode step rides the single-query kernel path, multi-token prefill
-    chunks ride the masked-dense path."""
+    decode step rides the single-query kernel path, longer slices the
+    tiled path over the rows `valid` [B, T] marks (default: all)."""
     if q.shape[1] == 1:
         return paged_decode_attention(
             q[:, 0], k_pool, v_pool, block_tables, ctx_lens, layer,
             kv_heads=kv_heads, scale=scale)[:, None]
-    return paged_attention_reference(q, k_pool, v_pool, block_tables,
-                                     ctx_lens, q_positions, layer,
-                                     kv_heads=kv_heads, scale=scale)
+    if valid is None:
+        valid = jnp.ones(q.shape[:2], bool)
+    return paged_chunk_attention(q, k_pool, v_pool, block_tables, ctx_lens,
+                                 q_positions, valid, layer,
+                                 kv_heads=kv_heads, scale=scale)
 
 
 # ---------------------------------------------------------------------------
@@ -1020,71 +1177,31 @@ def latent_chunk_attention(q, pool, block_tables, ctx_lens, q_positions,
                            valid, layer=0, *, v_width: int, scale: float,
                            q_tile: int = 128, ctx_tile: int = 512):
     """Latent attention for a [B, T, H, W] slice of T > 1 query rows (a
-    prefill chunk, a draft run): plain XLA, in tiles.  Only the lanes that
-    have valid rows do work, each over its OWN blocks: per lane a loop
-    over the tiles of `q_tile` query rows that hold a valid row, and under
-    it one over the tiles of `ctx_tile` context tokens at or before the
-    tile's last row (gathered through the lane's block table), with the
-    online softmax of the kernels above.  A padding lane, the rows of a
-    chunk behind the prompt's end and the context behind the causal
-    boundary cost nothing; no lane's whole context is ever gathered.
-    Rows without work come out zero.  Returns [B, T, H, v_width]."""
+    prefill chunk, a draft run): `_chunk_attention`'s tiles over the latent
+    pool.  A tile of the cache is gathered through the lane's block table
+    once for all heads: its rows whole for the scores, their first
+    `v_width` columns for the values.  Rows without work come out zero.
+    Returns [B, T, H, v_width]."""
     b, t, h, w = q.shape
-    bs = pool.shape[2]
-    mb = block_tables.shape[1]
-    qt = min(q_tile, t)
-    while t % qt:
-        qt -= 1
-    per = max(1, ctx_tile // bs)            # blocks a context tile
-    ct = per * bs
     layer = jnp.asarray(layer, jnp.int32)
-    n_valid = jnp.sum(valid, axis=1, dtype=jnp.int32)           # [B]
-    kcol = jnp.arange(ct, dtype=jnp.int32)
 
-    def lane_body(lane, out):
-        table = block_tables[lane]
-        pos0 = q_positions[lane, 0]
-        n_ctx = ctx_lens[lane]
+    def score(rows, c):
+        return jax.lax.dot_general(rows, c, (((1,), (1,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
 
-        def q_body(qi, out):
-            rows = jax.lax.dynamic_slice_in_dim(q[lane], qi * qt, qt, 0)
-            rows = rows.reshape(qt * h, w)
-            qpos = jnp.repeat(pos0 + qi * qt
-                              + jnp.arange(qt, dtype=jnp.int32), h)
-            reach = jnp.minimum(pos0 + (qi + 1) * qt, n_ctx)
+    def weigh(p, c):
+        return jnp.dot(p.astype(c.dtype), c[:, :v_width],
+                       preferred_element_type=jnp.float32)
 
-            def ctx_body(kj, carry):
-                m, l, acc = carry
-                ids = jnp.clip(kj * per + jnp.arange(per), 0, mb - 1)
-                c = pool[layer, table[ids]].reshape(ct, w)
-                s = jax.lax.dot_general(
-                    rows, c, (((1,), (1,)), ((), ())),
-                    preferred_element_type=jnp.float32) * scale
-                kpos = kj * ct + kcol
-                keep = ((kpos[None, :] <= qpos[:, None])
-                        & (kpos[None, :] < n_ctx))
-                s = jnp.where(keep, s, NEG_INF)
-                m_new = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
-                p = jnp.exp(s - m_new)
-                alpha = jnp.exp(m - m_new)
-                l = l * alpha + jnp.sum(p, -1, keepdims=True)
-                acc = acc * alpha + jnp.dot(
-                    p.astype(c.dtype), c[:, :v_width],
-                    preferred_element_type=jnp.float32)
-                return m_new, l, acc
-
-            init = (jnp.full((qt * h, 1), NEG_INF, jnp.float32),
-                    jnp.zeros((qt * h, 1), jnp.float32),
-                    jnp.zeros((qt * h, v_width), jnp.float32))
-            _, l, acc = jax.lax.fori_loop(0, -(-reach // ct), ctx_body, init)
-            o = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
-            return jax.lax.dynamic_update_slice(
-                out, o.reshape(1, qt, h, v_width), (lane, qi * qt, 0, 0))
-
-        return jax.lax.fori_loop(0, -(-n_valid[lane] // qt), q_body, out)
-
-    return jax.lax.fori_loop(0, b, lane_body,
-                             jnp.zeros((b, t, h, v_width), q.dtype))
+    return _chunk_attention(
+        q, jnp.zeros((b, t, h, v_width), q.dtype), block_tables, ctx_lens,
+        q_positions, valid,
+        fold=lambda tile: tile.reshape(-1, w),
+        read=lambda table, ids: pool[layer, table[ids]].reshape(-1, w),
+        score=score, weigh=weigh,
+        unfold=lambda o: o.reshape(1, -1, h, v_width), heads=h,
+        block_size=pool.shape[2], v_width=v_width, scale=scale,
+        q_tile=q_tile, ctx_tile=ctx_tile)
 
 
 def latent_attention(q, pool, block_tables, ctx_lens, q_positions, valid,

@@ -1,10 +1,14 @@
 #!/usr/bin/env python3
 """Time the engine's compiled step alone at `serve_gpt2xl_decode`'s sizes
 (gpt2-xl whole on the tree `serving_params` prepares, 16 lanes of 100-350
-tokens, 512 blocks of 16): the T=1 program and the T=32 program with two
-lanes prefilling a chunk, each called back to back with the pools fed
-round, so the figure is the device program's length and holds no host
-work.  Since PR 34 the host is the longer side of that cell's traced
+tokens, 512 blocks of 16): the T=1 program; the T=32 program with two
+lanes prefilling a chunk, once with a valid row in each of the other 14
+lanes (`t32`, the figure PERF.md has carried since PR 34) and once with
+them masked and at context 1 as `_build_batch` hands a prefill step over
+(`t32_masked`); and the speculative verify program (`verify_t5`: `spec_k`
+4, every lane's five rows valid over its whole context).  Each is called
+back to back with the pools fed round, so the figure is the device
+program's length and holds no host work.  Since PR 34 the host is the longer side of that cell's traced
 slice, and `decode_step_ms_p50` there reads the host (PERF.md sections 5
 and 7); this is where the program's own length comes from.  Not a tool the
 benchmark runs.  On the chip, from the root of a checkout (the parent's,
@@ -57,8 +61,12 @@ def main(unrolls):
         eng = object.__new__(InferenceEngine)     # the step, no thread
         eng.model, eng.config, eng._capture_logp = gpt, cfg, False
         eng.backend, eng._step_impls = jax.default_backend(), {}
-        step = eng._make_step_fn(False)
-        for t in (1, engine["prefill_chunk"]):
+        steps = {spec: eng._make_step_fn(False, spec)
+                 for spec in (False, True)}
+        chunk = engine["prefill_chunk"]
+        for name, t in (("t1", 1), (f"t{chunk}", chunk),
+                        (f"t{chunk}_masked", chunk), ("verify_t5", 5)):
+            step = steps[name.startswith("verify")]
             shape = (cfg.n_layers, nb, bs,
                      kv_row_width(cfg.n_heads, cfg.head_dim))
             k, v = jnp.zeros(shape, cfg.dtype), jnp.zeros(shape, cfg.dtype)
@@ -69,7 +77,13 @@ def main(unrolls):
             valid[:, 0] = True
             positions = np.repeat((ctx - 1)[:, None], t, 1)
             gather = np.zeros(lanes, np.int32)
-            for lane in (0, 1) if t > 1 else ():  # two prefill, 14 decode
+            if name.startswith("verify"):           # every lane, its draft
+                valid[:], gather[:] = True, t - 1
+                positions = ctx[:, None] - t + np.arange(t)[None]
+            elif name.endswith("masked"):
+                valid[:], ctx[:], positions[:] = False, 1, 0
+            for lane in (0, 1) if t == chunk else ():
+                # two lanes prefill, 14 decode
                 valid[lane], ctx[lane], gather[lane] = True, t, t - 1
                 positions[lane] = np.arange(t)
             args = [jnp.asarray(a) for a in (
@@ -90,7 +104,7 @@ def main(unrolls):
             for _ in range(3):
                 each, k, v = run(200 if t == 1 else 40, k, v)
                 ms.append(round(each, 4))
-            out[f"unroll{unroll}_t{t}_ms"] = ms
+            out[f"unroll{unroll}_{name}_ms"] = ms
             del k, v
     print(json.dumps(out))
 

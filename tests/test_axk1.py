@@ -419,11 +419,12 @@ def test_a_question_behind_a_cached_document_runs_the_short_program():
     eng.generate(doc + [70, 71, 72], 2)           # seals the document
     before = eng.stats()["prefill"]
     assert before == {"steps": 5, "lanes": 5, "rows": 4 * 16 + 4,
-                      "rows_valid": 67}           # 4 x [1, 16], then [1, 4]
+                      "rows_valid": 67,           # 4 x [1, 16], then [1, 4]
+                      "ctx_rows": 16 + 32 + 48 + 64 + 67}
     out = eng.generate(doc + [80, 81, 82, 83], 2)
     after = eng.stats()["prefill"]
     assert {k: after[k] - before[k] for k in after} == {
-        "steps": 1, "lanes": 1, "rows": 4, "rows_valid": 4}
+        "steps": 1, "lanes": 1, "rows": 4, "rows_valid": 4, "ctx_rows": 68}
     assert eng.stats()["prefix_hit_tokens"] == 64 and len(out) == 2
     # the same tokens as an engine with one program
     plain = InferenceEngine("axk1", cfg, eng.params, auto_start=False,

@@ -313,9 +313,10 @@ def test_a_latent_share_engine_counts_on_the_host_and_adds_nothing_to_a_step():
         return {k: s1[key][k] - s0[key][k] for k in s1[key]
                 if not isinstance(s1[key][k], list)}
 
-    # 19 prompt tokens in three chunks of a [1, 8] program
+    # 19 prompt tokens in three chunks of a [1, 8] program, whose last
+    # rows attend over 8, 16 and 19 rows of the lane's table
     assert grew("prefill") == {"steps": 3, "lanes": 3, "rows": 24,
-                               "rows_valid": 19}
+                               "rows_valid": 19, "ctx_rows": 8 + 16 + 19}
     # five T=1 steps, over contexts of 20, 21, ... tokens (the first
     # output token comes from the prefill)
     assert grew("latent") == {"decode_steps": len(out) - 1,
@@ -337,7 +338,7 @@ def test_engines_of_k_and_v_rows_have_no_latent_counters():
     stats = engine.stats()
     assert "latent" not in stats and "moe" not in stats
     assert stats["prefill"] == {"steps": 2, "lanes": 2, "rows": 32,
-                                "rows_valid": 11}
+                                "rows_valid": 11, "ctx_rows": 8 + 11}
 
 
 def test_an_engine_of_k_and_v_rows_counts_the_decode_kernels_live_runs():

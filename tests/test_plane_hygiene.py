@@ -370,6 +370,13 @@ def test_latent_prefill_and_share_counters_are_host_sums_in_build_batch():
     body = build[build.index('"""', build.index('"""') + 3):]
     assert "events.record" not in body and "spans." not in body
     assert "np.asarray(self." not in body          # no fetch from the device
+    # PR 38: the table rows the prefilling lanes' last valid rows attend
+    # over (what the tiled T > 1 attention reads), summed where
+    # `rows_valid` is and nowhere else
+    assert src.count('"ctx_rows"') == 2
+    assert body.count('pf["ctx_rows"] += ') == 1
+    assert body.index('pf["rows_valid"] += ') < body.index(
+        'pf["ctx_rows"] += ') < body.index("elif t == 1:")
     names = re.findall(r'name="(\w+)"',
                        (PKG / "ops" / "attention.py").read_text())
     assert "latent_decode_attention" in names

@@ -138,6 +138,54 @@ def _kernel_names(text):
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
+def _whole_contexts(text, lanes, mb, bs):
+    """Result shapes that hold `mb * bs` rows for each of `lanes` lanes: a
+    table gathered whole ([B, MB, BS, W], [B, max_ctx, KH, D]) or scored
+    whole ([B, H, T, max_ctx]), which the masked-dense T > 1 path made for
+    every lane a layer whatever the lanes held (PERF.md section 6, PR 38).
+    The tiled path holds a tile of one lane."""
+    found = set()
+    for dims in re.findall(r" = \w+\[([\d,]+)\]", text):
+        d = [int(x) for x in dims.split(",")]
+        rows = mb * bs in d or any(d[i:i + 2] == [mb, bs]
+                                   for i in range(len(d) - 1))
+        if rows and lanes in d:
+            found.add(dims)
+    return found
+
+
+@pytest.mark.parametrize("t,ctx_tile", [(32, None), (32, 128), (512, None)],
+                         ids=["t32", "t32_asked_for_one_block", "t512"])
+def test_a_tile_of_wide_rows_is_never_one_block_sliced_out(v5e, as_on_chip,
+                                                          t, ctx_tile):
+    """Rows of 4,096 bf16 columns in blocks of 128, EvaByte's: a context
+    tile of ONE block is a lone `dynamic_slice` that XLA fuses into the
+    product and re-lays the whole pool for (both pools copied: 9 GB at the
+    cell's sizes, found on the chip in PR 38's sweep).  The tiled path
+    takes two blocks at least there, whatever it is asked for, and the
+    pools stay where they are."""
+    from ray_tpu.ops.attention import paged_chunk_attention
+    lanes, h, d, bs, nb, mb, layers = 4, 32, 128, 128, 64, 8, 2
+    arg = _arg_on(v5e[0])
+    pool = arg(_pool_shape(layers, nb, bs, h, d), jnp.bfloat16)
+
+    def layers_of_attention(q, k_pool, v_pool, tables, ctx_lens, pos, valid):
+        def layer(i, x):
+            return x + paged_chunk_attention(x, k_pool, v_pool, tables,
+                                             ctx_lens, pos, valid, i,
+                                             ctx_tile=ctx_tile)
+        return jax.lax.fori_loop(0, layers, layer, q)
+
+    compiled = jax.jit(layers_of_attention).lower(
+        arg((lanes, t, h, d), jnp.bfloat16), pool, pool,
+        arg((lanes, mb), jnp.int32), arg((lanes,), jnp.int32),
+        arg((lanes, t), jnp.int32), arg((lanes, t), jnp.bool_)).compile()
+    assert count_pool_copies(compiled.as_text(), pool.shape) == 0
+    assert "mini-gather" not in compiled.as_text()
+    assert (compiled.memory_analysis().temp_size_in_bytes
+            < math.prod(pool.shape))                    # half a pool's bytes
+
+
 def test_paged_decode_kernel_keeps_its_name_inside_a_layer_scan(v5e,
                                                                  as_on_chip):
     """In the engine's step the kernel sits in the layer scan, under no
@@ -212,7 +260,11 @@ def test_engine_step_leaves_the_kv_pool_where_it_is(v5e, as_on_chip, heads,
     cfg = gpt.GPTConfig(vocab_size=512, n_layers=4, d_model=heads * 64,
                         n_heads=heads, d_ff=256, max_seq_len=256,
                         scan_unroll=2)
-    compiled, pool, params = _compile_engine_step(v5e[0], cfg, t)
+    # (128 blocks: at four layers of these widths XLA prefetches a whole
+    # stack of per-head projections into fast memory for the T=32 step's
+    # loops, 15.6 MB of "scratch" that is not HBM: more than a pool of 64.)
+    compiled, pool, params = _compile_engine_step(v5e[0], cfg, t,
+                                                  num_blocks=128)
     text, memory = compiled.as_text(), compiled.memory_analysis()
 
     # By the program's own counter, and read off the text once more: no
@@ -278,10 +330,12 @@ def test_olmoe_step_reads_its_experts_where_they_are(v5e, as_on_chip, t):
     assert len(paged) == (1 if t == 1 else 0)
     assert len(kernels) == len(grouped) + len(paged)
     # Scratch: a T=1 step needs next to none (2 MB at 16 layers); the T=32
-    # step gathers every lane's context of 1024 for the masked-dense
-    # attention (69 MB at 16 layers).  The parameters are the program's
-    # arguments: 2 layers are 1.89 GB of the model's 13.84.
-    assert memory.temp_size_in_bytes < (16 if t == 1 else 128) * 2 ** 20
+    # step's is the activations of 512 rows (until PR 38 it gathered every
+    # lane's context of 1024 for the masked-dense attention: 69 MB at 16
+    # layers).  The parameters are the program's arguments: 2 layers are
+    # 1.89 GB of the model's 13.84.
+    assert memory.temp_size_in_bytes < (16 if t == 1 else 48) * 2 ** 20
+    assert not _whole_contexts(text, 16, 1024 // 16, 16)
     assert memory.argument_size_in_bytes > sum(
         2 * math.prod(x.shape) for x in jax.tree.leaves(params))
 
@@ -362,11 +416,12 @@ def test_gpt2xl_step_multiplies_its_weights_as_they_are_held(v5e, as_on_chip,
     assert held
     in_hbm = {dims for dims, layout in held if "S(1)" not in layout}
     assert in_hbm <= ({"1,1600,25,64"} if prefill_of_4 else set()), in_hbm
-    # Scratch: a T=1 step's is activations; the T=32 step gathers every
-    # lane's context of 1024 for the masked-dense attention (two layers of
-    # K and V in flight).
-    assert memory.temp_size_in_bytes < (16 * 2 ** 20 if t == 1
-                                        else weights)
+    # Scratch: activations.  Until PR 38 the T=32 step gathered every
+    # lane's context of 1024 for the masked-dense attention, two layers of
+    # K and V in flight (`bf16[16,1024,25,64]`, float32 scores
+    # `[16,25,32,1024]`: 172 MB); now it holds a tile of one lane.
+    assert memory.temp_size_in_bytes < (16 if t == 1 else 64) * 2 ** 20
+    assert not _whole_contexts(text, 16, cfg.max_seq_len // 16, 16)
     kernels = _kernel_names(text)
     assert all(k.startswith("paged_decode_attention") for k in kernels)
     assert len(kernels) == (unroll if t == 1 else 0)
@@ -621,7 +676,13 @@ def test_evabyte_programs_fit_a_v5e_and_leave_pool_and_weights_in_place(
         assert memory.temp_size_in_bytes < 256 * 2 ** 20
     else:
         assert 12.9e9 < memory.argument_size_in_bytes < 13.0e9
-        assert memory.temp_size_in_bytes < (64 if t1 else 1100) * 2 ** 20
+        # A prefill program's scratch is its 2,048 rows' activations
+        # (98 MB at [4, 512]); until PR 38 also every row's table gathered
+        # whole and scored dense, `[4,22,128,4096]` twice and float32
+        # `[4,32,512,2816]` (0.96 GB of the cell's peak).
+        assert memory.temp_size_in_bytes < (64 if t1 else 160) * 2 ** 20
+        rows = int(program.split("rows=")[1])
+        assert not _whole_contexts(text, rows, 22, 128)
     assert count_pool_copies(text, pool) == 0
     # no half of a pool copied out to be gathered from (`_table_blocks`)
     assert "mini-gather" not in text
