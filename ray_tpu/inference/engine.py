@@ -67,6 +67,16 @@ summaries.  Positions are the host's by counting, so the loop stays a step
 ahead; a prefill chunk is cut at a window's edge, so a slice's rows are
 consecutive in its lane's table.
 
+A cache of layers of several kinds (kv_cache.py "layered": dots3's full and
+window layers) hands the step all its pools as one tuple and ONE table array
+whose halves are the kinds' tables, so a population's uploads stay what they
+were; the sliding kind's blocks that a commit leaves wholly behind a lane's
+window go back to their allocator as the last part of `commit`
+(`PagedKVCache.slide_release`, in the record's `windows_ms`), admission asks
+both allocators (`can_admit_prefix`), and `stats()` adds `sparse` (per T=1
+step and one layer of each kind: context tokens scored, rows chosen, window
+rows attended) and `windows` (sliding blocks given back so far).
+
 The weights the step multiplies are prepared once, not in every step:
 `model.serving_params` (one compiled program at construction and at
 every `update_params`) holds each leaf the cached forward would cast at
@@ -403,8 +413,17 @@ class InferenceEngine:
     has more than that left to feed (`_prefill_len`), and each of the two
     at ONE row, for steps in which one lane prefills (an admission behind
     a cached head is alone in its program more often than not, and three
-    rows of four were padding): four programs, all compiled when the
-    first of a T is (`_warm_widths`).
+    rows of four were padding): four programs, a T's second made behind
+    its first (`_warm_widths`) once nobody waits on it.
+
+    Whoever waits for a token waits for at most ONE program to be made (a
+    program of a cache of several kinds of layer takes 27 s on a v5e's
+    host, a stream's step gives up at 60, and three ahead of a first token
+    were 57-64 s: PERF.md section 6, PR 41): a population whose program is
+    still to be made is not dispatched ahead of a step in flight, which is
+    landed first (`_made`), and a sibling width is made when the loop goes
+    idle, or behind a commit with no program made since the commit before
+    (`_cold`).
     """
 
     def __init__(self, model="gpt", config="nano", params=None, *,
@@ -457,7 +476,10 @@ class InferenceEngine:
         self.cache = PagedKVCache.for_model(
             self.model, self.config, num_blocks=num_blocks,
             block_size=block_size, max_lanes=max_lanes,
-            max_seq_len=max_seq_len, prefix_cache=prefix_cache)
+            max_seq_len=max_seq_len, prefix_cache=prefix_cache,
+            # (a sliding kind reserves by how far a lane is written past
+            # its committed length: the longest slice, one step ahead)
+            ahead=2 * max(prefill_chunk, 1 + int(spec_k)))
         if kv_tier is None:
             from ray_tpu._private.config import GLOBAL_CONFIG
             kv_tier = bool(GLOBAL_CONFIG.kv_tier)
@@ -492,6 +514,11 @@ class InferenceEngine:
         self._ahead = {"steps": 0, "sync_steps": 0, "overrun_tokens": 0}
         self._rid = itertools.count(1)
         self._step_fns: Dict = {}
+        # Compact programs made at one width whose other width is still to
+        # make (`_warm_widths`), as the batches that made them; and whether
+        # a program was made since the last commit that delivered.
+        self._to_warm: list = []
+        self._cold = False
         self._step_impls: Dict = {}   # un-jitted twins (shape introspection)
         self._step_avals: Dict = {}   # argument shapes of each step's compile
         self._step_compile_s: Dict = {}   # wall of each step's first call
@@ -536,13 +563,24 @@ class InferenceEngine:
         self._prefill = {"steps": 0, "lanes": 0, "rows": 0, "rows_valid": 0,
                          "ctx_rows": 0}
         self._tokens_run = 0
-        latent = self.cache.kind == "latent"
+        latent = self.cache.kind in ("latent", "layered")
         self._latent = {"decode_steps": 0, "ctx_tokens": 0} if latent else None
         self._paged = None if latent else {
             "decode_steps": 0, "ctx_tokens": 0, "runs_live": 0}
         self._paged_run = block_size * paged_blocks_per_step(
-            block_size, self.cache.k.shape[3], self.cache.k.dtype.itemsize,
+            block_size, self.cache.pool_shape[3],
+            jnp.dtype(self.config.dtype).itemsize,
             self.cache.max_blocks_per_seq)
+        # Over layers of several kinds (kv_cache.py), by T=1 step and for
+        # ONE layer of each kind: the context tokens an indexer scored, the
+        # rows it chose (at most `_index_topk` a lane) and the rows a
+        # window layer attended (at most the window).
+        self._index_topk = max((getattr(run.sizes, "index_topk", 0) for run
+                                in self.model.spec(self.config).runs),
+                               default=0)
+        self._sparse = ({"decode_steps": 0, "ctx_tokens": 0,
+                         "rows_chosen": 0, "window_rows": 0}
+                        if self.cache.kind == "layered" else None)
         # Over a windowed cache `_paged` counts the rows attended (what the
         # kernel reads), `_eva` the same T=1 steps with their true context
         # beside those rows.  The compaction program is made at its first
@@ -554,6 +592,10 @@ class InferenceEngine:
             raise NotImplementedError(
                 "speculative decoding over a windowed cache: a verify chunk "
                 "may cross a window's edge (ROADMAP.md)")
+        if self._sparse is not None and self.spec_k > 0:
+            raise NotImplementedError(
+                "speculative decoding over layers of several kinds: a "
+                "rejected draft's sliding blocks are not rolled back yet")
         self._thread: Optional[threading.Thread] = None
         self._stopped = False
         self._auto = auto_start
@@ -872,6 +914,13 @@ class InferenceEngine:
                {"latent": dict(self._latent)}),
             **self._eva_stats(),
             **self._moe_stats(),
+            # Layers of several kinds: the T=1 steps' sums for one layer of
+            # each kind (`_sparse`), and the sliding kind's blocks given
+            # back in mid-sequence so far.
+            **({} if self._sparse is None else {
+                "sparse": dict(self._sparse),
+                "windows": {"blocks_freed":
+                            self.cache.stats["slide_blocks_freed"]}}),
         }
 
     def _eva_stats(self) -> dict:
@@ -938,7 +987,8 @@ class InferenceEngine:
                 "custom_calls": text.count("tpu_custom_call"),
                 "donated_bytes": memory.alias_size_in_bytes,
                 "temp_bytes": memory.temp_size_in_bytes,
-                "pool_copies": count_pool_copies(text, self.cache.k.shape),
+                "pool_copies": count_pool_copies(text,
+                                                 self.cache.pool_shape),
                 "weight_bytes_copied": count_weight_bytes_copied(
                     text, self._step_avals[key][0])}
         if "compile_s" in self._compact:
@@ -950,7 +1000,8 @@ class InferenceEngine:
                 "custom_calls": text.count("tpu_custom_call"),
                 "donated_bytes": memory.alias_size_in_bytes,
                 "temp_bytes": memory.temp_size_in_bytes,
-                "pool_copies": count_pool_copies(text, self.cache.k.shape),
+                "pool_copies": count_pool_copies(text,
+                                                 self.cache.pool_shape),
                 "weight_bytes_copied": count_weight_bytes_copied(
                     text, self._compact["avals"][0])}
         return out
@@ -1142,6 +1193,9 @@ class InferenceEngine:
                 live = [(i, r) for i, r in enumerate(self._lanes)
                         if r is not None and not self._ends_in_flight(i, r)]
                 if not live and not older:
+                    if not self._waiting:       # idle: nobody waits
+                        while self._to_warm:
+                            self._warm_widths(self._to_warm.pop())
                     return False
                 decode = [(i, r) for i, r in live
                           if r.next_fed == len(r.prompt)]
@@ -1165,14 +1219,18 @@ class InferenceEngine:
             took["admit"] = ph.seconds
             plans = []
             with spans.phase("engine", "build_batch") as ph:
+                pops = []
                 if decode:
                     t = (1 + max(len(r.draft) for _, r in decode)
                          if spec else 1)
-                    plans.append(self._plan(parts, spec, decode, t))
+                    pops.append((spec, decode, t, False))
                 if prefill:
-                    plans.append(self._plan(
-                        parts, False, prefill, self._prefill_len(prefill),
-                        True))
+                    pops.append((False, prefill, self._prefill_len(prefill),
+                                 True))
+                if older and not all(self._made(*pop) for pop in pops):
+                    pops = []       # land the step in flight first
+                for pop in pops:
+                    plans.append(self._plan(parts, *pop))
             took["build_batch"] = ph.seconds
         newer = []
         for spec, lanes, chunks, news, batch in plans:
@@ -1237,6 +1295,15 @@ class InferenceEngine:
                         self._commit(lanes, chunks, news, toks, lps)
                     self._work.notify()
                 parts["deliver"] = part.seconds
+                if self.cache.slide_window:
+                    # A sliding kind's blocks that now lie wholly behind
+                    # their lane's window go back to their allocator.
+                    with spans.phase("engine.commit", "windows") as part:
+                        for lanes, *_ in done:
+                            for lane, req in lanes:
+                                if self._lanes[lane] is req:
+                                    self.cache.slide_release(lane)
+                    parts["windows"] += part.seconds
             took["commit"] = ph.seconds
             wall = ph.t0 + ph.seconds - t_start
             paused = _GC["seconds"] - gc0
@@ -1277,6 +1344,12 @@ class InferenceEngine:
                 lock_ms=parts["lock"] * 1e3,
                 deliver_ms=parts["deliver"] * 1e3,
                 gc_ms=paused * 1e3, **clock)
+        if done:
+            # Behind a commit that no fresh program held up, a sibling
+            # width may hold up the next.
+            if self._to_warm and not self._cold:
+                self._warm_widths(self._to_warm.pop())
+            self._cold = False
         return True
 
     def _sums(self) -> list:
@@ -1469,6 +1542,13 @@ class InferenceEngine:
             seen = self._paged if self._latent is None else self._latent
             seen["decode_steps"] += 1
             seen["ctx_tokens"] += sum(ctx)
+            if self._sparse is not None:
+                sp, k, w = (self._sparse, self._index_topk,
+                            self.cache.slide_window)
+                sp["decode_steps"] += 1
+                sp["ctx_tokens"] += sum(ctx)
+                sp["rows_chosen"] += sum(min(c, k) for c in ctx)
+                sp["window_rows"] += sum(min(c, w) for c in ctx)
             if self._latent is None:
                 self._paged["runs_live"] += sum(
                     -(-c // self._paged_run) for c in ctx)
@@ -1533,9 +1613,19 @@ class InferenceEngine:
             gc.collect()
             gc.freeze()
         self.cache.update_pools(k, v)
+        self._cold = self._cold or first
         if first and compact and not spec:
-            self._warm_widths(batch)
+            self._to_warm.append(batch)
         return next_tok, logp
+
+    def _made(self, spec: bool, lanes, t: int, prefill: bool) -> bool:
+        """Whether the program a population's step will run has been made
+        (`_run_step`'s key, from what `_build_batch` will build)."""
+        n = 0
+        if prefill and self.prefill_lanes < self.max_lanes:
+            n = 1 if len(lanes) == 1 else self.prefill_lanes
+        sample = any(req.temperature > 0 for _, req in lanes)
+        return (t, sample, spec, n) in self._step_fns
 
     def _warm_widths(self, batch) -> None:
         """A compact program has been made at one of its two widths (one

@@ -51,6 +51,25 @@ completes plus the exact blocks of the window it ends in; a closed
 window's exact blocks serve only a match that ends inside it, and may be
 evicted without breaking a longer one.
 
+Layers of several kinds in one cache (`kind` "layered": dots3's full and
+window layers).  A model's runs of layers may leave rows of different
+shapes, kept for different spans; each kind has pools of its own, all under
+the one cache, its lanes and its chain of sealed blocks.  The GROWING kind
+is the cache as above (one latent row a token, kept for the whole
+sequence), with further pools on the same blocks and table for the other
+rows its layers leave a token (`extra`: an indexer's key).  The SLIDING
+kind (`slide`) keeps a row a token too, but only a lane's last
+`slide_window` positions are ever read again: its blocks come from a pool
+and an allocator of their own, under a second table with a slot a token
+block (the second half of `block_tables`' columns), and a block wholly
+behind a lane's window goes back in mid-sequence (`slide_release`, after
+every commit), staying in the index as evictable if it was sealed.  A token
+block is sealed in both kinds under the same chain key.  The prefix index
+serves a match of m blocks only where BOTH kinds hold it: every growing
+block of the prefix, and the sliding blocks that cover its last
+`slide_window` - 1 positions (`_held_by_both`); admission counts each kind
+at its own peak (`can_admit_prefix`).
+
 Prefix caching (content-addressed block sharing): a block that has been
 completely written ("sealed") is indexed by a hash chain over
 (parent_hash, block_tokens) — the chain hash of a block is a function of
@@ -421,7 +440,9 @@ class PagedKVCache:
                  num_blocks: int, block_size: int, max_lanes: int,
                  max_seq_len: int, dtype=jnp.float32,
                  prefix_cache: bool = True, latent: bool = False,
-                 window: int = 0, chunk: int = 0):
+                 window: int = 0, chunk: int = 0, extra: tuple = (),
+                 slide: Optional[Tuple[int, int, int, int]] = None,
+                 slide_blocks: Optional[int] = None, ahead: int = 2):
         self.block_size = block_size
         self.max_lanes = max_lanes
         self.max_seq_len = max_seq_len
@@ -452,17 +473,52 @@ class PagedKVCache:
         # What a row holds: K and V rows in a pool each, or (`latent`:
         # kv_heads 1, head_dim the latent and its rotated key together) one
         # latent row in the one pool.  `k` is that pool, `v` None.
-        self.kind = ("latent" if latent else "windowed" if window else "kv")
+        self.kind = ("layered" if extra or slide else "latent" if latent
+                     else "windowed" if window else "kv")
         # The stored layout (module docstring): rows of W columns.
         shape = (n_layers, num_blocks, block_size,
                  kv_row_width(kv_heads, head_dim))
         self.k = jnp.zeros(shape, dtype)
         self.v = None if latent else jnp.zeros(shape, dtype)
         self.allocator = BlockAllocator(num_blocks, on_evict=self._on_evict)
+        # Layers of several kinds (module docstring).  `k` is then the
+        # tuple of all pools as the model's runs index them: the growing
+        # kind's rows, its `extra` rows, the sliding kind's rows.
+        self.extra = tuple(extra)
+        self.slide_window = 0
+        tables = 1
+        if self.kind == "layered":
+            if not latent or window:
+                raise ValueError("layers of several kinds: latent rows only")
+            pools = [self.k] + [jnp.zeros(shape[:3] + (kv_row_width(1, w),),
+                                          dtype) for w in self.extra]
+            if slide:
+                s_layers, s_heads, s_dim, self.slide_window = slide
+                self._slide_row = (s_heads, s_dim)
+                # Positions a lane may be written past its committed
+                # length: the engine's longest slice, twice (one step runs
+                # ahead of the last commit); a decoding lane's are 2.
+                self._ahead = max(int(ahead), 2)
+                if slide_blocks is None:
+                    slide_blocks = max_lanes * self._slide_peak(True)
+                pools.append(jnp.zeros(
+                    (s_layers, slide_blocks, block_size,
+                     kv_row_width(s_heads, s_dim)), dtype))
+                self.slide_allocator = BlockAllocator(
+                    slide_blocks, on_evict=self._on_slide_evict)
+                # lane -> {slot: block} and the sliding kind's own index
+                self._slide_lane: List[Dict[int, int]] = [
+                    {} for _ in range(max_lanes)]
+                self._slide_index: Dict[Tuple, int] = {}
+                self._slide_key: Dict[int, Tuple] = {}
+                self._lane_prompt = [0] * max_lanes
+                tables = 2
+            self.k = tuple(pools)
         # Unused table entries stay 0 — always a valid pool index; the
         # attention mask (positions >= ctx_len) hides whatever lives there.
-        self.block_tables = np.zeros((max_lanes, self.max_blocks_per_seq),
-                                     np.int32)
+        # (A sliding kind's table is the second half of the columns.)
+        self.block_tables = np.zeros(
+            (max_lanes, tables * self.max_blocks_per_seq), np.int32)
         self.seq_lens = np.zeros((max_lanes,), np.int32)
         self._lane_blocks: List[List[int]] = [[] for _ in range(max_lanes)]
         self._dev_tables: Optional[jax.Array] = None
@@ -483,7 +539,8 @@ class PagedKVCache:
         self._lane_parent = [_ROOT_HASH] * max_lanes   # chain hash cursor
         self.stats = {"hit_tokens": 0, "miss_tokens": 0, "hits": 0,
                       "misses": 0, "sealed_blocks": 0, "imported_blocks": 0,
-                      "restored_blocks": 0, "windows_closed": 0}
+                      "restored_blocks": 0, "windows_closed": 0,
+                      "slide_blocks_freed": 0}
         # Optional tiered spill cache (serve/kv_tier): evicted sealed
         # blocks move here instead of being destroyed, and the match /
         # adopt path restores them on hit (the SPILLED index state).
@@ -493,6 +550,10 @@ class PagedKVCache:
         """Attach a spill tier (duck-typed: contains/put/pop/discard/
         summary_hashes/__len__).  Evictions start spilling immediately;
         match/adopt start seeing spilled chains."""
+        if self.kind == "layered":
+            raise NotImplementedError(
+                "a spill tier under layers of several kinds: a spilled "
+                "block would have to carry every kind's rows (ROADMAP.md)")
         self.tier = tier
 
     @classmethod
@@ -501,11 +562,42 @@ class PagedKVCache:
         row its spec's attention leaves there (`decoder.Attention`)."""
         kw.setdefault("max_seq_len", config.max_seq_len)
         kw.setdefault("dtype", config.dtype)
-        attn = model.spec(config).attn
+        spec = model.spec(config)
+        if isinstance(kw.get("num_blocks"), (tuple, list)):
+            # (the growing kind's blocks, the sliding kind's)
+            kw["num_blocks"], kw["slide_blocks"] = kw["num_blocks"]
+        if any(run.pools for run in spec.runs):
+            return cls._layered(spec.runs, config, **kw)
+        kw.pop("ahead", None)
+        attn = spec.attn
         rows = attn.rows(config)
         return cls(config.n_layers, rows.kv_heads, rows.head_dim,
                    latent=attn.pools == 1, window=rows.window,
                    chunk=rows.chunk, **kw)
+
+    @classmethod
+    def _layered(cls, runs, config, **kw) -> "PagedKVCache":
+        """A cache for runs of several kinds (`decoder.Run.table`): one
+        growing latent kind, and at most one sliding kind."""
+        kinds: Dict[int, list] = {}
+        for run in runs:
+            rows = run.attn.rows(run.sizes or config)
+            layers = kinds.setdefault(run.table[0], [rows, 0])
+            layers[1] = max(layers[1], run.first + run.n_layers)
+            if layers[0] != rows or run.attn.pools != 1 or rows.window:
+                raise NotImplementedError(
+                    "layers of several kinds: latent rows, one shape a kind")
+        grow = [k for k in kinds.values() if not k[0].slide]
+        slid = [k for k in kinds.values() if k[0].slide]
+        if len(grow) != 1 or len(slid) > 1:
+            raise NotImplementedError(
+                "layers of several kinds: one growing kind and at most one "
+                "sliding kind")
+        (rows, n_layers), = grow
+        slide = ((slid[0][1], slid[0][0].kv_heads, slid[0][0].head_dim,
+                  slid[0][0].slide) if slid else None)
+        return cls(n_layers, rows.kv_heads, rows.head_dim, latent=True,
+                   extra=rows.extra, slide=slide, **kw)
 
     # ---------------- host-side lane lifecycle ----------------
 
@@ -561,7 +653,8 @@ class PagedKVCache:
             / self.block_size)
 
     def can_admit(self, prompt_len: int) -> bool:
-        return self.allocator.can_alloc(self.peak_blocks(prompt_len))
+        return self.allocator.can_alloc(self.peak_blocks(prompt_len)) \
+            and self._slide_admits(())
 
     def alloc_lane(self, lane: int, prompt_len: int) -> None:
         """Sequence start without prefix reuse: claim fresh blocks
@@ -573,6 +666,8 @@ class PagedKVCache:
                              f"{self.max_seq_len}")
         blocks = self.allocator.alloc(self._blocks_to_start(prompt_len))
         self._install_lane(lane, blocks, cached_len=0)
+        if self.slide_window:
+            self._lane_prompt[lane] = prompt_len
 
     def _install_lane(self, lane: int, blocks: List[int],
                       cached_len: int, closed: int = 0) -> None:
@@ -644,7 +739,18 @@ class PagedKVCache:
             if entry is None:
                 break
             out.append(entry)
-        return out
+        return self._held_by_both(out) if self.slide_window else out
+
+    def _held_by_both(self, entries: List[Tuple]) -> List[Tuple]:
+        """The longest head of a matched chain of the growing kind that the
+        sliding kind can serve too: of m blocks it needs those that cover
+        the last `slide_window` - 1 positions before position m x
+        block_size, and nothing of what lies behind them."""
+        for m in range(len(entries), 0, -1):
+            if all(entries[i][1] in self._slide_index for i in range(
+                    self._slide_from(m * self.block_size), m)):
+                return entries[:m]
+        return []
 
     def _matched(self, entries: List[Tuple]) -> Tuple[int, int]:
         """(windows closed, tokens covered) of a matched chain."""
@@ -678,7 +784,7 @@ class PagedKVCache:
                 - len(dev) + headroom_blocks)
         free_after = (self.allocator.num_free
                       - sum(self.allocator.is_evictable(b) for b in dev))
-        return need <= free_after
+        return need <= free_after and self._slide_admits(entries)
 
     def adopt_prefix(self, lane: int, tokens: Sequence[int],
                      keys: Optional[List[Tuple]] = None) -> int:
@@ -760,6 +866,17 @@ class PagedKVCache:
                 self.stats["restored_blocks"] += 1
         cached = chain_blocks
         self._install_lane(lane, cached + tail, cached_len, closed)
+        if self.slide_window:
+            # The matched tail of the sliding kind, shared like the rest;
+            # what the prompt adds there is claimed as it is written
+            # (`ensure_capacity`).
+            self._lane_prompt[lane] = len(tokens)
+            held = self._slide_lane[lane]
+            for i in range(self._slide_from(cached_len), len(entries)):
+                block = self._slide_index[entries[i][1]]
+                self.slide_allocator.incref(block)
+                held[i] = block
+                self.block_tables[lane, self.max_blocks_per_seq + i] = block
         if cached:
             # The chain cursor at the sealed boundary, so blocks sealed
             # later extend the same chain: the hash of the last key.
@@ -805,6 +922,15 @@ class PagedKVCache:
             # an adopted shared block re-seals as itself (no-op).
             if key not in self._index and block not in self._block_key:
                 self._seal(key, block)
+            if self.slide_window:
+                # The same token block in the sliding kind, if the lane
+                # still holds it.
+                block = self._slide_lane[lane].get(i)
+                if block is not None and key not in self._slide_index \
+                        and block not in self._slide_key:
+                    self._slide_index[key] = block
+                    self._slide_key[block] = key
+                    self.slide_allocator.mark_cached(block)
             self._lane_parent[lane] = hash(key)
             self._lane_sealed[lane] += 1
 
@@ -857,8 +983,26 @@ class PagedKVCache:
         if not entries:
             return None
         idx = jnp.asarray(np.asarray([b for _k, b in entries], np.int32))
-        k_np, v_np = self.read_blocks(idx)
         chain = [list(key[1]) for key, _b in entries]
+        if self.kind == "layered":
+            # Every kind's blocks, each said to be whose: the growing
+            # kind's rows (`k`) and further rows (`extra`) of every block
+            # of the chain, the sliding kind's (`slide`) of the blocks from
+            # chain position `slide_from` on, which is all a match of this
+            # length reads of them.
+            first = self._slide_from(len(entries) * self.block_size)
+            more = {"extra": [self.read_blocks(idx, 1 + i)
+                              for i in range(len(self.extra))]}
+            if self.slide_window:
+                more.update(slide_from=first, slide=self.read_blocks(
+                    jnp.asarray(np.asarray(
+                        [self._slide_index[key] for key, _b in
+                         entries[first:]], np.int32)), len(self.k) - 1))
+            return {"v": 1, "kind": self.kind,
+                    "block_size": self.block_size, "chain": chain,
+                    "k": self.read_blocks(idx, 0), "v_pool": None,
+                    "more": more}
+        k_np, v_np = self.read_blocks(idx)
         if self.window:
             # The kind of each block by the length of its chain entry: a
             # summary block carries its whole window's tokens (every block
@@ -894,8 +1038,10 @@ class PagedKVCache:
         k_arr, v_arr = payload["k"], payload["v_pool"]
         if tuple(k_arr.shape[2:]) != (self.block_size, self.kv_heads,
                                       self.head_dim) or \
-                k_arr.shape[0] != self.k.shape[0]:
+                k_arr.shape[0] != self.pool_shape[0]:
             return 0            # foreign model shape: refuse quietly
+        if self.kind == "layered":
+            return self._install_layered(payload)
         parent = _ROOT_HASH
         new = []                # (chain_pos, key, block)
         bs, part = self.block_size, 0
@@ -940,6 +1086,58 @@ class PagedKVCache:
             self.allocator.decref(b)
             self.stats["imported_blocks"] += 1
         return len(new)
+
+    def _install_layered(self, payload: dict) -> int:
+        """`install_prefix` for layers of several kinds: the growing kind's
+        blocks with their further rows as one, then the sliding kind's
+        tail, each indexed under its chain key at refcount 0."""
+        more = payload.get("more") or {}
+        extra = more.get("extra", [])
+        if len(extra) != len(self.extra) or (
+                bool(self.slide_window) != ("slide" in more)):
+            return 0
+        keys, parent = [], _ROOT_HASH
+        for blk_tokens in payload["chain"]:
+            keys.append((parent, tuple(int(t) for t in blk_tokens)))
+            parent = hash(keys[-1])
+
+        def install(alloc, index, block_key, wanted, write):
+            new = []
+            for pos, key in wanted:
+                if key in index:
+                    continue
+                try:
+                    (b,) = alloc.alloc(1)
+                except RuntimeError:
+                    break
+                new.append((pos, key, b))
+            if new:
+                write(jnp.asarray(np.asarray([b for *_, b in new],
+                                             np.int32)),
+                      np.asarray([pos for pos, *_ in new]))
+            for _pos, key, b in new:
+                index[key] = b
+                block_key[b] = key
+                alloc.mark_cached(b)
+                alloc.decref(b)
+            return len(new)
+
+        def write_grow(idx, pos):
+            self.write_blocks(idx, payload["k"][:, pos], None, 0)
+            for i, rows in enumerate(extra):
+                self.write_blocks(idx, rows[:, pos], None, 1 + i)
+
+        n = install(self.allocator, self._index, self._block_key,
+                    list(enumerate(keys)), write_grow)
+        if self.slide_window:
+            first = int(more["slide_from"])
+            n += install(
+                self.slide_allocator, self._slide_index, self._slide_key,
+                list(enumerate(keys[first:])),
+                lambda idx, pos: self.write_blocks(
+                    idx, more["slide"][:, pos], None, len(self.k) - 1))
+        self.stats["imported_blocks"] += n
+        return n
 
     def prefix_summary(self, limit: int = 256) -> dict:
         """Compact routing summary: the cumulative chain hashes of every
@@ -1009,6 +1207,67 @@ class PagedKVCache:
         self._dev_tables = None
         return src, dst
 
+    # ---------------- the sliding kind ----------------
+
+    def _slide_from(self, length: int) -> int:
+        """The first table slot a lane of `length` tokens still reads in
+        the sliding kind: position `length`, the next written, attends from
+        `length - (slide_window - 1)` on."""
+        return max(length - (self.slide_window - 1), 0) // self.block_size
+
+    def _slide_peak(self, prefilling: bool) -> int:
+        """The most sliding blocks a lane holds at once: its window and the
+        positions written past its committed length, wherever the two fall
+        in their blocks."""
+        ahead = self._ahead if prefilling else 2
+        return (self.slide_window + ahead - 2) // self.block_size + 2
+
+    def _slide_admits(self, entries: List[Tuple]) -> bool:
+        """Whether the sliding kind has room for one more lane that starts
+        from the matched chain `entries`: its own peak less the matched
+        tail it shares, beside what every live lane may still claim."""
+        if not self.slide_window:
+            return True
+        tail = [self._slide_index[entries[i][1]] for i in range(
+            self._slide_from(len(entries) * self.block_size), len(entries))]
+        reserve = sum(
+            max(0, self._slide_peak(int(self.seq_lens[lane])
+                                    < self._lane_prompt[lane])
+                - len(self._slide_lane[lane]))
+            for lane, blocks in enumerate(self._lane_blocks) if blocks)
+        alloc = self.slide_allocator
+        return (self._slide_peak(True) - len(tail) + reserve
+                <= alloc.num_free - sum(alloc.is_evictable(b) for b in tail))
+
+    def slide_release(self, lane: int) -> int:
+        """Give back the sliding blocks that lie wholly behind the lane's
+        window at its committed length (called after every commit); a
+        sealed one stays in the index as evictable.  What a step in flight
+        reads lies at or past the committed length's window, and whoever is
+        given a block next writes it by a program dispatched later.
+        Returns how many went back."""
+        if not self.slide_window:
+            return 0
+        held = self._slide_lane[lane]
+        keep = self._slide_from(int(self.seq_lens[lane]))
+        gone = [slot for slot in held if slot < keep]
+        for slot in gone:
+            self.slide_allocator.decref(held.pop(slot))
+            self.block_tables[lane, self.max_blocks_per_seq + slot] = 0
+        if gone:
+            self.stats["slide_blocks_freed"] += len(gone)
+            self._dev_tables = None
+        return len(gone)
+
+    def slide_blocks(self, lane: int) -> Dict[int, int]:
+        """{table slot: block} of the sliding blocks the lane holds."""
+        return dict(self._slide_lane[lane]) if self.slide_window else {}
+
+    def _on_slide_evict(self, block: int) -> None:
+        key = self._slide_key.pop(block, None)
+        if key is not None and self._slide_index.get(key) == block:
+            del self._slide_index[key]
+
     def blocks_by_kind(self) -> Tuple[int, int]:
         """(summary blocks, exact blocks) the pool holds now, live or
         cached: the lanes' own and what the prefix index keeps."""
@@ -1036,6 +1295,15 @@ class PagedKVCache:
             self.block_tables[lane, len(blocks)] = b
             blocks.append(b)
             self._dev_tables = None
+        if self.slide_window:
+            held = self._slide_lane[lane]
+            for slot in range(self._slide_from(int(self.seq_lens[lane])),
+                              need):
+                if slot not in held:
+                    (held[slot],) = self.slide_allocator.alloc(1)
+                    self.block_tables[
+                        lane, self.max_blocks_per_seq + slot] = held[slot]
+                    self._dev_tables = None
 
     def truncate_lane(self, lane: int, new_len: int) -> None:
         """Speculative rollback: release the table-tail blocks past what
@@ -1075,6 +1343,10 @@ class PagedKVCache:
         blocks = self._lane_blocks[lane]
         for b in blocks:
             self.allocator.decref(b)
+        if self.slide_window:
+            self.slide_allocator.free(self._slide_lane[lane].values())
+            self._slide_lane[lane] = {}
+            self._lane_prompt[lane] = 0
         self._lane_blocks[lane] = []
         self.block_tables[lane, :] = 0
         self.seq_lens[lane] = 0
@@ -1089,12 +1361,14 @@ class PagedKVCache:
     # ---------------- device mirrors ----------------
 
     def device_tables(self) -> jax.Array:
-        """The tables as a step takes them: a copy made now.  (`jnp.asarray`
-        of a numpy array is the same memory on the CPU backend, and a
-        step dispatched ahead runs after the host has gone on: a lane's
-        row is rewritten when its window closes and zeroed when it ends.)"""
+        """The tables as a step takes them: a copy made now, on the host.
+        (`jnp.asarray` of a numpy array is the same memory on the CPU
+        backend, `jnp.array`'s copy is made by the device in its turn, and
+        a step dispatched ahead runs after the host has gone on: a lane's
+        row is rewritten when its window closes or slides and zeroed when
+        it ends.)"""
         if self._dev_tables is None:
-            self._dev_tables = jnp.array(self.block_tables)
+            self._dev_tables = jnp.asarray(self.block_tables.copy())
         return self._dev_tables
 
     def update_pools(self, k: jax.Array, v: Optional[jax.Array]) -> None:
@@ -1105,17 +1379,39 @@ class PagedKVCache:
 
     # ---------------- the wire format's boundary ----------------
 
-    def read_blocks(self, idx: jax.Array) -> Tuple[np.ndarray,
-                                                   Optional[np.ndarray]]:
+    @property
+    def pool_shape(self) -> tuple:
+        """The stored shape of the (first) pool."""
+        return (self.k[0] if self.kind == "layered" else self.k).shape
+
+    def _pool_rows(self) -> List[Tuple[int, int]]:
+        """(kv_heads, head_dim) of a row of each pool of a layered cache."""
+        rows = [(self.kv_heads, self.head_dim)] + [(1, w) for w in self.extra]
+        return rows + ([self._slide_row] if self.slide_window else [])
+
+    def read_blocks(self, idx: jax.Array, pool: Optional[int] = None
+                    ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
         """Blocks `idx` of the pools in the wire format
         [n_layers, n, block_size, kv_heads, head_dim], on the host: (K, V),
-        or (latent rows, None) with `kind` "latent"."""
+        or (latent rows, None) with `kind` "latent"; of a layered cache
+        the blocks of its pool number `pool`, an array."""
+        if pool is not None:
+            return np.asarray(unpack_kv_rows(self.k[pool][:, idx],
+                                             *self._pool_rows()[pool]))
         return tuple(None if pool is None else np.asarray(unpack_kv_rows(
             pool[:, idx], self.kv_heads, self.head_dim))
             for pool in (self.k, self.v))
 
-    def write_blocks(self, idx: jax.Array, k_blocks, v_blocks) -> None:
-        """Store wire-format blocks at `idx` (pad columns stay zero)."""
+    def write_blocks(self, idx: jax.Array, k_blocks, v_blocks,
+                     pool: Optional[int] = None) -> None:
+        """Store wire-format blocks at `idx` (pad columns stay zero); of a
+        layered cache `k_blocks` into its pool number `pool`."""
+        if pool is not None:
+            pools = list(self.k)
+            pools[pool] = pools[pool].at[:, idx].set(
+                pack_kv_rows(jnp.asarray(k_blocks, pools[pool].dtype)))
+            self.k = tuple(pools)
+            return
         self.k = self.k.at[:, idx].set(
             pack_kv_rows(jnp.asarray(k_blocks, self.k.dtype)))
         if self.v is not None:

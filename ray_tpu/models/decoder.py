@@ -1,11 +1,12 @@
 """The decoder-only transformer, defined once for every LM family.
 
 A family (models/gpt.py, models/llama.py, models/axk1.py,
-models/evabyte.py) is a config
+models/evabyte.py, models/dots3.py) is a config
 dataclass, its parameter format (`init_params`, `param_specs`) and
 `spec(config)`: a `Spec` naming the parts its block is made of (norms, an
 `Attention`, a `FeedForward`, a leading run of layers with another
-feed-forward) and the leaves they read.  Everything that runs is here: the
+feed-forward; or its `Run`s of like layers, each with its own attention,
+sizes, stacks and pools) and the leaves they read.  Everything that runs is here: the
 training block and the block over a paged cache, the two layer scans, the
 head, the loss, the form the weights are served in and the train step.
 The public functions take the family's `spec` function first; a family
@@ -25,7 +26,9 @@ Design (no reference counterpart: Ray hosts models, it doesn't ship them):
     chip and per shard (shard_map over batch and heads) under a mesh, ring
     attention when the mesh has a seq axis > 1; over a paged KV cache,
     ops/attention.py's paged path.  `LATENT`: multi-head latent attention,
-    expanded for a whole sequence, absorbed over a latent paged cache.
+    expanded for a whole sequence, absorbed over a latent paged cache; by
+    a run's `LatentSizes` also over a window, or over the positions a
+    learned indexer chooses, with a gate a head.
     `EVA`: an exact window beside one summary row for every chunk behind
     it, `HEADS`'s cached form over a table whose rows are not one a token);
   * `jax.checkpoint` (remat) on the block when configured: trades FLOPs for
@@ -165,7 +168,7 @@ def moe_ffn(h, p, config, mesh=None, valid=None):
     `config.norm_topk_prob` (then they sum to one), times
     `config.routed_scale`.  `p` holds the layer's router [D, E] and the
     experts of ALL layers with the index `layer` (the kernel reads them in
-    place).
+    place), and where the router has one its `router_bias` [E].
 
     The experts may be a share of the router's: `p` then holds experts
     `config.experts_offset` to `experts_offset + held` (an expert-parallel
@@ -182,7 +185,14 @@ def moe_ffn(h, p, config, mesh=None, valid=None):
                      precision=jax.lax.Precision.HIGHEST)
     scores = (jax.nn.sigmoid(logits) if c.scoring_func == "sigmoid"
               else jax.nn.softmax(logits, axis=-1))
-    weights, experts = jax.lax.top_k(scores, c.n_experts_per_tok)
+    if "router_bias" in p:
+        # A selection bias (DeepSeek-V3's `noaux_tc`): added to the scores
+        # for the choice only, the weights are the unbiased scores.
+        _, experts = jax.lax.top_k(scores + p["router_bias"],
+                                   c.n_experts_per_tok)
+        weights = jnp.take_along_axis(scores, experts, axis=-1)
+    else:
+        weights, experts = jax.lax.top_k(scores, c.n_experts_per_tok)
     if c.norm_topk_prob:
         weights = weights / jnp.sum(weights, -1, keepdims=True)
     if c.routed_scale != 1.0:
@@ -397,33 +407,113 @@ def eva_compact(pools, p, config, src, dst, live):
                          head_dim=config.head_dim)
 
 
-def _latent_qkv(h, p, spec, config, offset):
+@dataclasses.dataclass(frozen=True)
+class LatentSizes:
+    """What `LATENT` reads of a run of layers: MLA's published sizes, the
+    rotation and the softmax scale, and what a run may add to plain MLA: a
+    factor on each normed latent, a sigmoid gate a head on the output
+    (`w_head_gate`), a `window` (positions attended, the token's own
+    among them; 0: the whole context) and a learned indexer that chooses
+    the `index_topk` positions attended (`w_iq`, `w_ik`, `ik_scale`,
+    `ik_bias`, `w_iw`; 0: none).  A model of one kind of layer states them
+    in its config and spec (`latent_sizes`); one with several gives each
+    run its own (`Run.sizes`)."""
+    n_heads: int
+    q_lora_rank: int
+    kv_lora_rank: int
+    qk_nope_head_dim: int
+    qk_rope_head_dim: int
+    v_head_dim: int
+    rope_theta: float
+    attn_scale: float
+    norm_eps: float
+    rope_freqs: Optional[tuple] = None
+    q_rescale: float = 1.0
+    kv_rescale: float = 1.0
+    gate: bool = False
+    window: int = 0
+    index_topk: int = 0
+    index_n_heads: int = 0
+    index_head_dim: int = 0
+
+
+def latent_sizes(spec, config) -> LatentSizes:
+    """The sizes `LATENT` goes by: the run's own, or those of a config
+    that states MLA's sizes under their published names beside its spec's
+    rotation and scale."""
+    c = config
+    if isinstance(c, LatentSizes):
+        return c
+    return LatentSizes(
+        c.n_heads, c.q_lora_rank, c.kv_lora_rank, c.qk_nope_head_dim,
+        c.qk_rope_head_dim, c.v_head_dim, spec.rope_theta, spec.attn_scale,
+        c.norm_eps, spec.rope_freqs)
+
+
+def _latent_qkv(h, p, s: LatentSizes, offset):
     """MLA's projections of normed h [B, L, D]: per head q_nope
     [B, L, H, qk_nope] and the rotated q_rope [B, L, H, qk_rope]; per token
     the normed latent c_kv [B, L, kv_lora_rank] and the one rotated key
-    k_rope [B, L, qk_rope] all heads share."""
-    c = config
-    eps = c.norm_eps
+    k_rope [B, L, qk_rope] all heads share; last the query's own normed
+    latent c_q [B, L, q_lora_rank] (an indexer's queries are made of it)."""
+    eps = s.norm_eps
     c_q = rmsnorm(jnp.einsum("bld,dr->blr", h, p["w_qa"].astype(h.dtype)),
                   p["q_norm"], eps)
+    if s.q_rescale != 1.0:
+        c_q = c_q * jnp.asarray(s.q_rescale, c_q.dtype)
     q = jnp.einsum("blr,rhk->blhk", c_q, p["w_qb"].astype(h.dtype))
     kv = jnp.einsum("bld,dr->blr", h, p["w_kva"].astype(h.dtype))
-    c_kv = rmsnorm(kv[..., :c.kv_lora_rank], p["kv_norm"], eps)
-    freqs = spec.rope_freqs
-    q_rope = rope(q[..., c.qk_nope_head_dim:], spec.rope_theta, offset,
+    c_kv = rmsnorm(kv[..., :s.kv_lora_rank], p["kv_norm"], eps)
+    if s.kv_rescale != 1.0:
+        c_kv = c_kv * jnp.asarray(s.kv_rescale, c_kv.dtype)
+    freqs = s.rope_freqs
+    q_rope = rope(q[..., s.qk_nope_head_dim:], s.rope_theta, offset,
                   freqs)
-    k_rope = rope(kv[..., None, c.kv_lora_rank:], spec.rope_theta, offset,
+    k_rope = rope(kv[..., None, s.kv_lora_rank:], s.rope_theta, offset,
                   freqs)[:, :, 0]
-    return q[..., :c.qk_nope_head_dim], q_rope, c_kv, k_rope
+    return q[..., :s.qk_nope_head_dim], q_rope, c_kv, k_rope, c_q
 
 
-def _latent_up(p, config):
+def _index_qkw(h, c_q, p, s: LatentSizes, offset):
+    """The indexer's side of a token (DeepSeek-V3.2's lightning indexer):
+    queries q_i [B, L, Hi, Di] from the query latent, ONE key k_i
+    [B, L, Di] a token (a LayerNorm of a projection of h), both with their
+    first qk_rope dims rotated, and the heads' weights w [B, L, Hi] in
+    float32, the two scales (Hi^-0.5, Di^-0.5) folded in.
+    I(t, s) = sum_j w[t, j] relu(q_i[t, j] . k_i[s])."""
+    r = s.qk_rope_head_dim
+    q_i = jnp.einsum("blr,rhk->blhk", c_q, p["w_iq"].astype(h.dtype))
+    k_i = layernorm(jnp.einsum("bld,dk->blk", h, p["w_ik"].astype(h.dtype)),
+                    p["ik_scale"], p["ik_bias"])
+    q_i = jnp.concatenate([
+        rope(q_i[..., :r], s.rope_theta, offset, s.rope_freqs),
+        q_i[..., r:]], -1)
+    k_i = jnp.concatenate([
+        rope(k_i[..., None, :r], s.rope_theta, offset, s.rope_freqs)[:, :, 0],
+        k_i[..., r:]], -1)
+    w = jnp.einsum("bld,dh->blh", h, p["w_iw"].astype(h.dtype)).astype(
+        jnp.float32) * (s.index_n_heads ** -0.5 * s.index_head_dim ** -0.5)
+    return q_i, k_i, w
+
+
+def _head_gate(attn, h, p, s: LatentSizes):
+    """attn [B, L, H, V] times the headwise gate sigmoid(h W_g) [B, L, H]
+    where the run has one."""
+    if not s.gate:
+        return attn
+    g = jax.nn.sigmoid(jnp.einsum(
+        "bld,dh->blh", h, p["w_head_gate"].astype(h.dtype)).astype(
+            jnp.float32))
+    return attn * g[..., None].astype(attn.dtype)
+
+
+def _latent_up(p, s: LatentSizes):
     """(w_uk [H, qk_nope, C], w_uv [H, C, v]) of one layer: the two halves
     of `w_kvb` [C, H, qk_nope + v], from the tree where it holds them
     (serving_params), else split here."""
     if "w_uk" in p:
         return p["w_uk"], p["w_uv"]
-    return _kvb_served(p["w_kvb"], config.qk_nope_head_dim).values()
+    return _kvb_served(p["w_kvb"], s.qk_nope_head_dim).values()
 
 
 def latent_attention(h, p, spec, config, mesh, position_offset=0):
@@ -433,18 +523,55 @@ def latent_attention(h, p, spec, config, mesh, position_offset=0):
     score = (q_nope . k_nope + q_rope . k_rope) * attn_scale."""
     from ray_tpu.ops.attention import reference_attention
 
-    c = config
-    q_nope, q_rope, c_kv, k_rope = _latent_qkv(h, p, spec, c,
-                                               position_offset)
+    c = latent_sizes(spec, config)
+    q_nope, q_rope, c_kv, k_rope, c_q = _latent_qkv(h, p, c,
+                                                    position_offset)
     kv = jnp.einsum("blc,chk->blhk", c_kv, p["w_kvb"].astype(h.dtype))
     k = jnp.concatenate([
         kv[..., :c.qk_nope_head_dim],
         jnp.broadcast_to(k_rope[:, :, None], q_rope.shape)], -1)
     q = jnp.concatenate([q_nope, q_rope], -1)
-    attn = reference_attention(q, k, kv[..., c.qk_nope_head_dim:],
-                               causal=True, scale=spec.attn_scale)
-    return jnp.einsum("blhk,hkd->bld", attn.astype(h.dtype),
-                      p["wo"].astype(h.dtype))
+    if c.window or c.index_topk:
+        attn = _chosen_attention(q, k, kv[..., c.qk_nope_head_dim:],
+                                 _latent_chosen(h, c_q, p, c,
+                                                position_offset),
+                                 c.attn_scale)
+    else:
+        attn = reference_attention(q, k, kv[..., c.qk_nope_head_dim:],
+                                   causal=True, scale=c.attn_scale)
+    attn = _head_gate(attn.astype(h.dtype), h, p, c)
+    return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
+
+
+def _latent_chosen(h, c_q, p, s: LatentSizes, offset):
+    """[B, L, L] bool: the positions each token of a whole sequence
+    attends, under the causal mask: the last `window` of them, or the
+    `index_topk` of largest index score (`jax.lax.top_k`: exact, ties to
+    the lower position), all of them while there are no more than that."""
+    length = h.shape[1]
+    pos = jnp.arange(length)
+    keep = pos[None, :] <= pos[:, None]
+    if s.window:
+        keep = keep & (pos[None, :] > pos[:, None] - s.window)
+    keep = jnp.broadcast_to(keep[None], (h.shape[0], length, length))
+    if s.index_topk and length > s.index_topk:
+        q_i, k_i, w = _index_qkw(h, c_q, p, s, offset)
+        scores = jnp.einsum(
+            "blh,blhs->bls", w, jax.nn.relu(jnp.einsum(
+                "blhk,bsk->blhs", q_i, k_i,
+                preferred_element_type=jnp.float32)))
+        _, chosen = jax.lax.top_k(jnp.where(keep, scores, -jnp.inf),
+                                  s.index_topk)
+        keep = keep & jnp.any(chosen[..., None] == pos, axis=-2)
+    return keep
+
+
+def _chosen_attention(q, k, v, keep, scale):
+    """Plain attention [B, L, H, K] under a mask `keep` [B, L, L]."""
+    logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
+    probs = jax.nn.softmax(jnp.where(keep[:, None], logits.astype(
+        jnp.float32), -jnp.inf), axis=-1)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
 
 
 def latent_attention_cached(h, pools, p, spec, config, block_tables,
@@ -457,22 +584,33 @@ def latent_attention_cached(h, pools, p, spec, config, block_tables,
     reassociated."""
     from ray_tpu.ops import attention as ops
 
-    c = config
+    c = latent_sizes(spec, config)
     layer = p["cache_layer"]
-    q_nope, q_rope, c_kv, k_rope = _latent_qkv(h, p, spec, c,
-                                               positions[:, 0])
-    (pool,) = ops.paged_rows_update(
-        pools, (ops.pack_latent_rows(c_kv, k_rope),), block_tables,
-        positions, valid, layer)
+    q_nope, q_rope, c_kv, k_rope, c_q = _latent_qkv(h, p, c,
+                                                    positions[:, 0])
+    rows = (ops.pack_latent_rows(c_kv, k_rope),)
+    if c.index_topk:
+        # An indexed run's second pool: a token's one index key.
+        q_i, k_i, w_i = _index_qkw(h, c_q, p, c, positions[:, 0])
+        rows += (ops.pack_kv_rows(k_i[..., None, :]),)
+    pool, *index_pool = ops.paged_rows_update(
+        pools, rows, block_tables, positions, valid, layer)
     w_uk, w_uv = _latent_up(p, c)
     q_lat = jnp.einsum("blhk,hkc->blhc", q_nope, w_uk.astype(h.dtype))
-    out = ops.latent_attention(
-        ops.pack_latent_rows(q_lat, q_rope), pool, block_tables, ctx_lens,
-        positions, valid, layer, v_width=c.kv_lora_rank,
-        scale=spec.attn_scale)
+    if c.index_topk:
+        out = ops.sparse_latent_attention(
+            ops.pack_latent_rows(q_lat, q_rope), q_i, w_i, pool,
+            index_pool[0], block_tables, ctx_lens, positions, valid, layer,
+            v_width=c.kv_lora_rank, scale=c.attn_scale, topk=c.index_topk)
+    else:
+        out = ops.latent_attention(
+            ops.pack_latent_rows(q_lat, q_rope), pool, block_tables,
+            ctx_lens, positions, valid, layer, v_width=c.kv_lora_rank,
+            scale=c.attn_scale, **({"window": c.window} if c.window else {}))
     attn = jnp.einsum("blhc,hcv->blhv", out, w_uv.astype(h.dtype))
+    attn = _head_gate(attn, h, p, c)
     return (jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype)),
-            (pool,))
+            (pool, *index_pool))
 
 
 def _kvb_served(w, qk_nope: int):
@@ -489,11 +627,17 @@ class CacheRows:
     needs to know it (`PagedKVCache.for_model`): a stored row's shape, and
     how many rows a lane holds: one a token (`window` 0), or the exact rows
     of the open window of `window` tokens behind one summary row for every
-    `chunk` tokens of each closed one."""
+    `chunk` tokens of each closed one; or (`slide`) one a token of which
+    only the last `slide` positions are ever read again (a sliding table:
+    blocks behind them go back to the allocator).  `extra`: the widths of
+    further rows a token leaves in pools of their own, in the same blocks
+    under the same table (an indexer's key)."""
     kv_heads: int
     head_dim: int
     window: int = 0
     chunk: int = 0
+    slide: int = 0
+    extra: tuple = ()
 
 
 @dataclasses.dataclass(frozen=True)
@@ -528,10 +672,14 @@ HEADS = Attention(heads_attention, heads_attention_cached,
                   rows=lambda c: CacheRows(c.n_kv_heads, c.head_dim),
                   cast=("wq", "wk", "wv", "wo"))
 LATENT = Attention(latent_attention, latent_attention_cached,
-                   rows=lambda c: CacheRows(1, c.kv_lora_rank
-                                            + c.qk_rope_head_dim),
+                   rows=lambda c: CacheRows(
+                       1, c.kv_lora_rank + c.qk_rope_head_dim,
+                       slide=getattr(c, "window", 0),
+                       extra=((c.index_head_dim,)
+                              if getattr(c, "index_topk", 0) else ())),
                    pools=1, trains=False,
-                   cast=("w_qa", "w_qb", "w_kva", "w_kvb", "wo"),
+                   cast=("w_qa", "w_qb", "w_kva", "w_kvb", "wo",
+                         "w_head_gate", "w_iq", "w_ik", "w_iw"),
                    absorbed="w_kvb")
 EVA = Attention(eva_attention, eva_attention_cached,
                 rows=lambda c: CacheRows(c.n_kv_heads, c.head_dim,
@@ -539,6 +687,27 @@ EVA = Attention(eva_attention, eva_attention_cached,
                 cast=("wq", "wk", "wv", "wo"),
                 compact=eva_compact, compact_leaves=("eva_mu", "eva_phi"),
                 trains=False)
+
+
+@dataclasses.dataclass(frozen=True)
+class Run:
+    """A run of like layers in a model's stack: its feed-forward, its
+    attention and where its layers' leaves and cached rows live.  A model
+    whose layers differ in more than a leading run's feed-forward names
+    its runs itself (`Spec.runs`), in order."""
+    blocks: str             # the key of its stacks in the parameter tree
+    n_layers: int
+    ffn: FeedForward
+    attn: Attention
+    first: int = 0          # its first layer's index in its pools,
+    offset: int = 0         # and in its stacks (the runs of a kind share one)
+    # What its attention reads where that is not the model's config
+    # (`LatentSizes`).
+    sizes: Any = None
+    # Which of the cache's pools are its kind's, and which (part, of how
+    # many equal parts) of the block tables' columns: `None` is all.
+    pools: Optional[tuple] = None
+    table: Optional[tuple] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -570,6 +739,10 @@ class Spec:
     # feed-forward; the other n_layers - first_dense_layers have `ffn`.
     first_dense_layers: int = 0
     lead_ffn: Optional[FeedForward] = None
+    # Or, for a stack of several kinds of layer, its runs in order (each
+    # with its attention, its feed-forward and its pools: `Run`); `attn`
+    # and `ffn` above are then those of its most common run.
+    runs: tuple = ()
     # The dtype the residual stream is added in where it is not the
     # activations' (float32 under bf16 matrices; `norm` then casts back),
     # and that of the logits where the head's product is kept wider.
@@ -585,14 +758,13 @@ def _norm(spec: Spec, x, p, leaves):
     return spec.norm(x, *(p[name] for name in leaves))
 
 
-def _block(x, p, spec: Spec, ffn: FeedForward, config, mesh,
-           position_offset=0):
+def _block(x, p, spec: Spec, run: Run, config, mesh, position_offset=0):
     c = config
     h = _norm(spec, x, p, spec.attn_norm)
-    x = x + spec.attn.apply(h, p, spec, c, mesh, position_offset)
+    x = x + run.attn.apply(h, p, spec, run.sizes or c, mesh, position_offset)
 
     h = _norm(spec, x, p, spec.mlp_norm)
-    y, aux, _ = ffn.apply(h, p, c, mesh)
+    y, aux, _ = run.ffn.apply(h, p, c, mesh)
     if aux is None:
         aux = jnp.zeros((), jnp.float32)
     x = with_logical_constraint(x + y, ("batch", "length", "act_embed"),
@@ -600,21 +772,21 @@ def _block(x, p, spec: Spec, ffn: FeedForward, config, mesh,
     return x, aux
 
 
-def _block_cached(x, pools, p, spec: Spec, ffn: FeedForward, config,
+def _block_cached(x, pools, p, spec: Spec, run: Run, config,
                   block_tables, positions, valid, ctx_lens):
-    """One block over a paged cache: what the slice's tokens leave there
-    is written into the whole pools at `p["cache_layer"]`, then attention
-    runs over the block table in the same buffers (`Attention.cached`).
-    x [B, T, D]; positions [B, T] absolute; ctx_lens [B] = context length
-    including this slice.  Returns (x, pools, the expert layer's load or
-    None)."""
+    """One block of a run over a paged cache: what the slice's tokens leave
+    there is written into the run's whole pools at `p["cache_layer"]`, then
+    attention runs over its kind's block table in the same buffers
+    (`Attention.cached`).  x [B, T, D]; positions [B, T] absolute; ctx_lens
+    [B] = context length including this slice.  Returns (x, pools, the
+    expert layer's load or None)."""
     h = _norm(spec, x, p, spec.attn_norm)
-    attn, pools = spec.attn.cached(h, pools, p, spec, config, block_tables,
-                                   positions, valid, ctx_lens)
+    attn, pools = run.attn.cached(h, pools, p, spec, run.sizes or config,
+                                  block_tables, positions, valid, ctx_lens)
     x = x + attn
 
     h = _norm(spec, x, p, spec.mlp_norm)
-    y, _, load = ffn.apply(h, p, config, valid=valid)
+    y, _, load = run.ffn.apply(h, p, config, valid=valid)
     return x + y, pools, load
 
 
@@ -639,14 +811,17 @@ def _layer_of(blocks: dict, i, whole: tuple = ()) -> dict:
     return {**p, "layer": i}
 
 
-def _stacks(spec: Spec, params: dict, config) -> list:
-    """The runs of like layers, in order: (blocks, layers, feed-forward,
-    index of the run's first layer in the whole stack)."""
+def _stacks(spec: Spec, config) -> tuple:
+    """The runs of like layers, in order: the spec's own, or a leading run
+    of `first_dense_layers` layers with another feed-forward and the
+    rest."""
+    if spec.runs:
+        return spec.runs
     lead = spec.first_dense_layers
-    main = (params["blocks"], config.n_layers - lead, spec.ffn, lead)
+    main = Run("blocks", config.n_layers - lead, spec.ffn, spec.attn, lead)
     if not lead:
-        return [main]
-    return [(params["lead_blocks"], lead, spec.lead_ffn, 0), main]
+        return (main,)
+    return (Run("lead_blocks", lead, spec.lead_ffn, spec.attn), main)
 
 
 # --------------------------------------------------------------------------
@@ -672,13 +847,18 @@ def forward_trunk(family, params: dict, tokens: jax.Array, config,
     x = with_logical_constraint(x, ("batch", "length", "act_embed"), mesh=mesh)
 
     aux = None
-    for blocks, n_layers, ffn, _ in _stacks(spec, params, c):
-        block = partial(_block, spec=spec, ffn=ffn, config=c, mesh=mesh,
+    for run in _stacks(spec, c):
+        n_layers, ffn = run.n_layers, run.ffn
+        block = partial(_block, spec=spec, run=run, config=c, mesh=mesh,
                         position_offset=position_offset)
         if c.remat:
             block = jax.checkpoint(
                 block, policy=jax.checkpoint_policies.nothing_saveable)
 
+        blocks = params[run.blocks]
+        if run.offset or n_layers < jax.tree.leaves(blocks)[0].shape[0]:
+            blocks = {k: v[run.offset:run.offset + n_layers]
+                      for k, v in blocks.items()}
         scanned, whole = _layer_stack(blocks, n_layers, ffn.whole)
 
         def body(x, layer, block=block, whole=whole):
@@ -737,12 +917,13 @@ def loss_fn(family, params: dict, batch: dict, config, mesh=None):
                                            spmd_ce_applicable)
 
     c, spec = config, family(config)
-    if not spec.ffn.trains:
+    runs = _stacks(spec, c)
+    if not all(run.ffn.trains for run in runs):
         raise NotImplementedError(
             "training an expert configuration is not supported yet: the "
             "grouped matmul (ops/moe.py) has no backward pass and the "
             "router's auxiliary losses are not computed (ROADMAP.md R1)")
-    if not spec.attn.trains:
+    if not all(run.attn.trains for run in runs):
         raise NotImplementedError(
             "this attention has no train path yet: latent attention is "
             "served absorbed, EVA's whole-sequence form is plain XLA "
@@ -805,17 +986,16 @@ def _rows_served(name, keep):
     return served
 
 
-# By whether the head is the token table, and the width at which an
-# attention's `absorbed` leaf splits (module level: `_remake` is compiled
-# once per set of forms).
+# By whether the head is the token table, and the widths at which the
+# attentions' `absorbed` leaves split, a stack each (module level:
+# `_remake` is compiled once per set of forms).
 @functools.lru_cache(maxsize=None)
-def _served_forms(tied: bool, absorbed: Optional[str], split: int):
+def _served_forms(tied: bool, absorbed: tuple):
     forms = (("w_down", _w_down_served),
              ("tok_embed", _rows_served("tok", keep=tied)),
              ("pos_embed", _rows_served("pos", keep=False)))
-    if absorbed:
-        forms += ((absorbed, partial(_kvb_served, qk_nope=split)),)
-    return forms
+    return forms + tuple((key, partial(_kvb_served, qk_nope=split))
+                         for key, split in absorbed)
 
 
 @partial(jax.jit, static_argnums=(1, 2, 3))
@@ -845,20 +1025,34 @@ def serving_params(family, params: dict, config) -> dict:
     its step takes it; the raw tree gives the same tokens, paying casts
     and copies in every call."""
     spec = family(config)
-    ffn_cast = spec.ffn.cast + (spec.lead_ffn.cast if spec.lead_ffn else ())
-    cast = ("tok_embed", "pos_embed", "lm_head") + spec.attn.cast + ffn_cast
+    runs = _stacks(spec, config)
+    cast = ("tok_embed", "pos_embed", "lm_head") + tuple(
+        name for run in runs for name in run.attn.cast + run.ffn.cast)
+    # stack -> (its attention's absorbed leaf, the width it splits at)
+    absorbed = {run.blocks: (run.attn.absorbed, (
+        run.sizes or config).qk_nope_head_dim)
+        for run in runs if run.attn.absorbed}
     dtype = jnp.dtype(config.dtype)
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
     names = [path[-1].key for path, _ in flat]
+
+    def form_key(path, name):
+        """The name a leaf's form goes by: an absorbed leaf's with its
+        stack's split."""
+        leaf, split = absorbed.get(path[0].key, (None, 0))
+        return f"{name}:{split}" if name == leaf else name
+
+    keys = [form_key(path, name) for name, (path, _) in zip(names, flat)]
     todo = [i for i, (_, x) in enumerate(flat)
-            if names[i] == spec.attn.absorbed
+            if keys[i] != names[i]
             or (names[i] in cast and x.dtype != dtype)]
     if not todo:
         return params
     made = dict(zip(todo, _remake(
-        [flat[i][1] for i in todo], tuple(names[i] for i in todo), dtype,
-        _served_forms(spec.tied_head, spec.attn.absorbed,
-                      getattr(config, "qk_nope_head_dim", 0)))))
+        [flat[i][1] for i in todo], tuple(keys[i] for i in todo), dtype,
+        _served_forms(spec.tied_head, tuple(sorted(
+            {(f"{name}:{split}", split)
+             for name, split in absorbed.values()}))))))
     out = {}
     for i, (path, x) in enumerate(flat):
         node = out
@@ -911,7 +1105,8 @@ def forward_cached(family, params: dict, tokens: jax.Array,
     through the layer loop too and returned fourth: the load stays on the
     device until somebody asks."""
     c, spec = config, family(config)
-    if not spec.ffn.serves:
+    runs = _stacks(spec, c)
+    if not all(run.ffn.serves for run in runs):
         raise NotImplementedError(
             "this feed-forward has no path over a paged KV cache (the "
             "Switch layer's capacity is a whole batch's)")
@@ -922,16 +1117,38 @@ def forward_cached(family, params: dict, tokens: jax.Array,
         x = _embed(params, "tok", tokens, c)
     if spec.residual_dtype is not None:
         x = x.astype(spec.residual_dtype)
-    pools = (k_pool,) if v_pool is None else (k_pool, v_pool)
+    # A cache of several kinds of layer hands its pools over as one tuple
+    # (`k_pool`; `PagedKVCache.k`), a run takes its own (`Run.pools`) and
+    # its kind's columns of the block tables (`Run.table`).
+    several = isinstance(k_pool, (tuple, list))
+    pools = (tuple(k_pool) if several
+             else (k_pool,) if v_pool is None else (k_pool, v_pool))
     seen = moe_load
-    for blocks, n_layers, ffn, first in _stacks(spec, params, c):
+    for run in runs:
+        blocks, n_layers, first, off = (params[run.blocks], run.n_layers,
+                                        run.first, run.offset)
+        tables = block_tables
+        if run.table is not None:
+            part, parts = run.table
+            mb = block_tables.shape[1] // parts
+            tables = jax.lax.slice_in_dim(block_tables, part * mb,
+                                          (part + 1) * mb, axis=1)
 
-        def body(carry, i, blocks=blocks, ffn=ffn, first=first):
+        def body(carry, i, run=run, blocks=blocks, first=first, off=off,
+                 tables=tables):
             x, pools, seen = carry
-            x, pools, load = _block_cached(
-                x, pools, {**_layer_of(blocks, i, ffn.whole),
-                           "cache_layer": i + first if first else i},
-                spec, ffn, c, block_tables, positions, valid, ctx_lens)
+            own = pools if run.pools is None else tuple(
+                pools[j] for j in run.pools)
+            x, own, load = _block_cached(
+                x, own, {**_layer_of(blocks, i + off if off else i,
+                                     run.ffn.whole),
+                         "cache_layer": i + first if first else i},
+                spec, run, c, tables, positions, valid, ctx_lens)
+            if run.pools is None:
+                pools = own
+            else:
+                pools = tuple(own[run.pools.index(j)] if j in run.pools
+                              else pool for j, pool in enumerate(pools))
             if seen is not None and load is not None:
                 seen = seen + jnp.concatenate([
                     load, jnp.sum(load > 0, dtype=jnp.int32)[None],
@@ -942,7 +1159,8 @@ def forward_cached(family, params: dict, tokens: jax.Array,
             body, (x, pools, seen), jnp.arange(n_layers, dtype=jnp.int32),
             unroll=min(c.scan_unroll, n_layers))
     x = _norm(spec, x, params, spec.final_norm)
-    k_pool, v_pool = pools if len(pools) == 2 else (pools[0], None)
+    k_pool, v_pool = ((pools, None) if several else pools
+                      if len(pools) == 2 else (pools[0], None))
     return (x, k_pool, v_pool) if seen is None else (x, k_pool, v_pool, seen)
 
 
@@ -957,16 +1175,17 @@ def compact_cached(family, params: dict, k_pool: jax.Array,
     `compact_leaves` are read.  Returns (k_pool, v_pool)."""
     c, spec = config, family(config)
     pools = (k_pool, v_pool)
-    for blocks, n_layers, _, first in _stacks(spec, params, c):
-        read = {k: blocks[k] for k in spec.attn.compact_leaves}
+    for run in _stacks(spec, c):
+        blocks, first = params[run.blocks], run.first
+        read = {k: blocks[k] for k in run.attn.compact_leaves}
 
-        def body(pools, i, read=read, first=first):
-            return spec.attn.compact(
+        def body(pools, i, run=run, read=read, first=first):
+            return run.attn.compact(
                 pools, {**_layer_of(read, i), "cache_layer": i + first},
                 c, src, dst, live), None
 
         pools, _ = jax.lax.scan(body, pools,
-                                jnp.arange(n_layers, dtype=jnp.int32))
+                                jnp.arange(run.n_layers, dtype=jnp.int32))
     return pools
 
 

@@ -1009,11 +1009,13 @@ def pack_latent_rows(latent, rope_part):
 
 
 def latent_attention_reference(q, pool, block_tables, ctx_lens, q_positions,
-                               layer=0, *, v_width: int, scale: float):
+                               layer=0, *, v_width: int, scale: float,
+                               window: int = 0):
     """Masked-dense latent attention (ground truth, and the CPU's T=1
     path): q [B, T, H, W] rows at absolute q_positions [B, T]; pool
     [L, NB, BS, W] read at `layer`.  Gathers every lane's whole table.
-    Returns the output in latent space [B, T, H, v_width]."""
+    With `window` a row attends its last `window` positions, its own
+    among them.  Returns the output in latent space [B, T, H, v_width]."""
     b = q.shape[0]
     bs, w = pool.shape[2:]
     max_ctx = block_tables.shape[1] * bs
@@ -1023,6 +1025,9 @@ def latent_attention_reference(q, pool, block_tables, ctx_lens, q_positions,
     kpos = jnp.arange(max_ctx)
     mask = ((kpos[None, None, None, :] <= q_positions[:, None, :, None])
             & (kpos[None, None, None, :] < ctx_lens[:, None, None, None]))
+    if window:
+        mask = mask & (kpos[None, None, None, :]
+                       > q_positions[:, None, :, None] - window)
     probs = jax.nn.softmax(jnp.where(mask, logits, NEG_INF), axis=-1)
     out = jnp.einsum("bhtk,bkc->bthc", probs, ctx[..., :v_width])
     return out.astype(q.dtype)
@@ -1030,7 +1035,8 @@ def latent_attention_reference(q, pool, block_tables, ctx_lens, q_positions,
 
 def _latent_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
                           block_size: int, blocks_per_step: int,
-                          n_steps: int, v_width: int, scale: float):
+                          n_steps: int, v_width: int, scale: float,
+                          start_ref=None):
     """One (lane, run of `blocks_per_step` cache blocks) grid step.  q
     [H, W]; each block [BS, W] arrives by its own DMA (the pool is handed
     in once per block of the run, each with its own index map) and is read
@@ -1040,7 +1046,10 @@ def _latent_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
     across the lane's sweep, as in the kernels above, and is updated once
     for the whole run where the blocks are as wide as the lanes (one
     update a block was 31% of the kernel's roofline at blocks of 128 and
-    4.5% at blocks of 16: PERF.md section 6, PR 31)."""
+    4.5% at blocks of 16: PERF.md section 6, PR 31).  With `start_ref` a
+    lane's sweep begins at the block of its first attended position
+    (`_window_decode_kernel`): step 0 is that block's run, and positions
+    before the start are masked."""
     del bt_ref, layer_ref               # only the index maps read them
     blocks = refs[:blocks_per_step]
     o_ref, m_ref, l_ref, acc_ref = refs[blocks_per_step:]
@@ -1058,7 +1067,10 @@ def _latent_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
         """One online-softmax update with scores s [H, N] of the tokens
         from `base` on; values(p) is p @ their latents."""
         pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < n_ctx, s * scale, NEG_INF)
+        keep = pos < n_ctx
+        if start_ref is not None:
+            keep = keep & (pos >= start_ref[lane])
+        s = jnp.where(keep, s * scale, NEG_INF)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -1077,6 +1089,8 @@ def _latent_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
                        preferred_element_type=jnp.float32)       # [H, C]
 
     run = step * blocks_per_step * block_size
+    if start_ref is not None:
+        run = run + start_ref[lane] // block_size * block_size
     if block_size % 128 == 0:
         # The run's blocks side by side: one update of the softmax state
         # (a max, an exp, a rescale of the accumulator) for the whole run.
@@ -1102,11 +1116,85 @@ def _latent_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
         o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
 
 
+def _window_decode_kernel(bt_ref, len_ref, layer_ref, start_ref, q_ref,
+                          *refs, **kw):
+    """`_latent_decode_kernel` over a lane's last positions alone: from
+    `start_ref[lane]` on."""
+    _latent_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
+                          start_ref=start_ref, **kw)
+
+
+def window_latent_decode_attention(q, pool, block_tables, ctx_lens, starts,
+                                   layer=0, *, v_width: int, scale: float,
+                                   span: int,
+                                   name="window_latent_decode_attention",
+                                   use_kernel: Optional[bool] = None,
+                                   interpret: Optional[bool] = None):
+    """`latent_decode_attention` over the positions `starts` [B] to
+    `ctx_lens` - 1 of each lane, at most `span` of them: the blocks behind
+    a lane's start are neither fetched nor scored (its table may name
+    anything there), and the grid covers the blocks a span can touch, not
+    the table."""
+    b, h, w = q.shape
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+    if not use_kernel:
+        return latent_attention_reference(
+            q[:, None], pool, block_tables, ctx_lens,
+            (ctx_lens - 1)[:, None], layer, v_width=v_width, scale=scale,
+            window=span)[:, 0]
+    if interpret is None:
+        interpret = _interpret_kernels()
+    bs = pool.shape[2]
+    mb = block_tables.shape[1]
+    touched = min((span + bs - 2) // bs + 1, mb)
+    kb = min(max(1, 512 // bs), touched)
+    n_steps = -(-touched // kb)
+
+    def block_map(r):
+        def index(i, j, bt, ln, ly, st):
+            last = jnp.maximum(ln[i] - 1, 0) // bs
+            return (ly[0], bt[i, jnp.minimum(st[i] // bs + j * kb + r,
+                                             jnp.minimum(last, mb - 1))],
+                    0, 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,   # tables, context lengths, layer, starts
+        grid=(b, n_steps),
+        in_specs=[pl.BlockSpec((None, h, w),
+                               lambda i, j, bt, ln, ly, st: (i, 0, 0))]
+        + [pl.BlockSpec((None, None, bs, w), block_map(r))
+           for r in range(kb)],
+        out_specs=pl.BlockSpec((None, h, v_width),
+                               lambda i, j, bt, ln, ly, st: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, v_width), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_window_decode_kernel, block_size=bs,
+                          blocks_per_step=kb, n_steps=n_steps,
+                          v_width=v_width, scale=scale),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name=name,
+    )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1), starts.astype(jnp.int32),
+      q.astype(pool.dtype), *([pool] * kb))
+
+
 def latent_decode_attention(q, pool, block_tables, ctx_lens, layer=0, *,
                             v_width: int, scale: float,
                             blocks_per_step: Optional[int] = None,
                             use_kernel: Optional[bool] = None,
-                            interpret: Optional[bool] = None):
+                            interpret: Optional[bool] = None,
+                            name="latent_decode_attention"):
     """Single-query latent attention: q [B, H, W] rows (one decode token a
     lane) over each lane's block table in the latent pool [L, NB, BS, W]
     at `layer` (may be traced); ctx_lens counts the tokens written,
@@ -1167,7 +1255,7 @@ def latent_decode_attention(q, pool, block_tables, ctx_lens, layer=0, *,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         # The instruction's name in the HLO and so in a device trace.
-        name="latent_decode_attention",
+        name=name,
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1), q.astype(pool.dtype),
       *([pool] * kb))
@@ -1205,10 +1293,28 @@ def latent_chunk_attention(q, pool, block_tables, ctx_lens, q_positions,
 
 
 def latent_attention(q, pool, block_tables, ctx_lens, q_positions, valid,
-                     layer=0, *, v_width: int, scale: float):
+                     layer=0, *, v_width: int, scale: float,
+                     window: int = 0):
     """Dispatch latent attention for a [B, T, H, W] query slice: the T=1
     decode step rides the single-query kernel, longer slices the tiled
-    path."""
+    path.  With `window` a row attends its last `window` positions alone
+    (`window_latent_decode_attention`; a longer slice's rows each as a
+    lane of its own: `_rows_as_lanes`)."""
+    if window:
+        def attend(name):
+            def fn(tables, ctx, q_rows):
+                return window_latent_decode_attention(
+                    q_rows, pool, tables, ctx, jnp.maximum(ctx - window, 0),
+                    layer, v_width=v_width, scale=scale, span=window,
+                    name=name)
+            return fn
+        if q.shape[1] == 1:
+            return attend("window_latent_decode_attention")(
+                block_tables, ctx_lens, q[:, 0])[:, None]
+        return _rows_as_lanes(
+            attend("window_latent_chunk_attention"), block_tables,
+            q_positions, valid, (q,),
+            jnp.zeros(q.shape[:-1] + (v_width,), q.dtype))
     if q.shape[1] == 1:
         return latent_decode_attention(
             q[:, 0], pool, block_tables, ctx_lens, layer, v_width=v_width,
@@ -1216,6 +1322,221 @@ def latent_attention(q, pool, block_tables, ctx_lens, q_positions, valid,
     return latent_chunk_attention(q, pool, block_tables, ctx_lens,
                                   q_positions, valid, layer,
                                   v_width=v_width, scale=scale)
+
+
+# Rows of a T > 1 slice a trip of `_rows_as_lanes` takes as its lanes.
+_ROW_TILE = 64
+
+
+def _rows_as_lanes(fn, block_tables, q_positions, valid, parts, out):
+    """A T > 1 slice through a single-query form: every valid row is a lane
+    of its own whose context ends at its own position (the slice's rows are
+    in the pool before anything attends), `_ROW_TILE` rows a trip, a trip
+    for the tiles that hold a valid row and none for the others.
+    `fn(tables [R, MB], ctx_lens [R], *rows [R, ...])` -> [R, ...];
+    `parts` are the [B, T, ...] arrays whose rows it takes; rows without
+    work stay as `out` [B, T, ...] has them."""
+    b, t = q_positions.shape
+    n = b * t
+    tile = min(_ROW_TILE, n)
+    flat_valid = valid.reshape(n)
+    order = jnp.argsort(~flat_valid, stable=True).astype(jnp.int32)
+    order = jnp.pad(order, (0, -n % tile), constant_values=n)
+    n_valid = jnp.sum(flat_valid, dtype=jnp.int32)
+    flat_pos = q_positions.reshape(n)
+    flat_parts = [x.reshape((n,) + x.shape[2:]) for x in parts]
+
+    def body(i, flat_out):
+        rows = jax.lax.dynamic_slice_in_dim(order, i * tile, tile)
+        live = i * tile + jnp.arange(tile, dtype=jnp.int32) < n_valid
+        at = jnp.minimum(rows, n - 1)
+        ctx = jnp.where(live, flat_pos[at] + 1, 0)
+        res = fn(block_tables[at // t], ctx, *(x[at] for x in flat_parts))
+        return flat_out.at[jnp.where(live, rows, n)].set(
+            res.astype(flat_out.dtype), mode="drop")
+
+    flat = jax.lax.fori_loop(0, -(-n_valid // tile), body,
+                             out.reshape((n,) + out.shape[2:]))
+    return flat.reshape(out.shape)
+
+
+# --------------------------------------------------------------------------
+# Indexed (sparse) latent attention: a learned indexer scores every cached
+# position of a lane against the query (a paged pool of ONE index key a
+# token, in the blocks and under the table of the latent rows), the `topk`
+# positions of largest score are chosen, exactly, and attention reads the
+# chosen rows of the latent pool and no others.
+# --------------------------------------------------------------------------
+
+def index_scores_reference(q_i, w_i, index_pool, block_tables, ctx_lens,
+                           layer=0):
+    """I[b, s] = sum_j w_i[b, j] relu(q_i[b, j] . k[b, s]) for every
+    position s of lane b's table, NEG_INF from its context's end on
+    (ground truth and the CPU's path).  q_i [B, Hi, Di], w_i [B, Hi]
+    float32, index_pool [L, NB, BS, Di]; float32 [B, MB * BS]."""
+    b = q_i.shape[0]
+    keys = index_pool[layer, block_tables].reshape(b, -1, q_i.shape[-1])
+    s = jnp.einsum("bhd,bsd->bhs", q_i.astype(keys.dtype), keys,
+                   preferred_element_type=jnp.float32)
+    scores = jnp.sum(jax.nn.relu(s) * w_i[:, :, None], axis=1)
+    kpos = jnp.arange(keys.shape[1])
+    return jnp.where(kpos[None, :] < ctx_lens[:, None], scores, NEG_INF)
+
+
+def _index_scores_kernel(bt_ref, len_ref, layer_ref, q_ref, w_ref, *refs,
+                         block_size: int, blocks_per_step: int):
+    """One (lane, run of `blocks_per_step` blocks of index keys) grid
+    step: the run's keys against all Hi index queries on the MXU, ReLU,
+    the heads' weighted sum, and the run's scores written where they
+    belong in the lane's row; NEG_INF from the context's end on."""
+    del bt_ref, layer_ref               # only the index maps read them
+    blocks, o_ref = refs[:blocks_per_step], refs[blocks_per_step]
+    lane = pl.program_id(0)
+    run = pl.program_id(1) * blocks_per_step * block_size
+    s = jnp.concatenate([jax.lax.dot_general(
+        q_ref[...], c[...], (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32) for c in blocks], axis=1)
+    scores = jnp.sum(jnp.maximum(s, 0.0) * w_ref[...], axis=0,
+                     keepdims=True)                         # [1, N]
+    pos = run + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+    o_ref[...] = jnp.where(pos < len_ref[lane], scores, NEG_INF)
+
+
+# Keys a grid step of the index kernel scores: a step is some 0.35 us of its
+# own on a v5e and a key 256 bytes, so at 512 keys the steps were two thirds
+# of the kernel (34% of its roofline; PERF.md section 6, PR 41).
+_INDEX_RUN = 1024
+
+
+def sparse_index_scores(q_i, w_i, index_pool, block_tables, ctx_lens,
+                        layer=0, *, name: str = "sparse_index_scores",
+                        use_kernel: Optional[bool] = None,
+                        interpret: Optional[bool] = None):
+    """The indexer's score of every cached position of each lane, one
+    decode token a lane (`index_scores_reference`): float32 [B, N] with
+    N >= MB * BS.  The Pallas kernel on TPU (a run of `_INDEX_RUN` keys a
+    grid step, each block fetched once through the table; a step past the
+    lane's last block names that block again, so nothing is fetched for
+    it), the gathered form on the CPU."""
+    b, hi, _ = q_i.shape
+    di = index_pool.shape[3]            # keys narrower than a lane are padded
+    q_i = pack_kv_rows(q_i[..., None, :])
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+    if not use_kernel:
+        return index_scores_reference(q_i, w_i, index_pool, block_tables,
+                                      ctx_lens, layer)
+    if interpret is None:
+        interpret = _interpret_kernels()
+    bs = index_pool.shape[2]
+    mb = block_tables.shape[1]
+    kb = min(max(1, _INDEX_RUN // bs), mb)
+    n_steps = -(-mb // kb)
+
+    def block_map(r):
+        def index(i, j, bt, ln, ly):
+            last = jnp.maximum(ln[i] - 1, 0) // bs
+            return (ly[0], bt[i, jnp.minimum(j * kb + r,
+                                             jnp.minimum(last, mb - 1))],
+                    0, 0)
+        return index
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,      # block tables, context lengths, layer
+        grid=(b, n_steps),
+        in_specs=[pl.BlockSpec((None, hi, di),
+                               lambda i, j, bt, ln, ly: (i, 0, 0)),
+                  pl.BlockSpec((None, hi, 1),
+                               lambda i, j, bt, ln, ly: (i, 0, 0))]
+        + [pl.BlockSpec((None, None, bs, di), block_map(r))
+           for r in range(kb)],
+        out_specs=pl.BlockSpec((None, 1, kb * bs),
+                               lambda i, j, bt, ln, ly: (i, 0, j)),
+    )
+    out = pl.pallas_call(
+        functools.partial(_index_scores_kernel, block_size=bs,
+                          blocks_per_step=kb),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, 1, n_steps * kb * bs),
+                                       jnp.float32),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        name=name,
+    )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
+      jnp.asarray(layer, jnp.int32).reshape(1),
+      q_i.astype(index_pool.dtype), w_i.astype(jnp.float32)[:, :, None],
+      *([index_pool] * kb))
+    return out[:, 0]
+
+
+def sparse_latent_decode_attention(q, q_i, w_i, pool, index_pool,
+                                   block_tables, ctx_lens, layer=0, *,
+                                   v_width: int, scale: float, topk: int,
+                                   names=("sparse_index_scores",
+                                          "sparse_latent_decode_attention")):
+    """Single-query latent attention over the `topk` positions the indexer
+    scores highest (all of a context no longer than that): scores over the
+    index keys (`sparse_index_scores`), an exact top-k (one stable sort of
+    every lane's scores, best first, so ties go to the lower position, as
+    `jax.lax.top_k`'s do; in a trace under the scope `sparse_select`), ONE
+    gather of the chosen rows of the latent pool, and
+    `latent_decode_attention` over those rows laid side by side.  The
+    context's latent rows are never read.  q [B, H, W], q_i [B, Hi, Di],
+    w_i [B, Hi]; returns [B, H, v_width].
+
+    What is sorted beside a score is its row's place in the pool, not its
+    position: a position looked up in the table afterwards is a gather of
+    single numbers, 1.4 ms for 64 x 2,048 on a v5e where the sort itself
+    is 1.1; and the rows are taken from the pool as a table of rows
+    [L * NB * BS, W] (2.1 ms, where the same rows by (layer, block, row)
+    take 2.4: PERF.md section 6, PR 41)."""
+    b = q.shape[0]
+    n_layers, nb, bs, w = pool.shape
+    n = block_tables.shape[1] * bs
+    scores = sparse_index_scores(q_i, w_i, index_pool, block_tables,
+                                 ctx_lens, layer, name=names[0])[:, :n]
+    k = min(topk, n)
+    with jax.named_scope("sparse_select"):
+        # Best first, so the masked positions (past the context) last: the
+        # first min(ctx, k) are the chosen ones.
+        place = (jnp.repeat(block_tables, bs, axis=1) * bs
+                 + jnp.arange(n, dtype=jnp.int32) % bs)
+        _, place = jax.lax.sort((-scores, place), dimension=1, num_keys=1,
+                                is_stable=True)
+        place = place[:, :k] + jnp.asarray(layer, jnp.int32) * (nb * bs)
+    with jax.named_scope("sparse_gather"):
+        rows = jnp.take(pool.reshape(n_layers * nb * bs, w), place, axis=0,
+                        mode="clip")
+    per = max(d for d in range(1, min(k, 128) + 1) if k % d == 0)
+    return latent_decode_attention(
+        q, rows.reshape(1, b * k // per, per, w),
+        jnp.arange(b * k // per, dtype=jnp.int32).reshape(b, k // per),
+        jnp.minimum(ctx_lens, k), 0, v_width=v_width, scale=scale,
+        name=names[1])
+
+
+def sparse_latent_attention(q, q_i, w_i, pool, index_pool, block_tables,
+                            ctx_lens, q_positions, valid, layer=0, *,
+                            v_width: int, scale: float, topk: int):
+    """Dispatch indexed latent attention for a [B, T, ...] slice: the T=1
+    step as it is, a longer slice's valid rows each as a lane of its own
+    (`_rows_as_lanes`; kernels `sparse_index_chunk_scores`,
+    `sparse_latent_chunk_attention`)."""
+    def attend(**names):
+        def fn(tables, ctx, q_rows, qi_rows, wi_rows):
+            return sparse_latent_decode_attention(
+                q_rows, qi_rows, wi_rows, pool, index_pool, tables, ctx,
+                layer, v_width=v_width, scale=scale, topk=topk, **names)
+        return fn
+    if q.shape[1] == 1:
+        return attend()(block_tables, ctx_lens, q[:, 0], q_i[:, 0],
+                        w_i[:, 0])[:, None]
+    return _rows_as_lanes(
+        attend(names=("sparse_index_chunk_scores",
+                      "sparse_latent_chunk_attention")),
+        block_tables, q_positions, valid, (q, q_i, w_i),
+        jnp.zeros(q.shape[:-1] + (v_width,), q.dtype))
 
 
 def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,

@@ -25,6 +25,16 @@ import numpy as np
 _MAGIC = b"KVT1"
 
 
+def _as_numpy(tree):
+    """A payload's further kinds (`more`) with every array as contiguous
+    numpy."""
+    if isinstance(tree, dict):
+        return {k: _as_numpy(v) for k, v in tree.items()}
+    if isinstance(tree, (list, tuple)):
+        return [_as_numpy(v) for v in tree]
+    return tree if isinstance(tree, int) else np.ascontiguousarray(tree)
+
+
 class KVCodecError(ValueError):
     """Payload is not a KVBlockCodec frame (or an incompatible one)."""
 
@@ -48,8 +58,12 @@ class KVBlockCodec:
             {
                 "v": 1,
                 # "kv": K and V rows; "latent": one latent row in `k`,
-                # `v_pool` None (inference/kv_cache.py).
+                # `v_pool` None; "layered": as "latent", and under `more`
+                # the blocks of the cache's other kinds, each said to be
+                # whose (inference/kv_cache.py).
                 "kind": payload.get("kind", "kv"),
+                **({"more": _as_numpy(payload["more"])}
+                   if payload.get("more") else {}),
                 "block_size": int(payload["block_size"]),
                 "chain": [list(map(int, blk)) for blk in payload["chain"]],
                 "k": np.ascontiguousarray(payload["k"]),
@@ -75,7 +89,7 @@ class KVBlockCodec:
         k, v = payload["k"], payload["v_pool"]
         n = len(payload["chain"])
         bs = payload["block_size"]
-        latent = payload.setdefault("kind", "kv") == "latent"
+        latent = payload.setdefault("kind", "kv") in ("latent", "layered")
         if (v is None) != latent or (v is not None and k.shape != v.shape) \
                 or k.shape[1] != n or k.shape[2] != bs:
             raise KVCodecError(

@@ -1,0 +1,367 @@
+"""dots3-note (models/dots3.py): runs of two kinds of latent attention in one
+stack over a paged cache of several kinds of layer, against the plain
+reference (benchmark/reference/dots3.py) on seeded random weights at nano
+size on the CPU: an indexer that chooses 16 positions, a window of 9,
+contexts to 80, float32 throughout.
+
+Tolerances: float32 sums in another order (the program absorbs the
+up-projection and gathers chosen rows, the reference expands every key and
+masks); logits are of order 4, so 2e-4 is five digits."""
+
+import dataclasses
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import dots3 as ref
+from ray_tpu.inference import InferenceEngine, PagedKVCache
+from ray_tpu.models import decoder, dots3
+from ray_tpu.ops import attention as ops
+
+NANO = dots3.CONFIGS["dots3-nano"]
+SHARE = dots3.CONFIGS["dots3-nano-share"]
+LOGIT_TOL = 2e-4
+
+
+def _ref_kw(cfg):
+    return dict(top_k=cfg.n_experts_per_tok, first_held=cfg.experts_offset,
+                index_topk=cfg.index_topk, window=cfg.sliding_window)
+
+
+@functools.lru_cache(maxsize=None)
+def _init(cfg, seed=0):
+    """(one compiled program a config, not one dispatch an op)"""
+    return jax.jit(dots3.init_params, static_argnums=0)(
+        cfg, jax.random.key(seed))
+
+
+def _params(cfg, seed=0):
+    """Seeded weights with norm scales off one, so that a norm left out or
+    applied twice shows."""
+    params = dict(_init(cfg, seed))
+    for stack in ("lead_blocks", "full_blocks", "win_blocks"):
+        params[stack] = {
+            k: v * (1.0 + 0.1 * jax.random.normal(jax.random.key(9), v.shape))
+            if k.endswith("_norm") or k == "ik_scale" else v
+            for k, v in params[stack].items()}
+    return params
+
+
+def _tokens(cfg, shape, seed=1):
+    return jax.random.randint(jax.random.key(seed), shape, 0, cfg.vocab_size)
+
+
+def test_the_spec_names_the_runs_of_like_layers_in_order():
+    runs = dots3.spec(NANO).runs
+    assert [(r.blocks, r.n_layers, r.first, r.offset) for r in runs] == [
+        ("lead_blocks", 1, 0, 0), ("full_blocks", 1, 1, 0),
+        ("win_blocks", 3, 0, 0), ("full_blocks", 1, 2, 1),
+        ("win_blocks", 3, 3, 3)]
+    assert [r.pools for r in runs] == [(0, 1), (0, 1), (2,), (0, 1), (2,)]
+    full, win = runs[0].sizes, runs[2].sizes
+    assert (full.index_topk, full.window, win.index_topk, win.window) == (
+        16, 0, 0, 9)
+    assert full.q_rescale == pytest.approx((64 / 32) ** 0.5)
+    assert full.kv_rescale == pytest.approx((64 / 16) ** 0.5)
+    assert win.kv_rescale == pytest.approx((64 / 32) ** 0.5)
+    # the published pattern, where the config gives none
+    kinds = dots3.Dots3Config().kinds
+    assert kinds.count(dots3.FULL) == 13 and kinds.count(dots3.WINDOW) == 33
+    assert kinds[:6] == (dots3.FULL, dots3.FULL) + (dots3.WINDOW,) * 3 + (
+        dots3.FULL,)
+    # a model of one kind of layer has the two runs it always had
+    from ray_tpu.models import axk1
+    cfg = axk1.CONFIGS["axk1-nano"]
+    assert [(r.blocks, r.n_layers, r.first, r.sizes, r.pools) for r in
+            decoder._stacks(axk1.spec(cfg), cfg)] == [
+        ("lead_blocks", 1, 0, None, None), ("blocks", 2, 1, None, None)]
+
+
+@pytest.mark.parametrize("cfg", [NANO, SHARE], ids=["whole", "share"])
+def test_uncached_forward_matches_the_reference_on_logits(cfg):
+    params = _params(cfg)
+    tokens = _tokens(cfg, (2, 80))
+    with jax.default_matmul_precision("highest"):
+        got = dots3.forward(params, tokens, cfg)
+    want = ref.logits(params, tokens, **_ref_kw(cfg))
+    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
+    assert float(jnp.abs(want).max()) > 100 * LOGIT_TOL
+
+
+def _cached_logits(cfg, params, tokens, chunk, block_size=4, served=False):
+    """Prefill `tokens` [L] in chunks of `chunk`, the last 30 one token at
+    a time (the T=1 path), through a cache of both kinds whose blocks are
+    dealt out of order, giving back the sliding kind's blocks as the window
+    moves on; logits of every position, and the cache."""
+    length = len(tokens)
+    cache = PagedKVCache.for_model(dots3, cfg, num_blocks=(40, 12),
+                                   block_size=block_size, max_lanes=2,
+                                   max_seq_len=96, ahead=chunk)
+    assert cache.kind == "layered" and cache.v is None
+    assert [p.shape for p in cache.k] == [
+        (3, 40, block_size, 128), (3, 40, block_size, 128),
+        (6, 12, block_size, 128)]
+    cache.allocator.alloc(3)              # lane 1 does not start at block 0
+    cache.slide_allocator.alloc(2)
+    cache.alloc_lane(1, length)
+    tree = dots3.serving_params(params, cfg) if served else params
+    pools, out, at = cache.k, [], 0
+
+    @jax.jit        # (two shapes; op by op every call compiles its loops)
+    def step(tree, tok, pos, valid, pools, tables, ctx_lens):
+        with jax.default_matmul_precision("highest"):
+            x, pools, none = dots3.forward_cached(
+                tree, tok, pos, valid, pools, None, tables, ctx_lens, cfg)
+            assert none is None and len(pools) == 3
+            return dots3.lm_head(tree, x[1], cfg), pools
+
+    while at < length:
+        t = chunk if at + chunk <= length - 30 else 1
+        cache.ensure_capacity(1, at + t)
+        tok = np.zeros((2, t), np.int32)
+        tok[1] = tokens[at:at + t]
+        pos = np.zeros((2, t), np.int32)
+        pos[1] = at + np.arange(t)
+        valid = np.zeros((2, t), bool)
+        valid[1] = True
+        logits, pools = step(
+            tree, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(valid),
+            pools, cache.device_tables(), jnp.asarray([1, at + t], jnp.int32))
+        out.append(logits)
+        at += t
+        cache.seq_lens[1] = at
+        cache.slide_release(1)
+    return jnp.concatenate(out), cache
+
+
+@pytest.mark.parametrize("cfg,served", [(NANO, False), (NANO, True),
+                                        (SHARE, True)],
+                         ids=["whole_raw_tree", "whole_served_tree",
+                              "share_served_tree"])
+def test_prefill_in_chunks_then_decode_matches_the_reference(cfg, served):
+    """Through the cache (absorbed, indexed, chosen rows gathered; the
+    window's blocks given back behind it) = the reference's expanded, masked
+    full forward: across the window's slide (9 of 80 positions) and past
+    index_topk (16), in chunks whose rows each choose for themselves and at
+    T=1."""
+    params = _params(cfg)
+    tokens = np.asarray(_tokens(cfg, (80,)))
+    got, cache = _cached_logits(cfg, params, tokens, chunk=8, served=served)
+    want = ref.row_logits(params, tokens, **_ref_kw(cfg))
+    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
+    # 80 tokens in blocks of 4: the window's 8 positions behind position 80
+    # lie in slots 18 and 19 (72..79); every slot behind went back.
+    assert sorted(cache.slide_blocks(1)) == [18, 19]
+    assert cache.stats["slide_blocks_freed"] == 18
+    assert len(cache.lane_blocks(1)) == 20            # the growing kind
+
+
+def test_a_chunk_of_more_rows_than_a_tile_takes_its_rows_in_tiles(monkeypatch):
+    """`ops._rows_as_lanes` with 16 rows a chunk in tiles of 3: a trip for
+    each tile that holds a valid row, the last one part empty."""
+    monkeypatch.setattr(ops, "_ROW_TILE", 3)
+    cfg = NANO
+    params = _params(cfg)
+    tokens = np.asarray(_tokens(cfg, (80,)))
+    got, _ = _cached_logits(cfg, params, tokens, chunk=8)
+    want = ref.row_logits(params, tokens, **_ref_kw(cfg))
+    np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
+
+
+def test_serving_params_split_each_kinds_up_projection_at_its_own_width():
+    cfg = NANO
+    params = _params(cfg)
+    tree = dots3.serving_params(params, cfg)
+    for stack, (heads, nope, c, v) in {"lead_blocks": (4, 16, 16, 16),
+                                       "full_blocks": (4, 16, 16, 16),
+                                       "win_blocks": (2, 24, 32, 16)}.items():
+        n = params[stack]["w_kvb"].shape[0]
+        assert "w_kvb" not in tree[stack]
+        assert tree[stack]["w_uk"].shape == (n, heads, nope, c)
+        assert tree[stack]["w_uv"].shape == (n, heads, c, v)
+        np.testing.assert_array_equal(
+            tree[stack]["w_uk"][0, 1],
+            params[stack]["w_kvb"][0, :, 1, :nope].T)
+    assert tree["full_blocks"]["router_bias"].dtype == jnp.float32
+
+
+def _layer(cfg, params, stack, kind, x, **over):
+    """One layer's whole-sequence attention (`decoder.LATENT.apply`) at the
+    sizes of `kind`, with some replaced."""
+    sizes = dataclasses.replace(cfg.sizes(kind), **over)
+    p = {k: v[0] for k, v in params[stack].items()}
+    with jax.default_matmul_precision("highest"):
+        return decoder.LATENT.apply(x, p, dots3.spec(cfg), sizes, None)
+
+
+def test_the_indexed_form_is_the_dense_one_while_the_context_fits_topk():
+    """A full layer over 16 positions with index_topk 16 chooses them all:
+    its output is plain causal latent attention's (no indexer); over 40
+    positions it is not."""
+    cfg, params = NANO, _params(NANO)
+    x = jax.random.normal(jax.random.key(3), (2, 40, cfg.d_model))
+    short = x[:, :16]
+    np.testing.assert_allclose(
+        _layer(cfg, params, "full_blocks", dots3.FULL, short),
+        _layer(cfg, params, "full_blocks", dots3.FULL, short, index_topk=0),
+        atol=1e-5, rtol=0)
+    sparse = _layer(cfg, params, "full_blocks", dots3.FULL, x)
+    dense = _layer(cfg, params, "full_blocks", dots3.FULL, x, index_topk=0)
+    np.testing.assert_allclose(sparse[:, :16], dense[:, :16], atol=1e-5,
+                               rtol=0)
+    assert float(jnp.abs(sparse[:, 16:] - dense[:, 16:]).max()) > 1e-3
+
+
+def test_a_window_layer_is_a_full_latent_layer_under_a_band_mask():
+    """The window layer's whole-sequence form = the reference's expanded
+    attention of the same leaves under the band t - 9 < s <= t; without the
+    window it is another function."""
+    cfg, params = NANO, _params(NANO)
+    x = jax.random.normal(jax.random.key(3), (1, 40, cfg.d_model))
+    p = {k: v[0] for k, v in params["win_blocks"].items()}
+    h = decoder.rmsnorm(x, p["attn_norm"], cfg.norm_eps)
+    got = _layer(cfg, params, "win_blocks", dots3.WINDOW, h)
+    with jax.default_matmul_precision("highest"):
+        want = ref.attention(x[0], p, cfg.swa_rope_theta, cfg.norm_eps, True,
+                             window=cfg.sliding_window) - x[0]
+        wide = ref.attention(x[0], p, cfg.swa_rope_theta, cfg.norm_eps, True,
+                             window=41) - x[0]
+    np.testing.assert_allclose(got[0], want, atol=2e-5, rtol=0)
+    assert float(jnp.abs(want - wide).max()) > 1e-3
+
+
+def test_the_router_chooses_by_the_biased_scores_and_weighs_by_the_unbiased():
+    cfg = NANO
+    params = _params(cfg)
+    p = {k: v[0] for k, v in params["full_blocks"].items()
+         if k not in ("w_gate", "w_up", "w_down")}
+    # a bias that decides: expert 3 always in, expert 5 never
+    bias = jnp.zeros((cfg.n_routed_experts,)).at[3].set(10.).at[5].set(-10.)
+    x = jax.random.normal(jax.random.key(4), (1, 24, cfg.d_model))
+    want = np.asarray(ref.router_weights(x[0], p["router"], bias,
+                                         cfg.n_experts_per_tok, 1.0))
+    assert (want[:, 3] > 0).all() and (want[:, 5] == 0).all()
+    np.testing.assert_allclose(want.sum(-1), 1.0, atol=1e-6)
+    # the weight of expert 3 is its unbiased score's share, not the biased
+    scores = np.asarray(jax.nn.sigmoid(x[0] @ p["router"]))
+    chosen = want > 0
+    np.testing.assert_allclose(
+        want[:, 3], scores[:, 3] / (scores * chosen).sum(-1), atol=1e-6)
+    held = {k: params["full_blocks"][k] for k in ("w_gate", "w_up", "w_down")}
+    y, _, load = decoder.moe_ffn(
+        x, {**p, **held, "router_bias": bias, "layer": 0}, cfg)
+    assert int(load[3]) == 24 and int(load[5]) == 0
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """16 experts over 4 shares (`experts_offset` 0, 4, 8, 12): the routed
+    parts of all four shares, plus what every chip computes alike (the
+    shared expert) counted once, equal the uncut reference layer."""
+    cfg = NANO
+    params = _params(cfg)
+    blocks = params["win_blocks"]
+    layer = 1
+    p = {k: v[layer] for k, v in blocks.items()
+         if k not in ("w_gate", "w_up", "w_down")}
+    x = jax.random.normal(jax.random.key(4), (2, 12, cfg.d_model))
+    h2 = decoder.rmsnorm(x, p["mlp_norm"], cfg.norm_eps)
+    flat = x.reshape(-1, cfg.d_model)
+    with jax.default_matmul_precision("highest"):
+        whole = ref.experts(flat, blocks, layer, cfg.n_experts_per_tok, 0,
+                            cfg.norm_eps, cfg.routed_scale) - flat
+        shared = ref.swiglu(h2.reshape(-1, cfg.d_model), p["ws_gate"],
+                            p["ws_up"], p["ws_down"])
+        total, loads = jnp.zeros_like(flat), []
+        for s in range(4):
+            share = dataclasses.replace(cfg, n_experts_held=4,
+                                        experts_offset=4 * s)
+            held = {k: blocks[k][:, 4 * s:4 * s + 4]
+                    for k in ("w_gate", "w_up", "w_down")}
+            y, _, load = decoder.moe_ffn(h2, {**p, **held, "layer": layer},
+                                         share)
+            total = total + y.reshape(-1, cfg.d_model)
+            loads.append(np.asarray(load))
+    np.testing.assert_allclose(total + shared, whole, atol=5e-5, rtol=0)
+    assert sum(int(load.sum()) for load in loads) == 24 * cfg.n_experts_per_tok
+    assert float(jnp.abs(whole).max()) > 10 * 5e-5
+
+
+# -- the kernels, interpreted, against the gathered forms ---------------------
+
+def _pool_case(seed, lanes=3, bs=16, nb=24, mb=6, layers=2, width=128):
+    rng = np.random.default_rng(seed)
+    pool = jnp.asarray(rng.standard_normal((layers, nb, bs, width)),
+                       jnp.float32)
+    tables = jnp.asarray(rng.permutation(nb)[:lanes * mb].reshape(lanes, mb),
+                         jnp.int32)
+    return rng, pool, tables
+
+
+@pytest.mark.parametrize("window", [21, 33, 64])
+def test_window_decode_kernel_reads_from_the_lanes_start_on(window):
+    """The kernel over positions ctx - window .. ctx - 1 = the masked dense
+    path; table entries behind a lane's start may name anything (they are
+    never fetched)."""
+    rng, pool, tables = _pool_case(0)
+    ctx = jnp.asarray([90, 5, 37], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((3, 4, 128)), jnp.float32)
+    starts = jnp.maximum(ctx - window, 0)
+    kw = dict(v_width=96, scale=0.1, span=window)
+    for layer in (0, 1):
+        want = ops.window_latent_decode_attention(
+            q, pool, tables, ctx, starts, layer, use_kernel=False, **kw)
+        freed = tables.at[0, :int(starts[0]) // 16].set(0)
+        got = ops.window_latent_decode_attention(
+            q, pool, freed, ctx, starts, layer, use_kernel=True,
+            interpret=True, **kw)
+        np.testing.assert_allclose(got, want, atol=2e-6, rtol=0)
+
+
+def test_index_scores_kernel_matches_the_gathered_form():
+    rng, pool, tables = _pool_case(1)
+    ctx = jnp.asarray([90, 5, 37], jnp.int32)
+    q_i = jnp.asarray(rng.standard_normal((3, 4, 128)), jnp.float32)
+    w_i = jnp.asarray(rng.standard_normal((3, 4)), jnp.float32)
+    want = ops.sparse_index_scores(q_i, w_i, pool, tables, ctx, 1,
+                                   use_kernel=False)
+    got = ops.sparse_index_scores(q_i, w_i, pool, tables, ctx, 1,
+                                  use_kernel=True, interpret=True)
+    np.testing.assert_allclose(got[:, :96], want, atol=1e-4, rtol=0)
+    assert (np.asarray(want)[1, 5:] == ops.NEG_INF).all()
+    # a score is the heads' weighted sum of ReLUs
+    keys = np.asarray(pool[1, tables[0, 0]])
+    s = np.maximum(np.asarray(q_i[0]) @ keys.T, 0)
+    np.testing.assert_allclose(want[0, :16], np.asarray(w_i[0]) @ s,
+                               atol=1e-4, rtol=0)
+
+
+def test_sparse_decode_attends_the_chosen_rows_and_no_others():
+    """Indexed single-query attention = masked dense attention over the
+    top-k positions by index score; a lane whose context fits topk attends
+    all of it."""
+    rng, pool, tables = _pool_case(2)
+    _, index_pool, _ = _pool_case(3)
+    ctx = jnp.asarray([90, 12, 37], jnp.int32)
+    q = jnp.asarray(rng.standard_normal((3, 4, 128)), jnp.float32)
+    q_i = jnp.asarray(rng.standard_normal((3, 4, 128)), jnp.float32)
+    w_i = jnp.asarray(rng.standard_normal((3, 4)), jnp.float32)
+    got = ops.sparse_latent_decode_attention(
+        q, q_i, w_i, pool, index_pool, tables, ctx, 1, v_width=96,
+        scale=0.1, topk=16)
+    scores = np.asarray(ops.index_scores_reference(q_i, w_i, index_pool,
+                                                   tables, ctx, 1))
+    rows = np.asarray(pool[1, tables].reshape(3, -1, 128))
+    for lane in range(3):
+        keep = np.argsort(-scores[lane], kind="stable")[:16]
+        keep = keep[keep < int(ctx[lane])]
+        logits = np.asarray(q[lane]) @ rows[lane, keep].T * 0.1
+        p = np.exp(logits - logits.max(-1, keepdims=True))
+        want = (p / p.sum(-1, keepdims=True)) @ rows[lane, keep, :96]
+        np.testing.assert_allclose(got[lane], want, atol=2e-5, rtol=0)
+    dense = ops.latent_decode_attention(q, pool, tables, ctx, 1, v_width=96,
+                                        scale=0.1)
+    np.testing.assert_allclose(got[1], dense[1], atol=2e-5, rtol=0)
+    assert float(jnp.abs(got[0] - dense[0]).max()) > 1e-3

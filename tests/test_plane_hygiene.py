@@ -406,7 +406,10 @@ def test_the_parts_the_clocks_and_the_timeline_add_no_transfer_and_no_event():
     assert sorted(parts) == sorted([
         ("engine.build_batch", "windows"), ("engine.build_batch", "assemble"),
         ("engine.build_batch", "upload"), ("engine.commit", "release"),
-        ("engine.commit", "lock"), ("engine.commit", "deliver")])
+        ("engine.commit", "lock"), ("engine.commit", "deliver"),
+        # PR 41: a sliding kind's blocks go back behind a commit, into the
+        # same `windows` part
+        ("engine.commit", "windows")])
     assert 'spans.phase("engine/' not in src
     step_body = src[src.index("    def step(self)"):
                     src.index("    def _sums(self)")]

@@ -699,3 +699,40 @@ def test_evabyte_programs_fit_a_v5e_and_leave_pool_and_weights_in_place(
     assert kernels == ({"paged_decode_attention"} if t1 else set())
     if t1:
         assert "s32[24,22]" in text         # the table: lanes x peak blocks
+
+
+@pytest.mark.parametrize("which,t,rows", [("t1", 1, 64), ("short", 128, 1)],
+                         ids=["t1_64_lanes", "t128_one_row"])
+def test_dots3_steps_fit_a_v5e_and_leave_their_three_pools_in_place(
+        as_on_chip, which, t, rows):
+    """The cell's T=1 step and an admission's one-row program at the cell's
+    own sizes (`benchmark/tools/aot_dots3_sizes.py`, from its configuration
+    and traffic files): arguments and temporaries under the compiler's
+    15.75 GB, all three pools (the full layers' latent rows and index keys,
+    the window layers' rows) donated and left where they are, and the
+    kernels of both kinds of layer under the names the benchmark's readers
+    find them by."""
+    from benchmark.tools import aot_dots3_sizes
+    try:
+        texts = aot_dots3_sizes.main("serve_dots3_docs_decode", which)
+    except RuntimeError as e:           # no v5e topology can be described
+        pytest.skip(str(e))
+    text, memory, pools = texts[(t, rows)]
+    assert [tuple(p.shape) for p in pools] == [
+        (3, 1536, 128, 640), (3, 1536, 128, 128), (6, 768, 128, 1152)]
+    pool_bytes = sum(2 * math.prod(p.shape) for p in pools)
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    assert 11.3e9 < memory.argument_size_in_bytes < 11.6e9
+    assert all(count_pool_copies(text, p.shape) == 0 for p in pools)
+    kernels = {k.split(".")[0] for k in _kernel_names(text)}
+    assert kernels == ({"sparse_index_scores",
+                        "sparse_latent_decode_attention",
+                        "window_latent_decode_attention",
+                        "moe_grouped_matmul"} if t == 1 else
+                       {"sparse_index_chunk_scores",
+                        "sparse_latent_chunk_attention",
+                        "window_latent_chunk_attention",
+                        "moe_grouped_matmul"})
+
