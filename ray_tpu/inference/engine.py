@@ -50,6 +50,17 @@ discarded at commit.  A draft proposer needs the token on the host, so a
 speculative engine runs the same loop at depth 0: it fetches each step
 in the iteration that dispatched it (`InferenceEngine.step`).
 
+What the host does compute for a step (tokens, positions, masks, context
+lengths, sampling temperatures, seeds and counters, a compact program's
+rows) it writes into ONE int32 buffer a population (`_lane_views`: a lane a
+row, floats and unsigned seeds by their bits) and hands to the device in
+one transfer (`_upload`); the compiled program takes the buffer apart in
+front of the step (`_make_entry`, `_unpack_lanes`).  A transfer costs the
+host the same call whatever its bytes, and nine of them were two thirds of
+an iteration's host work (PERF.md section 6, PR 42).  `stats()["upload"]`
+counts populations, transfers (the block tables' copy goes only when a
+table changed) and bytes.
+
 The KV pools are donated on TPU and
 ride the step's layer loop whole: a step writes the blocks its new
 tokens fall in and reads the blocks it attends over in the engine's one
@@ -369,6 +380,41 @@ class GenerationHandle:
         return list(self._req.logps)
 
 
+def _lane_views(n: int, t: int, compact: bool, max_lanes: int):
+    """A population's lane arrays as views of ONE int32 buffer [n, 3 t + 5]
+    (a lane a row: `t` columns each of `tokens`, `positions` and `valid`,
+    then `ctx_lens`, `gather`, `temps`, `seeds`, `counters`, and a compact
+    program's `rows` last), so that they reach the device in one transfer.
+    `temps` (float32) and `seeds` (uint32) lie there by their bits, `valid`
+    as 0 / 1.  Nobody's lanes: masked, context 1, sampling nothing, no
+    row's lane.  Returns (buffer, the eight views, `rows` or None)."""
+    lanes = np.zeros((n, 3 * t + 5 + compact), np.int32)
+    tokens, positions, valid = (lanes[:, i * t:(i + 1) * t] for i in range(3))
+    ctx_lens, gather, temps, seeds, counters = (
+        lanes[:, 3 * t + i] for i in range(5))
+    ctx_lens[:] = 1
+    counters[:] = -1
+    rows = None
+    if compact:
+        rows = lanes[:, -1]
+        rows[:] = max_lanes
+    return lanes, (tokens, positions, valid, ctx_lens, gather,
+                   temps.view(np.float32), seeds.view(np.uint32),
+                   counters), rows
+
+
+def _unpack_lanes(lanes, t: int) -> tuple:
+    """`_lane_views`' arrays out of the buffer on the device, as a step
+    takes them: static slices, `valid` by `!= 0`, `temps` and `seeds` by
+    their bits (a compact program's `rows` ninth)."""
+    tokens, positions, valid = (lanes[:, i * t:(i + 1) * t] for i in range(3))
+    ctx_lens, gather, temps, seeds, counters, *rows = (
+        lanes[:, i] for i in range(3 * t, lanes.shape[1]))
+    return (tokens, positions, valid != 0, ctx_lens, gather,
+            jax.lax.bitcast_convert_type(temps, jnp.float32),
+            jax.lax.bitcast_convert_type(seeds, jnp.uint32), counters, *rows)
+
+
 def _by_lane(per_row, rows, max_lanes: int):
     """A compact program's per-row results [rows, T] as [max_lanes, T]."""
     out = np.zeros((max_lanes,) + per_row.shape[1:], per_row.dtype)
@@ -563,6 +609,9 @@ class InferenceEngine:
         self._prefill = {"steps": 0, "lanes": 0, "rows": 0, "rows_valid": 0,
                          "ctx_rows": 0}
         self._tokens_run = 0
+        # What `_upload` handed to the device: populations, transfers (a
+        # changed block table's copy among them) and their bytes.
+        self._uploads = {"populations": 0, "transfers": 0, "bytes": 0}
         latent = self.cache.kind in ("latent", "layered")
         self._latent = {"decode_steps": 0, "ctx_tokens": 0} if latent else None
         self._paged = None if latent else {
@@ -907,6 +956,9 @@ class InferenceEngine:
             # prefilled in them, the rows they computed and how many of
             # those held a prompt token.
             "prefill": dict(self._prefill),
+            # Populations handed to the device, the transfers that took
+            # and their bytes (`_upload`).
+            "upload": dict(self._uploads),
             # T=1 steps and the context tokens their lanes attended over;
             # over a K/V cache also the runs of the decode kernel that
             # held context, of `decode_steps` x lanes x runs a lane.
@@ -1279,8 +1331,8 @@ class InferenceEngine:
         with contextlib.ExitStack() as locked:
             with spans.phase("engine", "commit") as ph:
                 with spans.phase("engine.commit", "release") as part:
-                    # Let go of the steps' device arrays (nine uploads and
-                    # the sampled tokens per population) here, inside a
+                    # Let go of the steps' device arrays (the lanes' buffer
+                    # and the sampled tokens per population) here, inside a
                     # phase: left to the return, their release and what the
                     # runtime then does took 1.4 ms a step on a v5e, between
                     # two steps, under no phase's name (PERF.md 6, PR 23).
@@ -1432,15 +1484,19 @@ class InferenceEngine:
         return spec, lanes, chunks, news, batch
 
     def _upload(self, arrays) -> tuple:
-        """A population's host arrays (`_build_batch`) as `_run_step` takes
-        them: eight small device arrays and the block tables' copy, fourth
-        among them.  A compact program's `rows` stay on the host (`step`
-        gives each fetched row back to its lane by them); `_run_step`
-        uploads them, and `dispatch` has that time."""
-        t, sample, host, rows = arrays
-        args = [jnp.asarray(a) for a in host]
-        args.insert(3, self.cache.device_tables())
-        return t, sample, tuple(args), rows
+        """A population's lane arrays (`_build_batch`) as `_run_step` takes
+        them: their one buffer as ONE device array, and the block tables'
+        copy (a transfer only where a table has changed since the last).
+        `rows` stay on the host too (`step` gives each fetched row back to
+        its lane by them)."""
+        t, sample, lanes, _, rows = arrays
+        changed = not self.cache.tables_on_device
+        up = self._uploads
+        up["populations"] += 1
+        up["transfers"] += 1 + changed
+        up["bytes"] += lanes.nbytes + changed * self.cache.block_tables.nbytes
+        return (t, sample, (jnp.asarray(lanes), self.cache.device_tables()),
+                rows)
 
     def _ends_in_flight(self, lane: int, req: _Request) -> bool:
         """Whether the step in flight ends `req` by a count the host has
@@ -1457,7 +1513,8 @@ class InferenceEngine:
 
     def _build_batch(self, live, t, prefill=False):
         """Host-side assembly of the fixed-shape lane arrays for one
-        population (lanes not in `live` ride along fully masked), from the
+        population, as views of the one buffer that goes to the device
+        (`_lane_views`; lanes not in `live` ride along fully masked), from the
         lengths and counts the step in flight will have left: committed
         plus `ahead_len` / `ahead_new`.  A decode lane whose last token
         that step is still sampling is told to read it on the device
@@ -1476,15 +1533,9 @@ class InferenceEngine:
         n = self.max_lanes
         if compact:
             n = 1 if len(live) == 1 else self.prefill_lanes
-        tokens = np.zeros((n, t), np.int32)
-        positions = np.zeros((n, t), np.int32)
-        valid = np.zeros((n, t), bool)
-        ctx_lens = np.ones((n,), np.int32)
-        gather = np.zeros((n,), np.int32)
-        temps = np.zeros((n,), np.float32)
-        seeds = np.zeros((n,), np.uint32)
-        counters = np.full((n,), -1, np.int32)
-        rows = np.full((n,), self.max_lanes, np.int32) if compact else None
+        lanes, host, rows = _lane_views(n, t, compact, self.max_lanes)
+        (tokens, positions, valid, ctx_lens, gather, temps, seeds,
+         counters) = host
         chunks = {}
         sample = False
         for i, (lane, req) in enumerate(live):
@@ -1505,7 +1556,7 @@ class InferenceEngine:
                     -1 if req.ahead_new else req.last_token,) + tuple(
                         req.draft)
             positions[row] = start + np.arange(t)
-            valid[row, :chunk] = True
+            valid[row, :chunk] = 1
             ctx_lens[row] = start + chunk
             gather[row] = chunk - 1
             temps[row] = req.temperature
@@ -1531,7 +1582,7 @@ class InferenceEngine:
             # over: what the tiled T > 1 attention reads, where the dense
             # path read every lane's whole table.
             pf["ctx_rows"] += sum(self.cache.rows_held(int(c))
-                                  for c in ctx_lens[valid[:, 0]])
+                                  for c in ctx_lens[valid[:, 0] != 0])
         elif t == 1:
             ctx = [int(ctx_lens[lane]) for lane, _ in live]
             if self._eva is not None:
@@ -1552,9 +1603,7 @@ class InferenceEngine:
             if self._latent is None:
                 self._paged["runs_live"] += sum(
                     -(-c // self._paged_run) for c in ctx)
-        arrays = (t, sample, (tokens, positions, valid, ctx_lens, gather,
-                              temps, seeds, counters), rows)
-        return arrays, chunks
+        return (t, sample, lanes, host, rows), chunks
 
     def _run_step(self, batch, spec: bool = False):
         t, sample, args, rows = batch
@@ -1562,19 +1611,16 @@ class InferenceEngine:
         key = (t, sample, spec, compact)
         fn = self._step_fns.get(key)
         first = fn is None
-        # After its nine lane arrays (and a compact program's `rows`) a
-        # step takes the arrays that ride from step to step on the device:
-        # the lanes' last sampled tokens, and last an expert
-        # configuration's load counters (handed back last; not donated:
-        # stats() may be reading them).
+        # After the lanes' buffer and the block tables a step takes the
+        # arrays that ride from step to step on the device: the lanes'
+        # last sampled tokens, and last an expert configuration's load
+        # counters (handed back last; not donated: stats() may be reading
+        # them).
         moe = () if self._moe_load is None else (self._moe_load,)
-        if compact:
-            args = (*args, jnp.asarray(rows))
         carried = (self._last_tok, *moe)
         if first:
             t0 = time.perf_counter()
-            fn = self._step_fns[key] = self._make_step_fn(sample, spec,
-                                                          bool(compact))
+            fn = self._step_fns[key] = self._make_entry(*key)
             self._step_avals[key] = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
                 (self._served, self.cache.k, self.cache.v, *args, *carried))
@@ -1634,16 +1680,25 @@ class InferenceEngine:
         nothing), so that whichever warms one shape of an engine has warmed
         both and neither compiles when a second lane first prefills beside
         another, minutes into serving."""
-        t, sample, args, rows = batch
+        t, sample, _, rows = batch
         for n in {1, self.prefill_lanes} - {len(rows)}:
-            if (t, sample, False, n) in self._step_fns:
-                continue
-            self._run_step((t, sample, (
-                jnp.zeros((n, t), jnp.int32), jnp.zeros((n, t), jnp.int32),
-                jnp.zeros((n, t), bool), args[3], jnp.ones((n,), jnp.int32),
-                jnp.zeros((n,), jnp.int32), jnp.zeros((n,), jnp.float32),
-                jnp.zeros((n,), jnp.uint32), jnp.full((n,), -1, jnp.int32)),
-                np.full((n,), self.max_lanes, np.int32)))
+            if (t, sample, False, n) not in self._step_fns:
+                self._run_step(self._upload((t, sample, *_lane_views(
+                    n, t, True, self.max_lanes))))
+
+    def _make_entry(self, t: int, sample: bool, spec: bool, compact: int):
+        """The program the engine runs for one `_run_step` key: the step
+        (`_make_step_fn`) behind the unpacking of the lanes' one buffer."""
+        step = self._make_step_fn(sample, spec, bool(compact))
+
+        def entry(params, k, v, lanes, tables, *carried):
+            tokens, positions, valid, *rest = _unpack_lanes(lanes, t)
+            return step(params, k, v, tokens, positions, valid, tables,
+                        *rest, *carried)
+
+        entry.__name__ = step.__name__      # the program keeps its name
+        donate = () if self.backend == "cpu" else (1, 2)
+        return jax.jit(entry, donate_argnums=donate)
 
     def _make_step_fn(self, sample: bool, spec: bool = False,
                       compact: bool = False):

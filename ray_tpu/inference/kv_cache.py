@@ -1360,6 +1360,12 @@ class PagedKVCache:
 
     # ---------------- device mirrors ----------------
 
+    @property
+    def tables_on_device(self) -> bool:
+        """Whether `device_tables()` has its copy (no table changed since
+        it was made) and will transfer nothing."""
+        return self._dev_tables is not None
+
     def device_tables(self) -> jax.Array:
         """The tables as a step takes them: a copy made now, on the host.
         (`jnp.asarray` of a numpy array is the same memory on the CPU

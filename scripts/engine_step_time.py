@@ -20,6 +20,14 @@ With no argument the configuration's own `scan_unroll`; several give the
 layer loop at each (PERF.md section 6, PR 34: 9.45 ms at 4, 9.53 at 1,
 14.00 before the loop indexed its stacks).  The last line is one JSON
 object, ms a call over three sets of calls.
+
+Since PR 42 the engine runs each step behind the unpacking of the lanes' one
+buffer (`_make_entry`): every program is timed that way too (`..._entry_ms`,
+beside the nine-array step's `..._ms`: the difference is what the unpacking
+costs the device), and `host_upload_ms` is what handing a T=1 population to
+the device costs the host with nothing else running (`_upload`, mean of 200
+calls, three sets), beside the eight `jnp.asarray` of the same arrays that
+the engine made before (`host_upload_eight_ms`).
 """
 
 from __future__ import annotations
@@ -29,6 +37,7 @@ import json
 import os
 import sys
 import time
+import types
 
 sys.path.insert(0, os.getcwd())
 
@@ -37,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from benchmark import manifest
-from ray_tpu.inference.engine import InferenceEngine
+from ray_tpu.inference.engine import InferenceEngine, _lane_views
 from ray_tpu.models import gpt
 from ray_tpu.ops.attention import kv_row_width
 
@@ -86,26 +95,57 @@ def main(unrolls):
                 # two lanes prefill, 14 decode
                 valid[lane], ctx[lane], gather[lane] = True, t, t - 1
                 positions[lane] = np.arange(t)
-            args = [jnp.asarray(a) for a in (
+            host = (
                 rng.integers(0, cfg.vocab_size, (lanes, t)).astype(np.int32),
-                positions.astype(np.int32), valid, tables.astype(np.int32),
-                ctx.astype(np.int32), gather, np.zeros(lanes, np.float32),
-                np.zeros(lanes, np.uint32), np.zeros(lanes, np.int32))]
+                positions.astype(np.int32), valid, ctx.astype(np.int32),
+                gather, np.zeros(lanes, np.float32),
+                np.zeros(lanes, np.uint32), np.zeros(lanes, np.int32))
+            args = [jnp.asarray(a) for a in host]
+            args.insert(3, jnp.asarray(tables.astype(np.int32)))
+            # the same population as the engine hands it over: one buffer
+            buffer, views, _ = _lane_views(lanes, t, False, lanes)
+            for view, a in zip(views, host):
+                view[...] = a
+            entry = eng._make_entry(t, False, name.startswith("verify"), 0)
+            packed = (jnp.asarray(buffer), args[3])
 
-            def run(n, k, v):
+            def run(fn, args, n, k, v):
                 t0 = time.perf_counter()
                 for _ in range(n):
-                    tok, k, v = step(params, k, v, *args)
+                    tok, k, v = fn(params, k, v, *args)
                 jax.block_until_ready((tok, k, v))
                 return 1000 * (time.perf_counter() - t0) / n, k, v
 
-            _, k, v = run(3, k, v)                # compile and warm
-            ms = []
-            for _ in range(3):
-                each, k, v = run(200 if t == 1 else 40, k, v)
-                ms.append(round(each, 4))
-            out[f"unroll{unroll}_{name}_ms"] = ms
+            for fn, given, key in ((step, args, "ms"),
+                                   (entry, packed, "entry_ms")):
+                _, k, v = run(fn, given, 3, k, v)         # compile and warm
+                ms = []
+                for _ in range(3):
+                    each, k, v = run(fn, given, 200 if t == 1 else 40, k, v)
+                    ms.append(round(each, 4))
+                out[f"unroll{unroll}_{name}_{key}"] = ms
             del k, v
+            if t == 1 and "host_upload_ms" not in out:
+                eng._uploads = dict.fromkeys(
+                    ("populations", "transfers", "bytes"), 0)
+                eng.cache = types.SimpleNamespace(    # tables unchanged
+                    tables_on_device=True, block_tables=tables,
+                    device_tables=lambda: args[3])
+                population = (1, False, buffer, views, None)
+                for key, upload in (
+                        ("host_upload_ms", lambda: eng._upload(population)),
+                        ("host_upload_eight_ms",
+                         lambda: [jnp.asarray(a) for a in views])):
+                    upload()
+                    ms = []
+                    for _ in range(3):
+                        t0 = time.perf_counter()
+                        for _ in range(200):
+                            kept = upload()
+                        ms.append(round(
+                            (time.perf_counter() - t0) * 1000 / 200, 4))
+                        jax.block_until_ready(kept)
+                    out[key] = ms
     print(json.dumps(out))
 
 

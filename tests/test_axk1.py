@@ -397,7 +397,12 @@ def test_prefill_lanes_serve_the_same_tokens_from_fewer_rows(family):
     # [2, 8] programs, and [2, 2] ones for steps whose lanes all had two
     # tokens or fewer left to feed; each also at one row, for steps in
     # which one lane prefilled (made together: `_warm_widths`)
-    shapes = {v[3].shape for k, v in eng._step_avals.items() if k[3]}
+    # (a program's lane arrays arrive as one buffer, a row a lane, `_upload`:
+    # its rows by the buffer's, its T by the key's)
+    shapes = {(v[3].shape[0], k[0]) for k, v in eng._step_avals.items()
+              if k[3]}
+    assert all(v[3].shape == (k[3], 3 * k[0] + 6)
+               for k, v in eng._step_avals.items() if k[3])
     assert {(2, 8), (1, 8)} <= shapes <= {(2, 8), (1, 8), (2, 2), (1, 2)}
     assert ((2, 2) in shapes) == ((1, 2) in shapes)
     assert pf["rows"] <= pf["steps"] * 2 * 8 and pf["rows"] % 2 == 0

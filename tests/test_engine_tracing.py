@@ -502,6 +502,39 @@ def test_step_records_name_the_parts_of_build_batch_and_commit():
     assert set(s1["phase_s"]) == set(PHASES)       # five keys, as before
 
 
+def test_upload_sums_grow_by_population():
+    """`stats()["upload"]` (PR 42): host sums `_upload` makes, a population
+    at a time: the populations handed to the device (a decode and a prefill
+    population of one iteration are two), the transfers that took (the
+    lanes' one buffer, and the block tables' copy where a table changed)
+    and their bytes.  No ring event and no transfer of its own."""
+    engine = _engine()
+    assert engine.stats()["upload"] == {"populations": 0, "transfers": 0,
+                                        "bytes": 0}
+    engine.generate(list(range(1, 6)), 2)          # compile both shapes
+    s0, seq = engine.stats(), _last_seq()
+    handles = [engine.submit(list(range(1, n)), 6) for n in (4, 12, 20)]
+    _drain(engine)
+    assert all(len(h.tokens()) == 6 for h in handles)
+    up0, up1 = s0["upload"], engine.stats()["upload"]
+    records = [e["payload"] for e in _steps_since(seq)]
+    pops = sum(bool(r["decode"]) + bool(r["prefill"]) for r in records)
+    assert up1["populations"] - up0["populations"] == pops > 0
+    assert any(r["decode"] and r["prefill"] for r in records)
+    extra = up1["transfers"] - up0["transfers"] - pops
+    assert 0 < extra <= pops                       # tables changed, not always
+    # two lanes: a T=1 buffer is [2, 8] int32, a T=8 one [2, 29]; a table
+    # copy the cache's whole table
+    t8 = sum(bool(r["prefill"]) for r in records)
+    assert up1["bytes"] - up0["bytes"] == (
+        (pops - t8) * 2 * 8 * 4 + t8 * 2 * 29 * 4
+        + extra * engine.cache.block_tables.nbytes)
+    assert all(type(v) is int for v in up1.values())
+    assert {e["kind"] for e in events.snapshot(plane="engine")
+            if e["seq"] > seq} <= {"step", "submit", "admit", "finish",
+                                   "prefix_miss", "prefix_hit"}
+
+
 def test_bench_rl_warms_every_verify_width_through_build_upload_run():
     """`bench_rl.py` compiles its engine's programs outside the timed
     rollout by the chain the loop itself runs, `_build_batch` -> `_upload`

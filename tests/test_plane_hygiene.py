@@ -429,14 +429,103 @@ def test_the_parts_the_clocks_and_the_timeline_add_no_transfer_and_no_event():
         for banned in ("jnp.", "jax.", "np.", "events.", "spans.", "self.cache",
                        "_last_tok", "_moe_load"):
             assert banned not in body, banned
-    # the uploads are where `upload` times them, and nowhere else in a step
+    # the uploads are where `upload` times them, and nowhere else in a step:
+    # `_upload` makes one device array of the lanes' one buffer (PR 42)
+    # beside the tables' cached copy; `_build_batch`, `_run_step` and
+    # `_warm_widths` (which goes through `_upload`) hand nothing over
     plan = src[src.index("    def _plan(self"):
                src.index("    def _ends_in_flight(")]
     assert plan.count("self.cache.device_tables()") == 1 == src.count(
         "self.cache.device_tables()")
+    assert plan.count("jnp.asarray(") == 1 == plan.count("jnp.asarray(lanes)")
     build = src[src.index("    def _build_batch("):
                 src.index("    def _run_step(")]
-    run = src[src.index("    def _run_step("):src.index("    def _warm_widths(")]
-    # (but for a compact program's `rows`, which stay `dispatch`'s)
-    assert "jnp.asarray" not in build
-    assert run.count("jnp.asarray") == 1 == run.count("jnp.asarray(rows)")
+    run = src[src.index("    def _run_step("):src.index("    def _make_entry(")]
+    for body in (build, run):
+        assert "jnp." not in body and "device_put" not in body
+    assert "self._upload(" in run[run.index("    def _warm_widths("):]
+
+
+def test_a_population_costs_the_transfers_the_upload_counter_says(
+        monkeypatch):
+    """PR 42: a population's lane arrays are one host buffer and reach the
+    device by ONE call into the runtime, in `_upload`; `_run_step` hands
+    nothing over (a compact program's `rows` ride in the buffer); the block
+    tables go only when one changed.  Counted under patched `jnp.asarray` /
+    `jax.device_put` / `jnp.array`, beside `stats()["upload"]`."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.inference import InferenceEngine, engine as engine_mod
+    from ray_tpu.inference import kv_cache
+
+    eng = InferenceEngine("axk1", "axk1-nano-share", auto_start=False,
+                          max_lanes=2, prefill_chunk=8, prefill_lanes=1,
+                          block_size=8)
+    eng.generate(list(range(1, 20)), 12)            # every program is made
+    calls = []
+
+    def counted(fn):
+        def call(x, *a, **kw):
+            if isinstance(x, np.ndarray) or (
+                    isinstance(x, (tuple, list)) and x
+                    and isinstance(x[0], np.ndarray)):
+                calls.append((where[0], np.asarray(x).nbytes))
+            return fn(x, *a, **kw)
+        return call
+
+    where = [""]
+    assert engine_mod.jnp is jnp is kv_cache.jnp and engine_mod.jax is jax
+    for mod, name in ((jnp, "asarray"), (jnp, "array"), (jax, "device_put")):
+        monkeypatch.setattr(mod, name, counted(getattr(mod, name)))
+    upload, run_step = eng._upload, eng._run_step
+
+    def tagged(name, fn):
+        def call(*a, **kw):
+            where[0] = name
+            try:
+                return fn(*a, **kw)
+            finally:
+                where[0] = ""
+        return call
+
+    eng._upload = tagged("upload", upload)
+    eng._run_step = tagged("run_step", run_step)
+
+    def population(live, t, prefill=False):
+        up0, n0 = dict(eng.stats()["upload"]), len(calls)
+        arrays, _ = eng._build_batch(live, t, prefill)
+        assert len(calls) == n0                     # assembling uploads nothing
+        eng._run_step(eng._upload(arrays))
+        up1 = eng.stats()["upload"]
+        grew = {k: up1[k] - up0[k] for k in up1}
+        new = calls[n0:]
+        assert grew["populations"] == 1
+        assert grew["transfers"] == len(new)
+        assert grew["bytes"] == sum(b for _, b in new)
+        assert all(w == "upload" for w, _ in new)   # none in `_run_step`
+        return arrays, len(new)
+
+    # a T=1 population with unchanged tables: exactly one transfer
+    eng.cache.device_tables()
+    (t, _, lanes, _, rows), n = population([], 1)
+    assert n == 1 and rows is None and lanes.shape == (2, 3 * 1 + 5)
+    # a changed table is a second one, once
+    eng.cache._dev_tables = None
+    assert population([], 1)[1] == 2
+    assert population([], 1)[1] == 1
+    # a compact prefill population: one, its `rows` in the buffer
+    (t, _, lanes, _, rows), n = population([], 8, True)
+    assert n == 1 and lanes.shape == (1, 3 * 8 + 6)
+    assert rows.base is lanes and (rows == eng.max_lanes).all()
+    # and so through the loop: a request's populations, one to two each
+    up0, n0 = dict(eng.stats()["upload"]), len(calls)
+    eng._upload, eng._run_step = upload, run_step
+    eng.generate(list(range(2, 25)), 10)
+    up1 = eng.stats()["upload"]
+    pops = up1["populations"] - up0["populations"]
+    assert pops == 3 + 9            # 3 chunks (the last samples), 9 T=1
+    assert len(calls) - n0 == up1["transfers"] - up0["transfers"]
+    assert pops <= len(calls) - n0 <= 2 * pops
+    assert up1["bytes"] - up0["bytes"] == sum(b for _, b in calls[n0:])

@@ -736,3 +736,56 @@ def test_dots3_steps_fit_a_v5e_and_leave_their_three_pools_in_place(
                         "window_latent_chunk_attention",
                         "moe_grouped_matmul"})
 
+
+
+@pytest.mark.parametrize("t,rows", [(1, 8), (32, 8), (32, 2), (32, 1)],
+                         ids=["t1", "t_prefill_chunk", "compact_2_rows",
+                              "compact_1_row"])
+def test_the_engines_entry_is_the_step_behind_a_few_slices(v5e, as_on_chip,
+                                                           t, rows):
+    """PR 42: the program the engine runs takes a population's lane arrays
+    as ONE int32 buffer [rows, 3 T + 5 (+ 1: a compact program's `rows`)]
+    and unpacks it in front of the step `_make_step_fn` gives (which the
+    tests above and the benchmark's tools lower by itself).  Compiled for
+    the chip it is that step: the pools donated through the outer call and
+    left where they are, the same kernels, no scratch to speak of beyond
+    the step's."""
+    from ray_tpu.inference.engine import InferenceEngine
+    cfg = gpt.GPTConfig(vocab_size=512, n_layers=4, d_model=25 * 64,
+                        n_heads=25, d_ff=256, max_seq_len=256, scan_unroll=2)
+    lanes, num_blocks, block_size = 8, 128, 16
+    compact = rows < lanes
+    arg = _arg_on(v5e[0])
+    eng = object.__new__(InferenceEngine)
+    eng.model, eng.config, eng._capture_logp = gpt, cfg, False
+    eng.backend, eng._step_impls = "tpu", {}
+    params = jax.tree.map(
+        lambda x: arg(x.shape, x.dtype),
+        jax.eval_shape(lambda k: gpt.serving_params(
+            gpt.init_params(cfg, k), cfg), jax.random.key(0)))
+    pool = arg(_pool_shape(cfg.n_layers, num_blocks, block_size, cfg.n_heads,
+                           cfg.head_dim), cfg.dtype)
+    tables = arg((lanes, cfg.max_seq_len // block_size), jnp.int32)
+    last_tok = arg((lanes,), jnp.int32)
+    step = eng._make_step_fn(False, False, compact).lower(
+        params, pool, pool, arg((rows, t), jnp.int32),
+        arg((rows, t), jnp.int32), arg((rows, t), jnp.bool_), tables,
+        arg((rows,), jnp.int32), arg((rows,), jnp.int32),
+        arg((rows,), jnp.float32), arg((rows,), jnp.uint32),
+        arg((rows,), jnp.int32),
+        *((arg((rows,), jnp.int32),) if compact else ()),
+        last_tok).compile()
+    entry = eng._make_entry(t, False, False, rows if compact else 0).lower(
+        params, pool, pool, arg((rows, 3 * t + 5 + compact), jnp.int32),
+        tables, last_tok).compile()
+    text, memory = entry.as_text(), entry.memory_analysis()
+    pool_bytes = 2 * math.prod(pool.shape)
+    assert memory.alias_size_in_bytes == 2 * pool_bytes \
+        == step.memory_analysis().alias_size_in_bytes
+    assert count_pool_copies(text, pool.shape) == 0
+    assert _kernel_names(text) == _kernel_names(step.as_text())
+    scratch = step.memory_analysis().temp_size_in_bytes
+    assert abs(memory.temp_size_in_bytes - scratch) < 256 * 1024    # of MBs
+    # one lane argument where the step has eight or nine
+    assert len(entry.input_shardings[0]) == len(step.input_shardings[0]) \
+        - (8 if compact else 7)
