@@ -88,6 +88,21 @@ both allocators (`can_admit_prefix`), and `stats()` adds `sparse` (per T=1
 step and one layer of each kind: context tokens scored, rows chosen, window
 rows attended) and `windows` (sliding blocks given back so far).
 
+A cache with state that is not rows (kv_cache.py "state": Falcon-H1's
+state-space mixer beside its attention heads) hands the step its K and V
+pools and the two state buffers as one tuple (`PagedKVCache.step_pools`); a
+lane's slot there is its index, and a compact program's rows name theirs
+(`forward_cached`'s `slots`).  A prefilling lane starts its scan from zero
+at position 0 and otherwise from what its slot holds, which at admission is
+the snapshot `adopt_prefix` copied in; padded rows and lanes that are not
+stepped pass through as the recurrence's identity.  The engine says when a
+snapshot is taken: behind the LAST whole chunk of a prompt that leaves a
+final chunk to prefill (`_snapshot_due`: the deepest block boundary another
+request with the same head can start from), by a copy program of its own
+dispatched behind that chunk's step (`PagedKVCache.snapshot`).  No
+speculative decoding over such a cache (a rejected draft's state cannot be
+rolled back); `stats()` adds `ssm`.
+
 The weights the step multiplies are prepared once, not in every step:
 `model.serving_params` (one compiled program at construction and at
 every `update_params`) holds each leaf the cached forward would cast at
@@ -332,6 +347,11 @@ class GenerationHandle:
             self._buf.extend(item)
             return self._buf.popleft()
         return item
+
+    def ready(self) -> bool:
+        """Whether the next token, or the stream's end, is here: `next()`
+        would not wait.  For the one consumer that iterates."""
+        return bool(self._buf) or not self._req.out.empty()
 
     def tokens(self, timeout: Optional[float] = None) -> List[int]:
         """Block until the request finishes; returns all generated ids.
@@ -609,6 +629,7 @@ class InferenceEngine:
         self._prefill = {"steps": 0, "lanes": 0, "rows": 0, "rows_valid": 0,
                          "ctx_rows": 0}
         self._tokens_run = 0
+        self._decode_ctx = 0        # of the iteration being built
         # What `_upload` handed to the device: populations, transfers (a
         # changed block table's copy among them) and their bytes.
         self._uploads = {"populations": 0, "transfers": 0, "bytes": 0}
@@ -645,6 +666,14 @@ class InferenceEngine:
             raise NotImplementedError(
                 "speculative decoding over layers of several kinds: a "
                 "rejected draft's sliding blocks are not rolled back yet")
+        # Over a state cache: the tokens its scans (T > 1) and its updates
+        # (T = 1) stepped over.
+        self._stateful = self.cache.state is not None
+        self._ssm = {"tokens_scanned": 0, "tokens_updated": 0}
+        if self._stateful and self.spec_k > 0:
+            raise NotImplementedError(
+                "speculative decoding over a state cache: a rejected "
+                "draft's recurrent state cannot be rolled back")
         self._thread: Optional[threading.Thread] = None
         self._stopped = False
         self._auto = auto_start
@@ -969,6 +998,10 @@ class InferenceEngine:
             # Layers of several kinds: the T=1 steps' sums for one layer of
             # each kind (`_sparse`), and the sliding kind's blocks given
             # back in mid-sequence so far.
+            # A state cache: slots of state and of snapshots, what the
+            # index did with the snapshots, and the tokens stepped over.
+            **({"ssm": {**self.cache.state_stats(), **self._ssm}}
+               if self._stateful else {}),
             **({} if self._sparse is None else {
                 "sparse": dict(self._sparse),
                 "windows": {"blocks_freed":
@@ -1043,6 +1076,10 @@ class InferenceEngine:
                                                  self.cache.pool_shape),
                 "weight_bytes_copied": count_weight_bytes_copied(
                     text, self._step_avals[key][0])}
+            if self._stateful:
+                # The state buffer too is updated where it is.
+                out[name]["state_copies"] = count_pool_copies(
+                    text, self.cache.state.shape)
         if "compile_s" in self._compact:
             compiled = self._compact["fn"].lower(
                 *self._compact["avals"]).compile()
@@ -1225,12 +1262,16 @@ class InferenceEngine:
         (collector pauses that fell inside it) and, in one iteration of
         `_CPU_EVERY`, `cpu_ms` beside `cpu_wall_ms` (the thread's CPU time
         and the wall time from `admit`'s start to `dispatch`'s end and
-        over `commit`: the blocked `fetch` left out).  The same sums go
+        over `commit`: the blocked `fetch` left out), and `decode_ctx`,
+        the tokens of context its decoding lanes held (what the T=1
+        attention read: a trace's slice is divided by the sum over its
+        own steps, not by a client's later view).  The same sums go
         into `stats()` and, by the wall-clock second, into its
         `timeline`."""
         took = dict.fromkeys(_PHASES, 0.0)         # seconds
         parts = dict.fromkeys(_PARTS, 0.0)
         gc0 = _GC["seconds"]
+        self._decode_ctx = 0
         self._dice = (self._dice * 1103515245 + 12345) & 0x7FFFFFFF
         clocked = self._dice < 0x80000000 // _CPU_EVERY
         if clocked:
@@ -1285,10 +1326,13 @@ class InferenceEngine:
                     plans.append(self._plan(parts, *pop))
             took["build_batch"] = ph.seconds
         newer = []
-        for spec, lanes, chunks, news, batch in plans:
+        for spec, lanes, chunks, news, batch, due in plans:
             vtok = spans.begin("engine", "spec_verify") if spec else None
             with spans.phase("engine", "dispatch") as ph:
                 next_tok, lps = self._run_step(batch, spec)
+                for lane, key in due:
+                    # Behind the step that leaves the state in the slot.
+                    self.cache.snapshot(lane, key)
             took["dispatch"] += ph.seconds
             newer.append((spec, vtok, lanes, chunks, news, next_tok, lps,
                           batch[3]))
@@ -1383,6 +1427,7 @@ class InferenceEngine:
                 clock = {"cpu_ms": cpu * 1e3, "cpu_wall_ms": on * 1e3}
             events.record(
                 "engine", "step", decode=len(decode), prefill=len(prefill),
+                decode_ctx=self._decode_ctx,
                 waiting=waiting, wall_ms=wall * 1e3,
                 admit_ms=took["admit"] * 1e3,
                 build_ms=took["build_batch"] * 1e3,
@@ -1460,7 +1505,8 @@ class InferenceEngine:
         """One population's step of `t` positions, built from what the step
         in flight will have left, and from here on in flight itself: per
         lane the positions it writes (`chunks`) and whether it samples a
-        token (`news`), which `_commit` takes off again.  Lanes whose next
+        token (`news`), which `_commit` takes off again; over a state cache
+        also the snapshots due behind it (`due`).  Lanes whose next
         position opens a window have the one before closed first.  The
         seconds of its three parts are added to `parts`: `windows` (the
         compaction's dispatch included), `assemble` (host arrays, tables'
@@ -1472,16 +1518,35 @@ class InferenceEngine:
             parts["windows"] += ph.seconds
         with spans.phase("engine.build_batch", "assemble") as ph:
             arrays, chunks = self._build_batch(lanes, t, prefill)
-            news = {}
+            news, due = {}, []
             for lane, req in lanes:
                 news[lane] = int(req.samples(req.next_fed, chunks[lane]))
                 req.ahead_len += chunks[lane]
                 req.ahead_new += news[lane]
+                if self._stateful and prefill:
+                    key = self._snapshot_due(lane, req)
+                    if key is not None:
+                        due.append((lane, key))
         parts["assemble"] += ph.seconds
         with spans.phase("engine.build_batch", "upload") as ph:
             batch = self._upload(arrays)
         parts["upload"] += ph.seconds
-        return spec, lanes, chunks, news, batch
+        return spec, lanes, chunks, news, batch, due
+
+    def _snapshot_due(self, lane: int, req: _Request):
+        """The chain key to snapshot `lane`'s state under behind the chunk
+        just planned, or None: the chunk ends on a block's edge, short of
+        the prompt's end, and what is left of the prompt is one chunk (the
+        last such edge of a prompt: where the longest head it can share
+        with another request ends, if that is a multiple of the chunk from
+        where this lane began).  One snapshot a prompt, not one a chunk: a
+        pool of a few slots keeps the heads that are asked for."""
+        end = int(self.cache.seq_lens[lane]) + req.ahead_len
+        left = len(req.prompt) - end
+        if req.chain is None or end % self.cache.block_size \
+                or not 0 < left <= self.prefill_chunk:
+            return None
+        return req.chain[end // self.cache.block_size - 1]
 
     def _upload(self, arrays) -> tuple:
         """A population's lane arrays (`_build_batch`) as `_run_step` takes
@@ -1572,6 +1637,9 @@ class InferenceEngine:
             self.cache.ensure_capacity(lane, start + chunk)
         fed_now = sum(chunks.values())
         self._tokens_run += fed_now
+        if self._stateful:
+            self._ssm["tokens_scanned" if t > 1 else "tokens_updated"] \
+                += fed_now
         if prefill:
             pf = self._prefill
             pf["steps"] += 1
@@ -1585,6 +1653,7 @@ class InferenceEngine:
                                   for c in ctx_lens[valid[:, 0] != 0])
         elif t == 1:
             ctx = [int(ctx_lens[lane]) for lane, _ in live]
+            self._decode_ctx = sum(ctx)
             if self._eva is not None:
                 self._eva["decode_steps"] += 1
                 self._eva["ctx_tokens"] += sum(ctx)
@@ -1623,8 +1692,8 @@ class InferenceEngine:
             fn = self._step_fns[key] = self._make_entry(*key)
             self._step_avals[key] = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
-                (self._served, self.cache.k, self.cache.v, *args, *carried))
-        out = list(fn(self._served, self.cache.k, self.cache.v, *args,
+                (self._served, *self.cache.step_pools, *args, *carried))
+        out = list(fn(self._served, *self.cache.step_pools, *args,
                       *carried))
         if compact:
             # Every lane's last token, with those this program sampled
@@ -1717,9 +1786,11 @@ class InferenceEngine:
             return jnp.take_along_axis(lp, out[..., None], axis=-1)[..., 0]
 
         n_moe = 1 if config.n_experts else 0
+        stateful = any(run.mixer is not None
+                       for run in model.spec(config).runs)
 
         def step(params, k, v, tokens, positions, valid, tables, ctx_lens,
-                 gather, temps, seeds, counters, *carried):
+                 gather, temps, seeds, counters, *carried, slots=None):
             # `carried`: the lanes' last sampled tokens, then (an expert
             # configuration's step takes them last and returns them last,
             # summed up on the device) the load counters.  A caller that
@@ -1732,9 +1803,12 @@ class InferenceEngine:
                 # A decode lane whose token the step before sampled reads
                 # it where that step left it (`_build_batch`'s -1).
                 tokens = jnp.where(tokens < 0, last_tok[:, None], tokens)
+            # (over a state cache a compact program's rows name their
+            # slots; row i is lane i otherwise)
             x, k, v, *moe_load = model.forward_cached(
                 params, tokens, positions, valid, k, v, tables, ctx_lens,
-                config, *moe_load)
+                config, *moe_load,
+                **({"slots": slots} if slots is not None else {}))
             next_tok, *logp = sample_tokens(params, x, gather, temps, seeds,
                                             counters)
             if last_tok is not None and not spec:
@@ -1752,7 +1826,8 @@ class InferenceEngine:
             next_tok, *rest = step(
                 params, k, v, tokens, positions, valid,
                 jnp.take(tables, rows, axis=0, mode="clip"), ctx_lens,
-                gather, temps, seeds, counters, mine, *moe_load)
+                gather, temps, seeds, counters, mine, *moe_load,
+                slots=rows if stateful else None)
             return (next_tok, *rest,
                     last_tok.at[rows].set(next_tok, mode="drop"))
 

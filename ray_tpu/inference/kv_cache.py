@@ -70,6 +70,31 @@ block of the prefix, and the sliding blocks that cover its last
 `slide_window` - 1 positions (`_held_by_both`); admission counts each kind
 at its own peak (`can_admit_prefix`).
 
+State that is not rows (`kind` "state": Falcon-H1's state-space mixer beside
+its attention heads).  A layer's mixer keeps of a lane a float32 state
+[heads, d_state, head_dim] and the last rows its convolution reads again,
+of a fixed size whatever the lane's length: one SLOT a lane a layer in two
+buffers of their own beside the K and V pools (`state`
+[n_layers, max_lanes + 1, heads, d_state, head_dim] and `tail`
+[n_layers, max_lanes + 1, rows x width]; a lane's slot is its index, the last slot is
+where a program sends the rows nobody has), allocated with the lane,
+overwritten by every step, never grown, given back with the lane.  The
+step takes all four buffers as one tuple (`step_pools`) and updates a slot
+in place (ops/ssm.py; `count_pool_copies` of the state's shape over the
+compiled step must be 0 too).  A prefix of sealed K/V blocks is worthless
+without the state at its end, so the prefix index holds SNAPSHOTS beside
+its blocks: a pool of snapshot slots (`snaps`, `snap_tails`) under the chain
+key of the block a snapshot stands behind, refcounted and evicted least
+recently used like blocks (`snap_allocator`), and dropped with the block of
+their key.  The engine says when one is taken (`snapshot`: a copy of the
+lane's slot, dispatched behind the prefill step that left the state there);
+the index serves a match of m blocks only where the K/V blocks AND a
+snapshot at m exist (`_held_with_state`), and adopting it copies the
+snapshot into the lane's slot ahead of the lane's first step
+(`adopt_prefix`).  The wire format carries the snapshot beside the blocks
+(`more`), a cache installs only its own kind, no spill tier is attached, and
+a lane is never truncated: a state cannot be rolled back.
+
 Prefix caching (content-addressed block sharing): a block that has been
 completely written ("sealed") is indexed by a hash chain over
 (parent_hash, block_tokens) — the chain hash of a block is a function of
@@ -323,6 +348,25 @@ def count_weight_bytes_copied(hlo_text: str, weights) -> Dict[str, int]:
     return dict(out)
 
 
+def _copy_slot(dst_state, dst_tail, src_state, src_tail, src, dst):
+    """One slot of a state cache copied into another buffer, every layer:
+    dst[:, dst] = src[:, src] for the state and the tail (a snapshot taken
+    or adopted; `dst` out of range: nothing is written).  One
+    `dynamic_update_slice` a buffer, so that the donated destination stays
+    where it is."""
+    with jax.named_scope("ssm_snapshot"):
+        live = (dst >= 0) & (dst < dst_state.shape[1])
+        at = jnp.clip(dst, 0, dst_state.shape[1] - 1)
+        out = []
+        for into, frm in ((dst_state, src_state), (dst_tail, src_tail)):
+            new = jax.lax.dynamic_slice_in_dim(frm, src, 1, axis=1)
+            old = jax.lax.dynamic_slice_in_dim(into, at, 1, axis=1)
+            out.append(jax.lax.dynamic_update_slice_in_dim(
+                into, jnp.where(live, new.astype(into.dtype), old), at,
+                axis=1))
+        return tuple(out)
+
+
 class BlockAllocator:
     """Refcounted free-list over pool block ids.
 
@@ -442,7 +486,8 @@ class PagedKVCache:
                  prefix_cache: bool = True, latent: bool = False,
                  window: int = 0, chunk: int = 0, extra: tuple = (),
                  slide: Optional[Tuple[int, int, int, int]] = None,
-                 slide_blocks: Optional[int] = None, ahead: int = 2):
+                 slide_blocks: Optional[int] = None, ahead: int = 2,
+                 state=None, snapshots: Optional[int] = None):
         self.block_size = block_size
         self.max_lanes = max_lanes
         self.max_seq_len = max_seq_len
@@ -474,7 +519,8 @@ class PagedKVCache:
         # kv_heads 1, head_dim the latent and its rotated key together) one
         # latent row in the one pool.  `k` is that pool, `v` None.
         self.kind = ("layered" if extra or slide else "latent" if latent
-                     else "windowed" if window else "kv")
+                     else "windowed" if window else "state" if state
+                     else "kv")
         # The stored layout (module docstring): rows of W columns.
         shape = (n_layers, num_blocks, block_size,
                  kv_row_width(kv_heads, head_dim))
@@ -545,6 +591,44 @@ class PagedKVCache:
         # blocks move here instead of being destroyed, and the match /
         # adopt path restores them on hit (the SPILLED index state).
         self.tier = None
+        # State that is not rows (module docstring): `state` is what the
+        # model's mixer keeps of a lane (`decoder.StateRows`).
+        self.state = self.tail = self.snaps = self.snap_tails = None
+        if state is not None:
+            if latent or window:
+                raise ValueError("a state cache: beside K and V pools only")
+            if snapshots is None:
+                snapshots = max(2, max_lanes // 4)
+            snapshots = int(snapshots) if prefix_cache else 0
+            one = (state.heads, state.d_state, state.head_dim)
+            # (a tail's K - 1 rows one behind the other in ONE row of its
+            # slot: as [slots, 3, width] the compiler lays the three rows
+            # out one way for a program of all lanes and another for a
+            # program of one, and re-lays the buffer at a program's two
+            # ends: 5% of a decode step, PERF.md section 6, PR 43)
+            row = ((state.conv - 1) * state.conv_width,)
+            self.state = jnp.zeros((n_layers, max_lanes + 1) + one,
+                                   jnp.float32)
+            self.tail = jnp.zeros((n_layers, max_lanes + 1) + row, dtype)
+            self.snaps = jnp.zeros((n_layers, max(snapshots, 1)) + one,
+                                   jnp.float32)
+            self.snap_tails = jnp.zeros((n_layers, max(snapshots, 1)) + row,
+                                        dtype)
+            self.snapshot_slots = snapshots
+            self.snap_allocator = BlockAllocator(
+                max(snapshots, 1), on_evict=self._on_snap_evict)
+            self._snap_index: Dict[Tuple, int] = {}
+            self._snap_key: Dict[int, Tuple] = {}
+            # blocks the last match found and could serve none of
+            self._unserved = 0
+            self.stats.update(snapshots_taken=0, snapshots_adopted=0,
+                              snapshot_misses=0)
+            donate = () if jax.default_backend() == "cpu" else (0, 1)
+            self._copy_slot = jax.jit(_copy_slot, donate_argnums=donate)
+            # Both directions made now, by a copy to nowhere: neither is
+            # made under a request that waits.
+            self._move(-1, 0, take=True)
+            self._move(0, -1, take=False)
 
     def attach_tier(self, tier) -> None:
         """Attach a spill tier (duck-typed: contains/put/pop/discard/
@@ -554,6 +638,10 @@ class PagedKVCache:
             raise NotImplementedError(
                 "a spill tier under layers of several kinds: a spilled "
                 "block would have to carry every kind's rows (ROADMAP.md)")
+        if self.kind == "state":
+            raise NotImplementedError(
+                "a spill tier under a state cache: a spilled chain would "
+                "have to carry its snapshots (ROADMAP.md)")
         self.tier = tier
 
     @classmethod
@@ -563,6 +651,15 @@ class PagedKVCache:
         kw.setdefault("max_seq_len", config.max_seq_len)
         kw.setdefault("dtype", config.dtype)
         spec = model.spec(config)
+        mixers = [run.mixer for run in spec.runs if run.mixer is not None]
+        if mixers:
+            if len(spec.runs) != 1:
+                raise NotImplementedError(
+                    "a state cache: one run of layers, each with the mixer")
+            kw["state"] = mixers[0].state(config)
+            if isinstance(kw.get("num_blocks"), (tuple, list)):
+                # (the K/V blocks, the snapshot slots)
+                kw["num_blocks"], kw["snapshots"] = kw["num_blocks"]
         if isinstance(kw.get("num_blocks"), (tuple, list)):
             # (the growing kind's blocks, the sliding kind's)
             kw["num_blocks"], kw["slide_blocks"] = kw["num_blocks"]
@@ -739,7 +836,20 @@ class PagedKVCache:
             if entry is None:
                 break
             out.append(entry)
+        if self.state is not None:
+            held = self._held_with_state(out)
+            self._unserved = 0 if held else len(out)
+            return held
         return self._held_by_both(out) if self.slide_window else out
+
+    def _held_with_state(self, entries: List[Tuple]) -> List[Tuple]:
+        """The longest head of a matched chain of K/V blocks behind which a
+        snapshot of the state stands: blocks past it are worth nothing to a
+        lane that cannot start its recurrence there."""
+        for m in range(len(entries), 0, -1):
+            if entries[m - 1][1] in self._snap_index:
+                return entries[:m]
+        return []
 
     def _held_by_both(self, entries: List[Tuple]) -> List[Tuple]:
         """The longest head of a matched chain of the growing kind that the
@@ -800,6 +910,8 @@ class PagedKVCache:
             raise ValueError(f"prompt of {len(tokens)} exceeds max_seq_len "
                              f"{self.max_seq_len}")
         entries = self._match_chain(tokens, keys)
+        if self.state is not None and self._unserved:
+            self.stats["snapshot_misses"] += 1    # blocks, and no snapshot
         # Pop spilled payloads out of the tier FIRST: once held here,
         # the allocations below can spill other blocks into the tier
         # without LRU pressure dropping the very chain being restored.
@@ -877,6 +989,14 @@ class PagedKVCache:
                 self.slide_allocator.incref(block)
                 held[i] = block
                 self.block_tables[lane, self.max_blocks_per_seq + i] = block
+        if cached and self.state is not None:
+            # The snapshot behind the last adopted block into the lane's
+            # slot, ahead of the lane's first step (and most recently used).
+            slot = self._snap_index[entries[-1][1]]
+            self.snap_allocator.incref(slot)
+            self._move(slot, lane, take=False)
+            self.snap_allocator.decref(slot)
+            self.stats["snapshots_adopted"] += 1
         if cached:
             # The chain cursor at the sealed boundary, so blocks sealed
             # later extend the same chain: the hash of the last key.
@@ -957,6 +1077,11 @@ class PagedKVCache:
         key = self._block_key.pop(block, None)
         if key is not None and self._index.get(key) == block:
             del self._index[key]
+            if self.state is not None and key in self._snap_index:
+                # A snapshot goes with the block it stands behind.
+                slot = self._snap_index.pop(key)
+                del self._snap_key[slot]
+                self.snap_allocator.uncache(slot)
             if self.tier is not None:
                 k_np, v_np = self.read_blocks(
                     jnp.asarray([block], jnp.int32))
@@ -1003,6 +1128,15 @@ class PagedKVCache:
                     "k": self.read_blocks(idx, 0), "v_pool": None,
                     "more": more}
         k_np, v_np = self.read_blocks(idx)
+        if self.state is not None:
+            # The chain ends where a snapshot stands (`_match_chain`): it
+            # goes with the blocks.
+            slot = self._snap_index[entries[-1][0]]
+            return {"v": 1, "kind": self.kind,
+                    "block_size": self.block_size, "chain": chain,
+                    "k": k_np, "v_pool": v_np,
+                    "more": {"state": np.asarray(self.snaps[:, slot]),
+                             "tail": np.asarray(self.snap_tails[:, slot])}}
         if self.window:
             # The kind of each block by the length of its chain entry: a
             # summary block carries its whole window's tokens (every block
@@ -1042,6 +1176,8 @@ class PagedKVCache:
             return 0            # foreign model shape: refuse quietly
         if self.kind == "layered":
             return self._install_layered(payload)
+        if self.state is not None and not self._install_snapshot(payload):
+            return 0            # blocks behind no snapshot serve nobody
         parent = _ROOT_HASH
         new = []                # (chain_pos, key, block)
         bs, part = self.block_size, 0
@@ -1086,6 +1222,37 @@ class PagedKVCache:
             self.allocator.decref(b)
             self.stats["imported_blocks"] += 1
         return len(new)
+
+    def _install_snapshot(self, payload: dict) -> bool:
+        """The snapshot a state cache's payload carries, indexed behind the
+        last block of its chain; False where it carries none of this
+        cache's shape or no snapshot slot can be had."""
+        more = payload.get("more") or {}
+        state, tail = more.get("state"), more.get("tail")
+        if state is None or tail is None or not self.snapshot_slots \
+                or tuple(state.shape) != self.snaps.shape[:1] \
+                + self.snaps.shape[2:] \
+                or tuple(tail.shape) != self.snap_tails.shape[:1] \
+                + self.snap_tails.shape[2:]:
+            return False
+        key, parent = None, _ROOT_HASH
+        for blk_tokens in payload["chain"]:
+            key = (parent, tuple(int(t) for t in blk_tokens))
+            parent = hash(key)
+        if key is None:
+            return False
+        if key in self._snap_index:
+            return True
+        try:
+            (slot,) = self.snap_allocator.alloc(1)
+        except RuntimeError:
+            return False
+        self.snaps = self.snaps.at[:, slot].set(
+            jnp.asarray(state, self.snaps.dtype))
+        self.snap_tails = self.snap_tails.at[:, slot].set(
+            jnp.asarray(tail, self.snap_tails.dtype))
+        self._index_snapshot(key, slot)
+        return True
 
     def _install_layered(self, payload: dict) -> int:
         """`install_prefix` for layers of several kinds: the growing kind's
@@ -1278,6 +1445,72 @@ class PagedKVCache:
         held = self.allocator.num_blocks - self.allocator.num_unused
         return len(summary), held - len(summary)
 
+    # ---------------- state that is not rows ----------------
+
+    def _move(self, slot: int, lane: int, take: bool) -> None:
+        """Dispatch the copy of `lane`'s slot into snapshot slot `slot`
+        (`take`) or the other way round, every layer, state and tail.  The
+        device runs programs in dispatch order: a snapshot is taken behind
+        the step that left the state in the lane's slot, and adopted ahead
+        of the lane's first step."""
+        src, dst = jnp.int32(lane if take else slot), jnp.int32(
+            slot if take else lane)
+        if take:
+            self.snaps, self.snap_tails = self._copy_slot(
+                self.snaps, self.snap_tails, self.state, self.tail, src, dst)
+        else:
+            self.state, self.tail = self._copy_slot(
+                self.state, self.tail, self.snaps, self.snap_tails, src, dst)
+
+    def snapshot(self, lane: int, key: Tuple) -> bool:
+        """Keep the state `lane`'s slot holds (once the programs dispatched
+        so far have run) as the snapshot behind the block of chain key
+        `key`: a slot from the snapshot pool, the least recently used
+        unreferenced one if none is free, indexed at once (whoever adopts
+        it reads it by a program dispatched later).  False where the index
+        has one under `key` already, or no slot can be had."""
+        if self.state is None or not self.snapshot_slots \
+                or key in self._snap_index:
+            return False
+        try:
+            (slot,) = self.snap_allocator.alloc(1)
+        except RuntimeError:
+            return False
+        self._move(slot, lane, take=True)
+        self._index_snapshot(key, slot)
+        self.stats["snapshots_taken"] += 1
+        return True
+
+    def _index_snapshot(self, key: Tuple, slot: int) -> None:
+        """`slot` (held at refcount 1) into the index under `key`, parked
+        evictable."""
+        self._snap_index[key] = slot
+        self._snap_key[slot] = key
+        self.snap_allocator.mark_cached(slot)
+        self.snap_allocator.decref(slot)
+
+    def _on_snap_evict(self, slot: int) -> None:
+        key = self._snap_key.pop(slot, None)
+        if key is not None and self._snap_index.get(key) == slot:
+            del self._snap_index[key]
+
+    def state_stats(self) -> dict:
+        """Slots of state and of snapshots, and what the index did with the
+        latter (engine `stats()["ssm"]`)."""
+        alloc = self.snap_allocator
+        return {
+            "state_slots": self.max_lanes,
+            "state_slots_live": sum(bool(b) for b in self._lane_blocks),
+            "state_bytes": int(self.state.nbytes + self.tail.nbytes),
+            "snapshot_slots": self.snapshot_slots,
+            "snapshot_slots_live": len(self._snap_index),
+            "snapshot_bytes": int(self.snaps.nbytes + self.snap_tails.nbytes),
+            "snapshots_evicted": alloc.evictions,
+            "snapshots_taken": self.stats["snapshots_taken"],
+            "snapshots_adopted": self.stats["snapshots_adopted"],
+            "snapshot_misses": self.stats["snapshot_misses"],
+        }
+
     # ---------------- lane growth / teardown ----------------
 
     def ensure_capacity(self, lane: int, new_len: int) -> None:
@@ -1326,6 +1559,11 @@ class PagedKVCache:
         block with the others.  The device runs programs in dispatch
         order, so whoever gets the block next writes a position before it
         reads it."""
+        if self.state is not None:
+            raise NotImplementedError(
+                "a state cache does not truncate a lane: the recurrent state "
+                "has been overwritten past the new length and cannot be "
+                "rolled back")
         blocks = self._lane_blocks[lane]
         keep = max(self.blocks_needed(new_len), self._lane_sealed[lane]
                    - self._lane_closed[lane] * self._shrink)
@@ -1377,9 +1615,20 @@ class PagedKVCache:
             self._dev_tables = jnp.asarray(self.block_tables.copy())
         return self._dev_tables
 
+    @property
+    def step_pools(self) -> tuple:
+        """(k, v) as a step takes and returns them: the pools, or over a
+        state cache ((K, V, state, tail), None), the model's mixer taking
+        the buffers behind its attention's."""
+        if self.state is not None:
+            return (self.k, self.v, self.state, self.tail), None
+        return self.k, self.v
+
     def update_pools(self, k: jax.Array, v: Optional[jax.Array]) -> None:
         """Rebind the functional pools returned by a jitted step (`v` None
-        where the cache is latent)."""
+        where the cache is latent, and over a state cache: `step_pools`)."""
+        if self.state is not None:
+            k, v, self.state, self.tail = k
         self.k = k
         self.v = v
 

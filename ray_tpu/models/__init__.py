@@ -1,9 +1,10 @@
-"""Model families.  The LM families (gpt, llama, axk1, evabyte, dots3) are specs of one decoder
+"""Model families.  The LM families (gpt, llama, axk1, evabyte, dots3,
+falconh1) are specs of one decoder
 (models/decoder.py); `family` is where a name becomes one."""
 
 import importlib
 
-LM_FAMILIES = ("gpt", "llama", "axk1", "evabyte", "dots3")
+LM_FAMILIES = ("gpt", "llama", "axk1", "evabyte", "dots3", "falconh1")
 
 
 def family(model):

@@ -1,7 +1,7 @@
 """The decoder-only transformer, defined once for every LM family.
 
 A family (models/gpt.py, models/llama.py, models/axk1.py,
-models/evabyte.py, models/dots3.py) is a config
+models/evabyte.py, models/dots3.py, models/falconh1.py) is a config
 dataclass, its parameter format (`init_params`, `param_specs`) and
 `spec(config)`: a `Spec` naming the parts its block is made of (norms, an
 `Attention`, a `FeedForward`, a leading run of layers with another
@@ -31,6 +31,12 @@ Design (no reference counterpart: Ray hosts models, it doesn't ship them):
     learned indexer chooses, with a gate a head.
     `EVA`: an exact window beside one summary row for every chunk behind
     it, `HEADS`'s cached form over a table whose rows are not one a token);
+  * a run of layers may have a second mixer BESIDE its attention (`Mixer`;
+    `SSM`: Mamba-2's state-space mixer, ops/ssm.py): both read the block's
+    one normed input and both results are added to the residual stream,
+    each path by its own factor where the model states them
+    (`Multipliers`).  Over a cache the mixer's state is not rows: a
+    fixed-size slot a lane, overwritten by every step;
   * `jax.checkpoint` (remat) on the block when configured: trades FLOPs for
     HBM, the standard TPU memory lever.
 
@@ -76,6 +82,11 @@ def rmsnorm(x, scale, eps, unit_offset: bool = False, dtype=None):
     if unit_offset:
         scale = 1.0 + scale.astype(jnp.float32)
     return (y * scale).astype(dtype or x.dtype)
+
+
+def _scaled(x, factor):
+    """x times a model's stated factor, in x's dtype (1: x itself)."""
+    return x if factor == 1.0 else x * jnp.asarray(factor, x.dtype)
 
 
 def rope(x, theta: float, offset=0, freqs=None):
@@ -157,6 +168,20 @@ def swiglu_mlp(h, p, config, mesh=None, valid=None):
     hidden = with_logical_constraint(gate * up, ("batch", "length", "mlp"),
                                      mesh=mesh)
     return _down(hidden, p), None, None
+
+
+def scaled_swiglu_mlp(h, p, config, mesh=None, valid=None):
+    """`swiglu_mlp` with the two factors a model states on it
+    (`config.mlp_multipliers`): one on the gate's projection, inside the
+    SiLU, one on the result."""
+    m_gate, m_down = config.mlp_multipliers
+    gate = jnp.einsum("bld,df->blf", h, p["w_gate"].astype(h.dtype))
+    gate = jax.nn.silu(gate * jnp.asarray(m_gate, gate.dtype))
+    up = jnp.einsum("bld,df->blf", h, p["w_up"].astype(h.dtype))
+    hidden = with_logical_constraint(gate * up, ("batch", "length", "mlp"),
+                                     mesh=mesh)
+    y = _down(hidden, p)
+    return y * jnp.asarray(m_down, y.dtype), None, None
 
 
 def moe_ffn(h, p, config, mesh=None, valid=None):
@@ -271,6 +296,8 @@ class FeedForward:
 
 GELU = FeedForward(gelu_mlp, cast=("w_up", "w_down"))
 SWIGLU = FeedForward(swiglu_mlp, cast=("w_gate", "w_up", "w_down"))
+SCALED_SWIGLU = FeedForward(scaled_swiglu_mlp,
+                            cast=("w_gate", "w_up", "w_down"))
 EXPERTS = FeedForward(moe_ffn, whole=("w_gate", "w_up", "w_down"),
                       trains=False)
 SHARED_EXPERTS = FeedForward(shared_moe_ffn,
@@ -302,6 +329,8 @@ def _qkv(spec: Spec, h, p):
                            spec.qk_norm)
             return flat.reshape(x.shape)
         q, k = norm(q, p["q_norm"]), norm(k, p["k_norm"])
+    if spec.mult is not None:
+        k = _scaled(k, spec.mult.key)
     return q, k, v
 
 
@@ -689,6 +718,193 @@ EVA = Attention(eva_attention, eva_attention_cached,
                 trains=False)
 
 
+# --------------------------------------------------------------------------
+# Parts: a second mixer beside the attention.  `apply(h, p, config)` on
+# normed h [B, L, D] is the mixer over a whole sequence from its zero
+# state, projected back to [B, L, D]; `cached(h, pools, p, config, slots,
+# positions, valid)` continues each row's state in the mixer's buffers
+# (`pools`: what `Mixer.state` describes, a slot a lane) at
+# `p["cache_layer"]`, overwrites it with the state behind the slice's last
+# valid token and returns ([B, T, D], pools).
+# --------------------------------------------------------------------------
+
+@dataclasses.dataclass(frozen=True)
+class StateRows:
+    """What a mixer keeps of a lane between steps, as the cache manager
+    needs to know it (`PagedKVCache.for_model`): a float32 state
+    [heads, d_state, head_dim] and the last `conv - 1` rows of `conv_width`
+    columns that its convolution reads again, a layer each."""
+    heads: int
+    head_dim: int
+    d_state: int
+    conv: int
+    conv_width: int
+
+
+def _ssm_split(h, p, config):
+    """The state-space mixer's projection of normed h [B, L, D]: the gate z
+    [B, L, d_ssm], the convolution's input [x | B | C] [B, L, d_ssm + 2 G N]
+    and dt [B, L, H], each of the five segments times its factor."""
+    c = config
+    d_ssm, gn = c.ssm_heads * c.ssm_head_dim, c.ssm_groups * c.ssm_state
+    mz, mx, mb, mc, mdt = c.ssm_multipliers
+    proj = jnp.einsum("bld,de->ble", h, p["w_in"].astype(h.dtype))
+    scale = np.concatenate([np.full(d_ssm, mx), np.full(gn, mb),
+                            np.full(gn, mc)]).astype(np.float32)
+    xbc = proj[..., d_ssm:2 * d_ssm + 2 * gn]
+    if (scale != 1.0).any():
+        xbc = xbc * jnp.asarray(scale, xbc.dtype)
+    return (_scaled(proj[..., :d_ssm], mz), xbc,
+            _scaled(proj[..., 2 * d_ssm + 2 * gn:], mdt))
+
+
+def _ssm_heads(xbc, dt, p, config, valid=None):
+    """The convolved [x | B | C] taken apart (x [B, T, H, P], B and C
+    [B, T, G, N]), dt = softplus(dt + dt_bias) [B, T, H] in float32 (0 at a
+    row that is not `valid`: the recurrence's identity) and A = -exp(A_log)
+    [H]."""
+    c = config
+    b, t = xbc.shape[:2]
+    d_ssm, gn = c.ssm_heads * c.ssm_head_dim, c.ssm_groups * c.ssm_state
+    x = xbc[..., :d_ssm].reshape(b, t, c.ssm_heads, c.ssm_head_dim)
+    bm = xbc[..., d_ssm:d_ssm + gn].reshape(b, t, c.ssm_groups, c.ssm_state)
+    cm = xbc[..., d_ssm + gn:].reshape(b, t, c.ssm_groups, c.ssm_state)
+    dt = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
+    if valid is not None:
+        dt = dt * valid[..., None]
+    return x, bm, cm, dt, -jnp.exp(p["A_log"].astype(jnp.float32))
+
+
+def _ssm_out(y, x, z, p, config):
+    """Behind the recurrence: the skip `D x`, the gate (`y silu(z)`, then
+    the norm: Mamba-2's `norm_before_gate` false), an RMSNorm over each
+    group's columns with one learned scale, and the projection back."""
+    c = config
+    b, t = z.shape[:2]
+    y = y + p["D"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    y = y.reshape(b, t, -1) * jax.nn.silu(z.astype(jnp.float32))
+    y = rmsnorm(y.reshape(b, t, c.ssm_groups, -1), 1.0, c.norm_eps)
+    y = (y.reshape(b, t, -1) * p["ssm_norm"]).astype(z.dtype)
+    return jnp.einsum("ble,ed->bld", y, p["w_out"].astype(z.dtype))
+
+
+def ssm_mixer(h, p, config):
+    """Mamba-2's mixer over a whole sequence from the zero state
+    (ops/ssm.py has the recurrence)."""
+    from ray_tpu.ops import ssm
+
+    c = config
+    z, xbc, dt = _ssm_split(h, p, c)
+    b, t, width = xbc.shape
+    xbc, _ = ssm.conv_tail(xbc, jnp.zeros((b, c.ssm_conv - 1, width),
+                                          xbc.dtype),
+                           p["conv_w"], p["conv_b"],
+                           jnp.full((b,), t, jnp.int32))
+    x, bm, cm, dt, a = _ssm_heads(xbc, dt, p, c)
+    y, _ = ssm.ssm_sequence(x, dt, a, bm, cm, chunk=c.ssm_chunk)
+    return _ssm_out(y, x, z, p, c)
+
+
+def _slot_rows(buffer, layer, slots, b: int, new=None):
+    """Rows `slots` [B] (None: row i's is slot i) of a slotted buffer
+    [L, S, W] at `layer`, or with `new` [B, W] the buffer with those rows
+    written: as `dynamic_update_slice`s, one for all rows or one a row,
+    which leave the loop-carried buffer where it is (a scatter copied all
+    of it in every layer; PERF.md section 6, PR 43)."""
+    zero = jnp.zeros((), jnp.int32)
+    width = buffer.shape[2]
+    if slots is None:
+        if new is None:
+            return jax.lax.dynamic_slice(buffer, (layer, zero, zero),
+                                         (1, b, width))[0]
+        return jax.lax.dynamic_update_slice(buffer, new[None],
+                                            (layer, zero, zero))
+    if new is None:
+        # (a row at a time too: `buffer[layer, slots]` slices the whole
+        # layer out first)
+        return jnp.concatenate([jax.lax.dynamic_slice(
+            buffer, (layer, slots[i], zero), (1, 1, width))[0]
+            for i in range(b)])
+    return jax.lax.fori_loop(
+        0, b, lambda i, buf: jax.lax.dynamic_update_slice(
+            buf, jax.lax.dynamic_index_in_dim(new, i)[None],
+            (layer, slots[i], zero)), buffer, unroll=True)
+
+
+def ssm_mixer_cached(h, pools, p, config, slots, positions, valid):
+    """The mixer over a slice, continued from each row's slot (`slots` [B];
+    None: row i's is slot i) of the two buffers `pools` = (state [L, S, H, N, P] float32, tail
+    [L, S, (K - 1) C]: a slot's K - 1 rows one behind the other) at `p["cache_layer"]`: a row whose slice starts at
+    position 0 starts from nothing, every other from what its slot holds
+    (what the step before left there, or a snapshot the engine copied in),
+    and the slot is left holding the state and the convolution's tail
+    behind the row's last valid token; a row with no valid token leaves its
+    slot as it was.  One token a row is the update (`ops.ssm_update`), more
+    the chunked scan (`ops.ssm_scan`)."""
+    from ray_tpu.ops import ssm
+
+    c = config
+    state, tails = pools
+    layer = p["cache_layer"]
+    z, xbc, dt = _ssm_split(h, p, c)
+    # (a row nobody has also stands at position 0: it starts nothing)
+    fresh = (positions[:, 0] == 0) & valid[:, 0]
+    with jax.named_scope("ssm_conv"):
+        b = h.shape[0]
+        tail = jnp.where(fresh[:, None, None], 0, _slot_rows(
+            tails, layer, slots, b).reshape(b, c.ssm_conv - 1, -1))
+        xbc, tail = ssm.conv_tail(xbc, tail, p["conv_w"], p["conv_b"],
+                                  jnp.sum(valid, axis=1, dtype=jnp.int32))
+        tails = _slot_rows(tails, layer, slots, b, tail.reshape(b, -1))
+    if slots is None:
+        slots = jnp.arange(h.shape[0], dtype=jnp.int32)
+    x, bm, cm, dt, a = _ssm_heads(xbc, dt, p, c, valid)
+    if h.shape[1] == 1:
+        with jax.named_scope("ssm_update"):
+            # (a decode token never stands at position 0)
+            y, state = ssm.ssm_update(state, x[:, 0], dt[:, 0], a, bm[:, 0],
+                                      cm[:, 0], slots, layer)
+            y = y[:, None]
+    else:
+        with jax.named_scope("ssm_scan"):
+            y, state = ssm.ssm_scan(state, x, dt, a, bm, cm, slots, fresh,
+                                    layer, chunk=c.ssm_chunk)
+    return _ssm_out(y, x, z, p, c), (state, tails)
+
+
+@dataclasses.dataclass(frozen=True)
+class Mixer:
+    apply: Callable
+    cached: Callable
+    state: Callable         # config -> StateRows
+    # Leaves `serving_params` holds in the activation dtype.
+    cast: tuple = ()
+
+
+SSM = Mixer(ssm_mixer, ssm_mixer_cached,
+            state=lambda c: StateRows(
+                c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_conv,
+                c.ssm_heads * c.ssm_head_dim
+                + 2 * c.ssm_groups * c.ssm_state),
+            cast=("w_in", "w_out"))
+
+
+@dataclasses.dataclass(frozen=True)
+class Multipliers:
+    """The factors a model states on its paths (Falcon-H1's, from its
+    maximal-update parametrisation): on the embedding, on the logits, on
+    the keys, on the input and the output of the attention and of the
+    mixer beside it.  (The feed-forward's two are its own:
+    `scaled_swiglu_mlp`; the mixer's five segments': `_ssm_split`.)"""
+    embedding: float = 1.0
+    lm_head: float = 1.0
+    key: float = 1.0
+    attn_in: float = 1.0
+    attn_out: float = 1.0
+    mixer_in: float = 1.0
+    mixer_out: float = 1.0
+
+
 @dataclasses.dataclass(frozen=True)
 class Run:
     """A run of like layers in a model's stack: its feed-forward, its
@@ -708,6 +924,9 @@ class Run:
     # many equal parts) of the block tables' columns: `None` is all.
     pools: Optional[tuple] = None
     table: Optional[tuple] = None
+    # A second mixer beside the attention, on the same normed input; over
+    # a cache its buffers follow the attention's pools.
+    mixer: Optional[Mixer] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -748,6 +967,8 @@ class Spec:
     # and that of the logits where the head's product is kept wider.
     residual_dtype: Optional[Any] = None
     logits_dtype: Optional[Any] = None
+    # The factors a model states on its paths; None: none anywhere.
+    mult: Optional[Multipliers] = None
 
 
 # --------------------------------------------------------------------------
@@ -760,8 +981,14 @@ def _norm(spec: Spec, x, p, leaves):
 
 def _block(x, p, spec: Spec, run: Run, config, mesh, position_offset=0):
     c = config
+    m = spec.mult or Multipliers()
     h = _norm(spec, x, p, spec.attn_norm)
-    x = x + run.attn.apply(h, p, spec, run.sizes or c, mesh, position_offset)
+    x = x + _scaled(run.attn.apply(_scaled(h, m.attn_in), p, spec,
+                                   run.sizes or c, mesh, position_offset),
+                    m.attn_out)
+    if run.mixer is not None:
+        x = x + _scaled(run.mixer.apply(_scaled(h, m.mixer_in), p, c),
+                        m.mixer_out)
 
     h = _norm(spec, x, p, spec.mlp_norm)
     y, aux, _ = run.ffn.apply(h, p, c, mesh)
@@ -773,17 +1000,29 @@ def _block(x, p, spec: Spec, run: Run, config, mesh, position_offset=0):
 
 
 def _block_cached(x, pools, p, spec: Spec, run: Run, config,
-                  block_tables, positions, valid, ctx_lens):
+                  block_tables, positions, valid, ctx_lens, slots=None):
     """One block of a run over a paged cache: what the slice's tokens leave
     there is written into the run's whole pools at `p["cache_layer"]`, then
     attention runs over its kind's block table in the same buffers
     (`Attention.cached`).  x [B, T, D]; positions [B, T] absolute; ctx_lens
-    [B] = context length including this slice.  Returns (x, pools, the
-    expert layer's load or None)."""
+    [B] = context length including this slice.  A run with a mixer beside
+    its attention hands that the pools behind the attention's and each
+    row's slot in them (`slots` [B]).  Returns (x, pools, the expert
+    layer's load or None)."""
+    m = spec.mult or Multipliers()
     h = _norm(spec, x, p, spec.attn_norm)
-    attn, pools = run.attn.cached(h, pools, p, spec, run.sizes or config,
-                                  block_tables, positions, valid, ctx_lens)
-    x = x + attn
+    n = run.attn.pools if run.mixer is not None else len(pools)
+    attn, rows = run.attn.cached(_scaled(h, m.attn_in), pools[:n], p, spec,
+                                 run.sizes or config, block_tables,
+                                 positions, valid, ctx_lens)
+    x = x + _scaled(attn, m.attn_out)
+    if run.mixer is not None:
+        y, state = run.mixer.cached(_scaled(h, m.mixer_in), pools[n:], p,
+                                    config, slots, positions, valid)
+        x = x + _scaled(y, m.mixer_out)
+        pools = (*rows, *state)
+    else:
+        pools = rows
 
     h = _norm(spec, x, p, spec.mlp_norm)
     y, _, load = run.ffn.apply(h, p, config, valid=valid)
@@ -842,6 +1081,8 @@ def forward_trunk(family, params: dict, tokens: jax.Array, config,
         pos = jax.lax.dynamic_slice_in_dim(params["pos_embed"],
                                            position_offset, tokens.shape[1])
         x = x + pos[None].astype(c.dtype)
+    if spec.mult is not None:
+        x = _scaled(x, spec.mult.embedding)
     if spec.residual_dtype is not None:
         x = x.astype(spec.residual_dtype)
     x = with_logical_constraint(x, ("batch", "length", "act_embed"), mesh=mesh)
@@ -883,8 +1124,11 @@ def lm_head(family, params: dict, x: jax.Array, config) -> jax.Array:
     spec = family(config)
     head = _head(spec, params, config)
     if spec.logits_dtype is not None:
-        return jnp.dot(x, head, preferred_element_type=spec.logits_dtype)
-    return x @ head
+        logits = jnp.dot(x, head, preferred_element_type=spec.logits_dtype)
+    else:
+        logits = x @ head
+    return logits if spec.mult is None else _scaled(logits,
+                                                    spec.mult.lm_head)
 
 
 def forward(family, params: dict, tokens: jax.Array, config, mesh=None,
@@ -928,6 +1172,10 @@ def loss_fn(family, params: dict, batch: dict, config, mesh=None):
             "this attention has no train path yet: latent attention is "
             "served absorbed, EVA's whole-sequence form is plain XLA "
             "without a backward pass of its own (ROADMAP.md)")
+    if any(run.mixer is not None for run in runs):
+        raise NotImplementedError(
+            "a state-space mixer has no train path yet: its chunked scan "
+            "(ops/ssm.py) has no backward pass of its own (ROADMAP.md)")
     tokens = batch["tokens"]
     targets = jnp.roll(tokens, -1, axis=1)
     # Last position predicts the rolled-around token 0: always masked.
@@ -1027,7 +1275,8 @@ def serving_params(family, params: dict, config) -> dict:
     spec = family(config)
     runs = _stacks(spec, config)
     cast = ("tok_embed", "pos_embed", "lm_head") + tuple(
-        name for run in runs for name in run.attn.cast + run.ffn.cast)
+        name for run in runs for name in run.attn.cast + run.ffn.cast
+        + (run.mixer.cast if run.mixer is not None else ()))
     # stack -> (its attention's absorbed leaf, the width it splits at)
     absorbed = {run.blocks: (run.attn.absorbed, (
         run.sizes or config).qk_nope_head_dim)
@@ -1074,7 +1323,7 @@ def forward_cached(family, params: dict, tokens: jax.Array,
                    positions: jax.Array, valid: jax.Array,
                    k_pool: jax.Array, v_pool: Optional[jax.Array],
                    block_tables: jax.Array, ctx_lens: jax.Array, config,
-                   moe_load=None):
+                   moe_load=None, slots=None):
     """Cached (incremental) trunk for autoregressive decode/prefill.
 
     tokens [B, T] is a SLICE of each lane's sequence at absolute
@@ -1103,7 +1352,12 @@ def forward_cached(family, params: dict, tokens: jax.Array,
     running counters: assignments per expert, then experts hit summed
     over (layer, step) pairs, then the count of those pairs) it is carried
     through the layer loop too and returned fourth: the load stays on the
-    device until somebody asks."""
+    device until somebody asks.
+
+    Where a run has a mixer beside its attention, its state buffers follow
+    the K and V pools in the tuple `k_pool` (`PagedKVCache.step_pools`) and
+    ride the same carry; `slots` [B] names each row's slot there (None: row
+    i's is slot i)."""
     c, spec = config, family(config)
     runs = _stacks(spec, c)
     if not all(run.ffn.serves for run in runs):
@@ -1115,6 +1369,8 @@ def forward_cached(family, params: dict, tokens: jax.Array,
         x = _embed(params, "tok", tokens, c) + _embed(params, "pos", pos, c)
     else:
         x = _embed(params, "tok", tokens, c)
+    if spec.mult is not None:
+        x = _scaled(x, spec.mult.embedding)
     if spec.residual_dtype is not None:
         x = x.astype(spec.residual_dtype)
     # A cache of several kinds of layer hands its pools over as one tuple
@@ -1143,7 +1399,7 @@ def forward_cached(family, params: dict, tokens: jax.Array,
                 x, own, {**_layer_of(blocks, i + off if off else i,
                                      run.ffn.whole),
                          "cache_layer": i + first if first else i},
-                spec, run, c, tables, positions, valid, ctx_lens)
+                spec, run, c, tables, positions, valid, ctx_lens, slots)
             if run.pools is None:
                 pools = own
             else:

@@ -1,0 +1,326 @@
+"""State-space ops (Mamba-2's selective state space, SSD form): the causal
+depthwise convolution with a carried tail, the one-token update of a
+recurrent state and the chunked scan of a slice of tokens.
+
+The recurrence, a head j of group g at position t (x_t [P], B_t and C_t [N]
+shared by the group's heads, dt_t and A < 0 scalars a head):
+
+    S_t = exp(dt_t A) S_{t-1} + dt_t x_t B_t^T          S [P, N]
+    y_t = S_t C_t
+
+(the skip `D x_t`, the gate and the norm are the caller's).  dt_t = 0 is the
+identity: S_t = S_{t-1} exactly, which is how a padded row and a lane that
+is not stepped pass through.
+
+The state lives in ONE float32 buffer [layers, slots, H, N, P] for an
+engine's lifetime (inference/kv_cache.py: a slot a lane, and one more that
+rows nobody has are sent to), stored transposed (N rows of P columns: the
+P = 128 columns of a head are the device's lane width, so x_t and y_t are
+rows, and the sums over N run down the sublanes).  Both kernels read and
+write the slots of their rows in place (`input_output_aliases`), a block a
+grid step, named by the layer and the row's slot through scalar prefetch:
+
+  * `ssm_update` (T = 1): pure bandwidth, every number of the state read
+    and written once, a few multiply-adds each on the vector unit;
+  * `ssm_scan` (T > 1): chunks of `chunk` positions; inside a chunk the
+    recurrence unrolled into products on the matrix unit
+    (y = ((C B^T) * L) (dt x) + exp(cum) C S,  L[i, j] = exp(cum_i - cum_j)
+    for i >= j, cum the running sum of dt A inside the chunk), between
+    chunks the carried state in the kernel's output block.  What a chunk
+    needs beside the products (L, the decayed C and B, the chunk's total
+    decay) is made by XLA in front of the kernel, in float32.
+
+On the CPU (tests) the same arithmetic runs as plain XLA.
+"""
+
+from __future__ import annotations
+
+import functools
+from typing import Optional
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.attention import _interpret_kernels
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+# Heads of one grid step of the update kernel: 8 x [256, 128] float32 is
+# 1 MB a block, 4 MB with both directions double-buffered.
+_UPDATE_HEADS = 8
+
+
+def conv_tail(xbc, tail, conv_w, conv_b, n_valid):
+    """Causal depthwise convolution of a slice behind its lane's carried
+    tail, then SiLU.  xbc [B, T, C] (the slice's rows, the first
+    `n_valid` [B] of them real); tail [B, K - 1, C] (the lane's last K - 1
+    rows before the slice); conv_w [K, C] (tap K - 1 meets the row's own
+    position), conv_b [C].  Returns (silu(conv) [B, T, C] in xbc's dtype,
+    the tail after the slice's real rows [B, K - 1, C] in tail's dtype: the
+    old one where none was)."""
+    k, t = conv_w.shape[0], xbc.shape[1]
+    ext = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
+    w = conv_w.astype(jnp.float32)
+    y = conv_b.astype(jnp.float32) + sum(
+        ext[:, i:i + t].astype(jnp.float32) * w[i] for i in range(k))
+    rows = n_valid[:, None] + jnp.arange(k - 1, dtype=n_valid.dtype)
+    new_tail = jnp.take_along_axis(ext, rows[:, :, None], axis=1)
+    return jax.nn.silu(y).astype(xbc.dtype), new_tail.astype(tail.dtype)
+
+
+# --------------------------------------------------------------------------
+# T = 1: the update
+# --------------------------------------------------------------------------
+
+def _update_kernel(layer_ref, slot_ref, s_ref, dec_ref, dtx_ref, b_ref,
+                   c_ref, so_ref, y_ref, *, heads: int):
+    """One (row, block of `heads` heads of one group) grid step: every head's
+    [N, P] state decayed, the outer product of B and dt x added, written
+    back, and read against C."""
+    del layer_ref, slot_ref             # only the index maps read them
+    n, p = s_ref.shape[-2:]
+    # B and C as columns, broadcast over the head's P columns.
+    b_col = jnp.broadcast_to(b_ref[...], (p, n)).T
+    c_col = jnp.broadcast_to(c_ref[...], (p, n)).T
+    for h in range(heads):
+        s = s_ref[h] * dec_ref[h:h + 1, :] + b_col * dtx_ref[h:h + 1, :]
+        so_ref[h] = s
+        y_ref[h:h + 1, :] = jnp.sum(s * c_col, axis=0, keepdims=True)
+
+
+def ssm_update(state, x, dt, a, bm, cm, slots, layer=0, *,
+               use_kernel: Optional[bool] = None,
+               interpret: Optional[bool] = None):
+    """One token a row: the states of rows `slots` at `layer` overwritten
+    in place, and each read against its C.
+
+    state [L, S, H, N, P] float32; x [B, H, P]; dt [B, H] float32 (0: the
+    row is not stepped); a [H] (negative); bm, cm [B, G, N]; slots [B]
+    int32.  Returns (y [B, H, P] float32, state)."""
+    b, h, p = x.shape
+    g, n = bm.shape[1:]
+    dec = jnp.broadcast_to(jnp.exp(dt * a)[..., None], (b, h, p))
+    dtx = dt[..., None] * x.astype(jnp.float32)
+    bm, cm = bm.astype(jnp.float32), cm.astype(jnp.float32)
+    layer = jnp.asarray(layer, jnp.int32)
+    slots = slots.astype(jnp.int32)
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+    if not use_kernel:
+        rep = h // g
+        s = state[layer, slots]                               # [B, H, N, P]
+        s = s * dec[:, :, None, :] + (
+            jnp.repeat(bm, rep, axis=1)[..., None] * dtx[:, :, None, :])
+        y = jnp.einsum("bhnp,bhn->bhp", s, jnp.repeat(cm, rep, axis=1),
+                       precision=_HIGHEST)
+        return y, state.at[layer, slots].set(s, mode="drop")
+    if interpret is None:
+        interpret = _interpret_kernels()
+    hb = min(_UPDATE_HEADS, h // g)
+    if (h // g) % hb:
+        raise ValueError(f"{h // g} heads a group in blocks of {hb}")
+    per_group = h // g // hb
+
+    def state_map(i, j, ly, sl):
+        return (ly[0], sl[i], j, 0, 0)
+
+    def head_map(i, j, ly, sl):
+        return (i, j, 0)
+
+    def group_map(i, j, ly, sl):
+        return (i, j // per_group, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,              # layer, the rows' slots
+        grid=(b, h // hb),
+        in_specs=[pl.BlockSpec((None, None, hb, n, p), state_map),
+                  pl.BlockSpec((None, hb, p), head_map),
+                  pl.BlockSpec((None, hb, p), head_map),
+                  pl.BlockSpec((None, None, 1, n), group_map),
+                  pl.BlockSpec((None, None, 1, n), group_map)],
+        out_specs=[pl.BlockSpec((None, None, hb, n, p), state_map),
+                   pl.BlockSpec((None, hb, p), head_map)],
+    )
+    state, y = pl.pallas_call(
+        functools.partial(_update_kernel, heads=hb),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((b, h, p), jnp.float32)],
+        input_output_aliases={2: 0},        # the state, after the scalars
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name="ssm_update",
+    )(layer.reshape(1), slots, state, dec, dtx, bm[:, :, None, :],
+      cm[:, :, None, :])
+    return y, state
+
+
+# --------------------------------------------------------------------------
+# T > 1: the chunked scan
+# --------------------------------------------------------------------------
+
+def _chunk_terms(x, dt, a, bm, cm, chunk: int):
+    """What a chunked scan multiplies, head-major and float32, the slice
+    padded at its end to whole chunks with dt = 0 (the identity):
+    dtx [B, H, T, P], bm and cm [B, G, T, N] as given, lmat
+    [B, H, T / chunk, chunk, chunk], cs and bw [B, H, T, N] (C decayed from
+    its chunk's start, B to its chunk's end), dec [B, H, T / chunk] (a
+    chunk's whole decay)."""
+    b, t, h, p = x.shape
+    g = bm.shape[2]
+    pad = -t % chunk
+    if pad:
+        x, dt, bm, cm = (jnp.pad(v, ((0, 0), (0, pad)) + ((0, 0),) * (
+            v.ndim - 2)) for v in (x, dt, bm, cm))
+    nc = (t + pad) // chunk
+    dt = jnp.moveaxis(dt.astype(jnp.float32), 1, 2)           # [B, H, T]
+    dtx = dt[..., None] * jnp.moveaxis(x.astype(jnp.float32), 1, 2)
+    bm = jnp.moveaxis(bm.astype(jnp.float32), 1, 2)           # [B, G, T, N]
+    cm = jnp.moveaxis(cm.astype(jnp.float32), 1, 2)
+    cum = jnp.cumsum((dt * a[None, :, None]).reshape(b, h, nc, chunk), -1)
+    total = cum[..., -1:]
+    diff = cum[..., :, None] - cum[..., None, :]
+    causal = jnp.tril(jnp.ones((chunk, chunk), bool))
+    lmat = jnp.exp(jnp.where(causal, diff, -jnp.inf))
+    rep = h // g
+    into = jnp.exp(cum).reshape(b, h, -1)[..., None]          # [B, H, T, 1]
+    out_of = jnp.exp(total - cum).reshape(b, h, -1)[..., None]
+    cs = jnp.repeat(cm, rep, axis=1) * into
+    bw = jnp.repeat(bm, rep, axis=1) * out_of
+    return dtx, bm, cm, lmat, cs, bw, jnp.exp(total[..., 0])
+
+
+def _scan_kernel(layer_ref, slot_ref, fresh_ref, s_ref, dtx_ref, c_ref,
+                 b_ref, l_ref, cs_ref, bw_ref, dec_ref, so_ref, y_ref):
+    """One (row, head, chunk) grid step; the chunks of a (row, head) run in
+    order with the state carried in the output block, which is the head's
+    [N, P] state in the buffer: written back once, after the last."""
+    del layer_ref, slot_ref
+    i, c = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(c == 0)
+    def _():
+        so_ref[...] = jnp.where(fresh_ref[i] != 0, 0.0, s_ref[...])
+
+    s, dtx = so_ref[...], dtx_ref[...]
+    scores = jax.lax.dot_general(
+        c_ref[...], b_ref[...], (((1,), (1,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
+    y_ref[...] = (
+        jnp.dot(scores * l_ref[...], dtx, precision=_HIGHEST,
+                preferred_element_type=jnp.float32)
+        + jnp.dot(cs_ref[...], s, precision=_HIGHEST,
+                  preferred_element_type=jnp.float32))
+    so_ref[...] = dec_ref[...] * s + jax.lax.dot_general(
+        bw_ref[...], dtx, (((0,), (0,)), ((), ())),
+        precision=_HIGHEST, preferred_element_type=jnp.float32)
+
+
+def _scan_chunks(terms, s0):
+    """The chunked scan as plain XLA: s0 [B, H, N, P] -> (y [B, H, T, P],
+    the state after the last chunk)."""
+    dtx, bm, cm, lmat, cs, bw, dec = terms
+    b, h, t, p = dtx.shape
+    nc, chunk = lmat.shape[2:4]
+    rep = h // bm.shape[1]
+
+    def by_chunk(v):                    # [B, X, T, W] -> [nc, B, X, chunk, W]
+        return jnp.moveaxis(v.reshape(*v.shape[:2], nc, chunk, -1), 2, 0)
+
+    scores = jnp.einsum("cbgin,cbgjn->cbgij", by_chunk(cm), by_chunk(bm),
+                        precision=_HIGHEST)
+    scores = jnp.repeat(scores, rep, axis=2) * jnp.moveaxis(lmat, 2, 0)
+
+    def one(s, xs):
+        scores, dtx, cs, bw, dec = xs
+        y = (jnp.einsum("bhij,bhjp->bhip", scores, dtx, precision=_HIGHEST)
+             + jnp.einsum("bhin,bhnp->bhip", cs, s, precision=_HIGHEST))
+        s = dec[..., None, None] * s + jnp.einsum(
+            "bhjn,bhjp->bhnp", bw, dtx, precision=_HIGHEST)
+        return s, y
+
+    s, y = jax.lax.scan(one, s0, (scores, by_chunk(dtx), by_chunk(cs),
+                                  by_chunk(bw), jnp.moveaxis(dec, 2, 0)))
+    return jnp.moveaxis(y, 0, 2).reshape(b, h, t, p), s
+
+
+def ssm_sequence(x, dt, a, bm, cm, *, chunk: int):
+    """A whole sequence from the zero state: x [B, T, H, P], dt [B, T, H],
+    a [H], bm and cm [B, T, G, N] -> (y [B, T, H, P] float32, the final
+    state [B, H, N, P])."""
+    b, t, h, p = x.shape
+    y, s = _scan_chunks(_chunk_terms(x, dt, a, bm, cm, chunk),
+                        jnp.zeros((b, h, bm.shape[-1], p), jnp.float32))
+    return jnp.moveaxis(y[:, :, :t], 1, 2), s
+
+
+def ssm_scan(state, x, dt, a, bm, cm, slots, fresh, layer=0, *, chunk: int,
+             use_kernel: Optional[bool] = None,
+             interpret: Optional[bool] = None):
+    """A slice of T tokens a row, from each row's state at `layer` (zero
+    where `fresh`), which is overwritten in place with the state behind the
+    slice's last stepped token.
+
+    state [L, S, H, N, P] float32; x [B, T, H, P]; dt [B, T, H] float32 (0
+    at a padded row: the identity); a [H]; bm, cm [B, T, G, N]; slots [B]
+    int32; fresh [B] bool.  Returns (y [B, T, H, P] float32, state)."""
+    b, t, h, p = x.shape
+    n = bm.shape[-1]
+    terms = _chunk_terms(x, dt, a, bm, cm, chunk)
+    layer = jnp.asarray(layer, jnp.int32)
+    slots = slots.astype(jnp.int32)
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+    if not use_kernel:
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, state[layer, slots])
+        y, s = _scan_chunks(terms, s0)
+        return (jnp.moveaxis(y[:, :, :t], 1, 2),
+                state.at[layer, slots].set(s, mode="drop"))
+    if interpret is None:
+        interpret = _interpret_kernels()
+    dtx, bm, cm, lmat, cs, bw, dec = terms
+    nc = lmat.shape[2]
+    per_group = h // bm.shape[1]
+    dec = jnp.broadcast_to(dec[..., None, None], (b, h, nc, 1, p))
+
+    def state_map(i, j, c, ly, sl, fr):
+        return (ly[0], sl[i], j, 0, 0)
+
+    def rows_map(i, j, c, ly, sl, fr):
+        return (i, j, c, 0)
+
+    def group_map(i, j, c, ly, sl, fr):
+        return (i, j // per_group, c, 0)
+
+    def chunk_map(i, j, c, ly, sl, fr):
+        return (i, j, c, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,      # layer, the rows' slots, which are fresh
+        grid=(b, h, nc),
+        in_specs=[pl.BlockSpec((None, None, None, n, p), state_map),
+                  pl.BlockSpec((None, None, chunk, p), rows_map),
+                  pl.BlockSpec((None, None, chunk, n), group_map),
+                  pl.BlockSpec((None, None, chunk, n), group_map),
+                  pl.BlockSpec((None, None, None, chunk, chunk), chunk_map),
+                  pl.BlockSpec((None, None, chunk, n), rows_map),
+                  pl.BlockSpec((None, None, chunk, n), rows_map),
+                  pl.BlockSpec((None, None, None, 1, p), chunk_map)],
+        out_specs=[pl.BlockSpec((None, None, None, n, p), state_map),
+                   pl.BlockSpec((None, None, chunk, p), rows_map)],
+    )
+    state, y = pl.pallas_call(
+        _scan_kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct(dtx.shape, jnp.float32)],
+        input_output_aliases={3: 0},        # the state, after the scalars
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        name="ssm_scan",
+    )(layer.reshape(1), slots, fresh.astype(jnp.int32), state, dtx, cm, bm,
+      lmat, cs, bw, dec)
+    return jnp.moveaxis(y[:, :, :t], 1, 2), state

@@ -170,6 +170,27 @@ class DeploymentConfig:
     queue_limit: Optional[int] = None
 
 
+# Most chunks one next_chunk reply carries behind its first: what a
+# consumer a few seconds behind has waiting, and a bound on the pulls a
+# reply makes on the replica's loop.
+_STREAM_BURST = 256
+
+
+def _says_what_arrived(result) -> bool:
+    """Whether a method's result is a stream with `ready()`: an iterator
+    that can say its next chunk is here and has a `close()` for the
+    consumer that leaves."""
+    return all(callable(getattr(result, name, None))
+               for name in ("__next__", "ready", "close"))
+
+
+def _reply_chunks(out: dict):
+    """The chunks of one next_chunk reply, in order."""
+    if "chunk" in out:
+        yield out["chunk"]
+        yield from out.get("more", ())
+
+
 @ray_tpu.remote
 class ReplicaActor:
     """Hosts one copy of the user's callable (reference: replica.py:268).
@@ -273,14 +294,10 @@ class ReplicaActor:
                 # Streaming method: stash the generator and hand back a
                 # stream ticket; the in-flight slot stays charged until
                 # the consumer drains or cancels (next_chunk below).
-                gen = target(*args, **kwargs)
-                sid = next(self._stream_ids)
-                self._streams[sid] = gen
-                self._stream_deadlines[sid] = deadline
-                self._ongoing += 1   # held until stream end
+                ticket = self._open_stream(target(*args, **kwargs), deadline)
                 spans.end(tok, stream=True)
                 tok = None
-                return {"__serve_stream__": sid}
+                return ticket
             if inspect.iscoroutinefunction(target) or (
                     not inspect.isfunction(target)
                     and not inspect.ismethod(target)
@@ -298,6 +315,22 @@ class ReplicaActor:
             if inspect.iscoroutine(result):
                 # Sync wrapper handing back a coroutine: finish it here.
                 return await result
+            if _says_what_arrived(result):
+                # A stream handed back by a plain method (the LLM
+                # replica's `generate`): a generator method's ticket and
+                # slot, and `ready()` for next_chunk to send what has
+                # arrived in one reply.
+                if not stream:
+                    result.close()
+                    raise TypeError(
+                        f"method {method_name or '__call__'!r} returns a "
+                        f"stream; call it via handle.stream() / "
+                        f"stream_async() (or the ASGI route), not "
+                        f".remote()")
+                ticket = self._open_stream(result, deadline)
+                spans.end(tok, stream=True)
+                tok = None
+                return ticket
             return result
         finally:
             self._ongoing -= 1
@@ -305,10 +338,27 @@ class ReplicaActor:
             if cv is not None:
                 tracing._ctx.reset(cv)
 
+    def _open_stream(self, gen, deadline: Optional[float]) -> dict:
+        """Stash a streaming response and hand back its ticket; the
+        in-flight slot stays charged until the stream ends."""
+        sid = next(self._stream_ids)
+        self._streams[sid] = gen
+        self._stream_deadlines[sid] = deadline
+        self._ongoing += 1   # held until stream end
+        return {"__serve_stream__": sid}
+
     async def next_chunk(self, sid: int):
-        """Pull ONE chunk of stream `sid`: {"chunk": value} or
+        """Pull the next chunk of stream `sid`: {"chunk": value} or
         {"done": True}.  Sync generators advance on the thread pool so
-        they cannot stall the replica loop.  An UNKNOWN sid means this
+        they cannot stall the replica loop.  A stream that says what has
+        arrived (`ready()`: its next chunk, or its end, is here and a
+        pull would not wait) is pulled on the loop while it says so, and
+        the reply carries those chunks too, in order, under "more", and
+        "done" beside them if the end was among them: a consumer whose
+        round trip is longer than the producer's step gets two steps'
+        chunks a call, where one a call left it further behind with
+        every chunk and seconds late at the stream's end (PERF.md
+        section 6, PR 43).  An UNKNOWN sid means this
         replica restarted and lost its in-memory streams — raise
         ReplicaStreamLostError so the handle fails over instead of
         silently truncating the stream with a fake "done"."""
@@ -338,23 +388,40 @@ class ReplicaActor:
                         return True, gen.__next__()
                     except StopIteration:
                         return False, None
-                import contextvars
-                loop = asyncio.get_running_loop()
-                ctx = contextvars.copy_context()
-                alive, chunk = await loop.run_in_executor(
-                    self._pool, lambda: ctx.run(_pull))
-                if sid in self._cancelled:
-                    # cancel_stream caught this generator mid-pull and
-                    # could not close it; it is suspended now.
-                    self._cancelled.discard(sid)
-                    try:
-                        gen.close()
-                    except Exception:
-                        pass
-                    return {"done": True}
+                ready = getattr(gen, "ready", None)
+                if ready is not None and ready():
+                    alive, chunk = _pull()      # here already: no wait
+                else:
+                    import contextvars
+                    loop = asyncio.get_running_loop()
+                    ctx = contextvars.copy_context()
+                    alive, chunk = await loop.run_in_executor(
+                        self._pool, lambda: ctx.run(_pull))
+                    if sid in self._cancelled:
+                        # cancel_stream caught this generator mid-pull
+                        # and could not close it; it is suspended now.
+                        self._cancelled.discard(sid)
+                        try:
+                            gen.close()
+                        except Exception:
+                            pass
+                        return {"done": True}
                 if not alive:
                     self._finish_stream(sid)
                     return {"done": True}
+                out = {"chunk": chunk}
+                more = []
+                while (ready is not None and len(more) < _STREAM_BURST
+                       and ready()):
+                    alive, chunk = _pull()
+                    if not alive:
+                        self._finish_stream(sid)
+                        out["done"] = True
+                        break
+                    more.append(chunk)
+                if more:
+                    out["more"] = more
+                return out
             return {"chunk": chunk}
         except StopAsyncIteration:
             self._finish_stream(sid)
@@ -1447,12 +1514,13 @@ class DeploymentHandle:
                 while True:
                     out = ray_tpu.get(replica.next_chunk.remote(sid),
                                       timeout=self._step_timeout(deadline))
+                    for chunk in _reply_chunks(out):
+                        if skip > 0:
+                            skip -= 1
+                            continue
+                        yield chunk
                     if out.get("done"):
                         return
-                    if skip > 0:
-                        skip -= 1
-                        continue
-                    yield out["chunk"]
             except BaseException:
                 # Any abandonment (consumer close, get timeout, worker
                 # error) must release the replica's stream slot.
@@ -1543,12 +1611,13 @@ class DeploymentHandle:
                     out = await asyncio.wait_for(asyncio.wrap_future(
                         replica.next_chunk.remote(sid).future()),
                         _step(timeout))
+                    for chunk in _reply_chunks(out):
+                        if skip > 0:
+                            skip -= 1
+                            continue
+                        yield chunk
                     if out.get("done"):
                         return
-                    if skip > 0:
-                        skip -= 1
-                        continue
-                    yield out["chunk"]
             except BaseException:
                 # Same slot-release contract as the sync stream().
                 try:

@@ -57,10 +57,12 @@ class KVBlockCodec:
         pickle.dump(
             {
                 "v": 1,
-                # "kv": K and V rows; "latent": one latent row in `k`,
-                # `v_pool` None; "layered": as "latent", and under `more`
-                # the blocks of the cache's other kinds, each said to be
-                # whose (inference/kv_cache.py).
+                # "kv": K and V rows; "state": as "kv", and under `more`
+                # the snapshot of the recurrent state behind the chain;
+                # "latent": one latent row in `k`, `v_pool` None; "layered":
+                # as "latent", and under `more` the blocks of the cache's
+                # other kinds, each said to be whose
+                # (inference/kv_cache.py).
                 "kind": payload.get("kind", "kv"),
                 **({"more": _as_numpy(payload["more"])}
                    if payload.get("more") else {}),

@@ -61,6 +61,45 @@ def llm_stream_resume(args, kwargs, received):
     return (new_prompt,), kwargs
 
 
+class TokenStream:
+    """What `LLMReplica.generate` returns: the token ids of one request
+    as the engine emits them, for a `for`, a `next()` or a `list()`.
+    `ready()` says that the next token, or the stream's end, is here, so
+    the replica's `next_chunk` sends a caller what has arrived in one
+    reply.  `close()` is the consumer leaving mid-stream (cancel,
+    deadline, disconnect): the lane is evicted, so the engine stops
+    decoding for nobody; a stream dropped unclosed does the same when it
+    is collected."""
+
+    def __init__(self, handle):
+        self._handle = handle
+        self._open = True           # neither run to its end nor closed
+
+    def __iter__(self):
+        return self
+
+    def __next__(self) -> int:
+        try:
+            return int(next(self._handle))
+        except StopIteration:
+            self._open = False
+            raise
+
+    def ready(self) -> bool:
+        return self._handle.ready()
+
+    def close(self) -> None:
+        if self._open:
+            self._open = False
+            self._handle.cancel()
+
+    def __del__(self):
+        try:
+            self.close()
+        except Exception:
+            pass
+
+
 class LLMReplica:
     """Replica callable wrapping an InferenceEngine: the class that
     `serve.LLMDeployment` deploys, under a name of its own for whoever
@@ -113,26 +152,19 @@ class LLMReplica:
                  temperature: float = 0.0, eos_id: Optional[int] = None,
                  seed: Optional[int] = None, _produced_offset: int = 0,
                  _deadline_s: Optional[float] = None):
-        """Streaming entry point: a generator, so serve hands the caller
-        a stream ticket and each token is pulled as the engine emits it.
+        """Streaming entry point: a `TokenStream`, so serve hands the
+        caller a stream ticket and tokens are pulled as the engine emits
+        them, those that have arrived since the last pull in one reply.
 
         `_produced_offset` / `_deadline_s` are serve-plane plumbing:
         the failover policy sets the offset so a resumed request samples
         with the original request's key sequence, and the replica
         injects the remaining deadline budget so the engine evicts the
         lane (instead of decoding for nobody) once it lapses."""
-        handle = self._engine.submit(prompt, max_new_tokens,
-                                     temperature=temperature,
-                                     eos_id=eos_id, seed=seed,
-                                     sample_offset=_produced_offset,
-                                     deadline_s=_deadline_s)
-        try:
-            for tok in handle:
-                yield int(tok)
-        finally:
-            # Consumer gone mid-stream (cancel, deadline, disconnect):
-            # evict the lane so the engine stops decoding for nobody.
-            handle.cancel()
+        return TokenStream(self._engine.submit(
+            prompt, max_new_tokens, temperature=temperature,
+            eos_id=eos_id, seed=seed, sample_offset=_produced_offset,
+            deadline_s=_deadline_s))
 
     def __call__(self, prompt, max_new_tokens: int = 16,
                  temperature: float = 0.0,

@@ -25,8 +25,8 @@ BUILD_PARTS = ("windows", "assemble", "upload")
 COMMIT_PARTS = ("release", "lock", "deliver")
 PARTS = BUILD_PARTS + COMMIT_PARTS
 PART_FIELDS = tuple(f"{p}_ms" for p in PARTS)
-STEP_FIELDS = {"decode", "prefill", "waiting", "wall_ms", *PHASE_FIELDS,
-               "ahead", *PART_FIELDS, "gc_ms"}
+STEP_FIELDS = {"decode", "prefill", "decode_ctx", "waiting", "wall_ms",
+               *PHASE_FIELDS, "ahead", *PART_FIELDS, "gc_ms"}
 # one iteration in `engine._CPU_EVERY`, drawn, reads the thread's CPU clock too
 CLOCK_FIELDS = {"cpu_ms", "cpu_wall_ms"}
 
@@ -321,6 +321,9 @@ def test_a_latent_share_engine_counts_on_the_host_and_adds_nothing_to_a_step():
     # output token comes from the prefill)
     assert grew("latent") == {"decode_steps": len(out) - 1,
                               "ctx_tokens": sum(range(20, 19 + len(out)))}
+    # and each iteration's record says what its own T=1 step attended over
+    assert [e["payload"]["decode_ctx"] for e in steps
+            if e["payload"]["decode"]] == list(range(20, 19 + len(out)))
     moe = grew("moe")
     expert_layers = cfg.n_layers - cfg.first_dense_layers
     tokens = 19 + len(out) - 1

@@ -1114,6 +1114,45 @@ def test_llm_deployment_streams_tokens(cluster):
     serve.delete("llm")
 
 
+def test_a_replicas_stream_says_what_has_arrived_and_closes_its_lane():
+    """`LLMReplica.generate` hands back a `TokenStream`: `ready()` is the
+    next token, or the end, being here (so serve's `next_chunk` sends
+    what has arrived in one reply); it iterates like the generator it
+    was; a consumer that leaves (`close()`) evicts the lane, and one
+    that ran to the end leaves nothing to cancel."""
+    import time
+
+    from ray_tpu import serve
+    replica = serve.LLMReplica(model="gpt", config="nano", max_lanes=2,
+                               block_size=8, prefill_chunk=4)
+    try:
+        engine = replica._engine
+        whole = list(replica.generate([3, 14, 15, 9], 6))
+        assert len(whole) == 6
+        stream = replica.generate([3, 14, 15, 9], 6)
+        assert isinstance(stream, serve.llm.TokenStream)
+        assert next(stream) == whole[0]
+        deadline = time.time() + 60
+        while engine.stats()["active"]:             # every token is out
+            assert time.time() < deadline
+            time.sleep(0.01)
+        got = []
+        while stream.ready():                       # five, then the end
+            try:
+                got.append(next(stream))
+            except StopIteration:
+                break
+        assert got == whole[1:] and not stream.ready()
+        stream.close()                              # nothing to cancel
+        left = replica.generate([3, 14, 15, 9], 4000)
+        assert next(left) == whole[0]
+        left.close()
+        assert engine.stats()["active"] == 0
+        assert len(list(left)) < 3999       # ends behind what was out
+    finally:
+        replica._engine.shutdown()
+
+
 def test_llm_replica_metrics_scraped_through_cli_path(cluster):
     from ray_tpu import serve, state
     handle = serve.run(serve.LLMDeployment.bind(

@@ -677,6 +677,127 @@ def test_restarted_replica_raises_stream_lost(cluster):
     serve.delete("loststream")
 
 
+class _Arrived:
+    """A stream that says what has arrived: chunks put on a queue, None
+    for the end; `closed` counts the consumers that left."""
+    closed = 0
+
+    def __init__(self, chunks):
+        import queue
+        self.q = queue.Queue()
+        for c in chunks:
+            self.q.put(c)
+
+    def __iter__(self):
+        return self
+
+    def __next__(self):
+        c = self.q.get()
+        if c is None:
+            raise StopIteration
+        return c
+
+    def ready(self):
+        return not self.q.empty()
+
+    def close(self):
+        type(self).closed += 1
+
+
+def _replica_of(name):
+    from ray_tpu.serve._private import CONTROLLER_NAME, SERVE_NAMESPACE
+    controller = ray_tpu.get_actor(CONTROLLER_NAME, SERVE_NAMESPACE)
+    routing = ray_tpu.get(controller.get_routing.remote(name), timeout=30)
+    return routing["replicas"][0]
+
+
+@pytest.mark.parametrize("ended", [True, False])
+def test_a_reply_carries_every_chunk_that_has_arrived(cluster, ended):
+    """A method may hand back a stream with `ready()`: one next_chunk
+    reply then carries every chunk that is here, in order, and the
+    stream's end beside them if it is here too; what is not here yet is
+    the next call's, which waits for it like a generator's pull."""
+    from ray_tpu.serve import _private
+
+    @serve.deployment(name="arrived")
+    class Arrived:
+        def chunks(self, n, ended):
+            self.s = _Arrived(list(range(n)) + ([None] if ended else []))
+            return self.s
+
+        def put(self, c):
+            self.s.q.put(c)
+
+    serve.run(Arrived.bind())
+    replica = _replica_of("arrived")
+    ticket = ray_tpu.get(replica.handle_request.remote(
+        "chunks", (5, ended), {}, True, None), timeout=30)
+    sid = ticket["__serve_stream__"]
+    out = ray_tpu.get(replica.next_chunk.remote(sid), timeout=30)
+    assert list(_private._reply_chunks(out)) == [0, 1, 2, 3, 4]
+    assert bool(out.get("done")) is ended
+    if not ended:
+        ref = replica.next_chunk.remote(sid)        # waits on the pool
+        time.sleep(0.2)
+        ray_tpu.get(replica.handle_request.remote(
+            "put", (7,), {}, False, None), timeout=30)
+        assert ray_tpu.get(ref, timeout=30) == {"chunk": 7}
+        ray_tpu.get(replica.handle_request.remote(
+            "put", (None,), {}, False, None), timeout=30)
+        assert ray_tpu.get(replica.next_chunk.remote(sid),
+                           timeout=30) == {"done": True}
+    with pytest.raises(Exception):                  # the slot was given back
+        ray_tpu.get(replica.next_chunk.remote(sid), timeout=30)
+    assert ray_tpu.get(replica.ongoing_requests.remote(), timeout=30) == 0
+    serve.delete("arrived")
+
+
+def test_a_slow_consumer_of_such_a_stream_catches_up(cluster):
+    """handle.stream() over a stream with `ready()`: a consumer slower
+    than the producer still sees every chunk once and in order, hears of
+    the end with the last chunks, and one that leaves closes the stream;
+    `.remote()` on the method is refused as on a generator's and closes
+    it too."""
+
+    @serve.deployment(name="bursty", max_concurrent_queries=2)
+    class Bursty:
+        def chunks(self, n):
+            import threading
+            s = _Arrived([])
+
+            def feed():
+                for i in range(n):
+                    time.sleep(0.01)
+                    s.q.put(i)
+                s.q.put(None)
+            threading.Thread(target=feed, daemon=True).start()
+            return s
+
+        def closed(self):
+            return _Arrived.closed
+
+    serve.run(Bursty.bind())
+    h = serve.get_deployment_handle("bursty")
+    got = []
+    for c in h.options("chunks").stream(40):
+        got.append(c)
+        if len(got) == 1:
+            time.sleep(0.3)             # 30 chunks' time
+    assert got == list(range(40))
+    for c in h.options("chunks").stream(1000):
+        if c == 3:
+            break                       # the consumer leaves
+    for _ in range(3):                  # past max_concurrent_queries
+        with pytest.raises(Exception, match="stream"):
+            h.options("chunks").remote(3).result(timeout=30)
+    deadline = time.time() + 30
+    while h.options("closed").remote().result(timeout=30) < 4:
+        assert time.time() < deadline
+        time.sleep(0.05)
+    assert list(h.options("chunks").stream(3)) == [0, 1, 2]
+    serve.delete("bursty")
+
+
 def test_status_reports_replica_states(cluster):
     @serve.deployment(name="stately", num_replicas=2)
     def f(x):

@@ -1,0 +1,203 @@
+"""Falcon-H1 through the engine and its cache of state that is not rows
+(inference/kv_cache.py "state"): a slot a lane overwritten by every step,
+snapshots under the prefix index, a match served only where K/V blocks AND
+a snapshot stand, lanes that are not stepped, the wire format, and what is
+refused.  Nano size on the CPU; the model's own tests are
+tests/test_falconh1.py, the cell's rehearsal
+benchmark/tests/test_rehearse_falconh1.py."""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.reference import falconh1 as ref
+from ray_tpu.inference import InferenceEngine, PagedKVCache
+from ray_tpu.models import falconh1
+from ray_tpu.serve.kv_tier.codec import KVBlockCodec
+
+NANO = falconh1.CONFIGS["falconh1-nano"]
+ENGINE = dict(auto_start=False, max_lanes=4, block_size=4, num_blocks=(96, 4),
+              max_seq_len=96, prefill_chunk=8)
+
+
+@functools.lru_cache(maxsize=None)
+def _init(seed=0):
+    return jax.jit(falconh1.init_params, static_argnums=0)(
+        NANO, jax.random.key(seed))
+
+
+def _engine(**kw):
+    return InferenceEngine("falconh1", NANO, _init(), **{**ENGINE, **kw})
+
+
+def _run(eng, *handles):
+    while eng.step():
+        pass
+    return [h.tokens() for h in handles]
+
+
+def _greedy(prompt, n):
+    """The reference's own greedy continuation of `prompt`."""
+    seq = list(prompt)
+    for _ in range(n):
+        seq.append(int(jnp.argmax(ref.row_logits(_init(),
+                                                 np.asarray(seq))[-1])))
+    return seq[len(prompt):]
+
+
+def _served_is_the_references(prompt, out):
+    want = np.asarray(jnp.argmax(ref.row_logits(
+        _init(), np.asarray(prompt + out)), -1))
+    return out == want[len(prompt) - 1:len(prompt) + len(out) - 1].tolist()
+
+
+def _prompts(seed, head_len, tails):
+    rng = np.random.default_rng(seed)
+    head = rng.integers(0, 512, head_len).tolist()
+    return [head + rng.integers(0, 512, n).tolist() for n in tails]
+
+
+@pytest.mark.parametrize("prefill_lanes", [None, 2, 1],
+                         ids=["all_lanes", "two_rows", "one_row"])
+def test_the_engine_serves_the_references_greedy_tokens(prefill_lanes):
+    """Five requests of unlike lengths over four lanes: lanes prefill in
+    chunks while others decode (a lane that is not stepped keeps its state:
+    a decode step passes over the prefilling lanes, a prefill program over
+    the decoding ones, and with fewer rows than prefilling lanes some wait
+    whole steps), the fifth takes a lane another has left."""
+    eng = _engine(prefill_lanes=prefill_lanes, prefix_cache=False)
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 512, n).tolist() for n in (37, 9, 21, 30, 13)]
+    outs = _run(eng, *(eng.submit(p, n) for p, n in zip(
+        prompts, (12, 30, 18, 9, 14))))
+    for prompt, out in zip(prompts, outs):
+        assert _served_is_the_references(prompt, out)
+    ssm = eng.stats()["ssm"]
+    assert ssm["tokens_scanned"] == sum(map(len, prompts))
+    assert ssm["tokens_updated"] == 12 + 30 + 18 + 9 + 14 - 5
+    assert ssm["state_slots_live"] == 0 and ssm["state_slots"] == 4
+    assert ssm["snapshots_taken"] == ssm["snapshot_slots"] == 0
+
+
+def test_a_lane_adopted_from_a_snapshot_equals_one_prefilled_from_token_0():
+    """The second request of a head adopts its blocks and the snapshot
+    behind them and scans only its own turn; what it serves is what an
+    engine without a prefix cache serves for the same prompt, token for
+    token, and the reference's."""
+    first, second = _prompts(1, 32, (5, 7))
+    eng = _engine(prefill_lanes=2)
+    _run(eng, eng.submit(first, 4))
+    st = eng.stats()
+    assert st["ssm"]["snapshots_taken"] == 1 and st["prefix_hit_tokens"] == 0
+    out, = _run(eng, eng.submit(second, 16))
+    st = eng.stats()
+    assert st["prefix_hit_tokens"] == 32 and st["ssm"]["snapshots_adopted"] == 1
+    assert st["ssm"]["tokens_scanned"] == len(first) + 7
+    plain = _engine(prefix_cache=False)
+    cold, = _run(plain, plain.submit(second, 16))
+    assert out == cold
+    assert _served_is_the_references(second, out)
+
+
+def test_a_snapshot_stands_where_the_prompts_last_whole_chunk_ends():
+    """One snapshot a prompt, behind the last chunk that leaves a chunk to
+    prefill: 40 + 5 tokens in chunks of 8 from 0 -> at 40; a prompt that
+    adopts 40 and adds 19 -> at 56 (40 + 2 x 8), its own deepest edge."""
+    first, second = _prompts(2, 40, (5, 19))
+    eng = _engine(prefill_lanes=2)
+    _run(eng, eng.submit(first, 2))
+    cache = eng.cache
+    assert cache.match_len(first) == 40 and cache.match_len(second) == 40
+    _run(eng, eng.submit(second, 2))
+    assert cache.match_len(second) == 56
+    assert cache.match_len(second[:50] + [0] * 9) == 40
+    assert eng.stats()["ssm"]["snapshots_taken"] == 2
+
+
+def test_a_match_is_refused_where_blocks_exist_and_the_snapshot_was_evicted():
+    """Two snapshot slots, three heads: the first head's snapshot is the
+    least recently used and goes; its K/V blocks are still indexed, and the
+    index serves nothing of them: the request prefills from token 0 and is
+    counted."""
+    eng = _engine(num_blocks=(96, 2), prefill_lanes=2)
+    heads = [_prompts(10 + i, 32, (5,))[0] for i in range(3)]
+    for prompt in heads:
+        _run(eng, eng.submit(prompt, 2))
+    cache = eng.cache
+    assert [cache.match_len(p) for p in heads] == [0, 32, 32]
+    assert len(cache.match_prefix(heads[0])) == 0 and cache._unserved == 9
+    assert cache._index and eng.stats()["ssm"]["snapshots_evicted"] == 1
+    again = heads[0][:32] + [1, 2, 3]
+    out, = _run(eng, eng.submit(again, 8))
+    st = eng.stats()["ssm"]
+    assert st["snapshot_misses"] == 1 and st["snapshots_adopted"] == 0
+    assert _served_is_the_references(again, out)
+    assert cache.match_len(again) == 32         # taken anew behind its head
+
+
+def test_slots_and_snapshot_slots_are_all_free_after_the_lanes_go():
+    eng = _engine(prefill_lanes=2)
+    prompts = _prompts(3, 24, (5, 9, 3, 7, 11))
+    _run(eng, *(eng.submit(p, 6) for p in prompts))
+    cache, st = eng.cache, eng.stats()
+    assert st["active"] == 0 and st["ssm"]["state_slots_live"] == 0
+    assert cache.allocator.num_free == cache.allocator.num_blocks
+    assert cache.snap_allocator.num_free == cache.snap_allocator.num_blocks
+    assert all(cache.snap_allocator.refcount(s) == 0
+               for s in cache._snap_key)
+    # a snapshot goes with the block it stands behind
+    for block in list(cache._block_key):
+        cache.allocator.uncache(block)
+        cache._on_evict(block)
+    assert not cache._snap_index and not cache._snap_key
+    assert cache.snap_allocator.num_unused == cache.snap_allocator.num_blocks
+
+
+def test_what_a_state_cannot_do_is_refused():
+    with pytest.raises(NotImplementedError, match="rolled back"):
+        _engine(spec_k=2)
+    cache = _engine().cache
+    cache.alloc_lane(0, 9)
+    with pytest.raises(NotImplementedError, match="rolled back"):
+        cache.truncate_lane(0, 4)
+    with pytest.raises(NotImplementedError, match="snapshots"):
+        cache.attach_tier(object())
+
+
+def test_the_wire_format_carries_the_snapshot_and_a_cache_installs_its_own():
+    first, second = _prompts(4, 32, (5, 6))
+    eng = _engine(prefill_lanes=2)
+    _run(eng, eng.submit(first, 2))
+    payload = KVBlockCodec.decode(KVBlockCodec.encode(
+        eng.export_prefix(second)))
+    assert payload["kind"] == "state" and len(payload["chain"]) == 8
+    assert payload["more"]["state"].shape == (3, 4, 16, 8)
+    other = _engine(prefill_lanes=2)
+    assert other.import_prefix(payload) == 8
+    assert other.import_prefix(payload) == 0            # idempotent
+    out, = _run(other, other.submit(second, 12))
+    assert other.stats()["prefix_hit_tokens"] == 32
+    assert _served_is_the_references(second, out)
+    # nothing of it without its snapshot, nothing into a cache of K/V alone
+    bare = dict(payload, more={})
+    assert _engine().import_prefix(bare) == 0
+    assert _engine(num_blocks=(96, 0)).import_prefix(payload) == 0
+    from ray_tpu.models import llama
+    plain = PagedKVCache.for_model(
+        llama, llama.CONFIGS["llama-tiny"], num_blocks=16, block_size=4,
+        max_lanes=2)
+    assert plain.install_prefix(payload) == 0
+
+
+def test_compiled_steps_count_the_state_buffers_copies():
+    """What `compiled_steps()` reports of a state cache's programs.  (On
+    the CPU the update is XLA's scatter into a buffer it cannot donate;
+    that it is 0 compiled for the chip is tests/test_tpu_aot.py's.)"""
+    eng = _engine(prefill_lanes=2)
+    _run(eng, eng.submit(_prompts(5, 16, (3,))[0], 3))
+    steps = eng.compiled_steps()
+    assert set(steps) == {"t8_lanes1", "t8_lanes2", "t1"}
+    assert all(isinstance(s["state_copies"], int) for s in steps.values())

@@ -737,6 +737,38 @@ def test_dots3_steps_fit_a_v5e_and_leave_their_three_pools_in_place(
                         "moe_grouped_matmul"})
 
 
+@pytest.mark.parametrize("which,t,rows", [("t1", 1, 64), ("short", 64, 1)],
+                         ids=["t1_64_lanes", "t64_one_row"])
+def test_falconh1_steps_fit_a_v5e_and_update_the_state_buffer_in_place(
+        as_on_chip, which, t, rows):
+    """The cell's T=1 step and an admission's one-row program at the cell's
+    own sizes (`benchmark/tools/aot_falconh1_sizes.py`, from its
+    configuration and traffic files): arguments and temporaries under the
+    compiler's 15.75 GB beside the snapshot pool, the K and V pools AND the
+    2.45 GB state buffer donated and left where they are (no copy of it, no
+    whole layer of it sliced out or stacked back: one copy is 2.4 GB), and
+    the kernels under the names the benchmark's readers find them by."""
+    from benchmark.tools import aot_falconh1_sizes
+    try:
+        texts = aot_falconh1_sizes.main("serve_falconh1_chat_decode", which)
+    except RuntimeError as e:           # no v5e topology can be described
+        pytest.skip(str(e))
+    text, memory, pools = texts[(t, rows)]
+    assert [tuple(p.shape) for p in pools] == [
+        (9, 768, 128, 512), (9, 768, 128, 512), (9, 65, 32, 256, 128),
+        (9, 65, 15360)]
+    held = sum(p.dtype.itemsize * math.prod(p.shape) for p in pools)
+    # (the tails' 65 slots are padded to whole tiles)
+    assert held <= memory.alias_size_in_bytes < 1.002 * held
+    snapshots = 16 * (4 * 32 * 256 * 128 + 2 * 3 * 5120) * 9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + snapshots < 15.75 * 2 ** 30)
+    assert 12.6e9 < memory.argument_size_in_bytes < 12.8e9
+    assert all(count_pool_copies(text, p.shape) == 0 for p in pools)
+    kernels = {k.split(".")[0] for k in _kernel_names(text)}
+    assert kernels == ({"paged_decode_attention", "ssm_update"} if t == 1
+                       else {"ssm_scan"})
+
 
 @pytest.mark.parametrize("t,rows", [(1, 8), (32, 8), (32, 2), (32, 1)],
                          ids=["t1", "t_prefill_chunk", "compact_2_rows",
