@@ -1,10 +1,11 @@
-"""Attention ops: blockwise (flash) attention with a Pallas TPU kernel.
+"""Attention ops: blockwise (flash) attention with Pallas TPU kernels.
 
 No reference counterpart — Ray delegates compute to hosted frameworks
 (SURVEY.md §5 "Long-context: absent").  Here attention is a core op: the
-Pallas kernel keeps the softmax accumulation in VMEM (online softmax, never
-materialising the [L, L] score matrix in HBM) and tiles the contraction onto
-the MXU; a pure-jnp fallback covers CPU tests and odd shapes.
+Pallas kernels keep the softmax accumulation in VMEM (online softmax, never
+materialising the [L, L] score matrix in HBM), walk only the tiles of it at
+or under the causal diagonal and tile the contractions onto the MXU in the
+inputs' dtype; a pure-jnp fallback covers CPU tests and odd shapes.
 
 Layouts: q/k/v are [batch, length, heads, head_dim] (BLHD) throughout.
 """
@@ -12,8 +13,9 @@ Layouts: q/k/v are [batch, length, heads, head_dim] (BLHD) throughout.
 from __future__ import annotations
 
 import functools
+import itertools
 import logging
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -75,62 +77,185 @@ def _build_mask(q_len, k_len, causal, segment_ids):
 
 
 # ---------------------------------------------------------------------------
-# Pallas flash-attention kernel
+# Pallas flash-attention kernels
 # ---------------------------------------------------------------------------
+#
+# One head's scores are a [q_len, kv_len] square that never exists.  The
+# grid walks it in blocks (`block_q` x `block_k`, the whole head at 1,024),
+# K and V (backward: Q and dO) arriving a block a grid step with the carry
+# in VMEM scratch between them, and a grid step walks its block in tiles.
+# Which tiles is decided while tracing, not on the chip: a causal block on
+# the diagonal visits the tiles at or under it (10 of 16 at 256 x 256) and
+# masks only those the diagonal crosses, a block under the diagonal visits
+# all of its tiles and masks none, a block over it is neither copied in nor
+# computed.  So a grid step is straight-line code the compiler schedules
+# across tiles; the same walk as a `fori_loop` with bounds from the tile's
+# position was slower than the kernels it was to replace (PERF.md section
+# 6, PR 44: a loop trip waits out every product's latency).
+#
+# A tile is S^T: kv positions along sublanes, q positions along lanes.  The
+# softmax's running maximum and sum, the logsumexp and delta = rowsum(dO * O)
+# are then [1, q] rows, dense in their vector registers and 4 bytes a q
+# position in HBM ([heads, 1, q_len]; a [.., q_len, 1] array pads every
+# position to 128 lanes, 151 MB a layer at the train cell's shape), a
+# reduction over kv is over sublanes (no lane shuffle), and P^T dO and
+# dS^T Q are plain products.  Every product takes its operands in the
+# inputs' dtype and accumulates in float32 (P and dS are cast as
+# `reference_attention` casts `probs`); the scale multiplies the float32
+# scores; statistics and accumulators are float32.  The backward is one
+# kernel: S, P, dP and dS of a tile are computed once and feed dv, dk and
+# dq (five products a tile beside the forward's two), dq gathering as
+# dq^T over the kv blocks in a scratch of the whole head.  The tile
+# functions take [rows, d] values: only the BlockSpecs know how a head
+# lies in HBM.
+
+# Rows and columns of a tile at most, forward and backward (the sweep of
+# PERF.md section 6, PR 44, at a head of 64 over 1,024 positions).
+_FLASH_FWD_TILE = 512
+_FLASH_BWD_TILE = 256
+# Scoped VMEM a kernel may ask for beyond the compiler's default 16 MiB
+# (the backward holds a head's dq: 8 bytes a q position and column).
+_FLASH_VMEM_LIMIT = 96 * 1024 * 1024
+
+_NT = (((1,), (1,)), ((), ()))      # a @ b.T
+_NN = (((1,), (0,)), ((), ()))      # a @ b
+_TN = (((0,), (0,)), ((), ()))      # a.T @ b
+
+
+def _dot(a, b, dims=_NN):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _tiles(n_q, bq, n_kv, bk, diagonal):
+    """(q0, k0, offset) of the tiles to visit in a block of n_q x n_kv
+    tiles.  Off the diagonal (or not causal) all of them, unmasked (offset
+    None).  On it those with a position at or under the diagonal, and for
+    the ones it crosses the offset of the tile's first kv position past
+    its first q position, which is what its mask needs."""
+    for q0 in range(0, n_q * bq, bq):
+        for k0 in range(0, n_kv * bk, bk):
+            if not diagonal or k0 + bk - 1 <= q0:
+                yield q0, k0, None
+            elif k0 <= q0 + bq - 1:
+                yield q0, k0, k0 - q0
+
+
+def _scores_t(k, q, scale, offset):
+    """S^T = K Q^T * scale of one tile, [kv, q] float32; masked where
+    `offset` (the tile's first kv position past its first q position, see
+    `_tiles`) is not None."""
+    s = _dot(k, q, _NT) * scale
+    if offset is None:
+        return s
+    visible = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+               - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)) >= offset
+    return jnp.where(visible, s, NEG_INF)
+
+
+def _on_or_under_the_diagonal(walk, q_block, kv_block, causal, n_blocks):
+    """Run `walk(diagonal)` as the pair of blocks asks: causal blocks are
+    square, so the diagonal crosses the pairs of equal index alone, and a
+    head of one block has no other pair to trace, lower and compile."""
+    if not causal:
+        walk(False)
+    elif n_blocks == 1:
+        walk(True)
+    else:
+        pl.when(q_block == kv_block)(functools.partial(walk, True))
+        pl.when(q_block > kv_block)(functools.partial(walk, False))
+
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                  acc_ref, *, block_k: int, causal: bool, scale: float,
-                  n_kv_blocks: int):
-    """One (batch*head, q_block, kv_block) grid step: online softmax.
+                  acc_ref, *, bq: int, bk: int, n_blocks: int, causal: bool,
+                  scale: float):
+    """Grid (head, q block, kv block): the online softmax of a q block over
+    the kv blocks up to its own.  Refs: q, o [block_q, d]; k, v
+    [block_k, d]; lse [1, block_q]; scratch m, l [1, block_q] and acc
+    [d, block_q] (O^T, unnormalised), float32, carried between kv blocks."""
+    j, c = pl.program_id(1), pl.program_id(2)
+    n_q, n_kv = q_ref.shape[0] // bq, k_ref.shape[0] // bk
 
-    K/V arrive one VMEM block per grid step (the grid's last dim streams
-    them from HBM — memory is O(block), not O(kv_len)); softmax state
-    persists in VMEM scratch across the kv sweep for a given q block.
-    Refs: q [bq, d], k/v [block_k, d], o [bq, d], lse [bq, 1] (saved for
-    the backward); scratch m/l [bq, 1] f32, acc [bq, d] f32.
-    """
-    kv_idx = pl.program_id(2)
-    q_idx = pl.program_id(1)
-    bq = q_ref.shape[0]
-    q_offset = q_idx * bq
-    kv_offset = kv_idx * block_k
-
-    @pl.when(kv_idx == 0)
+    @pl.when(c == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _compute():
-        q = q_ref[...].astype(jnp.float32) * scale
-        k_blk = k_ref[...].astype(jnp.float32)
-        v_blk = v_ref[...].astype(jnp.float32)
-        s = q @ k_blk.T                                        # [bq, block_k]
-        if causal:
-            q_pos = q_offset + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 0)
-            k_pos = kv_offset + jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        m = m_ref[...]
-        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        alpha = jnp.exp(m - m_new)
-        m_ref[...] = m_new
-        l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + p @ v_blk
+    def walk(diagonal):
+        for q0, tiles in itertools.groupby(
+                _tiles(n_q, bq, n_kv, bk, diagonal), key=lambda t: t[0]):
+            cols = slice(q0, q0 + bq)
+            q = q_ref[cols, :]
+            m, l, acc = m_ref[:, cols], l_ref[:, cols], acc_ref[:, cols]
+            for _, k0, offset in tiles:
+                rows = slice(k0, k0 + bk)
+                v = v_ref[rows, :]
+                s = _scores_t(k_ref[rows, :], q, scale, offset)
+                m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+                p = jnp.exp(s - m_new)
+                alpha = jnp.exp(m - m_new)
+                m = m_new
+                l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+                acc = acc * alpha + _dot(v, p.astype(v.dtype), _TN)  # [d, bq]
+            m_ref[:, cols], l_ref[:, cols], acc_ref[:, cols] = m, l, acc
 
-    if causal:
-        # KV blocks strictly above the diagonal contribute nothing.
-        pl.when(q_offset + bq - 1 >= kv_offset)(_compute)
-    else:
-        _compute()
+    _on_or_under_the_diagonal(walk, j, c, causal, n_blocks)
 
-    @pl.when(kv_idx == n_kv_blocks - 1)
+    @pl.when(c == pl.num_programs(2) - 1)
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+        o_ref[...] = (acc_ref[...] / l_safe).T.astype(o_ref.dtype)
         lse_ref[...] = m_ref[...] + jnp.log(l_safe)
+
+
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+                      dq_ref, dk_ref, dv_ref, dqt_ref, dk_acc, dv_acc, *,
+                      bq: int, bk: int, causal: bool, scale: float):
+    """Grid (head, kv block, q block): dk and dv of a kv block over the q
+    blocks from its own on, and every pair's share of dq.
+    dS = P * (dO V^T - delta); dv = P^T dO; dk = dS^T Q * scale;
+    dq = dS K * scale.  Refs: k, v, dk, dv [block_k, d]; q, dO
+    [block_q, d]; lse, delta [1, block_q]; dq [q_len, d], the head's,
+    written at the head's last grid step from the scratch dq^T
+    [q blocks, d, block_q]; scratch dk, dv [block_k, d]; float32."""
+    j, c = pl.program_id(1), pl.program_id(2)
+    last = ((j == pl.num_programs(1) - 1) & (c == pl.num_programs(2) - 1))
+    n_q, n_kv = q_ref.shape[0] // bq, k_ref.shape[0] // bk
+
+    @pl.when(c == 0)
+    def _init():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    @pl.when(j == 0)
+    def _init_dq():
+        dqt_ref[c] = jnp.zeros(dqt_ref.shape[1:], jnp.float32)
+
+    def walk(diagonal):
+        for q0, k0, offset in _tiles(n_q, bq, n_kv, bk, diagonal):
+            cols, rows = slice(q0, q0 + bq), slice(k0, k0 + bk)
+            q, do = q_ref[cols, :], do_ref[cols, :]
+            k, v = k_ref[rows, :], v_ref[rows, :]
+            p = jnp.exp(_scores_t(k, q, scale, offset) - lse_ref[:, cols])
+            ds = (p * (_dot(v, do, _NT) - delta_ref[:, cols])).astype(q.dtype)
+            dv_acc[rows, :] += _dot(p.astype(do.dtype), do)
+            dk_acc[rows, :] += _dot(ds, q)
+            dqt_ref[c, :, cols] += _dot(k, ds, _TN)            # [d, bq]
+
+    _on_or_under_the_diagonal(walk, c, j, causal, dqt_ref.shape[0])
+
+    @pl.when(c == pl.num_programs(2) - 1)
+    def _finalize():
+        dk_ref[...] = (dk_acc[...] * scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+    @pl.when(last)
+    def _finalize_dq():
+        block_q = q_ref.shape[0]
+        for i in range(dqt_ref.shape[0]):
+            dq_ref[i * block_q:(i + 1) * block_q, :] = (
+                dqt_ref[i] * scale).T.astype(dq_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
@@ -142,8 +267,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
     shape does not tile (length % block != 0; logged once per shape on TPU).
 
     Differentiable end-to-end in Pallas: the forward saves (O, logsumexp)
-    and the backward runs flash-style dq and dk/dv kernels (causal block
-    skipping, f32 VMEM accumulators) — never materializing [L, L]."""
+    and the backward is one flash-style kernel for dq, dk and dv (causal
+    tile skipping, f32 VMEM accumulators) — never materializing [L, L]."""
     return _flash(q, k, v, causal, scale, block_q, block_k, interpret)
 
 
@@ -204,6 +329,11 @@ def _fold_heads(x):
     return x.transpose(0, 2, 1, 3).reshape(b * h, l, d)
 
 
+def _unfold_heads(x, b):
+    n, l, d = x.shape
+    return x.reshape(b, n // b, l, d).transpose(0, 2, 1, 3)
+
+
 def _fit_blocks(q_len, kv_len, block_q, block_k):
     """Clamp blocks to the lengths, then halve until they tile — lengths
     like 1536 must ride the Pallas path with 512-blocks rather than fall
@@ -217,48 +347,149 @@ def _fit_blocks(q_len, kv_len, block_q, block_k):
     return block_q, block_k
 
 
-def _flash_forward_impl(q, k, v, causal, scale, block_q, block_k, interpret):
-    b, q_len, h, d = q.shape
-    kv_len = k.shape[1]
+def _flash_tile(block: int, most: int) -> int:
+    """Rows (or columns) of a tile inside a block: the largest of `most`,
+    its halves down to 128 that divides the block, else the block (a short
+    or odd length is one tile)."""
+    while most >= 128:
+        if block % most == 0:
+            return most
+        most //= 2
+    return block
+
+
+class _FlashPlan(NamedTuple):
+    """What a call's shapes decide: the grid's blocks, and whether to run
+    the kernels under the interpreter."""
+    block_q: int
+    block_k: int
+    causal: bool
+    scale: float
+    interpret: bool
+
+
+def _flash_plan(q, k, causal, scale, block_q, block_k, interpret):
+    """The plan for q, k of [batch, length, heads, d], or None where the
+    shapes do not tile or a head's dq does not fit VMEM (the caller takes
+    the XLA reference)."""
+    (q_len, d), kv_len = q.shape[1::2], k.shape[1]
     block_q, block_k = _fit_blocks(q_len, kv_len, block_q, block_k)
+    if causal:          # square blocks: the diagonal crosses equal indices
+        block_q = block_k = min(block_q, block_k)
     if interpret is None:
         interpret = _interpret_kernels()
-    if not _use_pallas(q_len, kv_len, d, block_q, block_k, causal):
+    if (not _use_pallas(q_len, kv_len, d, block_q, block_k, causal)
+            or 2 * _flash_dq_bytes(q_len, d, q.dtype) > _FLASH_VMEM_LIMIT):
         if not interpret:
             _log_reference_path("flash_attention", (q.shape, k.shape))
-        return reference_attention(q, k, v, causal=causal, scale=scale), None
-    scale = scale if scale is not None else 1.0 / np.sqrt(d)
-    n_kv_blocks = kv_len // block_k
+        return None
+    return _FlashPlan(block_q, block_k, causal,
+                      scale if scale is not None else 1.0 / np.sqrt(d),
+                      interpret)
 
-    # Fold batch and heads into the grid; kernel sees [len, d] slices.
-    qr, kr, vr = _fold_heads(q), _fold_heads(k), _fold_heads(v)
 
-    kernel = functools.partial(_flash_kernel, block_k=block_k, causal=causal,
-                               scale=scale, n_kv_blocks=n_kv_blocks)
-    out, lse = pl.pallas_call(
-        kernel,
-        grid=(b * h, q_len // block_q, n_kv_blocks),
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((None, block_k, d), lambda i, j, kk: (i, kk, 0)),
-            pl.BlockSpec((None, block_k, d), lambda i, j, kk: (i, kk, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda i, j, kk: (i, j, 0)),
-            pl.BlockSpec((None, block_q, 1), lambda i, j, kk: (i, j, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, q_len, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, q_len, 1), jnp.float32),
-        ],
+def _flash_dq_bytes(q_len, d, dtype):
+    """VMEM the backward holds for a head's dq: dq^T in float32 and the
+    output block in two buffers."""
+    return q_len * d * (4 + 2 * jnp.dtype(dtype).itemsize)
+
+
+def _flash_call(plan, kernel, vmem=0, **kwargs):
+    """One of the kernels as a `pallas_call`.  Both carry the name the
+    trace reader keys on (`benchmark/readers.py::flash_roofline`)."""
+    return pl.pallas_call(
+        functools.partial(kernel, causal=plan.causal, scale=plan.scale),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            # the default 16 MiB holds the blocks and a dq of 4 MiB
+            vmem_limit_bytes=2 * vmem if vmem > 4 * 1024 * 1024 else None),
+        interpret=plan.interpret, name="flash_attention", **kwargs)
+
+
+def _flash_specs(plan, d, q_at, kv_at):
+    """Block specs of a grid (head, a, b): a q-side array, a kv-side array
+    and a q-side row of float32, whose blocks along the length are
+    `q_at(a, b)` and `kv_at(a, b)`."""
+    return (pl.BlockSpec((None, plan.block_q, d),
+                         lambda i, a, b: (i, q_at(a, b), 0)),
+            pl.BlockSpec((None, plan.block_k, d),
+                         lambda i, a, b: (i, kv_at(a, b), 0)),
+            pl.BlockSpec((None, 1, plan.block_q),
+                         lambda i, a, b: (i, 0, q_at(a, b))))
+
+
+def _flash_fwd_heads(plan, q, k, v):
+    """out [heads, q_len, d] and the logsumexp [heads, 1, q_len] of
+    q, k, v [heads, length, d]."""
+    (n, q_len, d), kv_len = q.shape, k.shape[1]
+    # A kv block past the q block's own is not copied in: the index stays
+    # at the last block the pair needs, and an unchanged block is kept.
+    q_spec, kv_spec, row_spec = _flash_specs(
+        plan, d, lambda a, b: a,
+        lambda a, b: jnp.minimum(a, b) if plan.causal else b)
+    return _flash_call(
+        plan, functools.partial(
+            _flash_kernel, bq=_flash_tile(plan.block_q, _FLASH_FWD_TILE),
+            bk=_flash_tile(plan.block_k, _FLASH_FWD_TILE),
+            n_blocks=q_len // plan.block_q),
+        grid=(n, q_len // plan.block_q, kv_len // plan.block_k),
+        in_specs=[q_spec, kv_spec, kv_spec],
+        out_specs=[q_spec, row_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct((n, 1, q_len), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((1, plan.block_q), jnp.float32),
+                        pltpu.VMEM((1, plan.block_q), jnp.float32),
+                        pltpu.VMEM((d, plan.block_q), jnp.float32)],
+    )(q, k, v)
+
+
+def _flash_bwd_heads(plan, q, k, v, do, lse, delta):
+    """dq, dk, dv of q, k, v, dO [heads, length, d] and the logsumexp and
+    delta [heads, 1, q_len]."""
+    (n, q_len, d), kv_len = q.shape, k.shape[1]
+    # Nor is a q block before the kv block's own.
+    q_spec, kv_spec, row_spec = _flash_specs(
+        plan, d, lambda a, b: jnp.maximum(a, b) if plan.causal else b,
+        lambda a, b: a)
+    return _flash_call(
+        plan, functools.partial(
+            _flash_bwd_kernel, bq=_flash_tile(plan.block_q, _FLASH_BWD_TILE),
+            bk=_flash_tile(plan.block_k, _FLASH_BWD_TILE)),
+        vmem=_flash_dq_bytes(q_len, d, q.dtype),
+        grid=(n, kv_len // plan.block_k, q_len // plan.block_q),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+        out_specs=[pl.BlockSpec((None, q_len, d), lambda i, a, b: (i, 0, 0)),
+                   kv_spec, kv_spec],
+        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
+                   jax.ShapeDtypeStruct(k.shape, k.dtype),
+                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, 1), jnp.float32),
-            pltpu.VMEM((block_q, d), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qr, kr, vr)
-    return out.reshape(b, h, q_len, d).transpose(0, 2, 1, 3), lse
+            pltpu.VMEM((q_len // plan.block_q, d, plan.block_q), jnp.float32),
+            pltpu.VMEM((plan.block_k, d), jnp.float32),
+            pltpu.VMEM((plan.block_k, d), jnp.float32)],
+    )(q, k, v, do, lse, delta)
+
+
+def _flash_forward_impl(q, k, v, causal, scale, block_q, block_k, interpret):
+    plan = _flash_plan(q, k, causal, scale, block_q, block_k, interpret)
+    if plan is None:
+        return reference_attention(q, k, v, causal=causal, scale=scale), None
+    # Fold batch and heads into the grid; kernel sees [len, d] slices.
+    out, lse = _flash_fwd_heads(plan, _fold_heads(q), _fold_heads(k),
+                                _fold_heads(v))
+    return _unfold_heads(out, q.shape[0]), lse
+
+
+def _flash_backward_impl(q, k, v, out, lse, g, causal, scale, block_q,
+                         block_k, interpret):
+    plan = _flash_plan(q, k, causal, scale, block_q, block_k, interpret)
+    # delta = rowsum(dO * O), once, where both still lie as the model left
+    # them: O is not folded for the kernel at all.
+    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
+    delta = delta.transpose(0, 2, 1).reshape(lse.shape)   # [b, l, h] first
+    grads = _flash_bwd_heads(plan, *(_fold_heads(x) for x in (q, k, v, g)),
+                             lse, delta)
+    return tuple(_unfold_heads(x, q.shape[0]) for x in grads)
 
 
 # ---------------------------------------------------------------------------
@@ -1537,141 +1768,3 @@ def sparse_latent_attention(q, q_i, w_i, pool, index_pool, block_tables,
                       "sparse_latent_chunk_attention")),
         block_tables, q_positions, valid, (q, q_i, w_i),
         jnp.zeros(q.shape[:-1] + (v_width,), q.dtype))
-
-
-def _flash_dq_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref, dq_ref,
-                     dq_acc, delta_ref, *, block_k: int, causal: bool,
-                     scale: float, n_kv_blocks: int):
-    """dq: grid (bh, q_block, kv_block) — kv streams, dq accumulates.
-    ds = p * (dO V^T - D), dq = ds K * scale, with D = rowsum(dO * O)."""
-    kv_idx = pl.program_id(2)
-    q_idx = pl.program_id(1)
-    bq = q_ref.shape[0]
-    q_offset = q_idx * bq
-    kv_offset = kv_idx * block_k
-
-    @pl.when(kv_idx == 0)
-    def _init():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-        delta_ref[...] = jnp.sum(
-            do_ref[...].astype(jnp.float32) * o_ref[...].astype(jnp.float32),
-            axis=-1, keepdims=True)
-
-    def _compute():
-        q = q_ref[...].astype(jnp.float32)
-        k_blk = k_ref[...].astype(jnp.float32)
-        v_blk = v_ref[...].astype(jnp.float32)
-        do = do_ref[...].astype(jnp.float32)
-        s = (q * scale) @ k_blk.T                     # [bq, bk]
-        if causal:
-            q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = kv_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[...])                 # [bq, bk]
-        dp = do @ v_blk.T                             # [bq, bk]
-        ds = p * (dp - delta_ref[...])
-        dq_acc[...] += (ds @ k_blk) * scale
-
-    if causal:
-        pl.when(q_offset + bq - 1 >= kv_offset)(_compute)
-    else:
-        _compute()
-
-    @pl.when(kv_idx == n_kv_blocks - 1)
-    def _finalize():
-        dq_ref[...] = dq_acc[...].astype(dq_ref.dtype)
-
-
-def _flash_dkv_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
-                      dk_ref, dv_ref, dk_acc, dv_acc, *, block_q: int,
-                      causal: bool, scale: float, n_q_blocks: int):
-    """dk/dv: grid (bh, kv_block, q_block) — q streams, dk/dv accumulate.
-    dv = P^T dO;  dk = ds^T Q * scale."""
-    q_idx = pl.program_id(2)
-    kv_idx = pl.program_id(1)
-    bk = k_ref.shape[0]
-    q_offset = q_idx * block_q
-    kv_offset = kv_idx * bk
-
-    @pl.when(q_idx == 0)
-    def _init():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
-
-    def _compute():
-        q = q_ref[...].astype(jnp.float32)
-        k_blk = k_ref[...].astype(jnp.float32)
-        v_blk = v_ref[...].astype(jnp.float32)
-        do = do_ref[...].astype(jnp.float32)
-        delta = jnp.sum(do * o_ref[...].astype(jnp.float32),
-                        axis=-1, keepdims=True)      # [bq, 1]
-        s = (q * scale) @ k_blk.T                    # [bq, bk]
-        if causal:
-            q_pos = q_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
-            k_pos = kv_offset + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-            s = jnp.where(q_pos >= k_pos, s, NEG_INF)
-        p = jnp.exp(s - lse_ref[...])                # [bq, bk]
-        dv_acc[...] += p.T @ do
-        dp = do @ v_blk.T
-        ds = p * (dp - delta)
-        dk_acc[...] += (ds.T @ q) * scale
-
-    if causal:
-        pl.when(q_offset + block_q - 1 >= kv_offset)(_compute)
-    else:
-        _compute()
-
-    @pl.when(q_idx == n_q_blocks - 1)
-    def _finalize():
-        dk_ref[...] = dk_acc[...].astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
-
-
-def _flash_backward_impl(q, k, v, out, lse, g, causal, scale, block_q,
-                         block_k, interpret):
-    b, q_len, h, d = q.shape
-    kv_len = k.shape[1]
-    block_q, block_k = _fit_blocks(q_len, kv_len, block_q, block_k)
-    if interpret is None:
-        interpret = _interpret_kernels()
-    scale = scale if scale is not None else 1.0 / np.sqrt(d)
-    n_q_blocks = q_len // block_q
-    n_kv_blocks = kv_len // block_k
-
-    qr, kr, vr = _fold_heads(q), _fold_heads(k), _fold_heads(v)
-    dor, outr = _fold_heads(g), _fold_heads(out)
-
-    q_spec = pl.BlockSpec((None, block_q, d), lambda i, j, kk: (i, j, 0))
-    kv_spec = pl.BlockSpec((None, block_k, d), lambda i, j, kk: (i, kk, 0))
-    lse_spec = pl.BlockSpec((None, block_q, 1), lambda i, j, kk: (i, j, 0))
-    dq = pl.pallas_call(
-        functools.partial(_flash_dq_kernel, block_k=block_k, causal=causal,
-                          scale=scale, n_kv_blocks=n_kv_blocks),
-        grid=(b * h, n_q_blocks, n_kv_blocks),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, lse_spec],
-        out_specs=q_spec,
-        out_shape=jax.ShapeDtypeStruct((b * h, q_len, d), q.dtype),
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32),
-                        pltpu.VMEM((block_q, 1), jnp.float32)],
-        interpret=interpret,
-    )(qr, kr, vr, dor, outr, lse)
-
-    # dkv sweep: middle grid dim = kv block (fixed per sweep), last = q.
-    q_spec2 = pl.BlockSpec((None, block_q, d), lambda i, j, kk: (i, kk, 0))
-    kv_spec2 = pl.BlockSpec((None, block_k, d), lambda i, j, kk: (i, j, 0))
-    lse_spec2 = pl.BlockSpec((None, block_q, 1), lambda i, j, kk: (i, kk, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_flash_dkv_kernel, block_q=block_q, causal=causal,
-                          scale=scale, n_q_blocks=n_q_blocks),
-        grid=(b * h, n_kv_blocks, n_q_blocks),
-        in_specs=[q_spec2, kv_spec2, kv_spec2, q_spec2, q_spec2, lse_spec2],
-        out_specs=[kv_spec2, kv_spec2],
-        out_shape=[jax.ShapeDtypeStruct((b * h, kv_len, d), k.dtype),
-                   jax.ShapeDtypeStruct((b * h, kv_len, d), v.dtype)],
-        scratch_shapes=[pltpu.VMEM((block_k, d), jnp.float32),
-                        pltpu.VMEM((block_k, d), jnp.float32)],
-        interpret=interpret,
-    )(qr, kr, vr, dor, outr, lse)
-
-    unfold = lambda x, l: x.reshape(b, h, l, d).transpose(0, 2, 1, 3)
-    return unfold(dq, q_len), unfold(dk, kv_len), unfold(dv, kv_len)
