@@ -1633,7 +1633,9 @@ class InferenceEngine:
             chunks[lane] = chunk
             if compact:
                 rows[row] = lane
-            # Table entries must exist before the step writes K/V.
+            # Table entries must exist before the step writes K/V, and
+            # be the lane's alone: the step's write has every lane's copy
+            # in flight at once (kv_cache.py, "private tail").
             self.cache.ensure_capacity(lane, start + chunk)
         fed_now = sum(chunks.values())
         self._tokens_run += fed_now

@@ -103,7 +103,14 @@ exactly that token prefix, so two sequences whose prefixes agree
 block-for-block may share the physical blocks.  Sealed blocks are
 immutable (decode writes always land at positions past the sealed
 boundary, i.e. in each lane's private tail), so copy-on-write semantics
-come for free.  When a sequence finishes, its sealed blocks stay in the
+come for free.  The write path relies on that tail being private: the
+blocks that the valid rows of one step land in (`ensure_capacity`'s, a
+sliding kind's slots) are held by one lane each, so the TPU's write kernel
+(ops/paged_write.py) may have every lane's copy in flight at once; two live
+lanes of a step naming one block there would be a lost write, where the XLA
+loop let the last lane win.  Whoever shares a block that is still being
+filled breaks it: `tests/test_paged_write.py` holds the engine's programs
+to it on the CPU.  When a sequence finishes, its sealed blocks stay in the
 index at refcount 0 on an LRU list and are evicted only when the
 allocator needs the space; a new request reuses the longest
 block-aligned cached prefix instead of re-prefilling it.

@@ -519,9 +519,12 @@ def _flash_backward_impl(q, k, v, out, lse, g, causal, scale, block_q,
 # chunk, draft run) attends in tiles of plain XLA over the blocks of the
 # lanes that have valid rows (`paged_chunk_attention`), on every backend.
 
+from ray_tpu.ops import paged_write  # noqa: E402  (the write path's kernel)
+
 KV_ROW_ALIGN = 128
-# Blocks written per trip of the write loop: a T=1 step of 8 lanes is
-# straight-line code, a prefill chunk a short loop.
+# Blocks a trip of the XLA loops that move whole blocks (the write path
+# where it has no kernel, `_table_blocks`, `eva_summarise`): a few are
+# straight-line code, many a short loop.
 _KV_WRITE_UNROLL = 8
 
 
@@ -546,7 +549,8 @@ def unpack_kv_rows(rows, kv_heads: int, head_dim: int):
         *rows.shape[:-1], kv_heads, head_dim)
 
 
-def paged_rows_update(pools, rows, block_tables, positions, valid, layer=0):
+def paged_rows_update(pools, rows, block_tables, positions, valid, layer=0,
+                      *, use_kernel: Optional[bool] = None):
     """Write one layer's new stored rows into their pools, in place.
 
     pools: arrays [L, NB, BS, W_i]; rows: as many [B, T, W_i] (a token's
@@ -554,16 +558,46 @@ def paged_rows_update(pools, rows, block_tables, positions, valid, layer=0):
     absolute and consecutive per lane (positions[:, :1] + arange(T), as
     every prefill chunk, decode token and draft run is); valid [B, T] bool
     (an invalid slot (padding lane, prompt overhang) changes nothing).
-    `layer` may be traced (the layer loop's index).
+    `layer` may be traced (the layer loop's index).  Lanes with a valid
+    row write blocks of their own; what the table of a lane without one
+    names is not touched.
 
-    A lane's run touches at most (T + BS - 2) // BS + 1 blocks.  Each is
-    read, merged with the rows that fall in it and written back with one
-    `dynamic_update_slice` of a whole [BS, W] block: XLA updates the
-    loop-carried pool in place and keeps its layout (a scatter makes it
-    pick another layout for the whole pool), and a tile-aligned block
-    costs no more than one row (a row at a time, a 32-token chunk took
-    46 ms over 48 layers on the v5e, this 3.5; PERF.md section 6).
-    """
+    On TPU ONE kernel call (`paged_write.paged_rows_write`): the aligned
+    groups of rows (a tile's: 16 of bfloat16) that get a new row are
+    copied out of every pool with all copies in flight, merged and copied
+    back, and a group no valid row lands in is skipped.  Until PR 45 it
+    was the loop below on every backend, 1.65 us a block whatever a block
+    held: 37 us a layer for gpt2-xl's 16 lanes, a fifth of its T=1 step
+    (PERF.md section 6).  The loop is the CPU's path (the interpreter is
+    too slow for the engine tests), the path of a block that is neither
+    whole tiles nor at most 32 rows and of a chunk of which one lane's
+    groups pass VMEM (logged once per shape on TPU), and the tests'
+    oracle: the pools come out bit for bit the same."""
+    rows = tuple(r.astype(p.dtype) for r, p in zip(rows, pools))
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+        if use_kernel and paged_write.group_rows(
+                pools, positions.shape[1]) is None:
+            _log_reference_path("paged_rows_write",
+                                (*(p.shape for p in pools), positions.shape))
+            use_kernel = False
+    if not use_kernel:
+        return _rows_update_loop(pools, rows, block_tables, positions, valid,
+                                 layer)
+    return paged_write.paged_rows_write(
+        pools, rows, block_tables, positions, valid, layer,
+        interpret=_interpret_kernels())
+
+
+def _rows_update_loop(pools, rows, block_tables, positions, valid, layer):
+    """`paged_rows_update` as plain XLA.  A lane's run touches at most
+    (T + BS - 2) // BS + 1 blocks.  Each is read, merged with the rows
+    that fall in it and written back with one `dynamic_update_slice` of a
+    whole [BS, W] block, one after the other: XLA updates the loop-carried
+    pool in place and keeps its layout (a scatter makes it pick another
+    layout for the whole pool), and a tile-aligned block costs no more
+    than one row (a row at a time, a 32-token chunk took 46 ms over 48
+    layers on the v5e, this 3.5; PERF.md section 6)."""
     bs = pools[0].shape[2]
     b, t = positions.shape
     n_touch = (t + bs - 2) // bs + 1
@@ -581,13 +615,9 @@ def paged_rows_update(pools, rows, block_tables, positions, valid, layer=0):
         block_tables,
         jnp.clip(first[:, None] + j, 0, block_tables.shape[1] - 1),
         axis=1).reshape(-1).astype(jnp.int32)                # [B * n_touch]
-
-    def blocks_of(new, pool):
-        return jnp.take_along_axis(
-            new.astype(pool.dtype), run[:, :, None], axis=1).reshape(
-                b * n_touch, bs, pool.shape[3])
-
-    new_blocks = tuple(blocks_of(*np_) for np_ in zip(rows, pools))
+    new_blocks = tuple(
+        jnp.take_along_axis(new, run[:, :, None], axis=1).reshape(
+            b * n_touch, bs, new.shape[2]) for new in rows)
     layer = jnp.asarray(layer, jnp.int32)
     zero = jnp.zeros((), jnp.int32)
 
@@ -606,12 +636,12 @@ def paged_rows_update(pools, rows, block_tables, positions, valid, layer=0):
 
 
 def paged_kv_update(k_pool, v_pool, k_new, v_new, block_tables, positions,
-                    valid, layer=0):
+                    valid, layer=0, *, use_kernel: Optional[bool] = None):
     """`paged_rows_update` for K and V: k_pool/v_pool [L, NB, BS, W];
     k_new/v_new [B, T, KH, D], packed into stored rows here."""
     return paged_rows_update(
         (k_pool, v_pool), (pack_kv_rows(k_new), pack_kv_rows(v_new)),
-        block_tables, positions, valid, layer)
+        block_tables, positions, valid, layer, use_kernel=use_kernel)
 
 
 # Rows up to this many bytes are gathered by XLA's own gather.
@@ -1139,7 +1169,7 @@ def eva_summarise(k_pool, v_pool, mu, phi, src, dst, live, layer=0, *,
     written into blocks `dst` [N, window / chunk / BS] of the same pools;
     `live` [N] bool (a row nobody has changes nothing).  `layer` may be
     traced.  The blocks are gathered by index and written back one whole
-    [BS, W] block at a time, as `paged_rows_update` writes: the pools stay
+    [BS, W] block at a time, as `_rows_update_loop` writes: the pools stay
     where they are."""
     bs = k_pool.shape[2]
     n, n_dst = dst.shape

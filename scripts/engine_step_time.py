@@ -28,11 +28,30 @@ costs the device), and `host_upload_ms` is what handing a T=1 population to
 the device costs the host with nothing else running (`_upload`, mean of 200
 calls, three sets), beside the eight `jnp.asarray` of the same arrays that
 the engine made before (`host_upload_eight_ms`).
+
+Since PR 45 also the write path alone (`ops/attention.py::paged_rows_update`):
+a layer's call in a loop of 48 trips over donated pools, the trip's layer its
+index, nothing else in the loop, as the XLA loop that wrote the rows until
+then (`loop`) and as the kernel (`kernel`), ms a 48-trip call, so 1000 / 48
+of it is microseconds a layer; both forms must leave the same pools (a
+digest of each that weighs a row by where it lies), or the script fails.
+`rows_write_t1_ms` is the T=1 step's call (every lane a row),
+`rows_write_t32_ms` a prefill step's as the engine builds it (two lanes a
+chunk, the others masked), at gpt2-xl's shapes; a cell's name as an
+argument (`write=evabyte`, `write=falconh1`, `write=olmoe`, `write=axk1`,
+`write=dots3`) gives `rows_write_<name>_t1_ms` and `..._t<chunk>_ms` at that
+cell's lanes, pools and prefill program, read from the files the benchmark
+runs the cell from (`cell_writes`; a second call a layer body makes, dots3's
+window layers', is `rows_write_<name>_call2_...`).  With `write=` arguments
+alone the step programs are not timed:
+
+  python3 scripts/engine_step_time.py write=gpt2xl write=evabyte
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 import os
 import sys
@@ -47,11 +66,112 @@ import numpy as np
 
 from benchmark import manifest
 from ray_tpu.inference.engine import InferenceEngine, _lane_views
+from ray_tpu.inference.kv_cache import PagedKVCache
 from ray_tpu.models import gpt
-from ray_tpu.ops.attention import kv_row_width
+from ray_tpu.ops.attention import kv_row_width, paged_rows_update
+
+WRITE_TRIPS = 48
+# The prefill program timed where it is not the cell's widest ([prefill_lanes,
+# prefill_chunk]): the one row of a turn behind an adopted prompt, which is
+# what these cells' windows hold (PERF.md section 5).
+ONE_ROW_CHUNKS = {"serve_falconh1_chat_decode": (1, 64),
+                  "serve_dots3_docs_decode": (1, 128)}
 
 
-def main(unrolls):
+def cell_writes(cell):
+    """What serve cell `cell` asks of the write path, from the files the
+    benchmark runs it from (`BENCHMARK.json`, `benchmark/configs`,
+    `benchmark/traffic`): (lanes, the prefill program's (rows, T), the
+    calls a step makes), a call the pools ([L, NB, BS, W] shapes) that one
+    `paged_rows_update` of a layer writes.  The pools are those of the
+    cache the engine would make (nothing is allocated); the pools of one
+    kind of layer, under one table, go through one call."""
+    m = manifest.load()
+    file = m.load_config(m.cells[cell]["config"])
+    config = manifest.model_config(file, None)
+    eng = m.load_traffic(m.cells[cell]["traffic"])["engine"]
+    pools = jax.eval_shape(lambda: PagedKVCache.for_model(
+        importlib.import_module(file["module"]), config,
+        num_blocks=eng["num_blocks"], block_size=eng["block_size"],
+        max_lanes=eng["max_lanes"],
+        max_seq_len=eng.get("max_seq_len", config.max_seq_len),
+        ahead=2 * eng["prefill_chunk"]).step_pools)
+    calls = {}
+    for pool in jax.tree.leaves(pools):
+        if pool.ndim == 4:                  # rows in blocks, not a state
+            calls.setdefault(pool.shape[:3], []).append(pool)
+    chunk = ONE_ROW_CHUNKS.get(cell, (
+        eng.get("prefill_lanes", eng["max_lanes"]), eng["prefill_chunk"]))
+    return eng["max_lanes"], chunk, [tuple(c) for c in calls.values()]
+
+
+def time_rows_write(name, out):
+    """The write path alone at the shapes of the serve cell of configuration
+    `name`, into `out`."""
+    (cell,) = [c for c in manifest.load().cells
+               if c.startswith(f"serve_{name}_")]
+    lanes, chunk, calls = cell_writes(cell)
+    for i, shapes in enumerate(calls):
+        key = "rows_write_" + ("" if name == "gpt2xl" else name + "_") + (
+            f"call{i + 1}_" if i else "")
+        time_one_call(key, lanes, chunk, shapes, out)
+
+
+def time_one_call(key, lanes, chunk, shapes, out):
+    """One call of the write path, `shapes` its pools, into `out[key...]`."""
+    layers, nb, bs = shapes[0].shape[:3]
+    chunk_rows, chunk = chunk
+    rng = np.random.default_rng(0)
+    mb = nb // lanes
+    tables = jnp.asarray((np.arange(lanes)[:, None] * mb
+                          + np.arange(mb)[None]).astype(np.int32))
+    for rows, t in ((lanes, 1), (chunk_rows, chunk)):
+        ctx = rng.integers(100, min(350, mb * bs - t), rows)
+        positions = (ctx[:, None] + np.arange(t)[None]).astype(np.int32)
+        valid = np.ones((rows, t), bool)
+        if t > 1 and rows == lanes:     # two lanes prefill, the others wait
+            valid[2:], positions[2:] = False, 0
+        new = tuple(jnp.asarray(rng.standard_normal((rows, t, p.shape[3])),
+                                p.dtype) for p in shapes)
+        args = (new, tables[:rows], jnp.asarray(positions),
+                jnp.asarray(valid))
+        left = {}
+
+        @jax.jit
+        def digest(pool):               # every row weighed by where it lies
+            at = (1 + jnp.arange(nb * bs) % 1021).reshape(nb, bs, 1)
+            return jnp.sum(pool.astype(jnp.float32) * at, axis=(1, 2))
+
+        for form, use_kernel in (("loop", False), ("kernel", True)):
+            def layers_of_writes(pools, new, tables, positions, valid):
+                return jax.lax.fori_loop(
+                    0, WRITE_TRIPS, lambda i, pools: paged_rows_update(
+                        pools, new, tables, positions, valid, i % layers,
+                        use_kernel=use_kernel), pools)
+
+            fn = jax.jit(layers_of_writes, donate_argnums=0)
+            pools = tuple(jnp.zeros(p.shape, p.dtype) for p in shapes)
+            pools = jax.block_until_ready(fn(pools, *args))
+            # what one call leaves: a digest of each pool, on the device
+            left[form] = [np.asarray(digest(p)) for p in pools]
+            ms = []
+            for _ in range(3):
+                n = 50 if t == 1 else 20
+                t0 = time.perf_counter()
+                for _ in range(n):
+                    pools = fn(pools, *args)
+                jax.block_until_ready(pools)
+                ms.append(round(1000 * (time.perf_counter() - t0) / n, 4))
+            out.setdefault(f"{key}t{t}_ms", {})[form] = ms
+            del pools
+        for a, b in zip(left["loop"], left["kernel"]):
+            np.testing.assert_array_equal(a, b)
+            assert a.any()
+
+
+def time_steps(unrolls, out):
+    """The engine's step programs at `serve_gpt2xl_decode`'s sizes, into
+    `out`."""
     m = manifest.load()
     cell = m.cells["serve_gpt2xl_decode"]
     base = manifest.model_config(m.load_config(cell["config"]), None)
@@ -63,8 +183,6 @@ def main(unrolls):
         lambda k: gpt.serving_params(gpt.init_params(base, k), base))(
             jax.random.key(0)))
     rng = np.random.default_rng(0)
-    dev = jax.devices()[0]
-    out = {"device": [dev.platform, dev.device_kind], "tree": os.getcwd()}
     for unroll in unrolls or [base.scan_unroll]:
         cfg = dataclasses.replace(base, scan_unroll=unroll)
         eng = object.__new__(InferenceEngine)     # the step, no thread
@@ -146,8 +264,19 @@ def main(unrolls):
                             (time.perf_counter() - t0) * 1000 / 200, 4))
                         jax.block_until_ready(kept)
                     out[key] = ms
+
+
+def main(argv):
+    writes = [a.split("=", 1)[1] for a in argv if a.startswith("write=")]
+    unrolls = [int(a) for a in argv if not a.startswith("write=")]
+    dev = jax.devices()[0]
+    out = {"device": [dev.platform, dev.device_kind], "tree": os.getcwd()}
+    if unrolls or not writes:
+        time_steps(unrolls, out)
+    for name in writes or ["gpt2xl"]:
+        time_rows_write(name, out)
     print(json.dumps(out))
 
 
 if __name__ == "__main__":
-    main([int(a) for a in sys.argv[1:]])
+    main(sys.argv[1:])

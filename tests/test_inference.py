@@ -252,7 +252,9 @@ def _dense_paged_attention(q, k_blocks, v_blocks, tables, ctx_lens,
                    [False, False, False, False, False, False]]),
                  id="t6_crosses_block_boundary_with_invalid"),
 ])
-def test_paged_kv_update_masks_invalid_lanes(case):
+@pytest.mark.parametrize("use_kernel", [False, True],
+                         ids=["xla_loop", "kernel_interpreted"])
+def test_paged_kv_update_masks_invalid_lanes(case, use_kernel):
     layers, layer, (kh, d), positions, valid = case
     nb, bs = 4, 4
     rng = np.random.default_rng(3)
@@ -264,7 +266,8 @@ def test_paged_kv_update_masks_invalid_lanes(case):
     v_new = rng.standard_normal((b, t, kh, d)).astype(np.float32)
     tables = np.array([[1, 2], [3, 0]], np.int32)
 
-    k2, v2 = jax.jit(paged_kv_update)(
+    k2, v2 = jax.jit(functools.partial(paged_kv_update,
+                                       use_kernel=use_kernel))(
         _stored_pool(before[0]), _stored_pool(before[1]), k_new, v_new,
         tables, positions, valid, layer)
 

@@ -7,6 +7,7 @@ says nothing about numerics, device ownership or the process model;
 `chip_smoke.py` covers those on the chip.
 """
 
+import collections
 import dataclasses
 import functools
 import math
@@ -24,7 +25,7 @@ from ray_tpu.inference.kv_cache import (count_pool_copies,
                                         count_weight_bytes_copied)
 from ray_tpu.models import decoder, gpt
 from ray_tpu.ops.attention import (kv_row_width, paged_blocks_per_step,
-                                   paged_decode_attention)
+                                   paged_decode_attention, paged_rows_update)
 from ray_tpu.parallel import MeshConfig, create_mesh
 from ray_tpu.parallel.sharding import named_sharding, tree_shardings
 
@@ -149,10 +150,72 @@ def test_paged_decode_kernel_compiles_for_v5e_at_the_serve_cells_shapes(
     assert steps_a_lane <= mb // run
 
 
+def _write_programs():
+    """Every call of the write path a serve cell's step makes
+    (`tests/test_paged_write.py::CELL_WRITES`, from the benchmark's own
+    files) at its T=1 step and at its prefill program, and gpt2-xl's
+    verify program of five rows a lane."""
+    from tests.test_paged_write import CELL_WRITES
+    for name, (lanes, (rows, chunk), pools) in CELL_WRITES.items():
+        yield pytest.param(lanes, 1, pools, id=f"{name}_t1")
+        yield pytest.param(rows, chunk, pools,
+                           id=f"{name}_t{chunk}_{rows}_rows")
+        if name.startswith("gpt2xl"):
+            yield pytest.param(lanes, 5, pools, id=f"{name}_verify_t5")
+
+
+@pytest.mark.parametrize("lanes,t,pools", _write_programs())
+def test_rows_write_kernel_compiles_for_v5e_at_the_serve_cells_shapes(
+        v5e, as_on_chip, lanes, t, pools):
+    """The six serve cells' pools (bf16; two layers of each) and the rows
+    of their T=1 step and of a prefill or verify program: ONE Mosaic call
+    for all pools of a layer, the pools aliased through it (nothing
+    allocated beside them), a group of 16 rows the unit whatever the
+    block's size, and the lanes of a call walked a few at a time only
+    where their groups would not fit (EvaByte's chunk of 512: 33 groups
+    of 2 x 128 KB a lane)."""
+    from ray_tpu.ops import paged_write
+    arg = _arg_on(v5e[0])
+    pools = tuple(arg((2, *p.shape[1:]), p.dtype) for p in pools)
+    rows = tuple(arg((lanes, t, p.shape[3]), p.dtype) for p in pools)
+    mb = pools[0].shape[1] // lanes
+    args = (pools, rows, arg((lanes, mb), jnp.int32),
+            arg((lanes, t), jnp.int32), arg((lanes, t), jnp.bool_),
+            arg((), jnp.int32))
+    assert paged_write.group_rows(pools, t) == 16
+    compiled = jax.jit(paged_rows_update, donate_argnums=0).lower(
+        *args).compile()
+    assert _kernel_counts(compiled.as_text()) == {"paged_rows_write": 1}
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes == sum(
+        2 * math.prod(p.shape) for p in pools)
+    assert memory.temp_size_in_bytes < 2 ** 20
+    (call,) = [e for e in jax.make_jaxpr(paged_rows_update)(*args).eqns
+               if e.primitive.name == "pallas_call"]
+    steps = math.prod(call.params["grid_mapping"].grid)
+    assert steps == (4 if (lanes, t, pools[0].shape[3]) == (4, 512, 4096)
+                     else 1)
+
+
 def _kernel_names(text):
     return [line.split(" = ")[0].strip().lstrip("%")
             for line in text.splitlines()
             if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def _kernel_counts(text):
+    """How many Mosaic calls of each name (less its number) a program has."""
+    return collections.Counter(k.split("%")[-1].split(".")[0]
+                               for k in _kernel_names(text))
+
+
+def _pool_block_updates(text, pool_shape):
+    """`dynamic-update-slice`s whose result is the whole pool, fused or not:
+    what the write path's XLA loop leaves in a program, a chain of them a
+    layer (PERF.md section 6, PR 45), and the kernel does not."""
+    made = re.compile(r" = \w+\[%s\]\S* dynamic-update-slice\("
+                      % ",".join(str(d) for d in pool_shape))
+    return [line for line in text.splitlines() if made.search(line)]
 
 
 def _whole_contexts(text, lanes, mb, bs):
@@ -306,9 +369,29 @@ def test_engine_step_leaves_the_kv_pool_where_it_is(v5e, as_on_chip, heads,
     # multiplies them, not bf16 copies made here (PERF.md section 6, PR 28).
     assert memory.temp_size_in_bytes < pool_bytes
 
-    kernels = _kernel_names(text)
-    assert all(k.startswith("paged_decode_attention") for k in kernels)
-    assert len(kernels) == (cfg.scan_unroll if t == 1 else 0)
+    # A layer body writes its rows with one kernel call at either T (the
+    # loop trip holds `scan_unroll` bodies), and no whole-block update of
+    # the pool is left behind it.
+    assert _kernel_counts(text) == {
+        "paged_rows_write": cfg.scan_unroll,
+        **({"paged_decode_attention": cfg.scan_unroll} if t == 1 else {})}
+    assert not _pool_block_updates(text, pool.shape)
+
+
+def test_pool_update_counter_sees_the_block_write_loop(v5e, as_on_chip,
+                                                       monkeypatch):
+    """What every tree before PR 45 compiled, and what a block shape the
+    kernel does not take still does: the XLA loop's whole-block updates of
+    both pools.  The counter must not call that nothing."""
+    from ray_tpu.ops import paged_write
+    monkeypatch.setattr(paged_write, "group_rows", lambda pools, t: None)
+    cfg = gpt.GPTConfig(vocab_size=512, n_layers=4, d_model=12 * 64,
+                        n_heads=12, d_ff=256, max_seq_len=256, scan_unroll=2)
+    compiled, pool, _ = _compile_engine_step(v5e[0], cfg, 1, num_blocks=128)
+    text = compiled.as_text()
+    assert len(_pool_block_updates(text, pool.shape)) >= 2      # K and V
+    assert "paged_rows_write" not in _kernel_counts(text)
+    assert count_pool_copies(text, pool.shape) == 0
 
 
 @pytest.mark.parametrize("t", [1, 32], ids=["t1", "t_prefill_chunk"])
@@ -340,12 +423,11 @@ def test_olmoe_step_reads_its_experts_where_they_are(v5e, as_on_chip, t):
     assert set(moved) <= {"parameter", "get-tuple-element"}, set(moved)
     assert count_pool_copies(text, pool.shape) == 0
 
-    kernels = _kernel_names(text)
-    grouped = [k for k in kernels if k.startswith("moe_grouped_matmul")]
-    paged = [k for k in kernels if k.startswith("paged_decode_attention")]
-    assert len(grouped) == 3                      # gate, up, down in the scan
-    assert len(paged) == (1 if t == 1 else 0)
-    assert len(kernels) == len(grouped) + len(paged)
+    assert _kernel_counts(text) == {
+        "moe_grouped_matmul": 3,                  # gate, up, down in the scan
+        "paged_rows_write": 1,
+        **({"paged_decode_attention": 1} if t == 1 else {})}
+    assert not _pool_block_updates(text, pool.shape)
     # Scratch: a T=1 step needs next to none (2 MB at 16 layers); the T=32
     # step's is the activations of 512 rows (until PR 38 it gathered every
     # lane's context of 1024 for the masked-dense attention: 69 MB at 16
@@ -357,9 +439,9 @@ def test_olmoe_step_reads_its_experts_where_they_are(v5e, as_on_chip, t):
         2 * math.prod(x.shape) for x in jax.tree.leaves(params))
 
 
-# gpt2-xl's widths, eight of its 48 layers (two trips of its layer loop at
-# the preset's four layer bodies a trip), the decode cell's 16 lanes and
-# 512 blocks.
+# gpt2-xl's widths at the preset's four layer bodies a trip, the decode
+# cell's 16 lanes and 512 blocks: eight of its 48 layers (two trips of its
+# layer loop), and all of them (the cell's own programs).
 XL8 = gpt.GPTConfig(n_layers=8, d_model=1600, n_heads=25, d_ff=6400,
                     scan_unroll=4)
 _MATRIX = re.compile(
@@ -387,10 +469,21 @@ def _layers_sliced_out(text, n_layers):
     return found
 
 
+def _whole_stack_prefetches(text, n_layers):
+    """(line, layout) of every `copy-done` whose result is a whole stacked
+    matrix of the layers: all `n_layers` of a leaf moved at once."""
+    made = re.compile(r" = \w+\[%d,(?:1600,25,64|25,64,1600|1600,6400|"
+                      r"6400,1600)\]\{([^}]*)\} copy-done\(" % n_layers)
+    return [(line, m.group(1)) for line in text.splitlines()
+            if (m := made.search(line))]
+
+
 @pytest.mark.parametrize("t", [1, 32], ids=["t1", "t_prefill_chunk"])
-@pytest.mark.parametrize("unroll", [4, 1], ids=["unroll_4", "unroll_1"])
+@pytest.mark.parametrize("layers,unroll", [(8, 4), (8, 1), (48, 4)],
+                         ids=["unroll_4", "unroll_1", "all_48_layers"])
 def test_gpt2xl_step_multiplies_its_weights_as_they_are_held(v5e, as_on_chip,
-                                                             unroll, t):
+                                                             layers, unroll,
+                                                             t):
     """The mechanisms of PERF.md section 6, PRs 28 and 34, without a chip.
     On the tree the engine prepares, the compiled step has no `convert`,
     `copy` or `transpose` whose result has a matrix leaf's shape (61% of
@@ -405,8 +498,9 @@ def test_gpt2xl_step_multiplies_its_weights_as_they_are_held(v5e, as_on_chip,
     as `xs`, which at `unroll=4` slices groups of four layers out: every
     matrix written to HBM and read back, 38% of the cell's busy time.
     How many layer bodies a trip holds (`scan_unroll`) decides what the
-    scheduler may overlap, not what is copied."""
-    cfg = dataclasses.replace(XL8, scan_unroll=unroll)
+    scheduler may overlap, not what is copied.  `all_48_layers`: the
+    cell's own two programs, held to the same limits."""
+    cfg = dataclasses.replace(XL8, n_layers=layers, scan_unroll=unroll)
     compiled, pool, params = _compile_engine_step(
         v5e[0], cfg, t, lanes=16, num_blocks=512)
     text, memory = compiled.as_text(), compiled.memory_analysis()
@@ -415,19 +509,32 @@ def test_gpt2xl_step_multiplies_its_weights_as_they_are_held(v5e, as_on_chip,
     moved = {m.group(1) for line in text.splitlines()
              if (m := _MATRIX.search(line))}
     assert not moved & {"convert", "copy", "transpose"}, moved
-    copied = count_weight_bytes_copied(text, params)
+    # Since the write path is a kernel (PR 45) the T=32 step of the EIGHT
+    # layers at four bodies a trip prefetches one whole stack of a per-head
+    # projection into fast memory in every trip (`copy-done
+    # bf16[8,1600,25,64] S(1)`, 41 MB) where it prefetched pairs of layers
+    # (`slice-done [2,1600,25,64] S(1)`).  The 48 layers of the cell leave
+    # no room for that (246 MB) and its program has none: that one
+    # instruction is an artefact of compiling a sixth of the model, so it
+    # is counted apart, and everything else is held to what it was.
+    prefill_of_4 = (t, unroll) == (32, 4)
+    whole = _whole_stack_prefetches(text, cfg.n_layers)
+    assert len(whole) == (1 if prefill_of_4 and layers == 8 else 0), whole
+    assert all("S(1)" in layout for _, layout in whole), whole
+    gone = {line for line, _ in whole}
+    copied = count_weight_bytes_copied(
+        "\n".join(x for x in text.splitlines() if x not in gone), params)
     assert not set(copied) & {"convert", "copy", "transpose", "remat"}, copied
     assert count_pool_copies(text, pool.shape) == 0
-    # The bf16 matrices of eight layers are 0.49 GB.
+    # The bf16 matrices of eight layers are 0.49 GB, of 48 2.95.
     weights = sum(2 * math.prod(x.shape) for x in jax.tree.leaves(params)
                   if x.ndim > 2)
     sliced = sum(copied.get(op, 0)
                  for op in ("dynamic-slice", "slice", "copy-done"))
-    # The T=32 step at four bodies a trip also prefetches pairs of layers
-    # of those two leaves (`slice-done [2,1600,25,64] S(1)`: 31% of the
-    # matrices' bytes in all) and keeps six of a trip's eight slices in
-    # HBM: a twelfth of the bytes, where the group copies were all.
-    prefill_of_4 = (t, unroll) == (32, 4)
+    # The T=32 step at four bodies a trip also prefetches more of those
+    # two leaves (31% of the matrices' bytes in all at eight layers, 29% at
+    # 48: 867 MB of 2,949) and keeps six of a trip's eight slices in HBM:
+    # a twelfth of the bytes, where the group copies were all.
     assert sliced < weights // (3 if prefill_of_4 else 4), copied
     held = _layers_sliced_out(text, cfg.n_layers)
     assert held
@@ -439,9 +546,10 @@ def test_gpt2xl_step_multiplies_its_weights_as_they_are_held(v5e, as_on_chip,
     # `[16,25,32,1024]`: 172 MB); now it holds a tile of one lane.
     assert memory.temp_size_in_bytes < (16 if t == 1 else 64) * 2 ** 20
     assert not _whole_contexts(text, 16, cfg.max_seq_len // 16, 16)
-    kernels = _kernel_names(text)
-    assert all(k.startswith("paged_decode_attention") for k in kernels)
-    assert len(kernels) == (unroll if t == 1 else 0)
+    assert _kernel_counts(text) == {
+        "paged_rows_write": unroll,
+        **({"paged_decode_attention": unroll} if t == 1 else {})}
+    assert not _pool_block_updates(text, pool.shape)
 
 
 def test_weight_copy_counter_sees_a_raw_float32_tree(v5e, as_on_chip):
@@ -625,9 +733,12 @@ def test_axk1_steps_fit_a_v5e_and_read_weights_and_pool_in_place(
     # norm scales, kilobytes)
     assert not set(copied) & {"copy", "transpose", "remat"}, copied
     assert copied.get("convert", 0) < 2 ** 20, copied
-    kernels = {k.split(".")[0] for k in _kernel_names(text)}
-    assert kernels == ({"latent_decode_attention", "moe_grouped_matmul"}
-                       if t == 1 else {"moe_grouped_matmul"})
+    assert set(_kernel_counts(text)) == {
+        "paged_rows_write", "moe_grouped_matmul",
+        *(["latent_decode_attention"] if t == 1 else [])}
+    # one call a layer body: the lead layer's and the scan's
+    assert _kernel_counts(text)["paged_rows_write"] == 2
+    assert not _pool_block_updates(text, pool.shape)
 
 
 def test_latent_decode_kernel_compiles_for_v5e_at_every_block_size(
@@ -712,9 +823,17 @@ def test_evabyte_programs_fit_a_v5e_and_leave_pool_and_weights_in_place(
         # wq, wk and wv of each layer re-laid for a [2048, 4096] x
         # [4096, 32, 128] product: PERF.md section 7
         assert copied.get("copy", 0) <= 8 * 3 * 4096 * 4096 * 2, copied
-    kernels = {k.split(".")[0] for k in _kernel_names(text)}
-    assert kernels == ({"paged_decode_attention"} if t1 else set())
+    # (the compaction writes whole summary blocks with a loop of its own)
+    assert set(_kernel_counts(text)) == (
+        {"paged_rows_write", "paged_decode_attention"} if t1 else
+        set() if program.startswith("compaction") else {"paged_rows_write"})
+    if not program.startswith("compaction"):
+        # one call for both pools in the scan's one layer body
+        assert _kernel_counts(text)["paged_rows_write"] == 1
     if t1:
+        # the table's 24 x 1 blocks of a megabyte are not read and written
+        # back for a row each
+        assert not _pool_block_updates(text, pool)
         assert "s32[24,22]" in text         # the table: lanes x peak blocks
 
 
@@ -743,15 +862,15 @@ def test_dots3_steps_fit_a_v5e_and_leave_their_three_pools_in_place(
             < 15.75 * 2 ** 30)
     assert 11.3e9 < memory.argument_size_in_bytes < 11.6e9
     assert all(count_pool_copies(text, p.shape) == 0 for p in pools)
-    kernels = {k.split(".")[0] for k in _kernel_names(text)}
-    assert kernels == ({"sparse_index_scores",
-                        "sparse_latent_decode_attention",
-                        "window_latent_decode_attention",
-                        "moe_grouped_matmul"} if t == 1 else
-                       {"sparse_index_chunk_scores",
-                        "sparse_latent_chunk_attention",
-                        "window_latent_chunk_attention",
-                        "moe_grouped_matmul"})
+    assert set(_kernel_counts(text)) == {"paged_rows_write"} | (
+        {"sparse_index_scores", "sparse_latent_decode_attention",
+         "window_latent_decode_attention", "moe_grouped_matmul"} if t == 1
+        else {"sparse_index_chunk_scores", "sparse_latent_chunk_attention",
+              "window_latent_chunk_attention", "moe_grouped_matmul"})
+    # a full layer's latent and index rows go in ONE call, a window
+    # layer's in another: three bodies of full layers, two of window layers
+    assert _kernel_counts(text)["paged_rows_write"] == 5
+    assert not any(_pool_block_updates(text, p.shape) for p in pools)
 
 
 @pytest.mark.parametrize("which,t,rows", [("t1", 1, 64), ("short", 64, 1)],
@@ -782,9 +901,10 @@ def test_falconh1_steps_fit_a_v5e_and_update_the_state_buffer_in_place(
             + snapshots < 15.75 * 2 ** 30)
     assert 12.6e9 < memory.argument_size_in_bytes < 12.8e9
     assert all(count_pool_copies(text, p.shape) == 0 for p in pools)
-    kernels = {k.split(".")[0] for k in _kernel_names(text)}
-    assert kernels == ({"paged_decode_attention", "ssm_update"} if t == 1
-                       else {"ssm_scan"})
+    assert set(_kernel_counts(text)) == {"paged_rows_write"} | (
+        {"paged_decode_attention", "ssm_update"} if t == 1 else {"ssm_scan"})
+    assert _kernel_counts(text)["paged_rows_write"] == 1    # K and V, a body
+    assert not any(_pool_block_updates(text, p.shape) for p in pools[:2])
 
 
 @pytest.mark.parametrize("t,rows", [(1, 8), (32, 8), (32, 2), (32, 1)],
