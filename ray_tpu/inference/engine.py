@@ -67,41 +67,32 @@ tokens fall in and reads the blocks it attends over in the engine's one
 buffer
 (`compiled_steps()` reports `pool_copies`, which must be 0).
 
-A cache whose lanes hold windows (kv_cache.py: EVA's exact window behind
-the closed windows' summary rows) adds a third program, the compaction: when
-a lane's next position opens a window, the host closes the one before
-(`PagedKVCache.close_window` rewrites the lane's table and hands the
-window's blocks back) and dispatches `model.compact_cached` for it, a
-[prefill_lanes] batch by block index, in order between the step in flight,
-which wrote the window's last row, and the step being built, which reads the
-summaries.  Positions are the host's by counting, so the loop stays a step
-ahead; a prefill chunk is cut at a window's edge, so a slice's rows are
-consecutive in its lane's table.
-
-A cache of layers of several kinds (kv_cache.py "layered": dots3's full and
-window layers) hands the step all its pools as one tuple and ONE table array
-whose halves are the kinds' tables, so a population's uploads stay what they
-were; the sliding kind's blocks that a commit leaves wholly behind a lane's
-window go back to their allocator as the last part of `commit`
-(`PagedKVCache.slide_release`, in the record's `windows_ms`), admission asks
-both allocators (`can_admit_prefix`), and `stats()` adds `sparse` (per T=1
-step and one layer of each kind: context tokens scored, rows chosen, window
-rows attended) and `windows` (sliding blocks given back so far).
-
-A cache with state that is not rows (kv_cache.py "state": Falcon-H1's
-state-space mixer beside its attention heads) hands the step its K and V
-pools and the two state buffers as one tuple (`PagedKVCache.step_pools`); a
-lane's slot there is its index, and a compact program's rows name theirs
-(`forward_cached`'s `slots`).  A prefilling lane starts its scan from zero
-at position 0 and otherwise from what its slot holds, which at admission is
-the snapshot `adopt_prefix` copied in; padded rows and lanes that are not
-stepped pass through as the recurrence's identity.  The engine says when a
-snapshot is taken: behind the LAST whole chunk of a prompt that leaves a
-final chunk to prefill (`_snapshot_due`: the deepest block boundary another
-request with the same head can start from), by a copy program of its own
-dispatched behind that chunk's step (`PagedKVCache.snapshot`).  No
-speculative decoding over such a cache (a rejected draft's state cannot be
-rolled back); `stats()` adds `ssm`.
+What the cache asks of the loop beside its steps it says once, at
+construction (kv_cache.py: `closes`, `releases`, `checkpoints`, and
+`no_rollback`, which refuses speculative decoding); the device programs and
+their order are the engine's.  Lanes that hold windows (EVA) add a third
+program, the compaction: when a lane's next position opens a window, the
+host closes the one before (`PagedKVCache.close_window`) and dispatches
+`model.compact_cached` for it, a [prefill_lanes] batch by block index,
+between the step in flight, which wrote the window's last row, and the step
+being built, which reads the summaries.  Positions are the host's by
+counting, so the loop stays a step ahead; a prefill chunk is cut at a
+window's edge (`window_room`).  A cache with further pools and a second
+table (dots3) hands the step all pools as one tuple and ONE table array, so
+a population's uploads stay what they were; what a commit leaves behind a
+lane's window goes back as the last part of `commit`
+(`PagedKVCache.after_commit`, the record's `windows_ms`).  A cache with
+state that is not rows (Falcon-H1) hands the step its pools and state
+buffers as one tuple (`step_pools`); a lane's slot there is its index, and
+a compact program's rows name theirs (`forward_cached`'s `slots`).  A
+prefilling lane starts its scan from zero at position 0 and otherwise from
+what its slot holds (at admission the snapshot `adopt_prefix` copied in);
+padded rows and lanes that are not stepped pass through as the recurrence's
+identity.  A checkpoint is taken behind the LAST whole chunk of a prompt
+that leaves a final chunk to prefill (`_snapshot_due`), by a copy program
+dispatched behind that chunk's step (`PagedKVCache.checkpoint`).
+`stats()` adds `eva`, `sparse` and `windows`, or `ssm` by the cache's
+`kind`.
 
 The weights the step multiplies are prepared once, not in every step:
 `model.serving_params` (one compiled program at construction and at
@@ -133,9 +124,9 @@ import numpy as np
 from ray_tpu import models
 from ray_tpu._private import compile_cache
 from ray_tpu._private.accelerators import leased_chips, require_chip_lease
-from ray_tpu.inference.kv_cache import (PagedKVCache, chain_keys,
-                                        count_pool_copies,
+from ray_tpu.inference.compiled import (count_pool_copies,
                                         count_weight_bytes_copied)
+from ray_tpu.inference.kv_cache import PagedKVCache, chain_keys
 from ray_tpu.ops.attention import paged_blocks_per_step
 from ray_tpu.util import events, spans
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
@@ -633,7 +624,9 @@ class InferenceEngine:
         # What `_upload` handed to the device: populations, transfers (a
         # changed block table's copy among them) and their bytes.
         self._uploads = {"populations": 0, "transfers": 0, "bytes": 0}
-        latent = self.cache.kind in ("latent", "layered")
+        # (Which of these `stats()` carries goes by the cache's `kind`: they
+        # are the parts' own to count once the keys may move, ROADMAP.md.)
+        latent, kind = self.cache.latent, self.cache.kind
         self._latent = {"decode_steps": 0, "ctx_tokens": 0} if latent else None
         self._paged = None if latent else {
             "decode_steps": 0, "ctx_tokens": 0, "runs_live": 0}
@@ -644,36 +637,36 @@ class InferenceEngine:
         # Over layers of several kinds (kv_cache.py), by T=1 step and for
         # ONE layer of each kind: the context tokens an indexer scored, the
         # rows it chose (at most `_index_topk` a lane) and the rows a
-        # window layer attended (at most the window).
-        self._index_topk = max((getattr(run.sizes, "index_topk", 0) for run
-                                in self.model.spec(self.config).runs),
-                               default=0)
+        # window layer attended (at most `_slide_rows`, the window).
+        self._index_topk, self._slide_rows = (
+            max((getattr(run.sizes, name, 0) for run
+                 in self.model.spec(self.config).runs), default=0)
+            for name in ("index_topk", "window"))
         self._sparse = ({"decode_steps": 0, "ctx_tokens": 0,
                          "rows_chosen": 0, "window_rows": 0}
-                        if self.cache.kind == "layered" else None)
+                        if kind == "layered" else None)
         # Over a windowed cache `_paged` counts the rows attended (what the
         # kernel reads), `_eva` the same T=1 steps with their true context
         # beside those rows.  The compaction program is made at its first
         # use (`_compact`: fn, argument shapes, seconds of the first call).
         self._eva = ({"decode_steps": 0, "ctx_tokens": 0, "rows_attended": 0}
-                     if self.cache.window else None)
+                     if kind == "windowed" else None)
         self._compact: dict = {}
-        if self.cache.window and self.spec_k > 0:
-            raise NotImplementedError(
-                "speculative decoding over a windowed cache: a verify chunk "
-                "may cross a window's edge (ROADMAP.md)")
-        if self._sparse is not None and self.spec_k > 0:
-            raise NotImplementedError(
-                "speculative decoding over layers of several kinds: a "
-                "rejected draft's sliding blocks are not rolled back yet")
         # Over a state cache: the tokens its scans (T > 1) and its updates
         # (T = 1) stepped over.
-        self._stateful = self.cache.state is not None
+        self._stateful = kind == "state"
         self._ssm = {"tokens_scanned": 0, "tokens_updated": 0}
-        if self._stateful and self.spec_k > 0:
+        # A verify step writes past what it may commit: the cache says
+        # whether a lane's layout and parts can be rolled back from there.
+        if self.spec_k > 0 and self.cache.no_rollback:
             raise NotImplementedError(
-                "speculative decoding over a state cache: a rejected "
-                "draft's recurrent state cannot be rolled back")
+                f"speculative decoding over a {kind} cache: "
+                f"{self.cache.no_rollback}")
+        # What the cache asks of the loop beside its steps, said once: windows
+        # to close before a batch, blocks to let go of after a commit,
+        # checkpoints behind a prefill step.
+        self._closes, self._releases, self._checkpoints = (
+            self.cache.closes, self.cache.releases, self.cache.checkpoints)
         self._thread: Optional[threading.Thread] = None
         self._stopped = False
         self._auto = auto_start
@@ -993,32 +986,23 @@ class InferenceEngine:
             # held context, of `decode_steps` x lanes x runs a lane.
             **({"paged": dict(self._paged)} if self._latent is None else
                {"latent": dict(self._latent)}),
-            **self._eva_stats(),
+            # Over a windowed cache: those T=1 steps with their true context
+            # beside the rows attended, the windows closed so far and the
+            # pool's blocks by kind now.
+            **({} if self._eva is None else
+               {"eva": {**self._eva, **self.cache.kind_stats()}}),
             **self._moe_stats(),
             # Layers of several kinds: the T=1 steps' sums for one layer of
             # each kind (`_sparse`), and the sliding kind's blocks given
             # back in mid-sequence so far.
             # A state cache: slots of state and of snapshots, what the
             # index did with the snapshots, and the tokens stepped over.
-            **({"ssm": {**self.cache.state_stats(), **self._ssm}}
+            **({"ssm": {**self.cache.kind_stats(), **self._ssm}}
                if self._stateful else {}),
             **({} if self._sparse is None else {
                 "sparse": dict(self._sparse),
-                "windows": {"blocks_freed":
-                            self.cache.stats["slide_blocks_freed"]}}),
+                "windows": self.cache.kind_stats()}),
         }
-
-    def _eva_stats(self) -> dict:
-        """Over a windowed cache: the T=1 steps, the context tokens of
-        their lanes and the rows those lanes attended over instead (host
-        sums of `_build_batch`), the windows closed so far, and the pool's
-        blocks by kind now."""
-        if self._eva is None:
-            return {}
-        summary, exact = self.cache.blocks_by_kind()
-        return {"eva": {**self._eva,
-                        "compactions": self.cache.stats["windows_closed"],
-                        "summary_blocks": summary, "window_blocks": exact}}
 
     def _moe_stats(self) -> dict:
         """An expert configuration's cumulative load, fetched from the
@@ -1049,9 +1033,9 @@ class InferenceEngine:
         (`donated_bytes`: the KV pools), the program's scratch
         (`temp_bytes`), the instructions that copy, slice out or stack
         back the pool or whole layers of it (`pool_copies`, see
-        `kv_cache.count_pool_copies`: 0 when the pool stays where it is)
+        `compiled.count_pool_copies`: 0 when the pool stays where it is)
         and the bytes of weight-shaped results the step makes by opcode
-        (`weight_bytes_copied`, see `kv_cache.count_weight_bytes_copied`:
+        (`weight_bytes_copied`, see `compiled.count_weight_bytes_copied`:
         no `convert`, `copy` or `transpose` when the weights are read
         where they are; a layer scan's slices of its groups are listed).
         Recompiles each shape ahead of time (a persistent-cache hit where
@@ -1079,7 +1063,7 @@ class InferenceEngine:
             if self._stateful:
                 # The state buffer too is updated where it is.
                 out[name]["state_copies"] = count_pool_copies(
-                    text, self.cache.state.shape)
+                    text, self.cache.buffers[0].shape)
         if "compile_s" in self._compact:
             compiled = self._compact["fn"].lower(
                 *self._compact["avals"]).compile()
@@ -1332,7 +1316,7 @@ class InferenceEngine:
                 next_tok, lps = self._run_step(batch, spec)
                 for lane, key in due:
                     # Behind the step that leaves the state in the slot.
-                    self.cache.snapshot(lane, key)
+                    self.cache.checkpoint(lane, key)
             took["dispatch"] += ph.seconds
             newer.append((spec, vtok, lanes, chunks, news, next_tok, lps,
                           batch[3]))
@@ -1391,14 +1375,13 @@ class InferenceEngine:
                         self._commit(lanes, chunks, news, toks, lps)
                     self._work.notify()
                 parts["deliver"] = part.seconds
-                if self.cache.slide_window:
-                    # A sliding kind's blocks that now lie wholly behind
-                    # their lane's window go back to their allocator.
+                if self._releases:
+                    # What a part of the cache keeps of a lane and a commit
+                    # leaves behind it (a sliding window's blocks) goes back.
                     with spans.phase("engine.commit", "windows") as part:
-                        for lanes, *_ in done:
-                            for lane, req in lanes:
-                                if self._lanes[lane] is req:
-                                    self.cache.slide_release(lane)
+                        self.cache.after_commit(
+                            lane for lanes, *_ in done for lane, req in lanes
+                            if self._lanes[lane] is req)
                     parts["windows"] += part.seconds
             took["commit"] = ph.seconds
             wall = ph.t0 + ph.seconds - t_start
@@ -1512,7 +1495,7 @@ class InferenceEngine:
         compaction's dispatch included), `assemble` (host arrays, tables'
         entries, counters), `upload` (the host arrays and the block tables
         handed to the device)."""
-        if self.cache.window:
+        if self._closes:
             with spans.phase("engine.build_batch", "windows") as ph:
                 self._close_windows(lanes)
             parts["windows"] += ph.seconds
@@ -1523,7 +1506,7 @@ class InferenceEngine:
                 news[lane] = int(req.samples(req.next_fed, chunks[lane]))
                 req.ahead_len += chunks[lane]
                 req.ahead_new += news[lane]
-                if self._stateful and prefill:
+                if self._checkpoints and prefill:
                     key = self._snapshot_due(lane, req)
                     if key is not None:
                         due.append((lane, key))
@@ -1666,7 +1649,7 @@ class InferenceEngine:
             seen["ctx_tokens"] += sum(ctx)
             if self._sparse is not None:
                 sp, k, w = (self._sparse, self._index_topk,
-                            self.cache.slide_window)
+                            self._slide_rows)
                 sp["decode_steps"] += 1
                 sp["ctx_tokens"] += sum(ctx)
                 sp["rows_chosen"] += sum(min(c, k) for c in ctx)

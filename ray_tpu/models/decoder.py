@@ -1063,6 +1063,28 @@ def _stacks(spec: Spec, config) -> tuple:
     return (Run("lead_blocks", lead, spec.lead_ffn, spec.attn), main)
 
 
+def cache_kinds(runs, config) -> tuple:
+    """([rows, layers] of the growing kind, the same of the sliding kinds)
+    of runs of several kinds (`Run.table`), as `PagedKVCache.for_model`
+    turns them into pools and a part: one growing latent kind, and at most
+    one sliding kind."""
+    kinds: dict = {}
+    for run in runs:
+        rows = run.attn.rows(run.sizes or config)
+        layers = kinds.setdefault(run.table[0], [rows, 0])
+        layers[1] = max(layers[1], run.first + run.n_layers)
+        if layers[0] != rows or run.attn.pools != 1 or rows.window:
+            raise NotImplementedError(
+                "layers of several kinds: latent rows, one shape a kind")
+    grow = [k for k in kinds.values() if not k[0].slide]
+    slid = [k for k in kinds.values() if k[0].slide]
+    if len(grow) != 1 or len(slid) > 1:
+        raise NotImplementedError(
+            "layers of several kinds: one growing kind and at most one "
+            "sliding kind")
+    return grow[0], slid
+
+
 # --------------------------------------------------------------------------
 # Forward, head and loss
 # --------------------------------------------------------------------------

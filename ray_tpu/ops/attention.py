@@ -508,8 +508,8 @@ def _flash_backward_impl(q, k, v, out, lse, g, causal, scale, block_q,
 # positions onto pool blocks.  A step writes the blocks its new tokens fall
 # in at (layer, block) and attends over the lane's blocks of that layer;
 # both take the whole pool and a layer index, so the pool is never sliced,
-# stacked or copied (`kv_cache.count_pool_copies` checks the compiled
-# program).  The Pallas kernel copies the blocks that hold a lane's context
+# stacked or copied (`inference/compiled.py`'s `count_pool_copies` checks the
+# compiled program).  The Pallas kernel copies the blocks that hold a lane's context
 # out of the pool by scalar-prefetched block-table indices, a run of blocks
 # at a time, and no others (unused table entries are never read; rows past
 # the context length inside the last block are masked); the dense fallback

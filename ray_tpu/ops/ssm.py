@@ -324,3 +324,22 @@ def ssm_scan(state, x, dt, a, bm, cm, slots, fresh, layer=0, *, chunk: int,
     )(layer.reshape(1), slots, fresh.astype(jnp.int32), state, dtx, cm, bm,
       lmat, cs, bw, dec)
     return jnp.moveaxis(y[:, :, :t], 1, 2), state
+
+
+def copy_slot(dst_state, dst_tail, src_state, src_tail, src, dst):
+    """One slot of a state cache copied into another buffer, every layer:
+    dst[:, dst] = src[:, src] for the state and the tail (a snapshot taken
+    or adopted; `dst` out of range: nothing is written).  One
+    `dynamic_update_slice` a buffer, so that the donated destination stays
+    where it is."""
+    with jax.named_scope("ssm_snapshot"):
+        live = (dst >= 0) & (dst < dst_state.shape[1])
+        at = jnp.clip(dst, 0, dst_state.shape[1] - 1)
+        out = []
+        for into, frm in ((dst_state, src_state), (dst_tail, src_tail)):
+            new = jax.lax.dynamic_slice_in_dim(frm, src, 1, axis=1)
+            old = jax.lax.dynamic_slice_in_dim(into, at, 1, axis=1)
+            out.append(jax.lax.dynamic_update_slice_in_dim(
+                into, jnp.where(live, new.astype(into.dtype), old), at,
+                axis=1))
+        return tuple(out)

@@ -124,13 +124,16 @@ def _is_replica_loss(e: BaseException) -> bool:
     return False
 
 
-def _chaos_kill_point() -> None:
+def _chaos_kill_point() -> bool:
     """Serve-plane chaos interposition: a replica process draws one
     deterministic kill verdict per serve event (request dispatch or
-    stream-chunk pull) — see fault_injection.kill_replica."""
+    stream-chunk pull) — see fault_injection.kill_replica.  Whether chaos
+    is on at all."""
     from ray_tpu._private.fault_injection import get_chaos
     chaos = get_chaos()
-    if chaos is not None and chaos.kill_replica():
+    if chaos is None:
+        return False
+    if chaos.kill_replica():
         import logging
         import os
         logging.getLogger("ray_tpu").warning(
@@ -138,6 +141,7 @@ def _chaos_kill_point() -> None:
         events.record("serve", "chaos_kill", pid=os.getpid())
         events.dump_crash("chaos_kill_replica")
         os._exit(1)
+    return True
 
 
 @dataclass
@@ -364,7 +368,10 @@ class ReplicaActor:
         silently truncating the stream with a fake "done"."""
         import asyncio
         import inspect
-        _chaos_kill_point()
+        # Under chaos a reply carries one chunk: a scripted kill names a
+        # replica's N-th serve event, which a stream must reach however
+        # the load batches its chunks (test_spans' torn span, ROADMAP D5).
+        burst = 0 if _chaos_kill_point() else _STREAM_BURST
         gen = self._streams.get(sid)
         if gen is None:
             raise ReplicaStreamLostError(sid)
@@ -411,8 +418,7 @@ class ReplicaActor:
                     return {"done": True}
                 out = {"chunk": chunk}
                 more = []
-                while (ready is not None and len(more) < _STREAM_BURST
-                       and ready()):
+                while ready is not None and len(more) < burst and ready():
                     alive, chunk = _pull()
                     if not alive:
                         self._finish_stream(sid)

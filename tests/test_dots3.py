@@ -105,7 +105,7 @@ def _cached_logits(cfg, params, tokens, chunk, block_size=4, served=False):
         (3, 40, block_size, 128), (3, 40, block_size, 128),
         (6, 12, block_size, 128)]
     cache.allocator.alloc(3)              # lane 1 does not start at block 0
-    cache.slide_allocator.alloc(2)
+    cache.parts[0].index.allocator.alloc(2)
     cache.alloc_lane(1, length)
     tree = dots3.serving_params(params, cfg) if served else params
     pools, out, at = cache.k, [], 0
@@ -133,7 +133,7 @@ def _cached_logits(cfg, params, tokens, chunk, block_size=4, served=False):
         out.append(logits)
         at += t
         cache.seq_lens[1] = at
-        cache.slide_release(1)
+        cache.after_commit([1])
     return jnp.concatenate(out), cache
 
 
@@ -154,7 +154,7 @@ def test_prefill_in_chunks_then_decode_matches_the_reference(cfg, served):
     np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
     # 80 tokens in blocks of 4: the window's 8 positions behind position 80
     # lie in slots 18 and 19 (72..79); every slot behind went back.
-    assert sorted(cache.slide_blocks(1)) == [18, 19]
+    assert sorted(cache.parts[0].held(1)) == [18, 19]
     assert cache.stats["slide_blocks_freed"] == 18
     assert len(cache.lane_blocks(1)) == 20            # the growing kind
 

@@ -63,7 +63,7 @@ def test_the_engine_serves_the_references_greedy_tokens():
     assert st["windows"]["blocks_freed"] > 0
     assert st["moe"]["layer_steps"] % 8 == 0          # 8 expert layers
     # everything went back: no lane holds a block of either kind
-    assert eng.cache.slide_allocator.num_free == 48
+    assert eng.cache.parts[0].index.allocator.num_free == 48
     assert eng.cache.allocator.num_free == 96
 
 
@@ -80,18 +80,18 @@ def test_sliding_blocks_go_back_as_the_window_moves_and_never_one_attended():
         for lane, req in enumerate(eng._lanes):
             if req is None:
                 continue
-            held = cache.slide_blocks(lane)
+            held = cache.parts[0].held(lane)
             length = int(cache.seq_lens[lane])
             first = max(length - (cfg.sliding_window - 1), 0) // bs
             assert min(held, default=first) >= first
             # every position the next token attends lies in a held block
             need = range(first, -(-length // bs))
             assert all(slot in held for slot in need)
-            assert all(cache.slide_allocator.refcount(b) >= 1
+            assert all(cache.parts[0].index.allocator.refcount(b) >= 1
                        for b in held.values())
             seen_peak = max(seen_peak, len(held))
     assert len(h.tokens()) == 50
-    assert seen_peak <= cache._slide_peak(True)
+    assert seen_peak <= cache.parts[0].peak(True)
     assert cache.stats["slide_blocks_freed"] >= (29 + 50 - 8) // bs - 1
 
 
@@ -105,8 +105,8 @@ def test_admission_reserves_each_kind_at_its_own_peak():
     probe = PagedKVCache.for_model(dots3, cfg, num_blocks=(96, 48),
                                    block_size=4, max_lanes=4, max_seq_len=96,
                                    ahead=16)
-    peak = probe._slide_peak(True)
-    assert peak == (9 + 16 - 2) // 4 + 2 and probe._slide_peak(False) == 4
+    peak = probe.parts[0].peak(True)
+    assert peak == (9 + 16 - 2) // 4 + 2 and probe.parts[0].peak(False) == 4
     eng = InferenceEngine("dots3", cfg, params, **dict(
         ENGINE, num_blocks=(96, peak + peak // 2)))
     a = eng.submit(list(range(1, 20)), 6)
@@ -115,7 +115,7 @@ def test_admission_reserves_each_kind_at_its_own_peak():
     assert eng.num_active == 1 and eng.num_waiting == 1
     outs = _run(eng, a, b)
     assert [len(o) for o in outs] == [6, 6]
-    assert eng.cache.slide_allocator.num_free == peak + peak // 2
+    assert eng.cache.parts[0].index.allocator.num_free == peak + peak // 2
 
 
 def test_a_prefix_is_served_only_where_both_kinds_hold_it():
@@ -131,17 +131,19 @@ def test_a_prefix_is_served_only_where_both_kinds_hold_it():
     first = eng.generate(doc + [7, 8, 9], 12)
     hit0 = eng.stats()["prefix_hit_tokens"]
     cache = eng.cache
-    keys = [k for k in cache._index if not isinstance(k[1][0], str)]
-    assert len(cache._slide_index) >= 2
+    keys = [k for _b, k in cache.index.items()
+            if not isinstance(k[1][0], str)]
+    slide = cache.parts[0].index
+    assert len(slide) >= 2
     again = eng.generate(doc + [7, 8, 9], 12)
     assert again == first
     assert eng.stats()["prefix_hit_tokens"] - hit0 == 40
     # only the tail of the sliding kind was shared: slots 8 and 9
     other = eng.generate(doc + [1, 2, 3], 12)
     # evict every sliding block: nothing of the document can be served
-    for block in list(cache._slide_key):
-        cache.slide_allocator.uncache(block)
-        cache._on_slide_evict(block)
+    for block, _key in list(slide.items()):
+        slide.allocator.uncache(block)
+        slide.evicted(block)
     assert cache.match_len(doc + [1, 2, 3]) == 0
     assert len(cache.match_prefix(doc + [1, 2, 3])) == 0
     hit1 = eng.stats()["prefix_hit_tokens"]
@@ -166,16 +168,14 @@ def test_a_match_ends_where_the_sliding_kind_still_holds_its_tail():
     from ray_tpu.inference.kv_cache import chain_keys
     keys = chain_keys(tokens, 4)
     for i in (7, 8, 9):
-        block = cache._slide_index.pop(keys[i])
-        del cache._slide_key[block]
+        cache.parts[0].index.discard(keys[i])
     assert cache.match_len(tokens) == 28
-    block = cache._slide_index.pop(keys[5])
-    del cache._slide_key[block]
+    cache.parts[0].index.discard(keys[5])
     # 7 needs blocks 5, 6; 6 needs 4, 5; 5 needs 3 (held), 4: five blocks
     assert cache.match_len(tokens) == 20
     cache.free_lane(0)
     assert cache.adopt_prefix(1, tokens) == 20
-    assert sorted(cache.slide_blocks(1)) == [3, 4]
+    assert sorted(cache.parts[0].held(1)) == [3, 4]
 
 
 def test_the_wire_format_says_which_kind_a_block_carries():

@@ -42,7 +42,7 @@ def _feed(cache, lane, tokens, upto=None, start=None):
     (41, 3, 17), (64, 5, 40), (65, 3, 17), (100, 4, 28)])
 def test_blocks_needed_is_a_sawtooth(n, blocks, rows):
     cache = _cache()
-    assert cache.blocks_needed(n) == blocks
+    assert cache.layout.blocks_needed(n) == blocks
     assert cache.rows_held(n) == rows
 
 
@@ -50,8 +50,8 @@ def test_a_cache_of_a_row_a_token_is_the_degenerate_case():
     plain = PagedKVCache(2, 2, 4, num_blocks=8, block_size=BS, max_lanes=1,
                          max_seq_len=64)
     assert plain.kind == "kv" and not plain.window
-    assert [plain.blocks_needed(n) for n in (1, 8, 9, 64)] == [1, 1, 2, 8]
-    assert plain.peak_blocks(40) == plain.blocks_needed(40) == 5
+    assert [plain.layout.blocks_needed(n) for n in (1, 8, 9, 64)] == [1, 1, 2, 8]
+    assert plain.layout.peak_blocks(40) == plain.layout.blocks_needed(40) == 5
     assert plain.rows_held(40) == 40 and plain.window_room(3) == 64
     assert not plain.window_due(0, 32) and plain.max_blocks_per_seq == 8
 
@@ -95,7 +95,7 @@ def test_the_table_before_and_after_a_window_closes():
     assert cache.stats["windows_closed"] == 2
     assert cache.blocks_by_kind() == (2, 4 + 4 + 1)
     cache.free_lane(0)
-    assert cache._lane_closed[0] == 0
+    assert cache.layout.closed[0] == 0
     assert cache.allocator.num_free == 32
 
 
@@ -116,7 +116,7 @@ def test_the_peak_admission_reserves(final, closed, peak):
     """The most a lane owns on its way: the close of the last window it
     completes (that window's four blocks, the summaries before it and the
     fresh summary block), unless that is behind it."""
-    assert _cache().peak_blocks(final, closed) == peak
+    assert _cache().layout.peak_blocks(final, closed) == peak
 
 
 def test_admission_counts_the_peak_and_a_lane_never_outgrows_it():
@@ -137,7 +137,7 @@ def test_admission_counts_the_peak_and_a_lane_never_outgrows_it():
         cache.seq_lens[0] = pos + 1
         most = max(most, len(cache.lane_blocks(0)))
         assert cache.lane_peak(0, 100) >= len(cache.lane_blocks(0))
-    assert most == cache.peak_blocks(100) == 7
+    assert most == cache.layout.peak_blocks(100) == 7
 
 
 def _sealed(tokens, n=None):
@@ -163,13 +163,13 @@ def test_a_prefix_match_ends_where_the_prompt_does(n_prompt, covered, blocks):
     assert len(cache.match_prefix(prompt)) == blocks
     got = cache.adopt_prefix(1, prompt)
     assert got == covered and int(cache.seq_lens[1]) == covered
-    assert cache._lane_closed[1] == covered // W
-    assert len(cache.lane_blocks(1)) == cache._blocks_to_start(
+    assert cache.layout.closed[1] == covered // W
+    assert len(cache.lane_blocks(1)) == cache.layout.to_start(
         n_prompt, covered // W) >= blocks
     # ... and the lane goes on from there like the one that sealed them:
     # the summaries it adopted are lane 0's, those it makes are its own
     _feed(cache, 1, tokens, upto=95)
-    assert cache._lane_closed[1] == 2
+    assert cache.layout.closed[1] == 2
     shared = covered // W
     assert cache.lane_blocks(1)[:shared] == cache.lane_blocks(0)[:shared]
     assert not set(cache.lane_blocks(1)[shared:2]) & set(
@@ -198,9 +198,9 @@ def test_a_full_window_without_its_summary_is_adopted_open():
     the match ends at the edge and the adopting lane closes it first."""
     tokens = _tokens(60)
     cache = _sealed(tokens, 32)
-    assert cache.match_len(tokens) == 32 and cache._lane_closed[0] == 0
+    assert cache.match_len(tokens) == 32 and cache.layout.closed[0] == 0
     cache.adopt_prefix(1, tokens)
-    assert cache._lane_closed[1] == 0 and len(cache.lane_blocks(1)) == 4
+    assert cache.layout.closed[1] == 0 and len(cache.lane_blocks(1)) == 4
     assert cache.window_due(1, 32)
     assert len(_feed(cache, 1, tokens)) == 1
 
@@ -253,7 +253,7 @@ def test_export_install_round_trip_carries_both_kinds(n_prompt):
     # the lane that adopts the installed chain seals on as the source did
     dst.adopt_prefix(0, tokens[:95])
     _feed(dst, 0, tokens, upto=95)
-    assert dst._lane_closed[0] == 2
+    assert dst.layout.closed[0] == 2
 
 
 def test_a_dispatched_steps_table_is_not_the_hosts_live_one():

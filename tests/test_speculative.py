@@ -137,7 +137,7 @@ def test_rejection_storm_rolls_back_blocks():
             if req is None:
                 continue
             assert len(spec.cache.lane_blocks(lane)) == \
-                spec.cache.blocks_needed(int(spec.cache.seq_lens[lane]))
+                spec.cache.layout.blocks_needed(int(spec.cache.seq_lens[lane]))
     assert h.tokens() == full               # still token-exact
     st = spec.stats()
     assert st["spec_drafted_tokens"] > 0

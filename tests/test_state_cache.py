@@ -128,8 +128,8 @@ def test_a_match_is_refused_where_blocks_exist_and_the_snapshot_was_evicted():
         _run(eng, eng.submit(prompt, 2))
     cache = eng.cache
     assert [cache.match_len(p) for p in heads] == [0, 32, 32]
-    assert len(cache.match_prefix(heads[0])) == 0 and cache._unserved == 9
-    assert cache._index and eng.stats()["ssm"]["snapshots_evicted"] == 1
+    assert len(cache.match_prefix(heads[0])) == 0 and cache.parts[0].unserved == 9
+    assert len(cache.index) and eng.stats()["ssm"]["snapshots_evicted"] == 1
     again = heads[0][:32] + [1, 2, 3]
     out, = _run(eng, eng.submit(again, 8))
     st = eng.stats()["ssm"]
@@ -145,15 +145,15 @@ def test_slots_and_snapshot_slots_are_all_free_after_the_lanes_go():
     cache, st = eng.cache, eng.stats()
     assert st["active"] == 0 and st["ssm"]["state_slots_live"] == 0
     assert cache.allocator.num_free == cache.allocator.num_blocks
-    assert cache.snap_allocator.num_free == cache.snap_allocator.num_blocks
-    assert all(cache.snap_allocator.refcount(s) == 0
-               for s in cache._snap_key)
+    snaps = cache.parts[0].index
+    assert snaps.allocator.num_free == snaps.allocator.num_blocks
+    assert all(snaps.allocator.refcount(s) == 0 for s, _key in snaps.items())
     # a snapshot goes with the block it stands behind
-    for block in list(cache._block_key):
+    for block, _key in list(cache.index.items()):
         cache.allocator.uncache(block)
-        cache._on_evict(block)
-    assert not cache._snap_index and not cache._snap_key
-    assert cache.snap_allocator.num_unused == cache.snap_allocator.num_blocks
+        cache.index.evicted(block)
+    assert not len(snaps) and not list(snaps.items())
+    assert snaps.allocator.num_unused == snaps.allocator.num_blocks
 
 
 def test_what_a_state_cannot_do_is_refused():

@@ -21,7 +21,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu._private.accelerators import (
     ChipAllocator, chip_env, leasable)
-from ray_tpu.inference.kv_cache import (count_pool_copies,
+from ray_tpu.inference.compiled import (count_pool_copies,
                                         count_weight_bytes_copied)
 from ray_tpu.models import decoder, gpt
 from ray_tpu.ops.attention import (kv_row_width, paged_blocks_per_step,
