@@ -88,8 +88,12 @@ a compact program's rows name theirs (`forward_cached`'s `slots`).  A
 prefilling lane starts its scan from zero at position 0 and otherwise from
 what its slot holds (at admission the snapshot `adopt_prefix` copied in);
 padded rows and lanes that are not stepped pass through as the recurrence's
-identity.  A checkpoint is taken behind the LAST whole chunk of a prompt
-that leaves a final chunk to prefill (`_snapshot_due`), by a copy program
+identity.  A checkpoint is taken where the prefix index has SEEN a shared
+head end (blocks its match found and no snapshot could serve: the lane
+that prefills them again cuts a chunk there), and by a lane that matched
+nothing behind the last whole chunk of its prompt that leaves a final
+chunk to prefill (`_snapshot_due`); never by a lane that adopted all its
+match, whose further chunks are its own turn.  The copy program is
 dispatched behind that chunk's step (`PagedKVCache.checkpoint`).
 `stats()` adds `eva`, `sparse` and `windows`, or `ssm` by the cache's
 `kind`.
@@ -127,6 +131,7 @@ from ray_tpu._private.accelerators import leased_chips, require_chip_lease
 from ray_tpu.inference.compiled import (count_pool_copies,
                                         count_weight_bytes_copied)
 from ray_tpu.inference.kv_cache import PagedKVCache, chain_keys
+from ray_tpu.models.decoder import layer_counts
 from ray_tpu.ops.attention import paged_blocks_per_step
 from ray_tpu.util import events, spans
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
@@ -288,6 +293,11 @@ class _Request:
     # the prompt's blocks up by it and walks no prompt (`_admit`,
     # `_head_is_being_sealed`).
     chain: Optional[List[tuple]] = None
+    # Over a cache that checkpoints: the prompt length behind which the
+    # cache wants one of this lane (`PagedKVCache.checkpoint_wanted`; 0:
+    # nowhere), and whether its admission matched no block at all.
+    snap_at: int = 0
+    cold: bool = False
 
     @property
     def prefilling(self) -> bool:
@@ -655,6 +665,10 @@ class InferenceEngine:
         # Over a state cache: the tokens its scans (T > 1) and its updates
         # (T = 1) stepped over.
         self._stateful = kind == "state"
+        # The layers a step runs by kind (K/V rows, state, experts): in a
+        # stack of one-part layers no reader can take them from `n_layers`.
+        self._layers = layer_counts(self.model.spec(self.config),
+                                    self.config)
         self._ssm = {"tokens_scanned": 0, "tokens_updated": 0}
         # A verify step writes past what it may commit: the cache says
         # whether a lane's layout and parts can be rolled back from there.
@@ -962,6 +976,9 @@ class InferenceEngine:
                 "parts": list(_PARTS), "rows": self._timeline_rows()},
             "admitted": self._admitted,
             "queue_wait_s": self._queue_wait_s,
+            # The layers a step runs that keep K/V rows, that keep a
+            # recurrent state, and that have dropless experts.
+            "layers": dict(self._layers),
             # Of those steps: `steps` dispatched while the step before was
             # still unfetched, `sync_steps` could not (a proposer drafts
             # from the fetched token; nothing was in flight; nothing was
@@ -1021,9 +1038,8 @@ class InferenceEngine:
                "experts_hit": load[-2], "layer_steps": load[-1]}
         if len(load) - 2 < c.n_experts:
             out["assignments_held"] = out["assignments"]
-            out["assignments"] = (
-                self._tokens_run * c.n_experts_per_tok
-                * (c.n_layers - getattr(c, "first_dense_layers", 0)))
+            out["assignments"] = (self._tokens_run * c.n_experts_per_tok
+                                  * self._layers["experts"])
         return {"moe": out}
 
     def compiled_steps(self) -> dict:
@@ -1140,6 +1156,9 @@ class InferenceEngine:
             reused = self.cache.adopt_prefix(lane, req.prompt, req.chain)
             self._waiting.popleft()
             req.fed = reused
+            if self._checkpoints:
+                req.snap_at = self.cache.checkpoint_wanted(lane)
+                req.cold = not (reused or req.snap_at)
             self._lanes[lane] = req
             met["hit_tokens"].inc(reused)
             met["miss_tokens"].inc(len(req.prompt) - reused)
@@ -1518,16 +1537,21 @@ class InferenceEngine:
 
     def _snapshot_due(self, lane: int, req: _Request):
         """The chain key to snapshot `lane`'s state under behind the chunk
-        just planned, or None: the chunk ends on a block's edge, short of
-        the prompt's end, and what is left of the prompt is one chunk (the
-        last such edge of a prompt: where the longest head it can share
-        with another request ends, if that is a multiple of the chunk from
-        where this lane began).  One snapshot a prompt, not one a chunk: a
-        pool of a few slots keeps the heads that are asked for."""
+        just planned, or None.  Where the index has seen a head shared
+        (`snap_at`: blocks this lane's admission matched and no snapshot
+        could serve), there: `_build_batch` cut the chunk to end on it.
+        A lane that matched nothing has seen nothing: behind the chunk that
+        ends on a block's edge and leaves one chunk of the prompt to
+        prefill (the last such edge: where the longest head it can share
+        with another request ends, if that is a multiple of the chunk).  A
+        lane that adopted all it matched takes none: what it prefills is
+        its own turn, and a snapshot behind it would push a shared head's
+        out of the few slots.  One snapshot a prompt, not one a chunk."""
         end = int(self.cache.seq_lens[lane]) + req.ahead_len
         left = len(req.prompt) - end
-        if req.chain is None or end % self.cache.block_size \
-                or not 0 < left <= self.prefill_chunk:
+        if req.chain is None or end % self.cache.block_size or left <= 0 \
+                or not (end == req.snap_at
+                        or req.cold and left <= self.prefill_chunk):
             return None
         return req.chain[end // self.cache.block_size - 1]
 
@@ -1594,6 +1618,8 @@ class InferenceEngine:
                 # (cut at a window's edge: a slice lies inside one window)
                 chunk = min(t, len(req.prompt) - fed,
                             self.cache.window_room(start))
+                if start < req.snap_at:     # end where a snapshot is due
+                    chunk = min(chunk, req.snap_at - start)
                 tokens[row, :chunk] = req.prompt[fed:fed + chunk]
             else:
                 # Speculative lanes feed [last_token, d_1 .. d_k]; the

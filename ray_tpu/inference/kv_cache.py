@@ -36,8 +36,9 @@ makes; none of its shared methods tests which kind it serves
 * PARTS (`PagedKVCache.parts`, a `CachePart` each; none for "kv", "latent"
   and "windowed"): what a lane keeps BESIDE its chain of growing blocks.
   `SlidingRows` (`kind` "layered", dots3's window layers beside its full
-  ones) and `LaneState` (`kind` "state", Falcon-H1's state-space mixer
-  beside its attention heads).  The manager loops over them at each point
+  ones) and `LaneState` (`kind` "state", a state-space mixer's state:
+  beside the attention heads in every layer, or in layers of its own with
+  their own count).  The manager loops over them at each point
   of a lane's life: which head of a matched chain the part can serve too,
   whether it admits, adopt, grow, seal, release after a commit, free, its
   share of the wire format's `more`, its buffers beside the pools, and why
@@ -86,7 +87,7 @@ from ray_tpu.inference.compiled import (  # noqa: F401
     count_pool_copies, count_weight_bytes_copied)
 from ray_tpu.ops.attention import (kv_row_width, pack_kv_rows,
                                    unpack_kv_rows)
-from ray_tpu.ops.ssm import copy_slot
+from ray_tpu.ops.ssm import copy_slot, state_shape
 
 # Root of every hash chain (a block with no parent).
 _ROOT_HASH = 0
@@ -593,6 +594,11 @@ class CachePart:
         far have run) as standing behind the block of `key`."""
         return False
 
+    def wanted(self, lane: int) -> int:
+        """The blocks of the lane's prompt behind which a `checkpoint` is
+        wanted (0: nowhere that the part knows of)."""
+        return 0
+
     def export(self, keys: List[Tuple]) -> dict:
         """Its share of the wire format's `more` for a chain of `keys`."""
         return {}
@@ -755,7 +761,10 @@ class LaneState(CachePart):
     """State that is not rows: what a layer's mixer keeps of a lane
     (`rows`: `decoder.StateRows`), of a fixed size whatever the lane's
     length.  One SLOT a lane a layer in two buffers beside the K and V
-    pools (`state` [n_layers, max_lanes + 1, heads, d_state, head_dim] and
+    pools, over its OWN `n_layers` (the model's layers that have the mixer,
+    which need not be those that have K and V rows): `state` [n_layers,
+    max_lanes + 1, heads, d_state, head_dim] (narrow heads folded:
+    `ops.ssm.state_shape`) and
     `tail` [n_layers, max_lanes + 1, rows x width]; a lane's slot is its
     index, the last slot is where a program sends the rows nobody has),
     overwritten by every step: `step_pools` hands the step all four as one
@@ -768,7 +777,11 @@ class LaneState(CachePart):
     when one is taken (`checkpoint`, behind the prefill step that left the
     state there); a match is served only up to a block a snapshot stands
     behind (`serves`), and adopting it copies the snapshot into the lane's
-    slot ahead of the lane's first step (`adopt`)."""
+    slot ahead of the lane's first step (`adopt`).  Blocks that matched
+    PAST the last snapshot are what the index has seen shared and cannot
+    serve: the lane that prefills them again is asked for a snapshot behind
+    the last of them (`wanted`), so a shared head gets its snapshot where
+    it ends, wherever the requests' own ends lie."""
 
     kind = "state"
     wire = ("state", "tail")
@@ -784,7 +797,8 @@ class LaneState(CachePart):
         if snapshots is None:
             snapshots = max(2, cache.max_lanes // 4)
         self.slots = int(snapshots) if cache.prefix_cache_enabled else 0
-        one = (rows.heads, rows.d_state, rows.head_dim)
+        one = state_shape(rows.heads, rows.d_state, rows.head_dim,
+                          rows.groups)
         # (a tail's K - 1 rows one behind the other in ONE row of its
         # slot: as [slots, 3, width] the compiler lays the three rows
         # out one way for a program of all lanes and another for a
@@ -797,8 +811,10 @@ class LaneState(CachePart):
         self.snaps = jnp.zeros((n_layers, snaps) + one, jnp.float32)
         self.snap_tails = jnp.zeros((n_layers, snaps) + row, dtype)
         self.index = SealedIndex(snaps)
-        # blocks the last match found and could serve none of
-        self.unserved = 0
+        # blocks the last match found past the last snapshot it could serve
+        self.beyond = 0
+        # lane -> the blocks of its prompt a snapshot is wanted behind
+        self._wanted = [0] * cache.max_lanes
         cache.stats.update(snapshots_taken=0, snapshots_adopted=0,
                            snapshot_misses=0)
         donate = () if jax.default_backend() == "cpu" else (0, 1)
@@ -836,20 +852,26 @@ class LaneState(CachePart):
         there."""
         held = next((m for m in range(len(keys), 0, -1)
                      if keys[m - 1] in self.index), 0)
-        self.unserved = 0 if held else len(keys)
+        self.beyond = len(keys) - held
         return held
 
     def adopt(self, lane, n_tokens, keys=None):
         """The snapshot behind the last adopted block into the lane's slot,
         ahead of the lane's first step (and most recently used)."""
-        if keys is not None and self.unserved:
-            self.cache.stats["snapshot_misses"] += 1  # blocks, no snapshot
+        self._wanted[lane] = 0
+        if keys is not None and self.beyond:
+            self._wanted[lane] = len(keys) + self.beyond
+            # blocks, no snapshot
+            self.cache.stats["snapshot_misses"] += not keys
         if keys:
             slot = self.index.get(keys[-1])
             self.index.allocator.incref(slot)
             self._move(slot, lane, take=False)
             self.index.allocator.decref(slot)
             self.cache.stats["snapshots_adopted"] += 1
+
+    def wanted(self, lane):
+        return self._wanted[lane]
 
     def dropped(self, key):
         """A snapshot goes with the block it stands behind."""
@@ -901,6 +923,7 @@ class LaneState(CachePart):
         latter (engine `stats()["ssm"]`)."""
         cs = cache.stats
         return {
+            "state_layers": int(self.state.shape[0]),
             "state_slots": self.cache.max_lanes,
             "state_slots_live": sum(map(bool, self.cache._lane_blocks)),
             "state_bytes": int(self.state.nbytes + self.tail.nbytes),
@@ -1030,30 +1053,43 @@ class PagedKVCache:
         layout and the pools from the rows its spec's attention leaves
         there (`decoder.CacheRows`), a `SlidingRows` part where some of its
         runs keep a sliding window's rows in pools of their own, a
-        `LaneState` part where its layers have a mixer beside the attention
-        (`decoder.StateRows`).  `ahead`: the positions a lane may be
-        written past its committed length."""
+        `LaneState` part where some of its layers have a mixer
+        (`decoder.StateRows`), beside the attention or in its place.  The
+        pools have as many layers as the runs with an attention count
+        (`Run.first`), the state part as many as those with the mixer.
+        `ahead`: the positions a lane may be written past its committed
+        length."""
         from ray_tpu.models.decoder import cache_kinds
         kw.setdefault("max_seq_len", config.max_seq_len)
         kw.setdefault("dtype", config.dtype)
         spec = model.spec(config)
         n_layers, rows, latent = (config.n_layers, spec.attn.rows(config),
                                   spec.attn.pools == 1)
+
+        def layers_of(runs):
+            return max((run.first + run.n_layers for run in runs), default=0)
+
         parts = []
-        if any(run.pools for run in spec.runs):
+        if any(run.table for run in spec.runs):
             (rows, n_layers), slid = cache_kinds(spec.runs, config)
             if slid:
                 (s, layers), = slid
                 parts.append(lambda cache, n: SlidingRows(
                     cache, layers, s.kv_heads, s.head_dim, s.slide, ahead, n))
-        mixers = [run.mixer for run in spec.runs if run.mixer is not None]
-        if mixers:
-            if len(spec.runs) != 1 or latent:
+        elif spec.runs:
+            n_layers = layers_of(
+                run for run in spec.runs if run.attn is not None)
+        mixed = [run for run in spec.runs if run.mixer is not None]
+        if mixed:
+            if latent or not n_layers or any(run.table for run in mixed):
                 raise NotImplementedError(
-                    "a state cache: one run of layers, each with the mixer "
-                    "beside K and V pools")
+                    "a state cache: K and V pools of at least one layer "
+                    "with an attention beside the mixers' state (a lane's "
+                    "chain of blocks is what its snapshots are keyed by)")
+            state_layers = layers_of(mixed)
             parts.append(lambda cache, n: LaneState(
-                cache, n_layers, mixers[0].state(config), kw["dtype"], n))
+                cache, state_layers, mixed[0].mixer.state(config),
+                kw["dtype"], n))
         return cls(n_layers, rows.kv_heads, rows.head_dim, latent=latent,
                    window=rows.window, chunk=rows.chunk, _extra=rows.extra,
                    _parts=parts, **kw)
@@ -1498,6 +1534,13 @@ class PagedKVCache:
         """Behind the step that has just been dispatched: what the parts
         keep of the lane to stand behind the block of chain key `key`."""
         return any([part.checkpoint(lane, key) for part in self.parts])
+
+    def checkpoint_wanted(self, lane: int) -> int:
+        """The length of the lane's prompt behind which some part wants a
+        `checkpoint` (0: none does): where the blocks its admission matched
+        ended, if the part could not serve them all."""
+        return self.block_size * max(
+            (part.wanted(lane) for part in self.parts), default=0)
 
     def kind_stats(self) -> dict:
         """The layout's and the parts' own counters, as one dict."""

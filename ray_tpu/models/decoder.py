@@ -1,7 +1,8 @@
 """The decoder-only transformer, defined once for every LM family.
 
 A family (models/gpt.py, models/llama.py, models/axk1.py,
-models/evabyte.py, models/dots3.py, models/falconh1.py) is a config
+models/evabyte.py, models/dots3.py, models/falconh1.py,
+models/nemotronh.py) is a config
 dataclass, its parameter format (`init_params`, `param_specs`) and
 `spec(config)`: a `Spec` naming the parts its block is made of (norms, an
 `Attention`, a `FeedForward`, a leading run of layers with another
@@ -37,6 +38,11 @@ Design (no reference counterpart: Ray hosts models, it doesn't ship them):
     each path by its own factor where the model states them
     (`Multipliers`).  Over a cache the mixer's state is not rows: a
     fixed-size slot a lane, overwritten by every step;
+  * a run's layers may be ONE part alone: the mixer with no attention, the
+    attention with no feed-forward behind it, or the feed-forward with
+    nothing in front (`Run.attn`, `Run.ffn`, `Run.mixer` None), each behind
+    its one norm; a stack is then its runs in order, the runs of a kind
+    sharing one stack of leaves and one part of the cache;
   * `jax.checkpoint` (remat) on the block when configured: trades FLOPs for
     HBM, the standard TPU memory lever.
 
@@ -200,12 +206,34 @@ def moe_ffn(h, p, config, mesh=None, valid=None):
     deployment's chip).  The router still chooses among all E, this layer
     computes the assignments that fall on its own, and the rest add
     nothing here: they are another chip's part of the sum.  The load is
-    the assignments each held expert took."""
+    the assignments each held expert took.
+
+    An expert is a SwiGLU over `w_gate`, `w_up`, `w_down`; where the layer
+    has no `w_gate` it is `w_down relu(w_up x)^2` over two
+    (`ops.moe.expert_ffn`).  The up matrix may be held as published,
+    [E, F, D] under the name `w_up_t`: what an expert width that is no
+    multiple of 128 needs (`ops.moe.grouped_matmul`)."""
     from ray_tpu.ops import moe
 
     c = config
     b, l, d = h.shape
-    x = h.reshape(b * l, d)
+    x, experts, weights = _route(h, p, c)
+    held_t = "w_up_t" in p
+    up = p["w_up_t" if held_t else "w_up"]
+    share = p.get("w_gate", up).shape[-3] < c.n_experts
+    y, load = moe.expert_ffn(
+        x, experts, weights, p.get("w_gate"), up, p["w_down"],
+        p["layer"], None if valid is None else valid.reshape(-1),
+        first_held=c.experts_offset if share else None,
+        up_transposed=held_t)
+    return y.reshape(b, l, d), None, load
+
+
+def _route(h, p, config):
+    """`moe_ffn`'s router: (x [T, D], each token's chosen experts [T, k],
+    what each counts for [T, k] float32)."""
+    c = config
+    x = h.reshape(-1, h.shape[-1])
     logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
     scores = (jax.nn.sigmoid(logits) if c.scoring_func == "sigmoid"
@@ -222,12 +250,7 @@ def moe_ffn(h, p, config, mesh=None, valid=None):
         weights = weights / jnp.sum(weights, -1, keepdims=True)
     if c.routed_scale != 1.0:
         weights = weights * c.routed_scale
-    share = p["w_gate"].shape[-3] < c.n_experts
-    y, load = moe.expert_ffn(
-        x, experts, weights, p["w_gate"], p["w_up"], p["w_down"],
-        p["layer"], None if valid is None else valid.reshape(-1),
-        first_held=c.experts_offset if share else None)
-    return y.reshape(b, l, d), None, load
+    return x, experts, weights
 
 
 def shared_moe_ffn(h, p, config, mesh=None, valid=None):
@@ -240,6 +263,18 @@ def shared_moe_ffn(h, p, config, mesh=None, valid=None):
     up = jnp.einsum("bld,df->blf", h, p["ws_up"].astype(h.dtype))
     shared = jnp.einsum("blf,fd->bld", gate * up,
                         p["ws_down"].astype(h.dtype))
+    return shared + y, None, load
+
+
+def shared_relu2_moe_ffn(h, p, config, mesh=None, valid=None):
+    """`moe_ffn` over two-matrix squared-ReLU experts (no `w_gate`; the up
+    matrix held [E, F, D], `w_up_t`) beside a shared expert of the same
+    form every token passes through (`ws_up`, `ws_down`), added as it
+    is."""
+    y, _, load = moe_ffn(h, p, config, mesh, valid)
+    hidden = jnp.square(jax.nn.relu(
+        jnp.einsum("bld,df->blf", h, p["ws_up"].astype(h.dtype))))
+    shared = jnp.einsum("blf,fd->bld", hidden, p["ws_down"].astype(h.dtype))
     return shared + y, None, load
 
 
@@ -304,6 +339,9 @@ SHARED_EXPERTS = FeedForward(shared_moe_ffn,
                              cast=("ws_gate", "ws_up", "ws_down"),
                              whole=("w_gate", "w_up", "w_down"),
                              trains=False)
+SHARED_RELU2_EXPERTS = FeedForward(shared_relu2_moe_ffn,
+                                   cast=("ws_up", "ws_down"),
+                                   whole=("w_up_t", "w_down"), trains=False)
 SWITCH = FeedForward(switch_moe, serves=False)
 
 
@@ -719,7 +757,7 @@ EVA = Attention(eva_attention, eva_attention_cached,
 
 
 # --------------------------------------------------------------------------
-# Parts: a second mixer beside the attention.  `apply(h, p, config)` on
+# Parts: a mixer, beside the attention or alone.  `apply(h, p, config)` on
 # normed h [B, L, D] is the mixer over a whole sequence from its zero
 # state, projected back to [B, L, D]; `cached(h, pools, p, config, slots,
 # positions, valid)` continues each row's state in the mixer's buffers
@@ -732,13 +770,16 @@ EVA = Attention(eva_attention, eva_attention_cached,
 class StateRows:
     """What a mixer keeps of a lane between steps, as the cache manager
     needs to know it (`PagedKVCache.for_model`): a float32 state
-    [heads, d_state, head_dim] and the last `conv - 1` rows of `conv_width`
-    columns that its convolution reads again, a layer each."""
+    [heads, d_state, head_dim] (stored as `ops.ssm.state_shape` folds it:
+    heads narrower than the lane width side by side) and the last
+    `conv - 1` rows of `conv_width` columns that its convolution reads
+    again, a layer each."""
     heads: int
     head_dim: int
     d_state: int
     conv: int
     conv_width: int
+    groups: int = 1     # heads that share B and C fold together, or none
 
 
 def _ssm_split(h, p, config):
@@ -833,8 +874,10 @@ def _slot_rows(buffer, layer, slots, b: int, new=None):
 
 def ssm_mixer_cached(h, pools, p, config, slots, positions, valid):
     """The mixer over a slice, continued from each row's slot (`slots` [B];
-    None: row i's is slot i) of the two buffers `pools` = (state [L, S, H, N, P] float32, tail
-    [L, S, (K - 1) C]: a slot's K - 1 rows one behind the other) at `p["cache_layer"]`: a row whose slice starts at
+    None: row i's is slot i) of the two buffers `pools` = (state
+    [L, S, H, N, P] float32 (as `ops.ssm.state_shape` folds a slot), tail
+    [L, S, (K - 1) C]: a slot's K - 1 rows one behind the other) at
+    `p["cache_layer"]`: a row whose slice starts at
     position 0 starts from nothing, every other from what its slot holds
     (what the step before left there, or a snapshot the engine copied in),
     and the slot is left holding the state and the convolution's tail
@@ -885,7 +928,7 @@ SSM = Mixer(ssm_mixer, ssm_mixer_cached,
             state=lambda c: StateRows(
                 c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_conv,
                 c.ssm_heads * c.ssm_head_dim
-                + 2 * c.ssm_groups * c.ssm_state),
+                + 2 * c.ssm_groups * c.ssm_state, c.ssm_groups),
             cast=("w_in", "w_out"))
 
 
@@ -910,13 +953,18 @@ class Run:
     """A run of like layers in a model's stack: its feed-forward, its
     attention and where its layers' leaves and cached rows live.  A model
     whose layers differ in more than a leading run's feed-forward names
-    its runs itself (`Spec.runs`), in order."""
+    its runs itself (`Spec.runs`), in order.  `attn` None: no attention
+    (then `mixer` stands alone behind the first norm, or there is no first
+    norm at all); `ffn` None: no second norm and no feed-forward."""
     blocks: str             # the key of its stacks in the parameter tree
     n_layers: int
-    ffn: FeedForward
-    attn: Attention
-    first: int = 0          # its first layer's index in its pools,
-    offset: int = 0         # and in its stacks (the runs of a kind share one)
+    ffn: Optional[FeedForward]
+    attn: Optional[Attention]
+    # Its first layer's index in its pools (a run of one part: in that
+    # part's, the attention's K and V or the mixer's buffers; of both: in
+    # both alike), and in its stacks (the runs of a kind share one).
+    first: int = 0
+    offset: int = 0
     # What its attention reads where that is not the model's config
     # (`LatentSizes`).
     sizes: Any = None
@@ -924,8 +972,8 @@ class Run:
     # many equal parts) of the block tables' columns: `None` is all.
     pools: Optional[tuple] = None
     table: Optional[tuple] = None
-    # A second mixer beside the attention, on the same normed input; over
-    # a cache its buffers follow the attention's pools.
+    # A mixer beside the attention, on the same normed input, or in its
+    # place; over a cache its buffers follow the attention's pools.
     mixer: Optional[Mixer] = None
 
 
@@ -944,8 +992,12 @@ class Spec:
     init_params: Callable   # (config, key) -> params
     param_specs: Callable   # (config) -> the congruent logical-spec tree
     attn: Attention = HEADS
-    # None: a learned table `pos_embed` added to the token embedding.
+    # The rotation's base; None: no rotation.  Positions then come from a
+    # learned table `pos_embed` added to the token embedding, or
+    # (`pos_table` false) from nowhere: causal order alone, as a model has
+    # it whose state-space layers carry the order.
     rope_theta: Optional[float] = None
+    pos_table: bool = True
     # Rotary frequencies other than theta's own (`yarn_freqs`), and the
     # factor on the scores where it is not head_dim ** -0.5 (`LATENT`).
     rope_freqs: Optional[tuple] = None
@@ -982,19 +1034,24 @@ def _norm(spec: Spec, x, p, leaves):
 def _block(x, p, spec: Spec, run: Run, config, mesh, position_offset=0):
     c = config
     m = spec.mult or Multipliers()
-    h = _norm(spec, x, p, spec.attn_norm)
-    x = x + _scaled(run.attn.apply(_scaled(h, m.attn_in), p, spec,
-                                   run.sizes or c, mesh, position_offset),
-                    m.attn_out)
+    if run.attn is not None or run.mixer is not None:
+        h = _norm(spec, x, p, spec.attn_norm)
+    if run.attn is not None:
+        x = x + _scaled(run.attn.apply(_scaled(h, m.attn_in), p, spec,
+                                       run.sizes or c, mesh,
+                                       position_offset), m.attn_out)
     if run.mixer is not None:
         x = x + _scaled(run.mixer.apply(_scaled(h, m.mixer_in), p, c),
                         m.mixer_out)
 
-    h = _norm(spec, x, p, spec.mlp_norm)
-    y, aux, _ = run.ffn.apply(h, p, c, mesh)
+    aux = None
+    if run.ffn is not None:
+        h = _norm(spec, x, p, spec.mlp_norm)
+        y, aux, _ = run.ffn.apply(h, p, c, mesh)
+        x = x + y
     if aux is None:
         aux = jnp.zeros((), jnp.float32)
-    x = with_logical_constraint(x + y, ("batch", "length", "act_embed"),
+    x = with_logical_constraint(x, ("batch", "length", "act_embed"),
                                 mesh=mesh)
     return x, aux
 
@@ -1007,23 +1064,28 @@ def _block_cached(x, pools, p, spec: Spec, run: Run, config,
     (`Attention.cached`).  x [B, T, D]; positions [B, T] absolute; ctx_lens
     [B] = context length including this slice.  A run with a mixer beside
     its attention hands that the pools behind the attention's and each
-    row's slot in them (`slots` [B]).  Returns (x, pools, the expert
-    layer's load or None)."""
+    row's slot in them (`slots` [B]).  A run of one part runs that part
+    alone, over the pools that are its (`Run.pools`).  Returns (x, pools,
+    the expert layer's load or None)."""
     m = spec.mult or Multipliers()
-    h = _norm(spec, x, p, spec.attn_norm)
-    n = run.attn.pools if run.mixer is not None else len(pools)
-    attn, rows = run.attn.cached(_scaled(h, m.attn_in), pools[:n], p, spec,
-                                 run.sizes or config, block_tables,
-                                 positions, valid, ctx_lens)
-    x = x + _scaled(attn, m.attn_out)
+    if run.attn is not None or run.mixer is not None:
+        h = _norm(spec, x, p, spec.attn_norm)
+    n = (0 if run.attn is None else
+         run.attn.pools if run.mixer is not None else len(pools))
+    if run.attn is not None:
+        attn, rows = run.attn.cached(_scaled(h, m.attn_in), pools[:n], p,
+                                     spec, run.sizes or config, block_tables,
+                                     positions, valid, ctx_lens)
+        x = x + _scaled(attn, m.attn_out)
+        pools = (*rows, *pools[n:])
     if run.mixer is not None:
         y, state = run.mixer.cached(_scaled(h, m.mixer_in), pools[n:], p,
                                     config, slots, positions, valid)
         x = x + _scaled(y, m.mixer_out)
-        pools = (*rows, *state)
-    else:
-        pools = rows
+        pools = (*pools[:n], *state)
 
+    if run.ffn is None:
+        return x, pools, None
     h = _norm(spec, x, p, spec.mlp_norm)
     y, _, load = run.ffn.apply(h, p, config, valid=valid)
     return x + y, pools, load
@@ -1050,6 +1112,11 @@ def _layer_of(blocks: dict, i, whole: tuple = ()) -> dict:
     return {**p, "layer": i}
 
 
+def _whole(run: Run) -> tuple:
+    """The leaves of a run's layers that stay outside the layer loop."""
+    return run.ffn.whole if run.ffn is not None else ()
+
+
 def _stacks(spec: Spec, config) -> tuple:
     """The runs of like layers, in order: the spec's own, or a leading run
     of `first_dense_layers` layers with another feed-forward and the
@@ -1061,6 +1128,18 @@ def _stacks(spec: Spec, config) -> tuple:
     if not lead:
         return (main,)
     return (Run("lead_blocks", lead, spec.lead_ffn, spec.attn), main)
+
+
+def layer_counts(spec: Spec, config) -> dict:
+    """The layers a step runs by what they keep or read: `kv` with an
+    attention (cached rows), `state` with a mixer (a recurrent state),
+    `experts` with dropless experts.  In a stack of one-part layers these
+    are three different numbers, none of them `n_layers`."""
+    runs = _stacks(spec, config)
+    return {
+        "kv": sum(r.n_layers for r in runs if r.attn is not None),
+        "state": sum(r.n_layers for r in runs if r.mixer is not None),
+        "experts": sum(r.n_layers for r in runs if _whole(r))}
 
 
 def cache_kinds(runs, config) -> tuple:
@@ -1099,7 +1178,7 @@ def forward_trunk(family, params: dict, tokens: jax.Array, config,
     rotate RoPE from p (there a scalar or a per-lane [B] array)."""
     c, spec = config, family(config)
     x = params["tok_embed"][tokens].astype(c.dtype)
-    if spec.rope_theta is None:
+    if spec.rope_theta is None and spec.pos_table:
         pos = jax.lax.dynamic_slice_in_dim(params["pos_embed"],
                                            position_offset, tokens.shape[1])
         x = x + pos[None].astype(c.dtype)
@@ -1111,7 +1190,7 @@ def forward_trunk(family, params: dict, tokens: jax.Array, config,
 
     aux = None
     for run in _stacks(spec, c):
-        n_layers, ffn = run.n_layers, run.ffn
+        n_layers = run.n_layers
         block = partial(_block, spec=spec, run=run, config=c, mesh=mesh,
                         position_offset=position_offset)
         if c.remat:
@@ -1122,7 +1201,7 @@ def forward_trunk(family, params: dict, tokens: jax.Array, config,
         if run.offset or n_layers < jax.tree.leaves(blocks)[0].shape[0]:
             blocks = {k: v[run.offset:run.offset + n_layers]
                       for k, v in blocks.items()}
-        scanned, whole = _layer_stack(blocks, n_layers, ffn.whole)
+        scanned, whole = _layer_stack(blocks, n_layers, _whole(run))
 
         def body(x, layer, block=block, whole=whole):
             p, i = layer
@@ -1184,12 +1263,12 @@ def loss_fn(family, params: dict, batch: dict, config, mesh=None):
 
     c, spec = config, family(config)
     runs = _stacks(spec, c)
-    if not all(run.ffn.trains for run in runs):
+    if not all(run.ffn is None or run.ffn.trains for run in runs):
         raise NotImplementedError(
             "training an expert configuration is not supported yet: the "
             "grouped matmul (ops/moe.py) has no backward pass and the "
             "router's auxiliary losses are not computed (ROADMAP.md R1)")
-    if not all(run.attn.trains for run in runs):
+    if not all(run.attn is None or run.attn.trains for run in runs):
         raise NotImplementedError(
             "this attention has no train path yet: latent attention is "
             "served absorbed, EVA's whole-sequence form is plain XLA "
@@ -1297,12 +1376,13 @@ def serving_params(family, params: dict, config) -> dict:
     spec = family(config)
     runs = _stacks(spec, config)
     cast = ("tok_embed", "pos_embed", "lm_head") + tuple(
-        name for run in runs for name in run.attn.cast + run.ffn.cast
-        + (run.mixer.cast if run.mixer is not None else ()))
+        name for run in runs
+        for part in (run.attn, run.ffn, run.mixer) if part is not None
+        for name in part.cast)
     # stack -> (its attention's absorbed leaf, the width it splits at)
     absorbed = {run.blocks: (run.attn.absorbed, (
         run.sizes or config).qk_nope_head_dim)
-        for run in runs if run.attn.absorbed}
+        for run in runs if run.attn is not None and run.attn.absorbed}
     dtype = jnp.dtype(config.dtype)
     flat = jax.tree_util.tree_flatten_with_path(params)[0]
     names = [path[-1].key for path, _ in flat]
@@ -1382,11 +1462,11 @@ def forward_cached(family, params: dict, tokens: jax.Array,
     i's is slot i)."""
     c, spec = config, family(config)
     runs = _stacks(spec, c)
-    if not all(run.ffn.serves for run in runs):
+    if not all(run.ffn is None or run.ffn.serves for run in runs):
         raise NotImplementedError(
             "this feed-forward has no path over a paged KV cache (the "
             "Switch layer's capacity is a whole batch's)")
-    if spec.rope_theta is None:
+    if spec.rope_theta is None and spec.pos_table:
         pos = jnp.clip(positions, 0, c.max_seq_len - 1)
         x = _embed(params, "tok", tokens, c) + _embed(params, "pos", pos, c)
     else:
@@ -1419,7 +1499,7 @@ def forward_cached(family, params: dict, tokens: jax.Array,
                 pools[j] for j in run.pools)
             x, own, load = _block_cached(
                 x, own, {**_layer_of(blocks, i + off if off else i,
-                                     run.ffn.whole),
+                                     _whole(run)),
                          "cache_layer": i + first if first else i},
                 spec, run, c, tables, positions, valid, ctx_lens, slots)
             if run.pools is None:

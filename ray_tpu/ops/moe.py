@@ -32,18 +32,26 @@ from ray_tpu.ops.attention import _interpret_kernels
 
 
 def _grouped_matmul_kernel(item_group, item_tile, starts, ends, n_items,
-                           layer, x_ref, w_ref, o_ref, *, block_m: int):
+                           layer, x_ref, w_ref, o_ref, *, block_m: int,
+                           transposed: bool = False):
     """Grid (N tiles, work items).  Item i is (expert g, row tile t): the
-    rows of tile t that belong to g are x[t] @ w[g]; the other rows of the
-    tile keep what earlier items wrote (zero on the tile's first visit)."""
+    rows of tile t that belong to g are x[t] @ w[g] (`transposed`: w[g] is
+    held [N, K] and both contract their last dimension); the other rows of
+    the tile keep what earlier items wrote (zero on the tile's first
+    visit)."""
     del layer                        # the index maps' operand
     i = pl.program_id(1)
 
     @pl.when(i < n_items[0])
     def _item():
         g, t = item_group[i], item_tile[i]
-        acc = jnp.dot(x_ref[...], w_ref[...],
-                      preferred_element_type=jnp.float32)
+        if transposed:
+            acc = jax.lax.dot_general(
+                x_ref[...], w_ref[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)
+        else:
+            acc = jnp.dot(x_ref[...], w_ref[...],
+                          preferred_element_type=jnp.float32)
         rows = t * block_m + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 0)
         mine = (rows >= starts[g]) & (rows < ends[g])
         acc = acc.astype(o_ref.dtype)
@@ -80,17 +88,24 @@ def _work_items(group_sizes, block_m: int, n_tiles: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "block_n",
-                                             "interpret"))
+                                             "interpret", "transposed"))
 def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
-                   block_n: int = 2048, interpret: Optional[bool] = None):
+                   block_n: int = 2048, interpret: Optional[bool] = None,
+                   transposed: bool = False):
     """x [M, K], its rows ordered by group; w [L, G, K, N] (or [G, K, N]);
     group_sizes [G], summing to M or less.  Row r of the result is
     x[r] @ w[layer, g] for the group g that holds r.  Rows behind the last
-    group are unspecified (the caller masks them)."""
+    group are unspecified (the caller masks them).
+
+    `transposed`: w is [L, G, N, K], a Linear's [out, in] as published.
+    That is how a matrix whose N is no multiple of the lane width has to be
+    held: as [K, N] the device lays it out with K minor (the layout that
+    pads nothing) and a kernel that wants it row-major is handed a copy of
+    every layer's experts every step."""
     if w.ndim == 3:
         w = w[None]
     m, k = x.shape
-    _, g, _, n = w.shape
+    g, n = w.shape[1], w.shape[2 if transposed else 3]
     if interpret is None:
         interpret = _interpret_kernels()
     # A decode step is bound by reading each hit expert's [K, N] once, so
@@ -99,11 +114,16 @@ def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
     # the chip's bandwidth and 16 x 512 80% (PERF.md 6, PR 27).
     # Wider experts (K 7168) take the widest tile under 8 MB that divides
     # N, so that two buffers of it fit the kernel's fast memory.
-    block_n = min(block_n, n, max(128, (4 * 2 ** 20 // k) // 128 * 128))
-    while block_n > 128 and n % block_n:
-        block_n -= 128
-    if n % block_n:
-        raise ValueError(f"N={n} is not a multiple of block_n={block_n}")
+    # An N that is no multiple of the lane width (1856 = 14.5 x 128) is
+    # one whole-N tile: the result's block has a last dimension that is a
+    # multiple of 128 or the array's own (10 MB a buffer at K 2688: two
+    # fit).
+    if n % 128:
+        block_n = n
+    else:
+        block_n = min(block_n, n, max(128, (4 * 2 ** 20 // k) // 128 * 128))
+        while block_n > 128 and n % block_n:
+            block_n -= 128
     pad = -m % block_m
     if pad:
         x = jnp.pad(x, ((0, pad), (0, 0)))
@@ -116,6 +136,9 @@ def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
         in_specs=[
             pl.BlockSpec((block_m, k),
                          lambda j, i, ig, it, s, e, c, ly: (it[i], 0)),
+            pl.BlockSpec((None, None, block_n, k),
+                         lambda j, i, ig, it, s, e, c, ly:
+                         (ly[0], ig[i], j, 0)) if transposed else
             pl.BlockSpec((None, None, k, block_n),
                          lambda j, i, ig, it, s, e, c, ly:
                          (ly[0], ig[i], 0, j)),
@@ -124,7 +147,8 @@ def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
                                lambda j, i, ig, it, s, e, c, ly: (it[i], j)),
     )
     out = pl.pallas_call(
-        functools.partial(_grouped_matmul_kernel, block_m=block_m),
+        functools.partial(_grouped_matmul_kernel, block_m=block_m,
+                          transposed=transposed),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((m + pad, n), x.dtype),
         interpret=interpret,
@@ -138,11 +162,15 @@ def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
 
 
 def expert_ffn(x, expert_ids, expert_weights, w_gate, w_up, w_down,
-               layer=0, valid=None, first_held=None):
-    """Dropless SwiGLU experts.  x [T, D]; expert_ids / expert_weights
-    [T, k] (each token's chosen experts and what each counts for); weights
-    [L, E, D, F] / [L, E, F, D] (or without L), multiplied as stored;
-    `valid` [T] masks padding tokens, which reach no expert.
+               layer=0, valid=None, first_held=None, up_transposed=False):
+    """Dropless experts: SwiGLU over three matrices
+    (`w_down (silu(w_gate x) * (w_up x))`), or with `w_gate` None a
+    squared ReLU over two (`w_down relu(w_up x)^2`).  x [T, D]; expert_ids
+    / expert_weights [T, k] (each token's chosen experts and what each
+    counts for); weights [L, E, D, F] / [L, E, F, D] (or without L),
+    multiplied as stored (`up_transposed`: `w_up` is held [L, E, F, D],
+    `grouped_matmul(transposed=True)`); `valid` [T] masks padding tokens,
+    which reach no expert.
 
     With `first_held` the E experts held here are a share of those the
     ids run over: first_held to first_held + E.  An assignment to any
@@ -152,7 +180,7 @@ def expert_ffn(x, expert_ids, expert_weights, w_gate, w_up, w_down,
     Returns (y [T, D], load [E] int32: the assignments each expert took)."""
     t, d = x.shape
     k = expert_ids.shape[1]
-    e = w_gate.shape[-3]
+    e = w_down.shape[-3]
     flat = expert_ids.reshape(-1).astype(jnp.int32)            # [T * k]
     if first_held is not None:
         flat = flat - first_held
@@ -163,8 +191,12 @@ def expert_ffn(x, expert_ids, expert_weights, w_gate, w_up, w_down,
     load = jnp.sum(flat[:, None] == jnp.arange(e)[None, :], axis=0,
                    dtype=jnp.int32)
     xs = x[order // k]                                         # [T * k, D]
-    hidden = (jax.nn.silu(grouped_matmul(xs, w_gate, load, layer))
-              * grouped_matmul(xs, w_up, load, layer))
+    if w_gate is None:
+        hidden = jnp.square(jax.nn.relu(grouped_matmul(
+            xs, w_up, load, layer, transposed=up_transposed)))
+    else:
+        hidden = (jax.nn.silu(grouped_matmul(xs, w_gate, load, layer))
+                  * grouped_matmul(xs, w_up, load, layer))
     ys = grouped_matmul(hidden, w_down, load, layer)           # [T * k, D]
     rank = jnp.zeros_like(order).at[order].set(
         jnp.arange(t * k, dtype=order.dtype))  # assignment -> sorted row

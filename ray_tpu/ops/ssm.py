@@ -15,8 +15,16 @@ is not stepped pass through.
 The state lives in ONE float32 buffer [layers, slots, H, N, P] for an
 engine's lifetime (inference/kv_cache.py: a slot a lane, and one more that
 rows nobody has are sent to), stored transposed (N rows of P columns: the
-P = 128 columns of a head are the device's lane width, so x_t and y_t are
-rows, and the sums over N run down the sublanes).  Both kernels read and
+columns of a head lie along the device's lanes, so x_t and y_t are rows,
+and the sums over N run down the sublanes).  A head of P = 128 columns is
+the lane width itself.  A narrower head (P = 64) would be padded to it, the
+buffer twice its bytes in HBM and both kernels moving the padding, so
+`state_shape` FOLDS 128 / P neighbouring heads of a group into one row of
+lanes: the buffer is [layers, slots, H / f, N, f P], head f j + i's columns
+at lanes i P to (i + 1) P of folded head j (x, dt x, the decays and y fold
+by a reshape; B and C are the group's and do not change).  Both kernels
+read the fold off the buffer's shape; at f = 1 nothing is folded and their
+programs are what they were.  Both read and
 write the slots of their rows in place (`input_output_aliases`), a block a
 grid step, named by the layer and the row's slot through scalar prefetch:
 
@@ -49,6 +57,47 @@ _HIGHEST = jax.lax.Precision.HIGHEST
 # Heads of one grid step of the update kernel: 8 x [256, 128] float32 is
 # 1 MB a block, 4 MB with both directions double-buffered.
 _UPDATE_HEADS = 8
+
+
+def state_shape(heads: int, d_state: int, head_dim: int,
+                groups: int = 1) -> tuple:
+    """A slot's stored shape [H / f, N, f P] (module docstring): f
+    neighbouring heads folded into one lane row where a head is narrower
+    than the 128 lanes, else f = 1: [H, N, P].  The heads of a fold share
+    B and C, so a GROUP's heads must divide by f (`_folded` refuses a
+    buffer folded otherwise): where they do not, nothing is folded."""
+    fold = 128 // head_dim if head_dim < 128 and 128 % head_dim == 0 else 1
+    if (heads // groups) % fold:
+        fold = 1
+    return heads // fold, d_state, fold * head_dim
+
+
+def _folded(state, heads: int, head_dim: int, groups: int) -> int:
+    """The fold of a state buffer for heads [H, P] in `groups` groups."""
+    fold = state.shape[-1] // head_dim
+    if fold > 1 and (heads // groups) % fold:
+        raise ValueError(f"{heads // groups} heads a group do not fold by "
+                         f"{fold} into rows of {state.shape[-1]} lanes")
+    return fold
+
+
+def _fold(v, fold: int):
+    """[..., H, R, P] -> [..., H / f, R, f P]: f neighbouring heads side by
+    side along the last axis."""
+    if fold == 1:
+        return v
+    *lead, h, r, p = v.shape
+    v = v.reshape(*lead, h // fold, fold, r, p)
+    return jnp.moveaxis(v, -3, -2).reshape(*lead, h // fold, r, fold * p)
+
+
+def _unfold(v, fold: int):
+    """`_fold`'s inverse: [..., H / f, R, f P] -> [..., H, R, P]."""
+    if fold == 1:
+        return v
+    *lead, h, r, p = v.shape
+    v = v.reshape(*lead, h, r, fold, p // fold)
+    return jnp.moveaxis(v, -2, -3).reshape(*lead, h * fold, r, p // fold)
 
 
 def conv_tail(xbc, tail, conv_w, conv_b, n_valid):
@@ -95,11 +144,12 @@ def ssm_update(state, x, dt, a, bm, cm, slots, layer=0, *,
     """One token a row: the states of rows `slots` at `layer` overwritten
     in place, and each read against its C.
 
-    state [L, S, H, N, P] float32; x [B, H, P]; dt [B, H] float32 (0: the
-    row is not stepped); a [H] (negative); bm, cm [B, G, N]; slots [B]
-    int32.  Returns (y [B, H, P] float32, state)."""
+    state [L, S, H, N, P] float32 (or folded: `state_shape`); x [B, H, P];
+    dt [B, H] float32 (0: the row is not stepped); a [H] (negative); bm, cm
+    [B, G, N]; slots [B] int32.  Returns (y [B, H, P] float32, state)."""
     b, h, p = x.shape
     g, n = bm.shape[1:]
+    fold = _folded(state, h, p, g)
     dec = jnp.broadcast_to(jnp.exp(dt * a)[..., None], (b, h, p))
     dtx = dt[..., None] * x.astype(jnp.float32)
     bm, cm = bm.astype(jnp.float32), cm.astype(jnp.float32)
@@ -109,14 +159,18 @@ def ssm_update(state, x, dt, a, bm, cm, slots, layer=0, *,
         use_kernel = not _interpret_kernels()
     if not use_kernel:
         rep = h // g
-        s = state[layer, slots]                               # [B, H, N, P]
+        s = _unfold(state[layer, slots], fold)                # [B, H, N, P]
         s = s * dec[:, :, None, :] + (
             jnp.repeat(bm, rep, axis=1)[..., None] * dtx[:, :, None, :])
         y = jnp.einsum("bhnp,bhn->bhp", s, jnp.repeat(cm, rep, axis=1),
                        precision=_HIGHEST)
-        return y, state.at[layer, slots].set(s, mode="drop")
+        return y, state.at[layer, slots].set(_fold(s, fold), mode="drop")
     if interpret is None:
         interpret = _interpret_kernels()
+    if fold > 1:
+        # f heads a lane row: the kernel's head is the folded one
+        h, p = h // fold, p * fold
+        dec, dtx = dec.reshape(b, h, p), dtx.reshape(b, h, p)
     hb = min(_UPDATE_HEADS, h // g)
     if (h // g) % hb:
         raise ValueError(f"{h // g} heads a group in blocks of {hb}")
@@ -125,8 +179,15 @@ def ssm_update(state, x, dt, a, bm, cm, slots, layer=0, *,
     def state_map(i, j, ly, sl):
         return (ly[0], sl[i], j, 0, 0)
 
+    # A block of fewer heads than a tile's 8 sublanes has to be whole
+    # trailing dimensions of its array: [B, H / hb, hb, P].
+    split = hb % 8 != 0
+    if split:
+        dec, dtx = (v.reshape(b, h // hb, hb, p) for v in (dec, dtx))
+    heads_block = (None, None, hb, p) if split else (None, hb, p)
+
     def head_map(i, j, ly, sl):
-        return (i, j, 0)
+        return (i, j, 0, 0) if split else (i, j, 0)
 
     def group_map(i, j, ly, sl):
         return (i, j // per_group, 0, 0)
@@ -135,18 +196,18 @@ def ssm_update(state, x, dt, a, bm, cm, slots, layer=0, *,
         num_scalar_prefetch=2,              # layer, the rows' slots
         grid=(b, h // hb),
         in_specs=[pl.BlockSpec((None, None, hb, n, p), state_map),
-                  pl.BlockSpec((None, hb, p), head_map),
-                  pl.BlockSpec((None, hb, p), head_map),
+                  pl.BlockSpec(heads_block, head_map),
+                  pl.BlockSpec(heads_block, head_map),
                   pl.BlockSpec((None, None, 1, n), group_map),
                   pl.BlockSpec((None, None, 1, n), group_map)],
         out_specs=[pl.BlockSpec((None, None, hb, n, p), state_map),
-                   pl.BlockSpec((None, hb, p), head_map)],
+                   pl.BlockSpec(heads_block, head_map)],
     )
     state, y = pl.pallas_call(
         functools.partial(_update_kernel, heads=hb),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
-                   jax.ShapeDtypeStruct((b, h, p), jnp.float32)],
+                   jax.ShapeDtypeStruct(dec.shape, jnp.float32)],
         input_output_aliases={2: 0},        # the state, after the scalars
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
@@ -154,7 +215,7 @@ def ssm_update(state, x, dt, a, bm, cm, slots, layer=0, *,
         name="ssm_update",
     )(layer.reshape(1), slots, state, dec, dtx, bm[:, :, None, :],
       cm[:, :, None, :])
-    return y, state
+    return (y if y.shape == x.shape else y.reshape(x.shape)), state
 
 
 # --------------------------------------------------------------------------
@@ -193,10 +254,15 @@ def _chunk_terms(x, dt, a, bm, cm, chunk: int):
 
 
 def _scan_kernel(layer_ref, slot_ref, fresh_ref, s_ref, dtx_ref, c_ref,
-                 b_ref, l_ref, cs_ref, bw_ref, dec_ref, so_ref, y_ref):
+                 b_ref, l_ref, cs_ref, bw_ref, dec_ref, so_ref, y_ref, *,
+                 fold: int = 1):
     """One (row, head, chunk) grid step; the chunks of a (row, head) run in
     order with the state carried in the output block, which is the head's
-    [N, P] state in the buffer: written back once, after the last."""
+    [N, P] state in the buffer: written back once, after the last.  With
+    `fold` > 1 the block is `fold` heads side by side along the lanes
+    ([N, f P]; the decays' refs have a leading axis of `fold`): each head's
+    products run over the whole lane row (the matrix unit is 128 columns
+    wide whatever the head's are) and keep their own lanes."""
     del layer_ref, slot_ref
     i, c = pl.program_id(0), pl.program_id(2)
 
@@ -208,14 +274,28 @@ def _scan_kernel(layer_ref, slot_ref, fresh_ref, s_ref, dtx_ref, c_ref,
     scores = jax.lax.dot_general(
         c_ref[...], b_ref[...], (((1,), (1,)), ((), ())),
         precision=_HIGHEST, preferred_element_type=jnp.float32)
-    y_ref[...] = (
-        jnp.dot(scores * l_ref[...], dtx, precision=_HIGHEST,
-                preferred_element_type=jnp.float32)
-        + jnp.dot(cs_ref[...], s, precision=_HIGHEST,
-                  preferred_element_type=jnp.float32))
-    so_ref[...] = dec_ref[...] * s + jax.lax.dot_general(
-        bw_ref[...], dtx, (((0,), (0,)), ((), ())),
-        precision=_HIGHEST, preferred_element_type=jnp.float32)
+
+    def head(lmat, cs, bw, dec):
+        y = (jnp.dot(scores * lmat, dtx, precision=_HIGHEST,
+                     preferred_element_type=jnp.float32)
+             + jnp.dot(cs, s, precision=_HIGHEST,
+                       preferred_element_type=jnp.float32))
+        return y, dec * s + jax.lax.dot_general(
+            bw, dtx, (((0,), (0,)), ((), ())),
+            precision=_HIGHEST, preferred_element_type=jnp.float32)
+
+    if fold == 1:
+        y_ref[...], so_ref[...] = head(l_ref[...], cs_ref[...], bw_ref[...],
+                                       dec_ref[...])
+        return
+    p = s.shape[-1] // fold
+    y, new = head(l_ref[0], cs_ref[0], bw_ref[0], dec_ref[0])
+    for f in range(1, fold):
+        y_f, new_f = head(l_ref[f], cs_ref[f], bw_ref[f], dec_ref[f])
+        own_y = jax.lax.broadcasted_iota(jnp.int32, y.shape, 1) // p == f
+        own_s = jax.lax.broadcasted_iota(jnp.int32, new.shape, 1) // p == f
+        y, new = jnp.where(own_y, y_f, y), jnp.where(own_s, new_f, new)
+    y_ref[...], so_ref[...] = y, new
 
 
 def _scan_chunks(terms, s0):
@@ -263,27 +343,35 @@ def ssm_scan(state, x, dt, a, bm, cm, slots, fresh, layer=0, *, chunk: int,
     where `fresh`), which is overwritten in place with the state behind the
     slice's last stepped token.
 
-    state [L, S, H, N, P] float32; x [B, T, H, P]; dt [B, T, H] float32 (0
-    at a padded row: the identity); a [H]; bm, cm [B, T, G, N]; slots [B]
-    int32; fresh [B] bool.  Returns (y [B, T, H, P] float32, state)."""
+    state [L, S, H, N, P] float32 (or folded: `state_shape`); x
+    [B, T, H, P]; dt [B, T, H] float32 (0 at a padded row: the identity); a
+    [H]; bm, cm [B, T, G, N]; slots [B] int32; fresh [B] bool.  Returns
+    (y [B, T, H, P] float32, state)."""
     b, t, h, p = x.shape
     n = bm.shape[-1]
+    fold = _folded(state, h, p, bm.shape[2])
     terms = _chunk_terms(x, dt, a, bm, cm, chunk)
     layer = jnp.asarray(layer, jnp.int32)
     slots = slots.astype(jnp.int32)
     if use_kernel is None:
         use_kernel = not _interpret_kernels()
     if not use_kernel:
-        s0 = jnp.where(fresh[:, None, None, None], 0.0, state[layer, slots])
+        s0 = jnp.where(fresh[:, None, None, None], 0.0,
+                       _unfold(state[layer, slots], fold))
         y, s = _scan_chunks(terms, s0)
         return (jnp.moveaxis(y[:, :, :t], 1, 2),
-                state.at[layer, slots].set(s, mode="drop"))
+                state.at[layer, slots].set(_fold(s, fold), mode="drop"))
     if interpret is None:
         interpret = _interpret_kernels()
     dtx, bm, cm, lmat, cs, bw, dec = terms
     nc = lmat.shape[2]
+    dec = jnp.broadcast_to(dec[..., None, None], (b, h, nc, 1, p * fold))
+    # `held`: a block's extent along the heads of the decays' arrays (None:
+    # the one head, squeezed); the kernel's head is the folded one.
+    held = None
+    if fold > 1:
+        h, p, held, dtx = h // fold, p * fold, fold, _fold(dtx, fold)
     per_group = h // bm.shape[1]
-    dec = jnp.broadcast_to(dec[..., None, None], (b, h, nc, 1, p))
 
     def state_map(i, j, c, ly, sl, fr):
         return (ly[0], sl[i], j, 0, 0)
@@ -304,15 +392,16 @@ def ssm_scan(state, x, dt, a, bm, cm, slots, fresh, layer=0, *, chunk: int,
                   pl.BlockSpec((None, None, chunk, p), rows_map),
                   pl.BlockSpec((None, None, chunk, n), group_map),
                   pl.BlockSpec((None, None, chunk, n), group_map),
-                  pl.BlockSpec((None, None, None, chunk, chunk), chunk_map),
-                  pl.BlockSpec((None, None, chunk, n), rows_map),
-                  pl.BlockSpec((None, None, chunk, n), rows_map),
-                  pl.BlockSpec((None, None, None, 1, p), chunk_map)],
+                  pl.BlockSpec((None, held, None, chunk, chunk), chunk_map),
+                  pl.BlockSpec((None, held, chunk, n), rows_map),
+                  pl.BlockSpec((None, held, chunk, n), rows_map),
+                  pl.BlockSpec((None, held, None, 1, p), chunk_map)],
         out_specs=[pl.BlockSpec((None, None, None, n, p), state_map),
                    pl.BlockSpec((None, None, chunk, p), rows_map)],
     )
     state, y = pl.pallas_call(
-        _scan_kernel,
+        _scan_kernel if fold == 1 else functools.partial(_scan_kernel,
+                                                         fold=fold),
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
                    jax.ShapeDtypeStruct(dtx.shape, jnp.float32)],
@@ -323,7 +412,7 @@ def ssm_scan(state, x, dt, a, bm, cm, slots, fresh, layer=0, *, chunk: int,
         name="ssm_scan",
     )(layer.reshape(1), slots, fresh.astype(jnp.int32), state, dtx, cm, bm,
       lmat, cs, bw, dec)
-    return jnp.moveaxis(y[:, :, :t], 1, 2), state
+    return jnp.moveaxis(_unfold(y, fold)[:, :, :t], 1, 2), state
 
 
 def copy_slot(dst_state, dst_tail, src_state, src_tail, src, dst):

@@ -202,4 +202,4 @@ def test_a_training_step_is_refused():
 def test_the_spec_names_the_mixer_and_what_it_keeps():
     run, = falconh1.spec(NANO).runs
     assert run.mixer is decoder.SSM and run.attn is decoder.HEADS
-    assert run.mixer.state(NANO) == decoder.StateRows(4, 8, 16, 4, 96)
+    assert run.mixer.state(NANO) == decoder.StateRows(4, 8, 16, 4, 96, 2)

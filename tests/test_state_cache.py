@@ -102,19 +102,54 @@ def test_a_lane_adopted_from_a_snapshot_equals_one_prefilled_from_token_0():
     assert _served_is_the_references(second, out)
 
 
-def test_a_snapshot_stands_where_the_prompts_last_whole_chunk_ends():
-    """One snapshot a prompt, behind the last chunk that leaves a chunk to
-    prefill: 40 + 5 tokens in chunks of 8 from 0 -> at 40; a prompt that
-    adopts 40 and adds 19 -> at 56 (40 + 2 x 8), its own deepest edge."""
+def test_a_snapshot_stands_where_a_cold_prompts_last_whole_chunk_ends():
+    """A lane that matched nothing leaves one snapshot, behind the last
+    chunk that leaves a chunk to prefill: 40 + 5 tokens in chunks of 8 from
+    0 -> at 40.  A lane that adopts those 40 and adds a turn of 19 (two
+    chunks and more) takes NONE: what it prefills is its own turn, and a
+    snapshot at 56 would serve nobody and cost a slot."""
     first, second = _prompts(2, 40, (5, 19))
     eng = _engine(prefill_lanes=2)
     _run(eng, eng.submit(first, 2))
     cache = eng.cache
     assert cache.match_len(first) == 40 and cache.match_len(second) == 40
-    _run(eng, eng.submit(second, 2))
-    assert cache.match_len(second) == 56
+    out, = _run(eng, eng.submit(second, 6))
+    assert cache.match_len(second) == 40
     assert cache.match_len(second[:50] + [0] * 9) == 40
-    assert eng.stats()["ssm"]["snapshots_taken"] == 2
+    st = eng.stats()["ssm"]
+    assert st["snapshots_taken"] == 1 and st["snapshots_adopted"] == 1
+    assert st["snapshots_evicted"] == 0
+    assert _served_is_the_references(second, out)
+
+
+def test_a_snapshot_is_taken_where_matched_blocks_had_none():
+    """A head of 36 tokens (off the chunks' grid of 8) under turns longer
+    than a chunk.  The first request matched nothing and leaves its one
+    snapshot at 48, behind its own turn.  The second matches the head's 9
+    blocks and no snapshot: it is counted, prefills from token 0, CUTS a
+    chunk at 36 (where the index saw the shared head end) and leaves a
+    snapshot there.  The third adopts blocks and snapshot and takes none."""
+    first, second, third = _prompts(6, 36, (13, 10, 11))
+    eng = _engine(prefill_lanes=2)
+    _run(eng, eng.submit(first, 2))
+    cache = eng.cache
+    assert cache.match_len(first) == 48 and cache.match_len(second) == 0
+    steps = eng.stats()["prefill"]["steps"]
+    out, = _run(eng, eng.submit(second, 5))
+    st = eng.stats()
+    assert st["ssm"]["snapshot_misses"] == 1
+    assert st["ssm"]["snapshots_taken"] == 2
+    # 8, 16, 24, 32, |36, 44, 46: one program more than six chunks of 8
+    assert st["prefill"]["steps"] - steps == 7
+    assert _served_is_the_references(second, out)
+    assert cache.match_len(second) == cache.match_len(third) == 36
+    out, = _run(eng, eng.submit(third, 5))
+    st = eng.stats()
+    assert st["ssm"]["snapshot_misses"] == 1
+    assert st["ssm"]["snapshots_adopted"] == 1
+    assert st["ssm"]["snapshots_taken"] == 2
+    assert st["prefix_hit_tokens"] == 36
+    assert _served_is_the_references(third, out)
 
 
 def test_a_match_is_refused_where_blocks_exist_and_the_snapshot_was_evicted():
@@ -128,7 +163,7 @@ def test_a_match_is_refused_where_blocks_exist_and_the_snapshot_was_evicted():
         _run(eng, eng.submit(prompt, 2))
     cache = eng.cache
     assert [cache.match_len(p) for p in heads] == [0, 32, 32]
-    assert len(cache.match_prefix(heads[0])) == 0 and cache.parts[0].unserved == 9
+    assert len(cache.match_prefix(heads[0])) == 0 and cache.parts[0].beyond == 9
     assert len(cache.index) and eng.stats()["ssm"]["snapshots_evicted"] == 1
     again = heads[0][:32] + [1, 2, 3]
     out, = _run(eng, eng.submit(again, 8))
@@ -201,3 +236,113 @@ def test_compiled_steps_count_the_state_buffers_copies():
     steps = eng.compiled_steps()
     assert set(steps) == {"t8_lanes1", "t8_lanes2", "t1"}
     assert all(isinstance(s["state_copies"], int) for s in steps.values())
+
+
+# -- a state part with a layer count of its own -------------------------------
+# Nemotron-H's nano model: three mixer layers, one attention layer and three
+# expert layers, a layer ONE part (tests/test_nemotronh.py has the model).
+
+from benchmark.reference import nemotronh as nemo_ref  # noqa: E402
+from ray_tpu.models import nemotronh  # noqa: E402
+
+NEMO = nemotronh.CONFIGS["nemotronh-nano"]
+
+
+@functools.lru_cache(maxsize=None)
+def _nemo_init():
+    return jax.jit(nemotronh.init_params, static_argnums=0)(
+        NEMO, jax.random.key(0))
+
+
+def _nemo_engine(**kw):
+    return InferenceEngine("nemotronh", NEMO, _nemo_init(),
+                           **{**ENGINE, **kw})
+
+
+def _nemo_served_is_the_references(prompt, out):
+    want = np.asarray(jnp.argmax(nemo_ref.row_logits(
+        _nemo_init(), np.asarray(prompt + out)), -1))
+    return out == want[len(prompt) - 1:len(prompt) + len(out) - 1].tolist()
+
+
+@pytest.mark.parametrize("prefill_lanes", [None, 2],
+                         ids=["all_lanes", "two_rows"])
+def test_one_part_layers_serve_the_references_greedy_tokens(prefill_lanes):
+    """Five requests over four lanes through layers that are a mixer, or
+    an attention, or experts: the state buffers have the three mixer
+    layers, the K and V pools the one attention layer, and what the step
+    counts goes by each kind's own layers."""
+    eng = _nemo_engine(prefill_lanes=prefill_lanes, prefix_cache=False)
+    state, tail = eng.cache.buffers
+    assert state.shape == (3, 5, 2, 16, 128) and tail.shape[0] == 3
+    assert eng.cache.pool_shape[0] == 1
+    rng = np.random.default_rng(0)
+    prompts = [rng.integers(0, 512, n).tolist() for n in (37, 9, 21, 30, 13)]
+    news = (12, 30, 18, 9, 14)
+    outs = _run(eng, *(eng.submit(p, n) for p, n in zip(prompts, news)))
+    for prompt, out in zip(prompts, outs):
+        assert _nemo_served_is_the_references(prompt, out)
+    st = eng.stats()
+    assert st["layers"] == {"kv": 1, "state": 3, "experts": 3}
+    fed = sum(map(len, prompts)) + sum(news) - 5
+    assert st["ssm"]["tokens_scanned"] + st["ssm"]["tokens_updated"] == fed
+    assert st["ssm"]["state_layers"] == 3
+    assert st["ssm"]["state_bytes"] == state.nbytes + tail.nbytes
+    # 4 choices a token in each of the 3 expert layers, all 16 held
+    assert st["moe"]["assignments"] == 4 * 3 * fed
+    assert sum(st["moe"]["expert_load"]) == 4 * 3 * fed
+    assert st["moe"]["experts_hit"] <= 16 * st["moe"]["layer_steps"]
+
+
+def test_a_share_of_the_experts_counts_what_it_holds():
+    share = nemotronh.CONFIGS["nemotronh-nano-share"]
+    params = jax.jit(nemotronh.init_params, static_argnums=0)(
+        share, jax.random.key(0))
+    eng = InferenceEngine("nemotronh", share, params, **ENGINE)
+    prompt = np.random.default_rng(1).integers(0, 512, 19).tolist()
+    out, = _run(eng, eng.submit(prompt, 6))
+    want = np.asarray(jnp.argmax(nemo_ref.row_logits(
+        params, np.asarray(prompt + out), experts_offset=8), -1))
+    assert out == want[18:24].tolist()
+    moe = eng.stats()["moe"]
+    assert len(moe["expert_load"]) == 8
+    assert moe["assignments"] == 4 * 3 * (19 + 5)
+    assert 0 < moe["assignments_held"] < moe["assignments"]
+
+
+def test_one_part_layers_adopt_a_snapshot_behind_shared_blocks():
+    """The second request of a head adopts the attention layer's blocks and
+    the three mixer layers' snapshot and scans only its own turn: what an
+    engine without a prefix cache serves, and the reference's."""
+    first, second = _prompts(1, 32, (5, 7))
+    eng = _nemo_engine(prefill_lanes=2)
+    _run(eng, eng.submit(first, 4))
+    assert eng.stats()["ssm"]["snapshots_taken"] == 1
+    out, = _run(eng, eng.submit(second, 16))
+    st = eng.stats()
+    assert st["prefix_hit_tokens"] == 32 and st["ssm"]["snapshots_adopted"] == 1
+    assert st["ssm"]["tokens_scanned"] == len(first) + 7
+    plain = _nemo_engine(prefix_cache=False)
+    cold, = _run(plain, plain.submit(second, 16))
+    assert out == cold and _nemo_served_is_the_references(second, out)
+    payload = KVBlockCodec.decode(KVBlockCodec.encode(
+        eng.export_prefix(second)))
+    assert payload["kind"] == "state"
+    assert payload["more"]["state"].shape == (3, 2, 16, 128)
+    # a cache whose state part has other layers installs none of it
+    assert _engine(prefill_lanes=2).import_prefix(payload) == 0
+    steps = eng.compiled_steps()
+    assert set(steps) == {"t8_lanes1", "t8_lanes2", "t1"}
+    assert all(isinstance(s["state_copies"], int) and "temp_bytes" in s
+               and "pool_copies" in s and "weight_bytes_copied" in s
+               for s in steps.values())
+
+
+def test_a_stack_with_no_attention_at_all_is_still_refused():
+    """A lane's snapshots are keyed by its chain of K/V blocks: a pure
+    state-space stack has none to hang its state behind."""
+    import dataclasses
+    pure = dataclasses.replace(NEMO, n_layers=2, pattern="ME")
+    with pytest.raises(NotImplementedError, match="at least one layer"):
+        PagedKVCache.for_model(nemotronh, pure, num_blocks=(8, 2),
+                               block_size=4, max_lanes=2)

@@ -907,6 +907,46 @@ def test_falconh1_steps_fit_a_v5e_and_update_the_state_buffer_in_place(
     assert not any(_pool_block_updates(text, p.shape) for p in pools[:2])
 
 
+@pytest.mark.parametrize("which,t,rows", [("t1", 1, 64), ("short", 64, 1)],
+                         ids=["t1_64_lanes", "t64_one_row"])
+def test_nemotronh_steps_fit_a_v5e_with_a_state_part_of_its_own_layers(
+        as_on_chip, which, t, rows):
+    """The cell's T=1 step and an admission's one-row program at published
+    widths (`benchmark/tools/aot_nemotronh_sizes.py`): K and V pools over
+    the 2 attention layers, the state buffers over the 6 mixer layers with
+    two heads of 64 folded into a lane row (0.82 GB, not the 1.64 a minor
+    of 64 would pad to), all four donated and left where they are; the
+    experts' matrices read where they lie (as [K, 1856] the up matrix was
+    laid out K-minor and copied whole every step: 3.2 GB; it is held
+    [1856, K]); and the kernels under the names the readers find them by."""
+    from benchmark.tools import aot_nemotronh_sizes
+    try:
+        texts = aot_nemotronh_sizes.main("serve_nemotron3_agents_decode",
+                                         which)
+    except RuntimeError as e:           # no v5e topology can be described
+        pytest.skip(str(e))
+    text, memory, pools = texts[(t, rows)]
+    assert [tuple(p.shape) for p in pools] == [
+        (2, 2048, 128, 256), (2, 2048, 128, 256), (6, 65, 32, 128, 128),
+        (6, 65, 18432)]
+    held = sum(p.dtype.itemsize * math.prod(p.shape) for p in pools)
+    assert held <= memory.alias_size_in_bytes < 1.002 * held
+    assert 8.6e9 < memory.argument_size_in_bytes < 8.8e9
+    assert memory.temp_size_in_bytes < 0.2e9
+    assert all(count_pool_copies(text, p.shape) == 0 for p in pools)
+    copied = count_weight_bytes_copied(
+        text, jax.eval_shape(lambda: {"w": jnp.zeros((5, 64, 1856, 2688),
+                                                     jnp.bfloat16)}))
+    assert not copied.get("copy") and not copied.get("transpose")
+    counts = _kernel_counts(text)
+    assert set(counts) == {"paged_rows_write", "moe_grouped_matmul"} | (
+        {"paged_decode_attention", "ssm_update"} if t == 1 else {"ssm_scan"})
+    assert counts["moe_grouped_matmul"] == 2 * 5        # up and down, 5 E
+    assert counts["paged_rows_write"] == 2              # the 2 * layers
+    assert counts["ssm_update" if t == 1 else "ssm_scan"] == 6
+    assert not any(_pool_block_updates(text, p.shape) for p in pools[:2])
+
+
 @pytest.mark.parametrize("t,rows", [(1, 8), (32, 8), (32, 2), (32, 1)],
                          ids=["t1", "t_prefill_chunk", "compact_2_rows",
                               "compact_1_row"])
