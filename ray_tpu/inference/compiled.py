@@ -1,8 +1,10 @@
 """What XLA built for a step, read from the compiled module's text: whether
 the KV pools (and a state cache's buffers) are updated where they are
-(`count_pool_copies`) and which weight-shaped results a step makes anew
-(`count_weight_bytes_copied`).  `InferenceEngine.compiled_steps()` and
-tests/test_tpu_aot.py hold every family's programs to both."""
+(`count_pool_copies`), which weight-shaped results a step makes anew
+(`count_weight_bytes_copied`) and whether an indexed layer's choice of rows
+sorts a lane's scores (`count_select_sorts`).
+`InferenceEngine.compiled_steps()` and tests/test_tpu_aot.py hold every
+family's programs to them."""
 
 from __future__ import annotations
 
@@ -118,6 +120,22 @@ def count_pool_copies(hlo_text: str, pool_shape: Sequence[int]) -> int:
 
     return sum(moves(comp, name) for comp, body in comps.items()
                if comp not in fused for name in body)
+
+
+def count_select_sorts(hlo_text: str, context: int) -> int:
+    """Sorts of a [rows, context] array in a compiled step, `context` the
+    positions a lane's table holds: what an indexed layer's choice of its
+    `topk` rows was until PR 48, a stable sort of every lane's 17k scores
+    for the best 2,048 (a seventh of dots3's step), and must not become
+    again: `ops.attention.sparse_select` finds the k-th score by bisection
+    and the places by counts.  A router's top-k over a row of experts is
+    narrower and the expert dispatch's sort of its assignments is of rank
+    1: neither is counted."""
+    comps = _parse_hlo(hlo_text)[0]
+    return sum(opcode == "sort" and any(
+        len(dims) == 2 and dims[1] >= context for _, dims in arrays)
+               for body in comps.values()
+               for arrays, opcode, _, _ in body.values())
 
 
 def count_weight_bytes_copied(hlo_text: str, weights) -> Dict[str, int]:

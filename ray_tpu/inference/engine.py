@@ -129,6 +129,7 @@ from ray_tpu import models
 from ray_tpu._private import compile_cache
 from ray_tpu._private.accelerators import leased_chips, require_chip_lease
 from ray_tpu.inference.compiled import (count_pool_copies,
+                                        count_select_sorts,
                                         count_weight_bytes_copied)
 from ray_tpu.inference.kv_cache import PagedKVCache, chain_keys
 from ray_tpu.models.decoder import layer_counts
@@ -1054,20 +1055,15 @@ class InferenceEngine:
         (`weight_bytes_copied`, see `compiled.count_weight_bytes_copied`:
         no `convert`, `copy` or `transpose` when the weights are read
         where they are; a layer scan's slices of its groups are listed).
+        Over layers of several kinds also the sorts of [rows, context]
+        arrays (`select_sorts`, see `compiled.count_select_sorts`: 0 while
+        an indexed layer chooses its rows without sorting a lane's scores).
         Recompiles each shape ahead of time (a persistent-cache hit where
         the cache is on), so call it for a check, not per request."""
-        out = {}
-        # _step_compile_s is filled last, so its keys are complete steps
-        # even while the scheduler thread is adding a new shape.
-        for key, compile_s in list(self._step_compile_s.items()):
-            t, sample, spec, compact = key
-            compiled = self._step_fns[key].lower(
-                *self._step_avals[key]).compile()
-            name = f"t{t}" + ("_sample" if sample else "") \
-                + ("_spec" if spec else "") \
-                + (f"_lanes{compact}" if compact else "")
+        def report(compile_s, fn, avals):
+            compiled = fn.lower(*avals).compile()
             text, memory = compiled.as_text(), compiled.memory_analysis()
-            out[name] = {
+            out = {
                 "compile_s": round(compile_s, 2),
                 "custom_calls": text.count("tpu_custom_call"),
                 "donated_bytes": memory.alias_size_in_bytes,
@@ -1075,24 +1071,31 @@ class InferenceEngine:
                 "pool_copies": count_pool_copies(text,
                                                  self.cache.pool_shape),
                 "weight_bytes_copied": count_weight_bytes_copied(
-                    text, self._step_avals[key][0])}
+                    text, avals[0])}
+            if self._sparse is not None:
+                out["select_sorts"] = count_select_sorts(
+                    text, (self.cache.max_blocks_per_seq
+                           * self.cache.block_size))
+            return text, out
+
+        out = {}
+        # _step_compile_s is filled last, so its keys are complete steps
+        # even while the scheduler thread is adding a new shape.
+        for key, compile_s in list(self._step_compile_s.items()):
+            t, sample, spec, compact = key
+            name = f"t{t}" + ("_sample" if sample else "") \
+                + ("_spec" if spec else "") \
+                + (f"_lanes{compact}" if compact else "")
+            text, out[name] = report(compile_s, self._step_fns[key],
+                                     self._step_avals[key])
             if self._stateful:
                 # The state buffer too is updated where it is.
                 out[name]["state_copies"] = count_pool_copies(
                     text, self.cache.buffers[0].shape)
         if "compile_s" in self._compact:
-            compiled = self._compact["fn"].lower(
-                *self._compact["avals"]).compile()
-            text, memory = compiled.as_text(), compiled.memory_analysis()
-            out[f"compact_lanes{self.prefill_lanes}"] = {
-                "compile_s": round(self._compact["compile_s"], 2),
-                "custom_calls": text.count("tpu_custom_call"),
-                "donated_bytes": memory.alias_size_in_bytes,
-                "temp_bytes": memory.temp_size_in_bytes,
-                "pool_copies": count_pool_copies(text,
-                                                 self.cache.pool_shape),
-                "weight_bytes_copied": count_weight_bytes_copied(
-                    text, self._compact["avals"][0])}
+            _, out[f"compact_lanes{self.prefill_lanes}"] = report(
+                self._compact["compile_s"], self._compact["fn"],
+                self._compact["avals"])
         return out
 
     # ---------------- scheduler ----------------

@@ -1626,7 +1626,10 @@ def _rows_as_lanes(fn, block_tables, q_positions, valid, parts, out):
 # position of a lane against the query (a paged pool of ONE index key a
 # token, in the blocks and under the table of the latent rows), the `topk`
 # positions of largest score are chosen, exactly, and attention reads the
-# chosen rows of the latent pool and no others.
+# chosen rows of the latent pool and no others.  The choice sorts nothing
+# (`sparse_select`, since PR 48): the k-th score by bisection over the
+# scores' bits, the chosen set by compares against it, their places by
+# counts over chunks of 128 positions and one one-hot product a lane.
 # --------------------------------------------------------------------------
 
 def index_scores_reference(q_i, w_i, index_pool, block_tables, ctx_lens,
@@ -1731,41 +1734,301 @@ def sparse_index_scores(q_i, w_i, index_pool, block_tables, ctx_lens,
     return out[:, 0]
 
 
+# Columns of a chunk the choice counts over.  A chunk's running counts ride
+# the matrix unit in bfloat16, which holds every integer up to 256.
+_SELECT_CHUNK = 128
+_KEY_MIN = np.int32(-2 ** 31)
+
+
+def _order_keys(scores):
+    """int32 keys that order as the float32 scores do (-inf < NEG_INF <
+    0.0): the bits are a sign and a magnitude, the key the same number in
+    two's complement.  -0.0 and +0.0 get one key: a comparison of floats
+    calls them equal, as the sort that chose until PR 48 did and the
+    benchmark's reference does (an index score is exactly zero, of either
+    sign, wherever every head's ReLU is).  No score's key is the least
+    int32, which is what a position that is none gets."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    return jnp.where(bits < 0, -(bits & np.int32(2 ** 31 - 1)), bits)
+
+
+def _kth_key(keys, k: int):
+    """The k-th largest key of each lane [B, ...] -> [B], exactly: the
+    threshold's bits from the top, the sign first (the least key plus
+    2**31 wraps round to 0); a bit stays where at least `k` keys are at or
+    over the candidate.  32 passes of compare and count, no sort."""
+    axes = tuple(range(1, keys.ndim))
+    at = (slice(None),) + (None,) * len(axes)
+
+    def bit(i, kth):
+        trial = kth + jnp.left_shift(jnp.int32(1), 31 - i)
+        enough = jnp.sum(keys >= trial[at], axes, dtype=jnp.int32) >= k
+        return jnp.where(enough, trial, kth)
+
+    return jax.lax.fori_loop(
+        0, 32, bit, jnp.full(keys.shape[:1], _KEY_MIN, jnp.int32))
+
+
+def sparse_select_reference(scores, block_tables, *, block_size: int,
+                            k: int, base=0, n: Optional[int] = None,
+                            chunk: int = _SELECT_CHUNK):
+    """The places in the pool's table of rows of the `k` positions of
+    largest score of each lane, ascending by position (ground truth and
+    the CPU's path; `sparse_select`).  Plain `jax.numpy`: compares, counts
+    and two one-hot products, no sort, gather or scatter."""
+    b, width = scores.shape
+    n = width if n is None else n
+    c = -(-width // chunk)
+    # (positions from `n` on get the least key there is, under a true -inf's)
+    keys = jnp.pad(jnp.where(jnp.arange(width) < n,
+                             _order_keys(scores.astype(jnp.float32)),
+                             _KEY_MIN), ((0, 0), (0, c * chunk - width)),
+                   constant_values=_KEY_MIN).reshape(b, c, chunk)
+    kth = _kth_key(keys, k)[:, None, None]
+    above, tied = keys > kth, keys == kth
+    upper = jnp.triu(jnp.ones((chunk, chunk), jnp.bfloat16))
+
+    def running(mask):                  # inclusive, along a chunk
+        return jnp.einsum("bcx,xy->bcy", mask.astype(jnp.bfloat16), upper,
+                          preferred_element_type=jnp.float32)
+
+    def before(count):                  # exclusive, over the chunks
+        return jnp.cumsum(count, axis=1) - count
+
+    # Of a chunk's ties, the first `take`: what the chunks before left of
+    # the room the scores over the k-th leave.
+    tied_run = running(tied)
+    room = k - jnp.sum(above, (1, 2), dtype=jnp.float32)
+    take = jnp.clip(room[:, None] - before(tied_run[..., -1]), 0, chunk)
+    chosen_run = running(above) + jnp.minimum(tied_run, take[..., None])
+    count = chosen_run[..., -1]
+    start = before(count)[:, None, :]                       # [B, 1, C]
+    slot = jnp.arange(k, dtype=jnp.float32)[None, :, None]  # [1, k, 1]
+    # Slot j's chunk is the one whose chosen hold the j-th: one-hot
+    # [B, k, C]; its row of running counts by a product (integers up to
+    # `chunk`, exact in bfloat16); its column is how many of them are no
+    # more than j's rank inside the chunk.
+    hot = (start <= slot) & (slot < start + count[:, None, :])
+    run_of = jnp.einsum("bjc,bcx->bjx", hot.astype(jnp.bfloat16),
+                        chosen_run.astype(jnp.bfloat16),
+                        preferred_element_type=jnp.float32)
+    rank = slot - jnp.sum(jnp.where(hot, start, 0), -1, keepdims=True)
+    col = jnp.sum(run_of <= rank, -1, dtype=jnp.int32)
+    pos = jnp.sum(jnp.where(hot, jnp.arange(c, dtype=jnp.int32) * chunk, 0),
+                  -1) + col
+    # The block's number in the lane's table: a masked sum over a one-hot
+    # of blocks, in int32 (block numbers pass bfloat16's integers).
+    mb = block_tables.shape[1]
+    block = jnp.sum(jnp.where(
+        (pos // block_size)[..., None] == jnp.arange(mb, dtype=jnp.int32),
+        block_tables[:, None, :], 0), -1)
+    return block * block_size + pos % block_size + base
+
+
+# Lanes a grid step of the choice's kernel takes: their thresholds are found
+# together, a sublane each (at 8 the 32 passes wait for each other: 0.170 ms
+# for 64 lanes of 17,024 on a v5e where 16 take 0.158; PERF.md section 6,
+# PR 48).
+_SELECT_LANES = 16
+
+
+def _select_kernel(s_ref, t_ref, o_ref, keys_ref, kth_ref, *, k: int,
+                   n: int):
+    """One grid step: `_SELECT_LANES` lanes' scores [G, C, 128] (a chunk
+    of 128 positions a row) and their tables [G, C] -> the places of each
+    lane's `k` best, ascending by position, [G, kp].  Every vector over
+    chunks is a column, every vector over slots a row, so slots lie along
+    the 128 lanes of a register and what comes out is stored dense."""
+    g_lanes, c, ch = s_ref.shape
+    kp = o_ref.shape[1]
+    f32, bf16, i32 = jnp.float32, jnp.bfloat16, jnp.int32
+
+    def ones_where(mask, dtype=bf16):
+        return jnp.where(mask, 1.0, 0.0).astype(dtype)
+
+    def iota(shape, axis):
+        return jax.lax.broadcasted_iota(i32, shape, axis)
+
+    pos = iota((c, ch), 0) * ch + iota((c, ch), 1)
+    for g in range(g_lanes):
+        keys_ref[g] = jnp.where(pos < n, _order_keys(s_ref[g]), _KEY_MIN)
+
+    # The k-th key of every lane of the step at once: lane g's candidate
+    # along sublane g, one count across the 128 columns a pass.
+    sub = iota((g_lanes, ch), 0)
+
+    def bit(i, kth):
+        trial = kth + jnp.left_shift(jnp.int32(1), 31 - i)
+        part = jnp.zeros((g_lanes, ch), i32)
+        for g in range(g_lanes):
+            hit = keys_ref[g] >= trial[g:g + 1, :]
+            part = jnp.where(sub == g, jnp.sum(
+                jnp.where(hit, 1, 0), axis=0, keepdims=True), part)
+        enough = jnp.sum(part, axis=1, keepdims=True) >= k
+        return jnp.where(enough, trial, kth)
+
+    kth_ref[...] = jax.lax.fori_loop(
+        0, 32, bit, jnp.full((g_lanes, ch), _KEY_MIN, i32))
+
+    upper = ones_where(iota((ch, ch), 0) <= iota((ch, ch), 1))  # [x', x]
+    lower = ones_where(iota((ch, ch), 1) <= iota((ch, ch), 0))  # [x, x']
+    earlier = ones_where(iota((c, c), 1) < iota((c, c), 0))     # [c, c']
+    ones = jnp.ones((8, ch), bf16)
+    row = iota((8, c), 0)
+    slot = iota((1, kp), 1).astype(f32)
+    nt = (((1,), (1,)), ((), ()))
+
+    def dot(a, b, dims=(((1,), (0,)), ((), ()))):
+        return jax.lax.dot_general(a, b, dims, preferred_element_type=f32)
+
+    def before(mask16):     # a chunk's count in the chunks before it [C, ch]
+        return dot(earlier, mask16)
+
+    def lane(g, carry):
+        keys = keys_ref[g]
+        kth = kth_ref[pl.ds(g, 1), :]
+        above, tied = keys > kth, keys == kth
+        tied16 = ones_where(tied)
+        # Ties go to the lower position: a tie is chosen while the ties up
+        # to it are no more than the room the scores over the k-th leave.
+        room = k - jnp.sum(jnp.sum(ones_where(above, f32), axis=0,
+                                   keepdims=True), axis=1, keepdims=True)
+        tie_rank = dot(tied16, upper) + jnp.sum(
+            before(tied16), axis=1, keepdims=True)
+        chosen16 = ones_where(above | (tied & (tie_rank <= room)))
+        prior = before(chosen16)
+        start = jnp.sum(prior, axis=1, keepdims=True)               # [C, 1]
+        end = start + jnp.sum(chosen16.astype(f32), axis=1, keepdims=True)
+        # The chosen's running count along each chunk, a chunk a column,
+        # and under it rows of what a slot needs of its chunk besides: the
+        # table's block number and the chunk's start, each in digits of
+        # base 256, which bfloat16 holds exactly.
+        start_row = dot(ones, prior.astype(bf16), nt)               # [8, C]
+        table = t_ref[pl.ds(g, 1), :]
+        start_hi = jnp.floor(start_row * (1.0 / 256))
+        extra = functools.reduce(
+            lambda rest, at: jnp.where(row == at[0], at[1], rest),
+            enumerate([table >> 16, (table >> 8) & 255, table & 255,
+                       start_hi, start_row - start_hi * 256]),
+            jnp.zeros((8, c), f32))
+        of_chunk = jnp.concatenate(
+            [dot(lower, chosen16, nt).astype(bf16), extra.astype(bf16)],
+            axis=0)                                             # [ch + 8, C]
+        of_slot = dot(of_chunk, ones_where((start <= slot) & (slot < end)))
+        block, start_of = (
+            functools.reduce(lambda hi, lo: hi * 256 + lo,
+                             [of_slot[ch + i:ch + i + 1] for i in rows])
+            for rows in ((0, 1, 2), (3, 4)))
+        col = jnp.sum(jnp.where(of_slot[:ch] <= slot - start_of, 1, 0),
+                      axis=0, keepdims=True)
+        o_ref[pl.ds(g, 1), :] = block.astype(i32) * ch + col
+        return carry
+
+    jax.lax.fori_loop(0, g_lanes, lane, 0)
+
+
+# (jitted so that a process traces the kernel once, not once a layer body of
+# every step program: each trace and lowering is a quarter of a second of
+# `setup_s`, PERF.md section 6, PR 48)
+@functools.partial(jax.jit, static_argnames=(
+    "block_size", "k", "n", "name", "use_kernel", "interpret"))
+def sparse_select(scores, block_tables, *, block_size: int, k: int, base=0,
+                  n: Optional[int] = None, name: str = "sparse_select",
+                  use_kernel: Optional[bool] = None,
+                  interpret: Optional[bool] = None):
+    """The choice of indexed attention: for each lane the places, in the
+    pool's table of rows [L * NB * BS, W], of the `k` positions of largest
+    score among the first `n` of `scores` [B, >= n] float32 (by default
+    all of them), ties to the lower position, as `jax.lax.top_k` chooses
+    (but -0.0 a tie of +0.0, as a stable sort has it); int32 [B, k],
+    ASCENDING BY POSITION, `base` (a layer's first row) added.  Nothing
+    is sorted, gathered or scattered:
+
+    1. the k-th largest score, exactly: the scores' bits as integers of
+       the same order (`_order_keys`), the threshold built bit by bit from
+       the top, a bit kept where at least `k` keys are at or over the
+       candidate (`_kth_key`: 32 passes of compare and count);
+    2. the chosen set: the keys over the k-th, and of those equal to it
+       the first as many as there is room left;
+    3. their places: with a lane's positions laid out [chunks, 128], slot
+       j's chunk is the one whose share of the chosen holds the j-th (a
+       count over the chunks' running totals: a one-hot), the chunk's row
+       of running counts comes from a one-hot product on the matrix unit
+       (integers up to 128, exact in bfloat16), the column is how many of
+       that row are no more than j's rank inside the chunk, and the
+       block's number in the lane's table rides the same product.
+
+    On TPU the Pallas kernel `sparse_select` where a block is a chunk of
+    128 (`_SELECT_LANES` lanes a grid step); anywhere else the same steps
+    in `jax.numpy` (`sparse_select_reference`)."""
+    b, width = scores.shape
+    n = width if n is None else n
+    ch = _SELECT_CHUNK
+    c = -(-(-(-width // ch)) // 16) * 16        # whole bfloat16 tiles
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+    # (the kernel's counts over chunks ride the matrix unit too: 256 chunks)
+    if not use_kernel or block_size != ch or c > 256:
+        return sparse_select_reference(scores, block_tables, k=k, n=n,
+                                       block_size=block_size, base=base)
+    if interpret is None:
+        interpret = _interpret_kernels()
+    g = min(_SELECT_LANES, -(-b // 8) * 8)
+    bp, kp = -(-b // g) * g, -(-k // 128) * 128
+    scores = jnp.pad(scores.astype(jnp.float32),
+                     ((0, bp - b), (0, c * ch - width)))
+    tables = jnp.pad(block_tables.astype(jnp.int32),
+                     ((0, bp - b), (0, c - block_tables.shape[1])))
+    place = pl.pallas_call(
+        functools.partial(_select_kernel, k=k, n=n),
+        grid=(bp // g,),
+        in_specs=[pl.BlockSpec((g, c, ch), lambda i: (i, 0, 0)),
+                  pl.BlockSpec((g, c), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((g, kp), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((bp, kp), jnp.int32),
+        scratch_shapes=[pltpu.VMEM((g, c, ch), jnp.int32),
+                        pltpu.VMEM((g, ch), jnp.int32)],
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",)),
+        name=name,
+    )(scores.reshape(bp, c, ch), tables)
+    return place[:b, :k] + base
+
+
 def sparse_latent_decode_attention(q, q_i, w_i, pool, index_pool,
                                    block_tables, ctx_lens, layer=0, *,
                                    v_width: int, scale: float, topk: int,
                                    names=("sparse_index_scores",
+                                          "sparse_select",
                                           "sparse_latent_decode_attention")):
     """Single-query latent attention over the `topk` positions the indexer
     scores highest (all of a context no longer than that): scores over the
-    index keys (`sparse_index_scores`), an exact top-k (one stable sort of
-    every lane's scores, best first, so ties go to the lower position, as
-    `jax.lax.top_k`'s do; in a trace under the scope `sparse_select`), ONE
-    gather of the chosen rows of the latent pool, and
-    `latent_decode_attention` over those rows laid side by side.  The
-    context's latent rows are never read.  q [B, H, W], q_i [B, Hi, Di],
-    w_i [B, Hi]; returns [B, H, v_width].
+    index keys (`sparse_index_scores`), the exact choice of the best `topk`
+    (`sparse_select`: a threshold and counts, no sort; ties go to the
+    lower position, as `jax.lax.top_k`'s do), ONE gather of the chosen rows
+    of the latent pool, and `latent_decode_attention` over those rows laid
+    side by side.  The context's latent rows are never read.  q [B, H, W],
+    q_i [B, Hi, Di], w_i [B, Hi]; returns [B, H, v_width].
 
-    What is sorted beside a score is its row's place in the pool, not its
-    position: a position looked up in the table afterwards is a gather of
-    single numbers, 1.4 ms for 64 x 2,048 on a v5e where the sort itself
-    is 1.1; and the rows are taken from the pool as a table of rows
-    [L * NB * BS, W] (2.1 ms, where the same rows by (layer, block, row)
-    take 2.4: PERF.md section 6, PR 41)."""
+    The choice hands over each row's place in the pool, not its position
+    (a position looked up in the table afterwards is a gather of single
+    numbers: 1.4 ms for 64 x 2,048 on a v5e), ascending by position: the
+    softmax that follows is over a SET of rows, and where a context is
+    shorter than `topk` its own positions come first, the filled ones
+    behind them, which is where `latent_decode_attention` stops reading.
+    The rows are taken from the pool as a table of rows [L * NB * BS, W]
+    (2.1 ms, where the same rows by (layer, block, row) take 2.4:
+    PERF.md section 6, PR 41)."""
     b = q.shape[0]
     n_layers, nb, bs, w = pool.shape
     n = block_tables.shape[1] * bs
-    scores = sparse_index_scores(q_i, w_i, index_pool, block_tables,
-                                 ctx_lens, layer, name=names[0])[:, :n]
     k = min(topk, n)
-    with jax.named_scope("sparse_select"):
-        # Best first, so the masked positions (past the context) last: the
-        # first min(ctx, k) are the chosen ones.
-        place = (jnp.repeat(block_tables, bs, axis=1) * bs
-                 + jnp.arange(n, dtype=jnp.int32) % bs)
-        _, place = jax.lax.sort((-scores, place), dimension=1, num_keys=1,
-                                is_stable=True)
-        place = place[:, :k] + jnp.asarray(layer, jnp.int32) * (nb * bs)
+    scores = sparse_index_scores(q_i, w_i, index_pool, block_tables,
+                                 ctx_lens, layer, name=names[0])
+    place = sparse_select(
+        scores, block_tables, block_size=bs, k=k, n=n, name=names[1],
+        base=jnp.asarray(layer, jnp.int32) * (nb * bs))
     with jax.named_scope("sparse_gather"):
         rows = jnp.take(pool.reshape(n_layers * nb * bs, w), place, axis=0,
                         mode="clip")
@@ -1774,7 +2037,7 @@ def sparse_latent_decode_attention(q, q_i, w_i, pool, index_pool,
         q, rows.reshape(1, b * k // per, per, w),
         jnp.arange(b * k // per, dtype=jnp.int32).reshape(b, k // per),
         jnp.minimum(ctx_lens, k), 0, v_width=v_width, scale=scale,
-        name=names[1])
+        name=names[2])
 
 
 def sparse_latent_attention(q, q_i, w_i, pool, index_pool, block_tables,
@@ -1783,7 +2046,7 @@ def sparse_latent_attention(q, q_i, w_i, pool, index_pool, block_tables,
     """Dispatch indexed latent attention for a [B, T, ...] slice: the T=1
     step as it is, a longer slice's valid rows each as a lane of its own
     (`_rows_as_lanes`; kernels `sparse_index_chunk_scores`,
-    `sparse_latent_chunk_attention`)."""
+    `sparse_select_chunk`, `sparse_latent_chunk_attention`)."""
     def attend(**names):
         def fn(tables, ctx, q_rows, qi_rows, wi_rows):
             return sparse_latent_decode_attention(
@@ -1794,7 +2057,7 @@ def sparse_latent_attention(q, q_i, w_i, pool, index_pool, block_tables,
         return attend()(block_tables, ctx_lens, q[:, 0], q_i[:, 0],
                         w_i[:, 0])[:, None]
     return _rows_as_lanes(
-        attend(names=("sparse_index_chunk_scores",
+        attend(names=("sparse_index_chunk_scores", "sparse_select_chunk",
                       "sparse_latent_chunk_attention")),
         block_tables, q_positions, valid, (q, q_i, w_i),
         jnp.zeros(q.shape[:-1] + (v_width,), q.dtype))

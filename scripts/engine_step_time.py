@@ -46,11 +46,22 @@ window layers', is `rows_write_<name>_call2_...`).  With `write=` arguments
 alone the step programs are not timed:
 
   python3 scripts/engine_step_time.py write=gpt2xl write=evabyte
+
+Since PR 48 also an indexed layer's choice of rows alone
+(`ops/attention.py::sparse_select`), `select=dots3`: every lane's `topk` of
+the scores of a full table at the cell's lanes, blocks and context, in a loop
+of 20 trips over four sets of scores (one of them rounded to quarters, ties by
+the hundred), ms a trip (`select_<name>_t1_ms`): as the stable two-operand
+`jax.lax.sort` that made the choice until then, re-created here (`sort`), as
+the kernel (`kernel`, with the relayout XLA makes in front of it) and as the
+same steps in `jax.numpy` (`jnp`, the CPU's path, compiled for the chip).  All
+three must choose the same set, or the script fails.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import importlib
 import json
 import os
@@ -68,7 +79,8 @@ from benchmark import manifest
 from ray_tpu.inference.engine import InferenceEngine, _lane_views
 from ray_tpu.inference.kv_cache import PagedKVCache
 from ray_tpu.models import gpt
-from ray_tpu.ops.attention import kv_row_width, paged_rows_update
+from ray_tpu.ops.attention import (NEG_INF, kv_row_width, paged_rows_update,
+                                   sparse_select, sparse_select_reference)
 
 WRITE_TRIPS = 48
 # The prefill program timed where it is not the cell's widest ([prefill_lanes,
@@ -169,6 +181,65 @@ def time_one_call(key, lanes, chunk, shapes, out):
             assert a.any()
 
 
+SELECT_TRIPS = 20
+
+
+def time_select(name, out):
+    """The choice alone at the shapes of the serve cell of configuration
+    `name`, into `out`."""
+    m = manifest.load()
+    (cell,) = [c for c in m.cells if c.startswith(f"serve_{name}_")]
+    file = m.load_config(m.cells[cell]["config"])
+    config = manifest.model_config(file, None)
+    eng = m.load_traffic(m.cells[cell]["traffic"])["engine"]
+    spec = importlib.import_module(file["module"]).spec(config)
+    topk = max(getattr(run.sizes, "index_topk", 0) for run in spec.runs)
+    lanes, bs = eng["max_lanes"], eng["block_size"]
+    nb = np.ravel(eng["num_blocks"])[0]     # the full layers' pool first
+    mb = -(-eng["max_seq_len"] // bs)
+    n, k = mb * bs, min(topk, mb * bs)
+    rng = np.random.default_rng(0)
+    scores = rng.standard_normal((4, lanes, n)).astype(np.float32)
+    scores[1] = np.round(scores[1] * 4) / 4
+    ctx = rng.integers(n - 5 * bs, n, (4, lanes, 1))
+    scores = jnp.asarray(np.where(np.arange(n) < ctx, scores, NEG_INF))
+    tables = jnp.asarray(np.stack([rng.permutation(nb)[:mb]
+                                   for _ in range(lanes)]).astype(np.int32))
+
+    def sort(scores, tables):       # the choice until PR 48
+        place = (jnp.repeat(tables, bs, axis=1) * bs
+                 + jnp.arange(n, dtype=jnp.int32) % bs)
+        return jax.lax.sort((-scores, place), dimension=1, num_keys=1,
+                            is_stable=True)[1][:, :k]
+
+    forms = {"sort": sort,
+             "kernel": functools.partial(sparse_select, block_size=bs, k=k),
+             "jnp": functools.partial(sparse_select_reference,
+                                      block_size=bs, k=k)}
+    chosen = {}
+    for form, fn in forms.items():
+        def trips(stack, tables):
+            return jax.lax.fori_loop(
+                0, SELECT_TRIPS,
+                lambda i, acc: acc + fn(stack[i % 4], tables),
+                jnp.zeros((lanes, k), jnp.int32))
+
+        chosen[form] = np.sort(np.asarray(jax.jit(fn)(scores[1], tables)))
+        many = jax.jit(trips)
+        jax.block_until_ready(many(scores, tables))
+        ms = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(5):
+                left = many(scores, tables)
+            jax.block_until_ready(left)
+            ms.append(round(1000 * (time.perf_counter() - t0)
+                            / (5 * SELECT_TRIPS), 4))
+        out.setdefault(f"select_{name}_t1_ms", {})[form] = ms
+    for form in ("kernel", "jnp"):
+        np.testing.assert_array_equal(chosen[form], chosen["sort"])
+
+
 def time_steps(unrolls, out):
     """The engine's step programs at `serve_gpt2xl_decode`'s sizes, into
     `out`."""
@@ -267,14 +338,18 @@ def time_steps(unrolls, out):
 
 
 def main(argv):
-    writes = [a.split("=", 1)[1] for a in argv if a.startswith("write=")]
-    unrolls = [int(a) for a in argv if not a.startswith("write=")]
+    named = {kind: [a.split("=", 1)[1] for a in argv
+                    if a.startswith(kind + "=")]
+             for kind in ("write", "select")}
+    unrolls = [int(a) for a in argv if "=" not in a]
     dev = jax.devices()[0]
     out = {"device": [dev.platform, dev.device_kind], "tree": os.getcwd()}
-    if unrolls or not writes:
+    if unrolls or not any(named.values()):
         time_steps(unrolls, out)
-    for name in writes or ["gpt2xl"]:
+    for name in named["write"] or ["gpt2xl"] * (not named["select"]):
         time_rows_write(name, out)
+    for name in named["select"]:
+        time_select(name, out)
     print(json.dumps(out))
 
 

@@ -365,3 +365,84 @@ def test_sparse_decode_attends_the_chosen_rows_and_no_others():
                                         scale=0.1)
     np.testing.assert_allclose(got[1], dense[1], atol=2e-5, rtol=0)
     assert float(jnp.abs(got[0] - dense[0]).max()) > 1e-3
+
+
+# -- the choice alone, against jax.lax.top_k as a set -------------------------
+
+# (block size, table blocks, k, chunk the counts run over or None for the
+# Pallas kernel, interpreted): small blocks through the jax.numpy form, which
+# is the CPU's path, at chunks that cut a lane into several and do not match
+# its blocks; the cell's own sizes through that form and through the kernel.
+_SELECT_SIZES = {"bs4": (4, 9, 12, 8), "bs16": (16, 6, 32, 32),
+                 "cell": (128, 133, 2048, 128),
+                 "cell_kernel": (128, 133, 2048, None)}
+
+
+def _select_scores(case, rng, lanes, n, k):
+    """Scores [lanes, n] and each lane's context for one case of the
+    choice."""
+    scores = rng.standard_normal((lanes, n)).astype(np.float32)
+    ctx = np.full(lanes, n)
+    if case == "ties_by_the_hundred":       # a few values, the k-th among them
+        scores = np.round(scores * (2 if n > 1000 else 1)) / 2
+    elif case == "all_equal":
+        scores[:] = 0.25
+    elif case == "signed_zeros":            # -0.0 beside +0.0 at the k-th
+        scores = np.round(scores * 0.75)
+        zero = scores == 0
+        scores[zero] = np.where(rng.random(zero.sum()) < 0.5, 0.0, -0.0)
+    elif case == "negatives":
+        scores = -np.abs(scores) - 1
+    elif case == "true_inf":                # under the NEG_INF of a tail
+        scores[:, ::3] = -np.inf
+        ctx = np.full(lanes, n - n // 8)
+    else:
+        ctx = np.resize({"ctx_under_k": [5, k - 1], "ctx_is_k": [k],
+                         "ctx_is_k_plus_1": [k + 1, k + 2],
+                         "ctx_is_n": [n, n - 1]}[case], lanes)
+    return np.where(np.arange(n) < ctx[:, None], scores, ops.NEG_INF), ctx
+
+
+@pytest.mark.parametrize("case", [
+    "ties_by_the_hundred", "all_equal", "signed_zeros", "negatives",
+    "true_inf", "ctx_under_k", "ctx_is_k", "ctx_is_k_plus_1", "ctx_is_n"])
+@pytest.mark.parametrize("size", list(_SELECT_SIZES))
+def test_the_choice_is_top_k_as_a_set_ascending_by_position(size, case):
+    """`sparse_select` chooses what `jax.lax.top_k` chooses (the k largest,
+    ties to the lower position, -inf under NEG_INF; -0.0 a tie of +0.0, as
+    the stable sort before it and the benchmark's reference have it, which
+    `top_k` is asked by adding 0.0), exactly k places a lane, ascending by
+    position so that a short context's own positions come first, each the
+    row's place in the pool's table of rows under the lane's table and the
+    layer."""
+    bs, mb, k, chunk = _SELECT_SIZES[size]
+    lanes, layer, n = 3, 2, bs * mb
+    nb = lanes * mb + 5
+    rng = np.random.default_rng(len(case) + bs)
+    tables = rng.permutation(nb)[:lanes * mb].reshape(lanes, mb).astype(
+        np.int32)
+    scores, ctx = _select_scores(case, rng, lanes, n, k)
+    # the kernel takes the index kernel's row as it comes: longer than the
+    # table, NEG_INF behind it, which must never be chosen before a -inf
+    given = np.pad(scores, ((0, 0), (0, 0 if chunk else 128)),
+                   constant_values=ops.NEG_INF)
+    kw = dict(block_size=bs, k=k, n=n, base=layer * nb * bs)
+    place = np.asarray(
+        ops.sparse_select(jnp.asarray(given), jnp.asarray(tables),
+                          use_kernel=True, interpret=True, **kw) if not chunk
+        else ops.sparse_select_reference(
+            jnp.asarray(given), jnp.asarray(tables), chunk=chunk, **kw))
+    assert place.shape == (lanes, k) and place.dtype == np.int32
+    block, row = np.divmod(place - layer * nb * bs, bs)
+    for lane in range(lanes):
+        # every place is a row of a block of the lane's table: its position
+        at = {int(b): i for i, b in enumerate(tables[lane])}
+        pos = np.asarray([at[int(b)] for b in block[lane]]) * bs + row[lane]
+        assert (np.diff(pos) > 0).all()     # ascending: k of them, distinct
+        assert (pos[:min(ctx[lane], k)] < ctx[lane]).all()
+        want = np.sort(np.asarray(jax.lax.top_k(
+            jnp.asarray(scores[lane] + np.float32(0)), k)[1]))
+        np.testing.assert_array_equal(pos, want)
+        np.testing.assert_array_equal(
+            place[lane], tables[lane, pos // bs] * bs + pos % bs
+            + layer * nb * bs)

@@ -67,6 +67,18 @@ def test_the_engine_serves_the_references_greedy_tokens():
     assert eng.cache.allocator.num_free == 96
 
 
+def test_compiled_steps_say_that_no_program_sorts_a_lanes_scores():
+    """`compiled_steps()` over layers of several kinds: `select_sorts`, the
+    sorts of a [rows, context] array in each program, 0 in the T=1 step
+    and in the chunk's (compiled for the chip: tests/test_tpu_aot.py)."""
+    cfg = NANO
+    eng = InferenceEngine("dots3", cfg, _init(cfg), **ENGINE)
+    _run(eng, eng.submit(list(range(30)), 3))
+    steps = eng.compiled_steps()
+    assert {"t1", "t8"} <= set(steps)
+    assert all(s["select_sorts"] == 0 for s in steps.values())
+
+
 def test_sliding_blocks_go_back_as_the_window_moves_and_never_one_attended():
     """After every commit a lane holds exactly the sliding blocks its window
     still reaches (and those its next positions were given), each behind

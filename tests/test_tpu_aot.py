@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from ray_tpu._private.accelerators import (
     ChipAllocator, chip_env, leasable)
 from ray_tpu.inference.compiled import (count_pool_copies,
+                                        count_select_sorts,
                                         count_weight_bytes_copied)
 from ray_tpu.models import decoder, gpt
 from ray_tpu.ops.attention import (kv_row_width, paged_blocks_per_step,
@@ -847,7 +848,9 @@ def test_dots3_steps_fit_a_v5e_and_leave_their_three_pools_in_place(
     15.75 GB, all three pools (the full layers' latent rows and index keys,
     the window layers' rows) donated and left where they are, and the
     kernels of both kinds of layer under the names the benchmark's readers
-    find them by."""
+    find them by; and the indexed layers choose their 2,048 rows of 17,024
+    without sorting a lane's scores (`select_sorts` 0: the kernel
+    `sparse_select`, a trip of a chunk's rows `sparse_select_chunk`)."""
     from benchmark.tools import aot_dots3_sizes
     try:
         texts = aot_dots3_sizes.main("serve_dots3_docs_decode", which)
@@ -863,14 +866,51 @@ def test_dots3_steps_fit_a_v5e_and_leave_their_three_pools_in_place(
     assert 11.3e9 < memory.argument_size_in_bytes < 11.6e9
     assert all(count_pool_copies(text, p.shape) == 0 for p in pools)
     assert set(_kernel_counts(text)) == {"paged_rows_write"} | (
-        {"sparse_index_scores", "sparse_latent_decode_attention",
+        {"sparse_index_scores", "sparse_select",
+         "sparse_latent_decode_attention",
          "window_latent_decode_attention", "moe_grouped_matmul"} if t == 1
-        else {"sparse_index_chunk_scores", "sparse_latent_chunk_attention",
+        else {"sparse_index_chunk_scores", "sparse_select_chunk",
+              "sparse_latent_chunk_attention",
               "window_latent_chunk_attention", "moe_grouped_matmul"})
+    assert count_select_sorts(text, 17024) == 0         # the table's rows
+    assert _kernel_counts(text)["sparse_select" + "_chunk" * (t > 1)] == 3
     # a full layer's latent and index rows go in ONE call, a window
     # layer's in another: three bodies of full layers, two of window layers
     assert _kernel_counts(text)["paged_rows_write"] == 5
     assert not any(_pool_block_updates(text, p.shape) for p in pools)
+
+
+def test_select_sort_counter_sees_a_sort_of_every_lanes_scores(v5e,
+                                                              as_on_chip):
+    """What every tree from PR 41 to PR 47 compiled for the choice: a stable
+    sort of all of a lane's scores, each carrying its row's place.  The
+    counter must not call that 0, nor count the choice as it is now, a
+    sort of one row (the expert dispatch's) or a narrower one (a router's
+    top-k over 256 experts, which the cell's step holds)."""
+    from ray_tpu.ops.attention import sparse_select
+    arg = _arg_on(v5e[0])
+    lanes, mb, bs, k = 64, 133, 128, 2048
+
+    def sorted_choice(scores, tables):
+        place = (jnp.repeat(tables, bs, axis=1) * bs
+                 + jnp.arange(mb * bs, dtype=jnp.int32) % bs)
+        return jax.lax.sort((-scores, place), dimension=1, num_keys=1,
+                            is_stable=True)[1][:, :k]
+
+    def one_row(scores, tables):
+        return (jnp.argsort(scores.reshape(-1))[:k] + tables[0, 0]
+                + jax.lax.top_k(scores[:, :256], 8)[1][0, 0])
+
+    def text_of(fn):
+        return jax.jit(fn).lower(arg((lanes, mb * bs), jnp.float32),
+                                 arg((lanes, mb), jnp.int32)
+                                 ).compile().as_text()
+
+    assert count_select_sorts(text_of(sorted_choice), mb * bs) == 1
+    assert count_select_sorts(text_of(one_row), mb * bs) == 0
+    chosen = text_of(functools.partial(sparse_select, block_size=bs, k=k))
+    assert count_select_sorts(chosen, mb * bs) == 0
+    assert _kernel_counts(chosen) == {"sparse_select": 1}
 
 
 @pytest.mark.parametrize("which,t,rows", [("t1", 1, 64), ("short", 64, 1)],
