@@ -15,6 +15,7 @@ from __future__ import annotations
 import functools
 import itertools
 import logging
+import math
 from typing import NamedTuple, Optional
 
 import jax
@@ -1294,42 +1295,157 @@ def latent_attention_reference(q, pool, block_tables, ctx_lens, q_positions,
     return out.astype(q.dtype)
 
 
-def _latent_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
-                          block_size: int, blocks_per_step: int,
-                          n_steps: int, v_width: int, scale: float,
-                          start_ref=None):
-    """One (lane, run of `blocks_per_step` cache blocks) grid step.  q
-    [H, W]; each block [BS, W] arrives by its own DMA (the pool is handed
-    in once per block of the run, each with its own index map) and is read
-    once: scores for all H heads against its rows, then its first v_width
-    columns as the values.  Products run on the MXU in the pool's dtype
-    with float32 accumulation; the softmax state is float32 in scratch
-    across the lane's sweep, as in the kernels above, and is updated once
-    for the whole run where the blocks are as wide as the lanes (one
-    update a block was 31% of the kernel's roofline at blocks of 128 and
-    4.5% at blocks of 16: PERF.md section 6, PR 31).  With `start_ref` a
-    lane's sweep begins at the block of its first attended position
-    (`_window_decode_kernel`): step 0 is that block's run, and positions
-    before the start are masked."""
-    del bt_ref, layer_ref               # only the index maps read them
-    blocks = refs[:blocks_per_step]
-    o_ref, m_ref, l_ref, acc_ref = refs[blocks_per_step:]
+# VMEM the kernels that walk ONE pool of rows hold in them: a run of blocks,
+# twice.  16 blocks of 128 rows of 640 bf16 columns are 5.2 MB.
+_ROWS_RUN_VMEM = 6 << 20
+
+
+def latent_blocks_per_step(block_size: int, width: int, itemsize: int,
+                           max_blocks: int) -> int:
+    """Blocks in a run of the kernels that walk one pool of rows (latent
+    rows, window rows, index keys): as many runs as a lane of `max_blocks`
+    blocks needs where a run fits `_ROWS_RUN_VMEM` (2 buffers x rows x
+    width) and is at most `_PAGED_RUN_BLOCKS`, and those runs of one
+    length: a run is multiplied whole, so a last run of a few live blocks
+    costs a full one, and these kernels' time goes by the blocks they
+    multiply (A.X-K1's 132 blocks a lane on a v5e: 0.925 ms a call in runs
+    of 11, 12 or 15, 0.965 in 10 runs of 14, 0.983 in 9 of 16; PERF.md
+    section 6, PR 49).  At blocks of 128 bf16 rows: 15 of 640 columns over
+    A.X-K1's table of 132, all 16 of dots3's gathered blocks, 6 of 1,152
+    (its window rows: all a span of 513 touches), 15 of 128 over its 133
+    blocks of index keys."""
+    fit = _ROWS_RUN_VMEM // (2 * block_size * width * itemsize)
+    n_runs = -(-max_blocks // max(1, min(fit, _PAGED_RUN_BLOCKS)))
+    return -(-max_blocks // n_runs)
+
+
+def _walk_lane_runs(bt_ref, len_ref, layer_ref, start_ref, hbm, buf, sems,
+                    state, on_rows):
+    """The walk of the single-query kernels over one pool of rows, as
+    `_paged_decode_kernel` walks two: a grid step is a lane, and sweeps the
+    lane's context run by run, R blocks a run, from the block of its first
+    attended position (`start_ref[lane]`, or 0 without one) to its last.
+
+    The pool stays where it is (HBM); the scalar-prefetched block table,
+    context lengths and layer index say which [BS, W] blocks to copy into
+    buf [2, R, BS, W], one DMA a live block, the next run (or the next
+    lane's first) in flight while `on_rows(rows, base)` works on the run
+    that has arrived: its rows [N, W], the first of them position `base`.
+    That is the whole run, [R * BS, W], where a block is whole tile rows of
+    its dtype, so that a run's blocks are one operand without a relayout
+    (a kernel with an online softmax then updates its state once a run),
+    and otherwise each block that holds context in turn.  A lane without
+    context starts no copy and makes no trip.  Rows behind a lane's last
+    block are never fetched: the buffer is zeroed once, at lane 0, so that
+    they are finite, and `on_rows` masks them by position."""
+    _, kb, bs, _ = buf.shape
+    lanes, mb = bt_ref.shape
     lane = pl.program_id(0)
-    step = pl.program_id(1)
+    layer = layer_ref[0]
+    nxt = jnp.minimum(lane + 1, lanes - 1)
+
+    def blocks(i):
+        """Lane i's first attended block, and one past its last."""
+        first = 0 if start_ref is None else start_ref[i] // bs
+        return first, (len_ref[i] + bs - 1) // bs
+
+    def each_copy(i, run, slot, do):
+        """`do` every DMA of lane i's `run` into buffer `slot`: the
+        blocks that hold context, no others.  (Straight-line code, a
+        branch a block: in a loop over the live blocks a copy of a block of
+        index keys, 32 KB, took longer to issue than to make: 0.55 ms a
+        call where this form takes 0.45; PERF.md section 6, PR 49.)"""
+        first, end = blocks(i)
+        for r in range(kb):
+            blk = first + run * kb + r
+
+            @pl.when(blk < end)
+            def _(r=r, blk=blk):
+                do(pltpu.make_async_copy(
+                    hbm.at[layer, bt_ref[i, jnp.minimum(blk, mb - 1)]],
+                    buf.at[slot, r], sems.at[slot]))
+
+    def start(i, run, slot):
+        each_copy(i, run, slot, lambda dma: dma.start())
+
+    @pl.when(lane == 0)
+    def _first():
+        state[0] = 0                # the buffer of this lane's first run
+        state[1] = 0                # 1: that run is already in flight
+        buf[...] = jnp.zeros_like(buf)
+
+    first, end = blocks(lane)
+    n_runs = (jnp.maximum(end - first, 0) + kb - 1) // kb
+    nxt_first, nxt_end = blocks(nxt)
+    next_live = (n_runs > 0) & (lane + 1 < lanes) & (nxt_end > nxt_first)
+    slot0 = state[0]
+
+    @pl.when((n_runs > 0) & (state[1] == 0))
+    def _cold():                    # lane 0, or the lane before was empty
+        start(lane, 0, slot0)
+
+    def sweep(run, carry):
+        slot = (slot0 + run) & 1
+
+        @pl.when(run + 1 < n_runs)
+        def _ahead():
+            start(lane, run + 1, 1 - slot)
+
+        @pl.when((run + 1 == n_runs) & next_live)
+        def _next_lane():
+            start(nxt, 0, 1 - slot)
+
+        each_copy(lane, run, slot, lambda dma: dma.wait())
+        base = (first + run * kb) * bs
+        if bs % (32 // buf.dtype.itemsize) == 0:
+            on_rows(buf[slot].reshape(kb * bs, -1), base)
+        else:
+            for r in range(kb):
+                @pl.when(base + r * bs < len_ref[lane])
+                def _(r=r):
+                    on_rows(buf[slot, r], base + r * bs)
+        return carry
+
+    jax.lax.fori_loop(0, n_runs, sweep, 0)
+
+    @pl.when(n_runs > 0)
+    def _advance():
+        state[0] = (slot0 + n_runs) & 1
+
+    state[1] = next_live.astype(jnp.int32)
+
+
+def _latent_decode_kernel(bt_ref, len_ref, layer_ref, *refs, v_width: int,
+                          scale: float, windowed: bool = False):
+    """One lane of single-query latent attention (`_walk_lane_runs`; with
+    `windowed` the next scalar-prefetched operand is the lanes' first
+    attended positions, and positions before a lane's are masked).  q
+    [H, W]; a run's rows are read once: scores for all H heads against
+    them, then their first v_width columns as the values.  Products run on
+    the MXU in the pool's dtype with float32 accumulation, the
+    probabilities rounded to the pool's dtype; the softmax state is float32
+    in scratch across the lane's sweep, as in the kernels above, and is
+    updated once for the whole run where a block is whole tile rows (one
+    update a block was 31% of the kernel's roofline at blocks of 128 and
+    4.5% at blocks of 16, and a grid step a run of 512 rows 63%: PERF.md
+    section 6, PRs 31 and 49)."""
+    start_ref, refs = (refs[0], refs[1:]) if windowed else (None, refs)
+    q_ref, hbm, o_ref, buf, sems, state, m_ref, l_ref, acc_ref = refs
+    lane = pl.program_id(0)
     n_ctx = len_ref[lane]
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+    acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    @pl.when(step == 0)
-    def _init():
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    def update(s, base, values):
-        """One online-softmax update with scores s [H, N] of the tokens
-        from `base` on; values(p) is p @ their latents."""
+    def update(c, base):
+        """One online-softmax update with the rows c [N, W] of the
+        positions from `base` on."""
+        s = jax.lax.dot_general(
+            q_ref[...], c, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                  # [H, N]
         pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         keep = pos < n_ctx
-        if start_ref is not None:
+        if windowed:
             keep = keep & (pos >= start_ref[lane])
         s = jnp.where(keep, s * scale, NEG_INF)
         m = m_ref[...]
@@ -1338,65 +1454,79 @@ def _latent_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
         alpha = jnp.exp(m - m_new)
         m_ref[...] = m_new
         l_ref[...] = l_ref[...] * alpha + jnp.sum(p, -1, keepdims=True)
-        acc_ref[...] = acc_ref[...] * alpha + values(p)
+        acc_ref[...] = acc_ref[...] * alpha + jnp.dot(
+            p.astype(c.dtype), c[:, :v_width],
+            preferred_element_type=jnp.float32)                  # [H, C]
 
-    def scores(c_ref):
-        return jax.lax.dot_general(
-            q_ref[...], c_ref[...], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # [H, BS]
-
-    def weighted(p, c_ref):
-        return jnp.dot(p.astype(c_ref.dtype), c_ref[:, :v_width],
-                       preferred_element_type=jnp.float32)       # [H, C]
-
-    run = step * blocks_per_step * block_size
-    if start_ref is not None:
-        run = run + start_ref[lane] // block_size * block_size
-    if block_size % 128 == 0:
-        # The run's blocks side by side: one update of the softmax state
-        # (a max, an exp, a rescale of the accumulator) for the whole run.
-        @pl.when(run < n_ctx)
-        def _compute():
-            update(jnp.concatenate([scores(c) for c in blocks], axis=1), run,
-                   lambda p: sum(weighted(
-                       p[:, r * block_size:(r + 1) * block_size], c)
-                       for r, c in enumerate(blocks)))
-    else:
-        # Blocks narrower than the lane width do not sit side by side
-        # without a relayout: one update a block.
-        for r, c_ref in enumerate(blocks):
-            base = run + r * block_size
-
-            @pl.when(base < n_ctx)
-            def _compute(c_ref=c_ref, base=base):
-                update(scores(c_ref), base, lambda p: weighted(p, c_ref))
-
-    @pl.when(step == n_steps - 1)
-    def _finalize():
-        l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l_safe).astype(o_ref.dtype)
+    _walk_lane_runs(bt_ref, len_ref, layer_ref, start_ref, hbm, buf, sems,
+                    state, update)
+    o_ref[...] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
+        o_ref.dtype)
 
 
-def _window_decode_kernel(bt_ref, len_ref, layer_ref, start_ref, q_ref,
-                          *refs, **kw):
-    """`_latent_decode_kernel` over a lane's last positions alone: from
-    `start_ref[lane]` on."""
-    _latent_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, *refs,
-                          start_ref=start_ref, **kw)
+# (jitted, like `sparse_select`, so that a process traces a kernel once and a
+# program lowers it once, not once a layer body: a body of 3 x R copy sites
+# is a third of a second to trace and lower, and dots3's step programs call
+# these kernels from five layer bodies each)
+@functools.partial(jax.jit, static_argnames=(
+    "v_width", "scale", "max_blocks", "blocks_per_step", "name",
+    "interpret"))
+def _latent_walk_call(q, pool, block_tables, ctx_lens, starts, layer, *,
+                      v_width: int, scale: float, max_blocks: int,
+                      blocks_per_step: Optional[int], name: str,
+                      interpret: bool):
+    """The `pallas_call` of `_latent_decode_kernel`: a lane a grid step over
+    the pool handed in whole, `max_blocks` the most a lane's walk can
+    touch; `starts` [B] or None."""
+    b, h, w = q.shape
+    bs = pool.shape[2]
+    kb = min(blocks_per_step or latent_blocks_per_step(
+        bs, w, pool.dtype.itemsize, max_blocks), max_blocks)
+    prefetch = [block_tables, ctx_lens, jnp.asarray(layer).reshape(1)] + (
+        [] if starts is None else [starts])
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        # block tables, context lengths, layer (, first positions)
+        num_scalar_prefetch=len(prefetch),
+        grid=(b,),
+        in_specs=[pl.BlockSpec((None, h, w), lambda i, *_: (i, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((None, h, v_width), lambda i, *_: (i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, kb, bs, w), pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),          # a buffer each
+            pltpu.SMEM((2,), jnp.int32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, 1), jnp.float32),
+            pltpu.VMEM((h, v_width), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(_latent_decode_kernel, v_width=v_width,
+                          scale=scale, windowed=starts is not None),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
+        interpret=interpret,
+        # Lane by lane in order: a lane starts the next one's first fetch.
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        # The instruction's name in the HLO and so in a device trace.
+        name=name,
+    )(*(x.astype(jnp.int32) for x in prefetch), q.astype(pool.dtype), pool)
 
 
 def window_latent_decode_attention(q, pool, block_tables, ctx_lens, starts,
                                    layer=0, *, v_width: int, scale: float,
                                    span: int,
                                    name="window_latent_decode_attention",
+                                   blocks_per_step: Optional[int] = None,
                                    use_kernel: Optional[bool] = None,
                                    interpret: Optional[bool] = None):
     """`latent_decode_attention` over the positions `starts` [B] to
-    `ctx_lens` - 1 of each lane, at most `span` of them: the blocks behind
-    a lane's start are neither fetched nor scored (its table may name
-    anything there), and the grid covers the blocks a span can touch, not
-    the table."""
-    b, h, w = q.shape
+    `ctx_lens` - 1 of each lane, at most `span` of them: the walk begins at
+    the block of a lane's start, so the blocks behind it are neither
+    fetched nor scored (its table may name anything there), and a run is
+    as long as the blocks a span can touch where they fit (a span of 513
+    over blocks of 128: one run of its 5 or 6 live blocks)."""
     if use_kernel is None:
         use_kernel = not _interpret_kernels()
     if not use_kernel:
@@ -1404,50 +1534,12 @@ def window_latent_decode_attention(q, pool, block_tables, ctx_lens, starts,
             q[:, None], pool, block_tables, ctx_lens,
             (ctx_lens - 1)[:, None], layer, v_width=v_width, scale=scale,
             window=span)[:, 0]
-    if interpret is None:
-        interpret = _interpret_kernels()
     bs = pool.shape[2]
-    mb = block_tables.shape[1]
-    touched = min((span + bs - 2) // bs + 1, mb)
-    kb = min(max(1, 512 // bs), touched)
-    n_steps = -(-touched // kb)
-
-    def block_map(r):
-        def index(i, j, bt, ln, ly, st):
-            last = jnp.maximum(ln[i] - 1, 0) // bs
-            return (ly[0], bt[i, jnp.minimum(st[i] // bs + j * kb + r,
-                                             jnp.minimum(last, mb - 1))],
-                    0, 0)
-        return index
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,   # tables, context lengths, layer, starts
-        grid=(b, n_steps),
-        in_specs=[pl.BlockSpec((None, h, w),
-                               lambda i, j, bt, ln, ly, st: (i, 0, 0))]
-        + [pl.BlockSpec((None, None, bs, w), block_map(r))
-           for r in range(kb)],
-        out_specs=pl.BlockSpec((None, h, v_width),
-                               lambda i, j, bt, ln, ly, st: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, v_width), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_window_decode_kernel, block_size=bs,
-                          blocks_per_step=kb, n_steps=n_steps,
-                          v_width=v_width, scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        name=name,
-    )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), starts.astype(jnp.int32),
-      q.astype(pool.dtype), *([pool] * kb))
+    return _latent_walk_call(
+        q, pool, block_tables, ctx_lens, starts, layer, v_width=v_width,
+        scale=scale, name=name, blocks_per_step=blocks_per_step,
+        max_blocks=min((span + bs - 2) // bs + 1, block_tables.shape[1]),
+        interpret=_interpret_kernels() if interpret is None else interpret)
 
 
 def latent_decode_attention(q, pool, block_tables, ctx_lens, layer=0, *,
@@ -1463,11 +1555,11 @@ def latent_decode_attention(q, pool, block_tables, ctx_lens, layer=0, *,
     latent space.  The Pallas kernel on TPU, the masked-dense path on the
     CPU (the interpreter is too slow for the engine tests).
 
-    A grid step takes `blocks_per_step` blocks (by default as many as
-    make 512 tokens): a 16k context is some 30 steps a lane, not a
-    thousand.  A step past the lane's last block names that block again,
-    so nothing is fetched for it."""
-    b, h, w = q.shape
+    The kernel takes a lane a grid step and a run of `blocks_per_step`
+    blocks at a time (by default what `latent_blocks_per_step` reads from
+    the pool's block size, row width and dtype; the argument is the
+    sweep's and the tests'): a lane costs the runs that hold its context,
+    an inactive lane (`ctx_lens` 0) nothing, and comes out zero."""
     if use_kernel is None:
         use_kernel = not _interpret_kernels()
     if not use_kernel:
@@ -1475,51 +1567,11 @@ def latent_decode_attention(q, pool, block_tables, ctx_lens, layer=0, *,
             q[:, None], pool, block_tables, ctx_lens,
             (ctx_lens - 1)[:, None], layer, v_width=v_width,
             scale=scale)[:, 0]
-    if interpret is None:
-        interpret = _interpret_kernels()
-    bs = pool.shape[2]
-    mb = block_tables.shape[1]
-    kb = blocks_per_step or max(1, 512 // bs)
-    kb = min(kb, mb)
-    n_steps = -(-mb // kb)
-
-    def block_map(r):
-        def index(i, j, bt, ln, ly):
-            last = jnp.maximum(ln[i] - 1, 0) // bs
-            return (ly[0], bt[i, jnp.minimum(j * kb + r,
-                                             jnp.minimum(last, mb - 1))],
-                    0, 0)
-        return index
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,      # block tables, context lengths, layer
-        grid=(b, n_steps),
-        in_specs=[pl.BlockSpec((None, h, w),
-                               lambda i, j, bt, ln, ly: (i, 0, 0))]
-        + [pl.BlockSpec((None, None, bs, w), block_map(r))
-           for r in range(kb)],
-        out_specs=pl.BlockSpec((None, h, v_width),
-                               lambda i, j, bt, ln, ly: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, 1), jnp.float32),
-            pltpu.VMEM((h, v_width), jnp.float32),
-        ],
-    )
-    return pl.pallas_call(
-        functools.partial(_latent_decode_kernel, block_size=bs,
-                          blocks_per_step=kb, n_steps=n_steps,
-                          v_width=v_width, scale=scale),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, h, v_width), q.dtype),
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        # The instruction's name in the HLO and so in a device trace.
-        name=name,
-    )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q.astype(pool.dtype),
-      *([pool] * kb))
+    return _latent_walk_call(
+        q, pool, block_tables, ctx_lens, None, layer, v_width=v_width,
+        scale=scale, name=name, blocks_per_step=blocks_per_step,
+        max_blocks=block_tables.shape[1],
+        interpret=_interpret_kernels() if interpret is None else interpret)
 
 
 def latent_chunk_attention(q, pool, block_tables, ctx_lens, q_positions,
@@ -1630,6 +1682,11 @@ def _rows_as_lanes(fn, block_tables, q_positions, valid, parts, out):
 # (`sparse_select`, since PR 48): the k-th score by bisection over the
 # scores' bits, the chosen set by compares against it, their places by
 # counts over chunks of 128 positions and one one-hot product a lane.
+# The keys and the chosen rows are read by the kernels that walk a lane's
+# rows in runs (`_walk_lane_runs`, since PR 49): `_index_scores_kernel` over
+# the pool of index keys through the lane's table, `_latent_decode_kernel`
+# over the gathered rows, which XLA's gather has laid side by side, as a
+# pool of one layer whose table counts its blocks up.
 # --------------------------------------------------------------------------
 
 def index_scores_reference(q_i, w_i, index_pool, block_tables, ctx_lens,
@@ -1647,90 +1704,99 @@ def index_scores_reference(q_i, w_i, index_pool, block_tables, ctx_lens,
     return jnp.where(kpos[None, :] < ctx_lens[:, None], scores, NEG_INF)
 
 
-def _index_scores_kernel(bt_ref, len_ref, layer_ref, q_ref, w_ref, *refs,
-                         block_size: int, blocks_per_step: int):
-    """One (lane, run of `blocks_per_step` blocks of index keys) grid
-    step: the run's keys against all Hi index queries on the MXU, ReLU,
-    the heads' weighted sum, and the run's scores written where they
-    belong in the lane's row; NEG_INF from the context's end on."""
-    del bt_ref, layer_ref               # only the index maps read them
-    blocks, o_ref = refs[:blocks_per_step], refs[blocks_per_step]
-    lane = pl.program_id(0)
-    run = pl.program_id(1) * blocks_per_step * block_size
-    s = jnp.concatenate([jax.lax.dot_general(
-        q_ref[...], c[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32) for c in blocks], axis=1)
-    scores = jnp.sum(jnp.maximum(s, 0.0) * w_ref[...], axis=0,
-                     keepdims=True)                         # [1, N]
-    pos = run + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
-    o_ref[...] = jnp.where(pos < len_ref[lane], scores, NEG_INF)
+def _index_scores_kernel(bt_ref, len_ref, layer_ref, q_ref, w_ref, hbm,
+                         o_ref, buf, sems, state):
+    """One lane of the indexer's scores (`_walk_lane_runs` over the pool of
+    index keys): a run's keys against all Hi index queries on the MXU,
+    ReLU, the heads' weighted sum, and the run's scores written where they
+    belong in the lane's row [1, N]; NEG_INF from the context's end on."""
+    n_ctx = len_ref[pl.program_id(0)]
+    o_ref[...] = jnp.full_like(o_ref, NEG_INF)
 
+    def score(keys, base):
+        s = jax.lax.dot_general(
+            q_ref[...], keys, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)                 # [Hi, N]
+        scores = jnp.sum(jnp.maximum(s, 0.0) * w_ref[...], axis=0,
+                         keepdims=True)                         # [1, N]
+        pos = base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        at = pl.ds(pl.multiple_of(base, keys.shape[0]), keys.shape[0])
+        o_ref[:, at] = jnp.where(pos < n_ctx, scores, NEG_INF)
 
-# Keys a grid step of the index kernel scores: a step is some 0.35 us of its
-# own on a v5e and a key 256 bytes, so at 512 keys the steps were two thirds
-# of the kernel (34% of its roofline; PERF.md section 6, PR 41).
-_INDEX_RUN = 1024
+    _walk_lane_runs(bt_ref, len_ref, layer_ref, None, hbm, buf, sems, state,
+                    score)
 
 
 def sparse_index_scores(q_i, w_i, index_pool, block_tables, ctx_lens,
                         layer=0, *, name: str = "sparse_index_scores",
+                        blocks_per_step: Optional[int] = None,
                         use_kernel: Optional[bool] = None,
                         interpret: Optional[bool] = None):
     """The indexer's score of every cached position of each lane, one
     decode token a lane (`index_scores_reference`): float32 [B, N] with
-    N >= MB * BS.  The Pallas kernel on TPU (a run of `_INDEX_RUN` keys a
-    grid step, each block fetched once through the table; a step past the
-    lane's last block names that block again, so nothing is fetched for
-    it), the gathered form on the CPU."""
-    b, hi, _ = q_i.shape
-    di = index_pool.shape[3]            # keys narrower than a lane are padded
-    q_i = pack_kv_rows(q_i[..., None, :])
+    N >= MB * BS.  The Pallas kernel on TPU (a lane a grid step, its keys
+    walked in runs of `blocks_per_step` blocks, by default what
+    `latent_blocks_per_step` reads from the pool: 15 blocks of 128 keys
+    over dots3's table of 133; only the blocks that hold context are
+    fetched), the gathered form on the CPU."""
+    q_i = pack_kv_rows(q_i[..., None, :])   # as wide as a (padded) key
     if use_kernel is None:
         use_kernel = not _interpret_kernels()
     if not use_kernel:
         return index_scores_reference(q_i, w_i, index_pool, block_tables,
                                       ctx_lens, layer)
-    if interpret is None:
-        interpret = _interpret_kernels()
+    return _index_walk_call(
+        q_i, w_i, index_pool, block_tables, ctx_lens, layer, name=name,
+        blocks_per_step=blocks_per_step,
+        interpret=_interpret_kernels() if interpret is None else interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("blocks_per_step", "name",
+                                             "interpret"))
+def _index_walk_call(q_i, w_i, index_pool, block_tables, ctx_lens, layer, *,
+                     blocks_per_step: Optional[int], name: str,
+                     interpret: bool):
+    """The `pallas_call` of `_index_scores_kernel` (jitted for what
+    `_latent_walk_call` is); q_i [B, Hi, Di] as wide as a key."""
+    b, hi, di = q_i.shape
     bs = index_pool.shape[2]
     mb = block_tables.shape[1]
-    kb = min(max(1, _INDEX_RUN // bs), mb)
-    n_steps = -(-mb // kb)
+    kb = min(blocks_per_step or latent_blocks_per_step(
+        bs, di, index_pool.dtype.itemsize, mb), mb)
+    # A run's scores are stored at a dynamic column of the lane's row:
+    # whole lane widths of them (blocks past the table are never fetched).
+    per = math.lcm(bs, 128) // bs
+    kb = -(-kb // per) * per
+    n = -(-mb // kb) * kb * bs          # whole runs
 
-    def block_map(r):
-        def index(i, j, bt, ln, ly):
-            last = jnp.maximum(ln[i] - 1, 0) // bs
-            return (ly[0], bt[i, jnp.minimum(j * kb + r,
-                                             jnp.minimum(last, mb - 1))],
-                    0, 0)
-        return index
+    def lane_spec(*shape):
+        return pl.BlockSpec((None,) + shape, lambda i, *_: (i, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,      # block tables, context lengths, layer
-        grid=(b, n_steps),
-        in_specs=[pl.BlockSpec((None, hi, di),
-                               lambda i, j, bt, ln, ly: (i, 0, 0)),
-                  pl.BlockSpec((None, hi, 1),
-                               lambda i, j, bt, ln, ly: (i, 0, 0))]
-        + [pl.BlockSpec((None, None, bs, di), block_map(r))
-           for r in range(kb)],
-        out_specs=pl.BlockSpec((None, 1, kb * bs),
-                               lambda i, j, bt, ln, ly: (i, 0, j)),
+        grid=(b,),
+        in_specs=[lane_spec(hi, di), lane_spec(hi, 1),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=lane_spec(1, n),
+        scratch_shapes=[
+            pltpu.VMEM((2, kb, bs, di), index_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),          # a buffer each
+            pltpu.SMEM((2,), jnp.int32),
+        ],
     )
     out = pl.pallas_call(
-        functools.partial(_index_scores_kernel, block_size=bs,
-                          blocks_per_step=kb),
+        _index_scores_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, 1, n_steps * kb * bs),
-                                       jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((b, 1, n), jnp.float32),
         interpret=interpret,
+        # Lane by lane in order: a lane starts the next one's first fetch.
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         name=name,
     )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
       jnp.asarray(layer, jnp.int32).reshape(1),
       q_i.astype(index_pool.dtype), w_i.astype(jnp.float32)[:, :, None],
-      *([index_pool] * kb))
+      index_pool)
     return out[:, 0]
 
 
@@ -2008,8 +2074,11 @@ def sparse_latent_decode_attention(q, q_i, w_i, pool, index_pool,
     (`sparse_select`: a threshold and counts, no sort; ties go to the
     lower position, as `jax.lax.top_k`'s do), ONE gather of the chosen rows
     of the latent pool, and `latent_decode_attention` over those rows laid
-    side by side.  The context's latent rows are never read.  q [B, H, W],
-    q_i [B, Hi, Di], w_i [B, Hi]; returns [B, H, v_width].
+    side by side: a pool of one layer, a lane's `topk` rows its blocks of
+    128 one after the other, which the kernel walks in runs as it walks a
+    context through a table (at 2,048 rows one run a lane).  The context's
+    latent rows are never read.  q [B, H, W], q_i [B, Hi, Di], w_i
+    [B, Hi]; returns [B, H, v_width].
 
     The choice hands over each row's place in the pool, not its position
     (a position looked up in the table afterwards is a gather of single

@@ -56,6 +56,22 @@ the hundred), ms a trip (`select_<name>_t1_ms`): as the stable two-operand
 the kernel (`kernel`, with the relayout XLA makes in front of it) and as the
 same steps in `jax.numpy` (`jnp`, the CPU's path, compiled for the chip).  All
 three must choose the same set, or the script fails.
+
+Since PR 49 also the single-query kernels over one pool of rows alone
+(`ops/attention.py`: `latent_decode_attention`, and dots3's
+`sparse_index_scores`, `window_latent_decode_attention` and the indexed
+attention over the gathered rows), `attend=axk1` / `attend=dots3`: each of
+the cell's kernels at its lanes, pools, table and a context a block short of
+full, 20 calls chained in one program as the layer scan chains them, ms a
+call (`attend_<name>_<kernel>_ms`) and the share of the least the chip could
+take by the benchmark's own arithmetic (`..._least_pct`:
+`latent_flops.latent_decode`, `sparse_flops.index_scores` / `.latent_rows`).
+`--baseline-root <a checkout>` times that tree's `ops/attention.py` beside
+this one's (`parent`), and `runs=4,8,16` this tree's at those
+`blocks_per_step`:
+
+  python3 scripts/engine_step_time.py attend=axk1 attend=dots3 \
+      runs=4,8,16 --baseline-root .scratch/parent
 """
 
 from __future__ import annotations
@@ -63,6 +79,7 @@ from __future__ import annotations
 import dataclasses
 import functools
 import importlib
+import importlib.util
 import json
 import os
 import sys
@@ -75,10 +92,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from benchmark import manifest
+from benchmark import flops, latent_flops, manifest, sparse_flops
 from ray_tpu.inference.engine import InferenceEngine, _lane_views
 from ray_tpu.inference.kv_cache import PagedKVCache
-from ray_tpu.models import gpt
+from ray_tpu.models import decoder, gpt
+from ray_tpu.ops import attention
 from ray_tpu.ops.attention import (NEG_INF, kv_row_width, paged_rows_update,
                                    sparse_select, sparse_select_reference)
 
@@ -240,6 +258,140 @@ def time_select(name, out):
         np.testing.assert_array_equal(chosen[form], chosen["sort"])
 
 
+ATTEND_CALLS = 20
+
+
+def time_attend(name, out, runs=(), baseline_root=None):
+    """The single-query kernels over one pool of rows alone at the shapes
+    of the serve cell of configuration `name`, into `out`."""
+    m = manifest.load()
+    (cell,) = [c for c in m.cells if c.startswith(f"serve_{name}_")]
+    file = m.load_config(m.cells[cell]["config"])
+    config = manifest.model_config(file, None)
+    eng = m.load_traffic(m.cells[cell]["traffic"])["engine"]
+    spec = importlib.import_module(file["module"]).spec(config)
+    lanes, _, calls = cell_writes(cell)
+    pools = {p.shape[3]: p for call in calls for p in call}
+    bs = eng["block_size"]
+    mb = -(-eng["max_seq_len"] // bs)
+    peaks = manifest.peaks(jax.devices()[0].device_kind)
+    rng = np.random.default_rng(0)
+    ctx = jnp.asarray(rng.integers((mb - 2) * bs, (mb - 1) * bs, lanes),
+                      jnp.int32)
+    n_ctx = float(ctx.sum())
+    trees = {"kernel": attention}
+    if baseline_root:
+        at = importlib.util.spec_from_file_location(
+            "baseline_attention",
+            os.path.join(baseline_root, "ray_tpu", "ops", "attention.py"))
+        trees["parent"] = importlib.util.module_from_spec(at)
+        at.loader.exec_module(trees["parent"])
+
+    def timed(kernel, least, pool, make, swept=True):
+        """`make(ops, **kw)(pool, layer)` -> a call's result, for each
+        tree's `ops` (and this tree's at each of `runs`)."""
+        forms = [(form, ops, {}) for form, ops in trees.items()]
+        forms += [(f"run{kb}", attention, {"blocks_per_step": kb})
+                  for kb in runs if swept]
+        data = jax.random.normal(jax.random.key(1), pool.shape, pool.dtype)
+        want = None
+        for form, ops, kw in forms:
+            call = make(ops, **kw)
+
+            def chain(data):
+                first = call(data, 0)
+                return jax.lax.fori_loop(
+                    1, ATTEND_CALLS, lambda i, acc: acc + call(
+                        data, i % pool.shape[0]).astype(acc.dtype),
+                    first.astype(jnp.float32)), first
+
+            many = jax.jit(chain)
+            _, first = jax.block_until_ready(many(data))
+            first = np.asarray(first, np.float32)
+            first = np.where(first > NEG_INF / 2, first, 0)
+            if want is None:
+                want = first
+            # (bf16 products summed in another order; a row of scores is
+            # as long as a form's whole runs)
+            width = min(first.shape[-1], want.shape[-1])
+            np.testing.assert_allclose(first[..., :width], want[..., :width],
+                                       atol=0.05, rtol=0.05)
+            ms = []
+            for _ in range(3):
+                t0 = time.perf_counter()
+                for _ in range(5):
+                    left = many(data)
+                jax.block_until_ready(left)
+                ms.append(round(1000 * (time.perf_counter() - t0)
+                                / (5 * ATTEND_CALLS), 4))
+            key = f"attend_{name}_{kernel}"
+            out.setdefault(key + "_ms", {})[form] = ms
+            out.setdefault(key + "_least_pct", {})[form] = round(
+                100 * least * 1000 / min(ms), 1)
+            print("[attend]", key, form, ms, flush=True)
+
+    def tables_of(nb):
+        return jnp.asarray(np.stack([rng.permutation(nb)[:mb]
+                                     for _ in range(lanes)]).astype(np.int32))
+
+    def rows(shape, dtype):
+        return jax.random.normal(jax.random.key(2), shape, dtype)
+
+    seen = set()
+    for run in decoder._stacks(spec, config):
+        s = decoder.latent_sizes(spec, run.sizes or config)
+        if s in seen:
+            continue
+        seen.add(s)
+        f = dataclasses.asdict(s)
+        pool = pools[attention.latent_row_width(s.kv_lora_rank,
+                                                s.qk_rope_head_dim)]
+        tables = tables_of(pool.shape[1])
+        q = rows((lanes, s.n_heads, pool.shape[3]), pool.dtype)
+        kw = dict(v_width=s.kv_lora_rank, scale=s.attn_scale)
+        if s.window:
+            least, _ = flops.roofline_s(*sparse_flops.latent_rows(
+                lanes * s.window, lanes, f), peaks)
+            timed("window_latent_decode_attention", least, pool,
+                  lambda ops, **x: lambda data, layer:
+                  ops.window_latent_decode_attention(
+                      q, data, tables, ctx, ctx - s.window, layer,
+                      span=s.window, use_kernel=True, **kw, **x))
+        elif s.index_topk:
+            keys = pools[s.index_head_dim]
+            q_i = rows((lanes, s.index_n_heads, s.index_head_dim),
+                       keys.dtype)
+            w_i = rows((lanes, s.index_n_heads), jnp.float32)
+            least, _ = flops.roofline_s(*sparse_flops.index_scores(
+                n_ctx, lanes, f), peaks)
+            timed("sparse_index_scores", least, keys,
+                  lambda ops, **x: lambda data, layer:
+                  ops.sparse_index_scores(q_i, w_i, data, tables, ctx,
+                                          layer, use_kernel=True, **x))
+            # the indexed attention's own call: the gathered rows as a
+            # pool of one layer under an `arange` table
+            k = min(s.index_topk, mb * bs)
+            least, _ = flops.roofline_s(*sparse_flops.latent_rows(
+                lanes * k, lanes, f), peaks)
+            gathered = jax.ShapeDtypeStruct(
+                (1, lanes * k // bs, bs, pool.shape[3]), pool.dtype)
+            own = jnp.arange(lanes * k // bs, dtype=jnp.int32).reshape(
+                lanes, k // bs)
+            timed("sparse_latent_decode_attention", least, gathered,
+                  lambda ops, **x: lambda data, layer:
+                  ops.latent_decode_attention(
+                      q, data, own, jnp.minimum(ctx, k), layer,
+                      use_kernel=True, **kw, **x))
+        else:
+            least, _ = flops.roofline_s(*latent_flops.latent_decode(
+                n_ctx, lanes, f), peaks)
+            timed("latent_decode_attention", least, pool,
+                  lambda ops, **x: lambda data, layer:
+                  ops.latent_decode_attention(
+                      q, data, tables, ctx, layer, use_kernel=True, **kw,
+                      **x))
+
+
 def time_steps(unrolls, out):
     """The engine's step programs at `serve_gpt2xl_decode`'s sizes, into
     `out`."""
@@ -338,18 +490,27 @@ def time_steps(unrolls, out):
 
 
 def main(argv):
+    baseline_root = None
+    if "--baseline-root" in argv:
+        at = argv.index("--baseline-root")
+        baseline_root = argv[at + 1]
+        argv = argv[:at] + argv[at + 2:]
     named = {kind: [a.split("=", 1)[1] for a in argv
                     if a.startswith(kind + "=")]
-             for kind in ("write", "select")}
+             for kind in ("write", "select", "attend", "runs")}
+    runs = [int(kb) for r in named.pop("runs") for kb in r.split(",")]
     unrolls = [int(a) for a in argv if "=" not in a]
     dev = jax.devices()[0]
     out = {"device": [dev.platform, dev.device_kind], "tree": os.getcwd()}
     if unrolls or not any(named.values()):
         time_steps(unrolls, out)
-    for name in named["write"] or ["gpt2xl"] * (not named["select"]):
+    for name in named["write"] or ["gpt2xl"] * (
+            not named["select"] and not named["attend"]):
         time_rows_write(name, out)
     for name in named["select"]:
         time_select(name, out)
+    for name in named["attend"]:
+        time_attend(name, out, runs, baseline_root)
     print(json.dumps(out))
 
 

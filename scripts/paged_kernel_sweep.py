@@ -17,7 +17,8 @@ needs are the live context's K and V rows, once.
 grid step, its live blocks copied by `make_async_copy` into a
 double-buffered [R, BS, W] scratch, the trip count read from `ctx_lens`, the
 next lane's first run started under the lane's last.  `specs` is the form
-kept here for the comparison, the latent kernel's: a grid step a run, every
+kept here for the comparison, the latent kernel's until PR 49 (the record
+of the form that is gone from `ops/attention.py`): a grid step a run, every
 block of the run by a `BlockSpec` of its own whose index map names a block
 already in VMEM for what is past the context, the pipeline fetching what
 changed.
@@ -100,7 +101,7 @@ def specs_paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                                  layer=0, *, kv_heads=None, scale=None,
                                  blocks_per_step=8, interpret=False):
     """`ops.attention.paged_decode_attention`'s arguments and result, the
-    blocks fetched by the pipeline (the latent kernel's form)."""
+    blocks fetched by the pipeline (the latent kernel's form until PR 49)."""
     b, h, d = q.shape
     kh = kv_heads or h
     _, _, bs, w = k_pool.shape

@@ -210,6 +210,97 @@ def test_latent_decode_kernel_updates_once_a_run_at_lane_wide_blocks():
         np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
 
 
+def _walk_case(seed, ctx, *, bs, mb=5, heads=4, dtype=jnp.float32,
+               layers=2):
+    """Lanes of the contexts `ctx` over a pool whose block 0 is NaN: every
+    table entry behind a lane's last block names it, and so does all of an
+    empty lane's table, so a kernel that fetched a block it has no use for
+    would hand NaN on (a probability of 0 times NaN).  Returns the
+    kernel's arguments and the same pool with block 0 zeroed, for the
+    masked-dense reference."""
+    rng = np.random.default_rng(seed)
+    c, r, lanes = 96, 32, len(ctx)
+    nb = lanes * mb + 1
+    rows = rng.standard_normal((layers, nb, bs, c + r)).astype(np.float32)
+    clean = jnp.asarray(rows, dtype).at[:, 0].set(0)
+    tables = 1 + rng.permutation(nb - 1).reshape(lanes, mb)
+    ctx = np.asarray(ctx, np.int32)
+    tables[np.arange(mb)[None] >= -(-ctx[:, None] // bs)] = 0
+    q = jnp.asarray(rng.standard_normal((lanes, heads, c + r)), dtype)
+    return (q, clean.at[:, 0].set(jnp.nan), clean,
+            jnp.asarray(tables, jnp.int32), jnp.asarray(ctx), c)
+
+
+def _walk_matches_the_masked_dense_path(case, atol, **kw):
+    q, pool, clean, tables, ctx, c = case
+    want = ops.latent_attention_reference(
+        q[:, None], clean, tables, ctx, (ctx - 1)[:, None], 1, v_width=c,
+        scale=0.2)[:, 0]
+    got = ops.latent_decode_attention(
+        q, pool, tables, ctx, jnp.asarray(1), v_width=c, scale=0.2,
+        use_kernel=True, interpret=True, **kw)
+    live = np.asarray(ctx) > 0
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live],
+        atol=atol, rtol=0)
+    # a lane without context comes out zero (the reference's uniform
+    # average over masked rows is its own business)
+    assert not np.asarray(got, np.float32)[~live].any()
+
+
+@pytest.mark.parametrize("blocks_per_step", [1, 2, None])
+@pytest.mark.parametrize("bs", [16, 32, 64, 128])
+def test_latent_walk_ends_where_each_lanes_context_does(bs, blocks_per_step):
+    """The run-walking kernel at every block size the cells' sweeps tried
+    and at runs of one block, of two and of the default (the whole table
+    here): contexts that end inside a run, at a run's edge (of two blocks
+    and of the table), of one token, and an EMPTY lane between live ones,
+    whose table names nothing good.  Only the blocks that hold context are
+    fetched."""
+    ctx = [3 * bs - 5, 2 * bs, 0, 1, 5 * bs]
+    _walk_matches_the_masked_dense_path(
+        _walk_case(bs, ctx, bs=bs), 2e-5, blocks_per_step=blocks_per_step)
+
+
+@pytest.mark.parametrize("heads", [64, 128])
+def test_latent_walk_takes_the_cells_heads(heads):
+    """64 query rows a tile (A.X-K1, dots3's window layers) and 128 (dots3's
+    indexed attention), at blocks of 128 in runs of two."""
+    _walk_matches_the_masked_dense_path(
+        _walk_case(heads, [300, 0, 256, 130], bs=128, mb=3, heads=heads),
+        2e-5, blocks_per_step=2)
+
+
+@pytest.mark.parametrize("ctx", [[1, 0, 0], [0, 0, 23], [0, 40, 0]])
+def test_latent_walk_starts_cold_behind_an_empty_lane(ctx):
+    """A lane starts the next one's first fetch only where that lane has
+    context; behind an empty lane (or as lane 0) a lane starts its own."""
+    _walk_matches_the_masked_dense_path(
+        _walk_case(sum(ctx), ctx, bs=8), 2e-5, blocks_per_step=2)
+
+
+def test_latent_walk_updates_once_a_block_where_blocks_do_not_stack():
+    """bfloat16 blocks of 8 rows are half a tile row: they do not sit one
+    under the other as one operand, so the softmax state is updated once a
+    block, as the paged kernel's."""
+    _walk_matches_the_masked_dense_path(
+        _walk_case(5, [29, 0, 16, 1], bs=8, dtype=jnp.bfloat16), 3e-2,
+        blocks_per_step=3)
+
+
+def test_latent_blocks_per_step_reads_the_run_from_the_rows():
+    """As many runs as the table needs at runs that fit the buffers' VMEM
+    and hold at most 16 blocks, and those of one length."""
+    assert ops.latent_blocks_per_step(128, 640, 2, 132) == 15   # 9 runs
+    assert ops.latent_blocks_per_step(128, 640, 2, 16) == 16
+    assert ops.latent_blocks_per_step(128, 1152, 2, 6) == 6
+    assert ops.latent_blocks_per_step(128, 1152, 2, 133) == 10  # 14 runs
+    assert ops.latent_blocks_per_step(128, 128, 2, 133) == 15
+    assert ops.latent_blocks_per_step(16, 640, 2, 1056) == 16
+    assert ops.latent_blocks_per_step(128, 640, 2, 3) == 3
+    assert ops.latent_blocks_per_step(2048, 4096, 4, 8) == 1
+
+
 @pytest.mark.parametrize("t,q_tile,ctx_tile", [(8, 4, 16), (8, 8, 8),
                                                (6, 128, 512)])
 def test_latent_chunk_attention_reads_its_own_blocks_in_tiles(t, q_tile,

@@ -338,6 +338,107 @@ def test_index_scores_kernel_matches_the_gathered_form():
                                atol=1e-4, rtol=0)
 
 
+def _poisoned(pool, tables, ctx, bs):
+    """The pool with block 0 NaN, the tables naming it wherever a lane
+    holds no context (behind its last block; all of an empty lane's), and
+    the pool with block 0 zero for the gathered forms: a kernel that
+    fetched a block it has no use for would hand NaN on."""
+    dead = np.arange(tables.shape[1])[None] >= -(-np.asarray(ctx)[:, None]
+                                                 // bs)
+    tables = jnp.where(dead, 0, jnp.where(tables == 0, tables.max() + 1,
+                                          tables))
+    clean = pool.at[:, 0].set(0)
+    return clean.at[:, 0].set(jnp.nan), clean, tables
+
+
+# (window, blocks a run, or None for the default: all a span can touch)
+@pytest.mark.parametrize("window,blocks_per_step", [
+    (21, None), (26, None), (26, 1), (40, 2), (64, 2), (70, None)],
+    ids=["start_inside_a_block", "start_at_a_blocks_edge",
+         "a_block_a_run", "span_crosses_a_runs_edge", "three_runs",
+         "window_longer_than_a_context"])
+def test_window_walk_begins_at_the_block_of_each_lanes_start(
+        window, blocks_per_step):
+    """The window kernel's walk from the block of `starts[lane]`: lane 0
+    (90 tokens) starts inside a block (69), at a block's edge (64) or runs
+    over several runs; a lane shorter than the window starts at 0; an
+    empty lane between live ones comes out zero and fetches nothing; the
+    blocks behind a start and past an end are never fetched."""
+    rng, pool, tables = _pool_case(4, lanes=4, nb=25)
+    ctx = jnp.asarray([90, 0, 37, 5], jnp.int32)
+    pool, clean, tables = _poisoned(pool, tables, ctx, 16)
+    starts = jnp.maximum(ctx - window, 0)
+    tables = jnp.where(jnp.arange(6)[None] < starts[:, None] // 16, 0,
+                       tables)              # freed behind the start
+    q = jnp.asarray(rng.standard_normal((4, 4, 128)), jnp.float32)
+    kw = dict(v_width=96, scale=0.1, span=window)
+    want = ops.window_latent_decode_attention(
+        q, clean, tables, ctx, starts, 1, use_kernel=False, **kw)
+    got = ops.window_latent_decode_attention(
+        q, pool, tables, ctx, starts, 1, use_kernel=True, interpret=True,
+        blocks_per_step=blocks_per_step, **kw)
+    live = np.asarray(ctx) > 0
+    np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
+                               atol=2e-6, rtol=0)
+    assert not np.asarray(got)[~live].any()
+
+
+@pytest.mark.parametrize("blocks_per_step", [1, 2, 4, None])
+@pytest.mark.parametrize("bs", [16, 128])
+def test_index_walk_scores_the_context_and_nothing_behind_it(
+        bs, blocks_per_step):
+    """The index kernel's walk: a run's scores land where they belong in
+    the lane's row, NEG_INF from the context's end on (inside a run, at a
+    run's edge, behind the last run) and all along an empty lane's row;
+    the keys behind a context's end are never fetched."""
+    rng, pool, tables = _pool_case(5, lanes=4, bs=bs, nb=25)
+    ctx = jnp.asarray([5 * bs + 10, 0, 2 * bs, 5], jnp.int32)
+    pool, clean, tables = _poisoned(pool, tables, ctx, bs)
+    q_i = jnp.asarray(rng.standard_normal((4, 4, 128)), jnp.float32)
+    w_i = jnp.asarray(rng.standard_normal((4, 4)), jnp.float32)
+    want = np.asarray(ops.index_scores_reference(
+        q_i, w_i, clean, tables, ctx, 1))
+    got = np.asarray(ops.sparse_index_scores(
+        q_i, w_i, pool, tables, ctx, 1, use_kernel=True, interpret=True,
+        blocks_per_step=blocks_per_step))
+    assert got.shape[1] >= 6 * bs and got.shape[1] % bs == 0
+    np.testing.assert_allclose(got[:, :6 * bs], want, atol=1e-4, rtol=0)
+    for lane, n in enumerate(np.asarray(ctx)):
+        assert (got[lane, n:] == ops.NEG_INF).all()
+        assert (got[lane, :n] > ops.NEG_INF).all()
+
+
+@pytest.mark.parametrize("heads", [4, 128])
+def test_indexed_attention_walks_the_gathered_rows_of_a_short_context(heads):
+    """The indexed attention's own call, as `sparse_latent_decode_attention`
+    makes it: the chosen rows side by side as a pool of one layer under an
+    `arange` table, read by the kernel as far as min(context, topk).  A
+    context shorter than `topk` has its own positions first and fill behind
+    them, which the walk never multiplies in; the reference path of the
+    whole function is the oracle."""
+    rng, pool, tables = _pool_case(6, bs=16)
+    _, index_pool, _ = _pool_case(7, bs=16)
+    ctx = jnp.asarray([90, 12, 32], jnp.int32)      # 12 < topk = 32 = a lane
+    q = jnp.asarray(rng.standard_normal((3, heads, 128)), jnp.float32)
+    q_i = jnp.asarray(rng.standard_normal((3, 4, 128)), jnp.float32)
+    w_i = jnp.asarray(rng.standard_normal((3, 4)), jnp.float32)
+    kw = dict(v_width=96, scale=0.1)
+    want = ops.sparse_latent_decode_attention(
+        q, q_i, w_i, pool, index_pool, tables, ctx, 1, topk=32, **kw)
+    scores = ops.sparse_index_scores(q_i, w_i, index_pool, tables, ctx, 1,
+                                     use_kernel=True, interpret=True)
+    place = ops.sparse_select(scores, tables, block_size=16, k=32, n=96,
+                              base=24 * 16)
+    rows = pool.reshape(-1, 128)[place]                         # [3, 32, W]
+    for per, kb in ((16, None), (32, None), (8, 3)):
+        got = ops.latent_decode_attention(
+            q, rows.reshape(1, 3 * 32 // per, per, 128),
+            jnp.arange(3 * 32 // per, dtype=jnp.int32).reshape(3, -1),
+            jnp.minimum(ctx, 32), 0, use_kernel=True, interpret=True,
+            blocks_per_step=kb, name="sparse_latent_decode_attention", **kw)
+        np.testing.assert_allclose(got, want, atol=2e-5, rtol=0)
+
+
 def test_sparse_decode_attends_the_chosen_rows_and_no_others():
     """Indexed single-query attention = masked dense attention over the
     top-k positions by index score; a lane whose context fits topk attends

@@ -757,6 +757,104 @@ def test_latent_decode_kernel_compiles_for_v5e_at_every_block_size(
         assert kernel.split("%")[-1].startswith("latent_decode_attention")
 
 
+def _latent_walk(name, lanes, heads, pool, mb):
+    """`latent_decode_attention` under `name`: (call, argument shapes, the
+    pool's place among them)."""
+    from ray_tpu.ops.attention import latent_decode_attention
+    return (functools.partial(latent_decode_attention, v_width=512,
+                              scale=0.13, name=name),
+            [((lanes, heads, pool[3]), jnp.bfloat16), (pool, jnp.bfloat16),
+             ((lanes, mb), jnp.int32), ((lanes,), jnp.int32),
+             ((), jnp.int32)], 1)
+
+
+def _window_walk(name, lanes, pool, mb):
+    from ray_tpu.ops.attention import window_latent_decode_attention
+    return (functools.partial(window_latent_decode_attention, v_width=1024,
+                              scale=0.13, span=513, name=name),
+            [((lanes, 64, pool[3]), jnp.bfloat16), (pool, jnp.bfloat16),
+             ((lanes, mb), jnp.int32), ((lanes,), jnp.int32),
+             ((lanes,), jnp.int32), ((), jnp.int32)], 1)
+
+
+def _index_walk(name, lanes, pool, mb):
+    from ray_tpu.ops.attention import sparse_index_scores
+    return (functools.partial(sparse_index_scores, name=name),
+            [((lanes, 64, 128), jnp.bfloat16), ((lanes, 64), jnp.float32),
+             (pool, jnp.bfloat16), ((lanes, mb), jnp.int32),
+             ((lanes,), jnp.int32), ((), jnp.int32)], 2)
+
+
+def _pallas_calls(jaxpr):
+    """Every `pallas_call` of a jaxpr, those of the jitted calls inside it
+    too."""
+    found = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            found.append(e)
+        elif e.primitive.name in ("jit", "pjit"):
+            found += _pallas_calls(e.params["jaxpr"].jaxpr)
+    return found
+
+
+# The single-query kernels over one pool of rows at the two cells' shapes:
+# A.X-K1's T=1 step (32 lanes, 64 heads, rows of 640, a table of 132 blocks
+# of 128 over a pool of 7 layers); dots3's (64 lanes, and 64 rows a trip of
+# a chunk): the indexed attention over 2,048 gathered rows a lane as a pool
+# of one layer (128 heads), the window layers' rows of 1,152, the index
+# keys of 128 under 64 index heads, each over a table of 133.
+_ROW_WALKS = {
+    "latent_decode_attention": lambda n: _latent_walk(
+        n, 32, 64, (7, 1536, 128, 640), 132),
+    "sparse_latent_decode_attention": lambda n: _latent_walk(
+        n, 64, 128, (1, 1024, 128, 640), 16),
+    "sparse_latent_chunk_attention": lambda n: _latent_walk(
+        n, 64, 128, (1, 1024, 128, 640), 16),
+    "window_latent_decode_attention": lambda n: _window_walk(
+        n, 64, (6, 768, 128, 1152), 133),
+    "window_latent_chunk_attention": lambda n: _window_walk(
+        n, 64, (6, 768, 128, 1152), 133),
+    "sparse_index_scores": lambda n: _index_walk(
+        n, 64, (3, 1536, 128, 128), 133),
+    "sparse_index_chunk_scores": lambda n: _index_walk(
+        n, 64, (3, 1536, 128, 128), 133),
+}
+
+
+@pytest.mark.parametrize("name", list(_ROW_WALKS))
+def test_row_walk_kernels_compile_for_v5e_at_the_cells_shapes(
+        v5e, as_on_chip, name):
+    """One Mosaic call under the name the benchmark's readers find it by,
+    a lane a grid step (the walk over the lane's context is inside it, as
+    the paged kernel's since PR 32; until PR 49 a run of 512-1,024 rows
+    was a grid step: 33 a lane at A.X-K1's table), the pool handed in
+    whole and left where it is."""
+    fn, shapes, pool_at = _ROW_WALKS[name](name)
+    arg = _arg_on(v5e[0])
+    args = [arg(*s) for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert [k.split("%")[-1].split(".")[0] for k in _kernel_names(text)] == [
+        name]
+    assert count_pool_copies(text, shapes[pool_at][0]) == 0
+    (call,) = _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert call.params["grid_mapping"].grid == (shapes[0][0][0],)
+
+
+@pytest.mark.parametrize("bs", [16, 32, 64, 128])
+def test_window_and_index_walks_compile_for_v5e_at_every_block_size(
+        v5e, as_on_chip, bs):
+    """Blocks narrower than the lane width: a run's rows stack all the
+    same (a block is whole tile rows), and a run of index keys is whole
+    lane widths of scores, so its store into the lane's row is aligned."""
+    for walk, width in ((_window_walk, 1152), (_index_walk, 128)):
+        for mb in (2048 // bs, 6):
+            fn, shapes, _ = walk("k", 8, (2, 4096 // bs, bs, width), mb)
+            arg = _arg_on(v5e[0])
+            text = jax.jit(fn).lower(
+                *(arg(*s) for s in shapes)).compile().as_text()
+            assert len(_kernel_names(text)) == 1
+
+
 # EvaByte at its published widths as `serve_evabyte_sessions_decode` serves
 # it: one stage of a four-stage pipeline (8 of 32 layers), 24 lanes over a
 # windowed pool of 576 blocks of 128 rows of 4,096 columns, requests of
