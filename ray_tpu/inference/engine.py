@@ -655,7 +655,7 @@ class InferenceEngine:
             for name in ("index_topk", "window"))
         self._sparse = ({"decode_steps": 0, "ctx_tokens": 0,
                          "rows_chosen": 0, "window_rows": 0}
-                        if kind == "layered" else None)
+                        if kind == "layered" and latent else None)
         # Over a windowed cache `_paged` counts the rows attended (what the
         # kernel reads), `_eva` the same T=1 steps with their true context
         # beside those rows.  The compaction program is made at its first
@@ -670,6 +670,11 @@ class InferenceEngine:
         # stack of one-part layers no reader can take them from `n_layers`.
         self._layers = layer_counts(self.model.spec(self.config),
                                     self.config)
+        if self._paged is not None and self._layers["window"]:
+            # K and V rows of which some layers read a window alone: the
+            # rows a T=1 step's full layers and its window layers read,
+            # summed over the layers of each kind.
+            self._paged.update(rows_full=0, rows_window=0)
         self._ssm = {"tokens_scanned": 0, "tokens_updated": 0}
         # A verify step writes past what it may commit: the cache says
         # whether a lane's layout and parts can be rolled back from there.
@@ -1018,8 +1023,11 @@ class InferenceEngine:
             **({"ssm": {**self.cache.kind_stats(), **self._ssm}}
                if self._stateful else {}),
             **({} if self._sparse is None else {
-                "sparse": dict(self._sparse),
-                "windows": self.cache.kind_stats()}),
+                "sparse": dict(self._sparse)}),
+            # A cache with a sliding part: its blocks given back in
+            # mid-sequence so far, and the blocks and bytes of each kind.
+            **({"windows": self.cache.kind_stats()} if self._releases
+               else {}),
         }
 
     def _moe_stats(self) -> dict:
@@ -1686,6 +1694,12 @@ class InferenceEngine:
             if self._latent is None:
                 self._paged["runs_live"] += sum(
                     -(-c // self._paged_run) for c in ctx)
+                if self._layers["window"]:
+                    w, n = self._slide_rows, self._layers["window"]
+                    self._paged["rows_full"] += (
+                        self._layers["kv"] - n) * sum(ctx)
+                    self._paged["rows_window"] += n * sum(
+                        min(c, w) for c in ctx)
         return (t, sample, lanes, host, rows), chunks
 
     def _run_step(self, batch, spec: bool = False):

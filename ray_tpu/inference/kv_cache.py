@@ -614,8 +614,10 @@ class CachePart:
 
 class SlidingRows(CachePart):
     """A row a token of which only a lane's last `window` positions are
-    ever read again: a pool, a `SealedIndex` and a second table (the second
-    half of `block_tables`' columns, a slot a token block) of its own.  A
+    ever read again: its pools (`pools` of them on the same blocks: one of
+    latent rows, or a K and a V pool), a `SealedIndex` and a second table
+    (the second half of `block_tables`' columns, a slot a token block) of
+    its own.  A
     block wholly behind a lane's window goes back in mid-sequence
     (`release`, after every commit), staying indexed as evictable if it was
     sealed; a token block is sealed here and in the growing kind under the
@@ -632,7 +634,7 @@ class SlidingRows(CachePart):
 
     def __init__(self, cache: "PagedKVCache", n_layers: int, kv_heads: int,
                  head_dim: int, window: int, ahead: int,
-                 num_blocks: Optional[int] = None):
+                 num_blocks: Optional[int] = None, pools: int = 1):
         self.cache, self.window = cache, window
         # Positions a lane may be written past its committed length: the
         # engine's longest slice, twice (one step runs ahead of the last
@@ -641,8 +643,9 @@ class SlidingRows(CachePart):
         if num_blocks is None:
             num_blocks = cache.max_lanes * self.peak(True)
         self.index = SealedIndex(num_blocks)
-        self.pool = cache._claim_pool(n_layers, num_blocks, kv_heads,
-                                      head_dim)
+        self.pools = tuple(cache._claim_pool(n_layers, num_blocks, kv_heads,
+                                             head_dim)
+                           for _ in range(pools))
         self.column = cache._claim_table()
         # lane -> {slot: block}, and its prompt's length (0: no lane)
         self._lane: List[Dict[int, int]] = [
@@ -738,23 +741,39 @@ class SlidingRows(CachePart):
         self._prompt[lane] = 0
 
     def stats(self, cache):
-        """(engine `stats()["windows"]`)"""
-        return {"blocks_freed": cache.stats["slide_blocks_freed"]}
+        """Blocks given back in mid-sequence so far, and the blocks each
+        kind holds now, live or cached, with their bytes over all layers
+        and pools (engine `stats()["windows"]`)."""
+        own, grown = self.index.allocator, cache.allocator
+        return {"blocks_freed": cache.stats["slide_blocks_freed"],
+                "sliding_blocks": own.num_blocks - own.num_unused,
+                "growing_blocks": grown.num_blocks - grown.num_unused,
+                "sliding_bytes": sum(cache.pool_bytes(i)
+                                     for i in self.pools),
+                "growing_bytes": sum(cache.pool_bytes(i)
+                                     for i in range(self.pools[0]))}
 
     def export(self, keys):
         """The blocks from chain position `slide_from` on, which is all a
-        match of this length reads of them."""
+        match of this length reads of them: one pool's rows, or a list of
+        every pool's."""
         tail = self._tail(len(keys))
-        return {"slide_from": tail.start, "slide": self.cache.read_blocks(
-            jnp.asarray(np.asarray([self.index.get(keys[i]) for i in tail],
-                                   np.int32)), self.pool)}
+        idx = jnp.asarray(np.asarray([self.index.get(keys[i]) for i in tail],
+                                     np.int32))
+        rows = [self.cache.read_blocks(idx, pool) for pool in self.pools]
+        return {"slide_from": tail.start,
+                "slide": rows[0] if len(rows) == 1 else rows}
 
     def install(self, more, keys):
         first = int(more["slide_from"])
-        return self.index.install(
-            list(enumerate(keys[first:])),
-            lambda idx, pos: self.cache.write_blocks(
-                jnp.asarray(idx), more["slide"][:, pos], None, self.pool))
+        rows = more["slide"] if len(self.pools) > 1 else [more["slide"]]
+
+        def write(idx, pos):
+            for pool, blocks in zip(self.pools, rows):
+                self.cache.write_blocks(jnp.asarray(idx), blocks[:, pos],
+                                        None, pool)
+
+        return self.index.install(list(enumerate(keys[first:])), write)
 
 
 class LaneState(CachePart):
@@ -981,19 +1000,20 @@ class PagedKVCache:
         # `_pool_rows` each pool's (kv_heads, head_dim).
         self.extra = tuple(_extra)
         self._dtype = dtype
+        # Every pool, in the order the model's runs index them: the growing
+        # kind's rows (`_paired`: a K and a V pool; else one), its further
+        # rows (`extra`), then what the parts claim.
         self._pools: list = []
         self._pool_rows: List[Tuple[int, int]] = []
         self._tables = 1
-        self._claim_pool(n_layers, num_blocks, kv_heads, head_dim)
-        self.v = None if latent else jnp.zeros_like(self._pools[0])
+        self._paired = not latent
+        for _ in range(1 + self._paired):
+            self._claim_pool(n_layers, num_blocks, kv_heads, head_dim)
         for width in self.extra:
             self._claim_pool(n_layers, num_blocks, 1, width)
         own = iter(own)
         self.parts: List[CachePart] = [
             make(self, next(own, None)) for make in _parts]
-        self.k = tuple(self._pools) if len(self._pools) > 1 \
-            else self._pools[0]
-        del self._pools
         self.kind = (["layered"] * bool(self.extra)
                      + [part.kind for part in self.parts]
                      + ["latent"] * latent + [self.layout.kind])[0]
@@ -1005,7 +1025,7 @@ class PagedKVCache:
             part.no_tier for part in self.parts] + [
                 "a spilled block would have to carry its further rows "
                 "(ROADMAP.md)"] * bool(self.extra)), None)
-        self._wire_more = ("extra",) * isinstance(self.k, tuple) + tuple(
+        self._wire_more = ("extra",) * bool(self.extra) + tuple(
             name for part in self.parts for name in part.wire)
         # Unused table entries stay 0 — always a valid pool index; the
         # attention mask (positions >= ctx_len) hides whatever lives there.
@@ -1022,6 +1042,22 @@ class PagedKVCache:
         # Optional spill tier (serve/kv_tier): evicted sealed blocks move
         # there instead of being destroyed, and match / adopt restore them.
         self.tier = None
+
+    # The pools as a step takes them: `k` the first and `v` its V pool or
+    # None, or, where the cache has more than that, `k` the tuple of all
+    # (`v` None).
+    @property
+    def k(self):
+        return self._pools[0] if len(self._pools) == 1 + self._paired \
+            else tuple(self._pools)
+
+    @property
+    def v(self):
+        return self._pools[1] if len(self._pools) == 2 and self._paired \
+            else None
+
+    def pool_bytes(self, pool: int) -> int:
+        return int(self._pools[pool].nbytes)
 
     def _claim_pool(self, n_layers, num_blocks, kv_heads, head_dim) -> int:
         """A further pool in the stored layout (module docstring: rows of W
@@ -1075,7 +1111,8 @@ class PagedKVCache:
             if slid:
                 (s, layers), = slid
                 parts.append(lambda cache, n: SlidingRows(
-                    cache, layers, s.kv_heads, s.head_dim, s.slide, ahead, n))
+                    cache, layers, s.kv_heads, s.head_dim, s.slide, ahead, n,
+                    pools=spec.attn.pools))
         elif spec.runs:
             n_layers = layers_of(
                 run for run in spec.runs if run.attn is not None)
@@ -1118,7 +1155,7 @@ class PagedKVCache:
     snaps = property(lambda self: self.parts[-1].snaps)
     snap_tails = property(lambda self: self.parts[-1].snap_tails)
     # One pool of latent rows a kind, no V pool.
-    latent = property(lambda self: self.v is None)
+    latent = property(lambda self: not self._paired)
     # The growing blocks' index and every part's.
     indexes = property(lambda self: [self.index] + [
         part.index for part in self.parts if part.index is not None])
@@ -1372,8 +1409,8 @@ class PagedKVCache:
         idx = jnp.asarray(np.asarray([b for *_, b in entries], np.int32))
         k_np, v_np = self.read_blocks(idx)
         more = {}
-        if isinstance(self.k, tuple):
-            more["extra"] = [self.read_blocks(idx, 1 + i)
+        if self.extra:
+            more["extra"] = [self.read_blocks(idx, self._first_extra + i)
                              for i in range(len(self.extra))]
         for part in self.parts:
             more.update(part.export(keys))
@@ -1424,7 +1461,8 @@ class PagedKVCache:
             self.write_blocks(idx, payload["k"][:, pos],
                               None if v_arr is None else v_arr[:, pos])
             for i, rows in enumerate(more.get("extra", ())):
-                self.write_blocks(idx, rows[:, pos], None, 1 + i)
+                self.write_blocks(idx, rows[:, pos], None,
+                                  self._first_extra + i)
 
         n += self.index.install(
             [(i, key) for i, key in enumerate(keys)
@@ -1634,39 +1672,34 @@ class PagedKVCache:
                 n = len(part.buffers)
                 part.rebind(tuple(more[:n]))
                 del more[:n]
-        self.k = k
-        self.v = v
+        self._pools = list(k) if isinstance(k, (tuple, list)) \
+            else [k] if v is None else [k, v]
 
     # ---------------- the wire format's boundary ----------------
 
     @property
     def pool_shape(self) -> tuple:
         """The stored shape of the (first) pool."""
-        return (self.k[0] if isinstance(self.k, tuple) else self.k).shape
+        return self._pools[0].shape
+
+    # The first of the pools of further rows (`extra`).
+    _first_extra = property(lambda self: 1 + self._paired)
 
     def read_blocks(self, idx: jax.Array, pool: Optional[int] = None):
         """Blocks `idx` in the wire format, on the host: (first pool's rows,
         V rows or None), or the rows of pool `pool` of several, an array."""
         if pool is not None:
-            return np.asarray(unpack_kv_rows(self.k[pool][:, idx],
+            return np.asarray(unpack_kv_rows(self._pools[pool][:, idx],
                                              *self._pool_rows[pool]))
-        first = self.k[0] if isinstance(self.k, tuple) else self.k
-        return tuple(None if rows is None else np.asarray(unpack_kv_rows(
-            rows[:, idx], self.kv_heads, self.head_dim))
-            for rows in (first, self.v))
+        return tuple(self.read_blocks(idx, i) if i <= self._paired else None
+                     for i in (0, 1))
 
     def write_blocks(self, idx: jax.Array, k_blocks, v_blocks,
                      pool: Optional[int] = None) -> None:
         """Store wire-format blocks at `idx` (pad columns stay zero): K and
         V rows, or `k_blocks` into pool `pool` of several."""
-        if isinstance(self.k, tuple):
-            pools = list(self.k)
-            pools[pool or 0] = pools[pool or 0].at[:, idx].set(pack_kv_rows(
-                jnp.asarray(k_blocks, pools[pool or 0].dtype)))
-            self.k = tuple(pools)
-            return
-        self.k = self.k.at[:, idx].set(
-            pack_kv_rows(jnp.asarray(k_blocks, self.k.dtype)))
-        if self.v is not None:
-            self.v = self.v.at[:, idx].set(
-                pack_kv_rows(jnp.asarray(v_blocks, self.v.dtype)))
+        new = [(pool, k_blocks)] if pool is not None else \
+            [(0, k_blocks)] + [(1, v_blocks)] * self._paired
+        for i, blocks in new:
+            self._pools[i] = self._pools[i].at[:, idx].set(pack_kv_rows(
+                jnp.asarray(blocks, self._pools[i].dtype)))

@@ -2,9 +2,11 @@
 
 A family (models/gpt.py, models/llama.py, models/axk1.py,
 models/evabyte.py, models/dots3.py, models/falconh1.py,
-models/nemotronh.py) is a config
+models/nemotronh.py, models/afmoe.py) is a config
 dataclass, its parameter format (`init_params`, `param_specs`) and
-`spec(config)`: a `Spec` naming the parts its block is made of (norms, an
+`spec(config)`: a `Spec` naming the parts its block is made of (norms: one
+in front of each part, and where the model has them one behind each too,
+four a layer; an
 `Attention`, a `FeedForward`, a leading run of layers with another
 feed-forward; or its `Run`s of like layers, each with its own attention,
 sizes, stacks and pools) and the leaves they read.  Everything that runs is here: the
@@ -26,7 +28,11 @@ Design (no reference counterpart: Ray hosts models, it doesn't ship them):
   * attention is a part (`HEADS`: per-head K and V, flash (Pallas) on one
     chip and per shard (shard_map over batch and heads) under a mesh, ring
     attention when the mesh has a seq axis > 1; over a paged KV cache,
-    ops/attention.py's paged path.  `LATENT`: multi-head latent attention,
+    ops/attention.py's paged path; by a run's `HeadSizes` with a rotation
+    of the run's own or none, a window a run (the rows behind it are
+    neither kept nor read: a sliding table), an RMSNorm over each head's
+    q and k and an elementwise gate on the result.  `LATENT`: multi-head
+    latent attention,
     expanded for a whole sequence, absorbed over a latent paged cache; by
     a run's `LatentSizes` also over a window, or over the positions a
     learned indexer chooses, with a gate a head.
@@ -354,10 +360,41 @@ SWITCH = FeedForward(switch_moe, serves=False)
 # there and returns ([B, T, D], pools).
 # --------------------------------------------------------------------------
 
-def _qkv(spec: Spec, h, p):
+@dataclasses.dataclass(frozen=True)
+class HeadSizes:
+    """What `HEADS` reads of a run of layers where that is not the model's
+    config and its spec's one rotation (`Run.sizes`; `head_sizes`): the
+    head counts, the run's OWN rotation (`rope_theta` None: the run's
+    layers have no positional encoding at all), a `window` (positions
+    attended, the token's own among them; 0: the whole context), the eps of
+    an RMSNorm on q and k over each head's own `head_dim` numbers
+    (`q_norm`, `k_norm` [head_dim]; `Spec.qk_norm` is the norm over the
+    whole projected vector), and whether the attention's result is gated
+    elementwise by sigmoid(h W_g) (`w_attn_gate` [D, H, head_dim]) before
+    the output projection."""
+    n_heads: int
+    n_kv_heads: int
+    head_dim: int
+    rope_theta: Optional[float] = None
+    window: int = 0
+    qk_norm: Optional[float] = None
+    gate: bool = False
+
+
+def head_sizes(spec, config) -> HeadSizes:
+    """The sizes `HEADS` goes by: the run's own, or those of a config that
+    states its head counts beside its spec's rotation."""
+    if isinstance(config, HeadSizes):
+        return config
+    return HeadSizes(config.n_heads, config.n_kv_heads, config.head_dim,
+                     spec.rope_theta)
+
+
+def _qkv(spec: Spec, h, p, sizes: Optional[HeadSizes] = None):
     """Projected q, k, v [B, L, heads, head_dim] of normed h; with
     `qk_norm`, q and k RMS-normalised over all their heads together
-    (OLMoE: the norm spans the whole projected vector, before RoPE)."""
+    (OLMoE: the norm spans the whole projected vector, before RoPE), with
+    a run's own (`HeadSizes.qk_norm`) each head over its own numbers."""
     q = jnp.einsum("bld,dhk->blhk", h, p["wq"].astype(h.dtype))
     k = jnp.einsum("bld,dhk->blhk", h, p["wk"].astype(h.dtype))
     v = jnp.einsum("bld,dhk->blhk", h, p["wv"].astype(h.dtype))
@@ -367,18 +404,34 @@ def _qkv(spec: Spec, h, p):
                            spec.qk_norm)
             return flat.reshape(x.shape)
         q, k = norm(q, p["q_norm"]), norm(k, p["k_norm"])
+    if sizes is not None and sizes.qk_norm is not None:
+        q = rmsnorm(q, p["q_norm"], sizes.qk_norm)
+        k = rmsnorm(k, p["k_norm"], sizes.qk_norm)
     if spec.mult is not None:
         k = _scaled(k, spec.mult.key)
     return q, k, v
 
 
+def _attn_gate(attn, h, p, sizes: HeadSizes):
+    """attn [B, L, H, K] times the elementwise gate sigmoid(h W_g)
+    [B, L, H, K] of the block's normed input h, where the run has one."""
+    if not sizes.gate:
+        return attn
+    g = jax.nn.sigmoid(jnp.einsum(
+        "bld,dhk->blhk", h, p["w_attn_gate"].astype(h.dtype)).astype(
+            jnp.float32))
+    return attn * g.astype(attn.dtype)
+
+
 def heads_attention(h, p, spec, config, mesh, position_offset=0):
-    """Multi-head or grouped-query attention with per-head K and V."""
-    c = config
-    q, k, v = _qkv(spec, h, p)
-    if spec.rope_theta is not None:
-        q = rope(q, spec.rope_theta, position_offset)
-        k = rope(k, spec.rope_theta, position_offset)
+    """Multi-head or grouped-query attention with per-head K and V; over a
+    run's `window` a masked XLA form (the flash kernel has no window: all
+    L x L scores are made, and that form has no train path)."""
+    c = head_sizes(spec, config)
+    q, k, v = _qkv(spec, h, p, c)
+    if c.rope_theta is not None:
+        q = rope(q, c.rope_theta, position_offset)
+        k = rope(k, c.rope_theta, position_offset)
     if c.n_kv_heads < c.n_heads:
         # GQA: each kv head serves n_heads / n_kv_heads query heads.
         # Materializing the repeat keeps the attention kernels
@@ -388,25 +441,39 @@ def heads_attention(h, p, spec, config, mesh, position_offset=0):
         v = jnp.repeat(v, c.n_heads // c.n_kv_heads, axis=2)
     q = with_logical_constraint(q, ("batch", "length", "heads", "kv"),
                                 mesh=mesh)
-    attn = mesh_flash_attention(q, k, v, mesh=mesh, causal=True)
+    if c.window:
+        pos = jnp.arange(h.shape[1])
+        keep = (pos[None, :] <= pos[:, None]) \
+            & (pos[None, :] > pos[:, None] - c.window)
+        attn = _chosen_attention(
+            q, k, v, jnp.broadcast_to(keep[None], (h.shape[0],) + keep.shape),
+            c.head_dim ** -0.5).astype(h.dtype)
+    else:
+        attn = mesh_flash_attention(q, k, v, mesh=mesh, causal=True)
+    attn = _attn_gate(attn, h, p, c)
     return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
 
 
 def _attend_rows(q, k, v, pools, p, config, block_tables, rows, valid,
-                 n_rows):
+                 n_rows, window: int = 0, gated=None):
     """The slice's K and V written into the whole pools at the layer, at
     `rows` [B, T] of each lane's table, then attention of the `valid` rows
     over the table's first `n_rows` [B] rows in the same buffers
     (ops/attention.py paged path), projected back.  K/V are cached with
     kv_heads (GQA un-repeated: the whole point of the grouped cache); the
-    paged attention path groups the query heads itself."""
+    paged attention path groups the query heads itself.  With `window` a
+    row attends its last `window` rows alone; `gated(attn)` stands between
+    the attention and the output projection."""
     from ray_tpu.ops.attention import paged_attention, paged_kv_update
 
     layer = p["cache_layer"]
     k_pool, v_pool = paged_kv_update(*pools, k, v, block_tables, rows, valid,
                                      layer)
     attn = paged_attention(q, k_pool, v_pool, block_tables, n_rows, rows,
-                           layer, valid=valid, kv_heads=config.n_kv_heads)
+                           layer, valid=valid, kv_heads=config.n_kv_heads,
+                           **({"window": window} if window else {}))
+    if gated is not None:
+        attn = gated(attn)
     return (jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(q.dtype)),
             (k_pool, v_pool))
 
@@ -416,13 +483,19 @@ def heads_attention_cached(h, pools, p, spec, config, block_tables,
     """A token's K and V are one row of its lane's table, at its position.
     (Per-token rotation at each token's own absolute position: offset =
     positions[:, 0] with L-consecutive slices means positions must be
-    contiguous per lane, which prefill/decode slices always are.)"""
-    q, k, v = _qkv(spec, h, p)
-    if spec.rope_theta is not None:
-        q = rope(q, spec.rope_theta, positions[:, 0])
-        k = rope(k, spec.rope_theta, positions[:, 0])
-    return _attend_rows(q, k, v, pools, p, config, block_tables, positions,
-                        valid, ctx_lens)
+    contiguous per lane, which prefill/decode slices always are.)  A run's
+    own `HeadSizes` may take the rotation away, add a window (the rows
+    behind it are not read: the run's table is a sliding one) and gate the
+    result."""
+    c = head_sizes(spec, config)
+    q, k, v = _qkv(spec, h, p, c)
+    if c.rope_theta is not None:
+        q = rope(q, c.rope_theta, positions[:, 0])
+        k = rope(k, c.rope_theta, positions[:, 0])
+    return _attend_rows(q, k, v, pools, p, c, block_tables, positions,
+                        valid, ctx_lens, c.window,
+                        partial(_attn_gate, h=h, p=p, sizes=c) if c.gate
+                        else None)
 
 
 def eva_attention(h, p, spec, config, mesh, position_offset=0):
@@ -736,8 +809,13 @@ class Attention:
 
 
 HEADS = Attention(heads_attention, heads_attention_cached,
-                  rows=lambda c: CacheRows(c.n_kv_heads, c.head_dim),
-                  cast=("wq", "wk", "wv", "wo"))
+                  rows=lambda c: CacheRows(
+                      c.n_kv_heads, c.head_dim,
+                      slide=c.window if isinstance(c, HeadSizes) else 0),
+                  cast=("wq", "wk", "wv", "wo", "w_attn_gate"))
+# `HEADS` for a run with a window: the same part, whose whole-sequence form
+# is then the masked one, which has no train path.
+WINDOW_HEADS = dataclasses.replace(HEADS, trains=False)
 LATENT = Attention(latent_attention, latent_attention_cached,
                    rows=lambda c: CacheRows(
                        1, c.kv_lora_rank + c.qk_rope_head_dim,
@@ -1021,6 +1099,11 @@ class Spec:
     logits_dtype: Optional[Any] = None
     # The factors a model states on its paths; None: none anywhere.
     mult: Optional[Multipliers] = None
+    # Sandwich norms: the leaves of a norm BEHIND the attention (and a
+    # mixer beside it) and of one behind the feed-forward, each on the
+    # part's result before it is added to the stream; (): none.
+    attn_post_norm: tuple = ()
+    mlp_post_norm: tuple = ()
 
 
 # --------------------------------------------------------------------------
@@ -1031,24 +1114,30 @@ def _norm(spec: Spec, x, p, leaves):
     return spec.norm(x, *(p[name] for name in leaves))
 
 
+def _behind(spec: Spec, leaves: tuple, p, y):
+    """A part's result `y` through the norm behind it (`attn_post_norm`,
+    `mlp_post_norm`), where the spec names one."""
+    return _norm(spec, y, p, leaves) if leaves else y
+
+
 def _block(x, p, spec: Spec, run: Run, config, mesh, position_offset=0):
     c = config
     m = spec.mult or Multipliers()
     if run.attn is not None or run.mixer is not None:
         h = _norm(spec, x, p, spec.attn_norm)
     if run.attn is not None:
-        x = x + _scaled(run.attn.apply(_scaled(h, m.attn_in), p, spec,
-                                       run.sizes or c, mesh,
-                                       position_offset), m.attn_out)
+        x = x + _behind(spec, spec.attn_post_norm, p, _scaled(
+            run.attn.apply(_scaled(h, m.attn_in), p, spec, run.sizes or c,
+                           mesh, position_offset), m.attn_out))
     if run.mixer is not None:
-        x = x + _scaled(run.mixer.apply(_scaled(h, m.mixer_in), p, c),
-                        m.mixer_out)
+        x = x + _behind(spec, spec.attn_post_norm, p, _scaled(
+            run.mixer.apply(_scaled(h, m.mixer_in), p, c), m.mixer_out))
 
     aux = None
     if run.ffn is not None:
         h = _norm(spec, x, p, spec.mlp_norm)
         y, aux, _ = run.ffn.apply(h, p, c, mesh)
-        x = x + y
+        x = x + _behind(spec, spec.mlp_post_norm, p, y)
     if aux is None:
         aux = jnp.zeros((), jnp.float32)
     x = with_logical_constraint(x, ("batch", "length", "act_embed"),
@@ -1076,19 +1165,21 @@ def _block_cached(x, pools, p, spec: Spec, run: Run, config,
         attn, rows = run.attn.cached(_scaled(h, m.attn_in), pools[:n], p,
                                      spec, run.sizes or config, block_tables,
                                      positions, valid, ctx_lens)
-        x = x + _scaled(attn, m.attn_out)
+        x = x + _behind(spec, spec.attn_post_norm, p,
+                        _scaled(attn, m.attn_out))
         pools = (*rows, *pools[n:])
     if run.mixer is not None:
         y, state = run.mixer.cached(_scaled(h, m.mixer_in), pools[n:], p,
                                     config, slots, positions, valid)
-        x = x + _scaled(y, m.mixer_out)
+        x = x + _behind(spec, spec.attn_post_norm, p,
+                        _scaled(y, m.mixer_out))
         pools = (*pools[:n], *state)
 
     if run.ffn is None:
         return x, pools, None
     h = _norm(spec, x, p, spec.mlp_norm)
     y, _, load = run.ffn.apply(h, p, config, valid=valid)
-    return x + y, pools, load
+    return x + _behind(spec, spec.mlp_post_norm, p, y), pools, load
 
 
 def _layer_stack(blocks: dict, n_layers: int, whole: tuple):
@@ -1132,12 +1223,15 @@ def _stacks(spec: Spec, config) -> tuple:
 
 def layer_counts(spec: Spec, config) -> dict:
     """The layers a step runs by what they keep or read: `kv` with an
-    attention (cached rows), `state` with a mixer (a recurrent state),
-    `experts` with dropless experts.  In a stack of one-part layers these
-    are three different numbers, none of them `n_layers`."""
+    attention (cached rows), of which `window` read a window of them
+    alone, `state` with a mixer (a recurrent state), `experts` with
+    dropless experts.  In a stack of one-part layers these are different
+    numbers, none of them `n_layers`."""
     runs = _stacks(spec, config)
     return {
         "kv": sum(r.n_layers for r in runs if r.attn is not None),
+        "window": sum(r.n_layers for r in runs if r.attn is not None
+                      and getattr(r.sizes, "window", 0)),
         "state": sum(r.n_layers for r in runs if r.mixer is not None),
         "experts": sum(r.n_layers for r in runs if _whole(r))}
 
@@ -1145,16 +1239,19 @@ def layer_counts(spec: Spec, config) -> dict:
 def cache_kinds(runs, config) -> tuple:
     """([rows, layers] of the growing kind, the same of the sliding kinds)
     of runs of several kinds (`Run.table`), as `PagedKVCache.for_model`
-    turns them into pools and a part: one growing latent kind, and at most
-    one sliding kind."""
+    turns them into pools and a part: one growing kind and at most one
+    sliding kind, both of latent rows (a pool a kind) or both of K and V
+    rows (a pair a kind)."""
     kinds: dict = {}
     for run in runs:
         rows = run.attn.rows(run.sizes or config)
         layers = kinds.setdefault(run.table[0], [rows, 0])
         layers[1] = max(layers[1], run.first + run.n_layers)
-        if layers[0] != rows or run.attn.pools != 1 or rows.window:
+        if layers[0] != rows or run.attn.pools != runs[0].attn.pools \
+                or rows.window:
             raise NotImplementedError(
-                "layers of several kinds: latent rows, one shape a kind")
+                "layers of several kinds: one shape of row a kind, and "
+                "every kind a latent pool or every kind a K and a V pool")
     grow = [k for k in kinds.values() if not k[0].slide]
     slid = [k for k in kinds.values() if k[0].slide]
     if len(grow) != 1 or len(slid) > 1:

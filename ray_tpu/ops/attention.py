@@ -677,14 +677,16 @@ def _table_blocks(pool, layer, block_tables):
 
 def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
                               q_positions, layer=0, *,
-                              kv_heads: Optional[int] = None, scale=None):
+                              kv_heads: Optional[int] = None, scale=None,
+                              window: int = 0):
     """Masked-dense paged attention (the T=1 fallback and the oracle).
 
     q [B, T, H, D] at absolute q_positions [B, T]; pools
     [L, NB, BS, W], read at `layer`; kv_heads (default H) may divide
     H — GQA; ctx_lens [B] = tokens written per lane.  Each query attends
     to context positions <= its own (the query's K/V must already be in
-    the pool).  All-masked rows (inactive lanes) come out as a uniform
+    the pool), with `window` to the last `window` of them alone, its own
+    among them.  All-masked rows (inactive lanes) come out as a uniform
     average, never NaN (finite NEG_INF).
     """
     b, t, h, d = q.shape
@@ -705,6 +707,9 @@ def paged_attention_reference(q, k_pool, v_pool, block_tables, ctx_lens,
     kpos = jnp.arange(max_ctx)
     mask = ((kpos[None, None, None, :] <= q_positions[:, None, :, None])
             & (kpos[None, None, None, :] < ctx_lens[:, None, None, None]))
+    if window:
+        mask = mask & (kpos[None, None, None, :]
+                       > q_positions[:, None, :, None] - window)
     logits = jnp.where(mask, logits, NEG_INF)
     probs = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bhtk,bkhd->bthd", probs, v_ctx.astype(jnp.float32))
@@ -748,12 +753,15 @@ def _head_columns(h: int, kh: int, d: int, w: int, dtype):
     return own, spread.astype(dtype)
 
 
-def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                         o_ref, k_buf, v_buf, sems, state, m_ref, l_ref,
-                         acc_ref, *, scale: float):
+def _paged_decode_kernel(bt_ref, len_ref, layer_ref, *refs, scale: float,
+                         windowed: bool = False):
     """One lane of single-query paged attention: a grid step sweeps the
     lane's context run by run, R cache blocks a run, and only the runs
-    that hold context.
+    that hold context.  With `windowed` the next scalar-prefetched operand
+    is the lanes' first attended positions: the sweep begins at the block
+    of a lane's own, so the blocks behind it are neither fetched nor scored
+    (a sliding table names nothing there), and the positions before it in
+    that block are masked.
 
     The pools stay where they are (HBM); the scalar-prefetched block
     table, context lengths and layer index say which [BS, W] blocks of
@@ -772,6 +780,9 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
     so that a run's blocks are one [R * BS, W] operand without a relayout
     (one update a block of 16 and a grid step a block held the kernel at
     10-17% of its roofline: PERF.md section 6, PR 32)."""
+    start_ref, refs = (refs[0], refs[1:]) if windowed else (None, refs)
+    (q_ref, k_hbm, v_hbm, o_ref, k_buf, v_buf, sems, state, m_ref, l_ref,
+     acc_ref) = refs
     _, kb, bs, _ = k_buf.shape
     lanes, mb = bt_ref.shape
     lane = pl.program_id(0)
@@ -779,7 +790,13 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
     run_tokens = kb * bs
     nxt = jnp.minimum(lane + 1, lanes - 1)
     n_ctx = len_ref[lane]
-    n_runs = (n_ctx + run_tokens - 1) // run_tokens
+    if windowed:
+        first = start_ref[lane] // bs
+        n_runs = (jnp.maximum((n_ctx + bs - 1) // bs - first, 0)
+                  + kb - 1) // kb
+    else:
+        first = 0
+        n_runs = (n_ctx + run_tokens - 1) // run_tokens
     dtype = jnp.promote_types(q_ref.dtype, k_buf.dtype)
 
     def each_copy(i, run, slot, do):
@@ -788,6 +805,8 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
         n_blocks = (len_ref[i] + bs - 1) // bs
         for r in range(kb):
             blk = run * kb + r
+            if windowed:
+                blk = blk + start_ref[i] // bs
 
             @pl.when(blk < n_blocks)
             def _(r=r, blk=blk):
@@ -828,7 +847,10 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
             (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale      # [H, N]
         pos = base + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(pos < n_ctx, s, NEG_INF)
+        keep = pos < n_ctx
+        if windowed:
+            keep = keep & (pos >= start_ref[lane])
+        s = jnp.where(keep, s, NEG_INF)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
@@ -852,6 +874,8 @@ def _paged_decode_kernel(bt_ref, len_ref, layer_ref, q_ref, k_hbm, v_hbm,
 
         each_copy(lane, run, slot, lambda dma: dma.wait())
         base = run * run_tokens
+        if windowed:
+            base = base + first * bs
         if bs % (32 // k_buf.dtype.itemsize) == 0:
             # Whole tile rows: the run's blocks are one operand, and the
             # softmax state (a max, an exp, a rescale of the accumulator)
@@ -916,14 +940,31 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     mb = block_tables.shape[1]
     kb = min(blocks_per_step or paged_blocks_per_step(
         bs, w, k_pool.dtype.itemsize, mb), mb)
+    return _paged_walk_call(q, k_pool, v_pool, block_tables, ctx_lens, None,
+                            layer, kh=kh, scale=scale, kb=kb,
+                            name="paged_decode_attention",
+                            interpret=interpret)
+
+
+def _paged_walk_call(q, k_pool, v_pool, block_tables, ctx_lens, starts,
+                     layer, *, kh: int, scale: Optional[float], kb: int,
+                     name: str, interpret: bool):
+    """The `pallas_call` of `_paged_decode_kernel`: a lane a grid step over
+    the pools handed in whole, runs of `kb` blocks; `starts` [B] (the
+    lanes' first attended positions) or None."""
+    b, h, d = q.shape
+    _, _, bs, w = k_pool.shape
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
     own, spread = _head_columns(h, kh, d, w, q.dtype)
     q_rows = jnp.where(own, jnp.einsum(
         "bhd,dw->bhw", q, spread, precision=jax.lax.Precision.HIGHEST),
         jnp.zeros((), q.dtype))                                 # [B, H, W]
-    lane_spec = pl.BlockSpec((None, h, w), lambda i, bt, ln, ly: (i, 0, 0))
+    prefetch = [block_tables, ctx_lens, jnp.asarray(layer).reshape(1)] + (
+        [] if starts is None else [starts])
+    lane_spec = pl.BlockSpec((None, h, w), lambda i, *_: (i, 0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,      # block tables, context lengths, layer
+        # block tables, context lengths, layer (, first positions)
+        num_scalar_prefetch=len(prefetch),
         grid=(b,),
         in_specs=[lane_spec, pl.BlockSpec(memory_space=pl.ANY),
                   pl.BlockSpec(memory_space=pl.ANY)],
@@ -939,7 +980,8 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens,
         ],
     )
     out = pl.pallas_call(
-        functools.partial(_paged_decode_kernel, scale=scale),
+        functools.partial(_paged_decode_kernel, scale=scale,
+                          windowed=starts is not None),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, h, w), q.dtype),
         interpret=interpret,
@@ -948,19 +990,68 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens,
             dimension_semantics=("arbitrary",)),
         # The instruction's name in the HLO and so in a device trace: in
         # the engine's layer scan it would be `closed_call.N` without.
-        name="paged_decode_attention",
-    )(block_tables.astype(jnp.int32), ctx_lens.astype(jnp.int32),
-      jnp.asarray(layer, jnp.int32).reshape(1), q_rows, k_pool, v_pool)
+        name=name,
+    )(*(x.astype(jnp.int32) for x in prefetch), q_rows, k_pool, v_pool)
     # [B, H, W] -> head h's own D columns, the same way back.
     return jnp.einsum("bhw,dw->bhd",
                       jnp.where(own, out, jnp.zeros((), out.dtype)), spread,
                       precision=jax.lax.Precision.HIGHEST)
 
 
+def window_blocks_per_step(block_size: int, width: int, itemsize: int,
+                           max_blocks: int) -> int:
+    """Blocks in a run of the windowed decode kernel: runs of one length
+    over the `max_blocks` a span can touch, as the kernels that walk ONE
+    pool take them (`latent_blocks_per_step`; a K and a V row side by side
+    are a row of twice the width).  A window of 2,048 positions over blocks
+    of 128 rows of 512 bf16 columns touches 17 blocks: two runs of 9, where
+    `paged_blocks_per_step`'s powers of two would make three of 8."""
+    return latent_blocks_per_step(block_size, 2 * width, itemsize, max_blocks)
+
+
+def window_paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens,
+                                  starts, layer=0, *, span: int,
+                                  kv_heads: Optional[int] = None,
+                                  scale: Optional[float] = None,
+                                  blocks_per_step: Optional[int] = None,
+                                  use_kernel: Optional[bool] = None,
+                                  interpret: Optional[bool] = None):
+    """`paged_decode_attention` over the positions `starts` [B] to
+    `ctx_lens` - 1 of each lane, at most `span` of them (a window layer's
+    T=1 step over K and V rows: `starts` = max(ctx_lens - span, 0)): the
+    kernel's sweep begins at the block of a lane's start, so what lies
+    behind it is neither fetched nor scored (a sliding table names nothing
+    there), under a name of its own in the HLO and in a device trace
+    (`paged_decode_attention` stays the full layers' alone)."""
+    b, h, d = q.shape
+    kh = kv_heads or h
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+        if use_kernel and d not in (64, 128, 256):
+            _log_reference_path("window_paged_decode_attention",
+                                (q.shape, k_pool.shape))
+            use_kernel = False
+    if not use_kernel:
+        out = paged_attention_reference(
+            q[:, None], k_pool, v_pool, block_tables, ctx_lens,
+            (ctx_lens - 1)[:, None], layer, kv_heads=kh, scale=scale,
+            window=span)
+        return out[:, 0]
+    _, _, bs, w = k_pool.shape
+    most = min((span + bs - 2) // bs + 1, block_tables.shape[1])
+    kb = min(blocks_per_step or window_blocks_per_step(
+        bs, w, k_pool.dtype.itemsize, most), most)
+    return _paged_walk_call(
+        q, k_pool, v_pool, block_tables, ctx_lens, starts, layer, kh=kh,
+        scale=scale, kb=kb, name="window_paged_decode_attention",
+        interpret=_interpret_kernels() if interpret is None else interpret)
+
+
 def _chunk_attention(q, out, block_tables, ctx_lens, q_positions, valid, *,
                      fold, read, score, weigh, unfold, heads: int,
                      block_size: int, v_width: int, scale: float,
-                     q_tile: int, ctx_tile: int, skip_idle: bool = False):
+                     q_tile: int, ctx_tile: int, skip_idle: bool = False,
+                     window: int = 0):
     """The loop of the tiled T > 1 paths (`paged_chunk_attention`,
     `latent_chunk_attention`), plain XLA.  Only the lanes that have valid
     rows do work, each over its OWN blocks: per lane a loop over the tiles
@@ -982,7 +1073,10 @@ def _chunk_attention(q, out, block_tables, ctx_lens, q_positions, valid, *,
     With `skip_idle` the lane loop makes a trip for each lane that has a
     valid row and none for the others (a trip that finds no work is 2.5 us
     on a v5e: 1.7 ms of a step of 48 layers in which 14 of 16 lanes ride
-    along; PERF.md section 6, PR 38)."""
+    along; PERF.md section 6, PR 38).  With `window` a row attends its last
+    `window` positions alone, its own among them, and a query tile's loop
+    over the context begins at the tile that holds its first row's first
+    attended position: what lies behind is neither read nor scored."""
     t = q.shape[1]
     mb = block_tables.shape[1]
     qt = min(q_tile, t)
@@ -1013,6 +1107,8 @@ def _chunk_attention(q, out, block_tables, ctx_lens, q_positions, valid, *,
                 kpos = kj * ct + kcol
                 keep = ((kpos[None, :] <= qpos[:, None])
                         & (kpos[None, :] < n_ctx))
+                if window:
+                    keep = keep & (kpos[None, :] > qpos[:, None] - window)
                 s = jnp.where(keep, s, NEG_INF)
                 m_new = jnp.maximum(m, jnp.max(s, -1, keepdims=True))
                 p = jnp.exp(s - m_new)
@@ -1025,7 +1121,10 @@ def _chunk_attention(q, out, block_tables, ctx_lens, q_positions, valid, *,
             init = (jnp.full(lead + (1,), NEG_INF, jnp.float32),
                     jnp.zeros(lead + (1,), jnp.float32),
                     jnp.zeros(lead + (v_width,), jnp.float32))
-            _, l, acc = jax.lax.fori_loop(0, -(-reach // ct), ctx_body, init)
+            lo = jnp.maximum(pos0 + qi * qt - (window - 1), 0) // ct \
+                if window else 0
+            _, l, acc = jax.lax.fori_loop(lo, -(-reach // ct), ctx_body,
+                                          init)
             o = (acc / jnp.maximum(l, 1e-30)).astype(q.dtype)
             return jax.lax.dynamic_update_slice(
                 out, unfold(o), (lane, qi * qt) + (0,) * (out.ndim - 2))
@@ -1045,7 +1144,8 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                           kv_heads: Optional[int] = None,
                           scale: Optional[float] = None,
                           q_tile: int = 512,
-                          ctx_tile: Optional[int] = None):
+                          ctx_tile: Optional[int] = None,
+                          window: int = 0):
     """Paged attention for a [B, T, H, D] slice of T > 1 query rows (a
     prefill chunk, a draft run) over K/V pools [L, NB, BS, W] at `layer`:
     `_chunk_attention`'s tiles, so a step of 16 lanes of which two prefill
@@ -1056,7 +1156,9 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, ctx_lens,
     multiply their one K/V head as they are (GQA without a repeat of the
     context).  Products in the pools' dtype accumulated in float32, as the
     T=1 kernel's.  Each valid query attends to the rows at or before its
-    own `q_positions`; rows without work come out zero.
+    own `q_positions` (with `window`: the last `window` of them, and the
+    tiles behind a query tile's window are not read); rows without work
+    come out zero.
 
     Tiles by the shapes (the arguments are the sweep's and the tests'): a
     chunk of up to 512 rows is one query tile, so a context tile is read
@@ -1106,16 +1208,23 @@ def paged_chunk_attention(q, k_pool, v_pool, block_tables, ctx_lens,
         block_tables, ctx_lens, q_positions, valid, fold=fold, read=read,
         score=score, weigh=weigh, unfold=unfold, heads=g, block_size=bs,
         v_width=d, scale=scale if scale is not None else 1.0 / np.sqrt(d),
-        q_tile=q_tile, ctx_tile=ctx_tile, skip_idle=True)
+        q_tile=q_tile, ctx_tile=ctx_tile, skip_idle=True, window=window)
     return out.astype(q.dtype)
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens, q_positions,
                     layer=0, *, valid=None, kv_heads: Optional[int] = None,
-                    scale: Optional[float] = None):
+                    scale: Optional[float] = None, window: int = 0):
     """Dispatch paged attention for a [B, T, H, D] query slice: the T=1
     decode step rides the single-query kernel path, longer slices the
-    tiled path over the rows `valid` [B, T] marks (default: all)."""
+    tiled path over the rows `valid` [B, T] marks (default: all).  With
+    `window` a row attends its last `window` positions alone
+    (`window_paged_decode_attention`, the tiled path's `window`)."""
+    if q.shape[1] == 1 and window:
+        return window_paged_decode_attention(
+            q[:, 0], k_pool, v_pool, block_tables, ctx_lens,
+            jnp.maximum(ctx_lens - window, 0), layer, span=window,
+            kv_heads=kv_heads, scale=scale)[:, None]
     if q.shape[1] == 1:
         return paged_decode_attention(
             q[:, 0], k_pool, v_pool, block_tables, ctx_lens, layer,
@@ -1124,7 +1233,8 @@ def paged_attention(q, k_pool, v_pool, block_tables, ctx_lens, q_positions,
         valid = jnp.ones(q.shape[:2], bool)
     return paged_chunk_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                                  q_positions, valid, layer,
-                                 kv_heads=kv_heads, scale=scale)
+                                 kv_heads=kv_heads, scale=scale,
+                                 window=window)
 
 
 # ---------------------------------------------------------------------------

@@ -60,8 +60,9 @@ class KVBlockCodec:
                 # "kv": K and V rows; "state": as "kv", and under `more`
                 # the snapshot of the recurrent state behind the chain;
                 # "latent": one latent row in `k`, `v_pool` None; "layered":
-                # as "latent", and under `more` the blocks of the cache's
-                # other kinds, each said to be whose
+                # as "latent" or as "kv" (a cache's kinds are all latent
+                # rows or all K and V rows), and under `more` the blocks of
+                # the cache's other kinds, each said to be whose
                 # (inference/kv_cache.py).
                 "kind": payload.get("kind", "kv"),
                 **({"more": _as_numpy(payload["more"])}
@@ -91,8 +92,11 @@ class KVBlockCodec:
         k, v = payload["k"], payload["v_pool"]
         n = len(payload["chain"])
         bs = payload["block_size"]
-        latent = payload.setdefault("kind", "kv") in ("latent", "layered")
-        if (v is None) != latent or (v is not None and k.shape != v.shape) \
+        # whether a frame of this kind comes without a V: "layered" either
+        no_v = {"latent": (True,), "layered": (True, False)}.get(
+            payload.setdefault("kind", "kv"), (False,))
+        if (v is None) not in no_v \
+                or (v is not None and k.shape != v.shape) \
                 or k.shape[1] != n or k.shape[2] != bs:
             raise KVCodecError(
                 f"frame shape mismatch: k{k.shape} "

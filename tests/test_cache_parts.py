@@ -28,6 +28,8 @@ KINDS = {"kv": ("gpt", "nano", 40),
          "latent": ("axk1", "axk1-nano", 40),
          "windowed": ("evabyte", "evabyte-nano", 40),
          "layered": ("dots3", "dots3-nano", (40, 24)),
+         # the sliding part over a K and a V pool beside the growing pair
+         "layered:pairs": ("afmoe", "afmoe-nano", (40, 24)),
          "state": ("falconh1", "falconh1-nano", (40, 3)),
          # the state part over three mixer layers, K and V pools over one
          # attention layer: a part with a layer count of its own
@@ -214,6 +216,37 @@ def test_one_sealed_index_and_one_install_loop_serve_every_kind():
     # no part: no per-lane work beside the chain
     assert all(_make(kind).parts == [] for kind in ("kv", "latent",
                                                     "windowed"))
+
+
+def test_a_sliding_part_takes_its_pools_in_pairs_where_rows_are_k_and_v():
+    """`SlidingRows` over as many pools as the model's attention leaves rows
+    in (a latent row: one; K and V rows: two), on the same blocks under the
+    same table; `k` is then every pool as the model's runs index them, the
+    growing pair first, and the wire format carries a list of the sliding
+    pools' blocks where there is more than one."""
+    latent, pairs = _make("layered"), _make("layered:pairs")
+    assert len(latent.parts[0].pools) == 1 and latent.latent
+    assert latent.parts[0].pools == (2,) and len(latent.k) == 3
+    assert pairs.parts[0].pools == (2, 3) and not pairs.latent
+    assert pairs.v is None and len(pairs.k) == 4
+    assert [p.shape[0] for p in pairs.k] == [2, 2, 6, 6]
+    assert pairs.k[2].shape == pairs.k[3].shape == (6, 24, BS, 128)
+    assert pairs.block_tables.shape == (3, 2 * pairs.max_blocks_per_seq)
+    assert pairs.step_pools == (pairs.k, None)
+    k_np, v_np = pairs.read_blocks(jnp.asarray([1, 2]))
+    assert k_np.shape == v_np.shape == (2, 2, BS, 2, 16)
+    pairs.write_blocks(jnp.asarray([5]), k_np[:, :1], v_np[:, 1:] + 1)
+    k5, v5 = pairs.read_blocks(jnp.asarray([5]))
+    assert np.array_equal(k5, k_np[:, :1])
+    assert np.array_equal(v5, v_np[:, 1:] + 1)
+    # no family's name in the manager, the decoder or the engine
+    from ray_tpu.inference import engine
+    from ray_tpu.models import decoder
+    for module in (kv_cache, decoder, engine):
+        src = inspect.getsource(module)
+        code = "\n".join(line.split("#")[0] for line in src.splitlines())
+        code = re.sub(r'"""[\s\S]*?"""', "", code)
+        assert not re.search(r"afmoe|trinity", code, re.I), module.__name__
 
 
 def test_one_builder_makes_every_cache():

@@ -283,7 +283,7 @@ def test_one_part_layers_serve_the_references_greedy_tokens(prefill_lanes):
     for prompt, out in zip(prompts, outs):
         assert _nemo_served_is_the_references(prompt, out)
     st = eng.stats()
-    assert st["layers"] == {"kv": 1, "state": 3, "experts": 3}
+    assert st["layers"] == {"kv": 1, "window": 0, "state": 3, "experts": 3}
     fed = sum(map(len, prompts)) + sum(news) - 5
     assert st["ssm"]["tokens_scanned"] + st["ssm"]["tokens_updated"] == fed
     assert st["ssm"]["state_layers"] == 3

@@ -1011,6 +1011,86 @@ def test_select_sort_counter_sees_a_sort_of_every_lanes_scores(v5e,
     assert _kernel_counts(chosen) == {"sparse_select": 1}
 
 
+def _kv_walk(windowed, lanes=64, mb=133):
+    """The single-query kernels over a K and a V pool at Trinity-Mini's
+    cell: 32 query heads over 4 key/value heads of 128 (rows of 512), the
+    full layers' pools of 2 layers and 1,536 blocks of 128, the window
+    layers' of 6 layers and 768 blocks, a table of 133."""
+    from ray_tpu.ops.attention import window_paged_decode_attention
+    pool = (6, 768, 128, 512) if windowed else (2, 1536, 128, 512)
+    shapes = [((lanes, 32, 128), jnp.bfloat16), (pool, jnp.bfloat16),
+              (pool, jnp.bfloat16), ((lanes, mb), jnp.int32),
+              ((lanes,), jnp.int32)]
+    if not windowed:
+        return (functools.partial(paged_decode_attention, kv_heads=4),
+                shapes + [((), jnp.int32)], pool)
+    return (functools.partial(window_paged_decode_attention, span=2048,
+                              kv_heads=4),
+            shapes + [((lanes,), jnp.int32), ((), jnp.int32)], pool)
+
+
+@pytest.mark.parametrize("windowed", [False, True], ids=["full", "window"])
+def test_trinity_decode_kernels_compile_for_v5e_under_names_of_their_own(
+        v5e, as_on_chip, windowed):
+    """One Mosaic call, a lane a grid step, both pools handed in whole and
+    left where they are; the window layers' kernel under a name of its own
+    (`paged_decode_attention` stays the full layers' alone, so a trace
+    tells the two apart), its runs two of 9 blocks for the 17 a window of
+    2,048 touches."""
+    from ray_tpu.ops.attention import window_blocks_per_step
+    fn, shapes, pool = _kv_walk(windowed)
+    arg = _arg_on(v5e[0])
+    args = [arg(*s) for s in shapes]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    assert [k.split("%")[-1].split(".")[0] for k in _kernel_names(text)] == [
+        "window_paged_decode_attention" if windowed
+        else "paged_decode_attention"]
+    assert count_pool_copies(text, pool) == 0
+    (call,) = _pallas_calls(jax.make_jaxpr(fn)(*args).jaxpr)
+    assert call.params["grid_mapping"].grid == (64,)
+    assert window_blocks_per_step(128, 512, 2, 17) == 9
+    assert paged_blocks_per_step(128, 512, 2, 133) == 8
+
+
+@pytest.mark.parametrize("which,t,rows", [("t1", 1, 64), ("short", 128, 1)],
+                         ids=["t1_64_lanes", "t128_one_row"])
+def test_trinity_steps_fit_a_v5e_and_leave_their_four_pools_in_place(
+        as_on_chip, which, t, rows):
+    """The cell's T=1 step and an admission's one-row program at the cell's
+    own sizes (`benchmark/tools/aot_afmoe_sizes.py`, from its configuration
+    and traffic files): arguments and temporaries under the compiler's
+    15.75 GB, all four pools (the full layers' K and V rows, the window
+    layers') donated and left where they are, the two attention kernels of
+    the T=1 step under the names the benchmark's readers find them by, a
+    K and a V pool written in ONE call a layer body."""
+    from benchmark.tools import aot_afmoe_sizes
+    try:
+        texts = aot_afmoe_sizes.main("serve_trinity_docs_decode", which)
+    except RuntimeError as e:           # no v5e topology can be described
+        pytest.skip(str(e))
+    text, memory, pools = texts[(t, rows)]
+    assert [tuple(p.shape) for p in pools] == [
+        (2, 1536, 128, 512), (2, 1536, 128, 512), (6, 768, 128, 512),
+        (6, 768, 128, 512)]
+    pool_bytes = sum(2 * math.prod(p.shape) for p in pools)
+    assert memory.alias_size_in_bytes == pool_bytes
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    assert 12.7e9 < memory.argument_size_in_bytes < 12.8e9
+    assert all(count_pool_copies(text, p.shape) == 0 for p in pools)
+    counts = _kernel_counts(text)
+    assert set(counts) == {"paged_rows_write", "moe_grouped_matmul"} | (
+        {"paged_decode_attention", "window_paged_decode_attention"}
+        if t == 1 else set())
+    # five runs of like layers, a layer body each: S S | S | F | S S S | F
+    assert counts["paged_rows_write"] == 5
+    assert counts["moe_grouped_matmul"] == 4 * 3
+    if t == 1:
+        assert counts["paged_decode_attention"] == 2
+        assert counts["window_paged_decode_attention"] == 3
+    assert not any(_pool_block_updates(text, p.shape) for p in pools)
+
+
 @pytest.mark.parametrize("which,t,rows", [("t1", 1, 64), ("short", 64, 1)],
                          ids=["t1_64_lanes", "t64_one_row"])
 def test_falconh1_steps_fit_a_v5e_and_update_the_state_buffer_in_place(
