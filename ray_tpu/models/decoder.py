@@ -390,14 +390,48 @@ def head_sizes(spec, config) -> HeadSizes:
                      spec.rope_theta)
 
 
-def _qkv(spec: Spec, h, p, sizes: Optional[HeadSizes] = None):
+def _to_heads(h, w):
+    """h [B, L, D] through w [D, heads, K] as [B, L, heads, K]."""
+    return jnp.einsum("bld,dhk->blhk", h, w.astype(h.dtype))
+
+
+def _to_heads_side_by_side(h, w):
+    """`_to_heads` with the product made [B, L, heads x K] wide and then
+    seen as heads: the same numbers, for a forward over whole sequences and
+    the train step.  A product that comes out in four dimensions is laid
+    out by XLA with the length innermost wherever a head is narrower than
+    the 128 lanes of a tile, and the flash kernels, which read the heads
+    side by side, would get a transposed copy of it (PERF.md section 6,
+    PR 51); the two views of one [B, L, heads x K] array cancel and the
+    kernels read what the product wrote.  The engine's programs keep
+    `_to_heads`: their weights arrive as prepared [D, heads, K] stacks
+    whose layout is fixed, and the wide view of one is a copy of it in
+    every step (EvaByte's T=1 step compiled for a v5e copies 537 MB of
+    weights with this form and none with `_to_heads`:
+    tests/test_tpu_aot.py holds it to none), where the train step's
+    float32 weights are converted a step anyway."""
+    wide = jnp.einsum("bld,de->ble", h,
+                      w.astype(h.dtype).reshape(w.shape[0], -1))
+    return wide.reshape(*h.shape[:2], *w.shape[1:])
+
+
+def _from_heads_side_by_side(attn, w):
+    """attn [B, L, heads, K] through w [heads, K, D] as [B, L, D], attn
+    read [B, L, heads x K] wide (`_to_heads_side_by_side`'s other end: with
+    "blhk,hkd->bld" here the train cell read 155,497 tokens/s and 12.01 GB
+    against 159,282 and 11.59, PERF.md section 6, PR 51; the engine's
+    programs keep the einsum, whose `wo` they read where it is held)."""
+    return jnp.einsum("ble,ed->bld", attn.reshape(*attn.shape[:2], -1),
+                      w.astype(attn.dtype).reshape(-1, w.shape[2]))
+
+
+def _qkv(spec: Spec, h, p, sizes: Optional[HeadSizes] = None,
+         to_heads=_to_heads):
     """Projected q, k, v [B, L, heads, head_dim] of normed h; with
     `qk_norm`, q and k RMS-normalised over all their heads together
     (OLMoE: the norm spans the whole projected vector, before RoPE), with
     a run's own (`HeadSizes.qk_norm`) each head over its own numbers."""
-    q = jnp.einsum("bld,dhk->blhk", h, p["wq"].astype(h.dtype))
-    k = jnp.einsum("bld,dhk->blhk", h, p["wk"].astype(h.dtype))
-    v = jnp.einsum("bld,dhk->blhk", h, p["wv"].astype(h.dtype))
+    q, k, v = (to_heads(h, p[w]) for w in ("wq", "wk", "wv"))
     if spec.qk_norm is not None:
         def norm(x, scale):
             flat = rmsnorm(x.reshape(*x.shape[:2], -1), scale.reshape(-1),
@@ -428,7 +462,7 @@ def heads_attention(h, p, spec, config, mesh, position_offset=0):
     run's `window` a masked XLA form (the flash kernel has no window: all
     L x L scores are made, and that form has no train path)."""
     c = head_sizes(spec, config)
-    q, k, v = _qkv(spec, h, p, c)
+    q, k, v = _qkv(spec, h, p, c, _to_heads_side_by_side)
     if c.rope_theta is not None:
         q = rope(q, c.rope_theta, position_offset)
         k = rope(k, c.rope_theta, position_offset)
@@ -439,8 +473,11 @@ def heads_attention(h, p, spec, config, mesh, position_offset=0):
         # load.
         k = jnp.repeat(k, c.n_heads // c.n_kv_heads, axis=2)
         v = jnp.repeat(v, c.n_heads // c.n_kv_heads, axis=2)
-    q = with_logical_constraint(q, ("batch", "length", "heads", "kv"),
-                                mesh=mesh)
+    # constrained as the kernels read it, the heads side by side: a
+    # [B, L, heads, 64] value that stands on its own is copied on both sides
+    q = with_logical_constraint(
+        q.reshape(*q.shape[:2], -1), ("batch", "length", "heads"),
+        mesh=mesh).reshape(q.shape)
     if c.window:
         pos = jnp.arange(h.shape[1])
         keep = (pos[None, :] <= pos[:, None]) \
@@ -451,7 +488,7 @@ def heads_attention(h, p, spec, config, mesh, position_offset=0):
     else:
         attn = mesh_flash_attention(q, k, v, mesh=mesh, causal=True)
     attn = _attn_gate(attn, h, p, c)
-    return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
+    return _from_heads_side_by_side(attn, p["wo"])
 
 
 def _attend_rows(q, k, v, pools, p, config, block_tables, rows, valid,
@@ -503,7 +540,7 @@ def eva_attention(h, p, spec, config, mesh, position_offset=0):
     counted from there): ops/attention.py's `eva_attention`."""
     from ray_tpu.ops import attention as ops
 
-    q, k, v = _qkv(spec, h, p)
+    q, k, v = _qkv(spec, h, p, to_heads=_to_heads_side_by_side)
     q = rope(q, spec.rope_theta, position_offset)
     k = rope(k, spec.rope_theta, position_offset)
     attn = ops.eva_attention(q, k, v, p["eva_mu"], p["eva_phi"],

@@ -7,7 +7,9 @@ materialising the [L, L] score matrix in HBM), walk only the tiles of it at
 or under the causal diagonal and tile the contractions onto the MXU in the
 inputs' dtype; a pure-jnp fallback covers CPU tests and odd shapes.
 
-Layouts: q/k/v are [batch, length, heads, head_dim] (BLHD) throughout.
+Layouts: q/k/v are [batch, length, heads, head_dim] (BLHD) throughout; the
+flash kernels read them as [batch, length, heads x head_dim], the same
+bytes wherever the caller made them that wide.
 """
 
 from __future__ import annotations
@@ -106,9 +108,32 @@ def _build_mask(q_len, k_len, causal, segment_ids):
 # scores; statistics and accumulators are float32.  The backward is one
 # kernel: S, P, dP and dS of a tile are computed once and feed dv, dk and
 # dq (five products a tile beside the forward's two), dq gathering as
-# dq^T over the kv blocks in a scratch of the whole head.  The tile
-# functions take [rows, d] values: only the BlockSpecs know how a head
-# lies in HBM.
+# dq^T over the kv blocks in a scratch of the whole length, and delta is
+# made in it from dO and O, a q tile at a time.
+#
+# How a head lies in HBM: where the model leaves it.  q, k, v, dO, O and
+# the results are [batch, length, heads x d], which is [batch, length,
+# heads, d] seen without its last split: the heads of a position side by
+# side in one row, as the projections write and read them
+# (`models/decoder.py::heads_attention`).  The grid's first axis goes over
+# (batch, column block), a column block being max(d, 128) columns: one head
+# of 128 or 256, or TWO heads of 64, and a grid step walks the tiles of each
+# of its heads.  Of two heads in a block, one's S^T is the product over all
+# 128 lanes with the OTHER head's lanes of Q selected to zero (`where`: a
+# lane that is not the head's must not reach a score), likewise dP with dO;
+# P^T dO and dS^T Q of the two heads, with dO and Q so selected, add up to
+# the packed dv and dk; V^T P and K^T dS take the head's 64 lanes of V and
+# K and give the head's 64 rows of the transposed accumulators O^T and dq^T
+# [128, q], which are transposed once into lane-dense [q, 128] stores.
+# (V^T P over all 128 lanes with half the rows thrown away was 16% slower
+# in the forward: PERF.md section 6, PR 51.)  A head count that is odd at
+# 64 leaves the last block half empty: what the copy brought past the last
+# column is selected to zero in K, V, dO and O as the other head's lanes
+# are, the half block's second head computes on zeros, and the partial
+# store drops its results.  Until PR 51 the kernels took [batch x heads,
+# length, d] and every call transposed q, k, v (and dO) to it and the
+# results back, 1.28 ms a layer-step beside kernels of 2.08 at the train
+# cell's shape.
 
 # Rows and columns of a tile at most, forward and backward (the sweep of
 # PERF.md section 6, PR 44, at a head of 64 over 1,024 positions).
@@ -167,15 +192,50 @@ def _on_or_under_the_diagonal(walk, q_block, kv_block, causal, n_blocks):
         pl.when(q_block > kv_block)(functools.partial(walk, False))
 
 
+def _only_lanes(x, lo, hi):
+    """x [rows, lanes] with zeros outside lanes lo .. hi - 1.  A select,
+    not a multiply: whatever a lane that is not chosen holds (the other
+    head, or what a copy past the arrays' last column left there) must not
+    reach a product."""
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    return jnp.where((lane >= lo) & (lane < hi), x, jnp.zeros_like(x))
+
+
+def _block_heads(lanes, d, width, block):
+    """How the heads of column block `block` lie in its `lanes` columns of
+    arrays `width` (heads x d) columns wide: `whole(x)`, x [rows, lanes]
+    with the lanes past the arrays' last column zero, and for each of the
+    lanes // d heads `(rows, only)`: the head's lanes of a [positions,
+    lanes] value, which are its rows of a transposed [lanes, positions]
+    accumulator, and `only(x)`, x with every lane that is not the head's
+    zero.  A block that is one whole head selects nothing."""
+    ragged = width % lanes != 0
+    end = width - block * lanes     # `lanes` or more in all but a last half
+
+    def select(lo, hi):
+        if ragged:
+            return lambda x: _only_lanes(x, lo, jnp.minimum(hi, end))
+        if (lo, hi) == (0, lanes):
+            return lambda x: x
+        return lambda x: _only_lanes(x, lo, hi)
+
+    return select(0, lanes), [(slice(lo, lo + d), select(lo, lo + d))
+                              for lo in range(0, lanes, d)]
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
-                  acc_ref, *, bq: int, bk: int, n_blocks: int, causal: bool,
-                  scale: float):
-    """Grid (head, q block, kv block): the online softmax of a q block over
-    the kv blocks up to its own.  Refs: q, o [block_q, d]; k, v
-    [block_k, d]; lse [1, block_q]; scratch m, l [1, block_q] and acc
-    [d, block_q] (O^T, unnormalised), float32, carried between kv blocks."""
-    j, c = pl.program_id(1), pl.program_id(2)
+                  acc_ref, *, d: int, width: int, bq: int, bk: int,
+                  n_blocks: int, causal: bool, scale: float):
+    """Grid (batch x column block, q block, kv block): the online softmax
+    of a q block over the kv blocks up to its own, for each head of the
+    column block.  Refs: q, o [block_q, lanes]; k, v [block_k, lanes];
+    lse [heads, 1, block_q]; scratch m, l [heads, 1, block_q] and acc
+    [lanes, block_q] (O^T, unnormalised, a head's d rows under those of
+    the head before it), float32, carried between kv blocks."""
+    i, j, c = (pl.program_id(a) for a in range(3))
     n_q, n_kv = q_ref.shape[0] // bq, k_ref.shape[0] // bk
+    lanes = q_ref.shape[1]
+    whole, heads = _block_heads(lanes, d, width, i % pl.cdiv(width, lanes))
 
     @pl.when(c == 0)
     def _init():
@@ -188,41 +248,58 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                 _tiles(n_q, bq, n_kv, bk, diagonal), key=lambda t: t[0]):
             cols = slice(q0, q0 + bq)
             q = q_ref[cols, :]
-            m, l, acc = m_ref[:, cols], l_ref[:, cols], acc_ref[:, cols]
+            qs = [only(q) for _, only in heads]
+            carry = [(m_ref[g, :, cols], l_ref[g, :, cols],
+                      acc_ref[rows, cols])
+                     for g, (rows, _) in enumerate(heads)]
             for _, k0, offset in tiles:
-                rows = slice(k0, k0 + bk)
-                v = v_ref[rows, :]
-                s = _scores_t(k_ref[rows, :], q, scale, offset)
-                m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-                p = jnp.exp(s - m_new)
-                alpha = jnp.exp(m - m_new)
-                m = m_new
-                l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
-                acc = acc * alpha + _dot(v, p.astype(v.dtype), _TN)  # [d, bq]
-            m_ref[:, cols], l_ref[:, cols], acc_ref[:, cols] = m, l, acc
+                at = slice(k0, k0 + bk)
+                k, v = whole(k_ref[at, :]), v_ref[at, :]
+                for g, (rows, _) in enumerate(heads):
+                    m, l, acc = carry[g]
+                    s = _scores_t(k, qs[g], scale, offset)
+                    m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
+                    p = jnp.exp(s - m_new)
+                    alpha = jnp.exp(m - m_new)
+                    l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+                    acc = acc * alpha + _dot(v[:, rows], p.astype(v.dtype),
+                                             _TN)               # [d, bq]
+                    carry[g] = m_new, l, acc
+            for g, (rows, _) in enumerate(heads):
+                m_ref[g, :, cols], l_ref[g, :, cols], acc_ref[rows, cols] = (
+                    carry[g])
 
     _on_or_under_the_diagonal(walk, j, c, causal, n_blocks)
 
     @pl.when(c == pl.num_programs(2) - 1)
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[...] = (acc_ref[...] / l_safe).T.astype(o_ref.dtype)
+        for g, (rows, _) in enumerate(heads):
+            acc_ref[rows, :] = acc_ref[rows, :] / l_safe[g]
+        o_ref[...] = acc_ref[...].T.astype(o_ref.dtype)
         lse_ref[...] = m_ref[...] + jnp.log(l_safe)
 
 
-def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
+def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref, dqt_ref, dk_acc, dv_acc, *,
-                      bq: int, bk: int, causal: bool, scale: float):
-    """Grid (head, kv block, q block): dk and dv of a kv block over the q
-    blocks from its own on, and every pair's share of dq.
-    dS = P * (dO V^T - delta); dv = P^T dO; dk = dS^T Q * scale;
-    dq = dS K * scale.  Refs: k, v, dk, dv [block_k, d]; q, dO
-    [block_q, d]; lse, delta [1, block_q]; dq [q_len, d], the head's,
-    written at the head's last grid step from the scratch dq^T
-    [q blocks, d, block_q]; scratch dk, dv [block_k, d]; float32."""
-    j, c = pl.program_id(1), pl.program_id(2)
+                      d: int, width: int, bq: int, bk: int, causal: bool,
+                      scale: float):
+    """Grid (batch x column block, kv block, q block): dk and dv of a kv
+    block over the q blocks from its own on, and every pair's share of dq,
+    for each head of the column block.
+    dS = P * (dO V^T - delta), delta = rowsum(dO * O); dv = P^T dO;
+    dk = dS^T Q * scale; dq = dS K * scale.  Refs: k, v, dk, dv
+    [block_k, lanes]; q, dO, O [block_q, lanes]; lse [heads, 1, block_q];
+    dq [q_len, lanes],
+    written at the column block's last grid step from the scratch dq^T
+    [q blocks, lanes, block_q]; scratch dk, dv [block_k, lanes]; float32.
+    With a head's Q and dO zero in the other head's lanes, dS^T Q and
+    P^T dO of the heads add up to the packed dk and dv."""
+    i, j, c = (pl.program_id(a) for a in range(3))
     last = ((j == pl.num_programs(1) - 1) & (c == pl.num_programs(2) - 1))
     n_q, n_kv = q_ref.shape[0] // bq, k_ref.shape[0] // bk
+    lanes = q_ref.shape[1]
+    whole, heads = _block_heads(lanes, d, width, i % pl.cdiv(width, lanes))
 
     @pl.when(c == 0)
     def _init():
@@ -234,15 +311,34 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         dqt_ref[c] = jnp.zeros(dqt_ref.shape[1:], jnp.float32)
 
     def walk(diagonal):
-        for q0, k0, offset in _tiles(n_q, bq, n_kv, bk, diagonal):
-            cols, rows = slice(q0, q0 + bq), slice(k0, k0 + bk)
+        for q0, tiles in itertools.groupby(
+                _tiles(n_q, bq, n_kv, bk, diagonal), key=lambda t: t[0]):
+            cols = slice(q0, q0 + bq)
             q, do = q_ref[cols, :], do_ref[cols, :]
-            k, v = k_ref[rows, :], v_ref[rows, :]
-            p = jnp.exp(_scores_t(k, q, scale, offset) - lse_ref[:, cols])
-            ds = (p * (_dot(v, do, _NT) - delta_ref[:, cols])).astype(q.dtype)
-            dv_acc[rows, :] += _dot(p.astype(do.dtype), do)
-            dk_acc[rows, :] += _dot(ds, q)
-            dqt_ref[c, :, cols] += _dot(k, ds, _TN)            # [d, bq]
+            qs = [only(q) for _, only in heads]
+            dos = [only(do) for _, only in heads]
+            # (dO * O)^T: a head's delta is the sum of its rows, a [1, q]
+            # row like the logsumexp it stands beside
+            do_o = (whole(do).astype(jnp.float32)
+                    * whole(o_ref[cols, :]).astype(jnp.float32)).T
+            deltas = [jnp.sum(do_o[rows], axis=0, keepdims=True)
+                      for rows, _ in heads]
+            for _, k0, offset in tiles:
+                at = slice(k0, k0 + bk)
+                k, v = whole(k_ref[at, :]), whole(v_ref[at, :])
+                dk = dv = None
+                for g, (rows, _) in enumerate(heads):
+                    p = jnp.exp(_scores_t(k, qs[g], scale, offset)
+                                - lse_ref[g, :, cols])
+                    ds = (p * (_dot(v, dos[g], _NT) - deltas[g])
+                          ).astype(q.dtype)
+                    dv_g = _dot(p.astype(do.dtype), dos[g])
+                    dk_g = _dot(ds, qs[g])
+                    dv = dv_g if dv is None else dv + dv_g
+                    dk = dk_g if dk is None else dk + dk_g
+                    dqt_ref[c, rows, cols] += _dot(k[:, rows], ds, _TN)
+                dv_acc[at, :] += dv
+                dk_acc[at, :] += dk
 
     _on_or_under_the_diagonal(walk, c, j, causal, dqt_ref.shape[0])
 
@@ -254,9 +350,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     @pl.when(last)
     def _finalize_dq():
         block_q = q_ref.shape[0]
-        for i in range(dqt_ref.shape[0]):
-            dq_ref[i * block_q:(i + 1) * block_q, :] = (
-                dqt_ref[i] * scale).T.astype(dq_ref.dtype)
+        for n in range(dqt_ref.shape[0]):
+            dq_ref[n * block_q:(n + 1) * block_q, :] = (
+                dqt_ref[n] * scale).T.astype(dq_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("causal", "scale", "block_q",
@@ -264,8 +360,16 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None, block_q: int = 1024,
                     block_k: int = 1024, interpret: Optional[bool] = None):
-    """Blockwise attention via Pallas.  Falls back to XLA attention when the
-    shape does not tile (length % block != 0; logged once per shape on TPU).
+    """Blockwise attention via Pallas of q, k, v [batch, length, heads, d].
+    Falls back to XLA attention when the shape does not tile (length %
+    block != 0; logged once per shape on TPU).
+
+    The kernels read q, k and v and write the result as [batch, length,
+    heads x d], two heads of 64 a block of 128 columns: no transpose on
+    either side.  That view is free where the caller's arrays came out that
+    wide (a product "bld,de->ble" seen as heads); an array MADE in four
+    dimensions XLA lays out with the length innermost when d < 128, and
+    the view then costs the copy it was to save.
 
     Differentiable end-to-end in Pallas: the forward saves (O, logsumexp)
     and the backward is one flash-style kernel for dq, dk and dv (causal
@@ -286,11 +390,21 @@ def mesh_flash_attention(q, k, v, *, mesh=None, causal: bool = True):
     if mesh.shape.get("seq", 1) > 1:
         from ray_tpu.ops.ring_attention import ring_attention
         return ring_attention(q, k, v, mesh=mesh, causal=causal)
-    spec = logical_to_spec(("batch", "length", "heads", "kv"), mesh=mesh)
+    # The shards' edge is crossed [batch, length, heads x d] wide, as the
+    # kernels read: a [batch, length, heads, 64] value that stands on its
+    # own there is laid out with the length innermost and copied on both
+    # sides.  Whole heads to a shard, as the split of `heads` gave them.
+    spec = logical_to_spec(("batch", "length", "heads"), mesh=mesh)
+    d = q.shape[-1]
+
+    def of_a_shard(*wide):
+        out = flash_attention(*(x.reshape(*x.shape[:2], -1, d) for x in wide),
+                              causal=causal)
+        return _heads_side_by_side(out)
+
     return jax.shard_map(
-        functools.partial(flash_attention, causal=causal), mesh=mesh,
-        in_specs=(spec, spec, spec), out_specs=spec,
-        check_vma=False)(q, k, v)
+        of_a_shard, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)(*map(_heads_side_by_side, (q, k, v))).reshape(q.shape)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
@@ -325,16 +439,6 @@ def _use_pallas(q_len, kv_len, d, block_q, block_k, causal):
             and d in (64, 128, 256) and not (causal and q_len != kv_len))
 
 
-def _fold_heads(x):
-    b, l, h, d = x.shape
-    return x.transpose(0, 2, 1, 3).reshape(b * h, l, d)
-
-
-def _unfold_heads(x, b):
-    n, l, d = x.shape
-    return x.reshape(b, n // b, l, d).transpose(0, 2, 1, 3)
-
-
 def _fit_blocks(q_len, kv_len, block_q, block_k):
     """Clamp blocks to the lengths, then halve until they tile — lengths
     like 1536 must ride the Pallas path with 512-blocks rather than fall
@@ -360,46 +464,67 @@ def _flash_tile(block: int, most: int) -> int:
 
 
 class _FlashPlan(NamedTuple):
-    """What a call's shapes decide: the grid's blocks, and whether to run
-    the kernels under the interpreter."""
+    """What a call's shapes decide: the grid's blocks along the length and
+    across the heads' columns, and whether to run the kernels under the
+    interpreter."""
     block_q: int
     block_k: int
     causal: bool
     scale: float
     interpret: bool
+    d: int              # columns of a head
+    width: int          # columns of the arrays: heads x d
+
+    @property
+    def lanes(self) -> int:
+        """Columns of a block: whole heads, two of 64 to fill 128 lanes."""
+        return max(self.d, 128)
+
+    @property
+    def heads(self) -> int:
+        return self.lanes // self.d
+
+    @property
+    def column_blocks(self) -> int:
+        """The last is not whole where the heads are odd at 64: the kernels
+        select what a copy leaves past the last column to zero."""
+        return pl.cdiv(self.width, self.lanes)
 
 
 def _flash_plan(q, k, causal, scale, block_q, block_k, interpret):
     """The plan for q, k of [batch, length, heads, d], or None where the
-    shapes do not tile or a head's dq does not fit VMEM (the caller takes
-    the XLA reference)."""
-    (q_len, d), kv_len = q.shape[1::2], k.shape[1]
+    shapes do not tile or a column block's dq does not fit VMEM (the caller
+    takes the XLA reference)."""
+    (_, q_len, h, d), kv_len = q.shape, k.shape[1]
     block_q, block_k = _fit_blocks(q_len, kv_len, block_q, block_k)
     if causal:          # square blocks: the diagonal crosses equal indices
         block_q = block_k = min(block_q, block_k)
     if interpret is None:
         interpret = _interpret_kernels()
+    plan = _FlashPlan(block_q, block_k, causal,
+                      scale if scale is not None else 1.0 / np.sqrt(d),
+                      interpret, d, h * d)
     if (not _use_pallas(q_len, kv_len, d, block_q, block_k, causal)
-            or 2 * _flash_dq_bytes(q_len, d, q.dtype) > _FLASH_VMEM_LIMIT):
+            or 2 * _flash_dq_bytes(q_len, plan.lanes, q.dtype)
+            > _FLASH_VMEM_LIMIT):
         if not interpret:
             _log_reference_path("flash_attention", (q.shape, k.shape))
         return None
-    return _FlashPlan(block_q, block_k, causal,
-                      scale if scale is not None else 1.0 / np.sqrt(d),
-                      interpret)
+    return plan
 
 
-def _flash_dq_bytes(q_len, d, dtype):
-    """VMEM the backward holds for a head's dq: dq^T in float32 and the
-    output block in two buffers."""
-    return q_len * d * (4 + 2 * jnp.dtype(dtype).itemsize)
+def _flash_dq_bytes(q_len, lanes, dtype):
+    """VMEM the backward holds for a column block's dq: dq^T in float32 and
+    the output block in two buffers."""
+    return q_len * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
 
 
 def _flash_call(plan, kernel, vmem=0, **kwargs):
     """One of the kernels as a `pallas_call`.  Both carry the name the
     trace reader keys on (`benchmark/readers.py::flash_roofline`)."""
     return pl.pallas_call(
-        functools.partial(kernel, causal=plan.causal, scale=plan.scale),
+        functools.partial(kernel, d=plan.d, width=plan.width,
+                          causal=plan.causal, scale=plan.scale),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             # the default 16 MiB holds the blocks and a dq of 4 MiB
@@ -407,90 +532,106 @@ def _flash_call(plan, kernel, vmem=0, **kwargs):
         interpret=plan.interpret, name="flash_attention", **kwargs)
 
 
-def _flash_specs(plan, d, q_at, kv_at):
-    """Block specs of a grid (head, a, b): a q-side array, a kv-side array
-    and a q-side row of float32, whose blocks along the length are
-    `q_at(a, b)` and `kv_at(a, b)`."""
-    return (pl.BlockSpec((None, plan.block_q, d),
-                         lambda i, a, b: (i, q_at(a, b), 0)),
-            pl.BlockSpec((None, plan.block_k, d),
-                         lambda i, a, b: (i, kv_at(a, b), 0)),
-            pl.BlockSpec((None, 1, plan.block_q),
+def _flash_specs(plan, q_at, kv_at):
+    """Block specs of a grid (batch x column block, a, b): a q-side array
+    and a kv-side array [batch, length, heads x d], read where the model
+    left them, and a q-side row of float32 a head, whose blocks along the
+    length are `q_at(a, b)` and `kv_at(a, b)`."""
+    n = plan.column_blocks
+    return (pl.BlockSpec((None, plan.block_q, plan.lanes),
+                         lambda i, a, b: (i // n, q_at(a, b), i % n)),
+            pl.BlockSpec((None, plan.block_k, plan.lanes),
+                         lambda i, a, b: (i // n, kv_at(a, b), i % n)),
+            pl.BlockSpec((plan.heads, 1, plan.block_q),
                          lambda i, a, b: (i, 0, q_at(a, b))))
 
 
+def _flash_rows(plan, batch, q_len):
+    """Shape of a float32 row a head and q position (the logsumexp, delta):
+    a column block's heads together, a head past the arrays' last among
+    them where the last block is not whole."""
+    return (batch * plan.column_blocks * plan.heads, 1, q_len)
+
+
 def _flash_fwd_heads(plan, q, k, v):
-    """out [heads, q_len, d] and the logsumexp [heads, 1, q_len] of
-    q, k, v [heads, length, d]."""
-    (n, q_len, d), kv_len = q.shape, k.shape[1]
+    """out [batch, q_len, heads x d] and the logsumexp (`_flash_rows`) of
+    q, k, v [batch, length, heads x d]."""
+    (batch, q_len, _), kv_len = q.shape, k.shape[1]
     # A kv block past the q block's own is not copied in: the index stays
     # at the last block the pair needs, and an unchanged block is kept.
     q_spec, kv_spec, row_spec = _flash_specs(
-        plan, d, lambda a, b: a,
+        plan, lambda a, b: a,
         lambda a, b: jnp.minimum(a, b) if plan.causal else b)
     return _flash_call(
         plan, functools.partial(
             _flash_kernel, bq=_flash_tile(plan.block_q, _FLASH_FWD_TILE),
             bk=_flash_tile(plan.block_k, _FLASH_FWD_TILE),
             n_blocks=q_len // plan.block_q),
-        grid=(n, q_len // plan.block_q, kv_len // plan.block_k),
+        grid=(batch * plan.column_blocks, q_len // plan.block_q,
+              kv_len // plan.block_k),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((n, 1, q_len), jnp.float32)],
-        scratch_shapes=[pltpu.VMEM((1, plan.block_q), jnp.float32),
-                        pltpu.VMEM((1, plan.block_q), jnp.float32),
-                        pltpu.VMEM((d, plan.block_q), jnp.float32)],
+                   jax.ShapeDtypeStruct(_flash_rows(plan, batch, q_len),
+                                        jnp.float32)],
+        scratch_shapes=[
+            pltpu.VMEM((plan.heads, 1, plan.block_q), jnp.float32),
+            pltpu.VMEM((plan.heads, 1, plan.block_q), jnp.float32),
+            pltpu.VMEM((plan.lanes, plan.block_q), jnp.float32)],
     )(q, k, v)
 
 
-def _flash_bwd_heads(plan, q, k, v, do, lse, delta):
-    """dq, dk, dv of q, k, v, dO [heads, length, d] and the logsumexp and
-    delta [heads, 1, q_len]."""
-    (n, q_len, d), kv_len = q.shape, k.shape[1]
+def _flash_bwd_heads(plan, q, k, v, do, out, lse):
+    """dq, dk, dv of q, k, v, dO and the forward's out [batch, length,
+    heads x d] and logsumexp (`_flash_rows`)."""
+    (batch, q_len, _), kv_len = q.shape, k.shape[1]
     # Nor is a q block before the kv block's own.
     q_spec, kv_spec, row_spec = _flash_specs(
-        plan, d, lambda a, b: jnp.maximum(a, b) if plan.causal else b,
+        plan, lambda a, b: jnp.maximum(a, b) if plan.causal else b,
         lambda a, b: a)
+    n = plan.column_blocks
     return _flash_call(
         plan, functools.partial(
             _flash_bwd_kernel, bq=_flash_tile(plan.block_q, _FLASH_BWD_TILE),
             bk=_flash_tile(plan.block_k, _FLASH_BWD_TILE)),
-        vmem=_flash_dq_bytes(q_len, d, q.dtype),
-        grid=(n, kv_len // plan.block_k, q_len // plan.block_q),
-        in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
-        out_specs=[pl.BlockSpec((None, q_len, d), lambda i, a, b: (i, 0, 0)),
+        vmem=_flash_dq_bytes(q_len, plan.lanes, q.dtype),
+        grid=(batch * n, kv_len // plan.block_k, q_len // plan.block_q),
+        in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
+        out_specs=[pl.BlockSpec((None, q_len, plan.lanes),
+                                lambda i, a, b: (i // n, 0, i % n)),
                    kv_spec, kv_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[
-            pltpu.VMEM((q_len // plan.block_q, d, plan.block_q), jnp.float32),
-            pltpu.VMEM((plan.block_k, d), jnp.float32),
-            pltpu.VMEM((plan.block_k, d), jnp.float32)],
-    )(q, k, v, do, lse, delta)
+            pltpu.VMEM((q_len // plan.block_q, plan.lanes, plan.block_q),
+                       jnp.float32),
+            pltpu.VMEM((plan.block_k, plan.lanes), jnp.float32),
+            pltpu.VMEM((plan.block_k, plan.lanes), jnp.float32)],
+    )(q, k, v, do, out, lse)
+
+
+def _heads_side_by_side(x):
+    """[batch, length, heads, d] as the kernels take it, [batch, length,
+    heads x d]: the same bytes, no copy."""
+    return x.reshape(*x.shape[:2], -1)
 
 
 def _flash_forward_impl(q, k, v, causal, scale, block_q, block_k, interpret):
     plan = _flash_plan(q, k, causal, scale, block_q, block_k, interpret)
     if plan is None:
         return reference_attention(q, k, v, causal=causal, scale=scale), None
-    # Fold batch and heads into the grid; kernel sees [len, d] slices.
-    out, lse = _flash_fwd_heads(plan, _fold_heads(q), _fold_heads(k),
-                                _fold_heads(v))
-    return _unfold_heads(out, q.shape[0]), lse
+    out, lse = _flash_fwd_heads(
+        plan, *(_heads_side_by_side(x) for x in (q, k, v)))
+    return out.reshape(q.shape), lse
 
 
 def _flash_backward_impl(q, k, v, out, lse, g, causal, scale, block_q,
                          block_k, interpret):
     plan = _flash_plan(q, k, causal, scale, block_q, block_k, interpret)
-    # delta = rowsum(dO * O), once, where both still lie as the model left
-    # them: O is not folded for the kernel at all.
-    delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32), axis=-1)
-    delta = delta.transpose(0, 2, 1).reshape(lse.shape)   # [b, l, h] first
-    grads = _flash_bwd_heads(plan, *(_fold_heads(x) for x in (q, k, v, g)),
-                             lse, delta)
-    return tuple(_unfold_heads(x, q.shape[0]) for x in grads)
+    grads = _flash_bwd_heads(
+        plan, *(_heads_side_by_side(x) for x in (q, k, v, g, out)), lse)
+    return tuple(dx.reshape(x.shape) for dx, x in zip(grads, (q, k, v)))
 
 
 # ---------------------------------------------------------------------------

@@ -1,19 +1,26 @@
 #!/usr/bin/env python3
 """Time the flash-attention kernels alone at the shapes the training
 programs call them with: `train_gpt2s_1chip`'s `[24,1024,12,64]`, gpt2-xl's
-per-chip fsdp4 share `[6,1024,25,64]` and one head-128 shape
-`[4,2048,16,128]`, bf16, causal.  Each shape runs `flash_attention` and its
-gradients as the models call it, a few times under the profiler; the
-figures are the device's own durations of the Mosaic calls (the forward and
-the backward, told apart by their operands since they carry one name; a
-tree with a dq and a dk/dv kernel has the two summed), in ms a call and as a
+per-chip fsdp4 share `[6,1024,25,64]` and its whole `[4,1024,25,64]` (25
+heads of 64: the thirteenth block of 128 columns is half a block), and one
+head-128 shape `[4,2048,16,128]`, bf16, causal.  Each shape runs
+`flash_attention` and its gradients as the models call it: q, k and v
+arrive `[b, l, h*d]`, as `models/decoder.py::heads_attention`'s products
+write them, are seen as `[b, l, h, d]` for the call, and the gradients
+leave `[b, l, h*d]` again.  A few calls under the profiler; the figures are
+the device's own durations of the Mosaic calls (the forward and the
+backward, told apart by their operands since they carry one name; a tree
+with a dq and a dk/dv kernel has the two summed), in ms a call and as a
 share of `benchmark/flops.py`'s roofline (`flash_fwd`, `flash_bwd`), beside
-what else the program ran (`other_ms`: the head
-transposes around the kernels and any layout copy) and the tiles the causal
+what else the program ran (`other_ms`: since PR 51 the cotangent's product
+with `g` and what XLA still moves between the `[b, l, h*d]` arrays and the
+kernels, which is nothing where `h*d` is whole blocks of 128; on a tree
+before PR 51 also the head transposes to `[b*h, l, d]` and back and the
+`delta` pass; `others` names its longest rows) and the tiles the causal
 walk visits of a head's score square.  The train cell's twin of
 `scripts/engine_step_time.py`: not a tool the benchmark runs.  On the chip,
-from the root of a checkout (the parent's, to compare: it needs nothing of
-the program but `flash_attention`):
+from the root of a checkout (a parent's, to compare, with this file's path:
+it needs nothing of the program but `flash_attention`):
 
   python3 scripts/flash_step_time.py [tile ...]
 
@@ -25,6 +32,7 @@ inputs, whose products stay float32.  The last line is one JSON object.
 
 from __future__ import annotations
 
+import collections
 import json
 import os
 import re
@@ -40,7 +48,7 @@ from benchmark import flops, manifest, trace_reduce
 from ray_tpu.ops import attention as A
 
 SHAPES = {"gpt2s_b24": (24, 1024, 12, 64), "gpt2xl_fsdp4": (6, 1024, 25, 64),
-          "head128": (4, 2048, 16, 128)}
+          "gpt2xl_b4": (4, 1024, 25, 64), "head128": (4, 2048, 16, 128)}
 CALLS = 10
 
 
@@ -66,7 +74,7 @@ def is_forward(text: str) -> bool:
 
 def device_ms(fn, args):
     """ms a call on the device: the forward kernel, the backward kernel(s),
-    and everything else the program ran."""
+    and everything else the program ran (`others`: its longest rows)."""
     fn = jax.jit(fn)
     jax.block_until_ready(fn(*args))
     with tempfile.TemporaryDirectory() as trace_dir:
@@ -78,12 +86,15 @@ def device_ms(fn, args):
         trace = trace_reduce.load(trace_reduce.find(trace_dir))
     (lines,) = trace["devices"].values()
     took = {"fwd": 0.0, "bwd": 0.0, "other": 0.0}
+    others = collections.Counter()
     for text, ns in trace_reduce.self_times(lines[trace_reduce.OPS_LINE]):
         part = "other"
         if trace_reduce.KERNEL_MARK in text:
             part = "fwd" if is_forward(text) else "bwd"
+        else:
+            others[trace_reduce.describe(text)[1]] += ns / 1e6 / CALLS
         took[part] += ns / 1e6 / CALLS
-    return took
+    return took, [[label, round(ms, 4)] for label, ms in others.most_common(4)]
 
 
 def main(tiles, dtype):
@@ -98,15 +109,18 @@ def main(tiles, dtype):
             A._FLASH_FWD_TILE, A._FLASH_BWD_TILE = fwd_tile, bwd_tile
             A.flash_attention.clear_cache()
         for name, (b, s, h, d) in SHAPES.items():
-            q, k, v, g = (jax.random.normal(jax.random.key(i), (b, s, h, d),
+            q, k, v, g = (jax.random.normal(jax.random.key(i), (b, s, h * d),
                                             dtype) for i in range(4))
 
-            def step(q, k, v):
-                return jax.value_and_grad(lambda *a: jnp.sum(
-                    A.flash_attention(*a, causal=True).astype(jnp.float32)
-                    * g.astype(jnp.float32)), argnums=(0, 1, 2))(q, k, v)
+            def step(q, k, v, shape=(b, s, h, d)):
+                def weighed(*wide):
+                    out = A.flash_attention(
+                        *(x.reshape(shape) for x in wide), causal=True)
+                    return jnp.sum(out.reshape(g.shape).astype(jnp.float32)
+                                   * g.astype(jnp.float32))
+                return jax.value_and_grad(weighed, argnums=(0, 1, 2))(q, k, v)
 
-            took = device_ms(step, (q, k, v))
+            took, others = device_ms(step, (q, k, v))
             block = min(s, 1024)
             row = {"shape": name, "tile": [fwd_tile, bwd_tile],
                    "tiles": [tiles_visited(t, block)
@@ -121,6 +135,7 @@ def main(tiles, dtype):
                 row[f"{part}_roofline_pct"] = 1e5 * least[part] / took[part]
             row["all_ms"] = took["fwd"] + took["bwd"]
             row["all_roofline_pct"] = 1e5 * sum(least.values()) / row["all_ms"]
+            row["others"] = others
             result["rows"].append(row)
             print("  ".join(f"{key}={val:.3f}" if isinstance(val, float)
                             else f"{key}={val}" for key, val in row.items()),

@@ -49,21 +49,21 @@ def as_on_chip(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
-def _compile_train_step(devices, mesh_cfg):
+def _compile_train_step(devices, mesh_cfg, cfg=CFG, batch=8):
     mesh = create_mesh(mesh_cfg, devices=devices)
     _, train_step = gpt.make_train_step(
-        CFG, optax.sgd(1e-3), mesh if len(devices) > 1 else None)
+        cfg, optax.sgd(1e-3), mesh if len(devices) > 1 else None)
 
     params = jax.tree.map(
         lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s),
-        jax.eval_shape(lambda k: gpt.init_params(CFG, k), jax.random.key(0)),
-        tree_shardings(mesh, gpt.param_specs(CFG)))
+        jax.eval_shape(lambda k: gpt.init_params(cfg, k), jax.random.key(0)),
+        tree_shardings(mesh, gpt.param_specs(cfg)))
     replicated = NamedSharding(mesh, P())
     state = {"params": params,
              "opt_state": optax.sgd(1e-3).init(params),    # holds no array
              "step": jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated)}
     tokens = jax.ShapeDtypeStruct(
-        (8, CFG.max_seq_len), jnp.int32,
+        (batch, cfg.max_seq_len), jnp.int32,
         sharding=named_sharding(mesh, ("batch", "length")))
     return jax.jit(train_step, donate_argnums=0).lower(
         state, {"tokens": tokens}).compile().as_text()
@@ -90,6 +90,103 @@ def test_flash_kernels_carry_the_name_the_trace_reader_keys_on(v5e,
     assert {trace_reduce.describe(line.strip())[0]
             for line in text.splitlines()
             if trace_reduce.KERNEL_MARK in line} == {"flash_attention"}
+
+
+_RESULT_ORDER = re.compile(
+    r"^\s*(?:ROOT )?%([\w.\-]+) = \w+\[([\d,]*)\]\{([\d,]*)\S* ([\w\-]+)\(%([\w.\-]+)")
+
+
+def _activation_relayouts(text, elements):
+    """The `copy` and `transpose` instructions of the layer loop's body
+    (fusions' insides apart) whose result has `elements` numbers in another
+    order in memory than their operand, by the product they are made for
+    (the tail of `op_name`).  A copy that keeps the order (a prefetch into
+    `S(1)`) moves nothing around and is not counted."""
+    order, found, fused = {}, collections.Counter(), False
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            fused = line.startswith("%fused_computation")
+        m = _RESULT_ORDER.match(line)
+        if m is None:
+            continue
+        name, dims, minor_to_major, op, operand = m.groups()
+        order[name] = dims, minor_to_major
+        if (fused or op not in ("copy", "transpose") or not dims
+                or math.prod(map(int, dims.split(","))) != elements
+                or "while/body" not in line
+                or order.get(operand) == (dims, minor_to_major)):
+            continue
+        found[re.search(r'op_name="([^"]*)"', line).group(1).split(
+            "closed_call/")[-1]] += 1
+    return dict(found)
+
+
+# (widths, mesh, chips, the batch, a chip's share of it, the relayouts of an
+# activation a layer-step at most)
+HEADS_LEFT_WHERE_THEY_ARE = {
+    # `train_gpt2s_1chip`'s step
+    "gpt2s_b24": (dict(d_model=768, n_heads=12, d_ff=3072),
+                  MeshConfig(data=1), 1, 24, 24, 1),
+    # 25 heads of 64: the thirteenth block of 128 columns is half a block
+    "gpt2xl_width_b6": (dict(d_model=1600, n_heads=25, d_ff=6400),
+                        MeshConfig(data=1), 1, 6, 6, 1),
+    # the kernels under `shard_map`, the weights' gradients scattered
+    "gpt2s_fsdp4": (dict(d_model=768, n_heads=12, d_ff=3072),
+                    MeshConfig(fsdp=4), 4, 24, 6, 4),
+}
+
+
+@pytest.mark.parametrize("program", list(HEADS_LEFT_WHERE_THEY_ARE))
+def test_flash_kernels_read_the_heads_where_the_train_step_leaves_them(
+        v5e, as_on_chip, program):
+    """A train step of one layer at 1,024 positions: the Mosaic calls take
+    q, k, v, dO and O and give out, dq, dk and dv as `[b, 1024, h*d]`
+    row-major, the heads side by side as the projections write and read
+    them, and nothing between a projection and a kernel moves an
+    activation.  Until PR 51 the kernels took `[b*h, 1024, 64]` and the
+    step transposed seven activations a layer to it and four back (the
+    trace's `copy bf16[24,12,1024,64]`, 7.85% of `train_gpt2s_1chip`'s
+    step; eight standalone copies a layer in each of these programs).  XLA
+    lays a `[b, 1024, h, 64]` value out with the length innermost (a head
+    of 64 is half a tile's lanes), so a projection that comes out in four
+    dimensions brings the copies back under another shape:
+    `heads_attention` makes its products `[b, l, h*d]` wide.
+
+    What is still moved, and held to no more: on one chip ONE activation a
+    layer-step, the forward's out turned round for the gradient of `wo`
+    (the kernel's result is row-major by constraint and the product
+    contracts over batch and length; the parent's unfolding copy gave it
+    either way).  Under a mesh (data, fsdp or tensor alike) the products
+    for the gradients of `wq`, `wk` and `wv` take dq, dk and dv turned
+    round as well: four, where the parent had eight, and seven before q, k
+    and v crossed the shards' edge wide (`mesh_flash_attention`).  A later
+    edit of `heads_attention` or of the kernels is held to this."""
+    widths, mesh_cfg, chips, batch, share, most = HEADS_LEFT_WHERE_THEY_ARE[
+        program]
+    cfg = dataclasses.replace(CFG, vocab_size=50304, max_seq_len=1024,
+                              remat=False, **widths)
+    text = _compile_train_step(v5e[:chips], mesh_cfg, cfg, batch=batch)
+    width = cfg.d_model
+    wide, names = f"bf16[{share},1024,{width}]", _kernel_names(text)
+    assert len(names) == 2 and {n.split(".")[0] for n in names} == {
+        "flash_attention"}
+    for line in text.splitlines():
+        if 'custom_call_target="tpu_custom_call"' in line:
+            results, operands = line.split(" custom-call(")
+            operands = operands.split("operand_layout_constraints=")[1].split(
+                ", frontend_attributes")[0]
+            # forward: out (and the logsumexp) of q, k, v; backward: dq, dk,
+            # dv of q, k, v, dO, O (and the logsumexp), each row-major
+            assert (results.count(wide), operands.count(wide + "{2,1,0}")
+                    ) in ((1, 3), (3, 5)), line
+        # no array in the shapes the kernels took or gave until PR 51
+        assert not re.search(
+            rf"\[{share},{cfg.n_heads},1024,64\]|"
+            rf"\[{share * cfg.n_heads},1024,64\]", line), line
+    moved = _activation_relayouts(text, share * 1024 * width)
+    assert sum(moved.values()) <= most, moved
+    assert any("hkd->bld" in made_for or "ed->bld" in made_for
+               for made_for in moved), moved
 
 
 def test_train_step_compiles_under_a_v5e_mesh(v5e, as_on_chip):
