@@ -44,7 +44,7 @@ from ray_tpu._private import spec_codec
 from ray_tpu._private.function_manager import FunctionManager
 from ray_tpu._private.ids import ActorID, JobID, NodeID, ObjectID, TaskID, WorkerID
 from ray_tpu._private.object_store import ObjectStore
-from ray_tpu.util import spans, tracing
+from ray_tpu.util import events, spans, tracing
 from ray_tpu._private.protocol import (
     INLINE_LIMIT,
     RefArg,
@@ -70,6 +70,10 @@ class _BundleNode:
     node_id: object
 
 logger = logging.getLogger("ray_tpu.worker")
+
+# A task whose arguments took this long to fetch and unpickle keeps a
+# `sched/arg_fetch` row in the process's start-up record.
+_ARGS_PIN_S = 0.1
 
 
 @dataclass
@@ -431,9 +435,9 @@ class CoreWorker:
         """This worker's flight-recorder ring (live scrape side of the
         black box).  `now` rides along so the aggregator can normalize
         clock skew across nodes."""
-        from ray_tpu.util import events
         return {"pid": os.getpid(), "now": time.time(),
-                "events": events.snapshot(since=req.get("since", 0.0))}
+                "events": events.snapshot(since=req.get("since", 0.0)),
+                "pinned": events.pinned()}
 
     # ---- execution services ----
 
@@ -630,6 +634,9 @@ class CoreWorker:
         return await done
 
     async def _rpc_kill_actor(self, req):
+        # What this process recorded outlives it: hostd reads the dump for
+        # `CollectEvents`, a killed replica's start-up record among it.
+        events.dump_crash("actor_killed")
         self.exec_queue.put(None)  # sentinel: exit main loop
         asyncio.get_running_loop().call_later(0.5, os._exit, 0)
         return {"ok": True}
@@ -2507,7 +2514,6 @@ class CoreWorker:
             # like a SIGKILL'd/preempted worker — the owner sees the
             # connection drop and must retry/reconstruct.
             logger.warning("chaos: killing worker before task %s", spec.name)
-            from ray_tpu.util import events
             events.record("proc", "chaos_kill", task=spec.name,
                           trace=getattr(spec, "trace_ctx", None))
             events.dump_crash("chaos_kill_worker")
@@ -2525,20 +2531,36 @@ class CoreWorker:
         tok_task = (spans.begin("sched", "task", ctx=(span[0], span[2]),
                                 sid=span[1], name=spec.name)
                     if span is not None else None)
+        # An actor's construction (its arguments and class fetched, its
+        # __init__ run) happens once a process: kept in the start-up
+        # record, a child of the creating task where that was traced.
+        tok_actor = (spans.begin("proc", "actor_init", pin=True,
+                                 name=spec.name)
+                     if spec.actor_creation else None)
         try:
             tok = spans.begin("sched", "arg_fetch",
                               n=len(spec.args) + len(spec.kwargs)) \
                 if tok_task is not None else None
+            t_args = time.perf_counter()
             args = [self._resolve_arg(a) for a in spec.args]
             kwargs = {k: self._resolve_arg(v) for k, v in spec.kwargs.items()}
             spans.end(tok)
-            self.current_task_id = spec.task_id
-            self.current_task_spec = spec
             if spec.actor_creation:
                 cls = self.fn_manager.fetch_cached(spec.fn_key) or \
                     self.io.run(self.fn_manager.fetch(spec.fn_key))
-                self.current_actor_pg = spec.placement_group
-                self.actor_instance = cls(*args, **kwargs)
+            took = time.perf_counter() - t_args
+            if took >= _ARGS_PIN_S:
+                # Unpickling what a task was given can import half the
+                # program (a model's config brings jax): where it cost
+                # this much it has a row the ring cannot lose.
+                events.pin("sched", "arg_fetch", time.time() - took, took,
+                           payload={"name": spec.name})
+            self.current_task_id = spec.task_id
+            self.current_task_spec = spec
+            if spec.actor_creation:
+                with spans.under(tok_actor):
+                    self.current_actor_pg = spec.placement_group
+                    self.actor_instance = cls(*args, **kwargs)
                 self._setup_actor_execution(cls, spec)
                 return {"returns": [], "error": None}
             tok = spans.begin("sched", "exec", name=spec.name) \
@@ -2564,6 +2586,7 @@ class CoreWorker:
         except BaseException as e:  # noqa: BLE001
             return self._error_reply(spec, e)
         finally:
+            spans.end(tok_actor)
             spans.end(tok_task)
             if span is not None:
                 tracing.exit_task()
@@ -3011,7 +3034,7 @@ class _KeyScheduler:
         # the trace actually blocked on it (specs sharing a key share the
         # lease, so this is the lease's best single owner).
         head = self.queue[0][0] if self.queue else spec
-        tok = (spans.begin("sched", "lease_wait",
+        tok = (spans.begin("sched", "lease_wait", pin=True,
                            ctx=getattr(head, "trace_ctx", None),
                            key=str(self.key)[:64], count=count)
                if getattr(head, "trace_ctx", None) is not None else None)

@@ -1318,7 +1318,7 @@ class GcsServer:
         actor-manager events would be invisible to state.events()."""
         from ray_tpu.util import events as ev
         return {"events": ev.snapshot(since=req.get("since", 0.0)),
-                "now": time.time()}
+                "now": time.time(), "pinned": [ev.pinned()]}
 
     # ---------------- lifecycle ----------------
 
@@ -1371,6 +1371,8 @@ class GcsServer:
 
 
 def main():
+    from ray_tpu.util import events as ev
+    ev.role = "gcs"
     parser = argparse.ArgumentParser()
     parser.add_argument("--port", type=int, default=0)
     parser.add_argument("--host", default="127.0.0.1")

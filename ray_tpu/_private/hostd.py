@@ -24,6 +24,7 @@ from collections import deque
 
 from ray_tpu._private import compile_cache
 from ray_tpu._private import gcs as gcs_mod
+from ray_tpu._private import worker_main
 from ray_tpu._private.accelerators import (
     LEASED_CHIPS_ENV, NODE_CHIPS_ENV, ChipAllocator, chip_env,
     count_local_chips, leasable)
@@ -428,6 +429,9 @@ class NodeDaemon:
             env["RAY_TPU_RUNTIME_ENV"] = _json.dumps(runtime_env)
             env["RAY_TPU_RUNTIME_ENV_CACHE"] = os.path.join(
                 self.session_dir, "runtime_env")
+        # The worker's `proc/boot` names the `sched/worker_boot` span
+        # below as its parent: the id goes ahead of the span.
+        boot_sid = env[worker_main.BOOT_SPAN_ENV] = spans.new_sid()
         argv = ["--gcs", self.gcs_address,
                 "--hostd", f"{self.host}:{self.server.port}",
                 "--store", self.store_path,
@@ -438,21 +442,23 @@ class NodeDaemon:
         # zygote_fork covers process creation (fork round trip or cold
         # Popen), worker_boot the child's interpreter/runtime ramp until
         # its WorkerReady lands.
-        ftok = spans.begin("sched", "zygote_fork",
+        # (Both are kept in the start-up record, like the worker's own.)
+        ftok = spans.begin("sched", "zygote_fork", pin=True,
                            cold=self._zygote is None or bool(chips))
+        proc = None
         try:
             proc = await asyncio.get_running_loop().run_in_executor(
                 self._spawn_exec, self._make_proc, argv, env, log_base,
                 bool(chips))
         finally:
             self._spawning -= 1
-            spans.end(ftok)
+            spans.end(ftok, pid=proc.pid if proc is not None else None)
         handle = WorkerHandle(proc, job_id, renv.env_hash(runtime_env),
                               chips)
         if chips:
             self._chip_procs.append((proc, chips))
-        handle.boot_span = spans.begin("sched", "worker_boot",
-                                       pid=proc.pid)
+        handle.boot_span = spans.begin("sched", "worker_boot", pin=True,
+                                       sid=boot_sid, pid=proc.pid)
         handle.log_paths = {"stdout": log_base + ".out",
                             "stderr": log_base + ".err"}
         handle.log_offsets = {"stdout": 0, "stderr": 0}
@@ -1494,29 +1500,44 @@ class NodeDaemon:
         live worker's ring (concurrent CollectEvents probes), and any
         crash dumps in the session log dir — the black boxes of processes
         that already died.  Each event gains pid/source; `now` rides
-        along for cluster-wide clock-skew normalization."""
+        along for cluster-wide clock-skew normalization.  `pinned` holds
+        the start-up records (`events.pinned()`) of the same processes,
+        the dead ones' rebuilt from their dumps."""
+        recv = time.time()
         since = float(req.get("since", 0.0))
         out = [dict(e, pid=os.getpid(), source="live")
                for e in events.snapshot(since=since)]
+        records = {os.getpid(): events.pinned()}     # by pid
         handles = [h for h in self.workers.values() if h.address]
 
         async def probe(handle):
             try:
                 reply = await self.pool.get(handle.address).call(
                     "CoreWorker", "CollectEvents", {"since": since},
-                    timeout=5)
-                return [dict(e, pid=reply["pid"], source="live")
-                        for e in reply.get("events") or []]
+                    timeout=float(req.get("timeout", 5)))
+                return (reply.get("pinned"),
+                        [dict(e, pid=reply["pid"], source="live")
+                         for e in reply.get("events") or []])
             except Exception:
-                return []
+                return None, []
 
-        for chunk in await asyncio.gather(*[probe(h) for h in handles]):
+        for record, chunk in await asyncio.gather(
+                *[probe(h) for h in handles]):
             out.extend(chunk)
-        out.extend(e for e in
-                   events.read_dumps(os.path.join(self.session_dir, "logs"))
-                   if e["ts"] >= since)
+            if record:
+                records[record["pid"]] = record
+        # (asked for nothing but the records: the dumps' heads will do)
+        dumped = events.read_dumps(os.path.join(self.session_dir, "logs"),
+                                   pinned_only=since > recv)
+        out.extend(e for e in dumped if e["ts"] >= since)
+        for record in events.dumped_records(dumped):
+            # (a live process's own answer outranks a dump of it)
+            records.setdefault(record["pid"], record)
+        # (`recv` beside `now`: probing the workers and reading the dumps
+        # can take a second, and the caller sets its clock against both)
         return {"events": out, "node_id": self.node_id.hex(),
-                "now": time.time()}
+                "recv": recv, "now": time.time(),
+                "pinned": list(records.values())}
 
     # ---------------- preemption (maintenance events) ----------------
 
@@ -2118,6 +2139,7 @@ class NodeDaemon:
 
 
 def main():
+    events.role = "hostd"
     parser = argparse.ArgumentParser()
     parser.add_argument("--gcs", required=True)
     parser.add_argument("--port", type=int, default=0)

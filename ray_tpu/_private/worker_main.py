@@ -15,6 +15,10 @@ import sys
 import threading
 import time
 
+# Set by hostd on the child it makes, read (and removed) here: the id of
+# the `sched/worker_boot` span the worker's `proc/boot` is a child of.
+BOOT_SPAN_ENV = "RAY_TPU_BOOT_SPAN"
+
 
 def main():
     parser = argparse.ArgumentParser()
@@ -25,31 +29,33 @@ def main():
     parser.add_argument("--job-id", type=int, default=0)
     args = parser.parse_args()
     logging.basicConfig(level=os.environ.get("RAY_TPU_LOGLEVEL", "INFO"))
-    boot_trace = os.environ.get("RAY_TPU_BOOT_TRACE")
     from ray_tpu._private.profiling import start_periodic_profile
     pr = start_periodic_profile("RAY_TPU_BOOT_PROFILE", "boot")
-    t0 = time.perf_counter()
+    from ray_tpu.util import events, spans
+    events.role = "worker"
+    # Boot span: from here through the WorkerReady ack, a child of the
+    # `sched/worker_boot` span hostd opened when it made this process (its
+    # id rides the environment hostd hands a worker), with the imports,
+    # CoreWorker's construction and the ack as children: a creation storm
+    # shows up as a wall of long proc/boot spans.  All of it is kept in the
+    # start-up record.
+    tok_boot = spans.begin("proc", "boot", pin=True, pid=os.getpid(),
+                           parent=os.environ.pop(BOOT_SPAN_ENV, None))
+    with spans.under(tok_boot):
+        with spans.span("proc", "imports", pin=True):
+            from ray_tpu._private.core_worker import CoreWorker
+            from ray_tpu._private.ids import JobID, NodeID
+            from ray_tpu._private.rpc import RpcClient
 
-    from ray_tpu._private.core_worker import CoreWorker
-    from ray_tpu._private.ids import JobID, NodeID
-    from ray_tpu._private.rpc import RpcClient
-    from ray_tpu.util import spans
-    t_imports = time.perf_counter() - t0
-    # Boot span: CoreWorker construction through WorkerReady ack, so a
-    # creation storm shows up as a wall of long proc/boot spans (import
-    # cost rides along in the payload — it predates the recorder).
-    tok_boot = spans.begin("proc", "boot", pid=os.getpid(),
-                           imports_ms=round(t_imports * 1e3, 1))
-
-    cw = CoreWorker(
-        mode="worker",
-        gcs_address=args.gcs,
-        store_path=args.store,
-        node_id=NodeID.from_hex(args.node_id),
-        hostd_address=args.hostd,
-        job_id=JobID(args.job_id.to_bytes(4, "little")),
-    )
-    t_core = time.perf_counter() - t0
+        with spans.span("proc", "core_worker", pin=True):
+            cw = CoreWorker(
+                mode="worker",
+                gcs_address=args.gcs,
+                store_path=args.store,
+                node_id=NodeID.from_hex(args.node_id),
+                hostd_address=args.hostd,
+                job_id=JobID(args.job_id.to_bytes(4, "little")),
+            )
 
     # Tasks call ray_tpu.get/put/remote through the process-global worker.
     from ray_tpu import api
@@ -67,6 +73,9 @@ def main():
                                        cache_root), timeout=120)
 
     hostd = RpcClient(args.hostd)
+    tok_ready = spans.begin("proc", "ready_rpc", pin=True,
+                            ctx=(tok_boot.trace_id, tok_boot.sid)
+                            if tok_boot is not None else None)
     # Registration retries: during a creation storm (hundreds of workers
     # booting on few cores) the daemon can miss a 10s window; a worker
     # dying here amplifies the storm instead of riding it out.
@@ -88,13 +97,8 @@ def main():
             time.sleep(0.5 * (attempt + 1))
     else:
         raise RuntimeError(f"WorkerReady never acknowledged: {last}")
+    spans.end(tok_ready)
     spans.end(tok_boot)
-    if boot_trace:
-        print(f"[boot-trace] imports={t_imports*1e3:.1f}ms "
-              f"core_worker={(t_core - t_imports)*1e3:.1f}ms "
-              f"ready_rpc={(time.perf_counter() - t0 - t_core)*1e3:.1f}ms "
-              f"total={(time.perf_counter() - t0)*1e3:.1f}ms",
-              file=sys.stderr, flush=True)
     if pr is not None:
         pr.disable()
         pr.dump_stats(os.path.join(
@@ -120,8 +124,6 @@ def main():
     import signal
 
     from ray_tpu._private.config import GLOBAL_CONFIG
-
-    from ray_tpu.util import events
 
     def _graceful_exit(signum=None, frame=None):
         # Black box first: the flight-recorder ring is the only record of

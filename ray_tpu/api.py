@@ -41,7 +41,21 @@ def init(address: str | None = None, *, num_cpus=None, num_tpus=None,
          resources=None, namespace: str = "default",
          object_store_memory: int = 256 << 20, ignore_reinit_error=False,
          log_to_driver: bool = True, _system_config=None):
-    """Connect to (or bootstrap) a cluster.  Reference: worker.py ray.init:1108."""
+    """Connect to (or bootstrap) a cluster.  Reference: worker.py ray.init:1108.
+
+    One `proc/init` span in the start-up record, with the daemons' starts
+    and the driver's own connection as children."""
+    from ray_tpu.util import spans
+    with spans.span("proc", "init", pin=True):
+        return _init(address, num_cpus, num_tpus, resources, namespace,
+                     object_store_memory, ignore_reinit_error, log_to_driver,
+                     _system_config)
+
+
+def _init(address, num_cpus, num_tpus, resources, namespace,
+          object_store_memory, ignore_reinit_error, log_to_driver,
+          _system_config):
+    from ray_tpu.util import spans
     global _worker, _cluster
     if address is None:
         # Reference parity: RAY_ADDRESS lets submitted job drivers join the
@@ -83,11 +97,14 @@ def init(address: str | None = None, *, num_cpus=None, num_tpus=None,
             session_dir = node_mod.new_session_dir()
             group = node_mod.ProcessGroup()
             try:
-                gcs_address = node_mod.start_gcs(session_dir, group, watch_parent=True)
-                head = node_mod.start_hostd(
-                    gcs_address, session_dir, group,
-                    num_cpus=num_cpus, num_tpus=num_tpus, resources=resources,
-                    store_capacity=object_store_memory, head=True)
+                with spans.span("proc", "gcs_start", pin=True):
+                    gcs_address = node_mod.start_gcs(
+                        session_dir, group, watch_parent=True)
+                with spans.span("proc", "hostd_start", pin=True):
+                    head = node_mod.start_hostd(
+                        gcs_address, session_dir, group, num_cpus=num_cpus,
+                        num_tpus=num_tpus, resources=resources,
+                        store_capacity=object_store_memory, head=True)
             except Exception:
                 group.reap()
                 raise
@@ -125,24 +142,25 @@ def init(address: str | None = None, *, num_cpus=None, num_tpus=None,
         from ray_tpu._private.rpc import RpcClient as _Rpc
         import asyncio as _aio
 
-        try:
-            async def next_job():
-                gcs = _Rpc(gcs_address)
-                try:
-                    reply = await gcs.call("Gcs", "next_job_id", {}, timeout=10)
-                    return reply["job_id"]
-                finally:
-                    await gcs.close()
-            job_int = _aio.run(next_job())
+        async def next_job():
+            gcs = _Rpc(gcs_address)
+            try:
+                reply = await gcs.call("Gcs", "next_job_id", {}, timeout=10)
+                return reply["job_id"]
+            finally:
+                await gcs.close()
 
-            _worker = CoreWorker(
-                mode="driver",
-                gcs_address=gcs_address,
-                store_path=head["store_path"],
-                node_id=NodeID.from_hex(head["node_id"]),
-                hostd_address=head["address"],
-                job_id=JobID(job_int.to_bytes(4, "little")),
-            )
+        try:
+            with spans.span("proc", "driver_connect", pin=True):
+                job_int = _aio.run(next_job())
+                _worker = CoreWorker(
+                    mode="driver",
+                    gcs_address=gcs_address,
+                    store_path=head["store_path"],
+                    node_id=NodeID.from_hex(head["node_id"]),
+                    hostd_address=head["address"],
+                    job_id=JobID(job_int.to_bytes(4, "little")),
+                )
         except Exception:
             _cluster = None
             if group is not None:
@@ -254,6 +272,13 @@ def shutdown():
     with _global_lock:
         if _worker is None:
             return
+        # Before anything is torn down: every process's start-up record,
+        # kept for `state.startup_timeline()` to give after the session.
+        from ray_tpu import state as _state
+        try:
+            _state._keep_startup_timeline()
+        except Exception:
+            pass
         cluster, worker = _cluster, _worker
         _worker = None
         _cluster = None
@@ -281,6 +306,11 @@ def shutdown():
         worker.shutdown()
         if cluster and cluster.get("owned") and cluster.get("group"):
             cluster["group"].reap()
+            try:        # (whoever the first call did not hear from)
+                _state._keep_startup_timeline(
+                    _os.path.join(cluster["session_dir"], "logs"))
+            except Exception:
+                pass
 
 
 def put(value) -> ObjectRef:

@@ -141,6 +141,53 @@ logger = logging.getLogger(__name__)
 
 _DONE = object()
 
+
+def _setup() -> dict:
+    """The process's start-up record (`events.pinned`) folded: `start`
+    (the process's, on `time.time()`), `seconds` by kind over every row,
+    and `programs`: each `engine.dispatch/make_program` row's payload (its
+    key and split) with its start and duration."""
+    record = events.pinned()
+    seconds: dict = {}
+    for row in record["rows"]:
+        seconds[row["kind"]] = seconds.get(row["kind"], 0.0) + row["dur"]
+    return {"start": record["start"], "seconds": seconds,
+            "programs": [dict(row["payload"], start=row["start"],
+                              dur=row["dur"])
+                         for row in record["rows"]
+                         if row["kind"] == "make_program"]}
+
+
+# A program's first call: nested in the `dispatch` phase (or, a compaction
+# program's, in `build_batch`) under a name of the parts' kind.
+_MAKE_PROGRAM = ("engine.dispatch", "make_program")
+
+
+@contextlib.contextmanager
+def _making(key):
+    """Around a program's first call (it is made once a process): one
+    `engine.dispatch/make_program` span, kept in the start-up record, with
+    the program's key and, at its end, what jax reported meanwhile
+    (`compile_cache.counters()`'s difference: seconds tracing, lowering,
+    loading from the persistent cache and compiling; the rest of the span is
+    the first run and the heap's tidying behind it).  Under the same name an
+    annotation in the jax profiler's trace, so that a program made inside a
+    traced slice lies over the gap it causes (the `engine.` prefix keeps it
+    out of the readers of the flat phases, like the parts of a phase).
+    Yields the dict that will hold the split, `wall_s` included."""
+    made: dict = {}
+    c0 = compile_cache.sums()
+    tok = spans.begin(*_MAKE_PROGRAM, pin=True, key=list(key))
+    try:
+        with spans.phase(*_MAKE_PROGRAM) as ph:
+            yield made
+    finally:
+        c1 = compile_cache.sums()
+        made.update({k: c1[k] - c0[k] for k in (
+            "trace_s", "lower_s", "cache_load_s", "compile_s")},
+            cached=c1["cache_hits"] - c0["cache_hits"], wall_s=ph.seconds)
+        spans.end(tok, **made)
+
 # The flat phases of one scheduler step (spans.phase: profiler annotations
 # `engine/<phase>`, seconds into the step's one ring record and stats()).
 _PHASES = ("admit", "build_batch", "dispatch", "fetch", "commit")
@@ -155,6 +202,8 @@ _TIMELINE = ("t", "steps", "prefill_steps", "wall_s", "phase_s", "part_s",
              "cpu_s", "cpu_wall_s", "cpu_steps", "gc_s", "longest_ms",
              "longest_phase")
 _TIMELINE_ROWS = 128
+# An iteration longer than this leaves one `engine/stall` event.
+_STALL_S = 0.5
 # The thread's CPU clock is read in one iteration of this many on average,
 # drawn and not counted off, so that no rhythm of the traffic falls in step
 # with it: the clock is a system call (5.5 us alone and 16 us in a serving
@@ -510,7 +559,11 @@ class InferenceEngine:
         self.model = models.family(model)
         self.config = (self.model.CONFIGS[config] if isinstance(config, str)
                        else config)
-        device = jax.devices()[0]
+        # What an engine does once goes into the process's start-up record
+        # (`pin=True`): the client's start here, then the weights, their
+        # preparation and the pools, each waited for inside its span.
+        with spans.span("proc", "backend_init", pin=True):
+            device = jax.devices()[0]
         self.backend = device.platform
         self.device_kind = device.device_kind
         logger.info("InferenceEngine on backend=%s device_kind=%s chips=%s",
@@ -518,15 +571,18 @@ class InferenceEngine:
         if params is None:
             # One compiled program, not one dispatch per op: building a
             # gpt2-small engine op by op took 55 s on a v5e chip.
-            params = jax.jit(self.model.init_params, static_argnums=0)(
-                self.config, jax.random.key(seed))
+            with spans.span("engine", "init_params", pin=True):
+                params = jax.block_until_ready(
+                    jax.jit(self.model.init_params, static_argnums=0)(
+                        self.config, jax.random.key(seed)))
         self._lock = threading.Lock()
         self._work = threading.Condition(self._lock)
         # What the caller gave, and what the step takes (_prepare).
         self.params = params
         self._weights = {"prepared": 0, "prepare_s": 0.0,
                          "served_bytes": 0, "given_bytes": 0}
-        self._served = self._prepare(params)
+        with spans.span("engine", "prepare", pin=True):
+            self._served = self._prepare(params)
         # An expert configuration's load counters live on the device and
         # ride the step (forward_cached's `moe_load`); stats() fetches.
         n_experts = self.config.n_experts
@@ -541,13 +597,16 @@ class InferenceEngine:
                           self.config.max_seq_len)
         if num_blocks is None:
             num_blocks = max_lanes * -(-max_seq_len // block_size)
-        self.cache = PagedKVCache.for_model(
-            self.model, self.config, num_blocks=num_blocks,
-            block_size=block_size, max_lanes=max_lanes,
-            max_seq_len=max_seq_len, prefix_cache=prefix_cache,
-            # (a sliding kind reserves by how far a lane is written past
-            # its committed length: the longest slice, one step ahead)
-            ahead=2 * max(prefill_chunk, 1 + int(spec_k)))
+        with spans.span("engine", "pools", pin=True):
+            self.cache = PagedKVCache.for_model(
+                self.model, self.config, num_blocks=num_blocks,
+                block_size=block_size, max_lanes=max_lanes,
+                max_seq_len=max_seq_len, prefix_cache=prefix_cache,
+                # (a sliding kind reserves by how far a lane is written
+                # past its committed length: the longest slice, one step
+                # ahead)
+                ahead=2 * max(prefill_chunk, 1 + int(spec_k)))
+            jax.block_until_ready(self.cache.step_pools)
         if kv_tier is None:
             from ray_tpu._private.config import GLOBAL_CONFIG
             kv_tier = bool(GLOBAL_CONFIG.kv_tier)
@@ -589,7 +648,7 @@ class InferenceEngine:
         self._cold = False
         self._step_impls: Dict = {}   # un-jitted twins (shape introspection)
         self._step_avals: Dict = {}   # argument shapes of each step's compile
-        self._step_compile_s: Dict = {}   # wall of each step's first call
+        self._step_made: Dict = {}    # each step's first call (`_making`)
         self._evictions_reported = 0
         # Cumulative step accounting (stats()): what the engine thread did
         # with its time, by phase, and how long admitted requests queued.
@@ -618,6 +677,7 @@ class InferenceEngine:
         self._timeline: "collections.deque[list]" = collections.deque(
             maxlen=_TIMELINE_ROWS)
         self._second = [0, self._sums(), 0.0, ""]
+        self._compile_base = compile_cache.sums()     # for `engine/stall`
         if _gc_hook not in gc.callbacks:
             gc.callbacks.append(_gc_hook)
         self._admitted = 0
@@ -991,8 +1051,11 @@ class InferenceEngine:
             # left to dispatch).  `overrun_tokens`: sampled for a request
             # that had ended by the time they were fetched, and discarded.
             "ahead": dict(self._ahead),
-            # This process's XLA compiles and persistent-cache loads.
+            # This process's XLA compiles and persistent-cache loads, and
+            # the seconds jax traced and lowered to ask for them.
             "compile": compile_cache.counters(),
+            # What the process did once, from its start-up record.
+            "setup": _setup(),
             # Preparations of the served weights (one at load, one per
             # update_params), their seconds, and the bytes of the tree
             # the step takes beside those of the tree given.
@@ -1053,7 +1116,10 @@ class InferenceEngine:
 
     def compiled_steps(self) -> dict:
         """What XLA built for each step shape dispatched so far: seconds
-        its first call spent compiling, the number of Mosaic kernel calls
+        its first call took (`compile_s`) and what jax says they were made
+        of (`made`: the `engine.dispatch/make_program` span's payload:
+        `trace_s`, `lower_s`, `cache_load_s`, `compile_s`, `cached`, the
+        rest of `wall_s` being the first run), the number of Mosaic kernel calls
         in the compiled program, the bytes of arguments updated in place
         (`donated_bytes`: the KV pools), the program's scratch
         (`temp_bytes`), the instructions that copy, slice out or stack
@@ -1068,11 +1134,12 @@ class InferenceEngine:
         an indexed layer chooses its rows without sorting a lane's scores).
         Recompiles each shape ahead of time (a persistent-cache hit where
         the cache is on), so call it for a check, not per request."""
-        def report(compile_s, fn, avals):
+        def report(made, fn, avals):
             compiled = fn.lower(*avals).compile()
             text, memory = compiled.as_text(), compiled.memory_analysis()
             out = {
-                "compile_s": round(compile_s, 2),
+                "compile_s": round(made["wall_s"], 2),
+                "made": {k: round(v, 3) for k, v in made.items()},
                 "custom_calls": text.count("tpu_custom_call"),
                 "donated_bytes": memory.alias_size_in_bytes,
                 "temp_bytes": memory.temp_size_in_bytes,
@@ -1087,22 +1154,22 @@ class InferenceEngine:
             return text, out
 
         out = {}
-        # _step_compile_s is filled last, so its keys are complete steps
+        # _step_made is filled last, so its keys are complete steps
         # even while the scheduler thread is adding a new shape.
-        for key, compile_s in list(self._step_compile_s.items()):
+        for key, made in list(self._step_made.items()):
             t, sample, spec, compact = key
             name = f"t{t}" + ("_sample" if sample else "") \
                 + ("_spec" if spec else "") \
                 + (f"_lanes{compact}" if compact else "")
-            text, out[name] = report(compile_s, self._step_fns[key],
+            text, out[name] = report(made, self._step_fns[key],
                                      self._step_avals[key])
             if self._stateful:
                 # The state buffer too is updated where it is.
                 out[name]["state_copies"] = count_pool_copies(
                     text, self.cache.buffers[0].shape)
-        if "compile_s" in self._compact:
+        if "made" in self._compact:
             _, out[f"compact_lanes{self.prefill_lanes}"] = report(
-                self._compact["compile_s"], self._compact["fn"],
+                self._compact["made"], self._compact["fn"],
                 self._compact["avals"])
         return out
 
@@ -1232,6 +1299,19 @@ class InferenceEngine:
                 break       # garbage proposal: verify nothing past it
             out.append(t)
         return tuple(out)
+
+    def _stalled(self, wall: float, took: dict, paused: float) -> None:
+        """An iteration held the loop over `_STALL_S`: one `engine/stall`
+        event with the phase that took most of it, the collector's pauses
+        inside it and what jax compiled, loaded, traced or lowered since
+        the open second began (`_close_second` keeps the base: at most a
+        second before the iteration did)."""
+        now = compile_cache.sums()
+        events.record(
+            "engine", "stall", phase=max(took, key=took.get),
+            wall_ms=wall * 1e3, gc_ms=paused * 1e3,
+            **{k: now[k] - v for k, v in self._compile_base.items()})
+        self._compile_base = now
 
     def step(self) -> bool:
         """One scheduler iteration, one step ahead of its own results:
@@ -1421,6 +1501,8 @@ class InferenceEngine:
                 self._close_second(now)
             if wall * 1e3 > self._second[2]:
                 self._second[2:] = wall * 1e3, max(took, key=took.get)
+            if wall > _STALL_S:
+                self._stalled(wall, took, paused)
             self._steps += 1
             self._prefill_steps += bool(prefill)
             self._step_wall_s += wall
@@ -1487,6 +1569,7 @@ class InferenceEngine:
         if sums[0] > self._second[1][0]:
             self._timeline.append(self._row_since(self._second, sums))
         self._second = [now, sums, 0.0, ""]
+        self._compile_base = compile_cache.sums()
 
     def _timeline_rows(self) -> list:
         """The closed rows and the open one (for `stats()`, from any
@@ -1716,7 +1799,8 @@ class InferenceEngine:
         moe = () if self._moe_load is None else (self._moe_load,)
         carried = (self._last_tok, *moe)
         if first:
-            t0 = time.perf_counter()
+            making = contextlib.ExitStack()
+            made = making.enter_context(_making(key))
             fn = self._step_fns[key] = self._make_entry(*key)
             self._step_avals[key] = jax.tree.map(
                 lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype),
@@ -1741,9 +1825,9 @@ class InferenceEngine:
         if logp is not None:
             logp.copy_to_host_async()
         if first:
-            # The first call of a shape returns once it has compiled (the
-            # dispatch itself is asynchronous): its wall is compile time.
-            self._step_compile_s[key] = time.perf_counter() - t0
+            # The first call of a shape returns once it has been traced,
+            # lowered and compiled or loaded (the dispatch itself is
+            # asynchronous): `_making`'s span has how long each took.
             # What tracing and compiling (or loading) a program leaves on
             # the heap lives as long as the process: out of the collector's
             # way with it, and with everything else this old.  A full
@@ -1755,6 +1839,8 @@ class InferenceEngine:
             # over what is left (PERF.md section 6, PR 32).
             gc.collect()
             gc.freeze()
+            making.close()
+            self._step_made[key] = made
         self.cache.update_pools(k, v)
         self._cold = self._cold or first
         if first and compact and not spec:
@@ -1959,16 +2045,17 @@ class InferenceEngine:
             args = (self._served, self.cache.k, self.cache.v,
                     jnp.asarray(src), jnp.asarray(dst),
                     jnp.asarray(np.arange(n) < len(group)))
-            first = not self._compact
-            if first:
-                t0 = time.perf_counter()
-                self._compact = {
-                    "fn": self._make_compact_fn(),
-                    "avals": jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-                        x.shape, x.dtype), args)}
-            self.cache.update_pools(*self._compact["fn"](*args))
-            if first:
-                self._compact["compile_s"] = time.perf_counter() - t0
+            if not self._compact:
+                with _making(("compact", self.prefill_lanes)) as made:
+                    self._compact = {
+                        "fn": self._make_compact_fn(),
+                        "avals": jax.tree.map(
+                            lambda x: jax.ShapeDtypeStruct(
+                                x.shape, x.dtype), args)}
+                    self.cache.update_pools(*self._compact["fn"](*args))
+                self._compact["made"] = made
+            else:
+                self.cache.update_pools(*self._compact["fn"](*args))
             spans.end(tok, lanes=len(group))
 
     def _make_compact_fn(self):

@@ -809,7 +809,8 @@ def main(argv=None) -> int:
             q.add_argument("--out", default="ray_tpu_timeline.json")
             q.add_argument("--events", action="store_true",
                            help="merge flight-recorder events as "
-                                "instant events")
+                                "instant events, and the start-up "
+                                "records' rows as intervals")
         q.set_defaults(fn=fn)
 
     q = sub.add_parser("events",

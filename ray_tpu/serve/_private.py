@@ -764,19 +764,26 @@ class ServeController:
                     vers.pop(r._actor_id.binary(), None)
             opts = dict(config.ray_actor_options)
             started = []
+            # From the decision to start replicas to their first healthy
+            # answers (or the failure of one): in the start-up record.
+            tok_start = (spans.begin("serve", "replica_start", pin=True,
+                                     name=name, n=config.num_replicas
+                                     - len(replicas))
+                         if len(replicas) < config.num_replicas else None)
             while len(replicas) + len(started) < config.num_replicas:
-                actor = ReplicaActor.options(
-                    num_cpus=opts.get("num_cpus", 0.1),
-                    num_tpus=default_num_tpus(cls_or_fn,
-                                              opts.get("num_tpus")),
-                    resources=opts.get("resources"),
-                    max_restarts=2,
-                    # Replicas must execute up to max_concurrent_queries
-                    # requests at once, or @serve.batch could never
-                    # accumulate a batch.
-                    max_concurrency=config.max_concurrent_queries,
-                ).remote(cls_or_fn, args, kwargs, config.user_config,
-                         config.max_concurrent_queries)
+                with spans.under(tok_start):
+                    actor = ReplicaActor.options(
+                        num_cpus=opts.get("num_cpus", 0.1),
+                        num_tpus=default_num_tpus(cls_or_fn,
+                                                  opts.get("num_tpus")),
+                        resources=opts.get("resources"),
+                        max_restarts=2,
+                        # Replicas must execute up to
+                        # max_concurrent_queries requests at once, or
+                        # @serve.batch could never accumulate a batch.
+                        max_concurrency=config.max_concurrent_queries,
+                    ).remote(cls_or_fn, args, kwargs, config.user_config,
+                             config.max_concurrent_queries)
                 started.append(actor)
                 vers[actor._actor_id.binary()] = def_version
             while len(replicas) > config.num_replicas:
@@ -804,12 +811,14 @@ class ServeController:
                 # still constructing — must not linger holding a chip
                 # lease, and serve.run's caller gets the reason.
                 _kill_quietly(started)
+                spans.end(tok_start, failed=True)
                 why = (f"no answer within 120 s of construction "
                        f"({type(e).__name__}); its worker's log has the rest"
                        if isinstance(e, GetTimeoutError) else str(e))
                 raise RuntimeError(
                     f"deployment {name!r}: replica failed to start: "
                     f"{why}") from e
+            spans.end(tok_start)
             replicas.extend(started)
             with self._lock:
                 entry = self._deployments.get(name)

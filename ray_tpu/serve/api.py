@@ -8,6 +8,7 @@ import functools
 from typing import Any, Callable, Optional
 
 import ray_tpu
+from ray_tpu.util import spans, tracing
 from ray_tpu.serve._private import (
     CONTROLLER_NAME, SERVE_NAMESPACE, AutoscalingConfig, DeploymentConfig,
     DeploymentHandle, ServeController)
@@ -19,13 +20,18 @@ def _get_or_start_controller():
     try:
         controller = ray_tpu.get_actor(CONTROLLER_NAME, SERVE_NAMESPACE)
     except ValueError:
-        controller = ServeController.options(
-            name=CONTROLLER_NAME, namespace=SERVE_NAMESPACE,
-            lifetime="detached", num_cpus=0.1,
-            get_if_exists=True).remote()
+        # (once a session: in the start-up record, until it answers)
+        with spans.span("serve", "controller_start", pin=True):
+            controller = ServeController.options(
+                name=CONTROLLER_NAME, namespace=SERVE_NAMESPACE,
+                lifetime="detached", num_cpus=0.1,
+                get_if_exists=True).remote()
+            ray_tpu.get(controller.list_deployments.remote(), timeout=120)
         # Fire-and-forget: the autoscaling/reconciliation loop runs on one
-        # of the threaded controller's pool threads (idempotent).
-        controller.run_control_loop.remote()
+        # of the threaded controller's pool threads (idempotent), for the
+        # controller's life: in no trace, whoever's call started it.
+        with tracing.untraced():
+            controller.run_control_loop.remote()
     return controller
 
 
@@ -130,9 +136,12 @@ def run(target: Application, *, _blocking: bool = False) -> DeploymentHandle:
     """Deploy an application graph; returns the ingress handle
     (reference: serve/api.py serve.run).  Bound arguments that are
     themselves Applications deploy first and are passed as handles —
-    the deployment-graph composition path."""
-    controller = _get_or_start_controller()
+    the deployment-graph composition path.
 
+    One `serve/run` span in the start-up record, with the controller's
+    start and each `serve/deploy` (the call that returns when the
+    deployment's replicas are RUNNING) as children; what the controller
+    and the replicas do meanwhile hangs off these in their own records."""
     def deploy_app(app: Application) -> DeploymentHandle:
         resolved_args = tuple(
             deploy_app(a) if isinstance(a, Application) else a
@@ -141,12 +150,15 @@ def run(target: Application, *, _blocking: bool = False) -> DeploymentHandle:
             k: deploy_app(v) if isinstance(v, Application) else v
             for k, v in app.kwargs.items()}
         dep = app.deployment
-        ray_tpu.get(controller.deploy.remote(
-            dep._config, dep._cls_or_fn, resolved_args, resolved_kwargs),
-            timeout=300)
+        with spans.span("serve", "deploy", pin=True, name=dep.name):
+            ray_tpu.get(controller.deploy.remote(
+                dep._config, dep._cls_or_fn, resolved_args, resolved_kwargs),
+                timeout=300)
         return DeploymentHandle(dep.name)
 
-    return deploy_app(target)
+    with spans.span("serve", "run", pin=True):
+        controller = _get_or_start_controller()
+        return deploy_app(target)
 
 
 def get_deployment_handle(name: str) -> DeploymentHandle:

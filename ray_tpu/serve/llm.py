@@ -131,7 +131,10 @@ class LLMReplica:
                  spec_k: Optional[int] = None, draft_proposer="ngram",
                  kv_tier: Optional[bool] = None):
         from ray_tpu._private.config import GLOBAL_CONFIG
-        from ray_tpu.inference import InferenceEngine  # jax: replica-only
+        # jax, and what the engine imports with it: replica-only, once a
+        # process, so in the start-up record.
+        with spans.span("proc", "jax_import", pin=True):
+            from ray_tpu.inference import InferenceEngine
         # `speculative=True` opts the replica into speculative decoding;
         # the draft length defaults to the cluster-wide `spec_k` config
         # knob unless pinned per deployment.

@@ -11,7 +11,10 @@ address (the CLI's mode).
 from __future__ import annotations
 
 import asyncio
+import logging
 from typing import Any, Dict, List, Optional
+
+_logger = logging.getLogger("ray_tpu.state")
 
 
 def _run(coro):
@@ -154,15 +157,24 @@ def list_tasks(address: Optional[str] = None,
 _SKEW_SLACK_S = 300.0
 
 
+def _clock_offset(reply: Dict[str, Any], t0: float, t1: float) -> float:
+    """What to add to a remote timestamp to put it on the caller's clock,
+    from one call sent at `t0` and answered at `t1`: the remote stamped
+    `recv` when the call arrived and `now` when it answered (NTP's four
+    instants; a reply with `now` alone is taken to have been handled in
+    no time, at the call's midpoint)."""
+    mid = (t0 + t1) / 2.0
+    now = reply.get("now", mid)
+    return mid - (reply.get("recv", now) + now) / 2.0
+
+
 def _normalize_events_reply(reply: Dict[str, Any], node_id: str,
                             t0: float, t1: float) -> List[Dict[str, Any]]:
     """Put one node's CollectEvents reply on the caller's clock.
 
-    The RPC midpoint approximates the remote `now` locally, so
-    ``ts_adj = ts + (local_midpoint - remote_now)`` (NTP-grade, good
-    enough to order cross-node decision sequences)."""
-    mid = (t0 + t1) / 2.0
-    offset = mid - reply.get("now", mid)
+    ``ts_adj = ts + offset`` with `_clock_offset`'s estimate (NTP-grade,
+    good enough to order cross-node decision sequences)."""
+    offset = _clock_offset(reply, t0, t1)
     out = []
     for e in reply.get("events", []):
         e = dict(e)
@@ -276,6 +288,107 @@ def events(address: Optional[str] = None, *, plane: Optional[str] = None,
             for e in ev.snapshot(since=pre_since)])
     return _merge_event_streams(streams, plane=plane, kind=kind,
                                 trace_id=trace_id, since=since)
+
+
+# ---------------------------------------------------------------------------
+# The start-up timeline: every process's start-up record, merged
+# ---------------------------------------------------------------------------
+
+_last_startup_records: list = []      # of the last session (`shutdown`)
+
+
+def _timeline_rows(records) -> List[Dict[str, Any]]:
+    """(node_id, clock offset, `events.pinned()` record) triples -> rows,
+    a process once (its first record), sorted by start."""
+    rows, seen = [], set()
+    for node_id, offset, record in records:
+        if record["pid"] in seen:
+            continue
+        seen.add(record["pid"])
+        rows += [dict(row, pid=record["pid"], role=record["role"],
+                      node_id=node_id, proc_start=record["start"] + offset,
+                      start=row["start"] + offset)
+                 for row in record["rows"]]
+    rows.sort(key=lambda r: (r["start"], -r["dur"]))
+    return rows
+
+
+def _collect_startup_records(address: str, timeout: float = 4.0) -> list:
+    """The caller's own record, then one CollectEvents call a node (hostd
+    answers for its live workers and for the dumps of those that ended)
+    and one to the GCS, each under `timeout`.  A node that does not answer
+    costs its rows and nothing else."""
+    import time as _time
+
+    async def _collect():
+        from ray_tpu._private.rpc import RpcClient
+        nodes = (await _gcs_call(address, "get_nodes"))["nodes"]
+        calls = [(n.node_id.hex(), n.address, "NodeManager", "CollectEvents")
+                 for n in nodes if n.alive]
+        calls.append(("gcs", address, "Gcs", "collect_events"))
+        records = []
+        for node_id, addr, service, method in calls:
+            client = RpcClient(addr)
+            try:
+                t0 = _time.time()
+                reply = await client.call(
+                    service, method, {"since": 1e18, "timeout": timeout / 2},
+                    timeout=timeout)
+                offset = _clock_offset(reply, t0, _time.time())
+                records += [(node_id, offset, r)
+                            for r in reply.get("pinned") or []]
+            except Exception as e:
+                _logger.warning("no start-up records from %s: %r",
+                                node_id, e)
+            finally:
+                await client.close()
+        return records
+
+    from ray_tpu.util import events as ev
+    records = [("driver", 0.0, ev.pinned())]
+    try:
+        records += _run(asyncio.wait_for(_collect(), 3 * timeout))
+    except Exception as e:
+        _logger.warning("start-up records not collected: %r", e)
+    return records
+
+
+def _keep_startup_timeline(logs_dir: Optional[str] = None) -> None:
+    """`ray_tpu.shutdown()` calls this twice: before it tears anything
+    down (every node asked once), and, of a cluster it owned, after, with
+    the session's log directory: whoever was not heard from the first time
+    has left its dump there on its way out."""
+    global _last_startup_records
+    from ray_tpu import api
+    from ray_tpu.util import events as ev
+    if logs_dir is None:
+        if not getattr(api._worker, "gcs_address", None):
+            return
+        _last_startup_records = _collect_startup_records(
+            api._worker.gcs_address)
+    else:
+        _last_startup_records += [
+            ("dump", 0.0, r)
+            for r in ev.dumped_records(
+                ev.read_dumps(logs_dir, pinned_only=True))]
+
+
+def startup_timeline(address: Optional[str] = None) -> List[Dict[str, Any]]:
+    """What every process of the session did once, between its start and
+    its first dispatch: the rows of the start-up records (`events.pinned`:
+    spans closed with ``pin=True``, and programs that cost 0.1 s to make)
+    of the driver, the GCS, every hostd and every worker, those that have
+    ended included (their exit dumps), sorted by start.  Each row:
+    ``pid, role, proc_start, node_id, plane, kind, start, dur, sid, parent,
+    trace_id, payload``, times on the caller's ``time.time()``.
+
+    Connected (or with ``address=``): collected now.  After
+    ``ray_tpu.shutdown()``: the last session's, collected by the shutdown
+    before it tore anything down."""
+    from ray_tpu import api
+    if address is None and api._worker is None:
+        return _timeline_rows(_last_startup_records)
+    return _timeline_rows(_collect_startup_records(_gcs_address(address)))
 
 
 # ---------------------------------------------------------------------------
@@ -521,7 +634,9 @@ def timeline(address: Optional[str] = None,
     """Chrome trace events (chrome://tracing / perfetto 'X' phases) —
     reference: `ray timeline` scripts.py:1840.  With `include_events`
     the flight-recorder stream is merged in as instant events, so one
-    trace shows tasks AND the runtime decisions around them."""
+    trace shows tasks AND the runtime decisions around them, and the
+    start-up records' rows (`startup_timeline`) as intervals beside them:
+    what each process did once, however long ago the ring lost it."""
     task_events = list_tasks(address)
     out = []
     for e in task_events:
@@ -537,7 +652,21 @@ def timeline(address: Optional[str] = None,
                      "actor_id": e.get("actor_id")},
         })
     if include_events:
+        for r in startup_timeline(address):
+            out.append({
+                "name": f'{r["plane"]}:{r["kind"]}',
+                "cat": f'startup:{r["plane"]}',
+                "ph": "X",
+                "ts": r["start"] * 1e6,
+                "dur": max(r["dur"], 1e-6) * 1e6,
+                "pid": f'{r.get("node_id", "")}:{r["pid"]}',
+                "tid": "startup",
+                "args": {"payload": r["payload"], "span_id": r["sid"],
+                         "parent": r["parent"], "role": r["role"]},
+            })
         for e in events(address):
+            if e.get("pinned"):
+                continue        # (a dead process's rows: drawn above)
             out.append({
                 "name": f'{e["plane"]}:{e["kind"]}',
                 "cat": f'event:{e["plane"]}',

@@ -76,7 +76,14 @@ def _coordinator_host() -> str:
 def _init_jax_distributed(coordinator: Optional[str], num_processes: int,
                           process_id: int, env: dict):
     os.environ.update({k: str(v) for k, v in env.items()})
-    import jax
+    from ray_tpu._private import compile_cache
+    from ray_tpu.util import spans
+
+    # Where a train worker first reaches jax, once a process: in the
+    # start-up record, as a replica's are.
+    with spans.span("proc", "jax_import", pin=True):
+        import jax
+    compile_cache.watch()       # before the worker's first program
 
     if "JAX_PLATFORMS" in env:
         # jax read the variable at import; a process that imported jax
@@ -87,8 +94,9 @@ def _init_jax_distributed(coordinator: Optional[str], num_processes: int,
             coordinator_address=coordinator,
             num_processes=num_processes,
             process_id=process_id)
-    return {"process_id": process_id,
-            "local_devices": len(jax.local_devices()),
+    with spans.span("proc", "backend_init", pin=True):
+        local = len(jax.local_devices())        # the client starts here
+    return {"process_id": process_id, "local_devices": local,
             "global_devices": len(jax.devices())}
 
 

@@ -35,6 +35,7 @@ from ray_tpu.exceptions import TrainHungError
 from ray_tpu.train.backend import Backend, BackendConfig
 from ray_tpu.train.session import TrainContext
 from ray_tpu.train.worker_group import WorkerGroup
+from ray_tpu.util import spans
 
 logger = logging.getLogger("ray_tpu.train")
 
@@ -105,8 +106,12 @@ class BackendExecutor:
     # ---------------- gang formation ----------------
 
     def start(self):
-        self.worker_group = self._form_gang()
-        self._backend.on_start(self.worker_group, self._backend_config)
+        # (Both once a gang: in the start-up record, under `train/fit_start`
+        # where `fit()` has it open.)
+        with spans.span("train", "form_gang", pin=True):
+            self.worker_group = self._form_gang()
+        with spans.span("train", "backend_start", pin=True):
+            self._backend.on_start(self.worker_group, self._backend_config)
         # Fresh gang: restart the capacity-probe debounce so a stale
         # pre-formation resource view can't immediately trigger a resize.
         self._last_resize_check = time.monotonic()

@@ -23,6 +23,7 @@ from ray_tpu._private.accelerators import chips_per_host
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.air.config import RunConfig, ScalingConfig
 from ray_tpu.train.backend import BackendConfig, TpuConfig
+from ray_tpu.util import spans
 from ray_tpu.train.backend_executor import (
     BackendExecutor, TrainingFailedError)
 
@@ -120,15 +121,21 @@ class DataParallelTrainer(BaseTrainer):
         last_checkpoint = self._resolve_resume(manager)
         error: Optional[BaseException] = None
 
-        executor.start()
+        # From here to the first result: one `train/fit_start` span in the
+        # start-up record; the gang's formation, the backend's start and
+        # what the workers do meanwhile hang off it.
+        fit_tok = spans.begin("train", "fit_start", pin=True)
+        with spans.under(fit_tok):
+            executor.start()
         try:
             while True:
                 # Datasets travel raw: the executor splits by the ACTUAL
                 # gang size each (re)start, so an elastic resize
                 # re-shards by the new world size (reference:
                 # DataParallelTrainer datasets= + streaming_split).
-                executor.start_training(train_fn, last_checkpoint,
-                                        self._datasets)
+                with spans.under(fit_tok):
+                    executor.start_training(train_fn, last_checkpoint,
+                                            self._datasets)
                 resized = False
                 try:
                     while True:
@@ -145,6 +152,7 @@ class DataParallelTrainer(BaseTrainer):
                             resized = True
                             break
                         results = executor.get_next_results()
+                        fit_tok = spans.end(fit_tok)    # (once: None now)
                         if results is None:
                             break
                         metrics = results[0][0]  # rank-0 metrics canonical
@@ -191,6 +199,7 @@ class DataParallelTrainer(BaseTrainer):
                             and e.__cause__ is not None) else e
                     break
         finally:
+            spans.end(fit_tok, failed=True)     # (no result ever came)
             executor.shutdown()
             if manager is not None:
                 try:

@@ -19,6 +19,7 @@ from typing import Any, Callable, Optional
 
 from ray_tpu.air.checkpoint import Checkpoint
 from ray_tpu.exceptions import TrainPreemptedError
+from ray_tpu.util import spans
 
 _session: Optional["_TrainSession"] = None
 
@@ -88,10 +89,15 @@ class _TrainSession:
         # Open train/step span between report() boundaries (always on:
         # step cadence is orders of magnitude below the ring's budget).
         self._step_span = None
+        # From the session's making to the user's loop, once a session: in
+        # the start-up record.
+        tok_start = spans.begin("train", "session_start", pin=True,
+                                rank=context.world_rank)
 
         def run():
             global _session
             _session = self
+            spans.end(tok_start)
             try:
                 train_fn()
             except StopIteration:
@@ -99,7 +105,6 @@ class _TrainSession:
             except BaseException as e:  # noqa: BLE001
                 self.error = e
             finally:
-                from ray_tpu.util import spans
                 spans.end(self._step_span, final=True)
                 self._step_span = None
                 # Sentinel BEFORE the finished flag: a concurrent get_next
@@ -135,7 +140,7 @@ class _TrainSession:
         prev_t = self._beacon_t
         self._beacon_step += 1
         self._beacon_t = time.monotonic()
-        from ray_tpu.util import events, spans
+        from ray_tpu.util import events
         events.record("train", "beacon", step=self._beacon_step,
                       rank=self.context.world_rank)
         # Durational step span: one per inter-report gap (the span for

@@ -21,6 +21,15 @@ list-item store.  Overflow overwrites the oldest slot; ``snapshot()``
 reorders by the monotonic sequence number each event carries.  With
 ``RAY_TPU_EVENTS=0`` the whole module collapses to one global read per
 ``record()`` call.
+
+Beside the ring lies the **start-up record**: at most ``PIN_ROWS`` rows
+that nothing overwrites, one per span closed with ``pin=True``
+(`spans.begin`) and one per program that cost 0.1 s to make
+(`compile_cache`).  A process's set-up happens once and the ring turns
+over within seconds of serving, so what set-up was made of is kept here:
+``pinned()`` gives the rows with the process's pid, role and start time,
+``dump()`` writes them ahead of the ring, and
+``state.startup_timeline()`` merges every process's.
 """
 
 from __future__ import annotations
@@ -95,6 +104,13 @@ class FlightRecorder:
 # Process-global recorder
 # ---------------------------------------------------------------------------
 
+PIN_ROWS = 256
+# What this process is, for the readers of its start-up record: "driver"
+# unless its entry point says otherwise ("worker", "hostd", "gcs").
+role = "driver"
+_pinned: List[Dict[str, Any]] = []
+_T_IMPORT = time.time()
+
 _recorder: Optional[FlightRecorder] = None
 _initialized = False
 _init_lock = threading.Lock()
@@ -129,6 +145,47 @@ def record(plane: str, kind: str,
     r.append(plane, kind, payload or None, trace)
 
 
+def pin(plane: str, kind: str, start: float, dur: float,
+        sid: Optional[str] = None, parent: Optional[str] = None,
+        trace_id: Optional[str] = None,
+        payload: Optional[Dict[str, Any]] = None) -> None:
+    """Keep one closed interval in the start-up record: `start` on
+    ``time.time()``, `dur` in seconds.  The first ``PIN_ROWS`` rows stay
+    and later ones are dropped (set-up comes first; nothing is ever
+    overwritten).  Nothing is kept while the recorder is off."""
+    if len(_pinned) < PIN_ROWS and enabled():
+        _pinned.append({"plane": plane, "kind": kind, "start": start,
+                        "dur": dur, "sid": sid, "parent": parent,
+                        "trace_id": trace_id, "payload": payload})
+
+
+def _process_start() -> float:
+    """When this process began, on ``time.time()``: the kernel's start
+    time of the process (field 22 of ``/proc/self/stat``, in clock ticks
+    since boot, against ``CLOCK_BOOTTIME``), which for a worker forked
+    from the zygote is the instant of its fork and for any other the
+    instant of its exec: one file read, no argument handed down.  Where
+    /proc cannot be read, the instant this module was imported."""
+    try:
+        with open("/proc/self/stat") as f:
+            ticks = int(f.read().rsplit(")", 1)[1].split()[19])
+        age = (time.clock_gettime(time.CLOCK_BOOTTIME)
+               - ticks / os.sysconf("SC_CLK_TCK"))
+        if age >= 0.0:
+            return time.time() - age
+    except (OSError, ValueError, IndexError, AttributeError):
+        pass
+    return _T_IMPORT
+
+
+def pinned() -> Dict[str, Any]:
+    """This process's start-up record: ``{"pid", "role", "start",
+    "rows"}``, each row ``{plane, kind, start, dur, sid, parent, trace_id,
+    payload}`` in the order the intervals closed."""
+    return {"pid": os.getpid(), "role": role, "start": _process_start(),
+            "rows": [dict(row) for row in list(_pinned)]}
+
+
 def enabled() -> bool:
     if not _initialized:
         _init()
@@ -159,6 +216,7 @@ def reset() -> None:
     with _init_lock:
         _recorder = None
         _initialized = False
+        del _pinned[:]
 
 
 # ---------------------------------------------------------------------------
@@ -185,20 +243,27 @@ def _incarnation() -> str:
 
 
 def dump(path: str, reason: str = "") -> Optional[str]:
-    """Write the ring to `path` as jsonl, atomically (tmp + fsync +
-    rename): a reader either sees the whole dump or no file.  Returns
-    the path, or None when the recorder is off/empty."""
+    """Write the start-up record and then the ring to `path` as jsonl,
+    atomically (tmp + fsync + rename): a reader either sees the whole
+    dump or no file.  Returns the path, or None when the recorder is
+    off/empty."""
     events = snapshot()
-    if not events:
+    record = pinned()
+    if not events and not record["rows"]:
         return None
     header = {"_flightrec": 1, "pid": os.getpid(),
               "incarnation": _incarnation(), "reason": reason,
-              "wall_time": time.time()}
-    tmp = f"{path}.tmp.{os.getpid()}"
+              "wall_time": time.time(), "role": role,
+              "start": record["start"]}
+    # (two threads of a dying process may both dump: a file each)
+    tmp = f"{path}.tmp.{os.getpid()}.{threading.get_ident()}"
     try:
         os.makedirs(os.path.dirname(path) or ".", exist_ok=True)
         with open(tmp, "w") as f:
             f.write(json.dumps(header) + "\n")
+            for row in record["rows"]:
+                f.write(json.dumps(dict(row, pinned=True), default=repr)
+                        + "\n")
             for e in events:
                 f.write(json.dumps(e, default=repr) + "\n")
             f.flush()
@@ -226,11 +291,29 @@ def dump_crash(reason: str) -> Optional[str]:
         return None
 
 
-def read_dumps(directory: str) -> List[Dict[str, Any]]:
+def dumped_records(dumped: List[Dict[str, Any]]) -> List[Dict[str, Any]]:
+    """The start-up records (as `pinned()` gives them) of the processes
+    whose dumps `read_dumps` read into `dumped`."""
+    records: Dict[Any, Dict[str, Any]] = {}
+    for e in dumped:
+        if e.get("pinned"):
+            records.setdefault(e["pid"], {
+                "pid": e["pid"], "role": e["role"],
+                "start": e["proc_start"], "rows": []},
+            )["rows"].append(e["pinned"])
+    return list(records.values())
+
+
+def read_dumps(directory: str,
+               pinned_only: bool = False) -> List[Dict[str, Any]]:
     """Parse every flightrec-*.jsonl in `directory`; each event gains
-    ``pid``, ``source="crash"``, and the dump's ``reason``.  Corrupt or
-    half-written files are skipped (dumps are atomic, but the directory
-    may hold unrelated debris)."""
+    ``pid``, ``source="crash"``, and the dump's ``reason``.  A row of
+    the start-up record comes back under an event's keys (``ts`` its
+    start, ``span_id`` its sid, no ``seq``) with the row itself as
+    ``pinned`` and the process's ``role`` and ``proc_start`` beside it;
+    with `pinned_only` a file is read no further than those rows, which
+    stand at its head.  Corrupt or half-written files are skipped (dumps
+    are atomic, but the directory may hold unrelated debris)."""
     out: List[Dict[str, Any]] = []
     try:
         names = sorted(os.listdir(directory))
@@ -241,16 +324,24 @@ def read_dumps(directory: str) -> List[Dict[str, Any]]:
             continue
         try:
             with open(os.path.join(directory, name)) as f:
-                lines = f.read().splitlines()
-            header = json.loads(lines[0]) if lines else {}
-            if header.get("_flightrec") != 1:
-                continue
-            for line in lines[1:]:
-                e = json.loads(line)
-                e["pid"] = header.get("pid")
-                e["source"] = "crash"
-                e["reason"] = header.get("reason")
-                out.append(e)
+                header = json.loads(next(f, "") or "{}")
+                if header.get("_flightrec") != 1:
+                    continue
+                for line in f:
+                    e = json.loads(line)
+                    if e.pop("pinned", False):
+                        e = {"ts": e["start"], "plane": e["plane"],
+                             "kind": e["kind"], "trace_id": e["trace_id"],
+                             "span_id": e["sid"], "payload": e["payload"],
+                             "seq": None, "pinned": e,
+                             "role": header.get("role"),
+                             "proc_start": header.get("start")}
+                    elif pinned_only:
+                        break
+                    e["pid"] = header.get("pid")
+                    e["source"] = "crash"
+                    e["reason"] = header.get("reason")
+                    out.append(e)
         except Exception:
             continue
     return out

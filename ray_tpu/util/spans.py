@@ -33,6 +33,13 @@ Usage:
     with spans.span("ingest", "h2d"):        # context form; nested spans
         device_put(batch)                    # become children via tracing
 
+``pin=True`` on either form keeps the closed span in the process's
+start-up record too (`events.pin`: one row with its start, duration, ids
+and both payloads, which the ring's turning over cannot lose).  It is for
+what happens once between a process's start and its first dispatch.  A
+pinned span always belongs to a trace (it opens one where none is
+active), so that what it causes in other processes hangs off it.
+
 A **phase** is the third form, for intervals too frequent for two ring
 slots (the engine's step has five, a dozen times a second):
 
@@ -51,6 +58,7 @@ from __future__ import annotations
 
 import contextlib
 import itertools
+import os
 import secrets
 import sys
 import time
@@ -66,31 +74,44 @@ _PREFIX = secrets.token_hex(3)
 _SEQ = itertools.count()
 
 
+def _new_prefix() -> None:
+    global _PREFIX
+    _PREFIX = secrets.token_hex(3)
+
+
+# A worker forked from the zygote must not mint its template's ids.
+os.register_at_fork(after_in_child=_new_prefix)
+
+
 class Span:
     """Token returned by :func:`begin`; pass it to :func:`end`."""
 
-    __slots__ = ("plane", "kind", "trace_id", "sid", "t0")
+    __slots__ = ("plane", "kind", "trace_id", "sid", "t0", "pin")
 
     def __init__(self, plane: str, kind: str, trace_id: Optional[str],
-                 sid: str, t0: float):
+                 sid: str, t0: float, pin: Optional[tuple] = None):
         self.plane = plane
         self.kind = kind
         self.trace_id = trace_id
         self.sid = sid
         self.t0 = t0
+        self.pin = pin      # (start on time.time(), parent, begin payload)
 
     def __repr__(self):  # pragma: no cover - debugging aid
         return f"Span({self.plane}:{self.kind} sid={self.sid})"
 
 
-def _new_sid() -> str:
+def new_sid() -> str:
+    """A span id ahead of its span (`begin(sid=...)`), for a parent that
+    must name itself to a child before it can open: hostd hands a worker
+    its `sched/worker_boot` id through the child's environment."""
     return f"{_PREFIX}{next(_SEQ):x}"
 
 
 def begin(plane: str, kind: str,
           ctx: Optional[Tuple[Optional[str], Optional[str]]] = None,
           sid: Optional[str] = None, parent: Optional[str] = None,
-          **payload: Any) -> Optional[Span]:
+          pin: bool = False, **payload: Any) -> Optional[Span]:
     """Open a span.  Returns None when the recorder is off (the disabled
     fast path is one global read, same as ``events.record``).
 
@@ -111,14 +132,17 @@ def begin(plane: str, kind: str,
     trace_id = ctx[0] if ctx else None
     if parent is None and ctx is not None:
         parent = ctx[1]
-    s = sid or _new_sid()
+    if pin and trace_id is None:
+        trace_id = secrets.token_hex(8)
+    s = sid or new_sid()
     p: dict = {"ph": "B"}
     if parent is not None:
         p["parent"] = parent
     if payload:
         p.update(payload)
     r.append(plane, kind, p, (trace_id, s))
-    return Span(plane, kind, trace_id, s, time.perf_counter())
+    return Span(plane, kind, trace_id, s, time.perf_counter(),
+                (time.time(), parent, payload) if pin else None)
 
 
 def end(tok: Optional[Span], **payload: Any) -> None:
@@ -129,20 +153,22 @@ def end(tok: Optional[Span], **payload: Any) -> None:
     r = events._recorder
     if r is None:
         return
-    p: dict = {"ph": "E", "dur": time.perf_counter() - tok.t0}
+    dur = time.perf_counter() - tok.t0
+    p: dict = {"ph": "E", "dur": dur}
     if payload:
         p.update(payload)
     r.append(tok.plane, tok.kind, p, (tok.trace_id, tok.sid))
+    if tok.pin is not None:
+        start, parent, began = tok.pin
+        events.pin(tok.plane, tok.kind, start, dur, tok.sid, parent,
+                   tok.trace_id, {**began, **payload} or None)
 
 
 @contextlib.contextmanager
-def span(plane: str, kind: str,
-         ctx: Optional[Tuple[Optional[str], Optional[str]]] = None,
-         **payload: Any):
-    """Context-manager form.  While open, the span becomes the active
-    trace context (when it belongs to a trace), so nested spans and any
-    tasks submitted inside attach to it as children."""
-    tok = begin(plane, kind, ctx=ctx, **payload)
+def under(tok: Optional[Span]):
+    """Make an open span the active trace context (when it belongs to a
+    trace): spans begun inside, and tasks submitted inside, attach to it
+    as children.  The scope of `span`, for a span held as a token."""
     cv = None
     if tok is not None and tok.trace_id is not None:
         cv = tracing._ctx.set((tok.trace_id, tok.sid))
@@ -151,6 +177,20 @@ def span(plane: str, kind: str,
     finally:
         if cv is not None:
             tracing._ctx.reset(cv)
+
+
+@contextlib.contextmanager
+def span(plane: str, kind: str,
+         ctx: Optional[Tuple[Optional[str], Optional[str]]] = None,
+         pin: bool = False, **payload: Any):
+    """Context-manager form.  While open, the span becomes the active
+    trace context (when it belongs to a trace), so nested spans and any
+    tasks submitted inside attach to it as children."""
+    tok = begin(plane, kind, ctx=ctx, pin=pin, **payload)
+    try:
+        with under(tok):
+            yield tok
+    finally:
         end(tok)
 
 

@@ -65,6 +65,19 @@ def trace(name: str = "trace"):
         spans.end(tok)
 
 
+@contextlib.contextmanager
+def untraced():
+    """Leave the active trace for a scope: what is submitted inside joins
+    none.  For a fire-and-forget task that outlives the caller's trace (a
+    control loop started from inside a traced call would otherwise trace
+    every task it ever submits)."""
+    token = _ctx.set(None)
+    try:
+        yield
+    finally:
+        _ctx.reset(token)
+
+
 def enter_task(spec) -> Optional[Tuple[str, str, str]]:
     """Called by the worker when a task starts executing.  Installs the
     propagated context (so the task's own submissions become children) and

@@ -7,6 +7,7 @@ request."""
 
 import glob
 import os
+import time
 
 import pytest
 
@@ -762,3 +763,86 @@ def test_a_replica_traces_itself_and_says_when(tmp_path):
     assert end["dur"] >= end["start_s"] + end["stop_s"]
     assert {n for _, _, n in _phases_in_trace(tmp_path)} >= {
         "engine/build_batch", "engine/fetch", "engine/commit"}
+
+
+def test_stats_hold_what_the_engine_did_once():
+    """`stats()["setup"]`: the process's start-up record folded (seconds by
+    kind, the process's start), with one `make_program` a step key whose
+    parts (jax's own trace, lower, load and compile seconds) sum to no more
+    than the span: the rest is the first run."""
+    events.reset()                  # a record of this engine alone
+    t0 = time.time()
+    engine = _engine()
+    engine.generate(list(range(1, 6)), 3)          # greedy T=8 and T=1
+    setup = engine.stats()["setup"]
+    assert setup["start"] <= t0
+    assert {"backend_init", "init_params", "prepare", "pools",
+            "make_program"} <= set(setup["seconds"])
+    assert all(s >= 0.0 for s in setup["seconds"].values())
+    assert setup["seconds"]["init_params"] > 0.0
+    programs = setup["programs"]
+    assert sorted(tuple(p["key"]) for p in programs) == sorted(
+        engine._step_fns) == [(1, False, False, 0), (8, False, False, 0)]
+    for p in programs:
+        parts = sum(p[k] for k in ("trace_s", "lower_s", "cache_load_s",
+                                   "compile_s"))
+        assert 0.0 < parts <= p["dur"] and p["start"] >= t0
+        assert p["trace_s"] > 0.0 and p["lower_s"] > 0.0
+        assert p["wall_s"] == pytest.approx(p["dur"], abs=0.05)
+    assert setup["seconds"]["make_program"] == pytest.approx(
+        sum(p["dur"] for p in programs))
+    # the same split, by the step's name, where a check asks for it
+    made = {name: s["made"] for name, s in engine.compiled_steps().items()}
+    assert set(made) == {"t1", "t8"}
+    assert all(m["wall_s"] >= m["trace_s"] + m["lower_s"] > 0
+               for m in made.values())
+    # a step whose program is there adds no row
+    engine.generate(list(range(2, 9)), 4)
+    assert engine.stats()["setup"]["programs"] == programs
+
+
+def test_a_program_made_in_a_traced_slice_lies_in_the_trace(tmp_path):
+    """`engine.dispatch/make_program` is an annotation too, inside the
+    `engine/dispatch` that found the program missing."""
+    engine = _engine()
+    engine.generate(list(range(1, 6)), 2)
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        engine.generate(list(range(1, 6)), 2, temperature=0.7)  # two shapes
+    finally:
+        jax.profiler.stop_trace()
+    made = [a for a in _annotations(tmp_path, "engine.")
+            if a[2] == "engine.dispatch/make_program"]
+    assert len(made) == 2
+    dispatches = [p for p in _phases_in_trace(tmp_path)
+                  if p[2] == "engine/dispatch"]
+    for start, end, _ in made:
+        assert any(d0 <= start and end <= d1 for d0, d1, _ in dispatches)
+
+
+def test_an_iteration_held_half_a_second_leaves_one_stall(monkeypatch):
+    engine = _engine()
+    engine.generate(list(range(1, 6)), 2)
+    seq, commit = _last_seq(), engine._commit
+
+    def held(*args, **kwargs):
+        monkeypatch.setattr(engine, "_commit", commit)      # this once
+        time.sleep(0.55)
+        return commit(*args, **kwargs)
+
+    monkeypatch.setattr(engine, "_commit", held)
+    engine.generate(list(range(2, 9)), 4)
+    stalls = [e["payload"] for e in events.snapshot(plane="engine",
+                                                    kind="stall")
+              if e["seq"] > seq]
+    assert len(stalls) == 1
+    stall, = stalls
+    assert stall["phase"] == "commit" and stall["wall_ms"] > 550.0
+    assert stall["gc_ms"] >= 0.0
+    # nothing was made meanwhile: the counters' difference says so
+    assert stall["compiles"] == stall["cache_hits"] == stall["programs"] == 0
+    assert stall["trace_s"] == stall["lower_s"] == 0.0
+    steps = [e["payload"] for e in _steps_since(seq)]
+    assert sum(s["wall_ms"] > 500.0 for s in steps) == 1
