@@ -132,7 +132,7 @@ from ray_tpu.inference.compiled import (count_pool_copies,
                                         count_select_sorts,
                                         count_weight_bytes_copied)
 from ray_tpu.inference.kv_cache import PagedKVCache, chain_keys
-from ray_tpu.models.decoder import layer_counts
+from ray_tpu.models.decoder import Lanes, layer_counts
 from ray_tpu.ops.attention import paged_blocks_per_step
 from ray_tpu.util import events, spans
 from ray_tpu.util.metrics import Counter, Gauge, Histogram
@@ -204,6 +204,24 @@ _TIMELINE = ("t", "steps", "prefill_steps", "wall_s", "phase_s", "part_s",
 _TIMELINE_ROWS = 128
 # An iteration longer than this leaves one `engine/stall` event.
 _STALL_S = 0.5
+# The rows a step's products may have and still be bound by the weights
+# they read: a v5e multiplies some 240 rows of bf16 in the time it reads
+# the matrix (197 TFLOP/s over 819 GB/s), so under that many a masked row of
+# a pair's chunk costs next to nothing.  An engine that is not told its
+# `prefill_lanes` takes as many as stay under it (`_chunk_lanes`).
+_PAIR_ROWS = 256
+
+
+def _chunk_lanes(max_lanes: int, chunk: int) -> int:
+    """The chunk's lanes of an engine that names none: the largest power of
+    two that keeps max_lanes + lanes x chunk rows within `_PAIR_ROWS`, at
+    least one, at most every lane."""
+    n = 1
+    while 2 * n <= max_lanes and max_lanes + 2 * n * chunk <= _PAIR_ROWS:
+        n *= 2
+    return n
+
+
 # The thread's CPU clock is read in one iteration of this many on average,
 # drawn and not counted off, so that no rhythm of the traffic falls in step
 # with it: the clock is a system call (5.5 us alone and 16 us in a serving
@@ -451,15 +469,17 @@ class GenerationHandle:
         return list(self._req.logps)
 
 
-def _lane_views(n: int, t: int, compact: bool, max_lanes: int):
+def _lane_views(n: int, t: int, compact: bool, max_lanes: int, out=None):
     """A population's lane arrays as views of ONE int32 buffer [n, 3 t + 5]
     (a lane a row: `t` columns each of `tokens`, `positions` and `valid`,
     then `ctx_lens`, `gather`, `temps`, `seeds`, `counters`, and a compact
     program's `rows` last), so that they reach the device in one transfer.
     `temps` (float32) and `seeds` (uint32) lie there by their bits, `valid`
     as 0 / 1.  Nobody's lanes: masked, context 1, sampling nothing, no
-    row's lane.  Returns (buffer, the eight views, `rows` or None)."""
-    lanes = np.zeros((n, 3 * t + 5 + compact), np.int32)
+    row's lane.  `out`: the zeroed buffer to lay them in (a pair's part of
+    the pair's).  Returns (buffer, the eight views, `rows` or None)."""
+    lanes = np.zeros((n, 3 * t + 5 + compact), np.int32) if out is None \
+        else out
     tokens, positions, valid = (lanes[:, i * t:(i + 1) * t] for i in range(3))
     ctx_lens, gather, temps, seeds, counters = (
         lanes[:, 3 * t + i] for i in range(5))
@@ -472,6 +492,19 @@ def _lane_views(n: int, t: int, compact: bool, max_lanes: int):
     return lanes, (tokens, positions, valid, ctx_lens, gather,
                    temps.view(np.float32), seeds.view(np.uint32),
                    counters), rows
+
+
+def _pair_views(max_lanes: int, n: int, t: int):
+    """The buffer of a pair's program, flat: the decoding lanes' [max_lanes,
+    8] (`_lane_views` at one position) and behind it the chunk's compact
+    [n, 3 t + 6], both populations in ONE transfer.  Returns (buffer, the
+    decode part's `_lane_views`, the chunk's)."""
+    cut = max_lanes * 8
+    flat = np.zeros((cut + n * (3 * t + 6),), np.int32)
+    return (flat,
+            _lane_views(max_lanes, 1, False, max_lanes,
+                        flat[:cut].reshape(max_lanes, 8)),
+            _lane_views(n, t, True, max_lanes, flat[cut:].reshape(n, -1)))
 
 
 def _unpack_lanes(lanes, t: int) -> tuple:
@@ -487,10 +520,15 @@ def _unpack_lanes(lanes, t: int) -> tuple:
 
 
 def _by_lane(per_row, rows, max_lanes: int):
-    """A compact program's per-row results [rows, T] as [max_lanes, T]."""
+    """A compact program's per-row results [rows, T] as [max_lanes, T]; a
+    pair's [max_lanes + rows, T] likewise: the decoding lanes' own rows
+    first, then the chunk's, each given to its lane (a lane is in one of
+    the two)."""
     out = np.zeros((max_lanes,) + per_row.shape[1:], per_row.dtype)
+    own = len(per_row) - len(rows)
+    out[:own] = per_row[:own]
     held = rows < max_lanes
-    out[rows[held]] = per_row[held]
+    out[rows[held]] = per_row[own:][held]
     return out
 
 
@@ -520,18 +558,29 @@ class InferenceEngine:
     full acceptance) so incompressible streams stop paying rejected
     verify FLOPs.
 
-    `prefill_lanes` < max_lanes makes the T=prefill_chunk program one
-    [prefill_lanes, T] batch of the lanes that prefill (the oldest
-    requests first; further prefilling lanes wait a step), gathered from
-    and scattered to their lanes by index, where by default it computes
-    [max_lanes, T] rows whether a lane prefills or not: with many lanes
-    and a long chunk those rows are most of the program.  Such an engine
-    also has the program at a quarter of T, for steps in which no lane
-    has more than that left to feed (`_prefill_len`), and each of the two
-    at ONE row, for steps in which one lane prefills (an admission behind
-    a cached head is alone in its program more often than not, and three
-    rows of four were padding): four programs, a T's second made behind
-    its first (`_warm_widths`) once nobody waits on it.
+    The lanes that prefill are one compact [prefill_lanes, T] batch (the
+    oldest requests first; further prefilling lanes wait a step), gathered
+    from and scattered to their lanes by index, and they RIDE the decoding
+    lanes' step: an iteration that admits dispatches ONE program (the
+    pair's: `_make_step_fn`), in which every row-wise product of a layer
+    runs once over the [max_lanes, 1] rows and the [prefill_lanes, T] rows
+    laid end to end, so that it reads the weights once where a T=1 program
+    and a prefill program behind it read them twice (PERF.md section 6,
+    PR 53).  An iteration in which nobody prefills runs the T=1 program
+    alone; one in which nobody decodes the pair's with its decode rows
+    masked.  An engine with a draft proposer keeps the two populations in
+    two programs, the decoding lanes' first.  `prefill_lanes` not named is
+    as many lanes as keep the pair's rows (max_lanes + prefill_lanes x T)
+    under `_PAIR_ROWS`, where a product is still bound by the weights it
+    reads and a masked row costs next to nothing, a power of two.  An
+    engine whose `prefill_lanes` is NAMED, under max_lanes, says that its
+    chunks are long (rows of them are compute): it also has the program at
+    a quarter of T, for steps in which no lane has more than that left to
+    feed (`_prefill_len`), and each of the two at ONE row, for steps in
+    which one lane prefills (an admission behind a cached head is alone in
+    its program more often than not, and three rows of four were padding):
+    four programs, a T's second made behind its first (`_warm_widths`)
+    once nobody waits on it.
 
     Whoever waits for a token waits for at most ONE program to be made (a
     program of a cache of several kinds of layer takes 27 s on a v5e's
@@ -591,7 +640,11 @@ class InferenceEngine:
                           if n_experts else None)
         self.max_lanes = max_lanes
         self.prefill_chunk = prefill_chunk
-        self.prefill_lanes = min(prefill_lanes or max_lanes, max_lanes)
+        # (named under max_lanes: the quarter-T form and the one-row width)
+        self._widths = bool(prefill_lanes) and prefill_lanes < max_lanes
+        self.prefill_lanes = min(
+            prefill_lanes or _chunk_lanes(max_lanes, prefill_chunk),
+            max_lanes)
         self.seed = seed
         max_seq_len = min(max_seq_len or self.config.max_seq_len,
                           self.config.max_seq_len)
@@ -622,6 +675,8 @@ class InferenceEngine:
             self._proposer = resolve_draft_proposer(draft_proposer)
         else:
             self._proposer = None
+        # Whether the prefilling lanes ride the decoding lanes' step.
+        self._pairs = self._proposer is None
         self._spec_stats = {"drafted": 0, "accepted": 0, "emitted": 0,
                             "steps": 0, "bursts": 0}
         # RL rollout support: per-token behavior log-prob capture (the
@@ -690,6 +745,11 @@ class InferenceEngine:
         # decode kernel found context in (it visits no others).
         self._prefill = {"steps": 0, "lanes": 0, "rows": 0, "rows_valid": 0,
                          "ctx_rows": 0}
+        # Iterations that dispatched, the step programs they dispatched,
+        # and those of them that ran the pair's program (`mixed`) with the
+        # rows of its two parts.
+        self._programs = {"iterations": 0, "programs": 0, "mixed": 0,
+                          "decode_rows": 0, "chunk_rows": 0}
         self._tokens_run = 0
         self._decode_ctx = 0        # of the iteration being built
         # What `_upload` handed to the device: populations, transfers (a
@@ -1064,8 +1124,13 @@ class InferenceEngine:
             # prefilled in them, the rows they computed and how many of
             # those held a prompt token.
             "prefill": dict(self._prefill),
-            # Populations handed to the device, the transfers that took
-            # and their bytes (`_upload`).
+            # Iterations that dispatched and the step programs they
+            # dispatched (1 an iteration where every admission rides the
+            # decoding lanes' step); `mixed`: the pair's programs, with the
+            # rows of their decode parts and of their chunks.
+            "programs": dict(self._programs),
+            # Populations handed to the device, the transfers that took (a
+            # pair's two go in one) and their bytes (`_upload`).
             "upload": dict(self._uploads),
             # T=1 steps and the context tokens their lanes attended over;
             # over a K/V cache also the runs of the decode kernel that
@@ -1160,7 +1225,8 @@ class InferenceEngine:
             t, sample, spec, compact = key
             name = f"t{t}" + ("_sample" if sample else "") \
                 + ("_spec" if spec else "") \
-                + (f"_lanes{compact}" if compact else "")
+                + (f"_{'pair' if self._pairs else 'lanes'}{compact}"
+                   if compact else "")
             text, out[name] = report(made, self._step_fns[key],
                                      self._step_avals[key])
             if self._stateful:
@@ -1328,9 +1394,12 @@ class InferenceEngine:
         fetches a step in the iteration that dispatched it: the same loop
         at depth 0, nothing in flight between iterations.
 
-        Decode lanes and prefilling lanes dispatch as SEPARATE jitted
-        steps (T=1 and T=prefill_chunk) so neither population pays the
-        other's FLOP shape.  When speculation is on and any decode lane
+        An iteration dispatches ONE program: the T=1 step where nobody
+        prefills, else the pair's, the prefilling lanes' [prefill_lanes, T]
+        rows beside the decoding lanes' [max_lanes, 1] under one read of
+        the weights.  An engine with a proposer dispatches the two
+        populations as SEPARATE jitted steps, the decoding lanes' first.
+        When speculation is on and any decode lane
         drafted, the decode population dispatches as ONE verify step
         sized to the WIDEST draft actually proposed this step
         (T = 1+max drafts, never more than spec_k+1) — draftless lanes
@@ -1347,7 +1416,8 @@ class InferenceEngine:
         An iteration is five flat phases (`_PHASES`), none inside another,
         so that an idle gap of the device in a profiler trace carries the
         name of what the host was doing; `dispatch` runs once per
-        population, so a mixed step has it twice.  One `engine/step` ring
+        program, so a drafting engine's mixed step has it twice.  One
+        `engine/step` ring
         record at the end holds the iteration's durations: `wall_ms` its
         period, `fetch_ms` the time blocked on the older step, `ahead`
         whether it dispatched with that step still unfetched; the parts of
@@ -1407,19 +1477,25 @@ class InferenceEngine:
             plans = []
             with spans.phase("engine", "build_batch") as ph:
                 pops = []
-                if decode:
-                    t = (1 + max(len(r.draft) for _, r in decode)
-                         if spec else 1)
-                    pops.append((spec, decode, t, False))
-                if prefill:
-                    pops.append((False, prefill, self._prefill_len(prefill),
-                                 True))
+                if prefill and self._pairs:
+                    pops.append((False, decode, prefill,
+                                 self._prefill_len(prefill)))
+                else:
+                    if decode:
+                        t = (1 + max(len(r.draft) for _, r in decode)
+                             if spec else 1)
+                        pops.append((spec, decode, [], t))
+                    if prefill:
+                        pops.append((False, [], prefill,
+                                     self._prefill_len(prefill)))
                 if older and not all(self._made(*pop) for pop in pops):
                     pops = []       # land the step in flight first
                 for pop in pops:
                     plans.append(self._plan(parts, *pop))
             took["build_batch"] = ph.seconds
         newer = []
+        self._programs["iterations"] += bool(plans)
+        self._programs["programs"] += len(plans)
         for spec, lanes, chunks, news, batch, due in plans:
             vtok = spans.begin("engine", "spec_verify") if spec else None
             with spans.phase("engine", "dispatch") as ph:
@@ -1588,46 +1664,69 @@ class InferenceEngine:
         lane has more than that left to feed (a question behind a document
         that came from the prefix cache: the long program would compute
         four times the rows for it).  Two programs, both warmed by whoever
-        warms the engine's shapes; an engine without `prefill_lanes` keeps
-        its one."""
+        warms the engine's shapes; an engine that names no `prefill_lanes`
+        has the one."""
         short = self.prefill_chunk // 4
-        if short and self.prefill_lanes < self.max_lanes and all(
+        if short and self._widths and all(
                 len(r.prompt) - r.next_fed <= short for _, r in lanes):
             return short
         return self.prefill_chunk
 
-    def _plan(self, parts: dict, spec: bool, lanes, t: int,
-              prefill=False) -> tuple:
-        """One population's step of `t` positions, built from what the step
-        in flight will have left, and from here on in flight itself: per
-        lane the positions it writes (`chunks`) and whether it samples a
-        token (`news`), which `_commit` takes off again; over a state cache
-        also the snapshots due behind it (`due`).  Lanes whose next
-        position opens a window have the one before closed first.  The
-        seconds of its three parts are added to `parts`: `windows` (the
-        compaction's dispatch included), `assemble` (host arrays, tables'
-        entries, counters), `upload` (the host arrays and the block tables
-        handed to the device)."""
-        if self._closes:
-            with spans.phase("engine.build_batch", "windows") as ph:
-                self._close_windows(lanes)
-            parts["windows"] += ph.seconds
-        with spans.phase("engine.build_batch", "assemble") as ph:
-            arrays, chunks = self._build_batch(lanes, t, prefill)
-            news, due = {}, []
-            for lane, req in lanes:
-                news[lane] = int(req.samples(req.next_fed, chunks[lane]))
-                req.ahead_len += chunks[lane]
-                req.ahead_new += news[lane]
-                if self._checkpoints and prefill:
-                    key = self._snapshot_due(lane, req)
-                    if key is not None:
-                        due.append((lane, key))
-        parts["assemble"] += ph.seconds
+    def _plan(self, parts: dict, spec: bool, decode, prefill, t: int) -> tuple:
+        """One program's step, built from what the step in flight will have
+        left, and from here on in flight itself: the decoding lanes at `t`
+        positions (1, or a verify's), the prefilling lanes at `t`, or both
+        (the pair: `decode` at one position beside `prefill` at `t`, each
+        built as it would be alone, the decoding lanes first, in its part
+        of the pair's one buffer; with nobody decoding that part rides
+        along masked and counts as no T=1 step).  Per lane the positions it
+        writes (`chunks`) and whether it samples a token (`news`), which
+        `_commit` takes off again; over a state cache also the snapshots
+        due behind it (`due`).  Lanes whose next position opens a window
+        have the one before closed first.  The seconds of its three parts
+        are added to `parts`: `windows` (the compaction's dispatch
+        included), `assemble` (host arrays, tables' entries, counters),
+        `upload` (the host arrays and the block tables handed to the
+        device)."""
+        pair = bool(prefill) and self._pairs
+        flat, *views = (_pair_views(self.max_lanes,
+                                    self._chunk_rows(prefill), t)
+                        if pair else (None, None, None))
+        built, chunks, news, due = [], {}, {}, []
+        for live, out in zip((decode, prefill), views):
+            if not live and not (pair and out is views[0]):
+                continue
+            if self._closes and live:
+                with spans.phase("engine.build_batch", "windows") as ph:
+                    self._close_windows(live)
+                parts["windows"] += ph.seconds
+            with spans.phase("engine.build_batch", "assemble") as ph:
+                arrays, fed = self._build_batch(
+                    live, 1 if pair and live is decode else t,
+                    live is prefill, out)
+                built.append(arrays)
+                chunks.update(fed)
+                for lane, req in live:
+                    news[lane] = int(req.samples(req.next_fed, fed[lane]))
+                    req.ahead_len += fed[lane]
+                    req.ahead_new += news[lane]
+                    if self._checkpoints and live is prefill:
+                        key = self._snapshot_due(lane, req)
+                        if key is not None:
+                            due.append((lane, key))
+            parts["assemble"] += ph.seconds
+        arrays = built[0]
+        if pair:
+            (_, drawn, _, host, _), (_, draws, _, more, rows) = built
+            arrays = (t, drawn or draws, flat, (host, more), rows)
+            mixed = self._programs
+            mixed["mixed"] += 1
+            mixed["decode_rows"] += self.max_lanes
+            mixed["chunk_rows"] += len(rows) * t
         with spans.phase("engine.build_batch", "upload") as ph:
-            batch = self._upload(arrays)
+            batch = self._upload(arrays, bool(decode) + bool(prefill))
         parts["upload"] += ph.seconds
-        return spec, lanes, chunks, news, batch, due
+        return spec, decode + prefill, chunks, news, batch, due
 
     def _snapshot_due(self, lane: int, req: _Request):
         """The chain key to snapshot `lane`'s state under behind the chunk
@@ -1649,16 +1748,16 @@ class InferenceEngine:
             return None
         return req.chain[end // self.cache.block_size - 1]
 
-    def _upload(self, arrays) -> tuple:
-        """A population's lane arrays (`_build_batch`) as `_run_step` takes
-        them: their one buffer as ONE device array, and the block tables'
-        copy (a transfer only where a table has changed since the last).
-        `rows` stay on the host too (`step` gives each fetched row back to
-        its lane by them)."""
+    def _upload(self, arrays, populations: int = 1) -> tuple:
+        """A program's lane arrays (`_build_batch`; a pair's: both
+        populations') as `_run_step` takes them: their one buffer as ONE
+        device array, and the block tables' copy (a transfer only where a
+        table has changed since the last).  `rows` stay on the host too
+        (`step` gives each fetched row back to its lane by them)."""
         t, sample, lanes, _, rows = arrays
         changed = not self.cache.tables_on_device
         up = self._uploads
-        up["populations"] += 1
+        up["populations"] += populations
         up["transfers"] += 1 + changed
         up["bytes"] += lanes.nbytes + changed * self.cache.block_tables.nbytes
         return (t, sample, (jnp.asarray(lanes), self.cache.device_tables()),
@@ -1677,10 +1776,17 @@ class InferenceEngine:
             or int(self.cache.seq_lens[lane]) + req.ahead_len
             >= self.cache.max_seq_len)
 
-    def _build_batch(self, live, t, prefill=False):
+    def _chunk_rows(self, prefill) -> int:
+        """The rows of the compact program that takes the lanes `prefill`:
+        `prefill_lanes`, or (`_widths`) one where one lane prefills."""
+        return (1 if self._widths and len(prefill) == 1
+                else self.prefill_lanes)
+
+    def _build_batch(self, live, t, prefill=False, out=None):
         """Host-side assembly of the fixed-shape lane arrays for one
         population, as views of the one buffer that goes to the device
-        (`_lane_views`; lanes not in `live` ride along fully masked), from the
+        (`_lane_views`, or its part `out` of a pair's buffer: that function's
+        result; lanes not in `live` ride along fully masked), from the
         lengths and counts the step in flight will have left: committed
         plus `ahead_len` / `ahead_new`.  A decode lane whose last token
         that step is still sampling is told to read it on the device
@@ -1689,17 +1795,14 @@ class InferenceEngine:
         chunk short of its prompt's end), and such a lane's entry of
         `_last_tok` stays what it was.
 
-        A `prefill` population under `prefill_lanes` < max_lanes is built
-        compact: row i of its arrays is the i-th lane of `live`, `rows`
-        ([prefill_lanes], or [1] where one lane prefills) names each row's
-        lane (max_lanes for a row nobody has: it reads lane max_lanes - 1's
-        table fully masked and writes nowhere), and the step gathers and
-        scatters by it."""
-        compact = prefill and self.prefill_lanes < self.max_lanes
-        n = self.max_lanes
-        if compact:
-            n = 1 if len(live) == 1 else self.prefill_lanes
-        lanes, host, rows = _lane_views(n, t, compact, self.max_lanes)
+        A `prefill` population is built compact: row i of its arrays is the
+        i-th lane of `live`, `rows` ([prefill_lanes], or (`_widths`) [1]
+        where one lane prefills) names each row's lane (max_lanes for a row
+        nobody has: it reads lane max_lanes - 1's table fully masked and
+        writes nowhere), and the step gathers and scatters by it."""
+        compact = prefill
+        n = self._chunk_rows(live) if compact else self.max_lanes
+        lanes, host, rows = out or _lane_views(n, t, compact, self.max_lanes)
         (tokens, positions, valid, ctx_lens, gather, temps, seeds,
          counters) = host
         chunks = {}
@@ -1756,7 +1859,7 @@ class InferenceEngine:
             # path read every lane's whole table.
             pf["ctx_rows"] += sum(self.cache.rows_held(int(c))
                                   for c in ctx_lens[valid[:, 0] != 0])
-        elif t == 1:
+        elif t == 1 and live:
             ctx = [int(ctx_lens[lane]) for lane, _ in live]
             self._decode_ctx = sum(ctx)
             if self._eva is not None:
@@ -1843,17 +1946,15 @@ class InferenceEngine:
             self._step_made[key] = made
         self.cache.update_pools(k, v)
         self._cold = self._cold or first
-        if first and compact and not spec:
+        if first and compact and self._widths:
             self._to_warm.append(batch)
         return next_tok, logp
 
-    def _made(self, spec: bool, lanes, t: int, prefill: bool) -> bool:
-        """Whether the program a population's step will run has been made
-        (`_run_step`'s key, from what `_build_batch` will build)."""
-        n = 0
-        if prefill and self.prefill_lanes < self.max_lanes:
-            n = 1 if len(lanes) == 1 else self.prefill_lanes
-        sample = any(req.temperature > 0 for _, req in lanes)
+    def _made(self, spec: bool, decode, prefill, t: int) -> bool:
+        """Whether the program a plan's step will run has been made
+        (`_run_step`'s key, from what `_plan` will build)."""
+        n = self._chunk_rows(prefill) if prefill else 0
+        sample = any(req.temperature > 0 for _, req in decode + prefill)
         return (t, sample, spec, n) in self._step_fns
 
     def _warm_widths(self, batch) -> None:
@@ -1866,15 +1967,27 @@ class InferenceEngine:
         t, sample, _, rows = batch
         for n in {1, self.prefill_lanes} - {len(rows)}:
             if (t, sample, False, n) not in self._step_fns:
-                self._run_step(self._upload((t, sample, *_lane_views(
-                    n, t, True, self.max_lanes))))
+                if self._pairs:
+                    lanes, _, (_, _, rows) = _pair_views(self.max_lanes, n, t)
+                else:
+                    lanes, _, rows = _lane_views(n, t, True, self.max_lanes)
+                self._run_step(self._upload((t, sample, lanes, None, rows)))
 
     def _make_entry(self, t: int, sample: bool, spec: bool, compact: int):
         """The program the engine runs for one `_run_step` key: the step
-        (`_make_step_fn`) behind the unpacking of the lanes' one buffer."""
-        step = self._make_step_fn(sample, spec, bool(compact))
+        (`_make_step_fn`) behind the unpacking of the lanes' one buffer (a
+        pair's: the decoding lanes' part, then the chunk's)."""
+        pair = bool(compact) and self._pairs
+        step = self._make_step_fn(sample, spec, bool(compact), pair)
 
         def entry(params, k, v, lanes, tables, *carried):
+            if pair:
+                cut = self.max_lanes * 8
+                return step(
+                    params, k, v,
+                    _unpack_lanes(lanes[:cut].reshape(-1, 8), 1),
+                    _unpack_lanes(lanes[cut:].reshape(compact, -1), t),
+                    tables, *carried)
             tokens, positions, valid, *rest = _unpack_lanes(lanes, t)
             return step(params, k, v, tokens, positions, valid, tables,
                         *rest, *carried)
@@ -1884,7 +1997,7 @@ class InferenceEngine:
         return jax.jit(entry, donate_argnums=donate)
 
     def _make_step_fn(self, sample: bool, spec: bool = False,
-                      compact: bool = False):
+                      compact: bool = False, pair: bool = False):
         model, config = self.model, self.config
         capture = self._capture_logp
 
@@ -1945,6 +2058,41 @@ class InferenceEngine:
             return (next_tok, *rest,
                     last_tok.at[rows].set(next_tok, mode="drop"))
 
+        def pair_step(params, k, v, decode, chunk, tables, last_tok,
+                      *moe_load):
+            # The decoding lanes' step and the compact step in ONE: `decode`
+            # the T=1 population's arrays (tokens ... counters) over
+            # [max_lanes, 1], `chunk` the compact one's (... rows) over
+            # [prefill_lanes, T].  The model lays the rows of both end to
+            # end under one read of each weight; the head multiplies the
+            # one sampled row of every lane of either.  Returns what either
+            # sampled, the decoding lanes' first, and last every lane's
+            # last token as both leave it.
+            tokens, positions, valid, ctx_lens, _, *draws = decode
+            (more, positions_c, valid_c, ctx_c, gather, *draws_c,
+             rows) = chunk
+            tokens = jnp.where(tokens < 0, last_tok[:, None], tokens)
+            mine = jnp.take(last_tok, rows, mode="clip")
+            x, k, v, *moe_load = model.forward_cached(
+                params, tokens, positions, valid, k, v, tables, ctx_lens,
+                config, *moe_load, chunk=(more, Lanes(
+                    jnp.take(tables, rows, axis=0, mode="clip"),
+                    positions_c, valid_c, ctx_c,
+                    rows if stateful else None)))
+            b, (n, t) = tokens.shape[0], more.shape
+            last = jnp.take_along_axis(
+                x[b:].reshape(n, t, -1),
+                gather[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+            temps, seeds, counters = (
+                jnp.concatenate(both) for both in zip(draws, draws_c))
+            next_tok, *logp = sample_rows(
+                params, jnp.concatenate([x[:b, 0], last]), temps, seeds,
+                counters)
+            next_tok = jnp.where(counters >= 0, next_tok,
+                                 jnp.concatenate([last_tok, mine]))
+            return (next_tok, *logp, k, v, *moe_load,
+                    next_tok[:b].at[rows].set(next_tok[b:], mode="drop"))
+
         def next_logits(params, x):
             # The next token's logits: the head's first `vocab_size`
             # columns (all of them, but for a model whose head also
@@ -1989,8 +2137,12 @@ class InferenceEngine:
             # a prefill chunk never materializes [B, T, V], and the
             # logits never leave the device: sampling happens HERE and
             # the step's only non-pool output is one token id per lane.
-            xg = jnp.take_along_axis(
-                x, gather[:, None, None].astype(jnp.int32), axis=1)[:, 0]
+            return sample_rows(params, jnp.take_along_axis(
+                x, gather[:, None, None].astype(jnp.int32), axis=1)[:, 0],
+                temps, seeds, counters)
+
+        def sample_rows(params, xg, temps, seeds, counters):
+            """`sample_tokens` of the rows that sample, xg [B, D]."""
             logits = next_logits(params, xg)                 # [B, V]
             greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             if not sample:
@@ -2012,7 +2164,7 @@ class InferenceEngine:
                 return next_tok, _logp_at(logits, next_tok, temps[:, None])
             return (next_tok,)
 
-        impl = compact_step if compact else step
+        impl = pair_step if pair else compact_step if compact else step
         self._step_impls[(sample, "spec") if spec else sample] = impl
         # Donated, the pools come back as the buffers they went in as
         # (forward_cached writes and reads blocks of them in place);

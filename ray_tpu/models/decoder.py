@@ -63,7 +63,7 @@ import functools
 import math
 import types
 from functools import partial
-from typing import Any, Callable, Optional
+from typing import Any, Callable, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -491,48 +491,82 @@ def heads_attention(h, p, spec, config, mesh, position_offset=0):
     return _from_heads_side_by_side(attn, p["wo"])
 
 
-def _attend_rows(q, k, v, pools, p, config, block_tables, rows, valid,
-                 n_rows, window: int = 0, gated=None):
+def _attend_rows(q, k, v, pools, p, config, lanes, rows, n_rows,
+                 window: int = 0):
     """The slice's K and V written into the whole pools at the layer, at
     `rows` [B, T] of each lane's table, then attention of the `valid` rows
     over the table's first `n_rows` [B] rows in the same buffers
-    (ops/attention.py paged path), projected back.  K/V are cached with
-    kv_heads (GQA un-repeated: the whole point of the grouped cache); the
-    paged attention path groups the query heads itself.  With `window` a
-    row attends its last `window` rows alone; `gated(attn)` stands between
-    the attention and the output projection."""
+    (ops/attention.py paged path).  K/V are cached with kv_heads (GQA
+    un-repeated: the whole point of the grouped cache); the paged attention
+    path groups the query heads itself.  With `window` a row attends its
+    last `window` rows alone."""
     from ray_tpu.ops.attention import paged_attention, paged_kv_update
 
     layer = p["cache_layer"]
-    k_pool, v_pool = paged_kv_update(*pools, k, v, block_tables, rows, valid,
-                                     layer)
-    attn = paged_attention(q, k_pool, v_pool, block_tables, n_rows, rows,
-                           layer, valid=valid, kv_heads=config.n_kv_heads,
+    k_pool, v_pool = paged_kv_update(*pools, k, v, lanes.block_tables, rows,
+                                     lanes.valid, layer)
+    attn = paged_attention(q, k_pool, v_pool, lanes.block_tables, n_rows,
+                           rows, layer, valid=lanes.valid,
+                           kv_heads=config.n_kv_heads,
                            **({"window": window} if window else {}))
-    if gated is not None:
-        attn = gated(attn)
-    return (jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(q.dtype)),
-            (k_pool, v_pool))
+    return attn, (k_pool, v_pool)
 
 
-def heads_attention_cached(h, pools, p, spec, config, block_tables,
-                           positions, valid, ctx_lens):
-    """A token's K and V are one row of its lane's table, at its position.
-    (Per-token rotation at each token's own absolute position: offset =
-    positions[:, 0] with L-consecutive slices means positions must be
-    contiguous per lane, which prefill/decode slices always are.)  A run's
-    own `HeadSizes` may take the rotation away, add a window (the rows
-    behind it are not read: the run's table is a sliding one) and gate the
-    result."""
+# --------------------------------------------------------------------------
+# An attention over a paged cache is three stages (`Attention.project`,
+# `.attend`, `.finish`; a mixer's likewise).  The first and the last are
+# ROW-WISE: products of a row with the layer's weights, whatever lane or
+# position the row has beyond its rotation, so they run once over all the
+# rows of a step, of one population [B, T] or of two laid end to end
+# (`forward_cached`'s `chunk`), and read a leaf of the weights once.  The
+# middle one is a LANE's own: the rows' write into the pools and the
+# attention over the lane's table, by the kernel its population's T takes.
+#   project(h, p, spec, sizes, offset) -> arrays a row each [B, L, ...]
+#     (`offset` [B]: the position of each lane's first row)
+#   attend(rows, pools, p, spec, sizes, lanes: Lanes) -> (arrays a row
+#     each, pools)
+#   finish(out, rows, h, p, spec, sizes) -> [B, L, D]
+# --------------------------------------------------------------------------
+
+class Lanes(NamedTuple):
+    """One population of a step as its lanes' own stage sees it: each
+    lane's table [B, MB], its rows' absolute positions [B, T] (consecutive
+    a lane) and which of them hold a token, its context length with the
+    slice [B], and over a state cache its slot [B] (None: row i's is slot
+    i)."""
+    block_tables: Any
+    positions: Any
+    valid: Any
+    ctx_lens: Any
+    slots: Any = None
+
+
+def _heads_project(h, p, spec, config, offset):
+    """(Per-token rotation at each token's own absolute position: `offset`
+    with L-consecutive slices means positions must be contiguous per lane,
+    which prefill/decode slices always are.)  A run's own `HeadSizes` may
+    take the rotation away."""
     c = head_sizes(spec, config)
     q, k, v = _qkv(spec, h, p, c)
     if c.rope_theta is not None:
-        q = rope(q, c.rope_theta, positions[:, 0])
-        k = rope(k, c.rope_theta, positions[:, 0])
-    return _attend_rows(q, k, v, pools, p, c, block_tables, positions,
-                        valid, ctx_lens, c.window,
-                        partial(_attn_gate, h=h, p=p, sizes=c) if c.gate
-                        else None)
+        q = rope(q, c.rope_theta, offset)
+        k = rope(k, c.rope_theta, offset)
+    return q, k, v
+
+
+def _heads_attend(rows, pools, p, spec, config, lanes):
+    """A token's K and V are one row of its lane's table, at its position;
+    under a run's window the rows behind it are not read (the run's table
+    is a sliding one)."""
+    c = head_sizes(spec, config)
+    return _attend_rows(*rows, pools, p, c, lanes, lanes.positions,
+                        lanes.ctx_lens, c.window)
+
+
+def _heads_finish(attn, rows, h, p, spec, config):
+    """The run's gate, where it has one, and the output projection."""
+    attn = _attn_gate(attn, h, p, head_sizes(spec, config))
+    return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(rows[0].dtype))
 
 
 def eva_attention(h, p, spec, config, mesh, position_offset=0):
@@ -549,10 +583,15 @@ def eva_attention(h, p, spec, config, mesh, position_offset=0):
     return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
 
 
-def eva_attention_cached(h, pools, p, spec, config, block_tables, positions,
-                         valid, ctx_lens):
-    """`heads_attention_cached` over a table whose rows are not one a token:
-    the summary rows of a lane's closed windows, then the exact rows of its
+def _eva_project(h, p, spec, config, offset):
+    q, k, v = _qkv(spec, h, p)
+    return (rope(q, spec.rope_theta, offset), rope(k, spec.rope_theta, offset),
+            v)
+
+
+def _eva_attend(rows, pools, p, spec, config, lanes):
+    """`_heads_attend` over a table whose rows are not one a token: the
+    summary rows of a lane's closed windows, then the exact rows of its
     open one (`ops.eva_row`).  Rotation goes by the true position, the write
     and the mask by the row, computed from it here; a slice lies inside one
     window (the engine cuts chunks at window edges), so its rows are
@@ -561,16 +600,16 @@ def eva_attention_cached(h, pools, p, spec, config, block_tables, positions,
     summaries."""
     from ray_tpu.ops.attention import eva_row
 
-    c = config
-    q, k, v = _qkv(spec, h, p)
-    q = rope(q, spec.rope_theta, positions[:, 0])
-    k = rope(k, spec.rope_theta, positions[:, 0])
-    rows = (eva_row(positions[:, :1], c.window_size, c.chunk_size)
-            + jnp.arange(positions.shape[1], dtype=positions.dtype))
-    n_rows = eva_row(jnp.maximum(ctx_lens - 1, 0), c.window_size,
+    c, positions = config, lanes.positions
+    at = (eva_row(positions[:, :1], c.window_size, c.chunk_size)
+          + jnp.arange(positions.shape[1], dtype=positions.dtype))
+    n_rows = eva_row(jnp.maximum(lanes.ctx_lens - 1, 0), c.window_size,
                      c.chunk_size) + 1
-    return _attend_rows(q, k, v, pools, p, c, block_tables, rows, valid,
-                        n_rows)
+    return _attend_rows(*rows, pools, p, c, lanes, at, n_rows)
+
+
+def _eva_finish(attn, rows, h, p, spec, config):
+    return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(rows[0].dtype))
 
 
 def eva_compact(pools, p, config, src, dst, live):
@@ -751,43 +790,57 @@ def _chosen_attention(q, k, v, keep, scale):
     return jnp.einsum("bhqk,bkhd->bqhd", probs.astype(v.dtype), v)
 
 
-def latent_attention_cached(h, pools, p, spec, config, block_tables,
-                            positions, valid, ctx_lens):
+def _latent_project(h, p, spec, config, offset):
     """MLA over a latent paged cache, absorbed: a token leaves one row
     [c_kv | k_rope] in the one pool; the query's no-position part is
     carried into the latent space (q_nope W_uk^T), scores and the weighted
     sum run against the cached rows as they are, and the result comes back
-    through W_uv.  The same mathematics as `latent_attention`,
-    reassociated."""
+    through W_uv (`_latent_finish`).  The same mathematics as
+    `latent_attention`, reassociated.  Returns (the query's packed row, the
+    token's stored row) and, of an indexed run, (its index queries, their
+    weights, the token's one index key as its second pool stores it)."""
+    from ray_tpu.ops import attention as ops
+
+    c = latent_sizes(spec, config)
+    q_nope, q_rope, c_kv, k_rope, c_q = _latent_qkv(h, p, c, offset)
+    w_uk, _ = _latent_up(p, c)
+    q_lat = jnp.einsum("blhk,hkc->blhc", q_nope, w_uk.astype(h.dtype))
+    out = (ops.pack_latent_rows(q_lat, q_rope),
+           ops.pack_latent_rows(c_kv, k_rope))
+    if c.index_topk:
+        q_i, k_i, w_i = _index_qkw(h, c_q, p, c, offset)
+        out += (q_i, w_i, ops.pack_kv_rows(k_i[..., None, :]))
+    return out
+
+
+def _latent_attend(rows, pools, p, spec, config, lanes):
     from ray_tpu.ops import attention as ops
 
     c = latent_sizes(spec, config)
     layer = p["cache_layer"]
-    q_nope, q_rope, c_kv, k_rope, c_q = _latent_qkv(h, p, c,
-                                                    positions[:, 0])
-    rows = (ops.pack_latent_rows(c_kv, k_rope),)
-    if c.index_topk:
-        # An indexed run's second pool: a token's one index key.
-        q_i, k_i, w_i = _index_qkw(h, c_q, p, c, positions[:, 0])
-        rows += (ops.pack_kv_rows(k_i[..., None, :]),)
+    q, row, *index = rows
+    tables, positions, valid, ctx_lens, _ = lanes
     pool, *index_pool = ops.paged_rows_update(
-        pools, rows, block_tables, positions, valid, layer)
-    w_uk, w_uv = _latent_up(p, c)
-    q_lat = jnp.einsum("blhk,hkc->blhc", q_nope, w_uk.astype(h.dtype))
+        pools, (row, *index[2:]), tables, positions, valid, layer)
     if c.index_topk:
         out = ops.sparse_latent_attention(
-            ops.pack_latent_rows(q_lat, q_rope), q_i, w_i, pool,
-            index_pool[0], block_tables, ctx_lens, positions, valid, layer,
-            v_width=c.kv_lora_rank, scale=c.attn_scale, topk=c.index_topk)
+            q, *index[:2], pool, index_pool[0], tables, ctx_lens, positions,
+            valid, layer, v_width=c.kv_lora_rank, scale=c.attn_scale,
+            topk=c.index_topk)
     else:
         out = ops.latent_attention(
-            ops.pack_latent_rows(q_lat, q_rope), pool, block_tables,
-            ctx_lens, positions, valid, layer, v_width=c.kv_lora_rank,
-            scale=c.attn_scale, **({"window": c.window} if c.window else {}))
+            q, pool, tables, ctx_lens, positions, valid, layer,
+            v_width=c.kv_lora_rank, scale=c.attn_scale,
+            **({"window": c.window} if c.window else {}))
+    return out, (pool, *index_pool)
+
+
+def _latent_finish(out, rows, h, p, spec, config):
+    c = latent_sizes(spec, config)
+    _, w_uv = _latent_up(p, c)
     attn = jnp.einsum("blhc,hcv->blhv", out, w_uv.astype(h.dtype))
     attn = _head_gate(attn, h, p, c)
-    return (jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype)),
-            (pool, *index_pool))
+    return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
 
 
 def _kvb_served(w, qk_nope: int):
@@ -820,7 +873,11 @@ class CacheRows:
 @dataclasses.dataclass(frozen=True)
 class Attention:
     apply: Callable
-    cached: Callable
+    # Over a paged cache: the row-wise stage, the lanes' own, the row-wise
+    # end (above `Lanes`).
+    project: Callable
+    attend: Callable
+    finish: Callable
     rows: Callable          # config -> CacheRows
     # A K and a V pool, or one pool (a latent row: inference/kv_cache.py).
     pools: int = 2
@@ -845,7 +902,8 @@ class Attention:
         return self.pools == 1
 
 
-HEADS = Attention(heads_attention, heads_attention_cached,
+HEADS = Attention(heads_attention, _heads_project, _heads_attend,
+                  _heads_finish,
                   rows=lambda c: CacheRows(
                       c.n_kv_heads, c.head_dim,
                       slide=c.window if isinstance(c, HeadSizes) else 0),
@@ -853,7 +911,8 @@ HEADS = Attention(heads_attention, heads_attention_cached,
 # `HEADS` for a run with a window: the same part, whose whole-sequence form
 # is then the masked one, which has no train path.
 WINDOW_HEADS = dataclasses.replace(HEADS, trains=False)
-LATENT = Attention(latent_attention, latent_attention_cached,
+LATENT = Attention(latent_attention, _latent_project, _latent_attend,
+                   _latent_finish,
                    rows=lambda c: CacheRows(
                        1, c.kv_lora_rank + c.qk_rope_head_dim,
                        slide=getattr(c, "window", 0),
@@ -863,7 +922,7 @@ LATENT = Attention(latent_attention, latent_attention_cached,
                    cast=("w_qa", "w_qb", "w_kva", "w_kvb", "wo",
                          "w_head_gate", "w_iq", "w_ik", "w_iw"),
                    absorbed="w_kvb")
-EVA = Attention(eva_attention, eva_attention_cached,
+EVA = Attention(eva_attention, _eva_project, _eva_attend, _eva_finish,
                 rows=lambda c: CacheRows(c.n_kv_heads, c.head_dim,
                                          c.window_size, c.chunk_size),
                 cast=("wq", "wk", "wv", "wo"),
@@ -874,11 +933,11 @@ EVA = Attention(eva_attention, eva_attention_cached,
 # --------------------------------------------------------------------------
 # Parts: a mixer, beside the attention or alone.  `apply(h, p, config)` on
 # normed h [B, L, D] is the mixer over a whole sequence from its zero
-# state, projected back to [B, L, D]; `cached(h, pools, p, config, slots,
-# positions, valid)` continues each row's state in the mixer's buffers
+# state, projected back to [B, L, D]; over a state cache its three stages
+# (above `Lanes`) continue each row's state in the mixer's buffers
 # (`pools`: what `Mixer.state` describes, a slot a lane) at
-# `p["cache_layer"]`, overwrites it with the state behind the slice's last
-# valid token and returns ([B, T, D], pools).
+# `p["cache_layer"]` and overwrite it with the state behind the slice's last
+# valid token.
 # --------------------------------------------------------------------------
 
 @dataclasses.dataclass(frozen=True)
@@ -987,9 +1046,13 @@ def _slot_rows(buffer, layer, slots, b: int, new=None):
             (layer, slots[i], zero)), buffer, unroll=True)
 
 
-def ssm_mixer_cached(h, pools, p, config, slots, positions, valid):
-    """The mixer over a slice, continued from each row's slot (`slots` [B];
-    None: row i's is slot i) of the two buffers `pools` = (state
+def _ssm_project(h, p, spec, config, offset):
+    return _ssm_split(h, p, config)
+
+
+def _ssm_attend(rows, pools, p, spec, config, lanes):
+    """The mixer over a slice, continued from each row's slot (`lanes.slots`
+    [B]; None: row i's is slot i) of the two buffers `pools` = (state
     [L, S, H, N, P] float32 (as `ops.ssm.state_shape` folds a slot), tail
     [L, S, (K - 1) C]: a slot's K - 1 rows one behind the other) at
     `p["cache_layer"]`: a row whose slice starts at
@@ -998,26 +1061,28 @@ def ssm_mixer_cached(h, pools, p, config, slots, positions, valid):
     and the slot is left holding the state and the convolution's tail
     behind the row's last valid token; a row with no valid token leaves its
     slot as it was.  One token a row is the update (`ops.ssm_update`), more
-    the chunked scan (`ops.ssm_scan`)."""
+    the chunked scan (`ops.ssm_scan`).  Returns ((y, the convolved x),
+    pools)."""
     from ray_tpu.ops import ssm
 
     c = config
     state, tails = pools
     layer = p["cache_layer"]
-    z, xbc, dt = _ssm_split(h, p, c)
+    _, xbc, dt = rows
+    _, positions, valid, _, slots = lanes
     # (a row nobody has also stands at position 0: it starts nothing)
     fresh = (positions[:, 0] == 0) & valid[:, 0]
     with jax.named_scope("ssm_conv"):
-        b = h.shape[0]
+        b = xbc.shape[0]
         tail = jnp.where(fresh[:, None, None], 0, _slot_rows(
             tails, layer, slots, b).reshape(b, c.ssm_conv - 1, -1))
         xbc, tail = ssm.conv_tail(xbc, tail, p["conv_w"], p["conv_b"],
                                   jnp.sum(valid, axis=1, dtype=jnp.int32))
         tails = _slot_rows(tails, layer, slots, b, tail.reshape(b, -1))
     if slots is None:
-        slots = jnp.arange(h.shape[0], dtype=jnp.int32)
+        slots = jnp.arange(b, dtype=jnp.int32)
     x, bm, cm, dt, a = _ssm_heads(xbc, dt, p, c, valid)
-    if h.shape[1] == 1:
+    if xbc.shape[1] == 1:
         with jax.named_scope("ssm_update"):
             # (a decode token never stands at position 0)
             y, state = ssm.ssm_update(state, x[:, 0], dt[:, 0], a, bm[:, 0],
@@ -1027,19 +1092,26 @@ def ssm_mixer_cached(h, pools, p, config, slots, positions, valid):
         with jax.named_scope("ssm_scan"):
             y, state = ssm.ssm_scan(state, x, dt, a, bm, cm, slots, fresh,
                                     layer, chunk=c.ssm_chunk)
-    return _ssm_out(y, x, z, p, c), (state, tails)
+    return (y, x), (state, tails)
+
+
+def _ssm_finish(out, rows, h, p, spec, config):
+    return _ssm_out(*out, rows[0], p, config)
 
 
 @dataclasses.dataclass(frozen=True)
 class Mixer:
     apply: Callable
-    cached: Callable
+    # Over a state cache, the three stages of a part (above `Lanes`).
+    project: Callable
+    attend: Callable
+    finish: Callable
     state: Callable         # config -> StateRows
     # Leaves `serving_params` holds in the activation dtype.
     cast: tuple = ()
 
 
-SSM = Mixer(ssm_mixer, ssm_mixer_cached,
+SSM = Mixer(ssm_mixer, _ssm_project, _ssm_attend, _ssm_finish,
             state=lambda c: StateRows(
                 c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_conv,
                 c.ssm_heads * c.ssm_head_dim
@@ -1182,32 +1254,59 @@ def _block(x, p, spec: Spec, run: Run, config, mesh, position_offset=0):
     return x, aux
 
 
-def _block_cached(x, pools, p, spec: Spec, run: Run, config,
-                  block_tables, positions, valid, ctx_lens, slots=None):
+def _part_cached(part, h, pools, p, spec, config, lanes: tuple, offset):
+    """A part (`Attention` or `Mixer`) of a block over the step's rows
+    `h` [B, T, D] of one population, or [rows, 1, D] of several laid end to
+    end (`lanes`: a `Lanes` each, in that order): its row-wise stages once
+    over all of them, the lanes' own once a population on the rows that are
+    its, one after the other on the same pools.  `offset`: the position of
+    the first row of each of h's leading entries.  Returns ([.., D],
+    pools)."""
+    rows = part.project(h, p, spec, config, offset)
+    if len(lanes) == 1:
+        out, pools = part.attend(rows, pools, p, spec, config, lanes[0])
+        return part.finish(out, rows, h, p, spec, config), pools
+    outs, at = [], 0
+    for pop in lanes:
+        b, t = pop.positions.shape
+        mine = jax.tree.map(
+            lambda a: a[at:at + b * t].reshape(b, t, *a.shape[2:]), rows)
+        out, pools = part.attend(mine, pools, p, spec, config, pop)
+        outs.append(jax.tree.map(
+            lambda a: a.reshape(b * t, 1, *a.shape[2:]), out))
+        at += b * t
+    out = jax.tree.map(lambda *a: jnp.concatenate(a), *outs)
+    return part.finish(out, rows, h, p, spec, config), pools
+
+
+def _block_cached(x, pools, p, spec: Spec, run: Run, config, lanes: tuple,
+                  offset, valid):
     """One block of a run over a paged cache: what the slice's tokens leave
     there is written into the run's whole pools at `p["cache_layer"]`, then
     attention runs over its kind's block table in the same buffers
-    (`Attention.cached`).  x [B, T, D]; positions [B, T] absolute; ctx_lens
-    [B] = context length including this slice.  A run with a mixer beside
-    its attention hands that the pools behind the attention's and each
-    row's slot in them (`slots` [B]).  A run of one part runs that part
-    alone, over the pools that are its (`Run.pools`).  Returns (x, pools,
-    the expert layer's load or None)."""
+    (`Attention.attend`).  x [B, T, D] of one population (`lanes`: its
+    `Lanes`, positions [B, T] absolute, ctx_lens [B] the context length
+    including this slice) or [rows, 1, D] of several laid end to end;
+    `offset` and `valid` as x's rows lie.  A run with a mixer beside its attention hands
+    that the pools behind the attention's and each row's slot in them
+    (`Lanes.slots`).  A run of one part runs that part alone, over the pools
+    that are its (`Run.pools`).  Returns (x, pools, the expert layer's load
+    or None)."""
     m = spec.mult or Multipliers()
     if run.attn is not None or run.mixer is not None:
         h = _norm(spec, x, p, spec.attn_norm)
     n = (0 if run.attn is None else
          run.attn.pools if run.mixer is not None else len(pools))
     if run.attn is not None:
-        attn, rows = run.attn.cached(_scaled(h, m.attn_in), pools[:n], p,
-                                     spec, run.sizes or config, block_tables,
-                                     positions, valid, ctx_lens)
+        attn, rows = _part_cached(run.attn, _scaled(h, m.attn_in), pools[:n],
+                                  p, spec, run.sizes or config, lanes,
+                                  offset)
         x = x + _behind(spec, spec.attn_post_norm, p,
                         _scaled(attn, m.attn_out))
         pools = (*rows, *pools[n:])
     if run.mixer is not None:
-        y, state = run.mixer.cached(_scaled(h, m.mixer_in), pools[n:], p,
-                                    config, slots, positions, valid)
+        y, state = _part_cached(run.mixer, _scaled(h, m.mixer_in), pools[n:],
+                                p, spec, config, lanes, offset)
         x = x + _behind(spec, spec.attn_post_norm, p,
                         _scaled(y, m.mixer_out))
         pools = (*pools[:n], *state)
@@ -1559,7 +1658,7 @@ def forward_cached(family, params: dict, tokens: jax.Array,
                    positions: jax.Array, valid: jax.Array,
                    k_pool: jax.Array, v_pool: Optional[jax.Array],
                    block_tables: jax.Array, ctx_lens: jax.Array, config,
-                   moe_load=None, slots=None):
+                   moe_load=None, slots=None, chunk=None):
     """Cached (incremental) trunk for autoregressive decode/prefill.
 
     tokens [B, T] is a SLICE of each lane's sequence at absolute
@@ -1593,13 +1692,32 @@ def forward_cached(family, params: dict, tokens: jax.Array,
     Where a run has a mixer beside its attention, its state buffers follow
     the K and V pools in the tuple `k_pool` (`PagedKVCache.step_pools`) and
     ride the same carry; `slots` [B] names each row's slot there (None: row
-    i's is slot i)."""
+    i's is slot i).
+
+    `chunk`: a SECOND population in the same step, as (tokens [N, C], its
+    `Lanes`): the prefilling lanes' rows beside the decoding lanes' [B, 1].  The rows
+    of both are laid end to end, [B T + N C, 1], and every row-wise product
+    of a layer (norms, projections, gates, the feed-forward with its
+    router) runs once over all of them, so a leaf of the weights is read
+    once a step; rotation goes by each row's own position, and what is a
+    lane's own (its rows' write, its attention or scan, by the kernel its
+    population's T takes) runs a population at a time on the same pools,
+    the first's before the second's (`_part_cached`).  x then comes back
+    [B T + N C, 1, D], in that order."""
     c, spec = config, family(config)
     runs = _stacks(spec, c)
     if not all(run.ffn is None or run.ffn.serves for run in runs):
         raise NotImplementedError(
             "this feed-forward has no path over a paged KV cache (the "
             "Switch layer's capacity is a whole batch's)")
+    lanes = (Lanes(block_tables, positions, valid, ctx_lens, slots),)
+    if chunk is not None:
+        more, pop = chunk
+        lanes += (pop,)
+        tokens, positions, valid = (
+            jnp.concatenate([a.reshape(-1, 1), b.reshape(-1, 1)])
+            for a, b in ((tokens, more), (positions, pop.positions),
+                         (valid, pop.valid)))
     if spec.rope_theta is None and spec.pos_table:
         pos = jnp.clip(positions, 0, c.max_seq_len - 1)
         x = _embed(params, "tok", tokens, c) + _embed(params, "pos", pos, c)
@@ -1619,15 +1737,16 @@ def forward_cached(family, params: dict, tokens: jax.Array,
     for run in runs:
         blocks, n_layers, first, off = (params[run.blocks], run.n_layers,
                                         run.first, run.offset)
-        tables = block_tables
+        mine = lanes
         if run.table is not None:
             part, parts = run.table
             mb = block_tables.shape[1] // parts
-            tables = jax.lax.slice_in_dim(block_tables, part * mb,
-                                          (part + 1) * mb, axis=1)
+            mine = tuple(pop._replace(block_tables=jax.lax.slice_in_dim(
+                pop.block_tables, part * mb, (part + 1) * mb, axis=1))
+                for pop in lanes)
 
         def body(carry, i, run=run, blocks=blocks, first=first, off=off,
-                 tables=tables):
+                 mine=mine):
             x, pools, seen = carry
             own = pools if run.pools is None else tuple(
                 pools[j] for j in run.pools)
@@ -1635,7 +1754,7 @@ def forward_cached(family, params: dict, tokens: jax.Array,
                 x, own, {**_layer_of(blocks, i + off if off else i,
                                      _whole(run)),
                          "cache_layer": i + first if first else i},
-                spec, run, c, tables, positions, valid, ctx_lens, slots)
+                spec, run, c, mine, positions[:, 0], valid)
             if run.pools is None:
                 pools = own
             else:

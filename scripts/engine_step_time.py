@@ -72,6 +72,25 @@ this one's (`parent`), and `runs=4,8,16` this tree's at those
 
   python3 scripts/engine_step_time.py attend=axk1 attend=dots3 \
       runs=4,8,16 --baseline-root .scratch/parent
+
+Since PR 53 also an ADMITTING iteration's programs, `mixed=<cell>`
+(`mixed=gpt2xl`, `mixed=olmoe`, ...): the cell's own engine (its weights from
+a seed, its lanes, pools and chunk), every lane but one decoding at a
+context of a quarter to a half of its share of the pool (130-320 tokens in
+gpt2-xl's cell) and one prefilling a chunk of its prompt, and
+the programs the engine dispatches for that iteration, called back to back
+on the batches the engine itself builds: the T=1 program alone
+(`mixed_<cell>_t1_ms`) and the whole iteration (`mixed_<cell>_admit_ms`: in a
+tree from before PR 53 the T=1 program and the prefill program behind it,
+since then the pair's one program; `..._programs` says how many it was).
+It goes by what the engine in the working directory's tree does, so the
+same script times a parent checkout when run from its root
+(`mixed=gpt2xl:8`: the chunk at 8 lanes, whatever the traffic or the rows
+rule says):
+
+  python3 scripts/engine_step_time.py mixed=gpt2xl mixed=olmoe
+  (cd .scratch/parent && python3 ../../scripts/engine_step_time.py \
+      mixed=gpt2xl mixed=olmoe)
 """
 
 from __future__ import annotations
@@ -489,6 +508,73 @@ def time_steps(unrolls, out):
                     out[key] = ms
 
 
+def time_mixed(name, out):
+    """An admitting iteration's programs at the serve cell of configuration
+    `name`, from the engine of the tree in the working directory, into
+    `out`."""
+    m = manifest.load()
+    name, _, rows = name.partition(":")     # `gpt2xl:8`: a chunk of 8 lanes
+    (cell,) = [c for c in m.cells if c.startswith(f"serve_{name}_")]
+    file = m.load_config(m.cells[cell]["config"])
+    traffic = m.load_traffic(m.cells[cell]["traffic"])["engine"]
+    eng = InferenceEngine(
+        file["module"].rsplit(".", 1)[-1], manifest.model_config(file, None),
+        seed=0, auto_start=False, prefix_cache=False,
+        **{**traffic, **({"prefill_lanes": int(rows)} if rows else {})})
+    if rows:
+        eng._widths, name = False, f"{name}_lanes{rows}"
+    lanes, chunk = eng.max_lanes, eng.prefill_chunk
+    rng = np.random.default_rng(0)
+    vocab = eng.config.vocab_size
+    # (admission reserves a lane's blocks to its worst-case final length)
+    blocks = traffic["num_blocks"]
+    room = min(eng.cache.max_seq_len, eng.cache.block_size * (
+        blocks if isinstance(blocks, int) else blocks[0]) // lanes)
+    for n in rng.integers(room // 4, room // 2, lanes - 1):
+        eng.submit(rng.integers(0, vocab, int(n)).tolist(), room - int(n))
+    while eng._waiting or any(r is not None and r.next_fed < len(r.prompt)
+                              for r in eng._lanes):
+        eng.step()
+    eng.step(), eng.step()              # the T=1 program is made
+    eng.submit(rng.integers(0, vocab, 2 * chunk + 3).tolist(), 8)
+    with eng._lock:
+        eng._admit()
+    live = [(i, r) for i, r in enumerate(eng._lanes) if r is not None]
+    decode = [(i, r) for i, r in live if r.next_fed == len(r.prompt)]
+    prefill = [(i, r) for i, r in live if r.next_fed < len(r.prompt)]
+    assert (len(decode), len(prefill)) == (lanes - 1, 1)
+    parts = dict.fromkeys(("windows", "assemble", "upload"), 0.0)
+    if hasattr(eng, "_pairs"):
+        alone = [eng._plan(parts, False, decode, [], 1)[4]]
+        admit = [eng._plan(parts, False, decode, prefill, chunk)[4]]
+    else:
+        alone = [eng._plan(parts, False, decode, 1)[4]]
+        admit = [alone[0], eng._plan(parts, False, prefill, chunk, True)[4]]
+
+    def run(batches, n):
+        for batch in batches:           # made and warm
+            eng._run_step(batch)
+        jax.block_until_ready(eng.cache.step_pools)
+        ms = []
+        for _ in range(3):
+            t0 = time.perf_counter()
+            for _ in range(n):
+                for batch in batches:
+                    eng._run_step(batch)
+            jax.block_until_ready(eng.cache.step_pools)
+            ms.append(round(1000 * (time.perf_counter() - t0) / n, 4))
+        return ms
+
+    out[f"mixed_{name}_t1_ms"] = run(alone, 100)
+    out[f"mixed_{name}_admit_ms"] = run(admit, 40)
+    out[f"mixed_{name}_programs"] = len(admit)
+    out[f"mixed_{name}_chunk"] = [
+        int(b[3].shape[0]) if b[3] is not None else lanes
+        for b in admit[-1:]] + [chunk]
+    eng.shutdown()      # (one engine's weights a process: a second `mixed=`
+    #                     of a cell this size needs a process of its own)
+
+
 def main(argv):
     baseline_root = None
     if "--baseline-root" in argv:
@@ -497,7 +583,7 @@ def main(argv):
         argv = argv[:at] + argv[at + 2:]
     named = {kind: [a.split("=", 1)[1] for a in argv
                     if a.startswith(kind + "=")]
-             for kind in ("write", "select", "attend", "runs")}
+             for kind in ("write", "select", "attend", "runs", "mixed")}
     runs = [int(kb) for r in named.pop("runs") for kb in r.split(",")]
     unrolls = [int(a) for a in argv if "=" not in a]
     dev = jax.devices()[0]
@@ -505,12 +591,15 @@ def main(argv):
     if unrolls or not any(named.values()):
         time_steps(unrolls, out)
     for name in named["write"] or ["gpt2xl"] * (
-            not named["select"] and not named["attend"]):
+            not named["select"] and not named["attend"]
+            and not named["mixed"]):
         time_rows_write(name, out)
     for name in named["select"]:
         time_select(name, out)
     for name in named["attend"]:
         time_attend(name, out, runs, baseline_root)
+    for name in named["mixed"]:
+        time_mixed(name, out)
     print(json.dumps(out))
 
 

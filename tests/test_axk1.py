@@ -485,21 +485,26 @@ def test_prefill_lanes_serve_the_same_tokens_from_fewer_rows(family):
     assert base.prefill_lanes == 4 and eng.prefill_lanes == 2
     pf0, pf = base.stats()["prefill"], eng.stats()["prefill"]
     assert pf0["rows"] == pf0["steps"] * 4 * 8
-    # [2, 8] programs, and [2, 2] ones for steps whose lanes all had two
-    # tokens or fewer left to feed; each also at one row, for steps in
-    # which one lane prefilled (made together: `_warm_widths`)
-    # (a program's lane arrays arrive as one buffer, a row a lane, `_upload`:
-    # its rows by the buffer's, its T by the key's)
-    shapes = {(v[3].shape[0], k[0]) for k, v in eng._step_avals.items()
-              if k[3]}
-    assert all(v[3].shape == (k[3], 3 * k[0] + 6)
+    # the pair's programs with a chunk of [2, 8], and of [2, 2] for steps
+    # whose lanes all had two tokens or fewer left to feed; each also at one
+    # row, for steps in which one lane prefilled (made together:
+    # `_warm_widths`)
+    # (a pair's lane arrays arrive as one flat buffer, `_pair_views`: the
+    # decoding lanes' [4, 8], then the chunk's, a row a lane)
+    shapes = {(k[3], k[0]) for k in eng._step_avals if k[3]}
+    assert all(v[3].shape == (4 * 8 + k[3] * (3 * k[0] + 6),)
                for k, v in eng._step_avals.items() if k[3])
     assert {(2, 8), (1, 8)} <= shapes <= {(2, 8), (1, 8), (2, 2), (1, 2)}
     assert ((2, 2) in shapes) == ((1, 2) in shapes)
     assert pf["rows"] <= pf["steps"] * 2 * 8 and pf["rows"] % 2 == 0
     assert pf["rows_valid"] == pf0["rows_valid"] == sum(map(len, prompts))
     assert pf["lanes"] <= 2 * pf["steps"] and pf["steps"] > pf0["steps"]
-    assert not [k for k in base._step_avals if k[3]]      # the default: none
+    # lanes not named: the rows rule's (every lane here), ONE chunk program
+    assert {k[3] for k in base._step_avals if k[3]} == {4}
+    for e in (base, eng):       # an iteration, a program
+        ran = e.stats()["programs"]
+        assert ran["programs"] == ran["iterations"]
+        assert ran["mixed"] == e.stats()["prefill"]["steps"]
 
 
 def test_a_question_behind_a_cached_document_runs_the_short_program():

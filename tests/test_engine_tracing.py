@@ -235,7 +235,7 @@ def test_the_next_step_is_dispatched_before_the_last_one_is_fetched(
 
 def test_compiles_are_counted_where_jax_reports_them():
     engine = _engine()
-    engine.generate(list(range(1, 6)), 3)          # greedy T=8 and T=1
+    engine.generate(list(range(1, 6)), 3)          # greedy pair and T=1
     assert len(engine._step_fns) == 2
 
     def built(c):       # a program is new whether XLA built or the cache had it
@@ -435,7 +435,7 @@ def test_a_windowed_engine_counts_rows_and_compactions_and_adds_a_span():
     assert s1["paged"]["ctx_tokens"] - s0["paged"]["ctx_tokens"] == sum(rows)
     assert "eva" not in _engine().stats()
     programs = engine.compiled_steps()
-    assert {"t1", "t16_lanes1", "compact_lanes1"} <= set(programs)
+    assert {"t1", "t16_pair1", "compact_lanes1"} <= set(programs)
     # (its `pool_copies` are 0 compiled for the chip: tests/test_tpu_aot.py;
     # the CPU backend donates nothing)
     assert programs["compact_lanes1"]["custom_calls"] == 0
@@ -507,11 +507,12 @@ def test_step_records_name_the_parts_of_build_batch_and_commit():
 
 
 def test_upload_sums_grow_by_population():
-    """`stats()["upload"]` (PR 42): host sums `_upload` makes, a population
+    """`stats()["upload"]` (PR 42): host sums `_upload` makes, a program
     at a time: the populations handed to the device (a decode and a prefill
-    population of one iteration are two), the transfers that took (the
-    lanes' one buffer, and the block tables' copy where a table changed)
-    and their bytes.  No ring event and no transfer of its own."""
+    population of one iteration are two, in the pair's ONE buffer since
+    PR 53), the transfers that took (a program's one buffer, and the block
+    tables' copy where a table changed) and their bytes.  No ring event and
+    no transfer of its own."""
     engine = _engine()
     assert engine.stats()["upload"] == {"populations": 0, "transfers": 0,
                                         "bytes": 0}
@@ -525,14 +526,20 @@ def test_upload_sums_grow_by_population():
     pops = sum(bool(r["decode"]) + bool(r["prefill"]) for r in records)
     assert up1["populations"] - up0["populations"] == pops > 0
     assert any(r["decode"] and r["prefill"] for r in records)
-    extra = up1["transfers"] - up0["transfers"] - pops
-    assert 0 < extra <= pops                       # tables changed, not always
-    # two lanes: a T=1 buffer is [2, 8] int32, a T=8 one [2, 29]; a table
-    # copy the cache's whole table
+    # an iteration's populations go in one transfer
+    programs = sum(bool(r["decode"] or r["prefill"]) for r in records)
+    extra = up1["transfers"] - up0["transfers"] - programs
+    assert 0 < extra <= programs < pops            # tables changed, not always
+    # two lanes: a T=1 buffer is [2, 8] int32, a pair's that and the chunk's
+    # compact [2, 30] behind it; a table copy the cache's whole table
     t8 = sum(bool(r["prefill"]) for r in records)
     assert up1["bytes"] - up0["bytes"] == (
-        (pops - t8) * 2 * 8 * 4 + t8 * 2 * 29 * 4
+        programs * 2 * 8 * 4 + t8 * 2 * 30 * 4
         + extra * engine.cache.block_tables.nbytes)
+    ran0, ran1 = s0["programs"], engine.stats()["programs"]
+    assert ran1["programs"] - ran0["programs"] == programs \
+        == ran1["iterations"] - ran0["iterations"]
+    assert ran1["mixed"] - ran0["mixed"] == t8
     assert all(type(v) is int for v in up1.values())
     assert {e["kind"] for e in events.snapshot(plane="engine")
             if e["seq"] > seq} <= {"step", "submit", "admit", "finish",
@@ -773,7 +780,7 @@ def test_stats_hold_what_the_engine_did_once():
     events.reset()                  # a record of this engine alone
     t0 = time.time()
     engine = _engine()
-    engine.generate(list(range(1, 6)), 3)          # greedy T=8 and T=1
+    engine.generate(list(range(1, 6)), 3)          # greedy pair and T=1
     setup = engine.stats()["setup"]
     assert setup["start"] <= t0
     assert {"backend_init", "init_params", "prepare", "pools",
@@ -782,7 +789,7 @@ def test_stats_hold_what_the_engine_did_once():
     assert setup["seconds"]["init_params"] > 0.0
     programs = setup["programs"]
     assert sorted(tuple(p["key"]) for p in programs) == sorted(
-        engine._step_fns) == [(1, False, False, 0), (8, False, False, 0)]
+        engine._step_fns) == [(1, False, False, 0), (8, False, False, 2)]
     for p in programs:
         parts = sum(p[k] for k in ("trace_s", "lower_s", "cache_load_s",
                                    "compile_s"))
@@ -793,7 +800,7 @@ def test_stats_hold_what_the_engine_did_once():
         sum(p["dur"] for p in programs))
     # the same split, by the step's name, where a check asks for it
     made = {name: s["made"] for name, s in engine.compiled_steps().items()}
-    assert set(made) == {"t1", "t8"}
+    assert set(made) == {"t1", "t8_pair2"}
     assert all(m["wall_s"] >= m["trace_s"] + m["lower_s"] > 0
                for m in made.values())
     # a step whose program is there adds no row

@@ -203,7 +203,10 @@ def test_engine_serves_the_configuration_and_counts_its_expert_load():
     moe_stats = stats["moe"]
     assert moe_stats["assignments"] == sum(moe_stats["expert_load"]) \
         == tokens * CFG.n_experts_per_tok * CFG.n_layers
-    assert moe_stats["layer_steps"] >= stats["steps"] * CFG.n_layers
+    # (an iteration that dispatched ran ONE program: a layer, a step)
+    assert moe_stats["layer_steps"] == (
+        stats["programs"]["programs"] * CFG.n_layers)
+    assert stats["programs"]["programs"] == stats["programs"]["iterations"]
     assert 0 < moe_stats["experts_hit"] \
         <= moe_stats["layer_steps"] * CFG.n_experts
     # a dense configuration's stats carry no such key

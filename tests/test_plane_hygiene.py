@@ -376,7 +376,15 @@ def test_latent_prefill_and_share_counters_are_host_sums_in_build_batch():
     assert src.count('"ctx_rows"') == 2
     assert body.count('pf["ctx_rows"] += ') == 1
     assert body.index('pf["rows_valid"] += ') < body.index(
-        'pf["ctx_rows"] += ') < body.index("elif t == 1:")
+        'pf["ctx_rows"] += ') < body.index("elif t == 1 and live:")
+    # PR 53: iterations, programs and the pair's rows are counted where a
+    # plan is made and dispatched, host integers like the others
+    writes = [m.start() for m in re.finditer(
+        r'(?:self\._programs|mixed)\["\w+"\] \+= ', src)]
+    assert len(writes) == 5
+    assert all(src.index("    def step(self)") < w
+               < src.index("    def _snapshot_due(") for w in writes)
+    assert src.count("self._programs") == 5     # made, read by `stats()`
     names = re.findall(r'name="(\w+)"',
                        (PKG / "ops" / "attention.py").read_text())
     assert "latent_decode_attention" in names
@@ -448,7 +456,8 @@ def test_the_parts_the_clocks_and_the_timeline_add_no_transfer_and_no_event():
 
 def test_a_population_costs_the_transfers_the_upload_counter_says(
         monkeypatch):
-    """PR 42: a population's lane arrays are one host buffer and reach the
+    """PR 42: a program's lane arrays (a pair's: both populations', since
+    PR 53) are one host buffer and reach the
     device by ONE call into the runtime, in `_upload`; `_run_step` hands
     nothing over (a compact program's `rows` ride in the buffer); the block
     tables go only when one changed.  Counted under patched `jnp.asarray` /
@@ -493,10 +502,8 @@ def test_a_population_costs_the_transfers_the_upload_counter_says(
     eng._upload = tagged("upload", upload)
     eng._run_step = tagged("run_step", run_step)
 
-    def population(live, t, prefill=False):
+    def program(arrays):
         up0, n0 = dict(eng.stats()["upload"]), len(calls)
-        arrays, _ = eng._build_batch(live, t, prefill)
-        assert len(calls) == n0                     # assembling uploads nothing
         eng._run_step(eng._upload(arrays))
         up1 = eng.stats()["upload"]
         grew = {k: up1[k] - up0[k] for k in up1}
@@ -507,6 +514,12 @@ def test_a_population_costs_the_transfers_the_upload_counter_says(
         assert all(w == "upload" for w, _ in new)   # none in `_run_step`
         return arrays, len(new)
 
+    def population(live, t):
+        n0 = len(calls)
+        arrays, _ = eng._build_batch(live, t)
+        assert len(calls) == n0                     # assembling uploads nothing
+        return program(arrays)
+
     # a T=1 population with unchanged tables: exactly one transfer
     eng.cache.device_tables()
     (t, _, lanes, _, rows), n = population([], 1)
@@ -515,10 +528,13 @@ def test_a_population_costs_the_transfers_the_upload_counter_says(
     eng.cache._dev_tables = None
     assert population([], 1)[1] == 2
     assert population([], 1)[1] == 1
-    # a compact prefill population: one, its `rows` in the buffer
-    (t, _, lanes, _, rows), n = population([], 8, True)
-    assert n == 1 and lanes.shape == (1, 3 * 8 + 6)
-    assert rows.base is lanes and (rows == eng.max_lanes).all()
+    # a pair's two populations (nobody's, here): ONE, the decoding lanes'
+    # [2, 8] and the chunk's compact [1, 30] in one flat buffer, the
+    # chunk's `rows` in it
+    flat, _, (chunk, _, rows) = engine_mod._pair_views(eng.max_lanes, 1, 8)
+    _, n = program((8, False, flat, None, rows))
+    assert n == 1 and flat.shape == (2 * 8 + 1 * (3 * 8 + 6),)
+    assert chunk.base is flat and (rows == eng.max_lanes).all()
     # and so through the loop: a request's populations, one to two each
     up0, n0 = dict(eng.stats()["upload"]), len(calls)
     eng._upload, eng._run_step = upload, run_step

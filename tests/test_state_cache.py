@@ -234,7 +234,7 @@ def test_compiled_steps_count_the_state_buffers_copies():
     eng = _engine(prefill_lanes=2)
     _run(eng, eng.submit(_prompts(5, 16, (3,))[0], 3))
     steps = eng.compiled_steps()
-    assert set(steps) == {"t8_lanes1", "t8_lanes2", "t1"}
+    assert set(steps) == {"t8_pair1", "t8_pair2", "t1"}
     assert all(isinstance(s["state_copies"], int) for s in steps.values())
 
 
@@ -332,7 +332,7 @@ def test_one_part_layers_adopt_a_snapshot_behind_shared_blocks():
     # a cache whose state part has other layers installs none of it
     assert _engine(prefill_lanes=2).import_prefix(payload) == 0
     steps = eng.compiled_steps()
-    assert set(steps) == {"t8_lanes1", "t8_lanes2", "t1"}
+    assert set(steps) == {"t8_pair1", "t8_pair2", "t1"}
     assert all(isinstance(s["state_copies"], int) and "temp_bytes" in s
                and "pool_copies" in s and "weight_bytes_copied" in s
                for s in steps.values())

@@ -1262,18 +1262,22 @@ def test_nemotronh_steps_fit_a_v5e_with_a_state_part_of_its_own_layers(
     assert not any(_pool_block_updates(text, p.shape) for p in pools[:2])
 
 
-@pytest.mark.parametrize("t,rows", [(1, 8), (32, 8), (32, 2), (32, 1)],
-                         ids=["t1", "t_prefill_chunk", "compact_2_rows",
-                              "compact_1_row"])
+@pytest.mark.parametrize("t,rows,pair", [
+    (1, 8, False), (32, 8, False), (32, 2, False), (32, 1, False),
+    (32, 2, True), (32, 1, True)],
+    ids=["t1", "t_prefill_chunk", "compact_2_rows", "compact_1_row",
+         "pair_2_rows", "pair_1_row"])
 def test_the_engines_entry_is_the_step_behind_a_few_slices(v5e, as_on_chip,
-                                                           t, rows):
+                                                           t, rows, pair):
     """PR 42: the program the engine runs takes a population's lane arrays
     as ONE int32 buffer [rows, 3 T + 5 (+ 1: a compact program's `rows`)]
     and unpacks it in front of the step `_make_step_fn` gives (which the
     tests above and the benchmark's tools lower by itself).  Compiled for
     the chip it is that step: the pools donated through the outer call and
     left where they are, the same kernels, no scratch to speak of beyond
-    the step's."""
+    the step's.  The pair's entry (PR 53) likewise: ONE flat buffer, the
+    decoding lanes' [lanes, 8] and behind it the chunk's [rows, 3 T + 6],
+    where its step has seventeen arrays."""
     from ray_tpu.inference.engine import InferenceEngine
     cfg = gpt.GPTConfig(vocab_size=512, n_layers=4, d_model=25 * 64,
                         n_heads=25, d_ff=256, max_seq_len=256, scan_unroll=2)
@@ -1283,6 +1287,7 @@ def test_the_engines_entry_is_the_step_behind_a_few_slices(v5e, as_on_chip,
     eng = object.__new__(InferenceEngine)
     eng.model, eng.config, eng._capture_logp = gpt, cfg, False
     eng.backend, eng._step_impls = "tpu", {}
+    eng._pairs, eng.max_lanes = pair, lanes
     params = jax.tree.map(
         lambda x: arg(x.shape, x.dtype),
         jax.eval_shape(lambda k: gpt.serving_params(
@@ -1291,25 +1296,139 @@ def test_the_engines_entry_is_the_step_behind_a_few_slices(v5e, as_on_chip,
                            cfg.head_dim), cfg.dtype)
     tables = arg((lanes, cfg.max_seq_len // block_size), jnp.int32)
     last_tok = arg((lanes,), jnp.int32)
-    step = eng._make_step_fn(False, False, compact).lower(
-        params, pool, pool, arg((rows, t), jnp.int32),
-        arg((rows, t), jnp.int32), arg((rows, t), jnp.bool_), tables,
-        arg((rows,), jnp.int32), arg((rows,), jnp.int32),
-        arg((rows,), jnp.float32), arg((rows,), jnp.uint32),
-        arg((rows,), jnp.int32),
-        *((arg((rows,), jnp.int32),) if compact else ()),
-        last_tok).compile()
+
+    def population(rows, t, compact):
+        return (arg((rows, t), jnp.int32), arg((rows, t), jnp.int32),
+                arg((rows, t), jnp.bool_), arg((rows,), jnp.int32),
+                arg((rows,), jnp.int32), arg((rows,), jnp.float32),
+                arg((rows,), jnp.uint32), arg((rows,), jnp.int32),
+                *((arg((rows,), jnp.int32),) if compact else ()))
+
+    if pair:
+        step = eng._make_step_fn(False, False, True, True).lower(
+            params, pool, pool, population(lanes, 1, False),
+            population(rows, t, True), tables, last_tok).compile()
+        buffer = arg((lanes * 8 + rows * (3 * t + 6),), jnp.int32)
+    else:
+        mine = population(rows, t, compact)
+        step = eng._make_step_fn(False, False, compact).lower(
+            params, pool, pool, *mine[:3], tables, *mine[3:],
+            last_tok).compile()
+        buffer = arg((rows, 3 * t + 5 + compact), jnp.int32)
     entry = eng._make_entry(t, False, False, rows if compact else 0).lower(
-        params, pool, pool, arg((rows, 3 * t + 5 + compact), jnp.int32),
-        tables, last_tok).compile()
+        params, pool, pool, buffer, tables, last_tok).compile()
     text, memory = entry.as_text(), entry.memory_analysis()
     pool_bytes = 2 * math.prod(pool.shape)
     assert memory.alias_size_in_bytes == 2 * pool_bytes \
         == step.memory_analysis().alias_size_in_bytes
     assert count_pool_copies(text, pool.shape) == 0
     assert _kernel_names(text) == _kernel_names(step.as_text())
+    if pair:        # both populations' kernels in the one program
+        assert {"paged_decode_attention", "paged_rows_write"} <= set(
+            _kernel_counts(text))
     scratch = step.memory_analysis().temp_size_in_bytes
     assert abs(memory.temp_size_in_bytes - scratch) < 256 * 1024    # of MBs
-    # one lane argument where the step has eight or nine
+    # one lane argument where the step has eight or nine, or a pair's two
+    # populations (of eight and nine)
     assert len(entry.input_shardings[0]) == len(step.input_shardings[0]) \
-        - (8 if compact else 7)
+        - (1 if pair else 8 if compact else 7)
+
+
+# The serve cells, and the pair's program each is compiled at here: its
+# widest where its chunk is short, the form of a question behind a cached
+# document (a quarter of the chunk, one row) where its chunk is long.
+_PAIRS = {
+    "serve_gpt2xl_decode": (32, 4), "serve_olmoe_decode": (32, 4),
+    "serve_axk1_docs_decode": (128, 1),
+    "serve_evabyte_sessions_decode": (128, 1),
+    "serve_dots3_docs_decode": (128, 1),
+    "serve_falconh1_chat_decode": (64, 1),
+    "serve_nemotron3_agents_decode": (64, 1),
+    "serve_trinity_docs_decode": (128, 1)}
+
+
+def _cell_engine(cell, device):
+    """(an engine's programs without an engine, the served weights, the
+    pools and state, the block tables) of a serve cell, as shapes on
+    `device`, from the files the benchmark runs the cell from."""
+    import importlib
+
+    from benchmark import manifest
+    from ray_tpu.inference.engine import InferenceEngine
+    from ray_tpu.inference.kv_cache import PagedKVCache
+    m = manifest.load()
+    file = m.load_config(m.cells[cell]["config"])
+    cfg = manifest.model_config(file, None)
+    traffic = m.load_traffic(m.cells[cell]["traffic"])["engine"]
+    model = importlib.import_module(file["module"])
+    arg = _arg_on(device)
+    eng = object.__new__(InferenceEngine)
+    eng.model, eng.config, eng._capture_logp = model, cfg, False
+    eng.backend, eng._step_impls, eng._pairs = "tpu", {}, True
+    eng.max_lanes = traffic["max_lanes"]
+    seen = {}
+
+    def pools():
+        cache = PagedKVCache.for_model(
+            model, cfg, num_blocks=traffic["num_blocks"],
+            block_size=traffic["block_size"], max_lanes=eng.max_lanes,
+            max_seq_len=traffic.get("max_seq_len", cfg.max_seq_len),
+            ahead=2 * traffic["prefill_chunk"])
+        seen["tables"] = cache.block_tables.shape
+        return cache.step_pools
+
+    def on_device(tree):
+        return jax.tree.map(lambda x: arg(x.shape, x.dtype), tree)
+
+    k, v = on_device(jax.eval_shape(pools))
+    params = on_device(jax.eval_shape(
+        lambda key: model.serving_params(model.init_params(cfg, key), cfg),
+        jax.random.key(0)))
+    held = getattr(cfg, "n_experts_held", 0) or getattr(cfg, "n_experts", 0)
+    carried = (arg((eng.max_lanes,), jnp.int32),
+               *((arg((held + 2,), jnp.int32),) if held else ()))
+    return eng, params, (k, v), arg(seen["tables"], jnp.int32), carried
+
+
+@pytest.mark.parametrize("cell", list(_PAIRS))
+def test_the_pairs_program_fits_a_v5e_and_reads_weights_and_pools_in_place(
+        v5e, as_on_chip, cell):
+    """PR 53: the program of an iteration that admits, the prefilling
+    lanes' [rows, T] beside the decoding lanes' [max_lanes, 1], compiled for
+    the chip at each serve cell's own sizes beside the cell's T=1 program:
+    arguments and temporaries under the compiler's 15.75 GB, every pool and
+    state buffer donated and left where it is, no more bytes of weights
+    copied than the T=1 program copies, and the kernels of BOTH populations
+    in one program: the T=1 program's own and a chunk's."""
+    t, rows = _PAIRS[cell]
+    eng, params, pools, tables, carried = _cell_engine(cell, v5e[0])
+    arg, lanes = _arg_on(v5e[0]), eng.max_lanes
+    one = eng._make_entry(1, False, False, 0).lower(
+        params, *pools, arg((lanes, 8), jnp.int32), tables,
+        *carried).compile()
+    pair = eng._make_entry(t, False, False, rows).lower(
+        params, *pools, arg((lanes * 8 + rows * (3 * t + 6),), jnp.int32),
+        tables, *carried).compile()
+    text, memory = pair.as_text(), pair.memory_analysis()
+    held = jax.tree.leaves(pools)
+    # (a state's tail buffer is padded to the chip's tiles: no less)
+    assert memory.alias_size_in_bytes \
+        == one.memory_analysis().alias_size_in_bytes >= sum(
+            math.prod(p.shape) * p.dtype.itemsize for p in held)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    assert memory.argument_size_in_bytes \
+        - one.memory_analysis().argument_size_in_bytes < 2 ** 20
+    for p in held:                      # rows in blocks, and a state's slots
+        assert count_pool_copies(text, p.shape) == 0, p.shape
+    # (`copy-done`: XLA's own prefetch of a layer's slice, which reads it
+    # once in the product's place)
+    copied, alone = ({k: v for k, v in count_weight_bytes_copied(
+        x, params).items() if k != "copy-done"}
+        for x in (text, one.as_text()))
+    assert sum(copied.values()) <= sum(alone.values()) + 2 ** 20, (
+        copied, alone)
+    # the decoding lanes' kernels are all there, under their names
+    assert set(_kernel_counts(one.as_text())) <= set(_kernel_counts(text))
+    # the last tokens of every lane come back, the sampled rows of both
+    assert f"s32[{lanes + rows}]" in text

@@ -17,13 +17,14 @@ from ray_tpu.inference import InferenceEngine
 BIG_SEED = 3_000_000_019            # above 2^31: negative as an int32's bits
 
 CASES = {
-    # a K/V cache, every program [max_lanes, T]
+    # a K/V cache, a chunk of every lane (the rows rule)
     "kv": ("gpt", "nano", dict(max_lanes=2, block_size=8, prefill_chunk=8)),
-    # the same behind a proposer: verify steps of T = 2 and 3
+    # the same behind a proposer: verify steps of T = 2 and 3, the chunk
+    # in a program of its own
     "kv_spec": ("gpt", "nano", dict(max_lanes=2, block_size=8,
                                     prefill_chunk=8, spec_k=2,
                                     draft_proposer="ngram")),
-    # a latent cache, compact prefill programs of 1 and `prefill_lanes` rows
+    # a latent cache, the pair's programs at 1 and `prefill_lanes` rows
     "latent_prefill_lanes": ("axk1", "axk1-nano-share", dict(
         max_lanes=3, block_size=8, prefill_chunk=8, prefill_lanes=2)),
     # EVA's windows: the compaction program beside the steps
@@ -92,30 +93,41 @@ def _serve(eng):
 
 def _spy(eng, built, received):
     """Record (copies of) what `_build_batch` hands over and, by a callback
-    from inside the compiled program, what the step body is called with."""
+    from inside the compiled program, what the step body is called with: a
+    program's populations in order (a pair's two: the decoding lanes',
+    then the chunk's)."""
     build, make = eng._build_batch, eng._make_step_fn
 
-    def build_batch(live, t, *prefill):
-        arrays, chunks = build(live, t, *prefill)
+    def build_batch(live, t, *more):
+        arrays, chunks = build(live, t, *more)
         _, _, _, host, rows = arrays
         built.append([np.array(a) for a in host]
                      + ([] if rows is None else [np.array(rows)]))
         return arrays, chunks
 
-    def make_step_fn(sample, spec=False, compact=False):
-        step = make(sample, spec, compact)
+    def make_step_fn(sample, spec=False, compact=False, pair=False):
+        step = make(sample, spec, compact, pair)
+
+        def seen(*populations):
+            flat = [x for p in populations for x in p]
+            cut = len(populations[0])
+            jax.debug.callback(
+                lambda *a: received.append([
+                    [np.array(x) for x in p] for p in (a[:cut], a[cut:])
+                    if p]),
+                *flat, ordered=True)
 
         def body(params, k, v, tokens, positions, valid, tables, *rest):
-            lane_arrays = (tokens, positions, valid,
-                           *rest[:6 if compact else 5])
-            jax.debug.callback(
-                lambda *a: received.append([np.array(x) for x in a]),
-                *lane_arrays, ordered=True)
+            seen((tokens, positions, valid, *rest[:6 if compact else 5]))
             return step(params, k, v, tokens, positions, valid, tables,
                         *rest)
 
-        body.__name__ = step.__name__
-        return body
+        def pair_body(params, k, v, decode, chunk, *rest):
+            seen(decode, chunk)
+            return step(params, k, v, decode, chunk, *rest)
+
+        body.__name__ = pair_body.__name__ = step.__name__
+        return pair_body if pair else body
 
     eng._build_batch, eng._make_step_fn = build_batch, make_step_fn
 
@@ -129,8 +141,10 @@ def test_the_step_body_receives_build_batchs_arrays_bit_for_bit(case):
     jax.effects_barrier()
     # (`_warm_widths`' programs run a population nobody is in, which no
     # `_build_batch` made: every row masked and no lane's)
-    received = [got for got in received
-                if len(got) == 8 or (got[8] < eng.max_lanes).any()]
+    received = [got for program in received
+                if len(program[-1]) == 8
+                or (program[-1][8] < eng.max_lanes).any()
+                for got in program]
     assert len(received) == len(built) > 10
     for host, got in zip(built, received):
         assert len(host) == len(got)
@@ -144,11 +158,11 @@ def test_the_step_body_receives_build_batchs_arrays_bit_for_bit(case):
     widths = {h[0].shape for h in built}
     lanes, chunk = eng.max_lanes, eng.prefill_chunk
     assert (lanes, 1) in widths
-    if eng.prefill_lanes < lanes:       # compact: both widths were served
-        assert {(1, chunk), (eng.prefill_lanes, chunk)} <= widths
-        assert {len(h) for h in built} == {8, 9}
-    else:
-        assert (lanes, chunk) in widths
+    # a chunk is compact; an engine that names its lanes served both widths
+    assert {len(h) for h in built} == {8, 9}
+    assert (eng.prefill_lanes, chunk) in widths
+    if eng._widths:
+        assert (1, chunk) in widths
     if eng.spec_k:
         assert widths & {(lanes, 2), (lanes, 3)}
     # the sampled lane's seed went over by its bits, its temperature too
