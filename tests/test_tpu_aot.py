@@ -1347,6 +1347,16 @@ _PAIRS = {
     "serve_trinity_docs_decode": (128, 1)}
 
 
+# Bytes of weights the pair's program may copy over its cell's T=1 program's
+# (a MiB anywhere else): what XLA re-lays for the chunk's products, once a
+# step, as the prefill programs the pair replaces did (ROADMAP.md S18's
+# kind; PERF.md section 7).  Falcon-H1: one of `wk` / `wv`, the whole
+# [9, 5120, 512] stack (47.2 MB of 12.7 GB read); Nemotron-3: a
+# [2688, 2816] matrix converted (15.1 MB of 8.7 GB).
+_PAIR_COPIES = {"serve_falconh1_chat_decode": 48 * 2 ** 20,
+                "serve_nemotron3_agents_decode": 16 * 2 ** 20}
+
+
 def _cell_engine(cell, device):
     """(an engine's programs without an engine, the served weights, the
     pools and state, the block tables) of a serve cell, as shapes on
@@ -1398,7 +1408,8 @@ def test_the_pairs_program_fits_a_v5e_and_reads_weights_and_pools_in_place(
     the chip at each serve cell's own sizes beside the cell's T=1 program:
     arguments and temporaries under the compiler's 15.75 GB, every pool and
     state buffer donated and left where it is, no more bytes of weights
-    copied than the T=1 program copies, and the kernels of BOTH populations
+    copied than the T=1 program copies (but `_PAIR_COPIES`), and the
+    kernels of BOTH populations
     in one program: the T=1 program's own and a chunk's."""
     t, rows = _PAIRS[cell]
     eng, params, pools, tables, carried = _cell_engine(cell, v5e[0])
@@ -1426,8 +1437,8 @@ def test_the_pairs_program_fits_a_v5e_and_reads_weights_and_pools_in_place(
     copied, alone = ({k: v for k, v in count_weight_bytes_copied(
         x, params).items() if k != "copy-done"}
         for x in (text, one.as_text()))
-    assert sum(copied.values()) <= sum(alone.values()) + 2 ** 20, (
-        copied, alone)
+    assert sum(copied.values()) <= sum(alone.values()) + _PAIR_COPIES.get(
+        cell, 2 ** 20), (copied, alone)
     # the decoding lanes' kernels are all there, under their names
     assert set(_kernel_counts(one.as_text())) <= set(_kernel_counts(text))
     # the last tokens of every lane come back, the sampled rows of both
