@@ -207,8 +207,12 @@ _STALL_S = 0.5
 # The rows a step's products may have and still be bound by the weights
 # they read: a v5e multiplies some 240 rows of bf16 in the time it reads
 # the matrix (197 TFLOP/s over 819 GB/s), so under that many a masked row of
-# a pair's chunk costs next to nothing.  An engine that is not told its
-# `prefill_lanes` takes as many as stay under it (`_chunk_lanes`).
+# a pair's chunk costs its lane's share of the chunk's attention and write
+# and little else.  An engine that is not told its `prefill_lanes` takes as
+# many as stay under it (`_chunk_lanes`).  gpt2-xl's pair at 16 lanes and a
+# chunk of 32, one lane prefilling: 8.9 ms at 2 lanes of chunk, 9.6 at 4
+# (the rule's), 11.7 at 8, where the two programs before it took 20.4
+# (PERF.md section 6, PR 53).
 _PAIR_ROWS = 256
 
 
@@ -572,7 +576,7 @@ class InferenceEngine:
     two programs, the decoding lanes' first.  `prefill_lanes` not named is
     as many lanes as keep the pair's rows (max_lanes + prefill_lanes x T)
     under `_PAIR_ROWS`, where a product is still bound by the weights it
-    reads and a masked row costs next to nothing, a power of two.  An
+    reads and a masked row costs little, a power of two.  An
     engine whose `prefill_lanes` is NAMED, under max_lanes, says that its
     chunks are long (rows of them are compute): it also has the program at
     a quarter of T, for steps in which no lane has more than that left to
@@ -1689,28 +1693,31 @@ class InferenceEngine:
         `upload` (the host arrays and the block tables handed to the
         device)."""
         pair = bool(prefill) and self._pairs
-        flat, *views = (_pair_views(self.max_lanes,
-                                    self._chunk_rows(prefill), t)
-                        if pair else (None, None, None))
+        flat = ahead = chunk = None
+        if pair:
+            flat, ahead, chunk = _pair_views(
+                self.max_lanes, self._chunk_rows(prefill), t)
+        # (whose, at how many positions, as a chunk?, in which part of a
+        # pair's buffer)
+        populations = [(decode, 1 if pair else t, False, ahead)] * (
+            pair or bool(decode))
+        if prefill:
+            populations.append((prefill, t, True, chunk))
         built, chunks, news, due = [], {}, {}, []
-        for live, out in zip((decode, prefill), views):
-            if not live and not (pair and out is views[0]):
-                continue
+        for live, at, chunked, out in populations:
             if self._closes and live:
                 with spans.phase("engine.build_batch", "windows") as ph:
                     self._close_windows(live)
                 parts["windows"] += ph.seconds
             with spans.phase("engine.build_batch", "assemble") as ph:
-                arrays, fed = self._build_batch(
-                    live, 1 if pair and live is decode else t,
-                    live is prefill, out)
+                arrays, fed = self._build_batch(live, at, chunked, out)
                 built.append(arrays)
                 chunks.update(fed)
                 for lane, req in live:
                     news[lane] = int(req.samples(req.next_fed, fed[lane]))
                     req.ahead_len += fed[lane]
                     req.ahead_new += news[lane]
-                    if self._checkpoints and live is prefill:
+                    if self._checkpoints and chunked:
                         key = self._snapshot_due(lane, req)
                         if key is not None:
                             due.append((lane, key))

@@ -1087,6 +1087,11 @@ def paged_decode_attention(q, k_pool, v_pool, block_tables, ctx_lens,
                             interpret=interpret)
 
 
+# (jitted like `_latent_walk_call`, below: a process traces the kernel once a
+# shape and a program lowers it once, whichever layer body or program calls
+# it: the T=1 program and the pair's call it alike)
+@functools.partial(jax.jit, static_argnames=("kh", "scale", "kb", "name",
+                                             "interpret"))
 def _paged_walk_call(q, k_pool, v_pool, block_tables, ctx_lens, starts,
                      layer, *, kh: int, scale: Optional[float], kb: int,
                      name: str, interpret: bool):

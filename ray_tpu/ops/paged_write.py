@@ -173,6 +173,9 @@ def write_plan(pools, positions, block_tables, valid):
             jax.lax.bitcast_convert_type(bits, jnp.int32).reshape(-1), shift)
 
 
+# (jitted: a process traces the call once a shape and a program lowers it
+# once, not once a layer body and a program: PERF.md section 6, PRs 49, 53)
+@functools.partial(jax.jit, static_argnames=("interpret",))
 def paged_rows_write(pools, rows, block_tables, positions, valid, layer=0,
                      *, interpret: bool = False):
     """`attention.paged_rows_update` as one Pallas call: pools [L, NB, BS,

