@@ -498,16 +498,20 @@ def _lane_views(n: int, t: int, compact: bool, max_lanes: int, out=None):
                    counters), rows
 
 
+# The columns of a T=1 population's buffer (`_lane_views`: 3 x 1 + 5).
+_T1_COLUMNS = 8
+
+
 def _pair_views(max_lanes: int, n: int, t: int):
     """The buffer of a pair's program, flat: the decoding lanes' [max_lanes,
     8] (`_lane_views` at one position) and behind it the chunk's compact
     [n, 3 t + 6], both populations in ONE transfer.  Returns (buffer, the
     decode part's `_lane_views`, the chunk's)."""
-    cut = max_lanes * 8
+    cut = max_lanes * _T1_COLUMNS
     flat = np.zeros((cut + n * (3 * t + 6),), np.int32)
     return (flat,
             _lane_views(max_lanes, 1, False, max_lanes,
-                        flat[:cut].reshape(max_lanes, 8)),
+                        flat[:cut].reshape(max_lanes, _T1_COLUMNS)),
             _lane_views(n, t, True, max_lanes, flat[cut:].reshape(n, -1)))
 
 
@@ -1989,10 +1993,10 @@ class InferenceEngine:
 
         def entry(params, k, v, lanes, tables, *carried):
             if pair:
-                cut = self.max_lanes * 8
+                cut = self.max_lanes * _T1_COLUMNS
                 return step(
                     params, k, v,
-                    _unpack_lanes(lanes[:cut].reshape(-1, 8), 1),
+                    _unpack_lanes(lanes[:cut].reshape(-1, _T1_COLUMNS), 1),
                     _unpack_lanes(lanes[cut:].reshape(compact, -1), t),
                     tables, *carried)
             tokens, positions, valid, *rest = _unpack_lanes(lanes, t)

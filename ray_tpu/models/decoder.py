@@ -583,15 +583,11 @@ def eva_attention(h, p, spec, config, mesh, position_offset=0):
     return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(h.dtype))
 
 
-def _eva_project(h, p, spec, config, offset):
-    q, k, v = _qkv(spec, h, p)
-    return (rope(q, spec.rope_theta, offset), rope(k, spec.rope_theta, offset),
-            v)
-
-
 def _eva_attend(rows, pools, p, spec, config, lanes):
-    """`_heads_attend` over a table whose rows are not one a token: the
-    summary rows of a lane's closed windows, then the exact rows of its
+    """`_heads_attend` (between `HEADS`' own row-wise stages: the same
+    projections, rotation and output) over a table whose rows are not one a
+    token: the summary rows of a lane's closed windows, then the exact rows
+    of its
     open one (`ops.eva_row`).  Rotation goes by the true position, the write
     and the mask by the row, computed from it here; a slice lies inside one
     window (the engine cuts chunks at window edges), so its rows are
@@ -606,10 +602,6 @@ def _eva_attend(rows, pools, p, spec, config, lanes):
     n_rows = eva_row(jnp.maximum(lanes.ctx_lens - 1, 0), c.window_size,
                      c.chunk_size) + 1
     return _attend_rows(*rows, pools, p, c, lanes, at, n_rows)
-
-
-def _eva_finish(attn, rows, h, p, spec, config):
-    return jnp.einsum("blhk,hkd->bld", attn, p["wo"].astype(rows[0].dtype))
 
 
 def eva_compact(pools, p, config, src, dst, live):
@@ -922,7 +914,7 @@ LATENT = Attention(latent_attention, _latent_project, _latent_attend,
                    cast=("w_qa", "w_qb", "w_kva", "w_kvb", "wo",
                          "w_head_gate", "w_iq", "w_ik", "w_iw"),
                    absorbed="w_kvb")
-EVA = Attention(eva_attention, _eva_project, _eva_attend, _eva_finish,
+EVA = Attention(eva_attention, _heads_project, _eva_attend, _heads_finish,
                 rows=lambda c: CacheRows(c.n_kv_heads, c.head_dim,
                                          c.window_size, c.chunk_size),
                 cast=("wq", "wk", "wv", "wo"),
@@ -1287,11 +1279,11 @@ def _block_cached(x, pools, p, spec: Spec, run: Run, config, lanes: tuple,
     (`Attention.attend`).  x [B, T, D] of one population (`lanes`: its
     `Lanes`, positions [B, T] absolute, ctx_lens [B] the context length
     including this slice) or [rows, 1, D] of several laid end to end;
-    `offset` and `valid` as x's rows lie.  A run with a mixer beside its attention hands
-    that the pools behind the attention's and each row's slot in them
-    (`Lanes.slots`).  A run of one part runs that part alone, over the pools
-    that are its (`Run.pools`).  Returns (x, pools, the expert layer's load
-    or None)."""
+    `offset` and `valid` as x's rows lie.  A run with a mixer beside its
+    attention hands that the pools behind the attention's and each row's
+    slot in them (`Lanes.slots`).  A run of one part runs that part alone,
+    over the pools that are its (`Run.pools`).  Returns (x, pools, the
+    expert layer's load or None)."""
     m = spec.mult or Multipliers()
     if run.attn is not None or run.mixer is not None:
         h = _norm(spec, x, p, spec.attn_norm)
@@ -1695,11 +1687,11 @@ def forward_cached(family, params: dict, tokens: jax.Array,
     i's is slot i).
 
     `chunk`: a SECOND population in the same step, as (tokens [N, C], its
-    `Lanes`): the prefilling lanes' rows beside the decoding lanes' [B, 1].  The rows
-    of both are laid end to end, [B T + N C, 1], and every row-wise product
-    of a layer (norms, projections, gates, the feed-forward with its
-    router) runs once over all of them, so a leaf of the weights is read
-    once a step; rotation goes by each row's own position, and what is a
+    `Lanes`): the prefilling lanes' rows beside the decoding lanes' [B, 1].
+    The rows of both are laid end to end, [B T + N C, 1], and every row-wise
+    product of a layer (norms, projections, gates, the feed-forward with
+    its router) runs once over all of them, so a leaf of the weights is
+    read once a step; rotation goes by each row's own position, and what is a
     lane's own (its rows' write, its attention or scan, by the kernel its
     population's T takes) runs a population at a time on the same pools,
     the first's before the second's (`_part_cached`).  x then comes back

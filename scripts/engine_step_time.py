@@ -568,9 +568,8 @@ def time_mixed(name, out):
     out[f"mixed_{name}_t1_ms"] = run(alone, 100)
     out[f"mixed_{name}_admit_ms"] = run(admit, 40)
     out[f"mixed_{name}_programs"] = len(admit)
-    out[f"mixed_{name}_chunk"] = [
-        int(b[3].shape[0]) if b[3] is not None else lanes
-        for b in admit[-1:]] + [chunk]
+    rows = admit[-1][3]             # the chunk's rows, where it is compact
+    out[f"mixed_{name}_chunk"] = [lanes if rows is None else len(rows), chunk]
     eng.shutdown()      # (one engine's weights a process: a second `mixed=`
     #                     of a cell this size needs a process of its own)
 
