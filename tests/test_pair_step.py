@@ -33,12 +33,12 @@ CASES = {
     # the same under dropless experts: an expert's rows are another set
     "paged_experts": ("llama", "olmoe-nano", dict(
         max_lanes=4, block_size=8, prefill_chunk=8), 5, (13, 20, 30)),
-    # latent rows; named lanes: the quarter-T form and the one-row width
+    # latent rows, a share of the experts held
     "latent": ("axk1", "axk1-nano-share", dict(
-        max_lanes=4, block_size=8, prefill_chunk=8, prefill_lanes=2),
-        5, (13, 20, 30)),
+        max_lanes=4, block_size=8, prefill_chunk=8), 5, (13, 20, 30)),
     # EVA (window 32, chunk 4): windows close inside the prompts' chunks
-    # and under the decoding lane, the compaction program in between
+    # and under the decoding lane, the compaction program in between;
+    # named lanes: the quarter-T form and the one-row width
     "windowed": ("evabyte", "evabyte-nano", dict(
         max_lanes=3, block_size=8, prefill_chunk=16, prefill_lanes=2,
         num_blocks=64), 27, (70, 41, 33)),
