@@ -791,9 +791,18 @@ class InferenceEngine:
         self._eva = ({"decode_steps": 0, "ctx_tokens": 0, "rows_attended": 0}
                      if kind == "windowed" else None)
         self._compact: dict = {}
-        # Over a state cache: the tokens its scans (T > 1) and its updates
-        # (T = 1) stepped over.
+        # Over a state cache: the tokens its mixers' scans (T > 1) and
+        # updates (T = 1) stepped over, under "ssm".  Where the mixers
+        # keep no recurrence (a gated short convolution: the cache's state
+        # part is its tails alone) the part's counters go under "state"
+        # and the mixers' own under "conv": the populations (`steps`) and
+        # the valid rows their layers convolved, T=1 and chunk apart.
         self._stateful = kind == "state"
+        self._recurrent = self._stateful and any(
+            run.mixer is not None and run.mixer.state(self.config).heads
+            for run in self.model.spec(self.config).runs)
+        self._conv = {"steps_t1": 0, "rows_t1": 0, "steps_chunk": 0,
+                      "rows_chunk": 0}
         # The layers a step runs by kind (K/V rows, state, experts): in a
         # stack of one-part layers no reader can take them from `n_layers`.
         self._layers = layer_counts(self.model.spec(self.config),
@@ -1156,8 +1165,11 @@ class InferenceEngine:
             # back in mid-sequence so far.
             # A state cache: slots of state and of snapshots, what the
             # index did with the snapshots, and the tokens stepped over.
-            **({"ssm": {**self.cache.kind_stats(), **self._ssm}}
-               if self._stateful else {}),
+            **({} if not self._stateful else
+               {"ssm": {**self.cache.kind_stats(), **self._ssm}}
+               if self._recurrent else
+               {"state": self.cache.kind_stats(),
+                "conv": {**self._conv, "layers": self._layers["state"]}}),
             **({} if self._sparse is None else {
                 "sparse": dict(self._sparse)}),
             # A cache with a sliding part: its blocks given back in
@@ -1238,9 +1250,11 @@ class InferenceEngine:
             text, out[name] = report(made, self._step_fns[key],
                                      self._step_avals[key])
             if self._stateful:
-                # The state buffer too is updated where it is.
+                # The state part's (largest) buffer too is updated where
+                # it is.
                 out[name]["state_copies"] = count_pool_copies(
-                    text, self.cache.buffers[0].shape)
+                    text, max(self.cache.buffers,
+                              key=lambda b: b.nbytes).shape)
         if "made" in self._compact:
             _, out[f"compact_lanes{self.prefill_lanes}"] = report(
                 self._compact["made"], self._compact["fn"],
@@ -1856,9 +1870,13 @@ class InferenceEngine:
             self.cache.ensure_capacity(lane, start + chunk)
         fed_now = sum(chunks.values())
         self._tokens_run += fed_now
-        if self._stateful:
+        if self._recurrent:
             self._ssm["tokens_scanned" if t > 1 else "tokens_updated"] \
                 += fed_now
+        elif self._stateful:
+            which = "chunk" if t > 1 else "t1"
+            self._conv[f"steps_{which}"] += 1
+            self._conv[f"rows_{which}"] += fed_now
         if prefill:
             pf = self._prefill
             pf["steps"] += 1

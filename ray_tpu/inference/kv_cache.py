@@ -779,32 +779,37 @@ class SlidingRows(CachePart):
 class LaneState(CachePart):
     """State that is not rows: what a layer's mixer keeps of a lane
     (`rows`: `decoder.StateRows`), of a fixed size whatever the lane's
-    length.  One SLOT a lane a layer in two buffers beside the K and V
-    pools, over its OWN `n_layers` (the model's layers that have the mixer,
-    which need not be those that have K and V rows): `state` [n_layers,
-    max_lanes + 1, heads, d_state, head_dim] (narrow heads folded:
-    `ops.ssm.state_shape`) and
-    `tail` [n_layers, max_lanes + 1, rows x width]; a lane's slot is its
-    index, the last slot is where a program sends the rows nobody has),
-    overwritten by every step: `step_pools` hands the step all four as one
-    tuple (ops/ssm.py; `count_pool_copies` of the state's shape must be 0
-    too).  A prefix of sealed K/V blocks is worthless without the state at
-    its end, so the prefix index holds SNAPSHOTS beside its blocks: a
-    `SealedIndex` of snapshot slots (`snaps`, `snap_tails`) under the chain
-    key of the block a snapshot stands behind, evicted least recently used
-    like blocks and dropped with the block of their key.  The engine says
-    when one is taken (`checkpoint`, behind the prefill step that left the
-    state there); a match is served only up to a block a snapshot stands
-    behind (`serves`), and adopting it copies the snapshot into the lane's
-    slot ahead of the lane's first step (`adopt`).  Blocks that matched
-    PAST the last snapshot are what the index has seen shared and cannot
-    serve: the lane that prefills them again is asked for a snapshot behind
-    the last of them (`wanted`), so a shared head gets its snapshot where
-    it ends, wherever the requests' own ends lie."""
+    length.  One SLOT a lane a layer in THE BUFFERS THE MIXER STATES
+    (`slot_buffers`), beside the K and V pools, over its OWN `n_layers`
+    (the model's layers that have the mixer, which need not be those that
+    have K and V rows): where the mixer has a recurrence, `state`
+    [n_layers, max_lanes + 1, heads, d_state, head_dim] float32 (narrow
+    heads folded: `ops.ssm.state_shape`); always `tail` [n_layers,
+    max_lanes + 1, rows x width], its convolution's last rows.  A gated
+    short convolution keeps the tail alone: one buffer, no other.  A
+    lane's slot is its index, the last slot is where a program sends the
+    rows nobody has; every step overwrites them: `step_pools` hands the
+    step the pools and these as one tuple (ops/ssm.py;
+    `count_pool_copies` of the largest's shape must be 0 too).  A prefix
+    of sealed K/V blocks is worthless without the state at its end, so
+    the prefix index holds SNAPSHOTS beside its blocks: a `SealedIndex`
+    of snapshot slots (`snap_buffers`, a buffer for each of the lanes')
+    under the chain key of the block a snapshot stands behind, evicted
+    least recently used like blocks and dropped with the block of their
+    key.  The engine says when one is taken (`checkpoint`, behind the
+    prefill step that left the state there); a match is served only up to
+    a block a snapshot stands behind (`serves`), and adopting it copies
+    the snapshot into the lane's slot ahead of the lane's first step
+    (`adopt`).  Blocks that matched PAST the last snapshot are what the
+    index has seen shared and cannot serve: the lane that prefills them
+    again is asked for a snapshot behind the last of them (`wanted`), so a
+    shared head gets its snapshot where it ends, wherever the requests'
+    own ends lie.  The wire format carries a snapshot under the buffers'
+    names (`wire`), and a cache installs only one of its own names and
+    shapes."""
 
     kind = "state"
-    wire = ("state", "tail")
-    no_rollback = ("the recurrent state has been overwritten past the new "
+    no_rollback = ("the lanes' state has been overwritten past the new "
                    "length and cannot be rolled back")
     no_tier = ("a spilled chain would have to carry its snapshots "
                "(ROADMAP.md)")
@@ -816,19 +821,23 @@ class LaneState(CachePart):
         if snapshots is None:
             snapshots = max(2, cache.max_lanes // 4)
         self.slots = int(snapshots) if cache.prefix_cache_enabled else 0
-        one = state_shape(rows.heads, rows.d_state, rows.head_dim,
-                          rows.groups)
-        # (a tail's K - 1 rows one behind the other in ONE row of its
-        # slot: as [slots, 3, width] the compiler lays the three rows
-        # out one way for a program of all lanes and another for a
-        # program of one, and re-lays the buffer at a program's two
-        # ends: 5% of a decode step, PERF.md section 6, PR 43)
-        row = ((rows.conv - 1) * rows.conv_width,)
+        # (name on the wire, a slot's shape, dtype) of each buffer the
+        # mixer states.  (A tail's K - 1 rows one behind the other in ONE
+        # row of its slot: as [slots, 3, width] the compiler lays the
+        # three rows out one way for a program of all lanes and another
+        # for a program of one, and re-lays the buffer at a program's two
+        # ends: 5% of a decode step, PERF.md section 6, PR 43.)
+        stated = [("tail", ((rows.conv - 1) * rows.conv_width,), dtype)]
+        if rows.heads:
+            stated.insert(0, ("state", state_shape(
+                rows.heads, rows.d_state, rows.head_dim, rows.groups),
+                jnp.float32))
+        self.wire = tuple(name for name, _, _ in stated)
         lanes, snaps = cache.max_lanes + 1, max(self.slots, 1)
-        self.state = jnp.zeros((n_layers, lanes) + one, jnp.float32)
-        self.tail = jnp.zeros((n_layers, lanes) + row, dtype)
-        self.snaps = jnp.zeros((n_layers, snaps) + one, jnp.float32)
-        self.snap_tails = jnp.zeros((n_layers, snaps) + row, dtype)
+        self.slot_buffers = tuple(
+            jnp.zeros((n_layers, lanes) + one, dt) for _, one, dt in stated)
+        self.snap_buffers = tuple(
+            jnp.zeros((n_layers, snaps) + one, dt) for _, one, dt in stated)
         self.index = SealedIndex(snaps)
         # blocks the last match found past the last snapshot it could serve
         self.beyond = 0
@@ -836,7 +845,7 @@ class LaneState(CachePart):
         self._wanted = [0] * cache.max_lanes
         cache.stats.update(snapshots_taken=0, snapshots_adopted=0,
                            snapshot_misses=0)
-        donate = () if jax.default_backend() == "cpu" else (0, 1)
+        donate = () if jax.default_backend() == "cpu" else (0,)
         self._copy_slot = jax.jit(copy_slot, donate_argnums=donate)
         # Both directions made now, by a copy to nowhere: neither is
         # made under a request that waits.
@@ -845,29 +854,41 @@ class LaneState(CachePart):
 
     @property
     def buffers(self) -> tuple:
-        return self.state, self.tail
+        return self.slot_buffers
 
     def rebind(self, buffers):
-        self.state, self.tail = buffers
+        self.slot_buffers = tuple(buffers)
+
+    def _named(self, buffers: tuple, name: str):
+        if name not in self.wire:
+            raise AttributeError(f"this mixer states no {name!r} buffer")
+        return buffers[self.wire.index(name)]
+
+    # The buffers by their names, where the mixer states them.
+    state = property(lambda self: self._named(self.slot_buffers, "state"))
+    tail = property(lambda self: self._named(self.slot_buffers, "tail"))
+    snaps = property(lambda self: self._named(self.snap_buffers, "state"))
+    snap_tails = property(
+        lambda self: self._named(self.snap_buffers, "tail"))
 
     def _move(self, slot: int, lane: int, take: bool) -> None:
         """Dispatch the copy of `lane`'s slot into snapshot slot `slot`
-        (`take`) or the other way round, every layer, state and tail.  The
+        (`take`) or the other way round, every layer of every buffer.  The
         device runs programs in dispatch order: a snapshot is taken behind
         the step that left the state in the lane's slot, and adopted ahead
         of the lane's first step."""
         src, dst = jnp.int32(lane if take else slot), jnp.int32(
             slot if take else lane)
         if take:
-            self.snaps, self.snap_tails = self._copy_slot(
-                self.snaps, self.snap_tails, self.state, self.tail, src, dst)
+            self.snap_buffers = self._copy_slot(
+                self.snap_buffers, self.slot_buffers, src, dst)
         else:
-            self.state, self.tail = self._copy_slot(
-                self.state, self.tail, self.snaps, self.snap_tails, src, dst)
+            self.slot_buffers = self._copy_slot(
+                self.slot_buffers, self.snap_buffers, src, dst)
 
     def serves(self, keys):
         """Up to the last block a snapshot stands behind: blocks past it
-        are worth nothing to a lane that cannot start its recurrence
+        are worth nothing to a lane that cannot start its mixers
         there."""
         held = next((m for m in range(len(keys), 0, -1)
                      if keys[m - 1] in self.index), 0)
@@ -912,43 +933,43 @@ class LaneState(CachePart):
 
     def export(self, keys):
         """The chain ends where a snapshot stands (`serves`): it goes with
-        the blocks."""
+        the blocks, a buffer under each of the part's names."""
         slot = self.index.get(keys[-1])
-        return {"state": np.asarray(self.snaps[:, slot]),
-                "tail": np.asarray(self.snap_tails[:, slot])}
+        return {name: np.asarray(snap[:, slot])
+                for name, snap in zip(self.wire, self.snap_buffers)}
 
     def install(self, more, keys):
         """The snapshot a payload carries, behind the last block of its
-        chain; None where it is not of this cache's shape or no snapshot
-        slot can be had: blocks behind no snapshot serve nobody."""
-        state, tail = more["state"], more["tail"]
-        if not keys or tuple(state.shape) != self.snaps.shape[:1] \
-                + self.snaps.shape[2:] \
-                or tuple(tail.shape) != self.snap_tails.shape[:1] \
-                + self.snap_tails.shape[2:]:
+        chain; None where it does not carry exactly this part's buffers in
+        their shapes, or no snapshot slot can be had: blocks behind no
+        snapshot serve nobody."""
+        if not keys or any(
+                name not in more or tuple(more[name].shape)
+                != snap.shape[:1] + snap.shape[2:]
+                for name, snap in zip(self.wire, self.snap_buffers)):
             return None
 
         def write(slot):
-            self.snaps = self.snaps.at[:, slot].set(
-                jnp.asarray(state, self.snaps.dtype))
-            self.snap_tails = self.snap_tails.at[:, slot].set(
-                jnp.asarray(tail, self.snap_tails.dtype))
+            self.snap_buffers = tuple(
+                snap.at[:, slot].set(jnp.asarray(more[name], snap.dtype))
+                for name, snap in zip(self.wire, self.snap_buffers))
 
         return 0 if keys[-1] in self.index or self._take(keys[-1], write) \
             else None
 
     def stats(self, cache):
         """Slots of state and of snapshots, and what the index did with the
-        latter (engine `stats()["ssm"]`)."""
+        latter (engine `stats()["ssm"]` or `["state"]`)."""
         cs = cache.stats
         return {
-            "state_layers": int(self.state.shape[0]),
+            "state_layers": int(self.slot_buffers[0].shape[0]),
+            "state_buffers": len(self.slot_buffers),
             "state_slots": self.cache.max_lanes,
             "state_slots_live": sum(map(bool, self.cache._lane_blocks)),
-            "state_bytes": int(self.state.nbytes + self.tail.nbytes),
+            "state_bytes": sum(int(b.nbytes) for b in self.slot_buffers),
             "snapshot_slots": self.slots,
             "snapshot_slots_live": len(self.index),
-            "snapshot_bytes": int(self.snaps.nbytes + self.snap_tails.nbytes),
+            "snapshot_bytes": sum(int(b.nbytes) for b in self.snap_buffers),
             "snapshots_evicted": self.index.allocator.evictions,
             "snapshots_taken": cs["snapshots_taken"],
             "snapshots_adopted": cs["snapshots_adopted"],

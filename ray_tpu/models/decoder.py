@@ -2,7 +2,7 @@
 
 A family (models/gpt.py, models/llama.py, models/axk1.py,
 models/evabyte.py, models/dots3.py, models/falconh1.py,
-models/nemotronh.py, models/afmoe.py) is a config
+models/nemotronh.py, models/afmoe.py, models/lfm2.py) is a config
 dataclass, its parameter format (`init_params`, `param_specs`) and
 `spec(config)`: a `Spec` naming the parts its block is made of (norms: one
 in front of each part, and where the model has them one behind each too,
@@ -47,8 +47,11 @@ Design (no reference counterpart: Ray hosts models, it doesn't ship them):
   * a run's layers may be ONE part alone: the mixer with no attention, the
     attention with no feed-forward behind it, or the feed-forward with
     nothing in front (`Run.attn`, `Run.ffn`, `Run.mixer` None), each behind
-    its one norm; a stack is then its runs in order, the runs of a kind
-    sharing one stack of leaves and one part of the cache;
+    its one norm; or the mixer in the attention's PLACE, in front of a
+    feed-forward (`CONV`: LFM2's gated short convolution, whose lane state
+    is its convolution's tail and no recurrence); a stack is then its runs
+    in order, the runs of a kind sharing one stack of leaves and one part
+    of the cache;
   * `jax.checkpoint` (remat) on the block when configured: trades FLOPs for
     HBM, the standard TPU memory lever.
 
@@ -58,6 +61,7 @@ choice is made from the spec, never from a family's name.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import math
@@ -202,7 +206,8 @@ def moe_ffn(h, p, config, mesh=None, valid=None):
     in float32 (the eighth expert is often chosen by a fourth decimal).
     `config.scoring_func` is "softmax" over the experts or "sigmoid" of
     each; the chosen scores weight the experts as they are unless
-    `config.norm_topk_prob` (then they sum to one), times
+    `config.norm_topk_prob` (then they sum to one: divided by their sum,
+    plus `config.norm_topk_eps` where the family states one), times
     `config.routed_scale`.  `p` holds the layer's router [D, E] and the
     experts of ALL layers with the index `layer` (the kernel reads them in
     place), and where the router has one its `router_bias` [E].
@@ -253,7 +258,10 @@ def _route(h, p, config):
     else:
         weights, experts = jax.lax.top_k(scores, c.n_experts_per_tok)
     if c.norm_topk_prob:
-        weights = weights / jnp.sum(weights, -1, keepdims=True)
+        total = jnp.sum(weights, -1, keepdims=True)
+        # (a family that states an epsilon under the sum: LFM2's 1e-6)
+        eps = getattr(c, "norm_topk_eps", 0.0)
+        weights = weights / (total + eps if eps else total)
     if c.routed_scale != 1.0:
         weights = weights * c.routed_scale
     return x, experts, weights
@@ -935,16 +943,18 @@ EVA = Attention(eva_attention, _heads_project, _eva_attend, _heads_finish,
 @dataclasses.dataclass(frozen=True)
 class StateRows:
     """What a mixer keeps of a lane between steps, as the cache manager
-    needs to know it (`PagedKVCache.for_model`): a float32 state
-    [heads, d_state, head_dim] (stored as `ops.ssm.state_shape` folds it:
-    heads narrower than the lane width side by side) and the last
+    needs to know it (`PagedKVCache.for_model`), a layer each: the last
     `conv - 1` rows of `conv_width` columns that its convolution reads
-    again, a layer each."""
-    heads: int
-    head_dim: int
-    d_state: int
+    again and, where it has a recurrence (`heads` > 0), a float32 state
+    [heads, d_state, head_dim] (stored as `ops.ssm.state_shape` folds it:
+    heads narrower than the lane width side by side).  A mixer with no
+    recurrence (a gated short convolution) states the rows alone, and the
+    cache holds that one buffer and no other."""
     conv: int
     conv_width: int
+    heads: int = 0
+    head_dim: int = 0
+    d_state: int = 0
     groups: int = 1     # heads that share B and C fold together, or none
 
 
@@ -995,6 +1005,12 @@ def _ssm_out(y, x, z, p, config):
     return jnp.einsum("ble,ed->bld", y, p["w_out"].astype(z.dtype))
 
 
+def _conv_act(conv, p, dtype):
+    """Mamba-2's end of its convolution: the taps' sum (`ops.ssm.conv_tail`)
+    plus the bias, through SiLU, in the activations' dtype."""
+    return jax.nn.silu(p["conv_b"].astype(jnp.float32) + conv).astype(dtype)
+
+
 def ssm_mixer(h, p, config):
     """Mamba-2's mixer over a whole sequence from the zero state
     (ops/ssm.py has the recurrence)."""
@@ -1003,10 +1019,10 @@ def ssm_mixer(h, p, config):
     c = config
     z, xbc, dt = _ssm_split(h, p, c)
     b, t, width = xbc.shape
-    xbc, _ = ssm.conv_tail(xbc, jnp.zeros((b, c.ssm_conv - 1, width),
-                                          xbc.dtype),
-                           p["conv_w"], p["conv_b"],
-                           jnp.full((b,), t, jnp.int32))
+    conv, _ = ssm.conv_tail(xbc, jnp.zeros((b, c.ssm_conv - 1, width),
+                                           xbc.dtype),
+                            p["conv_w"], jnp.full((b,), t, jnp.int32))
+    xbc = _conv_act(conv, p, xbc.dtype)
     x, bm, cm, dt, a = _ssm_heads(xbc, dt, p, c)
     y, _ = ssm.ssm_sequence(x, dt, a, bm, cm, chunk=c.ssm_chunk)
     return _ssm_out(y, x, z, p, c)
@@ -1068,8 +1084,9 @@ def _ssm_attend(rows, pools, p, spec, config, lanes):
         b = xbc.shape[0]
         tail = jnp.where(fresh[:, None, None], 0, _slot_rows(
             tails, layer, slots, b).reshape(b, c.ssm_conv - 1, -1))
-        xbc, tail = ssm.conv_tail(xbc, tail, p["conv_w"], p["conv_b"],
-                                  jnp.sum(valid, axis=1, dtype=jnp.int32))
+        conv, tail = ssm.conv_tail(xbc, tail, p["conv_w"],
+                                   jnp.sum(valid, axis=1, dtype=jnp.int32))
+        xbc = _conv_act(conv, p, xbc.dtype)
         tails = _slot_rows(tails, layer, slots, b, tail.reshape(b, -1))
     if slots is None:
         slots = jnp.arange(b, dtype=jnp.int32)
@@ -1101,14 +1118,105 @@ class Mixer:
     state: Callable         # config -> StateRows
     # Leaves `serving_params` holds in the activation dtype.
     cast: tuple = ()
+    # The named scope around its whole part over a cache (None: none).
+    scope: Optional[str] = None
 
 
 SSM = Mixer(ssm_mixer, _ssm_project, _ssm_attend, _ssm_finish,
             state=lambda c: StateRows(
-                c.ssm_heads, c.ssm_head_dim, c.ssm_state, c.ssm_conv,
-                c.ssm_heads * c.ssm_head_dim
-                + 2 * c.ssm_groups * c.ssm_state, c.ssm_groups),
+                c.ssm_conv, c.ssm_heads * c.ssm_head_dim
+                + 2 * c.ssm_groups * c.ssm_state, c.ssm_heads,
+                c.ssm_head_dim, c.ssm_state, c.ssm_groups),
             cast=("w_in", "w_out"))
+
+
+# --------------------------------------------------------------------------
+# A gated short convolution (LFM2's `conv` operator): [B | C | u] = h W_in,
+# v = B * u, a causal depthwise convolution of `conv_L_cache` taps over v
+# with neither bias nor activation, out = (C * conv) W_out.  The gate B * u
+# comes BEFORE the convolution, so what a lane keeps between steps is the
+# last conv - 1 rows of the gated product v and nothing else: no
+# recurrence.
+# --------------------------------------------------------------------------
+
+def _conv_in(h, p):
+    """[B | C | u] [B, L, 3 d_model] of normed h: `w_in` [D, 3 D], its
+    thirds in that order."""
+    return jnp.einsum("bld,de->ble", h, p["w_in"].astype(h.dtype))
+
+
+def _gated_conv(bcu, tail, p, n_valid):
+    """The part between the two products: v = B * u, the taps over the
+    carried `tail` [B, K - 1, D] and v, the gate C.  Returns (C * conv
+    [B, T, D], the tail behind the first `n_valid` rows)."""
+    from ray_tpu.ops import ssm
+
+    gate_b, gate_c, u = jnp.split(bcu, 3, axis=-1)
+    conv, tail = ssm.conv_tail(gate_b * u, tail, p["conv_w"], n_valid)
+    return gate_c * conv.astype(u.dtype), tail
+
+
+def conv_mixer(h, p, config):
+    """The gated short convolution over a whole sequence, zeros before
+    it."""
+    with jax.named_scope("conv_mix"):
+        bcu = _conv_in(h, p)
+        b, t, width = bcu.shape
+        y, _ = _gated_conv(
+            bcu, jnp.zeros((b, config.conv_taps - 1, width // 3), bcu.dtype),
+            p, jnp.full((b,), t, jnp.int32))
+        return jnp.einsum("ble,ed->bld", y, p["w_out"].astype(h.dtype))
+
+
+def _conv_project(h, p, spec, config, offset):
+    return (_conv_in(h, p),)
+
+
+def _conv_attend(rows, pools, p, spec, config, lanes):
+    """The lanes' own part of the gated short convolution over a slice,
+    continued from each row's slot (`lanes.slots` [B]; None: row i's is
+    slot i) of the ONE buffer `pools` = (tail [L, S, (K - 1) D]: a slot's
+    K - 1 rows of the gated product one behind the other) at
+    `p["cache_layer"]`: the gate B * u, the taps over the slot's rows and
+    the slice's, and the slot overwritten with the last K - 1 rows behind
+    the row's last valid token.  A row whose slice starts at position 0
+    starts from zeros, every other from what its slot holds (what the step
+    before left there, or a snapshot the engine copied in); a row with no
+    valid token leaves its slot as it was.  One token a row whose slot is
+    its index is one kernel on the chip (`ops.ssm.gated_conv_step`, named
+    `conv_tail` like this scope); a chunk's rows, rows that name their
+    slots and the CPU take the same steps in XLA.  Returns ((C * conv,),
+    pools)."""
+    from ray_tpu.ops import ssm
+
+    tails, = pools
+    layer = p["cache_layer"]
+    bcu, = rows
+    _, positions, valid, _, slots = lanes
+    # (a row nobody has also stands at position 0: it starts nothing)
+    fresh = (positions[:, 0] == 0) & valid[:, 0]
+    with jax.named_scope("conv_tail"):
+        b, t, width = bcu.shape[0], bcu.shape[1], bcu.shape[2] // 3
+        if t == 1 and slots is None and ssm.gated_conv_fits(
+                b, width, bcu.dtype):
+            y, tails = ssm.gated_conv_step(bcu[:, 0], tails, p["conv_w"],
+                                           valid[:, 0], fresh, layer)
+            return (y[:, None],), (tails,)
+        tail = jnp.where(fresh[:, None, None], 0, _slot_rows(
+            tails, layer, slots, b).reshape(b, config.conv_taps - 1, -1))
+        y, tail = _gated_conv(bcu, tail, p,
+                              jnp.sum(valid, axis=1, dtype=jnp.int32))
+        tails = _slot_rows(tails, layer, slots, b, tail.reshape(b, -1))
+    return (y,), (tails,)
+
+
+def _conv_finish(out, rows, h, p, spec, config):
+    return jnp.einsum("ble,ed->bld", out[0], p["w_out"].astype(h.dtype))
+
+
+CONV = Mixer(conv_mixer, _conv_project, _conv_attend, _conv_finish,
+             state=lambda c: StateRows(c.conv_taps, c.d_model),
+             cast=("w_in", "w_out"), scope="conv_mix")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -1133,8 +1241,9 @@ class Run:
     attention and where its layers' leaves and cached rows live.  A model
     whose layers differ in more than a leading run's feed-forward names
     its runs itself (`Spec.runs`), in order.  `attn` None: no attention
-    (then `mixer` stands alone behind the first norm, or there is no first
-    norm at all); `ffn` None: no second norm and no feed-forward."""
+    (then `mixer` stands in its place behind the first norm, or there is
+    no first norm at all); `ffn` None: no second norm and no
+    feed-forward."""
     blocks: str             # the key of its stacks in the parameter tree
     n_layers: int
     ffn: Optional[FeedForward]
@@ -1297,8 +1406,11 @@ def _block_cached(x, pools, p, spec: Spec, run: Run, config, lanes: tuple,
                         _scaled(attn, m.attn_out))
         pools = (*rows, *pools[n:])
     if run.mixer is not None:
-        y, state = _part_cached(run.mixer, _scaled(h, m.mixer_in), pools[n:],
-                                p, spec, config, lanes, offset)
+        with (jax.named_scope(run.mixer.scope) if run.mixer.scope
+              else contextlib.nullcontext()):
+            y, state = _part_cached(run.mixer, _scaled(h, m.mixer_in),
+                                    pools[n:], p, spec, config, lanes,
+                                    offset)
         x = x + _behind(spec, spec.attn_post_norm, p,
                         _scaled(y, m.mixer_out))
         pools = (*pools[:n], *state)

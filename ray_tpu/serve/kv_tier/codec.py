@@ -58,7 +58,9 @@ class KVBlockCodec:
             {
                 "v": 1,
                 # "kv": K and V rows; "state": as "kv", and under `more`
-                # the snapshot of the recurrent state behind the chain;
+                # the snapshot of the mixers' state behind the chain, a
+                # buffer under each name the cache's state part states
+                # ("state" and "tail", or "tail" alone);
                 # "latent": one latent row in `k`, `v_pool` None; "layered":
                 # as "latent" or as "kv" (a cache's kinds are all latent
                 # rows or all K and V rows), and under `more` the blocks of

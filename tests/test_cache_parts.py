@@ -33,7 +33,10 @@ KINDS = {"kv": ("gpt", "nano", 40),
          "state": ("falconh1", "falconh1-nano", (40, 3)),
          # the state part over three mixer layers, K and V pools over one
          # attention layer: a part with a layer count of its own
-         "state:own_layers": ("nemotronh", "nemotronh-nano", (40, 3))}
+         "state:own_layers": ("nemotronh", "nemotronh-nano", (40, 3)),
+         # the state part of ONE buffer: a gated short convolution keeps its
+         # tail and no recurrent state
+         "state:tail_only": ("lfm2", "lfm2-nano", (40, 3))}
 
 
 def _make(kind) -> PagedKVCache:
@@ -270,6 +273,54 @@ def test_one_builder_makes_every_cache():
     assert by_hand.pool_shape == built.pool_shape
     assert _make("layered").parts[0].index.allocator.num_blocks == 24
     assert _make("state").parts[0].slots == 3
+
+
+@pytest.mark.parametrize("kind,names", [
+    ("state", ("state", "tail")), ("state:own_layers", ("state", "tail")),
+    ("state:tail_only", ("tail",))])
+def test_a_state_part_holds_the_buffers_its_mixer_states(kind, names):
+    """One buffer or two out of one code path: what the part allocates,
+    hands to a step, takes back, snapshots and ships is exactly what the
+    mixer's `StateRows` states; a checkpoint copies a lane's slot of every
+    buffer into a snapshot slot and an adoption copies it back, other slots
+    as they were; a payload of other buffers is refused."""
+    cache, tokens = _make(kind), _tokens(0)
+    part = cache.parts[-1]
+    assert part.wire == names == cache._wire_more
+    assert len(part.buffers) == len(part.snap_buffers) == len(names)
+    pools, none = cache.step_pools
+    assert none is None and len(pools) == 2 + len(names)
+    assert all(a is b for a, b in zip(pools[2:], part.buffers))
+    assert all(b.shape[:2] == (part.buffers[0].shape[0], 3 + 1)
+               and s.shape == b.shape[:1] + (3,) + b.shape[2:]
+               for b, s in zip(part.buffers, part.snap_buffers))
+    if "state" not in names:
+        with pytest.raises(AttributeError, match="no 'state' buffer"):
+            part.snaps
+        assert part.tail is part.buffers[0]
+    else:
+        assert part.state.dtype == jnp.float32 and part.snaps.ndim == 5
+    # a step's return is rebound buffer for buffer
+    cache.update_pools(tuple(x + 1 for x in pools), None)
+    assert all(np.array_equal(a, b + 1) for a, b in zip(
+        cache.parts[-1].buffers, pools[2:]))
+    # checkpoint lane 0, adopt into lane 1: lane 1's slot is lane 0's
+    assert cache.adopt_prefix(0, tokens) == 0
+    _drive(cache, 0, tokens, mark=MARK)
+    before = [np.asarray(b) for b in part.buffers]
+    assert cache.adopt_prefix(1, tokens) == MARK
+    for old, new in zip(before, part.buffers):
+        new = np.asarray(new)
+        np.testing.assert_array_equal(new[:, 1], old[:, 0])
+        np.testing.assert_array_equal(np.delete(new, 1, 1),
+                                      np.delete(old, 1, 1))
+    payload = cache.export_prefix(tokens[:MARK] + [1])
+    assert set(payload["more"]) == set(names)
+    fewer = {k: v for k, v in payload["more"].items() if k != names[0]}
+    more = {**payload["more"], "other": payload["more"]["tail"]}
+    for wrong in (fewer, more):
+        assert _make(kind).install_prefix(dict(payload, more=wrong)) == 0
+    assert _make(kind).install_prefix(payload) == MARK // BS
 
 
 def test_what_the_cache_cannot_do_is_asked_of_it_once():

@@ -129,21 +129,24 @@ def test_the_update_is_one_step_of_the_recurrence():
 
 def test_the_convolution_carries_its_tail_over_padded_rows():
     """A sequence convolved in two slices, the first padded behind its 5
-    valid rows, is the sequence convolved whole."""
+    valid rows, is the sequence convolved whole (the taps' sum alone: bias
+    and activation are the caller's)."""
     rng = np.random.default_rng(9)
     seq = rng.standard_normal((1, 11, 6)).astype(np.float32)
     w = rng.standard_normal((4, 6)).astype(np.float32)
-    b = rng.standard_normal(6).astype(np.float32)
     zero = jnp.zeros((1, 3, 6))
-    whole, _ = ssm.conv_tail(seq, zero, w, b, jnp.array([11]))
+    whole, _ = ssm.conv_tail(seq, zero, w, jnp.array([11]))
+    padded = np.concatenate([np.zeros((1, 3, 6), np.float32), seq], 1)
+    np.testing.assert_allclose(
+        whole, sum(padded[:, i:i + 11] * w[i] for i in range(4)), atol=1e-6)
     first = np.concatenate([seq[:, :5], np.full((1, 3, 6), 9.0, np.float32)],
                            1)
-    y1, tail = ssm.conv_tail(first, zero, w, b, jnp.array([5]))
-    y2, tail = ssm.conv_tail(seq[:, 5:], tail, w, b, jnp.array([6]))
+    y1, tail = ssm.conv_tail(first, zero, w, jnp.array([5]))
+    y2, tail = ssm.conv_tail(seq[:, 5:], tail, w, jnp.array([6]))
     np.testing.assert_allclose(np.concatenate([y1[:, :5], y2], 1), whole,
                                atol=1e-6)
     np.testing.assert_array_equal(tail, seq[:, -3:])
-    _, kept = ssm.conv_tail(first, tail, w, b, jnp.array([0]))
+    _, kept = ssm.conv_tail(first, tail, w, jnp.array([0]))
     np.testing.assert_array_equal(kept, tail)       # no valid row: as it was
 
 
@@ -202,4 +205,4 @@ def test_a_training_step_is_refused():
 def test_the_spec_names_the_mixer_and_what_it_keeps():
     run, = falconh1.spec(NANO).runs
     assert run.mixer is decoder.SSM and run.attn is decoder.HEADS
-    assert run.mixer.state(NANO) == decoder.StateRows(4, 8, 16, 4, 96, 2)
+    assert run.mixer.state(NANO) == decoder.StateRows(4, 96, 4, 8, 16, 2)

@@ -310,7 +310,7 @@ def test_the_spec_names_one_part_a_run_and_what_each_keeps():
         assert sum(p is not None for p in parts) == 1
     assert decoder.layer_counts(spec, NANO) == {
         "kv": 1, "window": 0, "state": 3, "experts": 3}
-    assert decoder.SSM.state(NANO) == decoder.StateRows(4, 64, 16, 4, 320, 2)
+    assert decoder.SSM.state(NANO) == decoder.StateRows(4, 320, 4, 64, 16, 2)
     published = nemotronh.NemotronHConfig()
     assert decoder.layer_counts(nemotronh.spec(published), published) == {
         "kv": 6, "window": 0, "state": 23, "experts": 23}

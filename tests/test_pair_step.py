@@ -50,6 +50,9 @@ CASES = {
     # one-part layers: state and K/V rows in different layers
     "state_one_part": ("nemotronh", "nemotronh-nano", STATE, 5,
                        (20, 13, 30)),
+    # a conv mixer in the attention's place over experts: the state part
+    # is the tails alone, one buffer
+    "state_tail_only": ("lfm2", "lfm2-nano", STATE, 5, (20, 13, 30)),
     # window and full layers over K and V heads, two pairs of pools
     "layered_kv": ("afmoe", "afmoe-nano", LAYERED, 5, (13, 37, 30)),
 }

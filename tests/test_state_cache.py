@@ -2,8 +2,12 @@
 (inference/kv_cache.py "state"): a slot a lane overwritten by every step,
 snapshots under the prefix index, a match served only where K/V blocks AND
 a snapshot stand, lanes that are not stepped, the wire format, and what is
-refused.  Nano size on the CPU; the model's own tests are
-tests/test_falconh1.py, the cell's rehearsal
+refused.  The state part holds the buffers its mixer states: two under
+Falcon-H1's and Nemotron-H's Mamba-2 mixers (a recurrent state and the
+convolution's tail), ONE under LFM2's gated short convolution (the tail
+alone), out of one code path: the cases `two_buffers` and `one_buffer`.
+Nano size on the CPU; the models' own tests are tests/test_falconh1.py and
+tests/test_lfm2.py, the cell's rehearsal
 benchmark/tests/test_rehearse_falconh1.py."""
 
 import functools
@@ -14,8 +18,9 @@ import numpy as np
 import pytest
 
 from benchmark.reference import falconh1 as ref
+from benchmark.reference import lfm2 as lfm2_ref
 from ray_tpu.inference import InferenceEngine, PagedKVCache
-from ray_tpu.models import falconh1
+from ray_tpu.models import falconh1, lfm2
 from ray_tpu.serve.kv_tier.codec import KVBlockCodec
 
 NANO = falconh1.CONFIGS["falconh1-nano"]
@@ -29,8 +34,25 @@ def _init(seed=0):
         NANO, jax.random.key(seed))
 
 
-def _engine(**kw):
-    return InferenceEngine("falconh1", NANO, _init(), **{**ENGINE, **kw})
+@functools.lru_cache(maxsize=None)
+def _lfm2_init():
+    return jax.jit(lfm2.init_params, static_argnums=0)(
+        lfm2.CONFIGS["lfm2-nano"], jax.random.key(0))
+
+
+# buffers of the state part -> family, config, parameters, reference, and
+# the key of `stats()` that carries the part's counters
+FAMILIES = {
+    "two_buffers": ("falconh1", NANO, _init, ref, "ssm"),
+    "one_buffer": ("lfm2", lfm2.CONFIGS["lfm2-nano"], _lfm2_init, lfm2_ref,
+                   "state"),
+}
+BUFFERS = list(FAMILIES)
+
+
+def _engine(buffers="two_buffers", **kw):
+    family, cfg, init, _, _ = FAMILIES[buffers]
+    return InferenceEngine(family, cfg, init(), **{**ENGINE, **kw})
 
 
 def _run(eng, *handles):
@@ -48,9 +70,10 @@ def _greedy(prompt, n):
     return seq[len(prompt):]
 
 
-def _served_is_the_references(prompt, out):
-    want = np.asarray(jnp.argmax(ref.row_logits(
-        _init(), np.asarray(prompt + out)), -1))
+def _served_is_the_references(prompt, out, buffers="two_buffers"):
+    _, _, init, reference, _ = FAMILIES[buffers]
+    want = np.asarray(jnp.argmax(reference.row_logits(
+        init(), np.asarray(prompt + out)), -1))
     return out == want[len(prompt) - 1:len(prompt) + len(out) - 1].tolist()
 
 
@@ -82,24 +105,36 @@ def test_the_engine_serves_the_references_greedy_tokens(prefill_lanes):
     assert ssm["snapshots_taken"] == ssm["snapshot_slots"] == 0
 
 
-def test_a_lane_adopted_from_a_snapshot_equals_one_prefilled_from_token_0():
+@pytest.mark.parametrize("buffers", BUFFERS)
+def test_a_lane_adopted_from_a_snapshot_equals_one_prefilled_from_token_0(
+        buffers):
     """The second request of a head adopts its blocks and the snapshot
-    behind them and scans only its own turn; what it serves is what an
-    engine without a prefix cache serves for the same prompt, token for
-    token, and the reference's."""
+    behind them (state and tail, or the tail alone) and scans only its own
+    turn; what it serves is what an engine without a prefix cache serves
+    for the same prompt, token for token, and the reference's."""
     first, second = _prompts(1, 32, (5, 7))
-    eng = _engine(prefill_lanes=2)
+    key = FAMILIES[buffers][4]
+    eng = _engine(buffers, prefill_lanes=2)
     _run(eng, eng.submit(first, 4))
     st = eng.stats()
-    assert st["ssm"]["snapshots_taken"] == 1 and st["prefix_hit_tokens"] == 0
+    assert st[key]["snapshots_taken"] == 1 and st["prefix_hit_tokens"] == 0
     out, = _run(eng, eng.submit(second, 16))
     st = eng.stats()
-    assert st["prefix_hit_tokens"] == 32 and st["ssm"]["snapshots_adopted"] == 1
-    assert st["ssm"]["tokens_scanned"] == len(first) + 7
-    plain = _engine(prefix_cache=False)
+    assert st["prefix_hit_tokens"] == 32 and st[key]["snapshots_adopted"] == 1
+    part = eng.cache.parts[0]
+    assert st[key]["state_buffers"] == len(part.wire) == len(
+        eng.cache.buffers) == len(BUFFERS) - BUFFERS.index(buffers)
+    assert st[key]["state_bytes"] == sum(b.nbytes for b in part.buffers)
+    assert st[key]["snapshot_bytes"] == sum(
+        b.nbytes for b in part.snap_buffers)
+    if buffers == "two_buffers":
+        assert st["ssm"]["tokens_scanned"] == len(first) + 7
+    else:
+        assert st["conv"]["rows_chunk"] == len(first) + 7
+    plain = _engine(buffers, prefix_cache=False)
     cold, = _run(plain, plain.submit(second, 16))
     assert out == cold
-    assert _served_is_the_references(second, out)
+    assert _served_is_the_references(second, out, buffers)
 
 
 def test_a_snapshot_stands_where_a_cold_prompts_last_whole_chunk_ends():
@@ -152,24 +187,27 @@ def test_a_snapshot_is_taken_where_matched_blocks_had_none():
     assert _served_is_the_references(third, out)
 
 
-def test_a_match_is_refused_where_blocks_exist_and_the_snapshot_was_evicted():
+@pytest.mark.parametrize("buffers", BUFFERS)
+def test_a_match_is_refused_where_blocks_exist_and_the_snapshot_was_evicted(
+        buffers):
     """Two snapshot slots, three heads: the first head's snapshot is the
     least recently used and goes; its K/V blocks are still indexed, and the
     index serves nothing of them: the request prefills from token 0 and is
     counted."""
-    eng = _engine(num_blocks=(96, 2), prefill_lanes=2)
+    key = FAMILIES[buffers][4]
+    eng = _engine(buffers, num_blocks=(96, 2), prefill_lanes=2)
     heads = [_prompts(10 + i, 32, (5,))[0] for i in range(3)]
     for prompt in heads:
         _run(eng, eng.submit(prompt, 2))
     cache = eng.cache
     assert [cache.match_len(p) for p in heads] == [0, 32, 32]
     assert len(cache.match_prefix(heads[0])) == 0 and cache.parts[0].beyond == 9
-    assert len(cache.index) and eng.stats()["ssm"]["snapshots_evicted"] == 1
+    assert len(cache.index) and eng.stats()[key]["snapshots_evicted"] == 1
     again = heads[0][:32] + [1, 2, 3]
     out, = _run(eng, eng.submit(again, 8))
-    st = eng.stats()["ssm"]
+    st = eng.stats()[key]
     assert st["snapshot_misses"] == 1 and st["snapshots_adopted"] == 0
-    assert _served_is_the_references(again, out)
+    assert _served_is_the_references(again, out, buffers)
     assert cache.match_len(again) == 32         # taken anew behind its head
 
 
@@ -202,24 +240,38 @@ def test_what_a_state_cannot_do_is_refused():
         cache.attach_tier(object())
 
 
-def test_the_wire_format_carries_the_snapshot_and_a_cache_installs_its_own():
+@pytest.mark.parametrize("buffers", BUFFERS)
+def test_the_wire_format_carries_the_snapshot_and_a_cache_installs_its_own(
+        buffers):
+    """The payload's `more` holds exactly the buffers the part states,
+    under their names; a cache that states others installs none of it."""
     first, second = _prompts(4, 32, (5, 6))
-    eng = _engine(prefill_lanes=2)
+    eng = _engine(buffers, prefill_lanes=2)
     _run(eng, eng.submit(first, 2))
     payload = KVBlockCodec.decode(KVBlockCodec.encode(
         eng.export_prefix(second)))
     assert payload["kind"] == "state" and len(payload["chain"]) == 8
-    assert payload["more"]["state"].shape == (3, 4, 16, 8)
-    other = _engine(prefill_lanes=2)
+    assert {k: v.shape for k, v in payload["more"].items()} == {
+        "two_buffers": {"state": (3, 4, 16, 8), "tail": (3, 3 * 96)},
+        "one_buffer": {"tail": (3, 2 * 64)}}[buffers]
+    other = _engine(buffers, prefill_lanes=2)
     assert other.import_prefix(payload) == 8
     assert other.import_prefix(payload) == 0            # idempotent
     out, = _run(other, other.submit(second, 12))
     assert other.stats()["prefix_hit_tokens"] == 32
-    assert _served_is_the_references(second, out)
+    assert _served_is_the_references(second, out, buffers)
     # nothing of it without its snapshot, nothing into a cache of K/V alone
     bare = dict(payload, more={})
-    assert _engine().import_prefix(bare) == 0
-    assert _engine(num_blocks=(96, 0)).import_prefix(payload) == 0
+    assert _engine(buffers).import_prefix(bare) == 0
+    assert _engine(buffers, num_blocks=(96, 0)).import_prefix(payload) == 0
+    # nor into a cache whose part states other buffers: one more, one fewer
+    tail = payload["more"]["tail"]
+    for more in ({"tail": tail, "state": np.zeros((3, 4, 16, 8), np.float32),
+                  "extra_state": tail}, {"state": tail},
+                 {"tail": tail[:, :-1]}):
+        assert set(more) != set(payload["more"]) or more["tail"].shape \
+            != tail.shape
+        assert _engine(buffers).import_prefix(dict(payload, more=more)) == 0
     from ray_tpu.models import llama
     plain = PagedKVCache.for_model(
         llama, llama.CONFIGS["llama-tiny"], num_blocks=16, block_size=4,

@@ -1343,7 +1343,8 @@ _PAIRS = {
     "serve_dots3_docs_decode": (128, 1),
     "serve_falconh1_chat_decode": (64, 1),
     "serve_nemotron3_agents_decode": (64, 1),
-    "serve_trinity_docs_decode": (128, 1)}
+    "serve_trinity_docs_decode": (128, 1),
+    "serve_lfm2_rag_decode": (64, 1)}
 
 
 # Bytes of weights the pair's program may copy over its cell's T=1 program's
@@ -1353,7 +1354,10 @@ _PAIRS = {
 # [9, 5120, 512] stack (47.2 MB of 12.7 GB read); Nemotron-3: a
 # [2688, 2816] matrix converted (15.1 MB of 8.7 GB).
 _PAIR_COPIES = {"serve_falconh1_chat_decode": 48 * 2 ** 20,
-                "serve_nemotron3_agents_decode": 16 * 2 ** 20}
+                "serve_nemotron3_agents_decode": 16 * 2 ** 20,
+                # LFM2: a [2048, 512] `wk` or `wv` converted for the
+                # chunk's product (2 MB of 12.8 GB)
+                "serve_lfm2_rag_decode": 3 * 2 ** 20}
 
 
 def _cell_engine(cell, device):
@@ -1450,3 +1454,59 @@ def test_the_pairs_program_fits_a_v5e_and_reads_weights_and_pools_in_place(
     assert set(_kernel_counts(one.as_text())) <= set(_kernel_counts(text))
     # the last tokens of every lane come back, the sampled rows of both
     assert f"s32[{lanes + rows}]" in text
+
+
+@pytest.mark.parametrize("which,t,rows", [
+    ("t1", 1, 0), ("pair", 256, 4)], ids=["t1_128_lanes", "pair_128_4x256"])
+def test_lfm2_programs_fit_a_v5e_and_overwrite_the_tails_in_place(
+        v5e, as_on_chip, which, t, rows):
+    """PR 54: `serve_lfm2_rag_decode`'s T=1 step at 128 lanes and its widest
+    pair's program (128 + 4 x 256 rows), compiled for the chip from the
+    files the benchmark runs the cell from: K and V pools over the 2
+    attention layers and the state part's ONE buffer, the tails of the 7
+    conv layers (no float32 state anywhere), all three donated and left
+    where they are (`pool_copies`, `state_copies` 0); 13.2 GB of arguments,
+    which fit the chip with their temporaries; no weight copied or
+    transposed beyond what the other expert cells' programs do (a layer's
+    slice prefetched by XLA's own `copy-done`); and the kernels under the
+    names the benchmark's readers find them by: `conv_tail` once a run of
+    conv layers (three bodies), the grouped multiply three times an expert
+    run (four bodies), the paged kernel once an attention run (two)."""
+    eng, params, pools, tables, carried = _cell_engine(
+        "serve_lfm2_rag_decode", v5e[0])
+    arg, lanes = _arg_on(v5e[0]), eng.max_lanes
+    assert lanes == 128
+    lane_ints = lanes * 8 + rows * (3 * t + 6) if rows else None
+    compiled = eng._make_entry(t, False, False, rows).lower(
+        params, *pools,
+        arg((lanes, 8) if lane_ints is None else (lane_ints,), jnp.int32),
+        tables, *carried).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    held = jax.tree.leaves(pools)
+    assert [tuple(p.shape) for p in held] == [
+        (2, 5376, 128, 512), (2, 5376, 128, 512), (7, 129, 2 * 2048)]
+    assert {p.dtype for p in held} == {jnp.dtype(jnp.bfloat16)}
+    nbytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in held)
+    # (the tails' 129 slots are padded to whole tiles)
+    assert nbytes <= memory.alias_size_in_bytes < 1.002 * nbytes
+    assert 13.1e9 < memory.argument_size_in_bytes < 13.3e9
+    assert memory.temp_size_in_bytes < 0.1e9
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < 15.75 * 2 ** 30)
+    weights = sum(math.prod(x.shape) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    assert 10.35e9 < weights < 10.37e9
+    for p in held:
+        assert count_pool_copies(text, p.shape) == 0, p.shape
+    copied = count_weight_bytes_copied(text, params)
+    assert not set(copied) & {"copy", "transpose", "remat"}, copied
+    assert copied.get("convert", 0) <= 2 ** 20, copied
+    assert copied.get("slice", 0) + copied.get("dynamic-slice", 0) \
+        <= 5 * 2 ** 20, copied
+    counts = _kernel_counts(text)
+    assert set(counts) == {"conv_tail", "moe_grouped_matmul",
+                           "paged_rows_write", "paged_decode_attention"}
+    assert counts["conv_tail"] == 3 and counts["moe_grouped_matmul"] == 12
+    assert counts["paged_decode_attention"] == 2
+    assert counts["paged_rows_write"] == (4 if rows else 2)
+    assert not any(_pool_block_updates(text, p.shape) for p in held[:2])
