@@ -1,30 +1,33 @@
 """Operations and bytes of a gated short convolution's one-token call and of
 a decode step of a stack that has one in most layers beside a few attention
 layers, over sigmoid-routed experts (`lfm2-24b-a2b`), computed from shapes,
-from the program's own counters (`stats()["layers"]`, `["conv"]`, `["moe"]`,
-`["state"]`) and from the traced slice's kernel calls: the arithmetic behind
-the `conv_*` per-layer metrics, kept with the yardstick like `ssm_flops.py`
-and `moe_flops.py` (whose counts of single-query attention and of a grouped
+from the program's own counters (`stats()["layers"]`, `["conv"]`, `["moe"]`)
+and from the traced slice's kernel calls: the arithmetic behind the `conv_*`
+per-layer metrics, kept with the yardstick like `ssm_flops.py` and
+`moe_flops.py` (whose counts of single-query attention and of a grouped
 multiply these are).
 
 Counts are what the algorithm needs, whatever implements it.  A conv
 mixer's call reads its weights once (W_in d x 3d, W_out d x d and the taps
 k x d: 33.6 MB a layer at d = 2,048), each lane's tail (k - 1 rows of d
-numbers) once and writes it once, and its activations once.  The per-lane
-part between the two products (the kernel `conv_tail`: the gate B * u, the
-taps, the tail's overwrite, the gate C) must move the tail alone through
-the chip's memory, 2 (k - 1) d numbers a lane in and out, at (2 k + 2) d
-operations; its other operands are its neighbours' and may stay on the
-chip.  An expert that took no assignment is
-not read; a layer's K and V rows are read once for all query heads.  The
-layers of each kind are the program's own count (`stats()["layers"]`:
-`state` the conv layers, `kv` the attention layers, `experts`; the dense
-feed-forward layers are the rest), never `n_layers`.
+numbers) once and writes it once, and its activations once (`conv_mix`).
+The per-lane part between the two products (the gate B * u, the taps, the
+tail's overwrite, the gate C) has NO roofline here: the compiled step holds
+the tails' whole buffer, the projection and the result in the chip's vector
+memory (`S(1)` on every operand in the compiled text and in the trace's own
+event names), so the part moves no byte through HBM, and `peaks.json` has
+no peak for what it does move (PERF.md sections 6 and 7, PR 54: by the
+4.2 MB a call that the algorithm moves it read 195% of the HBM roofline).
+An expert that took no assignment is not read; a layer's K and V rows are
+read once for all query heads.  The layers of each kind are the program's
+own count (`stats()["layers"]`: `state` the conv layers, `kv` the attention
+layers, `experts`; the dense feed-forward layers are the rest), never
+`n_layers`.
 """
 
 from __future__ import annotations
 
-from benchmark import flops, moe_flops, ssm_flops
+from benchmark import moe_flops, ssm_flops
 
 
 def layers(run: dict):
@@ -36,21 +39,6 @@ def layers(run: dict):
         else None
 
 
-def conv_tail(lanes: float, f: dict, itemsize: int = 2):
-    """One layer's per-lane part of a T=1 call over `lanes` lanes: (flops,
-    bytes that must cross the chip's memory).  The tail (k - 1 rows of d) is
-    state: read once and written once; the taps' weights once.  The
-    projection's three thirds coming in and the gated result going out are
-    the neighbouring products' operands, which the compiler may and does
-    keep on the chip (a 1.5 MB value between two fusions of one program:
-    the cell's first traced run read 195% with them counted, 2.7 us a call
-    where 4.2 MB take 5.1), so they are NOT counted here; `conv_mix`, the
-    whole mixer, counts its activations once."""
-    d, k = f["d_model"], f["conv_taps"]
-    return (lanes * (2.0 * k + 2.0) * d,
-            itemsize * (lanes * d * 2 * (k - 1) + k * d))
-
-
 def conv_weight_bytes(f: dict, itemsize: int = 2) -> int:
     """A conv mixer's two projections and its taps."""
     d = f["d_model"]
@@ -58,11 +46,13 @@ def conv_weight_bytes(f: dict, itemsize: int = 2) -> int:
 
 
 def conv_mix(lanes: float, f: dict, itemsize: int = 2):
-    """One layer's whole T=1 call over `lanes` lanes: the two products, the
-    per-lane part between them, the weights once, the activations once (the
-    normed input, the projection written and read, the result)."""
+    """One layer's whole T=1 call over `lanes` lanes: the two products and
+    the per-lane part between them ((2 k + 2) d operations a lane: the gate
+    B * u, k taps, the gate C), the weights once, the lanes' tails read and
+    written (k - 1 rows of d each way), the activations once (the normed
+    input, the projection's three thirds written and read, the result)."""
     d, k = f["d_model"], f["conv_taps"]
-    return (2.0 * lanes * 4 * d * d + conv_tail(lanes, f, itemsize)[0],
+    return (lanes * d * (2.0 * 4 * d + 2.0 * k + 2.0),
             conv_weight_bytes(f, itemsize)
             + itemsize * lanes * d * (2 * (k - 1) + 1 + 3 + 1 + 1))
 
@@ -108,10 +98,10 @@ def lanes_per_step(run: dict):
 
 def steps(run: dict):
     """Step programs of the traced slice, from its kernel calls: every
-    program (the T=1 step and the pair's) runs `conv_tail` once a conv
-    layer over its [max_lanes, 1] rows."""
-    n = (layers(run) or {}).get("state")
-    kernel = ssm_flops.kernel(run, "conv_tail")
+    program (the T=1 step and the pair's) runs `paged_decode_attention`
+    once an attention layer over its [max_lanes, 1] rows."""
+    n = (layers(run) or {}).get("kv")
+    kernel = ssm_flops.kernel(run, "paged_decode_attention")
     return kernel["calls"] / n if kernel and n else None
 
 
@@ -153,7 +143,3 @@ def grouped_matmul_least_s(run: dict, peaks: dict):
         assignments / pairs, hit / pairs,
         {"d_model": f["d_model"], "d_ff": f["d_expert"]}, peaks)
     return least * kernel["calls"] / 3.0, kernel["seconds"]
-
-
-def roofline_s(counts: tuple, peaks: dict) -> float:
-    return flops.roofline_s(*counts, peaks)[0]

@@ -1,27 +1,28 @@
 """Share of device busy time in the traced slice that the conv mixers'
-own operations take, as far as the trace tells them apart: the kernel
-`conv_tail` (the per-lane part: gate, taps, the tail's overwrite) and every
-operation whose result is the projection [.., 3 d_model] wide (the product
-with W_in, of the T=1 rows, of a pair's rows and of a chunk's).  A named
-scope reaches nothing the trace's reader sees (PERF.md section 3), and the
-product with W_out has the shape of the attention's output product, so it is
-not told apart and not counted: a lower reading than the scope's whole.
-Nothing where the program has no `conv_tail` kernel."""
+own operations take, as far as the trace tells them apart, which is by the
+shape of their result (a named scope reaches nothing the trace's reader
+sees: PERF.md section 3): every operation whose result is the projection
+[.., 3 d_model] wide (the product with W_in, of the T=1 rows, of a pair's
+rows and of a chunk's) and every operation whose result is a slot's tail
+[.., (taps - 1) d_model] wide (the per-lane part's reads of the lanes'
+tails and their overwrite in the tails' buffer).  The per-lane part's other
+fusions and the product with W_out have the shapes of the attention's and
+the feed-forward's own, so they are not told apart and not counted, and an
+operation under the first sixty of the slice's table is not seen: a lower
+reading than the scope `conv_mix`'s whole.  Nothing where the configuration
+has no conv mixer."""
 
 from __future__ import annotations
 
 import re
 
-from benchmark import ssm_flops
-
 
 def read(run: dict):
-    t = run.get("trace") or {}
-    kernel = ssm_flops.kernel(run, "conv_tail")
-    if not kernel or not t.get("busy_s") or "conv_taps" not in run["fields"]:
+    t, f = run.get("trace") or {}, run["fields"]
+    if not t.get("busy_s") or "conv_taps" not in f:
         return None
-    wide = re.compile(r"\[(\d+,)*%d\]" % (3 * run["fields"]["d_model"]))
-    seconds = kernel["seconds"] + sum(
-        s for label, s in t.get("ops_table", [])
-        if wide.search(label) and not label.startswith("conv_tail"))
-    return 100.0 * seconds / t["busy_s"]
+    own = re.compile(r"\[(\d+,)*(%d|%d)\]" % (
+        3 * f["d_model"], (f["conv_taps"] - 1) * f["d_model"]))
+    seconds = sum(s for label, s in t.get("ops_table", [])
+                  if own.search(label))
+    return 100.0 * seconds / t["busy_s"] if seconds else None

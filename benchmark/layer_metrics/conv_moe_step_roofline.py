@@ -5,8 +5,8 @@ expert hit (the window's average from `stats()["moe"]`), the routers, the
 conv and attention operators' weights, the dense layer and the tied head;
 the K and V rows of the context the slice's own steps attended over in
 every attention layer; the tails of the lanes stepped, read and written, in
-every conv layer.  Steps are counted from the trace (`conv_tail` calls over
-the conv layers, the program's own count).  The share of the whole step: a
+every conv layer.  Steps are counted from the trace (`paged_decode_attention`
+calls over the attention layers, the program's own count).  The share of the whole step: a
 decode step is bound by these bytes."""
 
 from __future__ import annotations

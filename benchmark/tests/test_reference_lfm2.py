@@ -243,11 +243,9 @@ def test_conv_flops_against_hand_counts():
     # a conv mixer's weights: 2048 x 6144 + 2048 x 2048 + 3 x 2048, bf16
     assert conv_flops.conv_weight_bytes(f) == 2 * (12582912 + 4194304 + 6144)
     assert round(conv_flops.conv_weight_bytes(f) / 1e6, 1) == 33.6
-    # the per-lane part of 128 lanes: 2 rows of tail in and out (the
-    # projection and the result are the neighbouring products' operands)
-    flops, nbytes = conv_flops.conv_tail(128, f)
-    assert flops == 128 * 8 * 2048
-    assert nbytes == 2 * (128 * 2048 * 4 + 3 * 2048)
+    # the whole call of 128 lanes: two products, 8 operations a lane and
+    # column between them; 2 rows of tail in and out, the normed input,
+    # the projection written and read, the result
     flops, nbytes = conv_flops.conv_mix(128, f)
     assert flops == 2 * 128 * 4 * 2048 * 2048 + 128 * 8 * 2048
     assert nbytes == conv_flops.conv_weight_bytes(f) + 2 * 128 * 2048 * (
@@ -272,24 +270,26 @@ def _run(**over):
         "fields": f, "device": {"kind": "TPU v5 lite"},
         "traffic": {"engine": {"max_lanes": 128}, "trace": {}},
         "stats0": {"layers": {"state": 7, "kv": 2, "experts": 8, "window": 0},
-                   "admitted": 100,
+                   "prefix_misses": 10,
                    "conv": {"steps_t1": 0, "rows_t1": 0},
-                   "state": {"snapshots_adopted": 90},
+                   "ssm": {"snapshots_adopted": 90},
                    "moe": {"assignments": 0, "expert_load": [0] * 64,
                            "experts_hit": 0, "layer_steps": 0}},
         "stats1": {"layers": {"state": 7, "kv": 2, "experts": 8, "window": 0},
-                   "admitted": 300,
+                   "prefix_misses": 12,
                    "conv": {"steps_t1": 1000, "rows_t1": 126000},
-                   "state": {"snapshots_adopted": 288},
+                   "ssm": {"snapshots_adopted": 288},
                    "moe": {"assignments": 8000 * 600, "expert_load": [1] * 64,
                            "experts_hit": 8000 * 64, "layer_steps": 8000}},
         "trace": {"busy_s": 2.0, "kernels": {
-            "conv_tail": {"calls": 700.0, "seconds": 0.0035},
+            "paged_decode_attention": {"calls": 200.0, "seconds": 0.3},
             "moe_grouped_matmul": {"calls": 2400.0, "seconds": 1.4}},
-            "ops_table": [["fusion bf16[128,1,6144]", 0.02],
+            "ops_table": [["fusion bf16[128,6144]", 0.02],
                           ["fusion bf16[1152,1,6144]", 0.01],
-                          ["conv_tail bf16[128,2048] (kernel)", 0.0035],
-                          ["fusion bf16[128,1,2048]", 0.5]]},
+                          ["bitcast_select_fusion bf16[128,4096]", 0.002],
+                          ["fusion bf16[7,129,4096]", 0.0015],
+                          ["fusion bf16[128,11776]", 0.3],
+                          ["fusion bf16[128,2048]", 0.5]]},
     }
     run.update(over)
     return run
@@ -300,14 +300,13 @@ def test_the_conv_readers_on_hand_computed_numbers(monkeypatch):
     read = lambda name, run: manifest.module("layer_metrics", name).read(run)
     run = _run()
     monkeypatch.setattr(ssm_flops, "slice_context", lambda run: 128 * 4500.0)
+    # 198 admissions adopted a snapshot, 2 found no prefix
     assert read("conv_state_snapshot_hit_pct", run) == 100.0 * 198 / 200
+    # the W_in products and the operations on the lanes' tails, by shape
     assert read("conv_mix_share_pct", run) == pytest.approx(
-        100.0 * (0.0035 + 0.02 + 0.01) / 2.0)
+        100.0 * (0.02 + 0.01 + 0.002 + 0.0015) / 2.0)
     f = run["fields"]
-    least = conv_flops.conv_tail(126.0, f)[1] / 819e9
-    assert read("conv_tail_roofline", run) == pytest.approx(
-        100.0 * least * 700 / 0.0035)
-    # 100 step programs in the slice (700 calls over 7 conv layers)
+    # 100 step programs in the slice (200 calls over 2 attention layers)
     assert conv_flops.steps(run) == 100.0
     nbytes = 100 * (conv_flops.step_weight_bytes(f, run["stats1"]["layers"],
                                                  64.0)
@@ -328,8 +327,8 @@ def test_the_conv_readers_on_hand_computed_numbers(monkeypatch):
     # and no raise (the parent's side of a traced run)
     bare = _run(stats0={}, stats1={}, trace={"busy_s": 2.0, "kernels": {},
                                             "ops_table": []})
-    for name in ("conv_mix_share_pct", "conv_tail_roofline",
-                 "conv_moe_step_roofline", "conv_moe_grouped_matmul_roofline",
+    for name in ("conv_mix_share_pct", "conv_moe_step_roofline",
+                 "conv_moe_grouped_matmul_roofline",
                  "conv_state_snapshot_hit_pct"):
         assert read(name, bare) is None, name
         other = dict(bare, fields={"d_model": 2048})

@@ -5,11 +5,12 @@ lower precision or a wrong mechanism would read: the readings behind
 section 2).  On the chip, one process, no cluster:
 
   python3 benchmark/tools/lfm2_precision.py [--seed N] [--requests 192]
+                                            [--fixed-choice]
 
 It serves the cell's own load through the engine built from the cell's own
 files (bf16 weights, K/V pools over the attention layers and the tails'
 buffer over the conv layers, the grouped multiply over the 64 experts, the
-`conv_tail` and paged kernels): `--requests` greedy requests handed in AT
+paged kernels): `--requests` greedy requests handed in AT
 ONCE over the cell's `max_lanes` (its 16 shared 4,096-token heads, a
 question of the cell's lengths behind each, answers of the cell's lengths),
 so that every lane decodes beside 127 others, heads are prefilled in chunks
@@ -27,6 +28,20 @@ mantissa (e4m3's three bits under an ideal per-tensor scale: the nearest
 precision under the configuration's bf16; rounded in place, the chip holds
 one copy), each of which must NOT.  Exit code 1 where a verdict is the
 other way.
+
+`--fixed-choice` is the reading that says what the distance above is made
+of.  A top-4 of 64 sigmoid scores that lie close together is not stable
+under bf16 rounding: where the served path and the float32 reference choose
+other experts for a token in one layer, everything behind it differs, and
+the limits above have to leave room for that.  With the router's selection
+bias raised by 10 on every expert layer's first four experts (`s` lies in
+(0, 1): both sides then choose experts 0-3 for every token, weighted by
+their own unbiased `s` as before) the same engine, lanes, chunks, snapshots
+and decode rows are compared with the same reference, and what is left is
+the arithmetic's own distance.  The mode runs that one reading, prints it
+beside the limits, and writes `chiprun_out/lfm2_precision_fixed.json`; it
+must read correct, and by how much less than the drawn router's reading is
+the finding (PERF.md section 2).
 """
 
 from __future__ import annotations
@@ -56,11 +71,21 @@ def verdict(gaps, check) -> bool:
         and float(np.mean(gaps)) <= check["mean_gap"]
 
 
+def fixed_choice(params, k: int, by: float = 10.0):
+    """`params` with every `router_bias` raised by `by` on its first `k`
+    experts: the other leaves are the same arrays (the chip holds one
+    copy)."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, x: x.at[..., :k].add(by)
+        if getattr(path[-1], "key", None) == "router_bias" else x, params)
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--requests", type=int, default=192)
     ap.add_argument("--rehearse", action="store_true")
+    ap.add_argument("--fixed-choice", action="store_true")
     args = ap.parse_args(argv)
     from ray_tpu._private import compile_cache
     from ray_tpu.inference.engine import InferenceEngine
@@ -76,6 +101,8 @@ def main(argv=None) -> int:
     eng = InferenceEngine(model=config["module"].rsplit(".", 1)[-1],
                           config=cfg, seed=args.seed, auto_start=False,
                           **traffic["engine"])
+    if args.fixed_choice:
+        eng.update_params(fixed_choice(eng.params, cfg.n_experts_per_tok))
     rng = np.random.default_rng([args.seed, 5])
     heads = [rng.integers(0, cfg.vocab_size,
                           req["sessions"]["head_len"]).tolist()
@@ -101,8 +128,8 @@ def main(argv=None) -> int:
           traffic["engine"]["max_lanes"], "lanes live; judged",
           [len(o) for _, o in served], "tokens; prefix hits",
           st["prefix_hit_tokens"], "snapshots adopted",
-          st["state"]["snapshots_adopted"], "misses",
-          st["state"]["snapshot_misses"], "conv", st["conv"], flush=True)
+          st["ssm"]["snapshots_adopted"], "misses",
+          st["ssm"]["snapshot_misses"], "conv", st["conv"], flush=True)
     params = eng.params
     eng.shutdown()
     del eng, handles
@@ -125,18 +152,24 @@ def main(argv=None) -> int:
         print("[precision]", json.dumps(line), flush=True)
         return line
 
-    out = [judge(params, "float32 reference on the served bf16 weights",
-                 True),
-           judge(params, "the convolution's tail dropped (its own tap alone)",
-                 False, conv_tail=False),
-           judge(params, "the gate C left out", False, conv_c_gate=False)]
-    rounder = jax.jit(round_mantissa, donate_argnums=0)
-    params = jax.tree.map(lambda x: rounder(x) if x.ndim >= 2 else x, params)
-    out.append(judge(params, "every matrix rounded to a 3-bit mantissa",
-                     False))
+    if args.fixed_choice:
+        out = [judge(params, "float32 reference on the served bf16 weights, "
+                     "both sides' choice fixed to experts 0-3", True)]
+    else:
+        out = [judge(params, "float32 reference on the served bf16 weights",
+                     True),
+               judge(params, "the convolution's tail dropped (its own tap "
+                     "alone)", False, conv_tail=False),
+               judge(params, "the gate C left out", False,
+                     conv_c_gate=False)]
+        rounder = jax.jit(round_mantissa, donate_argnums=0)
+        params = jax.tree.map(lambda x: rounder(x) if x.ndim >= 2 else x,
+                              params)
+        out.append(judge(params, "every matrix rounded to a 3-bit mantissa",
+                         False))
     os.makedirs(os.path.join(ROOT, "chiprun_out"), exist_ok=True)
-    with open(os.path.join(ROOT, "chiprun_out", "lfm2_precision.json"),
-              "w") as f:
+    name = "lfm2_precision_fixed" if args.fixed_choice else "lfm2_precision"
+    with open(os.path.join(ROOT, "chiprun_out", name + ".json"), "w") as f:
         json.dump(out, f, indent=1)
     wrong = [r["reading"] for r in out if r["correct"] != r["must_be_correct"]]
     for reading in wrong:

@@ -791,12 +791,12 @@ class InferenceEngine:
         self._eva = ({"decode_steps": 0, "ctx_tokens": 0, "rows_attended": 0}
                      if kind == "windowed" else None)
         self._compact: dict = {}
-        # Over a state cache: the tokens its mixers' scans (T > 1) and
-        # updates (T = 1) stepped over, under "ssm".  Where the mixers
-        # keep no recurrence (a gated short convolution: the cache's state
-        # part is its tails alone) the part's counters go under "state"
-        # and the mixers' own under "conv": the populations (`steps`) and
-        # the valid rows their layers convolved, T=1 and chunk apart.
+        # Over a state cache, under "ssm" beside the state part's own
+        # counters: the tokens its mixers' scans (T > 1) and updates
+        # (T = 1) stepped over.  Mixers that keep no recurrence (a gated
+        # short convolution: the state part is its tails alone) count
+        # under "conv" instead: the populations (`steps`) and the valid
+        # rows their layers convolved, T=1 and chunk apart.
         self._stateful = kind == "state"
         self._recurrent = self._stateful and any(
             run.mixer is not None and run.mixer.state(self.config).heads
@@ -1166,10 +1166,10 @@ class InferenceEngine:
             # A state cache: slots of state and of snapshots, what the
             # index did with the snapshots, and the tokens stepped over.
             **({} if not self._stateful else
-               {"ssm": {**self.cache.kind_stats(), **self._ssm}}
-               if self._recurrent else
-               {"state": self.cache.kind_stats(),
-                "conv": {**self._conv, "layers": self._layers["state"]}}),
+               {"ssm": {**self.cache.kind_stats(),
+                        **(self._ssm if self._recurrent else {})},
+                **({} if self._recurrent else {"conv": {
+                    **self._conv, "layers": self._layers["state"]}})}),
             **({} if self._sparse is None else {
                 "sparse": dict(self._sparse)}),
             # A cache with a sliding part: its blocks given back in

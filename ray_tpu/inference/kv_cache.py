@@ -959,7 +959,7 @@ class LaneState(CachePart):
 
     def stats(self, cache):
         """Slots of state and of snapshots, and what the index did with the
-        latter (engine `stats()["ssm"]` or `["state"]`)."""
+        latter (engine `stats()["ssm"]`)."""
         cs = cache.stats
         return {
             "state_layers": int(self.slot_buffers[0].shape[0]),

@@ -1183,12 +1183,11 @@ def _conv_attend(rows, pools, p, spec, config, lanes):
     starts from zeros, every other from what its slot holds (what the step
     before left there, or a snapshot the engine copied in); a row with no
     valid token leaves its slot as it was.  One token a row whose slot is
-    its index is one kernel on the chip (`ops.ssm.gated_conv_step`, named
-    `conv_tail` like this scope); a chunk's rows, rows that name their
-    slots and the CPU take the same steps in XLA.  Returns ((C * conv,),
+    its index takes the same steps on the slots' rows as they are stored
+    (the form over [B, K - 1, D] gathers the new tail by each row's count
+    of valid tokens, and compiled for the chip turned the whole buffer's
+    layout around it: tests/test_tpu_aot.py).  Returns ((C * conv,),
     pools)."""
-    from ray_tpu.ops import ssm
-
     tails, = pools
     layer = p["cache_layer"]
     bcu, = rows
@@ -1196,12 +1195,26 @@ def _conv_attend(rows, pools, p, spec, config, lanes):
     # (a row nobody has also stands at position 0: it starts nothing)
     fresh = (positions[:, 0] == 0) & valid[:, 0]
     with jax.named_scope("conv_tail"):
-        b, t, width = bcu.shape[0], bcu.shape[1], bcu.shape[2] // 3
-        if t == 1 and slots is None and ssm.gated_conv_fits(
-                b, width, bcu.dtype):
-            y, tails = ssm.gated_conv_step(bcu[:, 0], tails, p["conv_w"],
-                                           valid[:, 0], fresh, layer)
-            return (y[:, None],), (tails,)
+        b = bcu.shape[0]
+        if bcu.shape[1] == 1 and slots is None:
+            # One token a row whose slot is its index (the decoding lanes
+            # of every step): the same steps on the slots' rows as they
+            # are stored, [B, (K - 1) D], with no row gathered.
+            d = bcu.shape[2] // 3
+            gate_b, gate_c, u = jnp.split(bcu[:, 0], 3, axis=-1)
+            old = _slot_rows(tails, layer, None, b)
+            start = jnp.where(fresh[:, None], 0, old)
+            v = gate_b * u
+            w = p["conv_w"].astype(jnp.float32)
+            behind = [start[:, i * d:(i + 1) * d]
+                      for i in range(config.conv_taps - 1)]
+            # (`ops.ssm.conv_tail`'s order of the sum: from the oldest)
+            conv = sum(r.astype(jnp.float32) * w[i]
+                       for i, r in enumerate(behind + [v]))
+            new = jnp.where(valid[:, :1], jnp.concatenate(
+                [start[:, d:], v.astype(old.dtype)], axis=1), old)
+            y = gate_c * conv.astype(u.dtype)
+            return (y[:, None],), (_slot_rows(tails, layer, None, b, new),)
         tail = jnp.where(fresh[:, None, None], 0, _slot_rows(
             tails, layer, slots, b).reshape(b, config.conv_taps - 1, -1))
         y, tail = _gated_conv(bcu, tail, p,

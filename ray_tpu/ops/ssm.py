@@ -1,8 +1,6 @@
 """State-space ops (Mamba-2's selective state space, SSD form): the causal
-depthwise convolution with a carried tail (and, for a mixer that is that
-convolution between two gates and nothing else, its one-token step as one
-kernel: `gated_conv_step`), the one-token update of a recurrent state and
-the chunked scan of a slice of tokens.
+depthwise convolution with a carried tail, the one-token update of a
+recurrent state and the chunked scan of a slice of tokens.
 
 The recurrence, a head j of group g at position t (x_t [P], B_t and C_t [N]
 shared by the group's heads, dt_t and A < 0 scalars a head):
@@ -118,88 +116,6 @@ def conv_tail(x, tail, conv_w, n_valid):
     rows = n_valid[:, None] + jnp.arange(k - 1, dtype=n_valid.dtype)
     new_tail = jnp.take_along_axis(ext, rows[:, :, None], axis=1)
     return y, new_tail.astype(tail.dtype)
-
-
-# --------------------------------------------------------------------------
-# T = 1 of a gated short convolution (LFM2's): gate, taps and the tail's
-# overwrite in one kernel, on the tails' buffer in place
-# --------------------------------------------------------------------------
-
-# Rows of one grid step: [32, 3 x 2048] bf16 in, two rows of tail in and
-# out and [32, 2048] out are 1.2 MB, double-buffered.
-_CONV_ROWS = 32
-
-
-def gated_conv_fits(rows: int, width: int, dtype) -> bool:
-    """Whether `gated_conv_step` has its kernel for these rows: on the TPU,
-    whole tiles of `dtype` (16 sublanes of bf16, 8 of float32) and whole
-    lanes."""
-    tile = 32 // jnp.dtype(dtype).itemsize
-    return (not _interpret_kernels() and rows % tile == 0
-            and width % 128 == 0)
-
-
-def _conv_tail_kernel(layer_ref, bcu_ref, tail_ref, w_ref, flags_ref, y_ref,
-                      tail_out_ref, *, taps: int, width: int):
-    """One block of rows: v = B * u, the taps over the slot's K - 1 rows and
-    v, y = C * conv, and the slot left holding its last K - 1 rows."""
-    del layer_ref                       # only the index maps read it
-    c = width
-    flags = flags_ref[...]              # [R, 1]: bit 0 live, bit 1 fresh
-    live, fresh = (flags & 1) > 0, (flags & 2) > 0
-    v = bcu_ref[:, 0:c] * bcu_ref[:, 2 * c:3 * c]
-    old = tail_ref[...]
-    start = jnp.where(fresh, jnp.zeros_like(old), old)
-    w = w_ref[...].astype(jnp.float32)
-    # (`conv_tail`'s order of the sum: from the oldest row)
-    acc = sum(start[:, i * c:(i + 1) * c].astype(jnp.float32) * w[i:i + 1, :]
-              for i in range(taps - 1)) + v.astype(jnp.float32) * w[
-                  taps - 1:taps, :]
-    y_ref[...] = bcu_ref[:, c:2 * c] * acc.astype(y_ref.dtype)
-    new = jnp.concatenate([start[:, c:], v.astype(old.dtype)], axis=1)
-    tail_out_ref[...] = jnp.where(live, new, old)
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def gated_conv_step(bcu, tails, conv_w, live, fresh, layer=0, *,
-                    interpret: bool = False):
-    """One token a row of a gated short convolution whose row i's slot is
-    slot i: bcu [B, 3 C] (the projection's thirds B | C | u), tails
-    [L, S, (K - 1) C] (a slot's K - 1 rows of B * u one behind the other,
-    S >= B), conv_w [K, C], live [B] (the row holds a token: a row that
-    does not leaves its slot as it was), fresh [B] (the row starts from
-    zeros, whatever its slot holds).  Returns (C * conv [B, C] in bcu's
-    dtype, tails with the rows' slots at `layer` overwritten in place):
-    what `conv_tail` and the gates around it compute, the taps summed in
-    float32 from the oldest."""
-    b, c = bcu.shape[0], bcu.shape[1] // 3
-    taps = conv_w.shape[0]
-    rows = next(r for r in (_CONV_ROWS, 16, 8) if b % r == 0)
-    flags = (live.astype(jnp.int32) + 2 * fresh.astype(jnp.int32))[:, None]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,              # the layer
-        grid=(b // rows,),
-        in_specs=[pl.BlockSpec((rows, 3 * c), lambda i, ly: (i, 0)),
-                  pl.BlockSpec((None, rows, (taps - 1) * c),
-                               lambda i, ly: (ly[0], i, 0)),
-                  pl.BlockSpec((taps, c), lambda i, ly: (0, 0)),
-                  pl.BlockSpec((rows, 1), lambda i, ly: (i, 0))],
-        out_specs=[pl.BlockSpec((rows, c), lambda i, ly: (i, 0)),
-                   pl.BlockSpec((None, rows, (taps - 1) * c),
-                                lambda i, ly: (ly[0], i, 0))],
-    )
-    y, tails = pl.pallas_call(
-        functools.partial(_conv_tail_kernel, taps=taps, width=c),
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((b, c), bcu.dtype),
-                   jax.ShapeDtypeStruct(tails.shape, tails.dtype)],
-        input_output_aliases={2: 1},        # the tails, after the scalar
-        interpret=interpret,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
-        name="conv_tail",
-    )(jnp.asarray(layer, jnp.int32).reshape(1), bcu, tails, conv_w, flags)
-    return y, tails
 
 
 # --------------------------------------------------------------------------

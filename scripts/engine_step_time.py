@@ -94,8 +94,8 @@ rule says):
 
 Since PR 54 `mixed=lfm2:4` is `serve_lfm2_rag_decode`'s shapes: the T=1
 program at 128 lanes (`mixed_lfm2_lanes4_t1_ms`: 127 lanes decoding at
-1.2-2.4k tokens, the experts of 8 layers, the `conv_tail` kernel over 128
-tails in 7) and the pair's at 128 + 4 x 256 rows
+1.2-2.4k tokens, the experts of 8 layers, the conv mixers' per-lane part
+over 128 tails in 7) and the pair's at 128 + 4 x 256 rows
 (`mixed_lfm2_lanes4_admit_ms`); `mixed=lfm2` the one-row chunk an admission
 behind a cached head rides ([1, 256] beside the 128):
 

@@ -19,7 +19,6 @@ import pytest
 from benchmark.reference import lfm2 as ref
 from ray_tpu.inference import InferenceEngine, PagedKVCache
 from ray_tpu.models import decoder, lfm2
-from ray_tpu.ops import ssm
 
 NANO = lfm2.CONFIGS["lfm2-nano"]
 # float32 on both sides, sums in another order: 2e-5 of the largest logit,
@@ -113,45 +112,51 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits(chunk):
 
 @pytest.mark.parametrize("dtype,rows,width", [
     (jnp.float32, 16, 128), (jnp.bfloat16, 48, 256)],
-    ids=["f32_one_block", "bf16_three_blocks"])
-def test_the_conv_tail_kernel_is_the_gates_the_taps_and_the_overwrite(
+    ids=["f32_16_rows", "bf16_48_rows"])
+def test_one_token_a_row_is_the_gates_the_taps_and_the_overwrite(
         dtype, rows, width):
-    """`ops.ssm.gated_conv_step` (the chip's T=1 path, here interpreted)
-    against the XLA steps a chunk's rows take: in float32 the same result
-    to the last bit but one (a fused multiply-add's); in bf16 to a few of
-    bf16's own last bits of the taps' terms (XLA keeps the gated product's
-    excess precision through the taps where the kernel rounds it to bf16
-    first, as the tail stores it); the same tails bit for bit; a row
-    that holds no token leaves its slot, a fresh row reads zeros, the slots
-    behind the rows and the other layers stay."""
+    """`decoder._conv_attend` over one token a row whose slot is its index
+    (the decoding lanes' part of every step) against the same steps written
+    out a row at a time in numpy: y = C * (w0 t0 + w1 t1 + w2 B u), the
+    taps summed in float32 from the oldest, and the slot left holding
+    (t1, B u); a row that holds no token leaves its slot, a fresh row reads
+    zeros, the slots behind the rows and the other layers stay.  Rows that
+    NAME their slots (a chunk's: the form over [B, K - 1, D] with its
+    gather) leave the same tails bit for bit."""
     rng = np.random.default_rng(0)
     layers, slots, taps = 3, rows + 1, 3
-    bcu = jnp.asarray(rng.standard_normal((rows, 3 * width)), dtype)
+    bcu = jnp.asarray(rng.standard_normal((rows, 1, 3 * width)), dtype)
     tails = jnp.asarray(
         rng.standard_normal((layers, slots, (taps - 1) * width)), dtype)
     w = jnp.asarray(rng.uniform(-0.5, 0.5, (taps, width)), dtype)
-    live = jnp.asarray(rng.random(rows) < 0.8)
-    fresh = jnp.asarray(rng.random(rows) < 0.3) & live
-    assert bool((~live).any()) and bool(fresh.any())
-    y, out = ssm.gated_conv_step(bcu, tails, w, live, fresh, 1,
-                                 interpret=True)
-    gate_b, gate_c, u = jnp.split(bcu[:, None], 3, axis=-1)
-    v = gate_b * u
-    start = jnp.where(fresh[:, None, None], 0,
-                      tails[1, :rows].reshape(rows, taps - 1, width))
-    conv, new = ssm.conv_tail(v, start, w, live.astype(jnp.int32))
-    want = (gate_c * conv.astype(dtype))[:, 0]
+    live = rng.random(rows) < 0.8
+    fresh = (rng.random(rows) < 0.3) & live
+    assert (~live).any() and fresh.any()
+    positions = jnp.asarray(np.where(fresh, 0, 7)[:, None], jnp.int32)
+    attend = lambda slots: decoder._conv_attend(
+        (bcu,), (tails,), {"conv_w": w, "cache_layer": jnp.int32(1)}, None,
+        dataclasses.replace(NANO, conv_taps=taps),
+        decoder.Lanes(None, positions, jnp.asarray(live[:, None]), None,
+                      slots))
+    (y,), (out,) = attend(None)
+    (named,), (named_out,) = attend(jnp.arange(rows, dtype=jnp.int32))
+    np.testing.assert_array_equal(out, named_out)
+    f32 = lambda x: np.asarray(x, np.float32)
+    gate_b, gate_c, u = np.split(f32(bcu[:, 0]), 3, axis=-1)
+    v = f32(jnp.asarray(gate_b * u, dtype))         # as the tail stores it
+    old = f32(tails[1, :rows]).reshape(rows, taps - 1, width)
+    start = np.where(fresh[:, None, None], 0, old)
+    conv = start[:, 0] * f32(w[0]) + start[:, 1] * f32(w[1]) + v * f32(w[2])
+    want = gate_c * f32(jnp.asarray(conv, dtype))
+    kept = np.where(live[:, None, None],
+                    np.stack([start[:, 1], v], axis=1), old)
     np.testing.assert_array_equal(
-        out, tails.at[1, :rows].set(new.reshape(rows, -1)))
-    np.testing.assert_allclose(
-        np.asarray(y, np.float32), np.asarray(want, np.float32),
-        **(dict(rtol=2 ** -22, atol=1e-6) if dtype == jnp.float32
-           else dict(rtol=2 ** -6, atol=0.02)))
-    keep = np.flatnonzero(~np.asarray(live))
-    np.testing.assert_array_equal(np.asarray(out)[1, keep],
-                                  np.asarray(tails)[1, keep])
-    # what decides the path: whole tiles, whole lanes, and the chip
-    assert not ssm.gated_conv_fits(rows, width, dtype)      # the CPU
+        f32(out), f32(tails.at[1, :rows].set(
+            jnp.asarray(kept.reshape(rows, -1), dtype))))
+    close = (dict(rtol=2 ** -22, atol=1e-6) if dtype == jnp.float32
+             else dict(rtol=2 ** -6, atol=0.02))
+    np.testing.assert_allclose(f32(y[:, 0]), want, **close)
+    np.testing.assert_allclose(f32(named), f32(y), **close)
 
 
 ENGINE = dict(auto_start=False, max_lanes=4, block_size=4, num_blocks=(96, 4),
@@ -177,13 +182,15 @@ def test_a_lane_that_adopts_a_snapshot_decodes_what_one_that_prefilled_does():
     eng = InferenceEngine("lfm2", NANO, _init(), **ENGINE)
     _run(eng, eng.submit(first, 4))
     st = eng.stats()
-    assert st["state"]["snapshots_taken"] == 1 and st["prefix_hit_tokens"] == 0
+    assert st["ssm"]["snapshots_taken"] == 1 and st["prefix_hit_tokens"] == 0
     handle = eng.submit(second, 16)
     out, = _run(eng, handle)
     st = eng.stats()
     assert st["prefix_hit_tokens"] == 32
-    assert st["state"]["snapshots_adopted"] == 1
-    assert st["state"]["state_buffers"] == 1 and "ssm" not in st
+    assert st["ssm"]["snapshots_adopted"] == 1
+    # the part's counters where every state cache has them; no recurrence
+    assert st["ssm"]["state_buffers"] == 1
+    assert "tokens_updated" not in st["ssm"]
     # 3 conv layers; rows: both prompts' own tokens less the adopted head
     assert st["conv"]["layers"] == 3
     assert st["conv"]["rows_chunk"] == len(first) + 7

@@ -40,18 +40,16 @@ def _lfm2_init():
         lfm2.CONFIGS["lfm2-nano"], jax.random.key(0))
 
 
-# buffers of the state part -> family, config, parameters, reference, and
-# the key of `stats()` that carries the part's counters
+# buffers of the state part -> family, config, parameters, reference
 FAMILIES = {
-    "two_buffers": ("falconh1", NANO, _init, ref, "ssm"),
-    "one_buffer": ("lfm2", lfm2.CONFIGS["lfm2-nano"], _lfm2_init, lfm2_ref,
-                   "state"),
+    "two_buffers": ("falconh1", NANO, _init, ref),
+    "one_buffer": ("lfm2", lfm2.CONFIGS["lfm2-nano"], _lfm2_init, lfm2_ref),
 }
 BUFFERS = list(FAMILIES)
 
 
 def _engine(buffers="two_buffers", **kw):
-    family, cfg, init, _, _ = FAMILIES[buffers]
+    family, cfg, init, _ = FAMILIES[buffers]
     return InferenceEngine(family, cfg, init(), **{**ENGINE, **kw})
 
 
@@ -71,7 +69,7 @@ def _greedy(prompt, n):
 
 
 def _served_is_the_references(prompt, out, buffers="two_buffers"):
-    _, _, init, reference, _ = FAMILIES[buffers]
+    _, _, init, reference = FAMILIES[buffers]
     want = np.asarray(jnp.argmax(reference.row_logits(
         init(), np.asarray(prompt + out)), -1))
     return out == want[len(prompt) - 1:len(prompt) + len(out) - 1].tolist()
@@ -113,19 +111,18 @@ def test_a_lane_adopted_from_a_snapshot_equals_one_prefilled_from_token_0(
     turn; what it serves is what an engine without a prefix cache serves
     for the same prompt, token for token, and the reference's."""
     first, second = _prompts(1, 32, (5, 7))
-    key = FAMILIES[buffers][4]
     eng = _engine(buffers, prefill_lanes=2)
     _run(eng, eng.submit(first, 4))
     st = eng.stats()
-    assert st[key]["snapshots_taken"] == 1 and st["prefix_hit_tokens"] == 0
+    assert st["ssm"]["snapshots_taken"] == 1 and st["prefix_hit_tokens"] == 0
     out, = _run(eng, eng.submit(second, 16))
     st = eng.stats()
-    assert st["prefix_hit_tokens"] == 32 and st[key]["snapshots_adopted"] == 1
+    assert st["prefix_hit_tokens"] == 32 and st["ssm"]["snapshots_adopted"] == 1
     part = eng.cache.parts[0]
-    assert st[key]["state_buffers"] == len(part.wire) == len(
+    assert st["ssm"]["state_buffers"] == len(part.wire) == len(
         eng.cache.buffers) == len(BUFFERS) - BUFFERS.index(buffers)
-    assert st[key]["state_bytes"] == sum(b.nbytes for b in part.buffers)
-    assert st[key]["snapshot_bytes"] == sum(
+    assert st["ssm"]["state_bytes"] == sum(b.nbytes for b in part.buffers)
+    assert st["ssm"]["snapshot_bytes"] == sum(
         b.nbytes for b in part.snap_buffers)
     if buffers == "two_buffers":
         assert st["ssm"]["tokens_scanned"] == len(first) + 7
@@ -194,7 +191,6 @@ def test_a_match_is_refused_where_blocks_exist_and_the_snapshot_was_evicted(
     least recently used and goes; its K/V blocks are still indexed, and the
     index serves nothing of them: the request prefills from token 0 and is
     counted."""
-    key = FAMILIES[buffers][4]
     eng = _engine(buffers, num_blocks=(96, 2), prefill_lanes=2)
     heads = [_prompts(10 + i, 32, (5,))[0] for i in range(3)]
     for prompt in heads:
@@ -202,10 +198,10 @@ def test_a_match_is_refused_where_blocks_exist_and_the_snapshot_was_evicted(
     cache = eng.cache
     assert [cache.match_len(p) for p in heads] == [0, 32, 32]
     assert len(cache.match_prefix(heads[0])) == 0 and cache.parts[0].beyond == 9
-    assert len(cache.index) and eng.stats()[key]["snapshots_evicted"] == 1
+    assert len(cache.index) and eng.stats()["ssm"]["snapshots_evicted"] == 1
     again = heads[0][:32] + [1, 2, 3]
     out, = _run(eng, eng.submit(again, 8))
-    st = eng.stats()[key]
+    st = eng.stats()["ssm"]
     assert st["snapshot_misses"] == 1 and st["snapshots_adopted"] == 0
     assert _served_is_the_references(again, out, buffers)
     assert cache.match_len(again) == 32         # taken anew behind its head
