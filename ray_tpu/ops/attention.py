@@ -88,13 +88,21 @@ def _build_mask(q_len, k_len, causal, segment_ids):
 # K and V (backward: Q and dO) arriving a block a grid step with the carry
 # in VMEM scratch between them, and a grid step walks its block in tiles.
 # Which tiles is decided while tracing, not on the chip: a causal block on
-# the diagonal visits the tiles at or under it (10 of 16 at 256 x 256) and
+# the diagonal visits the tiles at or under it (3 of 4 at 512 x 512) and
 # masks only those the diagonal crosses, a block under the diagonal visits
 # all of its tiles and masks none, a block over it is neither copied in nor
 # computed.  So a grid step is straight-line code the compiler schedules
 # across tiles; the same walk as a `fori_loop` with bounds from the tile's
 # position was slower than the kernels it was to replace (PERF.md section
-# 6, PR 44: a loop trip waits out every product's latency).
+# 6, PR 44: a loop trip waits out every product's latency).  The mask is a
+# constant the compiler knows: of a crossed tile it computes the vector
+# unit's passes (scale, maximum, exponential, sum) only for the registers
+# (8 kv x 128 q positions) that hold a visible pair, whole tile or not, so
+# what a crossed tile wastes is its products' time on the matrix units.
+# The backward walks a crossed tile as sub-tiles for that (`_tiles`); the
+# forward does not: each sub-tile is a step of the online softmax with its
+# own rescale, and its products fill the four matrix units worse than the
+# whole tile's (PERF.md section 6, PR 56).
 #
 # A tile is S^T: kv positions along sublanes, q positions along lanes.  The
 # softmax's running maximum and sum, the logsumexp and delta = rowsum(dO * O)
@@ -120,10 +128,17 @@ def _build_mask(q_len, k_len, causal, segment_ids):
 # of 128 or 256, or TWO heads of 64, and a grid step walks the tiles of each
 # of its heads.  Of two heads in a block, one's S^T is the product over all
 # 128 lanes with the OTHER head's lanes of Q selected to zero (`where`: a
-# lane that is not the head's must not reach a score), likewise dP with dO;
-# P^T dO and dS^T Q of the two heads, with dO and Q so selected, add up to
-# the packed dv and dk; V^T P and K^T dS take the head's 64 lanes of V and
-# K and give the head's 64 rows of the transposed accumulators O^T and dq^T
+# lane that is not the head's must not reach a score), likewise dP with dO,
+# and the two heads' are ONE product, [kv, 2 x q] of K with the two
+# selections of Q one under the other (`_heads_rows`): eight column parts
+# for the four matrix units where a head's four (two in a sub-tile) left
+# them to wait on each other, and from it the compiler drops the passes
+# that only the mask's constant would replace (PR 56: the forward 15% and
+# the backward 10% faster for it).  The backward's statistics, P and dS are
+# then [kv, 2 x q] too, and P^T dO and dS^T Q of the two heads, dO and Q so
+# selected, are each one product that contracts over both heads' q and
+# gives the packed dv and dk; V^T P and K^T dS take the head's 64 lanes of V
+# and K and give the head's 64 rows of the transposed accumulators O^T and dq^T
 # [128, q], which are transposed once into lane-dense [q, 128] stores.
 # (V^T P over all 128 lanes with half the rows thrown away was 16% slower
 # in the forward: PERF.md section 6, PR 51.)  A head count that is odd at
@@ -135,10 +150,22 @@ def _build_mask(q_len, k_len, causal, segment_ids):
 # results back, 1.28 ms a layer-step beside kernels of 2.08 at the train
 # cell's shape.
 
-# Rows and columns of a tile at most, forward and backward (the sweep of
-# PERF.md section 6, PR 44, at a head of 64 over 1,024 positions).
+# Rows and columns of a tile at most, forward and backward, and the columns
+# of the score product (q positions x the heads of a column block) down to
+# which the backward cuts a tile the diagonal crosses.  The sweeps of
+# PERF.md section 6: PR 44's at a head of 64 over 1,024 positions (whole
+# tiles, a product a head: forward 512, backward 256) and PR 56's with the
+# heads' scores one product, ms a layer-step at [24,1024,12,64] / at
+# [4,2048,16,128], the parent's whole tiles of 256 at 1.335 / 1.180:
+# backward 512 cut to 128: 1.162 / 1.216; 512 cut to 256: 1.194 / 1.175;
+# 512 whole: 1.399 / 1.284; 256 cut to 128: 1.189 / 1.221; 256 whole:
+# 1.210 / 1.180.  So 128 q positions where two heads share a block and 256
+# where a head has one to itself, which is 256 columns in both.  The
+# forward's crossed tiles stay whole: 0.634 against 0.672 as halves and
+# 0.820 as quarters (0.745, 0.975 and 0.994 with a product a head).
 _FLASH_FWD_TILE = 512
-_FLASH_BWD_TILE = 256
+_FLASH_BWD_TILE = 512
+_FLASH_BWD_CROSSED = 256
 # Scoped VMEM a kernel may ask for beyond the compiler's default 16 MiB
 # (the backward holds a head's dq: 8 bytes a q position and column).
 _FLASH_VMEM_LIMIT = 96 * 1024 * 1024
@@ -153,29 +180,49 @@ def _dot(a, b, dims=_NN):
                                preferred_element_type=jnp.float32)
 
 
-def _tiles(n_q, bq, n_kv, bk, diagonal):
-    """(q0, k0, offset) of the tiles to visit in a block of n_q x n_kv
-    tiles.  Off the diagonal (or not causal) all of them, unmasked (offset
-    None).  On it those with a position at or under the diagonal, and for
-    the ones it crosses the offset of the tile's first kv position past
-    its first q position, which is what its mask needs."""
-    for q0 in range(0, n_q * bq, bq):
-        for k0 in range(0, n_kv * bk, bk):
+def _tiles(n_q, bq, n_kv, bk, diagonal, least=0, q_at=0, k_at=0):
+    """(q0, rows, k0, columns, offset): where the tiles to visit in a block
+    of n_q x n_kv tiles begin (from `q_at`, `k_at`) and how many q and kv
+    positions each holds.  Off the diagonal (or not causal) all of them,
+    whole and unmasked (offset None).  On it the tiles wholly under the
+    diagonal, whole and unmasked, and the tiles the diagonal crosses with
+    the offset of their first kv position past their first q position,
+    which is what their mask needs.  With `least`, a crossed tile of twice
+    `least` positions (or a multiple) a side is walked as its four quarters
+    in the same way: the one over the diagonal is not visited, the one
+    under it is unmasked, the two it crosses are cut again."""
+    for q0 in range(q_at, q_at + n_q * bq, bq):
+        for k0 in range(k_at, k_at + n_kv * bk, bk):
             if not diagonal or k0 + bk - 1 <= q0:
-                yield q0, k0, None
-            elif k0 <= q0 + bq - 1:
-                yield q0, k0, k0 - q0
+                yield q0, bq, k0, bk, None
+            elif k0 > q0 + bq - 1:
+                pass                            # wholly over the diagonal
+            elif least and min(bq, bk) % (2 * least) == 0:
+                yield from _tiles(2, bq // 2, 2, bk // 2, True, least, q0, k0)
+            else:
+                yield q0, bq, k0, bk, k0 - q0
 
 
-def _scores_t(k, q, scale, offset):
-    """S^T = K Q^T * scale of one tile, [kv, q] float32; masked where
-    `offset` (the tile's first kv position past its first q position, see
-    `_tiles`) is not None."""
-    s = _dot(k, q, _NT) * scale
+def _scores_t(k, q, scale):
+    """S^T = K Q^T * scale of one tile for every head of the column block
+    at once, float32 [kv, heads x q positions]: q [heads x q positions,
+    lanes] holds the tile's q positions once a head (`_heads_rows`).  Why
+    one product and not one a head: the comment above, on how a head lies
+    in HBM."""
+    return _dot(k, q, _NT) * scale
+
+
+def _masked(s, offset, n_q):
+    """s, scores [kv, q] of a tile or [kv, heads x n_q] of its heads side by
+    side, with NEG_INF where a pair lies over the diagonal; `offset` is the
+    tile's first kv position past its first q position (`_tiles`), None
+    for a tile under the diagonal."""
     if offset is None:
         return s
-    visible = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-               - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)) >= offset
+    col = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if s.shape[1] != n_q:
+        col = jax.lax.rem(col, n_q)
+    visible = col - jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) >= offset
     return jnp.where(visible, s, NEG_INF)
 
 
@@ -223,6 +270,14 @@ def _block_heads(lanes, d, width, block):
                               for lo in range(0, lanes, d)]
 
 
+def _heads_rows(x, heads):
+    """x [rows, lanes] once a head of `heads` (`_block_heads`), one under
+    the other, [heads x rows, lanes]: a head's rows hold zeros in every
+    lane that is not its own, so that a product over all the lanes with
+    them is the head's."""
+    return jnp.concatenate([only(x) for _, only in heads])
+
+
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                   acc_ref, *, d: int, width: int, bq: int, bk: int,
                   n_blocks: int, causal: bool, scale: float):
@@ -247,17 +302,17 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
         for q0, tiles in itertools.groupby(
                 _tiles(n_q, bq, n_kv, bk, diagonal), key=lambda t: t[0]):
             cols = slice(q0, q0 + bq)
-            q = q_ref[cols, :]
-            qs = [only(q) for _, only in heads]
+            q = _heads_rows(q_ref[cols, :], heads)
             carry = [(m_ref[g, :, cols], l_ref[g, :, cols],
                       acc_ref[rows, cols])
                      for g, (rows, _) in enumerate(heads)]
-            for _, k0, offset in tiles:
+            for _, _, k0, _, offset in tiles:
                 at = slice(k0, k0 + bk)
                 k, v = whole(k_ref[at, :]), v_ref[at, :]
+                scores = _scores_t(k, q, scale)
                 for g, (rows, _) in enumerate(heads):
                     m, l, acc = carry[g]
-                    s = _scores_t(k, qs[g], scale, offset)
+                    s = _masked(scores[:, g * bq:(g + 1) * bq], offset, bq)
                     m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
                     p = jnp.exp(s - m_new)
                     alpha = jnp.exp(m - m_new)
@@ -282,8 +337,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref, dqt_ref, dk_acc, dv_acc, *,
-                      d: int, width: int, bq: int, bk: int, causal: bool,
-                      scale: float):
+                      d: int, width: int, bq: int, bk: int, least: int,
+                      causal: bool, scale: float):
     """Grid (batch x column block, kv block, q block): dk and dv of a kv
     block over the q blocks from its own on, and every pair's share of dq,
     for each head of the column block.
@@ -294,7 +349,9 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     written at the column block's last grid step from the scratch dq^T
     [q blocks, lanes, block_q]; scratch dk, dv [block_k, lanes]; float32.
     With a head's Q and dO zero in the other head's lanes, dS^T Q and
-    P^T dO of the heads add up to the packed dk and dv."""
+    P^T dO over the heads' q positions side by side are the packed dk and
+    dv.  A tile the diagonal crosses is walked as sub-tiles down to
+    `least` q and kv positions (`_tiles`)."""
     i, j, c = (pl.program_id(a) for a in range(3))
     last = ((j == pl.num_programs(1) - 1) & (c == pl.num_programs(2) - 1))
     n_q, n_kv = q_ref.shape[0] // bq, k_ref.shape[0] // bk
@@ -311,9 +368,10 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
         dqt_ref[c] = jnp.zeros(dqt_ref.shape[1:], jnp.float32)
 
     def walk(diagonal):
-        for q0, tiles in itertools.groupby(
-                _tiles(n_q, bq, n_kv, bk, diagonal), key=lambda t: t[0]):
-            cols = slice(q0, q0 + bq)
+        for tile, tiles in itertools.groupby(
+                _tiles(n_q, bq, n_kv, bk, diagonal, least),
+                key=lambda t: t[0] // bq):
+            cols = slice(tile * bq, (tile + 1) * bq)
             q, do = q_ref[cols, :], do_ref[cols, :]
             qs = [only(q) for _, only in heads]
             dos = [only(do) for _, only in heads]
@@ -323,22 +381,24 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                     * whole(o_ref[cols, :]).astype(jnp.float32)).T
             deltas = [jnp.sum(do_o[rows], axis=0, keepdims=True)
                       for rows, _ in heads]
-            for _, k0, offset in tiles:
-                at = slice(k0, k0 + bk)
+            for q0, nq, k0, nk, offset in tiles:
+                at, part = slice(k0, k0 + nk), slice(q0, q0 + nq)
+                of = slice(q0 - tile * bq, q0 - tile * bq + nq)
                 k, v = whole(k_ref[at, :]), whole(v_ref[at, :])
-                dk = dv = None
+                # the heads one beside the other: [kv, heads x q]
+                q_all = jnp.concatenate([x[of] for x in qs])
+                do_all = jnp.concatenate([x[of] for x in dos])
+                lse = jnp.concatenate(
+                    [lse_ref[g, :, part] for g in range(len(heads))], axis=1)
+                delta = jnp.concatenate([x[:, of] for x in deltas], axis=1)
+                p = jnp.exp(_masked(_scores_t(k, q_all, scale), offset, nq)
+                            - lse)
+                ds = (p * (_dot(v, do_all, _NT) - delta)).astype(q.dtype)
+                dv_acc[at, :] += _dot(p.astype(do.dtype), do_all)
+                dk_acc[at, :] += _dot(ds, q_all)
                 for g, (rows, _) in enumerate(heads):
-                    p = jnp.exp(_scores_t(k, qs[g], scale, offset)
-                                - lse_ref[g, :, cols])
-                    ds = (p * (_dot(v, dos[g], _NT) - deltas[g])
-                          ).astype(q.dtype)
-                    dv_g = _dot(p.astype(do.dtype), dos[g])
-                    dk_g = _dot(ds, qs[g])
-                    dv = dv_g if dv is None else dv + dv_g
-                    dk = dk_g if dk is None else dk + dk_g
-                    dqt_ref[c, rows, cols] += _dot(k[:, rows], ds, _TN)
-                dv_acc[at, :] += dv
-                dk_acc[at, :] += dk
+                    dqt_ref[c, rows, part] += _dot(
+                        k[:, rows], ds[:, g * nq:(g + 1) * nq], _TN)
 
     _on_or_under_the_diagonal(walk, c, j, causal, dqt_ref.shape[0])
 
@@ -593,7 +653,8 @@ def _flash_bwd_heads(plan, q, k, v, do, out, lse):
     return _flash_call(
         plan, functools.partial(
             _flash_bwd_kernel, bq=_flash_tile(plan.block_q, _FLASH_BWD_TILE),
-            bk=_flash_tile(plan.block_k, _FLASH_BWD_TILE)),
+            bk=_flash_tile(plan.block_k, _FLASH_BWD_TILE),
+            least=_FLASH_BWD_CROSSED // plan.heads),
         vmem=_flash_dq_bytes(q_len, plan.lanes, q.dtype),
         grid=(batch * n, kv_len // plan.block_k, q_len // plan.block_q),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],

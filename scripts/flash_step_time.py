@@ -17,17 +17,24 @@ with `g` and what XLA still moves between the `[b, l, h*d]` arrays and the
 kernels, which is nothing where `h*d` is whole blocks of 128; on a tree
 before PR 51 also the head transposes to `[b*h, l, d]` and back and the
 `delta` pass; `others` names its longest rows) and the tiles the causal
-walk visits of a head's score square.  The train cell's twin of
-`scripts/engine_step_time.py`: not a tool the benchmark runs.  On the chip,
-from the root of a checkout (a parent's, to compare, with this file's path:
-it needs nothing of the program but `flash_attention`):
+walk visits of a head's score square, by count and by area.  The train
+cell's twin of `scripts/engine_step_time.py`: not a tool the benchmark
+runs.  On the chip, from the root of a checkout (a parent's, to compare,
+with this file's path: it needs nothing of the program but
+`flash_attention`):
 
   python3 scripts/flash_step_time.py [tile ...]
 
 With no argument the program's own tiles (`ops.attention._FLASH_FWD_TILE`,
-`_FLASH_BWD_TILE`); `fwd,bwd` pairs give the walk at each (1024 is one tile
-a head: nothing skipped, every score masked).  `--f32` times float32
-inputs, whose products stay float32.  The last line is one JSON object.
+`_FLASH_BWD_TILE`) and sub-tiles (`_FLASH_BWD_CROSSED`); `fwd,bwd` pairs or
+`fwd,bwd,crossed` triples give the walk at each (1024 is one tile a head:
+nothing skipped, every score masked; `crossed` 0: no sub-tiles).  `tiles`
+holds, forward then backward, the tiles the causal walk visits of a
+block's score square, those of them it masks and all there are, then the
+visited and the masked AREA as shares of the square, which a parent's and
+the change's checkout print alike whatever their tiles are.  `--f32` times
+float32 inputs, whose products stay float32.  The last line is one JSON
+object.
 """
 
 from __future__ import annotations
@@ -52,14 +59,27 @@ SHAPES = {"gpt2s_b24": (24, 1024, 12, 64), "gpt2xl_fsdp4": (6, 1024, 25, 64),
 CALLS = 10
 
 
-def tiles_visited(tile, block):
-    """(visited, masked, all) tiles of a diagonal block's walk, as the
-    program lists them; a tree before PR 44 has one masked tile a head."""
+def tiles_visited(tile, block, least):
+    """(visited, masked, all) tiles of a diagonal block's walk as the
+    program lists them, then the visited and the masked AREA as shares of
+    the block's score square.  A tree since PR 56 lists each tile with its
+    extent and, with `least`, a crossed tile as sub-tiles down to that many
+    positions; a tree before it lists whole tiles (`least` None).  Where
+    two trees' counts differ in kind their areas are the figures to
+    compare.  A tree before PR 44 has one masked tile a head."""
     if not hasattr(A, "_tiles"):
-        return 1, 1, 1
+        return 1, 1, 1, 1.0, 1.0
+    tile = A._flash_tile(block, tile)
     n = block // tile
-    seen = list(A._tiles(n, tile, n, tile, True))
-    return len(seen), sum(t[2] is not None for t in seen), n * n
+    if least is None:
+        seen = [(tile * tile, t[2]) for t in A._tiles(n, tile, n, tile, True)]
+    else:
+        seen = [(t[1] * t[3], t[4])
+                for t in A._tiles(n, tile, n, tile, True, least)]
+    masked = [area for area, offset in seen if offset is not None]
+    return (len(seen), len(masked), n * n,
+            round(sum(area for area, _ in seen) / block ** 2, 4),
+            round(sum(masked) / block ** 2, 4))
 
 
 def is_forward(text: str) -> bool:
@@ -102,11 +122,16 @@ def main(tiles, dtype):
     peaks = manifest.peaks(dev.device_kind)
     result = {"device": [dev.platform, dev.device_kind], "tree": os.getcwd(),
               "dtype": jnp.dtype(dtype).name, "rows": []}
-    own = (getattr(A, "_FLASH_FWD_TILE", 1024),
-           getattr(A, "_FLASH_BWD_TILE", 1024))   # a tree before PR 44: 1024
-    for fwd_tile, bwd_tile in tiles or [own]:
+    names = ("_FLASH_FWD_TILE", "_FLASH_BWD_TILE", "_FLASH_BWD_CROSSED")
+    # a tree before PR 44: one tile of 1,024; before PR 56: no sub-tiles
+    own = tuple(getattr(A, name, 1024 if "TILE" in name else None)
+                for name in names)
+    for walk in tiles or [own]:
+        fwd_tile, bwd_tile, crossed = walk = walk + own[len(walk):]
         if tiles:
-            A._FLASH_FWD_TILE, A._FLASH_BWD_TILE = fwd_tile, bwd_tile
+            for name, size in zip(names, walk):
+                if size is not None:
+                    setattr(A, name, size)
             A.flash_attention.clear_cache()
         for name, (b, s, h, d) in SHAPES.items():
             q, k, v, g = (jax.random.normal(jax.random.key(i), (b, s, h * d),
@@ -123,8 +148,14 @@ def main(tiles, dtype):
             took, others = device_ms(step, (q, k, v))
             block = min(s, 1024)
             row = {"shape": name, "tile": [fwd_tile, bwd_tile],
-                   "tiles": [tiles_visited(t, block)
-                             for t in (fwd_tile, bwd_tile)]}
+                   "crossed": crossed,
+                   # the forward's crossed tiles stay whole; the backward's
+                   # are cut until the heads of a block of max(d, 128)
+                   # columns make a score product `crossed` columns wide
+                   "tiles": [tiles_visited(fwd_tile, block,
+                                           None if crossed is None else 0),
+                             tiles_visited(bwd_tile, block, crossed
+                                           and crossed // (max(d, 128) // d))]}
             row.update({f"{key}_ms": ms for key, ms in took.items()})
             size = jnp.dtype(dtype).itemsize
             least = {"fwd": flops.roofline_s(

@@ -91,6 +91,24 @@ def test_flash_gradients_at_a_head_of_128(causal, dtype):
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
                          ids=["f32", "bf16"])
+def test_flash_gradients_at_two_blocks_a_head_of_128(dtype):
+    """2,048 positions are two blocks of 1,024: the pair on the diagonal,
+    its crossed tiles of 512 as sub-tiles of 256 in the backward, beside a
+    pair under it walked whole, each under its `pl.when`."""
+    _check(2048, 2048, None, 128, dtype, True)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+def test_flash_gradients_of_two_heads_at_blocks_of_512(dtype):
+    """Two heads of 64 in a block, two blocks of 512 a head: the heads'
+    scores one product in every tile, on the diagonal (one tile of 512 in
+    the forward, sub-tiles of 256 and 128 in the backward) and under it."""
+    _check(1024, 1024, (512, 512), 64, dtype, True, heads=2, apart=True)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
 def test_flash_gradients_of_a_ring_step(dtype):
     """Non-causal, an explicit scale, more keys than queries."""
     _check(256, 512, (128, 128), 64, dtype, False, scale=0.1)
@@ -162,13 +180,115 @@ def test_flash_plan_puts_whole_heads_in_blocks_of_128_columns():
 def test_flash_tiles_follow_the_blocks():
     assert A._flash_tile(1024, 512) == 512 and A._flash_tile(1024, 256) == 256
     assert A._flash_tile(384, 512) == 128 and A._flash_tile(96, 256) == 96
-    # the diagonal block of 1,024 in tiles of 256: 10 of 16, 4 of them masked
-    tiles = list(A._tiles(4, 256, 4, 256, True))
-    assert len(tiles) == 10 and sum(t[2] is not None for t in tiles) == 4
-    assert len(list(A._tiles(4, 256, 4, 256, False))) == 16
+    # the diagonal block of 1,024 in whole tiles, as the forward walks it at
+    # 512: 3 of 4, the 2 the diagonal crosses masked (at 256: 10 of 16, 4)
+    whole = list(A._tiles(2, 512, 2, 512, True))
+    assert whole == [(0, 512, 0, 512, 0), (512, 512, 0, 512, None),
+                     (512, 512, 512, 512, 0)]
+    assert len(list(A._tiles(4, 256, 4, 256, True))) == 10
+    # as the backward walks it at one head a block: the crossed tiles as
+    # three sub-tiles of 256 each, the one under the diagonal unmasked and
+    # the two it crosses masked from their own first pair
+    halves = list(A._tiles(2, 512, 2, 512, True, 256))
+    assert [t for t in halves if t[0] < 512] == [
+        (0, 256, 0, 256, 0), (256, 256, 0, 256, None), (256, 256, 256, 256, 0)]
+    assert len(halves) == 1 + 2 * 3 and sum(
+        t[4] is not None for t in halves) == 4
+    # and at two heads a block: those two cut again, nine tiles of 128 in a
+    # crossed tile of 512 beside the tile of 256 under its diagonal
+    tiles = list(A._tiles(2, 512, 2, 512, True, 128))
+    assert [t[1:4:2] for t in tiles if t[0] < 512] == [
+        (128, 128)] * 3 + [(256, 256)] + [(128, 128)] * 3
+    assert len(tiles) == 1 + 2 * 7
+    assert sum(t[4] is not None for t in tiles) == 8
+    assert {t[4] for t in tiles} == {None, 0}
+    # the tiles of a q tile of the whole size follow one another: the
+    # backward makes delta and the heads' Q and dO once a q tile
+    assert [t[0] // 512 for t in tiles] == sorted(t[0] // 512 for t in tiles)
+    assert len(list(A._tiles(2, 512, 2, 512, False, 128))) == 4
     # 128 x 64: a q tile sees the kv tiles up to its last row
-    assert [t[1:] for t in A._tiles(1, 128, 4, 64, True)] == [
-        (0, 0), (64, 64)]
+    assert [t[2:] for t in A._tiles(1, 128, 4, 64, True, 128)] == [
+        (0, 64, 0), (64, 64, 64)]
+
+
+# (block, tile at most, the smallest sub-tile of a crossed tile or 0 for
+# whole tiles, the visited share of the block's square at most or None)
+WALKS = [(1024, 512, 256, 0.63), (1024, 512, 128, 0.57),
+         (1024, 256, 128, 0.57),
+         (1024, 512, 0, 0.75), (1024, 256, 0, 0.625),
+         (2048, 512, 128, None), (1536, 512, 128, None),
+         (384, 512, 128, None), (96, 256, 128, None)]
+
+
+def _pairs(tiles, masked):
+    """How often each (q, k) of the tiles [(q0, rows, k0, columns, offset)]
+    is computed and kept: all of an unmasked tile, of a masked one those
+    `_masked` shows."""
+    size = max(max(t[0] + t[1], t[2] + t[3]) for t in tiles)
+    seen = np.zeros((size, size), int)
+    for q0, nq, k0, nk, offset in tiles:
+        if (offset is None) == masked:
+            continue
+        keep = np.ones((nk, nq), bool)
+        if masked:      # S^T [kv, q], the mask as the kernels make it
+            keep = np.asarray(A._masked(jnp.ones((nk, nq)), offset, nq)) == 1
+        seen[q0:q0 + nq, k0:k0 + nk] += keep.T
+    return seen
+
+
+@pytest.mark.parametrize("block,most,least,area", WALKS,
+                         ids=[f"{w[0]}-{w[1]}-{w[2]}" for w in WALKS])
+def test_the_diagonal_walk_computes_every_visible_pair_once(block, most,
+                                                            least, area):
+    """Host-side, no kernel: the extents `_tiles` lists for a diagonal
+    block cover every pair with k <= q exactly once and no unmasked extent
+    holds a pair with k > q; with `least` a crossed tile of twice that or
+    more is visited as sub-tiles (of a 1,024 square at most 0.63 at halves
+    of 512 and 0.57 at tiles of 128, where whole tiles visit 0.75 and
+    0.625), a crossed tile of 128 or less or an odd one as it was; off the
+    diagonal every tile whole."""
+    tile = A._flash_tile(block, most)
+    n = block // tile
+    tiles = list(A._tiles(n, tile, n, tile, True, least))
+    unmasked, masked = _pairs(tiles, False), _pairs(tiles, True)
+    q, k = np.indices((block, block))
+    assert ((unmasked + masked) == (k <= q)).all()
+    assert not unmasked[k > q].any()
+    visited = sum(t[1] * t[3] for t in tiles) / block ** 2
+    whole = list(A._tiles(n, tile, n, tile, True))
+    assert whole == [
+        (q0, tile, k0, tile, k0 - q0 if k0 + tile - 1 > q0 else None)
+        for q0 in range(0, block, tile) for k0 in range(0, q0 + 1, tile)]
+    if not least or tile % (2 * least):
+        assert tiles == whole
+    else:
+        assert visited < sum(t[1] * t[3] for t in whole) / block ** 2
+        assert min(min(t[1], t[3]) for t in tiles) == least
+    if area is not None:
+        assert 0.5 < visited <= area
+    assert list(A._tiles(n, tile, n, tile, False, least)) == [
+        (q0, tile, k0, tile, None) for q0 in range(0, block, tile)
+        for k0 in range(0, block, tile)]
+
+
+def test_the_diagonal_walk_of_tiles_that_are_not_square():
+    """128 q positions by 64 kv positions a tile (no caller makes one: a
+    causal block's tiles are square): not cut, two crossed tiles a q tile,
+    every visible pair once."""
+    tiles = list(A._tiles(2, 128, 4, 64, True, 128))
+    q, k = np.indices((256, 256))
+    assert ((_pairs(tiles, False) + _pairs(tiles, True)) == (k <= q)).all()
+    assert not _pairs(tiles, False)[k > q].any()
+    assert all(t[1::2] == (128, 64) for t in tiles)
+
+
+def test_the_mask_of_heads_side_by_side_is_each_heads_own():
+    """Scores [kv, heads x q]: every head's columns carry the tile's mask."""
+    one = np.asarray(A._masked(jnp.ones((128, 256)), -64, 256))
+    both = np.asarray(A._masked(jnp.ones((128, 512)), -64, 256))
+    assert (both[:, :256] == one).all() and (both[:, 256:] == one).all()
+    assert (one == 1).sum() == sum(min(128, c + 65) for c in range(256))
+    assert A._masked(both, None, 256) is both
 
 
 def test_a_head_whose_dq_does_not_fit_vmem_takes_the_reference(monkeypatch):

@@ -189,6 +189,38 @@ def test_flash_kernels_read_the_heads_where_the_train_step_leaves_them(
                for made_for in moved), moved
 
 
+def test_flash_kernels_compile_for_v5e_at_two_blocks_a_head_of_128(
+        v5e, as_on_chip):
+    """`flash_attention` and its gradients at `[4,2048,16,128]`, bf16,
+    causal (`scripts/flash_step_time.py`'s `head128`; a head of 128 is a
+    column block and 2,048 positions are two blocks of 1,024): the walk of
+    the pair on the diagonal, the backward's crossed tiles of 512 as
+    sub-tiles of 256, beside the walk of a pair under it, whole, each
+    under its `pl.when`.  A sub-tile's slices (256 rows of bf16, 256-lane
+    parts of the float32 `[128, q]` accumulator and `[1, q]` rows) are
+    aligned to the chip's tiling or the compiler refuses them here; the
+    three programs of the test above lower the sub-tiles of 128 that two
+    heads of 64 a block take."""
+    from ray_tpu.ops import attention as A
+    arg = _arg_on(v5e[0])
+    x = arg((4, 2048, 16 * 128), jnp.bfloat16)
+
+    def step(q, k, v, g):
+        def weighed(*wide):
+            out = A.flash_attention(
+                *(w.reshape(4, 2048, 16, 128) for w in wide), causal=True)
+            return jnp.sum(out.reshape(g.shape).astype(jnp.float32)
+                           * g.astype(jnp.float32))
+        return jax.value_and_grad(weighed, argnums=(0, 1, 2))(q, k, v)
+
+    text = jax.jit(step).lower(x, x, x, x).compile().as_text()
+    assert _kernel_counts(text) == {"flash_attention": 2}
+    plan = A._flash_plan(*(jax.ShapeDtypeStruct((4, 2048, 16, 128),
+                                                jnp.bfloat16),) * 2,
+                         True, None, 1024, 1024, False)
+    assert (plan.block_q, plan.block_k, plan.column_blocks) == (1024, 1024, 16)
+
+
 def test_train_step_compiles_under_a_v5e_mesh(v5e, as_on_chip):
     """A bare pallas_call under a multi-device jit fails to lower with
     "Mosaic kernels cannot be automatically partitioned"; the kernel must
