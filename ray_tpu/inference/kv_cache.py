@@ -785,7 +785,9 @@ class LaneState(CachePart):
     have K and V rows): where the mixer has a recurrence, `state`
     [n_layers, max_lanes + 1, heads, d_state, head_dim] float32 (narrow
     heads folded: `ops.ssm.state_shape`); always `tail` [n_layers,
-    max_lanes + 1, rows x width], its convolution's last rows.  A gated
+    max_lanes + 1, rows x width], its convolution's last rows (the pools
+    may be ONE latent pool as well: the chain of blocks that snapshots are
+    keyed by is then a chain of latent rows).  A gated
     short convolution keeps the tail alone: one buffer, no other.  A
     lane's slot is its index, the last slot is where a program sends the
     rows nobody has; every step overwrites them: `step_pools` hands the
@@ -1111,7 +1113,8 @@ class PagedKVCache:
         there (`decoder.CacheRows`), a `SlidingRows` part where some of its
         runs keep a sliding window's rows in pools of their own, a
         `LaneState` part where some of its layers have a mixer
-        (`decoder.StateRows`), beside the attention or in its place.  The
+        (`decoder.StateRows`), beside the attention or in its place,
+        behind a K and a V pool or behind ONE latent pool alike.  The
         pools have as many layers as the runs with an attention count
         (`Run.first`), the state part as many as those with the mixer.
         `ahead`: the positions a lane may be written past its committed
@@ -1139,11 +1142,12 @@ class PagedKVCache:
                 run for run in spec.runs if run.attn is not None)
         mixed = [run for run in spec.runs if run.mixer is not None]
         if mixed:
-            if latent or not n_layers or any(run.table for run in mixed):
+            if not n_layers or any(run.table for run in mixed):
                 raise NotImplementedError(
-                    "a state cache: K and V pools of at least one layer "
-                    "with an attention beside the mixers' state (a lane's "
-                    "chain of blocks is what its snapshots are keyed by)")
+                    "a state cache: rows of at least one layer with an "
+                    "attention (a K and a V pool, or one latent pool) "
+                    "beside the mixers' state (a lane's chain of blocks "
+                    "is what its snapshots are keyed by)")
             state_layers = layers_of(mixed)
             parts.append(lambda cache, n: LaneState(
                 cache, state_layers, mixed[0].mixer.state(config),
@@ -1679,16 +1683,19 @@ class PagedKVCache:
     @property
     def step_pools(self) -> tuple:
         """(k, v) as a step takes and returns them: the pools, or where a
-        part has buffers ((K, V, *buffers), None), the model's mixer taking
-        them behind its attention's."""
+        part has buffers ((*pools, *buffers), None): K and V, or the one
+        latent pool, the model's mixer taking the buffers behind its
+        attention's."""
         more = self.buffers
-        return ((self.k, self.v) + more, None) if more else (self.k, self.v)
+        return (tuple(self._pools) + more, None) if more \
+            else (self.k, self.v)
 
     def update_pools(self, k: jax.Array, v: Optional[jax.Array]) -> None:
         """Rebind the functional pools returned by a jitted step (in the
         form of `step_pools`)."""
         if self.buffers:
-            k, v, *more = k
+            more = list(k[len(self._pools):])
+            k = tuple(k[:len(self._pools)])
             for part in self.parts:
                 n = len(part.buffers)
                 part.rebind(tuple(more[:n]))

@@ -2,7 +2,8 @@
 
 A family (models/gpt.py, models/llama.py, models/axk1.py,
 models/evabyte.py, models/dots3.py, models/falconh1.py,
-models/nemotronh.py, models/afmoe.py, models/lfm2.py) is a config
+models/nemotronh.py, models/afmoe.py, models/lfm2.py,
+models/kimilinear.py) is a config
 dataclass, its parameter format (`init_params`, `param_specs`) and
 `spec(config)`: a `Spec` naming the parts its block is made of (norms: one
 in front of each part, and where the model has them one behind each too,
@@ -39,7 +40,9 @@ Design (no reference counterpart: Ray hosts models, it doesn't ship them):
     `EVA`: an exact window beside one summary row for every chunk behind
     it, `HEADS`'s cached form over a table whose rows are not one a token);
   * a run of layers may have a second mixer BESIDE its attention (`Mixer`;
-    `SSM`: Mamba-2's state-space mixer, ops/ssm.py): both read the block's
+    `SSM`: Mamba-2's state-space mixer, `KDA`: Kimi Delta Attention, a
+    state decayed a key channel and corrected by a delta rule, both
+    ops/ssm.py): both read the block's
     one normed input and both results are added to the residual stream,
     each path by its own factor where the model states them
     (`Multipliers`).  Over a cache the mixer's state is not rows: a
@@ -49,7 +52,8 @@ Design (no reference counterpart: Ray hosts models, it doesn't ship them):
     nothing in front (`Run.attn`, `Run.ffn`, `Run.mixer` None), each behind
     its one norm; or the mixer in the attention's PLACE, in front of a
     feed-forward (`CONV`: LFM2's gated short convolution, whose lane state
-    is its convolution's tail and no recurrence); a stack is then its runs
+    is its convolution's tail and no recurrence; `KDA` likewise, with its
+    recurrent state); a stack is then its runs
     in order, the runs of a kind sharing one stack of leaves and one part
     of the cache;
   * `jax.checkpoint` (remat) on the block when configured: trades FLOPs for
@@ -631,7 +635,12 @@ class LatentSizes:
     (`w_head_gate`), a `window` (positions attended, the token's own
     among them; 0: the whole context) and a learned indexer that chooses
     the `index_topk` positions attended (`w_iq`, `w_ik`, `ik_scale`,
-    `ik_bias`, `w_iw`; 0: none).  A model of one kind of layer states them
+    `ik_bias`, `w_iw`; 0: none).  `q_lora_rank` 0: the query has no latent
+    of its own, `q = h W_q` (`wq` [D, H, qk_nope + qk_rope]; an indexer
+    needs the latent).  `rope_theta` None: nothing is rotated, the last
+    qk_rope numbers of a query and the one shared key are used as they are
+    projected (the stored row is the same [c_kv | k_pe]).  A model of one
+    kind of layer states them
     in its config and spec (`latent_sizes`); one with several gives each
     run its own (`Run.sizes`)."""
     n_heads: int
@@ -640,7 +649,7 @@ class LatentSizes:
     qk_nope_head_dim: int
     qk_rope_head_dim: int
     v_head_dim: int
-    rope_theta: float
+    rope_theta: Optional[float]
     attn_scale: float
     norm_eps: float
     rope_freqs: Optional[tuple] = None
@@ -670,23 +679,30 @@ def _latent_qkv(h, p, s: LatentSizes, offset):
     """MLA's projections of normed h [B, L, D]: per head q_nope
     [B, L, H, qk_nope] and the rotated q_rope [B, L, H, qk_rope]; per token
     the normed latent c_kv [B, L, kv_lora_rank] and the one rotated key
-    k_rope [B, L, qk_rope] all heads share; last the query's own normed
-    latent c_q [B, L, q_lora_rank] (an indexer's queries are made of it)."""
+    k_rope [B, L, qk_rope] all heads share (neither rotated where the
+    sizes state no rotation); last the query's own normed latent c_q
+    [B, L, q_lora_rank] (an indexer's queries are made of it; None where
+    the query has none: `q_lora_rank` 0)."""
     eps = s.norm_eps
-    c_q = rmsnorm(jnp.einsum("bld,dr->blr", h, p["w_qa"].astype(h.dtype)),
-                  p["q_norm"], eps)
-    if s.q_rescale != 1.0:
-        c_q = c_q * jnp.asarray(s.q_rescale, c_q.dtype)
-    q = jnp.einsum("blr,rhk->blhk", c_q, p["w_qb"].astype(h.dtype))
+    if s.q_lora_rank:
+        c_q = rmsnorm(jnp.einsum("bld,dr->blr", h,
+                                 p["w_qa"].astype(h.dtype)),
+                      p["q_norm"], eps)
+        if s.q_rescale != 1.0:
+            c_q = c_q * jnp.asarray(s.q_rescale, c_q.dtype)
+        q = jnp.einsum("blr,rhk->blhk", c_q, p["w_qb"].astype(h.dtype))
+    else:
+        c_q, q = None, _to_heads(h, p["wq"])
     kv = jnp.einsum("bld,dr->blr", h, p["w_kva"].astype(h.dtype))
     c_kv = rmsnorm(kv[..., :s.kv_lora_rank], p["kv_norm"], eps)
     if s.kv_rescale != 1.0:
         c_kv = c_kv * jnp.asarray(s.kv_rescale, c_kv.dtype)
-    freqs = s.rope_freqs
-    q_rope = rope(q[..., s.qk_nope_head_dim:], s.rope_theta, offset,
-                  freqs)
-    k_rope = rope(kv[..., None, s.kv_lora_rank:], s.rope_theta, offset,
-                  freqs)[:, :, 0]
+    q_rope, k_rope = q[..., s.qk_nope_head_dim:], kv[..., s.kv_lora_rank:]
+    if s.rope_theta is not None:
+        freqs = s.rope_freqs
+        q_rope = rope(q_rope, s.rope_theta, offset, freqs)
+        k_rope = rope(k_rope[..., None, :], s.rope_theta, offset,
+                      freqs)[:, :, 0]
     return q[..., :s.qk_nope_head_dim], q_rope, c_kv, k_rope, c_q
 
 
@@ -919,7 +935,7 @@ LATENT = Attention(latent_attention, _latent_project, _latent_attend,
                        extra=((c.index_head_dim,)
                               if getattr(c, "index_topk", 0) else ())),
                    pools=1, trains=False,
-                   cast=("w_qa", "w_qb", "w_kva", "w_kvb", "wo",
+                   cast=("w_qa", "w_qb", "wq", "w_kva", "w_kvb", "wo",
                          "w_head_gate", "w_iq", "w_ik", "w_iw"),
                    absorbed="w_kvb")
 EVA = Attention(eva_attention, _heads_project, _eva_attend, _heads_finish,
@@ -1054,6 +1070,30 @@ def _slot_rows(buffer, layer, slots, b: int, new=None):
             (layer, slots[i], zero)), buffer, unroll=True)
 
 
+def _conv_one_token(tails, layer, x, conv_w, fresh, valid):
+    """One token a row whose slot is its index (the decoding lanes of every
+    step) through a causal depthwise convolution behind the slots' tails
+    `tails` [L, S, (K - 1) W] at `layer`: `ops.ssm.conv_tail`'s steps on
+    the slots' rows AS THEY ARE STORED, [B, (K - 1) W], with no row
+    gathered (the form over [B, K - 1, W] gathers the new tail by each
+    row's count of valid tokens, and compiled for the chip turned the whole
+    buffer's layout around it: tests/test_tpu_aot.py).  x [B, W]; a `fresh`
+    row reads zeros, a row that is not `valid` leaves its slot as it was.
+    Returns (the taps' sum [B, W] float32, tails)."""
+    b, width = x.shape
+    taps = conv_w.shape[0]
+    old = _slot_rows(tails, layer, None, b)
+    start = jnp.where(fresh[:, None], 0, old)
+    w = conv_w.astype(jnp.float32)
+    behind = [start[:, i * width:(i + 1) * width] for i in range(taps - 1)]
+    # (`ops.ssm.conv_tail`'s order of the sum: from the oldest)
+    conv = sum(r.astype(jnp.float32) * w[i]
+               for i, r in enumerate(behind + [x]))
+    new = jnp.where(valid[:, :1], jnp.concatenate(
+        [start[:, width:], x.astype(old.dtype)], axis=1), old)
+    return conv, _slot_rows(tails, layer, None, b, new)
+
+
 def _ssm_project(h, p, spec, config, offset):
     return _ssm_split(h, p, config)
 
@@ -1184,10 +1224,7 @@ def _conv_attend(rows, pools, p, spec, config, lanes):
     before left there, or a snapshot the engine copied in); a row with no
     valid token leaves its slot as it was.  One token a row whose slot is
     its index takes the same steps on the slots' rows as they are stored
-    (the form over [B, K - 1, D] gathers the new tail by each row's count
-    of valid tokens, and compiled for the chip turned the whole buffer's
-    layout around it: tests/test_tpu_aot.py).  Returns ((C * conv,),
-    pools)."""
+    (`_conv_one_token`).  Returns ((C * conv,), pools)."""
     tails, = pools
     layer = p["cache_layer"]
     bcu, = rows
@@ -1197,24 +1234,11 @@ def _conv_attend(rows, pools, p, spec, config, lanes):
     with jax.named_scope("conv_tail"):
         b = bcu.shape[0]
         if bcu.shape[1] == 1 and slots is None:
-            # One token a row whose slot is its index (the decoding lanes
-            # of every step): the same steps on the slots' rows as they
-            # are stored, [B, (K - 1) D], with no row gathered.
-            d = bcu.shape[2] // 3
             gate_b, gate_c, u = jnp.split(bcu[:, 0], 3, axis=-1)
-            old = _slot_rows(tails, layer, None, b)
-            start = jnp.where(fresh[:, None], 0, old)
-            v = gate_b * u
-            w = p["conv_w"].astype(jnp.float32)
-            behind = [start[:, i * d:(i + 1) * d]
-                      for i in range(config.conv_taps - 1)]
-            # (`ops.ssm.conv_tail`'s order of the sum: from the oldest)
-            conv = sum(r.astype(jnp.float32) * w[i]
-                       for i, r in enumerate(behind + [v]))
-            new = jnp.where(valid[:, :1], jnp.concatenate(
-                [start[:, d:], v.astype(old.dtype)], axis=1), old)
+            conv, tails = _conv_one_token(tails, layer, gate_b * u,
+                                          p["conv_w"], fresh, valid)
             y = gate_c * conv.astype(u.dtype)
-            return (y[:, None],), (_slot_rows(tails, layer, None, b, new),)
+            return (y[:, None],), (tails,)
         tail = jnp.where(fresh[:, None, None], 0, _slot_rows(
             tails, layer, slots, b).reshape(b, config.conv_taps - 1, -1))
         y, tail = _gated_conv(bcu, tail, p,
@@ -1230,6 +1254,157 @@ def _conv_finish(out, rows, h, p, spec, config):
 CONV = Mixer(conv_mixer, _conv_project, _conv_attend, _conv_finish,
              state=lambda c: StateRows(c.conv_taps, c.d_model),
              cast=("w_in", "w_out"), scope="conv_mix")
+
+
+# --------------------------------------------------------------------------
+# Kimi Delta Attention (Kimi Linear's `kda` layers), H heads of N = P =
+# `kda_head_dim`.  With h the normed input:
+#   [q | k | v] = silu(conv(h W_qkv))   a causal depthwise convolution of
+#       `kda_conv` taps over each of the 3 H N columns, no bias;
+#   q <- q / |q|_2 N^-0.5,  k <- k / |k|_2   a head over its own N numbers;
+#   g = -exp(A_log[head]) softplus((h W_fa) W_fb + dt_bias)   [H N], the log
+#       of the decay, a number a KEY CHANNEL, float32;
+#   beta = sigmoid(h W_b)   a number a head;
+#   the recurrence of ops/ssm.py (`kda_update`, `kda_scan`), o [H, P];
+#   out = (rmsnorm_head(o) o_norm sigmoid((h W_ga) W_gb)) W_o.
+# What a lane keeps between steps: the float32 state [H, N, P] and the last
+# `kda_conv` - 1 rows of h W_qkv, the two buffers of a state part.
+# --------------------------------------------------------------------------
+
+# Under the L2 norms of q and k (the family's l2norm: x rsqrt(sum x^2 + eps)).
+_KDA_L2_EPS = 1e-6
+
+
+def _kda_in(h, p):
+    """The row-wise products of normed h [B, L, D]: ([q | k | v] before the
+    convolution [B, L, 3 H N], the decay's and the gate's low-rank
+    projections [B, L, H N] each, beta's [B, L, H])."""
+    def low_rank(a, b):
+        return jnp.einsum("blr,re->ble", jnp.einsum(
+            "bld,dr->blr", h, p[a].astype(h.dtype)), p[b].astype(h.dtype))
+
+    return (jnp.einsum("bld,de->ble", h, p["w_qkv"].astype(h.dtype)),
+            low_rank("w_fa", "w_fb"), low_rank("w_ga", "w_gb"),
+            jnp.einsum("bld,dh->blh", h, p["w_beta"].astype(h.dtype)))
+
+
+def _kda_heads(conv, decay, beta, p, config, valid=None):
+    """Behind the convolution's sum `conv` [B, T, 3 H N] float32: SiLU, the
+    heads taken apart and the two L2 norms (q, k [B, T, H, N], v
+    [B, T, H, P] in float32), the decay's log g [B, T, H, N] and beta
+    [B, T, H], both the identity's (0) at a row that is not `valid`."""
+    c = config
+    b, t = conv.shape[:2]
+    heads, n = c.kda_heads, c.kda_head_dim
+    q, k, v = (x.reshape(b, t, heads, n)
+               for x in jnp.split(jax.nn.silu(conv), 3, axis=-1))
+
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True)
+                                 + _KDA_L2_EPS)
+
+    g = -jnp.exp(p["A_log"].astype(jnp.float32))[:, None] * jax.nn.softplus(
+        decay.astype(jnp.float32).reshape(b, t, heads, n)
+        + p["dt_bias"].reshape(heads, n))
+    beta = jax.nn.sigmoid(beta.astype(jnp.float32))
+    if valid is not None:
+        g, beta = g * valid[..., None, None], beta * valid[..., None]
+    return unit(q) * n ** -0.5, unit(k), v, g, beta
+
+
+def _kda_out(o, gate, p, config, dtype):
+    """Behind the recurrence: an RMSNorm over each head's own numbers with
+    one learned scale [P], the sigmoid gate, the projection back."""
+    b, t = o.shape[:2]
+    y = rmsnorm(o, p["o_norm"].astype(jnp.float32), config.norm_eps)
+    y = y * jax.nn.sigmoid(gate.astype(jnp.float32).reshape(o.shape))
+    return jnp.einsum("ble,ed->bld", y.reshape(b, t, -1).astype(dtype),
+                      p["w_out"].astype(dtype))
+
+
+def kda_mixer(h, p, config):
+    """Kimi Delta Attention over a whole sequence from the zero state
+    (ops/ssm.py has the recurrence)."""
+    from ray_tpu.ops import ssm
+
+    c = config
+    with jax.named_scope("kda_mix"):
+        qkv, decay, gate, beta = _kda_in(h, p)
+        b, t, width = qkv.shape
+        conv, _ = ssm.conv_tail(
+            qkv, jnp.zeros((b, c.kda_conv - 1, width), qkv.dtype),
+            p["conv_w"], jnp.full((b,), t, jnp.int32))
+        o, _ = ssm.kda_sequence(*_kda_heads(conv, decay, beta, p, c),
+                                chunk=c.kda_chunk)
+        return _kda_out(o, gate, p, c, h.dtype)
+
+
+def _kda_project(h, p, spec, config, offset):
+    return _kda_in(h, p)
+
+
+def _kda_attend(rows, pools, p, spec, config, lanes):
+    """The lanes' own part over a slice, continued from each row's slot
+    (`lanes.slots` [B]; None: row i's is slot i) of the two buffers `pools`
+    = (state [L, S, H, N, P] float32, tail [L, S, (K - 1) 3 H N]: a slot's
+    K - 1 rows of [q | k | v] one behind the other) at `p["cache_layer"]`,
+    as `_ssm_attend`: a row whose slice starts at position 0 starts from
+    nothing, every other from what its slot holds, and the slot is left
+    holding the state and the tail behind the row's last valid token; a row
+    with no valid token leaves its slot as it was.  One token a row is
+    `ops.ssm.kda_update`, more `kda_scan`.  Returns ((o,), pools)."""
+    from ray_tpu.ops import ssm
+
+    c = config
+    state, tails = pools
+    layer = p["cache_layer"]
+    qkv, decay, _, beta = rows
+    _, positions, valid, _, slots = lanes
+    # (a row nobody has also stands at position 0: it starts nothing)
+    fresh = (positions[:, 0] == 0) & valid[:, 0]
+    with jax.named_scope("kda_conv"):
+        b = qkv.shape[0]
+        if qkv.shape[1] == 1 and slots is None:
+            conv, tails = _conv_one_token(tails, layer, qkv[:, 0],
+                                          p["conv_w"], fresh, valid)
+            conv = conv[:, None]
+        else:
+            tail = jnp.where(fresh[:, None, None], 0, _slot_rows(
+                tails, layer, slots, b).reshape(b, c.kda_conv - 1, -1))
+            conv, tail = ssm.conv_tail(
+                qkv, tail, p["conv_w"],
+                jnp.sum(valid, axis=1, dtype=jnp.int32))
+            tails = _slot_rows(tails, layer, slots, b, tail.reshape(b, -1))
+        q, k, v, g, beta = _kda_heads(conv, decay, beta, p, c, valid)
+    if slots is None:
+        slots = jnp.arange(b, dtype=jnp.int32)
+    if qkv.shape[1] == 1:
+        with jax.named_scope("kda_update"):
+            # (a row that starts at position 0 decays what its slot held
+            # to nothing: exp(-inf) = 0)
+            o, state = ssm.kda_update(
+                state, q[:, 0], k[:, 0], v[:, 0],
+                jnp.where(fresh[:, None, None], -jnp.inf, g[:, 0]),
+                beta[:, 0], slots, layer)
+            o = o[:, None]
+    else:
+        with jax.named_scope("kda_scan"):
+            o, state = ssm.kda_scan(state, q, k, v, g, beta, slots, fresh,
+                                    layer, chunk=c.kda_chunk)
+    return (o,), (state, tails)
+
+
+def _kda_finish(out, rows, h, p, spec, config):
+    return _kda_out(out[0], rows[2], p, config, h.dtype)
+
+
+KDA = Mixer(kda_mixer, _kda_project, _kda_attend, _kda_finish,
+            state=lambda c: StateRows(
+                c.kda_conv, 3 * c.kda_heads * c.kda_head_dim, c.kda_heads,
+                c.kda_head_dim, c.kda_head_dim),
+            cast=("w_qkv", "w_fa", "w_fb", "w_ga", "w_gb", "w_beta",
+                  "w_out"),
+            scope="kda_mix")
 
 
 @dataclasses.dataclass(frozen=True)

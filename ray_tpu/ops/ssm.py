@@ -434,6 +434,372 @@ def _ssm_scan(state, x, dt, a, bm, cm, slots, fresh, layer, *, chunk: int,
     return jnp.moveaxis(_unfold(y, fold)[:, :, :t], 1, 2), state
 
 
+# --------------------------------------------------------------------------
+# Kimi Delta Attention: a state decayed a KEY CHANNEL and corrected by a
+# delta rule.  A head at position t (q_t, k_t [N] by key channel, v_t [P],
+# g_t [N] <= 0 the log of the decay alpha_t = exp(g_t), beta_t a scalar):
+#
+#     S~  = diag(alpha_t) S_{t-1}                       S [N, P]
+#     S_t = S~ + beta_t k_t (v_t - S~^T k_t)^T
+#     o_t = S_t^T q_t
+#
+# (the convolution, the L2 norms, the gate and the norm behind it are the
+# caller's).  g_t = 0 and beta_t = 0 is the identity: how a padded row and
+# a lane that is not stepped pass through.  The state lives in the same
+# kind of buffer as Mamba-2's, [layers, slots, H, N, P] float32 with N rows
+# (key channels, down the sublanes) of P = 128 columns (the lane width: no
+# fold), read and written in place by both kernels, a head's block named
+# by the layer and the row's slot through scalar prefetch:
+#
+#   * `kda_update` (T = 1): ONE read and ONE write of the state.  With
+#     S~ = alpha * S in vector memory, p = S~^T k and r = S~^T q are sums
+#     down the sublanes of one pass, u = beta (v - p), S_t = S~ + k u^T the
+#     pass that writes the block, and o = r + (k . q) u;
+#   * `kda_scan` (T > 1): chunks of `chunk` positions; inside a chunk the
+#     recurrence unrolled into five products on the matrix unit, between
+#     chunks the carried state in the kernel's output block, so the state
+#     is read once and written once a (row, head) whatever the slice's
+#     length.  The corrections u of a chunk solve a unit-lower-triangular
+#     system whose entries are sums over the key channels of
+#     exp(G_i - G_j) k_i k_j: the decay is a channel's own, so the system
+#     is no product of two matrices as it stands (`_kda_chunk_terms` has
+#     how it is made one, sub-chunk by sub-chunk).  What a chunk needs
+#     beside the products (the system's inverse, the decayed q and k, the
+#     chunk's total decay) is made by XLA in front of the kernel, in
+#     float32, as Mamba-2's `_chunk_terms` are.  (A kernel that walks a
+#     chunk's positions one at a time with the state in registers, the
+#     update's pass a position, took 7.3 ms for `[4, 256]` rows where this
+#     takes 2.0: PERF.md section 6, PR 57.)
+#
+# In the update alpha, k and q meet the state as COLUMNS (a number a
+# sublane, the same along the lanes); they arrive as rows, and the kernel
+# makes a row's column form by a transpose of the row laid over 128
+# sublanes.
+# --------------------------------------------------------------------------
+
+# Heads of one grid step of `kda_update`: 16 x [128, 128] float32 is 1 MB a
+# block, 4 MB with both directions double-buffered.
+_KDA_UPDATE_HEADS = 16
+
+
+def _column(row, n: int, p: int):
+    """row [1, N] as [N, P]: entry i along the whole of sublane i."""
+    return jnp.broadcast_to(row, (p, n)).T
+
+
+def _kda_update_kernel(layer_ref, slot_ref, s_ref, a_ref, k_ref, q_ref,
+                       v_ref, beta_ref, kq_ref, so_ref, o_ref, *,
+                       heads: int):
+    """One (row, block of `heads` heads) grid step: every head's [N, P]
+    state decayed a row, read against k and q in one pass, corrected and
+    written back; v, beta and kq = k . q are rows [1, P]."""
+    del layer_ref, slot_ref             # only the index maps read them
+    n, p = s_ref.shape[-2:]
+    for h in range(heads):
+        at = slice(h, h + 1)
+        k_col = _column(k_ref[at, :], n, p)
+        s = s_ref[h] * _column(a_ref[at, :], n, p)
+        seen = jnp.sum(s * k_col, axis=0, keepdims=True)
+        read = jnp.sum(s * _column(q_ref[at, :], n, p), axis=0,
+                       keepdims=True)
+        u = beta_ref[at, :] * (v_ref[at, :] - seen)
+        so_ref[h] = s + k_col * u
+        o_ref[at, :] = read + kq_ref[at, :] * u
+
+
+def kda_update(state, q, k, v, g, beta, slots, layer=0, *,
+               use_kernel: Optional[bool] = None,
+               interpret: Optional[bool] = None):
+    """One token a row: the states of rows `slots` at `layer` overwritten
+    in place, and each read against its q.
+
+    state [L, S, H, N, P] float32; q, k [B, H, N]; v [B, H, P]; g [B, H, N]
+    float32 (the decay's log, <= 0; 0 with beta 0: the row is not stepped);
+    beta [B, H] float32; slots [B] int32.  Returns (o [B, H, P] float32,
+    state)."""
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+    if interpret is None:
+        interpret = _interpret_kernels()
+    return _kda_update(state, q, k, v, g, beta, slots, layer,
+                       use_kernel=use_kernel, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret"))
+def _kda_update(state, q, k, v, g, beta, slots, layer, *, use_kernel: bool,
+                interpret: bool):
+    b, h, n = k.shape
+    p = v.shape[-1]
+    q, k, v = (x.astype(jnp.float32) for x in (q, k, v))
+    alpha = jnp.exp(g.astype(jnp.float32))
+    beta = beta.astype(jnp.float32)[..., None]
+    layer = jnp.asarray(layer, jnp.int32)
+    slots = slots.astype(jnp.int32)
+    if not use_kernel:
+        s = state[layer, slots] * alpha[..., None]            # [B, H, N, P]
+        pred = jnp.einsum("bhnp,bhn->bhp", s, k, precision=_HIGHEST)
+        u = beta * (v - pred)
+        s = s + k[..., None] * u[:, :, None, :]
+        o = jnp.einsum("bhnp,bhn->bhp", s, q, precision=_HIGHEST)
+        return o, state.at[layer, slots].set(s, mode="drop")
+    hb = min(_KDA_UPDATE_HEADS, h)
+    if h % hb or hb % 8:
+        raise ValueError(f"{h} heads in blocks of {hb}")
+    beta, kq = (jnp.broadcast_to(x, (b, h, p)) for x in (
+        beta, jnp.sum(k * q, -1, keepdims=True)))
+
+    def state_map(i, j, ly, sl):
+        return (ly[0], sl[i], j, 0, 0)
+
+    def head_map(i, j, ly, sl):
+        return (i, j, 0)
+
+    by_key, by_value = (pl.BlockSpec((None, hb, w), head_map)
+                        for w in (n, p))
+    state, o = pl.pallas_call(
+        functools.partial(_kda_update_kernel, heads=hb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,              # layer, the rows' slots
+            grid=(b, h // hb),
+            in_specs=[pl.BlockSpec((None, None, hb, n, p), state_map),
+                      by_key, by_key, by_key, by_value, by_value, by_value],
+            out_specs=[pl.BlockSpec((None, None, hb, n, p), state_map),
+                       by_value]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((b, h, p), jnp.float32)],
+        input_output_aliases={2: 0},        # the state, after the scalars
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary")),
+        name="kda_update",
+    )(layer.reshape(1), slots, state, alpha, k, q, v, beta, kq)
+    return o, state
+
+
+# Positions of a sub-chunk of `_kda_chunk_terms`: the pairs inside one are
+# taken an exponential a (pair, channel), the pairs across two by a product.
+_KDA_SUB = 16
+
+
+def _kda_chunk_terms(g, k, q, beta, chunk: int):
+    """What a chunked scan multiplies, head-major and float32, of g, k, q
+    [B, H, T, N] and beta [B, H, T, 1] (T whole chunks).  Inside a chunk
+    from S_0, with G_i the running sum of the decays' logs (Gamma_i =
+    exp(G_i)):
+
+        u_i = beta_i (v_i - S_0^T (Gamma_i k_i) - sum_{j<i} A_ij u_j)
+        o_i = S_0^T (Gamma_i q_i) + sum_{j<=i} Q_ij u_j
+        S_C = diag(Gamma_C) S_0 + sum_j diag(Gamma_C / Gamma_j) k_j u_j^T
+
+    A_ij = sum_n k_in k_jn exp(G_in - G_jn) and Q_ij likewise with q_i.  The
+    decay is a channel's own, so A is no product of two matrices as it
+    stands; the ratios are exponentials of differences, never a division by
+    a small Gamma.  A chunk is cut into sub-chunks of `_KDA_SUB`: a pair
+    (i, j) of ONE sub-chunk takes its exponential a channel; a pair across
+    two goes through the start R of i's sub-chunk, exp(G_i - R) exp(R -
+    G_j) with both exponents <= 0, which IS a product of two matrices.
+    Returns (kin = Gamma k, qin = Gamma q, kout = (Gamma_C / Gamma) k
+    [B, H, T, N]; solve [B, H, T / chunk, chunk, chunk] with u = solve (v -
+    kin S_0): (I + diag(beta) A)^-1 diag(beta); qmat, Q's lower triangle,
+    the same shape; dec = Gamma_C [B, H, T / chunk, 1, N])."""
+    b, h, t, n = k.shape
+    nc = t // chunk
+    sub = _KDA_SUB if chunk % _KDA_SUB == 0 else chunk
+    ns = chunk // sub
+
+    def cut(x):                             # [B, H, T, N] -> [.., ns, sub, N]
+        return x.reshape(b, h, nc, ns, sub, n)
+
+    cum = jnp.cumsum(g.reshape(b, h, nc, chunk, n), axis=-2)
+    kc, qc, gc = cut(k), cut(q), cut(cum.reshape(b, h, t, n))
+    # pairs of one sub-chunk
+    lower = jnp.tril(jnp.ones((sub, sub), bool))
+    ratio = jnp.exp(jnp.where(
+        lower[..., None], gc[..., :, None, :] - gc[..., None, :, :],
+        -jnp.inf))                              # [.., ns, sub, sub, N]
+    near = [jnp.einsum("...in,...jn,...ijn->...ij", x, kc, ratio,
+                       precision=_HIGHEST) for x in (kc, qc)]
+    if ns == 1:
+        amat, qmat = (x[..., 0, :, :] for x in near)
+    else:
+        # pairs across sub-chunks, through the start of i's: R = G behind
+        # the sub-chunk before it
+        start = jnp.concatenate([jnp.zeros_like(gc[..., :1, 0, :]),
+                                 gc[..., :-1, -1, :]], axis=-2)
+        toward = jnp.exp(gc - start[..., None, :])      # i from its R: <= 1
+        # j to each later sub-chunk's R (0 where j is not before it)
+        before = (jnp.arange(chunk)[None, :]
+                  < sub * jnp.arange(ns)[:, None])[..., None]
+        back = jnp.exp(jnp.where(
+            before, start[..., :, None, :] - cum[..., None, :, :], -jnp.inf))
+        kj = k.reshape(b, h, nc, 1, chunk, n) * back    # [.., ns, chunk, N]
+        far = [jnp.einsum("...sin,...sjn->...sij", x * toward, kj,
+                          precision=_HIGHEST).reshape(b, h, nc, chunk, chunk)
+               for x in (kc, qc)]
+        eye = jnp.eye(ns)[:, None, :, None]
+        amat, qmat = (f + (x[..., :, :, None, :] * eye).reshape(
+            b, h, nc, chunk, chunk) for f, x in zip(far, near))
+    betac = beta.reshape(b, h, nc, chunk, 1)
+    strict = jnp.tril(jnp.ones((chunk, chunk), jnp.float32), -1)
+    solve = jax.scipy.linalg.solve_triangular(
+        jnp.eye(chunk) + betac * amat * strict,
+        jnp.broadcast_to(jnp.eye(chunk), amat.shape), lower=True,
+        unit_diagonal=True) * jnp.swapaxes(betac, -1, -2)
+    into = jnp.exp(cum)
+    total = cum[..., -1:, :]
+    out_of = jnp.exp(total - cum)
+    flat = lambda x: x.reshape(b, h, t, n)
+    return (flat(into) * k, flat(into) * q, flat(out_of) * k, solve, qmat,
+            jnp.exp(total))
+
+
+def _kda_chunks(terms, v, s0):
+    """The chunked scan as plain XLA: s0 [B, H, N, P], v [B, H, T, P] ->
+    (o [B, H, T, P], the state behind the last chunk)."""
+    kin, qin, kout, solve, qmat, dec = terms
+    b, h, t, _ = kin.shape
+    nc, chunk = solve.shape[2:4]
+
+    def by_chunk(x):                    # [B, H, T, W] -> [nc, B, H, chunk, W]
+        return jnp.moveaxis(x.reshape(b, h, nc, chunk, -1), 2, 0)
+
+    def one(s, xs):
+        kin, qin, kout, v, solve, qmat, dec = xs
+        u = jnp.einsum("bhij,bhjp->bhip", solve, v - jnp.einsum(
+            "bhin,bhnp->bhip", kin, s, precision=_HIGHEST),
+            precision=_HIGHEST)
+        o = jnp.einsum("bhin,bhnp->bhip", qin, s, precision=_HIGHEST) \
+            + jnp.einsum("bhij,bhjp->bhip", qmat, u, precision=_HIGHEST)
+        s = jnp.swapaxes(dec, -1, -2) * s + jnp.einsum(
+            "bhjn,bhjp->bhnp", kout, u, precision=_HIGHEST)
+        return s, o
+
+    s, o = jax.lax.scan(one, s0, (
+        *map(by_chunk, (kin, qin, kout, v)),
+        *(jnp.moveaxis(x, 2, 0) for x in (solve, qmat, dec))))
+    return jnp.moveaxis(o, 0, 2).reshape(b, h, t, -1), s
+
+
+def _kda_head_major(q, k, v, g, beta, chunk: int):
+    """[B, T, H, .] -> float32 [B, H, T', .], the slice padded at its end
+    to whole chunks with the identity (g = 0, beta = 0): (g, k, q, v, beta
+    [B, H, T', 1])."""
+    pad = -q.shape[1] % chunk
+
+    def lay(x):
+        x = x.astype(jnp.float32)
+        if pad:
+            x = jnp.pad(x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+        return jnp.moveaxis(x, 1, 2)
+
+    return lay(g), lay(k), lay(q), lay(v), lay(beta[..., None])
+
+
+def kda_sequence(q, k, v, g, beta, *, chunk: int):
+    """A whole sequence from the zero state: q, k, g [B, T, H, N], v
+    [B, T, H, P], beta [B, T, H] -> (o [B, T, H, P] float32, the final
+    state [B, H, N, P])."""
+    b, t, h, n = k.shape
+    g, k, q, v, beta = _kda_head_major(q, k, v, g, beta, chunk)
+    o, s = _kda_chunks(_kda_chunk_terms(g, k, q, beta, chunk), v,
+                       jnp.zeros((b, h, n, v.shape[-1]), jnp.float32))
+    return jnp.moveaxis(o[:, :, :t], 1, 2), s
+
+
+def _kda_scan_kernel(layer_ref, slot_ref, fresh_ref, s_ref, kin_ref,
+                     qin_ref, kout_ref, v_ref, solve_ref, qmat_ref, dec_ref,
+                     so_ref, o_ref):
+    """One (row, head, chunk) grid step; the chunks of a (row, head) run in
+    order with the state carried in the output block, which is the head's
+    [N, P] state in the buffer: written back once, after the last.  Five
+    products on the matrix unit a chunk (`_kda_chunk_terms`)."""
+    del layer_ref, slot_ref
+    i, c = pl.program_id(0), pl.program_id(2)
+
+    @pl.when(c == 0)
+    def _():
+        so_ref[...] = jnp.where(fresh_ref[i] != 0, 0.0, s_ref[...])
+
+    dot = functools.partial(jnp.dot, precision=_HIGHEST,
+                            preferred_element_type=jnp.float32)
+    s = so_ref[...]
+    u = dot(solve_ref[...], v_ref[...] - dot(kin_ref[...], s))
+    o_ref[...] = dot(qin_ref[...], s) + dot(qmat_ref[...], u)
+    so_ref[...] = _column(dec_ref[...], *s.shape) * s + jax.lax.dot_general(
+        kout_ref[...], u, (((0,), (0,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32)
+
+
+def kda_scan(state, q, k, v, g, beta, slots, fresh, layer=0, *, chunk: int,
+             use_kernel: Optional[bool] = None,
+             interpret: Optional[bool] = None):
+    """A slice of T tokens a row, from each row's state at `layer` (zero
+    where `fresh`), which is overwritten in place with the state behind the
+    slice's last stepped token.
+
+    state [L, S, H, N, P] float32; q, k [B, T, H, N]; v [B, T, H, P]; g
+    [B, T, H, N] float32 (<= 0; 0 with beta 0 at a padded row: the
+    identity); beta [B, T, H] float32; slots [B] int32; fresh [B] bool.
+    Returns (o [B, T, H, P] float32, state)."""
+    if use_kernel is None:
+        use_kernel = not _interpret_kernels()
+    if interpret is None:
+        interpret = _interpret_kernels()
+    return _kda_scan(state, q, k, v, g, beta, slots, fresh, layer,
+                     chunk=chunk, use_kernel=use_kernel, interpret=interpret)
+
+
+@functools.partial(jax.jit, static_argnames=("chunk", "use_kernel",
+                                             "interpret"))
+def _kda_scan(state, q, k, v, g, beta, slots, fresh, layer, *, chunk: int,
+              use_kernel: bool, interpret: bool):
+    b, t, h, n = k.shape
+    p = v.shape[-1]
+    g, k, q, v, beta = _kda_head_major(q, k, v, g, beta, chunk)
+    layer = jnp.asarray(layer, jnp.int32)
+    slots = slots.astype(jnp.int32)
+    if not use_kernel:
+        s0 = jnp.where(fresh[:, None, None, None], 0.0, state[layer, slots])
+        o, s = _kda_chunks(_kda_chunk_terms(g, k, q, beta, chunk), v, s0)
+        return (jnp.moveaxis(o[:, :, :t], 1, 2),
+                state.at[layer, slots].set(s, mode="drop"))
+    total = k.shape[2]
+
+    def state_map(i, j, c, ly, sl, fr):
+        return (ly[0], sl[i], j, 0, 0)
+
+    def rows_map(i, j, c, ly, sl, fr):
+        return (i, j, c, 0)
+
+    def chunk_map(i, j, c, ly, sl, fr):
+        return (i, j, c, 0, 0)
+
+    by_key, by_value = (pl.BlockSpec((None, None, chunk, w), rows_map)
+                        for w in (n, p))
+    kin, qin, kout, solve, qmat, dec = _kda_chunk_terms(g, k, q, beta, chunk)
+    square = pl.BlockSpec((None, None, None, chunk, chunk), chunk_map)
+    state, o = pl.pallas_call(
+        _kda_scan_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,  # layer, the rows' slots, which are fresh
+            grid=(b, h, total // chunk),
+            in_specs=[pl.BlockSpec((None, None, None, n, p), state_map),
+                      by_key, by_key, by_key, by_value, square, square,
+                      pl.BlockSpec((None, None, None, 1, n), chunk_map)],
+            out_specs=[pl.BlockSpec((None, None, None, n, p), state_map),
+                       by_value]),
+        out_shape=[jax.ShapeDtypeStruct(state.shape, state.dtype),
+                   jax.ShapeDtypeStruct((b, h, total, p), jnp.float32)],
+        input_output_aliases={3: 0},        # the state, after the scalars
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary", "arbitrary")),
+        name="kda_scan",
+    )(layer.reshape(1), slots, fresh.astype(jnp.int32), state, kin, qin,
+      kout, v, solve, qmat, dec)
+    return jnp.moveaxis(o[:, :, :t], 1, 2), state
+
+
 def copy_slot(dst_buffers, src_buffers, src, dst):
     """One slot of a state cache copied into other buffers, every layer:
     into[:, dst] = frm[:, src] for each pair of the two tuples (a mixer's

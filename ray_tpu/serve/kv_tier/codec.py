@@ -60,7 +60,8 @@ class KVBlockCodec:
                 # "kv": K and V rows; "state": as "kv", and under `more`
                 # the snapshot of the mixers' state behind the chain, a
                 # buffer under each name the cache's state part states
-                # ("state" and "tail", or "tail" alone);
+                # ("state" and "tail", or "tail" alone), or as "latent"
+                # with the same beside it (lane state behind a latent pool);
                 # "latent": one latent row in `k`, `v_pool` None; "layered":
                 # as "latent" or as "kv" (a cache's kinds are all latent
                 # rows or all K and V rows), and under `more` the blocks of
@@ -94,8 +95,10 @@ class KVBlockCodec:
         k, v = payload["k"], payload["v_pool"]
         n = len(payload["chain"])
         bs = payload["block_size"]
-        # whether a frame of this kind comes without a V: "layered" either
-        no_v = {"latent": (True,), "layered": (True, False)}.get(
+        # whether a frame of this kind comes without a V: "layered" and
+        # "state" either
+        no_v = {"latent": (True,), "layered": (True, False),
+                "state": (True, False)}.get(
             payload.setdefault("kind", "kv"), (False,))
         if (v is None) not in no_v \
                 or (v is not None and k.shape != v.shape) \

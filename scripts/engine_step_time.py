@@ -100,6 +100,23 @@ over 128 tails in 7) and the pair's at 128 + 4 x 256 rows
 behind a cached head rides ([1, 256] beside the 128):
 
   python3 scripts/engine_step_time.py mixed=lfm2:4
+
+Since PR 57 also Kimi Delta Attention's two kernels alone
+(`ops/ssm.py`), `update=kimilinear` / `scan=kimilinear`, at
+`serve_kimilinear_reasoning_decode`'s state buffer ([6, 129, 32, 128, 128]
+float32) from the files the benchmark runs the cell from: `kda_update` over
+the cell's 128 lanes, 24 calls chained in one program with the layer going
+round as the layer loop does, ms a call and the share of the least the chip
+could take by the benchmark's own arithmetic (`kda_flops.update`), beside
+`ssm_update` (Mamba-2's recurrence as `nemotron-3-nano-30b-a3b` runs it: 64
+heads of 64 folded by two, 8 groups, a state of 128) on a buffer of the same
+shape and the same lanes; `kda_scan` over
+`[4, 256]` and `[1, 256]` rows (an admission's chunk), as the kernel and as
+the same chunked form in plain XLA (`ops.ssm._kda_chunks`), at the
+configuration's `kda_chunk` and at chunks of 16 and 64; every form must
+leave the same state, or the script fails:
+
+  python3 scripts/engine_step_time.py update=kimilinear scan=kimilinear
 """
 
 from __future__ import annotations
@@ -583,6 +600,128 @@ def time_mixed(name, out):
     #                     of a cell this size needs a process of its own)
 
 
+KDA_CALLS = 24
+
+
+def _kda_cell():
+    """(the config's fields, the engine's, the state buffer's shape) of
+    `serve_kimilinear_reasoning_decode`."""
+    m = manifest.load()
+    cell = m.cells["serve_kimilinear_reasoning_decode"]
+    f = manifest.fields(m.load_config(cell["config"]))
+    eng = m.load_traffic(cell["traffic"])["engine"]
+    layers = len(f["kda_layers"])
+    return f, eng, (layers, eng["max_lanes"] + 1, f["kda_heads"],
+                    f["kda_head_dim"], f["kda_head_dim"])
+
+
+def _chained(call, state, calls=KDA_CALLS):
+    """ms a call of `call(state, layer) -> (y, state)`, `calls` of them
+    chained in one program over the donated state, the layer going round;
+    best of three sets."""
+    layers = state.shape[0]
+
+    @functools.partial(jax.jit, donate_argnums=0)
+    def run(state):
+        def body(i, carry):
+            state, acc = carry
+            y, state = call(state, i % layers)
+            return state, acc + jnp.sum(y[..., :1])
+        return jax.lax.fori_loop(0, calls, body, (state, jnp.float32(0)))
+
+    state, _ = jax.block_until_ready(run(state))
+    best = float("inf")
+    for _ in range(3):
+        t0 = time.perf_counter()
+        state, _ = jax.block_until_ready(run(state))
+        best = min(best, (time.perf_counter() - t0) / calls * 1e3)
+    return best, state
+
+
+def _kda_rows(rng, shape, n, p):
+    """q, k (unit a head), v, the decays' logs and beta of rows `shape` +
+    [H], as the mixer hands them over."""
+    def unit(x):
+        return x * jax.lax.rsqrt(jnp.sum(x * x, -1, keepdims=True) + 1e-6)
+
+    keys = jax.random.split(rng, 5)
+    q = unit(jax.random.normal(keys[0], shape + (n,))) * n ** -0.5
+    k = unit(jax.random.normal(keys[1], shape + (n,)))
+    v = jax.random.normal(keys[2], shape + (p,))
+    g = -0.05 * jnp.exp(jax.random.normal(keys[3], shape + (n,)))
+    beta = jax.nn.sigmoid(jax.random.normal(keys[4], shape))
+    return q, k, v, g, beta
+
+
+def time_kda_update(name, out):
+    """`kda_update` at the cell's lanes beside `ssm_update` on a buffer of
+    the same shape, into `out`."""
+    from benchmark import kda_flops, ssm_flops
+    from ray_tpu.ops import ssm
+    f, eng, shape = _kda_cell()
+    lanes, (_, _, h, n, p) = eng["max_lanes"], shape
+    peaks = manifest.peaks(jax.devices()[0].device_kind)
+    slots = jnp.arange(lanes, dtype=jnp.int32)
+    q, k, v, g, beta = _kda_rows(jax.random.key(0), (lanes, h), n, p)
+    ms, _ = _chained(lambda s, ly: ssm.kda_update(s, q, k, v, g, beta,
+                                                  slots, ly),
+                     jnp.zeros(shape, jnp.float32))
+    key = f"update_{name}_"
+    least = flops.roofline_s(*kda_flops.update(lanes, f), peaks)[0]
+    out[key + "kda_update_ms"] = ms
+    out[key + "kda_update_least_pct"] = 100.0 * least * 1e3 / ms
+    # Mamba-2's update as nemotron-3-nano-30b-a3b runs it, the same buffer
+    mamba = {"ssm_heads": 64, "ssm_head_dim": 64, "ssm_state": 128,
+             "ssm_groups": 8}
+    assert ssm.state_shape(64, 128, 64, 8) == shape[2:]
+    x = jax.random.normal(jax.random.key(1), (lanes, 64, 64))
+    dt = jax.nn.softplus(jax.random.normal(jax.random.key(2), (lanes, 64)))
+    a = -jnp.exp(jax.random.normal(jax.random.key(3), (64,)))
+    bm, cm = jax.random.normal(jax.random.key(4), (2, lanes, 8, 128))
+    ms, _ = _chained(lambda s, ly: ssm.ssm_update(s, x, dt, a, bm, cm,
+                                                  slots, ly),
+                     jnp.zeros(shape, jnp.float32))
+    least = flops.roofline_s(*ssm_flops.update(lanes, mamba), peaks)[0]
+    out[f"update_{name}_ssm_update_same_buffer_ms"] = ms
+    out[f"update_{name}_ssm_update_same_buffer_least_pct"] = \
+        100.0 * least * 1e3 / ms
+
+
+def time_kda_scan(name, out):
+    """`kda_scan` over an admission's chunks, the kernel and the chunked
+    form in plain XLA, into `out`."""
+    from benchmark import kda_flops
+    from ray_tpu.ops import ssm
+    f, eng, shape = _kda_cell()
+    _, _, h, n, p = shape
+    peaks = manifest.peaks(jax.devices()[0].device_kind)
+    t = eng["prefill_chunk"]
+    CHUNKS = (f["kda_chunk"], 16, 64)
+    for rows in (eng["prefill_lanes"], 1):
+        q, k, v, g, beta = _kda_rows(jax.random.key(rows), (rows, t, h), n, p)
+        slots = jnp.arange(rows, dtype=jnp.int32)
+        fresh = jnp.zeros((rows,), bool)
+        left = {}
+        for form, kw in (("kda_scan", dict(use_kernel=True)),
+                         ("chunked_xla", dict(use_kernel=False))):
+            for chunk in CHUNKS if form != "chunked_xla" else CHUNKS[:1]:
+                ms, state = _chained(
+                    lambda s, ly, kw=kw, chunk=chunk: ssm.kda_scan(
+                        s, q, k, v, g, beta, slots, fresh, ly, chunk=chunk,
+                        **kw),
+                    jax.random.normal(jax.random.key(9), shape), calls=6)
+                left[form, chunk] = np.asarray(state[:, :rows])
+                out[f"scan_{name}_rows{rows}x{t}_{form}_chunk{chunk}_ms"] = ms
+        for got in left.values():
+            np.testing.assert_allclose(got, left["chunked_xla", CHUNKS[0]],
+                                       atol=2e-4)
+        least = flops.roofline_s(*kda_flops.scan(rows * t, rows, f),
+                                 peaks)[0]
+        out[f"scan_{name}_rows{rows}x{t}_kda_scan_least_pct"] = (
+            100.0 * least * 1e3
+            / out[f"scan_{name}_rows{rows}x{t}_kda_scan_chunk{CHUNKS[0]}_ms"])
+
+
 def main(argv):
     baseline_root = None
     if "--baseline-root" in argv:
@@ -591,7 +730,8 @@ def main(argv):
         argv = argv[:at] + argv[at + 2:]
     named = {kind: [a.split("=", 1)[1] for a in argv
                     if a.startswith(kind + "=")]
-             for kind in ("write", "select", "attend", "runs", "mixed")}
+             for kind in ("write", "select", "attend", "runs", "mixed",
+                          "update", "scan")}
     runs = [int(kb) for r in named.pop("runs") for kb in r.split(",")]
     unrolls = [int(a) for a in argv if "=" not in a]
     dev = jax.devices()[0]
@@ -600,7 +740,8 @@ def main(argv):
         time_steps(unrolls, out)
     for name in named["write"] or ["gpt2xl"] * (
             not named["select"] and not named["attend"]
-            and not named["mixed"]):
+            and not named["mixed"] and not named["update"]
+            and not named["scan"]):
         time_rows_write(name, out)
     for name in named["select"]:
         time_select(name, out)
@@ -608,6 +749,10 @@ def main(argv):
         time_attend(name, out, runs, baseline_root)
     for name in named["mixed"]:
         time_mixed(name, out)
+    for name in named["update"]:
+        time_kda_update(name, out)
+    for name in named["scan"]:
+        time_kda_scan(name, out)
     print(json.dumps(out))
 
 

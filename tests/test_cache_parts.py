@@ -36,7 +36,10 @@ KINDS = {"kv": ("gpt", "nano", 40),
          "state:own_layers": ("nemotronh", "nemotronh-nano", (40, 3)),
          # the state part of ONE buffer: a gated short convolution keeps its
          # tail and no recurrent state
-         "state:tail_only": ("lfm2", "lfm2-nano", (40, 3))}
+         "state:tail_only": ("lfm2", "lfm2-nano", (40, 3)),
+         # the state part (a recurrent state and a tail over four KDA
+         # layers) behind ONE latent pool over two latent layers
+         "state:latent_pool": ("kimilinear", "kimilinear-nano", (40, 3))}
 
 
 def _make(kind) -> PagedKVCache:
@@ -277,7 +280,7 @@ def test_one_builder_makes_every_cache():
 
 @pytest.mark.parametrize("kind,names", [
     ("state", ("state", "tail")), ("state:own_layers", ("state", "tail")),
-    ("state:tail_only", ("tail",))])
+    ("state:tail_only", ("tail",)), ("state:latent_pool", ("state", "tail"))])
 def test_a_state_part_holds_the_buffers_its_mixer_states(kind, names):
     """One buffer or two out of one code path: what the part allocates,
     hands to a step, takes back, snapshots and ships is exactly what the
@@ -289,8 +292,9 @@ def test_a_state_part_holds_the_buffers_its_mixer_states(kind, names):
     assert part.wire == names == cache._wire_more
     assert len(part.buffers) == len(part.snap_buffers) == len(names)
     pools, none = cache.step_pools
-    assert none is None and len(pools) == 2 + len(names)
-    assert all(a is b for a, b in zip(pools[2:], part.buffers))
+    rows = 1 if cache.latent else 2     # one latent pool, or a K and a V
+    assert none is None and len(pools) == rows + len(names)
+    assert all(a is b for a, b in zip(pools[rows:], part.buffers))
     assert all(b.shape[:2] == (part.buffers[0].shape[0], 3 + 1)
                and s.shape == b.shape[:1] + (3,) + b.shape[2:]
                for b, s in zip(part.buffers, part.snap_buffers))
@@ -303,7 +307,9 @@ def test_a_state_part_holds_the_buffers_its_mixer_states(kind, names):
     # a step's return is rebound buffer for buffer
     cache.update_pools(tuple(x + 1 for x in pools), None)
     assert all(np.array_equal(a, b + 1) for a, b in zip(
-        cache.parts[-1].buffers, pools[2:]))
+        cache.parts[-1].buffers, pools[rows:]))
+    assert (cache.v is None) == cache.latent
+    assert np.array_equal(cache.k, pools[0] + 1)
     # checkpoint lane 0, adopt into lane 1: lane 1's slot is lane 0's
     assert cache.adopt_prefix(0, tokens) == 0
     _drive(cache, 0, tokens, mark=MARK)
@@ -321,6 +327,13 @@ def test_a_state_part_holds_the_buffers_its_mixer_states(kind, names):
     for wrong in (fewer, more):
         assert _make(kind).install_prefix(dict(payload, more=wrong)) == 0
     assert _make(kind).install_prefix(payload) == MARK // BS
+    # a frame of latent rows and a state goes through the codec, and into
+    # no cache whose rows are a K and a V pool (nor the other way round)
+    assert (payload["v_pool"] is None) == cache.latent
+    decoded = KVBlockCodec.decode(KVBlockCodec.encode(payload))
+    assert decoded["kind"] == "state" and set(decoded["more"]) == set(names)
+    other = "state" if cache.latent else "state:latent_pool"
+    assert _make(other).install_prefix(decoded) == 0
 
 
 def test_what_the_cache_cannot_do_is_asked_of_it_once():

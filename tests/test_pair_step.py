@@ -53,6 +53,10 @@ CASES = {
     # a conv mixer in the attention's place over experts: the state part
     # is the tails alone, one buffer
     "state_tail_only": ("lfm2", "lfm2-nano", STATE, 5, (20, 13, 30)),
+    # a recurrent state and its tail behind ONE latent pool, a share of the
+    # experts held: the update and the scan in one program
+    "state_latent_pool": ("kimilinear", "kimilinear-nano-share", STATE, 5,
+                          (20, 13, 30)),
     # window and full layers over K and V heads, two pairs of pools
     "layered_kv": ("afmoe", "afmoe-nano", LAYERED, 5, (13, 37, 30)),
 }

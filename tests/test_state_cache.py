@@ -18,9 +18,10 @@ import numpy as np
 import pytest
 
 from benchmark.reference import falconh1 as ref
+from benchmark.reference import kimilinear as kimi_ref
 from benchmark.reference import lfm2 as lfm2_ref
 from ray_tpu.inference import InferenceEngine, PagedKVCache
-from ray_tpu.models import falconh1, lfm2
+from ray_tpu.models import falconh1, kimilinear, lfm2
 from ray_tpu.serve.kv_tier.codec import KVBlockCodec
 
 NANO = falconh1.CONFIGS["falconh1-nano"]
@@ -40,12 +41,23 @@ def _lfm2_init():
         lfm2.CONFIGS["lfm2-nano"], jax.random.key(0))
 
 
+@functools.lru_cache(maxsize=None)
+def _kimi_init():
+    return jax.jit(kimilinear.init_params, static_argnums=0)(
+        kimilinear.CONFIGS["kimilinear-nano"], jax.random.key(0))
+
+
 # buffers of the state part -> family, config, parameters, reference
 FAMILIES = {
     "two_buffers": ("falconh1", NANO, _init, ref),
     "one_buffer": ("lfm2", lfm2.CONFIGS["lfm2-nano"], _lfm2_init, lfm2_ref),
+    # two buffers (Kimi Delta Attention's state and its tail) behind ONE
+    # latent pool: the chain snapshots are keyed by is of latent rows
+    "latent_pool": ("kimilinear", kimilinear.CONFIGS["kimilinear-nano"],
+                    _kimi_init, kimi_ref),
 }
 BUFFERS = list(FAMILIES)
+N_BUFFERS = {"two_buffers": 2, "one_buffer": 1, "latent_pool": 2}
 
 
 def _engine(buffers="two_buffers", **kw):
@@ -120,11 +132,12 @@ def test_a_lane_adopted_from_a_snapshot_equals_one_prefilled_from_token_0(
     assert st["prefix_hit_tokens"] == 32 and st["ssm"]["snapshots_adopted"] == 1
     part = eng.cache.parts[0]
     assert st["ssm"]["state_buffers"] == len(part.wire) == len(
-        eng.cache.buffers) == len(BUFFERS) - BUFFERS.index(buffers)
+        eng.cache.buffers) == N_BUFFERS[buffers]
+    assert eng.cache.latent == (buffers == "latent_pool")
     assert st["ssm"]["state_bytes"] == sum(b.nbytes for b in part.buffers)
     assert st["ssm"]["snapshot_bytes"] == sum(
         b.nbytes for b in part.snap_buffers)
-    if buffers == "two_buffers":
+    if buffers != "one_buffer":
         assert st["ssm"]["tokens_scanned"] == len(first) + 7
     else:
         assert st["conv"]["rows_chunk"] == len(first) + 7
@@ -247,9 +260,14 @@ def test_the_wire_format_carries_the_snapshot_and_a_cache_installs_its_own(
     payload = KVBlockCodec.decode(KVBlockCodec.encode(
         eng.export_prefix(second)))
     assert payload["kind"] == "state" and len(payload["chain"]) == 8
+    # a latent frame (one pool's rows, no V) or a K and a V frame, and the
+    # state frame of one lane beside it
+    assert (payload["v_pool"] is None) == (buffers == "latent_pool")
     assert {k: v.shape for k, v in payload["more"].items()} == {
         "two_buffers": {"state": (3, 4, 16, 8), "tail": (3, 3 * 96)},
-        "one_buffer": {"tail": (3, 2 * 64)}}[buffers]
+        "one_buffer": {"tail": (3, 2 * 64)},
+        "latent_pool": {"state": (4, 2, 128, 128), "tail": (4, 3 * 768)},
+    }[buffers]
     other = _engine(buffers, prefill_lanes=2)
     assert other.import_prefix(payload) == 8
     assert other.import_prefix(payload) == 0            # idempotent
@@ -273,6 +291,10 @@ def test_the_wire_format_carries_the_snapshot_and_a_cache_installs_its_own(
         llama, llama.CONFIGS["llama-tiny"], num_blocks=16, block_size=4,
         max_lanes=2)
     assert plain.install_prefix(payload) == 0
+    # nor into a state cache whose rows are of the other kind (latent rows
+    # into K and V pools, K and V rows into a latent pool)
+    foreign = "two_buffers" if buffers == "latent_pool" else "latent_pool"
+    assert _engine(foreign, prefill_lanes=2).import_prefix(payload) == 0
 
 
 def test_compiled_steps_count_the_state_buffers_copies():

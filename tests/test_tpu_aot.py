@@ -1376,7 +1376,8 @@ _PAIRS = {
     "serve_falconh1_chat_decode": (64, 1),
     "serve_nemotron3_agents_decode": (64, 1),
     "serve_trinity_docs_decode": (128, 1),
-    "serve_lfm2_rag_decode": (64, 1)}
+    "serve_lfm2_rag_decode": (64, 1),
+    "serve_kimilinear_reasoning_decode": (64, 1)}
 
 
 # Bytes of weights the pair's program may copy over its cell's T=1 program's
@@ -1435,14 +1436,17 @@ def _cell_engine(cell, device):
     return eng, params, (k, v), arg(seen["tables"], jnp.int32), carried
 
 
-# (the four cells whose two programs take one to three minutes to compile
-# here are `slow`: tier-1 has a time limit; the four others are the claimed
+# (the cells whose two programs take one to three minutes to compile here,
+# and the one whose programs another test compiles, are `slow`: tier-1 has
+# a time limit; the four others are the claimed
 # cells' K/V programs with and without experts, a state cache's and a
 # windowed one's)
 @pytest.mark.parametrize("cell", [
     pytest.param(cell, marks=pytest.mark.slow) if cell in (
         "serve_axk1_docs_decode", "serve_dots3_docs_decode",
-        "serve_nemotron3_agents_decode", "serve_trinity_docs_decode")
+        "serve_nemotron3_agents_decode", "serve_trinity_docs_decode",
+        # (its T=1 and widest pair's programs have a test of their own)
+        "serve_kimilinear_reasoning_decode")
     else cell for cell in _PAIRS])
 def test_the_pairs_program_fits_a_v5e_and_reads_weights_and_pools_in_place(
         v5e, as_on_chip, cell):
@@ -1546,3 +1550,68 @@ def test_lfm2_programs_fit_a_v5e_and_overwrite_the_tails_in_place(
     assert counts["paged_decode_attention"] == 2
     assert counts["paged_rows_write"] == (4 if rows else 2)
     assert not any(_pool_block_updates(text, p.shape) for p in held[:2])
+
+
+@pytest.mark.parametrize("which,t,rows", [
+    ("t1", 1, 0), ("pair", 256, 4)], ids=["t1_128_lanes", "pair_128_4x256"])
+def test_kimilinear_programs_fit_a_v5e_and_leave_pool_states_and_tails(
+        v5e, as_on_chip, which, t, rows):
+    """PR 57: `serve_kimilinear_reasoning_decode`'s T=1 step at 128 lanes
+    and its widest pair's program (128 + 4 x 256 rows), compiled for the
+    chip from the files the benchmark runs the cell from: ONE latent pool
+    over the 2 latent layers, the float32 states and the bf16 tails over
+    the 6 KDA layers, all three donated and left where they are
+    (`pool_copies`, `state_copies` 0: a copy of the states is 1.6 GB);
+    10.5 GB of arguments, which fit the chip with their temporaries beside
+    the 0.42 GB of snapshots; the kernels under the names the benchmark's
+    readers find them by: `kda_update` once a KDA run (three bodies) in
+    both programs, `kda_scan` beside it in the pair's, the latent kernel
+    once a latent run (two), the grouped multiply three times an expert run
+    (four bodies).  No weight is transposed or made again; what the counter
+    reads as `copy` and `convert` are activations of the 128 lanes that
+    have a weight's shape ([128, 2304] the stream and `w_fa` turned round,
+    [128, 4096] the decay's rows and `w_fb`: the gates' rank is the lane
+    count), 22 MB where the weights are 7.5 GB."""
+    eng, params, pools, tables, carried = _cell_engine(
+        "serve_kimilinear_reasoning_decode", v5e[0])
+    arg, lanes = _arg_on(v5e[0]), eng.max_lanes
+    assert lanes == 128
+    lane_ints = lanes * 8 + rows * (3 * t + 6) if rows else None
+    compiled = eng._make_entry(t, False, False, rows).lower(
+        params, *pools,
+        arg((lanes, 8) if lane_ints is None else (lane_ints,), jnp.int32),
+        tables, *carried).compile()
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    held = jax.tree.leaves(pools)
+    assert [(tuple(p.shape), p.dtype) for p in held] == [
+        ((2, 3840, 128, 640), jnp.bfloat16),
+        ((6, 129, 32, 128, 128), jnp.float32),
+        ((6, 129, 3 * 12288), jnp.bfloat16)]
+    nbytes = sum(math.prod(p.shape) * p.dtype.itemsize for p in held)
+    # (the tails' 129 slots are padded to whole tiles)
+    assert nbytes <= memory.alias_size_in_bytes < 1.002 * nbytes
+    assert 10.4e9 < memory.argument_size_in_bytes < 10.6e9
+    assert memory.temp_size_in_bytes < 0.25e9
+    snapshots = 32 * 6 * (4 * 32 * 128 * 128 + 2 * 3 * 12288)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            + snapshots < 15.75 * 2 ** 30)
+    weights = sum(math.prod(x.shape) * x.dtype.itemsize
+                  for x in jax.tree.leaves(params))
+    assert 7.53e9 < weights < 7.56e9
+    for p in held:
+        assert count_pool_copies(text, p.shape) == 0, p.shape
+    copied = count_weight_bytes_copied(text, params)
+    assert not set(copied) & {"transpose", "remat"}, copied
+    assert copied.get("copy", 0) + copied.get("convert", 0) \
+        <= 24 * 2 ** 20, copied
+    counts = _kernel_counts(text)
+    assert set(counts) == {"moe_grouped_matmul", "paged_rows_write",
+                           "latent_decode_attention", "kda_update"} | (
+        {"kda_scan"} if rows else set())
+    assert counts["moe_grouped_matmul"] == 12
+    assert counts["latent_decode_attention"] == 2
+    assert counts["kda_update"] == 3
+    assert counts["paged_rows_write"] == (4 if rows else 2)
+    if rows:
+        assert counts["kda_scan"] == 3
+    assert not _pool_block_updates(text, held[0].shape)
