@@ -126,6 +126,18 @@ def kernels_leg() -> dict:
                                jax.jit(grads(ref))(q, k, v)):
         errs[f"flash_{name}"] = check(f"flash {name}", got, want)
 
+    # the loss head's kernel at gpt2-small's head, a row tile of a chunk
+    from ray_tpu.ops import cross_entropy as C
+    x = jax.random.normal(jax.random.fold_in(key, 4), (2048, 768),
+                          jnp.bfloat16)
+    w = (0.02 * jax.random.normal(jax.random.fold_in(key, 5), (50304, 768))
+         ).astype(jnp.bfloat16)
+    logits, lse = compiled(C.logits_lse, x, w)(x, w)
+    want = jax.lax.dot(x, w.T, preferred_element_type=jnp.float32)
+    errs["logits_lse_logits"] = check("logits_lse logits", logits, want)
+    errs["logits_lse_lse"] = check(
+        "logits_lse lse", lse, jax.scipy.special.logsumexp(want, axis=-1))
+
     lanes, bs, nb, mb = 32, 16, 2048, 64
     rng = np.random.default_rng(0)
     tables = jnp.asarray(rng.permutation(nb)[:lanes * mb]

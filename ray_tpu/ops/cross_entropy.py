@@ -1,15 +1,28 @@
 """Fused chunked softmax cross-entropy over a large vocabulary.
 
 No reference counterpart (Ray hosts frameworks; the loss lives here).
-Motivation, measured on one v5e chip (PERF.md): computing GPT-2 logits
-[B,L,V] then fp32 log_softmax materializes ~2.4GB of HBM traffic per
-direction and ran the lm-head at ~10% MFU — ~100ms of a 130ms train step.
+Computing GPT-2's logits [B, L, V] whole and then a float32 log_softmax
+moved some 2.4 GB a direction and was most of a train step on one v5e chip
+(PERF.md).  This op holds one chunk of rows' float32 logits [chunk, V] at a
+time instead of [T, V]:
 
-This op never materializes the full [T, V] logits: it scans over row
-chunks, computing chunk logits -> logsumexp -> target gather on the fly,
-and the custom VJP recomputes chunk logits in the backward (flash-attention
--style recompute, here for the classifier head).  Peak extra memory is one
-[chunk, V] block instead of [T, V].
+- a chunk's logits and their logsumexp come from ONE Mosaic kernel,
+  `logits_lse`: the product walks the vocabulary tile by tile and keeps a
+  running row maximum and sum of exponentials beside it, so no pass reads
+  the logits back to make the logsumexp (PERF.md section 6, PR 58; until
+  then XLA's product gave the row maximum and a pass of its own over the
+  chunk's 1.236 GB summed the exponentials);
+- the target's logit is a row dot with the head's gathered columns, not a
+  pass over [chunk, V];
+- the custom VJP computes each chunk's logits once: its forward rule forms
+  `softmax - onehot` in the prologues of the dx and dhead products while
+  the chunk's logits are there and keeps dx and dhead, which the backward
+  rule scales by the cotangent.  A loss without a gradient runs the kernel
+  and the row dot alone.
+
+The kernel takes the call by the operands' shapes (`_lse_plan`); any other
+shape takes XLA's product and `logsumexp`, the form this file had before
+the kernel and the tests' reference for it.
 """
 
 from __future__ import annotations
@@ -18,6 +31,198 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops.attention import NEG_INF, _interpret_kernels
+
+_LANES = 128
+# `logits_lse`'s tiles.  A row tile's `x` stays in VMEM while the head
+# streams under it, so the head is read rows / row tile times a chunk (at
+# 1,024 rows the kernel waits for it); a vocabulary tile of 384 divides
+# GPT-2's padded 50,304 = 128 x 3 x 131 (any other width has a ragged last
+# tile, which the kernel masks, and a tile of 1,024 was slower besides).
+# `_LSE_SUB_ROWS` rows at a time go through the matrix units and the online
+# update.  PERF.md section 6, PR 58 has the sweep.
+_LSE_ROW_TILES = (3072, 2048, 1024, 512, 256, 128)
+_LSE_COL_TILE = 384
+_LSE_SUB_ROWS = 256
+_LSE_VMEM_MOST = 64 * 1024 * 1024     # of a v5e core's 128 MiB
+
+
+def _lse_vmem(tm: int, tn: int, d: int, size: int) -> int:
+    """VMEM bytes of a grid step: the pipeline's two buffers of each block
+    (x, the head's tile, the logits' tile, the logsumexp's column), the
+    statistics, and room for a sub-tile's values."""
+    blocks = (tm + tn) * d * size + tm * tn * 4 + tm * _LANES * 4
+    return (2 * blocks + 2 * tm * _LANES * 4
+            + 8 * min(_LSE_SUB_ROWS, tm) * tn * 4)
+
+
+def _lse_plan(rows: int, d: int, v: int, size: int = 2):
+    """(row tile, vocabulary tile) of `logits_lse` for a chunk of `rows`
+    rows of width `d` (`size` bytes a number), or None where the shapes do
+    not fit the kernel: the vocabulary a multiple of 128 lanes, the rows a
+    multiple of a row tile whose blocks fit `_LSE_VMEM_MOST`."""
+    if v % _LANES:
+        return None
+    tn = min(_LSE_COL_TILE, v)
+    return next(((tm, tn) for tm in _LSE_ROW_TILES if rows % tm == 0
+                 and _lse_vmem(tm, tn, d, size) <= _LSE_VMEM_MOST), None)
+
+
+def _logits_lse_kernel(x_ref, w_ref, z_ref, lse_ref, m_ref, l_ref, *,
+                       v: int, sub: int):
+    """One (row tile, vocabulary tile) grid step.  `m_ref` and `l_ref` hold
+    a running maximum and a running sum of exponentials for each row AND
+    lane, [tm, 128]: a lane keeps the statistics of the columns that fall
+    on it, so a tile's update is elementwise, with no reduction across
+    lanes (which would go through the cross-lane unit once a tile), and the
+    lanes are combined once, with the last tile."""
+    j, last = pl.program_id(1), pl.num_programs(1) - 1
+    tm, tn = z_ref.shape
+
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+
+    def walk(ragged: bool):
+        for r in range(tm // sub):
+            rows = pl.ds(r * sub, sub)
+            z = jax.lax.dot_general(
+                x_ref[rows, :], w_ref[...], (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32)           # [sub, tn]
+            z_ref[rows, :] = z
+            if ragged:      # columns past V: out of the maximum and the sum
+                col = j * tn + jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
+                z = jnp.where(col < v, z, NEG_INF)
+            parts = [z[:, k:k + _LANES] for k in range(0, tn, _LANES)]
+            m_old = m_ref[rows, :]
+            m_new = functools.reduce(jnp.maximum, parts, m_old)
+            l_ref[rows, :] = (l_ref[rows, :] * jnp.exp(m_old - m_new)
+                              + sum(jnp.exp(part - m_new) for part in parts))
+            m_ref[rows, :] = m_new
+
+    if v % tn:
+        pl.when(j == last)(lambda: walk(True))
+        pl.when(j != last)(lambda: walk(False))
+    else:
+        walk(False)
+
+    @pl.when(j == last)
+    def _():
+        for r in range(tm // sub):
+            rows = pl.ds(r * sub, sub)
+            m = m_ref[rows, :]
+            top = jnp.max(m, axis=1, keepdims=True)
+            total = jnp.sum(l_ref[rows, :] * jnp.exp(m - top), axis=1,
+                            keepdims=True)
+            lse_ref[rows, :] = top + jnp.log(total)
+
+
+def logits_lse(x, w):
+    """(x @ w.T as float32 [C, V], its row logsumexp [C]) from one Mosaic
+    kernel.  `w` is the head as the tied embedding lies, [V, D], contracted
+    over D: the compiled step then feeds the kernel the embedding's bf16
+    cast as it is, where a `[D, V]` operand cost a transposed copy of it
+    (PERF.md section 6, PR 58).  The operands as they are (bf16 in a train
+    step), float32 accumulation, float32 statistics over the float32
+    logits.  The shapes are ones `_lse_plan` takes."""
+    (c, d), v = x.shape, w.shape[0]
+    size = jnp.dtype(x.dtype).itemsize
+    tm, tn = _lse_plan(c, d, v, size)
+    logits, lse = pl.pallas_call(
+        functools.partial(_logits_lse_kernel, v=v,
+                          sub=min(_LSE_SUB_ROWS, tm)),
+        name="logits_lse",
+        grid=(c // tm, pl.cdiv(v, tn)),
+        in_specs=[pl.BlockSpec((tm, d), lambda i, j: (i, 0)),
+                  pl.BlockSpec((tn, d), lambda i, j: (j, 0))],
+        out_specs=[pl.BlockSpec((tm, tn), lambda i, j: (i, j)),
+                   pl.BlockSpec((tm, 1), lambda i, j: (i, 0))],
+        out_shape=[jax.ShapeDtypeStruct((c, v), jnp.float32),
+                   jax.ShapeDtypeStruct((c, 1), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((tm, _LANES), jnp.float32),
+                        pltpu.VMEM((tm, _LANES), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=max(_lse_vmem(tm, tn, d, size),
+                                 16 * 1024 * 1024)),
+        cost_estimate=pl.CostEstimate(
+            flops=2 * c * d * v, transcendentals=c * v,
+            bytes_accessed=4 * c * v + size * (c * d + (c // tm) * d * v)),
+        interpret=_interpret_kernels(),
+    )(x, w)
+    return logits, lse[:, 0]
+
+
+def _chunk_head(x_c, head, t_c):
+    """A chunk's float32 logits [C, V], their logsumexp [C] and the
+    targets' logits [C]."""
+    if _lse_plan(x_c.shape[0], *head.shape, x_c.dtype.itemsize) is not None:
+        w = head.T      # a tied head is the embedding turned round: undone
+        logits, lse = logits_lse(x_c, w)
+        # the targets' rows of it, gathered, and a row dot: the same bf16
+        # products in float32 as the kernel's, a [C, D] affair
+        tgt = jnp.sum(x_c.astype(jnp.float32)
+                      * jnp.take(w, t_c, axis=0, mode="clip"), axis=1)
+        return logits, lse, tgt
+    # bf16 MXU matmul with fp32 accumulation, never an fp32 matmul (8x
+    # slower on the MXU) and no separate [C, V] cast buffer.
+    logits = jax.lax.dot(x_c, head, preferred_element_type=jnp.float32)
+    lse = jax.scipy.special.logsumexp(logits, axis=-1)
+    # Row-gather of the target logit as a masked reduction: gathers and
+    # scatters on [C, V] do not vectorize on TPU, iota compares do.
+    tgt = jnp.sum(jnp.where(_is_target(logits, t_c), logits, 0.0), axis=1)
+    return logits, lse, tgt
+
+
+def _is_target(logits, t_c):
+    return jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1) == t_c[:, None]
+
+
+def _chunk(arr, n_chunks):
+    t = arr.shape[0]
+    c = t // n_chunks
+    return arr[: c * n_chunks].reshape((n_chunks, c) + arr.shape[1:])
+
+
+def _ce_chunks(x, head, targets, valid, n_chunks, with_grads: bool):
+    """(loss, dx, dhead): the chunks' walk.  With `with_grads` each chunk's
+    logits, while they are there, also give their part of the gradients of
+    the LOSS (cotangent 1): dx [T, D] in x's dtype, dhead [D, V] float32."""
+    (t, d), v = x.shape, head.shape[1]
+    if t % n_chunks:
+        n_chunks = 1
+    denom = jnp.maximum(jnp.sum(valid), 1.0)
+    targets = targets.astype(jnp.int32)
+
+    def body(carry, inp):
+        total, dhead = carry
+        x_c, t_c, v_c = inp
+        logits, lse, tgt = _chunk_head(x_c, head, t_c)
+        total = total + jnp.sum((lse - tgt) * v_c)
+        if not with_grads:
+            return (total, dhead), None
+        # dlogits = (softmax - onehot(t)) * valid / denom as ONE fused
+        # elementwise chain in each product's prologue: exp, scale, and an
+        # iota-mask subtraction (a scatter here would serialize on TPU).
+        dlogits = ((jnp.exp(logits - lse[:, None])
+                    - jnp.where(_is_target(logits, t_c), 1.0, 0.0))
+                   * (v_c / denom)[:, None]).astype(x.dtype)  # [C, V] bf16
+        dx_c = jax.lax.dot(dlogits, head.T.astype(x.dtype))   # [C, D]
+        # bf16 x bf16 -> fp32 accumulate on the MXU for the head grad.
+        dhead = dhead + jax.lax.dot(x_c.T, dlogits,
+                                    preferred_element_type=jnp.float32)
+        return (total, dhead), dx_c
+
+    dhead0 = jnp.zeros((d, v) if with_grads else (), jnp.float32)
+    (total, dhead), dxs = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32), dhead0),
+        (_chunk(x, n_chunks), _chunk(targets, n_chunks),
+         _chunk(valid, n_chunks)), unroll=True)
+    return total / denom, dxs.reshape(t, d) if with_grads else None, dhead
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
@@ -28,85 +233,18 @@ def fused_cross_entropy(x, head, targets, valid, n_chunks: int = 4):
     valid: [T] float mask.  Returns scalar fp32:
         sum(valid * nll) / max(sum(valid), 1).
     """
-    loss, _ = _ce_fwd_impl(x, head, targets, valid, n_chunks)
-    return loss
-
-
-def _chunk(arr, n_chunks):
-    t = arr.shape[0]
-    c = t // n_chunks
-    return arr[: c * n_chunks].reshape((n_chunks, c) + arr.shape[1:])
-
-
-def _ce_fwd_impl(x, head, targets, valid, n_chunks):
-    t = x.shape[0]
-    if t % n_chunks:
-        n_chunks = 1
-    xs = _chunk(x, n_chunks)
-    ts = _chunk(targets, n_chunks)
-    vs = _chunk(valid, n_chunks)
-
-    def body(acc, inp):
-        x_c, t_c, v_c = inp
-        # bf16 MXU matmul with fp32 accumulation — never an fp32 matmul
-        # (8x slower on the MXU) and no separate [C, V] cast buffer.
-        logits = jax.lax.dot(x_c, head,
-                             preferred_element_type=jnp.float32)  # [C, V]
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        # Row-gather of the target logit as a masked reduction — gathers/
-        # scatters on [C, V] do not vectorize on TPU, iota compares do.
-        iota_v = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        tgt = jnp.sum(jnp.where(iota_v == t_c[:, None].astype(jnp.int32),
-                                logits, 0.0), axis=1)
-        return acc + jnp.sum((lse - tgt) * v_c), None
-
-    total, _ = jax.lax.scan(body, jnp.zeros((), jnp.float32), (xs, ts, vs),
-                            unroll=True)
-    denom = jnp.maximum(jnp.sum(valid), 1.0)
-    return total / denom, denom
+    return _ce_chunks(x, head, targets, valid, n_chunks, False)[0]
 
 
 def _ce_fwd(x, head, targets, valid, n_chunks):
-    loss, denom = _ce_fwd_impl(x, head, targets, valid, n_chunks)
-    return loss, (x, head, targets, valid, denom)
+    loss, dx, dhead = _ce_chunks(x, head, targets, valid, n_chunks, True)
+    return loss, (dx, dhead.astype(head.dtype))
 
 
 def _ce_bwd(n_chunks, res, g):
-    x, head, targets, valid, denom = res
-    t, d = x.shape
-    v = head.shape[1]
-    nc = n_chunks if t % n_chunks == 0 else 1
-    xs = _chunk(x, nc)
-    ts = _chunk(targets, nc)
-    vs = _chunk(valid, nc)
-    scale = (g / denom).astype(jnp.float32)
-
-    c = xs.shape[1]
-
-    def body(dhead_acc, inp):
-        x_c, t_c, v_c = inp
-        logits = jax.lax.dot(x_c, head,
-                             preferred_element_type=jnp.float32)  # [C, V]
-        lse = jax.scipy.special.logsumexp(logits, axis=-1)
-        sv = v_c * scale                                  # [C]
-        # dlogits = (softmax - onehot(t)) * sv as ONE fused elementwise
-        # chain: exp, scale, and an iota-mask subtraction (a scatter here
-        # would serialize on TPU).
-        iota_v = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        is_tgt = iota_v == t_c[:, None].astype(jnp.int32)
-        dlogits = ((jnp.exp(logits - lse[:, None])
-                    - jnp.where(is_tgt, 1.0, 0.0))
-                   * sv[:, None]).astype(x.dtype)         # [C, V] bf16
-        dx_c = jax.lax.dot(dlogits, head.T.astype(x.dtype))   # [C, D]
-        # bf16 x bf16 -> fp32 accumulate on the MXU for the head grad.
-        dhead_acc = dhead_acc + jax.lax.dot(
-            x_c.T, dlogits, preferred_element_type=jnp.float32)
-        return dhead_acc, dx_c
-
-    dhead, dxs = jax.lax.scan(
-        body, jnp.zeros((d, v), jnp.float32), (xs, ts, vs), unroll=True)
-    dx = dxs.reshape(t, d)
-    return dx, dhead.astype(head.dtype), None, None
+    dx, dhead = (
+        (grad.astype(jnp.float32) * g).astype(grad.dtype) for grad in res)
+    return dx, dhead, None, None
 
 
 fused_cross_entropy.defvjp(_ce_fwd, _ce_bwd)

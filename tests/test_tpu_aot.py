@@ -50,7 +50,12 @@ def as_on_chip(monkeypatch):
 
 
 def _compile_train_step(devices, mesh_cfg, cfg=CFG, batch=8):
-    mesh = create_mesh(mesh_cfg, devices=devices)
+    return _compiled_train_step(tuple(devices), mesh_cfg, cfg, batch)
+
+
+@functools.lru_cache(maxsize=None)      # two tests read gpt2-small's step
+def _compiled_train_step(devices, mesh_cfg, cfg, batch):
+    mesh = create_mesh(mesh_cfg, devices=list(devices))
     _, train_step = gpt.make_train_step(
         cfg, optax.sgd(1e-3), mesh if len(devices) > 1 else None)
 
@@ -80,16 +85,18 @@ def test_flash_kernels_carry_the_name_the_trace_reader_keys_on(v5e,
     """`benchmark/readers.py::flash_roofline` reads the kernels whose
     instruction name, less its number, is `flash_attention`; a traced run
     of the train cell that lacks the metric is refused.  Every Mosaic call
-    of the train step is a flash kernel, so none may carry another name."""
+    of the train step's layers is a flash kernel, so none there may carry
+    another name; the loss head's are `logits_lse`, which
+    `benchmark/layer_metrics/logits_lse_roofline.py` reads by that name."""
     from benchmark import trace_reduce
     text = _compile_train_step(v5e[:1], MeshConfig(data=1))
-    names = _kernel_names(text)
-    assert len(names) >= 2, names
-    assert {name.split(".")[0] for name in names} == {"flash_attention"}
+    counts = _kernel_counts(text)
+    assert set(counts) == {"flash_attention", "logits_lse"}, counts
+    assert counts["flash_attention"] == 2, counts
     # and as the reducer of a trace cuts an operation's text down to it
     assert {trace_reduce.describe(line.strip())[0]
             for line in text.splitlines()
-            if trace_reduce.KERNEL_MARK in line} == {"flash_attention"}
+            if trace_reduce.KERNEL_MARK in line} == set(counts)
 
 
 _RESULT_ORDER = re.compile(
@@ -167,11 +174,15 @@ def test_flash_kernels_read_the_heads_where_the_train_step_leaves_them(
                               remat=False, **widths)
     text = _compile_train_step(v5e[:chips], mesh_cfg, cfg, batch=batch)
     width = cfg.d_model
-    wide, names = f"bf16[{share},1024,{width}]", _kernel_names(text)
-    assert len(names) == 2 and {n.split(".")[0] for n in names} == {
-        "flash_attention"}
+    wide, counts = f"bf16[{share},1024,{width}]", _kernel_counts(text)
+    # the loss head's kernel a chunk on one chip; under a mesh the head is
+    # `fused_cross_entropy_spmd`'s, plain XLA
+    assert counts["flash_attention"] == 2 and set(counts) <= {
+        "flash_attention", "logits_lse"}, counts
+    assert counts["logits_lse"] == (4 if chips == 1 else 0), counts
     for line in text.splitlines():
-        if 'custom_call_target="tpu_custom_call"' in line:
+        if ('custom_call_target="tpu_custom_call"' in line
+                and "%flash_attention" in line.split(" = ")[0]):
             results, operands = line.split(" custom-call(")
             operands = operands.split("operand_layout_constraints=")[1].split(
                 ", frontend_attributes")[0]
@@ -219,6 +230,70 @@ def test_flash_kernels_compile_for_v5e_at_two_blocks_a_head_of_128(
                                                 jnp.bfloat16),) * 2,
                          True, None, 1024, 1024, False)
     assert (plan.block_q, plan.block_k, plan.column_blocks) == (1024, 1024, 16)
+
+
+def _readers(text, value):
+    """The instructions of the entry computation that take `value` (a
+    name, `%` and all) as an operand, each with the text of the computation
+    it calls, if it calls one."""
+    bodies, name = {}, None
+    for line in text.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            name = line.split(" ")[1 if line.startswith("ENTRY") else 0]
+            bodies[name] = []
+        elif name is not None:
+            bodies[name].append(line)
+    entry = next(body for name, body in bodies.items()
+                 if f"ENTRY {name}" in text)
+    found = []
+    for line in entry:
+        made, _, rest = line.partition(" = ")
+        if re.search(re.escape(value) + r"[,)]", rest):
+            called = re.search(r"calls=(%[\w.\-]+)", rest)
+            found.append((made.strip(), "\n".join(
+                bodies.get(called.group(1), [])) if called else rest))
+    return found
+
+
+def test_the_loss_head_makes_a_chunks_logits_once_and_reads_them_twice(
+        v5e, as_on_chip):
+    """`train_gpt2s_1chip`'s step at one layer (b24 x 1024, vocabulary
+    50,304; four chunks of 6,144 rows): a `logits_lse` call a chunk and no
+    other product gives a chunk's `f32[6144,50304]`, so the backward
+    recomputes none; and each chunk's logits are read by two instructions,
+    the dx product and the dhead product (each forms `softmax - onehot` in
+    its prologue), where until PR 58 a third, `select_reduce_fusion`, read
+    all 1.236 GB of them again for the sum of exponentials (6.5 ms of a
+    150.8 ms step, PERF.md section 6)."""
+    cfg = dataclasses.replace(CFG, vocab_size=50304, max_seq_len=1024,
+                              remat=False, d_model=768, n_heads=12,
+                              d_ff=3072)
+    text = _compile_train_step(v5e[:1], MeshConfig(data=1), cfg, batch=24)
+    assert _kernel_counts(text)["logits_lse"] == 4
+    wide = r"f32\[6144,50304\]"
+    assert not re.search(rf" = {wide}\S* (convolution|dot)\(", text)
+    logits = re.findall(
+        rf"(%[\w.\-]+) = {wide}\S* get-tuple-element\((?:\([^)]*\) )?"
+        rf"%logits_lse[\w.\-]*\), index=0", text)
+    assert len(logits) == 4, logits
+    for value in logits:
+        readers = _readers(text, value)
+        assert len(readers) == 2, (value, [made for made, _ in readers])
+        assert all(" convolution(" in body for _, body in readers), readers
+
+
+def test_logits_lse_compiles_for_v5e_at_gpt2xls_width(v5e, as_on_chip):
+    """gpt2-xl's head: D 1,600 is twelve and a half blocks of 128, so the
+    blocks of `x` and of the head are the whole of D wide; a chunk of a
+    b4 x 1024 step's rows.  `_lse_plan` takes the shape and the compiler
+    takes the kernel (the fallback would be no `tpu_custom_call`)."""
+    from ray_tpu.ops import cross_entropy as ce
+    arg = _arg_on(v5e[0])
+    assert ce._lse_plan(1024, 1600, 50304) == (1024, 384)
+    text = jax.jit(ce.logits_lse).lower(
+        arg((1024, 1600), jnp.bfloat16),
+        arg((50304, 1600), jnp.bfloat16)).compile().as_text()
+    assert _kernel_counts(text) == {"logits_lse": 1}
 
 
 def test_train_step_compiles_under_a_v5e_mesh(v5e, as_on_chip):
