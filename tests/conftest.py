@@ -21,7 +21,42 @@ if "xla_force_host_platform_device_count" not in flags:
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+import gc  # noqa: E402
+
 import pytest  # noqa: E402
+
+MAPS = "/proc/self/maps"
+MAP_LIMIT = "/proc/sys/vm/max_map_count"
+
+
+def memory_maps():
+    """(maps this process holds, the kernel's limit a process), or None
+    where the two cannot be read (no /proc)."""
+    try:
+        with open(MAPS, "rb") as held, open(MAP_LIMIT) as limit:
+            return sum(1 for _ in held), int(limit.read())
+    except (OSError, ValueError):
+        return None
+
+
+def release_programs_past_half_the_map_limit():
+    """Every CPU executable a process holds keeps some ten memory maps and
+    jit's caches let none go; at `vm.max_map_count` (65,530) LLVM's next
+    mmap fails and the worker dies in `backend_compile_and_load` (PERF.md
+    section 7, "Found (PR 59)").  Past half the limit the process gives its
+    programs back; whoever needs one compiles it again."""
+    seen = memory_maps()
+    if seen and 2 * seen[0] > seen[1]:
+        import jax
+
+        jax.clear_caches()
+        gc.collect()
+
+
+@pytest.fixture(autouse=True)
+def _a_worker_never_reaches_the_map_limit():
+    yield
+    release_programs_past_half_the_map_limit()
 
 
 def pytest_configure(config):

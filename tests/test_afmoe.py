@@ -10,7 +10,6 @@ Tolerances: float32 sums in another order; logits are of order 4, so 2e-4
 is five digits."""
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -21,16 +20,14 @@ from benchmark.reference import afmoe as ref
 from ray_tpu.inference import InferenceEngine, PagedKVCache
 from ray_tpu.models import afmoe, decoder
 from ray_tpu.ops import attention as ops
+from tests import serving_script
 
 NANO = afmoe.CONFIGS["afmoe-nano"]
 LOGIT_TOL = 2e-4
 
 
-@functools.lru_cache(maxsize=None)
 def _init(cfg, seed=0):
-    """(one compiled program a config, not one dispatch an op)"""
-    return jax.jit(afmoe.init_params, static_argnums=0)(
-        cfg, jax.random.key(seed))
+    return serving_script.init_params(afmoe, cfg, seed)
 
 
 def _params(cfg, seed=0):
@@ -92,7 +89,7 @@ def test_uncached_forward_matches_the_reference_on_logits():
     params = _params(NANO)
     tokens = _tokens(NANO, (2, 80))
     with jax.default_matmul_precision("highest"):
-        got = afmoe.forward(params, tokens, NANO)
+        got = serving_script.forward(afmoe, params, tokens, NANO)
     want = ref.logits(params, tokens)
     np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
     assert float(jnp.abs(want).max()) > 100 * LOGIT_TOL
@@ -108,7 +105,6 @@ def _cached_logits(cfg, params, tokens, chunk, block_size=4, served=False):
     a time (the T=1 path), through a cache of both kinds whose blocks are
     dealt out of order, giving back the sliding kind's blocks as the window
     moves on; logits of every position, and the cache."""
-    length = len(tokens)
     cache = PagedKVCache.for_model(afmoe, cfg, num_blocks=(40, 12),
                                    block_size=block_size, max_lanes=2,
                                    max_seq_len=96, ahead=chunk)
@@ -118,35 +114,12 @@ def _cached_logits(cfg, params, tokens, chunk, block_size=4, served=False):
         (6, 12, block_size, 128), (6, 12, block_size, 128)]
     cache.allocator.alloc(3)              # lane 1 does not start at block 0
     cache.parts[0].index.allocator.alloc(2)
-    cache.alloc_lane(1, length)
     tree = afmoe.serving_params(params, cfg) if served else params
-    pools, out, at = cache.k, [], 0
-
-    @jax.jit        # (two shapes; op by op every call compiles its loops)
-    def step(tree, tok, pos, valid, pools, tables, ctx_lens):
-        with jax.default_matmul_precision("highest"):
-            x, pools, none = afmoe.forward_cached(
-                tree, tok, pos, valid, pools, None, tables, ctx_lens, cfg)
-            assert none is None and len(pools) == 4
-            return afmoe.lm_head(tree, x[1], cfg), pools
-
-    while at < length:
-        t = chunk if at + chunk <= length - 30 else 1
-        cache.ensure_capacity(1, at + t)
-        tok = np.zeros((2, t), np.int32)
-        tok[1] = tokens[at:at + t]
-        pos = np.zeros((2, t), np.int32)
-        pos[1] = at + np.arange(t)
-        valid = np.zeros((2, t), bool)
-        valid[1] = True
-        logits, pools = step(
-            tree, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(valid),
-            pools, cache.device_tables(), jnp.asarray([1, at + t], jnp.int32))
-        out.append(logits)
-        at += t
-        cache.seq_lens[1] = at
-        cache.after_commit([1])
-    return jnp.concatenate(out), cache
+    (_, got), (pools, none), _ = serving_script.serve(
+        afmoe, cfg, tree, cache, [None, tokens], chunk, [0, 1],
+        prefill=[0, (len(tokens) - 30) // chunk * chunk], precision="highest")
+    assert none is None and len(pools) == 4
+    return got, cache
 
 
 @pytest.mark.parametrize("served", [False, True],

@@ -22,6 +22,7 @@ from ray_tpu.inference import InferenceEngine, PagedKVCache
 from ray_tpu.models import axk1, decoder, gpt, llama
 from ray_tpu.ops import attention as ops
 from ray_tpu.ops import moe
+from tests import serving_script
 
 NANO = axk1.CONFIGS["axk1-nano"]
 SHARE = axk1.CONFIGS["axk1-nano-share"]
@@ -43,7 +44,7 @@ def _ref_kw(cfg):
 def _params(cfg, seed=0):
     """Seeded weights with norm scales off one, so that a norm applied to
     the wrong thing shows."""
-    params = axk1.init_params(cfg, jax.random.key(seed))
+    params = serving_script.init_params(axk1, cfg, seed)
 
     def jitter(tree):
         return {k: v * (1.0 + 0.1 * jax.random.normal(jax.random.key(9),
@@ -64,7 +65,7 @@ def _tokens(cfg, shape, seed=1):
 def test_uncached_forward_matches_the_reference_on_logits(cfg):
     params, tokens = _params(cfg), _tokens(cfg, (2, 48))
     with jax.default_matmul_precision("highest"):
-        got = axk1.forward(params, tokens, cfg)
+        got = serving_script.forward(axk1, params, tokens, cfg)
     want = ref.logits(params, tokens, **_ref_kw(cfg))
     assert float(jnp.abs(want).max()) > 1.0
     np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
@@ -82,7 +83,7 @@ def test_a_stack_may_lead_with_dense_layers(lead):
         assert params["lead_blocks"]["w_gate"].shape == (lead, 64, cfg.d_ff)
     assert params["blocks"]["router"].shape[0] == 4 - lead
     with jax.default_matmul_precision("highest"):
-        got = axk1.forward(params, tokens, cfg)
+        got = serving_script.forward(axk1, params, tokens, cfg)
     np.testing.assert_allclose(got, ref.logits(params, tokens, **_ref_kw(cfg)),
                                atol=LOGIT_TOL, rtol=0)
 
@@ -91,33 +92,18 @@ def _cached_logits(cfg, params, tokens, chunk, block_size=8, served=False):
     """Prefill `tokens` [L] in chunks of `chunk`, the last 6 one token at a
     time (the T=1 path), through a latent paged cache whose blocks are
     dealt out of order; logits of every position."""
-    length = len(tokens)
     cache = PagedKVCache.for_model(axk1, cfg, num_blocks=40,
                                    block_size=block_size, max_lanes=2,
                                    max_seq_len=128)
     assert cache.kind == "latent" and cache.v is None
     assert cache.k.shape == (cfg.n_layers, 40, block_size, 128)
     cache.allocator.alloc(3)              # lane 1 does not start at block 0
-    cache.alloc_lane(1, length)
     tree = axk1.serving_params(params, cfg) if served else params
-    tables = cache.device_tables()
-    pool, out, at = cache.k, [], 0
-    while at < length:
-        t = chunk if at + chunk <= length - 6 else 1
-        tok = np.zeros((2, t), np.int32)
-        tok[1] = tokens[at:at + t]
-        pos = np.zeros((2, t), np.int32)
-        pos[1] = at + np.arange(t)
-        valid = np.zeros((2, t), bool)
-        valid[1] = True
-        with jax.default_matmul_precision("highest"):
-            x, pool, none = axk1.forward_cached(
-                tree, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(valid),
-                pool, None, tables, jnp.asarray([1, at + t], jnp.int32), cfg)
-            out.append(axk1.lm_head(tree, x[1], cfg))
-        assert none is None
-        at += t
-    return jnp.concatenate(out)
+    (_, got), (_, none), _ = serving_script.serve(
+        axk1, cfg, tree, cache, [None, tokens], chunk, [0, 1],
+        prefill=[0, (len(tokens) - 6) // chunk * chunk], precision="highest")
+    assert none is None
+    return got
 
 
 # Two dense layers lead three expert layers, two layer bodies a trip: the
@@ -439,8 +425,8 @@ def test_a_sliced_vocabulary_is_a_smaller_vocabulary():
         lambda x: x.shape, sliced)
     tokens = _tokens(sliced_cfg, (1, 20))
     assert int(tokens.max()) < 64
-    whole = axk1.forward(params, tokens, cfg)
-    got = axk1.forward(sliced, tokens, sliced_cfg)
+    whole = serving_script.forward(axk1, params, tokens, cfg)
+    got = serving_script.forward(axk1, sliced, tokens, sliced_cfg)
     assert got.shape == (1, 20, 64)
     np.testing.assert_allclose(got, whole[..., :64], atol=1e-5)
     eng = InferenceEngine("axk1", sliced_cfg, sliced, auto_start=False,
@@ -465,7 +451,7 @@ def test_prefill_lanes_serve_the_same_tokens_from_fewer_rows(family):
     [prefill_lanes, T] rows, not [max_lanes, T]; further prefilling lanes
     wait a step; the served tokens are those of the default."""
     model, cfg = FAMILIES[family]
-    params = model.init_params(cfg, jax.random.key(2))
+    params = serving_script.init_params(model, cfg, 2)
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, cfg.vocab_size, n).tolist()
                for n in (21, 9, 30, 14, 5)]
@@ -537,7 +523,7 @@ def test_a_question_behind_a_cached_document_runs_the_short_program():
 def test_latent_cache_wire_format_says_its_kind():
     from ray_tpu.serve.kv_tier.codec import KVBlockCodec
     cfg = SHARE
-    params = axk1.init_params(cfg, jax.random.key(0))
+    params = serving_script.init_params(axk1, cfg)
     kw = dict(auto_start=False, max_lanes=2, block_size=8, prefill_chunk=8,
               max_seq_len=64, num_blocks=16)
     a = InferenceEngine("axk1", cfg, params, **kw)

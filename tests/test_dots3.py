@@ -9,7 +9,6 @@ up-projection and gathers chosen rows, the reference expands every key and
 masks); logits are of order 4, so 2e-4 is five digits."""
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -17,9 +16,10 @@ import numpy as np
 import pytest
 
 from benchmark.reference import dots3 as ref
-from ray_tpu.inference import InferenceEngine, PagedKVCache
+from ray_tpu.inference import PagedKVCache
 from ray_tpu.models import decoder, dots3
 from ray_tpu.ops import attention as ops
+from tests import serving_script
 
 NANO = dots3.CONFIGS["dots3-nano"]
 SHARE = dots3.CONFIGS["dots3-nano-share"]
@@ -31,11 +31,8 @@ def _ref_kw(cfg):
                 index_topk=cfg.index_topk, window=cfg.sliding_window)
 
 
-@functools.lru_cache(maxsize=None)
 def _init(cfg, seed=0):
-    """(one compiled program a config, not one dispatch an op)"""
-    return jax.jit(dots3.init_params, static_argnums=0)(
-        cfg, jax.random.key(seed))
+    return serving_script.init_params(dots3, cfg, seed)
 
 
 def _params(cfg, seed=0):
@@ -85,7 +82,7 @@ def test_uncached_forward_matches_the_reference_on_logits(cfg):
     params = _params(cfg)
     tokens = _tokens(cfg, (2, 80))
     with jax.default_matmul_precision("highest"):
-        got = dots3.forward(params, tokens, cfg)
+        got = serving_script.forward(dots3, params, tokens, cfg)
     want = ref.logits(params, tokens, **_ref_kw(cfg))
     np.testing.assert_allclose(got, want, atol=LOGIT_TOL, rtol=0)
     assert float(jnp.abs(want).max()) > 100 * LOGIT_TOL
@@ -96,7 +93,6 @@ def _cached_logits(cfg, params, tokens, chunk, block_size=4, served=False):
     a time (the T=1 path), through a cache of both kinds whose blocks are
     dealt out of order, giving back the sliding kind's blocks as the window
     moves on; logits of every position, and the cache."""
-    length = len(tokens)
     cache = PagedKVCache.for_model(dots3, cfg, num_blocks=(40, 12),
                                    block_size=block_size, max_lanes=2,
                                    max_seq_len=96, ahead=chunk)
@@ -106,35 +102,12 @@ def _cached_logits(cfg, params, tokens, chunk, block_size=4, served=False):
         (6, 12, block_size, 128)]
     cache.allocator.alloc(3)              # lane 1 does not start at block 0
     cache.parts[0].index.allocator.alloc(2)
-    cache.alloc_lane(1, length)
     tree = dots3.serving_params(params, cfg) if served else params
-    pools, out, at = cache.k, [], 0
-
-    @jax.jit        # (two shapes; op by op every call compiles its loops)
-    def step(tree, tok, pos, valid, pools, tables, ctx_lens):
-        with jax.default_matmul_precision("highest"):
-            x, pools, none = dots3.forward_cached(
-                tree, tok, pos, valid, pools, None, tables, ctx_lens, cfg)
-            assert none is None and len(pools) == 3
-            return dots3.lm_head(tree, x[1], cfg), pools
-
-    while at < length:
-        t = chunk if at + chunk <= length - 30 else 1
-        cache.ensure_capacity(1, at + t)
-        tok = np.zeros((2, t), np.int32)
-        tok[1] = tokens[at:at + t]
-        pos = np.zeros((2, t), np.int32)
-        pos[1] = at + np.arange(t)
-        valid = np.zeros((2, t), bool)
-        valid[1] = True
-        logits, pools = step(
-            tree, jnp.asarray(tok), jnp.asarray(pos), jnp.asarray(valid),
-            pools, cache.device_tables(), jnp.asarray([1, at + t], jnp.int32))
-        out.append(logits)
-        at += t
-        cache.seq_lens[1] = at
-        cache.after_commit([1])
-    return jnp.concatenate(out), cache
+    (_, got), (pools, none), _ = serving_script.serve(
+        dots3, cfg, tree, cache, [None, tokens], chunk, [0, 1],
+        prefill=[0, (len(tokens) - 30) // chunk * chunk], precision="highest")
+    assert none is None and len(pools) == 3
+    return got, cache
 
 
 @pytest.mark.parametrize("cfg,served", [(NANO, False), (NANO, True),
@@ -163,6 +136,12 @@ def test_a_chunk_of_more_rows_than_a_tile_takes_its_rows_in_tiles(monkeypatch):
     """`ops._rows_as_lanes` with 16 rows a chunk in tiles of 3: a trip for
     each tile that holds a valid row, the last one part empty."""
     monkeypatch.setattr(ops, "_ROW_TILE", 3)
+    # (a trace reads the tile's height, and jit keeps its traces by the
+    # function: a function of this case's own, so that neither the module's
+    # trace of this config answers here nor this one a later case)
+    step = serving_script.step.__wrapped__
+    monkeypatch.setattr(serving_script, "step", jax.jit(
+        lambda *args: step(*args), static_argnums=(0, 1)))
     cfg = NANO
     params = _params(cfg)
     tokens = np.asarray(_tokens(cfg, (80,)))

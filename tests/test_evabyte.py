@@ -18,6 +18,7 @@ from ray_tpu.inference.engine import InferenceEngine
 from ray_tpu.inference.kv_cache import PagedKVCache
 from ray_tpu.models import decoder, evabyte
 from ray_tpu.ops import attention as ops
+from tests import serving_script
 
 CFG = evabyte.CONFIGS["evabyte-nano"]
 W, C, BS = CFG.window_size, CFG.chunk_size, 8
@@ -28,7 +29,7 @@ TOL = dict(rtol=2e-4, atol=2e-4)
 
 @pytest.fixture(scope="module")
 def params():
-    p = evabyte.init_params(CFG, jax.random.key(3))
+    p = serving_script.init_params(evabyte, CFG, 3)
     # Norm scales off their initial zero, so that the unit offset shows.
     for name in ("attn_norm", "mlp_norm"):
         p["blocks"][name] = 0.1 * jax.random.normal(
@@ -55,7 +56,8 @@ def _engine(params, **kw):
 def test_whole_sequence_logits_of_all_heads_match_the_reference(params,
                                                                 length):
     tokens = _tokens(length, seed=length)
-    got = evabyte.forward(params, jnp.asarray(tokens)[None], CFG)[0]
+    got = serving_script.forward(evabyte, params,
+                                 jnp.asarray(tokens)[None], CFG)[0]
     want = reference.row_logits(params, tokens, **SHAPE)
     assert got.shape == (length, CFG.num_pred_heads * CFG.vocab_size)
     assert got.dtype == jnp.float32
@@ -97,7 +99,8 @@ def test_prefill_in_chunks_then_decode_across_two_window_edges(params, chunk):
     stats = engine.stats()
     assert stats["eva"]["compactions"] == 3
     seq = np.asarray(prompt + out)
-    logits = evabyte.forward(params, jnp.asarray(seq)[None], CFG)[0]
+    logits = serving_script.forward(evabyte, params,
+                                    jnp.asarray(seq)[None], CFG)[0]
     own = np.asarray(jnp.argmax(logits[:, :CFG.vocab_size], -1))
     np.testing.assert_array_equal(np.asarray(out),
                                   own[len(prompt) - 1:len(seq) - 1])
@@ -120,21 +123,25 @@ def test_cached_logits_equal_the_reference_at_every_position(params,
                                    max_seq_len=128)
     tokens = _tokens(2 * W + 9, seed=2)
     cache.alloc_lane(0, 1)
+    # (a window closed between two slices is not a step of the one serving
+    # script: its slices here, as one program each, and the compaction as
+    # another)
+    compact = jax.jit(evabyte.compact_cached, static_argnums=6)
     rows = []
     for pos, tok in enumerate(tokens):
         if cache.window_due(0, pos):
             src, dst = cache.close_window(0, tokens[:pos].tolist())
-            cache.update_pools(*evabyte.compact_cached(
+            cache.update_pools(*compact(
                 params, cache.k, cache.v, jnp.asarray([src]),
                 jnp.asarray([dst]), jnp.asarray([True]), cfg))
         cache.ensure_capacity(0, pos + 1)
-        x, k, v = evabyte.forward_cached(
-            params, jnp.asarray([[tok]]), jnp.asarray([[pos]]),
+        logits, k, v, _ = serving_script.step(
+            evabyte, cfg, params, jnp.asarray([[tok]]), jnp.asarray([[pos]]),
             jnp.asarray([[True]]), cache.k, cache.v, cache.device_tables(),
-            jnp.asarray([pos + 1]), cfg)
+            jnp.asarray([pos + 1]))
         cache.update_pools(k, v)
         cache.seq_lens[0] = pos + 1
-        rows.append(evabyte.lm_head(params, x[0, 0], cfg))
+        rows.append(logits[0, 0])
     want = reference.row_logits(params, tokens, **SHAPE)
     np.testing.assert_allclose(np.asarray(jnp.stack(rows)),
                                np.asarray(want), **TOL)
@@ -249,12 +256,12 @@ def test_bf16_matrices_keep_a_float32_residual_and_float32_logits():
                               param_dtype=jnp.bfloat16)
     spec = evabyte.spec(cfg)
     assert spec.residual_dtype == jnp.float32
-    p = evabyte.init_params(cfg, jax.random.key(0))
+    p = serving_script.init_params(evabyte, cfg)
     assert {x.dtype for x in jax.tree.leaves(p)} == {jnp.dtype(jnp.bfloat16)}
     tokens = jnp.asarray(_tokens(W + 3))[None]
     x, _ = decoder.forward_trunk(evabyte.spec, p, tokens, cfg)
     assert x.dtype == jnp.bfloat16          # normed for the head's product
-    logits = evabyte.forward(p, tokens, cfg)
+    logits = serving_script.forward(evabyte, p, tokens, cfg)
     assert logits.dtype == jnp.float32
     # the bf16 program against the float32 reference on the same weights:
     # bf16's rounding, not a fault (8 mantissa bits on logits of size ~1)

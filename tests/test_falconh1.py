@@ -5,7 +5,6 @@ the loop over positions it is defined by (ops/ssm.py).  Nano size on the
 CPU, float32; the engine and its cache are tests/test_state_cache.py."""
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -16,6 +15,7 @@ from benchmark.reference import falconh1 as ref
 from ray_tpu.inference import PagedKVCache
 from ray_tpu.models import decoder, falconh1
 from ray_tpu.ops import ssm
+from tests import serving_script
 
 NANO = falconh1.CONFIGS["falconh1-nano"]
 # float32 on both sides; what differs is the order of the sums (chunks on
@@ -24,10 +24,8 @@ NANO = falconh1.CONFIGS["falconh1-nano"]
 TOL = 1e-4
 
 
-@functools.lru_cache(maxsize=None)
 def _init(cfg=NANO, seed=0):
-    return jax.jit(falconh1.init_params, static_argnums=0)(
-        cfg, jax.random.key(seed))
+    return serving_script.init_params(falconh1, cfg, seed)
 
 
 def _loop(x, dt, a, bm, cm, s0):
@@ -58,7 +56,7 @@ def _draw(seed, b, t, h=4, p=8, g=2, n=16):
 def test_the_forward_pass_gives_the_references_logits():
     params = _init()
     tokens = jax.random.randint(jax.random.key(1), (2, 45), 0, 512)
-    got = falconh1.forward(params, tokens, NANO)
+    got = serving_script.forward(falconh1, params, tokens, NANO)
     want = ref.logits(params, tokens)
     assert float(jnp.abs(want).max()) > 1.0
     np.testing.assert_allclose(got, want, atol=TOL)
@@ -78,7 +76,7 @@ def test_every_stated_factor_moves_the_logits(factor):
     other = tuple(0.5 * v for v in was) if isinstance(was, tuple) else 1.0
     cfg = dataclasses.replace(NANO, **{factor: other})
     want = ref.logits(params, tokens)
-    moved = falconh1.forward(params, tokens, cfg)
+    moved = serving_script.forward(falconh1, params, tokens, cfg)
     assert float(jnp.abs(moved - want).max()) > 100 * TOL
 
 
@@ -156,44 +154,18 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits():
     not their rows; every position's logits against one forward pass of
     the reference."""
     cfg, params = NANO, _init()
-    served = falconh1.serving_params(params, cfg)
     rng = np.random.default_rng(3)
     seqs = [rng.integers(0, 512, n) for n in (29, 22)]
     cache = PagedKVCache.for_model(falconh1, cfg, num_blocks=(32, 2),
                                    block_size=4, max_lanes=4, max_seq_len=64)
-    lanes = [2, 0]                       # row i is lane lanes[i]
-    for lane, seq in zip(lanes, seqs):
-        cache.alloc_lane(lane, len(seq))
-    tables = jnp.asarray(cache.block_tables[lanes])
-    slots = jnp.asarray(lanes, jnp.int32)
-    pools, got, fed = cache.step_pools[0], [[], []], [0, 0]
-
-    def run(t, counts):
-        nonlocal pools
-        tokens = np.zeros((2, t), np.int32)
-        valid = np.zeros((2, t), bool)
-        for i, n in enumerate(counts):
-            tokens[i, :n] = seqs[i][fed[i]:fed[i] + n]
-            valid[i, :n] = True
-        pos = np.asarray(fed)[:, None] + np.arange(t)
-        x, pools, _ = falconh1.forward_cached(
-            served, jnp.asarray(tokens), jnp.asarray(pos),
-            jnp.asarray(valid), pools, None, tables,
-            jnp.asarray([f + n for f, n in zip(fed, counts)]), cfg,
-            slots=slots)
-        logits = falconh1.lm_head(served, x, cfg)
-        for i, n in enumerate(counts):
-            got[i].extend(np.asarray(logits[i, :n]))
-            fed[i] += n
-
-    run(8, [8, 8])
-    run(8, [8, 8])
-    run(8, [8, 3])                       # lane 0's chunk is padded
-    while fed[0] < len(seqs[0]) or fed[1] < len(seqs[1]):
-        run(1, [int(f < len(s)) for f, s in zip(fed, seqs)])
-    for i, seq in enumerate(seqs):
-        want = ref.row_logits(params, seq)
-        np.testing.assert_allclose(np.stack(got[i]), want, atol=TOL)
+    # row i is lane (2, 0)[i]; chunks of 8, 8 and 8 beside 8, 8 and 3 (lane
+    # 0's last chunk is padded)
+    got, _, _ = serving_script.serve(
+        falconh1, cfg, falconh1.serving_params(params, cfg), cache, seqs, 8,
+        [2, 0], prefill=[24, 19], name_slots=True)
+    for logits, seq in zip(got, seqs):
+        np.testing.assert_allclose(logits, ref.row_logits(params, seq),
+                                   atol=TOL)
 
 
 def test_a_training_step_is_refused():

@@ -23,6 +23,7 @@ from ray_tpu.models import decoder, gpt, llama
 from ray_tpu.ops import paged_attention_reference, paged_decode_attention, \
     paged_kv_update
 from ray_tpu.ops.attention import pack_kv_rows, unpack_kv_rows
+from tests import serving_script
 
 
 # ---------------------------------------------------------------------------
@@ -390,12 +391,13 @@ _CACHED = {"gpt": gpt.CONFIGS["nano"], "llama": llama.CONFIGS["llama-tiny"],
 def test_cached_logits_match_full_forward(family):
     model = llama if family == "llama" else gpt
     config = _CACHED[family]
-    params = model.init_params(config, jax.random.key(1))
+    params = serving_script.init_params(model, config, 1)
     rng = np.random.default_rng(1)
     tokens = rng.integers(0, config.vocab_size, size=21).tolist()
     prefill = 6
 
-    full = model.forward(params, jnp.asarray([tokens], jnp.int32), config)
+    full = serving_script.forward(
+        model, params, jnp.asarray([tokens], jnp.int32), config)
     if isinstance(full, tuple):                 # gpt returns (logits, aux)
         full = full[0]
     full = np.asarray(full[0], np.float32)      # [n, vocab]
@@ -405,25 +407,12 @@ def test_cached_logits_match_full_forward(family):
     cache = PagedKVCache.for_model(
         model, config, num_blocks=-(-n // block_size) + 1,
         block_size=block_size, max_lanes=1, max_seq_len=config.max_seq_len)
-    cache.alloc_lane(0, n)
-
-    got = {}
-
-    def run(chunk, start):
-        t = len(chunk)
-        x, k, v = model.forward_cached(
-            params, jnp.asarray([chunk], jnp.int32),
-            jnp.asarray([np.arange(start, start + t)], jnp.int32),
-            jnp.ones((1, t), bool), cache.k, cache.v,
-            cache.device_tables(), jnp.asarray([start + t], jnp.int32),
-            config)
-        cache.update_pools(k, v)
-        got[start + t - 1] = np.asarray(
-            model.lm_head(params, x[:, -1], config)[0], np.float32)
-
-    run(tokens[:prefill], 0)                    # chunked prefill
-    for i in range(prefill, n):                 # then position > 0 decode
-        run(tokens[i:i + 1], i)
+    # chunked prefill, then position > 0 decode; a chunk's last position
+    (logits,), _, _ = serving_script.serve(
+        model, config, params, cache, [tokens], prefill, [0],
+        prefill=[prefill])
+    got = {pos: logits[pos].astype(np.float32)
+           for pos in range(prefill - 1, n)}
 
     for pos, logits in got.items():
         np.testing.assert_allclose(logits, full[pos], atol=2e-4, rtol=2e-4,
@@ -905,7 +894,7 @@ def test_serving_params_rounds_each_matrix_once_and_keeps_the_rest():
     leaf `forward_cached` casts at its use is held as that cast, `w_down`
     the way round its matmul reads it and each table also as padded rows;
     the layer norms, used in float32, are the very arrays given."""
-    params = gpt.init_params(NANO_BF16, jax.random.key(0))
+    params = serving_script.init_params(gpt, NANO_BF16)
     served = gpt.serving_params(params, NANO_BF16)
     blocks, given = served["blocks"], params["blocks"]
     for name in ("wq", "wk", "wv", "wo", "w_up"):
@@ -949,7 +938,7 @@ def test_serving_params_of_a_tree_held_in_the_activation_dtype_is_the_tree(
         mod, cfg = llama, dataclasses.replace(
             llama.CONFIGS["olmoe-nano"], dtype=jnp.bfloat16,
             param_dtype="bfloat16")
-    params = mod.init_params(cfg, jax.random.key(0))
+    params = serving_script.init_params(mod, cfg)
     served = mod.serving_params(params, cfg)
     given, kept = jax.tree.leaves(params), jax.tree.leaves(served)
     assert len(given) == len(kept)
@@ -970,7 +959,7 @@ def test_a_float32_llama_under_bf16_activations_is_served_like_gpt2xl():
     import dataclasses
     cfg = dataclasses.replace(llama.CONFIGS["llama-tiny"],
                               dtype=jnp.bfloat16)
-    params = llama.init_params(cfg, jax.random.key(0))
+    params = serving_script.init_params(llama, cfg)
     served = llama.serving_params(params, cfg)
     for name in ("wq", "wk", "wv", "wo", "w_gate", "w_up"):
         np.testing.assert_array_equal(
@@ -1002,7 +991,7 @@ def test_engine_on_prepared_weights_emits_forward_cached_on_the_raw_tree(
     else:
         mod, cfg = llama, dataclasses.replace(llama.CONFIGS["llama-tiny"],
                                               dtype=jnp.bfloat16)
-    params = mod.init_params(cfg, jax.random.key(3))
+    params = serving_script.init_params(mod, cfg, 3)
     prompts = [list(range(1, 40)), [7, 9, 11], list(range(100, 150))]
 
     def run(raw):
@@ -1027,8 +1016,8 @@ def test_update_params_prepares_once_and_the_next_step_serves_the_new_tree():
     the lanes go on under the new weights: the tokens a fresh engine on
     those weights emits from the same state."""
     from ray_tpu.util import events
-    old = gpt.init_params(NANO_BF16, jax.random.key(0))
-    new = gpt.init_params(NANO_BF16, jax.random.key(1))
+    old = serving_script.init_params(gpt, NANO_BF16)
+    new = serving_script.init_params(gpt, NANO_BF16, 1)
     prompt = list(range(1, 30))
 
     def prepares():

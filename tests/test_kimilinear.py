@@ -6,13 +6,10 @@ against the plain reference (benchmark/reference/kimilinear.py); the two
 kernels of ops/ssm.py against the loop over positions the recurrence is
 defined by.  Nano size on the CPU, float32; the cache of one latent pool
 beside lane state is tests/test_state_cache.py and tests/test_cache_parts.py,
-the pair's program tests/test_pair_step.py (a process of its own).  The
-file's cached programs are few on purpose: a process that has made some
-thirty of these families' is not a steady one on the CPU backend (PERF.md
-section 7)."""
+the pair's program tests/test_pair_step.py, the cached forward by hand
+tests/serving_script.py."""
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -23,6 +20,7 @@ from benchmark.reference import kimilinear as ref
 from ray_tpu.inference import InferenceEngine, PagedKVCache
 from ray_tpu.models import decoder, kimilinear
 from ray_tpu.ops import ssm
+from tests import serving_script
 
 NANO = kimilinear.CONFIGS["kimilinear-nano"]
 SHARE = kimilinear.CONFIGS["kimilinear-nano-share"]
@@ -31,10 +29,8 @@ SHARE = kimilinear.CONFIGS["kimilinear-nano-share"]
 REL = 2e-5
 
 
-@functools.lru_cache(maxsize=None)
 def _init(cfg=NANO, seed=0):
-    return jax.jit(kimilinear.init_params, static_argnums=0)(
-        cfg, jax.random.key(seed))
+    return serving_script.init_params(kimilinear, cfg, seed)
 
 
 def _close(got, want, rel=REL):
@@ -46,7 +42,7 @@ def _close(got, want, rel=REL):
 def test_the_forward_pass_gives_the_references_logits():
     params = _init()
     tokens = jax.random.randint(jax.random.key(1), (2, 45), 0, 512)
-    _close(kimilinear.forward(params, tokens, NANO),
+    _close(serving_script.forward(kimilinear, params, tokens, NANO),
            ref.logits(params, tokens))
 
 
@@ -164,7 +160,6 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits(chunk):
     layers; a slot nobody writes stays as it was, and a lane that starts at
     position 0 starts from zeros whatever its slot held."""
     cfg, params = NANO, _init()
-    served = kimilinear.serving_params(params, cfg)
     rng = np.random.default_rng(3)
     seqs = [rng.integers(0, 512, n) for n in (14, 9)]
     cache = PagedKVCache.for_model(kimilinear, cfg, num_blocks=(32, 2),
@@ -176,46 +171,15 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits(chunk):
     assert cache.parts[-1].wire == ("state", "tail")
     dirty = [jnp.asarray(rng.standard_normal(x.shape), x.dtype)
              for x in (state, tails)]
-    lanes = [2, 0]                       # row i is lane lanes[i]
-    for lane, seq in zip(lanes, seqs):
-        cache.alloc_lane(lane, len(seq))
-    tables = jnp.asarray(cache.block_tables[lanes])
-    slots = jnp.asarray(lanes, jnp.int32)
-    pools, got, fed = (pool, *dirty), [[], []], [0, 0]
-    load = jnp.zeros((16 + 2,), jnp.int32)
-
-    # (one program a slice's length, not an operation at a time)
-    @jax.jit
-    def step(tokens, pos, valid, pools, ctx_lens, load):
-        x, pools, _, load = kimilinear.forward_cached(
-            served, tokens, pos, valid, pools, None, tables, ctx_lens, cfg,
-            load, slots=slots)
-        return kimilinear.lm_head(served, x, cfg), pools, load
-
-    def run(t, counts):
-        nonlocal pools, load
-        tokens = np.zeros((2, t), np.int32)
-        valid = np.zeros((2, t), bool)
-        for i, n in enumerate(counts):
-            tokens[i, :n] = seqs[i][fed[i]:fed[i] + n]
-            valid[i, :n] = True
-        pos = np.asarray(fed)[:, None] + np.arange(t)
-        logits, pools, load = step(
-            jnp.asarray(tokens), jnp.asarray(pos), jnp.asarray(valid), pools,
-            jnp.asarray([f + n for f, n in zip(fed, counts)]), load)
-        for i, n in enumerate(counts):
-            got[i].extend(np.asarray(logits[i, :n]))
-            fed[i] += n
-
-    # lane 0 prefills 10 of its 14 in chunks, lane 1 joins a chunk later
-    # and prefills 6 of its 9 (so its last chunk of 5 is padded)
-    run(chunk, [chunk, 0])
-    while fed[0] < 10 or fed[1] < 6:
-        run(chunk, [min(chunk, 10 - fed[0]), min(chunk, 6 - fed[1])])
-    while fed[0] < len(seqs[0]) or fed[1] < len(seqs[1]):
-        run(1, [int(f < len(s)) for f, s in zip(fed, seqs)])
-    for i, seq in enumerate(seqs):
-        _close(np.stack(got[i]), ref.row_logits(params, seq))
+    # row i is lane (2, 0)[i]; lane 0 prefills 10 of its 14 in chunks,
+    # lane 1 joins a chunk later and prefills 6 of its 9 (so its last chunk
+    # of 5 is padded)
+    got, (pools, _), load = serving_script.serve(
+        kimilinear, cfg, kimilinear.serving_params(params, cfg), cache, seqs,
+        chunk, [2, 0], prefill=[10, 6], late=[0, 1], name_slots=True,
+        pools=((pool, *dirty), None), load=jnp.zeros((16 + 2,), jnp.int32))
+    for logits, seq in zip(got, seqs):
+        _close(logits, ref.row_logits(params, seq))
     for left, was in zip(pools[1:], dirty):
         for slot in (1, 3, 4):           # nobody's: as they were
             np.testing.assert_array_equal(np.asarray(left)[:, slot],
@@ -314,7 +278,7 @@ def test_the_share_of_the_nano_model_is_the_references_share():
     params = _init(SHARE)
     assert params["kdas"]["w_up"].shape == (3, 4, 64, 24)
     tokens = jax.random.randint(jax.random.key(4), (1, 30), 0, 512)
-    _close(kimilinear.forward(params, tokens, SHARE)[0],
+    _close(serving_script.forward(kimilinear, params, tokens, SHARE)[0],
            ref.row_logits(params, tokens[0], experts_offset=4))
 
 
@@ -329,7 +293,7 @@ def test_what_the_family_states_moves_the_logits(what, over):
     first test) and each left out of the reference parts the two."""
     params = _init()
     tokens = jax.random.randint(jax.random.key(2), (1, 24), 0, 512)
-    got = kimilinear.forward(params, tokens, NANO)[0]
+    got = serving_script.forward(kimilinear, params, tokens, NANO)[0]
     moved = ref.row_logits(params, tokens[0], **over)
     assert float(jnp.abs(moved - got).max()) > 100 * REL * float(
         jnp.abs(got).max())

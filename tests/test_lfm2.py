@@ -3,13 +3,10 @@
 attention with a norm a head, over a dense SwiGLU or sigmoid-routed experts,
 against the plain reference (benchmark/reference/lfm2.py).  Nano size on the
 CPU, float32; the engine and its cache of one state buffer are
-tests/test_state_cache.py, the pair's program tests/test_pair_step.py (a
-process of its own).  The file's cached programs are few on purpose: a
-process that has made some thirty of these families' is not a steady one on
-the CPU backend (PERF.md section 7)."""
+tests/test_state_cache.py, the pair's program tests/test_pair_step.py, the
+cached forward by hand tests/serving_script.py."""
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +16,7 @@ import pytest
 from benchmark.reference import lfm2 as ref
 from ray_tpu.inference import InferenceEngine, PagedKVCache
 from ray_tpu.models import decoder, lfm2
+from tests import serving_script
 
 NANO = lfm2.CONFIGS["lfm2-nano"]
 # float32 on both sides, sums in another order: 2e-5 of the largest logit,
@@ -26,10 +24,8 @@ NANO = lfm2.CONFIGS["lfm2-nano"]
 REL = 2e-5
 
 
-@functools.lru_cache(maxsize=None)
 def _init(seed=0):
-    return jax.jit(lfm2.init_params, static_argnums=0)(
-        NANO, jax.random.key(seed))
+    return serving_script.init_params(lfm2, NANO, seed)
 
 
 def _close(got, want):
@@ -41,7 +37,8 @@ def _close(got, want):
 def test_the_forward_pass_gives_the_references_logits():
     params = _init()
     tokens = jax.random.randint(jax.random.key(1), (2, 45), 0, 512)
-    _close(lfm2.forward(params, tokens, NANO), ref.logits(params, tokens))
+    _close(serving_script.forward(lfm2, params, tokens, NANO),
+           ref.logits(params, tokens))
 
 
 @pytest.mark.parametrize("chunk", [1, 2, 5])
@@ -56,7 +53,6 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits(chunk):
     and a lane that starts at position 0 starts from zeros whatever its
     slot held."""
     cfg, params = NANO, _init()
-    served = lfm2.serving_params(params, cfg)
     rng = np.random.default_rng(3)
     seqs = [rng.integers(0, 512, n) for n in (14, 9)]
     cache = PagedKVCache.for_model(lfm2, cfg, num_blocks=(32, 2),
@@ -67,41 +63,15 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits(chunk):
     # every slot starts as garbage: the lanes' must not read it, the others
     # must keep it
     dirty = jnp.asarray(rng.standard_normal(tails.shape), tails.dtype)
-    lanes = [2, 0]                       # row i is lane lanes[i]
-    for lane, seq in zip(lanes, seqs):
-        cache.alloc_lane(lane, len(seq))
-    tables = jnp.asarray(cache.block_tables[lanes])
-    slots = jnp.asarray(lanes, jnp.int32)
-    pools, got, fed = (k, v, dirty), [[], []], [0, 0]
-    load = jnp.zeros((16 + 2,), jnp.int32)
-
-    def run(t, counts):
-        nonlocal pools, load
-        tokens = np.zeros((2, t), np.int32)
-        valid = np.zeros((2, t), bool)
-        for i, n in enumerate(counts):
-            tokens[i, :n] = seqs[i][fed[i]:fed[i] + n]
-            valid[i, :n] = True
-        pos = np.asarray(fed)[:, None] + np.arange(t)
-        x, pools, _, load = lfm2.forward_cached(
-            served, jnp.asarray(tokens), jnp.asarray(pos),
-            jnp.asarray(valid), pools, None, tables,
-            jnp.asarray([f + n for f, n in zip(fed, counts)]), cfg,
-            load, slots=slots)
-        logits = lfm2.lm_head(served, x, cfg)
-        for i, n in enumerate(counts):
-            got[i].extend(np.asarray(logits[i, :n]))
-            fed[i] += n
-
-    # lane 0 prefills 10 of its 14 in chunks, lane 1 joins a chunk later
-    # and prefills 6 of its 9 (so its last chunk of 5 is padded)
-    run(chunk, [chunk, 0])
-    while fed[0] < 10 or fed[1] < 6:
-        run(chunk, [min(chunk, 10 - fed[0]), min(chunk, 6 - fed[1])])
-    while fed[0] < len(seqs[0]) or fed[1] < len(seqs[1]):
-        run(1, [int(f < len(s)) for f, s in zip(fed, seqs)])
-    for i, seq in enumerate(seqs):
-        _close(np.stack(got[i]), ref.row_logits(params, seq))
+    # row i is lane (2, 0)[i]; lane 0 prefills 10 of its 14 in chunks,
+    # lane 1 joins a chunk later and prefills 6 of its 9 (so its last chunk
+    # of 5 is padded)
+    got, (pools, _), load = serving_script.serve(
+        lfm2, cfg, lfm2.serving_params(params, cfg), cache, seqs, chunk,
+        [2, 0], prefill=[10, 6], late=[0, 1], name_slots=True,
+        pools=((k, v, dirty), None), load=jnp.zeros((16 + 2,), jnp.int32))
+    for logits, seq in zip(got, seqs):
+        _close(logits, ref.row_logits(params, seq))
     left = np.asarray(pools[2])
     for slot in (1, 3, 4):               # nobody's: as they were
         np.testing.assert_array_equal(left[:, slot], np.asarray(dirty)[:, slot])
@@ -263,7 +233,7 @@ def test_what_the_family_states_moves_the_logits(what):
     else:
         cfg = dataclasses.replace(NANO, **{
             what: 1e4 if what == "rope_theta" else False})
-    moved = lfm2.forward(params, tokens, cfg)
+    moved = serving_script.forward(lfm2, params, tokens, cfg)
     assert float(jnp.abs(moved - want).max()) > 100 * REL
 
 

@@ -23,6 +23,7 @@ from ray_tpu.inference import InferenceEngine
 from ray_tpu.inference.kv_cache import PagedKVCache
 from ray_tpu.models import llama
 from ray_tpu.ops import moe
+from tests import serving_script
 
 CFG = llama.CONFIGS["olmoe-nano"]     # 2 layers, 64 wide, 16 experts, top-2
 TOL = 1e-4
@@ -40,14 +41,14 @@ def _tokens(shape, seed=1):
 def test_forward_matches_the_reference(qk_norm, norm_topk_prob):
     cfg = dataclasses.replace(CFG, qk_norm=qk_norm,
                               norm_topk_prob=norm_topk_prob)
-    params = llama.init_params(cfg, jax.random.key(0))
+    params = serving_script.init_params(llama, cfg)
     assert ("q_norm" in params["blocks"]) == qk_norm
     # the norms' scales are ones at init: make them count
     params["blocks"] = {
         k: v * (1.0 + 0.1 * jax.random.normal(jax.random.key(7), v.shape))
         if k.endswith("_norm") else v for k, v in params["blocks"].items()}
     tokens = _tokens((3, 40))
-    got = llama.forward(params, tokens, cfg)
+    got = serving_script.forward(llama, params, tokens, cfg)
     want = ref.logits(params, tokens, top_k=cfg.n_experts_per_tok,
                       norm_topk_prob=norm_topk_prob)
     assert float(jnp.max(jnp.abs(want))) > 1.0
@@ -69,7 +70,7 @@ def test_chunked_prefill_then_decode_through_the_paged_cache_matches(
     trip holds (one layer, all, two and a remainder of one)."""
     cfg = dataclasses.replace(CFG, n_layers=n_layers,
                               scan_unroll=scan_unroll)
-    params = llama.init_params(cfg, jax.random.key(1))
+    params = serving_script.init_params(llama, cfg, 1)
     rows = np.asarray(_tokens((3, 30), seed=2))
     prefill = [5, 8, 3]                   # each lane's first chunk
     want = np.asarray(ref.logits(params, rows))
@@ -77,37 +78,15 @@ def test_chunked_prefill_then_decode_through_the_paged_cache_matches(
     cache = PagedKVCache.for_model(llama, cfg, num_blocks=lanes * 4 + 1,
                                    block_size=bs, max_lanes=lanes,
                                    max_seq_len=32)
-    for lane in range(lanes):
-        cache.alloc_lane(lane, 30)
-    load = jnp.zeros((cfg.n_experts + 2,), jnp.int32)
-    depth = [0, 0, 0]
-
-    def run(chunks):
-        nonlocal load
-        t = max(len(c) for c in chunks)
-        tokens = np.zeros((lanes, t), np.int32)
-        valid = np.zeros((lanes, t), bool)
-        for lane, c in enumerate(chunks):
-            tokens[lane, :len(c)] = c
-            valid[lane, :len(c)] = True
-        positions = np.asarray(depth)[:, None] + np.arange(t)[None]
-        ctx = np.asarray([d + len(c) for d, c in zip(depth, chunks)])
-        x, k, v, load = llama.forward_cached(
-            params, jnp.asarray(tokens), jnp.asarray(positions, jnp.int32),
-            jnp.asarray(valid), cache.k, cache.v, cache.device_tables(),
-            jnp.asarray(ctx, jnp.int32), cfg, load)
-        cache.update_pools(k, v)
-        for lane, c in enumerate(chunks):
-            depth[lane] += len(c)
-            got = llama.lm_head(params, x[lane, len(c) - 1], cfg)
-            np.testing.assert_allclose(
-                got, want[lane, depth[lane] - 1], atol=TOL,
-                err_msg=f"lane {lane} position {depth[lane] - 1}")
-
-    run([rows[lane, :n].tolist() for lane, n in enumerate(prefill)])
-    for _ in range(6):                    # T=1, every lane at its own depth
-        run([[int(rows[lane, depth[lane]])] for lane in range(lanes)])
-    tokens_run = sum(depth)
+    # one slice of 8 rows, then 6 of T=1, every lane at its own depth
+    seqs = [rows[lane, :n + 6] for lane, n in enumerate(prefill)]
+    got, _, load = serving_script.serve(
+        llama, cfg, params, cache, seqs, max(prefill), [0, 1, 2],
+        prefill=prefill, load=jnp.zeros((cfg.n_experts + 2,), jnp.int32))
+    for lane, logits in enumerate(got):
+        np.testing.assert_allclose(logits, want[lane, :len(logits)],
+                                   atol=TOL, err_msg=f"lane {lane}")
+    tokens_run = sum(len(seq) for seq in seqs)
     load = np.asarray(load)
     assert load[:-2].sum() == tokens_run * cfg.n_experts_per_tok \
         * cfg.n_layers                    # padding positions reach no expert
@@ -161,13 +140,13 @@ def test_no_token_is_dropped_when_one_expert_takes_every_token():
     expert 5 nobody's (a feature every token's embedding shares, and two
     router columns that read it): a capacity-bound dispatch would drop most
     of 3's tokens; the dropless one agrees with the reference on all."""
-    params = llama.init_params(CFG, jax.random.key(3))
+    params = serving_script.init_params(llama, CFG, 3)
     params["tok_embed"] = params["tok_embed"].at[:, 0].set(4.0)
     params["blocks"]["router"] = params["blocks"]["router"].at[
         :, 0, 3].set(8.0).at[:, 0, 5].set(-8.0)
     tokens = _tokens((2, 24), seed=4)
     np.testing.assert_allclose(
-        llama.forward(params, tokens, CFG),
+        serving_script.forward(llama, params, tokens, CFG),
         ref.logits(params, tokens, top_k=CFG.n_experts_per_tok), atol=TOL)
     eng = InferenceEngine("llama", CFG, params=params, max_lanes=2,
                           block_size=8, prefill_chunk=8, auto_start=False)

@@ -8,7 +8,6 @@ no multiple of 128 (ops/moe.py).  Nano size on the CPU, float32; the engine
 and its cache are tests/test_state_cache.py."""
 
 import dataclasses
-import functools
 
 import jax
 import jax.numpy as jnp
@@ -19,6 +18,7 @@ from benchmark.reference import nemotronh as ref
 from ray_tpu.inference import PagedKVCache
 from ray_tpu.models import decoder, nemotronh
 from ray_tpu.ops import moe, ssm
+from tests import serving_script
 from tests.test_falconh1 import _draw, _loop
 
 NANO = nemotronh.CONFIGS["nemotronh-nano"]
@@ -26,16 +26,14 @@ SHARE = nemotronh.CONFIGS["nemotronh-nano-share"]
 TOL = 1e-4      # float32 on both sides: the order of the sums (test_falconh1)
 
 
-@functools.lru_cache(maxsize=None)
 def _init(cfg=NANO, seed=0):
-    return jax.jit(nemotronh.init_params, static_argnums=0)(
-        cfg, jax.random.key(seed))
+    return serving_script.init_params(nemotronh, cfg, seed)
 
 
 def test_the_forward_pass_gives_the_references_logits():
     params = _init()
     tokens = jax.random.randint(jax.random.key(1), (2, 45), 0, 512)
-    got = nemotronh.forward(params, tokens, NANO)
+    got = serving_script.forward(nemotronh, params, tokens, NANO)
     want = ref.logits(params, tokens)
     assert float(jnp.abs(want).max()) > 1.0
     np.testing.assert_allclose(got, want, atol=TOL)
@@ -79,7 +77,7 @@ def test_the_share_of_the_nano_model_is_the_references_share():
     assert params["experts"]["w_up_t"].shape == (3, 8, 24, 64)
     tokens = jax.random.randint(jax.random.key(4), (1, 30), 0, 512)
     np.testing.assert_allclose(
-        nemotronh.forward(params, tokens, SHARE),
+        serving_script.forward(nemotronh, params, tokens, SHARE),
         ref.logits(params, tokens, experts_offset=8), atol=TOL)
 
 
@@ -96,17 +94,17 @@ def test_what_the_family_states_moves_the_logits(what):
     if what == "positions":
         fam = decoder.bind(lambda c: dataclasses.replace(
             nemotronh.spec(c), rope_theta=10000.0))
-        moved = fam.forward(params, tokens, cfg)[0]
+        moved = fam.forward(params, tokens, cfg)[0]      # (a family of its own)
     elif what == "bias":
         bumped = {**params, "experts": {
             **params["experts"],
             "router_bias": params["experts"]["router_bias"].at[:, :8].add(
                 1.0)}}
-        moved = nemotronh.forward(bumped, tokens, cfg)
+        moved = serving_script.forward(nemotronh, bumped, tokens, cfg)
     else:
         cfg = dataclasses.replace(NANO, **{
             what: 1.0 if what == "routed_scale" else False})
-        moved = nemotronh.forward(params, tokens, cfg)
+        moved = serving_script.forward(nemotronh, params, tokens, cfg)
     assert float(jnp.abs(moved - want).max()) > 100 * TOL
 
 
@@ -244,47 +242,21 @@ def test_prefill_in_chunks_then_decode_gives_the_references_logits():
     the reference.  The K/V pools have the ONE attention layer, the state
     buffers the THREE mixer layers."""
     cfg, params = NANO, _init()
-    served = nemotronh.serving_params(params, cfg)
     rng = np.random.default_rng(3)
     seqs = [rng.integers(0, 512, n) for n in (29, 22)]
     cache = PagedKVCache.for_model(nemotronh, cfg, num_blocks=(32, 2),
                                    block_size=4, max_lanes=4, max_seq_len=64)
     assert [p.shape[0] for p in cache.step_pools[0]] == [1, 1, 3, 3]
     assert cache.step_pools[0][2].shape == (3, 5, 2, 16, 128)
-    lanes = [2, 0]                       # row i is lane lanes[i]
-    for lane, seq in zip(lanes, seqs):
-        cache.alloc_lane(lane, len(seq))
-    tables = jnp.asarray(cache.block_tables[lanes])
-    slots = jnp.asarray(lanes, jnp.int32)
-    pools, got, fed = cache.step_pools[0], [[], []], [0, 0]
-    load = jnp.zeros((16 + 2,), jnp.int32)
-
-    def run(t, counts):
-        nonlocal pools, load
-        tokens = np.zeros((2, t), np.int32)
-        valid = np.zeros((2, t), bool)
-        for i, n in enumerate(counts):
-            tokens[i, :n] = seqs[i][fed[i]:fed[i] + n]
-            valid[i, :n] = True
-        pos = np.asarray(fed)[:, None] + np.arange(t)
-        x, pools, _, load = nemotronh.forward_cached(
-            served, jnp.asarray(tokens), jnp.asarray(pos),
-            jnp.asarray(valid), pools, None, tables,
-            jnp.asarray([f + n for f, n in zip(fed, counts)]), cfg,
-            load, slots=slots)
-        logits = nemotronh.lm_head(served, x, cfg)
-        for i, n in enumerate(counts):
-            got[i].extend(np.asarray(logits[i, :n]))
-            fed[i] += n
-
-    run(8, [8, 8])
-    run(8, [8, 8])
-    run(8, [8, 3])                       # lane 0's chunk is padded
-    while fed[0] < len(seqs[0]) or fed[1] < len(seqs[1]):
-        run(1, [int(f < len(s)) for f, s in zip(fed, seqs)])
-    for i, seq in enumerate(seqs):
-        want = ref.row_logits(params, seq)
-        np.testing.assert_allclose(np.stack(got[i]), want, atol=TOL)
+    # row i is lane (2, 0)[i]; chunks of 8, 8 and 8 beside 8, 8 and 3 (lane
+    # 0's last chunk is padded)
+    got, _, load = serving_script.serve(
+        nemotronh, cfg, nemotronh.serving_params(params, cfg), cache, seqs,
+        8, [2, 0], prefill=[24, 19], name_slots=True,
+        load=jnp.zeros((16 + 2,), jnp.int32))
+    for logits, seq in zip(got, seqs):
+        np.testing.assert_allclose(logits, ref.row_logits(params, seq),
+                                   atol=TOL)
     # every valid token's 4 assignments in each of the 3 expert layers
     assert int(load[:16].sum()) == 4 * 3 * (29 + 22)
 

@@ -9,9 +9,6 @@ to pair keeps): the pools, the state, every lane's last token on the
 device, the served tokens and their log-probs."""
 
 import importlib
-import os
-import subprocess
-import sys
 
 import jax
 import numpy as np
@@ -110,25 +107,9 @@ def _left(eng):
 
 @pytest.mark.parametrize("case", list(CASES))
 def test_the_pair_leaves_what_the_two_programs_leave(case):
-    """(In a process of its own: two engines' programs of every family,
-    eight times over, are more than a test worker should be left holding;
-    a process that has made some thirty programs of these families is not
-    a steady one on the CPU backend, PERF.md section 7.)"""
-    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    out = subprocess.run(
-        [sys.executable, __file__, case], capture_output=True, text=True,
-        timeout=600, cwd=root,
-        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": root})
-    assert out.returncode == 0 and out.stdout.strip().endswith(
-        f"{case} ok"), (out.stdout[-1000:], out.stderr[-3000:])
-
-
-def _the_pair_leaves_what_the_two_programs_leave(case):
     *_, first, beside = CASES[case]
     one, two = _engine(case, True), _engine(case, False)
-    # (a sampled request beside greedy ones in two kinds: a process that has
-    # made some thirty programs of these families is not a steady one on the
-    # CPU backend, PERF.md section 7)
+    # (a sampled request beside greedy ones in two kinds)
     sampled = case in SAMPLED
     _make_programs(one, sampled), _make_programs(two, sampled)
     vocab = one.config.vocab_size
@@ -239,7 +220,3 @@ def test_a_drafting_engine_keeps_its_two_programs():
     assert all(name.endswith("_lanes2") or "_spec" in name or name == "t1"
                for name in eng.compiled_steps())
 
-
-if __name__ == "__main__":
-    _the_pair_leaves_what_the_two_programs_leave(sys.argv[1])
-    print(sys.argv[1], "ok")
