@@ -137,6 +137,28 @@ def kernels_leg() -> dict:
     errs["logits_lse_logits"] = check("logits_lse logits", logits, want)
     errs["logits_lse_lse"] = check(
         "logits_lse lse", lse, jax.scipy.special.logsumexp(want, axis=-1))
+    # and the kernel that makes dx and dhead of those logits, against the
+    # two XLA products it replaces; the running dhead is not zero
+    targets = jax.random.randint(jax.random.fold_in(key, 6), (2048,), 0,
+                                 50304)
+    scale = jnp.full((2048,), 1.0 / 2048, jnp.float32).at[:9].set(0.0)
+    dhead = 1e-3 * jax.random.normal(jax.random.fold_in(key, 7),
+                                     (50304, 768), jnp.float32)
+
+    def products(logits, lse, targets, scale, x, w, dhead):
+        p = ((jnp.exp(logits - lse[:, None]) - jax.nn.one_hot(
+            targets, w.shape[0])) * scale[:, None]).astype(x.dtype)
+        return jax.lax.dot(p, w), dhead + jax.lax.dot(
+            p.T, x, preferred_element_type=jnp.float32)
+
+    args = (logits, lse, targets, scale, x, w, dhead)
+    for name, got, want in zip(
+            ("dx", "dhead"), compiled(C.loss_head_grads, *args)(*args),
+            jax.jit(products)(*args)):
+        # gradients of a mean over 2,048 rows: held to a relative error
+        top = float(jnp.abs(want).max())
+        errs[f"loss_head_grads_{name}"] = check(
+            f"loss_head_grads {name}", got / top, want / top) * top
 
     lanes, bs, nb, mb = 32, 16, 2048, 64
     rng = np.random.default_rng(0)

@@ -14,15 +14,23 @@ time instead of [T, V]:
   chunk's 1.236 GB summed the exponentials);
 - the target's logit is a row dot with the head's gathered columns, not a
   pass over [chunk, V];
-- the custom VJP computes each chunk's logits once: its forward rule forms
-  `softmax - onehot` in the prologues of the dx and dhead products while
-  the chunk's logits are there and keeps dx and dhead, which the backward
-  rule scales by the cotangent.  A loss without a gradient runs the kernel
-  and the row dot alone.
+- the custom VJP computes each chunk's logits once: its forward rule makes
+  dx and dhead while the chunk's logits are there and keeps them, and the
+  backward rule scales them by the cotangent.  A loss without a gradient
+  runs the `logits_lse` kernel and the row dot alone;
+- dx and dhead of a chunk come from ONE Mosaic kernel, `loss_head_grads`:
+  it reads a tile of the float32 logits once, forms `(softmax - onehot) *
+  valid / denom` in float32 and casts it once, and feeds both `dx += p @ W`
+  and `dhead += p^T @ x`; dhead is summed over the chunks where it lies and
+  comes out `[V, D]`, the tied embedding's own order (PERF.md section 6,
+  PR 60; until then two XLA products each read the chunk's 1.236 GB of
+  logits and each formed `softmax - onehot` in its own prologue, and the
+  `[D, V]` sum was turned round for the lookup's scatter-add).
 
-The kernel takes the call by the operands' shapes (`_lse_plan`); any other
-shape takes XLA's product and `logsumexp`, the form this file had before
-the kernel and the tests' reference for it.
+Each kernel takes the call by the operands' shapes (`_lse_plan`,
+`_grads_plan`); any other shape takes XLA's product and `logsumexp`, and
+XLA's two gradient products: the forms this file had before the kernels
+and the tests' references for them.
 """
 
 from __future__ import annotations
@@ -34,7 +42,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ray_tpu.ops.attention import NEG_INF, _interpret_kernels
+from ray_tpu.ops.attention import NEG_INF, _TN, _dot, _interpret_kernels
 
 _LANES = 128
 # `logits_lse`'s tiles.  A row tile's `x` stays in VMEM while the head
@@ -157,6 +165,142 @@ def logits_lse(x, w):
     return logits, lse[:, 0]
 
 
+# `loss_head_grads`' tiles.  The vocabulary is the grid's outer axis and the
+# rows its inner one: a vocabulary tile's dhead stays in VMEM while the
+# chunk's rows pass under it, and the whole chunk's x and float32 dx stay
+# there over the call.  Every grid step costs its branches and the rows'
+# three columns again, so the row tile is the whole chunk where that fits
+# (a tile of 1,024 rows of GPT-2's 6,144 was 3.5% slower); `_GRAD_SUB_ROWS`
+# rows at a time are formed and go through the matrix units.  PERF.md
+# section 6, PR 60 has the sweep.
+_GRAD_ROW_TILES = (6144, 3072, 2048, 1024, 512, 256, 128)
+_GRAD_COL_TILE = 384
+_GRAD_SUB_ROWS = 512
+_GRAD_VMEM_MOST = 96 * 1024 * 1024    # of a v5e core's 128 MiB
+
+
+def _grads_vmem(c: int, tm: int, tn: int, d: int, size: int) -> int:
+    """VMEM bytes of `loss_head_grads`: the chunk's x and dx (one buffer
+    each) and dx's float32 sum; the pipeline's two buffers of a logits
+    tile, of the head's tile and of dhead's tile in and out; the rows'
+    three columns (a lane tile wide each, one buffer where the row tile is
+    the chunk); and room for a sub-tile's values."""
+    d = -(-d // _LANES) * _LANES          # as VMEM holds a row of it
+    whole = c * d * (2 * size + 4)
+    columns = 3 * tm * _LANES * 4 * (1 if tm == c else 2)
+    blocks = tm * tn * 4 + tn * d * (size + 8)
+    return (whole + columns + 2 * blocks
+            + 4 * min(_GRAD_SUB_ROWS, tm) * max(tn, d) * 4)
+
+
+def _grads_plan(rows: int, d: int, v: int, size: int = 2):
+    """(row tile, vocabulary tile) of `loss_head_grads` for a chunk of
+    `rows` rows of width `d` (`size` bytes a number), or None where the
+    shapes do not fit the kernel: the vocabulary a multiple of 128 lanes,
+    the rows a multiple of a row tile, and the chunk's x and dx and the
+    tiles within `_GRAD_VMEM_MOST`."""
+    if v % _LANES:
+        return None
+    tn = min(_GRAD_COL_TILE, v)
+    return next(((tm, tn) for tm in _GRAD_ROW_TILES if rows % tm == 0
+                 and _grads_vmem(rows, tm, tn, d, size) <= _GRAD_VMEM_MOST),
+                None)
+
+
+def _loss_head_grads_kernel(z_ref, lse_ref, tgt_ref, scale_ref, x_ref, w_ref,
+                            sum_ref, dx_ref, dw_ref, dx_acc, *, v: int,
+                            sub: int):
+    """One (vocabulary tile, row tile) grid step: `softmax - onehot` of the
+    tile's logits, formed once in float32 and cast once, goes into both
+    products.  `dx_acc` [C, D] gathers dx over the vocabulary's tiles and
+    is cast into `dx_ref` with the last; `dw_ref` starts as the running
+    dhead's tile (`sum_ref`, the same HBM) and gathers the row tiles'."""
+    j, i = pl.program_id(0), pl.program_id(1)
+    last = pl.num_programs(0) - 1
+    tm, tn = z_ref.shape
+    tile = pl.ds(pl.multiple_of(i * tm, tm), tm)
+
+    @pl.when(j == 0)
+    def _():
+        dx_acc[tile, :] = jnp.zeros((tm, dx_acc.shape[1]), jnp.float32)
+
+    @pl.when(i == 0)
+    def _():
+        dw_ref[...] = sum_ref[...]
+
+    def walk(ragged: bool):
+        w = w_ref[...]
+        if ragged:      # the head's rows past V: out of dx
+            row = j * tn + jax.lax.broadcasted_iota(jnp.int32, w.shape, 0)
+            w = jnp.where(row < v, w, jnp.zeros_like(w))
+        for r in range(tm // sub):
+            rows = pl.ds(r * sub, sub)
+            of_chunk = pl.ds(pl.multiple_of(i * tm + r * sub, sub), sub)
+            z = z_ref[rows, :]
+            col = j * tn + jax.lax.broadcasted_iota(jnp.int32, z.shape, 1)
+            # the same arithmetic in the same order as XLA's form below
+            p = ((jnp.exp(z - lse_ref[rows, :])
+                  - jnp.where(col == tgt_ref[rows, :], 1.0, 0.0))
+                 * scale_ref[rows, :])
+            if ragged:  # columns past V: out of both products
+                p = jnp.where(col < v, p, 0.0)
+            p = p.astype(x_ref.dtype)                          # [sub, tn]
+            dx_acc[of_chunk, :] += _dot(p, w)                  # [sub, D]
+            dw_ref[...] += _dot(p, x_ref[of_chunk, :], _TN)    # [tn, D]
+
+    if v % tn:
+        pl.when(j == last)(lambda: walk(True))
+        pl.when(j != last)(lambda: walk(False))
+    else:
+        walk(False)
+
+    @pl.when(j == last)
+    def _():
+        dx_ref[tile, :] = dx_acc[tile, :].astype(dx_ref.dtype)
+
+
+def loss_head_grads(logits, lse, targets, scale, x, w, dhead):
+    """(dx [C, D] in x's dtype, `dhead` + the chunk's part of it, [V, D]
+    float32) from one Mosaic kernel: a tile of the chunk's float32 `logits`
+    [C, V] is read once, `(exp(logits - lse) - onehot(targets)) * scale` is
+    formed in float32 and cast to x's dtype once, and feeds both
+    `dx += p @ w` and `dhead += p.T @ x`, each summed in float32.  `w` is
+    the head as the tied embedding lies, [V, D], and so is `dhead`, which
+    the call updates where it is.  The shapes are ones `_grads_plan`
+    takes."""
+    (c, d), v = x.shape, w.shape[0]
+    size = jnp.dtype(x.dtype).itemsize
+    tm, tn = _grads_plan(c, d, v, size)
+    once = dict(pipeline_mode=pl.Buffered(1))   # fetched once: one buffer
+    whole = pl.BlockSpec((c, d), lambda j, i: (0, 0), **once)
+    column = pl.BlockSpec((tm, 1), lambda j, i: (i, 0),
+                          **(once if tm == c else {}))
+    of_head = pl.BlockSpec((tn, d), lambda j, i: (j, 0))
+    return pl.pallas_call(
+        functools.partial(_loss_head_grads_kernel, v=v,
+                          sub=min(_GRAD_SUB_ROWS, tm)),
+        name="loss_head_grads",
+        grid=(pl.cdiv(v, tn), c // tm),
+        in_specs=[pl.BlockSpec((tm, tn), lambda j, i: (i, j)),
+                  column, column, column, whole, of_head, of_head],
+        out_specs=[whole, of_head],
+        out_shape=[jax.ShapeDtypeStruct((c, d), x.dtype),
+                   jax.ShapeDtypeStruct((v, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((c, d), jnp.float32)],
+        input_output_aliases={6: 1},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=max(_grads_vmem(c, tm, tn, d, size),
+                                 16 * 1024 * 1024)),
+        cost_estimate=pl.CostEstimate(
+            flops=4 * c * d * v, transcendentals=c * v,
+            bytes_accessed=(4 * c * v + 8 * d * v
+                            + size * (2 * c * d + d * v))),
+        interpret=_interpret_kernels(),
+    )(logits, lse.reshape(c, 1), targets.reshape(c, 1), scale.reshape(c, 1),
+      x, w, dhead)
+
+
 def _chunk_head(x_c, head, t_c):
     """A chunk's float32 logits [C, V], their logsumexp [C] and the
     targets' logits [C]."""
@@ -191,12 +335,16 @@ def _chunk(arr, n_chunks):
 def _ce_chunks(x, head, targets, valid, n_chunks, with_grads: bool):
     """(loss, dx, dhead): the chunks' walk.  With `with_grads` each chunk's
     logits, while they are there, also give their part of the gradients of
-    the LOSS (cotangent 1): dx [T, D] in x's dtype, dhead [D, V] float32."""
+    the LOSS (cotangent 1): dx [T, D] in x's dtype, dhead [D, V] float32,
+    from `loss_head_grads` where it takes the chunk and else from two XLA
+    products."""
     (t, d), v = x.shape, head.shape[1]
     if t % n_chunks:
         n_chunks = 1
     denom = jnp.maximum(jnp.sum(valid), 1.0)
     targets = targets.astype(jnp.int32)
+    kernel = with_grads and _grads_plan(
+        t // n_chunks, d, v, x.dtype.itemsize) is not None
 
     def body(carry, inp):
         total, dhead = carry
@@ -205,6 +353,11 @@ def _ce_chunks(x, head, targets, valid, n_chunks, with_grads: bool):
         total = total + jnp.sum((lse - tgt) * v_c)
         if not with_grads:
             return (total, dhead), None
+        if kernel:      # dhead as the embedding lies, [V, D]
+            dx_c, dhead = loss_head_grads(
+                logits, lse, t_c, v_c / denom, x_c, head.T.astype(x.dtype),
+                dhead)
+            return (total, dhead), dx_c
         # dlogits = (softmax - onehot(t)) * valid / denom as ONE fused
         # elementwise chain in each product's prologue: exp, scale, and an
         # iota-mask subtraction (a scatter here would serialize on TPU).
@@ -217,12 +370,19 @@ def _ce_chunks(x, head, targets, valid, n_chunks, with_grads: bool):
                                     preferred_element_type=jnp.float32)
         return (total, dhead), dx_c
 
-    dhead0 = jnp.zeros((d, v) if with_grads else (), jnp.float32)
+    dhead0 = jnp.zeros(((v, d) if kernel else (d, v)) if with_grads else (),
+                       jnp.float32)
+    # With the kernels the chunks are a loop in the compiled step too: laid
+    # out in a row, XLA held the chunks' logits side by side (3.6 GB more at
+    # GPT-2 small's shapes) and, with no product of its own left in the
+    # head, gave VMEM to other values of the whole step (PERF.md section 6,
+    # PR 60).  XLA's own products stay in a row, which it fuses across.
     (total, dhead), dxs = jax.lax.scan(
         body, (jnp.zeros((), jnp.float32), dhead0),
         (_chunk(x, n_chunks), _chunk(targets, n_chunks),
-         _chunk(valid, n_chunks)), unroll=True)
-    return total / denom, dxs.reshape(t, d) if with_grads else None, dhead
+         _chunk(valid, n_chunks)), unroll=not kernel)
+    return (total / denom, dxs.reshape(t, d) if with_grads else None,
+            dhead.T if kernel else dhead)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))

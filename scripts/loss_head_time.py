@@ -3,34 +3,44 @@
 `fused_cross_entropy` over `[24576, 768]` rows (bf16) and the tied
 embedding `[50304, 768]` (float32, turned round and cast as
 `models/decoder.py::_head` does), four chunks of 6,144 rows, its value and
-both gradients, as a train step runs it.  Two forms from the one tree: the
-`logits_lse` kernel a chunk (`ops/cross_entropy.py::_lse_plan` takes the
-shape), and XLA's product with a pass of its own over the float32 logits for
-the logsumexp (the fallback of every other shape, forced here).  A few calls
-under the profiler; the figures are the device's own durations, in ms a
-CHUNK, of
+both gradients, as a train step runs it.  Three forms from the one tree:
+the program's own (`kernel`: a `logits_lse` and a `loss_head_grads` call a
+chunk, `ops/cross_entropy.py`'s plans take the shape), `products` (the
+`logits_lse` kernel and XLA's two gradient products: what every shape the
+gradients' plan refuses runs, forced here), and `xla` (no kernel: XLA's
+product with a pass of its own over the float32 logits for the logsumexp).
+A few calls under the profiler; the figures are the device's own durations,
+in ms a CHUNK, of
 
   logits   the chunk's `f32[6144,50304]` product (the kernel, or XLA's fusion
            with the row maximum)
   pass     what else reads `[6144,50304]` for the logsumexp and the target's
            logit (XLA form only: the kernel form has no such row)
-  dx       the product `[6144,50304] x [50304,768]`
-  dhead    the products `[768,6144] x [6144,50304]`, summed over the chunks
+  dx       XLA's product `[6144,50304] x [50304,768]`
+  dhead    XLA's products `[768,6144] x [6144,50304]`, summed over the chunks
+  grads    the `loss_head_grads` kernel, which makes both (then `dx` and
+           `dhead` read 0)
   other    the rest of the program (the target's gather and row dot, the
            head's cast, the sums), whose longest rows `others` names
 
 and `logits_roofline_pct`, the least time for one `[24576,768] x
 [768,50304]` product (`benchmark/layer_metrics/logits_lse_roofline.py`'s
-count) over the time of the `logits` row.  The train cell's twin of
+count) over the time of the `logits` row, and `grads_roofline_pct`, the
+least time for the two gradient products
+(`benchmark/layer_metrics/loss_head_grads_roofline.py`'s count) over the
+`dx`, `dhead` and `grads` rows.  The train cell's twin of
 `scripts/engine_step_time.py`, as `scripts/flash_step_time.py` is for the
 flash kernels: not a tool the benchmark runs.  On the chip, from the root of
 a checkout:
 
-  python3 scripts/loss_head_time.py [xla] [tm,tn[,sub] ...]
+  python3 scripts/loss_head_time.py [xla] [products] [kernel]
+      [tm,tn[,sub] ...] [grads=tm,tn[,sub] ...]
 
-With no argument: the XLA form, then the kernel at the program's own tiles.
-`tm,tn,sub` gives the kernel at a row tile, a vocabulary tile and a sub-tile
-of rows (`_LSE_ROW_TILES`, `_LSE_COL_TILE`, `_LSE_SUB_ROWS`).  The last line
+With no argument: `xla`, `products`, then `kernel`.  `tm,tn,sub` gives
+`logits_lse` a row tile, a vocabulary tile and a sub-tile of rows
+(`_LSE_ROW_TILES`, `_LSE_COL_TILE`, `_LSE_SUB_ROWS`); `grads=tm,tn,sub`
+gives `loss_head_grads` its own (`_GRAD_ROW_TILES`, `_GRAD_COL_TILE`,
+`_GRAD_SUB_ROWS`), and says so where the plan refuses them.  The last line
 is one JSON object.
 """
 
@@ -62,7 +72,8 @@ def part_of(text: str) -> str:
     chunk's `[6144,50304]`."""
     base, label, kernel, _ = trace_reduce.describe(text)
     if kernel:
-        return "logits" if base == "logits_lse" else "other"
+        return {"logits_lse": "logits", "loss_head_grads": "grads"}.get(
+            base, "other")
     made = text.split(" = ", 1)[-1]       # `(a, b) fusion(...` or `a fusion(`
     result = made[:made.index(") ") + 1] if made.startswith("(") \
         else made.split(" ", 1)[0]
@@ -105,6 +116,8 @@ def main(forms):
     peaks = manifest.peaks(dev.device_kind)
     least_ms = 1e3 * flops.roofline_s(
         2.0 * T * D * V, 4.0 * T * V + 2.0 * T * D + 2.0 * D * V, peaks)[0]
+    least_grads_ms = 1e3 * flops.roofline_s(
+        4.0 * T * D * V, 4.0 * T * V + 2.0 * T * D + 6.0 * D * V, peaks)[0]
     keys = jax.random.split(jax.random.key(0), 4)
     x = jax.random.normal(keys[0], (T, D), jnp.bfloat16)
     embed = 0.02 * jax.random.normal(keys[1], (V, D), jnp.float32)
@@ -118,15 +131,28 @@ def main(forms):
             argnums=(0, 1))(x, embed)
 
     own = (ce._LSE_ROW_TILES, ce._LSE_COL_TILE, ce._LSE_SUB_ROWS)
-    plan = ce._lse_plan
+    own_grads = (ce._GRAD_ROW_TILES, ce._GRAD_COL_TILE, ce._GRAD_SUB_ROWS)
+    plan, grads_plan = ce._lse_plan, ce._grads_plan
     result = {"device": [dev.platform, dev.device_kind], "tree": os.getcwd(),
               "shape": [T, D, V, CHUNKS], "least_logits_ms_a_chunk":
               least_ms / CHUNKS, "rows": []}
-    for form in forms or ["xla", "kernel"]:
-        ce._lse_plan = plan
+    for form in forms or ["xla", "products", "kernel"]:
+        ce._lse_plan, ce._grads_plan = plan, grads_plan
         ce._LSE_ROW_TILES, ce._LSE_COL_TILE, ce._LSE_SUB_ROWS = own
-        if form == "xla":
-            ce._lse_plan = lambda *shape: None
+        ce._GRAD_ROW_TILES, ce._GRAD_COL_TILE, ce._GRAD_SUB_ROWS = own_grads
+        if form in ("xla", "products"):
+            ce._grads_plan = lambda *shape: None
+            if form == "xla":
+                ce._lse_plan = lambda *shape: None
+        elif form.startswith("grads="):
+            tiles = form[len("grads="):].split(",")
+            ce._GRAD_ROW_TILES, ce._GRAD_COL_TILE = (int(tiles[0]),), int(
+                tiles[1])
+            if len(tiles) > 2:
+                ce._GRAD_SUB_ROWS = int(tiles[2])
+            if ce._grads_plan(T // CHUNKS, D, V) is None:
+                print(f"form={form}: the plan refuses it", flush=True)
+                continue
         elif form != "kernel":
             tiles = [int(t) for t in form.split(",")]
             ce._LSE_ROW_TILES, ce._LSE_COL_TILE = (tiles[0],), tiles[1]
@@ -140,10 +166,12 @@ def main(forms):
             continue
         row = {"form": form}
         row.update({f"{part}_ms": took[part] / CHUNKS for part in
-                    ("logits", "pass", "dx", "dhead", "other")})
+                    ("logits", "pass", "dx", "dhead", "grads", "other")})
         row["all_ms_a_call"] = sum(took.values())
         row["logits_roofline_pct"] = 100.0 * least_ms / max(took["logits"],
                                                              1e-9)
+        row["grads_roofline_pct"] = 100.0 * least_grads_ms / max(
+            took["grads"] + took["dx"] + took["dhead"], 1e-9)
         row["rows"] = rows
         result["rows"].append(row)
         print("  ".join(f"{key}={val:.3f}" if isinstance(val, float)

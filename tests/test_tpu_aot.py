@@ -86,12 +86,16 @@ def test_flash_kernels_carry_the_name_the_trace_reader_keys_on(v5e,
     instruction name, less its number, is `flash_attention`; a traced run
     of the train cell that lacks the metric is refused.  Every Mosaic call
     of the train step's layers is a flash kernel, so none there may carry
-    another name; the loss head's are `logits_lse`, which
-    `benchmark/layer_metrics/logits_lse_roofline.py` reads by that name."""
+    another name; the loss head's are `logits_lse` and `loss_head_grads`,
+    which `benchmark/layer_metrics/logits_lse_roofline.py` and
+    `loss_head_grads_roofline.py` read by those names (the first sums every
+    kernel whose name is `logits_lse`, so the second kernel's name is none
+    that the reducer cuts down to that)."""
     from benchmark import trace_reduce
     text = _compile_train_step(v5e[:1], MeshConfig(data=1))
     counts = _kernel_counts(text)
-    assert set(counts) == {"flash_attention", "logits_lse"}, counts
+    assert set(counts) == {"flash_attention", "logits_lse",
+                           "loss_head_grads"}, counts
     assert counts["flash_attention"] == 2, counts
     # and as the reducer of a trace cuts an operation's text down to it
     assert {trace_reduce.describe(line.strip())[0]
@@ -175,11 +179,13 @@ def test_flash_kernels_read_the_heads_where_the_train_step_leaves_them(
     text = _compile_train_step(v5e[:chips], mesh_cfg, cfg, batch=batch)
     width = cfg.d_model
     wide, counts = f"bf16[{share},1024,{width}]", _kernel_counts(text)
-    # the loss head's kernel a chunk on one chip; under a mesh the head is
-    # `fused_cross_entropy_spmd`'s, plain XLA
+    # the loss head's two kernels on one chip, once each in the body of the
+    # chunks' loop (at gpt2-xl's width of twelve and a half blocks too);
+    # under a mesh the head is `fused_cross_entropy_spmd`'s, plain XLA
     assert counts["flash_attention"] == 2 and set(counts) <= {
-        "flash_attention", "logits_lse"}, counts
-    assert counts["logits_lse"] == (4 if chips == 1 else 0), counts
+        "flash_attention", "logits_lse", "loss_head_grads"}, counts
+    assert counts["logits_lse"] == counts["loss_head_grads"] == (
+        1 if chips == 1 else 0), counts
     for line in text.splitlines():
         if ('custom_call_target="tpu_custom_call"' in line
                 and "%flash_attention" in line.split(" = ")[0]):
@@ -232,10 +238,8 @@ def test_flash_kernels_compile_for_v5e_at_two_blocks_a_head_of_128(
     assert (plan.block_q, plan.block_k, plan.column_blocks) == (1024, 1024, 16)
 
 
-def _readers(text, value):
-    """The instructions of the entry computation that take `value` (a
-    name, `%` and all) as an operand, each with the text of the computation
-    it calls, if it calls one."""
+def _computations(text):
+    """{name: its lines} of a compiled module's computations."""
     bodies, name = {}, None
     for line in text.splitlines():
         if line.endswith("{") and not line.startswith(" "):
@@ -243,10 +247,15 @@ def _readers(text, value):
             bodies[name] = []
         elif name is not None:
             bodies[name].append(line)
-    entry = next(body for name, body in bodies.items()
-                 if f"ENTRY {name}" in text)
+    return bodies
+
+
+def _readers(bodies, lines, value):
+    """The instructions among `lines` that take `value` (a name, `%` and
+    all) as an operand, each with the text of the computation it calls, if
+    it calls one."""
     found = []
-    for line in entry:
+    for line in lines:
         made, _, rest = line.partition(" = ")
         if re.search(re.escape(value) + r"[,)]", rest):
             called = re.search(r"calls=(%[\w.\-]+)", rest)
@@ -255,31 +264,51 @@ def _readers(text, value):
     return found
 
 
-def test_the_loss_head_makes_a_chunks_logits_once_and_reads_them_twice(
+def test_the_loss_head_makes_a_chunks_logits_once_and_reads_them_once(
         v5e, as_on_chip):
     """`train_gpt2s_1chip`'s step at one layer (b24 x 1024, vocabulary
-    50,304; four chunks of 6,144 rows): a `logits_lse` call a chunk and no
+    50,304; four chunks of 6,144 rows).  The chunks are a loop of four
+    turns in the compiled step; its body holds one `logits_lse` call and no
     other product gives a chunk's `f32[6144,50304]`, so the backward
-    recomputes none; and each chunk's logits are read by two instructions,
-    the dx product and the dhead product (each forms `softmax - onehot` in
-    its prologue), where until PR 58 a third, `select_reduce_fusion`, read
-    all 1.236 GB of them again for the sum of exponentials (6.5 ms of a
-    150.8 ms step, PERF.md section 6)."""
+    recomputes none; and the chunk's logits have ONE reader, the
+    `loss_head_grads` call that forms `softmax - onehot` once and gives dx
+    and dhead.  Until PR 60 two XLA products read them, each forming it in
+    its own prologue (5.69 ms a chunk where the kernel takes 4.97), and
+    until PR 58 a third, `select_reduce_fusion`, read all 1.236 GB of them
+    again for the sum of exponentials (PERF.md section 6).  dhead leaves
+    the kernel `[V, D]`, as the embedding lies: nothing in the loop or in
+    the entry computation turns a value of that size round (the parent's
+    last dhead product came out `[D, V]` and a `copy f32[50304,768]` stood
+    between it and the lookup's scatter-add)."""
     cfg = dataclasses.replace(CFG, vocab_size=50304, max_seq_len=1024,
                               remat=False, d_model=768, n_heads=12,
                               d_ff=3072)
     text = _compile_train_step(v5e[:1], MeshConfig(data=1), cfg, batch=24)
-    assert _kernel_counts(text)["logits_lse"] == 4
+    counts = _kernel_counts(text)
+    assert counts["logits_lse"] == counts["loss_head_grads"] == 1, counts
     wide = r"f32\[6144,50304\]"
     assert not re.search(rf" = {wide}\S* (convolution|dot)\(", text)
-    logits = re.findall(
+    bodies = _computations(text)
+    entry = next(lines for name, lines in bodies.items()
+                 if f"ENTRY {name}" in text)
+    (chunk,) = [name for name, lines in bodies.items()
+                if any(re.match(r"\s*%loss_head_grads[\w.\-]* = ", line)
+                       for line in lines)]
+    # the body of a loop of the entry computation that runs four times
+    (loop,) = [line for line in entry if f"body={chunk}," in line]
+    turns = "\n".join(bodies[re.search(r"condition=(%[\w.\-]+)",
+                                       loop).group(1)])
+    assert "constant(4)" in turns and "direction=LT" in turns, turns
+    (value,) = re.findall(
         rf"(%[\w.\-]+) = {wide}\S* get-tuple-element\((?:\([^)]*\) )?"
-        rf"%logits_lse[\w.\-]*\), index=0", text)
-    assert len(logits) == 4, logits
-    for value in logits:
-        readers = _readers(text, value)
-        assert len(readers) == 2, (value, [made for made, _ in readers])
-        assert all(" convolution(" in body for _, body in readers), readers
+        rf"%logits_lse[\w.\-]*\), index=0", "\n".join(bodies[chunk]))
+    readers = _readers(bodies, bodies[chunk], value)
+    assert len(readers) == 1, [made for made, _ in readers]
+    assert "%loss_head_grads" in readers[0][0], readers
+    turned = [line.strip()[:160] for line in entry + bodies[chunk]
+              if re.search(r" = f32\[(50304,768|768,50304)\]\S* "
+                           r"(copy|transpose)\(", line)]
+    assert not turned, turned
 
 
 def test_logits_lse_compiles_for_v5e_at_gpt2xls_width(v5e, as_on_chip):
@@ -294,6 +323,23 @@ def test_logits_lse_compiles_for_v5e_at_gpt2xls_width(v5e, as_on_chip):
         arg((1024, 1600), jnp.bfloat16),
         arg((50304, 1600), jnp.bfloat16)).compile().as_text()
     assert _kernel_counts(text) == {"logits_lse": 1}
+
+
+def test_loss_head_grads_compiles_for_v5e_at_gpt2xls_width(v5e, as_on_chip):
+    """gpt2-xl's head under the gradients' kernel: `_grads_plan` takes D
+    1,600 (the blocks of x, of the head and of dhead are the whole of D
+    wide, dx's float32 sum pads a row to thirteen blocks of 128) and the
+    compiler takes the kernel (the fallback would be no
+    `tpu_custom_call`)."""
+    from ray_tpu.ops import cross_entropy as ce
+    arg = _arg_on(v5e[0])
+    assert ce._grads_plan(1024, 1600, 50304) == (1024, 384)
+    text = jax.jit(ce.loss_head_grads, donate_argnums=6).lower(
+        arg((1024, 50304), jnp.float32), arg((1024,), jnp.float32),
+        arg((1024,), jnp.int32), arg((1024,), jnp.float32),
+        arg((1024, 1600), jnp.bfloat16), arg((50304, 1600), jnp.bfloat16),
+        arg((50304, 1600), jnp.float32)).compile().as_text()
+    assert _kernel_counts(text) == {"loss_head_grads": 1}
 
 
 def test_train_step_compiles_under_a_v5e_mesh(v5e, as_on_chip):
