@@ -1,11 +1,11 @@
 """Model families.  The LM families (gpt, llama, axk1, evabyte, dots3,
-falconh1, nemotronh, afmoe, lfm2, kimilinear) are specs of one decoder
+falconh1, nemotronh, afmoe, lfm2, kimilinear, mellum) are specs of one decoder
 (models/decoder.py); `family` is where a name becomes one."""
 
 import importlib
 
 LM_FAMILIES = ("gpt", "llama", "axk1", "evabyte", "dots3", "falconh1",
-               "nemotronh", "afmoe", "lfm2", "kimilinear")
+               "nemotronh", "afmoe", "lfm2", "kimilinear", "mellum")
 
 
 def family(model):

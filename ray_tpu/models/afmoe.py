@@ -21,7 +21,8 @@ kinds of attention have the same leaves).  Over a paged cache a full layer
 leaves a K and a V row a token in pools 0 and 1 (the growing table), a
 window layer in pools 2 and 3 (the sliding table): `inference/kv_cache.py`.
 
-Served only (dropless experts and the masked window have no train path).
+It serves and it trains: the flash kernels take the window, the grouped
+multiply has a backward pass, the routers' balancing loss joins the loss.
 """
 
 from __future__ import annotations
@@ -260,7 +261,7 @@ def runs(config: AfmoeConfig) -> tuple:
         window = kind == WINDOW
         out.append(decoder.Run(
             blocks, n, decoder.SWIGLU if blocks == "lead_blocks" else _ffn(c),
-            decoder.WINDOW_HEADS if window else decoder.HEADS,
+            decoder.HEADS,
             first=cached[kind], offset=stacked[blocks], sizes=c.sizes(kind),
             pools=((2, 3) if window else (0, 1)) if both else None,
             table=((1, 2) if window else (0, 2)) if both else None))
@@ -292,6 +293,7 @@ _bound = decoder.bind(spec)
 lm_head = _bound.lm_head
 forward_cached = _bound.forward_cached
 loss_fn = _bound.loss_fn
+loss_and_metrics = _bound.loss_and_metrics
 serving_params = _bound.serving_params
 shard_params = _bound.shard_params
 num_params = _bound.num_params
@@ -301,7 +303,7 @@ make_train_step = _bound.make_train_step
 def forward_trunk(params: dict, tokens: jax.Array, config: AfmoeConfig,
                   mesh=None, position_offset=0) -> jax.Array:
     """tokens [B, L] -> hidden states [B, L, D] (pre-head, normed): the
-    decoder's, less the auxiliary loss no part of this family has."""
+    decoder's, less the routers' balancing loss."""
     return _bound.forward_trunk(params, tokens, config, mesh,
                                 position_offset)[0]
 

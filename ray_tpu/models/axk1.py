@@ -15,8 +15,8 @@ A config may describe ONE CHIP'S SHARE of an expert-parallel deployment:
 here (the router keeps all `n_routed_experts` outputs and its top-k; the
 assignments that fall elsewhere add nothing here), and `vocab_size` may be
 a slice of the published vocabulary (then simply a smaller vocabulary).
-Served only: the expert layer has no backward pass and latent attention no
-train path (ROADMAP.md).
+Served only: latent attention has no train path (ROADMAP.md); the expert
+layer has its backward pass since Mellum 2 was trained.
 """
 
 from __future__ import annotations

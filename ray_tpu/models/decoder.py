@@ -3,7 +3,7 @@
 A family (models/gpt.py, models/llama.py, models/axk1.py,
 models/evabyte.py, models/dots3.py, models/falconh1.py,
 models/nemotronh.py, models/afmoe.py, models/lfm2.py,
-models/kimilinear.py) is a config
+models/kimilinear.py, models/mellum.py) is a config
 dataclass, its parameter format (`init_params`, `param_specs`) and
 `spec(config)`: a `Spec` naming the parts its block is made of (norms: one
 in front of each part, and where the model has them one behind each too,
@@ -30,10 +30,11 @@ Design (no reference counterpart: Ray hosts models, it doesn't ship them):
     chip and per shard (shard_map over batch and heads) under a mesh, ring
     attention when the mesh has a seq axis > 1; over a paged KV cache,
     ops/attention.py's paged path; by a run's `HeadSizes` with a rotation
-    of the run's own or none, a window a run (the rows behind it are
-    neither kept nor read: a sliding table), an RMSNorm over each head's
-    q and k and an elementwise gate on the result.  `LATENT`: multi-head
-    latent attention,
+    of the run's own or none (YaRN's frequencies and factor among them),
+    a window a run (the flash kernels take it; over a cache the rows
+    behind it are neither kept nor read: a sliding table), an RMSNorm over
+    each head's q and k and an elementwise gate on the result.  `LATENT`:
+    multi-head latent attention,
     expanded for a whole sequence, absorbed over a latent paged cache; by
     a run's `LatentSizes` also over a window, or over the positions a
     learned indexer chooses, with a gate a head.
@@ -109,14 +110,15 @@ def _scaled(x, factor):
     return x if factor == 1.0 else x * jnp.asarray(factor, x.dtype)
 
 
-def rope(x, theta: float, offset=0, freqs=None):
+def rope(x, theta: float, offset=0, freqs=None, scale: float = 1.0):
     """Rotary position embedding over [B, L, H, K] (rotate-half pairing:
     the head dim splits into two halves treated as (real, imag)).
 
     `offset` is the absolute position of x's first token: a scalar shared
     by the batch, or a per-lane [B] array (cached decode: lanes sit at
     different depths).  `freqs` [K / 2] replaces theta's own frequencies
-    (`yarn_freqs`)."""
+    (`yarn_freqs`); `scale` multiplies the cosines and sines (YaRN's
+    `attention_factor`: on q and on k, so on the scores its square)."""
     b, l, h, k = x.shape
     half = k // 2
     if freqs is None:
@@ -130,6 +132,8 @@ def rope(x, theta: float, offset=0, freqs=None):
         ang = ang[None]
     cos = jnp.cos(ang)[:, :, None, :]
     sin = jnp.sin(ang)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x32 = x.astype(jnp.float32)
     x1, x2 = x32[..., :half], x32[..., half:]
     out = jnp.concatenate([x1 * cos - x2 * sin,
@@ -227,32 +231,86 @@ def moe_ffn(h, p, config, mesh=None, valid=None):
     has no `w_gate` it is `w_down relu(w_up x)^2` over two
     (`ops.moe.expert_ffn`).  The up matrix may be held as published,
     [E, F, D] under the name `w_up_t`: what an expert width that is no
-    multiple of 128 needs (`ops.moe.grouped_matmul`)."""
+    multiple of 128 needs (`ops.moe.grouped_matmul`).
+
+    The auxiliary loss is the router's balancing loss, E sum_e f_e P_e
+    over all E: f_e the share of the step's T x k assignments that chose
+    e, P_e the mean over the tokens of e's score as a share of a token's
+    scores (the softmax's probability), float32; 1 where both are even.
+
+    A share's router hears the task through the experts held here alone
+    (the rank's own part of a gradient that a deployment sums over its
+    ranks): nothing is cut from it, and with no other rank present it
+    draws tokens to this chip (PERF.md 6, PR 61).
+
+    Without `valid` the rows are whole sequences' (the train path): a
+    share's sorted rows are then held to twice the rows its experts
+    expect, T k held / E (`ops.moe.expert_ffn(rows=)`: a bound on buffers
+    that routing may pass at the cost of time, never of an assignment;
+    each page behind the first costs a gather over all T k assignments
+    whatever it holds), and where an expert expects 512 rows or more the
+    grouped products take a row tile of 512: a tile of 128 rows reads its
+    expert's [K, N] once for 112 flops a byte, under the chip's 240."""
     from ray_tpu.ops import moe
 
     c = config
     b, l, d = h.shape
-    x, experts, weights = _route(h, p, c)
+    x, scores = _scores(h, p, c)
+    experts, weights = _choose(scores, p, c)
     held_t = "w_up_t" in p
     up = p["w_up_t" if held_t else "w_up"]
-    share = p.get("w_gate", up).shape[-3] < c.n_experts
+    held = p.get("w_gate", up).shape[-3]
+    share = held < c.n_experts
+    t, k = experts.shape
+    sized = {}
+    if valid is None:
+        tile = sized["block_m"] = 512 if t * k // c.n_experts >= 512 else 128
+        if share:
+            sized["rows"] = -(-2 * t * k * held // c.n_experts // tile) * tile
     y, load = moe.expert_ffn(
         x, experts, weights, p.get("w_gate"), up, p["w_down"],
         p["layer"], None if valid is None else valid.reshape(-1),
         first_held=c.experts_offset if share else None,
-        up_transposed=held_t)
-    return y.reshape(b, l, d), None, load
+        up_transposed=held_t, **sized)
+    return y.reshape(b, l, d), _balance(scores, experts, valid), load
+
+
+def _balance(scores, experts, valid=None):
+    """The router's balancing loss of scores [T, E] and the chosen experts
+    [T, k] (`moe_ffn`); tokens that `valid` [..] masks count for
+    nothing."""
+    e = scores.shape[-1]
+    share = scores / jnp.sum(scores, -1, keepdims=True)
+    chose = jnp.sum(experts[..., None] == jnp.arange(e), 1,
+                    dtype=jnp.float32)                          # [T, E]
+    if valid is not None:
+        keep = valid.reshape(-1, 1).astype(jnp.float32)
+        share, chose = share * keep, chose * keep
+        n = jnp.maximum(jnp.sum(keep), 1.0)
+    else:
+        n = scores.shape[0]
+    f = jnp.sum(chose, 0) / (n * experts.shape[1])
+    return e * jnp.sum(f * jnp.sum(share, 0) / n)
 
 
 def _route(h, p, config):
     """`moe_ffn`'s router: (x [T, D], each token's chosen experts [T, k],
     what each counts for [T, k] float32)."""
-    c = config
+    x, scores = _scores(h, p, config)
+    return (x, *_choose(scores, p, config))
+
+
+def _scores(h, p, config):
+    """(x [T, D], every expert's score [T, E] float32)."""
     x = h.reshape(-1, h.shape[-1])
     logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
-    scores = (jax.nn.sigmoid(logits) if c.scoring_func == "sigmoid"
-              else jax.nn.softmax(logits, axis=-1))
+    return x, (jax.nn.sigmoid(logits) if config.scoring_func == "sigmoid"
+               else jax.nn.softmax(logits, axis=-1))
+
+
+def _choose(scores, p, config):
+    c = config
     if "router_bias" in p:
         # A selection bias (DeepSeek-V3's `noaux_tc`): added to the scores
         # for the choice only, the weights are the unbiased scores.
@@ -268,20 +326,20 @@ def _route(h, p, config):
         weights = weights / (total + eps if eps else total)
     if c.routed_scale != 1.0:
         weights = weights * c.routed_scale
-    return x, experts, weights
+    return experts, weights
 
 
 def shared_moe_ffn(h, p, config, mesh=None, valid=None):
     """`moe_ffn` beside a shared expert every token passes through: a dense
     SwiGLU (`ws_gate`, `ws_up`, `ws_down`) whose result is added as it
     is."""
-    y, _, load = moe_ffn(h, p, config, mesh, valid)
+    y, aux, load = moe_ffn(h, p, config, mesh, valid)
     gate = jax.nn.silu(jnp.einsum("bld,df->blf", h,
                                   p["ws_gate"].astype(h.dtype)))
     up = jnp.einsum("bld,df->blf", h, p["ws_up"].astype(h.dtype))
     shared = jnp.einsum("blf,fd->bld", gate * up,
                         p["ws_down"].astype(h.dtype))
-    return shared + y, None, load
+    return shared + y, aux, load
 
 
 def shared_relu2_moe_ffn(h, p, config, mesh=None, valid=None):
@@ -289,11 +347,11 @@ def shared_relu2_moe_ffn(h, p, config, mesh=None, valid=None):
     matrix held [E, F, D], `w_up_t`) beside a shared expert of the same
     form every token passes through (`ws_up`, `ws_down`), added as it
     is."""
-    y, _, load = moe_ffn(h, p, config, mesh, valid)
+    y, aux, load = moe_ffn(h, p, config, mesh, valid)
     hidden = jnp.square(jax.nn.relu(
         jnp.einsum("bld,df->blf", h, p["ws_up"].astype(h.dtype))))
     shared = jnp.einsum("blf,fd->bld", hidden, p["ws_down"].astype(h.dtype))
-    return shared + y, None, load
+    return shared + y, aux, load
 
 
 def switch_moe(h, p, config, mesh=None, valid=None):
@@ -340,10 +398,11 @@ class FeedForward:
     # Leaves `apply` casts to the activation dtype at their use, which
     # `serving_params` therefore holds in it (the rest are used as stored).
     cast: tuple = ()
-    # Leaves kept whole outside the layer scan and read in place at
-    # `p["layer"]`: a scan would slice a layer's experts out in every step.
+    # Leaves that the loops over a paged cache keep whole and read in place
+    # at `p["layer"]`: taking a layer's experts out of their stack would
+    # copy them in every step.  (The train path scans them with their
+    # layer: `forward_trunk`.)
     whole: tuple = ()
-    trains: bool = True     # has a backward pass and its auxiliary losses
     serves: bool = True     # runs over a paged KV cache
 
 
@@ -351,15 +410,13 @@ GELU = FeedForward(gelu_mlp, cast=("w_up", "w_down"))
 SWIGLU = FeedForward(swiglu_mlp, cast=("w_gate", "w_up", "w_down"))
 SCALED_SWIGLU = FeedForward(scaled_swiglu_mlp,
                             cast=("w_gate", "w_up", "w_down"))
-EXPERTS = FeedForward(moe_ffn, whole=("w_gate", "w_up", "w_down"),
-                      trains=False)
+EXPERTS = FeedForward(moe_ffn, whole=("w_gate", "w_up", "w_down"))
 SHARED_EXPERTS = FeedForward(shared_moe_ffn,
                              cast=("ws_gate", "ws_up", "ws_down"),
-                             whole=("w_gate", "w_up", "w_down"),
-                             trains=False)
+                             whole=("w_gate", "w_up", "w_down"))
 SHARED_RELU2_EXPERTS = FeedForward(shared_relu2_moe_ffn,
                                    cast=("ws_up", "ws_down"),
-                                   whole=("w_up_t", "w_down"), trains=False)
+                                   whole=("w_up_t", "w_down"))
 SWITCH = FeedForward(switch_moe, serves=False)
 
 
@@ -377,7 +434,9 @@ class HeadSizes:
     """What `HEADS` reads of a run of layers where that is not the model's
     config and its spec's one rotation (`Run.sizes`; `head_sizes`): the
     head counts, the run's OWN rotation (`rope_theta` None: the run's
-    layers have no positional encoding at all), a `window` (positions
+    layers have no positional encoding at all; `rope_freqs`: frequencies
+    other than theta's own, `yarn_freqs`; `rope_scale`: a factor on the
+    cosines and sines, YaRN's `attention_factor`), a `window` (positions
     attended, the token's own among them; 0: the whole context), the eps of
     an RMSNorm on q and k over each head's own `head_dim` numbers
     (`q_norm`, `k_norm` [head_dim]; `Spec.qk_norm` is the norm over the
@@ -391,6 +450,16 @@ class HeadSizes:
     window: int = 0
     qk_norm: Optional[float] = None
     gate: bool = False
+    rope_freqs: Optional[tuple] = None
+    rope_scale: float = 1.0
+
+
+def _rotated(x, sizes: HeadSizes, offset):
+    """x [B, L, heads, K] under the run's rotation, where it has one."""
+    if sizes.rope_theta is None:
+        return x
+    return rope(x, sizes.rope_theta, offset, sizes.rope_freqs,
+                sizes.rope_scale)
 
 
 def head_sizes(spec, config) -> HeadSizes:
@@ -470,14 +539,12 @@ def _attn_gate(attn, h, p, sizes: HeadSizes):
 
 
 def heads_attention(h, p, spec, config, mesh, position_offset=0):
-    """Multi-head or grouped-query attention with per-head K and V; over a
-    run's `window` a masked XLA form (the flash kernel has no window: all
-    L x L scores are made, and that form has no train path)."""
+    """Multi-head or grouped-query attention with per-head K and V, by the
+    flash kernels, which take a run's `window`; a length that does not
+    tile runs their XLA form, all L x L scores under the mask."""
     c = head_sizes(spec, config)
     q, k, v = _qkv(spec, h, p, c, _to_heads_side_by_side)
-    if c.rope_theta is not None:
-        q = rope(q, c.rope_theta, position_offset)
-        k = rope(k, c.rope_theta, position_offset)
+    q, k = _rotated(q, c, position_offset), _rotated(k, c, position_offset)
     if c.n_kv_heads < c.n_heads:
         # GQA: each kv head serves n_heads / n_kv_heads query heads.
         # Materializing the repeat keeps the attention kernels
@@ -490,15 +557,8 @@ def heads_attention(h, p, spec, config, mesh, position_offset=0):
     q = with_logical_constraint(
         q.reshape(*q.shape[:2], -1), ("batch", "length", "heads"),
         mesh=mesh).reshape(q.shape)
-    if c.window:
-        pos = jnp.arange(h.shape[1])
-        keep = (pos[None, :] <= pos[:, None]) \
-            & (pos[None, :] > pos[:, None] - c.window)
-        attn = _chosen_attention(
-            q, k, v, jnp.broadcast_to(keep[None], (h.shape[0],) + keep.shape),
-            c.head_dim ** -0.5).astype(h.dtype)
-    else:
-        attn = mesh_flash_attention(q, k, v, mesh=mesh, causal=True)
+    attn = mesh_flash_attention(q, k, v, mesh=mesh, causal=True,
+                                window=c.window)
     attn = _attn_gate(attn, h, p, c)
     return _from_heads_side_by_side(attn, p["wo"])
 
@@ -560,10 +620,7 @@ def _heads_project(h, p, spec, config, offset):
     take the rotation away."""
     c = head_sizes(spec, config)
     q, k, v = _qkv(spec, h, p, c)
-    if c.rope_theta is not None:
-        q = rope(q, c.rope_theta, offset)
-        k = rope(k, c.rope_theta, offset)
-    return q, k, v
+    return _rotated(q, c, offset), _rotated(k, c, offset), v
 
 
 def _heads_attend(rows, pools, p, spec, config, lanes):
@@ -924,9 +981,6 @@ HEADS = Attention(heads_attention, _heads_project, _heads_attend,
                       c.n_kv_heads, c.head_dim,
                       slide=c.window if isinstance(c, HeadSizes) else 0),
                   cast=("wq", "wk", "wv", "wo", "w_attn_gate"))
-# `HEADS` for a run with a window: the same part, whose whole-sequence form
-# is then the masked one, which has no train path.
-WINDOW_HEADS = dataclasses.replace(HEADS, trains=False)
 LATENT = Attention(latent_attention, _latent_project, _latent_attend,
                    _latent_finish,
                    rows=lambda c: CacheRows(
@@ -1531,16 +1585,16 @@ def _block(x, p, spec: Spec, run: Run, config, mesh, position_offset=0):
         x = x + _behind(spec, spec.attn_post_norm, p, _scaled(
             run.mixer.apply(_scaled(h, m.mixer_in), p, c), m.mixer_out))
 
-    aux = None
+    aux = load = None
     if run.ffn is not None:
         h = _norm(spec, x, p, spec.mlp_norm)
-        y, aux, _ = run.ffn.apply(h, p, c, mesh)
+        y, aux, load = run.ffn.apply(h, p, c, mesh)
         x = x + _behind(spec, spec.mlp_post_norm, p, y)
     if aux is None:
         aux = jnp.zeros((), jnp.float32)
     x = with_logical_constraint(x, ("batch", "length", "act_embed"),
                                 mesh=mesh)
-    return x, aux
+    return x, (aux, load)
 
 
 def _part_cached(part, h, pools, p, spec, config, lanes: tuple, offset):
@@ -1701,6 +1755,61 @@ def forward_trunk(family, params: dict, tokens: jax.Array, config,
     position_offset is the absolute position of the first token: a suffix
     call at position p must read pos_embed[p:p+l], not pos_embed[:l], and
     rotate RoPE from p (there a scalar or a per-lane [B] array)."""
+    x, aux, _ = _trunk(family, params, tokens, config, mesh, position_offset)
+    return x, aux
+
+
+# What `remat` keeps of a layer with experts: what only a kernel's forward
+# makes and its backward reads again (the flash kernels' result and
+# logsumexp, the experts' products over their sorted rows:
+# `ops/attention.py`, `ops/moe.py` name them).  Everything else of a layer
+# is made again from the layer's input, products with the weights and
+# passes over them.  A run without experts keeps nothing, as it always
+# has: its flash results alone are 40 MB a layer at gpt2-xl's fsdp4 share,
+# 7.15 -> 10.12 GB of temporaries a chip (AOT, PERF.md 6, PR 61), and a
+# job that asks for remat asks for memory.
+REMAT_KEEPS = ("flash_out", "expert_rows")
+
+
+def _run_layers(params: dict, runs: tuple) -> list:
+    """Each run's layers of its stack.  Where the runs of a stack divide it
+    between them in order they are its `lax.split`, whose transpose is one
+    concatenation of the runs' gradients; slices of it would each come back
+    padded to the stack's size, and the runs' gradients be summed at that
+    size (a read and a write of every layer's experts a run)."""
+    out = [None] * len(runs)
+    stacks: dict = {}
+    for i, run in enumerate(runs):
+        stacks.setdefault(run.blocks, []).append(i)
+    for name, mine in stacks.items():
+        blocks = params[name]
+        sizes = tuple(runs[i].n_layers for i in mine)
+        layers = jax.tree.leaves(blocks)[0].shape[0]
+        if len(mine) > 1 and sum(sizes) == layers and all(
+                runs[i].offset == sum(sizes[:j]) for j, i in enumerate(mine)):
+            parts = {k: jax.lax.split(v, sizes) for k, v in blocks.items()}
+            for j, i in enumerate(mine):
+                out[i] = {k: v[j] for k, v in parts.items()}
+            continue
+        for i in mine:
+            lo, n = runs[i].offset, runs[i].n_layers
+            out[i] = blocks if not lo and n == layers else {
+                k: v[lo:lo + n] for k, v in blocks.items()}
+    return out
+
+
+def _trunk(family, params: dict, tokens: jax.Array, config, mesh=None,
+           position_offset=0):
+    """`forward_trunk` and the expert layers' loads [expert layers, held]
+    (None: no layer has experts).
+
+    Every leaf of a layer is scanned with it, a layer's experts too: the
+    train path casts a float32 master a layer anyway, and the scan's
+    backward then writes a layer's gradient into its place in the stack.
+    (A stack kept outside the scan and read at a layer index, as the loops
+    over a paged cache keep it, gets a cotangent of the whole stack's size
+    a layer, zeros but for that layer, and the sum of them is a read and a
+    write of every layer's experts a layer.)"""
     c, spec = config, family(config)
     x = params["tok_embed"][tokens].astype(c.dtype)
     if spec.rope_theta is None and spec.pos_table:
@@ -1713,29 +1822,28 @@ def forward_trunk(family, params: dict, tokens: jax.Array, config,
         x = x.astype(spec.residual_dtype)
     x = with_logical_constraint(x, ("batch", "length", "act_embed"), mesh=mesh)
 
-    aux = None
-    for run in _stacks(spec, c):
+    aux, loads = None, []
+    runs = _stacks(spec, c)
+    for run, blocks in zip(runs, _run_layers(params, runs)):
         n_layers = run.n_layers
         block = partial(_block, spec=spec, run=run, config=c, mesh=mesh,
                         position_offset=position_offset)
         if c.remat:
+            policies = jax.checkpoint_policies
             block = jax.checkpoint(
-                block, policy=jax.checkpoint_policies.nothing_saveable)
+                block, policy=policies.save_only_these_names(*REMAT_KEEPS)
+                if _whole(run) else policies.nothing_saveable)
 
-        blocks = params[run.blocks]
-        if run.offset or n_layers < jax.tree.leaves(blocks)[0].shape[0]:
-            blocks = {k: v[run.offset:run.offset + n_layers]
-                      for k, v in blocks.items()}
-        scanned, whole = _layer_stack(blocks, n_layers, _whole(run))
+        def body(x, p, block=block):
+            return block(x, {**p, "layer": 0})
 
-        def body(x, layer, block=block, whole=whole):
-            p, i = layer
-            return block(x, {**p, **whole, "layer": i})
-
-        x, auxes = jax.lax.scan(body, x, scanned,
-                                unroll=min(c.scan_unroll, n_layers))
+        x, (auxes, load) = jax.lax.scan(body, x, blocks,
+                                        unroll=min(c.scan_unroll, n_layers))
         aux = jnp.sum(auxes) if aux is None else aux + jnp.sum(auxes)
-    return _norm(spec, x, params, spec.final_norm), aux
+        if load is not None:
+            loads.append(load)
+    return (_norm(spec, x, params, spec.final_norm), aux,
+            jnp.concatenate(loads) if loads else None)
 
 
 def _head(spec: Spec, params: dict, config):
@@ -1769,7 +1877,16 @@ def forward(family, params: dict, tokens: jax.Array, config, mesh=None,
 
 def loss_fn(family, params: dict, batch: dict, config, mesh=None):
     """batch = {"tokens": [B, L]}: next-token cross-entropy, plus 0.01 of
-    the feed-forward's auxiliary loss.
+    the feed-forward's auxiliary loss (`loss_and_metrics`'s first)."""
+    return loss_and_metrics(family, params, batch, config, mesh)[0]
+
+
+def loss_and_metrics(family, params: dict, batch: dict, config, mesh=None):
+    """(`loss_fn`'s loss, what the step reports of a model with expert
+    layers: `aux_loss`, the routers' balancing losses summed over the
+    layers, and `expert_load` [expert layers, held] int32, the assignments
+    each held expert took: with a share, the counter that says routing
+    stayed whole.  {} for a model without).
 
     Runs the model on the FULL length L and shifts targets instead of
     slicing inputs to L-1: the sequence dim must stay divisible by the
@@ -1788,11 +1905,6 @@ def loss_fn(family, params: dict, batch: dict, config, mesh=None):
 
     c, spec = config, family(config)
     runs = _stacks(spec, c)
-    if not all(run.ffn is None or run.ffn.trains for run in runs):
-        raise NotImplementedError(
-            "training an expert configuration is not supported yet: the "
-            "grouped matmul (ops/moe.py) has no backward pass and the "
-            "router's auxiliary losses are not computed (ROADMAP.md R1)")
     if not all(run.attn is None or run.attn.trains for run in runs):
         raise NotImplementedError(
             "this attention has no train path yet: latent attention is "
@@ -1800,8 +1912,11 @@ def loss_fn(family, params: dict, batch: dict, config, mesh=None):
             "without a backward pass of its own (ROADMAP.md)")
     if any(run.mixer is not None for run in runs):
         raise NotImplementedError(
-            "a state-space mixer has no train path yet: its chunked scan "
-            "(ops/ssm.py) has no backward pass of its own (ROADMAP.md)")
+            "a mixer has no train path yet: the chunked scans of the "
+            "state-space mixer and of Kimi Delta Attention (ops/ssm.py) "
+            "have no backward pass of their own, and the short "
+            "convolution's whole-sequence form has never been trained "
+            "(ROADMAP.md)")
     tokens = batch["tokens"]
     targets = jnp.roll(tokens, -1, axis=1)
     # Last position predicts the rolled-around token 0: always masked.
@@ -1812,22 +1927,27 @@ def loss_fn(family, params: dict, batch: dict, config, mesh=None):
 
     multichip = mesh is not None and any(
         s > 1 for s in mesh.shape.values())
-    if not multichip:
-        x, aux = forward_trunk(family, params, tokens, c, mesh)
-        b, l, d = x.shape
+    if not multichip or spmd_ce_applicable(mesh, c.vocab_size,
+                                           *tokens.shape):
+        x, aux, load = _trunk(family, params, tokens, c, mesh)
         head = _head(spec, params, c)
-        loss = fused_cross_entropy(x.reshape(b * l, d), head,
-                                   targets.reshape(-1), valid.reshape(-1))
-    elif spmd_ce_applicable(mesh, c.vocab_size, *tokens.shape):
-        x, aux = forward_trunk(family, params, tokens, c, mesh)
-        loss = fused_cross_entropy_spmd(x, _head(spec, params, c), targets,
-                                        valid, mesh)
+        if multichip:
+            loss = fused_cross_entropy_spmd(x, head, targets, valid, mesh)
+        else:
+            b, l, d = x.shape
+            loss = fused_cross_entropy(x.reshape(b * l, d), head,
+                                       targets.reshape(-1),
+                                       valid.reshape(-1))
     else:
-        logits, aux = forward(family, params, tokens, c, mesh)
+        x, aux, load = _trunk(family, params, tokens, c, mesh)
+        logits = with_logical_constraint(
+            lm_head(family, params, x, c), ("batch", "length", "vocab"),
+            mesh=mesh)
         logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
         nll = -jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
         loss = jnp.sum(nll * valid) / jnp.maximum(jnp.sum(valid), 1)
-    return loss + 0.01 * aux
+    metrics = {} if load is None else {"aux_loss": aux, "expert_load": load}
+    return loss + 0.01 * aux, metrics
 
 
 # --------------------------------------------------------------------------
@@ -2134,14 +2254,15 @@ def make_train_step(family, config, optimizer, mesh=None):
                 "step": jnp.zeros((), jnp.int32)}
 
     def train_step(state, batch):
-        loss, grads = jax.value_and_grad(partial(loss_fn, family))(
-            state["params"], batch, config, mesh)
+        (loss, metrics), grads = jax.value_and_grad(
+            partial(loss_and_metrics, family), has_aux=True)(
+                state["params"], batch, config, mesh)
         updates, opt_state = optimizer.update(grads, state["opt_state"],
                                               state["params"])
         params = optax.apply_updates(state["params"], updates)
         return ({"params": params, "opt_state": opt_state,
                  "step": state["step"] + 1},
-                {"loss": loss})
+                {"loss": loss, **metrics})
 
     return init_state, train_step
 
@@ -2153,5 +2274,5 @@ def bind(family) -> types.SimpleNamespace:
     return types.SimpleNamespace(spec=family, **{
         f.__name__: partial(f, family) for f in (
             forward_trunk, forward, lm_head, forward_cached, compact_cached,
-            loss_fn, serving_params, shard_params, num_params,
-            make_train_step)})
+            loss_fn, loss_and_metrics, serving_params, shard_params,
+            num_params, make_train_step)})

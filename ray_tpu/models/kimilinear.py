@@ -35,7 +35,7 @@ stretch of one kind, the runs of a kind sharing that kind's stack
 (`Run.offset`), every latent run the cache's ONE latent pool and every KDA
 run the state part's two buffers behind it (`Run.first`: the latent layers,
 or the KDA layers, before it).  Everything that runs is the decoder's.
-Served only (dropless experts and the scan have no backward).
+Served only (the scan has no backward pass, latent attention no train path).
 
 A config may describe ONE STAGE of a pipeline (the lists' entries up to
 `n_layers`, the leading dense layers counted once), one chip's share of the

@@ -29,7 +29,8 @@ every stretch of one kind, the runs of a kind sharing that kind's stack
 (`Run.offset`), every attention run the K and V pools and every conv run the
 cache's state part, whose ONE buffer is the tails (`Run.first`: the
 attention layers, or the conv layers, before it).  Everything that runs is
-the decoder's.  Served only (dropless experts have no backward).
+the decoder's.  Served only (the convolution's whole-sequence form has never
+been trained).
 
 A config may describe ONE STAGE of a pipeline: the `layer_types` it holds,
 with the leading dense layers counted once.

@@ -170,6 +170,7 @@ _bound = decoder.bind(spec)
 lm_head = _bound.lm_head
 forward_cached = _bound.forward_cached
 loss_fn = _bound.loss_fn
+loss_and_metrics = _bound.loss_and_metrics
 serving_params = _bound.serving_params
 shard_params = _bound.shard_params
 num_params = _bound.num_params

@@ -16,15 +16,28 @@ behind the last expert's rows and cost nothing, and so do assignments to
 experts that live elsewhere, where the experts held are a share of those the
 router chooses among (`expert_ffn(first_held=...)`: one chip of an
 expert-parallel deployment, without its exchange).
+
+Both have a backward pass.  Of `grouped_matmul`, dx is the same kernel in
+its other form over dy (it contracts the stored matrix's other dimension:
+no transposed copy of a layer's experts), and dw is a kernel of its own
+(`moe_grouped_matmul_dw` in a trace): over the same (row tile, expert)
+pairs, the rows of a tile that are an expert's contracted into its
+[K, N], in float32.  Of `expert_ffn`, whose dispatch is a sort, the
+backward is the same sort read the other way: gathers and sums and no
+scatter.  Where the experts held are a share, the sorted rows of the
+experts held come first and the buffers behind the sort (rows in, hidden,
+rows out) may be held to a bound (`expert_ffn(rows=)`): what routing sends
+elsewhere then costs neither multiply time nor activations.
 """
 
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -66,16 +79,24 @@ def _grouped_matmul_kernel(item_group, item_tile, starts, ends, n_items,
             o_ref[...] = jnp.where(mine, acc, o_ref[...])
 
 
-def _work_items(group_sizes, block_m: int, n_tiles: int):
+def _work_items(group_sizes, block_m: int, n_tiles: int,
+                every_group: bool = False):
     """The (expert, row tile) pairs that hold rows, in row order, padded to
     the static bound n_tiles + experts - 1 by repeating the last pair (the
-    same blocks again: nothing is fetched for a padding item)."""
+    same blocks again: nothing is fetched for a padding item).  With
+    `every_group` an expert with no rows has one pair too, with the last
+    tile that holds rows (none of them its own): the kernel that writes a
+    result an expert (`_grouped_dw_kernel`) then writes its zeros."""
     n_groups = group_sizes.shape[0]
     ends = jnp.cumsum(group_sizes)
     starts = ends - group_sizes
     first_tile = starts // block_m
     tiles = jnp.where(group_sizes > 0,
                       (ends - 1) // block_m - first_tile + 1, 0)
+    if every_group:
+        tiles = jnp.maximum(tiles, 1)
+        first_tile = jnp.minimum(
+            first_tile, jnp.maximum(ends[-1] - 1, 0) // block_m)
     item_end = jnp.cumsum(tiles)
     n_items = item_end[-1]
     i = jnp.minimum(jnp.arange(n_tiles + n_groups - 1),
@@ -87,27 +108,109 @@ def _work_items(group_sizes, block_m: int, n_tiles: int):
     return group, tile, starts, ends, n_items.reshape(1)
 
 
-@functools.partial(jax.jit, static_argnames=("block_m", "block_n",
-                                             "interpret", "transposed"))
-def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
-                   block_n: int = 2048, interpret: Optional[bool] = None,
-                   transposed: bool = False):
-    """x [M, K], its rows ordered by group; w [L, G, K, N] (or [G, K, N]);
-    group_sizes [G], summing to M or less.  Row r of the result is
-    x[r] @ w[layer, g] for the group g that holds r.  Rows behind the last
-    group are unspecified (the caller masks them).
+def _grouped_dw_kernel(item_group, item_tile, starts, ends, n_items,
+                       a_ref, b_ref, o_ref, acc_ref, *, block_m: int):
+    """Grid (P tiles, Q tiles, work items).  Item i is (expert g, row tile
+    t): the rows of tile t that are g's add a[t]^T b[t] to g's [P, Q]
+    (float32 in `acc_ref`), which is written with g's last item.  The rows
+    of the tile that are not g's are selected to zero on both sides, not
+    multiplied: whatever they hold must not reach a sum."""
+    i = pl.program_id(2)
 
-    `transposed`: w is [L, G, N, K], a Linear's [out, in] as published.
-    That is how a matrix whose N is no multiple of the lane width has to be
-    held: as [K, N] the device lays it out with K minor (the layout that
-    pads nothing) and a kernel that wants it row-major is handed a copy of
-    every layer's experts every step."""
-    if w.ndim == 3:
-        w = w[None]
+    @pl.when(i < n_items[0])
+    def _item():
+        g, t = item_group[i], item_tile[i]
+
+        def mine(ref):
+            rows = t * block_m + jax.lax.broadcasted_iota(
+                jnp.int32, ref.shape, 0)
+            return jnp.where((rows >= starts[g]) & (rows < ends[g]),
+                             ref[...], jnp.zeros(ref.shape, ref.dtype))
+
+        part = jax.lax.dot_general(mine(a_ref), mine(b_ref),
+                                   (((0,), (0,)), ((), ())),
+                                   preferred_element_type=jnp.float32)
+        first = (i == 0) | (item_group[jnp.maximum(i - 1, 0)] != g)
+        last = (i == n_items[0] - 1) | (item_group[i + 1] != g)
+
+        @pl.when(first)
+        def _():
+            acc_ref[...] = part
+
+        @pl.when(jnp.logical_not(first))
+        def _():
+            acc_ref[...] += part
+
+        @pl.when(last)
+        def _():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _tile_of(n: int, most: int) -> int:
+    """The widest tile of at most `most` columns that divides n in
+    multiples of the lane width; an n that is no such multiple is one
+    tile."""
+    if n % 128:
+        return n
+    tile = min(most, n) // 128 * 128
+    while n % tile:
+        tile -= 128
+    return tile
+
+
+def _rows_padded(x, block_m: int):
+    pad = -x.shape[0] % block_m
+    return jnp.pad(x, ((0, pad), (0, 0))) if pad else x
+
+
+def _grouped_dw(a, b, group_sizes, dtype, block_m: int, interpret: bool):
+    """a [M, P], b [M, Q], rows ordered by group -> [G, P, Q] in `dtype`:
+    for each group the sum over its rows r of a[r]^T b[r], accumulated in
+    float32; zeros for a group without rows; rows behind the last group are
+    not read."""
+    a, b = _rows_padded(a, block_m), _rows_padded(b, block_m)
+    (m, p), q = a.shape, b.shape[1]
+    g = group_sizes.shape[0]
+    n_tiles = m // block_m
+    # [1152, 896] of float32 is 4 MB in the scratch and twice in the
+    # result's two buffers; a tile of 512 rows against it is 800 flops a
+    # byte read, over the chip's 240.
+    block_p, block_q = _tile_of(p, 1152), _tile_of(q, 1024)
+    items = _work_items(group_sizes.astype(jnp.int32), block_m, n_tiles,
+                        every_group=True)
+    # (one pair more than the bound: the kernel looks one item ahead)
+    items = (jnp.pad(items[0], (0, 1), constant_values=-1),) + items[1:]
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=5,       # item group/tile, starts, ends, count
+        grid=(p // block_p, q // block_q, n_tiles + g - 1),
+        in_specs=[
+            pl.BlockSpec((block_m, block_p),
+                         lambda jp, jq, i, ig, it, s, e, c: (it[i], jp)),
+            pl.BlockSpec((block_m, block_q),
+                         lambda jp, jq, i, ig, it, s, e, c: (it[i], jq)),
+        ],
+        out_specs=pl.BlockSpec(
+            (None, block_p, block_q),
+            lambda jp, jq, i, ig, it, s, e, c: (ig[i], jp, jq)),
+        scratch_shapes=[pltpu.VMEM((block_p, block_q), jnp.float32)],
+    )
+    return pl.pallas_call(
+        functools.partial(_grouped_dw_kernel, block_m=block_m),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((g, p, q), dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary"),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="moe_grouped_matmul_dw",
+    )(*items, a, b)
+
+
+def _grouped_product(x, w, group_sizes, layer, block_m: int, block_n: int,
+                     interpret: bool, transposed: bool):
+    """`grouped_matmul` of w [L, G, K, N] in x's dtype."""
     m, k = x.shape
     g, n = w.shape[1], w.shape[2 if transposed else 3]
-    if interpret is None:
-        interpret = _interpret_kernels()
     # A decode step is bound by reading each hit expert's [K, N] once, so
     # the tile is the whole matrix where it fits (one 4 MB DMA for OLMoE's
     # experts) and the row tile is tall: on a v5e 128 x 2048 read 86% of
@@ -124,11 +227,8 @@ def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
         block_n = min(block_n, n, max(128, (4 * 2 ** 20 // k) // 128 * 128))
         while block_n > 128 and n % block_n:
             block_n -= 128
-    pad = -m % block_m
-    if pad:
-        x = jnp.pad(x, ((0, pad), (0, 0)))
-    n_tiles = (m + pad) // block_m
-    group_sizes = group_sizes.astype(jnp.int32)
+    x = _rows_padded(x, block_m)
+    n_tiles = x.shape[0] // block_m
     items = _work_items(group_sizes, block_m, n_tiles)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=6,   # item group/tile, starts, ends, count, layer
@@ -150,37 +250,88 @@ def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
         functools.partial(_grouped_matmul_kernel, block_m=block_m,
                           transposed=transposed),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((m + pad, n), x.dtype),
+        out_shape=jax.ShapeDtypeStruct((x.shape[0], n), x.dtype),
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=64 * 1024 * 1024),
         # The instruction's name in the HLO and so in a device trace.
         name="moe_grouped_matmul",
-    )(*items, jnp.asarray(layer, jnp.int32).reshape(1), x, w)
-    return out[:m] if pad else out
+    )(*items, layer, x, w)
+    return out[:m] if x.shape[0] != m else out
 
 
-def expert_ffn(x, expert_ids, expert_weights, w_gate, w_up, w_down,
-               layer=0, valid=None, first_held=None, up_transposed=False):
-    """Dropless experts: SwiGLU over three matrices
-    (`w_down (silu(w_gate x) * (w_up x))`), or with `w_gate` None a
-    squared ReLU over two (`w_down relu(w_up x)^2`).  x [T, D]; expert_ids
-    / expert_weights [T, k] (each token's chosen experts and what each
-    counts for); weights [L, E, D, F] / [L, E, F, D] (or without L),
-    multiplied as stored (`up_transposed`: `w_up` is held [L, E, F, D],
-    `grouped_matmul(transposed=True)`); `valid` [T] masks padding tokens,
-    which reach no expert.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
+def _grouped(x, w, group_sizes, layer, block_m, block_n, interpret,
+             transposed):
+    return _grouped_product(x, w.astype(x.dtype), group_sizes, layer,
+                            block_m, block_n, interpret, transposed)
 
-    With `first_held` the E experts held here are a share of those the
-    ids run over: first_held to first_held + E.  An assignment to any
-    other goes behind every group, as a padding token's do, so the grouped
-    multiply sees only held experts and that assignment adds nothing.
 
-    Returns (y [T, D], load [E] int32: the assignments each expert took)."""
-    t, d = x.shape
+def _grouped_fwd(x, w, group_sizes, layer, block_m, block_n, interpret,
+                 transposed):
+    return (_grouped(x, w, group_sizes, layer, block_m, block_n, interpret,
+                     transposed), (x, w, group_sizes, layer))
+
+
+def _grouped_bwd(block_m, block_n, interpret, transposed, res, dy):
+    """dx is the product's other form over dy, reading w where it is; dw
+    the layer's [G, K, N] (`transposed`: [G, N, K]), in w's own dtype from
+    float32 sums: a float32 master that the forward read in bf16 gets its
+    gradient unrounded.  A stacked w's is zeros but for the layer."""
+    x, w, group_sizes, layer = res
+    dx = _grouped_product(dy, w.astype(dy.dtype), group_sizes, layer,
+                          block_m, block_n, interpret, not transposed)
+    a, b = (dy, x) if transposed else (x, dy)
+    dw = _grouped_dw(a, b, group_sizes, w.dtype, block_m, interpret)
+    if w.shape[0] > 1:
+        dw = jnp.zeros_like(w).at[layer[0]].set(dw)
+    else:
+        dw = dw[None]
+    return dx, dw, None, None
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
+@functools.partial(jax.jit, static_argnames=("block_m", "block_n",
+                                             "interpret", "transposed"))
+def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
+                   block_n: int = 2048, interpret: Optional[bool] = None,
+                   transposed: bool = False):
+    """x [M, K], its rows ordered by group; w [L, G, K, N] (or [G, K, N]);
+    group_sizes [G], summing to M or less.  Row r of the result is
+    x[r] @ w[layer, g] for the group g that holds r.  Rows behind the last
+    group are unspecified (the caller masks them), and so are theirs of
+    the gradient in x; a w of another dtype is read in x's (a float32
+    master under bf16 rows: its gradient comes back in float32).
+
+    `transposed`: w is [L, G, N, K], a Linear's [out, in] as published.
+    That is how a matrix whose N is no multiple of the lane width has to be
+    held: as [K, N] the device lays it out with K minor (the layout that
+    pads nothing) and a kernel that wants it row-major is handed a copy of
+    every layer's experts every step."""
+    if interpret is None:
+        interpret = _interpret_kernels()
+    return _grouped(x, w if w.ndim == 4 else w[None],
+                    group_sizes.astype(jnp.int32),
+                    jnp.asarray(layer, jnp.int32).reshape(1), block_m,
+                    block_n, interpret, transposed)
+
+
+class _Dispatch(NamedTuple):
+    """The sort behind `expert_ffn`: `flat` [T * k] each assignment's
+    expert among those held (`experts`: none of them), `order` the
+    assignment of each sorted row, `rank` the sorted row of each
+    assignment, `load` [E] the assignments each held expert took."""
+    flat: jax.Array
+    order: jax.Array
+    rank: jax.Array
+    load: jax.Array
+
+
+def _dispatch(expert_ids, e: int, valid, first_held) -> _Dispatch:
     k = expert_ids.shape[1]
-    e = w_down.shape[-3]
     flat = expert_ids.reshape(-1).astype(jnp.int32)            # [T * k]
     if first_held is not None:
         flat = flat - first_held
@@ -190,20 +341,201 @@ def expert_ffn(x, expert_ids, expert_weights, w_gate, w_up, w_down,
     order = jnp.argsort(flat, stable=True)     # sorted row -> assignment
     load = jnp.sum(flat[:, None] == jnp.arange(e)[None, :], axis=0,
                    dtype=jnp.int32)
-    xs = x[order // k]                                         # [T * k, D]
-    if w_gate is None:
-        hidden = jnp.square(jax.nn.relu(grouped_matmul(
-            xs, w_up, load, layer, transposed=up_transposed)))
-    else:
-        hidden = (jax.nn.silu(grouped_matmul(xs, w_gate, load, layer))
-                  * grouped_matmul(xs, w_up, load, layer))
-    ys = grouped_matmul(hidden, w_down, load, layer)           # [T * k, D]
     rank = jnp.zeros_like(order).at[order].set(
-        jnp.arange(t * k, dtype=order.dtype))  # assignment -> sorted row
+        jnp.arange(order.shape[0], dtype=order.dtype))
+    return _Dispatch(flat, order, rank, load)
+
+
+def _act(g, u):
+    """The experts' hidden rows of their products' float32 values: SwiGLU,
+    or with no gate a squared ReLU."""
+    if g is None:
+        return jnp.square(jax.nn.relu(u))
+    return jax.nn.silu(g) * u
+
+
+def _page(p, r: int, sort: _Dispatch, masks):
+    """Sorted rows p r to (p + 1) r as `_experts_at` reads them: (the
+    assignment of each of the page's rows, each held expert's rows among
+    them, for each assignment its row in the page, and whether it has one:
+    None where the page is the whole sort and nothing is masked)."""
+    n = sort.order.shape[0]
+    if r == n:
+        return sort.order, sort.load, sort.rank, masks
+    lo = p * r
+    order = jnp.take(sort.order, lo + jnp.arange(r), mode="clip")
+    ends = jnp.cumsum(sort.load)
+    load = jnp.clip(ends, lo, lo + r) - jnp.clip(ends - sort.load, lo, lo + r)
+    here = (sort.rank >= lo) & (sort.rank < lo + r)
+    if masks is not None:
+        here = here & masks
+    return order, load, jnp.clip(sort.rank - lo, 0, r - 1), here
+
+
+def _experts_at(p, r: int, x, weights, w_gate, w_up, w_down, layer, sort,
+                masks, static):
+    """`expert_ffn` over page `p` of `r` sorted rows.  Returns (y [T, D],
+    what the backward reads again: the products g and u [r, F] (g None
+    without a gate) and the experts' results ys [r, D])."""
+    k, up_transposed, block_m = static
+    t, d = x.shape
+    order, load, rank, here = _page(p, r, sort, masks)
+    mm = functools.partial(grouped_matmul, group_sizes=load, layer=layer,
+                           block_m=block_m)
+    xs = x[order // k]                                             # [r, D]
+    g = None if w_gate is None else mm(xs, w_gate)
+    u = mm(xs, w_up, transposed=up_transposed)
+    hidden = _act(g, u)
+    ys = mm(hidden, w_down)                                        # [r, D]
     y = ys[rank].reshape(t, k, d).astype(jnp.float32)
+    if here is not None:
+        y = jnp.where(here.reshape(t, k, 1), y, 0.0)   # rows no expert wrote
+    out = jnp.einsum("tk,tkd->td", weights.astype(jnp.float32), y)
+    return out.astype(x.dtype), (g, u, ys)
+
+
+def _experts_bwd_at(p, r: int, x, weights, w_gate, w_up, w_down, layer, sort,
+                    masks, static, saved, dout):
+    """The cotangents of x, weights and the three matrices from page `p` of
+    `r` sorted rows.  The sort is a permutation, so what the forward
+    gathered by `order` comes back gathered by `rank` and the other way
+    round: a token's dx is the sum of its k sorted rows' (those of experts
+    held elsewhere masked: no kernel wrote them), a sorted row's dy is its
+    token's times its weight.  No scatter."""
+    k, up_transposed, block_m = static
+    t, d = x.shape
+    interpret = _interpret_kernels()
+    g, u, ys = saved
+    order, load, rank, here = _page(p, r, sort, masks)
+    tok = order // k
+    xs = x[tok]
+
+    def back(rows_in, w, dy, transposed=False):
+        """(the product's dx, its dw in w's own shape): `_grouped_bwd`."""
+        dx, dw, _, _ = _grouped_bwd(
+            block_m, 2048, interpret, transposed,
+            (rows_in, w if w.ndim == 4 else w[None], load, layer), dy)
+        return dx, dw if w.ndim == 4 else dw[0]
+
+    dtok = dout[tok].astype(jnp.float32)     # [r, D]: each row's token's
+    # a sorted row's share of the router's weight: <dout of its token, ys>
+    dweights = jnp.sum(dtok * ys.astype(jnp.float32), -1)[rank]
+    dys = (weights.astype(jnp.float32).reshape(-1)[order][:, None]
+           * dtok).astype(x.dtype)                                 # [r, D]
+    dhidden, dw_down = back(_act(g, u), w_down, dys)
+    dhidden, u32 = dhidden.astype(jnp.float32), u.astype(jnp.float32)
+    if g is None:
+        du = (dhidden * 2.0 * jax.nn.relu(u32)).astype(x.dtype)
+        dw_gate = None
+    else:
+        g32 = g.astype(jnp.float32)
+        sig = jax.nn.sigmoid(g32)
+        du = (dhidden * g32 * sig).astype(x.dtype)
+        dg = (dhidden * u32 * sig * (1.0 + g32 * (1.0 - sig))).astype(x.dtype)
+    dxs, dw_up = back(xs, w_up, du, up_transposed)
+    if g is not None:
+        more, dw_gate = back(xs, w_gate, dg)
+        dxs = dxs + more
+    dx = dxs[rank].reshape(t, k, d)
+    if here is not None:
+        dx = jnp.where(here.reshape(t, k, 1), dx, jnp.zeros_like(dx))
+        dweights = jnp.where(here, dweights, 0.0)
+    dx = jnp.sum(dx.astype(jnp.float32), 1).astype(x.dtype)
+    return (dx, dweights.reshape(t, k).astype(weights.dtype), dw_gate,
+            dw_up, dw_down)
+
+
+def _pages(sort: _Dispatch, rows: int):
+    """How many pages of `rows` sorted rows the held experts' rows fill."""
+    return (jnp.sum(sort.load) + rows - 1) // rows
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _experts(x, weights, w_gate, w_up, w_down, layer, sort, masks, rows,
+             static):
+    return _experts_fwd(x, weights, w_gate, w_up, w_down, layer, sort,
+                        masks, rows, static)[0]
+
+
+def _experts_fwd(x, weights, w_gate, w_up, w_down, layer, sort, masks, rows,
+                 static):
+    """The first page, whose products are kept for the backward, and behind
+    it as many more as the held experts' rows fill: none where routing
+    stays inside the bound."""
+    args = (x, weights, w_gate, w_up, w_down, layer, sort, masks, static)
+    y, saved = _experts_at(0, rows, *args)
+    # (by name, so that a `jax.checkpoint` around the layer may keep them:
+    # made again they are three grouped products and two gathers)
+    saved = jax.tree.map(lambda a: checkpoint_name(a, "expert_rows"), saved)
+    if rows < sort.order.shape[0]:
+        # (the later pages' sum starts from zeros, not from y: where nothing
+        # reads y, as in remat's second forward, the first page's gather
+        # goes with it, and a loop that y ran through would keep it)
+        y = y + jax.lax.fori_loop(
+            1, _pages(sort, rows),
+            lambda p, more: more + _experts_at(p, rows, *args)[0],
+            jnp.zeros_like(y))
+    return y, (*args[:-1], saved)
+
+
+def _experts_bwd(rows, static, res, dout):
+    *args, saved = res
+    grads = _experts_bwd_at(0, rows, *args, static, saved, dout)
+    if rows < args[6].order.shape[0]:
+        def more(p, grads):     # what did not fit was not kept: once more
+            saved = _experts_at(p, rows, *args, static)[1]
+            return jax.tree.map(jnp.add, grads, _experts_bwd_at(
+                p, rows, *args, static, saved, dout))
+        grads = jax.lax.fori_loop(1, _pages(args[6], rows), more, grads)
+    return (*grads, None, None, None)
+
+
+_experts.defvjp(_experts_fwd, _experts_bwd)
+
+
+def expert_ffn(x, expert_ids, expert_weights, w_gate, w_up, w_down,
+               layer=0, valid=None, first_held=None, up_transposed=False,
+               rows=None, block_m: int = 128):
+    """Dropless experts: SwiGLU over three matrices
+    (`w_down (silu(w_gate x) * (w_up x))`), or with `w_gate` None a
+    squared ReLU over two (`w_down relu(w_up x)^2`).  x [T, D]; expert_ids
+    / expert_weights [T, k] (each token's chosen experts and what each
+    counts for); weights [L, E, D, F] / [L, E, F, D] (or without L),
+    multiplied as stored, or in x's dtype where theirs is another
+    (`up_transposed`: `w_up` is held [L, E, F, D],
+    `grouped_matmul(transposed=True)`); `valid` [T] masks padding tokens,
+    which reach no expert.
+
+    With `first_held` the E experts held here are a share of those the
+    ids run over: first_held to first_held + E.  An assignment to any
+    other goes behind every group, as a padding token's do, so the grouped
+    multiply sees only held experts and that assignment adds nothing.
+
+    `rows`: the sorted rows that are worked on at a time (None: all
+    T x k).  The rows of the experts held are the first of the sort, so
+    where they are a share, buffers of the share's expected rows and some
+    room hold every one of them, and the rows in, the hidden rows and the
+    rows out are that long, forward and backward.  It bounds memory, never
+    routing: the sort is walked a page of `rows` at a time for as many
+    pages as the held experts' rows fill (a loop whose count is read on
+    the chip; its backward makes a later page's products again, since only
+    the first page's were kept), so however many assignments the held
+    experts take, each is multiplied: none can be dropped, whatever the
+    router does.  `block_m`: the row tile of the grouped products.
+
+    Differentiable in x, expert_weights and the three matrices
+    (`_experts_bwd_at`).  Returns (y [T, D], load [E] int32: the
+    assignments each expert took)."""
+    t, k = expert_ids.shape
+    sort = _dispatch(expert_ids, w_down.shape[-3], valid, first_held)
     if first_held is not None:
-        y = jnp.where((flat < e).reshape(t, k, 1), y, 0.0)
+        masks = sort.flat < w_down.shape[-3]
     elif valid is not None:
-        y = jnp.where(valid[:, None, None], y, 0.0)   # rows no expert wrote
-    out = jnp.einsum("tk,tkd->td", expert_weights.astype(jnp.float32), y)
-    return out.astype(x.dtype), load
+        masks = jnp.repeat(valid, k)
+    else:
+        masks = None
+    y = _experts(x, expert_weights, w_gate, w_up, w_down,
+                 jnp.asarray(layer, jnp.int32).reshape(1), sort, masks,
+                 t * k if rows is None else min(rows, t * k),
+                 (k, up_transposed, block_m))
+    return y, sort.load

@@ -29,7 +29,7 @@ def _grads(fn, g):
 
 
 def _check(q_len, kv_len, blocks, d, dtype, causal, scale=None, heads=None,
-           apart=False):
+           apart=False, window=0):
     """out, dq, dk and dv of `heads` heads, each head against the
     reference's: float32 to 2e-5, bf16 to two ulps at the head's largest
     value.  With `apart`, v and the cotangent of a head are a hundred times
@@ -50,10 +50,12 @@ def _check(q_len, kv_len, blocks, d, dtype, causal, scale=None, heads=None,
     blocks = dict(zip(("block_q", "block_k"), blocks)) if blocks else {}
 
     def flash(q, k, v):
-        return flash_attention(q, k, v, causal=causal, scale=scale, **blocks)
+        return flash_attention(q, k, v, causal=causal, scale=scale,
+                               window=window, **blocks)
 
     def ref(q, k, v):
-        return reference_attention(q, k, v, causal=causal, scale=scale)
+        return reference_attention(q, k, v, causal=causal, scale=scale,
+                                   window=window)
 
     got = (flash(q, k, v),) + _grads(flash, g)(q, k, v)
     want = (ref(q, k, v),) + _grads(ref, g)(q, k, v)
@@ -105,6 +107,50 @@ def test_flash_gradients_of_two_heads_at_blocks_of_512(dtype):
     scores one product in every tile, on the diagonal (one tile of 512 in
     the forward, sub-tiles of 256 and 128 in the backward) and under it."""
     _check(1024, 1024, (512, 512), 64, dtype, True, heads=2, apart=True)
+
+
+# (length, block, window, head width): windows below, at and above the
+# block, over 2 to 8 blocks, heads of 64 (two a column block) and of 128
+WINDOWS = [
+    (256, 128, 50, 64), (256, 128, 128, 128), (512, 128, 200, 64),
+    (512, 128, 1, 64), (640, 128, 129, 128), (1024, 128, 128, 64),
+    (768, 256, 600, 128), (1024, 256, 384, 64), (1024, 512, 600, 128),
+    (512, 256, 255, 128),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("length,block,window,d", WINDOWS,
+                         ids=[f"{w[0]}-{w[1]}-w{w[2]}-d{w[3]}"
+                              for w in WINDOWS])
+def test_flash_with_a_window_matches_the_masked_reference(length, block,
+                                                          window, d, dtype):
+    """out, dq, dk and dv of `flash_attention(window=)` against the plain
+    attention under the causal mask and the window's: a q block visits its
+    own kv block and those the window reaches and no others, the tiles the
+    window's edge crosses masked by a second diagonal (in the backward cut
+    into sub-tiles as the first diagonal's are)."""
+    _check(length, length, (block, block), d, dtype, True, window=window,
+           heads=2 if d == 64 else 1, apart=d == 64)
+
+
+def test_a_window_as_long_as_the_sequence_is_no_window(monkeypatch):
+    """The calls with a window carry a name of their own, and a window
+    that every position fits in is the causal attention, under its name."""
+    names = []
+    call = A.pl.pallas_call
+    monkeypatch.setattr(A.pl, "pallas_call", lambda *a, **kw: (
+        names.append(kw["name"]), call(*a, **kw))[1])
+    q = jnp.ones((1, 256, 2, 64), jnp.float32)
+    for window, name in ((256, "flash_attention"),
+                         (255, "window_flash_attention")):
+        del names[:]
+        jax.grad(lambda q: jnp.sum(flash_attention(
+            q, q, q, window=window, block_q=128, block_k=128)))(q)
+        assert len(names) >= 2 and set(names) == {name}
+    with pytest.raises(ValueError, match="causal"):
+        flash_attention(q, q, q, causal=False, window=8)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
@@ -280,6 +326,52 @@ def test_the_diagonal_walk_of_tiles_that_are_not_square():
     assert ((_pairs(tiles, False) + _pairs(tiles, True)) == (k <= q)).all()
     assert not _pairs(tiles, False)[k > q].any()
     assert all(t[1::2] == (128, 64) for t in tiles)
+
+
+# (block, tile at most, least sub-tile or 0, window)
+WINDOW_WALKS = [(1024, 512, 0, 1024), (1024, 512, 256, 1024),
+                (1024, 512, 128, 1024), (1024, 512, 256, 700),
+                (1024, 512, 0, 300), (512, 256, 128, 1500),
+                (256, 256, 0, 1), (1024, 512, 128, 2048)]
+
+
+@pytest.mark.parametrize("block,most,least,window", WINDOW_WALKS,
+                         ids=["-".join(map(str, w)) for w in WINDOW_WALKS])
+def test_the_window_walk_computes_every_visible_pair_once(block, most, least,
+                                                          window):
+    """Host-side, no kernel: over the kv blocks a q block visits under a
+    window (`_window_steps`), the extents `_tiles` lists cover every pair
+    with 0 <= q - k < window exactly once, no unmasked extent holds any
+    other, and the blocks behind the window are not visited at all."""
+    tile = A._flash_tile(block, most)
+    n = block // tile
+    n_blocks = 6
+    steps = A._window_steps(window, block, n_blocks)
+    assert steps == min(-(-(window - 1) // block) + 1, n_blocks)
+    q, k = np.indices((block, block))
+    for r in range(n_blocks):
+        tiles = list(A._tiles(n, tile, n, tile, True, least,
+                              back=r * block, window=window))
+        visible = (q + r * block - k >= 0) & (q + r * block - k < window)
+        if r >= steps:
+            assert not tiles and not visible.any()
+            continue
+        seen = np.zeros((block, block), int)
+        for q0, nq, k0, nk, offset, edge in tiles:
+            keep = np.asarray(A._masked(jnp.ones((nk, nq)), offset, nq,
+                                        edge)) == 1
+            if offset is None and edge is None:
+                assert visible[q0:q0 + nq, k0:k0 + nk].all()
+            seen[q0:q0 + nq, k0:k0 + nk] += keep.T
+        assert (seen == visible).all()
+        if least and tile % (2 * least) == 0 and not visible.all():
+            assert min(min(t[1], t[3]) for t in tiles) == least
+    # the block the window's edge crosses at 1,024 over blocks of 1,024 is
+    # the diagonal block's mirror: one whole tile and two crossed ones
+    if (block, most, least, window) == (1024, 512, 0, 1024):
+        assert [t[4:] for t in A._tiles(2, 512, 2, 512, True, back=1024,
+                                        window=1024)] == [
+            (None, 0), (None, None), (None, 0)]
 
 
 def test_the_mask_of_heads_side_by_side_is_each_heads_own():

@@ -21,7 +21,7 @@ import pytest
 from benchmark.reference import olmoe as ref
 from ray_tpu.inference import InferenceEngine
 from ray_tpu.inference.kv_cache import PagedKVCache
-from ray_tpu.models import llama
+from ray_tpu.models import decoder, llama
 from ray_tpu.ops import moe
 from tests import serving_script
 
@@ -135,6 +135,151 @@ def test_grouped_path_matches_the_dense_per_expert_loop(t, k, e, d, f,
             tiled, moe.grouped_matmul(rows, w_gate, counts, 1), atol=1e-5)
 
 
+# (rows, group sizes, K, N, row tile, transposed, the layer of a stack or
+# None for one layer's [G, K, N])
+GROUPED_GRADS = {
+    "plain": (40, [5, 0, 20, 7], 32, 24, 16, False, None),
+    "transposed": (40, [5, 0, 20, 7], 32, 24, 16, True, None),
+    "stacked_with_a_layer": (40, [5, 0, 20, 7], 32, 24, 16, False, 1),
+    "stacked_and_transposed": (40, [9, 9, 0, 9], 24, 40, 8, True, 2),
+    "rows_no_multiple_of_the_tile": (45, [11, 3, 0, 30], 32, 24, 16, False,
+                                     None),
+    "a_tile_of_several_groups": (32, [3, 2, 4, 1, 0, 9], 16, 128, 32, False,
+                                 None),
+}
+
+
+@pytest.mark.parametrize("case", list(GROUPED_GRADS))
+def test_grouped_matmul_gradients_match_a_loop_over_the_groups(case):
+    """dx (the product's other form over dy) and dw (`_grouped_dw_kernel`)
+    against `jax.grad` of a loop of plain products a group: a group
+    nobody chose gets zeros, the rows behind the last group count for
+    nothing, and of a stack only the layer read has a gradient."""
+    m, sizes, k, n, block_m, transposed, layer = GROUPED_GRADS[case]
+    rng = np.random.default_rng(m + k)
+    x = jnp.asarray(rng.normal(size=(m, k)), jnp.float32)
+    shape = (len(sizes),) + ((n, k) if transposed else (k, n))
+    w = jnp.asarray(rng.normal(size=((3,) if layer is not None else ())
+                               + shape), jnp.float32) / 4
+    held = sum(sizes)
+    cot = jnp.asarray(rng.normal(size=(held, n)), jnp.float32)
+
+    def kernel(x, w):
+        y = moe.grouped_matmul(x, w, jnp.asarray(sizes), layer or 0,
+                               block_m=block_m, transposed=transposed)
+        return jnp.sum(y[:held] * cot)
+
+    def loop(x, w):
+        own = w if layer is None else w[layer]
+        out, at = [], 0
+        for g, size in enumerate(sizes):
+            out.append(x[at:at + size] @ (own[g].T if transposed else own[g]))
+            at += size
+        return jnp.sum(jnp.concatenate(out) * cot)
+
+    (dx, dw), (want_dx, want_dw) = (jax.grad(f, (0, 1))(x, w)
+                                    for f in (kernel, loop))
+    np.testing.assert_allclose(dx[:held], want_dx[:held], atol=1e-5)
+    np.testing.assert_allclose(dw, want_dw, atol=1e-5)
+    assert float(jnp.abs(want_dw).max()) > 0.1
+    empty = sizes.index(0)
+    assert not np.asarray(dw if layer is None else dw[layer])[empty].any()
+
+
+def _dense_experts(x, ids, weights, w_gate, w_up, w_down, first=0,
+                   valid=None, up_transposed=False):
+    """Every held expert on every token, weighted by what the router gave
+    it (zero where it was not chosen): the plain form of `expert_ffn`."""
+    out = jnp.zeros_like(x)
+    for ex in range(w_down.shape[0]):
+        up = x @ (w_up[ex].T if up_transposed else w_up[ex])
+        hidden = (jnp.square(jax.nn.relu(up)) if w_gate is None
+                  else jax.nn.silu(x @ w_gate[ex]) * up)
+        share = jnp.sum(jnp.where(ids == ex + first, weights, 0.0), 1)
+        if valid is not None:
+            share = share * valid
+        out = out + share[:, None] * (hidden @ w_down[ex])
+    return out
+
+
+# (tokens, k, routed experts, held, the first held or None for all, width,
+# expert width, `rows` bound, row tile, padding rows, relu2 experts)
+EXPERT_GRADS = {
+    "all_experts_held": (24, 2, 8, 8, None, 32, 24, None, 16, False, False),
+    "padding_rows": (37, 2, 8, 8, None, 32, 24, None, 16, True, False),
+    "a_share_with_first_held": (50, 4, 16, 4, 4, 32, 24, None, 16, False,
+                                False),
+    "a_share_inside_its_bound": (50, 4, 16, 4, 4, 32, 24, 96, 16, False,
+                                 False),
+    "a_share_past_its_bound_two_pages": (50, 4, 16, 4, 4, 32, 24, 32, 16,
+                                         False, False),
+    "a_share_past_its_bound_four_pages": (50, 4, 16, 4, 8, 32, 24, 16, 8,
+                                          False, False),
+    "a_bound_and_padding_rows": (41, 4, 16, 4, 0, 32, 24, 48, 16, True,
+                                 False),
+    "tokens_no_multiple_of_the_tile": (13, 3, 8, 8, None, 16, 40, None, 32,
+                                       False, False),
+    "relu2_experts_held_transposed": (30, 2, 8, 4, 2, 32, 24, 32, 16, False,
+                                      True),
+}
+
+
+@pytest.mark.parametrize("case", list(EXPERT_GRADS))
+def test_expert_ffn_gradients_match_the_dense_loop(case):
+    """x, the router's weights and the three matrices against `jax.grad`
+    of every held expert on every token; expert 1 of those held is
+    nobody's choice (its gradient is zeros, not what memory held); with a
+    `rows` bound that routing passes the pages behind the first are
+    walked, and nothing is dropped: the load says so."""
+    (t, k, routed, held, first, d, f, rows, block_m, padded,
+     relu2) = EXPERT_GRADS[case]
+    rng = np.random.default_rng(t * k)
+    x = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    nobody = (first or 0) + 1
+    ids = np.stack([rng.permutation(
+        [e for e in range(routed) if e != nobody])[:k] for _ in range(t)])
+    ids = jnp.asarray(ids, jnp.int32)
+    weights = jnp.asarray(rng.random(size=(t, k)), jnp.float32)
+    valid = jnp.asarray(rng.random(t) < 0.7) if padded else None
+    w_gate = None if relu2 else jnp.asarray(
+        rng.normal(size=(held, d, f)), jnp.float32) / 4
+    w_up = jnp.asarray(rng.normal(size=(held, f, d) if relu2
+                                  else (held, d, f)), jnp.float32) / 4
+    w_down = jnp.asarray(rng.normal(size=(held, f, d)), jnp.float32) / 4
+    cot = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    share = first if held < routed else None
+
+    def kernel(x, weights, w_gate, w_up, w_down):
+        y, load = moe.expert_ffn(x, ids, weights, w_gate, w_up, w_down,
+                                 valid=valid, first_held=share,
+                                 up_transposed=relu2, rows=rows,
+                                 block_m=block_m)
+        return jnp.sum(y * cot), load
+
+    def dense(x, weights, w_gate, w_up, w_down):
+        return jnp.sum(_dense_experts(
+            x, ids, weights, w_gate, w_up, w_down, first or 0, valid,
+            relu2) * cot)
+
+    args = (x, weights, w_gate, w_up, w_down)
+    wrt = tuple(i for i, a in enumerate(args) if a is not None)
+    (got, load), grads = jax.value_and_grad(kernel, wrt, has_aux=True)(*args)
+    want, want_grads = jax.value_and_grad(dense, wrt)(*args)
+    np.testing.assert_allclose(got, want, rtol=1e-5, atol=1e-4)
+    for g, w in zip(grads, want_grads):
+        np.testing.assert_allclose(g, w, atol=2e-5)
+        assert float(jnp.abs(w).max()) > 1e-3
+    # dropless: every assignment of a valid token to a held expert counted
+    keep = np.ones(t, bool) if valid is None else np.asarray(valid)
+    lo = first or 0
+    taken = ((np.asarray(ids) >= lo) & (np.asarray(ids) < lo + held)
+             & keep[:, None])
+    assert int(load.sum()) == int(taken.sum())
+    if rows is not None and "past" in case:
+        assert int(load.sum()) > rows
+    assert int(load[1]) == 0 and not np.asarray(grads[-1])[1].any()
+
+
 def test_no_token_is_dropped_when_one_expert_takes_every_token():
     """A router biased so that expert 3 is every token's first choice and
     expert 5 nobody's (a feature every token's embedding shares, and two
@@ -234,5 +379,43 @@ def test_olmoe_sizes_specs_and_the_train_path():
     assert jax.tree.structure(
         specs, is_leaf=lambda x: isinstance(x, tuple)) == jax.tree.structure(
         shapes)
-    with pytest.raises(NotImplementedError, match="expert configuration"):
-        llama.loss_fn({}, {"tokens": jnp.zeros((1, 8), jnp.int32)}, CFG)
+    assert decoder.EXPERTS.whole == ("w_gate", "w_up", "w_down")
+
+
+def _next_token_loss(logits, tokens):
+    logp = jax.nn.log_softmax(logits[:, :-1], -1)
+    return -jnp.mean(jnp.take_along_axis(logp, tokens[:, 1:, None],
+                                         -1)[..., 0])
+
+
+def test_olmoe_trains_at_nano_size_against_the_references_loss():
+    """What `loss_fn` refused until the grouped multiply had a backward
+    pass: the loss is the reference's cross-entropy plus 0.01 of the
+    routers' balancing losses (1 a layer where the router is even, more
+    where it is not), every leaf has a gradient, the load counts every
+    assignment, and steps down the gradient lower the loss."""
+    params = serving_script.init_params(llama, CFG, 5)
+    tokens = _tokens((2, 24), seed=6)
+    batch = {"tokens": tokens}
+    loss, metrics = llama.loss_and_metrics(params, batch, CFG)
+    want = _next_token_loss(
+        ref.logits(params, tokens, top_k=CFG.n_experts_per_tok), tokens)
+    assert float(loss - 0.01 * metrics["aux_loss"]) == pytest.approx(
+        float(want), abs=TOL)
+    assert CFG.n_layers <= float(metrics["aux_loss"]) < 2 * CFG.n_layers
+    assert metrics["expert_load"].shape == (CFG.n_layers, CFG.n_experts)
+    assert np.asarray(metrics["expert_load"]).sum(1).tolist() == [
+        tokens.size * CFG.n_experts_per_tok] * CFG.n_layers
+    import optax
+    init_state, train_step = llama.make_train_step(CFG, optax.sgd(0.5))
+    state = {**init_state(jax.random.key(0)), "params": params}
+    grads = jax.grad(llama.loss_fn)(params, batch, CFG)
+    for path, g in jax.tree_util.tree_leaves_with_path(grads):
+        assert np.isfinite(g).all() and float(jnp.abs(g).max()) > 0, path
+    losses = []
+    step = jax.jit(train_step)
+    for _ in range(4):
+        state, out = step(state, batch)
+        losses.append(float(out["loss"]))
+    assert losses[0] == pytest.approx(float(loss), abs=1e-5)
+    assert losses[-1] < losses[0] - 0.1

@@ -1325,8 +1325,7 @@ def test_the_pairs_program_fits_a_v5e_and_reads_weights_and_pools_in_place(
     assert memory.alias_size_in_bytes \
         == one.memory_analysis().alias_size_in_bytes >= sum(
             math.prod(p.shape) * p.dtype.itemsize for p in held)
-    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
-            < 15.75 * 2 ** 30)
+    assert memory.temp_size_in_bytes < 11.8e9
     assert memory.argument_size_in_bytes \
         - one.memory_analysis().argument_size_in_bytes < 2 ** 20
     for p in held:                      # rows in blocks, and a state's slots
@@ -1708,3 +1707,60 @@ def test_a_cells_programs_fit_a_v5e_and_leave_their_buffers_in_place(
         assert often is None or counts[name] == often, (name, counts)
     if row.extra:
         row.extra(text, memory, pools, row)
+
+
+# ---------------------------------------------------------------------------
+# `train_mellum2_8k_ep4share`'s step, from the cell's own files
+# ---------------------------------------------------------------------------
+
+def test_the_moe_train_cells_step_fits_a_v5e_and_adds_no_expert_stack(
+        as_on_chip):
+    """Mellum 2's train step at the cell's shapes (2 x 8,192 tokens, 16 of
+    64 experts held, one period S S S F, AdamW over float32 state) compiled
+    for a described v5e.  The kernels it calls: the flash kernels under
+    both names (forward and the one backward kernel, a call each in the
+    window run's loop body and in the full run's: remat keeps their result
+    and logsumexp, `decoder.REMAT_KEEPS`, and makes no forward again), the
+    grouped multiply forward and dx and its dw (a run's loop body holds
+    them for the first page of sorted rows, whose products remat keeps
+    too, and once more in the loops over the pages behind it, which run
+    only when routing passes the bound; a run's fifteen: the first page's
+    three and their three dx, the later pages' three, made again in their
+    backward's loop beside their three dx), and the loss head's two.  The experts are scanned with
+    their layer and the runs' layers are their stack's `lax.split`: no two
+    cotangents of a stack's size are summed anywhere (a layer's gradient
+    goes into its place by a dynamic-update-slice, the two runs' into
+    theirs by one concatenation), nothing copies a layer's or a stack's
+    experts,
+    and the step fits the chip: the compiler refuses one that does not
+    (the same step without remat: 17.87 of 15.75 GiB), so compiling is
+    the check, and the temporaries it reports are held under what they
+    were (arguments: the 9.52 GB of ISSUE 61 less the gradients, which
+    are temporaries)."""
+    from benchmark.tools import aot_train_sizes
+    try:
+        cfg, compiled = aot_train_sizes.compile_step(
+            "mellum2-12b-a2.5b", "train_b2x8192_moe")
+    except RuntimeError as e:           # no v5e topology can be described
+        pytest.skip(str(e))
+    assert cfg.remat and cfg.n_experts_held == 16
+    text, memory = compiled.as_text(), compiled.memory_analysis()
+    counts = _kernel_counts(text)
+    assert counts == {"window_flash_attention": 2, "flash_attention": 2,
+                      "moe_grouped_matmul": 30, "moe_grouped_matmul_dw": 12,
+                      "logits_lse": 1, "loss_head_grads": 1}, counts
+    # (`add_any` is a sum of cotangents; the optimizer's own sums over its
+    # moments, once a step, are not)
+    stack = r"f32\[\d,16,(2304,896|896,2304)\]"
+    summed = [line for line in text.splitlines()
+              if re.search(rf" = {stack}\S* add\(", line)
+              and "add_any" in line]
+    assert not summed, summed[:2]
+    # a layer's gradient is written into the stack where it is
+    assert any(re.search(stack, line) and "dynamic-update-slice" in line
+               for line in text.splitlines())
+    experts = r"\w+\[(\d,)?16,(2304,896|896,2304)\]"
+    assert not re.findall(rf" = {experts}\S* (copy|transpose)\(", text)
+    assert 7.1e9 < memory.argument_size_in_bytes < 7.2e9
+    assert memory.alias_size_in_bytes > 7.1e9       # the state, donated
+    assert memory.temp_size_in_bytes < 11.8e9
