@@ -208,6 +208,14 @@ def scaled_swiglu_mlp(h, p, config, mesh=None, valid=None):
     return y * jnp.asarray(m_down, y.dtype), None, None
 
 
+# `moe_ffn`: whole sequences of this many tokens and more take the experts'
+# rows back to their tokens through `ops.moe.moe_combine`, this many tokens
+# a work item (`scripts/moe_train_time.py combine`: of tiles of 128, 256 and
+# 512 tokens, 256 was fastest over the rows the train cell's layers hold at
+# 16,384 tokens, and at every count down to 256).
+COMBINE_FROM, COMBINE_TILE = 4096, 256
+
+
 def moe_ffn(h, p, config, mesh=None, valid=None):
     """Dropless top-k experts: a router over all `config.n_experts`, top-k,
     dropless dispatch (ops/moe.py).  Router product, scores and top-k run
@@ -246,11 +254,17 @@ def moe_ffn(h, p, config, mesh=None, valid=None):
     Without `valid` the rows are whole sequences' (the train path): a
     share's sorted rows are then held to twice the rows its experts
     expect, T k held / E (`ops.moe.expert_ffn(rows=)`: a bound on buffers
-    that routing may pass at the cost of time, never of an assignment;
-    each page behind the first costs a gather over all T k assignments
-    whatever it holds), and where an expert expects 512 rows or more the
-    grouped products take a row tile of 512: a tile of 128 rows reads its
-    expert's [K, N] once for 112 flops a byte, under the chip's 240."""
+    that routing may pass at the cost of time, never of an assignment),
+    and where an expert expects 512 rows or more the grouped products take
+    a row tile of 512: a tile of 128 rows reads its expert's [K, N] once
+    for 112 flops a byte, under the chip's 240.  From `COMBINE_FROM` tokens
+    on, the experts' rows go back to their tokens through
+    `ops.moe.moe_combine` (`token_tile`): at 16,384 tokens x 8 the kernel
+    takes 1.6-1.9 ms a call where the gather of every assignment's row
+    takes 6.3-7.2, at 4,096 and under they are level (PERF.md 6, PR 62),
+    and a page behind the first costs its own rows, not a gather over all
+    T k assignments.  A served iteration (`valid` given:
+    16-128 tokens, a few thousand with admissions) keeps the gather."""
     from ray_tpu.ops import moe
 
     c = config
@@ -267,6 +281,8 @@ def moe_ffn(h, p, config, mesh=None, valid=None):
         tile = sized["block_m"] = 512 if t * k // c.n_experts >= 512 else 128
         if share:
             sized["rows"] = -(-2 * t * k * held // c.n_experts // tile) * tile
+        if t >= COMBINE_FROM:
+            sized["token_tile"] = COMBINE_TILE
     y, load = moe.expert_ffn(
         x, experts, weights, p.get("w_gate"), up, p["w_down"],
         p["layer"], None if valid is None else valid.reshape(-1),

@@ -28,6 +28,17 @@ scatter.  Where the experts held are a share, the sorted rows of the
 experts held come first and the buffers behind the sort (rows in, hidden,
 rows out) may be held to a bound (`expert_ffn(rows=)`): what routing sends
 elsewhere then costs neither multiply time nor activations.
+
+The way back, the experts' rows to their tokens, has two forms of one sum
+(`expert_ffn(token_tile=)`).  For few tokens a gather of every assignment's
+row, `[T x k, D]`, masked and summed over k (`_gathered`).  For whole
+sequences a kernel over the sort's runs (`moe_combine` in a trace): the
+sort is stable, so the rows of one expert that belong to a tile of
+consecutive tokens are one run of the sorted order, and a 0/1 matrix takes
+them to their tokens through the matrix units, exactly; it reads the held
+experts' rows alone, so a page of sorted rows behind the first costs its
+own rows and no longer a gather over all T x k assignments.  The backward's
+dx is the same kernel with weights of one.
 """
 
 from __future__ import annotations
@@ -319,6 +330,176 @@ def grouped_matmul(x, w, group_sizes, layer=0, *, block_m: int = 128,
                     block_n, interpret, transposed)
 
 
+# `moe_combine`'s block of sorted rows: the matrix units' depth (of 64, 128
+# and 256, 128 was fastest at every token tile: PERF.md 6, PR 62).
+COMBINE_ROWS = 128
+
+
+def _combine_kernel(item_tile, item_block, item_group, item_lo, item_hi,
+                    n_items, page_block, *refs, tile: int, block_rows: int):
+    """Grid (work items,).  Item n is (token tile i, block j of the page's
+    sorted rows, the rows lo to hi of held expert g's run of the tile's
+    tokens): a 0/1 matrix [tile, block rows] with a one where a row is a
+    token's takes each token's row out of the block through the matrix
+    units (one row a token at most, since an expert holds a token once:
+    the float32 product is that row, exactly), the token's float32 weight
+    for g scales it, and the tile's float32 sum, kept in `acc_ref` over the
+    tile's items, is written with the tile's last.  The block's other rows
+    meet zeros of the matrix, so they must be finite (`_zeros_behind` sees
+    to those behind the held experts' last)."""
+    del page_block                   # the index maps' operand
+    if len(refs) == 6:
+        tok_ref, ids_ref, w_ref, rows_ref, o_ref, acc_ref = refs
+    else:
+        (tok_ref, rows_ref, o_ref, acc_ref), ids_ref = refs, None
+    n = pl.program_id(0)
+
+    @pl.when(n < n_items[0])
+    def _item():
+        i, j = item_tile[n], item_block[n]
+
+        @pl.when((n == 0) | (item_tile[jnp.maximum(n - 1, 0)] != i))
+        def _():
+            acc_ref[...] = jnp.zeros(acc_ref.shape, acc_ref.dtype)
+
+        shape = (tile, block_rows)
+        col = j * block_rows + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        tok = i * tile + jax.lax.broadcasted_iota(jnp.int32, shape, 0)
+        hit = ((tok_ref[...] == tok) & (col >= item_lo[n])
+               & (col < item_hi[n]))
+        part = jnp.dot(hit.astype(rows_ref.dtype), rows_ref[...],
+                       preferred_element_type=jnp.float32,
+                       precision=None if rows_ref.dtype == jnp.bfloat16
+                       else jax.lax.Precision.HIGHEST)
+        if ids_ref is not None:
+            part = part * jnp.sum(
+                jnp.where(ids_ref[...] == item_group[n], w_ref[...], 0.0),
+                axis=1, keepdims=True)
+        acc_ref[...] += part
+
+        @pl.when((n == n_items[0] - 1) | (item_tile[n + 1] != i))
+        def _():
+            o_ref[...] = acc_ref[...].astype(o_ref.dtype)
+
+
+def _tile_runs(sort: "_Dispatch", k: int, tile: int):
+    """(first, rows) [token tiles, E]: the sorted row at which the run of
+    each (tile of `tile` consecutive tokens, held expert) starts and how
+    many rows it has.  The sort is stable, so inside an expert's rows the
+    tokens ascend and a tile's are one run."""
+    e = sort.load.shape[0]
+    flat = jnp.pad(sort.flat, (0, -(sort.flat.shape[0] // k) % tile * k),
+                   constant_values=e)
+    rows = jnp.sum(flat.reshape(-1, tile * k, 1) == jnp.arange(e), axis=1,
+                   dtype=jnp.int32)
+    before = jnp.cumsum(rows, axis=0) - rows
+    return (jnp.cumsum(sort.load) - sort.load)[None, :] + before, rows
+
+
+def _combine_items(first, rows, lo, r: int, block_rows: int):
+    """The (token tile, block of sorted rows) pairs that a run touches in
+    the page of `r` sorted rows from `lo`, tile by tile and inside a tile
+    in row order, with each one's expert and run (first row, behind its
+    last: in the page's own count); padded to the static bound
+    r / block_rows + tiles x experts by repeating the last (the same
+    blocks again: nothing is fetched for a padding item).  A tile's first
+    expert has an item whatever it holds, so that a tile no run touches is
+    written too (its zeros)."""
+    n_tiles, e = rows.shape
+    n_blocks = r // block_rows
+    a = jnp.clip(first - lo, 0, r).reshape(-1)
+    b = jnp.clip(first + rows - lo, 0, r).reshape(-1)
+    first_block = jnp.minimum(a // block_rows, n_blocks - 1)
+    blocks = jnp.where(b > a, (b - 1) // block_rows - first_block + 1, 0)
+    blocks = jnp.maximum(blocks, jnp.arange(n_tiles * e) % e == 0)
+    item_end = jnp.cumsum(blocks)
+    n_items = item_end[-1]
+    n = jnp.minimum(jnp.arange(n_blocks + n_tiles * e), n_items - 1)
+    pair = jnp.minimum(jnp.searchsorted(item_end, n, side="right"),
+                       n_tiles * e - 1)
+    block = first_block[pair] + n - (item_end - blocks)[pair]
+    # (one tile more than the bound: the kernel looks one item ahead)
+    return tuple(v.astype(jnp.int32) for v in (
+        jnp.pad(pair // e, (0, 1), constant_values=-1), block, pair % e,
+        a[pair], b[pair], n_items.reshape(1)))
+
+
+def _zeros_behind(rows, sort: "_Dispatch", p=0,
+                  block_rows: int = COMBINE_ROWS):
+    """Page p's sorted rows [r, D] with zeros from the held experts' last
+    row to the end of its block of `block_rows`: what `moe_combine` may be
+    given (the block's rows meet zeros of its 0/1 matrix, and 0 x nan is
+    nan; blocks behind that one are in no work item).  One block is
+    rewritten where it lies."""
+    r = rows.shape[0]
+    end = jnp.clip(jnp.sum(sort.load) - p * r, 0, r)
+    at = jnp.clip(end // block_rows * block_rows, 0,
+                  max(r - block_rows, 0))
+    last = jax.lax.dynamic_slice_in_dim(rows, at, min(block_rows, r))
+    last = jnp.where(at + jnp.arange(last.shape[0])[:, None] < end, last,
+                     jnp.zeros_like(last))
+    return jax.lax.dynamic_update_slice_in_dim(rows, last, at, 0)
+
+
+@functools.partial(jax.jit, static_argnames=("k", "tile", "block_rows",
+                                             "interpret"))
+def moe_combine(rows, sort: "_Dispatch", k: int, weights=None, *, tile: int,
+                p=0, block_rows: int = COMBINE_ROWS,
+                interpret: Optional[bool] = None):
+    """The experts' sorted rows back at their tokens.  rows [r, D] are
+    sorted rows p r to (p + 1) r of `sort` (what the held experts made of
+    them; behind the held experts' rows finite to their block's end,
+    `_zeros_behind`, then anything; a page that is not the whole sort is
+    whole blocks, r a multiple of `block_rows`); weights
+    [T, k] float32 or None for ones.  out[token] = the sum over the token's
+    assignments with a held expert and a row in the page of weight x row,
+    float32 products summed in float32 in the experts' order, [T, D] in
+    rows' dtype: `_gathered` without its [T x k, D] gather (`moe_combine`
+    in a device trace).  A token's k experts are distinct, as a top-k's
+    are.  `tile`: the tokens a work item takes."""
+    if interpret is None:
+        interpret = _interpret_kernels()
+    r, d = rows.shape
+    t = sort.flat.shape[0] // k
+    assert r == t * k or r % block_rows == 0, (r, block_rows)
+    rows = _rows_padded(rows, block_rows)
+    tok = _rows_padded((sort.order // k).astype(jnp.int32)[:, None],
+                       block_rows).reshape(-1, 1, block_rows)
+    last_block = tok.shape[0] - 1
+    items = _combine_items(*_tile_runs(sort, k, tile), p * r, rows.shape[0],
+                           block_rows)
+    page_block = jnp.asarray(p * (r // block_rows), jnp.int32).reshape(1)
+    at_tile = pl.BlockSpec((tile, k), lambda n, it, *_: (it[n], 0))
+    operands = [] if weights is None else [
+        _rows_padded(sort.flat.reshape(t, k), tile),
+        _rows_padded(weights.astype(jnp.float32), tile)]
+    n_tiles = -(-t // tile)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        # item tile/block/expert, its run's ends, the count, the page
+        num_scalar_prefetch=7,
+        grid=(items[1].shape[0],),
+        in_specs=[
+            pl.BlockSpec((None, 1, block_rows),
+                         lambda n, it, ib, ig, lo, hi, c, pb:
+                         (jnp.minimum(pb[0] + ib[n], last_block), 0, 0)),
+            *[at_tile] * len(operands),
+            pl.BlockSpec((block_rows, d), lambda n, it, ib, *_: (ib[n], 0))],
+        out_specs=pl.BlockSpec((tile, d), lambda n, it, *_: (it[n], 0)),
+        scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)],
+    )
+    out = pl.pallas_call(
+        functools.partial(_combine_kernel, tile=tile, block_rows=block_rows),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_tiles * tile, d), rows.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=64 * 1024 * 1024),
+        name="moe_combine",
+    )(*items, page_block, tok, *operands, rows)
+    return out[:t] if out.shape[0] != t else out
+
+
 class _Dispatch(NamedTuple):
     """The sort behind `expert_ffn`: `flat` [T * k] each assignment's
     expert among those held (`experts`: none of them), `order` the
@@ -372,12 +553,25 @@ def _page(p, r: int, sort: _Dispatch, masks):
     return order, load, jnp.clip(sort.rank - lo, 0, r - 1), here
 
 
+def _gathered(rows, rank, here, k: int, weights=None):
+    """`moe_combine` by a gather of EVERY assignment's row, [T x k, D]: its
+    form where the tokens fill no tile."""
+    t = rank.shape[0] // k
+    y = rows[rank].reshape(t, k, -1).astype(jnp.float32)
+    if here is not None:
+        y = jnp.where(here.reshape(t, k, 1), y, 0.0)   # rows no expert wrote
+    if weights is None:
+        return jnp.sum(y, 1).astype(rows.dtype)
+    out = jnp.einsum("tk,tkd->td", weights.astype(jnp.float32), y)
+    return out.astype(rows.dtype)
+
+
 def _experts_at(p, r: int, x, weights, w_gate, w_up, w_down, layer, sort,
                 masks, static):
     """`expert_ffn` over page `p` of `r` sorted rows.  Returns (y [T, D],
     what the backward reads again: the products g and u [r, F] (g None
     without a gate) and the experts' results ys [r, D])."""
-    k, up_transposed, block_m = static
+    k, up_transposed, block_m, tile = static
     t, d = x.shape
     order, load, rank, here = _page(p, r, sort, masks)
     mm = functools.partial(grouped_matmul, group_sizes=load, layer=layer,
@@ -387,11 +581,11 @@ def _experts_at(p, r: int, x, weights, w_gate, w_up, w_down, layer, sort,
     u = mm(xs, w_up, transposed=up_transposed)
     hidden = _act(g, u)
     ys = mm(hidden, w_down)                                        # [r, D]
-    y = ys[rank].reshape(t, k, d).astype(jnp.float32)
-    if here is not None:
-        y = jnp.where(here.reshape(t, k, 1), y, 0.0)   # rows no expert wrote
-    out = jnp.einsum("tk,tkd->td", weights.astype(jnp.float32), y)
-    return out.astype(x.dtype), (g, u, ys)
+    if tile:
+        ys = _zeros_behind(ys, sort, p)
+        return moe_combine(ys, sort, k, weights, tile=tile,
+                           p=jnp.int32(p)), (g, u, ys)
+    return _gathered(ys, rank, here, k, weights), (g, u, ys)
 
 
 def _experts_bwd_at(p, r: int, x, weights, w_gate, w_up, w_down, layer, sort,
@@ -402,7 +596,7 @@ def _experts_bwd_at(p, r: int, x, weights, w_gate, w_up, w_down, layer, sort,
     round: a token's dx is the sum of its k sorted rows' (those of experts
     held elsewhere masked: no kernel wrote them), a sorted row's dy is its
     token's times its weight.  No scatter."""
-    k, up_transposed, block_m = static
+    k, up_transposed, block_m, tile = static
     t, d = x.shape
     interpret = _interpret_kernels()
     g, u, ys = saved
@@ -436,11 +630,13 @@ def _experts_bwd_at(p, r: int, x, weights, w_gate, w_up, w_down, layer, sort,
     if g is not None:
         more, dw_gate = back(xs, w_gate, dg)
         dxs = dxs + more
-    dx = dxs[rank].reshape(t, k, d)
     if here is not None:
-        dx = jnp.where(here.reshape(t, k, 1), dx, jnp.zeros_like(dx))
         dweights = jnp.where(here, dweights, 0.0)
-    dx = jnp.sum(dx.astype(jnp.float32), 1).astype(x.dtype)
+    if tile:
+        dx = moe_combine(_zeros_behind(dxs, sort, p), sort, k, tile=tile,
+                         p=jnp.int32(p))
+    else:
+        dx = _gathered(dxs, rank, here, k)
     return (dx, dweights.reshape(t, k).astype(weights.dtype), dw_gate,
             dw_up, dw_down)
 
@@ -495,7 +691,8 @@ _experts.defvjp(_experts_fwd, _experts_bwd)
 
 def expert_ffn(x, expert_ids, expert_weights, w_gate, w_up, w_down,
                layer=0, valid=None, first_held=None, up_transposed=False,
-               rows=None, block_m: int = 128):
+               rows=None, block_m: int = 128,
+               token_tile: Optional[int] = None):
     """Dropless experts: SwiGLU over three matrices
     (`w_down (silu(w_gate x) * (w_up x))`), or with `w_gate` None a
     squared ReLU over two (`w_down relu(w_up x)^2`).  x [T, D]; expert_ids
@@ -523,10 +720,18 @@ def expert_ffn(x, expert_ids, expert_weights, w_gate, w_up, w_down,
     experts take, each is multiplied: none can be dropped, whatever the
     router does.  `block_m`: the row tile of the grouped products.
 
+    `token_tile`: the experts' rows go back to their tokens through
+    `moe_combine`, this many tokens a work item (None: through the gather
+    of every assignment's row, `_gathered`; the same float32 sum either
+    way, a token's k terms in the experts' order or the slots').  A page is
+    then whole blocks of `COMBINE_ROWS` sorted rows (`rows` rounded up).
+
     Differentiable in x, expert_weights and the three matrices
     (`_experts_bwd_at`).  Returns (y [T, D], load [E] int32: the
     assignments each expert took)."""
     t, k = expert_ids.shape
+    if token_tile and rows is not None:
+        rows = -(-rows // COMBINE_ROWS) * COMBINE_ROWS
     sort = _dispatch(expert_ids, w_down.shape[-3], valid, first_held)
     if first_held is not None:
         masks = sort.flat < w_down.shape[-3]
@@ -537,5 +742,5 @@ def expert_ffn(x, expert_ids, expert_weights, w_gate, w_up, w_down,
     y = _experts(x, expert_weights, w_gate, w_up, w_down,
                  jnp.asarray(layer, jnp.int32).reshape(1), sort, masks,
                  t * k if rows is None else min(rows, t * k),
-                 (k, up_transposed, block_m))
+                 (k, up_transposed, block_m, token_tile))
     return y, sort.load

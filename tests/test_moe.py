@@ -280,6 +280,195 @@ def test_expert_ffn_gradients_match_the_dense_loop(case):
     assert int(load[1]) == 0 and not np.asarray(grads[-1])[1].any()
 
 
+def _routing(rng, t, k, routed, kind, first, held):
+    """[t, k] distinct experts a token, drawn as `kind` says."""
+    ids = np.stack([rng.permutation(routed)[:k] for _ in range(t)])
+    if kind == "one_expert_takes_everyone":
+        taker = (first or 0) + 2
+        for row in ids:                 # `taker` first, the others distinct
+            rest = [e for e in row if e != taker][:k - 1]
+            row[:] = [taker] + rest
+    elif kind == "tokens_without_a_held_expert":
+        elsewhere = [e for e in range(routed)
+                     if not first <= e < first + held]
+        for i in list(range(16, 32)) + list(range(40, t, 3)):
+            ids[i] = rng.permutation(elsewhere)[:k]     # a whole tile too
+    return jnp.asarray(ids, jnp.int32)
+
+
+# (tokens, k, routed experts, held, the first held or None for all, the
+# page's sorted rows or None for all, token tile, block of sorted rows, the
+# router, padding tokens, the forward's weights or dx's ones, dtype)
+COMBINE = {
+    "an_even_router": (64, 2, 8, 8, None, None, 16, 8, "even", False, True,
+                       jnp.float32),
+    "one_expert_takes_every_token": (64, 2, 8, 8, None, None, 16, 8,
+                                     "one_expert_takes_everyone", False,
+                                     True, jnp.float32),
+    "tokens_with_no_held_expert": (64, 4, 16, 4, 4, None, 16, 8,
+                                   "tokens_without_a_held_expert", False,
+                                   True, jnp.float32),
+    "runs_across_blocks_and_blocks_of_two_experts": (
+        96, 4, 8, 8, None, None, 32, 16, "even", False, True, jnp.float32),
+    "tokens_no_multiple_of_the_tile": (37, 3, 8, 8, None, None, 16, 8,
+                                       "even", False, True, jnp.float32),
+    "a_share_in_pages": (50, 4, 16, 8, 4, 32, 16, 8, "even", False, True,
+                         jnp.float32),
+    "a_share_in_pages_one_expert_takes_everyone": (
+        50, 4, 16, 4, 8, 16, 8, 8, "one_expert_takes_everyone", False, True,
+        jnp.float32),
+    "padding_tokens": (41, 4, 16, 4, 0, 48, 16, 8, "even", True, True,
+                       jnp.float32),
+    "weights_of_one_the_dx_form": (50, 4, 16, 8, 4, 32, 16, 8, "even",
+                                   False, False, jnp.float32),
+    "the_dx_form_over_all_rows": (64, 2, 8, 8, None, None, 16, 8, "even",
+                                  False, False, jnp.float32),
+    "bf16_rows_and_float32_weights": (64, 4, 16, 8, 4, 128, 32, 16, "even",
+                                      False, True, jnp.bfloat16),
+    "a_tile_of_all_the_tokens": (24, 2, 8, 8, None, None, 32, 8, "even",
+                                 False, True, jnp.float32),
+}
+
+
+@pytest.mark.parametrize("case", list(COMBINE))
+def test_moe_combine_matches_the_gather_of_every_assignments_row(case):
+    """`moe_combine` over the sort's runs against `_gathered` (`ys[rank]`,
+    a `where` and the weighted sum over k), page by page: what the held
+    experts did not write holds NaN, which must reach no sum (behind the
+    held experts' last block no work item reads, and `_zeros_behind` clears
+    that block's end); a token with no held expert, and a whole tile
+    of them, comes out zeros; the pages' results add up to the unpaged
+    one's.  Float32 rows differ by the order of a token's k additions
+    alone; bf16 rows by one rounding of that sum."""
+    (t, k, routed, held, first, rows, tile, block_rows, kind, padded,
+     weighted, dtype) = COMBINE[case]
+    rng = np.random.default_rng(t + k)
+    ids = _routing(rng, t, k, routed, kind, first, held)
+    valid = jnp.asarray(rng.random(t) < 0.7) if padded else None
+    sort = moe._dispatch(ids, held, valid, first)
+    masks = (sort.flat < held if first is not None
+             else None if valid is None else jnp.repeat(valid, k))
+    n = t * k
+    taken = int(sort.load.sum())
+    made = jnp.asarray(rng.normal(size=(n, 24)), dtype)
+    made = jnp.where(jnp.arange(n)[:, None] < taken, made, jnp.nan)
+    weights = (jnp.asarray(rng.random(size=(t, k)), jnp.float32)
+               if weighted else None)
+    r = rows or n
+    pages = -(-taken // r)
+    if kind == "one_expert_takes_everyone":
+        assert int(sort.load.max()) == t
+    if "pages" in case:
+        assert pages > 1
+    total = want_total = 0.0
+    for p in range(max(pages, 1)):
+        page = jnp.take(made, p * r + jnp.arange(r), axis=0, mode="clip")
+        _, _, rank, here = moe._page(p, r, sort, masks)
+        want = moe._gathered(page, rank, here, k, weights)
+        got = moe.moe_combine(
+            moe._zeros_behind(page, sort, p, block_rows), sort, k, weights,
+            tile=tile, p=p, block_rows=block_rows)
+        assert got.shape == (t, 24) and got.dtype == dtype
+        np.testing.assert_allclose(
+            got.astype(jnp.float32), want.astype(jnp.float32),
+            atol=1e-6 if dtype == jnp.float32 else 0,
+            rtol=0 if dtype == jnp.float32 else 2 ** -7)
+        total = total + got.astype(jnp.float32)
+        want_total = want_total + want.astype(jnp.float32)
+    assert np.isfinite(np.asarray(total)).all()
+    assert float(jnp.abs(want_total).max()) > 1.0
+    none_held = np.asarray((sort.flat.reshape(t, k) == held).all(1))
+    if kind == "tokens_without_a_held_expert":
+        assert none_held[16:32].all()
+    assert not np.asarray(total)[none_held].any()
+    if rows is not None and dtype == jnp.float32:     # and against no pages
+        whole = moe.moe_combine(
+            moe._zeros_behind(made, sort, block_rows=block_rows), sort, k,
+            weights, tile=tile, block_rows=block_rows)
+        np.testing.assert_allclose(total, whole, atol=1e-5)
+
+
+# (tokens, k, routed, held, the first held or None, `rows`, padding rows,
+# relu2 experts held transposed)
+COMBINE_GRADS = {
+    "all_experts_held": (70, 2, 8, 8, None, None, False, False),
+    "a_share_in_two_pages": (100, 4, 16, 8, 4, 128, False, False),
+    "a_bound_and_padding_rows": (90, 4, 16, 8, 0, 128, True, False),
+    "relu2_experts_held_transposed": (70, 2, 8, 4, 2, None, False, True),
+}
+
+
+@pytest.mark.parametrize("case", list(COMBINE_GRADS))
+def test_expert_ffn_gradients_with_the_combine_kernel_and_without(case):
+    """`expert_ffn(token_tile=)` (the rows back through `moe_combine`,
+    forward and dx) against the same call without it and against the dense
+    loop: y and the gradients in x, the weights and the matrices agree to
+    float32 reassociation, the load is the same, nothing is dropped."""
+    t, k, routed, held, first, rows, padded, relu2 = COMBINE_GRADS[case]
+    d, f = 32, 24
+    rng = np.random.default_rng(t + k)
+    x = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    ids = _routing(rng, t, k, routed, "even", first, held)
+    weights = jnp.asarray(rng.random(size=(t, k)), jnp.float32)
+    valid = jnp.asarray(rng.random(t) < 0.7) if padded else None
+    w_gate = None if relu2 else jnp.asarray(
+        rng.normal(size=(held, d, f)), jnp.float32) / 4
+    w_up = jnp.asarray(rng.normal(size=(held, f, d) if relu2
+                                  else (held, d, f)), jnp.float32) / 4
+    w_down = jnp.asarray(rng.normal(size=(held, f, d)), jnp.float32) / 4
+    cot = jnp.asarray(rng.normal(size=(t, d)), jnp.float32)
+    share = first if held < routed else None
+
+    def through(token_tile):
+        def loss(x, weights, w_gate, w_up, w_down):
+            y, load = moe.expert_ffn(
+                x, ids, weights, w_gate, w_up, w_down, valid=valid,
+                first_held=share, up_transposed=relu2, rows=rows,
+                block_m=16, token_tile=token_tile)
+            return jnp.sum(y * cot), (y, load)
+        return loss
+
+    def dense(x, weights, w_gate, w_up, w_down):
+        return jnp.sum(_dense_experts(
+            x, ids, weights, w_gate, w_up, w_down, first or 0, valid,
+            relu2) * cot)
+
+    args = (x, weights, w_gate, w_up, w_down)
+    wrt = tuple(i for i, a in enumerate(args) if a is not None)
+    (_, (y, load)), grads = jax.value_and_grad(
+        through(32), wrt, has_aux=True)(*args)
+    (_, (y_plain, load_plain)), plain = jax.value_and_grad(
+        through(None), wrt, has_aux=True)(*args)
+    want = jax.grad(dense, wrt)(*args)
+    np.testing.assert_allclose(y, y_plain, atol=1e-5)
+    np.testing.assert_array_equal(load, load_plain)
+    for g, same, w in zip(grads, plain, want):
+        np.testing.assert_allclose(g, same, atol=1e-5)
+        np.testing.assert_allclose(g, w, atol=2e-5)
+        assert float(jnp.abs(w).max()) > 1e-3
+    if rows is not None and not padded:
+        assert int(load.sum()) > rows        # a page behind the first ran
+
+
+@pytest.mark.parametrize("trace, want", [
+    ({"busy_s": 2.0, "kernels": {"moe_combine": {"calls": 50,
+                                                 "seconds": 0.08}}}, 4.0),
+    ({"busy_s": 2.0, "kernels": {}}, None),      # a parent of the kernel's PR
+    ({"busy_s": 0.0, "kernels": {"moe_combine": {"calls": 0,
+                                                 "seconds": 0.0}}}, None),
+    (None, None)], ids=["traced", "no_such_kernel", "nothing_ran",
+                        "untraced"])
+def test_the_combines_share_reads_its_kernel_or_nothing(trace, want):
+    from benchmark import manifest
+    read = manifest.module("layer_metrics", "moe_combine_share_pct").read
+    got = read({"trace": trace})
+    assert got is None if want is None else got == pytest.approx(want)
+    entry = manifest.load().per_layer["moe_combine_share_pct"]
+    assert entry["workloads"] == ["train_mellum2_8k_ep4share"]
+    assert (entry["better"], entry["moves"]) == ("lower",
+                                                 "train_tokens_per_s")
+
+
 def test_no_token_is_dropped_when_one_expert_takes_every_token():
     """A router biased so that expert 3 is every token's first choice and
     expert 5 nobody's (a feature every token's embedding shares, and two

@@ -280,8 +280,10 @@ def test_expert_load_is_fetched_by_stats_alone_and_kernels_keep_their_names():
         "attention.py": re.findall(
             r'name="(\w+)"', (PKG / "ops" / "attention.py").read_text())}
     # (the grouped multiply's backward has a kernel of its own since PR 61,
-    # `dw`; forward and dx are the one kernel under the one name)
-    assert names["moe.py"] == ["moe_grouped_matmul_dw", "moe_grouped_matmul"]
+    # `dw`; forward and dx are the one kernel under the one name; since PR
+    # 62 the train path's way back to the tokens is `moe_combine`)
+    assert names["moe.py"] == ["moe_grouped_matmul_dw", "moe_grouped_matmul",
+                               "moe_combine"]
     assert "paged_decode_attention" in names["attention.py"]
 
 

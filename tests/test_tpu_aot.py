@@ -1703,6 +1703,9 @@ def test_a_cells_programs_fit_a_v5e_and_leave_their_buffers_in_place(
             assert sum(copied.get(k, 0) for k in kinds) <= most, copied
     counts = _kernel_counts(text)
     assert set(counts) == set(row.kernels)
+    # (a served iteration's tokens fill no tile: its experts' rows go back
+    # by `ys[rank]`, `decoder.COMBINE_FROM`)
+    assert "moe_combine" not in counts
     for name, often in row.kernels.items():
         assert often is None or counts[name] == often, (name, counts)
     if row.extra:
@@ -1726,7 +1729,9 @@ def test_the_moe_train_cells_step_fits_a_v5e_and_adds_no_expert_stack(
     too, and once more in the loops over the pages behind it, which run
     only when routing passes the bound; a run's fifteen: the first page's
     three and their three dx, the later pages' three, made again in their
-    backward's loop beside their three dx), and the loss head's two.  The experts are scanned with
+    backward's loop beside their three dx), the combine of the experts'
+    rows (a run's four: the first page's y and dx, the later pages' in
+    their two loops), and the loss head's two.  The experts are scanned with
     their layer and the runs' layers are their stack's `lax.split`: no two
     cotangents of a stack's size are summed anywhere (a layer's gradient
     goes into its place by a dynamic-update-slice, the two runs' into
@@ -1748,7 +1753,11 @@ def test_the_moe_train_cells_step_fits_a_v5e_and_adds_no_expert_stack(
     counts = _kernel_counts(text)
     assert counts == {"window_flash_attention": 2, "flash_attention": 2,
                       "moe_grouped_matmul": 30, "moe_grouped_matmul_dw": 12,
-                      "logits_lse": 1, "loss_head_grads": 1}, counts
+                      "moe_combine": 8, "logits_lse": 1,
+                      "loss_head_grads": 1}, counts
+    # the experts' rows go back to their tokens through `moe_combine`: no
+    # operation makes a row for every one of the 131,072 assignments
+    assert not re.findall(r" = \w+\[131072,2304\]", text)
     # (`add_any` is a sum of cotangents; the optimizer's own sums over its
     # moments, once a step, are not)
     stack = r"f32\[\d,16,(2304,896|896,2304)\]"
