@@ -104,9 +104,6 @@ def kernels_leg() -> dict:
 
     errs = {}
     key = jax.random.key(0)
-    q, k, v, g = (jax.random.normal(jax.random.fold_in(key, i),
-                                    (4, 1024, 12, 64), jnp.bfloat16)
-                  for i in range(4))
 
     def flash(q, k, v):
         return A.flash_attention(q, k, v, causal=True, interpret=False)
@@ -114,17 +111,28 @@ def kernels_leg() -> dict:
     def ref(q, k, v):
         return A.reference_attention(q, k, v, causal=True)
 
-    def grads(fn):
-        return jax.grad(lambda q, k, v: jnp.sum(
-            fn(q, k, v).astype(jnp.float32) * g.astype(jnp.float32)),
-            argnums=(0, 1, 2))
+    # gpt2-small's heads, and a kv head's group of 4 query heads of 128 over
+    # 2,048 positions (a grid step of the backward takes two of them and
+    # holds bytes of the whole length beside its blocks)
+    for name, (batch, length, heads, kv_heads, d) in (
+            ("flash", (4, 1024, 12, 12, 64)),
+            ("flash_group", (1, 2048, 8, 2, 128))):
+        q, k, v, g = (jax.random.normal(
+            jax.random.fold_in(key, i),
+            (batch, length, kv_heads if i in (1, 2) else heads, d),
+            jnp.bfloat16) for i in range(4))
 
-    errs["flash_fwd"] = check("flash fwd", compiled(flash, q, k, v)(q, k, v),
-                              ref(q, k, v))
-    for name, got, want in zip(("dq", "dk", "dv"),
-                               compiled(grads(flash), q, k, v)(q, k, v),
-                               jax.jit(grads(ref))(q, k, v)):
-        errs[f"flash_{name}"] = check(f"flash {name}", got, want)
+        def grads(fn):
+            return jax.grad(lambda q, k, v: jnp.sum(
+                fn(q, k, v).astype(jnp.float32) * g.astype(jnp.float32)),
+                argnums=(0, 1, 2))
+
+        errs[f"{name}_fwd"] = check(
+            f"{name} fwd", compiled(flash, q, k, v)(q, k, v), ref(q, k, v))
+        for grad, got, want in zip(("dq", "dk", "dv"),
+                                   compiled(grads(flash), q, k, v)(q, k, v),
+                                   jax.jit(grads(ref))(q, k, v)):
+            errs[f"{name}_{grad}"] = check(f"{name} {grad}", got, want)
 
     # the loss head's kernel at gpt2-small's head, a row tile of a chunk
     from ray_tpu.ops import cross_entropy as C

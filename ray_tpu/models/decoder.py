@@ -556,18 +556,12 @@ def _attn_gate(attn, h, p, sizes: HeadSizes):
 
 def heads_attention(h, p, spec, config, mesh, position_offset=0):
     """Multi-head or grouped-query attention with per-head K and V, by the
-    flash kernels, which take a run's `window`; a length that does not
-    tile runs their XLA form, all L x L scores under the mask."""
+    flash kernels, which take a run's `window` and K and V at their own
+    `n_kv_heads`; a length that does not tile runs their XLA form, all
+    L x L scores under the mask."""
     c = head_sizes(spec, config)
     q, k, v = _qkv(spec, h, p, c, _to_heads_side_by_side)
     q, k = _rotated(q, c, position_offset), _rotated(k, c, position_offset)
-    if c.n_kv_heads < c.n_heads:
-        # GQA: each kv head serves n_heads / n_kv_heads query heads.
-        # Materializing the repeat keeps the attention kernels
-        # head-uniform; XLA fuses the broadcast into the kernel operand
-        # load.
-        k = jnp.repeat(k, c.n_heads // c.n_kv_heads, axis=2)
-        v = jnp.repeat(v, c.n_heads // c.n_kv_heads, axis=2)
     # constrained as the kernels read it, the heads side by side: a
     # [B, L, heads, 64] value that stands on its own is copied on both sides
     q = with_logical_constraint(

@@ -58,11 +58,16 @@ def _log_reference_path(op: str, shape: tuple) -> None:
 def reference_attention(q, k, v, *, causal: bool = True,
                         scale: Optional[float] = None,
                         segment_ids=None, window: int = 0) -> jax.Array:
-    """Plain XLA attention (fallback + ground truth for kernel tests);
-    `window`: a position attends the last `window` positions alone, its
-    own among them."""
+    """Plain XLA attention (fallback + ground truth for kernel tests) of
+    q [batch, length, heads, d] and k, v [batch, length, kv_heads, d], a
+    kv head serving heads // kv_heads query heads that follow one another
+    (repeated here: the oracle, not the path); `window`: a position
+    attends the last `window` positions alone, its own among them."""
     d = q.shape[-1]
     scale = scale if scale is not None else 1.0 / np.sqrt(d)
+    if k.shape[2] != q.shape[2]:
+        k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
+                for x in (k, v))
     logits = jnp.einsum("bqhd,bkhd->bhqk", q, k) * scale
     mask = _build_mask(q.shape[1], k.shape[1], causal, segment_ids)
     if window:
@@ -148,7 +153,37 @@ def _build_mask(q_len, k_len, causal, segment_ids):
 # and K and give the head's 64 rows of the transposed accumulators O^T and dq^T
 # [128, q], which are transposed once into lane-dense [q, 128] stores.
 # (V^T P over all 128 lanes with half the rows thrown away was 16% slower
-# in the forward: PERF.md section 6, PR 51.)  A head count that is odd at
+# in the forward: PERF.md section 6, PR 51.)
+#
+# How a group lies: k and v are [batch, length, kv_heads x d], the model's
+# own few heads, and a kv head's `group` query heads follow one another in
+# q's columns (query head h reads kv head h // group), so a kv-side block
+# of one head of 128 or 256 columns has its group's `group` blocks of q, dO
+# and O right beside one another: a grid step takes the kv block and
+# `part` of them as ONE q-side block [block, part x lanes] (`_block_heads`).
+# The group's heads multiply the SAME K tile and the SAME V tile.  In the
+# forward their products are the two heads' one product without its
+# selects: the q tiles of `wide` heads one under the other (`_heads_rows`,
+# each head's own columns whole) give S^T [kv, wide x q] in one product of
+# K, its statistics ONE [1, wide x q] row, and V^T P one product [d, wide x
+# q] (`_flash_fwd_tiles`, `_FLASH_FWD_COLUMNS`).  The backward's products
+# stay a kv block's own heads' (the matrix units are its already): a
+# group's heads follow one another on the K and V tile that was copied in
+# once for them all, and each adds its P^T dO and dS^T Q to the same dv and
+# dk, which IS the sum over the group: dk and dv gather in float32 over
+# all of a group's heads and leave the kernel [batch, length, kv_heads x
+# d], and no array of the query heads' count ever holds K, V, dk or dv.
+# How many heads a grid step takes is what its straight-line code and VMEM
+# hold (`_flash_plan`, `_FLASH_FWD_PAIRS`; the backward keeps dq^T of the
+# whole length): where that is a part of the group, the parts follow one
+# another on the grid's second axis while dk and dv gather in a scratch of
+# the whole length, and the part's K and V blocks are copied in once a
+# part, not once a head.  A group of heads narrower than a block's 128
+# lanes (two kv heads a block, their groups' lanes not aligned with their
+# own) has K and V repeated to the query heads' count in HBM, as every
+# group had before PR 63, and runs as a group of one (`_FlashPlan.spread`):
+# dk and dv then leave the kernels a query head each and XLA sums them.
+# A head count that is odd at
 # 64 leaves the last block half empty: what the copy brought past the last
 # column is selected to zero in K, V, dO and O as the other head's lanes
 # are, the half block's second head computes on zeros, and the partial
@@ -173,9 +208,32 @@ def _build_mask(q_len, k_len, causal, segment_ids):
 _FLASH_FWD_TILE = 512
 _FLASH_BWD_TILE = 512
 _FLASH_BWD_CROSSED = 256
+# A group's heads (PR 63, at [2,8192,32,128] over 4 kv heads, ms a call
+# forward | backward, without a window / under one of 1,024; the kernels
+# before groups, K and V repeated: 10.79 | 15.98 / 3.26 | 4.82).  Columns at
+# most of a score product that a group's heads share in the forward, which
+# halves its tile down to 256 until the heads of a grid step fit in them
+# (four heads at 256: 7.79 / 2.21; eight at 256, 2,048 columns: 7.56 / 2.17;
+# eight at 512: 7.77 / 2.49).  The backward's products stay a kv head's own
+# (15.32 / 4.52 where two heads a product read 15.30 / 4.47: what it gains
+# of a group is K and V copied in once and dk and dv summed in VMEM; four
+# heads a step and a product read 14.77 / 4.02 and sat at 64.3 thousand
+# bundles).  And the (q, kv) pairs at most, over its heads, of the
+# block that a grid step's straight-line code walks: a kernel past 65,536
+# bundles runs at half its speed (the forward of eight heads at 66-68
+# thousand 17.8-18.7 where 64 thousand reads 7.6, the backward of four at
+# 67-69 thousand 35.3-36.3 where 64.3 reads 14.8; `scripts/flash_bundles.py`
+# counts them), so four heads of a block of 1,024 forward (33 thousand) and
+# two backward, and compiles in a quarter of the time.  The pairs are a
+# head of 128 columns'; one of 256 counts twice (two heads of a group of 4
+# at 256 compiled to 65.7 thousand backward, 56.6 four forward).
+_FLASH_FWD_COLUMNS = 1024
+_FLASH_FWD_PAIRS = 4 * 1024 * 1024
+_FLASH_BWD_PAIRS = 2 * 1024 * 1024
 # Scoped VMEM a kernel may ask for beyond the compiler's default 16 MiB
 # (the backward holds a head's dq: 8 bytes a q position and column).
 _FLASH_VMEM_LIMIT = 96 * 1024 * 1024
+_FLASH_VMEM_DEFAULT = 16 * 1024 * 1024
 
 _NT = (((1,), (1,)), ((), ()))      # a @ b.T
 _NN = (((1,), (0,)), ((), ()))      # a @ b
@@ -304,14 +362,24 @@ def _only_lanes(x, lo, hi):
     return jnp.where((lane >= lo) & (lane < hi), x, jnp.zeros_like(x))
 
 
-def _block_heads(lanes, d, width, block):
-    """How the heads of column block `block` lie in its `lanes` columns of
-    arrays `width` (heads x d) columns wide: `whole(x)`, x [rows, lanes]
-    with the lanes past the arrays' last column zero, and for each of the
-    lanes // d heads `(rows, only)`: the head's lanes of a [positions,
-    lanes] value, which are its rows of a transposed [lanes, positions]
-    accumulator, and `only(x)`, x with every lane that is not the head's
-    zero.  A block that is one whole head selects nothing."""
+class _Head(NamedTuple):
+    """A query head of a grid step (`_block_heads`)."""
+    rows: slice         # its columns of the q-side block, which are its rows
+    #                     of a transposed accumulator (O^T, dq^T)
+    lanes: slice        # the kv block's width of those columns it lies in
+    kv: slice           # its kv head's columns of the kv-side block
+    only: object        # x [rows, that width] with every lane not its own 0
+
+
+def _block_heads(lanes, d, width, block, part=1):
+    """How the heads of column block `block` lie in their blocks: a kv-side
+    block is `lanes` columns (its kv heads: two of 64, or one) of arrays
+    that would be `width` (heads x d) columns wide at a group of one, and
+    the q-side block beside it `part` times that, each kv head's `part`
+    query heads.  `whole(x)`, x [rows, lanes] with the lanes past the
+    arrays' last column zero, and a `_Head` for each of the q-side block's
+    heads.  A head that has its width of lanes whole (128 or 256 columns:
+    every head of a group) selects nothing."""
     ragged = width % lanes != 0
     end = width - block * lanes     # `lanes` or more in all but a last half
 
@@ -322,33 +390,76 @@ def _block_heads(lanes, d, width, block):
             return lambda x: x
         return lambda x: _only_lanes(x, lo, hi)
 
-    return select(0, lanes), [(slice(lo, lo + d), select(lo, lo + d))
-                              for lo in range(0, lanes, d)]
+    def head(lo):       # from column `lo` of the q-side block
+        at, within = lo - lo % lanes, lo % lanes
+        return _Head(slice(lo, lo + d), slice(at, at + lanes),
+                     slice(within, within + d), select(within, within + d))
+
+    return select(0, lanes), [head(lo) for lo in range(0, part * lanes, d)]
 
 
 def _heads_rows(x, heads):
-    """x [rows, lanes] once a head of `heads` (`_block_heads`), one under
-    the other, [heads x rows, lanes]: a head's rows hold zeros in every
-    lane that is not its own, so that a product over all the lanes with
-    them is the head's."""
-    return jnp.concatenate([only(x) for _, only in heads])
+    """x [rows, a q-side block's columns] once a head of `heads`
+    (`_block_heads`), one under the other, [heads x rows, lanes]: a head's
+    rows are its kv block's width of x with zeros in every lane that is
+    not its own, so that a product over all the lanes with them is the
+    head's."""
+    return jnp.concatenate([h.only(x[:, h.lanes]) for h in heads])
+
+
+def _products(heads, wide):
+    """The heads of a grid step in runs of `wide` whose scores are one
+    product, each run [(the head's number in the step, head)]."""
+    heads = list(enumerate(heads))
+    return [heads[n:n + wide] for n in range(0, len(heads), wide)]
+
+
+def _of_a_kv_head(run):
+    """[(heads, first)]: a run's heads that share a kv head (two heads of 64
+    in a block: each alone; a group's: all), and where their columns begin
+    among the run's, in heads."""
+    first, found = 0, []
+    for _, heads in itertools.groupby(run, key=lambda h: h[1].kv):
+        found.append((list(heads), first))
+        first += len(found[-1][0])
+    return found
+
+
+def _beside(xs):
+    """[.., n] arrays side by side, [.., sum of n]; one is itself."""
+    return xs[0] if len(xs) == 1 else jnp.concatenate(xs, axis=1)
+
+
+def _apart(x, n):
+    """x [.., n x columns] as its n equal parts; one is itself."""
+    size = x.shape[1] // n
+    return [x] if n == 1 else [x[:, g * size:(g + 1) * size]
+                               for g in range(n)]
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
                   acc_ref, *, d: int, width: int, bq: int, bk: int,
-                  n_blocks: int, causal: bool, scale: float, window: int):
-    """Grid (batch x column block, q block, kv block): the online softmax
-    of a q block over the kv blocks up to its own, for each head of the
-    column block.  Refs: q, o [block_q, lanes]; k, v [block_k, lanes];
-    lse [heads, 1, block_q]; scratch m, l [heads, 1, block_q] and acc
-    [lanes, block_q] (O^T, unnormalised, a head's d rows under those of
-    the head before it), float32, carried between kv blocks.  With a
-    `window` the last axis is the kv blocks a q block visits, the farthest
-    first (`_window_steps`), not all of them."""
+                  n_blocks: int, causal: bool, scale: float, window: int,
+                  parts: int, wide: int):
+    """Grid (batch x kv column block, q block, kv block): the online softmax
+    of a q block over the kv blocks up to its own, for each query head of
+    the kv column block's heads.  Refs: k, v [block_k, lanes]; q, o
+    [block_q, part x lanes], a kv head's `part` query heads; lse [heads, 1,
+    block_q]; scratch m, l [heads, 1, block_q] and acc [part x lanes,
+    block_q] (O^T, unnormalised, a head's d rows under those of the head
+    before it), float32, carried between kv blocks.  `wide` heads' scores
+    are one product (`_products`), and of them those of one kv head share
+    their statistics' rows [1, heads x q] and V^T P.  With a `window` the
+    last axis is the kv blocks a q block visits, the farthest first
+    (`_window_steps`), not all of them; with `parts` the second is (part of
+    the group, q block)."""
     i, j, c = (pl.program_id(a) for a in range(3))
+    if parts > 1:
+        j = j % n_blocks
     n_q, n_kv = q_ref.shape[0] // bq, k_ref.shape[0] // bk
-    lanes = q_ref.shape[1]
-    whole, heads = _block_heads(lanes, d, width, i % pl.cdiv(width, lanes))
+    lanes = k_ref.shape[1]
+    whole, heads = _block_heads(lanes, d, width, i % pl.cdiv(width, lanes),
+                                q_ref.shape[1] // lanes)
 
     @pl.when(c == 0)
     def _init():
@@ -358,29 +469,38 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 
     def walk(tiles):
         for q0, tiles in itertools.groupby(tiles, key=lambda t: t[0]):
-            cols = slice(q0, q0 + bq)
-            q = _heads_rows(q_ref[cols, :], heads)
-            carry = [(m_ref[g, :, cols], l_ref[g, :, cols],
-                      acc_ref[rows, cols])
-                     for g, (rows, _) in enumerate(heads)]
-            for _, _, k0, _, *mask in tiles:
-                at = slice(k0, k0 + bk)
-                k, v = whole(k_ref[at, :]), v_ref[at, :]
-                scores = _scores_t(k, q, scale)
-                for g, (rows, _) in enumerate(heads):
-                    m, l, acc = carry[g]
-                    s = _masked(scores[:, g * bq:(g + 1) * bq], mask[0], bq,
-                                *mask[1:])
-                    m_new = jnp.maximum(m, jnp.max(s, axis=0, keepdims=True))
-                    p = jnp.exp(s - m_new)
-                    alpha = jnp.exp(m - m_new)
-                    l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
-                    acc = acc * alpha + _dot(v[:, rows], p.astype(v.dtype),
-                                             _TN)               # [d, bq]
-                    carry[g] = m_new, l, acc
-            for g, (rows, _) in enumerate(heads):
-                m_ref[g, :, cols], l_ref[g, :, cols], acc_ref[rows, cols] = (
-                    carry[g])
+            tiles, cols = list(tiles), slice(q0, q0 + bq)
+            q_tile = q_ref[cols, :]
+            for run in _products(heads, wide):
+                q = _heads_rows(q_tile, [h for _, h in run])
+                shares = _of_a_kv_head(run)
+                carry = [(_beside([m_ref[g, :, cols] for g, _ in of]),
+                          _beside([l_ref[g, :, cols] for g, _ in of]),
+                          _beside([acc_ref[h.rows, cols] for _, h in of]))
+                         for of, _ in shares]
+                for _, _, k0, _, *mask in tiles:
+                    at = slice(k0, k0 + bk)
+                    k, v = whole(k_ref[at, :]), v_ref[at, :]
+                    scores = _scores_t(k, q, scale)
+                    for n, (of, first) in enumerate(shares):
+                        m, l, acc = carry[n]
+                        s = _masked(
+                            scores[:, first * bq:(first + len(of)) * bq],
+                            mask[0], bq, *mask[1:])
+                        m_new = jnp.maximum(m, jnp.max(s, axis=0,
+                                                       keepdims=True))
+                        p = jnp.exp(s - m_new)
+                        alpha = jnp.exp(m - m_new)
+                        l = l * alpha + jnp.sum(p, axis=0, keepdims=True)
+                        acc = acc * alpha + _dot(
+                            v[:, of[0][1].kv], p.astype(v.dtype),
+                            _TN)                            # [d, heads x bq]
+                        carry[n] = m_new, l, acc
+                for (of, _), kept in zip(shares, carry):
+                    for (g, h), m, l, acc in zip(
+                            of, *(_apart(x, len(of)) for x in kept)):
+                        m_ref[g, :, cols], l_ref[g, :, cols] = m, l
+                        acc_ref[h.rows, cols] = acc
 
     if window:
         steps = pl.num_programs(2)
@@ -396,8 +516,8 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
     @pl.when(c == pl.num_programs(2) - 1)
     def _finalize():
         l_safe = jnp.maximum(l_ref[...], 1e-30)
-        for g, (rows, _) in enumerate(heads):
-            acc_ref[rows, :] = acc_ref[rows, :] / l_safe[g]
+        for g, h in enumerate(heads):
+            acc_ref[h.rows, :] = acc_ref[h.rows, :] / l_safe[g]
         o_ref[...] = acc_ref[...].T.astype(o_ref.dtype)
         lse_ref[...] = m_ref[...] + jnp.log(l_safe)
 
@@ -405,27 +525,38 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_ref, l_ref,
 def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                       dq_ref, dk_ref, dv_ref, dqt_ref, dk_acc, dv_acc, *,
                       d: int, width: int, bq: int, bk: int, least: int,
-                      causal: bool, scale: float, window: int):
-    """Grid (batch x column block, kv block, q block): dk and dv of a kv
+                      causal: bool, scale: float, window: int, parts: int):
+    """Grid (batch x kv column block, kv block, q block): dk and dv of a kv
     block over the q blocks from its own on, and every pair's share of dq,
-    for each head of the column block.
+    for each query head of the kv column block's heads.
     dS = P * (dO V^T - delta), delta = rowsum(dO * O); dv = P^T dO;
     dk = dS^T Q * scale; dq = dS K * scale.  Refs: k, v, dk, dv
-    [block_k, lanes]; q, dO, O [block_q, lanes]; lse [heads, 1, block_q];
-    dq [q_len, lanes],
+    [block_k, lanes]; q, dO, O [block_q, part x lanes], a kv head's `part`
+    query heads; lse [heads, 1, block_q]; dq [q_len, part x lanes],
     written at the column block's last grid step from the scratch dq^T
-    [q blocks, lanes, block_q]; scratch dk, dv [block_k, lanes]; float32.
-    With a head's Q and dO zero in the other head's lanes, dS^T Q and
-    P^T dO over the heads' q positions side by side are the packed dk and
-    dv.  A tile the diagonal crosses is walked as sub-tiles down to
-    `least` q and kv positions (`_tiles`).  With a `window` the last axis
-    is the q blocks that see the kv block, its own first (`_window_steps`),
-    not all of them."""
+    [q blocks, part x lanes, block_q]; scratch dk, dv [block_k, lanes];
+    float32.  A kv block's own heads (two of 64, or one) are one product
+    each way: with a head's Q and dO zero in the other head's lanes, dS^T Q
+    and P^T dO over the heads' q positions side by side are the packed dk
+    and dv; a group's heads follow one another on the same K and V tile
+    and add to the same dk and dv, which is the sum over the group.  A
+    tile the diagonal crosses is walked as sub-tiles down to `least` q and
+    kv positions (`_tiles`).  With a `window` the last axis is the q blocks
+    that see the kv block, its own first (`_window_steps`), not all of
+    them.  With `parts` the second axis is (part of the group, kv block),
+    the scratch dk, dv [kv blocks, block_k, lanes] gather over the parts,
+    and what a part writes of them the next overwrites."""
     i, j, c = (pl.program_id(a) for a in range(3))
-    last = ((j == pl.num_programs(1) - 1) & (c == pl.num_programs(2) - 1))
+    n_kv_blocks, first = pl.num_programs(1), None
+    if parts > 1:
+        n_kv_blocks = n_kv_blocks // parts
+        first, j = j < n_kv_blocks, j % n_kv_blocks
+        dk_acc, dv_acc = dk_acc.at[j], dv_acc.at[j]
+    last = ((j == n_kv_blocks - 1) & (c == pl.num_programs(2) - 1))
     n_q, n_kv = q_ref.shape[0] // bq, k_ref.shape[0] // bk
-    lanes = q_ref.shape[1]
-    whole, heads = _block_heads(lanes, d, width, i % pl.cdiv(width, lanes))
+    lanes = k_ref.shape[1]
+    whole, heads = _block_heads(lanes, d, width, i % pl.cdiv(width, lanes),
+                                q_ref.shape[1] // lanes)
     n_blocks = dqt_ref.shape[0]
     # the q block of this step, and whether the step is the first to add
     # to its dq
@@ -437,7 +568,7 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
     else:
         qb, opens = c, j == 0
 
-    @pl.when(c == 0)
+    @pl.when(c == 0 if first is None else (c == 0) & first)
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -451,32 +582,34 @@ def _flash_bwd_kernel(q_ref, k_ref, v_ref, do_ref, o_ref, lse_ref,
                                              key=lambda t: t[0] // bq):
             cols = slice(tile * bq, (tile + 1) * bq)
             q, do = q_ref[cols, :], do_ref[cols, :]
-            qs = [only(q) for _, only in heads]
-            dos = [only(do) for _, only in heads]
+            qs = [h.only(q[:, h.lanes]) for h in heads]
+            dos = [h.only(do[:, h.lanes]) for h in heads]
             # (dO * O)^T: a head's delta is the sum of its rows, a [1, q]
             # row like the logsumexp it stands beside
             do_o = (whole(do).astype(jnp.float32)
                     * whole(o_ref[cols, :]).astype(jnp.float32)).T
-            deltas = [jnp.sum(do_o[rows], axis=0, keepdims=True)
-                      for rows, _ in heads]
+            deltas = [jnp.sum(do_o[h.rows], axis=0, keepdims=True)
+                      for h in heads]
             for q0, nq, k0, nk, *mask in tiles:
                 at, part = slice(k0, k0 + nk), slice(q0, q0 + nq)
                 of = slice(q0 - tile * bq, q0 - tile * bq + nq)
                 k, v = whole(k_ref[at, :]), whole(v_ref[at, :])
-                # the heads one beside the other: [kv, heads x q]
-                q_all = jnp.concatenate([x[of] for x in qs])
-                do_all = jnp.concatenate([x[of] for x in dos])
-                lse = jnp.concatenate(
-                    [lse_ref[g, :, part] for g in range(len(heads))], axis=1)
-                delta = jnp.concatenate([x[:, of] for x in deltas], axis=1)
-                p = jnp.exp(_masked(_scores_t(k, q_all, scale), mask[0], nq,
-                                    *mask[1:]) - lse)
-                ds = (p * (_dot(v, do_all, _NT) - delta)).astype(q.dtype)
-                dv_acc[at, :] += _dot(p.astype(do.dtype), do_all)
-                dk_acc[at, :] += _dot(ds, q_all)
-                for g, (rows, _) in enumerate(heads):
-                    dqt_ref[qb, rows, part] += _dot(
-                        k[:, rows], ds[:, g * nq:(g + 1) * nq], _TN)
+                for run in _products(heads, lanes // d):
+                    # the heads one beside the other: [kv, heads x q]
+                    q_all = jnp.concatenate([qs[g][of] for g, _ in run])
+                    do_all = jnp.concatenate([dos[g][of] for g, _ in run])
+                    lse = jnp.concatenate(
+                        [lse_ref[g, :, part] for g, _ in run], axis=1)
+                    delta = jnp.concatenate(
+                        [deltas[g][:, of] for g, _ in run], axis=1)
+                    p = jnp.exp(_masked(_scores_t(k, q_all, scale), mask[0],
+                                        nq, *mask[1:]) - lse)
+                    ds = (p * (_dot(v, do_all, _NT) - delta)).astype(q.dtype)
+                    dv_acc[at, :] += _dot(p.astype(do.dtype), do_all)
+                    dk_acc[at, :] += _dot(ds, q_all)
+                    for n, (_, h) in enumerate(run):
+                        dqt_ref[qb, h.rows, part] += _dot(
+                            k[:, h.kv], ds[:, n * nq:(n + 1) * nq], _TN)
 
     if window:
         _in_the_window(
@@ -509,9 +642,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
                     scale: Optional[float] = None, block_q: int = 1024,
                     block_k: int = 1024, interpret: Optional[bool] = None,
                     window: int = 0):
-    """Blockwise attention via Pallas of q, k, v [batch, length, heads, d].
-    Falls back to XLA attention when the shape does not tile (length %
-    block != 0; logged once per shape on TPU).
+    """Blockwise attention via Pallas of q [batch, length, heads, d] and
+    k, v [batch, length, kv_heads, d], kv_heads dividing heads: a kv head
+    serves the heads // kv_heads query heads that follow one another, and
+    is read, and its gradient written, once for them all.  Falls back to
+    XLA attention when the shape does not tile (length % block != 0;
+    logged once per shape on TPU).
 
     `window` (causal only): a position attends the last `window` positions
     alone, its own among them.  The kernels then visit, of a q block's kv
@@ -532,6 +668,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
     tile skipping, f32 VMEM accumulators) — never materializing [L, L]."""
     if window and not causal:
         raise ValueError("a window is a causal attention's")
+    if q.shape[2] % k.shape[2] or k.shape != v.shape:
+        raise ValueError(f"{q.shape[2]} query heads are no whole groups of "
+                         f"k {k.shape} and v {v.shape}")
     if window >= k.shape[1]:
         window = 0      # every position sees all that came before it
     return _flash(q, k, v, causal, scale, block_q, block_k, interpret,
@@ -545,14 +684,19 @@ def mesh_flash_attention(q, k, v, *, mesh=None, causal: bool = True,
     GSPMD cannot partition a Mosaic custom call ("Mosaic kernels cannot be
     automatically partitioned"), so on more than one device the kernel
     runs per shard inside shard_map: batch split over the data axes and
-    heads over tensor, the two dims attention is independent across.  A
-    mesh with a seq axis rides ring attention instead."""
+    heads over tensor, the two dims attention is independent across: whole
+    groups to a shard, k and v split over their own kv heads by the axis
+    that splits q's heads.  A mesh with a seq axis rides ring attention
+    instead."""
     if mesh is None or mesh.size == 1:
         return flash_attention(q, k, v, causal=causal, window=window)
     if mesh.shape.get("seq", 1) > 1:
         if window:
             raise NotImplementedError("ring attention has no window")
         from ray_tpu.ops.ring_attention import ring_attention
+        # the ring's steps take K and V at the query heads' count
+        k, v = (jnp.repeat(x, q.shape[2] // x.shape[2], axis=2)
+                for x in (k, v))
         return ring_attention(q, k, v, mesh=mesh, causal=causal)
     # The shards' edge is crossed [batch, length, heads x d] wide, as the
     # kernels read: a [batch, length, heads, 64] value that stands on its
@@ -560,6 +704,12 @@ def mesh_flash_attention(q, k, v, *, mesh=None, causal: bool = True,
     # sides.  Whole heads to a shard, as the split of `heads` gave them.
     spec = logical_to_spec(("batch", "length", "heads"), mesh=mesh)
     d = q.shape[-1]
+    over = spec[2] if len(spec) > 2 and spec[2] else ()
+    shards = math.prod(mesh.shape[a] for a in
+                       ((over,) if isinstance(over, str) else over))
+    if k.shape[2] % shards:
+        raise ValueError(f"{shards} shards of the heads would cut the groups "
+                         f"of {k.shape[2]} kv heads")
 
     def of_a_shard(*wide):
         out = flash_attention(*(x.reshape(*x.shape[:2], -1, d) for x in wide),
@@ -634,20 +784,25 @@ def _flash_tile(block: int, most: int) -> int:
 
 class _FlashPlan(NamedTuple):
     """What a call's shapes decide: the grid's blocks along the length and
-    across the heads' columns, and whether to run the kernels under the
-    interpreter."""
+    across the heads' columns, how many of a group's heads a grid step
+    takes, and whether to run the kernels under the interpreter."""
     block_q: int
     block_k: int
     causal: bool
     scale: float
     interpret: bool
     d: int              # columns of a head
-    width: int          # columns of the arrays: heads x d
+    width: int          # columns of q: heads x d
     window: int = 0     # positions a q position sees (0: all before it)
+    group: int = 1      # query heads a kv head of the kernels' serves
+    fwd_part: int = 1   # of them a grid step of the forward takes,
+    bwd_part: int = 1   # and one of the backward (which holds their dq)
+    spread: int = 1     # times K and V are repeated before the kernels
 
     @property
     def lanes(self) -> int:
-        """Columns of a block: whole heads, two of 64 to fill 128 lanes."""
+        """Columns of a kv-side block: whole kv heads, two of 64 to fill
+        128 lanes; a q-side block is a part of the group times that."""
         return max(self.d, 128)
 
     @property
@@ -656,38 +811,97 @@ class _FlashPlan(NamedTuple):
 
     @property
     def column_blocks(self) -> int:
-        """The last is not whole where the heads are odd at 64: the kernels
-        select what a copy leaves past the last column to zero."""
-        return pl.cdiv(self.width, self.lanes)
+        """Of K and V.  The last is not whole where the heads are odd at
+        64: the kernels select what a copy leaves past the last column to
+        zero."""
+        return pl.cdiv(self.width // self.group, self.lanes)
 
 
 def _flash_plan(q, k, causal, scale, block_q, block_k, interpret,
                 window=0):
-    """The plan for q, k of [batch, length, heads, d], or None where the
-    shapes do not tile or a column block's dq does not fit VMEM (the caller
-    takes the XLA reference)."""
-    (_, q_len, h, d), kv_len = q.shape, k.shape[1]
+    """The plan for q [batch, length, heads, d] and k [batch, length,
+    kv_heads, d], or None where the shapes do not tile or not one head's
+    dq fits VMEM (the caller takes the XLA reference).  A group too large
+    for VMEM is walked in parts, the largest that fit.  A group of heads
+    narrower than a block's 128 lanes (two kv heads a block, their groups'
+    lanes not aligned with their own) is `spread`: K and V repeated to the
+    query heads' count, as every group was before PR 63, and the kernels
+    those of a group of one."""
+    (_, q_len, h, d), (_, kv_len, kv_heads, _) = q.shape, k.shape
     block_q, block_k = _fit_blocks(q_len, kv_len, block_q, block_k)
     if causal:          # square blocks: the diagonal crosses equal indices
         block_q = block_k = min(block_q, block_k)
     if interpret is None:
         interpret = _interpret_kernels()
+    spread = h // kv_heads if d < 128 else 1
     plan = _FlashPlan(block_q, block_k, causal,
                       scale if scale is not None else 1.0 / np.sqrt(d),
-                      interpret, d, h * d, window)
+                      interpret, d, h * d, window, h // kv_heads // spread,
+                      spread=spread)
+
+    def part(vmem, pairs):
+        return max((n for n in range(1, plan.group + 1)
+                    if plan.group % n == 0
+                    and (n == 1 or n * block_q * block_k * plan.lanes
+                         <= 128 * pairs)
+                    and _flash_scoped(vmem(n)) <= _FLASH_VMEM_LIMIT),
+                   default=0)
+
+    plan = plan._replace(
+        fwd_part=part(lambda n: _flash_fwd_vmem(plan, n, q.dtype),
+                      _FLASH_FWD_PAIRS),
+        bwd_part=part(lambda n: _flash_bwd_vmem(plan, n, q_len, kv_len,
+                                                q.dtype), _FLASH_BWD_PAIRS))
     if (not _use_pallas(q_len, kv_len, d, block_q, block_k, causal)
-            or 2 * _flash_dq_bytes(q_len, plan.lanes, q.dtype)
-            > _FLASH_VMEM_LIMIT):
+            or not plan.fwd_part or not plan.bwd_part):
         if not interpret:
             _log_reference_path("flash_attention", (q.shape, k.shape))
         return None
     return plan
 
 
-def _flash_dq_bytes(q_len, lanes, dtype):
-    """VMEM the backward holds for a column block's dq: dq^T in float32 and
-    the output block in two buffers."""
-    return q_len * lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+def _flash_fwd_tiles(plan, part):
+    """(q positions, kv positions, heads) of a score product of the forward
+    at `part` of a group's heads a grid step: the tile halved down to 256
+    until the step's heads fit in `_FLASH_FWD_COLUMNS` columns, and as
+    many of them a product as do."""
+    most = _FLASH_FWD_TILE
+    while most > 256 and most * plan.heads * part > _FLASH_FWD_COLUMNS:
+        most //= 2
+    bq, bk = _flash_tile(plan.block_q, most), _flash_tile(plan.block_k, most)
+    return bq, bk, plan.heads * min(part, max(
+        1, _FLASH_FWD_COLUMNS // (bq * plan.heads)))
+
+
+def _flash_scoped(held):
+    """Scoped VMEM that a call holding `held` bytes asks for, which is what
+    the plan holds against `_FLASH_VMEM_LIMIT`: twice those bytes, and no
+    less than the compiler's default beside them for the blocks and a
+    tile's values (a group of 4 at 2,048 positions, 6 MiB held, was refused
+    on the chip when it asked for 12 where the default gives 16)."""
+    return max(2 * held, held + _FLASH_VMEM_DEFAULT)
+
+
+def _flash_fwd_vmem(plan, part, dtype):
+    """Bytes the forward's call holds at `part` heads a grid step
+    (`_flash_scoped`): O^T in float32, once more while it is turned round,
+    the blocks of q and the result in two buffers each, and the scores and
+    probabilities of a tile beyond those of a kv block's own heads.  0: the
+    compiler's default holds a kv block's own heads."""
+    if part == 1:
+        return 0
+    bq, bk, wide = _flash_fwd_tiles(plan, part)
+    return (plan.block_q * part * plan.lanes
+            * (8 + 4 * jnp.dtype(dtype).itemsize)
+            + 8 * bk * bq * (wide - plan.heads))
+
+
+def _flash_bwd_vmem(plan, part, q_len, kv_len, dtype):
+    """The same of the backward's call: dq^T of the whole length in float32
+    and the output block in two buffers, and dk and dv of the whole length,
+    float32, where they gather over the parts of a group."""
+    return (q_len * part * plan.lanes * (4 + 2 * jnp.dtype(dtype).itemsize)
+            + (8 * kv_len * plan.lanes if part < plan.group else 0))
 
 
 def _flash_call(plan, kernel, vmem=0, **kwargs):
@@ -695,43 +909,56 @@ def _flash_call(plan, kernel, vmem=0, **kwargs):
     trace reader keys on (`benchmark/readers.py::flash_roofline`), the
     calls with a window one of their own."""
     return pl.pallas_call(
-        functools.partial(kernel, d=plan.d, width=plan.width,
+        functools.partial(kernel, d=plan.d, width=plan.width // plan.group,
                           causal=plan.causal, scale=plan.scale,
                           window=plan.window),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             # the default 16 MiB holds the blocks and a dq of 4 MiB
-            vmem_limit_bytes=2 * vmem if vmem > 4 * 1024 * 1024 else None),
+            vmem_limit_bytes=(_flash_scoped(vmem) if vmem > 4 * 1024 * 1024
+                              else None)),
         interpret=plan.interpret,
         name="window_flash_attention" if plan.window else "flash_attention",
         **kwargs)
 
 
-def _flash_specs(plan, q_at, kv_at):
-    """Block specs of a grid (batch x column block, a, b): a q-side array
-    and a kv-side array [batch, length, heads x d], read where the model
-    left them, and a q-side row of float32 a head, whose blocks along the
-    length are `q_at(a, b)` and `kv_at(a, b)`."""
-    n = plan.column_blocks
-    return (pl.BlockSpec((None, plan.block_q, plan.lanes),
-                         lambda i, a, b: (i // n, q_at(a, b), i % n)),
+def _flash_specs(plan, part, blocks, q_at, kv_at):
+    """Block specs of a grid (batch x kv column block, a, b): a q-side
+    array [batch, length, heads x d] and a kv-side array [batch, length,
+    kv_heads x d], read where the model left them, a q-side row of float32
+    a head, whose blocks along the length are `q_at(a, b)` and `kv_at(a,
+    b)`, and `column(i, a)`, the q-side column block of a grid step.
+    Where a grid step takes a `part` of the group and not all of it, the
+    second axis is (part, a), `blocks` of a to a part."""
+    n, parts = plan.column_blocks, plan.group // part
+    column, rows, block = (lambda i, a: i % n), (lambda i, a: i), (lambda a: a)
+    if parts > 1:
+        column = lambda i, a: i % n * parts + a // blocks
+        rows = lambda i, a: i * parts + a // blocks
+        block = lambda a: a % blocks
+    return (pl.BlockSpec((None, plan.block_q, part * plan.lanes),
+                         lambda i, a, b: (i // n, q_at(block(a), b),
+                                          column(i, a))),
             pl.BlockSpec((None, plan.block_k, plan.lanes),
-                         lambda i, a, b: (i // n, kv_at(a, b), i % n)),
-            pl.BlockSpec((plan.heads, 1, plan.block_q),
-                         lambda i, a, b: (i, 0, q_at(a, b))))
+                         lambda i, a, b: (i // n, kv_at(block(a), b), i % n)),
+            pl.BlockSpec((part * plan.heads, 1, plan.block_q),
+                         lambda i, a, b: (rows(i, a), 0,
+                                          q_at(block(a), b))),
+            column)
 
 
 def _flash_rows(plan, batch, q_len):
     """Shape of a float32 row a head and q position (the logsumexp, delta):
     a column block's heads together, a head past the arrays' last among
     them where the last block is not whole."""
-    return (batch * plan.column_blocks * plan.heads, 1, q_len)
+    return (batch * plan.column_blocks * plan.group * plan.heads, 1, q_len)
 
 
 def _flash_fwd_heads(plan, q, k, v):
     """out [batch, q_len, heads x d] and the logsumexp (`_flash_rows`) of
-    q, k, v [batch, length, heads x d]."""
+    q [batch, length, heads x d] and k, v [batch, length, kv_heads x d]."""
     (batch, q_len, _), kv_len = q.shape, k.shape[1]
+    part, n_blocks = plan.fwd_part, q_len // plan.block_q
     # A kv block past the q block's own is not copied in: the index stays
     # at the last block the pair needs, and an unchanged block is kept.
     # Under a window the last axis is the blocks the window reaches, the
@@ -741,57 +968,65 @@ def _flash_fwd_heads(plan, q, k, v):
     if plan.window:
         steps = _window_steps(plan.window, plan.block_k, steps)
         kv_at = lambda a, b: jnp.maximum(a - (steps - 1) + b, 0)
-    q_spec, kv_spec, row_spec = _flash_specs(plan, lambda a, b: a, kv_at)
+    q_spec, kv_spec, row_spec, _ = _flash_specs(plan, part, n_blocks,
+                                                lambda a, b: a, kv_at)
+    bq, bk, wide = _flash_fwd_tiles(plan, part)
     return _flash_call(
         plan, functools.partial(
-            _flash_kernel, bq=_flash_tile(plan.block_q, _FLASH_FWD_TILE),
-            bk=_flash_tile(plan.block_k, _FLASH_FWD_TILE),
-            n_blocks=q_len // plan.block_q),
-        grid=(batch * plan.column_blocks, q_len // plan.block_q, steps),
+            _flash_kernel, bq=bq, bk=bk, n_blocks=n_blocks,
+            parts=plan.group // part, wide=wide),
+        vmem=_flash_fwd_vmem(plan, part, q.dtype),
+        grid=(batch * plan.column_blocks, plan.group // part * n_blocks,
+              steps),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=[q_spec, row_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(_flash_rows(plan, batch, q_len),
                                         jnp.float32)],
         scratch_shapes=[
-            pltpu.VMEM((plan.heads, 1, plan.block_q), jnp.float32),
-            pltpu.VMEM((plan.heads, 1, plan.block_q), jnp.float32),
-            pltpu.VMEM((plan.lanes, plan.block_q), jnp.float32)],
+            pltpu.VMEM((part * plan.heads, 1, plan.block_q), jnp.float32),
+            pltpu.VMEM((part * plan.heads, 1, plan.block_q), jnp.float32),
+            pltpu.VMEM((part * plan.lanes, plan.block_q), jnp.float32)],
     )(q, k, v)
 
 
 def _flash_bwd_heads(plan, q, k, v, do, out, lse):
-    """dq, dk, dv of q, k, v, dO and the forward's out [batch, length,
-    heads x d] and logsumexp (`_flash_rows`)."""
+    """dq [batch, length, heads x d] and dk, dv [batch, length, kv_heads x
+    d] of q, k, v, dO and the forward's out and logsumexp (`_flash_rows`)."""
     (batch, q_len, _), kv_len = q.shape, k.shape[1]
+    part, parts = plan.bwd_part, plan.group // plan.bwd_part
     # Nor is a q block before the kv block's own; under a window the last
     # axis is the q blocks that see the kv block, its own first.
     steps = n_blocks = q_len // plan.block_q
+    kv_blocks = kv_len // plan.block_k
     q_at = lambda a, b: jnp.maximum(a, b) if plan.causal else b
     if plan.window:
         steps = _window_steps(plan.window, plan.block_q, n_blocks)
         q_at = lambda a, b: jnp.minimum(a + b, n_blocks - 1)
-    q_spec, kv_spec, row_spec = _flash_specs(plan, q_at, lambda a, b: a)
+    q_spec, kv_spec, row_spec, column = _flash_specs(
+        plan, part, kv_blocks, q_at, lambda a, b: a)
     n = plan.column_blocks
+    # dk and dv of a kv block, of all of them where they gather over parts
+    gathered = ((kv_blocks,) if parts > 1 else ()) + (plan.block_k,
+                                                      plan.lanes)
     return _flash_call(
         plan, functools.partial(
             _flash_bwd_kernel, bq=_flash_tile(plan.block_q, _FLASH_BWD_TILE),
             bk=_flash_tile(plan.block_k, _FLASH_BWD_TILE),
-            least=_FLASH_BWD_CROSSED // plan.heads),
-        vmem=_flash_dq_bytes(q_len, plan.lanes, q.dtype),
-        grid=(batch * n, kv_len // plan.block_k, steps),
+            least=max(_FLASH_BWD_CROSSED // plan.heads, 128), parts=parts),
+        vmem=_flash_bwd_vmem(plan, part, q_len, kv_len, q.dtype),
+        grid=(batch * n, parts * kv_blocks, steps),
         in_specs=[q_spec, kv_spec, kv_spec, q_spec, q_spec, row_spec],
-        out_specs=[pl.BlockSpec((None, q_len, plan.lanes),
-                                lambda i, a, b: (i // n, 0, i % n)),
+        out_specs=[pl.BlockSpec((None, q_len, part * plan.lanes),
+                                lambda i, a, b: (i // n, 0, column(i, a))),
                    kv_spec, kv_spec],
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
         scratch_shapes=[
-            pltpu.VMEM((q_len // plan.block_q, plan.lanes, plan.block_q),
-                       jnp.float32),
-            pltpu.VMEM((plan.block_k, plan.lanes), jnp.float32),
-            pltpu.VMEM((plan.block_k, plan.lanes), jnp.float32)],
+            pltpu.VMEM((n_blocks, part * plan.lanes, plan.block_q),
+                       jnp.float32)] + 2 * [
+            pltpu.VMEM(gathered, jnp.float32)],
     )(q, k, v, do, out, lse)
 
 
@@ -799,6 +1034,12 @@ def _heads_side_by_side(x):
     """[batch, length, heads, d] as the kernels take it, [batch, length,
     heads x d]: the same bytes, no copy."""
     return x.reshape(*x.shape[:2], -1)
+
+
+def _spread(x, times):
+    """k or v [batch, length, kv_heads, d] with every head `times` times,
+    one after the other (query head h reads kv head h // times)."""
+    return x if times == 1 else jnp.repeat(x, times, axis=2)
 
 
 def _flash_forward_impl(q, k, v, causal, scale, block_q, block_k, interpret,
@@ -809,7 +1050,8 @@ def _flash_forward_impl(q, k, v, causal, scale, block_q, block_k, interpret,
         return reference_attention(q, k, v, causal=causal, scale=scale,
                                    window=window), None
     out, lse = _flash_fwd_heads(
-        plan, *(_heads_side_by_side(x) for x in (q, k, v)))
+        plan, _heads_side_by_side(q),
+        *(_heads_side_by_side(_spread(x, plan.spread)) for x in (k, v)))
     return out.reshape(q.shape), lse
 
 
@@ -817,9 +1059,16 @@ def _flash_backward_impl(q, k, v, out, lse, g, causal, scale, block_q,
                          block_k, interpret, window=0):
     plan = _flash_plan(q, k, causal, scale, block_q, block_k, interpret,
                        window)
-    grads = _flash_bwd_heads(
-        plan, *(_heads_side_by_side(x) for x in (q, k, v, g, out)), lse)
-    return tuple(dx.reshape(x.shape) for dx, x in zip(grads, (q, k, v)))
+    dq, dk, dv = _flash_bwd_heads(
+        plan, _heads_side_by_side(q),
+        *(_heads_side_by_side(_spread(x, plan.spread)) for x in (k, v)),
+        *(_heads_side_by_side(x) for x in (g, out)), lse)
+    if plan.spread > 1:     # a kv head's gradient is its copies' sum
+        dk, dv = (jnp.sum(dx.reshape(*k.shape[:3], plan.spread, -1),
+                          axis=3, dtype=jnp.float32).astype(dx.dtype)
+                  for dx in (dk, dv))
+    return tuple(dx.reshape(x.shape) for dx, x in zip((dq, dk, dv),
+                                                      (q, k, v)))
 
 
 # ---------------------------------------------------------------------------

@@ -2,7 +2,10 @@
 reference, on the CPU interpreter: tiles under, on and (non-causal) over the
 diagonal, a head of one block and of many, tiles smaller than a block, and
 how the heads lie in a block of 128 columns: two of 64 side by side, one
-alone in the last block of an odd count, one of 128 or 256 a block."""
+alone in the last block of an odd count, one of 128 or 256 a block, and a kv
+head's group of query heads, whose products are one product each."""
+
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -29,23 +32,26 @@ def _grads(fn, g):
 
 
 def _check(q_len, kv_len, blocks, d, dtype, causal, scale=None, heads=None,
-           apart=False, window=0):
-    """out, dq, dk and dv of `heads` heads, each head against the
+           apart=False, window=0, kv_heads=None):
+    """out, dq, dk and dv of `heads` heads over `kv_heads` of K and V (the
+    heads': no groups), each head (dk, dv: each kv head) against the
     reference's: float32 to 2e-5, bf16 to two ulps at the head's largest
-    value.  With `apart`, v and the cotangent of a head are a hundred times
-    its neighbour's (1, 100, 0.01, 1, ...): a head that reached its
-    neighbour's lanes would drown it, and there alone the float32 limit
-    too goes by the head's largest value (values of 100 do not round to
-    2e-5)."""
+    value.  With `apart`, the cotangent of a head is a hundredth of its
+    neighbour's and v of a kv head a hundred times its neighbour's (1,
+    100, 0.01, 1, ...): a head that reached its neighbour's lanes, or its
+    neighbour's columns of a product they share, would drown it, and there
+    alone the float32 limit too goes by the head's largest value (values
+    of 100 do not round to 2e-5)."""
     if heads is None:
         heads = 2 if q_len <= 512 else 1
+    kv_heads = kv_heads or heads
     q, k, v, g = (jax.random.normal(
         jax.random.fold_in(jax.random.key(q_len + d), i),
-        (1, kv_len if i in (1, 2) else q_len, heads, d), jnp.float32)
-        for i in range(4))
+        (1, kv_len, kv_heads, d) if i in (1, 2) else (1, q_len, heads, d),
+        jnp.float32) for i in range(4))
     if apart:
-        size = (100.0 ** ((jnp.arange(heads) + 1) % 3 - 1))[:, None]
-        v, g = v * size, g / size
+        size = lambda n: (100.0 ** ((jnp.arange(n) + 1) % 3 - 1))[:, None]
+        v, g = v * size(kv_heads), g / size(heads)
     q, k, v, g = (x.astype(dtype) for x in (q, k, v, g))
     blocks = dict(zip(("block_q", "block_k"), blocks)) if blocks else {}
 
@@ -62,7 +68,7 @@ def _check(q_len, kv_len, blocks, d, dtype, causal, scale=None, heads=None,
     for name, xs, ys in zip(("out", "dq", "dk", "dv"), got, want):
         xs, ys = (np.asarray(a, np.float32) for a in (xs, ys))
         assert xs.shape == ys.shape and np.isfinite(xs).all(), name
-        for head in range(heads):
+        for head in range(xs.shape[2]):
             x, y = xs[:, :, head], ys[:, :, head]
             if dtype == jnp.float32:
                 atol = 2e-5 * (max(1.0, float(np.abs(y).max())) if apart
@@ -208,6 +214,230 @@ def test_flash_gradients_of_a_ring_step_at_heads_apart(heads, dtype):
            apart=True)
 
 
+# (heads, kv heads, d, length, block, "causal" / "full" / a window): a kv
+# head's group of query heads takes a grid step, their scores ONE product
+# [kv, heads x q] of the shared K tile, as are V^T P, dP, dq^T, and dv and dk,
+# which contract over the group's q positions.  A group of 64-wide heads
+# (narrower than a block's lanes; llama-1b's 32 over 4) runs the kernels of
+# a group of one over K and V repeated, dk and dv summed after them.
+GROUPS = [
+    (4, 2, 128, 256, 128, "causal"),        # two kv heads, groups of 2
+    (4, 1, 128, 256, 128, "full"),
+    (8, 1, 128, 256, 128, "causal"),
+    (8, 2, 128, 512, 128, 200),             # the edge crosses a tile
+    (8, 1, 128, 512, 256, 255),
+    # tiles of 512 hold two heads a product: a group of 4 is two products,
+    # on the diagonal the backward's sub-tiles of 128
+    (4, 1, 128, 1024, 512, "causal"),
+    (2, 1, 256, 256, 128, "causal"),
+    (4, 2, 64, 256, 128, "causal"), (8, 1, 64, 256, 128, "full"),
+    (4, 1, 64, 512, 128, 200),
+]
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads,kv_heads,d,length,block,kind", GROUPS,
+                         ids=["-".join(map(str, g)) for g in GROUPS])
+def test_flash_of_a_kv_heads_group_of_query_heads(heads, kv_heads, d, length,
+                                                  block, kind, dtype):
+    like = [jax.ShapeDtypeStruct((1, length, n, d), dtype)
+            for n in (heads, kv_heads)]
+    plan = A._flash_plan(*like, kind != "full", None, block, block, True)
+    if d < 128:
+        assert (plan.spread, plan.group, plan.column_blocks) == (
+            heads // kv_heads, 1, heads * d // 128)
+        assert plan._replace(spread=1) == A._flash_plan(
+            like[0], like[0], kind != "full", None, block, block, True)
+    else:
+        assert (plan.group, plan.fwd_part, plan.bwd_part, plan.spread,
+                plan.column_blocks) == (heads // kv_heads,) * 3 + (1, kv_heads)
+    assert A._flash_rows(plan, 1, length) == (heads, 1, length)
+    _check(length, length, (block, block), d, dtype, kind != "full",
+           heads=heads, kv_heads=kv_heads, apart=True,
+           window=kind if isinstance(kind, int) else 0)
+
+
+# (what shrinks, forward part, backward part) of a group of 4: VMEM that
+# holds two heads' dq alone splits the backward, and straight-line code
+# held to two blocks' pairs forward and one backward splits both, unlike
+# (the train cell's plan: the forward 4 of 8, the backward 2): the forward
+# then walks `j % n_blocks` with the part on the grid's second axis, and
+# the logsumexp rows it writes two heads a step are read one a step.
+PARTS = [("vmem", 4, 2), ("pairs", 2, 1)]
+
+
+@pytest.mark.parametrize("window", [0, 200], ids=["causal", "window"])
+@pytest.mark.parametrize("what,fwd_part,bwd_part", PARTS,
+                         ids=[p[0] for p in PARTS])
+def test_a_group_too_large_for_a_grid_step_is_walked_in_parts(
+        monkeypatch, what, fwd_part, bwd_part, window):
+    """A group of 4 whose heads do not all fit a grid step goes a part of
+    them a step, the forward's and the backward's parts each their own: dk
+    and dv gather over the backward's parts in scratch."""
+    like = [jax.ShapeDtypeStruct((1, 512, n, 128), jnp.float32)
+            for n in (8, 2)]
+    whole = A._flash_plan(*like, True, None, 128, 128, True)
+    assert (whole.group, whole.fwd_part, whole.bwd_part) == (4, 4, 4)
+    if what == "vmem":
+        monkeypatch.setattr(A, "_FLASH_VMEM_LIMIT", A._flash_scoped(
+            A._flash_bwd_vmem(whole, 2, 512, 512, jnp.float32)))
+    else:
+        monkeypatch.setattr(A, "_FLASH_FWD_PAIRS", 2 * 128 * 128)
+        monkeypatch.setattr(A, "_FLASH_BWD_PAIRS", 128 * 128)
+    plan = A._flash_plan(*like, True, None, 128, 128, True)
+    assert (plan.group, plan.fwd_part, plan.bwd_part) == (4, fwd_part,
+                                                          bwd_part)
+    A.flash_attention.clear_cache()
+    try:
+        _check(512, 512, (128, 128), 128, jnp.float32, True, heads=8,
+               kv_heads=2, apart=True, window=window)
+    finally:
+        A.flash_attention.clear_cache()
+
+
+# (heads, kv heads, d, heads a grid step forward and backward, the forward's
+# tile and heads a product, MiB of VMEM forward and backward) at 8,192
+# positions: the MoE train cell's calls, llama3-8b's, Gemma 2 9B's
+CELLS = [(32, 4, 128, 4, 2, (256, 256, 4), 9.5, 16 + 8),
+         (32, 8, 128, 4, 2, (256, 256, 4), 9.5, 16 + 8),
+         (16, 8, 256, 2, 1, (512, 512, 2), 8 + 2, 16 + 16)]
+
+
+@pytest.mark.parametrize("heads,kv_heads,d,fwd_part,bwd_part,tiles,fwd,bwd",
+                         CELLS, ids=["-".join(map(str, c[:3])) for c in CELLS])
+def test_a_groups_grid_step_holds_what_its_code_and_vmem_allow(
+        monkeypatch, heads, kv_heads, d, fwd_part, bwd_part, tiles, fwd, bwd):
+    """A grid step's straight-line code walks a block of 1,024 x 1,024
+    pairs for each of its heads of 128 columns, four forward and two
+    backward (`_FLASH_FWD_PAIRS`: a kernel past 65,536 bundles runs at half
+    its speed), half as many heads of 256, whatever VMEM would hold;
+    shorter blocks take the whole group.  And the scoped VMEM a call asks
+    for is what the plan held against `_FLASH_VMEM_LIMIT`, all of it: the
+    backward's dq^T AND the dk and dv it gathers over the parts."""
+    length, mib = 8192, 2 ** 20
+
+    def like(length):
+        return [jax.ShapeDtypeStruct((2, length, n, d), jnp.bfloat16)
+                for n in (heads, kv_heads)]
+
+    cell = A._flash_plan(*like(length), True, None, 1024, 1024, True)
+    assert (cell.group, cell.fwd_part, cell.bwd_part, cell.column_blocks,
+            cell.lanes, cell.heads) == (heads // kv_heads, fwd_part, bwd_part,
+                                        kv_heads, d, 1)
+    assert A._flash_rows(cell, 2, length) == (2 * heads, 1, length)
+    assert A._flash_fwd_tiles(cell, fwd_part) == tiles
+    assert A._flash_fwd_tiles(cell, 1) == (512, 512, 1)
+    assert A._flash_fwd_vmem(cell, fwd_part, jnp.bfloat16) == fwd * mib
+    assert A._flash_bwd_vmem(cell, bwd_part, length, length,
+                             jnp.bfloat16) == bwd * mib
+    short = A._flash_plan(*like(2048), True, None, 256, 256, True)
+    assert (short.fwd_part, short.bwd_part) == (cell.group,) * 2
+    # what the calls ask for, forward and backward
+    asked = []
+    call = A.pl.pallas_call
+    monkeypatch.setattr(A.pl, "pallas_call", lambda *a, **kw: (
+        asked.append(kw["compiler_params"].vmem_limit_bytes),
+        call(*a, **kw))[1])
+    q, k = (jax.ShapeDtypeStruct((2, length, n * d), jnp.bfloat16)
+            for n in (heads, kv_heads))
+    lse = jax.ShapeDtypeStruct(A._flash_rows(cell, 2, length), jnp.float32)
+    jax.eval_shape(lambda *x: A._flash_fwd_heads(cell, *x), q, k, k)
+    jax.eval_shape(lambda *x: A._flash_bwd_heads(cell, *x), q, k, k, q, q,
+                   lse)
+    assert asked == [(fwd + 16) * mib, 2 * bwd * mib]
+    assert max(asked) <= A._FLASH_VMEM_LIMIT
+    # a short sequence's few bytes ask for no less than the default beside
+    # them (12 MiB for this backward was refused on the chip), fewer none
+    assert A._flash_scoped(6 * mib) == 22 * mib
+    del asked[:]
+    q, k = (jax.ShapeDtypeStruct((1, 2048, n * d), jnp.bfloat16)
+            for n in (heads, kv_heads))
+    lse = jax.ShapeDtypeStruct(A._flash_rows(cell, 1, 2048), jnp.float32)
+    jax.eval_shape(lambda *x: A._flash_bwd_heads(cell, *x), q, k, k, q, q,
+                   lse)
+    held = A._flash_bwd_vmem(cell, bwd_part, 2048, 2048, jnp.bfloat16)
+    assert asked == [held + 16 * mib] and held == bwd * mib / 4
+
+
+def test_heads_that_are_no_whole_groups_are_refused():
+    q, k = jnp.ones((1, 256, 3, 128)), jnp.ones((1, 256, 2, 128))
+    with pytest.raises(ValueError, match="whole groups"):
+        flash_attention(q, k, k)
+
+
+def test_a_mesh_splits_whole_groups_to_a_shard():
+    """Two shards of the heads take a kv head and its two query heads each;
+    four would cut the groups."""
+    q, k, v = (jax.random.normal(jax.random.key(i), (2, 256, n, 128))
+               for i, n in enumerate((4, 2, 2)))
+    mesh = jax.make_mesh((2,), ("tensor",), devices=jax.devices()[:2])
+    got = jax.jit(lambda q, k, v: A.mesh_flash_attention(
+        q, k, v, mesh=mesh, causal=True))(q, k, v)
+    np.testing.assert_allclose(got, reference_attention(q, k, v),
+                               atol=2e-5, rtol=2e-5)
+    mesh = jax.make_mesh((4,), ("tensor",), devices=jax.devices()[:4])
+    with pytest.raises(ValueError, match="cut the groups"):
+        A.mesh_flash_attention(q, k, v, mesh=mesh)
+
+
+def test_the_model_hands_k_and_v_over_at_their_own_heads():
+    """`heads_attention` at `mellum-nano`'s sizes (4 heads over 2 kv heads):
+    what reaches `flash_attention` is K and V at 2 heads, and nothing
+    beside the call spreads an array over a group (a repeat is a
+    `broadcast_in_dim` to [.., kv heads, group, d])."""
+    from ray_tpu.models import decoder, mellum
+
+    c = mellum.CONFIGS["mellum-nano"]
+    sizes = c.sizes(mellum.WINDOW)
+    params = mellum.init_params(c, jax.random.key(0))["blocks"]
+    h = jnp.ones((2, 32, c.d_model), jnp.float32)
+    jaxpr = jax.make_jaxpr(lambda h, p: decoder.heads_attention(
+        h, p, mellum.spec(c), sizes, None))(
+            h, {name: w[0] for name, w in params.items()}).jaxpr
+    (call,) = [e for e in jaxpr.eqns
+               if e.params.get("name") == "flash_attention"]
+    assert [v.aval.shape for v in call.invars] == [
+        (2, 32, n, c.head_dim) for n in (c.n_heads, c.n_kv_heads,
+                                         c.n_kv_heads)]
+    group = c.n_heads // c.n_kv_heads
+    spread = [e for e in jaxpr.eqns if e.primitive.name == "broadcast_in_dim"
+              and group in e.outvars[0].aval.shape[2:]
+              and e.outvars[0].aval.ndim > 4]
+    assert group > 1 and not spread, spread
+
+
+# sha256 of `_jaxpr_text` below at the tree before PR 63 (`git archive
+# 456b160`, this same function): a call with no groups is the kernels that
+# tree ran, forward and backward, equation for equation.  A PR that changes
+# the kernels on purpose records its own.
+NO_GROUPS = [
+    ((12, 64, 1024), "24029c4ba04513ad"), ((25, 64, 1024), "34958b12cb8ecf11"),
+    ((16, 128, 2048), "e035f4ee2c64a8a6"), ((4, 256, 1024), "965240b167be5497"),
+    ((2, 64, 2048, 700), "65a9d8d5b631a127"),
+    ((2, 128, 2048, 1024), "9d7449cd1af4eb7e"),
+    ((2, 64, 256, 0, jnp.float32, False), "bc0ce44608dd0bb2"),
+]
+
+
+def _jaxpr_text(h, d, length, window=0, dtype=jnp.bfloat16, causal=True):
+    x = jax.ShapeDtypeStruct((2, length, h, d), dtype)
+
+    def step(q, k, v):
+        f = lambda q, k, v: jnp.sum(A.flash_attention(
+            q, k, v, causal=causal, window=window,
+            interpret=False).astype(jnp.float32))
+        return jax.value_and_grad(f, argnums=(0, 1, 2))(q, k, v)
+    return str(jax.make_jaxpr(step)(x, x, x))
+
+
+@pytest.mark.parametrize("case,digest", NO_GROUPS,
+                         ids=["-".join(map(str, c[:4])) for c, _ in NO_GROUPS])
+def test_without_groups_the_kernels_are_those_before_groups(case, digest):
+    assert hashlib.sha256(
+        _jaxpr_text(*case).encode()).hexdigest()[:16] == digest
+
+
 def test_flash_plan_puts_whole_heads_in_blocks_of_128_columns():
     def like(h, d):
         return jax.ShapeDtypeStruct((2, 256, h, d), jnp.bfloat16)
@@ -219,6 +449,9 @@ def test_flash_plan_puts_whole_heads_in_blocks_of_128_columns():
                              True)
         assert (plan.lanes, plan.heads, plan.column_blocks) == (
             lanes, heads, blocks)
+        # as before there were groups, and the whole of a group of one
+        assert plan == A._FlashPlan(256, 256, True, 1.0 / np.sqrt(d), True, d,
+                                    h * d)
         # a float32 row a head of every block, the odd count's phantom too
         assert A._flash_rows(plan, 2, 256) == (2 * blocks * heads, 1, 256)
 
